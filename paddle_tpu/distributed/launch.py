@@ -23,6 +23,7 @@ program (fleet.init with PaddleCloudRoleMaker picks up the same envs).
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import secrets
 import signal
@@ -32,6 +33,27 @@ import sys
 import time
 
 __all__ = ["launch", "main"]
+
+
+def _refuse_shared_chips(n_children: int, backend: str | None) -> None:
+    """A TPU chip belongs to one process: with several children and no CPU
+    pin, every child inherits every local chip and the second one to
+    initialise fails or hangs. One process drives all local chips (a mesh
+    over jax.devices()), so that combination is refused up front. Chips are
+    detected by their device nodes — the launcher itself stays off jax."""
+    backend = (backend or os.environ.get("PADDLE_DIST_BACKEND")
+               or os.environ.get("JAX_PLATFORMS") or "")
+    if n_children <= 1 or backend.split(",")[0].strip().lower() == "cpu":
+        return
+    chips = glob.glob("/dev/accel*") + glob.glob("/dev/vfio/[0-9]*")
+    if chips:
+        raise SystemExit(
+            f"paddle_tpu.distributed.launch: refusing to start {n_children} "
+            f"processes on a host with TPU chips ({len(chips)} device "
+            "node(s)): a chip belongs to ONE process, so the second child "
+            "to initialise would fail or hang. One process drives all "
+            "local chips — run the script directly and build its mesh over "
+            "jax.devices() — or pass --backend=cpu for a CPU-only job.")
 
 
 def _free_port() -> int:
@@ -87,6 +109,7 @@ def launch_ps(args) -> int:
     the reference's procs[i].proc.terminate() for servers)."""
     n_servers = args.server_num
     n_workers = args.worker_num or 1
+    _refuse_shared_chips(n_servers + n_workers, args.backend)
     if args.servers:
         server_eps = [e for e in args.servers.split(",") if e]
         if args.server_num and len(server_eps) != args.server_num:
@@ -193,6 +216,7 @@ def launch(args) -> int:
     if args.server_num or args.worker_num:
         return launch_ps(args)
     n = args.nproc_per_node
+    _refuse_shared_chips(n, args.backend)
     coordinator = args.coordinator or f"{args.node_ip}:{_free_port()}"
     base_port = args.started_port or _free_port()
     endpoints = [f"{args.node_ip}:{base_port + i}" for i in range(n)]

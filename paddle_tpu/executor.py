@@ -208,6 +208,20 @@ class _Compiled:
         # mesh programs: {feed name: NamedSharding} for the DeviceLoader
         # prefetcher, so staged batches already carry the entry's layout
         self.feed_shardings = None
+        # single-process mesh programs: the (ro, rw) NamedShardings the step
+        # was compiled for. State is committed to them BEFORE the call: an
+        # array's mesh is part of its type (jax 0.9.0), so a step first
+        # called with the single-device state a startup run leaves would be
+        # traced and compiled a second time when its own mesh-resident
+        # outputs come back as inputs.
+        self.state_shardings = None
+
+
+def _on_mesh(v, sharding):
+    """`v` committed to `sharding` (itself when it already is)."""
+    if isinstance(v, jax.Array) and v.sharding == sharding:
+        return v
+    return jax.device_put(v, sharding)
 
 
 def _has_host_ops(block) -> bool:
@@ -775,6 +789,20 @@ class Executor:
             feed_vals = [_to_global(v, s) for v, s in zip(feed_vals, feed_sh)]
             ro_vals = tuple(_to_global(v, s) for v, s in zip(ro_vals, ro_sh))
             rw_vals = tuple(_to_global(v, s) for v, s in zip(rw_vals, rw_sh))
+        elif comp.state_shardings is not None:
+            ro_sh, rw_sh = comp.state_shardings
+            placed = tuple(_on_mesh(v, s) for v, s in zip(ro_vals, ro_sh))
+            for n, old, new in zip(comp.ro_names, ro_vals, placed):
+                if new is not old:
+                    scope.set_var(n, new)  # read-only state moves once
+            ro_vals = placed
+            rw_vals = tuple(_on_mesh(v, s) for v, s in zip(rw_vals, rw_sh))
+            # feeds too: a host batch and a batch the DeviceLoader staged
+            # with this entry's shardings must reach jit as one type
+            feed_vals = [
+                v if is_selected_rows(v)
+                else _on_mesh(v, comp.feed_shardings[n])
+                for n, v in zip(feed_names, feed_vals)]
         scope._run_counter += 1
         key = jax.random.PRNGKey(program.random_seed or 0)
         key = jax.random.fold_in(
@@ -1192,10 +1220,8 @@ class Executor:
                 tuple(P() for _ in extra_w),
                 P(),  # async completion token
             )
-            from .ops.collective_ops import compat_shard_map
-
-            sfn = compat_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs)
+            sfn = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                out_specs=out_specs, check_vma=False)
             jfn = jax.jit(sfn, donate_argnums=(2,))
             comp = _Compiled(jfn, feed_names, ro_names, rw_names, fetch_names)
             comp.extra_w = extra_w
@@ -1203,12 +1229,14 @@ class Executor:
 
             comp.feed_shardings = {
                 n: NamedSharding(mesh, _feed_spec(n)) for n in feed_names}
+            state_sh = (tuple(NamedSharding(mesh, P()) for _ in ro_names),
+                        tuple(NamedSharding(mesh, P()) for _ in rw_names))
             if _spans_processes(mesh):
                 comp.global_shardings = (
                     tuple(comp.feed_shardings[n] for n in feed_names),
-                    tuple(NamedSharding(mesh, P()) for _ in ro_names),
-                    tuple(NamedSharding(mesh, P()) for _ in rw_names),
-                )
+                    *state_sh)
+            else:
+                comp.state_shardings = state_sh
             return comp
 
         fn = _lower(block, feed_names, ro_names, rw_names, extra_w, fetch_names)
@@ -1229,4 +1257,6 @@ class Executor:
             comp.feed_shardings = dict(zip(feed_names, in_sh[0]))
             if _spans_processes(mesh):
                 comp.global_shardings = in_sh[:3]
+            else:
+                comp.state_shardings = in_sh[1:3]
         return comp

@@ -128,14 +128,8 @@ _define("attention_force_backend", "",
         "attention_backend decision to this arm ('xla', 'pallas_short', "
         "'pallas_short128', 'flash_bundled') regardless of the tuning DB "
         "and the analytic prior. A forced backend the platform/shape cannot "
-        "run still degrades to the XLA reference at dispatch (so an arm is "
-        "honest about where its kernel engaged). Empty (default) = normal "
-        "three-tier dispatch")
-_define("pallas_xent", False,
-        "route large-vocab hard-label softmax_with_cross_entropy through "
-        "the Pallas TPU kernel (ops/pallas_kernels/xent.py). Default OFF: "
-        "measured 8.5% SLOWER end-to-end than XLA's in-model fusion at "
-        "BERT shapes (PERF.md r5) — kept as a measured-and-retired lever")
+        "run raises at dispatch (an A/B arm never times the reference under "
+        "the kernel's name). Empty (default) = normal three-tier dispatch")
 _define("check_nan_inf", False,
         "run eagerly and validate every op's floating outputs are finite, "
         "raising with op attribution (reference operator.cc:949)")

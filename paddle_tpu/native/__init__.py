@@ -2,29 +2,43 @@
 
 The reference keeps its data layer in C++ (data_feed.cc, data_set.cc); here
 the hot MultiSlot text parser is C compiled at first use with the system
-compiler. Every binding has a pure-Python fallback so the framework still
-works without a toolchain (slower ingest only).
+compiler. The binding has a pure-Python fallback so the framework still
+works without a toolchain, at a much slower ingest: falling back warns, and
+the benches that measure ingest assert `native_available()`.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
+import tempfile
 import threading
+import warnings
 
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "multislot_parser.c")
-_SO = os.path.join(_DIR, "_multislot.so")
 
 _lock = threading.Lock()
 _lib = None
 _build_failed = False
 
 
+def _so_path() -> str:
+    """The library's path carries a hash of its source: a copy or a
+    checkout resets mtimes, so only the content can say whether a binary
+    found on disk was built from the committed `.c`."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_multislot_{digest}.so")
+
+
 def _load():
-    """Compile (if stale) and load the parser library; None if unavailable."""
+    """Compile (if no binary of this source exists) and load the parser
+    library; None, after one warning, if that is not possible."""
     global _lib, _build_failed
     if _lib is not None or _build_failed:
         return _lib
@@ -32,14 +46,26 @@ def _load():
         if _lib is not None or _build_failed:
             return _lib
         try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+            so = _so_path()
+            if not os.path.exists(so):
                 cc = os.environ.get("CC", "cc")
-                subprocess.run(
-                    [cc, "-O2", "-shared", "-fPIC", "-o", _SO + ".tmp", _SRC],
-                    check=True, capture_output=True)
-                os.replace(_SO + ".tmp", _SO)
-            lib = ctypes.CDLL(_SO)
+                # build beside the target, then rename: a concurrent
+                # process never loads a half-written library
+                fd, tmp = tempfile.mkstemp(dir=_DIR, prefix=".build_",
+                                           suffix=".so")
+                os.close(fd)
+                try:
+                    subprocess.run(
+                        [cc, "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
+                        check=True, capture_output=True)
+                    os.replace(tmp, so)
+                finally:
+                    if os.path.exists(tmp):
+                        os.unlink(tmp)
+                for stale in glob.glob(os.path.join(_DIR, "_multislot*.so")):
+                    if stale != so:
+                        os.unlink(stale)
+            lib = ctypes.CDLL(so)
             lib.multislot_count.restype = ctypes.c_longlong
             lib.multislot_count.argtypes = [ctypes.c_char_p]
             lib.multislot_parse.restype = ctypes.c_longlong
@@ -49,8 +75,14 @@ def _load():
                 ctypes.POINTER(ctypes.c_double), ctypes.c_longlong,
             ]
             _lib = lib
-        except (OSError, subprocess.CalledProcessError):
+        except (OSError, subprocess.CalledProcessError) as e:
             _build_failed = True
+            detail = getattr(e, "stderr", b"") or b""
+            warnings.warn(
+                "paddle_tpu.native: could not build/load the C MultiSlot "
+                f"parser ({e!r} {detail.decode(errors='replace')[-500:]}); "
+                "falling back to the pure-Python parser — dataset ingest "
+                "will be MUCH slower", RuntimeWarning, stacklevel=3)
     return _lib
 
 

@@ -245,6 +245,11 @@ DECLARED: list[tuple] = [
     ("watchdog.stalls", COUNTER, "StallError raises", ()),
     ("watchdog.stall", EVENT,
      "watchdog stall dump (what/window/in-flight state)", ()),
+    # -- attention dispatch (ops/attention_ops.py) --------------------------
+    ("attention.dispatches", COUNTER,
+     "traced attention dispatches by kind (dense/paged), the backend the "
+     "decision chose and the one that ran (they differ when a swept "
+     "verdict gave way to the reference)", ("kind", "chosen", "ran")),
     # -- autotuner provenance (tuning/policy.py) ----------------------------
     ("tuning.decisions", COUNTER,
      "decide() resolutions by (op, tier) — tier in "

@@ -22,6 +22,7 @@ QK^T -> softmax -> PV block is the single biggest transformer win
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -52,13 +53,6 @@ def _reference_attention(q, k, v, bias=None, causal=False, sm_scale=1.0):
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), v)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
 def _block_multiple_ok(s: int) -> bool:
     # the bundled kernel wants seq divisible by its block sizes (>=128 lanes)
     return s % 128 == 0
@@ -81,9 +75,48 @@ def _pallas_short128_ok(q_shape, k_shape, bias) -> bool:
 
 
 def _flash_bundled_ok(q_shape, k_shape, dtype) -> bool:
+    from .pallas_kernels import workbench
+
     sq, sk = q_shape[2], k_shape[2]
-    return (_on_tpu() and _block_multiple_ok(sq) and _block_multiple_ok(sk)
-            and dtype != jnp.float64)
+    return (workbench.on_tpu() and _block_multiple_ok(sq)
+            and _block_multiple_ok(sk) and dtype != jnp.float64)
+
+
+def _backend_runnable(backend, q_shape, k_shape, bias, dtype) -> bool:
+    """Can `backend` execute this shape on this platform — the re-check
+    every dispatch makes after the decision."""
+    if backend == "xla":
+        return True
+    if backend == "pallas_short":
+        return _pallas_short_ok(q_shape, k_shape, bias)
+    if backend == "pallas_short128":
+        return _pallas_short128_ok(q_shape, k_shape, bias)
+    if backend == "flash_bundled":
+        return _flash_bundled_ok(q_shape, k_shape, dtype)
+    return False
+
+
+def _note_dispatch(kind: str, chosen: str, ran: str) -> None:
+    """Make what ran observable: one count per traced dispatch under
+    (kind, chosen, ran). chosen != ran is a swept-DB verdict that gave way
+    to the reference because this platform cannot run it."""
+    from .. import observability as obs
+
+    obs.counter_inc("attention.dispatches",
+                    labels={"kind": kind, "chosen": chosen, "ran": ran})
+
+
+def dispatch_counts() -> dict:
+    """{(kind, chosen, ran): traces} since the series was last reset
+    (`observability.reset("attention.")`)."""
+    from .. import observability as obs
+
+    out = {}
+    for key, n in obs.snapshot()["counters"].items():
+        if obs.base_name(key) == "attention.dispatches":
+            lab = dict(re.findall(r'(\w+)="([^"]*)"', key))
+            out[(lab["kind"], lab["chosen"], lab["ran"])] = int(n)
+    return out
 
 
 def attention_backend(q_shape, k_shape, dtype, bias=None, causal=False,
@@ -96,17 +129,20 @@ def attention_backend(q_shape, k_shape, dtype, bias=None, causal=False,
     kernel when the caller forces O(S) memory (`use_pallas`) and the shape
     qualifies, the bundled flash kernel past S=1024 where the [S,S] scores
     outgrow the chip. Under FLAGS_tuning_mode=consult a swept-DB entry for
-    the exact (shape, dtype, device) overrides the rule — this is where the
-    measured BENCH_r05 split (XLA wins at seq<=128, the Pallas kernel wins
-    ~9% at s512) becomes a cache entry instead of a per-model flag. A
-    swept backend the current build cannot execute is degraded at dispatch
-    time (flash_attention), never obeyed blindly.
+    the exact (shape, dtype, device) overrides the rule — this is where a
+    measured split (on a v5e in 2026-07, before the PR 1-20 code: XLA ahead
+    at seq<=128, the Pallas kernel ~9% ahead at s512) becomes a cache entry
+    instead of a per-model flag. A swept backend the current build cannot
+    execute gives way to the reference at dispatch (flash_attention), and
+    the `attention.dispatches` counter records that it did.
 
     The seq<=128 regime additionally carries the `pallas_short128` arm
     (pallas_kernels/short_attention.py — ISSUE 9): the analytic prior keeps
-    XLA there (that is what r4/r5 measured), so the kernel engages only via
-    a swept keep or FLAGS_attention_force_backend (the A/B harness lever,
-    which precedes every tier and still degrades when un-runnable)."""
+    XLA there (that is what those runs measured), so the kernel engages
+    only via a swept keep or FLAGS_attention_force_backend (the A/B harness
+    lever, which precedes every tier; a forced backend that cannot run
+    here raises at dispatch — an A/B arm must never time the reference
+    under the kernel's name)."""
     from .. import flags as pt_flags
 
     B, nh, sq, dh = q_shape
@@ -156,23 +192,31 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=1.0,
       * "flash_bundled": jax's bundled flash kernel (the only O(S) option
         once the [S,S] scores outgrow VMEM/HBM budgets).
     A swept-DB backend the current platform/shape cannot run (e.g. a Pallas
-    verdict replayed off-TPU) degrades to the reference path here.
+    verdict replayed off-TPU) gives way to the reference path here and is
+    counted as such; a FORCED backend that cannot run raises.
     """
-    backend, _tier = attention_backend(q.shape, k.shape, q.dtype, bias,
-                                       causal, use_pallas)
-    if backend == "pallas_short" and _pallas_short_ok(q.shape, k.shape, bias):
+    backend, tier = attention_backend(q.shape, k.shape, q.dtype, bias,
+                                      causal, use_pallas)
+    ran = backend
+    if not _backend_runnable(backend, q.shape, k.shape, bias, q.dtype):
+        if tier == "forced":
+            raise RuntimeError(
+                f"FLAGS_attention_force_backend={backend!r} cannot run "
+                f"q{tuple(q.shape)} k{tuple(k.shape)} {q.dtype} on "
+                f"{jax.default_backend()!r}")
+        ran = "xla"
+    _note_dispatch("dense", backend, ran)
+    if ran == "pallas_short":
         from .pallas_kernels import attention as psa
 
         return psa.short_seq_attention(q, k, v, causal=causal,
                                        sm_scale=float(sm_scale))
-    if backend == "pallas_short128" and _pallas_short128_ok(
-            q.shape, k.shape, bias):
+    if ran == "pallas_short128":
         from .pallas_kernels import short_attention as s128
 
         return s128.short128_attention(q, k, v, causal=causal,
                                        sm_scale=float(sm_scale))
-    if backend == "flash_bundled" and _flash_bundled_ok(q.shape, k.shape,
-                                                        q.dtype):
+    if ran == "flash_bundled":
         from jax.experimental.pallas.ops.tpu import flash_attention as fa
 
         return fa.flash_attention(q, k, v, ab=bias, causal=causal,
@@ -200,8 +244,9 @@ def fused_attention(ctx: ExecContext):
 
 def _pallas_paged_ok(q_shape, pool_shape) -> bool:
     from .pallas_kernels import paged_attention as ppa
+    from .pallas_kernels import workbench
 
-    return ((_on_tpu() or ppa.INTERPRET)
+    return (workbench.runnable(ppa)
             and ppa.paged_supported(tuple(q_shape), tuple(pool_shape)))
 
 
@@ -302,8 +347,10 @@ def paged_decode_attention_fn(q, k_pool, v_pool, page_table, kv_lens,
     if backend == "pallas_paged" and _pallas_paged_ok(shard_q, shard_pool):
         from .pallas_kernels import paged_attention as ppa
 
+        _note_dispatch("paged", backend, backend)
         return ppa.paged_decode_attention(q, k_pool, v_pool, page_table,
                                           kv_lens, sm_scale=float(sm_scale))
+    _note_dispatch("paged", backend, "xla")
     return _paged_attention_reference(q, k_pool, v_pool, page_table, kv_lens,
                                       sm_scale)
 

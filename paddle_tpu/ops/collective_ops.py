@@ -27,39 +27,12 @@ from .registry import ExecContext, register_op
 AXIS_ENV_KEY = "__axis_env__"  # env key: dict ring_id/axis info set by executor
 
 
-def compat_shard_map(fn, mesh, in_specs, out_specs, check=False):
-    """Version-tolerant shard_map: the entry point moved from
-    jax.experimental.shard_map to jax.shard_map, and the replication-check
-    kwarg was renamed check_rep -> check_vma across jax releases. One shim
-    (the workbench discipline) so the executor, the ring-attention tests,
-    and any future caller stop carrying private try/except ladders."""
-    try:
-        from jax import shard_map as shard_map_fn
-    except ImportError:  # pragma: no cover - older jax layout
-        from jax.experimental.shard_map import shard_map as shard_map_fn
-    try:
-        return shard_map_fn(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=check)
-    except TypeError:  # 0.4.x spells the kwarg check_rep
-        return shard_map_fn(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=check)
-
-
 def _axis(ctx: ExecContext):
     env = ctx.env.get(AXIS_ENV_KEY)
     if env is None:
         return None
     ring = ctx.attr("ring_id", 0)
     return env.get(ring, env.get(0))
-
-
-def _axis_size(axis):
-    """jax.lax.axis_size where available (it landed after 0.4.x); else the
-    shard_map-safe spelling — a psum of 1 over the axis."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return jax.lax.psum(1, axis)
 
 
 def _axis_index(axis):
@@ -88,7 +61,7 @@ def _allreduce(red):
                 # fused mean-allreduce: the 1/nranks scale lives INSIDE the op
                 # so it only applies when a real reduction happens (a separate
                 # scale op would corrupt grads in the GSPMD identity regime)
-                out = out / _axis_size(axis)
+                out = out / jax.lax.axis_size(axis)
             return {"Out": out}
         if red == "max":
             return {"Out": jax.lax.pmax(x, axis)}
@@ -139,7 +112,7 @@ def c_allreduce_coalesced(ctx: ExecContext):
     # same psum c_allreduce_sum emits, hence the bitwise parity contract
     red = jax.lax.psum(tuple(xs), axis)
     if ctx.attr("avg", False):
-        n = _axis_size(axis)
+        n = jax.lax.axis_size(axis)
         red = tuple(r / n for r in red)
     return {"Out": list(red)}
 
@@ -155,7 +128,7 @@ def zero1_shard(ctx: ExecContext):
     axis = _axis(ctx)
     if axis is None:
         return {"Out": x}
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     k = x.shape[0] // n
     idx = _axis_index(axis)
     return {"Out": jax.lax.dynamic_slice_in_dim(x, idx * k, k, axis=0)}
@@ -187,7 +160,7 @@ def c_reducescatter(ctx: ExecContext):
     if ctx.attr("avg", False):
         # fused mean like c_allreduce_sum's `avg`: the scale only applies
         # when a real reduction runs (identity in the GSPMD regime above)
-        out = out / _axis_size(axis)
+        out = out / jax.lax.axis_size(axis)
     return {"Out": out}
 
 
@@ -212,7 +185,7 @@ def c_collective_permute(ctx: ExecContext):
     axis = _axis(ctx)
     if axis is None:
         return {"Out": x}
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     shift = ctx.attr("shift", 1)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return {"Out": jax.lax.ppermute(x, axis, perm)}
@@ -232,7 +205,7 @@ def local_sgd_sync(ctx: ExecContext):
     axis = _axis(ctx)
     delta = p - snap
     if axis is not None:
-        delta = jax.lax.psum(delta, axis) / _axis_size(axis)
+        delta = jax.lax.psum(delta, axis) / jax.lax.axis_size(axis)
     synced = snap + delta
     do_sync = (step % k) == 0
     new_p = jnp.where(do_sync, synced, p)

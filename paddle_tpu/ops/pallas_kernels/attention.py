@@ -29,6 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import workbench
 
@@ -126,10 +127,8 @@ def _hb_spec(gh, s, dh):
 
 
 def _params():
-    # version-tolerant CompilerParams via the workbench shim: the bare
-    # pltpu.CompilerParams spelling broke on jax 0.4.x (TPUCompilerParams
-    # there) and took test_pallas_attention with it
-    return workbench.compiler_params(("parallel", "parallel"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"))
 
 
 def _fwd(q, k, v, sm_scale, causal, interpret):

@@ -38,6 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import workbench
 
@@ -118,21 +119,25 @@ def _apply_bwd_kernel(x_ref, s_ref, b_ref, m_ref, v_ref, *rest,
     # per-channel partials: P1 = sum dz, P2 = sum dz*(x-m); the caller
     # derives dbias/dmean from P1 and dscale/dinv from P2 (scalar algebra
     # per channel), so the kernel ships two reductions, not four
-    p1_ref[...] = jnp.sum(dz, axis=red_axis, keepdims=True)
-    p2_ref[...] = jnp.sum(dz * xc, axis=red_axis, keepdims=True)
+    p1_ref[...] = jnp.sum(dz, axis=red_axis, keepdims=True).reshape(
+        p1_ref.shape)
+    p2_ref[...] = jnp.sum(dz * xc, axis=red_axis, keepdims=True).reshape(
+        p2_ref.shape)
 
 
 def _apply_specs(mode, tr, row, nt):
     """(x/out spec, param spec, partial spec) for one canonical layout.
 
     mode "cl": x2 [R, C] channels-last — params broadcast as [1, C] rows,
-    per-tile partials land in [NT, C]. mode "cr": x2 [R=N*C, HW] channels-
-    row — params are per-row [TR, 1] columns (pre-tiled to [R, 1]), partials
-    are complete per-row sums [R, 1]."""
+    per-tile partials land in [NT, 1, C] (a (1, C) block of an [NT, C]
+    array is not (8, 128)-tileable; as the two minor dims of a 3-D array it
+    is the whole plane). mode "cr": x2 [R=N*C, HW] channels-row — params
+    are per-row [TR, 1] columns (pre-tiled to [R, 1]), partials are
+    complete per-row sums [R, 1]."""
     xspec = pl.BlockSpec((tr, row), lambda i: (i, 0))
     if mode == "cl":
         pspec = pl.BlockSpec((1, row), lambda i: (0, 0))
-        partial = pl.BlockSpec((1, row), lambda i: (i, 0))
+        partial = pl.BlockSpec((1, 1, row), lambda i: (i, 0, 0))
     else:
         pspec = pl.BlockSpec((tr, 1), lambda i: (i, 0))
         partial = pl.BlockSpec((tr, 1), lambda i: (i, 0))
@@ -158,7 +163,8 @@ def _apply_call_fwd(x2, params, res2, act, mode, interpret):
             flops=6 * R * row, transcendentals=0,
             bytes_accessed=(2 + (1 if res2 is not None else 0))
             * R * row * x2.dtype.itemsize),
-        compiler_params=workbench.compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
 
@@ -169,7 +175,7 @@ def _apply_call_bwd(x2, params, res2, dy2, act, mode, interpret):
     tr = workbench.pick_block(R, row * 4 * (8 if has_res else 6))
     nt = R // tr
     xspec, pspec, partial = _apply_specs(mode, tr, row, nt)
-    pshape = (nt, row) if mode == "cl" else (R, 1)
+    pshape = (nt, 1, row) if mode == "cl" else (R, 1)
     in_specs = [xspec] + [pspec] * 4 + [xspec] * (2 if has_res else 1)
     out_specs = [xspec] + ([xspec] if has_res else []) + [partial] * 2
     out_shape = ([jax.ShapeDtypeStruct(x2.shape, x2.dtype)]
@@ -189,7 +195,8 @@ def _apply_call_bwd(x2, params, res2, dy2, act, mode, interpret):
             flops=10 * R * row, transcendentals=0,
             bytes_accessed=(3 + (2 if has_res else 0))
             * R * row * x2.dtype.itemsize),
-        compiler_params=workbench.compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
 
@@ -214,8 +221,8 @@ def _make_apply(act: str, mode: str, has_res: bool, interpret: bool):
             dx2, p1, p2 = outs
             dr2 = None
         if mode == "cl":
-            P1 = jnp.sum(p1, axis=0, keepdims=True)      # [1, C]
-            P2 = jnp.sum(p2, axis=0, keepdims=True)
+            P1 = jnp.sum(p1, axis=0)                     # [1, C]
+            P2 = jnp.sum(p2, axis=0)
         else:
             P1, P2 = p1, p2                              # [R, 1] complete
         ds = P2 * v
@@ -334,8 +341,8 @@ def _ln_bwd_kernel(x_ref, s_ref, b_ref, dy_ref, dx_ref, ds_ref, db_ref,
     a = jnp.mean(dxhat, axis=1, keepdims=True)
     c = jnp.mean(dxhat * xhat, axis=1, keepdims=True)
     dx_ref[...] = (r * (dxhat - a - xhat * c)).astype(dx_ref.dtype)
-    ds_ref[...] = jnp.sum(dz * xhat, axis=0, keepdims=True)
-    db_ref[...] = jnp.sum(dz, axis=0, keepdims=True)
+    ds_ref[0] = jnp.sum(dz * xhat, axis=0, keepdims=True)
+    db_ref[0] = jnp.sum(dz, axis=0, keepdims=True)
 
 
 @functools.lru_cache(maxsize=None)
@@ -354,7 +361,8 @@ def _make_ln(eps: float, act: str, interpret: bool):
             cost_estimate=pl.CostEstimate(
                 flops=9 * R * K, transcendentals=R,
                 bytes_accessed=2 * R * K * x2.dtype.itemsize),
-            compiler_params=workbench.compiler_params(("parallel",)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
             interpret=interpret,
         )(x2, s, b)
 
@@ -378,19 +386,19 @@ def _make_ln(eps: float, act: str, interpret: bool):
                       pl.BlockSpec((1, K), lambda i: (0, 0)),
                       pl.BlockSpec((tr, K), lambda i: (i, 0))],
             out_specs=[pl.BlockSpec((tr, K), lambda i: (i, 0)),
-                       pl.BlockSpec((1, K), lambda i: (i, 0)),
-                       pl.BlockSpec((1, K), lambda i: (i, 0))],
+                       pl.BlockSpec((1, 1, K), lambda i: (i, 0, 0)),
+                       pl.BlockSpec((1, 1, K), lambda i: (i, 0, 0))],
             out_shape=[jax.ShapeDtypeStruct(x2.shape, x2.dtype),
-                       jax.ShapeDtypeStruct((nt, K), jnp.float32),
-                       jax.ShapeDtypeStruct((nt, K), jnp.float32)],
+                       jax.ShapeDtypeStruct((nt, 1, K), jnp.float32),
+                       jax.ShapeDtypeStruct((nt, 1, K), jnp.float32)],
             cost_estimate=pl.CostEstimate(
                 flops=16 * R * K, transcendentals=R,
                 bytes_accessed=3 * R * K * x2.dtype.itemsize),
-            compiler_params=workbench.compiler_params(("parallel",)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
             interpret=interpret,
         )(x2, s, b, dy2)
-        return dx2, jnp.sum(ds_p, axis=0, keepdims=True), \
-            jnp.sum(db_p, axis=0, keepdims=True)
+        return dx2, jnp.sum(ds_p, axis=0), jnp.sum(db_p, axis=0)
 
     ln.defvjp(vjp_fwd, vjp_bwd)
     return ln

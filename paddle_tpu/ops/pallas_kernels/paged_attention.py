@@ -52,13 +52,6 @@ def paged_supported(q_shape, pool_shape) -> bool:
             and ps * nh * dh * 4 <= 2 * 1024 * 1024)
 
 
-def _compiler_params():
-    # version-tolerant spelling via the shared workbench shim
-    from . import workbench
-
-    return workbench.compiler_params(("parallel", "arbitrary"))
-
-
 def _decode_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, sm_scale, page_size, num_pages_p):
     b = pl.program_id(0)
@@ -70,33 +63,38 @@ def _decode_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32) * sm_scale      # [nh, dh]
-    k = k_ref[0].astype(jnp.float32)                 # [ps, nh, dh]
-    v = v_ref[0].astype(jnp.float32)
-    # batched-over-heads q.k^T: [nh, dh] x [ps, nh, dh] -> [nh, ps]
-    s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
-                            preferred_element_type=jnp.float32)
-    # ragged mask: slot p*ps + j is live iff it is below this row's context
-    pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-    s = jnp.where(pos < kl_ref[b], s, _NEG_INF)
+    # One query token per row: the step is bound by the page DMA, not by
+    # FLOPs, so q.k and p.v run on the VPU one slot at a time over plain
+    # [nh, dh] tiles. Mosaic's matmul wants the batch (head) dim leading in
+    # both operands, and the page slab [ps, nh, dh] has it in the middle.
+    q = q_ref[0].astype(jnp.float32) * sm_scale          # [nh, dh]
+    kv_len = kl_ref[b]
+    scores = []
+    for j in range(page_size):
+        kj = k_ref[0, j].astype(jnp.float32)             # [nh, dh]
+        sj = jnp.sum(q * kj, axis=-1, keepdims=True)     # [nh, 1]
+        # ragged mask: slot p*ps + j is live iff below this row's context
+        scores.append(jnp.where(p * page_size + j < kv_len, sj, _NEG_INF))
 
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    m_prev = m_ref[...]
+    m_new = functools.reduce(jnp.maximum, scores, m_prev)
     alpha = jnp.exp(m_prev - m_new)
-    pexp = jnp.exp(s - m_new)
+    l_new = l_ref[...] * alpha
+    acc = acc_ref[...] * alpha
+    for j, sj in enumerate(scores):
+        pj = jnp.exp(sj - m_new)                         # [nh, 1]
+        l_new = l_new + pj
+        acc = acc + pj * v_ref[0, j].astype(jnp.float32)
     m_ref[...] = m_new
-    l_ref[...] = l_prev * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-    # [nh, ps] x [ps, nh, dh] -> [nh, dh]
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pexp, v, (((1,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)
+    l_ref[...] = l_new
+    acc_ref[...] = acc
 
     @pl.when(p == num_pages_p - 1)
     def _emit():
-        # a padded row (kv_len 0) has l == 0: emit zeros, not NaN — the
-        # scheduler's batch_mask guarantees nobody reads it either way
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        # a padded row (kv_len 0) has every slot masked alike, so l > 0 and
+        # it emits the mean of whatever its table's pages hold — finite,
+        # and the scheduler's batch_mask guarantees nobody reads it
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
@@ -136,7 +134,8 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
             bytes_accessed=(2 * B * P * ps * nh * dh * k_pool.dtype.itemsize
                             + 2 * B * nh * dh * q.dtype.itemsize),
             transcendentals=B * nh * P * ps),
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, kv_lens, q, k_pool, v_pool)
 

@@ -33,6 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import workbench
 
@@ -87,7 +88,7 @@ def _softmax(s):
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, ragged):
     if ragged:
         kl_ref, o_ref = rest
-        kv_len = kl_ref[0, 0]
+        kv_len = kl_ref[pl.program_id(0)]
     else:
         (o_ref,) = rest
         kv_len = None
@@ -103,7 +104,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, ragged):
 def _bwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, ragged):
     if ragged:
         kl_ref, do_ref, dq_ref, dk_ref, dv_ref = rest
-        kv_len = kl_ref[0, 0]
+        kv_len = kl_ref[pl.program_id(0)]
     else:
         do_ref, dq_ref, dk_ref, dv_ref = rest
         kv_len = None
@@ -133,7 +134,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, ragged):
 
 def _specs(nh, s, dh, ragged, n_io):
     qspec = pl.BlockSpec((1, nh, s, dh), lambda b: (b, 0, 0, 0))
-    klspec = pl.BlockSpec((1, 1), lambda b: (b, 0))
+    # kv_lens rides whole in scalar memory, indexed by the grid position: a
+    # per-row (1, 1) block of a [B, 1] array is not (8, 128)-tileable
+    klspec = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [qspec] * n_io + ([klspec] if ragged else [])
     return qspec, in_specs
 
@@ -144,8 +147,7 @@ def _fwd(q, k, v, kv_lens, sm_scale, causal, interpret):
     qspec, in_specs = _specs(nh, s, dh, ragged, 3)
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
                                causal=causal, ragged=ragged)
-    args = (q, k, v) + ((kv_lens.reshape(B, 1).astype(jnp.int32),)
-                        if ragged else ())
+    args = (q, k, v) + ((kv_lens.astype(jnp.int32),) if ragged else ())
     return pl.pallas_call(
         kernel,
         grid=(B,),
@@ -156,7 +158,8 @@ def _fwd(q, k, v, kv_lens, sm_scale, causal, interpret):
             flops=B * nh * 2 * 2 * s * s * dh,
             bytes_accessed=4 * B * nh * s * dh * q.dtype.itemsize,
             transcendentals=B * nh * s * s),
-        compiler_params=workbench.compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
 
@@ -167,8 +170,8 @@ def _bwd(q, k, v, kv_lens, do, sm_scale, causal, interpret):
     qspec, in_specs = _specs(nh, s, dh, ragged, 3)
     kernel = functools.partial(_bwd_kernel, sm_scale=sm_scale,
                                causal=causal, ragged=ragged)
-    args = (q, k, v) + ((kv_lens.reshape(B, 1).astype(jnp.int32),)
-                        if ragged else ()) + (do,)
+    args = (q, k, v) + ((kv_lens.astype(jnp.int32),) if ragged
+                        else ()) + (do,)
     return pl.pallas_call(
         kernel,
         grid=(B,),
@@ -179,7 +182,8 @@ def _bwd(q, k, v, kv_lens, do, sm_scale, causal, interpret):
             flops=B * nh * 5 * 2 * s * s * dh,
             bytes_accessed=7 * B * nh * s * dh * q.dtype.itemsize,
             transcendentals=B * nh * s * s),
-        compiler_params=workbench.compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
 

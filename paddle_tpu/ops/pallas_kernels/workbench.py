@@ -2,14 +2,9 @@
 
 ROADMAP item 5 ("Tensor Processing Primitives", arXiv:2104.05755) calls for
 a small reusable custom-kernel layer rather than a pile of one-off files.
-This module is that layer's spine — the pieces attention.py / xent.py /
+This module is that layer's spine — the pieces attention.py /
 paged_attention.py each re-invented privately, factored once:
 
-  * `compiler_params` — version-tolerant CompilerParams construction. jax
-    renamed pltpu.TPUCompilerParams -> CompilerParams (and back) across
-    0.4.x/0.5.x; paged_attention.py carried the shim, attention.py did not
-    and broke on 0.4.37 (the pre-existing test_pallas_attention failures).
-    One spelling here, used by every kernel.
   * block-shape helpers — `pick_block` (largest divisor under a VMEM
     budget, sublane-friendly), `fit_heads` (the attention head-block rule),
     and the lane/sublane constants, so kernels size their slabs against the
@@ -39,17 +34,6 @@ LANES = 128
 # per-step VMEM slab budget (bytes): leaves room for double buffering and
 # fp32 score/stat scratch inside the ~16 MB of VMEM per core
 VMEM_BUDGET = 3 * 1024 * 1024
-
-
-def compiler_params(dimension_semantics: tuple):
-    """Version-tolerant pltpu CompilerParams: jax moved CompilerParams ->
-    TPUCompilerParams and back across releases; every kernel builds its
-    params through this one shim so a rename breaks one line, not N files."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cp = (getattr(pltpu, "CompilerParams", None)
-          or getattr(pltpu, "TPUCompilerParams"))
-    return cp(dimension_semantics=tuple(dimension_semantics))
 
 
 def sublanes(dtype) -> int:
@@ -90,10 +74,9 @@ def fit_heads(nh: int, per_head_bytes: int,
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return False
+    # a backend that fails to initialise raises here and stops the run: it
+    # must not quietly turn every kernel off
+    return jax.default_backend() == "tpu"
 
 
 def runnable(module) -> bool:
@@ -162,6 +145,5 @@ def all_kernels() -> dict[str, KernelSpec]:
     """Every registered kernel (import side effect: pulls in the kernel
     modules so their registrations run)."""
     from . import attention, epilogue, paged_attention, short_attention  # noqa: F401
-    from . import xent  # noqa: F401
 
     return dict(_KERNELS)

@@ -34,7 +34,7 @@ DB_SCHEMA = 1
 
 __all__ = ["DB_SCHEMA", "TuningDB", "canonical_key", "conv_key",
            "attention_key", "bucket_key", "amp_key", "collective_key",
-           "epilogue_key", "xent_key", "embedding_key", "evidence"]
+           "epilogue_key", "embedding_key", "evidence"]
 
 
 def evidence(measured: dict) -> dict:
@@ -95,12 +95,6 @@ def epilogue_key(kind: str, rows: int, channels: int, channel_pos: str,
     'bn' (apply given stats) or 'ln' (in-kernel row statistics)."""
     return (f"kind={kind} rows={rows} c={channels} ch={channel_pos} "
             f"act={act or 'identity'} res={int(bool(has_residual))}")
-
-
-def xent_key(rows: int, vocab: int) -> str:
-    """Fused softmax-xent decisions (ops/pallas_kernels/xent.py): the
-    kernel's problem is the flattened [rows, vocab] logits tile."""
-    return f"rows={rows} v={vocab}"
 
 
 def embedding_key(table: str, vocab: int, dim: int) -> str:

@@ -192,7 +192,7 @@ def _build_arms(op: str, shape_key: str, dtype: str) -> dict | None:
     """Reconstruct the timed arms for one candidate key — the same
     fwd+bwd jitted closures tools/tune.py sweeps, rebuilt from the key
     alone. Families explore cannot rebuild (paged decode needs a live KV
-    pool; epilogue/xent arms are platform-gated) return None and are
+    pool; epilogue arms are platform-gated) return None and are
     skipped — offline sweeps remain their path to a verdict."""
     kv = features.parse_shape_key(op, shape_key)
     if kv is None:

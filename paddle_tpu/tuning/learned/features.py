@@ -11,7 +11,7 @@ ones instead of memorizing keys.
 
 Only op families whose arms are timed alternatives of one categorical
 decision are featurizable (conv2d lowering, attention backend, epilogue
-backend, xent backend). Integer-valued levers (bucket boundaries, embedding
+backend). Integer-valued levers (bucket boundaries, embedding
 geometry, collective bucket sizing) and shapeless ones (AMP lists) stay on
 their analytic priors — a ranking model has nothing to rank there.
 """
@@ -27,7 +27,6 @@ FAMILIES = {
     "conv2d": "lowering",
     "attention": "backend",
     "epilogue": "backend",
-    "xent": "backend",
     # serving control (ISSUE 20): the "shape" is a traffic regime and the
     # "arm" is a canonical knob-config spelling — the same store rows and
     # ridge fit rank serving configs the way they rank conv lowerings
@@ -93,7 +92,6 @@ _ATTN_FEATURES = (
 _EPI_FEATURES = (
     "log_rows", "log_c", "log_elems", "fill_c", "ch_last", "has_res",
     "act_identity", "kind_bn", "itemsize")
-_XENT_FEATURES = ("log_rows", "log_v", "log_elems", "fill_v", "itemsize")
 # serving.control regime keys (serving/control/regime.py spells them):
 # arrival rate, prompt-length percentiles, output budget, prefix-hit rate,
 # pool occupancy, queue depth, TTFT/SLO headroom — ratios arrive as percent
@@ -105,7 +103,7 @@ _CTRL_FEATURES = (
 
 def feature_names(op: str) -> tuple | None:
     return {"conv2d": _CONV_FEATURES, "attention": _ATTN_FEATURES,
-            "epilogue": _EPI_FEATURES, "xent": _XENT_FEATURES,
+            "epilogue": _EPI_FEATURES,
             "serving.control": _CTRL_FEATURES}.get(op)
 
 
@@ -155,9 +153,6 @@ def featurize(op: str, shape_key: str, dtype: str) -> list | None:
                 float(kv.get("act", "identity") == "identity"),
                 float(kv.get("kind") == "bn"), it,
             ]
-        if op == "xent":
-            rows, v = kv["rows"], kv["v"]
-            return [_log(rows), _log(v), _log(rows * v), _fill(v), it]
         if op == "serving.control":
             return [
                 _log(float(kv["rate"])), _log(float(kv["p50"])),
@@ -180,7 +175,7 @@ def analytic_decision(op: str, shape_key: str, dtype: str) -> str | None:
     trained model's holdout ranking accuracy is judged against
     (tools/costmodel.py eval, gate.py --costmodel). Mirrors the registered
     priors: the PR 5 tile-fill-vs-HBM model for convs, the measured
-    dispatch rule for attention, XLA for epilogues, Pallas for xent."""
+    dispatch rule for attention, XLA for epilogues."""
     kv = parse_shape_key(op, shape_key)
     if kv is None:
         return None
@@ -200,8 +195,6 @@ def analytic_decision(op: str, shape_key: str, dtype: str) -> str | None:
                                        and kv["sq"] == kv["sk"]) else "xla"
         if op == "epilogue":
             return "xla"
-        if op == "xent":
-            return "pallas"
     except (KeyError, TypeError, ValueError):
         return None
     return None

@@ -68,12 +68,11 @@ def device_kind() -> str:
     global _device_kind
     if _device_kind is not None:
         return _device_kind
-    try:
-        import jax
+    import jax
 
-        _device_kind = str(getattr(jax.devices()[0], "device_kind", "cpu"))
-    except Exception:  # pragma: no cover - no backend at all
-        _device_kind = "cpu"
+    # a backend that fails to initialise raises here: keying every decision
+    # under "cpu" instead would consult the wrong device's verdicts
+    _device_kind = str(jax.devices()[0].device_kind)
     return _device_kind
 
 

@@ -74,13 +74,12 @@ def test_ring_attention_matches_full_attention():
     kv = rng.standard_normal((B, nh, S, dh)).astype(np.float32)
     vv = rng.standard_normal((B, nh, S, dh)).astype(np.float32)
 
-    from paddle_tpu.ops.collective_ops import compat_shard_map as shard_map_fn
-
-    fn = shard_map_fn(
+    fn = jax.shard_map(
         lambda q, k, v: ring_attention_local(q, k, v, "sp", sm_scale=dh ** -0.5),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3,
         out_specs=P(None, None, "sp", None),
+        check_vma=False,
     )
     got = np.asarray(jax.jit(fn)(qv, kv, vv))
     want = _np_attention(qv, kv, vv)
@@ -100,13 +99,12 @@ def test_ring_attention_causal_matches():
     qv = rng.standard_normal((B, nh, S, dh)).astype(np.float32)
     kv = rng.standard_normal((B, nh, S, dh)).astype(np.float32)
     vv = rng.standard_normal((B, nh, S, dh)).astype(np.float32)
-    from paddle_tpu.ops.collective_ops import compat_shard_map as shard_map_fn
-
-    fn = shard_map_fn(
+    fn = jax.shard_map(
         lambda q, k, v: ring_attention_local(q, k, v, "sp", causal=True, sm_scale=dh ** -0.5),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3,
         out_specs=P(None, None, "sp", None),
+        check_vma=False,
     )
     got = np.asarray(jax.jit(fn)(qv, kv, vv))
     want = _np_attention(qv, kv, vv, causal=True)
@@ -129,13 +127,12 @@ def test_ring_attention_grads():
     qv = rng.standard_normal((B, nh, S, dh)).astype(np.float32)
     kv = rng.standard_normal((B, nh, S, dh)).astype(np.float32)
     vv = rng.standard_normal((B, nh, S, dh)).astype(np.float32)
-    from paddle_tpu.ops.collective_ops import compat_shard_map as shard_map_fn
-
-    ring = shard_map_fn(
+    ring = jax.shard_map(
         lambda q, k, v: ring_attention_local(q, k, v, "sp", sm_scale=dh ** -0.5),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3,
         out_specs=P(None, None, "sp", None),
+        check_vma=False,
     )
     g_ring = jax.grad(lambda q: jax.jit(ring)(q, kv, vv).sum())(qv)
     g_full = jax.grad(

@@ -263,7 +263,16 @@ def test_fused_bn_under_amp_bf16_band():
 def test_resnet_cifar_end_to_end_levers_match():
     """Whole-model check: resnet_cifar10 trained 2 steps with both levers on
     matches the baseline step-for-step (the model wiring — shortcuts,
-    stride-2 blocks, global pool — picked the fused ops up unchanged)."""
+    stride-2 blocks, global pool — picked the fused ops up unchanged).
+
+    Step 1 is the same forward on the same weights and is held tightly.
+    Step 2 comes after one lr-0.05 Momentum update that takes the loss
+    from 2.73 to 0.95 on a batch of 4 — a trajectory that amplifies fp32
+    summation order: the im2col matmul sums each conv in another order
+    than the direct lowering and lands 3.7e-4 away (jax 0.9.0, XLA:CPU),
+    and merely reversing the batch rows of the BASELINE — pure
+    reassociation — is 8e-4 away one step later. A wrong lowering or a
+    mis-wired block moves the loss by tenths."""
     from paddle_tpu.models import resnet
 
     def run(igemm, fuse):
@@ -293,4 +302,5 @@ def test_resnet_cifar_end_to_end_levers_match():
     assert n0 == 0
     # every conv in the cifar net feeds a training BN directly -> all fuse
     assert n1 > 10
-    np.testing.assert_allclose(lev, ref, rtol=2e-5, atol=1e-6)
+    np.testing.assert_allclose(lev[0], ref[0], rtol=2e-5, atol=1e-6)
+    np.testing.assert_allclose(lev[1], ref[1], rtol=2e-3)

@@ -85,3 +85,24 @@ def test_launch_ps_spawns_servers_and_workers(tmp_path):
         if k.startswith("__"):
             continue
         np.testing.assert_allclose(t0[k], t1[k], rtol=1e-5, atol=1e-6)
+
+
+def test_launch_refuses_several_processes_on_a_tpu_host(monkeypatch):
+    """A chip belongs to one process: several children with no CPU pin on
+    a host with TPU device nodes are refused; a CPU pin or one child is
+    not."""
+    import pytest
+
+    from paddle_tpu.distributed import launch
+
+    monkeypatch.delenv("PADDLE_DIST_BACKEND", raising=False)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(
+        launch.glob, "glob",
+        lambda pat: ["/dev/vfio/0"] if pat.startswith("/dev/vfio") else [])
+    with pytest.raises(SystemExit, match="ONE process"):
+        launch._refuse_shared_chips(2, None)
+    launch._refuse_shared_chips(1, None)
+    launch._refuse_shared_chips(2, "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    launch._refuse_shared_chips(2, None)

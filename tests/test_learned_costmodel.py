@@ -162,8 +162,7 @@ def test_featurize_sanity():
                    ("attention", tuning.attention_key(2, 12, 128, 128, 64,
                                                       False)),
                    ("epilogue", "kind=bn rows=128 c=64 ch=last act=relu "
-                                "res=0"),
-                   ("xent", "rows=128 v=32000")]:
+                                "res=0")]:
         v = features.featurize(op, sk, "float32")
         assert isinstance(v, list) and len(v) >= 5
         assert all(np.isfinite(x) for x in v)

@@ -43,9 +43,6 @@ def _f32(*shape):
 
 
 def test_workbench_helpers():
-    # compiler_params resolves on this jax version (the shim IS the fix for
-    # the pre-existing test_pallas_attention env failures)
-    assert wb.compiler_params(("parallel",)) is not None
     assert wb.sublanes(jnp.float32) == 8 and wb.sublanes(jnp.bfloat16) == 16
     assert wb.round_up(129, 128) == 256
     # pick_block: largest fitting divisor, sublane multiples preferred

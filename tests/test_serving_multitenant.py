@@ -2,6 +2,7 @@
 prefix cache with copy-on-write, speculative draft-verify decoding (exact
 under the greedy oracle), the temperature/top-k/top-p sampling suite with
 seeded determinism, and TP-sharded decode through per-shard tuner keys."""
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -237,18 +238,29 @@ def test_spec_decode_exact_vs_plain_greedy():
 
 
 def test_spec_decode_accepts_on_repetitive_sequences():
-    """Greedy decoding of the tiny model settles into a loop (as real LLM
-    decode settles into templated spans): the n-gram self-draft picks the
-    cycle up, so accepted tokens > 0 and FEWER decode steps than tokens
-    generated — the whole point of the draft-verify window — while the
-    output stays bitwise the plain greedy sequence."""
+    """On a sequence that repeats (as real LLM decode settles into
+    templated spans) the n-gram self-draft picks the repetition up, so
+    accepted tokens > 0 and FEWER decode steps than tokens generated — the
+    whole point of the draft-verify window — while the output stays
+    bitwise the plain greedy sequence. The repetition is built, not hoped
+    for: with the head's weight zeroed and its bias favouring one token,
+    greedy decode emits that token whatever the installation's matmuls
+    draw."""
     cfg = decoder_tiny()
     prompt = list(np.random.default_rng(3).integers(1, cfg.vocab_size, 5))
-    _, want = _generate(cfg, [prompt], max_new=16, prefix_cache=False,
-                        draft_k=0)
-    eng, got = _generate(cfg, [prompt], max_new=16, prefix_cache=False,
-                         draft_k=3)
-    assert got == want
+    bias = np.zeros(cfg.vocab_size, np.float32)
+    bias[7] = 1.0
+    out = {}
+    for k in (0, 3):
+        eng = ServingEngine(cfg, page_size=4, pool_pages=64, max_inflight=4,
+                            prefix_cache=False, draft_k=k)
+        w = eng._scope.find_var("dec.lm_head.w")
+        eng._scope.set_var("dec.lm_head.w", jnp.zeros_like(w))
+        eng._scope.set_var("dec.lm_head.b", jnp.asarray(bias))
+        rid = eng.submit(prompt, max_new_tokens=16)
+        eng.run_until_drained()
+        out[k] = eng.result(rid)
+    assert out[3] == out[0] == [7] * 16
     st = eng.stats
     assert st["spec_accepted"] > 0, "no draft ever accepted"
     assert st["decode_steps"] < 16, (

@@ -13,16 +13,14 @@ import paddle_tpu as pt
 from paddle_tpu import layers as L
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_BIN = os.path.join(_REPO, "paddle_tpu", "native", "standalone_trainer")
 _BUILD = os.path.join(_REPO, "tools", "build_standalone_trainer.sh")
 
 
-def _ensure_built():
-    src = os.path.join(_REPO, "paddle_tpu", "native", "standalone_trainer.c")
-    if (os.path.exists(_BIN)
-            and os.path.getmtime(_BIN) >= os.path.getmtime(src)):
-        return True
-    r = subprocess.run(["bash", _BUILD], capture_output=True)
+def _build(out_path):
+    """Always build from the committed .c: a binary left in the tree by an
+    earlier run cannot be told from a stale one (mtimes do not survive a
+    copy or a checkout)."""
+    r = subprocess.run(["bash", _BUILD, out_path], capture_output=True)
     return r.returncode == 0
 
 
@@ -54,7 +52,8 @@ def test_save_load_train_model_roundtrip(tmp_path):
 
 @pytest.mark.skipif(shutil.which("cc") is None, reason="no C compiler")
 def test_standalone_trainer_binary_trains(tmp_path):
-    if not _ensure_built():
+    binary = str(tmp_path / "standalone_trainer")
+    if not _build(binary):
         pytest.skip("standalone trainer build failed (no python3-config?)")
     # build + save a CTR train model
     main, startup = pt.Program(), pt.Program()
@@ -92,7 +91,7 @@ def test_standalone_trainer_binary_trains(tmp_path):
     out_dir = str(tmp_path / "out")
     env = dict(os.environ)
     env["PADDLE_TPU_HOME"] = _REPO
-    r = subprocess.run([_BIN, model_dir, data, "32", "2", out_dir],
+    r = subprocess.run([binary, model_dir, data, "32", "2", out_dir],
                        env=env, capture_output=True, timeout=240)
     assert r.returncode == 0, (r.stdout.decode()[-2000:]
                                + r.stderr.decode()[-2000:])

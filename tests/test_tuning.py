@@ -7,9 +7,10 @@ recording, and the acceptance equivalences:
   * FLAGS_tuning_mode=consult with a swept DB reproduces the PR 5 per-shape
     igemm decisions on the PERF.md r6 cost-table shapes (and can beat them
     with a measured override);
-  * the swept BENCH_r05 attention split — XLA at seq<=128, the Pallas
-    kernel at s512 — resolves from the DB, and an un-runnable backend
-    degrades at dispatch instead of breaking numerics.
+  * a swept attention split — XLA at seq<=128, the Pallas kernel at s512,
+    which is what a v5e run of 2026-07 measured on code that predates
+    PRs 1-20 — resolves from the DB, and an un-runnable backend gives way
+    at dispatch instead of breaking numerics.
 """
 import json
 import os
@@ -247,7 +248,7 @@ def test_igemm_force_flags_override_the_db(tuned):
     pt.flags.set_flags({"conv_implicit_gemm": "auto"})
 
 
-# -- attention backend: the BENCH_r05 split (acceptance) ---------------------
+# -- attention backend: the swept s128/s512 split (acceptance) ---------------
 
 def _attn_key(b, nh, s, dh, dtype="float32"):
     return tuning.canonical_key(

@@ -12,7 +12,10 @@ from tools import _timing  # noqa: E402
 
 def run(use_flash):
     import paddle_tpu as pt
+    from paddle_tpu import compile_cache
     from paddle_tpu.models import transformer
+
+    compile_cache.configure()
     cfg = transformer.TransformerConfig(
         vocab_size=30522, hidden_size=768, num_layers=12, num_heads=12,
         ffn_size=3072, max_position=512, dropout=0.0, use_tp=False,

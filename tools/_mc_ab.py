@@ -99,10 +99,10 @@ def _build(cfg, seq_len, transpile=None, pipeline=None):
 
 class _Arm:
     """One built+initialized training arm with its own program/scope, so
-    competing arms can be timed in INTERLEAVED windows (A B A B ...): the
-    shared box's one-sided interference drifts on second-to-minute scales,
-    which sequential per-arm measurement aliases straight into the A/B
-    margin (observed: the same pair swinging keep<->retire between runs)."""
+    competing arms can be timed in INTERLEAVED windows (A B A B ...):
+    machine drift on second-to-minute scales is what sequential per-arm
+    measurement aliases straight into the A/B margin (observed: the same
+    pair swinging keep<->retire between runs)."""
 
     def __init__(self, build, target_of, feed):
         import paddle_tpu as pt
@@ -188,8 +188,8 @@ def _run_arm(build, target_of, feed, iters, passes, parity_steps=3):
 
 def _ab_row(tokens: int, off_stats: dict, on_stats: dict) -> dict:
     """One overlap_ab block entry. The verdict compares MIN-of-windows (the
-    bench.py steady-state convention: interference on the shared box is
-    one-sided, so best-window is the honest estimate and is far more stable
+    bench.py steady-state convention: interference only ever slows a
+    window, so best-window is the honest estimate and is far more stable
     across runs than the median of 2-3 interleaved windows) under the wider
     of the two arms' bands and the gate.py default."""
     band = max(_timing.DEFAULT_BAND, off_stats["band"], on_stats["band"])
@@ -228,9 +228,9 @@ def campaign(n_devices=8, iters=4, passes=2, sweep=None, record=None,
     devs = jax.devices()
     if len(devs) < n_devices:
         raise RuntimeError(
-            f"campaign needs {n_devices} devices, found {len(devs)} — "
-            f"provision a virtual CPU mesh first (bench.py --multichip "
-            f"re-execs with XLA_FLAGS=--xla_force_host_platform_device_count)")
+            f"campaign needs {n_devices} devices, found {len(devs)} — on a "
+            f"host with no TPU, `bench.py --multichip` provisions a virtual "
+            f"CPU mesh in a fresh process first")
     platform = devs[0].platform
     if quick:
         iters, passes = max(2, iters // 2), min(passes, 2)
@@ -446,6 +446,9 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args(argv)
 
+    from paddle_tpu import compile_cache
+
+    compile_cache.configure()
     sweep = [float(x) for x in args.sweep.split(",") if x.strip()] or None
     out = campaign(n_devices=args.devices, iters=args.iters,
                    passes=args.passes, sweep=sweep,

@@ -92,6 +92,9 @@ def main():
     ap.add_argument("--window", type=int, default=4)
     args = ap.parse_args()
 
+    from paddle_tpu import compile_cache
+
+    compile_cache.configure()
     sync_ex_s, sync_c = run_arm(False, args.batches, args.host_ms, args.window)
     pipe_ex_s, pipe_c = run_arm(True, args.batches, args.host_ms, args.window)
     print(json.dumps({

@@ -40,12 +40,11 @@ ARMS = {
 
 
 def main():
-    on_tpu = jax.devices()[0].platform == "tpu"
     peak = bench._peak_flops(jax.devices()[0])
     results = {}
     for name, (igemm, fuse) in ARMS.items():
         flags.set_flags({"conv_implicit_gemm": igemm, "bn_fuse_stats": fuse})
-        img_s, mfu, windows = bench._resnet_arm(on_tpu, peak)
+        img_s, mfu, windows = bench._resnet_arm(peak)
         results[name] = {"img_s": round(img_s, 1), "mfu": round(mfu, 4),
                          "windows_img_s": windows,
                          "band": round(_timing.interference_band(windows), 4)}

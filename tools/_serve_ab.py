@@ -1122,6 +1122,9 @@ def _mk_engine(cfg, args, prefix_cache=None, draft_k=None):
 def main():
     import jax
 
+    from paddle_tpu import compile_cache
+
+    compile_cache.configure()
     on_tpu = jax.devices()[0].platform == "tpu"
     ap = argparse.ArgumentParser()
     ap.add_argument("--rates", default=None,

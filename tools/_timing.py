@@ -26,8 +26,7 @@ __all__ = ["timed_windows", "time_call", "median", "interference_band",
            "latency_stats"]
 
 # gate.py's interference band: margins inside it are machine noise, not a
-# measured win (PERF.md r4 — single bursts on the shared box outlast a
-# timed pass)
+# measured win (PERF.md r4 — a single burst can outlast a timed pass)
 DEFAULT_BAND = 0.05
 
 

@@ -135,8 +135,8 @@ EMB_HIT_RATE_FLOOR = 0.5
 MC_PARITY_DRIFT = 5e-3
 # scaling floors. On a host-platform virtual mesh every "device" shares one
 # silicon, so ideal speedup_vs_single is ~1.0 and the number measures pure
-# partitioning/collective overhead; the dp shard_map arm measures ~0.13 on
-# the shared box, so 0.05 trips only on a real scheduling regression. On
+# partitioning/collective overhead; the dp shard_map arm measures ~0.13
+# there, so 0.05 trips only on a real scheduling regression. On
 # real chips per-device efficiency is the honest floor.
 MC_CPU_SPEEDUP_FLOOR = 0.05
 MC_EFFICIENCY_FLOOR = 0.5
@@ -234,19 +234,21 @@ def run_chaos() -> int:
 def check_kernel_registry() -> int:
     """Pallas kernel-workbench lint (ISSUE 9): every registered kernel must
     carry (1) a callable XLA reference, (2) a shape gate, (3) a tuning-DB
-    decision op with a real key speller, and (4) an equivalence test that
-    actually exists in tests/ — an unmeasured or unreferenced kernel cannot
-    land silently (the keep-or-retire contract made structural)."""
+    decision op with a real key speller, (4) an equivalence test that
+    actually exists in tests/, and (5) an on-chip case in
+    tools/kernel_check.py (the interpreter alone keeps no kernel in the
+    tree) — an unmeasured or unreferenced kernel cannot land silently (the
+    keep-or-retire contract made structural)."""
     sys.path.insert(0, REPO)
     from paddle_tpu import tuning
     from paddle_tpu.ops.pallas_kernels import all_kernels
+    from tools import kernel_check
 
     # decision op -> the tuning key speller that proves the op is wired
     key_spellers = {
         "attention": tuning.attention_key,
         "epilogue": tuning.epilogue_key,
         "conv2d": tuning.conv_key,
-        "xent": tuning.xent_key,
     }
     test_defs = []
     for path in glob.glob(os.path.join(REPO, "tests", "*.py")):
@@ -268,6 +270,8 @@ def check_kernel_registry() -> int:
         if not test or f"def {test}" not in blob:
             problems.append(
                 f"equivalence test {test!r} not defined under tests/")
+        if name not in kernel_check.CASES:
+            problems.append("no on-chip case in tools/kernel_check.py")
         if problems:
             print(f"[gate] FAIL: pallas kernel '{name}': "
                   + "; ".join(problems), flush=True)

@@ -1,0 +1,249 @@
+"""On-chip kernel check: every Pallas kernel in the workbench registry is
+compiled by Mosaic and run ON THE TPU (no interpreter) at the shape
+bench.py or the serving engine engages it, and compared with its
+registered XLA reference — forward, and backward where it has one.
+
+A kernel does not stay in the tree on the strength of the interpreter:
+tests/ pin each kernel to its reference in interpret mode on the CPU, this
+pins the compiled kernel on the chip. `CASES` is keyed by registry name and
+`tools/gate.py --kernels` fails a registered kernel that has no case here.
+
+The reference runs under `jax.default_matmul_precision("highest")` (for the
+reference only — the TPU's default fp32 matmul rounds operands to bf16,
+which would be the reference's error, not the kernel's). Errors are
+max-abs differences relative to the reference's max-abs value.
+
+    python tools/kernel_check.py        # exit 0 = all matched, on a TPU
+
+Exits 2 off the chip, 1 on any mismatch, compile failure or missing case.
+"""
+from __future__ import annotations
+
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+# tolerance by the dtype the kernel computes in: fp32 kernels agree with a
+# highest-precision reference to rounding; bf16 operands carry 2^-8
+# relative rounding into every product and into the stored result
+TOL = {"float32": 2e-5, "bfloat16": 3e-2}
+GRAD_TOL = {"float32": 1e-4, "bfloat16": 5e-2}
+
+
+def _rand(key, shape, dtype, scale=1.0):
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
+def _rel_err(got, want) -> float:
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(got - want)) / (np.max(np.abs(want)) + 1e-30))
+
+
+def _compare(fn, ref, args, n_diff: int, dtype: str) -> dict:
+    """Run fn and ref on `args`; with n_diff > 0 also pull one seeded
+    cotangent back through both and compare the first n_diff gradients."""
+    out = {}
+    if n_diff:
+        diff, rest = args[:n_diff], args[n_diff:]
+        got, got_vjp = jax.vjp(lambda *d: fn(*d, *rest), *diff)
+        with jax.default_matmul_precision("highest"):
+            want, want_vjp = jax.vjp(lambda *d: ref(*d, *rest), *diff)
+        ct = _rand(jax.random.PRNGKey(99), got.shape, got.dtype)
+        got_g = got_vjp(ct)
+        with jax.default_matmul_precision("highest"):
+            want_g = want_vjp(ct)
+        out["grad_err"] = max(_rel_err(g, w) for g, w in zip(got_g, want_g))
+        out["grad_tol"] = GRAD_TOL[dtype]
+    else:
+        got = fn(*args)
+        with jax.default_matmul_precision("highest"):
+            want = ref(*args)
+    out["err"] = _rel_err(got, want)
+    out["tol"] = TOL[dtype]
+    out["finite"] = bool(np.all(np.isfinite(np.asarray(got, np.float32))))
+    out["ok"] = bool(out["finite"] and out["err"] <= out["tol"]
+                     and out.get("grad_err", 0.0) <= out.get("grad_tol", 1.0))
+    return out
+
+
+# ---------------------------------------------------------------------------
+# cases: registry name -> [(label, thunk -> result dict)]
+# ---------------------------------------------------------------------------
+
+
+def _paged_cases(spec):
+    """The serving default: decode attention over the 12 x 64 heads of the
+    BERT-base-shaped decoder, 2048 x 16 pool (chip_smoke.py's geometry)."""
+
+    def case(dtype):
+        B, nh, dh, ps, pages, P = 4, 12, 64, 16, 2048, 16
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        q = _rand(ks[0], (B, nh, dh), dtype)
+        kp = _rand(ks[1], (pages, ps, nh, dh), dtype)
+        vp = _rand(ks[2], (pages, ps, nh, dh), dtype)
+        table = jax.random.permutation(ks[3], pages)[:B * P].reshape(B, P)
+        lens = jnp.asarray([208, 48, 13, 1], jnp.int32)
+        assert spec.supported(q.shape, kp.shape)
+        return _compare(
+            lambda *a: spec.fn(*a, sm_scale=dh ** -0.5),
+            lambda *a: spec.reference(*a, sm_scale=dh ** -0.5),
+            (q, kp, vp, table.astype(jnp.int32), lens), 0, dtype)
+
+    return [(f"b4 nh12 dh64 pool2048x16 {d}", lambda d=d: case(d))
+            for d in ("float32", "bfloat16")]
+
+
+def _attention_cases(spec, B, S, ragged=False):
+    """Self-attention at BERT-base heads under AMP (bf16), forward and the
+    fused backward."""
+
+    def case(causal, with_lens):
+        nh, dh = 12, 64
+        ks = jax.random.split(jax.random.PRNGKey(1), 3)
+        q, k, v = (_rand(kk, (B, nh, S, dh), "bfloat16") for kk in ks)
+        assert spec.supported(q.shape, k.shape, None)
+        kw = dict(causal=causal, sm_scale=dh ** -0.5)
+        if with_lens:
+            kw["kv_lens"] = jnp.asarray(
+                np.random.default_rng(2).integers(1, S + 1, B), jnp.int32)
+        return _compare(lambda *a: spec.fn(*a, **kw),
+                        lambda *a: spec.reference(*a, **kw),
+                        (q, k, v), 3, "bfloat16")
+
+    arms = [(False, False), (True, False)] + ([(False, True)] if ragged
+                                              else [])
+    return [(f"b{B} s{S} nh12 dh64 bf16 causal={c} ragged={r} fwd+bwd",
+             lambda c=c, r=r: case(c, r)) for c, r in arms]
+
+
+def _bn_apply_cases(spec):
+    """ResNet-50 NHWC stage tails under AMP: a stage-0 3x3 output, the
+    stage-0 block exit with its residual, and the last stage."""
+
+    def case(shape, residual):
+        C = shape[-1]
+        ks = jax.random.split(jax.random.PRNGKey(3), 6)
+        x = _rand(ks[0], shape, "bfloat16")
+        scale, bias, mean = (_rand(kk, (C,), "float32") for kk in ks[1:4])
+        inv = jnp.abs(_rand(ks[4], (C,), "float32")) + 0.5
+        args = (x, scale, bias, mean, inv)
+        if residual:
+            args += (_rand(ks[5], shape, "bfloat16"),)
+        assert spec.supported(shape, "bfloat16", True, "relu")
+
+        def call(f):
+            if residual:
+                return lambda x, s, b, m, v, r: f(x, s, b, m, v, act="relu",
+                                                  residual=r)
+            return lambda x, s, b, m, v: f(x, s, b, m, v, act="relu")
+
+        return _compare(call(spec.fn), call(spec.reference), args,
+                        len(args), "bfloat16")
+
+    return [(f"NHWC {shape} bf16 relu residual={res} fwd+bwd",
+             lambda shape=shape, res=res: case(shape, res))
+            for shape, res in (((128, 56, 56, 64), False),
+                               ((128, 56, 56, 256), True),
+                               ((128, 7, 7, 2048), True))]
+
+
+def _layer_norm_cases(spec):
+    """The BERT-base LN rows of one b128 s128 step, and the ffn width."""
+
+    def case(rows, width, act):
+        ks = jax.random.split(jax.random.PRNGKey(4), 3)
+        x = _rand(ks[0], (rows, width), "bfloat16")
+        scale = _rand(ks[1], (width,), "float32")
+        bias = _rand(ks[2], (width,), "float32")
+        assert spec.supported((rows, width), "bfloat16", act)
+        return _compare(lambda *a: spec.fn(*a, act=act),
+                        lambda *a: spec.reference(*a, act=act),
+                        (x, scale, bias), 3, "bfloat16")
+
+    return [(f"[{r}, {w}] bf16 act={a} fwd+bwd",
+             lambda r=r, w=w, a=a: case(r, w, a))
+            for r, w, a in ((128 * 128, 768, "identity"),
+                            (128 * 128, 3072, "relu"))]
+
+
+CASES = {
+    "attention_paged_decode": _paged_cases,
+    # bench_bert_long: b64 s512
+    "attention_short_seq": lambda spec: _attention_cases(spec, 64, 512),
+    # bench_bert_short: b128 s128
+    "attention_short128": lambda spec: _attention_cases(spec, 128, 128,
+                                                        ragged=True),
+    "epilogue_bn_apply": _bn_apply_cases,
+    "epilogue_layer_norm": _layer_norm_cases,
+}
+
+
+def _flash_bundled_case():
+    """Not a workbench kernel but an arm of attention_backend (S > 1024):
+    jax's bundled flash kernel against the dispatch's own reference."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    from paddle_tpu.ops.attention_ops import _reference_attention
+
+    B, nh, S, dh = 2, 12, 2048, 64
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q, k, v = (_rand(kk, (B, nh, S, dh), "bfloat16") for kk in ks)
+    return _compare(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                           sm_scale=dh ** -0.5),
+        lambda q, k, v: _reference_attention(q, k, v, None, True,
+                                             dh ** -0.5),
+        (q, k, v), 3, "bfloat16")
+
+
+def main() -> int:
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"kernel_check.py: needs a TPU, jax found {dev.platform!r}",
+              file=sys.stderr)
+        return 2
+    from paddle_tpu import compile_cache
+    from paddle_tpu.ops.pallas_kernels import all_kernels
+
+    compile_cache.configure()
+    kernels = all_kernels()
+    plan = []
+    failed = [f"{name}: registered kernel has no on-chip case"
+              for name in sorted(kernels) if name not in CASES]
+    for name in sorted(kernels):
+        if name in CASES:
+            plan += [(name, label, thunk)
+                     for label, thunk in CASES[name](kernels[name])]
+    plan.append(("flash_bundled", "b2 s2048 nh12 dh64 bf16 causal fwd+bwd",
+                 _flash_bundled_case))
+    results = {}
+    for name, label, thunk in plan:
+        # a refused compile must not hide the verdicts of the other kernels:
+        # record it, go on, and fail the run at the end
+        try:
+            res = thunk()
+        except Exception as e:  # noqa: BLE001 - reported and fails the run
+            res = {"ok": False,
+                   "error": f"{type(e).__name__}: {str(e)[:2000]}"}
+        results.setdefault(name, {})[label] = res
+        print(f"[{'ok' if res['ok'] else 'FAIL'}] {name} :: {label} :: "
+              f"{json.dumps(res)}", flush=True)
+        if not res["ok"]:
+            failed.append(f"{name} :: {label}")
+    print(json.dumps({
+        "ok": not failed, "failed": failed,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "results": results}))
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

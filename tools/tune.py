@@ -79,8 +79,9 @@ RN50_CONV_SHAPES = [
      [(1, 1), (1, 1)], (1, 1)),
 ]
 
-# bench.py's two BERT attention regimes: the headline s128 (XLA wins,
-# BENCH_r05) and the s512 kernel-proof row (Pallas wins ~9%)
+# bench.py's two BERT attention regimes: the headline s128 and the s512
+# kernel-proof row (a v5e run of 2026-07, on code older than PRs 1-20, had
+# XLA ahead at s128 and the Pallas kernel ~9% ahead at s512)
 ATTENTION_SHAPES = [
     ("bert_s128", 128, 12, 128, 64, False),
     ("bert_s512", 64, 12, 512, 64, False),
