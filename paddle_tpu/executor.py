@@ -701,6 +701,82 @@ class Executor:
             return program._pipeline.run_step(self, scope, feed,
                                               fetch_names), None, None
 
+        with profiler.stage_timer("pipeline.prepare"):
+            comp, feed_vals, ro_vals, rw_vals, key, emb_engine, emb_ticket = \
+                self._prepare_step(program, feed, fetch_names, scope, mesh,
+                                   spmd_mode, rng_counter)
+
+        # FLAGS_check_nan_inf per-op validation only works on concrete
+        # values: under jax.disable_jit() (the guard's blame replay, debug
+        # sessions) _maybe_check_finite fires with op attribution during the
+        # trace below. On the compiled path the flag used to silently force
+        # eager semantics; now the jit path is KEPT and a one-time warning
+        # points at the in-graph health sentinel instead.
+        check_nan = flags.get_flag("check_nan_inf")
+        eager = bool(jax.config.jax_disable_jit)
+        if check_nan and not eager:
+            _warn_check_nan_inf_keeps_jit()
+        with profiler.stage_timer("pipeline.dispatch"):
+            fetches, new_rw, new_extra, token = comp.fn(
+                tuple(feed_vals), ro_vals, rw_vals, key)
+        if check_nan and eager and getattr(comp, "spmd_mode",
+                                           "gspmd") == "shard_map":
+            # under shard_map the body values stay tracers even with
+            # disable_jit, so per-op attribution is unavailable — fall back
+            # to a whole-step output check
+            for group, names in ((fetches, comp.fetch_names),
+                                 (new_rw, comp.rw_names)):
+                for n, v in zip(names, group):
+                    arr = np.asarray(v)
+                    if arr.dtype.kind == "f" and not np.isfinite(arr).all():
+                        raise RuntimeError(
+                            f"FLAGS_check_nan_inf: non-finite value in "
+                            f"'{n}' (per-op attribution is unavailable "
+                            f"under shard_map/with_collective)")
+        if flags.get_flag("benchmark"):
+            jax.block_until_ready((fetches, new_rw))  # reference operator.cc:926
+
+        for n, v in zip(comp.rw_names, new_rw):
+            scope.set_var(n, v)
+        for n, v in zip(comp.extra_w, new_extra):
+            scope.set_var(n, v)
+
+        if emb_engine is not None and emb_ticket is not None:
+            # hand the step's evicted-row output handles to the engine (no
+            # sync — write-back lands when the device array materializes)
+            emb_engine.note_dispatched(emb_ticket, scope)
+
+        # the in-graph health vector (resilience/guardrails.py) rides the
+        # step's outputs: hand the DEVICE array back so reading it after the
+        # completion token resolves costs a 4-float transfer, no sync here
+        health = None
+        src = getattr(comp, "health_src", "?")
+        if src == "?":  # resolve once per compiled entry
+            src = None
+            if GUARD_HEALTH_NAME in comp.extra_w:
+                src = ("extra", comp.extra_w.index(GUARD_HEALTH_NAME))
+            elif GUARD_HEALTH_NAME in comp.rw_names:
+                src = ("rw", comp.rw_names.index(GUARD_HEALTH_NAME))
+            comp.health_src = src
+        if src is not None:
+            group, idx = src
+            health = (new_extra if group == "extra" else new_rw)[idx]
+
+        if return_numpy:
+            # blocks until the device has produced the fetches, then copies
+            # them to the host
+            with profiler.stage_timer("pipeline.fetch"):
+                outs = [np.asarray(x) for x in fetches]
+            return outs, token, health
+        return list(fetches), token, health
+
+    def _prepare_step(self, program, feed, fetch_names, scope, mesh,
+                      spmd_mode, rng_counter):
+        """Everything one step needs before its dispatch (the
+        `pipeline.prepare` stage): feeds cast to their declared dtypes, the
+        compile signature and the cache entry it names (`pipeline.compile`
+        on a miss), the state gathered from the scope and the step's PRNG
+        key."""
         from .core.selected_rows import is_selected_rows
 
         # tiered embeddings (embedding/engine.py): feeds staged by the
@@ -758,9 +834,10 @@ class Executor:
         prog_cache = self._cache.setdefault(program, {})
         comp = prog_cache.get(sig)
         if comp is None:
-            comp = self._compile(
-                program, block, feed_names, feed_vals, fetch_names, scope, mesh, spmd_mode
-            )
+            with profiler.stage_timer("pipeline.compile"):
+                comp = self._compile(
+                    program, block, feed_names, feed_vals, fetch_names,
+                    scope, mesh, spmd_mode)
             comp.spmd_mode = spmd_mode
             prog_cache[sig] = comp
             # bound the per-program cache (each entry pins a compiled XLA
@@ -808,68 +885,7 @@ class Executor:
         key = jax.random.fold_in(
             key,
             scope._run_counter if rng_counter is None else int(rng_counter))
-
-        # FLAGS_check_nan_inf per-op validation only works on concrete
-        # values: under jax.disable_jit() (the guard's blame replay, debug
-        # sessions) _maybe_check_finite fires with op attribution during the
-        # trace below. On the compiled path the flag used to silently force
-        # eager semantics; now the jit path is KEPT and a one-time warning
-        # points at the in-graph health sentinel instead.
-        check_nan = flags.get_flag("check_nan_inf")
-        eager = bool(jax.config.jax_disable_jit)
-        if check_nan and not eager:
-            _warn_check_nan_inf_keeps_jit()
-        t_dispatch = time.perf_counter()
-        fetches, new_rw, new_extra, token = comp.fn(
-            tuple(feed_vals), ro_vals, rw_vals, key)
-        profiler.record_stage("pipeline.dispatch",
-                              time.perf_counter() - t_dispatch)
-        if check_nan and eager and getattr(comp, "spmd_mode",
-                                           "gspmd") == "shard_map":
-            # under shard_map the body values stay tracers even with
-            # disable_jit, so per-op attribution is unavailable — fall back
-            # to a whole-step output check
-            for group, names in ((fetches, comp.fetch_names),
-                                 (new_rw, comp.rw_names)):
-                for n, v in zip(names, group):
-                    arr = np.asarray(v)
-                    if arr.dtype.kind == "f" and not np.isfinite(arr).all():
-                        raise RuntimeError(
-                            f"FLAGS_check_nan_inf: non-finite value in "
-                            f"'{n}' (per-op attribution is unavailable "
-                            f"under shard_map/with_collective)")
-        if flags.get_flag("benchmark"):
-            jax.block_until_ready((fetches, new_rw))  # reference operator.cc:926
-
-        for n, v in zip(comp.rw_names, new_rw):
-            scope.set_var(n, v)
-        for n, v in zip(comp.extra_w, new_extra):
-            scope.set_var(n, v)
-
-        if emb_engine is not None and emb_ticket is not None:
-            # hand the step's evicted-row output handles to the engine (no
-            # sync — write-back lands when the device array materializes)
-            emb_engine.note_dispatched(emb_ticket, scope)
-
-        # the in-graph health vector (resilience/guardrails.py) rides the
-        # step's outputs: hand the DEVICE array back so reading it after the
-        # completion token resolves costs a 4-float transfer, no sync here
-        health = None
-        src = getattr(comp, "health_src", "?")
-        if src == "?":  # resolve once per compiled entry
-            src = None
-            if GUARD_HEALTH_NAME in comp.extra_w:
-                src = ("extra", comp.extra_w.index(GUARD_HEALTH_NAME))
-            elif GUARD_HEALTH_NAME in comp.rw_names:
-                src = ("rw", comp.rw_names.index(GUARD_HEALTH_NAME))
-            comp.health_src = src
-        if src is not None:
-            group, idx = src
-            health = (new_extra if group == "extra" else new_rw)[idx]
-
-        if return_numpy:
-            return [np.asarray(x) for x in fetches], token, health
-        return list(fetches), token, health
+        return comp, feed_vals, ro_vals, rw_vals, key, emb_engine, emb_ticket
 
     def train_from_dataset(
         self,
@@ -1087,19 +1103,18 @@ class Executor:
                         pass
                 sh = comp.feed_shardings.get(n) if (
                     comp is not None and comp.feed_shardings) else None
-                t0 = time.perf_counter()
-                if sh is not None:
-                    out[n] = _to_global(v, sh) if multiproc \
-                        else jax.device_put(v, sh)
-                elif mesh is None:
-                    out[n] = v if isinstance(v, jax.Array) \
-                        else jax.device_put(v)
-                else:
-                    # mesh program before its first compile: leave the batch
-                    # on host; run() places it and later batches get staged
-                    out[n] = v
-                profiler.record_stage("pipeline.device_put",
-                                      time.perf_counter() - t0)
+                with profiler.stage_timer("pipeline.device_put"):
+                    if sh is not None:
+                        out[n] = _to_global(v, sh) if multiproc \
+                            else jax.device_put(v, sh)
+                    elif mesh is None:
+                        out[n] = v if isinstance(v, jax.Array) \
+                            else jax.device_put(v)
+                    else:
+                        # mesh program before its first compile: leave the
+                        # batch on host; run() places it and later batches
+                        # get staged
+                        out[n] = v
             return out
 
         return place

@@ -15,7 +15,7 @@ Usage is module-level against the process-wide default registry:
     from paddle_tpu import observability as obs
     obs.counter_inc("serving.prefills")
     obs.histogram_observe("serving.ttft_s", 0.042)
-    with obs.span("serving.decode"):
+    with obs.span("serving.decode", rows=8):
         ...                         # TraceAnnotation + histogram + JSONL
     snap = obs.snapshot()           # everything, atomically
 """
@@ -27,8 +27,8 @@ from .exporters import (  # noqa: F401
     start_http_exporter, write_prometheus)
 from .registry import (  # noqa: F401
     MetricsRegistry, attach_sink, base_name, counter_inc, detach_sink,
-    enabled, event, gauge_set, histogram_observe, registry, reset,
-    snapshot, span, stage_counters, stage_record)
+    enabled, event, gauge_set, gc_pause_seconds, histogram_observe,
+    registry, reset, snapshot, span, stage_counters, stage_record)
 from .slo import SloMonitor, SloRule, default_serving_monitor  # noqa: F401
 
 
@@ -47,6 +47,7 @@ __all__ = [
     "MetricsRegistry", "registry", "enabled", "counter_inc", "gauge_set",
     "histogram_observe", "event", "span", "snapshot", "stage_record",
     "stage_counters", "reset", "attach_sink", "detach_sink", "base_name",
+    "gc_pause_seconds",
     "schema", "JsonlWriter", "jsonl_line", "prometheus_text",
     "write_prometheus", "parse_prometheus", "start_http_exporter",
     "SloMonitor", "SloRule", "default_serving_monitor",

@@ -16,6 +16,12 @@ used to take five bespoke readers. Design points:
   * declared schema: names are registered up front (schema.DECLARED);
     free-form names still record but surface in `snapshot()["undeclared"]`
     and tools/gate.py --obs fails on them;
+  * spans nest: a per-thread stack gives every span its parent, its self
+    time and the `step` of its root, and both ways to time host work
+    (`span`, `profiler.stage_timer`) are the one `Span` class, a
+    `jax.profiler.TraceAnnotation` each, so they sit on the device trace's
+    clock; closing one takes the lock once, and builds a record only when a
+    sink is attached;
   * `snapshot(reset=True)` is atomic — read-and-zero under the lock, so
     concurrent writers can never be double-counted or lost across the
     reset boundary (the 8-thread test pins this);
@@ -28,7 +34,7 @@ used to take five bespoke readers. Design points:
 from __future__ import annotations
 
 import bisect
-import contextlib
+import gc
 import math
 import threading
 import time
@@ -40,7 +46,7 @@ from . import schema as _schema
 __all__ = ["MetricsRegistry", "registry", "enabled", "counter_inc",
            "gauge_set", "histogram_observe", "event", "span", "snapshot",
            "stage_record", "stage_counters", "reset", "attach_sink",
-           "detach_sink"]
+           "detach_sink", "gc_pause_seconds"]
 
 
 def enabled() -> bool:
@@ -121,6 +127,163 @@ def base_name(series_key: str) -> str:
     return series_key.split("{", 1)[0]
 
 
+class _ThreadState(threading.local):
+    """The open spans of one thread, innermost last, and the dict the
+    innermost collecting span books self seconds into."""
+
+    def __init__(self):
+        self.stack: list = []
+        self.collect: dict | None = None
+
+
+_tls = _ThreadState()
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, resolved once
+
+
+def _annotation_cls():
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        import jax  # deferred: tools that only read streams never pay it
+
+        _TraceAnnotation = jax.profiler.TraceAnnotation
+    return _TraceAnnotation
+
+
+class Span:
+    """One timed host interval: a `jax.profiler.TraceAnnotation` (so it sits
+    on the device trace's clock), a sample in the registry (`<name>.seconds`
+    for a span, the `[events, seconds]` stage for a stage timer) and, when a
+    sink is attached, one JSONL record carrying `parent` (the enclosing
+    span's name) and `step` (the `step` attribute of the outermost span that
+    has one), so the spans of one iteration share an identifier.
+
+    After exit `dur_s` is its duration and `self_s` that minus what its
+    direct children covered. A span opened with `collect=<dict>` has the self
+    seconds of itself and everything under it added to that dict by name:
+    the entries sum to its duration."""
+
+    __slots__ = ("_reg", "name", "labels", "attrs", "_stage", "_collect",
+                 "_outer_collect", "_ann", "_t0", "_child_s", "parent",
+                 "step", "dur_s", "self_s")
+
+    def __init__(self, reg, name, labels, attrs, stage=False, collect=None):
+        self._reg = reg
+        self.name = name
+        self.labels = labels
+        self.attrs = attrs
+        self._stage = stage
+        self._collect = collect
+
+    def note(self, **attrs) -> None:
+        """Attributes learned after entry: they reach the sink record, not
+        the annotation (which took its attributes when it was opened)."""
+        self.attrs.update(attrs)
+
+    def __enter__(self):
+        st = _tls
+        stack = st.stack
+        attrs = self.attrs
+        if stack:
+            outer = stack[-1]
+            self.parent = outer.name
+            self.step = attrs["step"] if "step" in attrs else outer.step
+        else:
+            self.parent = None
+            self.step = attrs.get("step")
+        if self._collect is not None:
+            self._outer_collect = st.collect
+            st.collect = self._collect
+        stack.append(self)
+        self._child_s = 0.0
+        self._ann = ann = _annotation_cls()(self.name, **attrs)
+        ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        st = _tls
+        stack = st.stack
+        name = self.name
+        stack.pop()  # `with` blocks of one thread close innermost first
+        self.dur_s = dt
+        self.self_s = own = dt - self._child_s
+        if stack:
+            stack[-1]._child_s += dt
+        collect = st.collect
+        if collect is not None:
+            collect[name] = collect.get(name, 0.0) + own
+            if self._collect is not None:
+                st.collect = self._outer_collect
+        reg = self._reg
+        with reg._lock:
+            if self._stage:
+                reg._stage_locked(name, dt, 1, True)
+            else:
+                reg._observe_locked(name + ".seconds", self.labels, dt)
+        sinks = reg._sinks
+        if sinks:
+            rec = {"ts": time.time(), "type": "span", "name": name,
+                   "dur_s": round(dt, 9)}
+            if self.labels:
+                rec["labels"] = dict(self.labels)
+            if self.attrs:
+                rec["attrs"] = dict(self.attrs)
+            if self.parent is not None:
+                rec["parent"] = self.parent
+            if self.step is not None:
+                rec["step"] = self.step
+            _emit(sinks, rec)
+        return False
+
+
+class _NullSpan:
+    """What `span` hands out while FLAGS_obs_enable is off."""
+
+    __slots__ = ()
+    dur_s = self_s = 0.0
+
+    def note(self, **attrs) -> None:
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _BareStageTimer:
+    """A stage timer while FLAGS_obs_enable is off: the always-on
+    `[events, seconds]` accumulator and nothing else."""
+
+    __slots__ = ("_reg", "_stage", "_t0")
+
+    def __init__(self, reg, stage):
+        self._reg = reg
+        self._stage = stage
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._reg.stage_record(self._stage, time.perf_counter() - self._t0)
+        return False
+
+
+def _emit(sinks, rec: dict) -> None:
+    for s in sinks:
+        try:
+            s(rec)
+        except Exception:  # noqa: BLE001 — a broken sink never kills work
+            pass
+
+
 class MetricsRegistry:
     """Thread-safe typed metric store; see module docstring."""
 
@@ -133,7 +296,7 @@ class MetricsRegistry:
         self._stages: dict[str, list] = {}  # name -> [events, seconds]
         self._undeclared: set[str] = set()
         self._events: deque = deque(maxlen=max(1, int(max_events)))
-        self._sinks: list = []
+        self._sinks: tuple = ()  # replaced whole, so readers take no lock
         for spec in (schema or ()):
             name, kind = spec[0], spec[1]
             help_ = spec[2] if len(spec) > 2 else ""
@@ -176,13 +339,16 @@ class MetricsRegistry:
                           labels: dict | None = None) -> None:
         if not enabled():
             return
-        key = (name, _lkey(labels))
         with self._lock:
-            self._note(name)
-            h = self._hists.get(key)
-            if h is None:
-                h = self._hists[key] = _Histogram()
-            h.observe(value)
+            self._observe_locked(name, labels, value)
+
+    def _observe_locked(self, name, labels, value) -> None:
+        key = (name, _lkey(labels) if labels else ())
+        self._note(name)
+        h = self._hists.get(key)
+        if h is None:
+            h = self._hists[key] = _Histogram()
+        h.observe(value)
 
     def stage_record(self, stage: str, seconds: float,
                      events: int = 1) -> None:
@@ -191,17 +357,20 @@ class MetricsRegistry:
         enabled, a latency histogram per timed stage."""
         hist = seconds > 0.0 and enabled()
         with self._lock:
-            self._note(stage)
-            c = self._stages.get(stage)
-            if c is None:
-                c = self._stages[stage] = [0, 0.0]
-            c[0] += events
-            c[1] += seconds
-            if hist:
-                h = self._hists.get((stage, ()))
-                if h is None:
-                    h = self._hists[(stage, ())] = _Histogram()
-                h.observe(seconds)
+            self._stage_locked(stage, seconds, events, hist)
+
+    def _stage_locked(self, stage, seconds, events, hist) -> None:
+        self._note(stage)
+        c = self._stages.get(stage)
+        if c is None:
+            c = self._stages[stage] = [0, 0.0]
+        c[0] += events
+        c[1] += seconds
+        if hist:
+            h = self._hists.get((stage, ()))
+            if h is None:
+                h = self._hists[(stage, ())] = _Histogram()
+            h.observe(seconds)
 
     def event(self, name: str, payload: dict | None = None,
               level: str = "info") -> dict | None:
@@ -214,42 +383,26 @@ class MetricsRegistry:
         with self._lock:
             self._note(name)
             self._events.append(rec)
-            sinks = list(self._sinks)
-        for s in sinks:
-            try:
-                s(rec)
-            except Exception:  # noqa: BLE001 — a broken sink never kills work
-                pass
+        _emit(self._sinks, rec)
         return rec
 
-    @contextlib.contextmanager
-    def span(self, name: str, labels: dict | None = None):
-        """Named span: a `jax.profiler.TraceAnnotation` (visible in XPlane
-        traces) + a `<name>.seconds` histogram sample + a JSONL span record
-        through the sinks. No-op when the layer is disabled."""
+    def span(self, name: str, labels: dict | None = None, *,
+             collect: dict | None = None, **attrs):
+        """Named span (see `Span`): `labels` key the `<name>.seconds`
+        histogram series, `attrs` go to the annotation and the sink record
+        only (a request id there mints no series). No-op when the layer is
+        disabled."""
         if not enabled():
-            yield
-            return
-        import jax
+            return _NULL_SPAN
+        return Span(self, name, labels, attrs, collect=collect)
 
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation(name):
-            try:
-                yield
-            finally:
-                dt = time.perf_counter() - t0
-                self.histogram_observe(name + ".seconds", dt, labels)
-                rec = {"ts": time.time(), "type": "span", "name": name,
-                       "dur_s": round(dt, 9)}
-                if labels:
-                    rec["labels"] = dict(labels)
-                with self._lock:
-                    sinks = list(self._sinks)
-                for s in sinks:
-                    try:
-                        s(rec)
-                    except Exception:  # noqa: BLE001
-                        pass
+    def stage_timer(self, stage: str):
+        """`profiler.stage_timer`: a span whose sample is the stage's
+        `[events, seconds]` accumulator (always on, also with the layer
+        disabled) and the stage's own histogram."""
+        if not enabled():
+            return _BareStageTimer(self, stage)
+        return Span(self, stage, None, {}, stage=True)
 
     # -- readers -------------------------------------------------------------
     def stage_counters(self, reset: bool = False) -> dict:
@@ -304,12 +457,78 @@ class MetricsRegistry:
     def attach_sink(self, sink) -> None:
         """`sink(record: dict)` receives every event/span record."""
         with self._lock:
-            self._sinks.append(sink)
+            self._sinks = self._sinks + (sink,)
 
     def detach_sink(self, sink) -> None:
         with self._lock:
-            if sink in self._sinks:
-                self._sinks.remove(sink)
+            self._sinks = tuple(s for s in self._sinks if s != sink)
+
+
+# -- garbage-collector pauses --------------------------------------------------
+class _GcWatch:
+    """The `gc.callbacks` hook of the default registry. Every collection
+    bumps `host.gc.collections{generation}` and the process-wide pause
+    total and observes `host.gc.seconds` (every one, so that the series'
+    maximum exists in any window that allocated at all); a generation-2
+    collection is also a `host.gc` annotation on the profiler's clock. Collections never nest (the interpreter holds its `collecting`
+    flag across both callbacks), so one start stamp is enough.
+
+    A collection can start inside a section that holds the registry's lock
+    (an allocation there triggers it), on the very thread that holds it: the
+    hook therefore never waits for the lock, and what it could not book
+    rides along with the next collection."""
+
+    def __init__(self, reg: MetricsRegistry):
+        self._reg = reg
+        self._t0 = None
+        self._ann = None
+        self._owed: list = []  # (generation, seconds) not yet booked
+        self.total_s = 0.0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = None
+            if not enabled():
+                return
+            # no import from inside a collection: annotate only once some
+            # span has resolved the class
+            if info["generation"] == 2 and _TraceAnnotation is not None:
+                self._ann = _TraceAnnotation("host.gc", generation=2)
+                self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        if self._t0 is None:
+            return
+        dt = time.perf_counter() - self._t0
+        self._t0 = None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self.total_s += dt
+        self._owed.append((info["generation"], dt))
+        reg = self._reg
+        if reg._lock.acquire(blocking=False):
+            try:
+                for gen, seconds in self._owed:
+                    key = ("host.gc.collections",
+                           (("generation", str(gen)),))
+                    reg._note("host.gc.collections")
+                    reg._counters[key] = reg._counters.get(key, 0) + 1
+                    reg._observe_locked("host.gc.seconds", None, seconds)
+                self._owed.clear()
+            finally:
+                reg._lock.release()
+
+
+_gc_watch: _GcWatch | None = None
+
+
+def gc_pause_seconds() -> float:
+    """Seconds this process has spent inside garbage collections, on any
+    thread, since the default registry was created: a caller reads it at
+    both ends of an interval to learn how much of the interval was the
+    collector's (a collection on any thread holds the interpreter lock)."""
+    return _gc_watch.total_s if _gc_watch is not None else 0.0
 
 
 # -- the process-wide default registry ----------------------------------------
@@ -318,10 +537,11 @@ _default_lock = threading.Lock()
 
 
 def registry() -> MetricsRegistry:
-    """The default registry, created on first use with the declared schema
-    and the flag-configured exporters (FLAGS_obs_jsonl_dir JSONL stream,
-    FLAGS_obs_http_port /metrics endpoint) attached."""
-    global _default
+    """The default registry, created on first use with the declared schema,
+    the flag-configured exporters (FLAGS_obs_jsonl_dir JSONL stream,
+    FLAGS_obs_http_port /metrics endpoint) attached and the collector's
+    pauses booked into it (`_GcWatch`)."""
+    global _default, _gc_watch
     if _default is None:
         with _default_lock:
             if _default is None:
@@ -333,6 +553,8 @@ def registry() -> MetricsRegistry:
                 from . import exporters
 
                 exporters.install_flag_exporters(reg)
+                _gc_watch = _GcWatch(reg)
+                gc.callbacks.append(_gc_watch)
                 _default = reg
     return _default
 
@@ -353,8 +575,8 @@ def event(name, payload=None, level="info"):
     return registry().event(name, payload, level)
 
 
-def span(name, labels=None):
-    return registry().span(name, labels)
+def span(name, labels=None, *, collect=None, **attrs):
+    return registry().span(name, labels, collect=collect, **attrs)
 
 
 def snapshot(reset=False):

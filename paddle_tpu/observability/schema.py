@@ -27,8 +27,19 @@ DECLARED: list[tuple] = [
      "DeviceLoader producer: host batch materialization", ()),
     ("pipeline.device_put", STAGE,
      "host->device staging transfers (DeviceLoader / feed_placer)", ()),
+    ("pipeline.prepare", STAGE,
+     "Executor step before its dispatch: feed cast, signature, cache "
+     "lookup, state gathering, PRNG key fold (self time excludes "
+     "pipeline.compile)", ()),
+    ("pipeline.compile", STAGE,
+     "Executor._compile on a signature miss (inside pipeline.prepare): "
+     "block analysis and building the jitted step; tracing and the XLA "
+     "compile happen in that entry's first pipeline.dispatch", ()),
     ("pipeline.dispatch", STAGE,
      "Executor compiled-step dispatch (host side of one async step)", ()),
+    ("pipeline.fetch", STAGE,
+     "Executor.run read-back: blocked on the device plus the "
+     "device-to-host copy of the fetches", ()),
     ("pipeline.window_drain", STAGE,
      "run_async window-boundary waits on the oldest completion token", ()),
     ("feed.skip_corrupt", STAGE,
@@ -72,6 +83,38 @@ DECLARED: list[tuple] = [
      "prefill span durations (also a TraceAnnotation in XPlane)", ()),
     ("serving.decode.seconds", HISTOGRAM,
      "decode-step span durations (also a TraceAnnotation in XPlane)", ()),
+    # one scheduler iteration as a span tree (ISSUE 23): every span is a
+    # TraceAnnotation, a <name>.seconds histogram and a JSONL record with
+    # `parent` and the root's `step`
+    ("serving.step.seconds", HISTOGRAM,
+     "one ServingEngine.step() iteration, the root of the tree", ()),
+    ("serving.housekeeping.seconds", HISTOGRAM,
+     "config adoption, fault points, deadline expiry, pool audit, ladder, "
+     "occupancy and the controller tick (three spans a step)", ()),
+    ("serving.control.epoch.seconds", HISTOGRAM,
+     "one controller epoch: features, record_row, propose", ()),
+    ("serving.admit.seconds", HISTOGRAM,
+     "the admission loop: prefix match, pin, allocate with eviction, and "
+     "the prefills of what it admitted", ()),
+    ("serving.admit.self_seconds", HISTOGRAM,
+     "serving.admit less its serving.prefill children: the scheduler's "
+     "own part of admission", ()),
+    ("serving.ensure_writable.seconds", HISTOGRAM,
+     "page growth, preemption and copy-on-write (with its dispatch) "
+     "before a decode step", ()),
+    ("serving.feed_build.seconds", HISTOGRAM,
+     "building the numpy feeds of one prefill or decode step", ()),
+    ("serving.accept.seconds", HISTOGRAM,
+     "sampling, the accept loop over rows and prefix registration", ()),
+    ("serving.prefill.host_seconds", HISTOGRAM,
+     "serving.prefill less the seconds inside pipeline.fetch: the host's "
+     "part of one prefill", ()),
+    ("serving.decode.host_seconds", HISTOGRAM,
+     "serving.decode less the seconds inside pipeline.fetch: the host's "
+     "part of one decode step", ()),
+    ("serving.slow_step", EVENT,
+     "one iteration over engine.SLOW_STEP_S: step, dur_s, self seconds by "
+     "span name, gc_s, rows decoded, requests admitted", ()),
     ("serving.request", EVENT,
      "per-request lifecycle record: queued/admitted/first_token/finished/"
      "aborted/deadline_exceeded/shed/rejected/quarantined",
@@ -228,6 +271,12 @@ DECLARED: list[tuple] = [
     ("serving.control.actuation", EVENT,
      "actuation lifecycle record (staged/adopted, geometry change, "
      "rewarm)", ()),
+    # -- the host's own pauses (observability/registry._GcWatch) -------------
+    ("host.gc.collections", COUNTER,
+     "garbage collections by generation", ("generation",)),
+    ("host.gc.seconds", HISTOGRAM,
+     "seconds per collection, every generation (a generation-2 collection "
+     "is also a host.gc TraceAnnotation)", ()),
     # -- training step telemetry (executor.py async window) -----------------
     ("train.steps", COUNTER, "async steps drained to completion", ()),
     ("train.step_latency_s", HISTOGRAM,

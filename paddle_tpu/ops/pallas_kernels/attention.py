@@ -147,6 +147,7 @@ def _fwd(q, k, v, sm_scale, causal, interpret):
             transcendentals=B * nh * s * s),
         compiler_params=_params(),
         interpret=interpret,
+        name="short_seq_attention_fwd",
     )(q, k, v)
 
 
@@ -166,6 +167,7 @@ def _bwd(q, k, v, do, sm_scale, causal, interpret):
             transcendentals=B * nh * s * s),
         compiler_params=_params(),
         interpret=interpret,
+        name="short_seq_attention_bwd",
     )(q, k, v, do)
 
 

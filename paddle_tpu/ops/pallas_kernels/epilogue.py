@@ -166,6 +166,7 @@ def _apply_call_fwd(x2, params, res2, act, mode, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="bn_apply_act_fwd",
     )(*args)
 
 
@@ -198,6 +199,7 @@ def _apply_call_bwd(x2, params, res2, dy2, act, mode, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="bn_apply_act_bwd",
     )(*args)
 
 
@@ -364,6 +366,7 @@ def _make_ln(eps: float, act: str, interpret: bool):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
+            name="layer_norm_act_fwd",
         )(x2, s, b)
 
     @jax.custom_vjp
@@ -397,6 +400,7 @@ def _make_ln(eps: float, act: str, interpret: bool):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
+            name="layer_norm_act_bwd",
         )(x2, s, b, dy2)
         return dx2, jnp.sum(ds_p, axis=0), jnp.sum(db_p, axis=0)
 

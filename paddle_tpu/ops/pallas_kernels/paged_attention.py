@@ -137,6 +137,7 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(page_table, kv_lens, q, k_pool, v_pool)
 
 

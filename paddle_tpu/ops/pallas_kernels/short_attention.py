@@ -161,6 +161,7 @@ def _fwd(q, k, v, kv_lens, sm_scale, causal, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="short128_attention_fwd",
     )(*args)
 
 
@@ -185,6 +186,7 @@ def _bwd(q, k, v, kv_lens, do, sm_scale, causal, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="short128_attention_bwd",
     )(*args)
 
 

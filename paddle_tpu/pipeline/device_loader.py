@@ -45,10 +45,8 @@ def default_placement(feed_vars=None, device=None):
             arr = np.asarray(v)
             if name in dtypes:
                 arr = arr.astype(dtypes[name], copy=False)
-            t0 = time.perf_counter()
-            out[name] = jax.device_put(arr, device)
-            profiler.record_stage("pipeline.device_put",
-                                  time.perf_counter() - t0)
+            with profiler.stage_timer("pipeline.device_put"):
+                out[name] = jax.device_put(arr, device)
         return out
 
     return place

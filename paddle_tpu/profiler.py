@@ -19,7 +19,6 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
-import time
 
 import jax
 
@@ -139,13 +138,12 @@ def bump(stage: str, events: int = 1):
     _obs.stage_record(stage, 0.0, events)
 
 
-@contextlib.contextmanager
 def stage_timer(stage: str):
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        record_stage(stage, time.perf_counter() - t0)
+    """`with stage_timer(stage):` accumulates the block's wall time against
+    the stage and, with FLAGS_obs_enable on, is a span like `obs.span`: a
+    TraceAnnotation on the profiler's clock, nested under the span that
+    encloses it."""
+    return _obs.registry().stage_timer(stage)
 
 
 def stage_counters(reset: bool = False) -> dict:

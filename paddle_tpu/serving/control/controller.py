@@ -80,7 +80,8 @@ class Controller:
         if _policy.mode() == "off":
             self._next_t[eid] = now + self.epoch_s
             return False
-        self._epoch(engine, eid, now)
+        with obs.span("serving.control.epoch"):
+            self._epoch(engine, eid, now)
         self._next_t[eid] = now + self.epoch_s
         return True
 

@@ -115,6 +115,9 @@ _TERMINAL = frozenset({FINISHED, ABORTED, DEADLINE_EXCEEDED, SHED})
 # graceful-degradation ladder rungs, mildest first (see _update_ladder)
 _LADDER_RUNGS = {1: "spec_off", 2: "lookahead_shrink",
                  3: "cache_evict", 4: "shed"}
+# an iteration this long emits serving.slow_step with what it was made of:
+# on the chip a normal one takes 0.06-0.5 s and a stall thousands of ms
+SLOW_STEP_S = 1.0
 
 
 class AdmissionRejected(RuntimeError):
@@ -369,6 +372,12 @@ class ServingEngine:
         self._pressure_steps = 0
         self._calm_steps = 0
         self._step_i = 0
+        # self seconds of the current step's spans by name (the collect dict
+        # of serving.step), what the step did, and the slowest step since
+        # reset_stats() with both
+        self._phase_s: dict[str, float] = {}
+        self._step_admitted = self._step_rows = 0
+        self._slowest_step: dict | None = None
         self.prefill_only = bool(prefill_only)
         self._shared_pool = shared_pool is not None
         if shared_pool is not None:
@@ -626,8 +635,9 @@ class ServingEngine:
         touching the executor compile cache, the pool, or the prefix
         cache — the steady-state measurement boundary: warm the engine on
         one pass of a workload, reset, measure the second pass. The
-        registry's `serving.` series reset with it so both views stay
-        scoped to the same measurement window."""
+        registry's `serving.` series reset with it, and the `pipeline.`
+        stages and `host.` series a step's spans and the collector's pauses
+        feed, so every view stays scoped to the same measurement window."""
         for k, v in self.stats.items():
             if isinstance(v, set):
                 v.clear()
@@ -635,7 +645,9 @@ class ServingEngine:
                 self.stats[k] = 0.0
             else:
                 self.stats[k] = 0
-        obs.reset("serving.")
+        self._slowest_step = None
+        for prefix in ("serving.", "pipeline.", "host."):
+            obs.reset(prefix)
 
     def _count(self, key: str, n: int = 1) -> None:
         """Bump a stats counter AND its registry mirror (`serving.<key>`):
@@ -666,6 +678,11 @@ class ServingEngine:
             st["occupancy_sum"] / st["occupancy_n"]
             if st["occupancy_n"] else 0.0)
         out["leaked_pages"] = self.leaked_pages()
+        # the slowest iteration since reset_stats(): step, dur_s, self
+        # seconds by span name (they sum to dur_s), the collector's seconds
+        # inside it, rows decoded, requests admitted; None before any step
+        # or with FLAGS_obs_enable off
+        out["slowest_step"] = self._slowest_step
         return out
 
     def _exec_target(self, prog: Program):
@@ -921,78 +938,127 @@ class ServingEngine:
         made progress (admitted or decoded a token). Supervised: a compiled
         dispatch that exhausts its retry budget becomes a recovery pass
         (quarantine + pool rebuild + prompt replay) instead of a poisoned
-        batch."""
+        batch.
+
+        The iteration is one span tree under `serving.step`: housekeeping,
+        admission (one `serving.prefill` per admitted request), one
+        `serving.decode`, and under those the feed building, the executor's
+        `pipeline.*` stages and the accept loop. Each span's self seconds
+        land in `_phase_s`, so a slow iteration says what it was made of
+        (`_note_step`)."""
         self._step_i += 1
-        self.maybe_adopt_config()
-        try:
-            progressed = self._step_inner()
-        except _StepFailure as e:
-            self._recover(f"step_fail:{e.kind}")
-            progressed = True
-        # controller epoch hook: one perf_counter read + compare per step
-        # until an epoch is due (the shadow-mode 0% overhead budget)
-        self._ctrl.tick(self)
+        self._step_admitted = self._step_rows = 0
+        self._phase_s.clear()
+        gc0 = obs.gc_pause_seconds()
+        with obs.span("serving.step", collect=self._phase_s,
+                      step=self._step_i) as sp:
+            try:
+                progressed = self._step_inner()
+            except _StepFailure as e:
+                self._recover(f"step_fail:{e.kind}")
+                progressed = True
+            # controller epoch hook: one perf_counter read + compare per
+            # step until an epoch is due (the shadow-mode 0% overhead
+            # budget); a due epoch is its own span, serving.control.epoch
+            with obs.span("serving.housekeeping"):
+                self._ctrl.tick(self)
+        self._note_step(sp.dur_s, obs.gc_pause_seconds() - gc0)
         return progressed
 
+    def _note_step(self, dur_s: float, gc_s: float) -> None:
+        """Keep the slowest iteration since reset_stats() with what it was
+        made of, and emit serving.slow_step for one over SLOW_STEP_S."""
+        slowest = self._slowest_step
+        if not self._phase_s:
+            return  # FLAGS_obs_enable off: no span, nothing to say
+        if dur_s < SLOW_STEP_S and slowest is not None \
+                and dur_s <= slowest["dur_s"]:
+            return
+        rec = {"step": self._step_i, "dur_s": dur_s,
+               "phases": dict(self._phase_s), "gc_s": gc_s,
+               "rows": self._step_rows, "admitted": self._step_admitted}
+        if slowest is None or dur_s > slowest["dur_s"]:
+            self._slowest_step = rec
+        if dur_s >= SLOW_STEP_S:
+            obs.event("serving.slow_step", rec, level="warning")
+
     def _step_inner(self) -> bool:
-        try:
-            fault_point("serving_deadline")
-        except InjectedFault:
-            # chaos: the oldest live request's deadline collapses to the past
-            victim = self._running[0] if self._running else (
-                self._waiting[0] if self._waiting else None)
-            if victim is not None:
-                victim.deadline_t = time.perf_counter() - 1e-9
-        try:
-            fault_point("serving_pool_corrupt")
-        except InjectedFault as e:
-            self._corrupt_pool(e.hit)
-        try:
-            fault_point("serving_abort")
-        except InjectedFault:
-            # chaos: the oldest running request's client vanished mid-decode
-            victim = self._running[0] if self._running else (
-                self._waiting[0] if self._waiting else None)
-            if victim is not None:
-                self.abort(victim.rid)
-        self._expire_deadlines(time.perf_counter())
-        if self.audit_every > 0 and self._step_i % self.audit_every == 0:
-            problems, poisoned = self.audit_pool()
-            if problems:
-                self._recover("pool_corrupt", poisoned=poisoned,
-                              problems=problems)
-                return True
-        self._update_ladder()
-        admitted = self._admit()
+        with obs.span("serving.housekeeping"):
+            self.maybe_adopt_config()
+            try:
+                fault_point("serving_deadline")
+            except InjectedFault:
+                # chaos: the oldest live request's deadline collapses to the
+                # past
+                victim = self._running[0] if self._running else (
+                    self._waiting[0] if self._waiting else None)
+                if victim is not None:
+                    victim.deadline_t = time.perf_counter() - 1e-9
+            try:
+                fault_point("serving_pool_corrupt")
+            except InjectedFault as e:
+                self._corrupt_pool(e.hit)
+            try:
+                fault_point("serving_abort")
+            except InjectedFault:
+                # chaos: the oldest running request's client vanished
+                # mid-decode
+                victim = self._running[0] if self._running else (
+                    self._waiting[0] if self._waiting else None)
+                if victim is not None:
+                    self.abort(victim.rid)
+            self._expire_deadlines(time.perf_counter())
+            if self.audit_every > 0 and self._step_i % self.audit_every == 0:
+                problems, poisoned = self.audit_pool()
+                if problems:
+                    self._recover("pool_corrupt", poisoned=poisoned,
+                                  problems=problems)
+                    return True
+            self._update_ladder()
+        with obs.span("serving.admit") as sp:
+            admitted = self._step_admitted = self._admit()
+        obs.histogram_observe("serving.admit.self_seconds", sp.self_s)
         if self._running and not self.prefill_only:
-            with obs.span("serving.decode"):
-                decoded = self._decode_once()
+            fetch0 = self._phase_s.get("pipeline.fetch", 0.0)
+            with obs.span("serving.decode", rows=len(self._running)) as sp:
+                decoded = self._decode_once(sp)
+            self._observe_host_seconds("serving.decode", sp, fetch0)
         else:
             # prefill-only engines stop at the prompt boundary: freshly
             # prefilled rows sit RUNNING until extract_for_handoff moves
             # them to a decode engine
             decoded = False
-        # a request that crossed its TTL inside the prefill/decode above is
-        # caught here — "mid-step" expiry still releases pages this step
-        self._expire_deadlines(time.perf_counter())
-        if not decoded and not admitted and self._waiting:
-            need = min(self.pool.pages_for(len(r.all_tokens) + 1)
-                       for r in self._waiting)
-            if need > self.pool.num_pages:
-                raise RuntimeError(
-                    f"request needs {need} pages but the pool only has "
-                    f"{self.pool.num_pages} (FLAGS_serving_pool_pages / "
-                    f"FLAGS_serving_page_size)")
-            if not self._running and not self._shared_pool:
-                # over a SHARED pool this engine being starved is not
-                # fatal: peers (or the lease reaper) free pages it never
-                # could — keep waiting instead of declaring deadlock
-                raise RuntimeError(
-                    "admission stuck: no running requests to free pages, "
-                    f"yet {len(self._waiting)} waiting (free "
-                    f"{self.pool.free_count}/{self.pool.num_pages} pages)")
-        self._note_occupancy()
+        with obs.span("serving.housekeeping"):
+            # a request that crossed its TTL inside the prefill/decode above
+            # is caught here — "mid-step" expiry still releases pages this
+            # step
+            self._expire_deadlines(time.perf_counter())
+            if not decoded and not admitted and self._waiting:
+                need = min(self.pool.pages_for(len(r.all_tokens) + 1)
+                           for r in self._waiting)
+                if need > self.pool.num_pages:
+                    raise RuntimeError(
+                        f"request needs {need} pages but the pool only has "
+                        f"{self.pool.num_pages} (FLAGS_serving_pool_pages / "
+                        f"FLAGS_serving_page_size)")
+                if not self._running and not self._shared_pool:
+                    # over a SHARED pool this engine being starved is not
+                    # fatal: peers (or the lease reaper) free pages it never
+                    # could — keep waiting instead of declaring deadlock
+                    raise RuntimeError(
+                        "admission stuck: no running requests to free "
+                        f"pages, yet {len(self._waiting)} waiting (free "
+                        f"{self.pool.free_count}/{self.pool.num_pages} "
+                        f"pages)")
+            self._note_occupancy()
         return bool(admitted or decoded)
+
+    def _observe_host_seconds(self, name: str, sp, fetch0: float) -> None:
+        """`<name>.host_seconds`: the span's duration less the seconds it
+        spent inside `pipeline.fetch` (blocked on the device and copying the
+        fetches) since `fetch0` was read: the host's part of the step."""
+        waited = self._phase_s.get("pipeline.fetch", 0.0) - fetch0
+        obs.histogram_observe(name + ".host_seconds", sp.dur_s - waited)
 
     # -- internals ----------------------------------------------------------
     def _release(self, req: GenRequest) -> None:
@@ -1327,10 +1393,14 @@ class ServingEngine:
             obs.event("serving.request", {"rid": req.rid, "phase": "admitted",
                                           "cached_len": req.cached_len,
                                           "pages": len(req.pages)})
-            # no rid label: span labels flow to the histogram series key,
-            # and a per-request label would mint unbounded series
-            with obs.span("serving.prefill"):
+            # attributes, not labels: span labels flow to the histogram
+            # series key, and a per-request label would mint unbounded series
+            fetch0 = self._phase_s.get("pipeline.fetch", 0.0)
+            with obs.span("serving.prefill", rid=req.rid,
+                          tokens=len(req.all_tokens),
+                          cached_len=req.cached_len) as sp:
                 self._prefill(req)
+            self._observe_host_seconds("serving.prefill", sp, fetch0)
             admitted += 1
         return admitted
 
@@ -1359,24 +1429,26 @@ class ServingEngine:
         self._running.append(req)
         if req.cached_len >= n:
             self._count("prefix_full_hits")
-            self._register_prefix(req)
+            with obs.span("serving.accept"):
+                self._register_prefix(req)
             return
         if req.cached_len > 0:
-            suf = n - req.cached_len
-            sb = self._seq_bucket(suf)
-            pb = _round_up_pow2(max(len(req.pages),
-                                    self.pool.pages_for(req.cached_len + sb)))
-            tok = np.zeros((1, sb), np.int32)
-            tok[0, :suf] = req.all_tokens[req.cached_len:]
-            pos = req.cached_len + np.arange(sb, dtype=np.int32)[None, :]
-            pos = np.minimum(pos, self.cfg.max_position - 1)
-            pages = np.zeros((1, pb), np.int32)
-            pages[0, :len(req.pages)] = req.pages
-            feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
-                    sv_model.PAGES_FEED: pages,
-                    sv_model.START_FEED: np.asarray([req.cached_len],
-                                                    np.int32),
-                    sv_model.LEN_FEED: np.asarray([suf], np.int32)}
+            with obs.span("serving.feed_build"):
+                suf = n - req.cached_len
+                sb = self._seq_bucket(suf)
+                pb = _round_up_pow2(max(
+                    len(req.pages), self.pool.pages_for(req.cached_len + sb)))
+                tok = np.zeros((1, sb), np.int32)
+                tok[0, :suf] = req.all_tokens[req.cached_len:]
+                pos = req.cached_len + np.arange(sb, dtype=np.int32)[None, :]
+                pos = np.minimum(pos, self.cfg.max_position - 1)
+                pages = np.zeros((1, pb), np.int32)
+                pages[0, :len(req.pages)] = req.pages
+                feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
+                        sv_model.PAGES_FEED: pages,
+                        sv_model.START_FEED: np.asarray([req.cached_len],
+                                                        np.int32),
+                        sv_model.LEN_FEED: np.asarray([suf], np.int32)}
             nxt, lg = self._dispatch(
                 "suffix_prefill", self._window_run, feed,
                 [self._window_io["next_token"],
@@ -1384,17 +1456,18 @@ class ServingEngine:
             self.stats["prefill_signatures"].add(("suffix", sb, pb))
             self._count("prefill_tokens_computed", suf)
         else:
-            sb = self._seq_bucket(n)
-            pb = max(len(req.pages), self.pool.pages_for(sb))
-            tok = np.zeros((1, sb), np.int32)
-            tok[0, :n] = req.all_tokens
-            pos = np.arange(sb, dtype=np.int32)[None, :]
-            pos = np.minimum(pos, self.cfg.max_position - 1)
-            pages = np.zeros((1, pb), np.int32)
-            pages[0, :len(req.pages)] = req.pages
-            feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
-                    sv_model.PAGES_FEED: pages,
-                    sv_model.LEN_FEED: np.asarray([n], np.int32)}
+            with obs.span("serving.feed_build"):
+                sb = self._seq_bucket(n)
+                pb = max(len(req.pages), self.pool.pages_for(sb))
+                tok = np.zeros((1, sb), np.int32)
+                tok[0, :n] = req.all_tokens
+                pos = np.arange(sb, dtype=np.int32)[None, :]
+                pos = np.minimum(pos, self.cfg.max_position - 1)
+                pages = np.zeros((1, pb), np.int32)
+                pages[0, :len(req.pages)] = req.pages
+                feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
+                        sv_model.PAGES_FEED: pages,
+                        sv_model.LEN_FEED: np.asarray([n], np.int32)}
             nxt, lg = self._dispatch(
                 "prefill", self._prefill_run, feed,
                 [self._prefill_io["next_token"],
@@ -1402,8 +1475,9 @@ class ServingEngine:
             self.stats["prefill_signatures"].add((sb, pb))
             self._count("prefill_tokens_computed", n)
         self._count("prefills")
-        self._register_prefix(req)
-        self._accept_token(req, self._first_token(req, nxt, lg))
+        with obs.span("serving.accept"):
+            self._register_prefix(req)
+            self._accept_token(req, self._first_token(req, nxt, lg))
 
     def _register_prefix(self, req: GenRequest) -> None:
         """Index the request's full PROMPT pages so later arrivals sharing
@@ -1468,6 +1542,10 @@ class ServingEngine:
         the lookahead shrinks before anyone is preempted (speculative slots
         are optional; the required slot is cache_len's). Returns per-rid
         granted lookahead."""
+        with obs.span("serving.ensure_writable"):
+            return self._grow_and_cow(lookahead)
+
+    def _grow_and_cow(self, lookahead: int) -> dict[int, int]:
         ps = self.page_size
         granted: dict[int, int] = {}
         for req in list(self._running):
@@ -1515,48 +1593,54 @@ class ServingEngine:
         # outranks new arrivals under fcfs
         self._waiting.insert(0, req)
 
-    def _decode_once(self) -> bool:
+    def _decode_once(self, sp) -> bool:
+        """One decode step under the open `serving.decode` span `sp`."""
         # ladder rung 1+ falls back to plain one-token decode: the verify
         # window is the most speculative compute in the engine, so it is
         # the first thing sustained overload switches off
         if self.draft_k > 0 and self._ladder_rung < 1:
-            return self._decode_spec()
+            return self._decode_spec(sp)
         self._ensure_writable(0)
         rows = [r for r in self._running if r.state == RUNNING]
         if not rows:
             return False
-        bb = min(_round_up_pow2(len(rows)), _round_up_pow2(self.max_inflight))
-        pb = _round_up_pow2(max(len(r.pages) for r in rows))
-        tok = np.zeros((bb, 1), np.int32)
-        pos = np.zeros((bb,), np.int32)
-        pages = np.zeros((bb, pb), np.int32)
-        mask = np.zeros((bb, 1), np.float32)
-        for i, r in enumerate(rows):
-            tok[i, 0] = r.all_tokens[-1]
-            pos[i] = r.cache_len
-            pages[i, :len(r.pages)] = r.pages
-            mask[i, 0] = 1.0
-        feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
-                sv_model.PAGES_FEED: pages, sv_model.MASK_FEED: mask}
+        with obs.span("serving.feed_build"):
+            bb = min(_round_up_pow2(len(rows)),
+                     _round_up_pow2(self.max_inflight))
+            pb = _round_up_pow2(max(len(r.pages) for r in rows))
+            tok = np.zeros((bb, 1), np.int32)
+            pos = np.zeros((bb,), np.int32)
+            pages = np.zeros((bb, pb), np.int32)
+            mask = np.zeros((bb, 1), np.float32)
+            for i, r in enumerate(rows):
+                tok[i, 0] = r.all_tokens[-1]
+                pos[i] = r.cache_len
+                pages[i, :len(r.pages)] = r.pages
+                mask[i, 0] = 1.0
+            feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
+                    sv_model.PAGES_FEED: pages, sv_model.MASK_FEED: mask}
+        self._step_rows = len(rows)
+        sp.note(rows=len(rows), bb=bb, pb=pb)
         nxt, lg = self._dispatch(
             "decode", self._decode_run, feed,
             [self._decode_io["next_token"], self._decode_io["logits"]])
-        nxt = np.asarray(nxt).reshape(-1)
-        self._count("decode_steps")
-        self.stats["decode_signatures"].add((bb, pb))
-        lg = None if all(r.sampling.is_greedy for r in rows) \
-            else np.asarray(lg)
-        for i, r in enumerate(rows):
-            if r.sampling.is_greedy:
-                t = int(nxt[i])
-            else:
-                rng = request_rng(self.seed, r.rid, r.n_generated)
-                t = sample_token(lg[i], r.sampling, rng)
-            self._count("decode_tokens")
-            self._accept_token(r, t)
+        with obs.span("serving.accept"):
+            nxt = np.asarray(nxt).reshape(-1)
+            self._count("decode_steps")
+            self.stats["decode_signatures"].add((bb, pb))
+            lg = None if all(r.sampling.is_greedy for r in rows) \
+                else np.asarray(lg)
+            for i, r in enumerate(rows):
+                if r.sampling.is_greedy:
+                    t = int(nxt[i])
+                else:
+                    rng = request_rng(self.seed, r.rid, r.n_generated)
+                    t = sample_token(lg[i], r.sampling, rng)
+                self._count("decode_tokens")
+                self._accept_token(r, t)
         return True
 
-    def _decode_spec(self) -> bool:
+    def _decode_spec(self, sp) -> bool:
         """One draft-verify window step: propose k tokens per row
         (ngram_draft over the row's own history), run all k+1 positions
         through the windowed program in ONE compiled step, and accept the
@@ -1569,58 +1653,64 @@ class ServingEngine:
                 and r.rid in granted]
         if not rows:
             return False
-        plans = []
-        for r in rows:
-            n_valid = min(S,
-                          self.cfg.max_position - len(r.all_tokens),
-                          r.max_new_tokens - r.n_generated,
-                          granted.get(r.rid, 0) + 1)
-            plans.append((r, max(1, n_valid),
-                          ngram_draft(r.all_tokens, k)))
-        bb = min(_round_up_pow2(len(rows)), _round_up_pow2(self.max_inflight))
-        pb = _round_up_pow2(max(len(r.pages) for r in rows))
-        tok = np.zeros((bb, S), np.int32)
-        pos = np.zeros((bb, S), np.int32)
-        pages = np.zeros((bb, pb), np.int32)
-        start = np.zeros((bb,), np.int32)
-        lens = np.zeros((bb,), np.int32)
-        for i, (r, n_valid, drafts) in enumerate(plans):
-            tok[i, 0] = r.all_tokens[-1]
-            tok[i, 1:] = drafts
-            pos[i] = np.minimum(r.cache_len + np.arange(S),
-                                self.cfg.max_position - 1)
-            pages[i, :len(r.pages)] = r.pages
-            start[i] = r.cache_len
-            lens[i] = n_valid
-        feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
-                sv_model.PAGES_FEED: pages, sv_model.START_FEED: start,
-                sv_model.LEN_FEED: lens}
+        with obs.span("serving.feed_build"):
+            plans = []
+            for r in rows:
+                n_valid = min(S,
+                              self.cfg.max_position - len(r.all_tokens),
+                              r.max_new_tokens - r.n_generated,
+                              granted.get(r.rid, 0) + 1)
+                plans.append((r, max(1, n_valid),
+                              ngram_draft(r.all_tokens, k)))
+            bb = min(_round_up_pow2(len(rows)),
+                     _round_up_pow2(self.max_inflight))
+            pb = _round_up_pow2(max(len(r.pages) for r in rows))
+            tok = np.zeros((bb, S), np.int32)
+            pos = np.zeros((bb, S), np.int32)
+            pages = np.zeros((bb, pb), np.int32)
+            start = np.zeros((bb,), np.int32)
+            lens = np.zeros((bb,), np.int32)
+            for i, (r, n_valid, drafts) in enumerate(plans):
+                tok[i, 0] = r.all_tokens[-1]
+                tok[i, 1:] = drafts
+                pos[i] = np.minimum(r.cache_len + np.arange(S),
+                                    self.cfg.max_position - 1)
+                pages[i, :len(r.pages)] = r.pages
+                start[i] = r.cache_len
+                lens[i] = n_valid
+            feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
+                    sv_model.PAGES_FEED: pages, sv_model.START_FEED: start,
+                    sv_model.LEN_FEED: lens}
+        self._step_rows = len(rows)
+        sp.note(rows=len(rows), bb=bb, pb=pb)
         toks, lg = self._dispatch(
             "verify_window", self._window_run, feed,
             [self._window_io["tokens"], self._window_io["logits"]])
-        toks = np.asarray(toks)
-        self._count("decode_steps")
-        self._count("spec_steps")
-        self.stats["decode_signatures"].add((bb, pb))
-        lg = None if all(r.sampling.is_greedy for r, _, _ in plans) \
-            else np.asarray(lg)
-        for i, (r, n_valid, drafts) in enumerate(plans):
-            if not r.sampling.is_greedy:
-                # sampling rows take exactly one (seeded) token per step;
-                # draft acceptance is a greedy-only contract
-                rng = request_rng(self.seed, r.rid, r.n_generated)
-                t = sample_token(lg[i, 0], r.sampling, rng)
-                self._count("decode_tokens")
-                self._accept_token(r, t)
-                continue
-            m = 0
-            while m < n_valid - 1 and int(drafts[m]) == int(toks[i, m]):
-                m += 1
-            self._count("spec_proposed", n_valid - 1)
-            self._count("spec_accepted", m)
-            for j in range(m + 1):
-                if r.state != RUNNING:
-                    break
-                self._count("decode_tokens")
-                self._accept_token(r, int(toks[i, j]))
+        with obs.span("serving.accept"):
+            toks = np.asarray(toks)
+            self._count("decode_steps")
+            self._count("spec_steps")
+            self.stats["decode_signatures"].add((bb, pb))
+            lg = None if all(r.sampling.is_greedy for r, _, _ in plans) \
+                else np.asarray(lg)
+            for i, (r, n_valid, drafts) in enumerate(plans):
+                if not r.sampling.is_greedy:
+                    # sampling rows take exactly one (seeded) token per
+                    # step; draft acceptance is a greedy-only contract
+                    rng = request_rng(self.seed, r.rid, r.n_generated)
+                    t = sample_token(lg[i, 0], r.sampling, rng)
+                    self._count("decode_tokens")
+                    self._accept_token(r, t)
+                    continue
+                m = 0
+                while m < n_valid - 1 \
+                        and int(drafts[m]) == int(toks[i, m]):
+                    m += 1
+                self._count("spec_proposed", n_valid - 1)
+                self._count("spec_accepted", m)
+                for j in range(m + 1):
+                    if r.state != RUNNING:
+                        break
+                    self._count("decode_tokens")
+                    self._accept_token(r, int(toks[i, j]))
         return True
