@@ -15,6 +15,9 @@ the full width of BERT-base (depth kept too — it fits):
     every generated token is, by the dense oracle's own logits
     (`build_full_forward_program`), within ORACLE_LOGIT_TOL of the oracle's
     best token;
+  * pool layout — the decode, prefill, window and copy-on-write programs
+    compiled at the serving cells' geometry (3072 x 16 pool, 64 rows): none
+    holds an instruction that copies a whole KV pool (`tools/pool_hlo.py`);
   * with four or more chips, the trainer again as a dp x 4 GSPMD program at
     the same widths: feed and parameters on four distinct devices, device
     memory of the same order on all four.
@@ -47,6 +50,8 @@ from paddle_tpu.ops import attention_ops
 from paddle_tpu.parallel.mesh import make_mesh
 from paddle_tpu.serving import DecoderConfig, ServingEngine
 from paddle_tpu.serving import model as sv_model
+from paddle_tpu.serving.kv_cache import pool_shape
+from tools.pool_hlo import pool_sized_copies, serving_program_hlos
 
 # How far below the dense oracle's best logit the logit of a token the
 # engine generated may sit. The engine and the oracle run different
@@ -230,10 +235,10 @@ def server_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
     bb = 1 << (len(prompt_lens) - 1).bit_length()
     pages = -(-(max(prompt_lens) + max_new) // page_size)
     pb = 1 << (pages - 1).bit_length()
-    pool_shape = (pool_pages, page_size, cfg.num_heads, cfg.head_dim)
     expected, _tier = attention_ops.paged_attention_backend(
         bb, cfg.num_heads, pb * page_size, cfg.head_dim, cfg.dtype,
-        pool_shape=pool_shape)
+        pool_shape=pool_shape(pool_pages, page_size, cfg.num_heads,
+                              cfg.head_dim))
     return {
         "config": f"L{cfg.num_layers} h{cfg.hidden_size} nh{cfg.num_heads} "
                   f"v{cfg.vocab_size} {cfg.dtype} pool{pool_pages}x"
@@ -250,6 +255,32 @@ def server_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
         "first_step_s": round(t_first - t0, 2),
         "later_steps_s": round(t_end - t_first, 2),
     }
+
+
+def pool_layout_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
+                      rows: int, device=None) -> dict:
+    """The KV pool has one device layout: compile the decode (`rows` rows of
+    a full context), prefill, window and copy-on-write programs, for this
+    chip or for `device` (a described one), and require that none writes a
+    fresh pool-sized array — a relayout copy of a whole pool."""
+    eng = ServingEngine(cfg, page_size=page_size, pool_pages=pool_pages,
+                        max_inflight=rows, seed=21)
+    elements = int(np.prod(pool_shape(pool_pages, page_size, cfg.num_heads,
+                                      cfg.head_dim)))
+    texts = serving_program_hlos(
+        eng, rows=rows, pages=eng.pool.pages_for(cfg.max_position),
+        prompt=128, device=device)
+    copies = {}
+    for name, text in texts.items():
+        found = pool_sized_copies(text, elements)
+        _require(not found,
+                 f"the compiled {name} program moves a whole KV pool "
+                 f"{len(found)} times, first: {found[0] if found else None}")
+        copies[name] = len(found)
+    return {"config": f"L{cfg.num_layers} nh{cfg.num_heads} "
+                      f"dh{cfg.head_dim} pool{pool_pages}x{page_size} "
+                      f"rows{rows}",
+            "pool_sized_copies": copies}
 
 
 def main() -> int:
@@ -276,6 +307,12 @@ def main() -> int:
         DecoderConfig(), page_size=16, pool_pages=2048,
         prompt_lens=(40, 5, 100, 200), max_new=8)
     print("server", json.dumps(phases["server"]), flush=True)
+    gc.collect()
+
+    # the serving cells' geometry (benchmark/configs/bert_base_decoder.json)
+    phases["pool_layout"] = pool_layout_phase(
+        DecoderConfig(), page_size=16, pool_pages=3072, rows=64)
+    print("pool_layout", json.dumps(phases["pool_layout"]), flush=True)
     gc.collect()
 
     if len(jax.devices()) >= 4:

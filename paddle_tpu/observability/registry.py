@@ -19,9 +19,9 @@ used to take five bespoke readers. Design points:
   * spans nest: a per-thread stack gives every span its parent, its self
     time and the `step` of its root, and both ways to time host work
     (`span`, `profiler.stage_timer`) are the one `Span` class, a
-    `jax.profiler.TraceAnnotation` each, so they sit on the device trace's
-    clock; closing one takes the lock once, and builds a record only when a
-    sink is attached;
+    `jax.profiler.TraceAnnotation` each (within a per-thread budget, by
+    whole trees), so they sit on the device trace's clock; closing one
+    takes the lock once, and builds a record only when a sink is attached;
   * `snapshot(reset=True)` is atomic — read-and-zero under the lock, so
     concurrent writers can never be double-counted or lost across the
     reset boundary (the 8-thread test pins this);
@@ -127,13 +127,29 @@ def base_name(series_key: str) -> str:
     return series_key.split("{", 1)[0]
 
 
+# What one thread may put on the profiler's clock: a trace reduction that
+# holds every device idle gap against every host span (benchmark/
+# trace_reduce._attribute) costs gaps x spans, and both grow with the loop's
+# rate. At 140 serving steps a second a 3 s slice held 257k gaps and 5,200
+# spans of the program's, and its reduction took 608 s (PERF.md section 6,
+# PR 24). A loop under the budget is annotated whole; a faster one is sampled
+# by whole span trees, and the registry and the stream still see every span.
+ANNOTATED_SPANS_PER_S = 100.0
+ANNOTATED_SPANS_BURST = 200.0
+
+
 class _ThreadState(threading.local):
-    """The open spans of one thread, innermost last, and the dict the
-    innermost collecting span books self seconds into."""
+    """The open spans of one thread, innermost last, the dict the innermost
+    collecting span books self seconds into, and the thread's budget of
+    profiler annotations: `annotate` is the decision its outermost open span
+    took for its whole tree."""
 
     def __init__(self):
         self.stack: list = []
         self.collect: dict | None = None
+        self.annotate = True
+        self.budget = ANNOTATED_SPANS_BURST
+        self.budget_at = time.perf_counter()
 
 
 _tls = _ThreadState()
@@ -151,11 +167,13 @@ def _annotation_cls():
 
 class Span:
     """One timed host interval: a `jax.profiler.TraceAnnotation` (so it sits
-    on the device trace's clock), a sample in the registry (`<name>.seconds`
-    for a span, the `[events, seconds]` stage for a stage timer) and, when a
-    sink is attached, one JSONL record carrying `parent` (the enclosing
-    span's name) and `step` (the `step` attribute of the outermost span that
-    has one), so the spans of one iteration share an identifier.
+    on the device trace's clock; an outermost span decides it for its whole
+    tree, within the thread's budget of `ANNOTATED_SPANS_PER_S`), a sample
+    in the registry (`<name>.seconds` for a span, the `[events, seconds]`
+    stage for a stage timer) and, when a sink is attached, one JSONL record
+    carrying `parent` (the enclosing span's name) and `step` (the `step`
+    attribute of the outermost span that has one), so the spans of one
+    iteration share an identifier.
 
     After exit `dur_s` is its duration and `self_s` that minus what its
     direct children covered. A span opened with `collect=<dict>` has the self
@@ -190,19 +208,29 @@ class Span:
         else:
             self.parent = None
             self.step = attrs.get("step")
+            now = time.perf_counter()
+            st.budget = min(ANNOTATED_SPANS_BURST, st.budget
+                            + (now - st.budget_at) * ANNOTATED_SPANS_PER_S)
+            st.budget_at = now
+            st.annotate = st.budget >= 1.0  # a tree may overdraw: it is whole
         if self._collect is not None:
             self._outer_collect = st.collect
             st.collect = self._collect
         stack.append(self)
         self._child_s = 0.0
-        self._ann = ann = _annotation_cls()(self.name, **attrs)
-        ann.__enter__()
+        if st.annotate:
+            st.budget -= 1.0
+            self._ann = ann = _annotation_cls()(self.name, **attrs)
+            ann.__enter__()
+        else:
+            self._ann = None
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self._t0
-        self._ann.__exit__(*exc)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         st = _tls
         stack = st.stack
         name = self.name

@@ -253,16 +253,17 @@ def _pallas_paged_ok(q_shape, pool_shape) -> bool:
 def _shard_paged_shapes(q_shape, pool_shape, tp=1):
     """The PER-SHARD view of a paged decode shape under tp-way head
     sharding: GSPMD hands each shard nh/tp heads of BOTH the query and the
-    pool, so the tuning key and every executability check must see the same
-    nh/tp shapes — a verdict decided at one head count and dispatched at
-    another is wrong in both directions."""
+    pool (whose last dim `nh*dh` holds heads contiguously), so the tuning
+    key and every executability check must see the same nh/tp shapes — a
+    verdict decided at one head count and dispatched at another is wrong in
+    both directions."""
     tp = max(1, int(tp))
     B, nh, dh = q_shape
     q = (B, max(1, int(nh) // tp), dh)
     if pool_shape is None:
         return q, None
-    num_pages, ps, p_nh, p_dh = pool_shape
-    return q, (num_pages, ps, max(1, int(p_nh) // tp), p_dh)
+    num_pages, ps, width = pool_shape
+    return q, (num_pages, ps, max(dh, int(width) // tp))
 
 
 def paged_attention_backend(batch, num_heads, kv_slots, head_dim, dtype,
@@ -309,6 +310,16 @@ def paged_attention_backend(batch, num_heads, kv_slots, head_dim, dtype,
     return decision.get("backend", "xla"), tier
 
 
+def _gather_pages(pool, page_table, nh):
+    """Rows `page_table` [B, P] names, as heads: `[B, P*ps, nh, dh]`. Whole
+    `[ps, nh*dh]` page rows are gathered and the (small) result is what
+    gets reshaped, never the pool: the pool keeps its one layout."""
+    num_pages, ps, width = pool.shape
+    B, P = page_table.shape
+    pt = jnp.clip(page_table, 0, num_pages - 1)
+    return pool[pt].reshape(B, P * ps, nh, width // nh)
+
+
 def _paged_attention_reference(q, k_pool, v_pool, page_table, kv_lens,
                                sm_scale=1.0):
     """XLA gather-based paged decode attention — the numeric oracle and the
@@ -316,13 +327,11 @@ def _paged_attention_reference(q, k_pool, v_pool, page_table, kv_lens,
     [B, P*ps, nh, dh] view (XLA fuses the gather into the matmuls, but the
     materialized bytes still move); fp32 softmax statistics, slots past a
     row's kv_len masked with the framework-wide -1e9 convention so a padded
-    row (kv_len 0) stays finite."""
+    row (kv_len 0) stays finite. Pools are `[num_pages, ps, nh*dh]`."""
     B, nh, dh = q.shape
-    num_pages, ps = k_pool.shape[0], k_pool.shape[1]
-    P = page_table.shape[1]
-    pt = jnp.clip(page_table, 0, num_pages - 1)
-    k = k_pool[pt].reshape(B, P * ps, nh, dh)
-    v = v_pool[pt].reshape(B, P * ps, nh, dh)
+    P, ps = page_table.shape[1], k_pool.shape[1]
+    k = _gather_pages(k_pool, page_table, nh)
+    v = _gather_pages(v_pool, page_table, nh)
     s = jnp.einsum("bhd,bkhd->bhk", q, k) * sm_scale
     s = s.astype(jnp.float32)
     pos = jnp.arange(P * ps, dtype=jnp.int32)
@@ -361,15 +370,26 @@ def paged_decode_attention_fn(q, k_pool, v_pool, page_table, kv_lens,
 _DROP_PAGE = 1 << 30
 
 
+def _write_rows(pool, rows, page_idx, slot):
+    """pool[page_idx[i], slot[i], :] = rows[i] for every i whose page is in
+    the pool; `_DROP_PAGE` rows write nothing. A row is a token's whole
+    `[nh*dh]` line of the lane-dense pool, so on a donated buffer XLA keeps
+    this scatter in place in the pool's own layout (`tools/pool_hlo.py`
+    checks the compiled programs for a pool-sized copy)."""
+    return pool.at[page_idx, slot].set(rows.astype(pool.dtype), mode="drop")
+
+
 def kv_cache_append_fn(k_pool, v_pool, k, v, page_table, positions,
                        live=None):
     """Write one decode step's K/V into the paged pool.
 
-    k/v: [B, nh, dh] (this token's projections); positions: [B] int32 — the
-    logical slot each row writes (its current context length); live: [B]
-    0/1 mask (rows the scheduler padded in write nowhere). Returns the
-    updated pools; the executor's donation makes the update in-place in HBM.
+    k/v: [B, nh, dh] (this token's projections); pools
+    [num_pages, ps, nh*dh]; positions: [B] int32 — the logical slot each
+    row writes (its current context length); live: [B] 0/1 mask (rows the
+    scheduler padded in write nowhere). Returns the updated pools; the
+    executor's donation makes the update in-place in HBM.
     """
+    B = k.shape[0]
     ps = k_pool.shape[1]
     P = page_table.shape[1]
     page_of = jnp.clip(positions // ps, 0, P - 1)
@@ -378,10 +398,8 @@ def kv_cache_append_fn(k_pool, v_pool, k, v, page_table, positions,
     if live is not None:
         page_idx = jnp.where(jnp.reshape(live, (-1,)) > 0, page_idx,
                              _DROP_PAGE)
-    k_pool = k_pool.at[page_idx, slot].set(k.astype(k_pool.dtype),
-                                           mode="drop")
-    v_pool = v_pool.at[page_idx, slot].set(v.astype(v_pool.dtype),
-                                           mode="drop")
+    k_pool = _write_rows(k_pool, k.reshape(B, -1), page_idx, slot)
+    v_pool = _write_rows(v_pool, v.reshape(B, -1), page_idx, slot)
     return k_pool, v_pool
 
 
@@ -390,7 +408,8 @@ def kv_cache_prefill_write_fn(k_pool, v_pool, k, v, page_table, lens,
     """Write a prefill window's K/V into the paged pool.
 
     k/v: [B, nh, S, dh] (the prefill attention's per-layer projections, in
-    head-major layout as the encoder produces them).
+    head-major layout as the encoder produces them); pools
+    [num_pages, ps, nh*dh].
 
     Without `start` (the PR 7 whole-prompt prefill): local index s writes
     slot s; lens [B] are actual prompt lengths, positions s >= lens[b]
@@ -406,22 +425,19 @@ def kv_cache_prefill_write_fn(k_pool, v_pool, k, v, page_table, lens,
     ps = k_pool.shape[1]
     P = page_table.shape[1]
     pos = jnp.arange(S, dtype=jnp.int32)
-    if start is None:
-        gpos = jnp.broadcast_to(pos[None, :], (B, S))     # [B, S]
-        valid = pos[None, :] < lens[:, None]
-    else:
-        gpos = jnp.reshape(start, (-1,))[:, None] + pos[None, :]
-        valid = pos[None, :] < lens[:, None]
+    gpos = jnp.broadcast_to(pos[None, :], (B, S))          # [B, S]
+    if start is not None:
+        gpos = jnp.reshape(start, (-1,))[:, None] + gpos
+    valid = pos[None, :] < lens[:, None]
     page_idx = jnp.take_along_axis(
         page_table, jnp.clip(gpos // ps, 0, P - 1), axis=1)  # [B, S]
     page_idx = jnp.where(valid, page_idx, _DROP_PAGE)
     slot = gpos % ps
-    k_bs = jnp.transpose(k, (0, 2, 1, 3))                 # [B, S, nh, dh]
-    v_bs = jnp.transpose(v, (0, 2, 1, 3))
-    k_pool = k_pool.at[page_idx, slot].set(k_bs.astype(k_pool.dtype),
-                                           mode="drop")
-    v_pool = v_pool.at[page_idx, slot].set(v_bs.astype(v_pool.dtype),
-                                           mode="drop")
+    # [B, nh, S, dh] -> one [nh*dh] row per token
+    k_rows = jnp.transpose(k, (0, 2, 1, 3)).reshape(B, S, nh * dh)
+    v_rows = jnp.transpose(v, (0, 2, 1, 3)).reshape(B, S, nh * dh)
+    k_pool = _write_rows(k_pool, k_rows, page_idx, slot)
+    v_pool = _write_rows(v_pool, v_rows, page_idx, slot)
     return k_pool, v_pool
 
 
@@ -437,14 +453,12 @@ def paged_prefill_attention_fn(q, k_pool, v_pool, page_table, start,
     and the speculative-decode verify window (k+1 queries per row in one
     step). XLA gather reference; fp32 softmax statistics; garbage slots
     past the window are masked with the framework-wide -1e9 convention.
-    q: [B, nh, S, dh] -> out [B, nh, S, dh].
+    q: [B, nh, S, dh] -> out [B, nh, S, dh]; pools [num_pages, ps, nh*dh].
     """
     B, nh, S, dh = q.shape
-    num_pages, ps = k_pool.shape[0], k_pool.shape[1]
-    P = page_table.shape[1]
-    pt = jnp.clip(page_table, 0, num_pages - 1)
-    k = k_pool[pt].reshape(B, P * ps, nh, dh)
-    v = v_pool[pt].reshape(B, P * ps, nh, dh)
+    P, ps = page_table.shape[1], k_pool.shape[1]
+    k = _gather_pages(k_pool, page_table, nh)
+    v = _gather_pages(v_pool, page_table, nh)
     s = jnp.einsum("bhsd,bkhd->bhsk", q, k) * sm_scale
     s = s.astype(jnp.float32)
     slot = jnp.arange(P * ps, dtype=jnp.int32)
@@ -458,7 +472,7 @@ def paged_prefill_attention_fn(q, k_pool, v_pool, page_table, start,
 
 @register_op("paged_decode_attention", grad="none")
 def paged_decode_attention_op(ctx: ExecContext):
-    """inputs: Q [B, nh, dh], KPool/VPool [pages, ps, nh, dh], PageTable
+    """inputs: Q [B, nh, dh], KPool/VPool [pages, ps, nh*dh], PageTable
     [B, P] int32, Positions [B] int32 (current slot index; the context this
     step attends over is 0..Positions inclusive — the just-appended token
     attends to itself); attrs: sm_scale. Output: [B, nh, dh]."""

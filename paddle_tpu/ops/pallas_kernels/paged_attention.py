@@ -10,16 +10,25 @@ gather IS the decode step's HBM bill. This kernel never materializes it:
 
   * grid (batch row, page): the page index for each grid step comes from the
     request's page table via scalar prefetch — the BlockSpec index_map reads
-    `page_table[b, p]` and DMAs exactly that [ps, nh, dh] page slab from the
-    pool, so HBM traffic is the used pages once, nothing else.
+    `page_table[b, p]` and DMAs exactly that [ps, nh*dh] page slab from the
+    pool, so HBM traffic is the used pages once, nothing else. The pool is
+    lane-dense, `[num_pages, ps, nh*dh]` (serving/kv_cache.pool_shape): a
+    slab is whole (8, 128) tiles in the pool's own row-major layout, so the
+    kernel's operand IS the resident buffer — no conversion before the call.
   * the ragged part: rows in one batch have different context lengths
     (`kv_lens`, also scalar-prefetched). Slots past a row's length are masked
-    to -1e9 inside the online-softmax update; rows the continuous-batching
+    to -1e30 inside the online-softmax update; rows the continuous-batching
     scheduler padded in (kv_len 0) produce finite garbage nobody reads — the
     batch_mask convention from PR 2.
-  * online softmax state (m, l, acc) lives in VMEM scratch across the page
-    steps of one row (grid dims are ("parallel", "arbitrary")); the output
-    block is written once, on the row's last page step.
+  * heads never leave the lanes: a token's row holds head h in lanes
+    h*dh..(h+1)*dh, q.k is one [ps, nh*dh] VPU product, and the per-head sum
+    is a butterfly of lane rotations inside each dh-lane segment that leaves
+    every lane holding its head's score. The online softmax state (m, l,
+    acc, each [1, nh*dh], the per-head statistics repeated over the head's
+    lanes) lives in VMEM scratch across the page steps of one row (grid dims
+    are ("parallel", "arbitrary")); the output block is written once, on
+    the row's last page step. float32 products and sums throughout: no MXU
+    pass, so nothing is rounded to bfloat16.
 
 Decode q is a single token per row, so there is no backward pass: the kernel
 is forward-only (serving never differentiates), which keeps it free of the
@@ -40,20 +49,44 @@ _NEG_INF = -1e30
 INTERPRET = False
 
 
+_LANES = 128
+
+
 def paged_supported(q_shape, pool_shape) -> bool:
     """Shapes this kernel handles: q [B, nh, dh] against a pool
-    [num_pages, page_size, nh, dh]. dh must be sublane-aligned; the per-page
-    slab [ps, nh, dh] must be modest enough to double-buffer in VMEM."""
-    if len(q_shape) != 3 or len(pool_shape) != 4:
+    [num_pages, page_size, nh*dh]. A page slab must be whole (8, 128) tiles
+    (page_size a multiple of 8, nh*dh of 128), a head must sit inside one
+    128-lane register (dh a power of two up to 128), and the slab modest
+    enough to double-buffer in VMEM. Everything else (the CPU rehearsal
+    geometry, most unit tests) takes the XLA path on the same pool."""
+    if len(q_shape) != 3 or len(pool_shape) != 3:
         return False
     B, nh, dh = q_shape
-    num_pages, ps, p_nh, p_dh = pool_shape
-    return (nh == p_nh and dh == p_dh and dh % 8 == 0 and dh <= 256
-            and ps * nh * dh * 4 <= 2 * 1024 * 1024)
+    num_pages, ps, width = pool_shape
+    return (nh * dh == width and width % _LANES == 0 and ps % 8 == 0
+            and 8 <= dh <= _LANES and dh & (dh - 1) == 0
+            and ps * width * 4 <= 2 * 1024 * 1024)
+
+
+def _head_sums(x, head_dim):
+    """x [ps, 128]: every lane's sum over the `head_dim`-lane segment it
+    lies in (a head; segments are aligned, `head_dim` a power of two).
+    Butterfly all-reduce: at step s a lane adds its partner `lane ^ s`,
+    fetched by one rotation in each direction."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    s = 1
+    while s < head_dim:
+        # roll(x, k)[i] = x[i - k]: the partner sits s lanes below where
+        # bit s of the lane is set, s lanes above where it is clear
+        x = x + jnp.where(lane & s != 0, pltpu.roll(x, s, 1),
+                          pltpu.roll(x, _LANES - s, 1))
+        s *= 2
+    return x
 
 
 def _decode_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, sm_scale, page_size, num_pages_p):
+                   m_ref, l_ref, acc_ref, *, sm_scale, page_size, num_pages_p,
+                   head_dim):
     b = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -64,30 +97,28 @@ def _decode_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # One query token per row: the step is bound by the page DMA, not by
-    # FLOPs, so q.k and p.v run on the VPU one slot at a time over plain
-    # [nh, dh] tiles. Mosaic's matmul wants the batch (head) dim leading in
-    # both operands, and the page slab [ps, nh, dh] has it in the middle.
-    q = q_ref[0].astype(jnp.float32) * sm_scale          # [nh, dh]
-    kv_len = kl_ref[b]
-    scores = []
-    for j in range(page_size):
-        kj = k_ref[0, j].astype(jnp.float32)             # [nh, dh]
-        sj = jnp.sum(q * kj, axis=-1, keepdims=True)     # [nh, 1]
-        # ragged mask: slot p*ps + j is live iff below this row's context
-        scores.append(jnp.where(p * page_size + j < kv_len, sj, _NEG_INF))
-
-    m_prev = m_ref[...]
-    m_new = functools.reduce(jnp.maximum, scores, m_prev)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_ref[...] * alpha
-    acc = acc_ref[...] * alpha
-    for j, sj in enumerate(scores):
-        pj = jnp.exp(sj - m_new)                         # [nh, 1]
-        l_new = l_new + pj
-        acc = acc + pj * v_ref[0, j].astype(jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-    acc_ref[...] = acc
+    # FLOPs, so q.k and p.v stay on the VPU in float32, one 128-lane column
+    # of the [ps, nh*dh] slab at a time (whole heads: dh divides 128).
+    width = k_ref.shape[2]
+    slot = p * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (page_size, _LANES), 0)
+    # ragged mask: slot p*ps + j is live iff below this row's context
+    live = slot < kl_ref[b]
+    for c in range(0, width, _LANES):
+        col = pl.ds(c, _LANES)
+        q = q_ref[0, :, col].astype(jnp.float32) * sm_scale     # [1, 128]
+        k = k_ref[0, :, col].astype(jnp.float32)                # [ps, 128]
+        s = jnp.where(live, _head_sums(q * k, head_dim), _NEG_INF)
+        m_prev = m_ref[:, col]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        pexp = jnp.exp(s - m_new)                               # [ps, 128]
+        v = v_ref[0, :, col].astype(jnp.float32)
+        l_ref[:, col] = l_ref[:, col] * alpha + jnp.sum(
+            pexp, axis=0, keepdims=True)
+        acc_ref[:, col] = acc_ref[:, col] * alpha + jnp.sum(
+            pexp * v, axis=0, keepdims=True)
+        m_ref[:, col] = m_new
 
     @pl.when(p == num_pages_p - 1)
     def _emit():
@@ -100,45 +131,43 @@ def _decode_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
     B, nh, dh = q.shape
-    num_pages, ps = k_pool.shape[0], k_pool.shape[1]
+    num_pages, ps, width = k_pool.shape
     P = page_table.shape[1]
     # clamp so a padded/garbage table entry DMAs a real page (its slots are
     # masked by kv_lens anyway) instead of reading out of bounds
     page_table = jnp.clip(page_table, 0, num_pages - 1).astype(jnp.int32)
     kv_lens = kv_lens.astype(jnp.int32)
     kernel = functools.partial(_decode_kernel, sm_scale=float(sm_scale),
-                               page_size=ps, num_pages_p=P)
+                               page_size=ps, num_pages_p=P, head_dim=dh)
+    row = pl.BlockSpec((1, 1, width), lambda b, p, pt, kl: (b, 0, 0))
+    page = pl.BlockSpec((1, ps, width),
+                        lambda b, p, pt, kl: (pt[b, p], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, nh, dh), lambda b, p, pt, kl: (b, 0, 0)),
-            pl.BlockSpec((1, ps, nh, dh),
-                         lambda b, p, pt, kl: (pt[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, ps, nh, dh),
-                         lambda b, p, pt, kl: (pt[b, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, nh, dh), lambda b, p, pt, kl: (b, 0, 0)),
+        in_specs=[row, page, page],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((nh, 1), jnp.float32),   # running max
-            pltpu.VMEM((nh, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((nh, dh), jnp.float32),  # running numerator
+            pltpu.VMEM((1, width), jnp.float32),   # running max
+            pltpu.VMEM((1, width), jnp.float32),   # running denominator
+            pltpu.VMEM((1, width), jnp.float32),   # running numerator
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, width), q.dtype),
         cost_estimate=pl.CostEstimate(
             flops=B * nh * 2 * 2 * P * ps * dh,
-            bytes_accessed=(2 * B * P * ps * nh * dh * k_pool.dtype.itemsize
-                            + 2 * B * nh * dh * q.dtype.itemsize),
-            transcendentals=B * nh * P * ps),
+            bytes_accessed=(2 * B * P * ps * width * k_pool.dtype.itemsize
+                            + 2 * B * width * q.dtype.itemsize),
+            transcendentals=B * P * ps * width),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention",
-    )(page_table, kv_lens, q, k_pool, v_pool)
+    )(page_table, kv_lens, q.reshape(B, 1, width), k_pool, v_pool)
+    return out.reshape(B, nh, dh)
 
 
 def _workbench_register():
@@ -166,7 +195,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, kv_lens,
     """One decode step of ragged paged attention.
 
     q: [B, nh, dh] (this step's query per request row);
-    k_pool/v_pool: [num_pages, page_size, nh, dh] (the preallocated pool);
+    k_pool/v_pool: [num_pages, page_size, nh*dh] (the preallocated pool);
     page_table: [B, P] int32 (row b's context lives in pages
     page_table[b, 0..ceil(kv_lens[b]/page_size))); kv_lens: [B] int32 valid
     slot counts. Returns [B, nh, dh] in q's dtype. Callers gate on

@@ -6,10 +6,11 @@ arXiv:2604.15464): a max-seq-len KV buffer per request wastes
 concurrent requests — not compute. Instead:
 
   * the DEVICE side is one preallocated pool per layer,
-    [num_pages, page_size, num_heads, head_dim] for K and V each, living in
-    the serving scope as persistable vars the compiled prefill/decode steps
-    read AND write (the executor donates the buffers, so every append is an
-    in-place HBM scatter, never a reallocation);
+    [num_pages, page_size, num_heads * head_dim] for K and V each
+    (`pool_shape`: a token's heads side by side in ONE lane-dense row),
+    living in the serving scope as persistable vars the compiled
+    prefill/decode steps read AND write (the executor donates the buffers,
+    so every append is an in-place HBM scatter, never a reallocation);
   * the HOST side (this module) is pure bookkeeping: a free-list of page
     ids, a PER-PAGE REFCOUNT, and a per-request page table (list of page
     ids). allocate/share/release are O(pages moved); nothing here touches
@@ -50,7 +51,7 @@ import heapq
 import jax.numpy as jnp
 
 __all__ = ["PagedKVPool", "PrefixCache", "OwnedPoolView", "pool_var_names",
-           "create_device_pools", "declare_pool_vars"]
+           "pool_shape", "create_device_pools", "declare_pool_vars"]
 
 
 def pool_var_names(num_layers: int) -> list[tuple[str, str]]:
@@ -59,19 +60,37 @@ def pool_var_names(num_layers: int) -> list[tuple[str, str]]:
     return [(f"kv_cache.k{i}", f"kv_cache.v{i}") for i in range(num_layers)]
 
 
+def pool_shape(num_pages: int, page_size: int, num_heads: int,
+               head_dim: int) -> tuple[int, int, int]:
+    """THE shape of one K or V pool: `[num_pages, page_size, nh * dh]`, a
+    token's heads side by side in one row (head h is columns
+    `h*dh : (h+1)*dh`). Every program, op and kernel reads and writes the
+    pool in this shape and never reshapes the pool itself (only what it
+    gathered from it), so the buffer keeps ONE device layout from step to
+    step. Why not `[pages, page_size, nh, dh]`: under the TPU's (8, 128)
+    tiling two small minor dims (12, 64) pad to (16, 128), 2.67x, so the
+    chip's client stored that buffer pages-minor-most while the scatter and
+    the Pallas kernel wanted it row-major, and every decode and prefill
+    step copied all 24 pools there and back (86% of device time; PERF.md,
+    PR 24). With `page_size` a multiple of 8 and `nh * dh` a multiple of
+    128 the row-major layout is an exact number of tiles: unpadded, the
+    client's default, what a Pallas block `(1, page_size, nh*dh)` DMAs as
+    is, and indifferent to `num_kv_heads != num_heads`."""
+    return (int(num_pages), int(page_size), int(num_heads) * int(head_dim))
+
+
 def declare_pool_vars(block, num_layers: int, num_pages: int, page_size: int,
                       num_heads: int, head_dim: int, dtype: str = "float32"):
     """Declare the pool vars in a program block (both the prefill and the
     decode program must see them so the executor's def-use analysis
     classifies them read-write and donates their buffers). Under TP,
-    model.apply_tp_annotations shards their heads dim afterwards."""
+    model.apply_tp_annotations shards their last dim (heads are contiguous
+    in it) afterwards."""
+    shape = list(pool_shape(num_pages, page_size, num_heads, head_dim))
     for kn, vn in pool_var_names(num_layers):
         for name in (kn, vn):
-            block.create_var(name=name,
-                             shape=[num_pages, page_size, num_heads,
-                                    head_dim],
-                             dtype=dtype, persistable=True,
-                             stop_gradient=True)
+            block.create_var(name=name, shape=shape, dtype=dtype,
+                             persistable=True, stop_gradient=True)
 
 
 def create_device_pools(scope, num_layers: int, num_pages: int,
@@ -79,11 +98,10 @@ def create_device_pools(scope, num_layers: int, num_pages: int,
                         dtype: str = "float32") -> None:
     """Preallocate the zeroed device pools into `scope` (once, at engine
     construction — this is the only allocation the cache ever does)."""
+    shape = pool_shape(num_pages, page_size, num_heads, head_dim)
     for kn, vn in pool_var_names(num_layers):
         for name in (kn, vn):
-            scope.set_var(name, jnp.zeros(
-                (num_pages, page_size, num_heads, head_dim),
-                jnp.dtype(dtype)))
+            scope.set_var(name, jnp.zeros(shape, jnp.dtype(dtype)))
 
 
 class PagedKVPool:

@@ -337,10 +337,12 @@ _TP_PARAM_LAYOUT = [
 def apply_tp_annotations(program, cfg: DecoderConfig, tp: int) -> int:
     """Annotate a built serving program's vars for tensor parallelism over
     the `tp` mesh axis (parallel/mesh.MODEL_AXIS): attention/FFN weights
-    per _TP_PARAM_LAYOUT and the KV pool vars on their heads dim — the
-    layout "Ragged Paged Attention" (arXiv:2604.15464) head-sharded decode
-    assumes. Returns how many vars were annotated. Dims that `tp` does not
-    divide are left replicated (GSPMD stays correct either way)."""
+    per _TP_PARAM_LAYOUT and the KV pool vars on their last dim
+    `[.., nh*dh]`, in which heads are contiguous, so a tp shard holds whole
+    heads — the layout "Ragged Paged Attention" (arXiv:2604.15464)
+    head-sharded decode assumes. Returns how many vars were annotated.
+    Dims that `tp` does not divide are left replicated (GSPMD stays correct
+    either way)."""
     from ..parallel.mesh import MODEL_AXIS
     from ..parallel.sharding import annotate_sharding
 
@@ -356,7 +358,7 @@ def apply_tp_annotations(program, cfg: DecoderConfig, tp: int) -> int:
                 annotate_sharding(var, axes)
                 done += 1
         if name.startswith("kv_cache.") and cfg.num_heads % tp == 0:
-            annotate_sharding(var, (None, None, MODEL_AXIS, None))
+            annotate_sharding(var, (None, None, MODEL_AXIS))
             done += 1
     return done
 

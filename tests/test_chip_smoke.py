@@ -10,7 +10,7 @@ import chip_smoke
 from paddle_tpu.models import transformer
 from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
 from paddle_tpu.pipeline import jit_compile_counter
-from paddle_tpu.serving import decoder_tiny
+from paddle_tpu.serving import DecoderConfig, decoder_tiny
 
 
 def test_trainer_phase_tiny_single_and_dp():
@@ -37,7 +37,11 @@ def test_server_phase_tiny_runs_the_paged_kernel(monkeypatch):
     # interpret mode makes the Pallas paged kernel runnable here, so the
     # dispatch picks it exactly as it does on the chip
     monkeypatch.setattr(ppa, "INTERPRET", True)
-    out = chip_smoke.server_phase(decoder_tiny(), page_size=4, pool_pages=64,
+    # the smallest geometry the kernel takes: two heads of 64 fill one
+    # 128-lane row of the pool, a page is one sublane tile
+    cfg = DecoderConfig(vocab_size=97, hidden_size=128, num_layers=2,
+                        num_heads=2, ffn_size=64, max_position=64)
+    out = chip_smoke.server_phase(cfg, page_size=8, pool_pages=32,
                                   prompt_lens=(9, 3, 17), max_new=4)
     assert out["paged_attention"]["backend"] == "pallas_paged"
     assert out["tokens"] == 12 and out["leaked_pages"] == 0
@@ -79,6 +83,7 @@ def test_result_line_is_ok_and_device_alone(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "trainer_phase",
                         lambda *a, **k: {"param_platform": "tpu"})
     monkeypatch.setattr(chip_smoke, "server_phase", lambda *a, **k: {})
+    monkeypatch.setattr(chip_smoke, "pool_layout_phase", lambda *a, **k: {})
     assert chip_smoke.main() == 0
     last = capsys.readouterr().out.strip().splitlines()[-1]
     assert json.loads(last) == {"ok": True, "device": {

@@ -1,0 +1,124 @@
+"""The KV pool keeps one device layout (tools/pool_hlo.py): the check on two
+short HLO texts recorded from the v5e's compiler, and the four serving
+programs compiled here for a described v5e (nothing runs; libtpu compiles
+for a chip that is not attached). The only test file that loads libtpu."""
+import pytest
+
+import chip_smoke
+from paddle_tpu.serving import DecoderConfig
+from tools.pool_hlo import pool_sized_copies
+
+POOL = 3072 * 16 * 12 * 64
+
+# PR 23's decode program (parent of PR 24), one K pool of one layer: the
+# resident buffer is pages-minor-most, the scatter and the kernel want it
+# row-major, the output goes back. Instruction attributes trimmed.
+HLO_RELAYOUT = """\
+HloModule jit_fn, is_scheduled=true, input_output_alias={ {2}: (34, {}, may-alias) }
+
+%fused_computation.3 (param_0.9: f32[3072,16,12,64], param_1.14: s32[64], param_2.16: f32[64,12,64]) -> f32[3072,16,12,64] {
+  %param_0.9 = f32[3072,16,12,64]{3,2,1,0:T(8,128)} parameter(0)
+  %param_2.16 = f32[64,12,64]{2,1,0:T(8,128)} parameter(2)
+  %param_1.14 = s32[64]{0:T(128)} parameter(1)
+  ROOT %scatter.0 = f32[3072,16,12,64]{3,2,1,0:T(8,128)} scatter(%param_0.9, %param_1.14, %param_2.16), update_window_dims={1,2}, inserted_window_dims={0,1}, scatter_dims_to_operand_dims={0,1}, index_vector_dim=1, to_apply=%region_5.15
+}
+
+ENTRY %main.31 (feed_vals_0_.1: f32[64,1], rw_vals_0_.1: f32[3072,16,12,64]) -> (s32[64], f32[3072,16,12,64]) {
+  %rw_vals_0_.1 = f32[3072,16,12,64]{0,3,2,1:T(8,128)} parameter(34), sharding={replicated}, metadata={op_name="rw_vals[0]"}
+  %copy.22 = f32[3072,16,12,64]{3,2,1,0:T(8,128)} copy(%rw_vals_0_.1), sharding={replicated}, metadata={op_name="rw_vals[0]"}
+  %fusion.3 = f32[3072,16,12,64]{3,2,1,0:T(8,128)} fusion(%copy.22, %fusion.237, %get-tuple-element.27), kind=kCustom, calls=%fused_computation.3, metadata={op_name="jit(fn)/scatter" stack_frame_id=45}
+  %paged_decode_attention.2 = f32[64,12,64]{2,1,0:T(8,128)S(1)} custom-call(%copy-done.14, %copy-done.34, %copy-done.11, %fusion.3, %fusion.4), custom_call_target="tpu_custom_call"
+  %copy.27 = f32[3072,16,12,64]{0,3,2,1:T(8,128)} copy(%fusion.3), backend_config={"flag_configs":[]}
+  ROOT %tuple.16 = (s32[64]{0:T(128)}, f32[3072,16,12,64]{0,3,2,1:T(8,128)}) tuple(%copy-done.46, %copy.27)
+}
+"""
+
+# PR 24's decode program, the same pool as [3072, 16, 768], and its
+# copy-on-write program (a loop fusion that ends in a dynamic-update-slice).
+HLO_IN_PLACE = """\
+HloModule jit_fn, is_scheduled=true, input_output_alias={ {2}: (34, {}, may-alias) }
+
+%fused_computation.3 (param_0.9: f32[3072,16,768], param_1.14: s32[64], param_2.16: f32[64,768]) -> f32[3072,16,768] {
+  %param_0.9 = f32[3072,16,768]{2,1,0:T(8,128)} parameter(0)
+  %param_2.16 = f32[64,768]{1,0:T(8,128)} parameter(2)
+  %param_1.14 = s32[64]{0:T(128)} parameter(1)
+  ROOT %scatter.0 = f32[3072,16,768]{2,1,0:T(8,128)} scatter(%param_0.9, %param_1.14, %param_2.16), update_window_dims={1}, inserted_window_dims={0,1}, scatter_dims_to_operand_dims={0,1}, index_vector_dim=1, to_apply=%region_5.15
+}
+
+%fused_computation.1 (param_0.1: f32[3072,16,768], param_1.3: s32[], param_2.8: f32[1,16,768]) -> f32[3072,16,768] {
+  %param_0.1 = f32[3072,16,768]{2,1,0:T(8,128)} parameter(0)
+  %param_2.8 = f32[1,16,768]{2,1,0:T(8,128)} parameter(2)
+  %param_1.3 = s32[]{:T(128)} parameter(1)
+  ROOT %dynamic-update-slice.3 = f32[3072,16,768]{2,1,0:T(8,128)} dynamic-update-slice(%param_0.1, %param_2.8, %param_1.3, %constant.25, %constant.25)
+}
+
+ENTRY %main.31 (feed_vals_0_.1: f32[64,1], rw_vals_0_.1: f32[3072,16,768]) -> (s32[64], f32[3072,16,768]) {
+  %rw_vals_0_.1 = f32[3072,16,768]{2,1,0:T(8,128)} parameter(34), sharding={replicated}, metadata={op_name="rw_vals[0]"}
+  %fusion.3 = f32[3072,16,768]{2,1,0:T(8,128)} fusion(%rw_vals_0_.1, %fusion.253, %reshape.232), kind=kCustom, calls=%fused_computation.3, metadata={op_name="jit(fn)/scatter" stack_frame_id=46}
+  %paged_decode_attention.2 = f32[64,1,768]{2,1,0:T(1,128)S(1)} custom-call(%broadcast_clamp_fusion, %get-tuple-element.9, %reshape.261, %fusion.3, %fusion.4), custom_call_target="tpu_custom_call"
+  %fusion.9 = f32[3072,16,768]{2,1,0:T(8,128)} fusion(%fusion.3, %select_n.3, %constant_dynamic-slice_fusion), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(cow)/scatter"}
+  %bitcast.4 = f32[49152,768]{1,0:T(8,128)} bitcast(%fusion.9)
+  ROOT %tuple.13 = (s32[64]{0:T(128)}, f32[3072,16,768]{2,1,0:T(8,128)}) tuple(%copy-done.46, %fusion.9)
+}
+"""
+
+
+def test_pool_sized_copies_finds_the_parents_relayout():
+    found = pool_sized_copies(HLO_RELAYOUT, POOL)
+    assert [(c["name"], c["op"], c["from_layout"], c["layout"])
+            for c in found] == [
+        ("copy.22", "copy", "{0,3,2,1:T(8,128)}", "{3,2,1,0:T(8,128)}"),
+        ("copy.27", "copy", "{3,2,1,0:T(8,128)}", "{0,3,2,1:T(8,128)}")]
+    assert all(c["shape"] == "f32[3072,16,12,64]" for c in found)
+    # another pool size in the same text is not this pool's business
+    assert pool_sized_copies(HLO_RELAYOUT, POOL // 2) == []
+
+
+def test_pool_sized_copies_passes_in_place_updates():
+    """Scatter and dynamic-update-slice fusions, the kernel's custom call,
+    parameters, bitcasts and tuples are not copies, whatever the metadata
+    says; a loop fusion that ends in anything else is."""
+    assert pool_sized_copies(HLO_IN_PLACE, POOL) == []
+    rewritten = HLO_IN_PLACE.replace(
+        "ROOT %dynamic-update-slice.3 = f32[3072,16,768]{2,1,0:T(8,128)} "
+        "dynamic-update-slice(", "ROOT %select.3 = f32[3072,16,768]"
+        "{2,1,0:T(8,128)} select(")
+    assert [c["name"] for c in pool_sized_copies(rewritten, POOL)] == [
+        "fusion.9"]
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+def test_serving_programs_compiled_for_v5e_move_no_pool(v5e_chip):
+    """Decode (with the Mosaic-compiled paged kernel), prefill, window and
+    copy-on-write at the serving cells' widths and pool, two layers deep,
+    compiled by the chip's own compiler: no pool-sized copy in any. A
+    compile that passes is not a chip run; chip_smoke.py repeats it there."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        out = chip_smoke.pool_layout_phase(
+            DecoderConfig(num_layers=2), page_size=16, pool_pages=3072,
+            rows=64, device=v5e_chip)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert out["pool_sized_copies"] == {
+        "decode": 0, "prefill": 0, "window": 0, "cow": 0}

@@ -12,6 +12,7 @@ from paddle_tpu.ops import attention_ops as ao
 from paddle_tpu.serving import (PagedKVPool, ServingEngine, decoder_tiny,
                                 build_full_forward_program)
 from paddle_tpu.serving import model as sv_model
+from paddle_tpu.serving.kv_cache import pool_shape
 
 
 def _rand(shape, seed):
@@ -35,8 +36,8 @@ def _scattered_pool(lens, ps, nh, dh, num_pages, seed=0):
     for b in range(B):
         for p in range(-(-lens[b] // ps)):
             pt_[b, p] = next(perm)
-    kp = jnp.zeros((num_pages, ps, nh, dh), jnp.float32)
-    vp = jnp.zeros((num_pages, ps, nh, dh), jnp.float32)
+    kp = jnp.zeros(pool_shape(num_pages, ps, nh, dh), jnp.float32)
+    vp = jnp.zeros(pool_shape(num_pages, ps, nh, dh), jnp.float32)
     kp, vp = ao.kv_cache_prefill_write_fn(
         kp, vp, jnp.asarray(k), jnp.asarray(v), jnp.asarray(pt_),
         jnp.asarray(lens, np.int32))
@@ -67,31 +68,87 @@ def test_paged_attention_matches_dense_ragged_rows():
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_paged_attention_pallas_matches_reference():
+@pytest.mark.parametrize("ps,nh,dh,lens", [
+    (16, 12, 64, [40, 200, 16, 1, 0, 97]),   # the serving cells' geometry
+    (8, 2, 64, [7, 12, 3, 1]),               # one 128-lane column
+    (8, 1, 128, [9, 0, 24]),                 # a head fills the register
+    (8, 8, 16, [5, 17]),                     # eight heads to a register
+], ids=["cell_12x64_p16", "2x64_p8", "1x128_p8", "8x16_p8"])
+def test_paged_attention_pallas_matches_reference(ps, nh, dh, lens):
     """The Pallas page-DMA kernel (interpret mode on the CPU mesh) ==
-    the XLA gather reference, ragged lengths included."""
+    the XLA gather reference to float32 rounding, on the lane-dense pool:
+    ragged lengths, a row that ends on a page boundary, a row of one token
+    and a padded row (kv_len 0: finite, read by nobody)."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
 
-    ps, nh, dh = 4, 2, 8
-    lens = [7, 12, 3, 1]
-    _, _, kp, vp, pt_ = _scattered_pool(lens, ps, nh, dh, num_pages=16,
-                                        seed=3)
-    q = jnp.asarray(_rand((4, nh, dh), 4))
-    ref = ao._paged_attention_reference(q, kp, vp, pt_,
-                                        jnp.asarray(lens, np.int32),
+    B = len(lens)
+    P = max(1, max(-(-l // ps) for l in lens))
+    num_pages = B * P + 3
+    _, _, kp, vp, pt_ = _scattered_pool(
+        [max(l, 1) for l in lens], ps, nh, dh, num_pages=num_pages, seed=3)
+    q = jnp.asarray(_rand((B, nh, dh), 4))
+    assert ppa.paged_supported(q.shape, kp.shape)
+    kv = jnp.asarray(lens, np.int32)
+    ref = ao._paged_attention_reference(q, kp, vp, pt_, kv,
                                         sm_scale=dh ** -0.5)
     old = ppa.INTERPRET
     ppa.INTERPRET = True
     try:
-        out = ppa.paged_decode_attention(q, kp, vp, pt_,
-                                         jnp.asarray(lens, np.int32),
+        out = ppa.paged_decode_attention(q, kp, vp, pt_, kv,
                                          sm_scale=dh ** -0.5)
     finally:
         ppa.INTERPRET = old
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    out, ref = np.asarray(out), np.asarray(ref)
+    assert out.shape == (B, nh, dh) and np.all(np.isfinite(out))
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(out[live], ref[live], rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("nh,dh,ps,chosen", [
+    (12, 64, 16, "pallas_paged"),    # nh*dh = 768: whole (8, 128) tiles
+    (2, 64, 8, "pallas_paged"),
+    (2, 8, 4, "xla"),                # nh*dh = 16: the rehearsal geometry
+    (3, 32, 8, "xla"),               # nh*dh = 96, no multiple of 128
+    (2, 64, 4, "xla"),               # a page is no whole sublane tile
+    (1, 256, 8, "xla"),              # a head wider than a register
+], ids=["12x64_p16", "2x64_p8", "2x8_p4", "3x32_p8", "2x64_p4", "1x256_p8"])
+def test_paged_backend_follows_the_pool_shape(monkeypatch, nh, dh, ps,
+                                              chosen):
+    """Which path a decode shape takes is decided by the shape alone: a
+    lane-dense page slab of whole tiles runs the kernel, every other
+    geometry the XLA gather on the SAME pool shape — and both give the
+    dense answer."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    monkeypatch.setattr(ppa, "INTERPRET", True)   # the kernel is runnable
+    lens = [ps + 1, 2]
+    k, v, kp, vp, pt_ = _scattered_pool(lens, ps, nh, dh, num_pages=6)
+    assert kp.shape == (6, ps, nh * dh)
+    q = jnp.asarray(_rand((2, nh, dh), 7))
+    backend, _ = ao.paged_attention_backend(
+        2, nh, pt_.shape[1] * ps, dh, np.dtype("float32"),
+        pool_shape=kp.shape)
+    assert backend == chosen
+    before = dict(ao.dispatch_counts())
+    out = ao.paged_decode_attention_fn(q, kp, vp, pt_,
+                                       jnp.asarray(lens, np.int32),
+                                       sm_scale=dh ** -0.5)
+    ran = {k_: n - before.get(k_, 0)
+           for k_, n in ao.dispatch_counts().items()
+           if k_[0] == "paged" and n - before.get(k_, 0)}
+    assert ran == {("paged", chosen, chosen): 1}
+    for b, L_ in enumerate(lens):
+        ref = ao._reference_attention(
+            jnp.asarray(q[b:b + 1, :, None, :]),
+            jnp.asarray(k[b:b + 1, :, :L_]), jnp.asarray(v[b:b + 1, :, :L_]),
+            sm_scale=dh ** -0.5)
+        np.testing.assert_allclose(np.asarray(out)[b],
+                                   np.asarray(ref)[0, :, 0, :],
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_kv_append_page_boundary_and_mask():
@@ -100,22 +157,200 @@ def test_kv_append_page_boundary_and_mask():
     import jax.numpy as jnp
 
     ps, nh, dh = 4, 2, 8
-    kp = jnp.zeros((8, ps, nh, dh), jnp.float32)
-    vp = jnp.zeros((8, ps, nh, dh), jnp.float32)
+    kp = jnp.zeros(pool_shape(8, ps, nh, dh), jnp.float32)
+    vp = jnp.zeros(pool_shape(8, ps, nh, dh), jnp.float32)
     pt_ = jnp.asarray([[5, 2], [3, 6]], np.int32)
     k = jnp.asarray(_rand((2, nh, dh), 0))
     v = jnp.asarray(_rand((2, nh, dh), 1))
+    row0 = np.asarray(k)[0].reshape(nh * dh)    # head h at h*dh:(h+1)*dh
     # row 0 writes slot 3 (last of page 5); row 1 is masked out
     live = jnp.asarray([[1.0], [0.0]], np.float32)
     kp1, vp1 = ao.kv_cache_append_fn(kp, vp, k, v, pt_,
                                      jnp.asarray([3, 3], np.int32), live)
-    np.testing.assert_allclose(np.asarray(kp1)[5, 3], np.asarray(k)[0])
+    np.testing.assert_allclose(np.asarray(kp1)[5, 3], row0)
     assert np.all(np.asarray(kp1)[3] == 0), "masked row wrote to its page"
     # row 0's next append (slot 4 == page boundary) lands in page 2 slot 0
     kp2, _ = ao.kv_cache_append_fn(kp1, vp1, k, v, pt_,
                                    jnp.asarray([4, 4], np.int32), live)
-    np.testing.assert_allclose(np.asarray(kp2)[2, 0], np.asarray(k)[0])
-    np.testing.assert_allclose(np.asarray(kp2)[5, 3], np.asarray(k)[0])
+    np.testing.assert_allclose(np.asarray(kp2)[2, 0], row0)
+    np.testing.assert_allclose(np.asarray(kp2)[5, 3], row0)
+
+
+def _dense_write(pool, rows, page, slot):
+    """The oracle's write: one token's [nh, dh] heads into row (page, slot)
+    of a numpy pool [pages, ps, nh*dh], head h at columns h*dh:(h+1)*dh."""
+    nh, dh = rows.shape
+    for h in range(nh):
+        pool[page, slot, h * dh:(h + 1) * dh] = rows[h]
+
+
+def _oracle_append_boundary():
+    """Appends at slots 3, 4 (a page boundary) and 5 of a row, beside a
+    row that is masked throughout."""
+    import jax.numpy as jnp
+
+    ps, nh, dh = 4, 2, 8
+    shape = pool_shape(8, ps, nh, dh)
+    got = (jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32))
+    want = (np.zeros(shape, np.float32), np.zeros(shape, np.float32))
+    table = np.asarray([[5, 2], [3, 6]], np.int32)
+    live = jnp.asarray([[1.0], [0.0]], np.float32)
+    for step, slot in enumerate((3, 4, 5)):
+        k, v = _rand((2, nh, dh), 10 + step), _rand((2, nh, dh), 20 + step)
+        got = ao.kv_cache_append_fn(
+            *got, jnp.asarray(k), jnp.asarray(v), jnp.asarray(table),
+            jnp.asarray([slot, slot], np.int32), live)
+        _dense_write(want[0], k[0], table[0, slot // ps], slot % ps)
+        _dense_write(want[1], v[0], table[0, slot // ps], slot % ps)
+    return got, want
+
+
+def _oracle_append_masked():
+    """No Mask input writes every row; an all-zero mask writes none; a
+    position past the table's last page clamps to it (the engine never
+    feeds one, the op must not write out of the row's pages)."""
+    import jax.numpy as jnp
+
+    ps, nh, dh = 4, 3, 8
+    shape = pool_shape(6, ps, nh, dh)
+    k, v = _rand((3, nh, dh), 1), _rand((3, nh, dh), 2)
+    table = np.asarray([[0], [4], [2]], np.int32)
+    pos = np.asarray([1, 3, 0], np.int32)
+    zeros = (jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32))
+    none = ao.kv_cache_append_fn(
+        *zeros, jnp.asarray(k), jnp.asarray(v), jnp.asarray(table),
+        jnp.asarray(pos), jnp.zeros((3, 1), jnp.float32))
+    assert not np.any(np.asarray(none[0])) and not np.any(np.asarray(none[1]))
+    got = ao.kv_cache_append_fn(*zeros, jnp.asarray(k), jnp.asarray(v),
+                                jnp.asarray(table), jnp.asarray(pos))
+    want = (np.zeros(shape, np.float32), np.zeros(shape, np.float32))
+    for b in range(3):
+        _dense_write(want[0], k[b], table[b, 0], pos[b])
+        _dense_write(want[1], v[b], table[b, 0], pos[b])
+    return got, want
+
+
+def _oracle_prefill_write(start):
+    """A bucket-padded window of 7 tokens a row: without Start token s
+    goes to slot s, with Start to slot Start+s; positions past Lens and
+    rows of length 0 write nothing."""
+    import jax.numpy as jnp
+
+    ps, nh, dh, S = 4, 2, 8, 7
+    shape = pool_shape(12, ps, nh, dh)
+    k, v = _rand((3, nh, S, dh), 5), _rand((3, nh, S, dh), 6)
+    table = np.asarray([[7, 1, 9], [0, 4, 2], [3, 5, 6]], np.int32)
+    lens = np.asarray([7, 0, 3], np.int32)
+    base = np.asarray(start if start is not None else [0, 0, 0], np.int32)
+    got = ao.kv_cache_prefill_write_fn(
+        jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32),
+        jnp.asarray(k), jnp.asarray(v), jnp.asarray(table),
+        jnp.asarray(lens),
+        None if start is None else jnp.asarray(base))
+    want = (np.zeros(shape, np.float32), np.zeros(shape, np.float32))
+    for b in range(3):
+        for s_ in range(lens[b]):
+            g = base[b] + s_
+            _dense_write(want[0], k[b, :, s_], table[b, g // ps], g % ps)
+            _dense_write(want[1], v[b, :, s_], table[b, g // ps], g % ps)
+    return got, want
+
+
+def _oracle_window_attention():
+    """Suffix prefill: a window of 3 queries behind cached prefixes of 5
+    and 9 tokens attends the whole pooled context, == dense causal
+    attention over prefix + window at the window's positions."""
+    import jax.numpy as jnp
+
+    ps, nh, dh, S = 4, 2, 8, 3
+    prefix = [5, 9]
+    total = [p_ + S for p_ in prefix]
+    k, v, kp, vp, pt_ = _scattered_pool(total, ps, nh, dh, num_pages=12,
+                                        seed=11)
+    q = _rand((2, nh, S, dh), 12)
+    got = ao.paged_prefill_attention_fn(
+        jnp.asarray(q), kp, vp, pt_, jnp.asarray(prefix, np.int32),
+        sm_scale=dh ** -0.5)
+    want = np.zeros((2, nh, S, dh), np.float32)
+    for b, (p_, t) in enumerate(zip(prefix, total)):
+        full_q = np.zeros((1, nh, t, dh), np.float32)
+        full_q[0, :, p_:] = q[b]
+        ref = ao._reference_attention(
+            jnp.asarray(full_q), jnp.asarray(k[b:b + 1, :, :t]),
+            jnp.asarray(v[b:b + 1, :, :t]), causal=True,
+            sm_scale=dh ** -0.5)
+        want[b] = np.asarray(ref)[0, :, p_:]
+    return (got,), (want,)
+
+
+def _oracle_cow_copy():
+    """Copy-on-write's copy through the op itself: page Dst becomes page
+    Src for K and V, every other page keeps its bytes."""
+    import types
+
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.registry import ExecContext
+
+    shape = pool_shape(6, 4, 2, 8)
+    kp, vp = _rand(shape, 30), _rand(shape, 31)
+    op = types.SimpleNamespace(
+        inputs={s_: [s_] for s_ in ("KPool", "VPool", "Src", "Dst")},
+        attrs={})
+    out = ao.kv_cache_copy_page_op(ExecContext(op, {
+        "KPool": jnp.asarray(kp), "VPool": jnp.asarray(vp),
+        "Src": jnp.asarray([4], np.int32),
+        "Dst": jnp.asarray([1], np.int32)}))
+    want = (kp.copy(), vp.copy())
+    want[0][1], want[1][1] = kp[4], vp[4]
+    return (out["KPoolOut"], out["VPoolOut"]), want
+
+
+@pytest.mark.parametrize("case", [
+    _oracle_append_boundary, _oracle_append_masked,
+    lambda: _oracle_prefill_write(None),
+    lambda: _oracle_prefill_write([2, 1, 6]),
+    _oracle_window_attention, _oracle_cow_copy,
+], ids=["append_page_boundary", "append_masked_rows", "prefill_write",
+        "prefill_write_start", "window_attention_prefix", "cow_copy"])
+def test_paged_cache_ops_match_dense_oracle(case):
+    """Each paged-cache op on the `[pages, page_size, nh*dh]` pool against
+    a plain numpy oracle of the same semantics."""
+    got, want = case()
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert np.asarray(g).shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), w, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "window", "cow",
+                                     "decode_tp2"])
+def test_serving_programs_donate_the_pools(program):
+    """Every serving program updates the pools in place: the buffers it
+    was handed are donated (deleted once the step has run) and the scope
+    holds the step's outputs, of the one pool shape."""
+    from paddle_tpu.serving.kv_cache import pool_var_names
+    from tools.pool_hlo import serving_program_cases
+
+    cfg = decoder_tiny()
+    eng = ServingEngine(cfg, page_size=4, pool_pages=16, max_inflight=2,
+                        tp=2 if program.endswith("_tp2") else 1)
+    names = [n for pair in pool_var_names(cfg.num_layers) for n in pair]
+    target, feed, fetches = serving_program_cases(
+        eng, rows=2, pages=2, prompt=8)[program.removesuffix("_tp2")]
+
+    def run():
+        eng._exe.run(target, feed=feed, fetch_list=fetches,
+                     scope=eng._scope)
+
+    run()    # under tp the first run also moves the state onto the mesh
+    before = [eng._scope.find_var(n) for n in names]
+    run()
+    shape = pool_shape(16, 4, cfg.num_heads, cfg.head_dim)
+    for n, old in zip(names, before):
+        new = eng._scope.find_var(n)
+        assert old.is_deleted(), f"{program}: {n} was copied, not donated"
+        assert not new.is_deleted() and new.shape == shape
 
 
 def test_paged_backend_tuner_lever(tmp_path):
@@ -171,8 +406,8 @@ def test_decode_candidate_upgrades_via_tune(tmp_path, monkeypatch):
         db_path = str(tmp_path / "db.json")
         db = tuning.TuningDB(db_path)
         key = tuning.canonical_key(
-            "attention", tuning.attention_key(2, 2, 1, 16, 8, True),
-            "float32", tuning.device_kind())
+            "attention", tuning.attention_key(2, 2, 1, 16, 64, True),
+            "float32", tuning.device_kind())   # nh*dh = 128: a kernel shape
         db.put(key, {"backend": "xla"}, source="candidate")
         tune.sweep_candidates(db, iters=1, passes=2, band=0.05)
         entry = db.lookup(key)

@@ -343,14 +343,14 @@ def test_sampling_rows_mix_with_greedy_and_spec_rows():
 def test_tp_engine_matches_single_shard():
     """tp=2 over the host-device mesh: head-sharded prefill+decode emits
     exactly the tp=1 tokens (GSPMD correctness), with the KV pools
-    annotated on their heads dim."""
+    annotated on their last dim [nh*dh], where heads are contiguous."""
     cfg = decoder_tiny()
     prompts = _prompts(cfg, 13, (5, 11))
     _, want = _generate(cfg, prompts, prefix_cache=False)
     eng, got = _generate(cfg, prompts, prefix_cache=False, tp=2)
     assert got == want
     pool_var = eng._decode_prog.global_block.var("kv_cache.k0")
-    assert pool_var.sharding == (None, None, "tp", None)
+    assert pool_var.sharding == (None, None, "tp")
 
 
 def test_tp_decode_consults_per_shard_tuner_key(tmp_path):

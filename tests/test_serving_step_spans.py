@@ -4,6 +4,7 @@ registry and in the JSONL stream; the collector's pauses; the slow-step
 record. A CPU run proves names, nesting, counts and sums; it gives no speed."""
 import gc
 import glob
+import importlib
 import os
 import re
 import time
@@ -20,6 +21,8 @@ from paddle_tpu.serving import DecoderConfig, ServingEngine
 from paddle_tpu.serving import engine as sv_engine
 from paddle_tpu.serving import model as sv_model
 
+# the module: `observability.registry` the attribute is the accessor function
+obs_registry = importlib.import_module("paddle_tpu.observability.registry")
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # child -> the parents it may hang under (the table of ISSUE 23)
@@ -147,9 +150,12 @@ def test_the_leaves_cover_a_step_and_host_plus_fetch_is_the_decode(records):
             h[stage]["sum"])
 
 
-def test_the_spans_are_on_the_profilers_clock_and_nest(tmp_path):
+def test_the_spans_are_on_the_profilers_clock_and_nest(tmp_path, monkeypatch):
     from benchmark.trace_reduce import find_xplane, read_planes
 
+    # a CPU loop of tiny steps runs past the annotation budget: lift it here
+    monkeypatch.setattr(obs_registry, "ANNOTATED_SPANS_PER_S", 1e9)
+    monkeypatch.setattr(obs_registry, "ANNOTATED_SPANS_BURST", 1e9)
     eng = _engine()
     _serve(eng)
     with profiler.profiler(profile_path=str(tmp_path)):
@@ -173,6 +179,56 @@ def test_the_spans_are_on_the_profilers_clock_and_nest(tmp_path):
                           ("serving.accept", "serving.step")):
         assert inside(child, parent), (child, parent)
     assert len(by_name["serving.step"]) == len(by_name["serving.decode"])
+
+
+class _FakeAnnotation:
+    opened = []
+
+    def __init__(self, name, **attrs):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.opened.append(self.name)
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize("trees_per_s,annotated", [(10, "all"), (2000, "some")])
+def test_a_fast_loop_is_annotated_by_whole_trees_within_the_budget(
+        monkeypatch, trees_per_s, annotated):
+    """The profiler's timeline takes `ANNOTATED_SPANS_PER_S` spans a second
+    from one thread: a slow loop is annotated whole, a fast one by whole
+    trees, and the registry sees every span of both."""
+
+    monkeypatch.setattr(obs_registry, "_TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.opened = []
+    st = obs_registry._tls
+    st.budget, st.budget_at = 0.0, time.perf_counter()  # a loop long under way
+    before = obs.snapshot()["histograms"].get(
+        "serving.step.seconds", {}).get("count", 0)
+    trees = 3 * trees_per_s                      # three seconds of it
+    for i in range(trees):
+        st.budget_at -= 1.0 / trees_per_s         # that much time has passed
+        with obs.span("serving.step", step=i):
+            with obs.span("serving.decode", rows=1):
+                with profiler.stage_timer("pipeline.fetch"):
+                    pass
+            with obs.span("serving.housekeeping"):
+                pass
+    opened = _FakeAnnotation.opened
+    assert opened[:4] == ["serving.step", "serving.decode", "pipeline.fetch",
+                          "serving.housekeeping"] * (len(opened) > 0)
+    assert len(opened) % 4 == 0
+    assert all(opened[i:i + 4] == opened[:4] for i in range(0, len(opened), 4))
+    if annotated == "all":
+        assert len(opened) == 4 * trees
+    else:
+        rate = len(opened) / 3.0
+        assert 0.9 * obs_registry.ANNOTATED_SPANS_PER_S <= rate \
+            <= 1.2 * obs_registry.ANNOTATED_SPANS_PER_S  # + the loop's own time
+    after = obs.snapshot()["histograms"]["serving.step.seconds"]["count"]
+    assert after - before == trees
 
 
 def test_a_collection_inside_a_phase_is_booked_to_the_collector(monkeypatch):
@@ -350,8 +406,8 @@ def test_the_paged_decode_kernel_is_found_by_its_name_in_the_lowering():
 
     from paddle_tpu.ops.pallas_kernels import paged_attention as pa
 
-    q = jnp.zeros((2, 2, 8), jnp.float32)
-    pool = jnp.zeros((4, 4, 2, 8), jnp.float32)
+    q = jnp.zeros((2, 2, 64), jnp.float32)
+    pool = jnp.zeros((4, 8, 2 * 64), jnp.float32)   # [pages, ps, nh*dh]
     table = jnp.zeros((2, 2), jnp.int32)
     lens = jnp.ones((2,), jnp.int32)
     text = jax.jit(lambda *a: pa._call(*a, 1.0, True)).lower(

@@ -80,14 +80,17 @@ def _compare(fn, ref, args, n_diff: int, dtype: str) -> dict:
 
 def _paged_cases(spec):
     """The serving default: decode attention over the 12 x 64 heads of the
-    BERT-base-shaped decoder, 2048 x 16 pool (chip_smoke.py's geometry)."""
+    BERT-base-shaped decoder, a 2048 x 16 pool of lane-dense [nh*dh] rows
+    (serving/kv_cache.pool_shape)."""
+
+    from paddle_tpu.serving.kv_cache import pool_shape
 
     def case(dtype):
         B, nh, dh, ps, pages, P = 4, 12, 64, 16, 2048, 16
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         q = _rand(ks[0], (B, nh, dh), dtype)
-        kp = _rand(ks[1], (pages, ps, nh, dh), dtype)
-        vp = _rand(ks[2], (pages, ps, nh, dh), dtype)
+        kp = _rand(ks[1], pool_shape(pages, ps, nh, dh), dtype)
+        vp = _rand(ks[2], pool_shape(pages, ps, nh, dh), dtype)
         table = jax.random.permutation(ks[3], pages)[:B * P].reshape(B, P)
         lens = jnp.asarray([208, 48, 13, 1], jnp.int32)
         assert spec.supported(q.shape, kp.shape)

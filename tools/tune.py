@@ -338,6 +338,7 @@ def sweep_decode_attention(db, shapes, dtype: str, iters: int, passes: int,
     from paddle_tpu import flags as pt_flags
     from paddle_tpu.ops.attention_ops import (_paged_attention_reference,
                                               _pallas_paged_ok)
+    from paddle_tpu.serving.kv_cache import pool_shape
 
     key_dtype = str(jnp.dtype(dtype))
     ps = int(pt_flags.get_flag("serving_page_size"))
@@ -346,8 +347,8 @@ def sweep_decode_attention(db, shapes, dtype: str, iters: int, passes: int,
         num_pages = b * (kv // ps) + 1
         rng = np.random.default_rng(0)
         kp, vp = (jax.device_put(rng.standard_normal(
-            (num_pages, ps, nh, dh), dtype=np.float32).astype(dtype))
-            for _ in range(2))
+            pool_shape(num_pages, ps, nh, dh), dtype=np.float32)
+            .astype(dtype)) for _ in range(2))
         q = jax.device_put(rng.standard_normal(
             (b, nh, dh), dtype=np.float32).astype(dtype))
         pt_ = jax.device_put(rng.permutation(num_pages - 1)[:b * (kv // ps)]
