@@ -265,8 +265,10 @@ def pool_layout_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
     fresh pool-sized array — a relayout copy of a whole pool."""
     eng = ServingEngine(cfg, page_size=page_size, pool_pages=pool_pages,
                         max_inflight=rows, seed=21)
-    elements = int(np.prod(pool_shape(pool_pages, page_size, cfg.num_heads,
-                                      cfg.head_dim)))
+    # a block with stacked pools keeps every layer's pages in one buffer
+    elements = int(np.prod(pool_shape(pool_pages, page_size, cfg.kv_heads,
+                                      cfg.head_dim))) \
+        * (cfg.num_layers if cfg.stateful else 1)
     texts = serving_program_hlos(
         eng, rows=rows, pages=eng.pool.pages_for(cfg.max_position),
         prompt=128, device=device)

@@ -94,6 +94,20 @@ class Normal(Initializer):
         return jax.random.normal(key, shape, dtype) * self.scale + self.loc
 
 
+class StackedNormal(Normal):
+    """Normal for a stack of layers `[L, ...]` too large to draw at once
+    (a served mixture-of-experts' expert weights): the startup op draws one
+    leading index at a time."""
+
+    def __call__(self, var, block):
+        block.append_op(
+            "stacked_gaussian_random",
+            outputs={"Out": [var.name]},
+            attrs={"shape": list(var.shape), "dtype": var.dtype.value,
+                   "mean": self.loc, "std": self.scale, "seed": self.seed},
+        )
+
+
 class TruncatedNormal(Initializer):
     def __init__(self, loc=0.0, scale=1.0, seed=0):
         self.loc, self.scale, self.seed = loc, scale, seed

@@ -54,6 +54,10 @@ DECLARED: list[tuple] = [
     ("serving.prefills", COUNTER, "prompt prefills executed", ()),
     ("serving.decode_steps", COUNTER, "batched decode steps", ()),
     ("serving.decode_tokens", COUNTER, "tokens accepted by decode", ()),
+    ("serving.decode_context_pages", COUNTER,
+     "pages of context the rows of a plain decode step attended over, "
+     "summed over rows and steps (x a page's K+V bytes: what decode "
+     "attention had to read in one layer)", ()),
     ("serving.preemptions", COUNTER,
      "requests preempted back to the waiting queue", ()),
     ("serving.aborts", COUNTER, "requests aborted", ()),
@@ -259,6 +263,21 @@ DECLARED: list[tuple] = [
     ("serving.control.applies", COUNTER,
      "pending EngineConfigs adopted at a safe boundary (engine idle gap "
      "/ router epoch tick)", ()),
+    # -- per-sequence state rows and expert routing (ISSUE 25) --------------
+    ("serving.state.restores", COUNTER,
+     "prefix hits that resumed from a page's state row", ()),
+    ("serving.state.recomputed_tokens", COUNTER,
+     "cached prompt tokens re-run because a hit was cut back to the last "
+     "whole page before the prompt's last token", ()),
+    ("serving.moe.tokens", COUNTER,
+     "tokens routed to an expert, summed over layers (prefill and decode)",
+     ("expert",)),
+    ("serving.moe.experts_touched", COUNTER,
+     "distinct experts a layer routed to in a decode step, summed over "
+     "layers and steps", ()),
+    ("serving.moe.layer_steps", COUNTER,
+     "layer x decode-step pairs counted in serving.moe.experts_touched: "
+     "the calls of the decode expert kernel", ()),
     ("serving.control.rewarmups", COUNTER,
      "warmup_decode re-runs forced by an adopted bucket-geometry change "
      "(keeps XLA compiles off the serving path)", ()),

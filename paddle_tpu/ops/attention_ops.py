@@ -53,6 +53,26 @@ def _reference_attention(q, k, v, bias=None, causal=False, sm_scale=1.0):
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), v)
 
 
+def grouped_query_attention(q, k, v, causal=False, sm_scale=1.0):
+    """Dense attention of `nh` query heads over `nkv` key/value heads
+    (`nh % nkv == 0`; query head h reads KV head `h // (nh // nkv)`), without
+    repeating K/V. q [B, nh, Sq, dh], k/v [B, nkv, Sk, dh]; scores and
+    softmax float32 whatever the operands (a bfloat16 score rounds a logit
+    of 11 by 0.02)."""
+    B, nh, sq, dh = q.shape
+    nkv, sk = k.shape[1], k.shape[2]
+    qg = q.reshape(B, nkv, nh // nkv, sq, dh)
+    scores = jnp.einsum("bjgqd,bjkd->bjgqk", qg, k,
+                        preferred_element_type=jnp.float32) * sm_scale
+    if causal:
+        mask = jnp.tril(jnp.ones((sq, sk), jnp.bool_), sk - sq)
+        scores = jnp.where(mask, scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bjgqk,bjkd->bjgqd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, nh, sq, dh).astype(q.dtype)
+
+
 def _block_multiple_ok(s: int) -> bool:
     # the bundled kernel wants seq divisible by its block sizes (>=128 lanes)
     return s % 128 == 0
@@ -227,9 +247,17 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=1.0,
 @register_op("fused_attention")
 def fused_attention(ctx: ExecContext):
     """inputs: Q, K, V [B, nh, S, dh], optional Bias (broadcastable to
-    [B, nh, Sq, Sk]); attrs: causal, sm_scale. Output: [B, nh, Sq, dh]."""
+    [B, nh, Sq, Sk]); attrs: causal, sm_scale. Output: [B, nh, Sq, dh].
+    K and V may carry fewer heads than Q (grouped-query attention)."""
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
     bias = ctx.input("Bias") if ctx.has_input("Bias") else None
+    if k.shape[1] != q.shape[1]:
+        if bias is not None:
+            raise NotImplementedError(
+                "fused_attention: grouped-query heads take no Bias")
+        return {"Out": grouped_query_attention(
+            q, k, v, causal=ctx.attr("causal", False),
+            sm_scale=ctx.attr("sm_scale", 1.0))}
     out = flash_attention(q, k, v, bias,
                           causal=ctx.attr("causal", False),
                           sm_scale=ctx.attr("sm_scale", 1.0),
@@ -242,12 +270,13 @@ def fused_attention(ctx: ExecContext):
 # ---------------------------------------------------------------------------
 
 
-def _pallas_paged_ok(q_shape, pool_shape) -> bool:
+def _pallas_paged_ok(q_shape, pool_shape, pool_dtype=jnp.float32) -> bool:
     from .pallas_kernels import paged_attention as ppa
     from .pallas_kernels import workbench
 
     return (workbench.runnable(ppa)
-            and ppa.paged_supported(tuple(q_shape), tuple(pool_shape)))
+            and ppa.paged_supported(tuple(q_shape), tuple(pool_shape),
+                                    pool_dtype))
 
 
 def _shard_paged_shapes(q_shape, pool_shape, tp=1):
@@ -267,7 +296,7 @@ def _shard_paged_shapes(q_shape, pool_shape, tp=1):
 
 
 def paged_attention_backend(batch, num_heads, kv_slots, head_dim, dtype,
-                            pool_shape=None, tp=1):
+                            pool_shape=None, tp=1, pool_dtype=None):
     """Which kernel carries one ragged decode-attention shape (sq=1, sk =
     the padded slot count P*page_size). Returns (backend, tier) with backend
     in {"xla", "pallas_paged"}.
@@ -288,7 +317,8 @@ def paged_attention_backend(batch, num_heads, kv_slots, head_dim, dtype,
 
     def analytic():
         if pool_shape is not None and _pallas_paged_ok(
-                (batch, num_heads, head_dim), pool_shape):
+                (batch, num_heads, head_dim), pool_shape,
+                pool_dtype or dtype):
             return {"backend": "pallas_paged"}
         return {"backend": "xla"}
 
@@ -311,9 +341,10 @@ def paged_attention_backend(batch, num_heads, kv_slots, head_dim, dtype,
 
 
 def _gather_pages(pool, page_table, nh):
-    """Rows `page_table` [B, P] names, as heads: `[B, P*ps, nh, dh]`. Whole
-    `[ps, nh*dh]` page rows are gathered and the (small) result is what
-    gets reshaped, never the pool: the pool keeps its one layout."""
+    """Rows `page_table` [B, P] names, as heads: `[B, P*ps, nh, dh]` (`nh`
+    the pool's own head count, the KV heads under grouped-query attention).
+    Whole `[ps, nh*dh]` page rows are gathered and the (small) result is
+    what gets reshaped, never the pool: the pool keeps its one layout."""
     num_pages, ps, width = pool.shape
     B, P = page_table.shape
     pt = jnp.clip(page_table, 0, num_pages - 1)
@@ -330,14 +361,22 @@ def _paged_attention_reference(q, k_pool, v_pool, page_table, kv_lens,
     row (kv_len 0) stays finite. Pools are `[num_pages, ps, nh*dh]`."""
     B, nh, dh = q.shape
     P, ps = page_table.shape[1], k_pool.shape[1]
-    k = _gather_pages(k_pool, page_table, nh)
-    v = _gather_pages(v_pool, page_table, nh)
-    s = jnp.einsum("bhd,bkhd->bhk", q, k) * sm_scale
-    s = s.astype(jnp.float32)
+    nkv = k_pool.shape[2] // dh
     pos = jnp.arange(P * ps, dtype=jnp.int32)
-    s = jnp.where(pos[None, None, :] < kv_lens[:, None, None], s, _NEG_INF)
+    # query head h reads KV head h // (nh // nkv) (a group of 1 when the
+    # pool holds every head); the scores stay float32 (bfloat16 pools round
+    # them by 0.02 otherwise)
+    k = _gather_pages(k_pool, page_table, nkv)
+    v = _gather_pages(v_pool, page_table, nkv)
+    qg = q.reshape(B, nkv, nh // nkv, dh)
+    s = jnp.einsum("bjgd,bkjd->bjgk", qg.astype(k.dtype), k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(pos[None, None, None, :]
+                  < kv_lens[:, None, None, None], s, _NEG_INF)
     probs = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhk,bkhd->bhd", probs.astype(q.dtype), v)
+    out = jnp.einsum("bjgk,bkjd->bjgd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, nh, dh).astype(q.dtype)
 
 
 def paged_decode_attention_fn(q, k_pool, v_pool, page_table, kv_lens,
@@ -349,11 +388,13 @@ def paged_decode_attention_fn(q, k_pool, v_pool, page_table, kv_lens,
     B, nh, dh = q.shape
     P, ps = page_table.shape[1], k_pool.shape[1]
     backend, _tier = paged_attention_backend(B, nh, P * ps, dh, q.dtype,
-                                             pool_shape=k_pool.shape, tp=tp)
+                                             pool_shape=k_pool.shape, tp=tp,
+                                             pool_dtype=k_pool.dtype)
     # re-check executability at the SAME per-shard shapes the decision saw
     # (under tp > 1 the global q/pool head counts are not what a shard runs)
     shard_q, shard_pool = _shard_paged_shapes(q.shape, k_pool.shape, tp)
-    if backend == "pallas_paged" and _pallas_paged_ok(shard_q, shard_pool):
+    if backend == "pallas_paged" and _pallas_paged_ok(shard_q, shard_pool,
+                                                      k_pool.dtype):
         from .pallas_kernels import paged_attention as ppa
 
         _note_dispatch("paged", backend, backend)
@@ -457,17 +498,21 @@ def paged_prefill_attention_fn(q, k_pool, v_pool, page_table, start,
     """
     B, nh, S, dh = q.shape
     P, ps = page_table.shape[1], k_pool.shape[1]
-    k = _gather_pages(k_pool, page_table, nh)
-    v = _gather_pages(v_pool, page_table, nh)
-    s = jnp.einsum("bhsd,bkhd->bhsk", q, k) * sm_scale
-    s = s.astype(jnp.float32)
+    nkv = k_pool.shape[2] // dh
     slot = jnp.arange(P * ps, dtype=jnp.int32)
     limit = (jnp.reshape(start, (-1,))[:, None]
              + jnp.arange(S, dtype=jnp.int32)[None, :])   # [B, S]
     mask = slot[None, None, None, :] <= limit[:, None, :, None]
-    s = jnp.where(mask, s, _NEG_INF)
+    k = _gather_pages(k_pool, page_table, nkv)
+    v = _gather_pages(v_pool, page_table, nkv)
+    qg = q.reshape(B, nkv, nh // nkv, S, dh)
+    s = jnp.einsum("bjgsd,bkjd->bjgsk", qg.astype(k.dtype), k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(mask[:, :, None], s, _NEG_INF)
     probs = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhsk,bkhd->bhsd", probs.astype(q.dtype), v)
+    out = jnp.einsum("bjgsk,bkjd->bjgsd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, nh, S, dh).astype(q.dtype)
 
 
 @register_op("paged_decode_attention", grad="none")

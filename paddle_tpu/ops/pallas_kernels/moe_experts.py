@@ -1,0 +1,161 @@
+"""Top-1 expert layer over stacked expert weights: a Pallas TPU kernel.
+
+What it computes: `out[t] = sum_e cw[t, e] * W_down[e] (silu(W_gate[e] z_t)
+* (W_up[e] z_t))` for the experts this call holds, with `cw[t, e]` the
+router's probability where token t chose expert e and zero elsewhere. Under
+top-1 routing one term of the sum is non-zero per token; the kernel still
+walks EVERY expert it holds, so a step's time does not depend on where the
+router sent the tokens (a decode batch of 64 rows touches nearly all of 16
+experts anyway, and a step whose cost moved with the routing would make two
+runs with different weights incomparable).
+
+Why a kernel: a decode step is bound by streaming the expert weights
+(3 x H x F a expert) through the chip once, and this is that stream and
+nothing else:
+
+  * the weights stay where they live, stacked `[layers, experts, ...]`; the
+    layer index is a prefetched scalar the BlockSpec index maps read, so a
+    loop over layers (`lax.scan`) slices nothing out of the stack;
+  * grid (token tile, expert, F tile): one step DMAs a `[H, tf]` slab of
+    W_gate and W_up and a `[tf, H]` slab of W_down, multiplies the token
+    tile through all three on the MXU (operands in the weights' dtype,
+    float32 accumulation) and adds into the output tile, which stays in
+    VMEM across the (expert, F tile) steps of its token tile;
+  * the combine weight is applied to the `[tt, tf]` hidden tile before the
+    down projection, so a token's row is zero for every expert it did not
+    choose.
+
+Forward only: serving never differentiates.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# tests flip this to run the kernel through the Pallas interpreter on CPU
+INTERPRET = False
+
+_LANES = 128
+_TOKEN_TILE = 256
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _f_tile(ffn: int) -> int:
+    return 512 if ffn % 512 == 0 else ffn
+
+
+def experts_supported(z_shape, w_gate_shape, dtype) -> bool:
+    """z [T, H] against W_gate `[L, E, H, F]`: whole 128-lane rows on both
+    widths, a 2- or 4-byte dtype, and at most 128 experts (the combine
+    weights ride one lane register)."""
+    if len(z_shape) != 2 or len(w_gate_shape) != 4:
+        return False
+    _, E, H, F = w_gate_shape
+    return (z_shape[1] == H and H % _LANES == 0 and F % _LANES == 0
+            and E <= _LANES and jnp.dtype(dtype).itemsize in (2, 4)
+            and 3 * H * _f_tile(F) * jnp.dtype(dtype).itemsize
+            <= 8 * 1024 * 1024)
+
+
+def _kernel(layer_ref, z_ref, cw_ref, wg_ref, wu_ref, wd_ref, o_ref):
+    del layer_ref                      # read by the index maps
+    e = pl.program_id(1)
+    f = pl.program_id(2)
+
+    @pl.when((e == 0) & (f == 0))
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    z = z_ref[...]                                             # [tt, H]
+    g = jnp.dot(z, wg_ref[0, 0], preferred_element_type=jnp.float32)
+    u = jnp.dot(z, wu_ref[0, 0], preferred_element_type=jnp.float32)
+    cw = cw_ref[...]                                           # [tt, 128]
+    lane = jax.lax.broadcasted_iota(jnp.int32, cw.shape, 1)
+    col = jnp.sum(jnp.where(lane == e, cw, 0.0), axis=1, keepdims=True)
+    hidden = (g * jax.nn.sigmoid(g) * u * col).astype(z.dtype)  # [tt, tf]
+    o_ref[...] += jnp.dot(hidden, wd_ref[0, 0],
+                          preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("tag", "interpret"))
+def _call(z, cw, w_gate, w_up, w_down, layer, tag, interpret):
+    T, H = z.shape
+    _, E, _, F = w_gate.shape
+    dtype = w_gate.dtype
+    sub = 32 // dtype.itemsize                  # sublanes of one tile
+    tt = _TOKEN_TILE if T > _TOKEN_TILE else -(-T // sub) * sub
+    t_pad = -(-T // tt) * tt
+    tf = _f_tile(F)
+    zp = jnp.zeros((t_pad, H), dtype).at[:T].set(z.astype(dtype))
+    cwp = jnp.zeros((t_pad, _LANES), jnp.float32).at[:T, :E].set(
+        cw.astype(jnp.float32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(t_pad // tt, E, F // tf),
+        in_specs=[
+            pl.BlockSpec((tt, H), lambda t, e, f, l: (t, 0)),
+            pl.BlockSpec((tt, _LANES), lambda t, e, f, l: (t, 0)),
+            pl.BlockSpec((1, 1, H, tf), lambda t, e, f, l: (l[0], e, 0, f)),
+            pl.BlockSpec((1, 1, H, tf), lambda t, e, f, l: (l[0], e, 0, f)),
+            pl.BlockSpec((1, 1, tf, H), lambda t, e, f, l: (l[0], e, f, 0)),
+        ],
+        out_specs=pl.BlockSpec((tt, H), lambda t, e, f, l: (t, 0)),
+    )
+    out = pl.pallas_call(
+        _kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t_pad, H), jnp.float32),
+        cost_estimate=pl.CostEstimate(
+            flops=6 * t_pad * E * H * F,
+            bytes_accessed=((t_pad // tt) * 3 * E * H * F * dtype.itemsize
+                            + t_pad * H * (dtype.itemsize + 4)),
+            transcendentals=t_pad * E * F),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_top1_experts_" + tag,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), zp, cwp,
+      w_gate, w_up, w_down)
+    return out[:T]
+
+
+def _reference(z, cw, w_gate, w_up, w_down, layer=0, tag="decode"):
+    """The same sum in plain jnp (the numeric oracle and the path off the
+    chip): every expert over every token, weighted."""
+    del tag
+    wg = jax.lax.dynamic_index_in_dim(w_gate, layer, 0, keepdims=False)
+    wu = jax.lax.dynamic_index_in_dim(w_up, layer, 0, keepdims=False)
+    wd = jax.lax.dynamic_index_in_dim(w_down, layer, 0, keepdims=False)
+    zc = z.astype(wg.dtype)
+    g = jnp.einsum("th,ehf->etf", zc, wg, preferred_element_type=jnp.float32)
+    u = jnp.einsum("th,ehf->etf", zc, wu, preferred_element_type=jnp.float32)
+    hidden = g * jax.nn.sigmoid(g) * u * cw.astype(jnp.float32).T[:, :, None]
+    return jnp.einsum("etf,efh->th", hidden.astype(wg.dtype), wd,
+                      preferred_element_type=jnp.float32)
+
+
+def _workbench_register():
+    from . import workbench
+
+    return workbench.register_kernel(
+        "moe_top1_experts",
+        reference=_reference,
+        supported=lambda z, w: experts_supported(z, w, jnp.bfloat16),
+        decision_op="moe_experts",
+        equivalence_test="test_moe_experts_pallas_matches_reference",
+        note="top-1 SwiGLU experts over stacked [L, E, ...] weights; layer "
+             "index by scalar prefetch, every held expert streamed once")
+
+
+@_workbench_register()
+def moe_top1_experts(z, cw, w_gate, w_up, w_down, layer=0, tag="decode"):
+    """z [T, H], cw [T, E] (combine weight of token t for held expert e),
+    weights `[L, E, H, F]`, `[L, E, H, F]`, `[L, E, F, H]`, `layer` a scalar
+    int. Returns float32 [T, H]. Callers gate on `experts_supported`."""
+    return _call(z, cw, w_gate, w_up, w_down, jnp.asarray(layer, jnp.int32),
+                 str(tag), bool(INTERPRET))

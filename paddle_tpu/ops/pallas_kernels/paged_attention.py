@@ -52,20 +52,31 @@ INTERPRET = False
 _LANES = 128
 
 
-def paged_supported(q_shape, pool_shape) -> bool:
+def paged_supported(q_shape, pool_shape, pool_dtype=jnp.float32) -> bool:
     """Shapes this kernel handles: q [B, nh, dh] against a pool
-    [num_pages, page_size, nh*dh]. A page slab must be whole (8, 128) tiles
-    (page_size a multiple of 8, nh*dh of 128), a head must sit inside one
-    128-lane register (dh a power of two up to 128), and the slab modest
-    enough to double-buffer in VMEM. Everything else (the CPU rehearsal
-    geometry, most unit tests) takes the XLA path on the same pool."""
+    [num_pages, page_size, nkv*dh] of `pool_dtype`. A page slab must be
+    whole tiles ((8, 128) of float32, (16, 128) of bfloat16: page_size a
+    multiple of the sublane count, the row of 128) and modest enough to
+    double-buffer in VMEM. With nkv == nh a head must sit inside one
+    128-lane register (dh a power of two up to 128); with fewer KV heads
+    than query heads (grouped-query) a head is one register (dh 128), the
+    query heads fill whole sublane tiles and a page fills whole lanes of
+    the score tile. Everything else (the CPU rehearsal geometry, most unit
+    tests) takes the XLA path on the same pool."""
     if len(q_shape) != 3 or len(pool_shape) != 3:
         return False
     B, nh, dh = q_shape
     num_pages, ps, width = pool_shape
-    return (nh * dh == width and width % _LANES == 0 and ps % 8 == 0
-            and 8 <= dh <= _LANES and dh & (dh - 1) == 0
-            and ps * width * 4 <= 2 * 1024 * 1024)
+    itemsize = jnp.dtype(pool_dtype).itemsize
+    if itemsize not in (2, 4):
+        return False
+    tiled = (width % _LANES == 0 and ps % (32 // itemsize) == 0
+             and ps * width * itemsize <= 2 * 1024 * 1024)
+    if nh * dh == width:
+        return tiled and 8 <= dh <= _LANES and dh & (dh - 1) == 0
+    nkv = width // dh if dh else 0
+    return (tiled and dh == _LANES and nkv * dh == width and nkv > 0
+            and nh % nkv == 0 and nh % 8 == 0 and ps % _LANES == 0)
 
 
 def _head_sums(x, head_dim):
@@ -128,11 +139,119 @@ def _decode_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
+def _gqa_decode_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
+                       m_ref, l_ref, acc_ref, *, sm_scale, page_size,
+                       num_pages_p, num_kv_heads):
+    """Grouped-query twin of `_decode_kernel`: `nh` query heads (sublanes
+    of one [nh, dh] tile) over `nkv` KV heads of dh = 128 lanes. A head is
+    a whole register here, so q.k and p.v are MXU products in float32, one
+    KV head at a time over ALL query rows; each row keeps the product of
+    its own group (the others cost a few hundred cycles and no byte)."""
+    b = pl.program_id(0)
+    p = pl.program_id(1)
+    nh, dh = q_ref.shape[1], q_ref.shape[2]
+    group = nh // num_kv_heads
+
+    @pl.when(p == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # pages past the row's context repeat its last page (the index map is
+    # clamped, so nothing is fetched) and compute nothing
+    @pl.when(p * page_size < kl_ref[b])
+    def _page():
+        q = q_ref[0].astype(jnp.float32) * sm_scale              # [nh, dh]
+        row_kv = jax.lax.broadcasted_iota(
+            jnp.int32, (nh, page_size), 0) // group
+        nt = (((1,), (1,)), ((), ()))
+        s = jnp.zeros((nh, page_size), jnp.float32)
+        for j in range(num_kv_heads):
+            k = k_ref[0, :, pl.ds(j * dh, dh)].astype(jnp.float32)
+            sj = jax.lax.dot_general(
+                q, k, nt, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)              # [nh, ps]
+            s = jnp.where(row_kv == j, sj, s)
+        slot = p * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (nh, page_size), 1)
+        s = jnp.where(slot < kl_ref[b], s, _NEG_INF)
+        m_prev = m_ref[...]                                      # [nh, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        pexp = jnp.exp(s - m_new[:, :1])                         # [nh, ps]
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=1,
+                                                   keepdims=True)
+        row_kv_d = jax.lax.broadcasted_iota(jnp.int32, (nh, dh), 0) // group
+        pv = jnp.zeros((nh, dh), jnp.float32)
+        for j in range(num_kv_heads):
+            v = v_ref[0, :, pl.ds(j * dh, dh)].astype(jnp.float32)
+            pvj = jnp.dot(pexp, v, precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)    # [nh, dh]
+            pv = jnp.where(row_kv_d == j, pvj, pv)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_new
+
+    @pl.when(p == num_pages_p - 1)
+    def _emit():
+        # a padded row (kv_len 0) ran no page: it emits zeros
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _gqa_call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
+    B, nh, dh = q.shape
+    num_pages, ps, width = k_pool.shape
+    P = page_table.shape[1]
+    nkv = width // dh
+    # a grid step past the row's last page names that page again: pallas
+    # skips the DMA of a block whose index did not change
+    last = jnp.clip((kv_lens - 1) // ps, 0, P - 1)
+    cols = jnp.minimum(jnp.arange(P, dtype=jnp.int32)[None, :],
+                       last[:, None])
+    page_table = jnp.take_along_axis(page_table, cols, axis=1)
+    kernel = functools.partial(_gqa_decode_kernel, sm_scale=float(sm_scale),
+                               page_size=ps, num_pages_p=P, num_kv_heads=nkv)
+    row = pl.BlockSpec((1, nh, dh), lambda b, p, pt, kl: (b, 0, 0))
+    page = pl.BlockSpec((1, ps, width),
+                        lambda b, p, pt, kl: (pt[b, p], 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, P),
+        in_specs=[row, page, page],
+        out_specs=row,
+        scratch_shapes=[
+            pltpu.VMEM((nh, _LANES), jnp.float32),   # running max
+            pltpu.VMEM((nh, _LANES), jnp.float32),   # running denominator
+            pltpu.VMEM((nh, dh), jnp.float32),       # running numerator
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, nh, dh), jnp.float32),
+        cost_estimate=pl.CostEstimate(
+            flops=B * nh * 2 * 2 * P * ps * dh,
+            bytes_accessed=(2 * B * P * ps * width * k_pool.dtype.itemsize
+                            + 2 * B * nh * dh * 4),
+            transcendentals=B * P * ps * nh),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="paged_decode_attention_gqa",
+    )(page_table, kv_lens, q.astype(jnp.float32), k_pool, v_pool)
+    return out.astype(q.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
     B, nh, dh = q.shape
     num_pages, ps, width = k_pool.shape
     P = page_table.shape[1]
+    if nh * dh != width:
+        page_table = jnp.clip(page_table, 0, num_pages - 1).astype(jnp.int32)
+        return _gqa_call(q, k_pool, v_pool, page_table,
+                         kv_lens.astype(jnp.int32), sm_scale, interpret)
     # clamp so a padded/garbage table entry DMAs a real page (its slots are
     # masked by kv_lens anyway) instead of reading out of bounds
     page_table = jnp.clip(page_table, 0, num_pages - 1).astype(jnp.int32)
@@ -195,7 +314,8 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, kv_lens,
     """One decode step of ragged paged attention.
 
     q: [B, nh, dh] (this step's query per request row);
-    k_pool/v_pool: [num_pages, page_size, nh*dh] (the preallocated pool);
+    k_pool/v_pool: [num_pages, page_size, nkv*dh] (the preallocated pool;
+    nkv == nh, or fewer KV heads than query heads: grouped-query);
     page_table: [B, P] int32 (row b's context lives in pages
     page_table[b, 0..ceil(kv_lens[b]/page_size))); kv_lens: [B] int32 valid
     slot counts. Returns [B, nh, dh] in q's dtype. Callers gate on

@@ -144,6 +144,7 @@ def register_kernel(name: str, *, reference, supported, decision_op,
 def all_kernels() -> dict[str, KernelSpec]:
     """Every registered kernel (import side effect: pulls in the kernel
     modules so their registrations run)."""
-    from . import attention, epilogue, paged_attention, short_attention  # noqa: F401
+    from . import (attention, epilogue, moe_experts,  # noqa: F401
+                   paged_attention, short_attention)
 
     return dict(_KERNELS)

@@ -13,7 +13,19 @@ Four pieces, one runtime:
                    windowed suffix-prefill+verify / ragged decode programs
                    over one explicit weight namespace (plus the dense
                    oracle for equivalence tests, the COW page-copy step,
-                   and the GSPMD tp annotations);
+                   and the GSPMD tp annotations). TWO block families,
+                   selected by `DecoderConfig.block`: `"post_ln"` (the
+                   default: BERT-base run causally; fields vocab_size,
+                   hidden_size, num_layers, num_heads, ffn_size,
+                   max_position, dtype) and `"cca_moe"` (ZAYA1's layer:
+                   compressed-convolutional grouped-query attention with
+                   carried convolution state, partial rotary, top-1 experts
+                   behind an MLP router, RMSNorm, tied head; adds
+                   num_kv_heads, attn_head_dim, num_experts,
+                   router_hidden_size, cca_time0/1, partial_rotary_factor,
+                   rope_theta, rms_norm_eps). The second keeps one state
+                   row a page beside the K/V pools and reports the experts
+                   it chose with every step's tokens (engine docstring);
   * `engine`     — the continuous-batching scheduler: admit/evict between
                    decode steps, copy-on-write prefix reuse, speculative
                    draft-verify decode (exact under greedy), backpressure
@@ -41,7 +53,7 @@ from .kv_cache import (OwnedPoolView, PagedKVPool, PrefixCache,
                        create_device_pools, pool_var_names)
 from .model import (DecoderConfig, build_decode_program,
                     build_full_forward_program, build_prefill_program,
-                    build_window_program, decoder_tiny)
+                    build_window_program, cca_moe_tiny, decoder_tiny)
 from .sampling import SamplingParams, sample_token
 from .fleet import (EngineReplica, FleetRequest, FleetRouter,
                     HandoffManager, KVLease, NoHealthyReplica,
@@ -53,7 +65,7 @@ __all__ = [
     "ServingEngine", "GenRequest", "ContinuousBatchingScheduler",
     "AdmissionRejected", "OwnedPoolView",
     "PagedKVPool", "PrefixCache", "pool_var_names", "create_device_pools",
-    "DecoderConfig", "decoder_tiny", "build_prefill_program",
+    "DecoderConfig", "decoder_tiny", "cca_moe_tiny", "build_prefill_program",
     "build_decode_program", "build_window_program",
     "build_full_forward_program", "SamplingParams", "sample_token",
     "ngram_draft",

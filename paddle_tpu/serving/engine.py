@@ -38,6 +38,23 @@ Multi-tenant machinery (ISSUE 11), three composable stages:
     (nh/tp) so TP decode resolves through the same swept verdicts as every
     other lever.
 
+Per-sequence state besides K/V (`cfg.stateful`, the "cca_moe" block): a
+layer continues a sequence at t from three tails of token t-1 that no K/V
+gives back. They live in the state pool, one row per PAGE and layer holding
+the state after that page's latest token (written by prefill at every page
+end and at its last token, by decode as it goes, copied by copy-on-write),
+so the row of a FULL page is final and a prefix hit on whole pages restores
+exactly the state it resumes from. One rule follows: a hit may never cover
+the prompt's last token (the "full hit" regime re-derives that slot from a
+state one token back, which no row holds), so it is cut back to the last
+whole page before it and that page re-runs as a suffix prefill
+(`serving.state.recomputed_tokens`). Speculation, tensor parallelism and
+the fleet handoff are refused for such a block at construction. A mixture
+of experts also reports, with every step's tokens, the expert each layer
+chose for every token it computed; the engine keeps them per page
+(`_page_routes`, the host twin of the pools) and hands a finished request
+its `routes`, which is what a reference needs to follow the same experts.
+
 Compile discipline (the PR 2 machinery doing serving duty):
   * prefill compiles once per prompt-length bucket (pow2 rounding); suffix
     prefill once per (suffix-bucket, page-bucket);
@@ -88,7 +105,7 @@ import dataclasses
 
 import numpy as np
 
-from .. import flags, unique_name
+from .. import flags, profiler, unique_name
 from .. import observability as obs
 from ..data_feeder import _round_up_pow2
 from ..executor import Executor, Scope
@@ -98,7 +115,8 @@ from ..resilience.faults import InjectedFault, fault_point
 from ..resilience.retry import serving_policy
 from . import model as sv_model
 from .kv_cache import (OwnedPoolView, PagedKVPool, PrefixCache,
-                       create_device_pools, pool_var_names)
+                       create_device_pools, create_stacked_pools,
+                       pool_var_names)
 from .sampling import SamplingParams, request_rng, sample_token
 
 __all__ = ["GenRequest", "ContinuousBatchingScheduler", "ServingEngine",
@@ -232,6 +250,9 @@ class GenRequest:
                            if deadline_s and deadline_s > 0 else None)
         self.t_first_token: float | None = None
         self.t_done: float | None = None
+        # mixture-of-experts blocks: [cache_len, layers] expert ids, one row
+        # per position whose K/V the engine computed, set when it finishes
+        self.routes = None
 
     @property
     def n_generated(self) -> int:
@@ -350,6 +371,17 @@ class ServingEngine:
         self._warm_ctx: int | None = None
         if self.draft_k < 0:
             raise ValueError(f"draft_k must be >= 0, got {self.draft_k}")
+        if self.cfg.stateful:
+            unsupported = [what for what, on in (
+                ("speculative decoding (draft_k > 0)", self.draft_k > 0),
+                ("tensor parallelism (tp > 1)", self.tp > 1),
+                ("a shared pool / the fleet handoff",
+                 shared_pool is not None or prefill_only)) if on]
+            if unsupported:
+                raise NotImplementedError(
+                    f"block {self.cfg.block!r} carries per-sequence state "
+                    f"in page rows; not supported with it yet: "
+                    + "; ".join(unsupported))
         retries = int(step_retries if step_retries is not None
                       else flags.get_flag("serving_step_retries"))
         self._retry = serving_policy(max_attempts=max(1, retries),
@@ -438,11 +470,22 @@ class ServingEngine:
         # every peer's context, so only the FIRST engine materializes them.
         # Identically-seeded startup runs make the weight re-init above a
         # bitwise no-op on a shared scope.
-        if not self._scope.has_var(pool_var_names(self.cfg.num_layers)[0][0]):
+        if self.cfg.stateful:
+            create_stacked_pools(self._scope, *sv_model._cca_pool_geometry(
+                self.cfg, self.pool_pages, self.page_size))
+        elif not self._scope.has_var(
+                pool_var_names(self.cfg.num_layers)[0][0]):
             create_device_pools(self._scope, self.cfg.num_layers,
                                 self.pool_pages, self.page_size,
                                 self.cfg.num_heads, self.cfg.head_dim,
                                 self.cfg.dtype)
+        # which expert each layer chose for the token in (page, slot): the
+        # host twin of the pools, for blocks that route
+        self._page_routes = None
+        if "routes" in self._decode_io:
+            self._page_routes = np.zeros(
+                (self.pool_pages, self.page_size, self.cfg.num_layers),
+                np.int8)
         self._prefill_run = self._exec_target(self._prefill_prog)
         self._decode_run = self._exec_target(self._decode_prog)
         self._window_run = self._exec_target(self._window_prog)
@@ -455,6 +498,7 @@ class ServingEngine:
         self._admit_seq = 0
         self.stats = {
             "prefills": 0, "decode_steps": 0, "decode_tokens": 0,
+            "decode_context_pages": 0,
             "preemptions": 0, "aborts": 0,
             "prefill_signatures": set(), "decode_signatures": set(),
             "peak_pages_in_use": 0, "occupancy_sum": 0.0, "occupancy_n": 0,
@@ -474,6 +518,9 @@ class ServingEngine:
             "ladder.cache_evict": 0, "ladder.shed": 0,
             # learned serving control (ISSUE 20)
             "control.applies": 0, "control.rewarmups": 0,
+            # per-sequence state rows and expert routing (ISSUE 25)
+            "state.restores": 0, "state.recomputed_tokens": 0,
+            "moe.experts_touched": 0, "moe.layer_steps": 0,
         }
         # the learned controller's per-engine epoch hook (ISSUE 20):
         # shadow by default — one perf_counter read per step until an
@@ -623,10 +670,11 @@ class ServingEngine:
                             sv_model.PAGES_FEED: pages,
                             sv_model.MASK_FEED: np.zeros((bb, 1),
                                                          np.float32)}
-                    self._exe.run(self._decode_run, feed=feed,
-                                  fetch_list=[self._decode_io["next_token"],
-                                              self._decode_io["logits"]],
-                                  scope=self._scope)
+                    outs = self._exe.run(
+                        self._decode_run, feed=feed,
+                        fetch_list=self._step_fetches(self._decode_io),
+                        scope=self._scope, return_numpy=False)
+                    np.asarray(outs[0])     # wait for the step
                 n += 1
         return n
 
@@ -791,6 +839,10 @@ class ServingEngine:
         The caller (the prefill replica) grants the lease over the
         returned page table before anything else moves."""
         req = self.requests[rid]
+        if self.cfg.stateful:
+            raise NotImplementedError(
+                f"block {self.cfg.block!r}: the handoff would have to move "
+                f"state rows with the pages")
         if req.state != RUNNING:
             raise ValueError(
                 f"request {rid} is {req.state}; only RUNNING (prefilled) "
@@ -1197,18 +1249,27 @@ class ServingEngine:
             self._shed_one()
 
     # -- supervision: retried dispatch, invariant audit, recovery -----------
-    def _dispatch(self, kind: str, target, feed, fetch_list):
+    def _dispatch(self, kind: str, target, feed, fetch_list, to_host=None):
         """Every compiled prefill/decode/window/COW step dispatches here:
         the serving_step_fail fault site, then the executor, under the
         serving RetryPolicy. Retrying a step is safe — the compiled
         programs write fixed KV slots derived from the feed, so attempt
         N+1 overwrites attempt N's partial effects exactly. Retry
         exhaustion raises _StepFailure; step() turns it into the recovery
-        pass."""
+        pass. `to_host` names, fetch by fetch, which results are copied to
+        the host (None for the others, which stay on the device unread);
+        without it every fetch is."""
         def attempt():
             fault_point("serving_step_fail")
-            return self._exe.run(target, feed=feed, fetch_list=fetch_list,
-                                 scope=self._scope)
+            outs = self._exe.run(target, feed=feed, fetch_list=fetch_list,
+                                 scope=self._scope,
+                                 return_numpy=to_host is None)
+            if to_host is None:
+                return outs
+            with profiler.stage_timer("pipeline.fetch"):
+                # blocks until the device has produced the step
+                return [np.asarray(o) if wanted else None
+                        for o, wanted in zip(outs, to_host)]
 
         def on_retry(n, exc):
             self._count("step_retries")
@@ -1369,6 +1430,7 @@ class ServingEngine:
                     # mapped at two ordinals)
                     if matched:
                         self.pool.share(matched)
+                    matched = self._cut_hit_for_state(req, matched)
             # +1: the decode step after prefill writes one more slot (the
             # ladder's lookahead-shrink rung drops the reservation to the
             # bare context; _ensure_writable then allocates on demand)
@@ -1403,6 +1465,77 @@ class ServingEngine:
             self._observe_host_seconds("serving.prefill", sp, fetch0)
             admitted += 1
         return admitted
+
+    def _cut_hit_for_state(self, req: GenRequest, matched: list) -> list:
+        """A block with state rows resumes from the row of the last WHOLE
+        page before the position it continues at, and the prompt's last
+        token must run (its logits are the first token): a hit that covers
+        it is cut back one page, the pins of the cut pages returned."""
+        if not self.cfg.stateful or not matched:
+            return matched
+        keep = (len(req.all_tokens) - 1) // self.page_size
+        if len(matched) > keep:
+            self.pool.release(matched[keep:])
+            self._count("state.recomputed_tokens",
+                        (len(matched) - keep) * self.page_size)
+            matched = matched[:keep]
+        if matched:
+            self._count("state.restores")
+        return matched
+
+    @staticmethod
+    def _step_fetches(io: dict, logits: str = "logits") -> list:
+        """The one fetch list of a step's program (it is part of the
+        executor's compile signature, so warm-up and serving share it): the
+        greedy token, the logits, and the experts chosen where the block
+        routes."""
+        return [io["next_token"], io[logits]] + (
+            [io["routes"]] if "routes" in io else [])
+
+    def _run_step(self, kind: str, target, io: dict, feed: dict,
+                  greedy: bool, logits: str = "logits") -> tuple:
+        """Dispatch one prefill / window / decode step; returns (next_token,
+        routes or None, logits or None) on the host. One compiled program
+        serves greedy and sampled rows: the logits are a device output of
+        every step and cross the host link (`[rows, V]` float32) only when
+        a sampler needs them."""
+        routed = "routes" in io
+        nxt, lg, *routes = self._dispatch(
+            kind, target, feed, self._step_fetches(io, logits),
+            to_host=[True, not greedy] + [True] * routed)
+        return nxt, (routes[0] if routed else None), lg
+
+    def _count_routed(self, per_expert) -> None:
+        for e in np.flatnonzero(per_expert):
+            obs.counter_inc("serving.moe.tokens", int(per_expert[e]),
+                            {"expert": str(e)})
+
+    def _note_routes(self, req: GenRequest, first: int, routes) -> None:
+        """Keep the experts chosen for `req`'s positions first.. (one row of
+        `routes` [n, layers] each) with the pages that hold their K/V, and
+        count the routed tokens per expert."""
+        g = first + np.arange(len(routes))
+        pages = np.asarray(req.pages, np.int64)[g // self.page_size]
+        self._page_routes[pages, g % self.page_size] = routes
+        self._count_routed(np.bincount(routes.ravel(),
+                                       minlength=self.cfg.num_experts))
+
+    def _note_decode_routes(self, rows: list, routes) -> None:
+        """One decode step's routes [rows, layers] (inside serving.accept):
+        kept per page as above, counted per expert, and how many distinct
+        experts each layer touched (what a kernel that skipped the others
+        would have to read; today's streams every held expert)."""
+        routes = np.asarray(routes)[:len(rows)]
+        ps = self.page_size
+        pages = [r.pages[r.cache_len // ps] for r in rows]
+        self._page_routes[pages, [r.cache_len % ps for r in rows]] = routes
+        L, E = self.cfg.num_layers, self.cfg.num_experts
+        per_layer = np.zeros((L, E), np.int64)
+        np.add.at(per_layer, (np.broadcast_to(np.arange(L), routes.shape),
+                              routes), 1)
+        self._count_routed(per_layer.sum(axis=0))
+        self._count("moe.experts_touched", int(np.count_nonzero(per_layer)))
+        self._count("moe.layer_steps", L)
 
     def _seq_bucket(self, n: int) -> int:
         return min(self.cfg.max_position, max(8, _round_up_pow2(n)))
@@ -1449,10 +1582,9 @@ class ServingEngine:
                         sv_model.START_FEED: np.asarray([req.cached_len],
                                                         np.int32),
                         sv_model.LEN_FEED: np.asarray([suf], np.int32)}
-            nxt, lg = self._dispatch(
-                "suffix_prefill", self._window_run, feed,
-                [self._window_io["next_token"],
-                 self._window_io["last_logits"]])
+            nxt, routes, lg = self._run_step(
+                "suffix_prefill", self._window_run, self._window_io, feed,
+                req.sampling.is_greedy, "last_logits")
             self.stats["prefill_signatures"].add(("suffix", sb, pb))
             self._count("prefill_tokens_computed", suf)
         else:
@@ -1468,14 +1600,16 @@ class ServingEngine:
                 feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
                         sv_model.PAGES_FEED: pages,
                         sv_model.LEN_FEED: np.asarray([n], np.int32)}
-            nxt, lg = self._dispatch(
-                "prefill", self._prefill_run, feed,
-                [self._prefill_io["next_token"],
-                 self._prefill_io["last_logits"]])
+            nxt, routes, lg = self._run_step(
+                "prefill", self._prefill_run, self._prefill_io, feed,
+                req.sampling.is_greedy, "last_logits")
             self.stats["prefill_signatures"].add((sb, pb))
             self._count("prefill_tokens_computed", n)
         self._count("prefills")
         with obs.span("serving.accept"):
+            if routes is not None:
+                self._note_routes(req, req.cached_len,
+                                  np.asarray(routes)[0, :n - req.cached_len])
             self._register_prefix(req)
             self._accept_token(req, self._first_token(req, nxt, lg))
 
@@ -1499,6 +1633,11 @@ class ServingEngine:
         if req.is_done() or len(req.all_tokens) >= self.cfg.max_position:
             if req in self._running:
                 self._running.remove(req)
+            if self._page_routes is not None:
+                g = np.arange(req.cache_len)
+                req.routes = self._page_routes[
+                    np.asarray(req.pages, np.int64)[g // self.page_size],
+                    g % self.page_size]
             self._release(req)
             req.state = FINISHED
             req.t_done = now
@@ -1530,6 +1669,8 @@ class ServingEngine:
         self._dispatch("cow", self._cow_run, {
             sv_model.COW_SRC_FEED: np.asarray([old], np.int32),
             sv_model.COW_DST_FEED: np.asarray([new[0]], np.int32)}, [])
+        if self._page_routes is not None:
+            self._page_routes[new[0]] = self._page_routes[old]
         self.pool.release([old])
         req.pages[ordinal] = new[0]
         self._count("cow_copies")
@@ -1621,15 +1762,17 @@ class ServingEngine:
                     sv_model.PAGES_FEED: pages, sv_model.MASK_FEED: mask}
         self._step_rows = len(rows)
         sp.note(rows=len(rows), bb=bb, pb=pb)
-        nxt, lg = self._dispatch(
-            "decode", self._decode_run, feed,
-            [self._decode_io["next_token"], self._decode_io["logits"]])
+        self._count("decode_context_pages",
+                    sum(r.cache_len // self.page_size + 1 for r in rows))
+        nxt, routes, lg = self._run_step(
+            "decode", self._decode_run, self._decode_io, feed,
+            all(r.sampling.is_greedy for r in rows))
         with obs.span("serving.accept"):
             nxt = np.asarray(nxt).reshape(-1)
             self._count("decode_steps")
             self.stats["decode_signatures"].add((bb, pb))
-            lg = None if all(r.sampling.is_greedy for r in rows) \
-                else np.asarray(lg)
+            if routes is not None:
+                self._note_decode_routes(rows, routes)
             for i, r in enumerate(rows):
                 if r.sampling.is_greedy:
                     t = int(nxt[i])

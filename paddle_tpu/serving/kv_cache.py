@@ -51,7 +51,9 @@ import heapq
 import jax.numpy as jnp
 
 __all__ = ["PagedKVPool", "PrefixCache", "OwnedPoolView", "pool_var_names",
-           "pool_shape", "create_device_pools", "declare_pool_vars"]
+           "pool_shape", "create_device_pools", "declare_pool_vars",
+           "STACKED_POOLS", "stacked_pool_shapes", "declare_stacked_pools",
+           "create_stacked_pools"]
 
 
 def pool_var_names(num_layers: int) -> list[tuple[str, str]]:
@@ -102,6 +104,36 @@ def create_device_pools(scope, num_layers: int, num_pages: int,
     for kn, vn in pool_var_names(num_layers):
         for name in (kn, vn):
             scope.set_var(name, jnp.zeros(shape, jnp.dtype(dtype)))
+
+
+# -- the stacked pools of a block family with per-sequence state -----------
+# (model.py "cca_moe"): all layers in ONE K, ONE V and ONE state buffer, a
+# layer's page p at row `l * num_pages + p`. The allocator below is the same
+# one: a page id names a K/V slab AND a state row in every layer.
+STACKED_POOLS = ("kv_cache.k", "kv_cache.v", "kv_cache.state")
+
+
+def stacked_pool_shapes(num_layers: int, num_pages: int, page_size: int,
+                        kv_width: int, state_width: int, dtype: str):
+    """[(name, shape, dtype)] of the three stacked pools. K and V rows are
+    `kv_width = num_kv_heads * head_dim` wide (`pool_shape`'s lane-dense
+    row); the state pool holds one float32 row a page: the state after the
+    page's latest token, final once the page is full."""
+    rows = int(num_layers) * int(num_pages)
+    kv = (rows, int(page_size), int(kv_width))
+    return [(STACKED_POOLS[0], kv, dtype), (STACKED_POOLS[1], kv, dtype),
+            (STACKED_POOLS[2], (rows, int(state_width)), "float32")]
+
+
+def declare_stacked_pools(block, *geometry) -> None:
+    for name, shape, dtype in stacked_pool_shapes(*geometry):
+        block.create_var(name=name, shape=list(shape), dtype=dtype,
+                         persistable=True, stop_gradient=True)
+
+
+def create_stacked_pools(scope, *geometry) -> None:
+    for name, shape, dtype in stacked_pool_shapes(*geometry):
+        scope.set_var(name, jnp.zeros(shape, jnp.dtype(dtype)))
 
 
 class PagedKVPool:

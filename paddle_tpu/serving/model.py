@@ -1,6 +1,23 @@
-"""Serving program builders: a small decoder-only transformer ("bert
-decoder" — BERT-base geometry, causal masking) expressed twice over ONE
-weight namespace:
+"""Serving program builders. TWO block families exist, and
+`DecoderConfig.block` selects one:
+
+  * `"post_ln"` (the default; every other field at its default is the "bert
+    decoder": BERT-base geometry, causal masking): post-LN, multi-head
+    attention, GELU FFN, learned positions (`max_position` rows). Fields:
+    vocab_size, hidden_size, num_layers, num_heads, ffn_size, max_position,
+    dtype.
+  * `"cca_moe"` (ZAYA1's layer, `ops/cca_moe_ops.py`): RMSNorm,
+    compressed-convolutional grouped-query attention in a latent with
+    carried convolution state, partial rotary, a top-1 mixture of SwiGLU
+    experts behind an MLP router, embedding tied to the head. It reads, on
+    top of the fields above (`ffn_size` is one expert's width,
+    `max_position` only the context cap): num_kv_heads, attn_head_dim,
+    num_experts, router_hidden_size, cca_time0/1, partial_rotary_factor,
+    rope_theta, rms_norm_eps. Its layers are ONE op scanned over weights
+    stacked `[L, ...]` and its pools are stacked too (`kv_cache.
+    STACKED_POOLS`, with one state row a page).
+
+Either family is expressed several times over ONE weight namespace:
 
   * `build_prefill_program` — whole-prompt forward (dense causal attention:
     with bucket padding on the right, every query position attends only to
@@ -29,9 +46,13 @@ from .. import layers as L
 from ..framework import default_main_program
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
-from .kv_cache import declare_pool_vars, pool_var_names
+from ..initializer import Constant, Normal, StackedNormal
+from ..ops import cca_moe_ops
+from .kv_cache import (STACKED_POOLS, declare_pool_vars,
+                       declare_stacked_pools, pool_var_names)
 
-__all__ = ["DecoderConfig", "decoder_tiny", "build_prefill_program",
+__all__ = ["DecoderConfig", "decoder_tiny", "cca_moe_tiny",
+           "build_prefill_program",
            "build_decode_program", "build_window_program",
            "build_full_forward_program", "apply_tp_annotations"]
 
@@ -57,15 +78,167 @@ class DecoderConfig:
     ffn_size: int = 3072
     max_position: int = 512
     dtype: str = "float32"
+    # which block family (see the module docstring); the fields below are
+    # read by "cca_moe" only
+    block: str = "post_ln"
+    num_kv_heads: int = 0          # 0: as many as num_heads
+    attn_head_dim: int = 0         # 0: hidden_size // num_heads
+    num_experts: int = 0
+    router_hidden_size: int = 0
+    cca_time0: int = 2
+    cca_time1: int = 2
+    partial_rotary_factor: float = 1.0
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+
+    def __post_init__(self):
+        if self.block not in ("post_ln", "cca_moe"):
+            raise ValueError(f"unknown DecoderConfig.block {self.block!r} "
+                             f"(post_ln | cca_moe)")
+        if self.block == "cca_moe":
+            if (self.cca_time0, self.cca_time1) != (2, 2):
+                raise ValueError(
+                    "block 'cca_moe' carries one token of convolution "
+                    "state: cca_time0 and cca_time1 must be 2")
+            if self.kv_heads != 2 or self.num_heads % 2:
+                raise ValueError(
+                    "block 'cca_moe' builds its values from two halves "
+                    "(this token, the last): num_kv_heads must be 2")
+            if self.num_experts < 1 or self.router_hidden_size < 1:
+                raise ValueError("block 'cca_moe' needs num_experts and "
+                                 "router_hidden_size")
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def stateful(self) -> bool:
+        """Whether a sequence carries state besides its K/V (one row a page
+        in the state pool)."""
+        return self.block == "cca_moe"
 
 
 def decoder_tiny() -> DecoderConfig:
     return DecoderConfig(vocab_size=97, hidden_size=32, num_layers=2,
                          num_heads=2, ffn_size=64, max_position=64)
+
+
+def cca_moe_tiny(**over) -> DecoderConfig:
+    """The "cca_moe" block at test size: 4 query heads over 2 KV heads of
+    8, 4 experts of width 32."""
+    kw = dict(vocab_size=97, hidden_size=32, num_layers=3, num_heads=4,
+              num_kv_heads=2, attn_head_dim=8, ffn_size=32, num_experts=4,
+              router_hidden_size=16, partial_rotary_factor=0.5,
+              rope_theta=5e6, max_position=64, block="cca_moe")
+    kw.update(over)
+    return DecoderConfig(**kw)
+
+
+# -- the "cca_moe" family ----------------------------------------------------
+
+
+def _cca_geometry(cfg: DecoderConfig) -> dict:
+    return {"num_heads": cfg.num_heads, "num_kv_heads": cfg.kv_heads,
+            "head_dim": cfg.head_dim,
+            "rotary_dim": int(cfg.head_dim * cfg.partial_rotary_factor),
+            "rope_theta": float(cfg.rope_theta),
+            "eps": float(cfg.rms_norm_eps)}
+
+
+def cca_state_width(cfg: DecoderConfig) -> int:
+    return cca_moe_ops.state_width(
+        cca_moe_ops.Geometry(**_cca_geometry(cfg)))
+
+
+def _cca_pool_geometry(cfg: DecoderConfig, num_pages: int, page_size: int):
+    return (cfg.num_layers, num_pages, page_size,
+            cfg.kv_heads * cfg.head_dim, cca_state_width(cfg), cfg.dtype)
+
+
+def _cca_param_specs(cfg: DecoderConfig) -> dict:
+    """name -> (shape, dtype, initializer) of every parameter, layers
+    stacked on the leading axis. Matrices are drawn at fan_in^-0.5, the two
+    projections back into the residual stream at 0.5x (attention) and 2x
+    (an expert's output is weighed by a probability of 0.1-0.4) of that, so
+    that both branches of every layer move the logits; the large ones are
+    in `cfg.dtype`, everything a norm, convolution or the router reads in
+    float32."""
+    L, H, F, E = cfg.num_layers, cfg.hidden_size, cfg.ffn_size, \
+        cfg.num_experts
+    nh, nkv, dh, R = cfg.num_heads, cfg.kv_heads, cfg.head_dim, \
+        cfg.router_hidden_size
+    lat, groups = (nh + nkv) * dh, nh + nkv
+    f32, big = "float32", cfg.dtype
+    near_one = Normal(1.0, 0.05)
+    return {
+        "dec.word_emb": ([cfg.vocab_size, H], big, Normal(0.0, 0.02)),
+        "dec.final_norm.scale": ([H], f32, near_one),
+        "attn_norm": ([L, H], f32, near_one),
+        "wqk": ([L, H, lat], big, Normal(0.0, H ** -0.5)),
+        "wv": ([L, H, nkv * dh], big, Normal(0.0, H ** -0.5)),
+        "wo": ([L, nh * dh, H], big, Normal(0.0, 0.5 * (nh * dh) ** -0.5)),
+        "conv0_w": ([L, lat, 2], f32, Normal(0.0, 0.5)),
+        "conv0_b": ([L, lat], f32, Normal(0.0, 0.02)),
+        "conv1_w": ([L, 2, groups, dh, dh], f32,
+                    Normal(0.0, (2 * dh) ** -0.5)),
+        "conv1_b": ([L, lat], f32, Normal(0.0, 0.02)),
+        "k_temp": ([L, nkv], f32, Normal(0.0, 0.1)),
+        "ffn_norm": ([L, H], f32, near_one),
+        "router_in_w": ([L, H, R], f32, Normal(0.0, H ** -0.5)),
+        "router_in_b": ([L, R], f32, Normal(0.0, 0.02)),
+        "router_gamma": ([L], f32, Normal(0.5, 0.1)),
+        "router_norm": ([L, R], f32, near_one),
+        "router_w1": ([L, R, R], f32, Normal(0.0, R ** -0.5)),
+        "router_b1": ([L, R], f32, Normal(0.0, 0.02)),
+        "router_w2": ([L, R, R], f32, Normal(0.0, R ** -0.5)),
+        "router_b2": ([L, R], f32, Normal(0.0, 0.02)),
+        "router_w3": ([L, R, E], f32, Normal(0.0, 2.0 * R ** -0.5)),
+        "router_b3": ([L, E], f32, Constant(0.0)),
+        "router_bias": ([L, E], f32, Normal(0.0, 0.01)),
+        "w_gate": ([L, E, H, F], big, StackedNormal(0.0, H ** -0.5)),
+        "w_up": ([L, E, H, F], big, StackedNormal(0.0, H ** -0.5)),
+        "w_down": ([L, E, F, H], big, StackedNormal(0.0, 2.0 * F ** -0.5)),
+    }
+
+
+def cca_param_name(key: str) -> str:
+    """The scope name of a `_cca_param_specs` key."""
+    return key if key.startswith("dec.") else "dec.layers." + key
+
+
+def _cca_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
+               page_size: int = 0, **feeds):
+    """Append the one `cca_moe_stack` op of a program; returns its outputs
+    (next_token, logits, routes)."""
+    helper = LayerHelper("cca_moe_stack")
+    specs = _cca_param_specs(cfg)
+    params = {key: helper.create_parameter(
+        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
+        for key, (shape, dtype, init) in specs.items()}
+    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
+              "FinalNorm": [params["dec.final_norm.scale"]],
+              "LayerParams": [params[k] for k in cca_moe_ops.LAYER_PARAMS],
+              "Experts": [params[k] for k in cca_moe_ops.EXPERT_PARAMS]}
+    inputs.update({slot: [var] for slot, var in feeds.items()})
+    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
+            for slot, dtype in (("NextToken", "int32"),
+                                ("Logits", "float32"), ("Routes", "int32"))}
+    if mode != "full":
+        declare_stacked_pools(default_main_program().global_block,
+                              *_cca_pool_geometry(cfg, num_pages, page_size))
+        for slot, name in zip(("KPool", "VPool", "SPool"), STACKED_POOLS):
+            inputs[slot] = [name]
+            outs[slot + "Out"] = [name]
+    helper.append_op("cca_moe_stack", inputs, outs,
+                     dict(_cca_geometry(cfg), mode=mode,
+                          num_pages=int(num_pages)))
+    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
+            "routes": outs["Routes"][0]}
 
 
 def _proj(x, size, name, act=None):
@@ -151,6 +324,19 @@ def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int):
     pos = L.data(name=POS_FEED, shape=[cfg.max_position], dtype="int32")
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     lens = L.data(name=LEN_FEED, shape=[], dtype="int32")
+    return dict(_FAMILY[cfg.block]["prefill"](cfg, num_pages, page_size, tok,
+                                              pos, pages, lens),
+                feeds=[TOK_FEED, POS_FEED, PAGES_FEED, LEN_FEED])
+
+
+def _cca_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
+    out = _cca_stack(cfg, "prefill", tok, pos, num_pages, page_size,
+                     PageTable=pages, Lens=lens)
+    return {"next_token": out["next_token"], "last_logits": out["logits"],
+            "routes": out["routes"]}
+
+
+def _post_ln_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
     declare_pool_vars(default_main_program().global_block, cfg.num_layers,
                       num_pages, page_size, cfg.num_heads, cfg.head_dim,
                       cfg.dtype)
@@ -162,9 +348,7 @@ def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int):
     last = helper.create_variable_for_type_inference(logits.dtype)
     helper.append_op("gather_token_logits",
                      {"X": [logits], "Lens": [lens]}, {"Out": [last]}, {})
-    nxt = _greedy(last)
-    return {"feeds": [TOK_FEED, POS_FEED, PAGES_FEED, LEN_FEED],
-            "next_token": nxt, "last_logits": last}
+    return {"next_token": _greedy(last), "last_logits": last}
 
 
 def _window_layer(x, i, cfg: DecoderConfig, pages, start, lens, tp: int):
@@ -224,6 +408,22 @@ def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     start = L.data(name=START_FEED, shape=[], dtype="int32")
     lens = L.data(name=LEN_FEED, shape=[], dtype="int32")
+    return dict(_FAMILY[cfg.block]["window"](cfg, num_pages, page_size, tp,
+                                             tok, pos, pages, start, lens),
+                feeds=[TOK_FEED, POS_FEED, PAGES_FEED, START_FEED, LEN_FEED])
+
+
+def _cca_window(cfg, num_pages, page_size, tp, tok, pos, pages, start, lens):
+    # suffix prefill only: the window restores the state row of the page
+    # before Start, so Start is a page boundary (no verify window)
+    out = _cca_stack(cfg, "window", tok, pos, num_pages, page_size,
+                     PageTable=pages, Start=start, Lens=lens)
+    return {"next_token": out["next_token"], "last_logits": out["logits"],
+            "routes": out["routes"]}
+
+
+def _post_ln_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
+                    lens):
     declare_pool_vars(default_main_program().global_block, cfg.num_layers,
                       num_pages, page_size, cfg.num_heads, cfg.head_dim,
                       cfg.dtype)
@@ -235,8 +435,7 @@ def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     last = helper.create_variable_for_type_inference(logits.dtype)
     helper.append_op("gather_token_logits",
                      {"X": [logits], "Lens": [lens]}, {"Out": [last]}, {})
-    return {"feeds": [TOK_FEED, POS_FEED, PAGES_FEED, START_FEED, LEN_FEED],
-            "next_token": _greedy(last),
+    return {"next_token": _greedy(last),
             "last_logits": last,
             "tokens": L.argmax(logits, axis=2),
             "logits": logits}
@@ -250,6 +449,23 @@ def build_cow_program(cfg: DecoderConfig, num_pages: int, page_size: int):
     per engine — COW cost is one tiny device step, not a recompile."""
     src = L.data(name=COW_SRC_FEED, shape=[], dtype="int32")
     dst = L.data(name=COW_DST_FEED, shape=[], dtype="int32")
+    _FAMILY[cfg.block]["cow"](cfg, num_pages, page_size, src, dst)
+    return {"feeds": [COW_SRC_FEED, COW_DST_FEED]}
+
+
+def _cca_cow(cfg, num_pages, page_size, src, dst):
+    # the page's K/V slab and its state row, in every layer
+    declare_stacked_pools(default_main_program().global_block,
+                          *_cca_pool_geometry(cfg, num_pages, page_size))
+    slots = dict(zip(("KPool", "VPool", "SPool"), STACKED_POOLS))
+    LayerHelper("cca_state_copy_page").append_op(
+        "cca_state_copy_page",
+        dict({k: [v] for k, v in slots.items()}, Src=[src], Dst=[dst]),
+        {k + "Out": [v] for k, v in slots.items()},
+        {"num_pages": int(num_pages)})
+
+
+def _post_ln_cow(cfg, num_pages, page_size, src, dst):
     declare_pool_vars(default_main_program().global_block, cfg.num_layers,
                       num_pages, page_size, cfg.num_heads, cfg.head_dim,
                       cfg.dtype)
@@ -259,7 +475,6 @@ def build_cow_program(cfg: DecoderConfig, num_pages: int, page_size: int):
             "kv_cache_copy_page",
             {"KPool": [kn], "VPool": [vn], "Src": [src], "Dst": [dst]},
             {"KPoolOut": [kn], "VPoolOut": [vn]}, {})
-    return {"feeds": [COW_SRC_FEED, COW_DST_FEED]}
 
 
 def build_decode_program(cfg: DecoderConfig, num_pages: int, page_size: int,
@@ -275,6 +490,17 @@ def build_decode_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     pos = L.data(name=POS_FEED, shape=[], dtype="int32")
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     mask = L.data(name=MASK_FEED, shape=[1], dtype="float32")
+    return dict(_FAMILY[cfg.block]["decode"](cfg, num_pages, page_size, tp,
+                                             tok, pos, pages, mask),
+                feeds=[TOK_FEED, POS_FEED, PAGES_FEED, MASK_FEED])
+
+
+def _cca_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
+    return _cca_stack(cfg, "decode", tok, pos, num_pages, page_size,
+                      PageTable=pages, Mask=mask)
+
+
+def _post_ln_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
     declare_pool_vars(default_main_program().global_block, cfg.num_layers,
                       num_pages, page_size, cfg.num_heads, cfg.head_dim,
                       cfg.dtype)
@@ -315,9 +541,7 @@ def build_decode_program(cfg: DecoderConfig, num_pages: int, page_size: int,
         x = _ln(L.elementwise_add(x, a), name + ".ln1")
         x = _ffn_block(x, cfg, name)
     logits = L.squeeze(_head(x, cfg), axes=[1])        # [B, V]
-    nxt = _greedy(logits)
-    return {"feeds": [TOK_FEED, POS_FEED, PAGES_FEED, MASK_FEED],
-            "next_token": nxt, "logits": logits}
+    return {"next_token": _greedy(logits), "logits": logits}
 
 
 # per-dim mesh-axis layout of the decoder's TP-sharded parameters
@@ -370,7 +594,28 @@ def build_full_forward_program(cfg: DecoderConfig):
     exactly (tests, and the debugging path for kernel mismatches)."""
     tok = L.data(name=TOK_FEED, shape=[cfg.max_position], dtype="int32")
     pos = L.data(name=POS_FEED, shape=[cfg.max_position], dtype="int32")
+    return dict(_FAMILY[cfg.block]["full"](cfg, tok, pos),
+                feeds=[TOK_FEED, POS_FEED])
+
+
+def _cca_full(cfg, tok, pos):
+    out = _cca_stack(cfg, "full", tok, pos)
+    return {"logits": out["logits"], "routes": out["routes"]}
+
+
+def _post_ln_full(cfg, tok, pos):
     x = _embed(tok, pos, cfg)
     for i in range(cfg.num_layers):
         x = _prefill_layer(x, i, cfg, None, None, write_cache=False)
-    return {"feeds": [TOK_FEED, POS_FEED], "logits": _head(x, cfg)}
+    return {"logits": _head(x, cfg)}
+
+
+# the body of each program, by block family: the `build_*` functions above
+# declare the feeds, which the families share, and hand over here
+_FAMILY = {
+    "post_ln": {"prefill": _post_ln_prefill, "window": _post_ln_window,
+                "cow": _post_ln_cow, "decode": _post_ln_decode,
+                "full": _post_ln_full},
+    "cca_moe": {"prefill": _cca_prefill, "window": _cca_window,
+                "cow": _cca_cow, "decode": _cca_decode, "full": _cca_full},
+}

@@ -34,7 +34,7 @@ DB_SCHEMA = 1
 
 __all__ = ["DB_SCHEMA", "TuningDB", "canonical_key", "conv_key",
            "attention_key", "bucket_key", "amp_key", "collective_key",
-           "epilogue_key", "embedding_key", "evidence"]
+           "epilogue_key", "embedding_key", "moe_experts_key", "evidence"]
 
 
 def evidence(measured: dict) -> dict:
@@ -95,6 +95,13 @@ def epilogue_key(kind: str, rows: int, channels: int, channel_pos: str,
     'bn' (apply given stats) or 'ln' (in-kernel row statistics)."""
     return (f"kind={kind} rows={rows} c={channels} ch={channel_pos} "
             f"act={act or 'identity'} res={int(bool(has_residual))}")
+
+
+def moe_experts_key(tokens: int, experts: int, hidden: int, ffn: int) -> str:
+    """Top-1 expert layer decisions (ops/pallas_kernels/moe_experts.py):
+    the token rows of one call against the `experts` x (hidden -> ffn ->
+    hidden) SwiGLU stack it holds."""
+    return f"t={tokens} e={experts} h={hidden} f={ffn}"
 
 
 def embedding_key(table: str, vocab: int, dim: int) -> str:

@@ -122,3 +122,34 @@ def test_serving_programs_compiled_for_v5e_move_no_pool(v5e_chip):
         compilation_cache.reset_cache()
     assert out["pool_sized_copies"] == {
         "decode": 0, "prefill": 0, "window": 0, "cow": 0}
+
+
+def test_cca_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
+    """The same four programs of the "cca_moe" block at ZAYA1-8B's widths
+    (benchmark/configs/zaya1_8b.json), two layers deep over a stacked pool
+    as large as the cell's (2 x 7,680 = 24 x 640 pages of 128 bfloat16
+    slots): Mosaic takes the expert kernel and the grouped-query paged
+    kernel at their real shapes, and the scanned layer carries the stacked
+    pools without a copy."""
+    import json
+    import os
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "zaya1_8b.json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = DecoderConfig(**dict(engine["config_kwargs"], num_layers=2))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        out = chip_smoke.pool_layout_phase(
+            cfg, page_size=engine["page_size"],
+            pool_pages=engine["pool_pages"] * 12, rows=64, device=v5e_chip)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert out["pool_sized_copies"] == {
+        "decode": 0, "prefill": 0, "window": 0, "cow": 0}

@@ -249,6 +249,7 @@ def check_kernel_registry() -> int:
         "attention": tuning.attention_key,
         "epilogue": tuning.epilogue_key,
         "conv2d": tuning.conv_key,
+        "moe_experts": tuning.moe_experts_key,
     }
     test_defs = []
     for path in glob.glob(os.path.join(REPO, "tests", "*.py")):

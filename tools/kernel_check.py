@@ -176,8 +176,60 @@ def _layer_norm_cases(spec):
                             (128 * 128, 3072, "relu"))]
 
 
+def _paged_gqa_cases(spec):
+    """The grouped-query arm of the paged kernel at the ZAYA1 geometry: 8
+    query heads over 2 KV heads of 128 in 128-token bfloat16 pages."""
+
+    def case(dtype):
+        B, nh, nkv, dh, ps, pages, P = 8, 8, 2, 128, 128, 96, 8
+        ks = jax.random.split(jax.random.PRNGKey(1), 4)
+        q = _rand(ks[0], (B, nh, dh), "float32")
+        kp = _rand(ks[1], (pages, ps, nkv * dh), dtype)
+        vp = _rand(ks[2], (pages, ps, nkv * dh), dtype)
+        table = jax.random.permutation(ks[3], pages)[:B * P].reshape(B, P)
+        lens = jnp.asarray([1024, 900, 513, 512, 129, 128, 1, 0], jnp.int32)
+        assert spec.supported(q.shape, kp.shape, dtype)
+        res = _compare(
+            lambda *a: spec.fn(*a, sm_scale=dh ** -0.5)[:7],
+            lambda *a: spec.reference(*a, sm_scale=dh ** -0.5)[:7],
+            (q, kp, vp, table.astype(jnp.int32), lens), 0, "float32")
+        # q.k and p.v are float32 on both sides whatever the pool holds; the
+        # reference's bfloat16 cast of q is the whole difference
+        res["tol"] = 2e-2 if dtype == "bfloat16" else 2e-5
+        res["ok"] = bool(res["finite"] and res["err"] <= res["tol"])
+        return res
+
+    return [(f"b8 nh8 nkv2 dh128 ps128 {dt} ragged",
+             lambda dt=dt: case(dt)) for dt in ("float32", "bfloat16")]
+
+
+def _moe_cases(spec):
+    """One layer of ZAYA1's experts (16 x 2048 -> 2048 -> 2048, bfloat16)
+    out of a stack of two, at a decode batch and at a prefill window."""
+
+    def case(tokens):
+        L, E, H, F = 2, 16, 2048, 2048
+        ks = jax.random.split(jax.random.PRNGKey(2), 6)
+        z = _rand(ks[0], (tokens, H), "float32")
+        wg = _rand(ks[1], (L, E, H, F), "bfloat16", H ** -0.5)
+        wu = _rand(ks[2], (L, E, H, F), "bfloat16", H ** -0.5)
+        wd = _rand(ks[3], (L, E, F, H), "bfloat16", F ** -0.5)
+        choice = jax.random.randint(ks[4], (tokens,), 0, E)
+        cw = jax.nn.one_hot(choice, E) * jax.random.uniform(
+            ks[5], (tokens, 1), minval=0.1, maxval=0.9)
+        assert spec.supported(z.shape, wg.shape)
+        return _compare(lambda *a: spec.fn(*a, 1),
+                        lambda *a: spec.reference(*a, 1),
+                        (z, cw, wg, wu, wd), 0, "bfloat16")
+
+    return [(f"t{t} e16 h2048 f2048 bf16 layer 1 of 2",
+             lambda t=t: case(t)) for t in (64, 300)]
+
+
 CASES = {
-    "attention_paged_decode": _paged_cases,
+    "attention_paged_decode": lambda spec: (_paged_cases(spec)
+                                            + _paged_gqa_cases(spec)),
+    "moe_top1_experts": _moe_cases,
     # bench_bert_long: b64 s512
     "attention_short_seq": lambda spec: _attention_cases(spec, 64, 512),
     # bench_bert_short: b128 s128
