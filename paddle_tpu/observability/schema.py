@@ -58,6 +58,11 @@ DECLARED: list[tuple] = [
      "pages of context the rows of a plain decode step attended over, "
      "summed over rows and steps (x a page's K+V bytes: what decode "
      "attention had to read in one layer)", ()),
+    ("serving.decode_grid_steps", COUNTER,
+     "grid steps of one layer's paged decode kernel (padded rows x page "
+     "blocks of the bucket), summed over plain decode steps the Pallas "
+     "arm served; 0 on the XLA path (decode_context_pages over this: live "
+     "pages a grid step)", ()),
     ("serving.preemptions", COUNTER,
      "requests preempted back to the waiting queue", ()),
     ("serving.aborts", COUNTER, "requests aborted", ()),

@@ -379,22 +379,46 @@ def _paged_attention_reference(q, k_pool, v_pool, page_table, kv_lens,
     return out.reshape(B, nh, dh).astype(q.dtype)
 
 
+def _paged_arm(q_shape, q_dtype, pool_shape, pool_dtype, bucket_pages, tp):
+    """(decided backend, the per-shard pool shape the Pallas kernel runs on
+    or None where the XLA gather serves the shape). Executability is
+    re-checked at the SAME per-shard shapes the decision saw (under tp > 1
+    the global q/pool head counts are not what a shard runs)."""
+    B, nh, dh = q_shape
+    backend, _tier = paged_attention_backend(
+        B, nh, bucket_pages * pool_shape[1], dh, q_dtype,
+        pool_shape=pool_shape, tp=tp, pool_dtype=pool_dtype)
+    shard_q, shard_pool = _shard_paged_shapes(q_shape, pool_shape, tp)
+    pallas = backend == "pallas_paged" and _pallas_paged_ok(
+        shard_q, shard_pool, pool_dtype)
+    return backend, shard_pool if pallas else None
+
+
+def paged_decode_grid_steps(q_shape, q_dtype, pool_shape, pool_dtype,
+                            bucket_pages, tp=1) -> int:
+    """Grid steps of ONE `paged_decode_attention_fn` call at this shape
+    (rows x page blocks of the Pallas kernel, per tp shard), 0 where the
+    XLA gather serves it: what the engine books as
+    `serving.decode_grid_steps`."""
+    from .pallas_kernels import paged_attention as ppa
+
+    _, shard_pool = _paged_arm(tuple(q_shape), q_dtype, tuple(pool_shape),
+                               pool_dtype, bucket_pages, tp)
+    if shard_pool is None:
+        return 0
+    return ppa.grid_steps(q_shape[0], bucket_pages, shard_pool[1],
+                          shard_pool[2], jnp.dtype(pool_dtype).itemsize)
+
+
 def paged_decode_attention_fn(q, k_pool, v_pool, page_table, kv_lens,
                               sm_scale=1.0, tp=1):
     """Dispatch per `paged_attention_backend`: the Pallas page-DMA kernel
     where it can run (and the tuner has not retired it for this shape), the
     XLA gather reference everywhere else — including when a swept-DB verdict
     names a kernel this platform cannot execute."""
-    B, nh, dh = q.shape
-    P, ps = page_table.shape[1], k_pool.shape[1]
-    backend, _tier = paged_attention_backend(B, nh, P * ps, dh, q.dtype,
-                                             pool_shape=k_pool.shape, tp=tp,
-                                             pool_dtype=k_pool.dtype)
-    # re-check executability at the SAME per-shard shapes the decision saw
-    # (under tp > 1 the global q/pool head counts are not what a shard runs)
-    shard_q, shard_pool = _shard_paged_shapes(q.shape, k_pool.shape, tp)
-    if backend == "pallas_paged" and _pallas_paged_ok(shard_q, shard_pool,
-                                                      k_pool.dtype):
+    backend, pallas_pool = _paged_arm(q.shape, q.dtype, k_pool.shape,
+                                      k_pool.dtype, page_table.shape[1], tp)
+    if pallas_pool is not None:
         from .pallas_kernels import paged_attention as ppa
 
         _note_dispatch("paged", backend, backend)

@@ -8,27 +8,39 @@ pages of the preallocated HBM pool. The XLA reference path
 dense [B, P*ps, nh, dh] tensor first — at long contexts that materialized
 gather IS the decode step's HBM bill. This kernel never materializes it:
 
-  * grid (batch row, page): the page index for each grid step comes from the
-    request's page table via scalar prefetch — the BlockSpec index_map reads
-    `page_table[b, p]` and DMAs exactly that [ps, nh*dh] page slab from the
-    pool, so HBM traffic is the used pages once, nothing else. The pool is
+  * grid (batch row, page BLOCK): a grid step covers `G` pages of one
+    row (`pages_per_grid_step`: the pages whose K + V slabs fit
+    `BLOCK_BYTES`, 16 at both serving geometries, never more than the page
+    bucket). A row's pages lie scattered in the pool, so no BlockSpec can
+    fetch a block: the pools stay in HBM (`pl.ANY`) and the step starts one
+    DMA per LIVE page and pool into one half of a two-block VMEM scratch,
+    each page index read from the request's page table (scalar prefetch).
+    The DMAs of the NEXT live block (this row's, or the first block of the
+    next row that has context) are started before the step waits for its
+    own, so 2 G copies are in flight while a block is computed. HBM traffic
+    is the used pages once, nothing else; a block past the row's context
+    costs one empty grid step (about 0.3 us on a v5e). The pool is
     lane-dense, `[num_pages, ps, nh*dh]` (serving/kv_cache.pool_shape): a
     slab is whole (8, 128) tiles in the pool's own row-major layout, so the
-    kernel's operand IS the resident buffer — no conversion before the call.
+    kernel reads the resident buffer — no conversion before the call.
   * the ragged part: rows in one batch have different context lengths
-    (`kv_lens`, also scalar-prefetched). Slots past a row's length are masked
-    to -1e30 inside the online-softmax update; rows the continuous-batching
-    scheduler padded in (kv_len 0) produce finite garbage nobody reads — the
+    (`kv_lens`, also scalar-prefetched). A block is computed in chunks of
+    `MXU_CHUNK_TOKENS` / `VPU_CHUNK_TOKENS` slots, one online-softmax update
+    a chunk (one max, one rescale of m, l, acc) over all its pages: slots
+    past a row's length are masked to -1e30, a chunk or block with no live
+    slot is branched around, and rows the continuous-batching scheduler
+    padded in (kv_len 0) run nothing and emit zeros nobody reads — the
     batch_mask convention from PR 2.
   * heads never leave the lanes: a token's row holds head h in lanes
-    h*dh..(h+1)*dh, q.k is one [ps, nh*dh] VPU product, and the per-head sum
-    is a butterfly of lane rotations inside each dh-lane segment that leaves
-    every lane holding its head's score. The online softmax state (m, l,
-    acc, each [1, nh*dh], the per-head statistics repeated over the head's
-    lanes) lives in VMEM scratch across the page steps of one row (grid dims
-    are ("parallel", "arbitrary")); the output block is written once, on
-    the row's last page step. float32 products and sums throughout: no MXU
-    pass, so nothing is rounded to bfloat16.
+    h*dh..(h+1)*dh. With as many KV heads as query heads, q.k is one
+    [tokens, nh*dh] VPU product a 128-lane column, and the per-head sum is
+    a butterfly of lane rotations inside each dh-lane segment that leaves
+    every lane holding its head's score: float32 products and sums, no MXU
+    pass. With fewer KV heads (grouped-query, dh = 128) a head is a whole
+    register and q.k, p.v are MXU products of float32 exactness (`_dot3`).
+    The online softmax state (m, l, acc; the per-head statistics repeated
+    over the head's lanes) lives in VMEM scratch across the blocks of one
+    row; the output block is written once, on the row's last grid step.
 
 Decode q is a single token per row, so there is no backward pass: the kernel
 is forward-only (serving never differentiates), which keeps it free of the
@@ -37,6 +49,7 @@ residual bookkeeping the short-seq training kernel needs.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -95,152 +108,286 @@ def _head_sums(x, head_dim):
     return x
 
 
-def _decode_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, sm_scale, page_size, num_pages_p,
-                   head_dim):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
+# VMEM one grid step's K + V pages may take, all together (the kernel keeps
+# two such blocks: the one it computes on and the next one's DMAs in
+# flight). Sixteen pages of a 64 KB grouped-query slab or of the 48 KB slab
+# of 12 heads of 64.
+BLOCK_BYTES = 2 * 1024 * 1024
+# Slots one online-softmax update covers: a block is computed in chunks, a
+# loop over the block's live chunks around ONE straight-line body (the body
+# is the kernel's code, and a decode program holds a copy for every layer:
+# unrolled chunk and column bodies made a decode program 4.5 MB larger and
+# a serving cell's warm set-up 9 s longer; my chip run, PR 26).
+# The MXU arm keeps four MXUs busy over eight 128-token pages. The VPU arm
+# pays its reductions and rescales once a chunk and column, so its chunk is
+# the whole block of 16 x 16 slots (64: 670 us a call where 256 takes 358).
+MXU_CHUNK_TOKENS = 1024
+VPU_CHUNK_TOKENS = 256
 
-    @pl.when(p == 0)
-    def _init():
+
+def pages_per_grid_step(bucket_pages: int, page_size: int, width: int,
+                        itemsize: int) -> int:
+    """G: how many pages of one row a grid step covers. The largest power
+    of two whose K + V slabs fit `BLOCK_BYTES`, and never more than the
+    page bucket. The kernel's grid and the engine's
+    `serving.decode_grid_steps` both come from here."""
+    fit = max(1, BLOCK_BYTES // (2 * page_size * width * itemsize))
+    return max(1, min(int(bucket_pages), 1 << (fit.bit_length() - 1)))
+
+
+def grid_steps(rows: int, bucket_pages: int, page_size: int, width: int,
+               itemsize: int) -> int:
+    """Grid steps of one call: rows x page blocks."""
+    group = pages_per_grid_step(bucket_pages, page_size, width, itemsize)
+    return rows * -(-bucket_pages // group)
+
+
+def _bf16_pieces(x, n):
+    """`n` bfloat16 arrays that sum to float32 `x`: three hold every bit
+    of a float32 mantissa."""
+    pieces = []
+    for _ in range(n):
+        piece = x.astype(jnp.bfloat16)
+        pieces.append(piece)
+        x = x - piece.astype(jnp.float32)
+    return pieces
+
+
+def _stack3(a):
+    """float32 `a` [m, k] as its three bfloat16 pieces one under the other,
+    [3m, k]: ONE pass of a stored tile through the MXU multiplies all
+    three (m a multiple of 8: whole float32 sublane tiles are stacked,
+    then rounded, which is exact)."""
+    return jnp.concatenate(
+        [p.astype(jnp.float32) for p in _bf16_pieces(a, 3)],
+        axis=0).astype(jnp.bfloat16)
+
+
+def _dot3(a3, b, dims):
+    """The float32 product of `a` (given as `_stack3(a)`) and the stored
+    tile `b`, contracting `dims`: bfloat16 x bfloat16 products are exact
+    in float32 and the MXU accumulates in float32, so the sum over the
+    pieces is the product `Precision.HIGHEST` computes, from the same
+    terms. A bfloat16 pool is one piece as it lies and passes the MXU once
+    (HIGHEST passes it six times, its lower pieces all zero); a float32
+    pool is split in three."""
+    pieces = [b] if b.dtype == jnp.bfloat16 else _bf16_pieces(b, 3)
+    out = sum(jax.lax.dot_general(a3, p, (dims, ((), ())),
+                                  preferred_element_type=jnp.float32)
+              for p in pieces)
+    m = a3.shape[0] // 3
+    return out[:m] + out[m:2 * m] + out[2 * m:]
+
+
+def _page_copy(start, pools, bufs, sem, src, half, slot):
+    """Start (or wait for) the K and the V DMA of pool page `src` into
+    slot `slot` of half `half` of the two VMEM blocks."""
+    for which, (pool, buf) in enumerate(zip(pools, bufs)):
+        copy = pltpu.make_async_copy(pool.at[src], buf.at[half, slot],
+                                     sem.at[which, half])
+        copy.start() if start else copy.wait()
+
+
+# The scalar arithmetic of a grid step, jitted for the reason given at
+# `_column_update`: every decode program traces it with the same types.
+# `lax.div`, not `//`: floor division of a traced integer lowers through
+# `sign`, a third of this kernel's lowering time.
+
+@functools.partial(jax.jit, static_argnames=("page_size", "group"))
+def _block_pages(kv_len, i, *, page_size, group):
+    """Live pages of block i of a row of `kv_len` slots: 0..group."""
+    pages = jax.lax.div(kv_len + (page_size - 1), page_size)
+    return jnp.maximum(jnp.minimum(pages - i * group, group), 0)
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "group"))
+def _step_ahead(kv_len, next_row, b, i, rows, *, page_size, group):
+    """(row, block, is there one) of the next live grid step after (b, i):
+    this row's next block, or block 0 of the next row that has context."""
+    more = (i + 1) * (group * page_size) < kv_len
+    b2 = jax.lax.select(more, b, next_row)
+    return (jnp.minimum(b2, rows - 1), jax.lax.select(more, i + 1, 0),
+            b2 < rows)
+
+
+def _fetch_block(pt_ref, kl_ref, nxt_ref, pools, bufs, sem, count_ref, b, i,
+                 *, page_size, group):
+    """Inside a live grid step (b, i): start the DMAs of the next live
+    block into the other half, wait for this block's, and return its half.
+    Only LIVE pages are ever copied: HBM traffic is the used pages, once.
+    The first live block of a call starts its own copies too (one loop, so
+    one copy of the DMA code), after zeroing the V blocks: a slot that is
+    never fetched then holds a zero or an older page, and its probability
+    is exactly 0."""
+    rows, blocks = pl.num_programs(0), pl.num_programs(1)
+    geometry = dict(page_size=page_size, group=group)
+    n = count_ref[0]
+    half = jax.lax.rem(n, 2)
+
+    @pl.when(n == 0)
+    def _first():
+        bufs[1][...] = jnp.zeros_like(bufs[1])
+
+    b2, i2, there = _step_ahead(kl_ref[b], nxt_ref[b], b, i, rows, **geometry)
+    own = _block_pages(kl_ref[b], i, **geometry)
+    unstarted = jax.lax.select(n == 0, own, 0)
+    ahead = jax.lax.select(there,
+                           _block_pages(kl_ref[b2], i2, **geometry), 0)
+    here, then = (b * blocks + i) * group, (b2 * blocks + i2) * group
+
+    def start(j, carry):
+        late = j >= unstarted             # a page of the block ahead
+        slot = j - jax.lax.select(late, unstarted, 0)
+        _page_copy(True, pools, bufs, sem,
+                   pt_ref[jax.lax.select(late, then, here) + slot],
+                   jax.lax.select(late, 1 - half, half), slot)
+        return carry
+    jax.lax.fori_loop(0, unstarted + ahead, start, 0)
+
+    def wait(j, carry):
+        _page_copy(False, pools, bufs, sem, pt_ref[here + j], half, j)
+        return carry
+    jax.lax.fori_loop(0, own, wait, 0)
+    count_ref[0] = n + 1
+    return half
+
+
+def _kernel(pt_ref, kl_ref, nxt_ref, q_ref, k_hbm, v_hbm, o_ref, m_ref,
+            l_ref, acc_ref, k_buf, v_buf, sem, count_ref, *, chunk_update,
+            chunk_tokens, page_size, group):
+    """One grid step: block i (pages i * group ..) of row b. `chunk_update`
+    is the arm's online-softmax update over one chunk of the block."""
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    kv_len = kl_ref[b]
+    first = i * (group * page_size)              # the block's first slot
+    chunk = math.gcd(group, max(1, chunk_tokens // page_size))  # pages
+    tokens = chunk * page_size
+
+    @pl.when((b == 0) & (i == 0))
+    def _call_start():
+        count_ref[0] = 0
+
+    @pl.when(i == 0)
+    def _row_start():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # One query token per row: the step is bound by the page DMA, not by
-    # FLOPs, so q.k and p.v stay on the VPU in float32, one 128-lane column
-    # of the [ps, nh*dh] slab at a time (whole heads: dh divides 128).
-    width = k_ref.shape[2]
-    slot = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (page_size, _LANES), 0)
-    # ragged mask: slot p*ps + j is live iff below this row's context
-    live = slot < kl_ref[b]
-    for c in range(0, width, _LANES):
-        col = pl.ds(c, _LANES)
-        q = q_ref[0, :, col].astype(jnp.float32) * sm_scale     # [1, 128]
-        k = k_ref[0, :, col].astype(jnp.float32)                # [ps, 128]
-        s = jnp.where(live, _head_sums(q * k, head_dim), _NEG_INF)
-        m_prev = m_ref[:, col]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new)                               # [ps, 128]
-        v = v_ref[0, :, col].astype(jnp.float32)
-        l_ref[:, col] = l_ref[:, col] * alpha + jnp.sum(
-            pexp, axis=0, keepdims=True)
-        acc_ref[:, col] = acc_ref[:, col] * alpha + jnp.sum(
-            pexp * v, axis=0, keepdims=True)
-        m_ref[:, col] = m_new
+    # a block past the row's context is neither fetched nor computed, nor
+    # is a chunk past it inside a live block
+    @pl.when(first < kv_len)
+    def _block():
+        half = _fetch_block(pt_ref, kl_ref, nxt_ref, (k_hbm, v_hbm),
+                            (k_buf, v_buf), sem, count_ref, b, i,
+                            page_size=page_size, group=group)
+        live = jnp.minimum(kv_len - first, group * page_size)
 
-    @pl.when(p == num_pages_p - 1)
+        def one_chunk(c, carry):
+            pages = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            chunk_update(q_ref, k_buf.at[half, pages], v_buf.at[half, pages],
+                         live - c * tokens, m_ref, l_ref, acc_ref)
+            return carry
+        jax.lax.fori_loop(0, jax.lax.div(live + (tokens - 1), tokens),
+                          one_chunk, 0)
+
+    @pl.when(i == pl.num_programs(1) - 1)
     def _emit():
-        # a padded row (kv_len 0) has every slot masked alike, so l > 0 and
-        # it emits the mean of whatever its table's pages hold — finite,
-        # and the scheduler's batch_mask guarantees nobody reads it
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-
-
-def _gqa_decode_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_ref, l_ref, acc_ref, *, sm_scale, page_size,
-                       num_pages_p, num_kv_heads):
-    """Grouped-query twin of `_decode_kernel`: `nh` query heads (sublanes
-    of one [nh, dh] tile) over `nkv` KV heads of dh = 128 lanes. A head is
-    a whole register here, so q.k and p.v are MXU products in float32, one
-    KV head at a time over ALL query rows; each row keeps the product of
-    its own group (the others cost a few hundred cycles and no byte)."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    nh, dh = q_ref.shape[1], q_ref.shape[2]
-    group = nh // num_kv_heads
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # pages past the row's context repeat its last page (the index map is
-    # clamped, so nothing is fetched) and compute nothing
-    @pl.when(p * page_size < kl_ref[b])
-    def _page():
-        q = q_ref[0].astype(jnp.float32) * sm_scale              # [nh, dh]
-        row_kv = jax.lax.broadcasted_iota(
-            jnp.int32, (nh, page_size), 0) // group
-        nt = (((1,), (1,)), ((), ()))
-        s = jnp.zeros((nh, page_size), jnp.float32)
-        for j in range(num_kv_heads):
-            k = k_ref[0, :, pl.ds(j * dh, dh)].astype(jnp.float32)
-            sj = jax.lax.dot_general(
-                q, k, nt, precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)              # [nh, ps]
-            s = jnp.where(row_kv == j, sj, s)
-        slot = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (nh, page_size), 1)
-        s = jnp.where(slot < kl_ref[b], s, _NEG_INF)
-        m_prev = m_ref[...]                                      # [nh, 128]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new[:, :1])                         # [nh, ps]
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=1,
-                                                   keepdims=True)
-        row_kv_d = jax.lax.broadcasted_iota(jnp.int32, (nh, dh), 0) // group
-        pv = jnp.zeros((nh, dh), jnp.float32)
-        for j in range(num_kv_heads):
-            v = v_ref[0, :, pl.ds(j * dh, dh)].astype(jnp.float32)
-            pvj = jnp.dot(pexp, v, precision=jax.lax.Precision.HIGHEST,
-                          preferred_element_type=jnp.float32)    # [nh, dh]
-            pv = jnp.where(row_kv_d == j, pvj, pv)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
-
-    @pl.when(p == num_pages_p - 1)
-    def _emit():
-        # a padded row (kv_len 0) ran no page: it emits zeros
+        # a padded row (kv_len 0) ran no block: it emits zeros
         o_ref[0] = (acc_ref[...]
                     / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _gqa_call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
-    B, nh, dh = q.shape
-    num_pages, ps, width = k_pool.shape
-    P = page_table.shape[1]
-    nkv = width // dh
-    # a grid step past the row's last page names that page again: pallas
-    # skips the DMA of a block whose index did not change
-    last = jnp.clip((kv_lens - 1) // ps, 0, P - 1)
-    cols = jnp.minimum(jnp.arange(P, dtype=jnp.int32)[None, :],
-                       last[:, None])
-    page_table = jnp.take_along_axis(page_table, cols, axis=1)
-    kernel = functools.partial(_gqa_decode_kernel, sm_scale=float(sm_scale),
-                               page_size=ps, num_pages_p=P, num_kv_heads=nkv)
-    row = pl.BlockSpec((1, nh, dh), lambda b, p, pt, kl: (b, 0, 0))
-    page = pl.BlockSpec((1, ps, width),
-                        lambda b, p, pt, kl: (pt[b, p], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=[row, page, page],
-        out_specs=row,
-        scratch_shapes=[
-            pltpu.VMEM((nh, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((nh, _LANES), jnp.float32),   # running denominator
-            pltpu.VMEM((nh, dh), jnp.float32),       # running numerator
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nh, dh), jnp.float32),
-        cost_estimate=pl.CostEstimate(
-            flops=B * nh * 2 * 2 * P * ps * dh,
-            bytes_accessed=(2 * B * P * ps * width * k_pool.dtype.itemsize
-                            + 2 * B * nh * dh * 4),
-            transcendentals=B * P * ps * nh),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-        name="paged_decode_attention_gqa",
-    )(page_table, kv_lens, q.astype(jnp.float32), k_pool, v_pool)
-    return out.astype(q.dtype)
+# The two arms' arithmetic, as jitted functions of values: a decode program
+# of every (rows, bucket) signature traces this kernel anew, and jit hands
+# the later ones the jaxpr of the first (the chunk's shapes do not depend on
+# rows or bucket). Traced inline, this kernel cost the 35 decode programs
+# of the grouped-query cell 6-8 s of every start (`setup_s` +12%; my chip
+# runs, PR 26).
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "head_dim"))
+def _column_update(q, k, v, n_live, m_prev, l_prev, acc_prev, *, sm_scale,
+                   head_dim):
+    """One 128-lane column of a chunk: `q` [1, 128], `k`, `v` [tokens, 128]
+    with the first `n_live` tokens live; returns the new (m, l, acc)."""
+    live = jax.lax.broadcasted_iota(jnp.int32, k.shape, 0) < n_live
+    s = jnp.where(live, _head_sums(
+        q.astype(jnp.float32) * sm_scale * k.astype(jnp.float32), head_dim),
+        _NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    pexp = jnp.exp(s - m_new)                                # [tokens, 128]
+    return (m_new,
+            l_prev * alpha + jnp.sum(pexp, axis=0, keepdims=True),
+            acc_prev * alpha + jnp.sum(pexp * v.astype(jnp.float32), axis=0,
+                                       keepdims=True))
+
+
+def _chunk_update(q_ref, k_ref, v_ref, n_live, m_ref, l_ref, acc_ref, *,
+                  sm_scale, head_dim):
+    """`k_ref`, `v_ref` [pages, ps, nh*dh]: a chunk whose first `n_live`
+    tokens are live. One query token per row: the step is bound by the page
+    DMAs and the VPU, not by FLOPs, so q.k and p.v stay on the VPU in
+    float32, one 128-lane column at a time (whole heads: dh divides 128; a
+    loop, so the code is one column's). float32 products and sums
+    throughout: no MXU pass, so nothing is rounded to bfloat16."""
+    pages, ps, width = k_ref.shape
+
+    def column(c, carry):
+        col = pl.ds(pl.multiple_of(c * _LANES, _LANES), _LANES)
+        m_ref[:, col], l_ref[:, col], acc_ref[:, col] = _column_update(
+            q_ref[0, :, col], k_ref[:, :, col].reshape(pages * ps, _LANES),
+            v_ref[:, :, col].reshape(pages * ps, _LANES), n_live,
+            m_ref[:, col], l_ref[:, col], acc_ref[:, col],
+            sm_scale=sm_scale, head_dim=head_dim)
+        return carry
+    jax.lax.fori_loop(0, width // _LANES, column, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "num_kv_heads"))
+def _gqa_update(q, k, v, n_live, m_prev, l_prev, acc_prev, *, sm_scale,
+                num_kv_heads):
+    """A chunk of the grouped-query arm: `q` [nh, dh], `k`, `v` [tokens,
+    nkv*dh] with the first `n_live` tokens live; returns the new (m, l,
+    acc). q.k and p.v are MXU products in float32 (`_dot3`), one KV head at
+    a time over ALL query rows; each row keeps the product of its own group
+    (the others cost no byte)."""
+    (nh, dh), tokens = q.shape, k.shape[0]
+    heads_per_kv = nh // num_kv_heads
+    q3 = _stack3(q.astype(jnp.float32) * sm_scale)              # [3nh, dh]
+    head = jax.lax.broadcasted_iota(jnp.int32, (nh, tokens), 0)
+    s = jnp.full((nh, tokens), _NEG_INF, jnp.float32)
+    for j in range(num_kv_heads):
+        sj = _dot3(q3, k[:, j * dh:(j + 1) * dh], ((1,), (1,)))
+        mine = (head >= j * heads_per_kv) & (head < (j + 1) * heads_per_kv)
+        s = jax.lax.select(mine, sj, s)                      # [nh, tokens]
+    live = jax.lax.broadcasted_iota(jnp.int32, (nh, tokens), 1) < n_live
+    s = jax.lax.select(live, s, jnp.full_like(s, _NEG_INF))
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)                          # [nh, 128]
+    pexp = jnp.exp(s - m_new[:, :1])
+    p3 = _stack3(pexp)                                       # [3nh, tokens]
+    head = jax.lax.broadcasted_iota(jnp.int32, (nh, dh), 0)
+    pv = jnp.zeros((nh, dh), jnp.float32)
+    for j in range(num_kv_heads):
+        pvj = _dot3(p3, v[:, j * dh:(j + 1) * dh], ((1,), (0,)))
+        mine = (head >= j * heads_per_kv) & (head < (j + 1) * heads_per_kv)
+        pv = jax.lax.select(mine, pvj, pv)                   # [nh, dh]
+    return (m_new, l_prev * alpha + jnp.sum(pexp, axis=1, keepdims=True),
+            acc_prev * alpha + pv)
+
+
+def _gqa_chunk_update(q_ref, k_ref, v_ref, n_live, m_ref, l_ref, acc_ref, *,
+                      sm_scale, num_kv_heads):
+    """Grouped-query twin of `_chunk_update`: `nh` query heads (sublanes
+    of one [nh, dh] tile) over `nkv` KV heads of dh = 128 lanes. A head is
+    a whole register here, so the chunk is one update on the MXU."""
+    pages, ps, width = k_ref.shape
+    m_ref[...], l_ref[...], acc_ref[...] = _gqa_update(
+        q_ref[0], k_ref[...].reshape(pages * ps, width),
+        v_ref[...].reshape(pages * ps, width), n_live, m_ref[...],
+        l_ref[...], acc_ref[...], sm_scale=sm_scale,
+        num_kv_heads=num_kv_heads)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
@@ -248,45 +395,65 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
     B, nh, dh = q.shape
     num_pages, ps, width = k_pool.shape
     P = page_table.shape[1]
-    if nh * dh != width:
-        page_table = jnp.clip(page_table, 0, num_pages - 1).astype(jnp.int32)
-        return _gqa_call(q, k_pool, v_pool, page_table,
-                         kv_lens.astype(jnp.int32), sm_scale, interpret)
-    # clamp so a padded/garbage table entry DMAs a real page (its slots are
-    # masked by kv_lens anyway) instead of reading out of bounds
-    page_table = jnp.clip(page_table, 0, num_pages - 1).astype(jnp.int32)
+    grouped = nh * dh != width
+    group = pages_per_grid_step(P, ps, width, k_pool.dtype.itemsize)
+    blocks = -(-P // group)
     kv_lens = kv_lens.astype(jnp.int32)
-    kernel = functools.partial(_decode_kernel, sm_scale=float(sm_scale),
-                               page_size=ps, num_pages_p=P, head_dim=dh)
-    row = pl.BlockSpec((1, 1, width), lambda b, p, pt, kl: (b, 0, 0))
-    page = pl.BlockSpec((1, ps, width),
-                        lambda b, p, pt, kl: (pt[b, p], 0, 0))
+    # clamp so a padded/garbage table entry names a real page; one row of
+    # the flat table is `blocks * group` entries
+    page_table = jnp.pad(
+        jnp.clip(page_table, 0, num_pages - 1).astype(jnp.int32),
+        ((0, 0), (0, blocks * group - P))).reshape(B * blocks * group)
+    # the next row after b that has any context (B: none)
+    has = jnp.where(kv_lens > 0, jnp.arange(B, dtype=jnp.int32), B)
+    nxt = jnp.concatenate([jax.lax.cummin(has[::-1])[::-1][1:],
+                           jnp.full((1,), B, jnp.int32)])
+    if grouped:
+        update = functools.partial(_gqa_chunk_update, sm_scale=float(sm_scale),
+                                   num_kv_heads=width // dh)
+        row_shape, lanes, out_dtype = (1, nh, dh), (nh, _LANES), jnp.float32
+    else:
+        update = functools.partial(_chunk_update, sm_scale=float(sm_scale),
+                                   head_dim=dh)
+        row_shape, lanes, out_dtype = (1, 1, width), (1, width), q.dtype
+    row = pl.BlockSpec(row_shape, lambda b, i, pt, kl, nx: (b, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=[row, page, page],
+        num_scalar_prefetch=3,
+        grid=(B, blocks),
+        in_specs=[row, pool, pool],
         out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((1, width), jnp.float32),   # running max
-            pltpu.VMEM((1, width), jnp.float32),   # running denominator
-            pltpu.VMEM((1, width), jnp.float32),   # running numerator
+            pltpu.VMEM(lanes, jnp.float32),             # running max
+            pltpu.VMEM(lanes, jnp.float32),             # running denominator
+            pltpu.VMEM(row_shape[1:], jnp.float32),     # running numerator
+            pltpu.VMEM((2, group, ps, width), k_pool.dtype),
+            pltpu.VMEM((2, group, ps, width), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),            # [K | V, half]
+            pltpu.SMEM((1,), jnp.int32),                # live blocks so far
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(
+            _kernel, chunk_update=update, page_size=ps, group=group,
+            chunk_tokens=MXU_CHUNK_TOKENS if grouped else VPU_CHUNK_TOKENS),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B,) + row_shape[1:], out_dtype),
         cost_estimate=pl.CostEstimate(
             flops=B * nh * 2 * 2 * P * ps * dh,
             bytes_accessed=(2 * B * P * ps * width * k_pool.dtype.itemsize
-                            + 2 * B * width * q.dtype.itemsize),
-            transcendentals=B * P * ps * width),
+                            + 2 * B * nh * dh * 4),
+            transcendentals=B * P * ps * (nh if grouped else width)),
+        # a block's DMAs are started one live block ahead, across rows:
+        # the grid runs in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="paged_decode_attention",
-    )(page_table, kv_lens, q.reshape(B, 1, width), k_pool, v_pool)
-    return out.reshape(B, nh, dh)
+        name="paged_decode_attention_gqa" if grouped
+        else "paged_decode_attention",
+    )(page_table, kv_lens, nxt,
+      q.reshape((B,) + row_shape[1:]).astype(out_dtype), k_pool, v_pool)
+    return out.reshape(B, nh, dh).astype(q.dtype)
 
 
 def _workbench_register():

@@ -111,6 +111,7 @@ from ..data_feeder import _round_up_pow2
 from ..executor import Executor, Scope
 from ..framework import Program, program_guard
 from ..observability.slo import hist_p99_above
+from ..ops import attention_ops
 from ..resilience.faults import InjectedFault, fault_point
 from ..resilience.retry import serving_policy
 from . import model as sv_model
@@ -486,6 +487,7 @@ class ServingEngine:
             self._page_routes = np.zeros(
                 (self.pool_pages, self.page_size, self.cfg.num_layers),
                 np.int8)
+        self._grid_steps_by_signature: dict[tuple[int, int], int] = {}
         self._prefill_run = self._exec_target(self._prefill_prog)
         self._decode_run = self._exec_target(self._decode_prog)
         self._window_run = self._exec_target(self._window_prog)
@@ -498,7 +500,7 @@ class ServingEngine:
         self._admit_seq = 0
         self.stats = {
             "prefills": 0, "decode_steps": 0, "decode_tokens": 0,
-            "decode_context_pages": 0,
+            "decode_context_pages": 0, "decode_grid_steps": 0,
             "preemptions": 0, "aborts": 0,
             "prefill_signatures": set(), "decode_signatures": set(),
             "peak_pages_in_use": 0, "occupancy_sum": 0.0, "occupancy_n": 0,
@@ -1734,6 +1736,25 @@ class ServingEngine:
         # outranks new arrivals under fcfs
         self._waiting.insert(0, req)
 
+    def _decode_grid_steps(self, bb: int, pb: int) -> int:
+        """Grid steps of one layer's paged decode call at the (rows, page
+        bucket) signature, 0 where the XLA gather serves it: the kernel's
+        own arithmetic, asked once a signature."""
+        steps = self._grid_steps_by_signature.get((bb, pb))
+        if steps is None:
+            cfg = self.cfg
+            pool = self._scope.find_var(
+                "kv_cache.k" if cfg.stateful
+                else pool_var_names(cfg.num_layers)[0][0])
+            # the "cca_moe" block attends with float32 queries whatever
+            # dtype its weights and pools have
+            steps = self._grid_steps_by_signature[(bb, pb)] = \
+                attention_ops.paged_decode_grid_steps(
+                    (bb, cfg.num_heads, cfg.head_dim),
+                    "float32" if cfg.stateful else cfg.dtype,
+                    pool.shape, pool.dtype, pb, tp=self.tp)
+        return steps
+
     def _decode_once(self, sp) -> bool:
         """One decode step under the open `serving.decode` span `sp`."""
         # ladder rung 1+ falls back to plain one-token decode: the verify
@@ -1764,6 +1785,7 @@ class ServingEngine:
         sp.note(rows=len(rows), bb=bb, pb=pb)
         self._count("decode_context_pages",
                     sum(r.cache_len // self.page_size + 1 for r in rows))
+        self._count("decode_grid_steps", self._decode_grid_steps(bb, pb))
         nxt, routes, lg = self._run_step(
             "decode", self._decode_run, self._decode_io, feed,
             all(r.sampling.is_greedy for r in rows))

@@ -153,3 +153,45 @@ def test_cca_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
         compilation_cache.reset_cache()
     assert out["pool_sized_copies"] == {
         "decode": 0, "prefill": 0, "window": 0, "cow": 0}
+
+
+@pytest.mark.parametrize("q_shape,pool,dtype,bucket", [
+    ((64, 12, 64), (3072, 16, 768), "float32", 32),
+    ((64, 8, 128), (24 * 640, 128, 256), "bfloat16", 16),
+], ids=["post_ln_P32", "cca_moe_P16_stacked"])
+def test_paged_decode_blocks_fit_their_vmem_budget_on_v5e(
+        v5e_chip, q_shape, pool, dtype, bucket):
+    """The blocked decode kernel alone at the serving cells' sizes (64 rows;
+    a bucket of 32 pages of 16 float32 slots, and of 16 pages of 128
+    bfloat16 slots over the stacked pool): a grid step covers 16 pages, its
+    K + V block stays under `BLOCK_BYTES` (the kernel holds two: 4 MB of
+    the chip's 16 MB of scoped VMEM), and Mosaic takes it with the pools
+    left in HBM."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    _, ps, width = pool
+    itemsize = jnp.dtype(dtype).itemsize
+    group = ppa.pages_per_grid_step(bucket, ps, width, itemsize)
+    assert group == 16
+    assert group * 2 * ps * width * itemsize <= ppa.BLOCK_BYTES
+    assert ppa.grid_steps(q_shape[0], bucket, ps, width, itemsize) \
+        == q_shape[0] * bucket // 16
+    sh = SingleDeviceSharding(v5e_chip)
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=sh) for shape, dt in (
+        (q_shape, jnp.float32), (pool, dtype), (pool, dtype),
+        ((q_shape[0], bucket), jnp.int32), ((q_shape[0],), jnp.int32))]
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(lambda *a: ppa._call(*a, 0.125, False)).lower(
+            *args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    assert pool_sized_copies(text, pool[0] * ps * width) == []

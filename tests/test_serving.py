@@ -68,42 +68,61 @@ def test_paged_attention_matches_dense_ragged_rows():
                                    rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("ps,nh,dh,lens", [
-    (16, 12, 64, [40, 200, 16, 1, 0, 97]),   # the serving cells' geometry
-    (8, 2, 64, [7, 12, 3, 1]),               # one 128-lane column
-    (8, 1, 128, [9, 0, 24]),                 # a head fills the register
-    (8, 8, 16, [5, 17]),                     # eight heads to a register
-], ids=["cell_12x64_p16", "2x64_p8", "1x128_p8", "8x16_p8"])
-def test_paged_attention_pallas_matches_reference(ps, nh, dh, lens):
+@pytest.mark.parametrize("ps,nh,dh,lens,bucket,block_pages", [
+    (16, 12, 64, [40, 200, 16, 1, 0, 97], None, None),  # the cells' widths
+    (8, 2, 64, [7, 12, 3, 1], None, None),       # one 128-lane column
+    (8, 1, 128, [9, 0, 24], None, None),         # a head fills the register
+    (8, 8, 16, [5, 17], None, None),             # eight heads to a register
+    # the serving cells' own geometry: 64-row buckets of 32 pages, two
+    # blocks of 16. A row that ends exactly on the block boundary (256), rows
+    # whose second block is all dead, a kv_len 0 row between live rows
+    (16, 12, 64, [256, 300, 0, 512, 1, 255, 257], 32, None),
+    (16, 12, 64, [40, 100, 0, 17], 8, None),     # a bucket smaller than G
+    # blocks of two pages over a bucket of five: a padded last block
+    (8, 2, 64, [40, 16, 0, 33, 9], 5, 2),
+    (8, 8, 16, [64, 0, 0, 1, 32], 8, 4),         # dead rows side by side
+], ids=["cell_12x64_p16", "2x64_p8", "1x128_p8", "8x16_p8",
+        "cell_P32_two_blocks", "bucket_below_G", "P5_blocks_of_2",
+        "P8_blocks_of_4"])
+def test_paged_attention_pallas_matches_reference(monkeypatch, ps, nh, dh,
+                                                  lens, bucket, block_pages):
     """The Pallas page-DMA kernel (interpret mode on the CPU mesh) ==
     the XLA gather reference to float32 rounding, on the lane-dense pool:
     ragged lengths, a row that ends on a page boundary, a row of one token
-    and a padded row (kv_len 0: finite, read by nobody)."""
+    and a padded row (kv_len 0: zeros, read by nobody). Table entries past
+    a row's live pages are out of range: nothing may fetch them."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
 
     B = len(lens)
-    P = max(1, max(-(-l // ps) for l in lens))
+    P = bucket or max(1, max(-(-l // ps) for l in lens))
     num_pages = B * P + 3
     _, _, kp, vp, pt_ = _scattered_pool(
         [max(l, 1) for l in lens], ps, nh, dh, num_pages=num_pages, seed=3)
+    table = np.full((B, P), num_pages + 7, np.int32)
+    for b, l in enumerate(lens):
+        n = -(-l // ps)
+        table[b, :n] = np.asarray(pt_)[b, :n]
+    table = jnp.asarray(table)
     q = jnp.asarray(_rand((B, nh, dh), 4))
     assert ppa.paged_supported(q.shape, kp.shape)
     kv = jnp.asarray(lens, np.int32)
-    ref = ao._paged_attention_reference(q, kp, vp, pt_, kv,
+    ref = ao._paged_attention_reference(q, kp, vp, table, kv,
                                         sm_scale=dh ** -0.5)
-    old = ppa.INTERPRET
-    ppa.INTERPRET = True
-    try:
-        out = ppa.paged_decode_attention(q, kp, vp, pt_, kv,
-                                         sm_scale=dh ** -0.5)
-    finally:
-        ppa.INTERPRET = old
+    monkeypatch.setattr(ppa, "INTERPRET", True)
+    if block_pages:
+        monkeypatch.setattr(ppa, "BLOCK_BYTES",
+                            block_pages * 2 * ps * nh * dh * 4)
+    assert ppa.pages_per_grid_step(P, ps, nh * dh, 4) == (
+        block_pages or min(P, 16))
+    out = ppa.paged_decode_attention(q, kp, vp, table, kv,
+                                     sm_scale=dh ** -0.5)
     out, ref = np.asarray(out), np.asarray(ref)
-    assert out.shape == (B, nh, dh) and np.all(np.isfinite(out))
+    assert out.shape == (B, nh, dh)
     live = np.asarray(lens) > 0
     np.testing.assert_allclose(out[live], ref[live], rtol=2e-6, atol=2e-6)
+    assert not out[~live].any()
 
 
 @pytest.mark.parametrize("nh,dh,ps,chosen", [
@@ -416,6 +435,48 @@ def test_decode_candidate_upgrades_via_tune(tmp_path, monkeypatch):
         assert {"xla", "pallas_paged"} <= set(entry["measured"])
     finally:
         pt.flags.set_flags({"serving_page_size": 16})
+
+
+@pytest.mark.parametrize("pallas", [True, False], ids=["pallas", "xla"])
+def test_decode_grid_steps_counts_rows_times_page_blocks(monkeypatch, pallas):
+    """`serving.decode_grid_steps` books, for every plain decode step the
+    Pallas arm serves, the grid of ONE layer's call, padded rows x page
+    blocks of the bucket (G from the kernel's own `pages_per_grid_step`),
+    and stays 0 where the XLA gather serves the same pool."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+    from paddle_tpu.serving import DecoderConfig
+
+    monkeypatch.setattr(ppa, "INTERPRET", pallas)
+    # blocks of two pages: a page's K + V is 2 x [8, 128] float32
+    monkeypatch.setattr(ppa, "BLOCK_BYTES", 2 * 2 * 8 * 128 * 4)
+    cfg = DecoderConfig(vocab_size=64, hidden_size=128, num_layers=1,
+                        num_heads=2, ffn_size=128, max_position=64)
+    eng = ServingEngine(cfg, page_size=8, pool_pages=32, max_inflight=4,
+                        seed=0)
+    eng.reset_stats()          # the registry's serving.* series start at 0
+    seen = []
+    run_step = eng._run_step
+
+    def spy(kind, target, io, feed, *args, **kwargs):
+        if kind == "decode":
+            seen.append(feed[sv_model.PAGES_FEED].shape)
+        return run_step(kind, target, io, feed, *args, **kwargs)
+
+    monkeypatch.setattr(eng, "_run_step", spy)
+    before = dict(ao.dispatch_counts())
+    for n in (5, 20, 33):
+        eng.submit(list(range(1, n + 1)), max_new_tokens=4)
+    eng.run_until_drained()
+    assert seen and max(pb for _, pb in seen) > 2     # several blocks a row
+    ran = {k: n - before.get(k, 0) for k, n in ao.dispatch_counts().items()
+           if k[0] == "paged" and n != before.get(k, 0)}
+    assert {k[2] for k in ran} == {"pallas_paged" if pallas else "xla"}
+    want = sum(bb * -(-pb // 2) for bb, pb in seen) if pallas else 0
+    assert eng.stats["decode_grid_steps"] == want
+    assert obs.snapshot()["counters"].get(
+        "serving.decode_grid_steps", 0) == want
+    assert eng.stats["decode_context_pages"] > 0
 
 
 # -- pool allocator ----------------------------------------------------------

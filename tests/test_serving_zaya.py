@@ -249,27 +249,43 @@ def test_moe_experts_pallas_matches_reference(dtype, monkeypatch):
         rtol=3e-2, atol=3e-2)
 
 
+@pytest.mark.parametrize("lens,bucket,layer", [
+    ([300, 128, 0], 4, 0),
+    # the cell's geometry: a bucket of 16 pages of 128 over a pool stacked
+    # [layers * pages, ...], read at layer 1's offset. bfloat16 pages make
+    # one block of 16, float32 pages two of 8: 1,024 tokens end exactly on
+    # the block (and chunk) boundary, the second block of three rows is all
+    # dead, and a kv_len 0 row sits between live rows
+    ([1792, 1024, 0, 700, 129, 1025], 16, 1),
+], ids=["P4", "cell_P16_stacked"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_grouped_query_paged_decode_pallas_matches_xla(dtype, monkeypatch):
+def test_grouped_query_paged_decode_pallas_matches_xla(dtype, lens, bucket,
+                                                       layer, monkeypatch):
     ppa = importlib.import_module(
         "paddle_tpu.ops.pallas_kernels.paged_attention")
     monkeypatch.setattr(ppa, "INTERPRET", True)
     rng = np.random.default_rng(13)
-    B, nh, nkv, dh, ps, pages, P = 3, 8, 2, 128, 128, 12, 4
+    B, P, nh, nkv, dh, ps = len(lens), bucket, 8, 2, 128, 128
+    pages = B * P          # a layer's pages
     dt = jnp.dtype(dtype)
     q = jnp.asarray(rng.standard_normal((B, nh, dh)), jnp.float32)
-    kp, vp = (jnp.asarray(rng.standard_normal((pages, ps, nkv * dh)), dt)
-              for _ in range(2))
-    table = jnp.asarray(rng.permutation(pages)[:B * P].reshape(B, P),
-                        jnp.int32)
-    lens = jnp.asarray([300, 128, 0], jnp.int32)
+    kp, vp = (jnp.asarray(rng.standard_normal(
+        ((layer + 1) * pages, ps, nkv * dh)), dt) for _ in range(2))
+    table = rng.permutation(pages).reshape(B, P).astype(np.int32) \
+        + layer * pages
+    for b, n in enumerate(lens):     # past the live pages: out of range
+        table[b, -(-n // ps):] = 2 ** 30
+    table, lens = jnp.asarray(table), jnp.asarray(lens, jnp.int32)
     assert ppa.paged_supported(q.shape, kp.shape, dt)
     assert not ppa.paged_supported(q.shape, (pages, 8, nkv * dh), dt)
+    assert ppa.pages_per_grid_step(P, ps, nkv * dh, dt.itemsize) == min(
+        P, 32 // dt.itemsize)
     got = ppa.paged_decode_attention(q, kp, vp, table, lens, dh ** -0.5)
     want = _paged_attention_reference(q, kp, vp, table, lens, dh ** -0.5)
     tol = 2e-2 if dt.itemsize == 2 else 1e-4
-    np.testing.assert_allclose(got[:2], want[:2], rtol=tol, atol=tol)
-    assert np.all(np.isfinite(np.asarray(got)))    # the padded row too
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+    assert not np.asarray(got)[~live].any()        # a padded row: zeros
 
 
 def test_bfloat16_engine_stays_inside_the_bfloat16_tolerances():
