@@ -4,7 +4,7 @@ One process, the two main paths, through the entry points a user calls, at
 the full width of BERT-base (depth kept too — it fits):
 
   * trainer — `transformer.bert_pretrain` + bf16 AMP Adam `minimize`, shaped
-    exactly as bench.py's headline cell (BERT_BASE, batch 128, seq 128),
+    exactly as the cell `bert_base.s128` (TRAINER_CFG, batch 128, seq 128),
     `Executor.run(startup)` then a handful of `Executor.run(main)` steps on
     one repeated seeded batch: every loss finite, the last below the first,
     a trained parameter resident on the chip;
@@ -33,6 +33,7 @@ seconds); the last stdout line is the result alone:
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import json
 import sys
@@ -42,7 +43,6 @@ import jax
 import numpy as np
 
 import paddle_tpu as pt
-from bench import BERT_BASE
 from paddle_tpu import compile_cache
 from paddle_tpu import observability as obs
 from paddle_tpu.models import transformer
@@ -61,6 +61,11 @@ from tools.pool_hlo import pool_sized_copies, serving_program_hlos
 # by more than that noise. A wrong page, mask or position moves the gap to
 # the scale of the logit spread (order 1).
 ORACLE_LOGIT_TOL = 0.05
+
+# BERT-base at its published widths, as benchmark/configs/bert_base.json
+# trains it: no dropout (a repeatable loss), no tp annotations
+TRAINER_CFG = dataclasses.replace(transformer.bert_base(), dropout=0.0,
+                                  use_tp=False)
 
 
 class SmokeFailure(RuntimeError):
@@ -298,8 +303,8 @@ def main() -> int:
           flush=True)
 
     phases = {}
-    cfg = transformer.TransformerConfig(**BERT_BASE)
-    phases["trainer"] = trainer_phase(cfg, batch=128, seq_len=128, steps=6)
+    phases["trainer"] = trainer_phase(TRAINER_CFG, batch=128, seq_len=128,
+                                      steps=6)
     _require(phases["trainer"]["param_platform"] == "tpu",
              "trained parameters are not on the TPU")
     print("trainer", json.dumps(phases["trainer"]), flush=True)
@@ -319,7 +324,7 @@ def main() -> int:
 
     if len(jax.devices()) >= 4:
         phases["trainer_dp4"] = trainer_phase(
-            cfg, batch=128, seq_len=128, steps=4, dp=4)
+            TRAINER_CFG, batch=128, seq_len=128, steps=4, dp=4)
         _require("bytes_in_use_grown" in phases["trainer_dp4"],
                  "the chips report no memory statistics")
         print("trainer_dp4", json.dumps(phases["trainer_dp4"]), flush=True)

@@ -1,6 +1,6 @@
 """Where the persistent XLA compile cache lives.
 
-Entry points (chip_smoke.py, bench.py, the tools/_*_ab.py mains,
+Entry points (chip_smoke.py, benchmark/run.py, tools/_mc_ab.py,
 tests/conftest.py) call `configure()` once before their first compile. The
 directory is part of a cache entry's key, so it must not move between runs:
 it is either whatever JAX_COMPILATION_CACHE_DIR says (jax reads that

@@ -89,15 +89,15 @@ _define("tuning_db", "",
         "file warns once and falls back to analytic — never an error")
 _define("tuning_measurements", "",
         "path of the append-only JSONL measurement store "
-        "(tuning/learned/store.py) the sweeps, A/B harnesses, bench rounds "
+        "(tuning/learned/store.py) tools/tune.py sweeps, tools/_mc_ab.py "
         "and explore probes append raw per-arm window timings to — the "
         "learned cost model's training set. Empty = derived from "
         "FLAGS_tuning_db (<db stem>.measurements.jsonl next to it); with "
         "no DB either, nothing records")
 _define("tuning_record", "auto",
         "measurement-store gate (tuning/learned/store.py): 'auto' "
-        "(default) records from the tools (tune.py sweeps, the A/B "
-        "harnesses) whenever a store path resolves but from the runtime "
+        "(default) records from the tools (tune.py sweeps, _mc_ab.py) "
+        "whenever a store path resolves but from the runtime "
         "only under tuning_mode sweep/explore; 'on' always records; 'off' "
         "never records")
 _define("tuning_model", "",
@@ -139,9 +139,6 @@ _define("op_callstack", True,
 _define("benchmark", False,
         "block on the device after every Executor.run for timing-accurate "
         "debugging (reference operator.cc:926)")
-_define("cpu_deterministic", False,
-        "request deterministic XLA reductions (maps to XLA determinism; "
-        "reference flags.cc:98)")
 _define("profiler_dir", "/tmp/paddle_tpu_profile",
         "default trace output directory for profiler.profiler()")
 # unified telemetry layer (observability/: registry, exporters, spans, SLO)
@@ -152,8 +149,7 @@ _define("obs_enable", True,
         "spans alongside every counter; OFF reduces the layer to the bare "
         "counter/gauge/stage accumulators (exactly the pre-ISSUE-13 cost — "
         "profiler.stage_counters() and the serving stats keep working "
-        "either way). bench.py measures the on-vs-off overhead on the "
-        "timed-window protocol; tools/gate.py --obs fails it above 2%")
+        "either way)")
 _define("obs_jsonl_dir", "",
         "directory for the JSONL telemetry stream: when set, every event "
         "and span record appends atomically to <dir>/obs.jsonl (rotated at "
@@ -186,7 +182,7 @@ _define("obs_slo_min_hit_rate", 0.0,
 _define("obs_slo_max_leaked_pages", 0,
         "SLO monitor: warn/alert when the serving.leaked_pages gauge "
         "exceeds this count (default 0 — any leak breaches, matching the "
-        "gate's zero-leak invariant)")
+        "tests' zero-leak invariant)")
 # multichip collective-overlap knobs (parallel/collective.py, sharding.py,
 # pipeline.py — the measured scaling campaign, see README "Multichip")
 _define("allreduce_bucket_mb", 4.0,

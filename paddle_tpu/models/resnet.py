@@ -61,7 +61,7 @@ def fold_stem_to_s2d(w7, data_format="NCHW"):
     Derivation: y[o] = sum_u w[u] x[2o-3+u]; n = 2(o+j)+p gives 2j+p = u-3,
     j in [-2,1] -> 4 taps with spatial padding (2, 1). Measured on TPU v5e:
     widening the stem contraction 3->12 is +1.3 MFU points end-to-end
-    (tools/_rn_s2d.py, PERF.md r5).
+    (a round-5 probe, no ledger line).
 
     data_format: layout of the TARGET model's stem parameter — "NCHW"
     returns OIHW [64, 12, 4, 4]; "NHWC" returns HWIO [4, 4, 12, 64] (NHWC
@@ -91,7 +91,7 @@ def resnet(img, depth=50, num_classes=1000, s2d_stem=False,
 
     data_format: "NHWC" keeps the whole activation chain channels-last —
     on TPU v5e the s2d stem win measures 2.3 ms in NHWC vs 0.6 ms in NCHW
-    (tools/_rn_s2d.py vs /tmp probes, PERF.md r5)."""
+    (round-5 probes, no ledger line)."""
     kind, layers_per_stage = _DEPTH_CFG[depth]
     fmt = data_format
     block = _basic_block if kind == "basic" else _bottleneck_block
@@ -106,7 +106,7 @@ def resnet(img, depth=50, num_classes=1000, s2d_stem=False,
             x = L.transpose(x, [0, 1, 3, 2, 4, 5])
             x = L.reshape(x, [n, h // 2, w // 2, 4 * c])
         # asymmetric (2,1) padding folded INTO the conv: a separate pad op
-        # measures 2.4x slower on TPU (XLA does not fold it, tools/_rn_s2d.py)
+        # measures 2.4x slower on TPU (XLA does not fold it; a round-5 probe)
         x = L.conv2d(x, num_filters=64, filter_size=4, stride=1,
                      padding=[2, 1, 2, 1], bias_attr=False, name="stem",
                      data_format=fmt)

@@ -4,7 +4,7 @@ The reference keeps its data layer in C++ (data_feed.cc, data_set.cc); here
 the hot MultiSlot text parser is C compiled at first use with the system
 compiler. The binding has a pure-Python fallback so the framework still
 works without a toolchain, at a much slower ingest: falling back warns, and
-the benches that measure ingest assert `native_available()`.
+`native_available()` says which one a process got.
 """
 from __future__ import annotations
 

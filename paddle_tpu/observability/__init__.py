@@ -6,7 +6,7 @@ guardrail events, tuner provenance — all migrate onto the typed
 thread-safe registry here. `registry.py` is the spine (counters, gauges,
 streaming-percentile histograms, labeled series, events, spans,
 atomic `snapshot(reset=True)`), `schema.py` declares every permitted
-metric name (tools/gate.py --obs lints drift), `exporters.py` ships it
+metric name (tests/test_observability.py lints drift), `exporters.py` ships it
 (rotating atomic JSONL, Prometheus text, /metrics endpoint) and `slo.py`
 watches it (rolling-window thresholds -> warn/alert callbacks).
 

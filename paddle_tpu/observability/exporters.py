@@ -16,8 +16,8 @@ Three consumers, three formats:
     /metrics from a stdlib daemon thread (FLAGS_obs_http_port).
 
 `parse_prometheus()` is the round-trip half: it parses the exposition
-text back to {series: value}, and tools/gate.py-adjacent tests use it to
-prove a live run's export is byte-for-byte parseable.
+text back to {series: value}; tests/test_observability.py and `tools/obs.py
+prom` use it to prove a live run's export is byte-for-byte parseable.
 """
 from __future__ import annotations
 

@@ -14,8 +14,7 @@ used to take five bespoke readers. Design points:
     per-(op, tier) provenance and the embedding engine's per-table
     counters stop being ad-hoc nested dicts;
   * declared schema: names are registered up front (schema.DECLARED);
-    free-form names still record but surface in `snapshot()["undeclared"]`
-    and tools/gate.py --obs fails on them;
+    free-form names still record but surface in `snapshot()["undeclared"]`;
   * spans nest: a per-thread stack gives every span its parent, its self
     time and the `step` of its root, and both ways to time host work
     (`span`, `profiler.stage_timer`) are the one `Span` class, a

@@ -1,10 +1,10 @@
 """The declared metric schema: every name the runtime is allowed to emit.
 
-One flat list, imported by the registry at construction and by
-tools/gate.py --obs at lint time. A metric recorded under a name that is
-not declared here still lands (post-mortems beat purity), but the registry
-tracks it in `snapshot()["undeclared"]` and the gate turns that into a
-hard failure — adding a counter is a schema act, not just a call site.
+One flat list, imported by the registry at construction. A metric recorded
+under a name that is not declared here still lands (post-mortems beat
+purity), but the registry tracks it in `snapshot()["undeclared"]` and
+tests/test_observability.py holds the runtime's own names to the list —
+adding a counter is a schema act, not just a call site.
 
 Kinds:
   stage     — the profiler.record_stage/bump accumulators ([events, seconds]

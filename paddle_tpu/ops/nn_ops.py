@@ -40,7 +40,7 @@ def _conv_pads(praw):
 # 157-162 TF/s sustained, HBM 476-522 GB/s; conv MXU efficiency ~0.7-0.75 of
 # the matmul ceiling at >=half lane fill). The model only has to rank two
 # lowerings of the SAME conv, so absolute calibration error mostly cancels;
-# tools/_rn_igemm.py is the end-to-end A/B that checks it per shape.
+# `tools/tune.py --what conv` measures both per shape.
 _IGEMM_MXU_FLOPS = 157e12
 _IGEMM_HBM_BPS = 450e9
 _IGEMM_MXU_EFF = 0.75
@@ -546,8 +546,8 @@ def conv2d_bn(ctx: ExecContext):
     statistics (passes.fuse_conv_bn_stats rewrites eligible pairs to this).
 
     The separate batch_norm op re-reads the conv output from HBM to reduce
-    E[x]/E[x^2] — measured at 17-35% of ResNet stage time (PERF.md r5,
-    tools/_rn_diag.py). Here both statistics are computed as siblings of the
+    E[x]/E[x^2] — measured at 17-35% of ResNet stage time (a round-5
+    probe, no ledger line). Here both statistics are computed as siblings of the
     conv's own result — on the implicit-GEMM path directly from the fp32 GEMM
     accumulator before the bf16 down-cast — so XLA's multi-output fusion can
     emit them in the producer's epilogue while the tile is still on-chip,

@@ -2,7 +2,7 @@
 
 XLA fuses most of the framework's ops well; these kernels exist for the
 cases where measurement (PERF.md) showed XLA leaving throughput on the
-table. The package is organized as a small kernel WORKBENCH (workbench.py):
+table. The package is organized as a small kernel WORKBENCH (module `workbench`):
 shared block-shape/VMEM helpers and a
 registry in which every kernel records its XLA reference, shape gate,
 tuning-DB decision op, and equivalence test — `tools/gate.py

@@ -160,7 +160,7 @@ class GradAllReduce(Collective):
         super().__init__(nrings)
         self.bucket_mb = bucket_mb
         self.zero1 = zero1
-        # introspection for tests/tools: [(insert_pos, [grad names])] of the
+        # introspection for tests and tools: [(insert_pos, [grad names])] of the
         # last transpile, plus the resolved size and its provenance tier
         self.last_buckets: list[tuple[int, list[str]]] = []
         self.resolved_bucket_mb: float | None = None

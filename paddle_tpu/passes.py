@@ -10,7 +10,7 @@ fusion alone cannot recover:
     (on the implicit-GEMM path: from the fp32 GEMM accumulator before the
     low-precision down-cast). The standalone batch_norm reads the conv
     output back from HBM for its E[x]/E[x^2] reductions — measured at
-    17-35% of ResNet-50 stage time (PERF.md r5, tools/_rn_diag.py).
+    17-35% of ResNet-50 stage time (a round-5 probe, no ledger line).
   * fuse_epilogue_act (ISSUE 9): norm -> relu and norm -> residual-add ->
     relu chains collapse into the norm op (attr `act`, input `Residual`),
     whose lowering then dispatches the WHOLE apply chain through the

@@ -6,7 +6,7 @@ TPU-native replacement for the reference's profiler stack:
   * CUPTI device tracer -> here the XLA runtime's own trace collection
     (/root/reference/paddle/fluid/platform/device_tracer.cc:272); the output
     is an XPlane protobuf directory loadable in TensorBoard/Xprof instead of
-    the reference's chrome://tracing JSON (tools/timeline.py).
+    the reference's chrome://tracing JSON (its timeline.py).
 
 The stage counters below are thin shims over the unified telemetry
 registry (observability/): record_stage/bump/stage_counters keep their PR 2
@@ -120,8 +120,9 @@ record_event = RecordEvent
 # -- pipeline stage counters --------------------------------------------------
 # Cheap always-on accumulators for the async feed/dispatch pipeline (host
 # ingest / device transfer / dispatch / window drain). Unlike the XPlane
-# trace these need no viewer: tools/_pipeline_ab.py and ad-hoc debugging read
-# them directly to see which stage the end-to-end path is losing time to.
+# trace these need no viewer: the benchmark's `stage_seconds` reader
+# (`host_dispatch_ms`, `host_prepare_ms`) and ad-hoc debugging read them
+# directly to see which stage the end-to-end path is losing time to.
 # Since ISSUE 13 the storage is the observability registry — same API, same
 # cost, but the counters ride the unified snapshot/export path too.
 

@@ -42,10 +42,9 @@ Four pieces, one runtime:
 Knobs: FLAGS_serving_page_size, FLAGS_serving_pool_pages,
 FLAGS_serving_max_inflight, FLAGS_serving_sched_policy,
 FLAGS_serving_prefix_cache, FLAGS_serving_draft_k, FLAGS_serving_tp (see
-README "Serving"). Load: tools/_serve_ab.py (open-loop arrival sweep incl.
-the --shared-prefix zipf mix + --ab baseline arm) and the bench.py
-`serving` block (served tokens/s, p50/p99 latency, pool occupancy, the
-three-arm shared_prefix A/B) gated by tools/gate.py.
+README "Serving"). Load: the serving cells of BENCHMARK.json
+(benchmark/runners/serve_open_loop.py drives an engine open loop from
+benchmark/traffic/open_loop.py's schedules).
 """
 from .engine import (AdmissionRejected, ContinuousBatchingScheduler,
                      GenRequest, ServingEngine, ngram_draft)

@@ -3,9 +3,10 @@
 The learned controller keys the measurement store the way the kernel tier
 keys it: `op="serving.control"`, a canonical `shape_key` naming the TRAFFIC
 REGIME, and the knob-config spelling as the arm. This module owns that
-spelling. Two producers write it:
+spelling. It is spelled in two ways:
 
-  * the offline sweep (`tools/_serve_ab.py --sweep-knobs`) spells the
+  * an offline sweep over knob arms (`workload_signals`; no script in the
+    tree runs one since PR 27, ROADMAP D3) spells the
     regime from the WORKLOAD INTENT (arrival rate, prompt-length
     percentiles, output budget) plus the runtime signals observed under
     the hand-flag reference pass — every knob arm of one regime then
@@ -106,7 +107,7 @@ def _percentile(xs, frac: float) -> float:
 def workload_signals(reqs, rate: float, *, hit: float = 0.0,
                      occ: float = 0.0, q: int = 0, hr: float = 1.0) -> dict:
     """Regime signals from a workload INTENT: `reqs` is the seeded arrival
-    list ((t, prompt, max_new) tuples) a `_serve_ab` sweep is about to
+    list ((t, prompt, max_new) tuples) a knob sweep is about to
     offer. Runtime signals default to the quiet values unless the caller
     measured them (the sweep passes the hand-flag reference pass's)."""
     plens = [len(p) for _, p, _ in reqs]

@@ -274,7 +274,7 @@ class FleetRouter:
         self._refresh_gauges()
 
     def kill(self, rid: int) -> None:
-        """Administrative kill (tests/chaos): same path a discovered death
+        """Administrative kill (tests, chaos drills): same path a discovered death
         takes — mark dead and fail over its in-flight requests."""
         self._on_dead(self.replicas[rid], reason="killed")
 

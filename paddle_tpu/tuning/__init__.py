@@ -13,11 +13,11 @@ Levers wired through it today: conv2d lowering (direct vs implicit-GEMM,
 incl. 1x1-as-matmul), attention backend (XLA fusion vs the short-seq Pallas
 kernel vs the bundled flash kernel), conv+BN epilogue fusion
 (passes.fuse_conv_bn_stats), AMP gray-op list membership, and feed-bucketing
-boundaries. The DB is populated offline by `tools/tune.py` (the
-tools/_rn_igemm.py loop made generic: median-of-windows timing, interference
-band, keep-or-retire verdict per shape) and consulted at minimize()/trace
-time under FLAGS_tuning_mode=consult; bench.py reports per-workload hit-rate
-so tools/gate.py can flag a workload running mostly untuned.
+boundaries. The DB is populated offline by `tools/tune.py`
+(median-of-windows timing, interference band, keep-or-retire verdict per
+shape) and consulted at minimize()/trace time under
+FLAGS_tuning_mode=consult; `provenance_snapshot()` says how much of a run
+resolved on swept decisions.
 """
 from .db import (DB_SCHEMA, TuningDB, amp_key, attention_key, bucket_key,
                  canonical_key, collective_key, conv_key, embedding_key,

@@ -49,8 +49,7 @@ _model_cache: tuple[str, float, dict | None] | None = None
 _warned_paths: set[str] = set()
 
 # learned-tier provenance: predictions that stood, fallbacks by reason,
-# explore promotions — bench.py's tuning block and gate.py's fallback-rate
-# ceiling read the snapshot
+# explore promotions (read through `snapshot()`)
 _counts = {"predictions": 0, "fallbacks": 0, "promotions": 0}
 _fallback_reasons: dict[str, int] = {}
 

@@ -12,9 +12,8 @@ bucket boundaries). Four tiers, strictly ordered:
   4. default    — the caller's conservative fallback (what the code did
                   before the tuner existed).
 
-Every resolution bumps a per-op provenance counter so bench.py can report
-how much of a workload ran on swept decisions vs the prior (`gate.py` flags
-a consult-mode workload that runs mostly untuned).
+Every resolution bumps a per-op provenance counter (`provenance_snapshot`):
+how much of a workload ran on swept decisions vs the prior.
 
 Modes (FLAGS_tuning_mode):
   off     — decide() is never consulted; levers use their pre-tuner logic.
@@ -119,12 +118,10 @@ def reset_provenance() -> None:
 
 
 def provenance_snapshot() -> dict:
-    """Per-op tier counts plus the aggregate rates bench.py reports:
-    hit_rate is swept-DB resolutions over all resolutions, tuned_rate
-    additionally credits the learned tier (a model prediction IS a
-    measured-data decision, just an interpolated one — gate.py's coverage
-    floor reads tuned_rate so a model-served workload is not flagged as
-    untuned)."""
+    """Per-op tier counts plus the aggregate rates: hit_rate is swept-DB
+    resolutions over all resolutions, tuned_rate additionally credits the
+    learned tier (a model prediction IS a measured-data decision, just an
+    interpolated one)."""
     with _lock:
         per_op = {op: dict(c) for op, c in _counters.items()}
     total = sum(sum(c.values()) for c in per_op.values())

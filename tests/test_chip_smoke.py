@@ -1,11 +1,10 @@
 """chip_smoke.py's two phases at tiny sizes on the CPU, so the script cannot
-rot between chip runs — plus the refusals: chip_smoke.main and bench.main
-exit non-zero off the chip, and _peak_flops raises on an unknown device."""
+rot between chip runs — plus the refusal: chip_smoke.main exits non-zero
+off the chip and prints no result line."""
 import types
 
 import pytest
 
-import bench
 import chip_smoke
 from paddle_tpu.models import transformer
 from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
@@ -62,14 +61,9 @@ def test_server_phase_fails_when_the_reference_runs_for_the_kernel(
                                 prompt_lens=(9, 3), max_new=2)
 
 
-def test_mains_refuse_without_a_chip_and_unknown_peak_raises(capsys):
+def test_main_refuses_without_a_chip(capsys):
     assert chip_smoke.main() != 0
-    assert bench.main() != 0
     assert capsys.readouterr().out == ""      # no result line off the chip
-    with pytest.raises(ValueError, match="no published peak"):
-        bench._peak_flops(types.SimpleNamespace(device_kind="TPU v5"))
-    assert bench._peak_flops(
-        types.SimpleNamespace(device_kind="TPU v5 lite")) == 197e12
 
 
 def test_result_line_is_ok_and_device_alone(monkeypatch, capsys):

@@ -1,13 +1,12 @@
 """Multichip collective-overlap tests (ISSUE 8) on the virtual 8-device mesh.
 
 The exactness contracts behind the measured scaling campaign
-(tools/_mc_ab.py, bench.py --multichip): bucketed allreduce is BITWISE
+(tools/_mc_ab.py): bucketed allreduce is BITWISE
 payload-layout-invariant, ZeRO-1 sharding lands on the single-device
 parameter trajectory, the 1F1B schedule's bubble accounting is explicit and
 its numerics equal fill-drain's, and the PR 3 watchdog surfaces a hung
 allreduce with step ids and queue depths.
 """
-import json
 import warnings
 
 import numpy as np
@@ -399,66 +398,7 @@ def test_collective_stall_surfaces_hung_allreduce():
         pt_flags.set_flags({"watchdog_stall_s": saved})
 
 
-# -- campaign artifact + gate ------------------------------------------------
-
-def _artifact(**overrides):
-    base = {
-        "metric": "multichip_scaling", "value": 0.4, "unit": "ratio",
-        "n_devices": 8, "platform": "cpu",
-        "scaling": {
-            "dp": {"tokens_per_sec": 14000.0, "n_devices": 8,
-                   "speedup_vs_single": 1.2, "efficiency": 0.15,
-                   "band": 0.02},
-            "pp": {"tokens_per_sec": 8000.0, "n_devices": 4,
-                   "speedup_vs_single": 0.64, "efficiency": 0.16,
-                   "band": 0.02},
-        },
-        "overlap_ab": {
-            "dp_bucketed": {"off_tok_s": 13800.0, "on_tok_s": 14000.0,
-                            "band": 0.05, "verdict": "keep"},
-            "dp_zero1": {"off_tok_s": 14000.0, "on_tok_s": 13000.0,
-                         "band": 0.05, "verdict": "retire"},
-            "pp_1f1b": {"off_tok_s": 8000.0, "on_tok_s": 8100.0,
-                        "band": 0.05, "verdict": "tie"},
-        },
-        "parity": {"dp": 0.0002, "pp": 0.0003},
-    }
-    base.update(overrides)
-    return base
-
-
-def test_gate_multichip_checks(tmp_path):
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "mc_gate", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "tools", "gate.py"))
-    gate = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(gate)
-
-    def check(art):
-        p = tmp_path / "MULTICHIP_test.json"
-        # the driver wrapper shape: metrics line rides in the tail
-        p.write_text(json.dumps({"n_devices": 8, "rc": 0, "ok": True,
-                                 "tail": "noise\n" + json.dumps(art)}))
-        return gate.check_multichip(str(p))
-
-    assert check(_artifact()) == 0  # zero1 retire is WARN-only (memory lever)
-    bad_parity = _artifact(parity={"dp": 0.02, "pp": 0.0003})
-    assert check(bad_parity) == 1
-    slow = _artifact()
-    slow["scaling"]["dp"]["speedup_vs_single"] = 0.01
-    assert check(slow) == 1
-    regressed = _artifact()
-    regressed["overlap_ab"]["dp_bucketed"]["verdict"] = "retire"
-    assert check(regressed) == 1
-    # pre-campaign artifact (parity dryrun only): skipped, green
-    p = tmp_path / "MULTICHIP_old.json"
-    p.write_text(json.dumps({"n_devices": 8, "rc": 0, "ok": True,
-                             "tail": "dryrun_multichip ok: ..."}))
-    assert gate.check_multichip(str(p)) == 0
-
+# -- tools/_mc_ab.py -----------------------------------------------------------
 
 def test_mc_ab_record_verdict_roundtrip(tmp_path):
     """A sweep winner beating the per-grad baseline beyond the band lands

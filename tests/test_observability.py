@@ -329,41 +329,7 @@ def test_slo_breaches_age_out_of_the_window():
     assert sev == ["warn", "warn"]
 
 
-# -- gate + CLI tooling -------------------------------------------------------
-
-def _load_gate():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "gate_obs_test", os.path.join(REPO, "tools", "gate.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_gate_obs_checks(capsys):
-    gate = _load_gate()
-    good = {"telemetry": {
-        "obs_overhead_pct": 0.4, "examples_per_sec_obs_on": 100.0,
-        "examples_per_sec_obs_off": 100.4, "undeclared_metrics": [],
-        "metric_names": ["serving.prefills", "train.steps",
-                         "pipeline.dispatch"]}}
-    assert gate._check_obs(good, "t") == 0
-    # artifacts predating the layer: green unless --obs demands the block
-    assert gate._check_obs({}, "t") == 0
-    assert gate._check_obs({}, "t", require=True) == 1
-    over = {"telemetry": dict(good["telemetry"], obs_overhead_pct=3.1)}
-    assert gate._check_obs(over, "t") == 1
-    rogue = {"telemetry": dict(good["telemetry"],
-                               undeclared_metrics=["rogue.metric"])}
-    assert gate._check_obs(rogue, "t") == 1
-    drift = {"telemetry": dict(good["telemetry"],
-                               metric_names=["serving.prefills",
-                                             "not.in.schema"])}
-    assert gate._check_obs(drift, "t") == 1
-    out = capsys.readouterr().out
-    assert "not.in.schema" in out and "rogue.metric" in out
-
+# -- CLI tooling --------------------------------------------------------------
 
 def test_obs_cli_tail_summarize_diff_prom(tmp_path):
     stream = tmp_path / "obs.jsonl"

@@ -198,6 +198,8 @@ def test_staged_config_adopts_only_at_idle_boundary():
     assert eng.stats["control.rewarmups"] == 1  # bucket geometry moved
     while eng.has_work():
         eng.step()
+    # an actuated engine holds the hand-configured engine's hard zeros
+    assert eng.leaked_pages() == 0 and eng.audit_pool()[0] == []
 
 
 def test_rewarmup_leaves_zero_compiles_on_serving_path():
@@ -280,6 +282,7 @@ def test_controller_apply_stages_then_engine_adopts(ctrl_flags,
     assert eng.degrade_after == ARM_FAST["da"]
     while eng.has_work():
         eng.step()
+    assert eng.leaked_pages() == 0 and eng.audit_pool()[0] == []
 
 
 def test_controller_off_mode_skips_epochs(ctrl_flags):

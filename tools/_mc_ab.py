@@ -24,9 +24,9 @@ Efficiency convention: `speedup_vs_single` = tokens/s of the mesh arm over
 tokens/s of the single-device arm at the SAME global batch. On real chips
 that is the scaling win (ideal = n); on a host-platform virtual mesh every
 "device" shares the same silicon, so ideal is ~1.0 and the number measures
-pure partitioning/collective overhead — which is exactly what a CPU CI can
-gate on (tools/gate.py --multichip). `efficiency` = speedup / n_devices is
-the per-chip spelling for real accelerators.
+pure partitioning/collective overhead. `efficiency` = speedup / n_devices
+is the per-chip spelling for real accelerators. The speed of the dp x 4
+step on the chip is the cell `bert_base.s128.dp4` of BENCHMARK.json.
 
     python tools/_mc_ab.py [--devices 8] [--iters 4] [--passes 2]
                            [--sweep 0,1,4] [--record DB.json] [--quick]
@@ -131,8 +131,8 @@ class _Arm:
         self._drain()
 
     def window(self, iters):
-        """One timed window (the bench.py protocol: async-dispatched iters
-        ended by a host drain read)."""
+        """One timed window: async-dispatched iters ended by a host drain
+        read."""
         import time
 
         t0 = time.perf_counter()
@@ -187,11 +187,11 @@ def _run_arm(build, target_of, feed, iters, passes, parity_steps=3):
 
 
 def _ab_row(tokens: int, off_stats: dict, on_stats: dict) -> dict:
-    """One overlap_ab block entry. The verdict compares MIN-of-windows (the
-    bench.py steady-state convention: interference only ever slows a
-    window, so best-window is the honest estimate and is far more stable
-    across runs than the median of 2-3 interleaved windows) under the wider
-    of the two arms' bands and the gate.py default."""
+    """One overlap_ab block entry. The verdict compares MIN-of-windows
+    (interference only ever slows a window, so best-window is the honest
+    estimate and is far more stable across runs than the median of 2-3
+    interleaved windows) under the wider of the two arms' bands and
+    `_timing.DEFAULT_BAND`."""
     band = max(_timing.DEFAULT_BAND, off_stats["band"], on_stats["band"])
     return {
         "off_tok_s": round(tokens / off_stats["min_s"], 1),
@@ -229,8 +229,8 @@ def campaign(n_devices=8, iters=4, passes=2, sweep=None, record=None,
     if len(devs) < n_devices:
         raise RuntimeError(
             f"campaign needs {n_devices} devices, found {len(devs)} — on a "
-            f"host with no TPU, `bench.py --multichip` provisions a virtual "
-            f"CPU mesh in a fresh process first")
+            f"host with no TPU, start the process with JAX_PLATFORMS=cpu "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={n_devices}")
     platform = devs[0].platform
     if quick:
         iters, passes = max(2, iters // 2), min(passes, 2)

@@ -1,8 +1,8 @@
 """Train / evaluate the learned cost model (paddle_tpu/tuning/learned/).
 
 The offline half of ROADMAP item 3's measured story: the measurement store
-(grown as a side effect by tools/tune.py sweeps, the A/B harnesses, bench
-rounds and explore-mode probes) is the dataset; this CLI turns it into the
+(grown as a side effect by tools/tune.py sweeps, tools/_mc_ab.py and
+explore-mode probes) is the dataset; this CLI turns it into the
 trained artifact the policy's learned tier consults, and re-scores a
 committed artifact so gate.py --costmodel can hold the line in CI.
 

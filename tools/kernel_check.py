@@ -1,6 +1,6 @@
 """On-chip kernel check: every Pallas kernel in the workbench registry is
-compiled by Mosaic and run ON THE TPU (no interpreter) at the shape
-bench.py or the serving engine engages it, and compared with its
+compiled by Mosaic and run ON THE TPU (no interpreter) at the shape a
+BERT-base trainer or the serving engine engages it, and compared with its
 registered XLA reference — forward, and backward where it has one.
 
 A kernel does not stay in the tree on the strength of the interpreter:

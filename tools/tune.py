@@ -1,9 +1,8 @@
 """Offline A/B sweeper: populate the tuning DB with measured verdicts.
 
-The tools/_rn_igemm.py loop made generic (ISSUE 6): for every shape in the
-sweep set, each candidate implementation is timed with the shared
-tools/_timing.py protocol (warmup, median-of-windows, interference band)
-and the keep-or-retire verdict is written into the persistent decision DB
+For every shape in the sweep set, each candidate implementation is timed
+with the tools/_timing.py protocol (warmup, median-of-windows, interference
+band) and the keep-or-retire verdict is written into the persistent decision DB
 (paddle_tpu/tuning/) that FLAGS_tuning_mode=consult reads at minimize()/
 trace time. A tie inside the band records the ANALYTIC decision — a noise
 margin must never overwrite a cost model with a coin flip — and every entry
@@ -11,17 +10,17 @@ carries its measured medians + band so a later reader can re-judge it.
 
 Sweeps:
   conv       — direct vs implicit-GEMM lowering per conv shape (default
-               set: the PERF.md r6 ResNet-50 cost-table shapes; add yours
+               set: the four ResNet-50 conv shapes below; add yours
                with repeated --conv-shape n,h,w,cin,cout,kh,kw,sh,sw).
   attention  — XLA einsum composition vs the short-seq Pallas kernels
                (seq<=128 and the 128-multiple kernel) vs the bundled flash
-               kernel per (batch, heads, seq, head_dim) (default: the
-               bench.py BERT s128 and s512 configs). Arms a platform
+               kernel per (batch, heads, seq, head_dim) (default:
+               BERT-base at b128 s128 and b64 s512). Arms a platform
                cannot run (Pallas off-TPU) are skipped.
   epilogue   — XLA composition vs the fused normalize+affine+act(+residual)
                Pallas kernel (ops/pallas_kernels/epilogue.py) over the
-               PERF.md r6 cost-table conv OUTPUT shapes (the BN apply tail,
-               NHWC + NCHW, with and without residual) and the bench BERT
+               ResNet-50 conv OUTPUT shapes (the BN apply tail,
+               NHWC + NCHW, with and without residual) and the BERT-base
                s128 layer-norm rows.
   embedding  — tiered-embedding cache geometry (ISSUE 10): slot-count and
                prefetch-width arms per table geometry, each arm a real
@@ -37,9 +36,9 @@ Sweeps:
 These are per-shape microbenches — TVM-style schedule search, deliberately
 NOT the chained-per-op instrument PERF.md retired (each arm here is one
 jitted fwd+bwd of a single op, not a chain whose interactions poison the
-sum). The end-to-end confirmation stays where it always was: bench.py's
-`resnet50_lever_ab` and tools/_rn_igemm.py re-measure the composed effect
-every round, and gate.py arbitrates.
+sum). The end-to-end confirmation is a cell of BENCHMARK.json run with the
+DB consulted against the same cell without it; no cell consults a DB today
+(ROADMAP D3).
 
     python tools/tune.py --db TUNING_DB.json                  # full sweep
     python tools/tune.py --db x.json --what conv --iters 20
@@ -65,7 +64,7 @@ from paddle_tpu.ops.nn_ops import (_conv2d_igemm_f32,  # noqa: E402
 from paddle_tpu.tuning.learned import store as learned_store  # noqa: E402
 from tools import _timing  # noqa: E402
 
-# The PERF.md r6 cost-table shapes (b128 NHWC, the bench configuration):
+# The ResNet-50 cost-table shapes (b128 NHWC):
 # raw 7x7-s2 stem, the s2d 4x4 stem, s0's 3x3 and s1's 3x3. These are the
 # shapes the acceptance equivalence test replays.
 RN50_CONV_SHAPES = [
@@ -79,7 +78,7 @@ RN50_CONV_SHAPES = [
      [(1, 1), (1, 1)], (1, 1)),
 ]
 
-# bench.py's two BERT attention regimes: the headline s128 and the s512
+# BERT-base's two attention regimes: the headline s128 and the s512
 # kernel-proof row (a v5e run of 2026-07, on code older than PRs 1-20, had
 # XLA ahead at s128 and the Pallas kernel ~9% ahead at s512)
 ATTENTION_SHAPES = [
@@ -107,9 +106,10 @@ SERVING_TP_DEGREES = (2, 4)
 
 
 # the epilogue lever's sweep set (ISSUE 9): the BN apply tail of the
-# PERF.md r6 cost-table conv OUTPUT shapes — (name, batch, channels,
-# spatial) — expanded over layout x residual below; plus the bench BERT
-# s128 LN rows. These are the shapes bench.py's resnet/bert arms dispatch.
+# ResNet-50 conv OUTPUT shapes — (name, batch, channels,
+# spatial) — expanded over layout x residual below; plus the BERT-base
+# s128 LN rows. These are the shapes the ResNet-50 and BERT-base trainers
+# dispatch.
 EPILOGUE_BN_SHAPES = [
     ("stem_7x7_out", 128, 64, 112 * 112),
     ("s0_3x3_out", 128, 64, 56 * 56),
