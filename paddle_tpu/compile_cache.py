@@ -20,7 +20,7 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # about that: it was written in some runs and not in others, and the warm
 # set-up of `zaya1_8b.decode.sat` drifted from 85 to 58 s over six runs of
 # the same code (my chip run, PR 25). A quarter of a second still keeps the
-# three tiny PRNG programs of every executor step out.
+# one-primitive programs of eager jax calls out.
 MIN_COMPILE_SECS = 0.25
 
 

@@ -49,6 +49,9 @@ import numpy as np
 
 logger = logging.getLogger("paddle_tpu.distributed.ps_rpc")
 
+# how long a server that is shutting down waits for its own threads
+THREAD_DRAIN_S = 5.0
+
 
 def rpc_deadline_s() -> float:
     """`FLAGS_rpc_deadline` (milliseconds, reference
@@ -263,7 +266,9 @@ class _HeartbeatSender(threading.Thread):
 
     def stop(self):
         self.stop_event.set()
-        for conn in self._conns.values():
+        # a snapshot: the beat thread may still be dialing its first
+        # connections into the dict
+        for conn in list(self._conns.values()):
             try:
                 conn.close()
             except Exception:
@@ -917,9 +922,10 @@ class PServerRuntime:
                 "launcher does this automatically)")
         self._warm_optimize_programs()
         listener = Listener(_parse_ep(self.endpoint), authkey=_authkey())
-        threading.Thread(target=self._monitor_loop, daemon=True,
-                         name="ps-liveness-monitor").start()
-        threads = []
+        monitor = threading.Thread(target=self._monitor_loop, daemon=True,
+                                   name="ps-liveness-monitor")
+        monitor.start()
+        threads = [monitor]
         while not self._shutdown.is_set():
             try:
                 conn = listener.accept()
@@ -939,6 +945,16 @@ class PServerRuntime:
             listener.close()
         except OSError:
             pass
+        # The runtime owns its threads: wait for them to end (the monitor
+        # wakes on the flag, a trainer closes its connections right after
+        # `complete`). One still alive here holds the runtime through its
+        # target; if it dropped the LAST reference while the interpreter
+        # shuts down, the executor's compiled programs would be destroyed
+        # on a daemon thread that can no longer take the GIL, and the
+        # process aborts ("FATAL: exception not rethrown").
+        deadline = time.monotonic() + THREAD_DRAIN_S
+        for t in threads:
+            t.join(max(0.0, deadline - time.monotonic()))
 
     def _client_loop(self, conn):
         while not self._shutdown.is_set():

@@ -20,7 +20,11 @@ function, which is exactly the eager-deletion GC behaviour executor.cc:86).
 
 Randomness: ops that need RNG receive fresh subkeys split from a per-run key
 derived from (program.random_seed, scope run counter) — counter-based PRNG is
-the TPU-native equivalent of the reference's per-op seed attrs.
+the TPU-native equivalent of the reference's per-op seed attrs. The host only
+names the two integers (`_prepare_step`, one np.uint32[2] that rides the
+dispatch like a feed); the key itself is derived INSIDE the compiled step
+(`_step_key`, the first thing every lowered form does), so a step costs the
+host no eager jax call and a program without a random op pays nothing.
 """
 from __future__ import annotations
 
@@ -311,10 +315,23 @@ def _step_token(*groups):
     return tok
 
 
+def _step_key(seed_counter, segment=None):
+    """The step's PRNG key, derived under the trace from the np.uint32[2]
+    (program.random_seed, run counter) `_prepare_step` hands every compiled
+    entry: fold_in(PRNGKey(seed), counter), bit for bit what the two calls
+    give for the Python integers. The seed is a value, not a traced
+    constant: `program.random_seed` is not part of the compile signature.
+    `segment` folds in a host-op program's jit-segment index."""
+    key = jax.random.fold_in(jax.random.PRNGKey(seed_counter[0]),
+                             seed_counter[1])
+    return key if segment is None else jax.random.fold_in(key, segment)
+
+
 def _lower(block, feed_names, ro_names, rw_names, extra_w, fetch_names, axis_env=None):
     ops = [op for op in block.ops if op.type not in _SKIP_OPS]
 
-    def fn(feed_vals, ro_vals, rw_vals, key):
+    def fn(feed_vals, ro_vals, rw_vals, seed_counter):
+        key = _step_key(seed_counter)
         env: dict[str, Any] = {}
         if axis_env is not None:
             from .ops.collective_ops import AXIS_ENV_KEY
@@ -388,13 +405,15 @@ class _SegmentedFn:
             out_names = [n for n in dict.fromkeys(
                 n for op in seg_ops for n in op.output_names if n)
                 if n in need_later[i]]
-            fn = jax.jit(self._make_segment_fn(block, seg_ops, in_names, out_names))
+            fn = jax.jit(self._make_segment_fn(
+                block, seg_ops, in_names, out_names, i))
             self.segments.append(("jit", seg_ops, in_names, out_names, fn))
 
     @staticmethod
-    def _make_segment_fn(block, seg_ops, in_names, out_names):
-        def fn(in_vals, key):
-            env: dict[str, Any] = {"__rng_key": key}
+    def _make_segment_fn(block, seg_ops, in_names, out_names, index):
+        def fn(in_vals, seed_counter):
+            env: dict[str, Any] = {
+                "__rng_key": _step_key(seed_counter, segment=index)}
             env.update({n: v for n, v in zip(in_names, in_vals) if v is not None})
 
             def lowerer(block_idx):
@@ -421,15 +440,14 @@ class _SegmentedFn:
 
         return fn
 
-    def __call__(self, feed_vals, ro_vals, rw_vals, key):
+    def __call__(self, feed_vals, ro_vals, rw_vals, seed_counter):
         env: dict[str, Any] = {}
         env.update(zip(self.ro, ro_vals))
         env.update(zip(self.rw, rw_vals))
         env.update(zip(self.feed_names, feed_vals))
-        for i, (kind, seg_ops, in_names, out_names, fn) in enumerate(self.segments):
+        for kind, seg_ops, in_names, out_names, fn in self.segments:
             if kind == "jit":
-                vals = fn(tuple(env.get(n) for n in in_names),
-                          jax.random.fold_in(key, i))
+                vals = fn(tuple(env.get(n) for n in in_names), seed_counter)
                 for n, v in zip(out_names, vals):
                     if v is not None:
                         env[n] = v
@@ -533,10 +551,12 @@ class Executor:
         rng_counter: int | None = None,
     ):
         """rng_counter: caller-controlled replacement for the scope run
-        counter in the PRNG key derivation. Two runs of programs sharing a
-        random_seed and an op prefix draw IDENTICAL per-op keys when given
-        the same counter — how the pipeline backward replay reproduces the
-        forward's dropout masks exactly (parallel/pipeline.py)."""
+        counter in the PRNG key derivation (`_step_key`, inside the compiled
+        step; it travels there as an unsigned 32-bit value). Two runs of
+        programs sharing a random_seed and an op prefix draw IDENTICAL
+        per-op keys when given the same counter — how the pipeline backward
+        replay reproduces the forward's dropout masks exactly
+        (parallel/pipeline.py)."""
         outs, _, _ = self._run_impl(program, feed, fetch_list, scope,
                                     return_numpy, rng_counter)
         return outs
@@ -702,9 +722,10 @@ class Executor:
                                               fetch_names), None, None
 
         with profiler.stage_timer("pipeline.prepare"):
-            comp, feed_vals, ro_vals, rw_vals, key, emb_engine, emb_ticket = \
-                self._prepare_step(program, feed, fetch_names, scope, mesh,
-                                   spmd_mode, rng_counter)
+            (comp, feed_vals, ro_vals, rw_vals, seed_counter, emb_engine,
+             emb_ticket) = self._prepare_step(
+                 program, feed, fetch_names, scope, mesh, spmd_mode,
+                 rng_counter)
 
         # FLAGS_check_nan_inf per-op validation only works on concrete
         # values: under jax.disable_jit() (the guard's blame replay, debug
@@ -718,7 +739,7 @@ class Executor:
             _warn_check_nan_inf_keeps_jit()
         with profiler.stage_timer("pipeline.dispatch"):
             fetches, new_rw, new_extra, token = comp.fn(
-                tuple(feed_vals), ro_vals, rw_vals, key)
+                tuple(feed_vals), ro_vals, rw_vals, seed_counter)
         if check_nan and eager and getattr(comp, "spmd_mode",
                                            "gspmd") == "shard_map":
             # under shard_map the body values stay tracers even with
@@ -775,8 +796,10 @@ class Executor:
         """Everything one step needs before its dispatch (the
         `pipeline.prepare` stage): feeds cast to their declared dtypes, the
         compile signature and the cache entry it names (`pipeline.compile`
-        on a miss), the state gathered from the scope and the step's PRNG
-        key."""
+        on a miss), the state gathered from the scope and the two integers
+        the compiled step derives its PRNG key from (`_step_key`): seed and
+        run counter as one np.uint32[2]. Host work only: on a signature hit
+        nothing here binds a jax primitive or launches a device program."""
         from .core.selected_rows import is_selected_rows
 
         # tiered embeddings (embedding/engine.py): feeds staged by the
@@ -818,10 +841,12 @@ class Executor:
                 tuple(d.id for d in mesh.devices.flat),
             )
         def _sig_of(v):
+            # dtypes by object: they hash and compare like their names, and
+            # str() of one costs more than the rest of the signature
             if is_selected_rows(v):
                 return ("sr", tuple(v.rows.shape), tuple(v.values.shape),
-                        str(v.values.dtype), v.height)
-            return (tuple(v.shape), str(v.dtype))
+                        v.values.dtype, v.height)
+            return (tuple(v.shape), v.dtype)
 
         sig = (
             program._version,
@@ -881,11 +906,13 @@ class Executor:
                 else _on_mesh(v, comp.feed_shardings[n])
                 for n, v in zip(feed_names, feed_vals)]
         scope._run_counter += 1
-        key = jax.random.PRNGKey(program.random_seed or 0)
-        key = jax.random.fold_in(
-            key,
-            scope._run_counter if rng_counter is None else int(rng_counter))
-        return comp, feed_vals, ro_vals, rw_vals, key, emb_engine, emb_ticket
+        counter = scope._run_counter if rng_counter is None else int(rng_counter)
+        # low 32 bits of each, what PRNGKey / fold_in keep of a Python int
+        seed_counter = np.array(
+            [(program.random_seed or 0) & 0xFFFFFFFF, counter & 0xFFFFFFFF],
+            np.uint32)
+        return (comp, feed_vals, ro_vals, rw_vals, seed_counter, emb_engine,
+                emb_ticket)
 
     def train_from_dataset(
         self,

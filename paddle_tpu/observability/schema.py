@@ -29,8 +29,8 @@ DECLARED: list[tuple] = [
      "host->device staging transfers (DeviceLoader / feed_placer)", ()),
     ("pipeline.prepare", STAGE,
      "Executor step before its dispatch: feed cast, signature, cache "
-     "lookup, state gathering, PRNG key fold (self time excludes "
-     "pipeline.compile)", ()),
+     "lookup, state gathering, the key's seed and counter (host work "
+     "only; self time excludes pipeline.compile)", ()),
     ("pipeline.compile", STAGE,
      "Executor._compile on a signature miss (inside pipeline.prepare): "
      "block analysis and building the jitted step; tracing and the XLA "
