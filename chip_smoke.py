@@ -267,19 +267,23 @@ def pool_layout_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
     """The KV pool has one device layout: compile the decode (`rows` rows of
     a full context), prefill, window and copy-on-write programs, for this
     chip or for `device` (a described one), and require that none writes a
-    fresh pool-sized array — a relayout copy of a whole pool."""
+    fresh pool-sized array — a relayout copy of a whole pool (K or V, or the
+    per-token indexer-key pool of a block that has one)."""
     eng = ServingEngine(cfg, page_size=page_size, pool_pages=pool_pages,
                         max_inflight=rows, seed=21)
-    # a block with stacked pools keeps every layer's pages in one buffer
-    elements = int(np.prod(pool_shape(pool_pages, page_size, cfg.kv_heads,
-                                      cfg.head_dim))) \
-        * (cfg.num_layers if cfg.stateful else 1)
+    # a scanned block keeps every layer's pages in one buffer
+    layers = cfg.num_layers if cfg.scanned else 1
+    sizes = {layers * int(np.prod(pool_shape(pool_pages, page_size,
+                                             cfg.kv_heads, cfg.head_dim)))}
+    if cfg.index_head_dim:
+        sizes.add(layers * pool_pages * cfg.index_head_dim * page_size)
     texts = serving_program_hlos(
-        eng, rows=rows, pages=eng.pool.pages_for(cfg.max_position),
+        eng, rows=rows,
+        pages=eng._page_bucket(eng.pool.pages_for(cfg.max_position)),
         prompt=128, device=device)
     copies = {}
     for name, text in texts.items():
-        found = pool_sized_copies(text, elements)
+        found = [c for n in sorted(sizes) for c in pool_sized_copies(text, n)]
         _require(not found,
                  f"the compiled {name} program moves a whole KV pool "
                  f"{len(found)} times, first: {found[0] if found else None}")

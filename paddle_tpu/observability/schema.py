@@ -283,6 +283,25 @@ DECLARED: list[tuple] = [
     ("serving.moe.layer_steps", COUNTER,
      "layer x decode-step pairs counted in serving.moe.experts_touched: "
      "the calls of the decode expert kernel", ()),
+    # -- chunked prefill and learned sparse attention (ISSUE 29) ------------
+    ("serving.prefill.chunk.seconds", HISTOGRAM,
+     "one window of a chunked prefill (attrs rid, chunk, tokens), under "
+     "serving.prefill: a prompt of a block with prefill_chunk runs as "
+     "consecutive windows of that many tokens", ()),
+    ("serving.prefill.chunks", COUNTER,
+     "windows run by chunked prefills (serving.prefills counts the "
+     "requests)", ()),
+    ("serving.sparse.context_tokens", COUNTER,
+     "live cached tokens the indexer scored in decode steps, summed over "
+     "rows, layers and steps (x an indexer key's bytes: what the scan had "
+     "to read)", ()),
+    ("serving.sparse.selected_tokens", COUNTER,
+     "cached tokens decode rows attended after selection, summed over "
+     "rows, layers and steps (x a token's K+V bytes: what the gather had "
+     "to read)", ()),
+    ("serving.sparse.layer_steps", COUNTER,
+     "layer x decode-step pairs in which the indexer ran (a table wider "
+     "than index_topk slots)", ()),
     ("serving.control.rewarmups", COUNTER,
      "warmup_decode re-runs forced by an adopted bucket-geometry change "
      "(keeps XLA compiles off the serving path)", ()),

@@ -1,13 +1,16 @@
-"""Top-1 expert layer over stacked expert weights: a Pallas TPU kernel.
+"""Expert layer over stacked expert weights, top-1 or top-k: a Pallas TPU
+kernel.
 
 What it computes: `out[t] = sum_e cw[t, e] * W_down[e] (silu(W_gate[e] z_t)
 * (W_up[e] z_t))` for the experts this call holds, with `cw[t, e]` the
-router's probability where token t chose expert e and zero elsewhere. Under
-top-1 routing one term of the sum is non-zero per token; the kernel still
-walks EVERY expert it holds, so a step's time does not depend on where the
-router sent the tokens (a decode batch of 64 rows touches nearly all of 16
-experts anyway, and a step whose cost moved with the routing would make two
-runs with different weights incomparable).
+weight the router gave expert e for token t (its probability under top-1
+routing, its renormalised share under top-k) and zero for every expert the
+token did not choose. One term of the sum is non-zero per token under
+top-1 routing, k under top-k; the kernel walks EVERY expert it holds either
+way, so a step's time does not depend on where the router sent the tokens
+(a decode batch of 64 rows touches nearly all of 16 experts at top-1 and
+126 of 128 at top-8 anyway, and a step whose cost moved with the routing
+would make two runs with different weights incomparable).
 
 Why a kernel: a decode step is bound by streaming the expert weights
 (3 x H x F a expert) through the chip once, and this is that stream and
@@ -45,13 +48,20 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _f_tile(ffn: int) -> int:
-    return 512 if ffn % 512 == 0 else ffn
+    """Columns of an expert's width one grid step takes: 512, or 384 for a
+    width only that divides (768 = 2 x 384: three double-buffered slabs of
+    2048 x 384 bfloat16 are 9.4 MB of VMEM), else the whole width."""
+    for tile in (512, 384):
+        if ffn % tile == 0:
+            return tile
+    return ffn
 
 
 def experts_supported(z_shape, w_gate_shape, dtype) -> bool:
     """z [T, H] against W_gate `[L, E, H, F]`: whole 128-lane rows on both
-    widths, a 2- or 4-byte dtype, and at most 128 experts (the combine
-    weights ride one lane register)."""
+    widths, a 2- or 4-byte dtype, at most 128 experts (the combine weights
+    ride one lane register, however many of them a token's row fills) and
+    an F tile (`_f_tile`) whose three slabs fit 8 MB."""
     if len(z_shape) != 2 or len(w_gate_shape) != 4:
         return False
     _, E, H, F = w_gate_shape
@@ -81,8 +91,8 @@ def _kernel(layer_ref, z_ref, cw_ref, wg_ref, wu_ref, wd_ref, o_ref):
                           preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("tag", "interpret"))
-def _call(z, cw, w_gate, w_up, w_down, layer, tag, interpret):
+@functools.partial(jax.jit, static_argnames=("tag", "interpret", "topk"))
+def _call(z, cw, w_gate, w_up, w_down, layer, tag, interpret, topk=False):
     T, H = z.shape
     _, E, _, F = w_gate.shape
     dtype = w_gate.dtype
@@ -118,7 +128,7 @@ def _call(z, cw, w_gate, w_up, w_down, layer, tag, interpret):
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-        name="moe_top1_experts_" + tag,
+        name="moe_topk_experts_" + tag if topk else "moe_top1_experts_" + tag,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), zp, cwp,
       w_gate, w_up, w_down)
     return out[:T]
@@ -159,3 +169,12 @@ def moe_top1_experts(z, cw, w_gate, w_up, w_down, layer=0, tag="decode"):
     int. Returns float32 [T, H]. Callers gate on `experts_supported`."""
     return _call(z, cw, w_gate, w_up, w_down, jnp.asarray(layer, jnp.int32),
                  str(tag), bool(INTERPRET))
+
+
+def moe_topk_experts(z, cw, w_gate, w_up, w_down, layer=0, tag="decode"):
+    """The same kernel under another name in the trace
+    (`moe_topk_experts_<tag>`): a top-k family's call, so that a reader of
+    `^moe_top1_experts` keeps finding the top-1 family's calls and nothing
+    else."""
+    return _call(z, cw, w_gate, w_up, w_down, jnp.asarray(layer, jnp.int32),
+                 str(tag), bool(INTERPRET), topk=True)

@@ -1,0 +1,537 @@
+"""The mechanisms of a decoder layer whose attention reads a LEARNED SUBSET
+of its cache, with a top-k mixture of experts behind it, and the op that
+runs a stack of them (the "sparse_moe" block of serving/model.py).
+
+Each mechanism is a plain jax function (`<name>_fn`); the one registered op
+that runs them is `sparse_moe_stack`:
+
+  * `indexer_scores`     — the "lightning indexer" of DeepSeek Sparse
+                           Attention: a few small query heads, ONE cached
+                           key head, `I(t, s) = sum_j w_tj relu(qI_tj .
+                           kI_s)`, float32;
+  * `select_indices` / `select_mask` — the `k` cached positions `s <= t`
+                           of largest `I(t, s)` (every position while
+                           `t < k`; ties go to the lower position), as
+                           indices (`lax.top_k`: a decode row gathers by
+                           them) or as a mask found without a sort (a
+                           window attends under it). Each form is handed
+                           back as the attention used it: a decode row's
+                           indices, a window's mask packed into words
+                           (`pack_selection`);
+  * `sparse_decode_attention` — one query a row over the K/V rows of its
+                           selected positions, gathered from the paged pool
+                           a token row at a time;
+  * `masked_window_attention` — a window of queries over the whole paged
+                           context with everything unselected masked: for
+                           hundreds of queries the selections cover most of
+                           the context between them, so the pages are read
+                           once and the selection is a mask;
+  * `topk_router`        — softmax over all experts, the k largest,
+                           renormalised to sum to one.
+
+`sparse_moe_stack` composes them into the decoder (embedding, L layers,
+final norm, untied head) in the shapes serving needs: dense oracle
+(`full`), a window over the paged pool (`window`: a prompt's 512-token
+chunk or the suffix behind a prefix hit; `prefill` is the same at start 0)
+and the ragged decode step. As in `cca_moe_ops`, the layer is written once
+and scanned over weights stacked `[L, ...]`, and the pools of all layers
+are one buffer each: K and V `[L * pages, page_size, nkv*dh]` and the
+indexer keys `[L * pages, index_dim, page_size]`, index_dim values a TOKEN,
+a page's tokens side by side on the lanes (layer l's page p is row `l *
+pages + p`).
+
+The experts run through `pallas_kernels.moe_experts` in its combine-weight
+form: a token's row of the `[T, E]` weight matrix holds its k renormalised
+probabilities, zero elsewhere.
+
+Precision: matmul operands in the weights' dtype (bfloat16 as served),
+float32 accumulation; residual stream, norms, router, rotary, the indexer's
+scores and the selection, and softmax in float32.
+"""
+from __future__ import annotations
+
+import collections
+
+import jax
+import jax.numpy as jnp
+
+from .attention_ops import (_DROP_PAGE, _NEG_INF, _gather_pages,
+                            _write_rows, paged_decode_attention_fn)
+from .cca_moe_ops import (_experts_backend, _page_row_index, rms_norm_fn,
+                          rotary_partial_fn)
+from .registry import ExecContext, register_op
+
+_HI = jax.lax.Precision.HIGHEST
+_F32 = jnp.float32
+
+Geometry = collections.namedtuple(
+    "Geometry", "num_heads num_kv_heads head_dim rope_theta eps index_heads "
+                "index_dim index_topk experts_per_token")
+
+# the stacked per-layer parameters, in the order the stack op takes them
+LAYER_PARAMS = (
+    "attn_norm", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "wqi", "wki",
+    "ki_norm_w", "ki_norm_b", "ww", "ffn_norm", "router_w")
+EXPERT_PARAMS = ("w_gate", "w_up", "w_down")
+
+# queries of a window attended together: the float32 scores of one block
+# are `[heads, block, context]` (32 x 64 x 36,864 x 4 B = 302 MB)
+_QUERY_BLOCK = 64
+# a window whose `[heads, queries, context]` indexer scores pass this many
+# values (a quarter of a GB of float32) adds the heads up one at a time
+# instead. A decode step must stay under it: one query a row, so a product
+# a head has ONE row and the chip's MXU idles (5.9 ms a layer at 64 rows
+# with the heads one at a time; my chip runs, PR 29)
+_INDEX_SCORES_AT_ONCE = 1 << 26
+
+
+# ---------------------------------------------------------------------------
+# the mechanisms
+# ---------------------------------------------------------------------------
+
+
+def index_rotary_dim(index_dim: int) -> int:
+    """Lanes of an indexer head that carry rotary: the first half."""
+    return index_dim // 2
+
+
+def layer_norm_fn(x, w, b, eps: float):
+    xf = x.astype(_F32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    return (xf - mu) * jax.lax.rsqrt(var + eps) * w + b
+
+
+def indexer_scores_fn(qi, w, ki_pages):
+    """qi [B, S, J, D] float32, w [B, S, J] float32 (the score's scale
+    folded in), ki_pages [B, P, D, page_size] as cached (a page's keys side
+    by side, one LANE a token: `kv_cache.stacked_pool_shapes`) -> `I` [B,
+    S, P * page_size] float32. The product contracts D and leaves a page's
+    tokens on the lanes, so nothing is transposed on the way."""
+    B, S, J, _ = qi.shape
+    P, ps = ki_pages.shape[1], ki_pages.shape[3]
+    qc = qi.astype(ki_pages.dtype)
+    if B * S * J * P * ps <= _INDEX_SCORES_AT_ONCE:
+        s = jnp.einsum("bsjd,bpdt->bsjpt", qc, ki_pages,
+                       preferred_element_type=_F32)
+        return jnp.einsum("bsjpt,bsj->bspt", jax.nn.relu(s),
+                          w).reshape(B, S, P * ps)
+
+    def add_head(acc, head):
+        qj, wj = head                                  # [B, S, D], [B, S]
+        s = jnp.einsum("bsd,bpdt->bspt", qj, ki_pages,
+                       preferred_element_type=_F32)
+        return acc + jax.nn.relu(s) * wj[..., None, None], None
+
+    acc, _ = jax.lax.scan(add_head, jnp.zeros((B, S, P, ps), _F32),
+                          (jnp.moveaxis(qc, 2, 0), jnp.moveaxis(w, 2, 0)))
+    return acc.reshape(B, S, P * ps)
+
+
+def write_index_keys_fn(i_pool, ki, page_table, layer_off, first, count):
+    """The keys ki [B, S, D] of positions `first[b] .. first[b] +
+    count[b] - 1` into the index pool `[rows, D, page_size]`, where a
+    token's key is one LANE of its page's `[D, page_size]` slab: the pages
+    a row's window touches are read whole, the new lanes laid over them and
+    the slabs written back, so that the pool is only ever read and written
+    in whole 128-lane rows and keeps the row-major layout the chip's client
+    stores it in (a `[rows, page_size, 64]` pool was stored slots-minor and
+    copied whole, in and out, by every step; a per-token column scatter
+    made the compiler want the transposed layout). A page being written
+    belongs to one row alone (copy-on-write), so slabs never collide."""
+    R, D, ps = i_pool.shape
+    B, S, _ = ki.shape
+    P = page_table.shape[1]
+    n_pages = min(P, (S + ps - 2) // ps + 1)         # a window can straddle
+    ords = (first // ps)[:, None] + jnp.arange(n_pages, dtype=jnp.int32)
+    rel = (ords[..., None] * ps + jnp.arange(ps, dtype=jnp.int32)
+           - first[:, None, None])                   # [B, n_pages, ps]
+    new = (rel >= 0) & (rel < count[:, None, None])
+    touched = jnp.any(new, axis=-1) & (ords < P)
+    rows = jnp.take_along_axis(page_table, jnp.clip(ords, 0, P - 1), axis=1)
+    rows = jnp.where(touched, rows + layer_off, _DROP_PAGE)
+    old = i_pool[jnp.clip(rows, 0, R - 1)]           # [B, n_pages, D, ps]
+    at = jnp.clip(rel, 0, S - 1).reshape(B, n_pages * ps, 1)
+    vals = jnp.take_along_axis(ki.astype(i_pool.dtype), at, axis=1)
+    vals = jnp.swapaxes(vals.reshape(B, n_pages, ps, D), 2, 3)
+    slabs = jnp.where(new[:, :, None, :], vals, old)
+    return i_pool.at[rows].set(slabs, mode="drop")
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 in the same order (-0.0 read as +0.0)."""
+    u = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+def _kth_largest(u, k: int):
+    """u [..., T] uint32 -> [...]: its k-th largest value, found a bit at a
+    time (32 counting passes; a sort of 36,864 values a row costs 30x)."""
+    def narrow(i, lo):
+        cand = lo | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum((u >= cand[..., None]).astype(jnp.int32), -1) >= k
+        return jnp.where(enough, cand, lo)
+
+    return jax.lax.fori_loop(0, 32, narrow,
+                             jnp.zeros(u.shape[:-1], jnp.uint32))
+
+
+def select_indices_fn(scores, limit, k: int):
+    """scores [B, S, T], limit [B, S] (positions `s < limit` exist) -> sel
+    [B, S, kk] int32: the `kk = min(k, T)` positions of largest score, ties
+    to the lower position, -1 where fewer exist."""
+    kk = min(int(k), scores.shape[-1])
+    live = jnp.arange(scores.shape[-1], dtype=jnp.int32) < limit[..., None]
+    # -0.0 read as +0.0: the sort orders them, a comparison does not
+    scores = jnp.where(scores == 0, 0.0, scores)
+    # rows flat: the chip sorts a `[64, 1, T]` array seven times slower
+    # than the same rows as `[64, T]` (a unit second-minor dimension pads
+    # to a tile of 8)
+    flat = jnp.where(live, scores, -jnp.inf).reshape(-1, scores.shape[-1])
+    vals, idx = jax.lax.top_k(flat, kk)
+    sel = jnp.where(vals > -jnp.inf, idx.astype(jnp.int32), -1)
+    return sel.reshape(scores.shape[:-1] + (kk,))
+
+
+def select_mask_fn(scores, limit, k: int):
+    """The set `select_indices_fn` names, as a mask [B, S, T], without a
+    sort: everything above the k-th largest score, and of the scores equal
+    to it the lowest positions that fill the k."""
+    T = scores.shape[-1]
+    kk = min(int(k), T)
+    live = jnp.arange(T, dtype=jnp.int32) < limit[..., None]
+    u = _ordered_bits(jnp.where(live, scores, -jnp.inf))
+    kth = _kth_largest(u, kk)[..., None]
+    above, ties = u > kth, u == kth
+    room = kk - jnp.sum(above.astype(jnp.int32), -1, keepdims=True)
+    crowded = jnp.sum(ties.astype(jnp.int32), -1, keepdims=True) > room
+    ties = jax.lax.cond(
+        jnp.any(crowded),
+        lambda: ties & (jnp.cumsum(ties.astype(jnp.int32), axis=-1) <= room),
+        lambda: ties)
+    return live & (above | ties)
+
+
+def pack_selection_fn(keep, page_size: int):
+    """keep [..., P * page_size] bool -> int32 words [..., G, page_size], G
+    = ceil(P / 32): bit `p % 32` of word `[p // 32, slot]` says whether
+    position `p * page_size + slot` is kept. Thirty-two PAGES share a
+    word, so a page's slots stay side by side on the lanes and the packing
+    is a sum over whole rows."""
+    lead, P = keep.shape[:-1], keep.shape[-1] // page_size
+    G = -(-P // 32)
+    pages = keep.reshape(lead + (P, page_size))
+    if G * 32 > P:
+        pages = jnp.pad(pages, [(0, 0)] * len(lead)
+                        + [(0, G * 32 - P), (0, 0)])
+    bit = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))[:, None]
+    words = jnp.sum(jnp.where(pages.reshape(lead + (G, 32, page_size)), bit,
+                              jnp.uint32(0)), axis=-2, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def sparse_decode_attention_fn(q, k_pool, v_pool, page_table, sel,
+                               sm_scale: float):
+    """q [B, nh, dh] float32; pools `[rows, page_size, nkv*dh]`; page_table
+    [B, P] (already shifted to the layer's rows); sel [B, kk] positions, -1
+    for none -> [B, nh, dh] float32: softmax over the selected positions
+    only. The K and V rows are gathered a token at a time from the pool
+    seen as `[rows * page_size, nkv*dh]` (whole tiles either way: no
+    copy)."""
+    B, nh, dh = q.shape
+    rows, ps, width = k_pool.shape
+    nkv, g = width // dh, nh // (width // dh)
+    P = page_table.shape[1]
+    have = sel >= 0
+    at = jnp.maximum(sel, 0)
+    # the page of every selected position, as a masked sum over the table
+    # (131,072 scalar gathers a layer cost the chip 1.2 ms)
+    ordinal = jnp.arange(P, dtype=jnp.int32)
+    page = jnp.sum(jnp.where((at // ps)[..., None] == ordinal,
+                             page_table[:, None, :], 0), axis=-1)
+    flat = jnp.clip(page, 0, rows - 1) * ps + at % ps          # [B, kk]
+    k = k_pool.reshape(rows * ps, width)[flat]                 # [B, kk, W]
+    v = v_pool.reshape(rows * ps, width)[flat]
+    qg = q.reshape(B, nkv, g, dh).astype(k.dtype)
+    out = []
+    for j in range(nkv):        # a KV head is a 128-lane slice of a row
+        kj, vj = (a[..., j * dh:(j + 1) * dh] for a in (k, v))
+        s = jnp.einsum("bgd,bkd->bgk", qg[:, j], kj,
+                       preferred_element_type=_F32) * sm_scale
+        s = jnp.where(have[:, None, :], s, _NEG_INF)
+        probs = jax.nn.softmax(s, axis=-1)
+        out.append(jnp.einsum("bgk,bkd->bgd", probs.astype(vj.dtype), vj,
+                              preferred_element_type=_F32))
+    return jnp.stack(out, axis=1).reshape(B, nh, dh)
+
+
+def _masked_attention(q, k, v, mask, sm_scale):
+    """q [B, S, nh, dh], k/v [B, T, nkv, dh], mask [B, S, T] -> [B, S, nh,
+    dh] float32, query block by query block."""
+    B, S, nh, dh = q.shape
+    nkv = k.shape[2]
+    qg = q.reshape(B, S, nkv, nh // nkv, dh).astype(k.dtype)
+
+    def block(args):
+        qb, mb = args                       # [B, s, nkv, g, dh], [B, s, T]
+        s = jnp.einsum("bsjgd,btjd->bjgst", qb, k,
+                       preferred_element_type=_F32) * sm_scale
+        s = jnp.where(mb[:, None, None], s, _NEG_INF)
+        probs = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bjgst,btjd->bsjgd", probs.astype(v.dtype), v,
+                          preferred_element_type=_F32)
+
+    if S <= _QUERY_BLOCK or S % _QUERY_BLOCK:
+        out = block((qg, mask))
+    else:
+        n = S // _QUERY_BLOCK
+        split = lambda a: jnp.moveaxis(                      # noqa: E731
+            a.reshape((B, n, _QUERY_BLOCK) + a.shape[2:]), 1, 0)
+        out = jnp.moveaxis(jax.lax.map(block, (split(qg), split(mask))),
+                           0, 1).reshape(B, S, nkv, nh // nkv, dh)
+    return out.reshape(B, S, nh, dh)
+
+
+def masked_window_attention_fn(q, k_pool, v_pool, page_table, mask,
+                               sm_scale: float):
+    """q [B, S, nh, dh] over the paged context of `page_table` [B, P]
+    (shifted to the layer's rows), mask [B, S, P * page_size]."""
+    dh = q.shape[-1]
+    nkv = k_pool.shape[2] // dh
+    return _masked_attention(q, _gather_pages(k_pool, page_table, nkv),
+                             _gather_pages(v_pool, page_table, nkv), mask,
+                             sm_scale)
+
+
+def topk_router_fn(z, router_w, k: int):
+    """z [T, H] float32 -> (ids [T, k] int32, the k most probable experts
+    in order; cw [T, E] float32: their probabilities renormalised to sum
+    to one, zero elsewhere)."""
+    probs = jax.nn.softmax(jnp.dot(z, router_w, precision=_HI), axis=-1)
+    vals, ids = jax.lax.top_k(probs, k)
+    weights = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    held = jnp.arange(probs.shape[-1], dtype=jnp.int32)
+    cw = jnp.sum(jnp.where(ids[:, :, None] == held, weights[:, :, None],
+                           0.0), axis=1)
+    return ids.astype(jnp.int32), cw
+
+
+def moe_topk_experts_fn(z, cw, w_gate, w_up, w_down, layer=0,
+                        tag: str = "decode"):
+    """`sum_e cw[t, e] * expert_e(z[t])`, float32 [T, H]; weights stacked
+    `[L, E, ...]`, `layer` picks the layer."""
+    from .pallas_kernels import moe_experts as pme
+
+    if _experts_backend(z.shape[0], w_gate.shape, w_gate.dtype) == "pallas":
+        return pme.moe_topk_experts(z, cw, w_gate, w_up, w_down, layer,
+                                    tag=tag)
+    return pme._reference(z, cw, w_gate, w_up, w_down, layer)
+
+
+# ---------------------------------------------------------------------------
+# one layer, in two halves around the attention
+# ---------------------------------------------------------------------------
+
+
+def _mm(x, w):
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_F32)
+
+
+def _pre_attention(x, p, positions, geom: Geometry):
+    """x [B, S, H] -> q [B, S, nh, dh], k, v [B, S, nkv, dh], the indexer's
+    qi [B, S, J, D], ki [B, S, D] and w [B, S, J], all float32."""
+    B, S, _ = x.shape
+    nh, nkv, dh = geom.num_heads, geom.num_kv_heads, geom.head_dim
+    J, D = geom.index_heads, geom.index_dim
+    z = rms_norm_fn(x, p["attn_norm"], geom.eps)
+    q = rms_norm_fn(_mm(z, p["wq"]).reshape(B, S, nh, dh), p["q_norm"],
+                    geom.eps)
+    k = rms_norm_fn(_mm(z, p["wk"]).reshape(B, S, nkv, dh), p["k_norm"],
+                    geom.eps)
+    q = rotary_partial_fn(q, positions, dh, geom.rope_theta)
+    k = rotary_partial_fn(k, positions, dh, geom.rope_theta)
+    v = _mm(z, p["wv"]).reshape(B, S, nkv, dh)
+    rot = index_rotary_dim(D)
+    qi = rotary_partial_fn(_mm(z, p["wqi"]).reshape(B, S, J, D), positions,
+                           rot, geom.rope_theta)
+    ki = layer_norm_fn(_mm(z, p["wki"]), p["ki_norm_w"], p["ki_norm_b"],
+                       geom.eps)
+    ki = rotary_partial_fn(ki[:, :, None, :], positions, rot,
+                           geom.rope_theta)[:, :, 0]
+    w = _mm(z, p["ww"]) * (J ** -0.5 * D ** -0.5)
+    return q, k, v, qi, ki, w
+
+
+def _post_attention(x, o, p, experts, layer, geom: Geometry, tag):
+    """x [B, S, H], o [B, S, nh*dh] -> (y [B, S, H], ids [B, S, k])."""
+    B, S, H = x.shape
+    h = x + _mm(o, p["wo"])
+    z = rms_norm_fn(h, p["ffn_norm"], geom.eps).reshape(B * S, H)
+    ids, cw = topk_router_fn(z, p["router_w"], geom.experts_per_token)
+    y = moe_topk_experts_fn(z, cw, *experts, layer=layer, tag=tag)
+    return h + y.reshape(B, S, H), ids.reshape(B, S, -1)
+
+
+# ---------------------------------------------------------------------------
+# the stack
+# ---------------------------------------------------------------------------
+
+
+def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
+                        layer_params: dict, experts: tuple, geom: Geometry,
+                        pools=None, page_table=None, lens=None, start=None,
+                        mask=None, mark=None, num_pages: int = 0):
+    """Run the decoder. `mode`:
+
+      full     tok/pos [B, S]                          -> logits [B, S, V]
+      window   + page_table, start, lens (context in
+               the pool; `prefill` is start 0)         -> last logits [B, V]
+      decode   tok/pos [B], page_table, mask [B],
+               mark [M] (rows whose selection is kept) -> logits [B, V]
+
+    Returns a dict: logits; routes ([B, S, L, k], decode [B, L, k]);
+    selection, what each layer's attention was given: the mask a window
+    (or `full`: the sequence as one page) attended under, as
+    `pack_selection_fn` words [B, S, L, G, page_size]; the positions the
+    marked rows of a decode step gathered, [M, L, kk], -1 where fewer
+    exist; and, with pools, k_pool/v_pool/i_pool as written."""
+    decode = mode == "decode"
+    paged = mode != "full"
+    if decode:
+        tok, pos = jnp.reshape(tok, (-1, 1)), jnp.reshape(pos, (-1, 1))
+    x = emb[tok].astype(_F32)
+    B, S, _ = x.shape
+    L = layer_params["wq"].shape[0]
+    sm_scale = geom.head_dim ** -0.5
+    tag = "decode" if decode else "prefill"
+    rel = jnp.arange(S, dtype=jnp.int32)[None, :]
+    if paged:
+        page_size = pools[0].shape[1]
+        page_table = page_table.astype(jnp.int32)
+        context = page_table.shape[1] * page_size
+        first = (pos[:, 0] if decode
+                 else (start if start is not None
+                       else jnp.zeros((B,), jnp.int32))).astype(jnp.int32)
+        gpos = first[:, None] + rel                             # [B, S]
+        valid = (jnp.reshape(mask, (-1, 1)) > 0) if decode \
+            else rel < lens[:, None]
+        count = valid[:, 0].astype(jnp.int32) if decode else lens
+        # a decode step whose whole bucket fits the selection attends every
+        # live page through the dense paged kernel and scores nothing
+        dense_decode = decode and context <= geom.index_topk
+    else:
+        gpos = jnp.broadcast_to(rel, (B, S))
+        context = S
+    def layer(carry, xs):
+        l, p = xs
+        if paged:
+            x, k_pool, v_pool, i_pool = carry
+            off = l * num_pages
+            table = page_table + off
+        else:
+            (x,) = carry
+        q, k, v, qi, ki, w = _pre_attention(x, p, pos, geom)
+        if paged:
+            idx = _page_row_index(page_table, gpos, page_size, off, valid)
+            slot = gpos % page_size
+            k_pool = _write_rows(k_pool, k.reshape(B, S, -1), idx, slot)
+            v_pool = _write_rows(v_pool, v.reshape(B, S, -1), idx, slot)
+            i_pool = write_index_keys_fn(i_pool, ki, page_table, off, first,
+                                         count)
+        if paged and dense_decode:
+            o = paged_decode_attention_fn(q[:, 0], k_pool, v_pool, table,
+                                          first + 1, sm_scale=sm_scale)
+            at = jnp.arange(context, dtype=jnp.int32)[None, :]
+            sel = jnp.where(at <= first[:, None], at, -1)[:, None]
+            o = o[:, None]
+        else:
+            if paged:
+                ki_ctx = i_pool[jnp.clip(table, 0, i_pool.shape[0] - 1)]
+            else:       # the sequence as one page
+                ki_ctx = jnp.swapaxes(ki.astype(emb.dtype), 1, 2)[:, None]
+            scores = indexer_scores_fn(qi, w, ki_ctx)
+            if decode:
+                sel = select_indices_fn(scores, gpos + 1, geom.index_topk)
+                o = sparse_decode_attention_fn(
+                    q[:, 0], k_pool, v_pool, table, sel[:, 0],
+                    sm_scale)[:, None]
+            else:
+                keep = select_mask_fn(scores, gpos + 1, geom.index_topk)
+                if paged:
+                    o = masked_window_attention_fn(q, k_pool, v_pool, table,
+                                                   keep, sm_scale)
+                else:
+                    o = _masked_attention(q, k.astype(emb.dtype),
+                                          v.astype(emb.dtype), keep,
+                                          sm_scale)
+                # handed back as attended under: the mask itself
+                sel = pack_selection_fn(keep, page_size if paged else S)
+        y, ids = _post_attention(x, o.reshape(B, S, -1), p, experts, l,
+                                 geom, tag)
+        if decode:
+            sel = sel[:, 0][mark]                               # [M, kk]
+        carry = (y, k_pool, v_pool, i_pool) if paged else (y,)
+        return carry, (ids, sel)
+
+    init = (x,) + (tuple(pools) if paged else ())
+    xs = (jnp.arange(L, dtype=jnp.int32), layer_params)
+    carry, (routes, selection) = jax.lax.scan(layer, init, xs)
+    xn = rms_norm_fn(carry[0], final_norm, geom.eps)
+    if mode == "window":
+        at = jnp.clip(lens - 1, 0, S - 1)[:, None, None]
+        xn = jnp.take_along_axis(xn, at, axis=1)
+    logits = jnp.einsum("bsh,hv->bsv", xn.astype(head.dtype), head,
+                        preferred_element_type=_F32)
+    routes = jnp.moveaxis(routes, 0, -2)                  # [B, S, L, k]
+    if decode:
+        selection = jnp.moveaxis(selection, 0, 1)         # [M, L, kk]
+    else:
+        selection = jnp.moveaxis(selection, 0, 2)     # [B, S, L, G, ps]
+    out = {"logits": logits if mode == "full" else logits[:, 0],
+           "routes": routes[:, 0] if decode else routes,
+           "selection": selection}
+    if paged:
+        out.update(k_pool=carry[1], v_pool=carry[2], i_pool=carry[3])
+    return out
+
+
+# ---------------------------------------------------------------------------
+# registered op
+# ---------------------------------------------------------------------------
+
+
+@register_op("sparse_moe_stack", grad="none")
+def sparse_moe_stack_op(ctx: ExecContext):
+    """The whole decoder in one op; see `sparse_moe_stack_fn`. inputs: Tok,
+    Pos, Emb, Head, FinalNorm, LayerParams (the `LAYER_PARAMS`, in order),
+    Experts (`EXPERT_PARAMS`), and by mode PageTable, Lens, Start, Mask,
+    Mark (decode), KPool/VPool/IPool. attrs: mode and the geometry. Outputs:
+    NextToken (greedy), Logits, Routes, Selection, and the pools under
+    their own names."""
+    mode = ctx.attr("mode")
+    geom = Geometry(*(ctx.attr(f) for f in Geometry._fields))
+    params = dict(zip(LAYER_PARAMS, ctx.inputs("LayerParams")))
+    paged = mode != "full"
+
+    def opt(slot):
+        return ctx.input(slot).astype(jnp.int32) if ctx.has_input(slot) \
+            else None
+
+    out = sparse_moe_stack_fn(
+        "window" if mode == "prefill" else mode,
+        ctx.input("Tok").astype(jnp.int32),
+        ctx.input("Pos").astype(jnp.int32), ctx.input("Emb"),
+        ctx.input("Head"), ctx.input("FinalNorm"), params,
+        tuple(ctx.inputs("Experts")), geom,
+        pools=(ctx.input("KPool"), ctx.input("VPool"), ctx.input("IPool"))
+        if paged else None,
+        page_table=opt("PageTable"), lens=opt("Lens"), start=opt("Start"),
+        mask=ctx.input("Mask") if ctx.has_input("Mask") else None,
+        mark=opt("Mark"), num_pages=int(ctx.attr("num_pages", 0)))
+    res = {"Logits": out["logits"], "Routes": out["routes"],
+           "Selection": out["selection"],
+           "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
+    if paged:
+        res.update(KPoolOut=out["k_pool"], VPoolOut=out["v_pool"],
+                   IPoolOut=out["i_pool"])
+    return res

@@ -13,7 +13,7 @@ Four pieces, one runtime:
                    windowed suffix-prefill+verify / ragged decode programs
                    over one explicit weight namespace (plus the dense
                    oracle for equivalence tests, the COW page-copy step,
-                   and the GSPMD tp annotations). TWO block families,
+                   and the GSPMD tp annotations). THREE block families,
                    selected by `DecoderConfig.block`: `"post_ln"` (the
                    default: BERT-base run causally; fields vocab_size,
                    hidden_size, num_layers, num_heads, ffn_size,
@@ -23,9 +23,17 @@ Four pieces, one runtime:
                    behind an MLP router, RMSNorm, tied head; adds
                    num_kv_heads, attn_head_dim, num_experts,
                    router_hidden_size, cca_time0/1, partial_rotary_factor,
-                   rope_theta, rms_norm_eps). The second keeps one state
-                   row a page beside the K/V pools and reports the experts
-                   it chose with every step's tokens (engine docstring);
+                   rope_theta, rms_norm_eps) and `"sparse_moe"` (RMSNorm
+                   pre-norm, grouped-query attention over the index_topk
+                   cached positions a learned indexer scores highest, a
+                   renormalised top-k mixture of experts behind a linear
+                   router, untied head; adds experts_per_token,
+                   index_heads, index_head_dim, index_topk,
+                   prefill_chunk). The second keeps one state row a page
+                   beside the K/V pools, the third one indexer key a
+                   token; both report the experts they chose with every
+                   step's tokens, the third also the positions it attended
+                   (engine docstring);
   * `engine`     — the continuous-batching scheduler: admit/evict between
                    decode steps, copy-on-write prefix reuse, speculative
                    draft-verify decode (exact under greedy), backpressure

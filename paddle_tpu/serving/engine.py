@@ -55,6 +55,24 @@ chose for every token it computed; the engine keeps them per page
 (`_page_routes`, the host twin of the pools) and hands a finished request
 its `routes`, which is what a reference needs to follow the same experts.
 
+Learned sparse attention (the "sparse_moe" block): a third, per-token pool
+of indexer keys rides the same page ids as K and V (allocate, share,
+copy-on-write and release move all three). A prompt longer than
+`cfg.prefill_chunk` runs as CONSECUTIVE windows of that many tokens through
+the window program, each attending the pool the ones before it filled (one
+`serving.prefill.chunk` span each; a chunk is not yet batched with decode
+rows). Page tables round up to a multiple of 32 pages past 32 instead of a
+power of two (`cfg.page_bucket_step`): 261 live pages scan 288, not 512.
+What each layer's attention was given is a device output of every step, in
+the form the attention used: the mask a window attended under, packed into
+words, and the positions a decode row gathered. The host copies it only for
+MARKED requests (submitted with `keep_selection`, at most `MARK_ROWS`
+running at a time), whose `selection` a reference can then follow and
+judge. The block routes
+`experts_per_token` experts a token, so its `routes` are `[n, layers, k]`.
+Speculation, tensor parallelism and the fleet handoff are refused for it
+as for "cca_moe".
+
 Compile discipline (the PR 2 machinery doing serving duty):
   * prefill compiles once per prompt-length bucket (pow2 rounding); suffix
     prefill once per (suffix-bucket, page-bucket);
@@ -215,6 +233,40 @@ def ngram_draft(tokens, k: int, window: int = 128) -> list[int]:
     return [toks[-1]] * k
 
 
+def _selection_words(pieces: list, page_size: int) -> np.ndarray:
+    """A marked request's pieces, one a step in order, as one array of
+    `GenRequest.selection` words: a window's piece is words already ([n,
+    layers, G, page_size] int32), a decode step's the positions its row
+    gathered ([1, layers, kk], -1 none)."""
+    runs = []                   # consecutive decode steps convert together
+    for p in pieces:
+        if p.ndim == 3 and runs and runs[-1][0].ndim == 3:
+            runs[-1].append(p)
+        else:
+            runs.append([p])
+    top = max(int(p.max(initial=0)) for p in pieces if p.ndim == 3) \
+        if any(p.ndim == 3 for p in pieces) else 0
+    G = max([top // page_size // 32 + 1]
+            + [p.shape[2] for p in pieces if p.ndim == 4])
+    out = []
+    for run in runs:
+        words = np.zeros((sum(map(len, run)), run[0].shape[1], G, page_size),
+                         np.uint32)
+        if run[0].ndim == 4:
+            words[:, :, :run[0].shape[2]] = run[0].view(np.uint32)
+        else:
+            row = 0
+            for p in run:       # kk differs where a table fits the selection
+                n, layer, at = np.nonzero(p >= 0)
+                page, slot = np.divmod(p[n, layer, at], page_size)
+                np.bitwise_or.at(
+                    words, (row + n, layer, page // 32, slot),
+                    np.uint32(1) << (page % 32).astype(np.uint32))
+                row += len(p)
+        out.append(words)
+    return np.concatenate(out)
+
+
 class GenRequest:
     """One generate request's lifetime.
 
@@ -251,9 +303,34 @@ class GenRequest:
                            if deadline_s and deadline_s > 0 else None)
         self.t_first_token: float | None = None
         self.t_done: float | None = None
-        # mixture-of-experts blocks: [cache_len, layers] expert ids, one row
-        # per position whose K/V the engine computed, set when it finishes
+        # mixture-of-experts blocks: [cache_len, layers] expert ids (with
+        # k > 1 experts a token [cache_len, layers, k]), one row per
+        # position whose K/V the engine computed, set when it finishes
         self.routes = None
+        # learned sparse attention: `keep_selection` asks for `selection`;
+        # `marked` says that this admission records it (a slot was free)
+        self.keep_selection = False
+        self.marked = False
+        self._select_from = 0
+        self._selected: list = []   # a step's piece each, as the device gave it
+        self._kept = None           # (first, pieces, page size) when finished
+        self._selection = None
+
+    @property
+    def selection(self):
+        """A finished MARKED request: (first position, uint32 words [n,
+        layers, G, page_size]), the cached positions each layer attended at
+        the n positions this admission computed, first onward: bit `p % 32`
+        of word `[p // 32, slot]` is position `p * page_size + slot`
+        (`sparse_moe_ops.pack_selection_fn`; a window's words are the mask
+        it attended under, a decode step's are made here from the positions
+        it gathered). None otherwise. Put together when first read, not
+        inside a serving step."""
+        if self._kept is not None:
+            first, pieces, page_size = self._kept
+            self._selection = (first, _selection_words(pieces, page_size))
+            self._kept = None
+        return self._selection
 
     @property
     def n_generated(self) -> int:
@@ -372,7 +449,7 @@ class ServingEngine:
         self._warm_ctx: int | None = None
         if self.draft_k < 0:
             raise ValueError(f"draft_k must be >= 0, got {self.draft_k}")
-        if self.cfg.stateful:
+        if self.cfg.scanned:
             unsupported = [what for what, on in (
                 ("speculative decoding (draft_k > 0)", self.draft_k > 0),
                 ("tensor parallelism (tp > 1)", self.tp > 1),
@@ -380,9 +457,9 @@ class ServingEngine:
                  shared_pool is not None or prefill_only)) if on]
             if unsupported:
                 raise NotImplementedError(
-                    f"block {self.cfg.block!r} carries per-sequence state "
-                    f"in page rows; not supported with it yet: "
-                    + "; ".join(unsupported))
+                    f"block {self.cfg.block!r} is one scanned op over "
+                    f"stacked pools (state rows, indexer keys); not "
+                    f"supported with it yet: " + "; ".join(unsupported))
         retries = int(step_retries if step_retries is not None
                       else flags.get_flag("serving_step_retries"))
         self._retry = serving_policy(max_attempts=max(1, retries),
@@ -471,8 +548,8 @@ class ServingEngine:
         # every peer's context, so only the FIRST engine materializes them.
         # Identically-seeded startup runs make the weight re-init above a
         # bitwise no-op on a shared scope.
-        if self.cfg.stateful:
-            create_stacked_pools(self._scope, *sv_model._cca_pool_geometry(
+        if self.cfg.scanned:
+            create_stacked_pools(self._scope, *sv_model.stacked_pool_geometry(
                 self.cfg, self.pool_pages, self.page_size))
         elif not self._scope.has_var(
                 pool_var_names(self.cfg.num_layers)[0][0]):
@@ -484,9 +561,11 @@ class ServingEngine:
         # host twin of the pools, for blocks that route
         self._page_routes = None
         if "routes" in self._decode_io:
+            per_token = (self.cfg.experts_per_token,) \
+                if self.cfg.experts_per_token > 1 else ()
             self._page_routes = np.zeros(
-                (self.pool_pages, self.page_size, self.cfg.num_layers),
-                np.int8)
+                (self.pool_pages, self.page_size, self.cfg.num_layers)
+                + per_token, np.int8)
         self._grid_steps_by_signature: dict[tuple[int, int], int] = {}
         self._prefill_run = self._exec_target(self._prefill_prog)
         self._decode_run = self._exec_target(self._decode_prog)
@@ -523,6 +602,9 @@ class ServingEngine:
             # per-sequence state rows and expert routing (ISSUE 25)
             "state.restores": 0, "state.recomputed_tokens": 0,
             "moe.experts_touched": 0, "moe.layer_steps": 0,
+            # chunked prefill and learned sparse attention (ISSUE 29)
+            "prefill.chunks": 0, "sparse.context_tokens": 0,
+            "sparse.selected_tokens": 0, "sparse.layer_steps": 0,
         }
         # the learned controller's per-engine epoch hook (ISSUE 20):
         # shadow by default — one perf_counter read per step until an
@@ -632,7 +714,27 @@ class ServingEngine:
                    "draft_k": pend.draft_k})
         return True
 
-    def warmup_decode(self, max_context: int | None = None) -> int:
+    def _page_bucket(self, n: int) -> int:
+        """The page-table width a step with `n` live pages compiles for: a
+        power of two, or past `cfg.page_bucket_step` pages a multiple of
+        it."""
+        step = self.cfg.page_bucket_step
+        if step and n > step:
+            return -(-n // step) * step
+        return _round_up_pow2(n)
+
+    def _mark_feed(self, rows=()) -> dict:
+        """The `sv_mark` feed of a decode step that hands selections back
+        ({} where the program has none): `rows` first, -1 for the unused
+        slots."""
+        if sv_model.MARK_FEED not in self._decode_io["feeds"]:
+            return {}
+        mark = np.full((sv_model.MARK_ROWS,), -1, np.int32)
+        mark[:len(rows)] = rows
+        return {sv_model.MARK_FEED: mark}
+
+    def warmup_decode(self, max_context: int | None = None,
+                      min_context: int = 1) -> int:
         """Precompile the decode-step signature lattice for contexts up to
         `max_context` (default max_position): which (batch-bucket,
         page-bucket) a step hits depends on how many requests HAPPEN to be
@@ -640,16 +742,19 @@ class ServingEngine:
         uncompiled and a mid-measurement XLA compile (~1s on CPU) then
         decides an open-loop verdict instead of the engines. Drives every
         signature with fully-masked rows (zero valid lengths): writes drop,
-        outputs are ignored, no engine state moves. Returns the signature
-        count."""
+        outputs are ignored, no engine state moves. `min_context` starts the
+        lattice at the page bucket of the shortest context the caller will
+        serve (a cell whose every context is a long document skips the
+        buckets below it). Returns the signature count."""
         max_context = min(int(max_context or self.cfg.max_position),
                           self.cfg.max_position)
         # remembered so a controller actuation that changes the bucket
         # geometry can re-warm the SAME context range before serving
         self._warm_ctx = max_context
-        pbs = sorted({_round_up_pow2(self.pool.pages_for(c))
-                      for c in range(1, max_context + 2)})
-        bbs = sorted({_round_up_pow2(b)
+        pbs = sorted({self._page_bucket(self.pool.pages_for(c))
+                      for c in range(max(1, int(min_context)),
+                                     max_context + 2)})
+        bbs = sorted({self._row_bucket(b)
                       for b in range(1, self.max_inflight + 1)})
         n = 0
         for bb in bbs:
@@ -671,7 +776,8 @@ class ServingEngine:
                             sv_model.POS_FEED: np.zeros((bb,), np.int32),
                             sv_model.PAGES_FEED: pages,
                             sv_model.MASK_FEED: np.zeros((bb, 1),
-                                                         np.float32)}
+                                                         np.float32),
+                            **self._mark_feed()}
                     outs = self._exe.run(
                         self._decode_run, feed=feed,
                         fetch_list=self._step_fetches(self._decode_io),
@@ -750,10 +856,14 @@ class ServingEngine:
     def submit(self, prompt, max_new_tokens: int, eos_id=None,
                sampling: "SamplingParams | dict | None" = None,
                deadline_s: float | None = None,
-               priority: int | None = None) -> int:
+               priority: int | None = None,
+               keep_selection: bool = False) -> int:
         """Queue one request. `deadline_s`/`priority` default to the
         engine-wide knobs (FLAGS_serving_deadline_s /
-        FLAGS_serving_priority_default). Under overload (any
+        FLAGS_serving_priority_default). `keep_selection` (a family whose
+        attention selects) asks for `GenRequest.selection`: what each layer
+        attended comes to the host with every step of this request and of
+        no other. Under overload (any
         FLAGS_serving_shed_* floor tripped) this sheds WAITING requests of
         strictly lower priority to make room, and raises AdmissionRejected
         with a retry-after hint when that is not enough — explicit refusal
@@ -801,6 +911,7 @@ class ServingEngine:
         self._next_rid += 1
         req = GenRequest(rid, prompt, max_new_tokens, eos_id, sampling,
                          deadline_s=deadline_s, priority=int(priority))
+        req.keep_selection = bool(keep_selection)
         self.requests[rid] = req
         self._waiting.append(req)
         obs.event("serving.request", {"rid": rid, "phase": "queued",
@@ -841,10 +952,10 @@ class ServingEngine:
         The caller (the prefill replica) grants the lease over the
         returned page table before anything else moves."""
         req = self.requests[rid]
-        if self.cfg.stateful:
+        if self.cfg.scanned:
             raise NotImplementedError(
                 f"block {self.cfg.block!r}: the handoff would have to move "
-                f"state rows with the pages")
+                f"state rows or indexer keys with the pages")
         if req.state != RUNNING:
             raise ValueError(
                 f"request {rid} is {req.state}; only RUNNING (prefilled) "
@@ -1373,6 +1484,7 @@ class ServingEngine:
         self._running = []
         for req in survivors:
             del req.all_tokens[req.prompt_len:]  # replay from the prompt
+            self._unmark(req)
             req.pages = []
             req.cached_len = 0
             req.state = WAITING
@@ -1489,23 +1601,27 @@ class ServingEngine:
     def _step_fetches(io: dict, logits: str = "logits") -> list:
         """The one fetch list of a step's program (it is part of the
         executor's compile signature, so warm-up and serving share it): the
-        greedy token, the logits, and the experts chosen where the block
-        routes."""
-        return [io["next_token"], io[logits]] + (
-            [io["routes"]] if "routes" in io else [])
+        greedy token, the logits, the experts chosen where the block
+        routes, and the positions attended where it selects them."""
+        return [io["next_token"], io[logits]] + [
+            io[k] for k in ("routes", "selection") if k in io]
 
     def _run_step(self, kind: str, target, io: dict, feed: dict,
-                  greedy: bool, logits: str = "logits") -> tuple:
+                  greedy: bool, logits: str = "logits",
+                  selection: bool = False) -> tuple:
         """Dispatch one prefill / window / decode step; returns (next_token,
-        routes or None, logits or None) on the host. One compiled program
-        serves greedy and sampled rows: the logits are a device output of
-        every step and cross the host link (`[rows, V]` float32) only when
-        a sampler needs them."""
-        routed = "routes" in io
-        nxt, lg, *routes = self._dispatch(
+        routes or None, logits or None, selection or None) on the host. One
+        compiled program serves greedy and sampled rows, marked and
+        unmarked: the logits (`[rows, V]` float32) and the selection are
+        device outputs of every step and cross the host link only when a
+        sampler, or a marked request, needs them."""
+        extra = [k for k in ("routes", "selection") if k in io]
+        nxt, lg, *rest = self._dispatch(
             kind, target, feed, self._step_fetches(io, logits),
-            to_host=[True, not greedy] + [True] * routed)
-        return nxt, (routes[0] if routed else None), lg
+            to_host=[True, not greedy] + [k == "routes" or selection
+                                          for k in extra])
+        got = dict(zip(extra, rest))
+        return nxt, got.get("routes"), lg, got.get("selection")
 
     def _count_routed(self, per_expert) -> None:
         for e in np.flatnonzero(per_expert):
@@ -1533,14 +1649,48 @@ class ServingEngine:
         self._page_routes[pages, [r.cache_len % ps for r in rows]] = routes
         L, E = self.cfg.num_layers, self.cfg.num_experts
         per_layer = np.zeros((L, E), np.int64)
-        np.add.at(per_layer, (np.broadcast_to(np.arange(L), routes.shape),
+        layer_of = np.arange(L).reshape((1, L) + (1,) * (routes.ndim - 2))
+        np.add.at(per_layer, (np.broadcast_to(layer_of, routes.shape),
                               routes), 1)
         self._count_routed(per_layer.sum(axis=0))
         self._count("moe.experts_touched", int(np.count_nonzero(per_layer)))
         self._count("moe.layer_steps", L)
 
+    def _mark(self, req: GenRequest) -> None:
+        """Mark `req` (just admitted) if it asked for its selection and one
+        of the decode step's `MARK_ROWS` slots is free."""
+        self._unmark(req)
+        req.marked = req.keep_selection and "selection" in self._decode_io \
+            and sum(r.marked for r in self._running) < sv_model.MARK_ROWS
+        req._select_from = min(req.cached_len, len(req.all_tokens) - 1)
+
+    @staticmethod
+    def _unmark(req: GenRequest) -> None:
+        req.marked, req._selected = False, []
+
+    @staticmethod
+    def _keep_selection(req: GenRequest, sel) -> None:
+        """`sel`, n positions leading: what the next n positions of marked
+        `req` attended, as the step gave it (every admission starts the
+        record anew, so the positions follow one another from
+        `_select_from`)."""
+        req._selected.append(np.asarray(sel))
+
     def _seq_bucket(self, n: int) -> int:
-        return min(self.cfg.max_position, max(8, _round_up_pow2(n)))
+        """The window length a prompt (or suffix, or a chunked prefill's
+        tail) of `n` tokens compiles for: a power of two from 8; a block
+        that prefills in chunks starts at 128 (or its chunk, if smaller):
+        its windows stream every expert whatever their length, and each
+        length is a program to compile."""
+        low = min(128, self.cfg.prefill_chunk) if self.cfg.prefill_chunk \
+            else 8
+        return min(self.cfg.max_position, max(low, _round_up_pow2(n)))
+
+    def _row_bucket(self, n: int) -> int:
+        """The row count a decode step of `n` rows compiles for: a power of
+        two from `cfg.min_row_bucket` up to max_inflight's."""
+        return min(_round_up_pow2(max(n, self.cfg.min_row_bucket)),
+                   _round_up_pow2(self.max_inflight))
 
     def _first_token(self, req: GenRequest, nxt, last_logits) -> int:
         """The prompt's first generated token: compiled argmax for greedy
@@ -1561,13 +1711,17 @@ class ServingEngine:
         the last prompt slot under copy-on-write and emits token one)."""
         n = len(req.all_tokens)
         req.state = RUNNING
+        self._mark(req)
         self._running.append(req)
         if req.cached_len >= n:
             self._count("prefix_full_hits")
             with obs.span("serving.accept"):
                 self._register_prefix(req)
             return
-        if req.cached_len > 0:
+        routes = None
+        if self.cfg.prefill_chunk:
+            nxt, lg = self._prefill_chunks(req, n)   # notes its own routes
+        elif req.cached_len > 0:
             with obs.span("serving.feed_build"):
                 suf = n - req.cached_len
                 sb = self._seq_bucket(suf)
@@ -1584,7 +1738,7 @@ class ServingEngine:
                         sv_model.START_FEED: np.asarray([req.cached_len],
                                                         np.int32),
                         sv_model.LEN_FEED: np.asarray([suf], np.int32)}
-            nxt, routes, lg = self._run_step(
+            nxt, routes, lg, _ = self._run_step(
                 "suffix_prefill", self._window_run, self._window_io, feed,
                 req.sampling.is_greedy, "last_logits")
             self.stats["prefill_signatures"].add(("suffix", sb, pb))
@@ -1602,7 +1756,7 @@ class ServingEngine:
                 feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
                         sv_model.PAGES_FEED: pages,
                         sv_model.LEN_FEED: np.asarray([n], np.int32)}
-            nxt, routes, lg = self._run_step(
+            nxt, routes, lg, _ = self._run_step(
                 "prefill", self._prefill_run, self._prefill_io, feed,
                 req.sampling.is_greedy, "last_logits")
             self.stats["prefill_signatures"].add((sb, pb))
@@ -1614,6 +1768,46 @@ class ServingEngine:
                                   np.asarray(routes)[0, :n - req.cached_len])
             self._register_prefix(req)
             self._accept_token(req, self._first_token(req, nxt, lg))
+
+    def _prefill_chunks(self, req: GenRequest, n: int) -> tuple:
+        """Positions cached_len.. of `req` as consecutive windows of
+        `cfg.prefill_chunk` tokens through the window program (one
+        `serving.prefill.chunk` span each): a window writes its K/V and
+        indexer keys into the pool and attends the pool, so the next one
+        finds them there. Every chunk runs at the page bucket of the whole
+        request: one compiled program a window length. Returns the last
+        window's (next token, last logits)."""
+        chunk = self.cfg.prefill_chunk
+        pb = self._page_bucket(len(req.pages))
+        pages = np.zeros((1, pb), np.int32)
+        pages[0, :len(req.pages)] = req.pages
+        for i, c0 in enumerate(range(req.cached_len, n, chunk)):
+            m = min(chunk, n - c0)
+            with obs.span("serving.prefill.chunk", rid=req.rid, chunk=i,
+                          tokens=m):
+                with obs.span("serving.feed_build"):
+                    sb = chunk if m == chunk else self._seq_bucket(m)
+                    tok = np.zeros((1, sb), np.int32)
+                    tok[0, :m] = req.all_tokens[c0:c0 + m]
+                    pos = np.minimum(c0 + np.arange(sb, dtype=np.int32),
+                                     self.cfg.max_position - 1)[None, :]
+                    feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
+                            sv_model.PAGES_FEED: pages,
+                            sv_model.START_FEED: np.asarray([c0], np.int32),
+                            sv_model.LEN_FEED: np.asarray([m], np.int32)}
+                nxt, routes, lg, sel = self._run_step(
+                    "prefill_chunk", self._window_run, self._window_io,
+                    feed, req.sampling.is_greedy, "last_logits",
+                    selection=req.marked)
+                self.stats["prefill_signatures"].add(("suffix", sb, pb))
+                self._count("prefill_tokens_computed", m)
+                self._count("prefill.chunks")
+                with obs.span("serving.accept"):
+                    if routes is not None:
+                        self._note_routes(req, c0, np.asarray(routes)[0, :m])
+                    if req.marked:
+                        self._keep_selection(req, sel[0, :m])
+        return nxt, lg
 
     def _register_prefix(self, req: GenRequest) -> None:
         """Index the request's full PROMPT pages so later arrivals sharing
@@ -1640,6 +1834,10 @@ class ServingEngine:
                 req.routes = self._page_routes[
                     np.asarray(req.pages, np.int64)[g // self.page_size],
                     g % self.page_size]
+            if req.marked and req._selected:
+                req._kept = (req._select_from, req._selected,
+                             self.page_size)
+            self._unmark(req)
             self._release(req)
             req.state = FINISHED
             req.t_done = now
@@ -1730,6 +1928,7 @@ class ServingEngine:
         self._running.remove(req)
         self._release(req)
         req.state = WAITING
+        self._unmark(req)
         req.preemptions += 1
         self._count("preemptions")
         # head of the waiting queue: a preempted request lost work, so it
@@ -1744,14 +1943,17 @@ class ServingEngine:
         if steps is None:
             cfg = self.cfg
             pool = self._scope.find_var(
-                "kv_cache.k" if cfg.stateful
+                "kv_cache.k" if cfg.scanned
                 else pool_var_names(cfg.num_layers)[0][0])
-            # the "cca_moe" block attends with float32 queries whatever
-            # dtype its weights and pools have
+            # the scanned blocks attend with float32 queries whatever dtype
+            # their weights and pools have; a family that selects leaves
+            # the paged kernel for a gather once a table outgrows its
+            # selection
             steps = self._grid_steps_by_signature[(bb, pb)] = \
+                0 if cfg.selects_within(pb * self.page_size) else \
                 attention_ops.paged_decode_grid_steps(
                     (bb, cfg.num_heads, cfg.head_dim),
-                    "float32" if cfg.stateful else cfg.dtype,
+                    "float32" if cfg.scanned else cfg.dtype,
                     pool.shape, pool.dtype, pb, tp=self.tp)
         return steps
 
@@ -1767,9 +1969,8 @@ class ServingEngine:
         if not rows:
             return False
         with obs.span("serving.feed_build"):
-            bb = min(_round_up_pow2(len(rows)),
-                     _round_up_pow2(self.max_inflight))
-            pb = _round_up_pow2(max(len(r.pages) for r in rows))
+            bb = self._row_bucket(len(rows))
+            pb = self._page_bucket(max(len(r.pages) for r in rows))
             tok = np.zeros((bb, 1), np.int32)
             pos = np.zeros((bb,), np.int32)
             pages = np.zeros((bb, pb), np.int32)
@@ -1779,22 +1980,34 @@ class ServingEngine:
                 pos[i] = r.cache_len
                 pages[i, :len(r.pages)] = r.pages
                 mask[i, 0] = 1.0
+            marked = [i for i, r in enumerate(rows) if r.marked]
             feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
-                    sv_model.PAGES_FEED: pages, sv_model.MASK_FEED: mask}
+                    sv_model.PAGES_FEED: pages, sv_model.MASK_FEED: mask,
+                    **self._mark_feed(marked)}
         self._step_rows = len(rows)
         sp.note(rows=len(rows), bb=bb, pb=pb)
         self._count("decode_context_pages",
                     sum(r.cache_len // self.page_size + 1 for r in rows))
         self._count("decode_grid_steps", self._decode_grid_steps(bb, pb))
-        nxt, routes, lg = self._run_step(
+        if self.cfg.selects_within(pb * self.page_size):
+            L, k = self.cfg.num_layers, self.cfg.index_topk
+            self._count("sparse.context_tokens",
+                        L * sum(r.cache_len + 1 for r in rows))
+            self._count("sparse.selected_tokens",
+                        L * sum(min(k, r.cache_len + 1) for r in rows))
+            self._count("sparse.layer_steps", L)
+        nxt, routes, lg, sel = self._run_step(
             "decode", self._decode_run, self._decode_io, feed,
-            all(r.sampling.is_greedy for r in rows))
+            all(r.sampling.is_greedy for r in rows),
+            selection=bool(marked))
         with obs.span("serving.accept"):
             nxt = np.asarray(nxt).reshape(-1)
             self._count("decode_steps")
             self.stats["decode_signatures"].add((bb, pb))
             if routes is not None:
                 self._note_decode_routes(rows, routes)
+            for j, i in enumerate(marked):
+                self._keep_selection(rows[i], sel[j][None])
             for i, r in enumerate(rows):
                 if r.sampling.is_greedy:
                     t = int(nxt[i])

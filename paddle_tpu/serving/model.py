@@ -1,4 +1,4 @@
-"""Serving program builders. TWO block families exist, and
+"""Serving program builders. THREE block families exist, and
 `DecoderConfig.block` selects one:
 
   * `"post_ln"` (the default; every other field at its default is the "bert
@@ -16,8 +16,22 @@
     rope_theta, rms_norm_eps. Its layers are ONE op scanned over weights
     stacked `[L, ...]` and its pools are stacked too (`kv_cache.
     STACKED_POOLS`, with one state row a page).
+  * `"sparse_moe"` (`ops/sparse_moe_ops.py`): RMSNorm pre-norm,
+    grouped-query attention with per-head q/k norms and full rotary that
+    reads only the `index_topk` cached positions a learned indexer scores
+    highest (a few small query heads over one cached key head, kept in a
+    third, per-token pool, `kv_cache.INDEX_POOL`), a renormalised top-k
+    mixture of SwiGLU experts behind a linear router, an untied head. It
+    reads num_kv_heads, attn_head_dim, num_experts, experts_per_token,
+    index_heads, index_head_dim, index_topk, prefill_chunk, rope_theta,
+    rms_norm_eps. Scanned and stacked like "cca_moe". A prompt runs as
+    consecutive windows of `prefill_chunk` tokens through the window
+    program (its prefill program is that window at start 0), and every
+    program also returns what each layer's attention was given
+    (`selection`: a window's mask in packed words, a decode row's
+    gathered positions).
 
-Either family is expressed several times over ONE weight namespace:
+Every family is expressed several times over ONE weight namespace:
 
   * `build_prefill_program` — whole-prompt forward (dense causal attention:
     with bucket padding on the right, every query position attends only to
@@ -47,11 +61,12 @@ from ..framework import default_main_program
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 from ..initializer import Constant, Normal, StackedNormal
-from ..ops import cca_moe_ops
-from .kv_cache import (STACKED_POOLS, declare_pool_vars,
+from ..ops import cca_moe_ops, sparse_moe_ops
+from .kv_cache import (INDEX_POOL, STACKED_POOLS, declare_pool_vars,
                        declare_stacked_pools, pool_var_names)
 
 __all__ = ["DecoderConfig", "decoder_tiny", "cca_moe_tiny",
+           "sparse_moe_tiny",
            "build_prefill_program",
            "build_decode_program", "build_window_program",
            "build_full_forward_program", "apply_tp_annotations"]
@@ -65,6 +80,9 @@ START_FEED = "sv_start"   # first global slot of a prefill/verify window
 MASK_FEED = "batch_mask"  # the PR 2 row-mask convention (data_feeder)
 COW_SRC_FEED = "sv_cow_src"  # copy-on-write: source page id
 COW_DST_FEED = "sv_cow_dst"  # copy-on-write: destination page id
+MARK_FEED = "sv_mark"     # "sparse_moe" decode: rows whose selection is kept
+# how many rows of a decode step can have their selection handed back
+MARK_ROWS = 8
 
 
 @dataclass
@@ -79,7 +97,7 @@ class DecoderConfig:
     max_position: int = 512
     dtype: str = "float32"
     # which block family (see the module docstring); the fields below are
-    # read by "cca_moe" only
+    # read by "cca_moe" and "sparse_moe" only
     block: str = "post_ln"
     num_kv_heads: int = 0          # 0: as many as num_heads
     attn_head_dim: int = 0         # 0: hidden_size // num_heads
@@ -90,11 +108,36 @@ class DecoderConfig:
     partial_rotary_factor: float = 1.0
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-5
+    # "sparse_moe" only
+    experts_per_token: int = 1
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    prefill_chunk: int = 0         # 0: a prompt is one prefill program
+    # a deployment's choice, any family: the fewest rows a decode step is
+    # compiled for (a power of two). Steps of fewer live rows pay for that
+    # many; every row bucket below it is a program less to compile
+    min_row_bucket: int = 1
 
     def __post_init__(self):
-        if self.block not in ("post_ln", "cca_moe"):
+        if self.block not in ("post_ln", "cca_moe", "sparse_moe"):
             raise ValueError(f"unknown DecoderConfig.block {self.block!r} "
-                             f"(post_ln | cca_moe)")
+                             f"(post_ln | cca_moe | sparse_moe)")
+        if self.block == "sparse_moe":
+            if min(self.index_heads, self.index_head_dim, self.index_topk,
+                   self.prefill_chunk) < 1 or self.index_head_dim % 4:
+                raise ValueError(
+                    "block 'sparse_moe' needs index_heads, index_head_dim "
+                    "(a multiple of 4: half of it carries rotary), "
+                    "index_topk and prefill_chunk")
+            if not 1 <= self.experts_per_token <= self.num_experts:
+                raise ValueError("block 'sparse_moe' needs num_experts >= "
+                                 "experts_per_token >= 1")
+            if self.num_heads % self.kv_heads:
+                raise ValueError("num_kv_heads must divide num_heads")
+        if self.min_row_bucket < 1 \
+                or self.min_row_bucket & (self.min_row_bucket - 1):
+            raise ValueError("min_row_bucket must be a power of two")
         if self.block == "cca_moe":
             if (self.cca_time0, self.cca_time1) != (2, 2):
                 raise ValueError(
@@ -122,6 +165,32 @@ class DecoderConfig:
         in the state pool)."""
         return self.block == "cca_moe"
 
+    @property
+    def scanned(self) -> bool:
+        """Whether the layers are one scanned op over stacked weights and
+        stacked pools (`kv_cache.STACKED_POOLS`). Speculation, tensor
+        parallelism and the fleet handoff are not written for that form."""
+        return self.block in ("cca_moe", "sparse_moe")
+
+    @property
+    def selects(self) -> bool:
+        """Whether attention reads a learned selection of the cache: an
+        indexer scores every slot of a row's page table, a third pool
+        holds its keys, and every step reports what it attended."""
+        return self.block == "sparse_moe"
+
+    def selects_within(self, slots: int) -> bool:
+        """Whether a decode step over a page table of `slots` slots runs
+        the indexer (a table that fits the selection is attended whole)."""
+        return self.selects and slots > self.index_topk
+
+    @property
+    def page_bucket_step(self) -> int:
+        """0: page tables round up to a power of two. n: past n pages
+        they round to a multiple of n, for a family whose every step scans
+        its whole table (the dead part stays under an eighth)."""
+        return 32 if self.selects else 0
+
 
 def decoder_tiny() -> DecoderConfig:
     return DecoderConfig(vocab_size=97, hidden_size=32, num_layers=2,
@@ -135,6 +204,19 @@ def cca_moe_tiny(**over) -> DecoderConfig:
               num_kv_heads=2, attn_head_dim=8, ffn_size=32, num_experts=4,
               router_hidden_size=16, partial_rotary_factor=0.5,
               rope_theta=5e6, max_position=64, block="cca_moe")
+    kw.update(over)
+    return DecoderConfig(**kw)
+
+
+def sparse_moe_tiny(**over) -> DecoderConfig:
+    """The "sparse_moe" block at test size: 4 query heads over 2 KV heads
+    of 8, an indexer of 2 heads of 8 keeping 8 positions, 8 experts of
+    width 32 and 2 a token, prompts in chunks of 16."""
+    kw = dict(vocab_size=97, hidden_size=32, num_layers=3, num_heads=4,
+              num_kv_heads=2, attn_head_dim=8, ffn_size=32, num_experts=8,
+              experts_per_token=2, index_heads=2, index_head_dim=8,
+              index_topk=8, prefill_chunk=16, rope_theta=1e7,
+              rms_norm_eps=1e-6, max_position=128, block="sparse_moe")
     kw.update(over)
     return DecoderConfig(**kw)
 
@@ -158,6 +240,14 @@ def cca_state_width(cfg: DecoderConfig) -> int:
 def _cca_pool_geometry(cfg: DecoderConfig, num_pages: int, page_size: int):
     return (cfg.num_layers, num_pages, page_size,
             cfg.kv_heads * cfg.head_dim, cca_state_width(cfg), cfg.dtype)
+
+
+def stacked_pool_geometry(cfg: DecoderConfig, num_pages: int,
+                          page_size: int) -> tuple:
+    """`kv_cache.stacked_pool_shapes`' arguments for a scanned family."""
+    geometry = _sparse_pool_geometry if cfg.block == "sparse_moe" \
+        else _cca_pool_geometry
+    return geometry(cfg, num_pages, page_size)
 
 
 def _cca_param_specs(cfg: DecoderConfig) -> dict:
@@ -239,6 +329,154 @@ def _cca_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
                           num_pages=int(num_pages)))
     return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
             "routes": outs["Routes"][0]}
+
+
+# -- the "sparse_moe" family -------------------------------------------------
+
+
+def _sparse_geometry(cfg: DecoderConfig) -> dict:
+    return {"num_heads": cfg.num_heads, "num_kv_heads": cfg.kv_heads,
+            "head_dim": cfg.head_dim, "rope_theta": float(cfg.rope_theta),
+            "eps": float(cfg.rms_norm_eps), "index_heads": cfg.index_heads,
+            "index_dim": cfg.index_head_dim, "index_topk": cfg.index_topk,
+            "experts_per_token": cfg.experts_per_token}
+
+
+def _sparse_pool_geometry(cfg: DecoderConfig, num_pages: int,
+                          page_size: int):
+    return (cfg.num_layers, num_pages, page_size,
+            cfg.kv_heads * cfg.head_dim, 0, cfg.dtype, cfg.index_head_dim)
+
+
+_SPARSE_POOLS = (("KPool", STACKED_POOLS[0]), ("VPool", STACKED_POOLS[1]),
+                 ("IPool", INDEX_POOL))
+
+
+def _sparse_param_specs(cfg: DecoderConfig) -> dict:
+    """name -> (shape, dtype, initializer), layers stacked on the leading
+    axis. Matrices are drawn at fan_in^-0.5; the attention's way back into
+    the residual stream at 0.5x and an expert's output (weighed by a share
+    of about 1/k) at 2x of that, so that both branches of every layer move
+    the logits; the router at 2x, so that its probabilities differ by more
+    than rounding. The large ones are in `cfg.dtype`; norms and the router
+    in float32."""
+    L, H, F, E = cfg.num_layers, cfg.hidden_size, cfg.ffn_size, \
+        cfg.num_experts
+    nh, nkv, dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    J, D = cfg.index_heads, cfg.index_head_dim
+    f32, big = "float32", cfg.dtype
+    near_one = Normal(1.0, 0.05)
+    fan = Normal(0.0, H ** -0.5)
+    return {
+        "dec.word_emb": ([cfg.vocab_size, H], big, Normal(0.0, 0.02)),
+        "dec.lm_head": ([H, cfg.vocab_size], big, fan),
+        "dec.final_norm.scale": ([H], f32, near_one),
+        "attn_norm": ([L, H], f32, near_one),
+        "wq": ([L, H, nh * dh], big, fan),
+        "wk": ([L, H, nkv * dh], big, fan),
+        "wv": ([L, H, nkv * dh], big, fan),
+        "wo": ([L, nh * dh, H], big, Normal(0.0, 0.5 * (nh * dh) ** -0.5)),
+        "q_norm": ([L, dh], f32, near_one),
+        "k_norm": ([L, dh], f32, near_one),
+        "wqi": ([L, H, J * D], big, fan),
+        "wki": ([L, H, D], big, fan),
+        "ki_norm_w": ([L, D], f32, near_one),
+        "ki_norm_b": ([L, D], f32, Normal(0.0, 0.02)),
+        "ww": ([L, H, J], big, fan),
+        "ffn_norm": ([L, H], f32, near_one),
+        "router_w": ([L, H, E], f32, Normal(0.0, 2.0 * H ** -0.5)),
+        "w_gate": ([L, E, H, F], big, StackedNormal(0.0, H ** -0.5)),
+        "w_up": ([L, E, H, F], big, StackedNormal(0.0, H ** -0.5)),
+        "w_down": ([L, E, F, H], big, StackedNormal(0.0, 2.0 * F ** -0.5)),
+    }
+
+
+def _sparse_stack(cfg: DecoderConfig, mode: str, tok, pos,
+                  num_pages: int = 0, page_size: int = 0, **feeds):
+    """Append the one `sparse_moe_stack` op of a program; returns its
+    outputs (next_token, logits, routes, selection)."""
+    helper = LayerHelper("sparse_moe_stack")
+    params = {key: helper.create_parameter(
+        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
+        for key, (shape, dtype, init) in _sparse_param_specs(cfg).items()}
+    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
+              "Head": [params["dec.lm_head"]],
+              "FinalNorm": [params["dec.final_norm.scale"]],
+              "LayerParams": [params[k]
+                              for k in sparse_moe_ops.LAYER_PARAMS],
+              "Experts": [params[k] for k in sparse_moe_ops.EXPERT_PARAMS]}
+    inputs.update({slot: [var] for slot, var in feeds.items()})
+    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
+            for slot, dtype in (("NextToken", "int32"),
+                                ("Logits", "float32"), ("Routes", "int32"),
+                                ("Selection", "int32"))}
+    if mode != "full":
+        declare_stacked_pools(default_main_program().global_block,
+                              *_sparse_pool_geometry(cfg, num_pages,
+                                                     page_size))
+        for slot, name in _SPARSE_POOLS:
+            inputs[slot] = [name]
+            outs[slot + "Out"] = [name]
+    helper.append_op("sparse_moe_stack", inputs, outs,
+                     dict(_sparse_geometry(cfg), mode=mode,
+                          num_pages=int(num_pages)))
+    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
+            "routes": outs["Routes"][0],
+            "selection": outs["Selection"][0]}
+
+
+def _sparse_window_io(out):
+    return {"next_token": out["next_token"], "last_logits": out["logits"],
+            "routes": out["routes"], "selection": out["selection"]}
+
+
+def _sparse_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
+    return _sparse_window_io(_sparse_stack(
+        cfg, "prefill", tok, pos, num_pages, page_size, PageTable=pages,
+        Lens=lens))
+
+
+def _sparse_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
+                   lens):
+    # a prompt's chunk or the suffix behind a prefix hit (no verify window)
+    return _sparse_window_io(_sparse_stack(
+        cfg, "window", tok, pos, num_pages, page_size, PageTable=pages,
+        Start=start, Lens=lens))
+
+
+def _sparse_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
+    # [MARK_ROWS] int32: the rows whose selection comes back, -1 unused
+    mark = L.data(name=MARK_FEED, shape=[MARK_ROWS], dtype="int32",
+                  append_batch_size=False)
+    return dict(_sparse_stack(cfg, "decode", tok, pos, num_pages, page_size,
+                              PageTable=pages, Mask=mask, Mark=mark),
+                extra_feeds=[MARK_FEED])
+
+
+def _sparse_full(cfg, tok, pos):
+    out = _sparse_stack(cfg, "full", tok, pos)
+    return {"logits": out["logits"], "routes": out["routes"],
+            "selection": out["selection"]}
+
+
+def _sparse_cow(cfg, num_pages, page_size, src, dst):
+    # the page's K/V slabs and its indexer keys, in every layer
+    declare_stacked_pools(default_main_program().global_block,
+                          *_sparse_pool_geometry(cfg, num_pages, page_size))
+    _stacked_copy_page([name for _, name in _SPARSE_POOLS], num_pages, src,
+                       dst)
+
+
+def _stacked_copy_page(pools, num_pages, src, dst):
+    """Append the copy of page Src to page Dst in every layer of three
+    stacked pools (`cca_state_copy_page` copies rows `l * num_pages +
+    page` of whatever it is given)."""
+    slots = dict(zip(("KPool", "VPool", "SPool"), pools))
+    LayerHelper("cca_state_copy_page").append_op(
+        "cca_state_copy_page",
+        dict({k: [v] for k, v in slots.items()}, Src=[src], Dst=[dst]),
+        {k + "Out": [v] for k, v in slots.items()},
+        {"num_pages": int(num_pages)})
 
 
 def _proj(x, size, name, act=None):
@@ -324,9 +562,16 @@ def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int):
     pos = L.data(name=POS_FEED, shape=[cfg.max_position], dtype="int32")
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     lens = L.data(name=LEN_FEED, shape=[], dtype="int32")
-    return dict(_FAMILY[cfg.block]["prefill"](cfg, num_pages, page_size, tok,
-                                              pos, pages, lens),
-                feeds=[TOK_FEED, POS_FEED, PAGES_FEED, LEN_FEED])
+    return _with_feeds(_FAMILY[cfg.block]["prefill"](
+        cfg, num_pages, page_size, tok, pos, pages, lens),
+        [TOK_FEED, POS_FEED, PAGES_FEED, LEN_FEED])
+
+
+def _with_feeds(io: dict, feeds: list) -> dict:
+    """A family body's outputs plus the program's feed names: the shared
+    ones and any the family declared itself (`extra_feeds`)."""
+    io = dict(io)
+    return dict(io, feeds=feeds + io.pop("extra_feeds", []))
 
 
 def _cca_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
@@ -408,9 +653,9 @@ def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     start = L.data(name=START_FEED, shape=[], dtype="int32")
     lens = L.data(name=LEN_FEED, shape=[], dtype="int32")
-    return dict(_FAMILY[cfg.block]["window"](cfg, num_pages, page_size, tp,
-                                             tok, pos, pages, start, lens),
-                feeds=[TOK_FEED, POS_FEED, PAGES_FEED, START_FEED, LEN_FEED])
+    return _with_feeds(_FAMILY[cfg.block]["window"](
+        cfg, num_pages, page_size, tp, tok, pos, pages, start, lens),
+        [TOK_FEED, POS_FEED, PAGES_FEED, START_FEED, LEN_FEED])
 
 
 def _cca_window(cfg, num_pages, page_size, tp, tok, pos, pages, start, lens):
@@ -457,12 +702,7 @@ def _cca_cow(cfg, num_pages, page_size, src, dst):
     # the page's K/V slab and its state row, in every layer
     declare_stacked_pools(default_main_program().global_block,
                           *_cca_pool_geometry(cfg, num_pages, page_size))
-    slots = dict(zip(("KPool", "VPool", "SPool"), STACKED_POOLS))
-    LayerHelper("cca_state_copy_page").append_op(
-        "cca_state_copy_page",
-        dict({k: [v] for k, v in slots.items()}, Src=[src], Dst=[dst]),
-        {k + "Out": [v] for k, v in slots.items()},
-        {"num_pages": int(num_pages)})
+    _stacked_copy_page(STACKED_POOLS, num_pages, src, dst)
 
 
 def _post_ln_cow(cfg, num_pages, page_size, src, dst):
@@ -490,9 +730,9 @@ def build_decode_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     pos = L.data(name=POS_FEED, shape=[], dtype="int32")
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     mask = L.data(name=MASK_FEED, shape=[1], dtype="float32")
-    return dict(_FAMILY[cfg.block]["decode"](cfg, num_pages, page_size, tp,
-                                             tok, pos, pages, mask),
-                feeds=[TOK_FEED, POS_FEED, PAGES_FEED, MASK_FEED])
+    return _with_feeds(_FAMILY[cfg.block]["decode"](
+        cfg, num_pages, page_size, tp, tok, pos, pages, mask),
+        [TOK_FEED, POS_FEED, PAGES_FEED, MASK_FEED])
 
 
 def _cca_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
@@ -618,4 +858,7 @@ _FAMILY = {
                 "full": _post_ln_full},
     "cca_moe": {"prefill": _cca_prefill, "window": _cca_window,
                 "cow": _cca_cow, "decode": _cca_decode, "full": _cca_full},
+    "sparse_moe": {"prefill": _sparse_prefill, "window": _sparse_window,
+                   "cow": _sparse_cow, "decode": _sparse_decode,
+                   "full": _sparse_full},
 }

@@ -155,6 +155,41 @@ def test_cca_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
         "decode": 0, "prefill": 0, "window": 0, "cow": 0}
 
 
+def test_sparse_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
+    """The four programs of the "sparse_moe" block at the served widths
+    (benchmark/configs/keye_vl2_30b_a3b.json: 32 query / 4 KV heads of 128,
+    an indexer of 16 heads of 64 keeping 2,048 positions, 128 experts of
+    width 768 top-8, the whole vocabulary), one layer deep over stacked
+    pools as large as the cell's (1 x 10,752 = 6 x 1,792 pages of 128
+    bfloat16 slots: a one-layer index pool would fit the chip's VMEM and be
+    prefetched there whole), decode at 64 rows over the cell's 288-page
+    tables: Mosaic takes the expert kernel at F = 768, and the scanned
+    layer carries K, V and the per-token indexer-key pool without a copy
+    of any."""
+    import json
+    import os
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "keye_vl2_30b_a3b.json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = DecoderConfig(**dict(engine["config_kwargs"], num_layers=1))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        out = chip_smoke.pool_layout_phase(
+            cfg, page_size=engine["page_size"],
+            pool_pages=engine["pool_pages"] * 6, rows=64, device=v5e_chip)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert out["pool_sized_copies"] == {
+        "decode": 0, "prefill": 0, "window": 0, "cow": 0}
+
+
 @pytest.mark.parametrize("q_shape,pool,dtype,bucket", [
     ((64, 12, 64), (3072, 16, 768), "float32", 32),
     ((64, 8, 128), (24 * 640, 128, 256), "bfloat16", 16),

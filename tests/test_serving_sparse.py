@@ -1,0 +1,497 @@
+"""The "sparse_moe" block family (learned sparse attention behind an
+indexer, a top-k mixture of experts) through ServingEngine, on the CPU at
+toy size with seeded float32 weights, against the plain reference
+`benchmark/reference/keye_lm.py` (which imports nothing from paddle_tpu).
+The indexer keeps 8 positions, pages hold 8 tokens and a prompt runs in
+chunks of 16, so contexts of 5-70 tokens lie on both sides of the
+selection."""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import keye_lm
+from paddle_tpu import observability as obs
+from paddle_tpu import unique_name
+from paddle_tpu.executor import Executor, Scope
+from paddle_tpu.framework import Program, program_guard
+from paddle_tpu.ops import sparse_moe_ops
+from paddle_tpu.serving import DecoderConfig, ServingEngine
+from paddle_tpu.serving import model as sv_model
+
+PS = 8
+TOL = 2e-4          # float32 on both sides: rounding order only
+
+
+def _engine(cfg=None, **kw):
+    kw.setdefault("page_size", PS)
+    kw.setdefault("pool_pages", 64)
+    kw.setdefault("max_inflight", 4)
+    kw.setdefault("seed", 3)
+    return ServingEngine(cfg or sv_model.sparse_moe_tiny(), **kw)
+
+
+def _positions(words):
+    """Selection words [G, page_size] -> the sorted positions they name."""
+    bits = (np.asarray(words).view(np.uint32)[:, None, :]
+            >> np.arange(32, dtype=np.uint32)[None, :, None]) & 1
+    return np.flatnonzero(bits.reshape(-1))
+
+
+def _prompts(seed, *lengths, shared=()):
+    rng = np.random.default_rng(seed)
+    return [list(shared) + rng.integers(1, 97, n).tolist() for n in lengths]
+
+
+def _serve(eng, prompts, new=6, keep=True):
+    rids = [eng.submit(p, new, keep_selection=keep) for p in prompts]
+    eng.run_until_drained()
+    out = [eng.requests[r] for r in rids]
+    assert all(r.state == "finished" for r in out)
+    assert eng.audit_pool() == ([], []) and eng.leaked_pages() == 0
+    return out
+
+
+def _graded(eng, prompts, done, ahead=None):
+    """check_sequences on what the engine served, its experts and (for a
+    marked request) its selection followed; `ahead`, the selection at the
+    positions before it."""
+    params = keye_lm.read_params(eng._scope.find_var, eng.cfg)
+    return keye_lm.check_sequences(
+        params, [(p, r.out_tokens, r.routes, r.selection, ahead)
+                 for p, r in zip(prompts, done)], eng.cfg)
+
+
+def _assert_right(eng, prompts, done, gap=TOL, margin=1e-4):
+    cfg = eng.cfg
+    for r, g in zip(done, _graded(eng, prompts, done)):
+        assert r.routes.shape == (r.cache_len, cfg.num_layers,
+                                  cfg.experts_per_token)
+        assert g["gap"] <= gap and g["route_margin"] <= margin \
+            and g["select_margin"] <= margin, g
+
+
+def test_full_forward_matches_reference():
+    cfg = sv_model.sparse_moe_tiny()
+    prog, startup = Program(), Program()
+    startup.random_seed = 7
+    with program_guard(prog, startup), unique_name.guard():
+        io = sv_model.build_full_forward_program(cfg)
+    exe, scope = Executor(), Scope()
+    exe.run(startup, scope=scope)
+    tok = np.asarray(_prompts(0, 40), np.int32)
+    pos = np.arange(40, dtype=np.int32)[None, :]
+    logits, routes, sel = exe.run(
+        prog, feed={sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos},
+        fetch_list=[io["logits"], io["routes"], io["selection"]],
+        scope=scope)
+    params = keye_lm.read_params(scope.find_var, cfg)
+    x, route_margin, _, _ = keye_lm.forward(params, tok[0], cfg)
+    want = np.asarray(x) @ np.asarray(params["lm_head"], np.float32)
+    np.testing.assert_allclose(logits[0], want, atol=TOL)
+    # the program's experts and selection, followed, leave no margin
+    # the sequence as one page: words [S, L, 1, S], bit 0 a position
+    sel = sel[0].view(np.uint32)
+    assert sel.shape == (40, cfg.num_layers, 1, 40)
+    x2, route_margin, select_margin, followed = keye_lm.forward(
+        params, tok[0], cfg, routes[0], (0, sel))
+    np.testing.assert_allclose(np.asarray(x2), np.asarray(x), atol=TOL)
+    assert route_margin.max() <= 1e-5 and select_margin.max() <= 1e-5
+    assert followed.all()
+    # position 3 has four positions to attend, position 20 exactly top-k
+    assert list(_positions(sel[3, 0])) == [0, 1, 2, 3]
+    assert len(_positions(sel[20, 0])) == 8
+    assert len(np.unique(routes)) == cfg.num_experts
+
+
+@pytest.mark.parametrize("length,new", [(5, 3), (5, 12), (40, 6), (70, 9)],
+                         ids=["under_topk", "across_topk", "three_chunks",
+                              "five_chunks"])
+def test_chunked_prefill_then_decode_matches_reference(length, new):
+    eng = _engine()
+    prompts = _prompts(1, length)
+    done = _serve(eng, prompts, new=new)
+    assert eng.stats["prefill.chunks"] == -(-length // 16)
+    first, sel = done[0].selection
+    assert first == 0 and sel.dtype == np.uint32 \
+        and sel.shape[:2] == (done[0].cache_len, 3) and sel.shape[3] == PS
+    # every position attends min(top-k, what exists), itself included
+    for t in (0, length - 1, done[0].cache_len - 1):
+        for layer in range(3):
+            kept = _positions(sel[t, layer])
+            assert len(kept) == min(8, t + 1) and (kept <= t).all()
+    _assert_right(eng, prompts, done)
+
+
+def test_batched_requests_of_different_lengths():
+    eng = _engine()
+    prompts = _prompts(2, 3, 30, 17, 50)
+    done = _serve(eng, prompts, new=6)
+    assert eng.stats["decode_signatures"]
+    assert eng.stats["sparse.layer_steps"] > 0
+    # what the indexer scored and kept, as the engine counts them
+    assert 0 < eng.stats["sparse.selected_tokens"] \
+        < eng.stats["sparse.context_tokens"]
+    _assert_right(eng, prompts, done)
+
+
+def test_prefix_hit_keeps_the_indexer_keys_with_the_page():
+    shared = _prompts(3, 48)[0]                   # six whole pages
+    first, second = (shared + tail for tail in _prompts(4, 7, 11))
+    eng = _engine()
+    _serve(eng, [first])
+    hit = _serve(eng, [second])
+    assert eng.stats["prefix_hit_tokens"] == 48
+    assert hit[0].selection[0] == 48              # the suffix onward
+    # past top-k the suffix attends document positions chosen by keys that
+    # another request wrote
+    assert (_positions(hit[0].selection[1][0, 0]) < 48).any()
+    cold = _serve(_engine(prefix_cache=False), [second])
+    assert hit[0].out_tokens == cold[0].out_tokens
+    np.testing.assert_array_equal(hit[0].routes, cold[0].routes)
+    np.testing.assert_array_equal(hit[0].selection[1],
+                                  cold[0].selection[1][48:])
+    _assert_right(eng, [second], hit)
+
+
+def test_reference_shares_a_prefix_forward(monkeypatch):
+    """`check_sequences` computes the tokens before a selection once for
+    the sequences that share them; the two halves are the one forward."""
+    shared = _prompts(3, 48)[0]
+    prompts = [shared + tail for tail in _prompts(4, 7, 11, 5)]
+    eng = _engine()
+    ahead = _serve(eng, prompts[:1])[0].selection[1][:48]
+    done = _serve(eng, prompts[1:])
+    assert [r.selection[0] for r in done] == [48, 48]
+    whole = _graded(eng, prompts[1:], done)
+    calls = []
+    forward = keye_lm.forward
+    monkeypatch.setattr(keye_lm, "forward", lambda *a, **kw: (
+        calls.append(kw.get("keep", 0)), forward(*a, **kw))[1])
+    monkeypatch.setattr(keye_lm, "_LONG", 16)
+    monkeypatch.setattr(keye_lm, "_QUERY_BLOCK", 8)
+    split = _graded(eng, prompts[1:], done)
+    assert calls == [48, 0, 0]            # one prefix forward, two suffixes
+    for a, b in zip(whole, split):
+        assert abs(a["gap"] - b["gap"]) <= 1e-5
+        assert b["route_margin"] <= 1e-4 and b["select_margin"] <= 1e-4
+        assert b["route_margin_unfollowed"] <= 1e-4
+    # with the selection of the request that prefilled the shared tokens
+    # every position is followed, and judged
+    calls.clear()
+    for b in _graded(eng, prompts[1:], done, ahead):
+        assert b["route_margin"] <= 1e-4 and b["select_margin"] <= 1e-4
+        assert b["route_margin_unfollowed"] == 0.0
+    assert calls == [48, 0, 0]
+
+
+def test_a_wrong_selection_ahead_fails_by_select_margin():
+    """The document's positions are judged through `ahead`: the words of
+    another document's prefill there read as a wrong selection."""
+    shared, other = _prompts(3, 48, 48)
+    eng = _engine()
+    ahead = _serve(eng, [other + [5]])[0].selection[1][:48]
+    _serve(eng, [shared + [5]])
+    prompt = shared + _prompts(4, 7)[0]
+    done = _serve(eng, [prompt])
+    assert _graded(eng, [prompt], done, ahead)[0]["select_margin"] > 1.0
+
+
+def test_full_hit_copies_the_page_on_write():
+    prompt = _prompts(5, 32)[0]                   # four whole pages
+    eng = _engine()
+    _serve(eng, [prompt])
+    again = _serve(eng, [prompt], new=8)
+    assert eng.stats["prefix_full_hits"] == 1 and eng.stats["cow_copies"] >= 1
+    cold = _serve(_engine(prefix_cache=False), [prompt], new=8)
+    assert again[0].out_tokens == cold[0].out_tokens
+    _assert_right(eng, [prompt], again)
+
+
+def test_copy_on_write_moves_keys_routes_and_indexer_keys():
+    prompt = _prompts(6, 20)[0]
+    want = _serve(_engine(), [prompt], new=10)[0]
+    eng = _engine()
+    rid = eng.submit(prompt, 10, keep_selection=True)
+    while eng.requests[rid].n_generated < 2:
+        eng.step()
+    req = eng.requests[rid]
+    old = list(req.pages)
+    before = np.asarray(eng._scope.find_var("kv_cache.index"))
+    assert eng._cow(req, len(req.pages) - 1)      # the page being written
+    assert req.pages[-1] != old[-1] and eng.stats["cow_copies"] == 1
+    after = np.asarray(eng._scope.find_var("kv_cache.index"))
+    for layer in range(eng.cfg.num_layers):
+        row = layer * eng.pool_pages
+        assert np.abs(before[row + old[-1]]).max() > 0
+        np.testing.assert_array_equal(after[row + req.pages[-1]],
+                                      before[row + old[-1]])
+    eng.run_until_drained()
+    assert req.out_tokens == want.out_tokens
+    np.testing.assert_array_equal(req.routes, want.routes)
+    np.testing.assert_array_equal(req.selection[1], want.selection[1])
+    assert eng.audit_pool() == ([], []) and eng.leaked_pages() == 0
+
+
+def test_preemption_and_resume():
+    prompts = _prompts(7, 20, 22)
+    roomy = _serve(_engine(), prompts, new=14)
+    eng = _engine(pool_pages=8, prefix_cache=False)
+    tight = _serve(eng, prompts, new=14)
+    assert eng.stats["preemptions"] > 0
+    assert [r.out_tokens for r in tight] == [r.out_tokens for r in roomy]
+    # a preempted request is marked anew when it comes back, and its
+    # selection then covers the replayed positions too
+    assert any(r.preemptions and r.selection[0] == 0 for r in tight)
+    _assert_right(eng, prompts, tight)
+
+
+def _newest_indices(scores, limit, k):
+    kk = min(int(k), scores.shape[-1])
+    at = limit[..., None] - 1 - jnp.arange(kk, dtype=jnp.int32)
+    return jnp.where(at >= 0, at, -1)
+
+
+def _newest_mask(scores, limit, k):
+    at = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+    return (at < limit[..., None]) & (at >= limit[..., None] - k)
+
+
+@pytest.mark.parametrize("fault", ["mask", "indices", "both"])
+def test_a_wrong_selection_fails_by_select_margin(fault, monkeypatch):
+    """An engine that attends "the newest k" instead of the indexer's k, in
+    its windows (the mask), in its decode steps (the indices) or in both,
+    serves tokens whose logits a random model barely tells apart, and is
+    caught by the margin of what it hands back: each form is what its
+    attention used, so a fault in one alone cannot hide behind the
+    other."""
+    if fault != "mask":
+        monkeypatch.setattr(sparse_moe_ops, "select_indices_fn",
+                            _newest_indices)
+    if fault != "indices":
+        monkeypatch.setattr(sparse_moe_ops, "select_mask_fn", _newest_mask)
+    eng = _engine()
+    prompts = _prompts(8, 40)
+    done = _serve(eng, prompts, new=8)
+    graded = _graded(eng, prompts, done)[0]
+    assert graded["select_margin"] > 1.0, graded
+    assert min(graded["select_margin_by_layer"]) > 1.0, graded
+    # followed, the wrong selection reproduces the engine's own logits: the
+    # logit gap alone would have passed it
+    assert graded["gap"] <= TOL
+
+
+@pytest.mark.parametrize("fault", ["one_more", "one_fewer", "the_future"])
+def test_a_miscounted_mask_fails_by_select_margin(fault, monkeypatch):
+    """A window's mask that keeps a position too many, one too few, or one
+    the query cannot see reads `MISCOUNT`, whatever its scores."""
+    right = sparse_moe_ops.select_mask_fn
+
+    def wrong(scores, limit, k):
+        keep = right(scores, limit, k)
+        at = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+        if fault == "one_more":
+            first_out = jnp.argmin(keep | (at >= limit[..., None]), axis=-1)
+            return keep | ((at == first_out[..., None])
+                           & (limit[..., None] > k))
+        if fault == "one_fewer":
+            return keep & (at != jnp.argmax(keep, axis=-1)[..., None])
+        return keep | (at == limit[..., None])
+
+    monkeypatch.setattr(sparse_moe_ops, "select_mask_fn", wrong)
+    eng = _engine()
+    prompts = _prompts(8, 40)
+    done = _serve(eng, prompts, new=2)
+    assert _graded(eng, prompts, done)[0]["select_margin"] \
+        == keye_lm.MISCOUNT
+
+
+def test_only_marked_requests_bring_their_selection_to_the_host(monkeypatch):
+    eng = _engine()
+    fetched = []
+    dispatch = eng._dispatch
+
+    def spy(kind, target, feed, fetch_list, to_host=None):
+        outs = dispatch(kind, target, feed, fetch_list, to_host=to_host)
+        fetched.append([None if o is None else o.shape for o in outs])
+        return outs
+
+    monkeypatch.setattr(eng, "_dispatch", spy)
+    prompts = _prompts(9, 30, 30)
+    rids = [eng.submit(p, 4, keep_selection=keep)
+            for p, keep in zip(prompts, (True, False))]
+    eng.run_until_drained()
+    done = [eng.requests[r] for r in rids]
+    assert done[0].selection is not None and done[1].selection is None
+    # selections cross only for the marked request: a window's words [1,
+    # bucket, L, G, page], the marked rows' positions [M, L, kk] a decode
+    # step
+    crossed = {shapes[-1] for shapes in fetched if len(shapes) == 4}
+    assert crossed == {None, (1, 16, 3, 1, PS), (sv_model.MARK_ROWS, 3, 8)}
+    fetched.clear()
+    _serve(eng, _prompts(10, 30), new=4, keep=False)
+    assert fetched and all(shapes[-1] is None for shapes in fetched)
+
+
+def test_more_than_mark_rows_asking_leaves_the_rest_unmarked():
+    eng = _engine(max_inflight=16, pool_pages=128)
+    done = _serve(eng, _prompts(17, *[12] * 10), new=3)
+    assert sum(r.selection is not None for r in done) == sv_model.MARK_ROWS
+
+
+def test_chunks_are_spans_and_counted():
+    obs.reset("serving.")
+    eng = _engine()
+    _serve(eng, _prompts(11, 40), new=2)
+    snap = obs.snapshot()
+    assert snap["counters"]["serving.prefill.chunks"] == 3
+    assert snap["histograms"]["serving.prefill.chunk.seconds"]["count"] == 3
+
+
+def test_topk_router_combines_as_a_per_token_loop():
+    rng = np.random.default_rng(12)
+    T, H, F, E, k = 9, 16, 24, 128, 8
+    z = jnp.asarray(rng.standard_normal((T, H)), jnp.float32)
+    router_w = jnp.asarray(rng.standard_normal((H, E)), jnp.float32)
+    wg, wu = (jnp.asarray(rng.standard_normal((1, E, H, F)) * H ** -0.5,
+                          jnp.float32) for _ in range(2))
+    wd = jnp.asarray(rng.standard_normal((1, E, F, H)) * F ** -0.5,
+                     jnp.float32)
+    ids, cw = sparse_moe_ops.topk_router_fn(z, router_w, k)
+    got = sparse_moe_ops.moe_topk_experts_fn(z, cw, wg, wu, wd)
+    probs = np.asarray(jax.nn.softmax(z @ router_w, axis=-1), np.float64)
+    zs = np.asarray(z, np.float64)
+    for t in range(T):
+        top = np.argsort(-probs[t])[:k]
+        assert set(top) == set(np.asarray(ids[t]))
+        share = probs[t, top] / probs[t, top].sum()
+        np.testing.assert_allclose(np.asarray(cw[t])[top], share, rtol=1e-5)
+        assert np.count_nonzero(np.asarray(cw[t])) == k
+        want = 0.0
+        for e, s in zip(top, share):
+            g = zs[t] @ np.asarray(wg[0, e], np.float64)
+            u = zs[t] @ np.asarray(wu[0, e], np.float64)
+            want = want + s * ((g / (1 + np.exp(-g)) * u)
+                               @ np.asarray(wd[0, e], np.float64))
+        np.testing.assert_allclose(np.asarray(got[t]), want, atol=1e-4)
+
+
+def test_selection_mask_and_indices_name_the_same_set():
+    rng = np.random.default_rng(13)
+    scores = rng.standard_normal((2, 5, 40)).astype(np.float32)
+    scores[0, 1, :] = 0.5                          # every score ties
+    scores[0, 2, 5:30] = -0.0                      # -0.0 ties with +0.0
+    scores[0, 2, 30:] = 0.0
+    scores[1, 0, ::3] = 7.0                        # ties above the cut
+    limit = jnp.asarray([[40, 33, 40, 6, 1], [40, 20, 9, 40, 8]], jnp.int32)
+    for k in (8, 64):
+        sel = np.asarray(sparse_moe_ops.select_indices_fn(
+            jnp.asarray(scores), limit, k))
+        mask = np.asarray(sparse_moe_ops.select_mask_fn(
+            jnp.asarray(scores), limit, k))
+        for b in range(2):
+            for s in range(5):
+                kept = sel[b, s][sel[b, s] >= 0]
+                assert len(kept) == min(k, 40, int(limit[b, s]))
+                assert sorted(kept) == list(np.flatnonzero(mask[b, s]))
+    assert sorted(np.asarray(sparse_moe_ops.select_indices_fn(
+        jnp.asarray(scores), limit, 8))[0, 1]) == list(range(8))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_moe_experts_pallas_at_width_768(dtype, monkeypatch):
+    """The served geometry's expert width: 768 = 2 tiles of 384; eight
+    non-zero combine weights a row."""
+    pme = importlib.import_module(
+        "paddle_tpu.ops.pallas_kernels.moe_experts")
+    monkeypatch.setattr(pme, "INTERPRET", True)
+    assert pme._f_tile(768) == 384 and pme._f_tile(2048) == 512
+    assert pme.experts_supported((64, 2048), (6, 128, 2048, 768),
+                                 jnp.bfloat16)
+    assert pme.experts_supported((64, 2048), (24, 16, 2048, 2048),
+                                 jnp.bfloat16)
+    rng = np.random.default_rng(14)
+    L, E, H, F, T, k = 2, 16, 128, 768, 20, 8
+    dt = jnp.dtype(dtype)
+    z = jnp.asarray(rng.standard_normal((T, H)), jnp.float32)
+    wg, wu = (jnp.asarray(rng.standard_normal((L, E, H, F)) * H ** -0.5, dt)
+              for _ in range(2))
+    wd = jnp.asarray(rng.standard_normal((L, E, F, H)) * F ** -0.5, dt)
+    cw = np.zeros((T, E), np.float32)
+    for t in range(T):
+        cw[t, rng.choice(E, k, replace=False)] = rng.dirichlet(np.ones(k))
+    assert pme.experts_supported(z.shape, wg.shape, dt)
+    tol = 2e-2 if dt.itemsize == 2 else 1e-4
+    for layer in range(L):
+        got = pme.moe_topk_experts(z, jnp.asarray(cw), wg, wu, wd, layer)
+        want = pme._reference(z, jnp.asarray(cw), wg, wu, wd, layer)
+        np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    via = sparse_moe_ops.moe_topk_experts_fn(z, jnp.asarray(cw), wg, wu, wd,
+                                             layer=1)
+    np.testing.assert_allclose(
+        via, pme._reference(z, jnp.asarray(cw), wg, wu, wd, 1),
+        rtol=tol, atol=tol)
+
+
+def test_bfloat16_engine_stays_inside_the_bfloat16_tolerances():
+    """bfloat16 weights and pools (the indexer keys too), everything else
+    float32, against the float32 reference on the same stored weights. The
+    limits are this test's own (hidden 32 rounds coarser than 2,048); the
+    cell's are in benchmark/configs/keye_vl2_30b_a3b.json."""
+    eng = _engine(sv_model.sparse_moe_tiny(dtype="bfloat16"))
+    assert eng._scope.find_var("kv_cache.index").dtype == jnp.bfloat16
+    assert eng._scope.find_var("kv_cache.index").shape == (3 * 64, 8, PS)
+    prompts = _prompts(15, 30, 45)
+    done = _serve(eng, prompts, new=8)
+    _assert_right(eng, prompts, done, gap=0.1, margin=0.3)
+    other = _serve(_engine(seed=4), prompts, new=8)
+    worst = max(g["gap"] for g in _graded(eng, prompts, other))
+    assert worst > 0.1
+
+
+def test_page_buckets_round_to_32_past_32():
+    eng = _engine(sv_model.sparse_moe_tiny(max_position=4096),
+                  pool_pages=600)
+    assert [eng._page_bucket(n) for n in (1, 3, 32, 33, 261, 288, 289)] \
+        == [1, 4, 32, 64, 288, 288, 320]
+    assert ServingEngine(sv_model.decoder_tiny(), page_size=4,
+                         pool_pages=16)._page_bucket(261) == 512
+    # the lattice starts at the shortest context's bucket
+    assert eng.warmup_decode(70 * PS, min_context=40 * PS) == 3 * 2
+    # a deployment may start its row buckets higher; nothing is raised for
+    # a family
+    assert [eng._row_bucket(n) for n in (1, 3, 4)] == [1, 4, 4]
+    low = _engine(sv_model.sparse_moe_tiny(min_row_bucket=4))
+    assert [low._row_bucket(n) for n in (1, 3, 4)] == [4, 4, 4]
+    assert low.warmup_decode(20, min_context=20) == 1
+    with pytest.raises(ValueError, match="power of two"):
+        sv_model.sparse_moe_tiny(min_row_bucket=3)
+
+
+def test_block_field_and_refusals():
+    cfg = sv_model.sparse_moe_tiny()
+    assert cfg.scanned and not cfg.stateful and cfg.selects
+    assert cfg.page_bucket_step == 32 and cfg.selects_within(9) \
+        and not cfg.selects_within(8)
+    assert not sv_model.cca_moe_tiny().selects \
+        and DecoderConfig().page_bucket_step == 0
+    assert sv_model.cca_moe_tiny().scanned and not DecoderConfig().scanned
+    with pytest.raises(ValueError, match="post_ln | cca_moe | sparse_moe"):
+        DecoderConfig(block="mamba")
+    with pytest.raises(ValueError, match="index_topk"):
+        sv_model.sparse_moe_tiny(index_topk=0)
+    with pytest.raises(ValueError, match="experts_per_token"):
+        sv_model.sparse_moe_tiny(experts_per_token=9)
+    with pytest.raises(NotImplementedError, match="draft_k"):
+        _engine(draft_k=2)
+    with pytest.raises(NotImplementedError, match="tp > 1"):
+        _engine(tp=2)
+    with pytest.raises(NotImplementedError, match="shared pool"):
+        _engine(prefill_only=True)
+    eng = _engine()
+    rid = eng.submit(_prompts(16, 5)[0], 4)
+    eng.step()
+    with pytest.raises(NotImplementedError, match="indexer keys"):
+        eng.extract_for_handoff(rid)

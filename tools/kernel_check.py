@@ -205,7 +205,9 @@ def _paged_gqa_cases(spec):
 
 def _moe_cases(spec):
     """One layer of ZAYA1's experts (16 x 2048 -> 2048 -> 2048, bfloat16)
-    out of a stack of two, at a decode batch and at a prefill window."""
+    out of a stack of two, at a decode batch and at a prefill window; and
+    one of the "sparse_moe" block's (128 x 2048 -> 768 -> 2048, top-8), at
+    a decode batch and at a 512-token chunk."""
 
     def case(tokens):
         L, E, H, F = 2, 16, 2048, 2048
@@ -222,8 +224,27 @@ def _moe_cases(spec):
                         lambda *a: spec.reference(*a, 1),
                         (z, cw, wg, wu, wd), 0, "bfloat16")
 
+    def topk_case(tokens):
+        # the "sparse_moe" geometry: 128 experts of width 768 (F tile 384),
+        # eight renormalised combine weights a row
+        L, E, H, F, k = 2, 128, 2048, 768, 8
+        ks = jax.random.split(jax.random.PRNGKey(3), 6)
+        z = _rand(ks[0], (tokens, H), "float32")
+        wg = _rand(ks[1], (L, E, H, F), "bfloat16", H ** -0.5)
+        wu = _rand(ks[2], (L, E, H, F), "bfloat16", H ** -0.5)
+        wd = _rand(ks[3], (L, E, F, H), "bfloat16", F ** -0.5)
+        vals, ids = jax.lax.top_k(jax.random.uniform(ks[4], (tokens, E)), k)
+        cw = jnp.sum(jax.nn.one_hot(ids, E)
+                     * (vals / vals.sum(-1, keepdims=True))[..., None], 1)
+        assert spec.supported(z.shape, wg.shape)
+        return _compare(lambda *a: spec.fn(*a, 1),
+                        lambda *a: spec.reference(*a, 1),
+                        (z, cw, wg, wu, wd), 0, "bfloat16")
+
     return [(f"t{t} e16 h2048 f2048 bf16 layer 1 of 2",
-             lambda t=t: case(t)) for t in (64, 300)]
+             lambda t=t: case(t)) for t in (64, 300)] + [
+        (f"t{t} e128 top8 h2048 f768 bf16 layer 1 of 2",
+         lambda t=t: topk_case(t)) for t in (64, 512)]
 
 
 CASES = {

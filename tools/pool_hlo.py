@@ -156,7 +156,8 @@ def serving_program_cases(engine, rows: int = 64, pages: int = 32,
     `rows` x `pages`, cold prefill of a `prompt` bucket, the window program
     (suffix prefill) of the same bucket behind `pages` pages, and
     copy-on-write of page 0 onto itself. Every row is masked or of length
-    0, so a run writes nothing."""
+    0, so a run writes nothing. Feeds and fetches are the engine's own
+    (`_mark_feed`, `_step_fetches`): the signature it serves with."""
     from paddle_tpu.serving import model as m
 
     e, i32 = engine, np.int32
@@ -165,21 +166,22 @@ def serving_program_cases(engine, rows: int = 64, pages: int = 32,
             m.TOK_FEED: np.zeros((rows, 1), i32),
             m.POS_FEED: np.zeros((rows,), i32),
             m.PAGES_FEED: np.zeros((rows, pages), i32),
-            m.MASK_FEED: np.zeros((rows, 1), np.float32)},
-            [e._decode_io["next_token"], e._decode_io["logits"]]),
+            m.MASK_FEED: np.zeros((rows, 1), np.float32),
+            **e._mark_feed()},
+            e._step_fetches(e._decode_io)),
         "prefill": (e._prefill_run, {
             m.TOK_FEED: np.zeros((1, prompt), i32),
             m.POS_FEED: np.zeros((1, prompt), i32),
             m.PAGES_FEED: np.zeros((1, e.pool.pages_for(prompt)), i32),
             m.LEN_FEED: np.zeros((1,), i32)},
-            [e._prefill_io["next_token"], e._prefill_io["last_logits"]]),
+            e._step_fetches(e._prefill_io, "last_logits")),
         "window": (e._window_run, {
             m.TOK_FEED: np.zeros((1, prompt), i32),
             m.POS_FEED: np.zeros((1, prompt), i32),
             m.PAGES_FEED: np.zeros((1, pages), i32),
             m.START_FEED: np.zeros((1,), i32),
             m.LEN_FEED: np.zeros((1,), i32)},
-            [e._window_io["next_token"], e._window_io["last_logits"]]),
+            e._step_fetches(e._window_io, "last_logits")),
         "cow": (e._cow_run, {
             m.COW_SRC_FEED: np.zeros((1,), i32),
             m.COW_DST_FEED: np.zeros((1,), i32)}, []),
