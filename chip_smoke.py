@@ -50,8 +50,10 @@ from paddle_tpu.ops import attention_ops
 from paddle_tpu.parallel.mesh import make_mesh
 from paddle_tpu.serving import DecoderConfig, ServingEngine
 from paddle_tpu.serving import model as sv_model
-from paddle_tpu.serving.kv_cache import pool_shape
-from tools.pool_hlo import pool_sized_copies, serving_program_hlos
+from paddle_tpu.serving.kv_cache import (INDEX_POOL, JOINED_POOL,
+                                         pool_shape)
+from tools.pool_hlo import (pool_sized_copies, serving_program_hlos,
+                            token_row_gathers)
 
 # How far below the dense oracle's best logit the logit of a token the
 # engine generated may sit. The engine and the oracle run different
@@ -268,15 +270,18 @@ def pool_layout_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
     a full context), prefill, window and copy-on-write programs, for this
     chip or for `device` (a described one), and require that none writes a
     fresh pool-sized array — a relayout copy of a whole pool (K or V, or the
-    per-token indexer-key pool of a block that has one)."""
+    joined rows and the per-token indexer-key pool of a block that has
+    them) — and that a block that selects gathers a token once."""
     eng = ServingEngine(cfg, page_size=page_size, pool_pages=pool_pages,
                         max_inflight=rows, seed=21)
     # a scanned block keeps every layer's pages in one buffer
     layers = cfg.num_layers if cfg.scanned else 1
     sizes = {layers * int(np.prod(pool_shape(pool_pages, page_size,
                                              cfg.kv_heads, cfg.head_dim)))}
-    if cfg.index_head_dim:
-        sizes.add(layers * pool_pages * cfg.index_head_dim * page_size)
+    if cfg.selects:
+        # K and V joined in one pool of 32-bit words, and the indexer keys
+        sizes = {int(np.prod(eng._scope.find_var(name).shape))
+                 for name in (JOINED_POOL, INDEX_POOL)}
     texts = serving_program_hlos(
         eng, rows=rows,
         pages=eng._page_bucket(eng.pool.pages_for(cfg.max_position)),
@@ -288,10 +293,20 @@ def pool_layout_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
                  f"the compiled {name} program moves a whole KV pool "
                  f"{len(found)} times, first: {found[0] if found else None}")
         copies[name] = len(found)
-    return {"config": f"L{cfg.num_layers} nh{cfg.num_heads} "
-                      f"dh{cfg.head_dim} pool{pool_pages}x{page_size} "
-                      f"rows{rows}",
-            "pool_sized_copies": copies}
+    out = {"config": f"L{cfg.num_layers} nh{cfg.num_heads} "
+                     f"dh{cfg.head_dim} pool{pool_pages}x{page_size} "
+                     f"rows{rows}",
+           "pool_sized_copies": copies}
+    if cfg.selects:
+        # a decode row fetches a selected token's K and V as one row
+        words = eng._scope.find_var(JOINED_POOL).shape[-1]
+        out["token_row_gathers"] = {
+            name: len(token_row_gathers(text, words))
+            for name, text in texts.items()}
+        _require(out["token_row_gathers"]["decode"] == 1,
+                 "the compiled decode program gathers a selected token "
+                 f"{out['token_row_gathers']['decode']} times a layer")
+    return out
 
 
 def main() -> int:

@@ -433,12 +433,15 @@ def cca_moe_stack_op(ctx: ExecContext):
 @register_op("cca_state_copy_page", grad="none")
 def cca_state_copy_page_op(ctx: ExecContext):
     """Copy-on-write for the stacked pools: page Src of EVERY layer to page
-    Dst (rows `l * num_pages + page`), K, V and the state row."""
+    Dst (rows `l * num_pages + page`), K, V and the state row, or as many
+    pools as the block has."""
     src = ctx.input("Src").astype(jnp.int32)[0]
     dst = ctx.input("Dst").astype(jnp.int32)[0]
     P = int(ctx.attr("num_pages"))
     out = {}
     for slot in ("KPool", "VPool", "SPool"):
+        if not ctx.has_input(slot):
+            continue
         pool = ctx.input(slot)
         rows = jnp.arange(pool.shape[0] // P, dtype=jnp.int32) * P
         out[slot + "Out"] = pool.at[rows + dst].set(pool[rows + src])

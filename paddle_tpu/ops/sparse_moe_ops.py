@@ -18,9 +18,12 @@ that runs them is `sparse_moe_stack`:
                            back as the attention used it: a decode row's
                            indices, a window's mask packed into words
                            (`pack_selection`);
-  * `sparse_decode_attention` — one query a row over the K/V rows of its
-                           selected positions, gathered from the paged pool
-                           a token row at a time;
+  * `join_rows` / `split_rows` — a token's K and V as ONE row of 32-bit
+                           words of ONE pool, so that whoever reads a token
+                           addresses it once;
+  * `sparse_decode_attention` — one query a row over the joined rows of
+                           its selected positions, gathered from the paged
+                           pool a token at a time, once;
   * `masked_window_attention` — a window of queries over the whole paged
                            context with everything unselected masked: for
                            hundreds of queries the selections cover most of
@@ -35,10 +38,10 @@ final norm, untied head) in the shapes serving needs: dense oracle
 chunk or the suffix behind a prefix hit; `prefill` is the same at start 0)
 and the ragged decode step. As in `cca_moe_ops`, the layer is written once
 and scanned over weights stacked `[L, ...]`, and the pools of all layers
-are one buffer each: K and V `[L * pages, page_size, nkv*dh]` and the
-indexer keys `[L * pages, index_dim, page_size]`, index_dim values a TOKEN,
-a page's tokens side by side on the lanes (layer l's page p is row `l *
-pages + p`).
+are one buffer each: the joined K/V rows `[L * pages, page_size, words]`
+(32-bit words; `join_rows_fn`) and the indexer keys `[L * pages, index_dim,
+page_size]`, index_dim values a TOKEN, a page's tokens side by side on the
+lanes (layer l's page p is row `l * pages + p`).
 
 The experts run through `pallas_kernels.moe_experts` in its combine-weight
 form: a token's row of the `[T, E]` weight matrix holds its k renormalised
@@ -56,7 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from .attention_ops import (_DROP_PAGE, _NEG_INF, _gather_pages,
-                            _write_rows, paged_decode_attention_fn)
+                            _write_rows)
 from .cca_moe_ops import (_experts_backend, _page_row_index, rms_norm_fn,
                           rotary_partial_fn)
 from .registry import ExecContext, register_op
@@ -230,17 +233,71 @@ def pack_selection_fn(keep, page_size: int):
     return jax.lax.bitcast_convert_type(words, jnp.int32)
 
 
-def sparse_decode_attention_fn(q, k_pool, v_pool, page_table, sel,
-                               sm_scale: float):
-    """q [B, nh, dh] float32; pools `[rows, page_size, nkv*dh]`; page_table
-    [B, P] (already shifted to the layer's rows); sel [B, kk] positions, -1
-    for none -> [B, nh, dh] float32: softmax over the selected positions
-    only. The K and V rows are gathered a token at a time from the pool
-    seen as `[rows * page_size, nkv*dh]` (whole tiles either way: no
-    copy)."""
+def join_rows_fn(k, v, dtype):
+    """k, v [..., W] -> the token's ONE row [..., words] of 32-bit words
+    (int32, as the framework spells a word), K's words then V's, each the
+    `dtype` bits of its W values. A 32-bit dtype gives W words a side. A
+    16-bit one gives W / 2: lane `i` of the side's second half rides in the
+    high 16 bits of the word whose low 16 are lane `i` of its first half,
+    so a head stays a whole 128-lane slice of words (`kv_heads` even) and
+    is read back by a mask or a shift. The chip gathers a row of 32-bit
+    words at half the cost a byte of a 16-bit row, which shares every
+    sublane word with its neighbour row (PERF.md section 6, PR 30)."""
+    def words(x):
+        x = x.astype(dtype)
+        if x.dtype.itemsize == 4:
+            return jax.lax.bitcast_convert_type(x, jnp.int32)
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
+        half = x.shape[-1] // 2
+        return jax.lax.bitcast_convert_type(
+            bits[..., :half] | (bits[..., half:] << 16), jnp.int32)
+
+    return jnp.concatenate([words(k), words(v)], axis=-1)
+
+
+def _word_values(w, dtype, halves=(False, True)):
+    """The `dtype` values that 32-bit words hold: a word whole or, of a
+    16-bit dtype, the low (False) and high (True) halves named, side by
+    side. Integer operations until the last bitcast, so every bit pattern
+    comes back as it went in."""
+    if jnp.dtype(dtype).itemsize == 4:
+        return jax.lax.bitcast_convert_type(w, dtype)
+    w = jax.lax.bitcast_convert_type(w, jnp.uint32)
+    bits = [((w >> 16) if high else (w & 0xFFFF)).astype(jnp.uint16)
+            for high in halves]
+    return jax.lax.bitcast_convert_type(
+        bits[0] if len(bits) == 1 else jnp.concatenate(bits, axis=-1), dtype)
+
+
+def split_rows_fn(rows, dtype):
+    """`join_rows_fn` read back: rows [..., words] int32 -> k, v [..., W] in
+    `dtype`, the same bits."""
+    side = rows.shape[-1] // 2
+    return (_word_values(rows[..., :side], dtype),
+            _word_values(rows[..., side:], dtype))
+
+
+def head_of_rows_fn(rows, j: int, dh: int, dtype):
+    """K and V [..., dh] of KV head `j` alone: its `dh` words of each side
+    are sliced out BEFORE they are unpacked, so the products read the
+    gathered words as they lie and nothing writes K and V out again (268 MB
+    a layer at the served shapes)."""
+    side = rows.shape[-1] // 2
+    at = j * dh % side
+    return tuple(_word_values(rows[..., lo:lo + dh], dtype, (j * dh >= side,))
+                 for lo in (at, side + at))
+
+
+def sparse_decode_attention_fn(q, kv_pool, page_table, sel, sm_scale: float,
+                               dtype):
+    """q [B, nh, dh] float32; kv_pool `[rows, page_size, words]` of joined
+    rows holding `dtype` values; page_table [B, P] (already shifted to the
+    layer's rows); sel [B, kk] positions, -1 for none -> [B, nh, dh]
+    float32: softmax over the selected positions only. A selected token is
+    gathered ONCE, K and V together, from the pool seen as `[rows *
+    page_size, words]` (whole tiles either way: no copy)."""
     B, nh, dh = q.shape
-    rows, ps, width = k_pool.shape
-    nkv, g = width // dh, nh // (width // dh)
+    rows, ps, words = kv_pool.shape
     P = page_table.shape[1]
     have = sel >= 0
     at = jnp.maximum(sel, 0)
@@ -250,12 +307,12 @@ def sparse_decode_attention_fn(q, k_pool, v_pool, page_table, sel,
     page = jnp.sum(jnp.where((at // ps)[..., None] == ordinal,
                              page_table[:, None, :], 0), axis=-1)
     flat = jnp.clip(page, 0, rows - 1) * ps + at % ps          # [B, kk]
-    k = k_pool.reshape(rows * ps, width)[flat]                 # [B, kk, W]
-    v = v_pool.reshape(rows * ps, width)[flat]
-    qg = q.reshape(B, nkv, g, dh).astype(k.dtype)
+    tokens = kv_pool.reshape(rows * ps, words)[flat]           # [B, kk, w]
+    nkv = words * 4 // 2 // jnp.dtype(dtype).itemsize // dh    # K's bytes
+    qg = q.reshape(B, nkv, nh // nkv, dh).astype(dtype)
     out = []
     for j in range(nkv):        # a KV head is a 128-lane slice of a row
-        kj, vj = (a[..., j * dh:(j + 1) * dh] for a in (k, v))
+        kj, vj = head_of_rows_fn(tokens, j, dh, dtype)
         s = jnp.einsum("bgd,bkd->bgk", qg[:, j], kj,
                        preferred_element_type=_F32) * sm_scale
         s = jnp.where(have[:, None, :], s, _NEG_INF)
@@ -292,14 +349,16 @@ def _masked_attention(q, k, v, mask, sm_scale):
     return out.reshape(B, S, nh, dh)
 
 
-def masked_window_attention_fn(q, k_pool, v_pool, page_table, mask,
-                               sm_scale: float):
+def masked_window_attention_fn(q, kv_pool, page_table, mask,
+                               sm_scale: float, dtype):
     """q [B, S, nh, dh] over the paged context of `page_table` [B, P]
-    (shifted to the layer's rows), mask [B, S, P * page_size]."""
+    (shifted to the layer's rows), mask [B, S, P * page_size]: a page's
+    slab of joined rows is gathered once and split into K and V."""
     dh = q.shape[-1]
-    nkv = k_pool.shape[2] // dh
-    return _masked_attention(q, _gather_pages(k_pool, page_table, nkv),
-                             _gather_pages(v_pool, page_table, nkv), mask,
+    k, v = split_rows_fn(_gather_pages(kv_pool, page_table, 1)[:, :, 0],
+                         dtype)
+    heads = k.shape[:2] + (k.shape[2] // dh, dh)
+    return _masked_attention(q, k.reshape(heads), v.reshape(heads), mask,
                              sm_scale)
 
 
@@ -394,7 +453,8 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
     (or `full`: the sequence as one page) attended under, as
     `pack_selection_fn` words [B, S, L, G, page_size]; the positions the
     marked rows of a decode step gathered, [M, L, kk], -1 where fewer
-    exist; and, with pools, k_pool/v_pool/i_pool as written."""
+    exist; and, with `pools` (the joined K/V rows, the indexer keys),
+    kv_pool/i_pool as written."""
     decode = mode == "decode"
     paged = mode != "full"
     if decode:
@@ -404,6 +464,7 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
     L = layer_params["wq"].shape[0]
     sm_scale = geom.head_dim ** -0.5
     tag = "decode" if decode else "prefill"
+    kv_dtype = layer_params["wk"].dtype     # K and V are stored as made
     rel = jnp.arange(S, dtype=jnp.int32)[None, :]
     if paged:
         page_size = pools[0].shape[1]
@@ -416,8 +477,8 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
         valid = (jnp.reshape(mask, (-1, 1)) > 0) if decode \
             else rel < lens[:, None]
         count = valid[:, 0].astype(jnp.int32) if decode else lens
-        # a decode step whose whole bucket fits the selection attends every
-        # live page through the dense paged kernel and scores nothing
+        # a decode step whose whole bucket fits the selection scores
+        # nothing and attends every live position of its pages' slabs
         dense_decode = decode and context <= geom.index_topk
     else:
         gpos = jnp.broadcast_to(rel, (B, S))
@@ -425,7 +486,7 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
     def layer(carry, xs):
         l, p = xs
         if paged:
-            x, k_pool, v_pool, i_pool = carry
+            x, kv_pool, i_pool = carry
             off = l * num_pages
             table = page_table + off
         else:
@@ -434,16 +495,16 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
         if paged:
             idx = _page_row_index(page_table, gpos, page_size, off, valid)
             slot = gpos % page_size
-            k_pool = _write_rows(k_pool, k.reshape(B, S, -1), idx, slot)
-            v_pool = _write_rows(v_pool, v.reshape(B, S, -1), idx, slot)
+            kv_pool = _write_rows(kv_pool, join_rows_fn(
+                k.reshape(B, S, -1), v.reshape(B, S, -1), kv_dtype), idx, slot)
             i_pool = write_index_keys_fn(i_pool, ki, page_table, off, first,
                                          count)
         if paged and dense_decode:
-            o = paged_decode_attention_fn(q[:, 0], k_pool, v_pool, table,
-                                          first + 1, sm_scale=sm_scale)
             at = jnp.arange(context, dtype=jnp.int32)[None, :]
-            sel = jnp.where(at <= first[:, None], at, -1)[:, None]
-            o = o[:, None]
+            live = (at <= first[:, None])[:, None]              # [B, 1, T]
+            o = masked_window_attention_fn(q, kv_pool, table, live,
+                                           sm_scale, kv_dtype)
+            sel = jnp.where(live, at[:, None], -1)
         else:
             if paged:
                 ki_ctx = i_pool[jnp.clip(table, 0, i_pool.shape[0] - 1)]
@@ -453,13 +514,13 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
             if decode:
                 sel = select_indices_fn(scores, gpos + 1, geom.index_topk)
                 o = sparse_decode_attention_fn(
-                    q[:, 0], k_pool, v_pool, table, sel[:, 0],
-                    sm_scale)[:, None]
+                    q[:, 0], kv_pool, table, sel[:, 0], sm_scale,
+                    kv_dtype)[:, None]
             else:
                 keep = select_mask_fn(scores, gpos + 1, geom.index_topk)
                 if paged:
-                    o = masked_window_attention_fn(q, k_pool, v_pool, table,
-                                                   keep, sm_scale)
+                    o = masked_window_attention_fn(q, kv_pool, table, keep,
+                                                   sm_scale, kv_dtype)
                 else:
                     o = _masked_attention(q, k.astype(emb.dtype),
                                           v.astype(emb.dtype), keep,
@@ -470,7 +531,7 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                                  geom, tag)
         if decode:
             sel = sel[:, 0][mark]                               # [M, kk]
-        carry = (y, k_pool, v_pool, i_pool) if paged else (y,)
+        carry = (y, kv_pool, i_pool) if paged else (y,)
         return carry, (ids, sel)
 
     init = (x,) + (tuple(pools) if paged else ())
@@ -491,7 +552,7 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
            "routes": routes[:, 0] if decode else routes,
            "selection": selection}
     if paged:
-        out.update(k_pool=carry[1], v_pool=carry[2], i_pool=carry[3])
+        out.update(kv_pool=carry[1], i_pool=carry[2])
     return out
 
 
@@ -505,7 +566,7 @@ def sparse_moe_stack_op(ctx: ExecContext):
     """The whole decoder in one op; see `sparse_moe_stack_fn`. inputs: Tok,
     Pos, Emb, Head, FinalNorm, LayerParams (the `LAYER_PARAMS`, in order),
     Experts (`EXPERT_PARAMS`), and by mode PageTable, Lens, Start, Mask,
-    Mark (decode), KPool/VPool/IPool. attrs: mode and the geometry. Outputs:
+    Mark (decode), KVPool/IPool. attrs: mode and the geometry. Outputs:
     NextToken (greedy), Logits, Routes, Selection, and the pools under
     their own names."""
     mode = ctx.attr("mode")
@@ -523,8 +584,7 @@ def sparse_moe_stack_op(ctx: ExecContext):
         ctx.input("Pos").astype(jnp.int32), ctx.input("Emb"),
         ctx.input("Head"), ctx.input("FinalNorm"), params,
         tuple(ctx.inputs("Experts")), geom,
-        pools=(ctx.input("KPool"), ctx.input("VPool"), ctx.input("IPool"))
-        if paged else None,
+        pools=(ctx.input("KVPool"), ctx.input("IPool")) if paged else None,
         page_table=opt("PageTable"), lens=opt("Lens"), start=opt("Start"),
         mask=ctx.input("Mask") if ctx.has_input("Mask") else None,
         mark=opt("Mark"), num_pages=int(ctx.attr("num_pages", 0)))
@@ -532,6 +592,5 @@ def sparse_moe_stack_op(ctx: ExecContext):
            "Selection": out["selection"],
            "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
     if paged:
-        res.update(KPoolOut=out["k_pool"], VPoolOut=out["v_pool"],
-                   IPoolOut=out["i_pool"])
+        res.update(KVPoolOut=out["kv_pool"], IPoolOut=out["i_pool"])
     return res

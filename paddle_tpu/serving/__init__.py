@@ -31,7 +31,8 @@ Four pieces, one runtime:
                    index_heads, index_head_dim, index_topk,
                    prefill_chunk). The second keeps one state row a page
                    beside the K/V pools, the third one indexer key a
-                   token; both report the experts they chose with every
+                   token beside ONE pool that holds a token's K and V as
+                   one row; both report the experts they chose with every
                    step's tokens, the third also the positions it attended
                    (engine docstring);
   * `engine`     — the continuous-batching scheduler: admit/evict between

@@ -55,9 +55,11 @@ chose for every token it computed; the engine keeps them per page
 (`_page_routes`, the host twin of the pools) and hands a finished request
 its `routes`, which is what a reference needs to follow the same experts.
 
-Learned sparse attention (the "sparse_moe" block): a third, per-token pool
-of indexer keys rides the same page ids as K and V (allocate, share,
-copy-on-write and release move all three). A prompt longer than
+Learned sparse attention (the "sparse_moe" block): a per-token pool of
+indexer keys rides the same page ids as the K/V rows (allocate, share,
+copy-on-write and release move both), and a token's K and V are ONE row of
+ONE pool (`kv_cache.JOINED_POOL`), because a decode row gathers single
+tokens and the chip's gather pays by the row. A prompt longer than
 `cfg.prefill_chunk` runs as CONSECUTIVE windows of that many tokens through
 the window program, each attending the pool the ones before it filled (one
 `serving.prefill.chunk` span each; a chunk is not yet batched with decode
@@ -1942,19 +1944,19 @@ class ServingEngine:
         steps = self._grid_steps_by_signature.get((bb, pb))
         if steps is None:
             cfg = self.cfg
-            pool = self._scope.find_var(
-                "kv_cache.k" if cfg.scanned
-                else pool_var_names(cfg.num_layers)[0][0])
-            # the scanned blocks attend with float32 queries whatever dtype
-            # their weights and pools have; a family that selects leaves
-            # the paged kernel for a gather once a table outgrows its
-            # selection
-            steps = self._grid_steps_by_signature[(bb, pb)] = \
-                0 if cfg.selects_within(pb * self.page_size) else \
-                attention_ops.paged_decode_grid_steps(
+            if cfg.selects:   # gathers through XLA at every context
+                steps = 0
+            else:
+                pool = self._scope.find_var(
+                    "kv_cache.k" if cfg.scanned
+                    else pool_var_names(cfg.num_layers)[0][0])
+                # the scanned blocks attend with float32 queries whatever
+                # dtype their weights and pools have
+                steps = attention_ops.paged_decode_grid_steps(
                     (bb, cfg.num_heads, cfg.head_dim),
                     "float32" if cfg.scanned else cfg.dtype,
                     pool.shape, pool.dtype, pb, tp=self.tp)
+            self._grid_steps_by_signature[(bb, pb)] = steps
         return steps
 
     def _decode_once(self, sp) -> bool:

@@ -52,7 +52,8 @@ import jax.numpy as jnp
 
 __all__ = ["PagedKVPool", "PrefixCache", "OwnedPoolView", "pool_var_names",
            "pool_shape", "create_device_pools", "declare_pool_vars",
-           "STACKED_POOLS", "INDEX_POOL", "stacked_pool_shapes",
+           "STACKED_POOLS", "INDEX_POOL", "JOINED_POOL",
+           "stacked_pool_shapes",
            "declare_stacked_pools",
            "create_stacked_pools"]
 
@@ -111,20 +112,26 @@ def create_device_pools(scope, num_layers: int, num_pages: int,
 # (model.py "cca_moe", "sparse_moe"): all layers in ONE K and ONE V buffer, a
 # layer's page p at row `l * num_pages + p`, and beside them what the family
 # keeps besides K/V: one float32 STATE row a page ("cca_moe") or one
-# indexer key a TOKEN ("sparse_moe"). The allocator below is the same
-# one: a page id names a K/V slab and its state row or indexer-key slab in
-# every layer, so share, copy-on-write and release move them together.
+# indexer key a TOKEN ("sparse_moe"). A family that GATHERS single tokens
+# ("sparse_moe") keeps a token's K and V in ONE row of ONE pool instead of
+# two. The allocator below is the same one: a page id names a K/V slab and
+# its state row or indexer-key slab in every layer, so share, copy-on-write
+# and release move them together.
 STACKED_POOLS = ("kv_cache.k", "kv_cache.v", "kv_cache.state")
 INDEX_POOL = "kv_cache.index"
+JOINED_POOL = "kv_cache.kv"
 
 
 def stacked_pool_shapes(num_layers: int, num_pages: int, page_size: int,
                         kv_width: int, state_width: int, dtype: str,
-                        index_width: int = 0):
+                        index_width: int = 0, joined: bool = False):
     """[(name, shape, dtype)] of the stacked pools. K and V rows are
     `kv_width = num_kv_heads * head_dim` wide (`pool_shape`'s lane-dense
-    row). With `state_width` a state pool holds one float32 row a page: the
-    state after the page's latest token, final once the page is full. With
+    row), in a pool each or, `joined`, side by side in one row of 32-bit
+    words of one pool (`sparse_moe_ops.join_rows_fn`: the same bytes, and a
+    token read with one address). With `state_width` a state pool holds one
+    float32 row a page: the state after the page's latest token, final once
+    the page is full. With
     `index_width` an index pool holds `index_width` values a token in the
     K/V pools' dtype, `[rows, index_width, page_size]`: a page's tokens side
     by side on the lanes, so that a page of 128 slots is whole 128-lane
@@ -132,7 +139,11 @@ def stacked_pool_shapes(num_layers: int, num_pages: int, page_size: int,
     dimension would not do) and the scores' matmul reads it as it lies."""
     rows = int(num_layers) * int(num_pages)
     kv = (rows, int(page_size), int(kv_width))
-    pools = [(STACKED_POOLS[0], kv, dtype), (STACKED_POOLS[1], kv, dtype)]
+    if joined:
+        words = 2 * int(kv_width) * jnp.dtype(dtype).itemsize // 4
+        pools = [(JOINED_POOL, kv[:2] + (words,), "int32")]
+    else:
+        pools = [(STACKED_POOLS[0], kv, dtype), (STACKED_POOLS[1], kv, dtype)]
     if state_width:
         pools.append((STACKED_POOLS[2], (rows, int(state_width)), "float32"))
     if index_width:

@@ -20,7 +20,8 @@
     grouped-query attention with per-head q/k norms and full rotary that
     reads only the `index_topk` cached positions a learned indexer scores
     highest (a few small query heads over one cached key head, kept in a
-    third, per-token pool, `kv_cache.INDEX_POOL`), a renormalised top-k
+    per-token pool, `kv_cache.INDEX_POOL`, beside ONE pool of joined K/V
+    rows, `kv_cache.JOINED_POOL`), a renormalised top-k
     mixture of SwiGLU experts behind a linear router, an untied head. It
     reads num_kv_heads, attn_head_dim, num_experts, experts_per_token,
     index_heads, index_head_dim, index_topk, prefill_chunk, rope_theta,
@@ -56,14 +57,17 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax.numpy as jnp
+
 from .. import layers as L
 from ..framework import default_main_program
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 from ..initializer import Constant, Normal, StackedNormal
 from ..ops import cca_moe_ops, sparse_moe_ops
-from .kv_cache import (INDEX_POOL, STACKED_POOLS, declare_pool_vars,
-                       declare_stacked_pools, pool_var_names)
+from .kv_cache import (INDEX_POOL, JOINED_POOL, STACKED_POOLS,
+                       declare_pool_vars, declare_stacked_pools,
+                       pool_var_names)
 
 __all__ = ["DecoderConfig", "decoder_tiny", "cca_moe_tiny",
            "sparse_moe_tiny",
@@ -135,6 +139,11 @@ class DecoderConfig:
                                  "experts_per_token >= 1")
             if self.num_heads % self.kv_heads:
                 raise ValueError("num_kv_heads must divide num_heads")
+            if self.kv_heads % 2 and jnp.dtype(self.dtype).itemsize == 2:
+                raise ValueError(
+                    "block 'sparse_moe' in a 16-bit dtype needs an even "
+                    "num_kv_heads: the halves of a token's K (and V) share "
+                    "32-bit words (sparse_moe_ops.join_rows_fn)")
         if self.min_row_bucket < 1 \
                 or self.min_row_bucket & (self.min_row_bucket - 1):
             raise ValueError("min_row_bucket must be a power of two")
@@ -175,8 +184,9 @@ class DecoderConfig:
     @property
     def selects(self) -> bool:
         """Whether attention reads a learned selection of the cache: an
-        indexer scores every slot of a row's page table, a third pool
-        holds its keys, and every step reports what it attended."""
+        indexer scores every slot of a row's page table, a pool of its
+        own holds its keys, a token's K and V are one row of one pool, and
+        every step reports what it attended."""
         return self.block == "sparse_moe"
 
     def selects_within(self, slots: int) -> bool:
@@ -345,11 +355,12 @@ def _sparse_geometry(cfg: DecoderConfig) -> dict:
 def _sparse_pool_geometry(cfg: DecoderConfig, num_pages: int,
                           page_size: int):
     return (cfg.num_layers, num_pages, page_size,
-            cfg.kv_heads * cfg.head_dim, 0, cfg.dtype, cfg.index_head_dim)
+            cfg.kv_heads * cfg.head_dim, 0, cfg.dtype, cfg.index_head_dim,
+            True)
 
 
-_SPARSE_POOLS = (("KPool", STACKED_POOLS[0]), ("VPool", STACKED_POOLS[1]),
-                 ("IPool", INDEX_POOL))
+# a token's K and V in one row of one pool: this block gathers tokens
+_SPARSE_POOLS = (("KVPool", JOINED_POOL), ("IPool", INDEX_POOL))
 
 
 def _sparse_param_specs(cfg: DecoderConfig) -> dict:
@@ -460,7 +471,7 @@ def _sparse_full(cfg, tok, pos):
 
 
 def _sparse_cow(cfg, num_pages, page_size, src, dst):
-    # the page's K/V slabs and its indexer keys, in every layer
+    # the page's slab of joined K/V rows and its indexer keys, in every layer
     declare_stacked_pools(default_main_program().global_block,
                           *_sparse_pool_geometry(cfg, num_pages, page_size))
     _stacked_copy_page([name for _, name in _SPARSE_POOLS], num_pages, src,
@@ -468,9 +479,9 @@ def _sparse_cow(cfg, num_pages, page_size, src, dst):
 
 
 def _stacked_copy_page(pools, num_pages, src, dst):
-    """Append the copy of page Src to page Dst in every layer of three
-    stacked pools (`cca_state_copy_page` copies rows `l * num_pages +
-    page` of whatever it is given)."""
+    """Append the copy of page Src to page Dst in every layer of up to
+    three stacked pools (`cca_state_copy_page` copies rows `l * num_pages
+    + page` of whatever it is given)."""
     slots = dict(zip(("KPool", "VPool", "SPool"), pools))
     LayerHelper("cca_state_copy_page").append_op(
         "cca_state_copy_page",

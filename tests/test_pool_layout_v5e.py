@@ -6,7 +6,7 @@ import pytest
 
 import chip_smoke
 from paddle_tpu.serving import DecoderConfig
-from tools.pool_hlo import pool_sized_copies
+from tools.pool_hlo import pool_sized_copies, token_row_gathers
 
 POOL = 3072 * 16 * 12 * 64
 
@@ -164,8 +164,11 @@ def test_sparse_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
     bfloat16 slots: a one-layer index pool would fit the chip's VMEM and be
     prefetched there whole), decode at 64 rows over the cell's 288-page
     tables: Mosaic takes the expert kernel at F = 768, and the scanned
-    layer carries K, V and the per-token indexer-key pool without a copy
-    of any."""
+    layer carries the joined K/V rows and the per-token indexer-key pool
+    without a copy of either. The decode program fetches a selected token
+    ONCE, as a row of 512 words (PR 29's fetched it from a K pool and from
+    a V pool, and the chip gathers by the row: PERF.md, PR 30); the windows
+    read whole pages and gather no token."""
     import json
     import os
 
@@ -188,6 +191,28 @@ def test_sparse_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
         compilation_cache.reset_cache()
     assert out["pool_sized_copies"] == {
         "decode": 0, "prefill": 0, "window": 0, "cow": 0}
+    assert out["token_row_gathers"] == {
+        "decode": 1, "prefill": 0, "window": 0, "cow": 0}
+
+
+def test_token_row_gathers_counts_rows_not_slabs():
+    """Recorded from the v5e's compiler: PR 29's decode layer fetched a
+    selected token from two pools, PR 30's from one; a page's slab of
+    indexer keys and a table lookup are no token rows."""
+    two = """\
+  %gather.33 = bf16[64,2048,512]{2,1,0:T(8,128)(2,1)} gather(%param_0.17, %transpose.103), offset_dims={2}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=2, slice_sizes={1,512}, metadata={op_name="jit(fn)/while/body/closed_call/gather"}
+  %gather.34 = bf16[64,2048,512]{2,1,0:T(8,128)(2,1)} gather(%param_0.18, %transpose.104), offset_dims={2}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=2, slice_sizes={1,512}
+  %gather.31 = bf16[64,288,64,128]{3,2,1,0:T(8,128)(2,1)} gather(%param_0.14, %transpose.97), offset_dims={2,3}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=2, slice_sizes={1,64,128}
+  %gather.37 = s32[64]{0:T(128)} gather(%param_0.26, %custom-call.8), offset_dims={}, collapsed_slice_dims={0,1}, start_index_map={0,1}, index_vector_dim=1, slice_sizes={1,1}
+"""
+    one = "  ROOT %gather.33 = s32[64,2048,512]{2,1,0:T(8,128)} gather(" \
+          "%param_0.17, %transpose.103), offset_dims={2}, " \
+          "collapsed_slice_dims={0}, slice_sizes={1,512}\n"
+    assert [g["name"] for g in token_row_gathers(two, 512)] \
+        == ["gather.33", "gather.34"]
+    assert [g["shape"][:16] for g in token_row_gathers(one, 512)] \
+        == ["s32[64,2048,512]"]
+    assert token_row_gathers(two, 128) == []
 
 
 @pytest.mark.parametrize("q_shape,pool,dtype,bucket", [

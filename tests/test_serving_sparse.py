@@ -210,7 +210,8 @@ def test_full_hit_copies_the_page_on_write():
     _assert_right(eng, [prompt], again)
 
 
-def test_copy_on_write_moves_keys_routes_and_indexer_keys():
+@pytest.mark.parametrize("pool", ["kv_cache.index", "kv_cache.kv"])
+def test_copy_on_write_moves_keys_routes_and_indexer_keys(pool):
     prompt = _prompts(6, 20)[0]
     want = _serve(_engine(), [prompt], new=10)[0]
     eng = _engine()
@@ -219,10 +220,10 @@ def test_copy_on_write_moves_keys_routes_and_indexer_keys():
         eng.step()
     req = eng.requests[rid]
     old = list(req.pages)
-    before = np.asarray(eng._scope.find_var("kv_cache.index"))
+    before = np.asarray(eng._scope.find_var(pool))
     assert eng._cow(req, len(req.pages) - 1)      # the page being written
     assert req.pages[-1] != old[-1] and eng.stats["cow_copies"] == 1
-    after = np.asarray(eng._scope.find_var("kv_cache.index"))
+    after = np.asarray(eng._scope.find_var(pool))
     for layer in range(eng.cfg.num_layers):
         row = layer * eng.pool_pages
         assert np.abs(before[row + old[-1]]).max() > 0
@@ -435,6 +436,75 @@ def test_moe_experts_pallas_at_width_768(dtype, monkeypatch):
         rtol=tol, atol=tol)
 
 
+# What PR 29's tree (two pools, K and V a row each; the short-context step
+# through the paged kernel's XLA reference) served for `_joined_rounds`:
+# out_tokens, sha1[:12] of routes, the selection's first position, sha1[:12]
+# of its words. Recorded from that tree on the CPU, seed 3.
+_TWO_POOL_SERVED = {
+    "float32": [
+        ([10, 74, 59, 38, 57], "f22eaf7b1020", 0, "acfd2bbf86ed"),
+        ([50, 13, 71, 44, 72], "dc73726b185c", 32, "e0cf7939edd3"),
+        ([2, 74, 8, 31], "31a8f14cb752", 0, "6dda62d43cb4"),
+        ([84, 50, 65, 63, 64], "04a07272c535", 31, "382a4c8a6c3d")],
+    "bfloat16": [
+        ([10, 74, 59, 38, 57], "0a32a4645d59", 0, "9fc830b06514"),
+        ([50, 13, 71, 22, 41], "6c1e5c3fdf9e", 32, "8a669fa6cb72"),
+        ([2, 74, 8, 31], "31a8f14cb752", 0, "6dda62d43cb4"),
+        ([84, 50, 65, 63, 64], "f53d62cb4112", 31, "19bd612b1441")],
+}
+
+
+def _digest(a):
+    import hashlib
+    return hashlib.sha1(np.ascontiguousarray(a).tobytes()).hexdigest()[:12]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_joined_rows_serve_what_two_pools_served(dtype):
+    """Prefix sharing (a second request behind four shared pages), the
+    short-context step (a request that never leaves its first page attends
+    every live position of its slab), then a full hit whose last page is
+    copied on write: tokens, routes and handed-back selections are those of
+    the tree that kept K and V in a pool each."""
+    eng = _engine(sv_model.sparse_moe_tiny(dtype=dtype))
+    shared = _prompts(20, 32)[0]
+    tails = _prompts(21, 7, 11)
+    served = []
+    for prompts in ([shared + tails[0]],
+                    [shared + tails[1], _prompts(22, 3)[0]], [shared]):
+        rids = [eng.submit(p, 5 if len(p) > 3 else 4, keep_selection=True)
+                for p in prompts]
+        eng.run_until_drained()
+        served += [eng.requests[r] for r in rids]
+    assert eng.stats["prefix_hit_tokens"] == 64 \
+        and eng.stats["prefix_full_hits"] == 1 \
+        and eng.stats["cow_copies"] == 1
+    assert eng.audit_pool() == ([], []) and eng.leaked_pages() == 0
+    # the short one stayed under the selection: no indexer step scored it
+    assert not eng.cfg.selects_within(PS) and served[2].cache_len < PS
+    assert [(r.out_tokens, _digest(r.routes), r.selection[0],
+             _digest(r.selection[1])) for r in served] \
+        == _TWO_POOL_SERVED[dtype]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_joined_pool_holds_the_bytes_of_the_two_pools(dtype):
+    """One pool of 32-bit words where K and V had one each: the same bytes
+    a token a layer (2 x kv_heads x head_dim values), so the engine's
+    device memory is what it was."""
+    cfg = sv_model.sparse_moe_tiny(dtype=dtype)
+    eng = _engine(cfg)
+    kv = eng._scope.find_var("kv_cache.kv")
+    item = jnp.dtype(dtype).itemsize
+    assert kv.dtype == jnp.int32 and kv.shape == (
+        3 * 64, PS, 2 * cfg.kv_heads * cfg.head_dim * item // 4)
+    assert kv.nbytes == 2 * 3 * 64 * PS * cfg.kv_heads * cfg.head_dim * item
+    assert not eng._scope.has_var("kv_cache.k") \
+        and not eng._scope.has_var("kv_cache.v")
+    # the Pallas paged kernel reads two pools: this block never calls it
+    assert eng._decode_grid_steps(4, 1) == 0
+
+
 def test_bfloat16_engine_stays_inside_the_bfloat16_tolerances():
     """bfloat16 weights and pools (the indexer keys too), everything else
     float32, against the float32 reference on the same stored weights. The
@@ -484,6 +554,9 @@ def test_block_field_and_refusals():
         sv_model.sparse_moe_tiny(index_topk=0)
     with pytest.raises(ValueError, match="experts_per_token"):
         sv_model.sparse_moe_tiny(experts_per_token=9)
+    with pytest.raises(ValueError, match="even num_kv_heads"):
+        sv_model.sparse_moe_tiny(num_kv_heads=1, dtype="bfloat16")
+    assert sv_model.sparse_moe_tiny(num_kv_heads=1).kv_heads == 1
     with pytest.raises(NotImplementedError, match="draft_k"):
         _engine(draft_k=2)
     with pytest.raises(NotImplementedError, match="tp > 1"):

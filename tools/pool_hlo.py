@@ -8,7 +8,10 @@ a `transpose`, a `bitcast-convert`. On the v5e such a copy of 24 pools took
 86% of the device's time in every decode and prefill step (PERF.md, PR 24).
 
 `pool_sized_copies(hlo_text, pool_elements)` finds them in an optimized HLO
-text; `serving_program_hlos(engine)` compiles the engine's decode, prefill,
+text; `token_row_gathers(hlo_text, row_elements)` lists the gathers that
+fetch one token's row at a time (a block that gathers tokens pays by the
+row: its decode program holds ONE a layer since PR 30);
+`serving_program_hlos(engine)` compiles the engine's decode, prefill,
 window and COW programs (`serving_program_cases`) at one signature each and
 returns their texts.
 `chip_smoke.py` fails on a finding; run here it prints the table:
@@ -29,8 +32,8 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-__all__ = ["pool_sized_copies", "serving_program_cases",
-           "serving_program_hlos"]
+__all__ = ["pool_sized_copies", "token_row_gathers",
+           "serving_program_cases", "serving_program_hlos"]
 
 # `  %name = f32[3072,16,768]{2,1,0:T(8,128)} opcode(operands...), attrs`
 _INSTR = re.compile(
@@ -112,6 +115,22 @@ def pool_sized_copies(hlo_text: str, pool_elements: int) -> list[dict]:
             "line": line.strip()[:400],
         })
     return found
+
+
+_GATHER = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*(?P<shape>\S+)\s+gather\("
+    r".*slice_sizes=\{(?P<slice>[\d,]*)\}")
+
+
+def token_row_gathers(hlo_text: str, row_elements: int) -> list[dict]:
+    """The gathers of an optimized HLO text (inside fusions too) whose
+    slice is one row of `row_elements` values out of a two-dimensional
+    operand, `[{"name", "shape"}]` in text order: a pool seen as `[tokens,
+    row]` read a token at a time. A scanned layer's body appears once."""
+    want = f"1,{int(row_elements)}"
+    return [{"name": m["name"], "shape": m["shape"]}
+            for line in hlo_text.splitlines()
+            if (m := _GATHER.match(line)) and m["slice"] == want]
 
 
 def _program_hlo(exe, target, feed, fetch_list, scope, device=None) -> str:
