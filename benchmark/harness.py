@@ -31,7 +31,8 @@ class RunResult:
     """What a runner returns. `values` holds every end-to-end number it can
     compute (run.py keeps those BENCHMARK.json lists for the cell);
     `series`, `counters`, `histograms`, `stages` and `trace` are what the
-    per-layer readers read."""
+    per-layer readers read; `compared` is every number `correct` rests on
+    beside its limit, `{short name: [number, limit]}`."""
     correct: bool
     attempted: int
     failed: int
@@ -42,6 +43,7 @@ class RunResult:
     stages: dict = dataclasses.field(default_factory=dict)
     trace: dict | None = None
     notes: dict = dataclasses.field(default_factory=dict)
+    compared: dict = dataclasses.field(default_factory=dict)
     ctx: RunContext | None = None
 
 

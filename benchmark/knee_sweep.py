@@ -24,12 +24,30 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark.harness import (RunContext, load_json, merge,  # noqa: E402
-                               percentile, percentile_band)
+                               percentile, percentile_band, registry_view)
 
 
 def _band_mean(depth, lo, hi):
     vals = [d for t, d in depth if lo <= t < hi]
     return sum(vals) / len(vals) if vals else 0.0
+
+
+def _rates(listed, verdicts: dict, refine: float):
+    """The listed rates in order, less those above two that already failed,
+    then (with --refine f) the midpoints between the highest rate found
+    sustained below every failing one and the lowest failing one, until they
+    lie within `f` of the knee of each other. `verdicts` (rate -> sustained)
+    is filled by the caller as it goes."""
+    for rate in listed:
+        if sum(not ok for r, ok in verdicts.items() if r < rate) < 2:
+            yield rate
+    while refine > 0:
+        bad = min((r for r, ok in verdicts.items() if not ok), default=None)
+        good = max((r for r, ok in verdicts.items()
+                    if ok and (bad is None or r < bad)), default=None)
+        if bad is None or good is None or bad - good <= refine * good:
+            return
+        yield round((good + bad) / 2, 1)
 
 
 def main(argv=None) -> int:
@@ -39,6 +57,8 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--schedule-seed", type=int,
                     help="replay another drawn schedule than the cells'")
+    ap.add_argument("--refine", type=float, default=0.0,
+                    help="bisect around the knee to this share of it")
     ap.add_argument("sweeps", nargs="+", help="<cell>:<rate>,<rate>,...")
     args = ap.parse_args(argv)
 
@@ -70,7 +90,8 @@ def main(argv=None) -> int:
     engine.warmup_decode(max(int(c["traffic"]["max_total"])
                              for _, c, _ in plans))
     for name, cell, rates in plans:
-        for i, rate in enumerate(rates):
+        verdicts = {}
+        for i, rate in enumerate(_rates(rates, verdicts, args.refine)):
             mix = merge(cell["traffic"],
                          {"arrivals": {"rate_per_s": rate}})
             if args.schedule_seed is not None:
@@ -107,7 +128,12 @@ def main(argv=None) -> int:
                     percentile(s["submit_wait_s"], 99) * 1e3,
                 "batch_rows_mean": stats["tokens_per_decode_step"],
                 "prefix_hit_rate": stats["prefix_cache_hit_rate"],
-                "peak_pages_in_use": stats["peak_pages_in_use"], **end}
+                "peak_pages_in_use": stats["peak_pages_in_use"],
+                "decode_step_ms": sol.window_readings(
+                    s, registry_view()).get("decode_step_ms"),
+                "memory_peak_bytes": (jax.devices()[0].memory_stats() or {})
+                .get("peak_bytes_in_use", 0), **end}
+            verdicts[rate] = row["sustained"]
             print("sweep", json.dumps(row), flush=True)
     return 0
 

@@ -4,16 +4,19 @@
 
 set-up (build, warm every shape, compile) -> one measured window of
 `--seconds` -> correctness -> the last stdout line, a JSON object with the
-keys `correct`, `attempted`, `failed`, `metrics`, `device` (and
-`breakdown` with `--trace 1`). `--trace 0` reports the cell's end-to-end
-metrics; `--trace 1` profiles the tail of the window and reports its
-per-layer metrics.
+keys `correct`, `attempted`, `failed`, `metrics`, `device` (`breakdown`
+with `--trace 1`) and last `compared`: every number `correct` rests on
+beside its limit, also the last lines of standard error. `--trace 0`
+reports the cell's end-to-end metrics; `--trace 1` profiles the tail of the
+window and reports its per-layer metrics.
 
 Everything that belongs to one cell, configuration, kind of run or
 per-layer metric is a file found by name; this file holds no list:
 
     BENCHMARK.json                         which cells and metrics exist
     benchmark/workloads/<cell>.json        config, chips, runner, traffic
+                                           (`reports`: an end-to-end name
+                                           of its own for a runner's number)
     benchmark/configs/<config>.json        sizes, builder, feeds, reference
     benchmark/runners/<runner>.py          run(ctx) -> RunResult
     benchmark/layer_metrics/<metric>.json  reader and its arguments (a
@@ -57,6 +60,15 @@ def cell_metrics(manifest: dict, kind: str, cell: str) -> list:
     those with no `workloads` key, or with the cell in it."""
     return [m for m in manifest[kind]
             if "workloads" not in m or cell in m["workloads"]]
+
+
+def end_to_end_value(values: dict, cell: dict, name: str):
+    """The runner's value for the end-to-end metric `name`. One quantity
+    judged under two bounds takes two entries of BENCHMARK.json, and the
+    cell's file says which of the runner's numbers it reports under the
+    second name (`"reports": {"<metric>": "<runner's name>"}`); a name the
+    runner does not give is a KeyError."""
+    return values[cell.get("reports", {}).get(name, name)]
 
 
 def main(argv=None, t_start: float | None = None) -> int:
@@ -120,8 +132,9 @@ def main(argv=None, t_start: float | None = None) -> int:
     metrics = {}
     if not args.trace:
         for m in cell_metrics(manifest, "end_to_end", args.workload):
-            metrics[m["name"]] = {"value": result.values[m["name"]],
-                                  "unit": m["unit"]}
+            metrics[m["name"]] = {
+                "value": end_to_end_value(result.values, cell, m["name"]),
+                "unit": m["unit"]}
     else:
         reported = {m["name"] for m in
                     cell_metrics(manifest, "end_to_end", args.workload)}
@@ -146,9 +159,16 @@ def main(argv=None, t_start: float | None = None) -> int:
         device["window_s"] = result.trace["window_s"]
         line["breakdown"] = {"device_ops": result.trace["device_ops"],
                              "idle_gaps": result.trace["idle_gaps"]}
+    # what `correct` compared, each number beside its limit: last in the
+    # line and the last lines of standard error
+    line["compared"] = {k: {"value": v, "limit": lim}
+                        for k, (v, lim) in result.compared.items()}
     if result.notes:
         print("notes", json.dumps(result.notes), flush=True)
     print(json.dumps(line), flush=True)
+    for k, (v, lim) in result.compared.items():
+        print(f"compared {k} {v} limit {lim}", file=sys.stderr)
+    print(f"correct {bool(result.correct)}", file=sys.stderr, flush=True)
     return 0
 
 

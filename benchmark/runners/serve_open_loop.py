@@ -125,7 +125,13 @@ def drive(engine, requests: list, seconds: float, slice_=None):
     from paddle_tpu.serving.engine import AdmissionRejected
 
     pending = collections.deque(requests)
-    tracks, active, depth, steps = [], [], [], []
+    tracks, depth, steps = [], [], []
+    # submitted requests, apart by whether the engine has admitted them: a
+    # cell above its knee queues hundreds. One pass a step moves the newly
+    # admitted over (a state read a queued request); stamping, which reads
+    # and writes several fields, walks the admitted alone (stamping all of
+    # them cost 0.4 ms an iteration at 700 queued)
+    queued, active = [], []
     t0 = time.perf_counter()
     free_s = 0.0                    # when the engine last gave control back
     while True:
@@ -146,20 +152,25 @@ def drive(engine, requests: list, seconds: float, slice_=None):
                         pass
                     else:
                         tr.live, tr.state = engine.requests[rid], "waiting"
-                        active.append(tr)
+                        queued.append(tr)
                     tracks.append(tr)
         if engine.has_work():
             with span("bench.step"):
                 engine.step()
+            waiting = []
+            for tr in queued:
+                (waiting if tr.live.state == "waiting" else active).append(tr)
+            queued = waiting
             active, emitted = _stamp(active, time.perf_counter() - t0)
-            depth.append((now, sum(1 for tr in active if not tr.token_s)))
+            depth.append((now, len(queued)
+                          + sum(1 for tr in active if not tr.token_s)))
             free_s = time.perf_counter() - t0
             steps.append((now, free_s, emitted))
         else:
             with span("bench.idle"):
                 next_s = pending[0].due_s if pending else seconds
                 time.sleep(max(0.0, min(0.001, next_s - now)))
-    return tracks, active, depth, steps, t0
+    return tracks, queued + active, depth, steps, t0
 
 
 def _stamp(active: list, t: float) -> tuple:
@@ -269,6 +280,48 @@ def check_sample(engine, cfg, tracks: list, ctx: RunContext) -> dict:
             "wrong": [tr for tr, g in zip(picked, gaps) if g > tol]}
 
 
+def window_readings(s: dict, view: dict) -> dict:
+    """What an untraced run can say of its window beside its end-to-end
+    numbers, for the notes: the gaps' percentiles and the program's own
+    means of a decode step and a prefill with the host's part of each."""
+    out = {f"itl_p{q}_ms": percentile(s["itl_s"], q) * 1e3
+           for q in (50, 90, 95, 98, 99, 99.5) if s["itl_s"]}
+    for name, series in (("decode_step_ms", "serving.decode.seconds"),
+                         ("decode_host_ms", "serving.decode.host_seconds"),
+                         ("prefill_step_ms", "serving.prefill.seconds"),
+                         ("prefill_host_ms", "serving.prefill.host_seconds")):
+        h = view["histograms"].get(series)
+        if h and h["count"]:
+            out[name] = h["sum"] / h["count"] * 1e3
+    if s["loop_iter_s"]:
+        out["loop_iter_p50_ms"] = percentile(s["loop_iter_s"], 50) * 1e3
+    return out
+
+
+def _depth_near(depth: list, at_s: float) -> int:
+    """The queue's depth at the first sample taken at or after `at_s`."""
+    return next((d for t, d in depth if t >= at_s), 0)
+
+
+def compared(end: dict, compiles: int, grade: dict) -> dict:
+    """Every number `correct` rests on beside its limit (a number may not
+    pass it), for the three serving runners: the reference's readings carry
+    the names their runner's `check_sample` gave them."""
+    out = {"leaked_pages": [end["leaked_pages"], 0],
+           "audit_problems": [end["audit_problems"], 0],
+           "window_compiles": [compiles, 0]}
+    for name, key, limit in (
+            ("logit_gap", "worst_gap", "tolerance"),
+            ("route_margin", "worst_route_margin", "route_margin_tolerance")):
+        if grade.get(key) is not None:
+            out[name] = [grade[key], grade[limit]]
+    for layer, pair in enumerate(zip(
+            grade.get("worst_select_margin_by_layer", ()),
+            grade.get("select_margin_tolerance", ()))):
+        out[f"select_margin.l{layer}"] = list(pair)
+    return out
+
+
 def run(ctx: RunContext) -> RunResult:
     from paddle_tpu.pipeline import jit_compile_counter
 
@@ -317,13 +370,13 @@ def run(ctx: RunContext) -> RunResult:
     if s["ttft_s"]:
         values["ttft_p85_95_ms"] = percentile_band(s["ttft_s"], 85, 95) * 1e3
         values["ttft_mean_ms"] = sum(s["ttft_s"]) / len(s["ttft_s"]) * 1e3
-    if s["itl_s"]:
-        values["itl_p99_ms"] = percentile(s["itl_s"], 99) * 1e3
+    for q in (50, 95, 99) if s["itl_s"] else ():
+        values[f"itl_p{q}_ms"] = percentile(s["itl_s"], q) * 1e3
     return RunResult(
         correct=correct, attempted=len(judged), failed=failed, values=values,
         series={k: s[k] for k in ("loop_iter_s", "ttft_s", "itl_s",
                                   "gen_late_s", "submit_wait_s")},
-        trace=trace, **view,
+        trace=trace, **view, compared=compared(end, compiles.count, grade),
         notes={"window_compiles": compiles.count, "offered": s["offered"],
                "finished": s["finished"], "tokens": s["tokens"],
                "tok_s_by_second": s["tok_s_by_second"],
@@ -334,7 +387,10 @@ def run(ctx: RunContext) -> RunResult:
                                  "decode_lattice": t_replay - t_lattice,
                                  "prefill_replay": t_window - t_replay},
                "peak_pages_in_use": stats["peak_pages_in_use"],
+               "preemptions": stats["preemptions"],
                "queue_depth_end": depth[-1][1] if depth else 0,
+               "queue_depth_mid": _depth_near(depth, ctx.seconds / 2),
+               "window": window_readings(s, view),
                "ttft_ms_sorted": [round(t * 1e3, 1)
                                   for t in sorted(s["ttft_s"])],
                **end, **grade})

@@ -49,9 +49,9 @@ import numpy as np
 
 from benchmark.harness import (RunContext, RunResult, TraceSlice, percentile,
                                percentile_band, registry_view)
-from benchmark.runners.serve_open_loop import (SAMPLE, build_engine, drive,
-                                               settle, summarize,
-                                               warm_prefills)
+from benchmark.runners.serve_open_loop import (SAMPLE, build_engine,
+                                               compared, drive, settle,
+                                               summarize, warm_prefills)
 from benchmark.runners.serve_open_loop_routed import judged
 from benchmark.traffic import open_loop
 
@@ -212,7 +212,7 @@ def run(ctx: RunContext) -> RunResult:
         correct=correct, attempted=len(kept), failed=failed, values=values,
         series={k: s[k] for k in ("loop_iter_s", "ttft_s", "itl_s",
                                   "gen_late_s", "submit_wait_s")},
-        trace=trace, **view,
+        trace=trace, **view, compared=compared(end, compiles.count, grade),
         notes={"window_compiles": compiles.count, "offered": s["offered"],
                "finished": s["finished"], "tokens": s["tokens"],
                "tok_s_by_second": s["tok_s_by_second"],

@@ -204,6 +204,14 @@ def run(ctx: RunContext) -> RunResult:
         failed=int(sum(not np.isfinite(v) for v in host_losses[n0:])),
         values={"train_items_s": rate, "setup_s": setup_s},
         trace=trace, **view,
+        compared={"loss_gap": [worst, tol["loss_tolerance"]],
+                  # held from below: the shortfall from 1 against its room
+                  "update_cosine_short": [1.0 - cosine,
+                                          1.0 - tol["update_cosine_min"]],
+                  "update_rms_off": [abs(rms_ratio - 1.0),
+                                     tol["update_rms_tolerance"]],
+                  "loss_rise": [last10 - first10, DIVERGED],
+                  "window_compiles": [compiles.count, 0]},
         notes={"steps": steps, "window_s": window_s,
                "items_per_step": items_per_step,
                "window_compiles": compiles.count,

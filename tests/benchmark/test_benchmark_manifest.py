@@ -68,6 +68,33 @@ def test_cell_has_its_files(cell):
                for m in MANIFEST["per_layer"])
 
 
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cell_says_which_runner_number_a_name_of_its_own_reports(cell):
+    """One quantity judged under two bounds takes two end-to-end entries;
+    the second name is spelt out in the cell's file (`reports`), is an
+    end-to-end metric that lists the cell, and stands for a number that
+    another end-to-end entry already names."""
+    e2e = {m["name"]: m for m in MANIFEST["end_to_end"]}
+    reports = _load(BENCH, "workloads", cell + ".json").get("reports", {})
+    for name, of in reports.items():
+        assert cell in e2e[name]["workloads"], name
+        assert of in e2e and of != name and e2e[of]["unit"] == e2e[name]["unit"]
+
+
+def test_an_end_to_end_name_the_runner_does_not_give_is_an_error():
+    from benchmark.run import end_to_end_value
+
+    values = {"sat_tok_s": 4000.0, "setup_s": 60.0}
+    cell = {"reports": {"sat_tok_s.bert": "sat_tok_s"}}
+    assert end_to_end_value(values, cell, "sat_tok_s.bert") == 4000.0
+    assert end_to_end_value(values, {}, "setup_s") == 60.0
+    for name in ("sat_tok_s.brt", "sat_tok_s.zaya", "serve_tok_s"):
+        with pytest.raises(KeyError):
+            end_to_end_value(values, cell, name)
+    with pytest.raises(KeyError):
+        end_to_end_value(values, {}, "sat_tok_s.bert")
+
+
 @pytest.mark.parametrize("config", [c["name"] for c in MANIFEST["configs"]])
 def test_config_has_its_file(config):
     entry = next(c for c in MANIFEST["configs"] if c["name"] == config)

@@ -44,7 +44,11 @@ def test_rehearsal_ends_in_the_contract_line(capsys, cell):
     rc, line, notes = _rehearse(capsys, cell, trace=0)
     assert rc == 0
     assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+                         "device", "compared"}
+    # every number `correct` rests on, beside its limit, last in the line
+    assert list(line)[-1] == "compared" and len(line["compared"]) >= 4
+    for name, c in line["compared"].items():
+        assert set(c) == {"value", "limit"} and c["value"] <= c["limit"], name
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     # --trace 0 reports exactly the cell's end-to-end metrics, none zero
@@ -126,8 +130,8 @@ def test_serving_rehearsal_agrees_with_the_reference(capsys):
     _, line, notes = _rehearse(capsys, SERVE, trace=1)
     assert notes["sampled"] == sol.SAMPLE and notes["worst_gap"] < 1e-3
     assert notes["leaked_pages"] == 0 and notes["audit_problems"] == 0
-    assert line["metrics"]["prefix_hit_rate"]["value"] > 20
-    assert line["metrics"]["batch_rows_mean"]["value"] >= 1
+    assert line["metrics"]["prefix_hit_rate.bert"]["value"] > 20
+    assert line["metrics"]["batch_rows_mean.bert"]["value"] >= 1
 
 
 class _SlowEngine:

@@ -60,14 +60,17 @@ def test_a_rehearsal_prints_the_metric(cell, metric):
     assert metric in metrics and metrics[metric] > 0
 
 
-@pytest.mark.parametrize("cell,sfx", [(CHAT, ""), (SAT, ".sat")])
-def test_the_new_readings_split_the_old_ones(cell, sfx):
+# the older readings of the saturated BERT cell go by `.bert` since PR 32
+# (the cell is judged on `sat_tok_s.bert`, a bound of its own)
+@pytest.mark.parametrize("cell,sfx,old", [(CHAT, "", ""),
+                                          (SAT, ".sat", ".bert")])
+def test_the_new_readings_split_the_old_ones(cell, sfx, old):
     m = _traced_rehearsal(cell)
     # the host's part of a step is part of the step
-    assert m["decode_host_ms" + sfx] < m["decode_step_ms" + sfx]
-    assert m["prefill_host_ms" + sfx] < m["prefill_step_ms" + sfx]
+    assert m["decode_host_ms" + sfx] < m["decode_step_ms" + old]
+    assert m["prefill_host_ms" + sfx] < m["prefill_step_ms" + old]
     # step() runs inside the benchmark's loop iteration
-    assert m["step_max_ms" + sfx] <= m["loop_iter_max_ms" + sfx]
+    assert m["step_max_ms" + sfx] <= m["loop_iter_max_ms" + old]
     # one read-back is inside one step
     assert m["device_wait_max_ms" + sfx] <= m["step_max_ms" + sfx]
     # the collector's pauses are the process's, inside a step or not: the
@@ -99,7 +102,8 @@ def test_the_metric_reads_what_the_program_declares(metric):
         re.compile(args["pattern"])
     # the twin of a serving metric points at the saturated cell's rate
     if metric.endswith(".sat"):
-        assert entry["moves"] == "sat_tok_s" and entry["workloads"] == [SAT]
+        assert entry["moves"] == "sat_tok_s.bert"
+        assert entry["workloads"] == [SAT]
 
 
 def test_the_paged_kernel_is_found_by_name_in_a_reduced_trace():
