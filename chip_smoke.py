@@ -51,6 +51,7 @@ from paddle_tpu.parallel.mesh import make_mesh
 from paddle_tpu.serving import DecoderConfig, ServingEngine
 from paddle_tpu.serving import model as sv_model
 from paddle_tpu.serving.kv_cache import (INDEX_POOL, JOINED_POOL,
+                                         STACKED_POOLS, WINDOW_POOLS,
                                          pool_shape)
 from tools.pool_hlo import (pool_sized_copies, serving_program_hlos,
                             token_row_gathers)
@@ -282,6 +283,10 @@ def pool_layout_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
         # K and V joined in one pool of 32-bit words, and the indexer keys
         sizes = {int(np.prod(eng._scope.find_var(name).shape))
                  for name in (JOINED_POOL, INDEX_POOL)}
+    if cfg.windowed:
+        # the full layers' pools and the sliding layers' second pair
+        sizes = {int(np.prod(eng._scope.find_var(name).shape))
+                 for name in STACKED_POOLS[:2] + WINDOW_POOLS}
     texts = serving_program_hlos(
         eng, rows=rows,
         pages=eng._page_bucket(eng.pool.pages_for(cfg.max_position)),

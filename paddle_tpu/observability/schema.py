@@ -302,6 +302,40 @@ DECLARED: list[tuple] = [
     ("serving.sparse.layer_steps", COUNTER,
      "layer x decode-step pairs in which the indexer ran (a table wider "
      "than index_topk slots)", ()),
+    # -- window and full attention layers over two pools (ISSUE 33) ---------
+    ("serving.kv.window_release.seconds", HISTOGRAM,
+     "returning to the sliding layers' pool the pages a row's window has "
+     "left (under serving.ensure_writable, or a prefill chunk)", ()),
+    ("serving.kv.window_pages_released", COUNTER,
+     "pages of the sliding layers' pool that rows let go of because their "
+     "window had moved past them", ()),
+    ("serving.kv.window_row_pages", COUNTER,
+     "pages of the sliding layers' pool that the live rows of a decode "
+     "step have mapped, summed over rows and steps (a shared page once a "
+     "row that maps it)", ()),
+    ("serving.kv.global_row_pages", COUNTER,
+     "pages of the full layers' pool that the live rows of a decode step "
+     "have mapped, summed over rows and steps", ()),
+    ("serving.kv.window_pages_in_use", GAUGE,
+     "pages of the sliding layers' pool currently mapped (rows and the "
+     "prefix cache)", ()),
+    ("serving.kv.global_pages_in_use", GAUGE,
+     "pages of the full layers' pool currently mapped, for a family with "
+     "two pools (serving.pages_in_use reads the same)", ()),
+    ("serving.attn.full_context_tokens", COUNTER,
+     "live tokens the rows of a decode step attended in full-attention "
+     "layers, summed over rows, those layers and steps (x a token's K+V "
+     "bytes a layer: what the kernel had to read)", ()),
+    ("serving.attn.window_context_tokens", COUNTER,
+     "live tokens the rows of a decode step attended in sliding-window "
+     "layers (at most the window a row), summed over rows, those layers "
+     "and steps", ()),
+    ("serving.attn.full_layer_steps", COUNTER,
+     "full-attention layer x decode-step pairs: the calls of the paged "
+     "decode kernel for full layers", ()),
+    ("serving.attn.window_layer_steps", COUNTER,
+     "sliding-window layer x decode-step pairs: the calls of the paged "
+     "decode kernel with a first live slot", ()),
     ("serving.control.rewarmups", COUNTER,
      "warmup_decode re-runs forced by an adopted bucket-geometry change "
      "(keeps XLA compiles off the serving path)", ()),

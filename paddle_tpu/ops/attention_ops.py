@@ -352,13 +352,15 @@ def _gather_pages(pool, page_table, nh):
 
 
 def _paged_attention_reference(q, k_pool, v_pool, page_table, kv_lens,
-                               sm_scale=1.0):
+                               sm_scale=1.0, first_live=None):
     """XLA gather-based paged decode attention — the numeric oracle and the
     dispatch fallback. Gathers every row's pages into a dense
     [B, P*ps, nh, dh] view (XLA fuses the gather into the matmuls, but the
     materialized bytes still move); fp32 softmax statistics, slots past a
     row's kv_len masked with the framework-wide -1e9 convention so a padded
-    row (kv_len 0) stays finite. Pools are `[num_pages, ps, nh*dh]`."""
+    row (kv_len 0) stays finite. Pools are `[num_pages, ps, nh*dh]`. With
+    `first_live` [B] (a sliding-window layer) the slots before it are
+    masked too."""
     B, nh, dh = q.shape
     P, ps = page_table.shape[1], k_pool.shape[1]
     nkv = k_pool.shape[2] // dh
@@ -371,8 +373,10 @@ def _paged_attention_reference(q, k_pool, v_pool, page_table, kv_lens,
     qg = q.reshape(B, nkv, nh // nkv, dh)
     s = jnp.einsum("bjgd,bkjd->bjgk", qg.astype(k.dtype), k,
                    preferred_element_type=jnp.float32) * sm_scale
-    s = jnp.where(pos[None, None, None, :]
-                  < kv_lens[:, None, None, None], s, _NEG_INF)
+    live = pos[None, None, None, :] < kv_lens[:, None, None, None]
+    if first_live is not None:
+        live &= pos[None, None, None, :] >= first_live[:, None, None, None]
+    s = jnp.where(live, s, _NEG_INF)
     probs = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bjgk,bkjd->bjgd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
@@ -411,11 +415,13 @@ def paged_decode_grid_steps(q_shape, q_dtype, pool_shape, pool_dtype,
 
 
 def paged_decode_attention_fn(q, k_pool, v_pool, page_table, kv_lens,
-                              sm_scale=1.0, tp=1):
+                              sm_scale=1.0, tp=1, first_live=None):
     """Dispatch per `paged_attention_backend`: the Pallas page-DMA kernel
     where it can run (and the tuner has not retired it for this shape), the
     XLA gather reference everywhere else — including when a swept-DB verdict
-    names a kernel this platform cannot execute."""
+    names a kernel this platform cannot execute. `first_live` [B] int32 (a
+    sliding-window layer): a row attends slots `first_live .. kv_len - 1`
+    only."""
     backend, pallas_pool = _paged_arm(q.shape, q.dtype, k_pool.shape,
                                       k_pool.dtype, page_table.shape[1], tp)
     if pallas_pool is not None:
@@ -423,10 +429,11 @@ def paged_decode_attention_fn(q, k_pool, v_pool, page_table, kv_lens,
 
         _note_dispatch("paged", backend, backend)
         return ppa.paged_decode_attention(q, k_pool, v_pool, page_table,
-                                          kv_lens, sm_scale=float(sm_scale))
+                                          kv_lens, sm_scale=float(sm_scale),
+                                          first_live=first_live)
     _note_dispatch("paged", backend, "xla")
     return _paged_attention_reference(q, k_pool, v_pool, page_table, kv_lens,
-                                      sm_scale)
+                                      sm_scale, first_live)
 
 
 # sentinel page index far past any real pool: scatters routed here are

@@ -1,0 +1,482 @@
+"""The mechanisms of a decoder whose layers are NOT one shape, and the op
+that runs a stack of them (the "hybrid_moe" block of serving/model.py):
+full-attention layers and sliding-window layers of different query-head
+counts over the same KV heads, a sigmoid gate a head on the attention's
+output, one dense SwiGLU layer in front of layers that route each token to
+the top-k of a sigmoid-scored mixture of experts and add one shared expert.
+
+Each mechanism is a plain jax function (`<name>_fn`); the one registered op
+that runs them is `hybrid_moe_stack`:
+
+  * `yarn_inv_freq`      — YaRN's inverse frequencies: below the correction
+                           dimension of `beta_fast` a lane keeps its own
+                           frequency, above that of `beta_slow` it is
+                           interpolated (divided by `factor`), a linear
+                           ramp between;
+  * `rotary`             — rotate-half rotary on the first `rotary_dim`
+                           lanes of every head from given inverse
+                           frequencies, cos and sin times a factor;
+  * `band_attention`     — a window of queries over keys that each query
+                           sees only `window` positions back: query block
+                           by query block, each against the slice of keys
+                           its band covers and nothing else (never a
+                           `[queries, context]` product);
+  * `causal_attention`   — the same queries over everything before them,
+                           query block by query block;
+  * `sigmoid_router`     — `s = sigmoid(z W_r)`, the k largest of `s + b`
+                           (ties to the lower expert), weights
+                           `scaling * s_e / sum_chosen s`: the bias selects
+                           and never weighs;
+  * `swiglu`             — `W_d(silu(W_g z) * (W_u z))`, the dense layer
+                           and the shared expert.
+
+`hybrid_moe_stack` composes them into the decoder (embedding, the layers of
+a PLAN, final norm, untied head) in the shapes serving needs: dense oracle
+(`full`), a window over the paged pools (`window`; `prefill` is the same at
+start 0) and the ragged decode step. The plan is a tuple of `(attention
+kind, index among the layers of that kind, feed-forward kind, index among
+those)`; weights are stacked by KIND (`full.*` `[L_full, ...]`, `slide.*`
+`[L_slide, ...]`, `dense.*`, the experts `[L_moe, E, ...]`) and the layers
+run one after another, each taking its slice.
+
+Two pools, because the two kinds of layer need different things kept: a
+full layer's K/V live as long as the row (`kv_cache.k/v`, `[L_full * pages,
+page_size, nkv*dh]`, the row's page table as every family has it); a sliding
+layer reads only the last `window` positions, so its K/V live in a second
+stacked pool (`kv_cache.wk/wv`, `[L_slide * window_pages, ...]`) under a
+COMPACT table: entry j is the page of logical page `base / page_size + j`,
+`base` (a multiple of the page size) the position of the table's slot 0.
+A position p lies at local slot `p - base`.
+
+The experts run through `pallas_kernels.moe_experts` in its combine-weight
+form, as in `sparse_moe_ops`.
+
+Precision: matmul operands in the weights' dtype (bfloat16 as served),
+float32 accumulation; residual stream, norms, router (scores, bias,
+selection, weights), gate logits, rotary and softmax in float32.
+"""
+from __future__ import annotations
+
+import collections
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .attention_ops import (_NEG_INF, _gather_pages, _write_rows,
+                            kv_cache_append_fn, paged_decode_attention_fn)
+from .cca_moe_ops import _page_row_index, rms_norm_fn
+from .registry import ExecContext, register_op
+from .sparse_moe_ops import moe_topk_experts_fn
+
+_HI = jax.lax.Precision.HIGHEST
+_F32 = jnp.float32
+
+Geometry = collections.namedtuple(
+    "Geometry", "full_heads slide_heads num_kv_heads head_dim window eps "
+                "full_rotary_dim full_theta yarn slide_rotary_dim "
+                "slide_theta experts_per_token routed_scaling")
+
+# the stacked parameters, in the order the stack op takes them
+LAYER_PARAMS = ("attn_norm", "ffn_norm")                     # [L, H]
+ATTENTION_PARAMS = ("wq", "wk", "wv", "wg", "wo")            # a kind each
+DENSE_PARAMS = ("w_gate", "w_up", "w_down")
+MOE_PARAMS = ("router_w", "router_bias", "shared_gate", "shared_up",
+              "shared_down")
+EXPERT_PARAMS = ("w_gate", "w_up", "w_down")
+
+FULL, SLIDE, DENSE, MOE = "full", "slide", "dense", "moe"
+
+# queries attended together: a full layer's float32 scores of one block are
+# `[heads, block, context]` (48 x 64 x 20,480 x 4 B = 252 MB); a sliding
+# layer's band is `[heads, block, block + window]`
+_QUERY_BLOCK = 64
+_BAND_BLOCK = 128
+
+
+# ---------------------------------------------------------------------------
+# the mechanisms
+# ---------------------------------------------------------------------------
+
+
+def yarn_inv_freq_fn(rotary_dim: int, theta: float, yarn=()) -> np.ndarray:
+    """The `rotary_dim / 2` inverse frequencies of a rotary embedding,
+    float32. `yarn` = (factor, original context, beta_fast, beta_slow,
+    attention factor) or (): lane pair i turns `theta^(-2i/d)` a position;
+    YaRN keeps that below `low`, divides it by `factor` above `high` and
+    ramps linearly between, `low`/`high` the (floored/ceiled) pair indices
+    that turn `beta_fast`/`beta_slow` times over the original context."""
+    half = rotary_dim // 2
+    inv = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / rotary_dim)
+    if not yarn:
+        return inv.astype(np.float32)
+    factor, original, beta_fast, beta_slow = (float(v) for v in yarn[:4])
+
+    def correction_dim(turns):
+        return rotary_dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    return (inv / factor * ramp + inv * (1.0 - ramp)).astype(np.float32)
+
+
+def rotary_fn(x, positions, inv_freq, rotary_dim: int, factor: float = 1.0):
+    """x [..., heads, dh] float32, positions [...] (one a token): lanes
+    [0, rotary_dim) of every head turn as (i, i + rotary_dim/2) pairs by
+    `position * inv_freq[i]`, cos and sin times `factor`; the lanes past
+    `rotary_dim` pass."""
+    half = rotary_dim // 2
+    ang = positions.astype(_F32)[..., None, None] * jnp.asarray(inv_freq)
+    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
+    x1, x2, rest = (x[..., :half], x[..., half:rotary_dim],
+                    x[..., rotary_dim:])
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+
+
+def _attend(qg, k, v, mask, sm_scale):
+    """qg [B, s, nkv, g, dh], k/v [B, T, nkv, dh], mask [B, s, T] ->
+    [B, s, nkv, g, dh] float32."""
+    s = jnp.einsum("bsjgd,btjd->bjgst", qg, k,
+                   preferred_element_type=_F32) * sm_scale
+    s = jnp.where(mask[:, None, None], s, _NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bjgst,btjd->bsjgd", probs.astype(v.dtype), v,
+                      preferred_element_type=_F32)
+
+
+def _by_query_block(fn, qg, block: int):
+    """`fn(block index, qg's block [B, block, ...])` over the query blocks
+    of qg [B, S, ...], one after another (`lax.map`); one call where S is
+    no multiple of `block`."""
+    B, S = qg.shape[:2]
+    if S <= block or S % block:
+        return fn(jnp.int32(0), qg)
+    n = S // block
+    split = jnp.moveaxis(qg.reshape((B, n, block) + qg.shape[2:]), 1, 0)
+    out = jax.lax.map(lambda a: fn(*a),
+                      (jnp.arange(n, dtype=jnp.int32), split))
+    return jnp.moveaxis(out, 0, 1).reshape((B, S) + out.shape[3:])
+
+
+def causal_attention_fn(q, k, v, q_pos0, sm_scale: float):
+    """q [B, S, nh, dh] at positions `q_pos0[b] + s` over k/v [B, T, nkv,
+    dh] at positions 0..T-1: every key at or before the query, query block
+    by query block -> [B, S, nh, dh] float32."""
+    B, S, nh, dh = q.shape
+    T, nkv = k.shape[1], k.shape[2]
+    block = S if S <= _QUERY_BLOCK or S % _QUERY_BLOCK else _QUERY_BLOCK
+    kp = jnp.arange(T, dtype=jnp.int32)
+
+    def one(j, qb):
+        qp = q_pos0[:, None] + j * block + jnp.arange(block, dtype=jnp.int32)
+        return _attend(qb, k, v, kp[None, None, :] <= qp[:, :, None],
+                       sm_scale)
+
+    qg = q.reshape(B, S, nkv, nh // nkv, dh).astype(k.dtype)
+    return _by_query_block(one, qg, block).reshape(B, S, nh, dh)
+
+
+def band_attention_fn(q, k, v, q_pos0, k_pos0, window: int, sm_scale: float):
+    """q [B, S, nh, dh] at positions `q_pos0[b] + s` over k/v [B, T, nkv,
+    dh] at positions `k_pos0[b] + t`: a query at p sees the keys at
+    `p - window + 1 .. p`. A block of queries is multiplied with the
+    `block + window - 1` keys its band covers, sliced out of k/v, and with
+    nothing else -> [B, S, nh, dh] float32."""
+    B, S, nh, dh = q.shape
+    T, nkv = k.shape[1], k.shape[2]
+    block = S if S <= _BAND_BLOCK or S % _BAND_BLOCK else _BAND_BLOCK
+    span = min(T, block + window - 1)
+
+    def one(j, qb):
+        first = q_pos0 + j * block                               # [B]
+        lo = jnp.clip(first - (window - 1) - k_pos0, 0, T - span)
+        cut = jax.vmap(lambda a, at: jax.lax.dynamic_slice_in_dim(
+            a, at, span, axis=0))
+        qp = first[:, None] + jnp.arange(block, dtype=jnp.int32)
+        kp = (k_pos0 + lo)[:, None] + jnp.arange(span, dtype=jnp.int32)
+        seen = (kp[:, None, :] <= qp[:, :, None]) \
+            & (qp[:, :, None] - kp[:, None, :] < window)
+        return _attend(qb, cut(k, lo), cut(v, lo), seen, sm_scale)
+
+    qg = q.reshape(B, S, nkv, nh // nkv, dh).astype(k.dtype)
+    return _by_query_block(one, qg, block).reshape(B, S, nh, dh)
+
+
+def sigmoid_router_fn(z, router_w, router_bias, k: int, scaling: float):
+    """z [T, H] float32 -> (ids [T, k] int32: the k experts of largest
+    `sigmoid(z W_r) + bias`, in order, ties to the lower index; cw [T, E]
+    float32: `scaling * s_e / sum_chosen s` at the chosen, zero
+    elsewhere)."""
+    s = jax.nn.sigmoid(jnp.dot(z, router_w, precision=_HI))
+    _, ids = jax.lax.top_k(s + router_bias, k)
+    chosen = jnp.take_along_axis(s, ids, axis=-1)
+    weights = scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    held = jnp.arange(s.shape[-1], dtype=jnp.int32)
+    cw = jnp.sum(jnp.where(ids[:, :, None] == held, weights[:, :, None],
+                           0.0), axis=1)
+    return ids.astype(jnp.int32), cw
+
+
+def _mm(x, w):
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_F32)
+
+
+def swiglu_fn(z, w_gate, w_up, w_down):
+    """z [T, H] float32 -> `W_d(silu(W_g z) * (W_u z))` float32."""
+    g = _mm(z, w_gate)
+    return _mm(g * jax.nn.sigmoid(g) * _mm(z, w_up), w_down)
+
+
+# ---------------------------------------------------------------------------
+# one layer, in two halves around the attention
+# ---------------------------------------------------------------------------
+
+
+def _rotary_of(kind: str, geom: Geometry):
+    """(inverse frequencies, rotary lanes, factor on cos and sin)."""
+    if kind == FULL:
+        yarn = tuple(geom.yarn)
+        return (yarn_inv_freq_fn(geom.full_rotary_dim, geom.full_theta, yarn),
+                geom.full_rotary_dim, float(yarn[4]) if yarn else 1.0)
+    return (yarn_inv_freq_fn(geom.slide_rotary_dim, geom.slide_theta),
+            geom.slide_rotary_dim, 1.0)
+
+
+def _pre_attention(x, norm, p, positions, kind: str, geom: Geometry):
+    """x [B, S, H] -> q [B, S, nh, dh], k, v [B, S, nkv, dh], gate [B, S,
+    nh] (float32; nh the kind's)."""
+    B, S, _ = x.shape
+    nkv, dh = geom.num_kv_heads, geom.head_dim
+    nh = geom.full_heads if kind == FULL else geom.slide_heads
+    inv_freq, rot, factor = _rotary_of(kind, geom)
+    z = rms_norm_fn(x, norm, geom.eps)
+    q = rotary_fn(_mm(z, p["wq"]).reshape(B, S, nh, dh), positions,
+                  inv_freq, rot, factor)
+    k = rotary_fn(_mm(z, p["wk"]).reshape(B, S, nkv, dh), positions,
+                  inv_freq, rot, factor)
+    v = _mm(z, p["wv"]).reshape(B, S, nkv, dh)
+    gate = jax.nn.sigmoid(jnp.dot(z, p["wg"], precision=_HI))
+    return q, k, v, gate
+
+
+def _feed_forward(h, norm, kind: str, p, experts, index, geom: Geometry,
+                  tag: str):
+    """h [B, S, H] -> (y [B, S, H], ids [B, S, k] or None)."""
+    B, S, H = h.shape
+    z = rms_norm_fn(h, norm, geom.eps).reshape(B * S, H)
+    if kind == DENSE:
+        return h + swiglu_fn(z, p["w_gate"], p["w_up"],
+                             p["w_down"]).reshape(B, S, H), None
+    ids, cw = sigmoid_router_fn(z, p["router_w"], p["router_bias"],
+                                geom.experts_per_token, geom.routed_scaling)
+    y = moe_topk_experts_fn(z, cw, *experts, layer=index, tag=tag) \
+        + swiglu_fn(z, p["shared_gate"], p["shared_up"], p["shared_down"])
+    return h + y.reshape(B, S, H), ids.reshape(B, S, -1)
+
+
+# ---------------------------------------------------------------------------
+# the stack
+# ---------------------------------------------------------------------------
+
+
+def hybrid_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
+                        layer_params: dict, attention: dict, dense: dict,
+                        moe: dict, experts: tuple, plan: tuple,
+                        geom: Geometry, pools=None, page_table=None,
+                        window_table=None, window_base=None, lens=None,
+                        start=None, mask=None, num_pages: int = 0,
+                        window_pages: int = 0):
+    """Run the decoder. `mode`:
+
+      full     tok/pos [B, S]                          -> logits [B, S, V]
+      window   + page_table, window_table, window_base,
+               start, lens (context in the pools;
+               `prefill` is start 0)                   -> last logits [B, V]
+      decode   tok/pos [B], page_table, window_table,
+               window_base, mask [B]                   -> logits [B, V]
+
+    `attention` holds the weights of a kind under `FULL` and `SLIDE`, each
+    a dict of `ATTENTION_PARAMS` stacked over the layers of that kind;
+    `dense`, `moe` and `experts` alike over theirs. Returns a dict: logits;
+    routes ([B, S, L_moe, k], decode [B, L_moe, k]); with `pools` (K, V of
+    the full layers, K, V of the sliding ones) the four as written."""
+    decode = mode == "decode"
+    paged = mode != "full"
+    if decode:
+        tok, pos = jnp.reshape(tok, (-1, 1)), jnp.reshape(pos, (-1, 1))
+    x = emb[tok].astype(_F32)
+    B, S, _ = x.shape
+    sm_scale = geom.head_dim ** -0.5
+    tag = "decode" if decode else "prefill"
+    rel = jnp.arange(S, dtype=jnp.int32)[None, :]
+    W, nkv = int(geom.window), geom.num_kv_heads
+    if paged:
+        k_pool, v_pool, wk_pool, wv_pool = pools
+        page_size = k_pool.shape[1]
+        page_table = page_table.astype(jnp.int32)
+        window_table = window_table.astype(jnp.int32)
+        base = window_base.astype(jnp.int32)                      # [B]
+        first = (pos[:, 0] if decode
+                 else (start if start is not None
+                       else jnp.zeros((B,), jnp.int32))).astype(jnp.int32)
+        gpos = first[:, None] + rel                               # [B, S]
+        valid = (jnp.reshape(mask, (-1, 1)) > 0) if decode \
+            else rel < lens[:, None]
+        local = gpos - base[:, None]          # slots of the compact table
+    routes = []
+    for l, (a_kind, a_i, f_kind, f_i) in enumerate(plan):
+        p = {k: w[a_i] for k, w in attention[a_kind].items()}
+        q, k, v, gate = _pre_attention(x, layer_params["attn_norm"][l], p,
+                                       pos, a_kind, geom)
+        if not paged:
+            zero = jnp.zeros((B,), jnp.int32)
+            kd, vd = k.astype(emb.dtype), v.astype(emb.dtype)
+            o = causal_attention_fn(q, kd, vd, zero, sm_scale) \
+                if a_kind == FULL else \
+                band_attention_fn(q, kd, vd, zero, zero, W, sm_scale)
+        elif a_kind == FULL:
+            off = a_i * num_pages
+            table = page_table + off
+            kd, vd = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
+            if decode:
+                k_pool, v_pool = kv_cache_append_fn(
+                    k_pool, v_pool, kd[:, 0], vd[:, 0], table, first,
+                    valid[:, 0])
+                o = paged_decode_attention_fn(
+                    q[:, 0], k_pool, v_pool, table, first + 1,
+                    sm_scale=sm_scale)[:, None]
+            else:
+                idx = _page_row_index(page_table, gpos, page_size, off,
+                                      valid)
+                slot = gpos % page_size
+                k_pool = _write_rows(k_pool, kd.reshape(B, S, -1), idx, slot)
+                v_pool = _write_rows(v_pool, vd.reshape(B, S, -1), idx, slot)
+                o = causal_attention_fn(
+                    q, _gather_pages(k_pool, table, nkv),
+                    _gather_pages(v_pool, table, nkv), first, sm_scale)
+        else:
+            off = a_i * window_pages
+            table = window_table + off
+            kd, vd = k.astype(wk_pool.dtype), v.astype(wv_pool.dtype)
+            if decode:
+                at = local[:, 0]
+                wk_pool, wv_pool = kv_cache_append_fn(
+                    wk_pool, wv_pool, kd[:, 0], vd[:, 0], table, at,
+                    valid[:, 0])
+                o = paged_decode_attention_fn(
+                    q[:, 0], wk_pool, wv_pool, table, at + 1,
+                    sm_scale=sm_scale,
+                    first_live=jnp.maximum(at - (W - 1), 0))[:, None]
+            else:
+                idx = _page_row_index(window_table, local, page_size, off,
+                                      valid)
+                slot = local % page_size
+                wk_pool = _write_rows(wk_pool, kd.reshape(B, S, -1), idx,
+                                      slot)
+                wv_pool = _write_rows(wv_pool, vd.reshape(B, S, -1), idx,
+                                      slot)
+                o = band_attention_fn(
+                    q, _gather_pages(wk_pool, table, nkv),
+                    _gather_pages(wv_pool, table, nkv), first, base, W,
+                    sm_scale)
+        o = (o.astype(_F32) * gate[..., None]).reshape(B, S, -1)
+        h = x + _mm(o, p["wo"])
+        fp = {k: w[f_i] for k, w in (dense if f_kind == DENSE
+                                     else moe).items()}
+        x, ids = _feed_forward(h, layer_params["ffn_norm"][l], f_kind, fp,
+                               experts, f_i, geom, tag)
+        if ids is not None:
+            routes.append(ids)
+    xn = rms_norm_fn(x, final_norm, geom.eps)
+    if mode == "window":
+        at = jnp.clip(lens - 1, 0, S - 1)[:, None, None]
+        xn = jnp.take_along_axis(xn, at, axis=1)
+    logits = jnp.einsum("bsh,hv->bsv", xn.astype(head.dtype), head,
+                        preferred_element_type=_F32)
+    routes = jnp.stack(routes, axis=2)                    # [B, S, L_moe, k]
+    out = {"logits": logits if mode == "full" else logits[:, 0],
+           "routes": routes[:, 0] if decode else routes}
+    if paged:
+        out["pools"] = (k_pool, v_pool, wk_pool, wv_pool)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# registered ops
+# ---------------------------------------------------------------------------
+
+_POOL_SLOTS = ("KPool", "VPool", "WKPool", "WVPool")
+
+
+@register_op("hybrid_moe_stack", grad="none")
+def hybrid_moe_stack_op(ctx: ExecContext):
+    """The whole decoder in one op; see `hybrid_moe_stack_fn`. inputs: Tok,
+    Pos, Emb, Head, FinalNorm, LayerParams (`LAYER_PARAMS`), FullParams and
+    SlideParams (`ATTENTION_PARAMS` each), DenseParams, MoeParams, Experts,
+    and by mode PageTable, WindowTable, WindowBase, Lens, Start, Mask and
+    the four pools. attrs: mode, plan (flat: four entries a layer), the
+    geometry. Outputs: NextToken (greedy), Logits, Routes, and the pools
+    under their own names."""
+    mode = ctx.attr("mode")
+    geom = Geometry(*(ctx.attr(f) for f in Geometry._fields))
+    flat = list(ctx.attr("plan"))
+    plan = tuple((flat[i], int(flat[i + 1]), flat[i + 2], int(flat[i + 3]))
+                 for i in range(0, len(flat), 4))
+    paged = mode != "full"
+
+    def group(slot, names):
+        return dict(zip(names, ctx.inputs(slot)))
+
+    def opt(slot):
+        return ctx.input(slot).astype(jnp.int32) if ctx.has_input(slot) \
+            else None
+
+    out = hybrid_moe_stack_fn(
+        "window" if mode == "prefill" else mode,
+        ctx.input("Tok").astype(jnp.int32),
+        ctx.input("Pos").astype(jnp.int32), ctx.input("Emb"),
+        ctx.input("Head"), ctx.input("FinalNorm"),
+        group("LayerParams", LAYER_PARAMS),
+        {FULL: group("FullParams", ATTENTION_PARAMS),
+         SLIDE: group("SlideParams", ATTENTION_PARAMS)},
+        group("DenseParams", DENSE_PARAMS), group("MoeParams", MOE_PARAMS),
+        tuple(ctx.inputs("Experts")), plan, geom,
+        pools=tuple(ctx.input(s) for s in _POOL_SLOTS) if paged else None,
+        page_table=opt("PageTable"), window_table=opt("WindowTable"),
+        window_base=opt("WindowBase"), lens=opt("Lens"), start=opt("Start"),
+        mask=ctx.input("Mask") if ctx.has_input("Mask") else None,
+        num_pages=int(ctx.attr("num_pages", 0)),
+        window_pages=int(ctx.attr("window_pages", 0)))
+    res = {"Logits": out["logits"], "Routes": out["routes"],
+           "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
+    if paged:
+        res.update({s + "Out": pool
+                    for s, pool in zip(_POOL_SLOTS, out["pools"])})
+    return res
+
+
+@register_op("hybrid_copy_page", grad="none")
+def hybrid_copy_page_op(ctx: ExecContext):
+    """Copy-on-write for the two stacked pools: page Src of every full
+    layer to page Dst (rows `l * num_pages + page` of K and V), page WSrc
+    of every sliding layer to page WDst (rows `l * window_pages + page`).
+    A side with nothing to copy is given the same page twice."""
+    out = {}
+    for slots, src, dst, pages in (
+            (_POOL_SLOTS[:2], "Src", "Dst", "num_pages"),
+            (_POOL_SLOTS[2:], "WSrc", "WDst", "window_pages")):
+        s = ctx.input(src).astype(jnp.int32)[0]
+        d = ctx.input(dst).astype(jnp.int32)[0]
+        P = int(ctx.attr(pages))
+        for slot in slots:
+            pool = ctx.input(slot)
+            rows = jnp.arange(pool.shape[0] // P, dtype=jnp.int32) * P
+            out[slot + "Out"] = pool.at[rows + d].set(pool[rows + s])
+    return out

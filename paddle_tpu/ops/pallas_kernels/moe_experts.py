@@ -59,14 +59,14 @@ def _f_tile(ffn: int) -> int:
 
 def experts_supported(z_shape, w_gate_shape, dtype) -> bool:
     """z [T, H] against W_gate `[L, E, H, F]`: whole 128-lane rows on both
-    widths, a 2- or 4-byte dtype, at most 128 experts (the combine weights
-    ride one lane register, however many of them a token's row fills) and
-    an F tile (`_f_tile`) whose three slabs fit 8 MB."""
+    widths, a 2- or 4-byte dtype, at most 256 experts (the combine weights
+    ride one lane register a 128 experts, however many of them a token's
+    row fills) and an F tile (`_f_tile`) whose three slabs fit 8 MB."""
     if len(z_shape) != 2 or len(w_gate_shape) != 4:
         return False
     _, E, H, F = w_gate_shape
     return (z_shape[1] == H and H % _LANES == 0 and F % _LANES == 0
-            and E <= _LANES and jnp.dtype(dtype).itemsize in (2, 4)
+            and E <= 2 * _LANES and jnp.dtype(dtype).itemsize in (2, 4)
             and 3 * H * _f_tile(F) * jnp.dtype(dtype).itemsize
             <= 8 * 1024 * 1024)
 
@@ -83,7 +83,7 @@ def _kernel(layer_ref, z_ref, cw_ref, wg_ref, wu_ref, wd_ref, o_ref):
     z = z_ref[...]                                             # [tt, H]
     g = jnp.dot(z, wg_ref[0, 0], preferred_element_type=jnp.float32)
     u = jnp.dot(z, wu_ref[0, 0], preferred_element_type=jnp.float32)
-    cw = cw_ref[...]                                           # [tt, 128]
+    cw = cw_ref[...]                            # [tt, 128 or 256]
     lane = jax.lax.broadcasted_iota(jnp.int32, cw.shape, 1)
     col = jnp.sum(jnp.where(lane == e, cw, 0.0), axis=1, keepdims=True)
     hidden = (g * jax.nn.sigmoid(g) * u * col).astype(z.dtype)  # [tt, tf]
@@ -100,15 +100,16 @@ def _call(z, cw, w_gate, w_up, w_down, layer, tag, interpret, topk=False):
     tt = _TOKEN_TILE if T > _TOKEN_TILE else -(-T // sub) * sub
     t_pad = -(-T // tt) * tt
     tf = _f_tile(F)
+    lanes = -(-E // _LANES) * _LANES
     zp = jnp.zeros((t_pad, H), dtype).at[:T].set(z.astype(dtype))
-    cwp = jnp.zeros((t_pad, _LANES), jnp.float32).at[:T, :E].set(
+    cwp = jnp.zeros((t_pad, lanes), jnp.float32).at[:T, :E].set(
         cw.astype(jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(t_pad // tt, E, F // tf),
         in_specs=[
             pl.BlockSpec((tt, H), lambda t, e, f, l: (t, 0)),
-            pl.BlockSpec((tt, _LANES), lambda t, e, f, l: (t, 0)),
+            pl.BlockSpec((tt, lanes), lambda t, e, f, l: (t, 0)),
             pl.BlockSpec((1, 1, H, tf), lambda t, e, f, l: (l[0], e, 0, f)),
             pl.BlockSpec((1, 1, H, tf), lambda t, e, f, l: (l[0], e, 0, f)),
             pl.BlockSpec((1, 1, tf, H), lambda t, e, f, l: (l[0], e, f, 0)),

@@ -252,11 +252,15 @@ def _fetch_block(pt_ref, kl_ref, nxt_ref, pools, bufs, sem, count_ref, b, i,
     return half
 
 
-def _kernel(pt_ref, kl_ref, nxt_ref, q_ref, k_hbm, v_hbm, o_ref, m_ref,
-            l_ref, acc_ref, k_buf, v_buf, sem, count_ref, *, chunk_update,
-            chunk_tokens, page_size, group):
+def _kernel(pt_ref, kl_ref, nxt_ref, *refs, chunk_update, chunk_tokens,
+            page_size, group, windowed=False):
     """One grid step: block i (pages i * group ..) of row b. `chunk_update`
-    is the arm's online-softmax update over one chunk of the block."""
+    is the arm's online-softmax update over one chunk of the block.
+    `windowed`: a fourth prefetched scalar a row, its first live slot; the
+    slots of a chunk before it are masked (`n_dead`)."""
+    fl_ref = refs[0] if windowed else None
+    (q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf, sem,
+     count_ref) = refs[1:] if windowed else refs
     b = pl.program_id(0)
     i = pl.program_id(1)
     kv_len = kl_ref[b]
@@ -285,8 +289,10 @@ def _kernel(pt_ref, kl_ref, nxt_ref, q_ref, k_hbm, v_hbm, o_ref, m_ref,
 
         def one_chunk(c, carry):
             pages = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            dead = {"n_dead": fl_ref[b] - first - c * tokens} if windowed \
+                else {}
             chunk_update(q_ref, k_buf.at[half, pages], v_buf.at[half, pages],
-                         live - c * tokens, m_ref, l_ref, acc_ref)
+                         live - c * tokens, m_ref, l_ref, acc_ref, **dead)
             return carry
         jax.lax.fori_loop(0, jax.lax.div(live + (tokens - 1), tokens),
                           one_chunk, 0)
@@ -345,13 +351,14 @@ def _chunk_update(q_ref, k_ref, v_ref, n_live, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "num_kv_heads"))
-def _gqa_update(q, k, v, n_live, m_prev, l_prev, acc_prev, *, sm_scale,
-                num_kv_heads):
+def _gqa_update(q, k, v, n_live, m_prev, l_prev, acc_prev, n_dead=None, *,
+                sm_scale, num_kv_heads):
     """A chunk of the grouped-query arm: `q` [nh, dh], `k`, `v` [tokens,
-    nkv*dh] with the first `n_live` tokens live; returns the new (m, l,
-    acc). q.k and p.v are MXU products in float32 (`_dot3`), one KV head at
-    a time over ALL query rows; each row keeps the product of its own group
-    (the others cost no byte)."""
+    nkv*dh] with the first `n_live` tokens live (and, with `n_dead`, the
+    first `n_dead` of those not: a sliding window's slots before its first
+    live one); returns the new (m, l, acc). q.k and p.v are MXU products
+    in float32 (`_dot3`), one KV head at a time over ALL query rows; each
+    row keeps the product of its own group (the others cost no byte)."""
     (nh, dh), tokens = q.shape, k.shape[0]
     heads_per_kv = nh // num_kv_heads
     q3 = _stack3(q.astype(jnp.float32) * sm_scale)              # [3nh, dh]
@@ -361,11 +368,18 @@ def _gqa_update(q, k, v, n_live, m_prev, l_prev, acc_prev, *, sm_scale,
         sj = _dot3(q3, k[:, j * dh:(j + 1) * dh], ((1,), (1,)))
         mine = (head >= j * heads_per_kv) & (head < (j + 1) * heads_per_kv)
         s = jax.lax.select(mine, sj, s)                      # [nh, tokens]
-    live = jax.lax.broadcasted_iota(jnp.int32, (nh, tokens), 1) < n_live
+    slot = jax.lax.broadcasted_iota(jnp.int32, (nh, tokens), 1)
+    live = slot < n_live
+    if n_dead is not None:
+        live &= slot >= n_dead
     s = jax.lax.select(live, s, jnp.full_like(s, _NEG_INF))
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)                          # [nh, 128]
     pexp = jnp.exp(s - m_new[:, :1])
+    if n_dead is not None:
+        # a chunk wholly before the first live slot leaves m at -1e30 and
+        # every exp(s - m) at 1: the mask, not the exponent, zeroes them
+        pexp = jnp.where(live, pexp, 0.0)
     p3 = _stack3(pexp)                                       # [3nh, tokens]
     head = jax.lax.broadcasted_iota(jnp.int32, (nh, dh), 0)
     pv = jnp.zeros((nh, dh), jnp.float32)
@@ -378,20 +392,22 @@ def _gqa_update(q, k, v, n_live, m_prev, l_prev, acc_prev, *, sm_scale,
 
 
 def _gqa_chunk_update(q_ref, k_ref, v_ref, n_live, m_ref, l_ref, acc_ref, *,
-                      sm_scale, num_kv_heads):
+                      sm_scale, num_kv_heads, n_dead=None):
     """Grouped-query twin of `_chunk_update`: `nh` query heads (sublanes
     of one [nh, dh] tile) over `nkv` KV heads of dh = 128 lanes. A head is
     a whole register here, so the chunk is one update on the MXU."""
     pages, ps, width = k_ref.shape
+    dead = () if n_dead is None else (n_dead,)
     m_ref[...], l_ref[...], acc_ref[...] = _gqa_update(
         q_ref[0], k_ref[...].reshape(pages * ps, width),
         v_ref[...].reshape(pages * ps, width), n_live, m_ref[...],
-        l_ref[...], acc_ref[...], sm_scale=sm_scale,
+        l_ref[...], acc_ref[...], *dead, sm_scale=sm_scale,
         num_kv_heads=num_kv_heads)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
-def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
+def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret,
+          first_live=None):
     B, nh, dh = q.shape
     num_pages, ps, width = k_pool.shape
     P = page_table.shape[1]
@@ -399,6 +415,19 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
     group = pages_per_grid_step(P, ps, width, k_pool.dtype.itemsize)
     blocks = -(-P // group)
     kv_lens = kv_lens.astype(jnp.int32)
+    windowed = first_live is not None
+    if windowed and not grouped:
+        raise NotImplementedError(
+            "a first live slot is written for the grouped-query arm only")
+    if windowed:
+        # the pages wholly before a row's first live slot leave its table:
+        # the table turns left by that many entries, and the lengths with it
+        skip = jnp.clip(first_live.astype(jnp.int32), 0, kv_lens) // ps
+        at = jnp.minimum(skip[:, None] + jnp.arange(P, dtype=jnp.int32),
+                         P - 1)
+        page_table = jnp.take_along_axis(page_table, at, axis=1)
+        kv_lens = kv_lens - skip * ps
+        first_live = jnp.maximum(first_live.astype(jnp.int32) - skip * ps, 0)
     # clamp so a padded/garbage table entry names a real page; one row of
     # the flat table is `blocks * group` entries
     page_table = jnp.pad(
@@ -416,10 +445,12 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
         update = functools.partial(_chunk_update, sm_scale=float(sm_scale),
                                    head_dim=dh)
         row_shape, lanes, out_dtype = (1, 1, width), (1, width), q.dtype
-    row = pl.BlockSpec(row_shape, lambda b, i, pt, kl, nx: (b, 0, 0))
+    row = pl.BlockSpec(row_shape, lambda b, i, *prefetched: (b, 0, 0)) \
+        if windowed else \
+        pl.BlockSpec(row_shape, lambda b, i, pt, kl, nx: (b, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4 if windowed else 3,
         grid=(B, blocks),
         in_specs=[row, pool, pool],
         out_specs=row,
@@ -436,7 +467,8 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
     out = pl.pallas_call(
         functools.partial(
             _kernel, chunk_update=update, page_size=ps, group=group,
-            chunk_tokens=MXU_CHUNK_TOKENS if grouped else VPU_CHUNK_TOKENS),
+            chunk_tokens=MXU_CHUNK_TOKENS if grouped else VPU_CHUNK_TOKENS,
+            **({"windowed": True} if windowed else {})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B,) + row_shape[1:], out_dtype),
         cost_estimate=pl.CostEstimate(
@@ -449,9 +481,10 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="paged_decode_attention_gqa" if grouped
+        name="paged_window_attention_gqa" if windowed
+        else "paged_decode_attention_gqa" if grouped
         else "paged_decode_attention",
-    )(page_table, kv_lens, nxt,
+    )(page_table, kv_lens, nxt, *((first_live,) if windowed else ()),
       q.reshape((B,) + row_shape[1:]).astype(out_dtype), k_pool, v_pool)
     return out.reshape(B, nh, dh).astype(q.dtype)
 
@@ -477,7 +510,7 @@ def _workbench_register():
 
 @_workbench_register()
 def paged_decode_attention(q, k_pool, v_pool, page_table, kv_lens,
-                           sm_scale=1.0):
+                           sm_scale=1.0, first_live=None):
     """One decode step of ragged paged attention.
 
     q: [B, nh, dh] (this step's query per request row);
@@ -486,7 +519,14 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, kv_lens,
     page_table: [B, P] int32 (row b's context lives in pages
     page_table[b, 0..ceil(kv_lens[b]/page_size))); kv_lens: [B] int32 valid
     slot counts. Returns [B, nh, dh] in q's dtype. Callers gate on
-    `paged_supported`.
+    `paged_supported`. `first_live` [B] int32 (grouped-query arm only; a
+    sliding-window layer): row b attends slots `first_live[b] ..
+    kv_lens[b] - 1`; the slots before are masked, the pages wholly before
+    are never fetched, and the call runs under the name
+    `paged_window_attention_gqa`.
     """
+    if first_live is None:
+        return _call(q, k_pool, v_pool, page_table, kv_lens,
+                     float(sm_scale), bool(INTERPRET))
     return _call(q, k_pool, v_pool, page_table, kv_lens,
-                 float(sm_scale), bool(INTERPRET))
+                 float(sm_scale), bool(INTERPRET), first_live)

@@ -75,6 +75,21 @@ judge. The block routes
 Speculation, tensor parallelism and the fleet handoff are refused for it
 as for "cca_moe".
 
+Window and full attention layers (the "hybrid_moe" block): the layers that
+attend everything keep their K/V under `req.pages`, as every family does;
+the layers that attend a sliding window keep theirs in a SECOND pool
+(`window_pool`, the same `PagedKVPool`, page ids of its own) under a compact
+table, `req.wpages`, entry j the page of logical page `req.wfirst + j`. A
+row maps there only the pages its window touches: a page is released in the
+step after the window leaves it (`_slide_window`; a chunked prefill
+releases as it goes), so a row never holds more than
+`window_table_pages` of them whatever its context. Admission, growth,
+copy-on-write, preemption, the leak count and the audit answer for both
+pools. The prefix cache keeps a cached block's window page where one is
+still held, and a prompt resumes only a prefix whose window tail is held
+(`PrefixCache.match_resumable`); under pressure in the window pool alone
+the cache gives up window pages before a row is made to wait.
+
 Compile discipline (the PR 2 machinery doing serving duty):
   * prefill compiles once per prompt-length bucket (pow2 rounding); suffix
     prefill once per (suffix-bucket, page-bucket);
@@ -294,6 +309,10 @@ class GenRequest:
         self.sampling = sampling or SamplingParams()
         self.state = WAITING
         self.pages: list[int] = []
+        # a family with sliding-window layers: the row's pages of the window
+        # pool, logical pages wfirst .. wfirst + len(wpages) - 1
+        self.wpages: list[int] = []
+        self.wfirst = 0
         self.cached_len = 0      # slots mapped from the prefix cache
         self.admit_seq = -1      # admission order; preemption evicts the newest
         self.preemptions = 0
@@ -381,6 +400,7 @@ class ServingEngine:
                  pool_pages: int | None = None,
                  max_inflight: int | None = None,
                  policy: str | None = None,
+                 window_pool_pages: int | None = None,
                  seed: int = 0,
                  prefix_cache: bool | None = None,
                  draft_k: int | None = None,
@@ -403,7 +423,11 @@ class ServingEngine:
         device pools and weights every role reads/writes) to build a
         role-split engine. `prefill_only=True` skips the decode stage of
         every step: requests prefill, then sit RUNNING until
-        `extract_for_handoff` publishes them to a decode engine."""
+        `extract_for_handoff` publishes them to a decode engine.
+        `window_pool_pages` sizes the second pool of a family with
+        sliding-window layers (default: twice what `max_inflight` decoding
+        rows hold there while each grows into its next page, the other half
+        for prefills in flight and the prefix cache's tails)."""
         self.cfg = cfg or sv_model.decoder_tiny()
         self.page_size = int(page_size
                              or flags.get_flag("serving_page_size"))
@@ -503,7 +527,22 @@ class ServingEngine:
                                       pool_owner or f"engine@{id(self)}")
         else:
             self.pool = PagedKVPool(self.pool_pages, self.page_size)
-        self.prefix_cache = PrefixCache(self.pool) if prefix_cache else None
+        # the sliding-window layers' pool: the same allocator a second time
+        self.window_pool = None
+        self._wtable_decode = self._wtable_chunk = 0
+        if self.cfg.windowed:
+            kinds = self.cfg.layer_types
+            self._full_layers = kinds.count("full_attention")
+            self._slide_layers = len(kinds) - self._full_layers
+            self._wtable_decode = sv_model.window_table_pages(
+                self.cfg, self.page_size)
+            self._wtable_chunk = sv_model.window_table_pages(
+                self.cfg, self.page_size, self.cfg.prefill_chunk)
+            self.window_pool = PagedKVPool(
+                int(window_pool_pages or 2 * self.max_inflight
+                    * (self._wtable_decode + 1)), self.page_size)
+        self.prefix_cache = PrefixCache(self.pool, self.window_pool) \
+            if prefix_cache else None
         self._exe = Executor()
         self._scope = shared_scope if shared_scope is not None else Scope()
 
@@ -524,22 +563,26 @@ class ServingEngine:
         startup = Program()
         decoy_startup = Program()  # non-prefill progs re-declare; inits unused
         self._prefill_prog.random_seed = startup.random_seed = self.seed
+        second = {"window_pages": self.window_pool.num_pages} \
+            if self.window_pool is not None else {}
         with program_guard(self._prefill_prog, startup), \
                 unique_name.guard():
             self._prefill_io = sv_model.build_prefill_program(
-                self.cfg, self.pool_pages, self.page_size)
+                self.cfg, self.pool_pages, self.page_size, **second)
         with program_guard(self._decode_prog, decoy_startup), \
                 unique_name.guard():
             self._decode_io = sv_model.build_decode_program(
-                self.cfg, self.pool_pages, self.page_size, tp=self.tp)
+                self.cfg, self.pool_pages, self.page_size, tp=self.tp,
+                **second)
         with program_guard(self._window_prog, decoy_startup), \
                 unique_name.guard():
             self._window_io = sv_model.build_window_program(
-                self.cfg, self.pool_pages, self.page_size, tp=self.tp)
+                self.cfg, self.pool_pages, self.page_size, tp=self.tp,
+                **second)
         with program_guard(self._cow_prog, decoy_startup), \
                 unique_name.guard():
             self._cow_io = sv_model.build_cow_program(
-                self.cfg, self.pool_pages, self.page_size)
+                self.cfg, self.pool_pages, self.page_size, **second)
         # rng_counter pinned to what a FRESH scope's first run folds in:
         # on a shared scope the run counter has already advanced, and
         # letting it leak into the init keys would give every engine after
@@ -550,7 +593,12 @@ class ServingEngine:
         # every peer's context, so only the FIRST engine materializes them.
         # Identically-seeded startup runs make the weight re-init above a
         # bitwise no-op on a shared scope.
-        if self.cfg.scanned:
+        if self.cfg.windowed:
+            for geometry in sv_model.hybrid_pool_geometry(
+                    self.cfg, self.pool_pages, self.page_size,
+                    self.window_pool.num_pages):
+                create_stacked_pools(self._scope, *geometry)
+        elif self.cfg.scanned:
             create_stacked_pools(self._scope, *sv_model.stacked_pool_geometry(
                 self.cfg, self.pool_pages, self.page_size))
         elif not self._scope.has_var(
@@ -566,8 +614,9 @@ class ServingEngine:
             per_token = (self.cfg.experts_per_token,) \
                 if self.cfg.experts_per_token > 1 else ()
             self._page_routes = np.zeros(
-                (self.pool_pages, self.page_size, self.cfg.num_layers)
-                + per_token, np.int8)
+                (self.pool_pages, self.page_size, self.cfg.routed_layers)
+                + per_token,
+                np.int8 if self.cfg.num_experts <= 128 else np.int16)
         self._grid_steps_by_signature: dict[tuple[int, int], int] = {}
         self._prefill_run = self._exec_target(self._prefill_prog)
         self._decode_run = self._exec_target(self._decode_prog)
@@ -607,6 +656,11 @@ class ServingEngine:
             # chunked prefill and learned sparse attention (ISSUE 29)
             "prefill.chunks": 0, "sparse.context_tokens": 0,
             "sparse.selected_tokens": 0, "sparse.layer_steps": 0,
+            # window and full attention layers over two pools (ISSUE 33)
+            "kv.window_pages_released": 0, "kv.window_row_pages": 0,
+            "kv.global_row_pages": 0, "attn.full_context_tokens": 0,
+            "attn.window_context_tokens": 0, "attn.full_layer_steps": 0,
+            "attn.window_layer_steps": 0, "peak_window_pages_in_use": 0,
         }
         # the learned controller's per-engine epoch hook (ISSUE 20):
         # shadow by default — one perf_counter read per step until an
@@ -719,7 +773,11 @@ class ServingEngine:
     def _page_bucket(self, n: int) -> int:
         """The page-table width a step with `n` live pages compiles for: a
         power of two, or past `cfg.page_bucket_step` pages a multiple of
-        it."""
+        it; for a family of `cfg.one_page_bucket` the width of
+        `max_position` whatever `n`."""
+        if self.cfg.one_page_bucket:
+            n = max(n, self.pool.pages_for(self.cfg.max_position))
+            return n if n <= 32 else -(-n // 32) * 32
         step = self.cfg.page_bucket_step
         if step and n > step:
             return -(-n // step) * step
@@ -734,6 +792,19 @@ class ServingEngine:
         mark = np.full((sv_model.MARK_ROWS,), -1, np.int32)
         mark[:len(rows)] = rows
         return {sv_model.MARK_FEED: mark}
+
+    def _window_feed(self, rows, bb: int, width: int) -> dict:
+        """The compact tables of `rows` in the sliding layers' pool and the
+        position of each table's slot 0, for a step of `bb` rows ({} where
+        the family has no such pool)."""
+        if self.window_pool is None:
+            return {}
+        wpages = np.zeros((bb, width), np.int32)
+        wbase = np.zeros((bb,), np.int32)
+        for i, r in enumerate(rows):
+            wpages[i, :len(r.wpages)] = r.wpages
+            wbase[i] = r.wfirst * self.page_size
+        return {sv_model.WPAGES_FEED: wpages, sv_model.WBASE_FEED: wbase}
 
     def warmup_decode(self, max_context: int | None = None,
                       min_context: int = 1) -> int:
@@ -779,7 +850,8 @@ class ServingEngine:
                             sv_model.PAGES_FEED: pages,
                             sv_model.MASK_FEED: np.zeros((bb, 1),
                                                          np.float32),
-                            **self._mark_feed()}
+                            **self._mark_feed(),
+                            **self._window_feed((), bb, self._wtable_decode)}
                     outs = self._exe.run(
                         self._decode_run, feed=feed,
                         fetch_list=self._step_fetches(self._decode_io),
@@ -1079,7 +1151,14 @@ class ServingEngine:
             mapped.update(n.page for n in self.prefix_cache._nodes.values())
         in_use = getattr(self.pool, "owned_pages_in_use",
                          self.pool.pages_in_use)
-        return in_use - len(mapped)
+        leaked = in_use - len(mapped)
+        if self.window_pool is not None:
+            held = {p for r in self.requests.values() for p in r.wpages}
+            if self.prefix_cache is not None:
+                held.update(n.wpage for n in self.prefix_cache._nodes.values()
+                            if n.wpage is not None)
+            leaked += self.window_pool.pages_in_use - len(held)
+        return leaked
 
     def flush_prefix_cache(self) -> int:
         """Evict every prefix-cache entry no live request still maps (frees
@@ -1232,7 +1311,38 @@ class ServingEngine:
         if req.pages:
             self.pool.release(req.pages)
             req.pages = []
+        if req.wpages:
+            self.window_pool.release(req.wpages)
+            req.wpages = []
+        req.wfirst = 0
         req.cached_len = 0
+
+    def _allocate_window(self, n: int) -> list[int] | None:
+        """`_allocate` in the sliding layers' pool: when its free list runs
+        dry the prefix cache gives up window pages (least recently resumed
+        from first; the cached blocks stay) before giving up."""
+        if n <= 0:
+            return []
+        got = self.window_pool.allocate(n)
+        if got is None and self.prefix_cache is not None:
+            self.prefix_cache.strip_window(n - self.window_pool.free_count)
+            got = self.window_pool.allocate(n)
+        return got
+
+    def _slide_window(self, req: GenRequest, first_pos: int) -> None:
+        """Return to the sliding layers' pool the pages of `req` that lie
+        wholly before position `first_pos - (window - 1)`: no position from
+        `first_pos` on attends them."""
+        keep_from = max(0, first_pos - (self.cfg.sliding_window - 1)) \
+            // self.page_size
+        gone = min(len(req.wpages), keep_from - req.wfirst)
+        if gone <= 0:
+            return
+        with obs.span("serving.kv.window_release"):
+            self.window_pool.release(req.wpages[:gone])
+            del req.wpages[:gone]
+            req.wfirst += gone
+            self._count("kv.window_pages_released", gone)
 
     def _allocate(self, n: int) -> list[int] | None:
         """allocate() with prefix-cache pressure relief: when the free list
@@ -1255,6 +1365,12 @@ class ServingEngine:
         self.stats["occupancy_n"] += 1
         obs.gauge_set("serving.pages_in_use", used)
         obs.gauge_set("serving.pool_occupancy", used / self.pool.num_pages)
+        if self.window_pool is not None:
+            wused = self.window_pool.pages_in_use
+            self.stats["peak_window_pages_in_use"] = max(
+                self.stats["peak_window_pages_in_use"], wused)
+            obs.gauge_set("serving.kv.global_pages_in_use", used)
+            obs.gauge_set("serving.kv.window_pages_in_use", wused)
 
     # -- resilience: deadlines, shedding, the degradation ladder ------------
     def _terminate(self, req: GenRequest, state: str, counter: str,
@@ -1428,7 +1544,15 @@ class ServingEngine:
         problems: list[str] = []
         poisoned: list[int] = []
         holders: dict[int, int] = {}
+        wholders: dict[int, int] = {}
         for r in self.requests.values():
+            if r.state not in _TERMINAL:
+                for p in r.wpages:
+                    wholders[p] = wholders.get(p, 0) + 1
+                if len(set(r.wpages)) != len(r.wpages):
+                    problems.append(f"request {r.rid} maps a page of the "
+                                    f"window pool twice")
+                    poisoned.append(r.rid)
             if not r.pages or r.state in _TERMINAL:
                 continue
             bad = False
@@ -1449,7 +1573,12 @@ class ServingEngine:
         if self.prefix_cache is not None:
             for node in self.prefix_cache._nodes.values():
                 holders[node.page] = holders.get(node.page, 0) + 1
+                if node.wpage is not None:
+                    wholders[node.wpage] = wholders.get(node.wpage, 0) + 1
         problems.extend(self.pool.check_consistency(holders))
+        if self.window_pool is not None:
+            problems.extend("window pool: " + p for p in
+                            self.window_pool.check_consistency(wholders))
         return problems, poisoned
 
     def _recover(self, reason: str, poisoned=(), problems=()) -> None:
@@ -1475,6 +1604,7 @@ class ServingEngine:
             if req in self._running:
                 self._running.remove(req)
             req.pages = []  # garbage table; the pool rebuild reclaims it
+            req.wpages, req.wfirst = [], 0
             req.cached_len = 0
             req.state = ABORTED
             req.t_done = time.perf_counter()
@@ -1488,12 +1618,14 @@ class ServingEngine:
             del req.all_tokens[req.prompt_len:]  # replay from the prompt
             self._unmark(req)
             req.pages = []
+            req.wpages, req.wfirst = [], 0
             req.cached_len = 0
             req.state = WAITING
             req.admit_seq = -1
             self._count("recovery.replayed")
         for req in self._waiting:
             req.pages = []  # admission pins die with the pool rebuild
+            req.wpages, req.wfirst = [], 0
             req.cached_len = 0
         for req in self.requests.values():
             if req.state == HANDED_OFF:
@@ -1506,6 +1638,8 @@ class ServingEngine:
         if self.prefix_cache is not None:
             self.prefix_cache.clear()
         self.pool.reset()
+        if self.window_pool is not None:
+            self.window_pool.reset()
         post, _ = self.audit_pool()
         if post:
             raise RuntimeError(
@@ -1536,8 +1670,17 @@ class ServingEngine:
                 matched = []
                 if self.prefix_cache is not None:
                     self._count("prefix_lookups")
-                    matched = self.prefix_cache.match(
-                        req.all_tokens[:req.prompt_len])
+                    if self.window_pool is not None:
+                        # only a prefix whose window tail is still held
+                        matched, req.wfirst, tail = \
+                            self.prefix_cache.match_resumable(
+                                req.all_tokens[:req.prompt_len],
+                                self._wtable_decode - 1)
+                        self.window_pool.share(tail)
+                        req.wpages = list(tail)
+                    else:
+                        matched = self.prefix_cache.match(
+                            req.all_tokens[:req.prompt_len])
                     # pin the hit BEFORE allocating: the cache's own ref
                     # may be these pages' only holder, and _allocate's
                     # eviction relief under pool pressure could otherwise
@@ -1553,6 +1696,10 @@ class ServingEngine:
             lookahead = 0 if self._ladder_rung >= 2 else 1
             need = self.pool.pages_for(len(req.all_tokens) + lookahead)
             private = self._allocate(max(0, need - len(matched)))
+            if private is not None and self.window_pool is not None \
+                    and not self._reserve_window(req):
+                self.pool.release(private)
+                private = None
             if private is None:
                 # keep the pin on the request: abort/shed/deadline release
                 # it through _terminate, and the next attempt starts with
@@ -1581,6 +1728,36 @@ class ServingEngine:
             self._observe_host_seconds("serving.prefill", sp, fetch0)
             admitted += 1
         return admitted
+
+    def _reserve_window(self, req: GenRequest) -> bool:
+        """Whether the sliding layers' pool has, or the prefix cache can
+        give up, the pages `req` will hold there at once beyond those it
+        holds: its whole context, or at most a window and a chunk. A
+        prefill allocates them chunk by chunk and nothing else allocates
+        meanwhile, so a reservation that holds here cannot fail there."""
+        most = min(self.pool.pages_for(len(req.all_tokens) + 1) - req.wfirst,
+                   self._wtable_chunk) - len(req.wpages)
+        short = most - self.window_pool.free_count
+        if short > 0 and self.prefix_cache is not None:
+            self.prefix_cache.strip_window(short)
+        return most <= self.window_pool.free_count
+
+    def _window_for(self, req: GenRequest, first_pos: int, tokens: int,
+                    allocate) -> bool:
+        """Make `req`'s compact table in the sliding layers' pool cover
+        positions `first_pos - (window - 1) .. first_pos + tokens - 1`:
+        the pages before leave it, the pages the new positions touch are
+        taken with `allocate(1)` (False when that gave None)."""
+        self._slide_window(req, first_pos)
+        if not req.wpages:
+            req.wfirst = first_pos // self.page_size
+        last = (first_pos + tokens - 1) // self.page_size
+        while req.wfirst + len(req.wpages) <= last:
+            got = allocate(1)
+            if got is None:
+                return False
+            req.wpages.extend(got)
+        return True
 
     def _cut_hit_for_state(self, req: GenRequest, matched: list) -> list:
         """A block with state rows resumes from the row of the last WHOLE
@@ -1649,7 +1826,7 @@ class ServingEngine:
         ps = self.page_size
         pages = [r.pages[r.cache_len // ps] for r in rows]
         self._page_routes[pages, [r.cache_len % ps for r in rows]] = routes
-        L, E = self.cfg.num_layers, self.cfg.num_experts
+        L, E = self.cfg.routed_layers, self.cfg.num_experts
         per_layer = np.zeros((L, E), np.int64)
         layer_of = np.arange(L).reshape((1, L) + (1,) * (routes.ndim - 2))
         np.add.at(per_layer, (np.broadcast_to(layer_of, routes.shape),
@@ -1787,6 +1964,12 @@ class ServingEngine:
             m = min(chunk, n - c0)
             with obs.span("serving.prefill.chunk", rid=req.rid, chunk=i,
                           tokens=m):
+                if self.window_pool is not None and not self._window_for(
+                        req, c0, m, self._allocate_window):
+                    raise RuntimeError(
+                        f"request {req.rid}: the window pool "
+                        f"({self.window_pool.num_pages} pages) ran dry "
+                        f"inside a prefill that admission had reserved")
                 with obs.span("serving.feed_build"):
                     sb = chunk if m == chunk else self._seq_bucket(m)
                     tok = np.zeros((1, sb), np.int32)
@@ -1796,7 +1979,9 @@ class ServingEngine:
                     feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
                             sv_model.PAGES_FEED: pages,
                             sv_model.START_FEED: np.asarray([c0], np.int32),
-                            sv_model.LEN_FEED: np.asarray([m], np.int32)}
+                            sv_model.LEN_FEED: np.asarray([m], np.int32),
+                            **self._window_feed((req,), 1,
+                                                self._wtable_chunk)}
                 nxt, routes, lg, sel = self._run_step(
                     "prefill_chunk", self._window_run, self._window_io,
                     feed, req.sampling.is_greedy, "last_logits",
@@ -1809,15 +1994,28 @@ class ServingEngine:
                         self._note_routes(req, c0, np.asarray(routes)[0, :m])
                     if req.marked:
                         self._keep_selection(req, sel[0, :m])
+                    if self.window_pool is not None and c0 + m < n:
+                        # before the next chunk lets go of window pages,
+                        # the cache takes its reference on them
+                        self._register_prefix(req, c0 + m)
         return nxt, lg
 
-    def _register_prefix(self, req: GenRequest) -> None:
-        """Index the request's full PROMPT pages so later arrivals sharing
-        the prompt map them instead of recomputing. The cache takes its own
-        refcount per page, so the entries outlive the request."""
-        if self.prefix_cache is not None:
-            self.prefix_cache.insert(req.all_tokens[:req.prompt_len],
-                                     req.pages)
+    def _register_prefix(self, req: GenRequest, upto: int | None = None
+                         ) -> None:
+        """Index the request's full PROMPT pages (those before position
+        `upto`, while a chunked prefill is under way) so later arrivals
+        sharing the prompt map them instead of recomputing. The cache takes
+        its own refcount per page, so the entries outlive the request; with
+        a second pool also on the window pages the request still holds."""
+        if self.prefix_cache is None:
+            return
+        n = req.prompt_len if upto is None else min(upto, req.prompt_len)
+        if self.window_pool is None:
+            self.prefix_cache.insert(req.all_tokens[:n], req.pages)
+        else:
+            self.prefix_cache.insert(
+                req.all_tokens[:n], req.pages,
+                {req.wfirst + j: p for j, p in enumerate(req.wpages)})
 
     def _accept_token(self, req: GenRequest, tok: int) -> None:
         req.all_tokens.append(tok)
@@ -1853,30 +2051,65 @@ class ServingEngine:
     def _cow(self, req: GenRequest, ordinal: int) -> bool:
         """Copy-on-write req's page `ordinal`: fresh page, one in-place
         device copy across every layer's K/V pools, table repointed, old
-        refcount released (other holders untouched). Returns False when the
-        pool pressure this created preempted `req` itself."""
-        new = self._allocate(1)
+        refcount released (other holders untouched). Over two pools the
+        page of EITHER pool that someone else maps is copied, in the one
+        device step (the side with nothing to copy is given page 0 onto
+        itself). Returns False when the pool pressure this created
+        preempted `req` itself."""
+        windowed = self._window_shared(req, ordinal)
+        # the caller's page is copied whoever maps it, unless the call is
+        # for the window pool's page alone
+        sides = [(self.pool, req.pages, ordinal, self._allocate,
+                  sv_model.COW_SRC_FEED, sv_model.COW_DST_FEED,
+                  not windowed or self.pool.refcount(req.pages[ordinal]) > 1)]
+        if self.window_pool is not None:
+            sides.append((self.window_pool, req.wpages, ordinal - req.wfirst,
+                          self._allocate_window, sv_model.COW_WSRC_FEED,
+                          sv_model.COW_WDST_FEED, windowed))
+        fresh, feed = [], {}
+        for pool, table, at, allocate, src, dst, copied in sides:
+            new = None
+            if copied:
+                new = self._take_or_preempt(req, allocate)
+                if new is None:       # `req` itself was preempted
+                    for other, _, _, page in fresh:
+                        other.release([page])
+                    return False
+                fresh.append((pool, table, at, new[0]))
+            feed[src] = np.asarray([table[at] if new else 0], np.int32)
+            feed[dst] = np.asarray([new[0] if new else 0], np.int32)
+        self._dispatch("cow", self._cow_run, feed, [])
+        for pool, table, at, page in fresh:
+            if pool is self.pool and self._page_routes is not None:
+                self._page_routes[page] = self._page_routes[table[at]]
+            pool.release([table[at]])
+            table[at] = page
+        self._count("cow_copies")
+        return True
+
+    def _take_or_preempt(self, req: GenRequest, allocate):
+        """`allocate(1)`, the youngest running request preempted while it
+        gives None; None once that was `req` itself."""
+        new = allocate(1)
         while new is None:
             victim = max(self._running, key=lambda r: r.admit_seq)
             if victim is req and len(self._running) == 1:
                 raise RuntimeError(
-                    f"request {req.rid} needs a copy-on-write page but the "
-                    f"pool ({self.pool.num_pages} pages) is exhausted with "
-                    f"nothing left to preempt")
+                    f"request {req.rid} needs a page but its pool is "
+                    f"exhausted with nothing left to preempt (the pool has "
+                    f"{self.pool.num_pages} pages)")
             self._preempt(victim)
             if victim is req:
-                return False
-            new = self._allocate(1)
-        old = req.pages[ordinal]
-        self._dispatch("cow", self._cow_run, {
-            sv_model.COW_SRC_FEED: np.asarray([old], np.int32),
-            sv_model.COW_DST_FEED: np.asarray([new[0]], np.int32)}, [])
-        if self._page_routes is not None:
-            self._page_routes[new[0]] = self._page_routes[old]
-        self.pool.release([old])
-        req.pages[ordinal] = new[0]
-        self._count("cow_copies")
-        return True
+                return None
+            new = allocate(1)
+        return new
+
+    def _window_shared(self, req: GenRequest, ordinal: int) -> bool:
+        """Whether logical page `ordinal` of `req` is a page of the window
+        pool that someone else maps too."""
+        j = ordinal - req.wfirst
+        return self.window_pool is not None and 0 <= j < len(req.wpages) \
+            and self.window_pool.refcount(req.wpages[j]) > 1
 
     def _ensure_writable(self, lookahead: int = 0) -> dict[int, int]:
         """Every running request must OWN every page its next write window
@@ -1915,10 +2148,17 @@ class ServingEngine:
                     break
             if req.state != RUNNING:
                 continue
+            if self.window_pool is not None and not self._window_for(
+                    req, req.cache_len, 1,
+                    lambda n, req=req: self._take_or_preempt(
+                        req, self._allocate_window)):
+                continue              # preempted for a window page
             top = min(req.cache_len + extra, len(req.pages) * ps - 1)
             ok = True
             for o in range(req.cache_len // ps, top // ps + 1):
-                if self.pool.refcount(req.pages[o]) > 1:
+                if self.pool.refcount(req.pages[o]) > 1 or (
+                        self.window_pool is not None
+                        and self._window_shared(req, o)):
                     if not self._cow(req, o):
                         ok = False
                         break
@@ -1985,12 +2225,25 @@ class ServingEngine:
             marked = [i for i, r in enumerate(rows) if r.marked]
             feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
                     sv_model.PAGES_FEED: pages, sv_model.MASK_FEED: mask,
-                    **self._mark_feed(marked)}
+                    **self._mark_feed(marked),
+                    **self._window_feed(rows, bb, self._wtable_decode)}
         self._step_rows = len(rows)
         sp.note(rows=len(rows), bb=bb, pb=pb)
         self._count("decode_context_pages",
                     sum(r.cache_len // self.page_size + 1 for r in rows))
         self._count("decode_grid_steps", self._decode_grid_steps(bb, pb))
+        if self.window_pool is not None:
+            full, slide = self._full_layers, self._slide_layers
+            W = self.cfg.sliding_window
+            self._count("kv.global_row_pages", sum(len(r.pages) for r in rows))
+            self._count("kv.window_row_pages",
+                        sum(len(r.wpages) for r in rows))
+            self._count("attn.full_context_tokens",
+                        full * sum(r.cache_len + 1 for r in rows))
+            self._count("attn.window_context_tokens",
+                        slide * sum(min(W, r.cache_len + 1) for r in rows))
+            self._count("attn.full_layer_steps", full)
+            self._count("attn.window_layer_steps", slide)
         if self.cfg.selects_within(pb * self.page_size):
             L, k = self.cfg.num_layers, self.cfg.index_topk
             self._count("sparse.context_tokens",

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 
 __all__ = ["PagedKVPool", "PrefixCache", "OwnedPoolView", "pool_var_names",
            "pool_shape", "create_device_pools", "declare_pool_vars",
-           "STACKED_POOLS", "INDEX_POOL", "JOINED_POOL",
+           "STACKED_POOLS", "INDEX_POOL", "JOINED_POOL", "WINDOW_POOLS",
            "stacked_pool_shapes",
            "declare_stacked_pools",
            "create_stacked_pools"]
@@ -120,11 +120,18 @@ def create_device_pools(scope, num_layers: int, num_pages: int,
 STACKED_POOLS = ("kv_cache.k", "kv_cache.v", "kv_cache.state")
 INDEX_POOL = "kv_cache.index"
 JOINED_POOL = "kv_cache.kv"
+# A family with sliding-window layers ("hybrid_moe") keeps THEIR K and V in
+# a second pair of stacked pools with page ids of their own (a second
+# `PagedKVPool`): a sliding layer needs the last `window` tokens of a row
+# and nothing older, so a row maps there only the pages its window touches,
+# while its full-attention layers keep every page in the pools above.
+WINDOW_POOLS = ("kv_cache.wk", "kv_cache.wv")
 
 
 def stacked_pool_shapes(num_layers: int, num_pages: int, page_size: int,
                         kv_width: int, state_width: int, dtype: str,
-                        index_width: int = 0, joined: bool = False):
+                        index_width: int = 0, joined: bool = False,
+                        names: tuple = STACKED_POOLS):
     """[(name, shape, dtype)] of the stacked pools. K and V rows are
     `kv_width = num_kv_heads * head_dim` wide (`pool_shape`'s lane-dense
     row), in a pool each or, `joined`, side by side in one row of 32-bit
@@ -137,15 +144,16 @@ def stacked_pool_shapes(num_layers: int, num_pages: int, page_size: int,
     by side on the lanes, so that a page of 128 slots is whole 128-lane
     rows whatever the key's width (`pool_shape` says why a 64-wide minor
     dimension would not do) and the scores' matmul reads it as it lies."""
+    # `names`: the K, V (and state) pools' names, for a second set of them
     rows = int(num_layers) * int(num_pages)
     kv = (rows, int(page_size), int(kv_width))
     if joined:
         words = 2 * int(kv_width) * jnp.dtype(dtype).itemsize // 4
         pools = [(JOINED_POOL, kv[:2] + (words,), "int32")]
     else:
-        pools = [(STACKED_POOLS[0], kv, dtype), (STACKED_POOLS[1], kv, dtype)]
+        pools = [(names[0], kv, dtype), (names[1], kv, dtype)]
     if state_width:
-        pools.append((STACKED_POOLS[2], (rows, int(state_width)), "float32"))
+        pools.append((names[2], (rows, int(state_width)), "float32"))
     if index_width:
         pools.append((INDEX_POOL, (rows, int(index_width), int(page_size)),
                       dtype))
@@ -526,7 +534,8 @@ class OwnedPoolView:
 
 
 class _PrefixNode:
-    __slots__ = ("nid", "page", "key", "parent_id", "children", "last_use")
+    __slots__ = ("nid", "page", "key", "parent_id", "children", "last_use",
+                 "wpage", "wlast_use")
 
     def __init__(self, nid, page, key, parent_id):
         self.nid = nid
@@ -535,6 +544,8 @@ class _PrefixNode:
         self.parent_id = parent_id
         self.children = 0
         self.last_use = 0
+        self.wpage = None           # the block's page of the window pool
+        self.wlast_use = 0
 
 
 class PrefixCache:
@@ -557,10 +568,26 @@ class PrefixCache:
     stale ones — so `evict(need)` is O((popped + need) log n) instead of a
     full O(nodes) scan per freed page (a scheduler-thread stall at exactly
     the pool-pressure moments eviction runs).
+
+    Two pools (`window_pool`, a family with sliding-window layers): a node
+    keeps its block's page of the full layers' pool and, WHERE ONE IS STILL
+    HELD, its page of the sliding layers' pool (`wpage`, one more cache
+    refcount, in that pool). A prefix of n pages can be resumed only if the
+    window pages covering the window before position `n * page_size` are
+    all held (`match_resumable`): the K/V a sliding layer needs to go on.
+    The cache's reference keeps those tail pages alive after their request
+    ends; a node's two pages are evicted together; and under pressure in
+    the window pool alone the cache gives up window pages (never nodes),
+    least recently RESUMED FROM first (`strip_window`): a lookup stamps the
+    tail it hands out, not the path to it, so the interior of a long
+    shared prompt goes first and its tail last.
     """
 
-    def __init__(self, pool: PagedKVPool):
+    def __init__(self, pool: PagedKVPool, window_pool=None):
         self.pool = pool
+        self.window_pool = window_pool
+        self._wheap: list[tuple[int, int]] = []  # (wlast_use, nid), lazy
+        self.stripped_window_pages = 0
         self.page_size = pool.page_size
         self._nodes: dict[tuple, _PrefixNode] = {}
         self._by_id: dict[int, _PrefixNode] = {}
@@ -602,12 +629,76 @@ class PrefixCache:
         self.hit_pages += len(pages)
         return pages
 
-    def insert(self, tokens, pages: list[int]) -> int:
+    def _touch_window(self, node: _PrefixNode) -> None:
+        node.wlast_use = self._tick()
+        heapq.heappush(self._wheap, (node.wlast_use, node.nid))
+
+    def match_resumable(self, tokens, tail: int) -> tuple:
+        """`match` for two pools: the longest cached prefix of `tokens`
+        (full blocks) whose last `tail` blocks (all of them, if it has
+        fewer) each hold a window page, as (pages, first block of the tail,
+        the tail's window pages); a longer match whose window tail was
+        given up falls back to the longest shorter one that can resume, or
+        to ([], 0, [])."""
+        self.lookups += 1
+        path: list[_PrefixNode] = []
+        pid = 0
+        for i in range(len(tokens) // self.page_size):
+            block = tuple(int(t) for t in
+                          tokens[i * self.page_size:(i + 1) * self.page_size])
+            node = self._nodes.get((pid, block))
+            if node is None:
+                break
+            path.append(node)
+            pid = node.nid
+        # the longest n whose last min(tail, n) blocks all hold window pages
+        n = held = 0
+        for end in range(len(path), 0, -1):
+            want = min(tail, end)
+            if all(node.wpage is not None for node in path[end - want:end]):
+                n, held = end, want
+                break
+        for node in path[:n]:
+            self._touch(node)
+        for node in path[n - held:n]:
+            self._touch_window(node)
+        self.hit_pages += n
+        return ([node.page for node in path[:n]], n - held,
+                [node.wpage for node in path[n - held:n]])
+
+    def strip_window(self, need: int) -> int:
+        """Give up to `need` window pages back to the window pool's free
+        list, least recently resumed from first, nodes kept: only pages
+        nobody else maps (refcount 1 == the cache's own). Returns pages
+        freed."""
+        freed = 0
+        skipped: list[tuple[int, int]] = []
+        while freed < need and self._wheap:
+            stamp, nid = heapq.heappop(self._wheap)
+            node = self._by_id.get(nid)
+            if node is None or node.wpage is None \
+                    or node.wlast_use != stamp:
+                continue
+            if self.window_pool.refcount(node.wpage) != 1:
+                skipped.append((stamp, nid))
+                continue
+            self.window_pool.release([node.wpage])
+            node.wpage = None
+            self.stripped_window_pages += 1
+            freed += 1
+        for entry in skipped:
+            heapq.heappush(self._wheap, entry)
+        return freed
+
+    def insert(self, tokens, pages: list[int], window_pages=None) -> int:
         """Index `tokens`' full blocks onto `pages` (pages[i] must hold
         block i's KV, already written). New nodes take a cache refcount via
         pool.share; blocks already indexed are left on their existing page
         (first writer wins — both copies hold identical KV). Returns the
-        number of pages newly indexed."""
+        number of pages newly indexed. `window_pages` {block: its page of
+        the window pool}, for the blocks the writer still holds there: a
+        node without one takes it (one refcount in that pool), new or
+        not."""
         pid = 0
         added = 0
         for i in range(len(tokens) // self.page_size):
@@ -625,6 +716,10 @@ class PrefixCache:
                     self._by_id[pid].children += 1
                 added += 1
                 self.inserted_pages += 1
+            if window_pages and node.wpage is None and i in window_pages:
+                self.window_pool.share([window_pages[i]])
+                node.wpage = window_pages[i]
+                self._touch_window(node)
             self._touch(node)
             pid = node.nid
         return added
@@ -668,6 +763,8 @@ class PrefixCache:
                 # stamp may sit in `skipped` until the pass ends)
                 heapq.heappush(self._heap, (parent.last_use, parent.nid))
         self.pool.release([node.page])
+        if node.wpage is not None:
+            self.window_pool.release([node.wpage])
         self.evicted_pages += 1
 
     def clear(self) -> int:
@@ -680,6 +777,7 @@ class PrefixCache:
         self._nodes.clear()
         self._by_id.clear()
         self._heap.clear()
+        self._wheap.clear()
         return n
 
     def flush(self) -> int:

@@ -1,4 +1,4 @@
-"""Serving program builders. THREE block families exist, and
+"""Serving program builders. FOUR block families exist, and
 `DecoderConfig.block` selects one:
 
   * `"post_ln"` (the default; every other field at its default is the "bert
@@ -31,6 +31,22 @@
     program also returns what each layer's attention was given
     (`selection`: a window's mask in packed words, a decode row's
     gathered positions).
+  * `"hybrid_moe"` (`ops/hybrid_moe_ops.py`): layers of MORE THAN ONE
+    SHAPE, laid out by a plan read from the per-layer lists `layer_types`
+    ("full_attention" | "sliding_attention"), `mlp_layer_types` ("dense" |
+    "sparse") and `heads_per_layer` (`layer_plan`): full-attention layers
+    (`num_heads` query heads, partial rotary under YaRN) and
+    sliding-window layers (their own head count, the last
+    `sliding_window` positions, plain rotary) over the same KV heads, a
+    sigmoid gate a head on the attention's output; a dense SwiGLU
+    (`dense_ffn_size`) or the top-k of a sigmoid-scored mixture of experts
+    (`ffn_size` wide, their sum times `routed_scaling`) beside one shared
+    expert (`shared_expert_size`). Weights are stacked by layer KIND and
+    the layers run one after another. The sliding layers keep their K/V in
+    a second pair of stacked pools (`kv_cache.WINDOW_POOLS`, page ids and a
+    compact page table of their own: feeds `sv_wpages`, `sv_wbase`), the
+    full layers in the first. Prompts run in `prefill_chunk`-token windows
+    as "sparse_moe"'s do.
 
 Every family is expressed several times over ONE weight namespace:
 
@@ -64,13 +80,13 @@ from ..framework import default_main_program
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 from ..initializer import Constant, Normal, StackedNormal
-from ..ops import cca_moe_ops, sparse_moe_ops
-from .kv_cache import (INDEX_POOL, JOINED_POOL, STACKED_POOLS,
+from ..ops import cca_moe_ops, hybrid_moe_ops, sparse_moe_ops
+from .kv_cache import (INDEX_POOL, JOINED_POOL, STACKED_POOLS, WINDOW_POOLS,
                        declare_pool_vars, declare_stacked_pools,
                        pool_var_names)
 
 __all__ = ["DecoderConfig", "decoder_tiny", "cca_moe_tiny",
-           "sparse_moe_tiny",
+           "sparse_moe_tiny", "hybrid_moe_tiny", "layer_plan",
            "build_prefill_program",
            "build_decode_program", "build_window_program",
            "build_full_forward_program", "apply_tp_annotations"]
@@ -85,6 +101,12 @@ MASK_FEED = "batch_mask"  # the PR 2 row-mask convention (data_feeder)
 COW_SRC_FEED = "sv_cow_src"  # copy-on-write: source page id
 COW_DST_FEED = "sv_cow_dst"  # copy-on-write: destination page id
 MARK_FEED = "sv_mark"     # "sparse_moe" decode: rows whose selection is kept
+# "hybrid_moe": a row's compact page table in the sliding layers' pool, and
+# the global position of that table's slot 0 (a multiple of the page size)
+WPAGES_FEED = "sv_wpages"
+WBASE_FEED = "sv_wbase"
+COW_WSRC_FEED = "sv_cow_wsrc"   # copy-on-write in the sliding layers' pool
+COW_WDST_FEED = "sv_cow_wdst"
 # how many rows of a decode step can have their selection handed back
 MARK_ROWS = 8
 
@@ -118,15 +140,45 @@ class DecoderConfig:
     index_head_dim: int = 0
     index_topk: int = 0
     prefill_chunk: int = 0         # 0: a prompt is one prefill program
+    # "hybrid_moe" only: the per-layer lists the layer plan is read from
+    # (`num_heads` is a full layer's head count; `rope_theta` and
+    # `partial_rotary_factor` are a full layer's rotary, `yarn` its scaling:
+    # (factor, original context, beta_fast, beta_slow, attention factor))
+    layer_types: tuple = ()
+    mlp_layer_types: tuple = ()
+    heads_per_layer: tuple = ()
+    sliding_window: int = 0
+    sliding_rope_theta: float = 10000.0
+    sliding_rotary_factor: float = 1.0
+    yarn: tuple = ()
+    shared_expert_size: int = 0
+    dense_ffn_size: int = 0
+    routed_scaling: float = 1.0
     # a deployment's choice, any family: the fewest rows a decode step is
     # compiled for (a power of two). Steps of fewer live rows pay for that
     # many; every row bucket below it is a program less to compile
     min_row_bucket: int = 1
 
     def __post_init__(self):
-        if self.block not in ("post_ln", "cca_moe", "sparse_moe"):
+        if self.block not in ("post_ln", "cca_moe", "sparse_moe",
+                              "hybrid_moe"):
             raise ValueError(f"unknown DecoderConfig.block {self.block!r} "
-                             f"(post_ln | cca_moe | sparse_moe)")
+                             f"(post_ln | cca_moe | sparse_moe | hybrid_moe)")
+        for name in ("layer_types", "mlp_layer_types", "heads_per_layer",
+                     "yarn"):
+            setattr(self, name, tuple(getattr(self, name)))
+        if self.block == "hybrid_moe":
+            layer_plan(self)       # raises on lists that name no plan
+            if min(self.sliding_window, self.prefill_chunk,
+                   self.shared_expert_size) < 1 or self.yarn \
+                    and len(self.yarn) != 5:
+                raise ValueError(
+                    "block 'hybrid_moe' needs sliding_window, prefill_chunk, "
+                    "shared_expert_size and yarn as () or (factor, original "
+                    "context, beta_fast, beta_slow, attention factor)")
+            if not 1 <= self.experts_per_token <= self.num_experts:
+                raise ValueError("block 'hybrid_moe' needs num_experts >= "
+                                 "experts_per_token >= 1")
         if self.block == "sparse_moe":
             if min(self.index_heads, self.index_head_dim, self.index_topk,
                    self.prefill_chunk) < 1 or self.index_head_dim % 4:
@@ -179,7 +231,21 @@ class DecoderConfig:
         """Whether the layers are one scanned op over stacked weights and
         stacked pools (`kv_cache.STACKED_POOLS`). Speculation, tensor
         parallelism and the fleet handoff are not written for that form."""
-        return self.block in ("cca_moe", "sparse_moe")
+        return self.block in ("cca_moe", "sparse_moe", "hybrid_moe")
+
+    @property
+    def windowed(self) -> bool:
+        """Whether some layers attend a sliding window only and keep their
+        K/V in a second pool under page ids of their own."""
+        return self.block == "hybrid_moe"
+
+    @property
+    def routed_layers(self) -> int:
+        """Layers that route tokens to experts (the middle axis of a
+        request's `routes`)."""
+        if self.block == "hybrid_moe":
+            return sum(kind == "sparse" for kind in self.mlp_layer_types)
+        return self.num_layers
 
     @property
     def selects(self) -> bool:
@@ -200,6 +266,16 @@ class DecoderConfig:
         they round to a multiple of n, for a family whose every step scans
         its whole table (the dead part stays under an eighth)."""
         return 32 if self.selects else 0
+
+    @property
+    def one_page_bucket(self) -> bool:
+        """Whether every step is compiled at ONE page-table width, that of
+        `max_position`: a family whose layers are unrolled pays a compile
+        of every layer for each (row bucket, page bucket) program (ten page
+        buckets below 19k tokens are seventy decode programs), and whose
+        paged decode kernels pass a block of dead pages in a grid step of
+        a third of a microsecond."""
+        return self.windowed
 
 
 def decoder_tiny() -> DecoderConfig:
@@ -227,6 +303,26 @@ def sparse_moe_tiny(**over) -> DecoderConfig:
               experts_per_token=2, index_heads=2, index_head_dim=8,
               index_topk=8, prefill_chunk=16, rope_theta=1e7,
               rms_norm_eps=1e-6, max_position=128, block="sparse_moe")
+    kw.update(over)
+    return DecoderConfig(**kw)
+
+
+def hybrid_moe_tiny(**over) -> DecoderConfig:
+    """The "hybrid_moe" block at test size: a dense layer and two periods
+    of (full, sliding x 3), 4 and 6 query heads over 2 KV heads of 8, a
+    window of 8, 8 experts of width 16 and 2 a token beside a shared one,
+    prompts in chunks of 8."""
+    kw = dict(vocab_size=97, hidden_size=32, num_layers=9, num_heads=4,
+              num_kv_heads=2, attn_head_dim=8, ffn_size=16, num_experts=8,
+              experts_per_token=2, shared_expert_size=16, dense_ffn_size=64,
+              routed_scaling=2.5, sliding_window=8, prefill_chunk=8,
+              layer_types=("full_attention",) + 2 * (
+                  ("sliding_attention",) * 3 + ("full_attention",)),
+              mlp_layer_types=("dense",) + ("sparse",) * 8,
+              heads_per_layer=(4,) + 2 * ((6,) * 3 + (4,)),
+              partial_rotary_factor=0.5, rope_theta=5e5,
+              yarn=(64.0, 16, 64.0, 1.0, 1.4158883083359672),
+              rms_norm_eps=1e-6, max_position=128, block="hybrid_moe")
     kw.update(over)
     return DecoderConfig(**kw)
 
@@ -490,6 +586,253 @@ def _stacked_copy_page(pools, num_pages, src, dst):
         {"num_pages": int(num_pages)})
 
 
+# -- the "hybrid_moe" family -------------------------------------------------
+
+_ATTENTION_KINDS = {"full_attention": hybrid_moe_ops.FULL,
+                    "sliding_attention": hybrid_moe_ops.SLIDE}
+_FFN_KINDS = {"dense": hybrid_moe_ops.DENSE, "sparse": hybrid_moe_ops.MOE}
+
+
+def layer_plan(cfg: DecoderConfig) -> tuple:
+    """The layers of a "hybrid_moe" decoder, derived from its per-layer
+    lists: a tuple of (attention kind, index among the layers of that
+    kind, feed-forward kind, index among those), `hybrid_moe_ops`' kinds."""
+    lists = (cfg.layer_types, cfg.mlp_layer_types, cfg.heads_per_layer)
+    if {len(x) for x in lists} != {cfg.num_layers}:
+        raise ValueError(
+            f"block 'hybrid_moe' needs layer_types, mlp_layer_types and "
+            f"heads_per_layer of num_layers = {cfg.num_layers} entries each")
+    counts: dict = {}
+    plan = []
+    for attn, ffn, heads in zip(*lists):
+        if attn not in _ATTENTION_KINDS or ffn not in _FFN_KINDS:
+            raise ValueError(f"unknown layer kind {attn!r} / {ffn!r}")
+        a, f = _ATTENTION_KINDS[attn], _FFN_KINDS[ffn]
+        if a == hybrid_moe_ops.FULL and heads != cfg.num_heads:
+            raise ValueError(
+                f"a full-attention layer has {heads} heads, num_heads says "
+                f"{cfg.num_heads}")
+        if a == hybrid_moe_ops.SLIDE and heads != sliding_heads(cfg):
+            raise ValueError("sliding layers of different head counts")
+        if heads % cfg.kv_heads:
+            raise ValueError("num_kv_heads must divide every head count")
+        plan.append((a, counts.get(a, 0), f, counts.get(f, 0)))
+        counts[a] = counts.get(a, 0) + 1
+        counts[f] = counts.get(f, 0) + 1
+    if not all(counts.get(k) for k in (hybrid_moe_ops.FULL,
+                                       hybrid_moe_ops.SLIDE,
+                                       hybrid_moe_ops.DENSE,
+                                       hybrid_moe_ops.MOE)):
+        raise ValueError("block 'hybrid_moe' needs a layer of every kind: "
+                         "full and sliding attention, dense and sparse")
+    return tuple(plan)
+
+
+def sliding_heads(cfg: DecoderConfig) -> int:
+    """Query heads of a sliding layer (the first one's)."""
+    return next(h for t, h in zip(cfg.layer_types, cfg.heads_per_layer)
+                if t == "sliding_attention")
+
+
+def _kind_count(cfg: DecoderConfig, kind: str) -> int:
+    return sum(kind in (a, f) for a, _, f, _ in layer_plan(cfg))
+
+
+def window_table_pages(cfg: DecoderConfig, page_size: int,
+                       tokens: int = 1) -> int:
+    """Width of a row's compact table in the sliding layers' pool for a
+    step that computes `tokens` positions: the pages that `sliding_window -
+    1` positions back and `tokens` positions on can touch."""
+    return -(-(cfg.sliding_window + tokens - 2) // page_size) + 1
+
+
+def _hybrid_geometry(cfg: DecoderConfig) -> dict:
+    dh = cfg.head_dim
+    return {"full_heads": cfg.num_heads, "slide_heads": sliding_heads(cfg),
+            "num_kv_heads": cfg.kv_heads, "head_dim": dh,
+            "window": cfg.sliding_window, "eps": float(cfg.rms_norm_eps),
+            "full_rotary_dim": int(dh * cfg.partial_rotary_factor),
+            "full_theta": float(cfg.rope_theta),
+            "yarn": [float(v) for v in cfg.yarn],
+            "slide_rotary_dim": int(dh * cfg.sliding_rotary_factor),
+            "slide_theta": float(cfg.sliding_rope_theta),
+            "experts_per_token": cfg.experts_per_token,
+            "routed_scaling": float(cfg.routed_scaling)}
+
+
+def hybrid_pool_geometry(cfg: DecoderConfig, num_pages: int, page_size: int,
+                         window_pages: int) -> tuple:
+    """`kv_cache.stacked_pool_shapes`' arguments, the full layers' pools
+    and the sliding layers'."""
+    width = cfg.kv_heads * cfg.head_dim
+    return ((_kind_count(cfg, hybrid_moe_ops.FULL), num_pages, page_size,
+             width, 0, cfg.dtype),
+            (_kind_count(cfg, hybrid_moe_ops.SLIDE), window_pages, page_size,
+             width, 0, cfg.dtype, 0, False, WINDOW_POOLS))
+
+
+def _hybrid_param_specs(cfg: DecoderConfig) -> dict:
+    """name -> (shape, dtype, initializer), stacked by layer kind: norms
+    over all layers, `full.*` and `slide.*` over the attention layers of
+    that kind, `dense.*` over the dense layers, the router, the shared
+    expert and the experts over the routed ones. Matrices are drawn at
+    fan_in^-0.5, the attention's way back into the residual stream at 0.5x
+    and an expert's output (weighed by about scaling / k) at 2x of that;
+    the router at 2x, so that its sigmoids differ by more than rounding;
+    the queries at 3x, so that attention over random keys is peaked, as a
+    trained model's is, and not a near-uniform average of thousands of
+    values (which is next to nothing, whatever the window: at 1x a window
+    not honoured moved no logit by more than rounding does; my chip runs,
+    PR 33). The large ones are in `cfg.dtype`; norms, the gate and the
+    router in float32."""
+    L, H, F, E = cfg.num_layers, cfg.hidden_size, cfg.ffn_size, \
+        cfg.num_experts
+    nkv, dh = cfg.kv_heads, cfg.head_dim
+    Fd, Fs = cfg.dense_ffn_size, cfg.shared_expert_size
+    n = {kind: _kind_count(cfg, kind) for kind in (
+        hybrid_moe_ops.FULL, hybrid_moe_ops.SLIDE, hybrid_moe_ops.DENSE,
+        hybrid_moe_ops.MOE)}
+    f32, big = "float32", cfg.dtype
+    near_one = Normal(1.0, 0.05)
+    fan = Normal(0.0, H ** -0.5)
+    specs = {
+        "dec.word_emb": ([cfg.vocab_size, H], big, Normal(0.0, 0.02)),
+        "dec.lm_head": ([H, cfg.vocab_size], big, fan),
+        "dec.final_norm.scale": ([H], f32, near_one),
+        "attn_norm": ([L, H], f32, near_one),
+        "ffn_norm": ([L, H], f32, near_one),
+    }
+    for kind, nh in ((hybrid_moe_ops.FULL, cfg.num_heads),
+                     (hybrid_moe_ops.SLIDE, sliding_heads(cfg))):
+        k = n[kind]
+        specs.update({
+            f"{kind}.wq": ([k, H, nh * dh], big, Normal(0.0, 3.0 * H ** -0.5)),
+            f"{kind}.wk": ([k, H, nkv * dh], big, fan),
+            f"{kind}.wv": ([k, H, nkv * dh], big, fan),
+            f"{kind}.wg": ([k, H, nh], f32, fan),
+            f"{kind}.wo": ([k, nh * dh, H], big,
+                           Normal(0.0, 0.5 * (nh * dh) ** -0.5))})
+    Ld, Le = n[hybrid_moe_ops.DENSE], n[hybrid_moe_ops.MOE]
+    specs.update({
+        "dense.w_gate": ([Ld, H, Fd], big, fan),
+        "dense.w_up": ([Ld, H, Fd], big, fan),
+        "dense.w_down": ([Ld, Fd, H], big, Normal(0.0, Fd ** -0.5)),
+        "moe.router_w": ([Le, H, E], f32, Normal(0.0, 2.0 * H ** -0.5)),
+        "moe.router_bias": ([Le, E], f32, Normal(0.0, 0.01)),
+        "moe.shared_gate": ([Le, H, Fs], big, fan),
+        "moe.shared_up": ([Le, H, Fs], big, fan),
+        "moe.shared_down": ([Le, Fs, H], big, Normal(0.0, Fs ** -0.5)),
+        "w_gate": ([Le, E, H, F], big, StackedNormal(0.0, H ** -0.5)),
+        "w_up": ([Le, E, H, F], big, StackedNormal(0.0, H ** -0.5)),
+        "w_down": ([Le, E, F, H], big, StackedNormal(0.0, 2.0 * F ** -0.5)),
+    })
+    return specs
+
+
+_HYBRID_POOLS = tuple(zip(("KPool", "VPool", "WKPool", "WVPool"),
+                          STACKED_POOLS[:2] + WINDOW_POOLS))
+
+
+def _declare_hybrid_pools(cfg, num_pages, page_size, window_pages):
+    for geometry in hybrid_pool_geometry(cfg, num_pages, page_size,
+                                         window_pages):
+        declare_stacked_pools(default_main_program().global_block, *geometry)
+
+
+def _hybrid_stack(cfg: DecoderConfig, mode: str, tok, pos,
+                  num_pages: int = 0, page_size: int = 0,
+                  window_pages: int = 0, **feeds):
+    """Append the one `hybrid_moe_stack` op of a program; returns its
+    outputs (next_token, logits, routes)."""
+    helper = LayerHelper("hybrid_moe_stack")
+    params = {key: helper.create_parameter(
+        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
+        for key, (shape, dtype, init) in _hybrid_param_specs(cfg).items()}
+    ops = hybrid_moe_ops
+    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
+              "Head": [params["dec.lm_head"]],
+              "FinalNorm": [params["dec.final_norm.scale"]],
+              "LayerParams": [params[k] for k in ops.LAYER_PARAMS],
+              "FullParams": [params["full." + k]
+                             for k in ops.ATTENTION_PARAMS],
+              "SlideParams": [params["slide." + k]
+                              for k in ops.ATTENTION_PARAMS],
+              "DenseParams": [params["dense." + k]
+                              for k in ops.DENSE_PARAMS],
+              "MoeParams": [params["moe." + k] for k in ops.MOE_PARAMS],
+              "Experts": [params[k] for k in ops.EXPERT_PARAMS]}
+    inputs.update({slot: [var] for slot, var in feeds.items()})
+    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
+            for slot, dtype in (("NextToken", "int32"),
+                                ("Logits", "float32"), ("Routes", "int32"))}
+    if mode != "full":
+        _declare_hybrid_pools(cfg, num_pages, page_size, window_pages)
+        for slot, name in _HYBRID_POOLS:
+            inputs[slot] = [name]
+            outs[slot + "Out"] = [name]
+    helper.append_op(
+        "hybrid_moe_stack", inputs, outs,
+        dict(_hybrid_geometry(cfg), mode=mode, num_pages=int(num_pages),
+             window_pages=int(window_pages),
+             plan=[str(v) for layer in layer_plan(cfg) for v in layer]))
+    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
+            "routes": outs["Routes"][0]}
+
+
+def _window_feeds():
+    """The two feeds of a row's compact table in the sliding layers' pool."""
+    return {"WindowTable": L.data(name=WPAGES_FEED, shape=[1], dtype="int32"),
+            "WindowBase": L.data(name=WBASE_FEED, shape=[], dtype="int32")}
+
+
+def _hybrid_window_io(out):
+    return {"next_token": out["next_token"], "last_logits": out["logits"],
+            "routes": out["routes"],
+            "extra_feeds": [WPAGES_FEED, WBASE_FEED]}
+
+
+def _hybrid_prefill(cfg, num_pages, page_size, tok, pos, pages, lens,
+                    window_pages=0):
+    return _hybrid_window_io(_hybrid_stack(
+        cfg, "prefill", tok, pos, num_pages, page_size, window_pages,
+        PageTable=pages, Lens=lens, **_window_feeds()))
+
+
+def _hybrid_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
+                   lens, window_pages=0):
+    # a prompt's chunk or the suffix behind a prefix hit (no verify window)
+    return _hybrid_window_io(_hybrid_stack(
+        cfg, "window", tok, pos, num_pages, page_size, window_pages,
+        PageTable=pages, Start=start, Lens=lens, **_window_feeds()))
+
+
+def _hybrid_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask,
+                   window_pages=0):
+    return dict(_hybrid_stack(cfg, "decode", tok, pos, num_pages, page_size,
+                              window_pages, PageTable=pages, Mask=mask,
+                              **_window_feeds()),
+                extra_feeds=[WPAGES_FEED, WBASE_FEED])
+
+
+def _hybrid_full(cfg, tok, pos):
+    out = _hybrid_stack(cfg, "full", tok, pos)
+    return {"logits": out["logits"], "routes": out["routes"]}
+
+
+def _hybrid_cow(cfg, num_pages, page_size, src, dst, window_pages=0):
+    # a page of the full layers' pool and a page of the sliding layers'
+    _declare_hybrid_pools(cfg, num_pages, page_size, window_pages)
+    wsrc = L.data(name=COW_WSRC_FEED, shape=[], dtype="int32")
+    wdst = L.data(name=COW_WDST_FEED, shape=[], dtype="int32")
+    LayerHelper("hybrid_copy_page").append_op(
+        "hybrid_copy_page",
+        dict({slot: [name] for slot, name in _HYBRID_POOLS}, Src=[src],
+             Dst=[dst], WSrc=[wsrc], WDst=[wdst]),
+        {slot + "Out": [name] for slot, name in _HYBRID_POOLS},
+        {"num_pages": int(num_pages), "window_pages": int(window_pages)})
+    return [COW_WSRC_FEED, COW_WDST_FEED]
+
+
 def _proj(x, size, name, act=None):
     return L.fc(x, size=size, num_flatten_dims=len(x.shape) - 1,
                 param_attr=ParamAttr(name=name + ".w"),
@@ -562,7 +905,13 @@ def _prefill_layer(x, i, cfg: DecoderConfig, pages, lens, write_cache: bool):
     return _ffn_block(x, cfg, name)
 
 
-def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int):
+def _second_pool(window_pages: int) -> dict:
+    """The keyword a family with a second pool takes its size under."""
+    return {"window_pages": int(window_pages)} if window_pages else {}
+
+
+def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int,
+                          window_pages: int = 0):
     """Build (in the current default main program) the bucketed prefill.
 
     Feeds: sv_tok/sv_pos [B, S_bucket] int32, sv_pages [B, P] int32,
@@ -574,7 +923,8 @@ def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int):
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     lens = L.data(name=LEN_FEED, shape=[], dtype="int32")
     return _with_feeds(_FAMILY[cfg.block]["prefill"](
-        cfg, num_pages, page_size, tok, pos, pages, lens),
+        cfg, num_pages, page_size, tok, pos, pages, lens,
+        **_second_pool(window_pages)),
         [TOK_FEED, POS_FEED, PAGES_FEED, LEN_FEED])
 
 
@@ -638,7 +988,7 @@ def _window_layer(x, i, cfg: DecoderConfig, pages, start, lens, tp: int):
 
 
 def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
-                         tp: int = 1):
+                         tp: int = 1, window_pages: int = 0):
     """Build (in the current default main program) the windowed forward the
     two ISSUE 11 stages share:
 
@@ -665,7 +1015,8 @@ def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     start = L.data(name=START_FEED, shape=[], dtype="int32")
     lens = L.data(name=LEN_FEED, shape=[], dtype="int32")
     return _with_feeds(_FAMILY[cfg.block]["window"](
-        cfg, num_pages, page_size, tp, tok, pos, pages, start, lens),
+        cfg, num_pages, page_size, tp, tok, pos, pages, start, lens,
+        **_second_pool(window_pages)),
         [TOK_FEED, POS_FEED, PAGES_FEED, START_FEED, LEN_FEED])
 
 
@@ -697,7 +1048,8 @@ def _post_ln_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
             "logits": logits}
 
 
-def build_cow_program(cfg: DecoderConfig, num_pages: int, page_size: int):
+def build_cow_program(cfg: DecoderConfig, num_pages: int, page_size: int,
+                      window_pages: int = 0):
     """Build (in the current default main program) the copy-on-write step:
     one `kv_cache_copy_page` per layer — pool[Dst] := pool[Src] for K and V,
     in place. Feeds sv_cow_src/sv_cow_dst [1] int32; fetches nothing (the
@@ -705,8 +1057,9 @@ def build_cow_program(cfg: DecoderConfig, num_pages: int, page_size: int):
     per engine — COW cost is one tiny device step, not a recompile."""
     src = L.data(name=COW_SRC_FEED, shape=[], dtype="int32")
     dst = L.data(name=COW_DST_FEED, shape=[], dtype="int32")
-    _FAMILY[cfg.block]["cow"](cfg, num_pages, page_size, src, dst)
-    return {"feeds": [COW_SRC_FEED, COW_DST_FEED]}
+    more = _FAMILY[cfg.block]["cow"](cfg, num_pages, page_size, src, dst,
+                                     **_second_pool(window_pages))
+    return {"feeds": [COW_SRC_FEED, COW_DST_FEED] + (more or [])}
 
 
 def _cca_cow(cfg, num_pages, page_size, src, dst):
@@ -729,7 +1082,7 @@ def _post_ln_cow(cfg, num_pages, page_size, src, dst):
 
 
 def build_decode_program(cfg: DecoderConfig, num_pages: int, page_size: int,
-                         tp: int = 1):
+                         tp: int = 1, window_pages: int = 0):
     """Build (in the current default main program) the ragged decode step.
 
     Feeds: sv_tok [B, 1] int32 (each row's latest token), sv_pos [B] int32
@@ -742,7 +1095,8 @@ def build_decode_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     mask = L.data(name=MASK_FEED, shape=[1], dtype="float32")
     return _with_feeds(_FAMILY[cfg.block]["decode"](
-        cfg, num_pages, page_size, tp, tok, pos, pages, mask),
+        cfg, num_pages, page_size, tp, tok, pos, pages, mask,
+        **_second_pool(window_pages)),
         [TOK_FEED, POS_FEED, PAGES_FEED, MASK_FEED])
 
 
@@ -872,4 +1226,7 @@ _FAMILY = {
     "sparse_moe": {"prefill": _sparse_prefill, "window": _sparse_window,
                    "cow": _sparse_cow, "decode": _sparse_decode,
                    "full": _sparse_full},
+    "hybrid_moe": {"prefill": _hybrid_prefill, "window": _hybrid_window,
+                   "cow": _hybrid_cow, "decode": _hybrid_decode,
+                   "full": _hybrid_full},
 }

@@ -195,6 +195,41 @@ def test_sparse_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
         "decode": 1, "prefill": 0, "window": 0, "cow": 0}
 
 
+def test_hybrid_moe_programs_compiled_for_v5e_move_neither_pool(v5e_chip):
+    """The four programs of the "hybrid_moe" block at the served widths
+    (benchmark/configs/laguna_xs2.json: layers 0-4, 48 and 64 query heads
+    over 8 KV heads of 128, window 512, the dense layer of 8,192, experts of
+    512 top-8, the whole vocabulary; 16 experts a layer where the cell
+    holds 256, which sizes nothing but the weights drawn here) over both
+    pools as large as the cell's (2 x 2,048 and 3 x 768 pages of 128
+    bfloat16 slots), decode at 64 rows over the cell's 160-page tables and
+    5-page window tables: Mosaic takes the grouped-query paged kernel at 48
+    heads and, with a first live slot, at 64, and the expert kernel at F =
+    512; the unrolled layers carry four pools without a copy of any."""
+    import json
+    import os
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna_xs2.json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = DecoderConfig(**dict(engine["config_kwargs"], num_experts=16))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        out = chip_smoke.pool_layout_phase(
+            cfg, page_size=engine["page_size"],
+            pool_pages=engine["pool_pages"], rows=64, device=v5e_chip)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert out["pool_sized_copies"] == {
+        "decode": 0, "prefill": 0, "window": 0, "cow": 0}
+
+
 def test_token_row_gathers_counts_rows_not_slabs():
     """Recorded from the v5e's compiler: PR 29's decode layer fetched a
     selected token from two pools, PR 30's from one; a page's slab of

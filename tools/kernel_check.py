@@ -199,15 +199,47 @@ def _paged_gqa_cases(spec):
         res["ok"] = bool(res["finite"] and res["err"] <= res["tol"])
         return res
 
+    def window_case(nh):
+        # Laguna-XS.2's sliding layers (64 query heads; 48 are a full
+        # layer's) over 8 KV heads of 128: a row attends its last 512 slots
+        # of a six-page compact table, from a first live slot on
+        B, nkv, dh, ps, pages, P, W = 8, 8, 128, 128, 64, 6, 512
+        ks = jax.random.split(jax.random.PRNGKey(6), 4)
+        q = _rand(ks[0], (B, nh, dh), "float32")
+        kp = _rand(ks[1], (pages, ps, nkv * dh), "bfloat16")
+        vp = _rand(ks[2], (pages, ps, nkv * dh), "bfloat16")
+        table = jax.random.permutation(ks[3], pages)[:B * P].reshape(B, P)
+        lens = jnp.asarray([768, 640, 639, 513, 512, 130, 1, 0], jnp.int32)
+        first = jnp.maximum(lens - W, 0)
+        assert spec.supported(q.shape, kp.shape, "bfloat16")
+        res = _compare(
+            lambda *a: spec.fn(*a[:5], sm_scale=dh ** -0.5,
+                               first_live=a[5])[:7],
+            lambda *a: _windowed_reference(*a, dh ** -0.5)[:7],
+            (q, kp, vp, table.astype(jnp.int32), lens, first), 0, "float32")
+        res["tol"] = 2e-2
+        res["ok"] = bool(res["finite"] and res["err"] <= res["tol"])
+        return res
+
     return [(f"b8 nh8 nkv2 dh128 ps128 {dt} ragged",
-             lambda dt=dt: case(dt)) for dt in ("float32", "bfloat16")]
+             lambda dt=dt: case(dt)) for dt in ("float32", "bfloat16")] + [
+        (f"b8 nh{nh} nkv8 dh128 ps128 bfloat16 window 512 first live slot",
+         lambda nh=nh: window_case(nh)) for nh in (64, 48)]
+
+
+def _windowed_reference(q, kp, vp, table, lens, first, sm_scale):
+    from paddle_tpu.ops.attention_ops import _paged_attention_reference
+
+    return _paged_attention_reference(q, kp, vp, table, lens, sm_scale,
+                                      first)
 
 
 def _moe_cases(spec):
     """One layer of ZAYA1's experts (16 x 2048 -> 2048 -> 2048, bfloat16)
     out of a stack of two, at a decode batch and at a prefill window; and
     one of the "sparse_moe" block's (128 x 2048 -> 768 -> 2048, top-8), at
-    a decode batch and at a 512-token chunk."""
+    a decode batch and at a 512-token chunk; and one of the "hybrid_moe"
+    block's (256 x 2048 -> 512 -> 2048, top-8), the same two."""
 
     def case(tokens):
         L, E, H, F = 2, 16, 2048, 2048
@@ -241,10 +273,31 @@ def _moe_cases(spec):
                         lambda *a: spec.reference(*a, 1),
                         (z, cw, wg, wu, wd), 0, "bfloat16")
 
+    def wide_case(tokens):
+        # the "hybrid_moe" geometry: 256 experts of width 512 (one F tile an
+        # expert), the combine weights in two lane registers, eight a row
+        # summing to 2.5
+        L, E, H, F, k = 2, 256, 2048, 512, 8
+        ks = jax.random.split(jax.random.PRNGKey(7), 6)
+        z = _rand(ks[0], (tokens, H), "float32")
+        wg = _rand(ks[1], (L, E, H, F), "bfloat16", H ** -0.5)
+        wu = _rand(ks[2], (L, E, H, F), "bfloat16", H ** -0.5)
+        wd = _rand(ks[3], (L, E, F, H), "bfloat16", F ** -0.5)
+        vals, ids = jax.lax.top_k(jax.random.uniform(ks[4], (tokens, E)), k)
+        cw = jnp.sum(jax.nn.one_hot(ids, E)
+                     * (2.5 * vals / vals.sum(-1, keepdims=True))[..., None],
+                     1)
+        assert spec.supported(z.shape, wg.shape)
+        return _compare(lambda *a: spec.fn(*a, 1),
+                        lambda *a: spec.reference(*a, 1),
+                        (z, cw, wg, wu, wd), 0, "bfloat16")
+
     return [(f"t{t} e16 h2048 f2048 bf16 layer 1 of 2",
              lambda t=t: case(t)) for t in (64, 300)] + [
         (f"t{t} e128 top8 h2048 f768 bf16 layer 1 of 2",
-         lambda t=t: topk_case(t)) for t in (64, 512)]
+         lambda t=t: topk_case(t)) for t in (64, 512)] + [
+        (f"t{t} e256 top8 h2048 f512 bf16 layer 1 of 2",
+         lambda t=t: wide_case(t)) for t in (64, 512)]
 
 
 CASES = {

@@ -17,6 +17,11 @@ returns their texts.
 `chip_smoke.py` fails on a finding; run here it prints the table:
 
     python tools/pool_hlo.py [--pool-pages 3072] [--page-size 16]
+    python tools/pool_hlo.py --config zaya1_8b --layers 2 --dump <dir>
+
+(`--config`: the engine of `benchmark/configs/<name>.json`, any block
+family, `--layers` deep where the family is one scanned layer; every pool
+the family keeps is listed, the sliding layers' second pool too.)
 
 (on a host without a TPU it compiles for a described v5e: what the chip's
 compiler would emit, nothing run).
@@ -176,7 +181,9 @@ def serving_program_cases(engine, rows: int = 64, pages: int = 32,
     (suffix prefill) of the same bucket behind `pages` pages, and
     copy-on-write of page 0 onto itself. Every row is masked or of length
     0, so a run writes nothing. Feeds and fetches are the engine's own
-    (`_mark_feed`, `_step_fetches`): the signature it serves with."""
+    (`_mark_feed`, `_window_feed`: the compact tables of a second,
+    sliding-window pool where the family has one, `_step_fetches`): the
+    signature it serves with."""
     from paddle_tpu.serving import model as m
 
     e, i32 = engine, np.int32
@@ -186,24 +193,27 @@ def serving_program_cases(engine, rows: int = 64, pages: int = 32,
             m.POS_FEED: np.zeros((rows,), i32),
             m.PAGES_FEED: np.zeros((rows, pages), i32),
             m.MASK_FEED: np.zeros((rows, 1), np.float32),
-            **e._mark_feed()},
+            **e._mark_feed(),
+            **e._window_feed((), rows, e._wtable_decode)},
             e._step_fetches(e._decode_io)),
         "prefill": (e._prefill_run, {
             m.TOK_FEED: np.zeros((1, prompt), i32),
             m.POS_FEED: np.zeros((1, prompt), i32),
             m.PAGES_FEED: np.zeros((1, e.pool.pages_for(prompt)), i32),
-            m.LEN_FEED: np.zeros((1,), i32)},
+            m.LEN_FEED: np.zeros((1,), i32),
+            **e._window_feed((), 1, e._wtable_chunk)},
             e._step_fetches(e._prefill_io, "last_logits")),
         "window": (e._window_run, {
             m.TOK_FEED: np.zeros((1, prompt), i32),
             m.POS_FEED: np.zeros((1, prompt), i32),
             m.PAGES_FEED: np.zeros((1, pages), i32),
             m.START_FEED: np.zeros((1,), i32),
-            m.LEN_FEED: np.zeros((1,), i32)},
+            m.LEN_FEED: np.zeros((1,), i32),
+            **e._window_feed((), 1, e._wtable_chunk)},
             e._step_fetches(e._window_io, "last_logits")),
-        "cow": (e._cow_run, {
-            m.COW_SRC_FEED: np.zeros((1,), i32),
-            m.COW_DST_FEED: np.zeros((1,), i32)}, []),
+        # a family with a second pool copies a page of each
+        "cow": (e._cow_run, {name: np.zeros((1,), i32)
+                             for name in e._cow_io["feeds"]}, []),
     }
 
 
@@ -229,7 +239,11 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--pool-pages", type=int, default=3072)
     ap.add_argument("--page-size", type=int, default=16)
-    ap.add_argument("--layers", type=int, default=12)
+    ap.add_argument("--layers", type=int, default=None)
+    ap.add_argument("--config", default=None,
+                    help="a file of benchmark/configs (its engine block)")
+    ap.add_argument("--rows", type=int, default=64)
+    ap.add_argument("--pages", type=int, default=32)
     ap.add_argument("--dump", default=None,
                     help="directory to write <program>.hlo.txt into")
     a = ap.parse_args(argv)
@@ -240,18 +254,37 @@ def main(argv=None) -> int:
 
         device = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2").devices[0]
-    cfg = DecoderConfig(num_layers=a.layers)
-    eng = ServingEngine(cfg, page_size=a.page_size, pool_pages=a.pool_pages,
-                        max_inflight=64)
-    pool_elements = (a.pool_pages * a.page_size * cfg.num_heads
-                     * cfg.head_dim)
+    if a.config:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs",
+                               a.config + ".json")) as f:
+            spec = json.load(f)["engine"]
+        kw = dict(spec["config_kwargs"])
+        if a.layers and "layer_types" not in kw:
+            kw["num_layers"] = a.layers
+        cfg = DecoderConfig(**kw)
+        eng = ServingEngine(cfg, page_size=spec["page_size"],
+                            pool_pages=spec["pool_pages"],
+                            max_inflight=spec["max_inflight"])
+    else:
+        cfg = DecoderConfig(num_layers=a.layers or 12)
+        eng = ServingEngine(cfg, page_size=a.page_size,
+                            pool_pages=a.pool_pages, max_inflight=64)
+    # every pool the family keeps, by its own size
+    pools = {name: int(np.prod(np.shape(eng._scope.find_var(name))))
+             for name in sorted(eng._scope.var_names())
+             if name.startswith("kv_cache.")}
+    print(json.dumps({"pools": pools}), flush=True)
     bad = 0
-    for name, text in serving_program_hlos(eng, device=device).items():
+    texts = serving_program_hlos(eng, rows=a.rows, pages=a.pages,
+                                 device=device)
+    for name, text in texts.items():
         if a.dump:
             os.makedirs(a.dump, exist_ok=True)
             with open(os.path.join(a.dump, f"{name}.hlo.txt"), "w") as f:
                 f.write(text)
-        found = pool_sized_copies(text, pool_elements)
+        found = [c for n in sorted(set(pools.values()))
+                 for c in pool_sized_copies(text, n)]
         bad += len(found)
         kinds: dict = {}
         for c in found:
