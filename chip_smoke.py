@@ -54,7 +54,7 @@ from paddle_tpu.serving.kv_cache import (INDEX_POOL, JOINED_POOL,
                                          STACKED_POOLS, WINDOW_POOLS,
                                          pool_shape)
 from tools.pool_hlo import (pool_sized_copies, serving_program_hlos,
-                            token_row_gathers)
+                            sorts_over, token_row_gathers)
 
 # How far below the dense oracle's best logit the logit of a token the
 # engine generated may sit. The engine and the oracle run different
@@ -287,10 +287,9 @@ def pool_layout_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
         # the full layers' pools and the sliding layers' second pair
         sizes = {int(np.prod(eng._scope.find_var(name).shape))
                  for name in STACKED_POOLS[:2] + WINDOW_POOLS}
-    texts = serving_program_hlos(
-        eng, rows=rows,
-        pages=eng._page_bucket(eng.pool.pages_for(cfg.max_position)),
-        prompt=128, device=device)
+    pages = eng._page_bucket(eng.pool.pages_for(cfg.max_position))
+    texts = serving_program_hlos(eng, rows=rows, pages=pages, prompt=128,
+                                 device=device)
     copies = {}
     for name, text in texts.items():
         found = [c for n in sorted(sizes) for c in pool_sized_copies(text, n)]
@@ -311,6 +310,12 @@ def pool_layout_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
         _require(out["token_row_gathers"]["decode"] == 1,
                  "the compiled decode program gathers a selected token "
                  f"{out['token_row_gathers']['decode']} times a layer")
+        # and names its selection without sorting a row's context
+        out["context_sorts"] = {
+            name: len(sorts_over(text, pages * page_size))
+            for name, text in texts.items()}
+        _require(out["context_sorts"]["decode"] == 0,
+                 "the compiled decode program sorts a row's whole context")
     return out
 
 

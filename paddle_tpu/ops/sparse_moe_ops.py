@@ -9,14 +9,17 @@ that runs them is `sparse_moe_stack`:
                            Attention: a few small query heads, ONE cached
                            key head, `I(t, s) = sum_j w_tj relu(qI_tj .
                            kI_s)`, float32;
-  * `select_indices` / `select_mask` — the `k` cached positions `s <= t`
+  * `select_mask` / `select_indices` — the `k` cached positions `s <= t`
                            of largest `I(t, s)` (every position while
-                           `t < k`; ties go to the lower position), as
-                           indices (`lax.top_k`: a decode row gathers by
-                           them) or as a mask found without a sort (a
-                           window attends under it). Each form is handed
-                           back as the attention used it: a decode row's
-                           indices, a window's mask packed into words
+                           `t < k`; ties go to the lower position): ONE
+                           rule, found without a sort (the k-th largest
+                           score by counting passes over its bits), in
+                           two forms. A window attends under the mask; a
+                           decode row gathers by the mask's set positions,
+                           named in ascending order by a two-level
+                           compaction (`_mask_positions`). Each form is
+                           handed back as the attention used it: a decode
+                           row's indices, a window's mask packed into words
                            (`pack_selection`);
   * `join_rows` / `split_rows` — a token's K and V as ONE row of 32-bit
                            words of ONE pool, so that whoever reads a token
@@ -169,7 +172,8 @@ def _ordered_bits(x):
 
 def _kth_largest(u, k: int):
     """u [..., T] uint32 -> [...]: its k-th largest value, found a bit at a
-    time (32 counting passes; a sort of 36,864 values a row costs 30x)."""
+    time: 32 counting passes, 0.1 ms for 64 rows of 36,864 where a sort of
+    the rows takes 2.16 (PERF.md, PR 34)."""
     def narrow(i, lo):
         cand = lo | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
         enough = jnp.sum((u >= cand[..., None]).astype(jnp.int32), -1) >= k
@@ -179,33 +183,20 @@ def _kth_largest(u, k: int):
                              jnp.zeros(u.shape[:-1], jnp.uint32))
 
 
-def select_indices_fn(scores, limit, k: int):
-    """scores [B, S, T], limit [B, S] (positions `s < limit` exist) -> sel
-    [B, S, kk] int32: the `kk = min(k, T)` positions of largest score, ties
-    to the lower position, -1 where fewer exist."""
-    kk = min(int(k), scores.shape[-1])
-    live = jnp.arange(scores.shape[-1], dtype=jnp.int32) < limit[..., None]
-    # -0.0 read as +0.0: the sort orders them, a comparison does not
-    scores = jnp.where(scores == 0, 0.0, scores)
-    # rows flat: the chip sorts a `[64, 1, T]` array seven times slower
-    # than the same rows as `[64, T]` (a unit second-minor dimension pads
-    # to a tile of 8)
-    flat = jnp.where(live, scores, -jnp.inf).reshape(-1, scores.shape[-1])
-    vals, idx = jax.lax.top_k(flat, kk)
-    sel = jnp.where(vals > -jnp.inf, idx.astype(jnp.int32), -1)
-    return sel.reshape(scores.shape[:-1] + (kk,))
-
-
 def select_mask_fn(scores, limit, k: int):
-    """The set `select_indices_fn` names, as a mask [B, S, T], without a
-    sort: everything above the k-th largest score, and of the scores equal
-    to it the lowest positions that fill the k."""
+    """scores [..., T], limit [...] (positions `s < limit` exist) -> mask
+    [..., T] of the `min(k, T, limit)` positions of largest score, ties to
+    the lower position, found without a sort: everything above the k-th
+    largest score, and of the scores equal to it the lowest positions that
+    fill the k."""
     T = scores.shape[-1]
     kk = min(int(k), T)
     live = jnp.arange(T, dtype=jnp.int32) < limit[..., None]
     u = _ordered_bits(jnp.where(live, scores, -jnp.inf))
     kth = _kth_largest(u, kk)[..., None]
-    above, ties = u > kth, u == kth
+    # live ties only: where fewer than k positions exist the cut is the
+    # dead positions' -inf, and they would crowd every row into the cumsum
+    above, ties = u > kth, (u == kth) & live
     room = kk - jnp.sum(above.astype(jnp.int32), -1, keepdims=True)
     crowded = jnp.sum(ties.astype(jnp.int32), -1, keepdims=True) > room
     ties = jax.lax.cond(
@@ -213,6 +204,72 @@ def select_mask_fn(scores, limit, k: int):
         lambda: ties & (jnp.cumsum(ties.astype(jnp.int32), axis=-1) <= room),
         lambda: ties)
     return live & (above | ties)
+
+
+# positions a group of the compaction below: one row of lanes
+_LANES = 128
+
+
+def _mask_positions(mask, kk: int):
+    """mask [R, T] bool, at most `kk` set a row -> [R, kk] int32: the set
+    positions in ascending order, -1 in the slots past their count. A
+    two-level compaction in exact small integers (bfloat16 operands no
+    larger than 128, float32 sums), with no scatter, no gather and no scan
+    over T. The row is cut into groups of 128 lanes; a product with a
+    triangle ranks a position among the set lanes of its group, another
+    gives the count before each group. Output slot `j` lies in the last
+    group whose count-before is at most `j`; it fetches that group's ranks
+    with a ONE-HOT product (the chip gathers by the row, 12-15 ns each:
+    131,072 gathered rank rows would cost what the sort cost) and takes the
+    lane whose rank is `j` less the count-before. The chip's compiler fuses
+    the one-hot, the product and the lane search into one operation:
+    neither the `[R, kk, groups]` one-hot nor the `[R, kk, 128]` ranks are
+    written out (0.26 ms for 64 rows of 36,864 with the cut, where the sort
+    took 2.16: PERF.md, PR 34)."""
+    R, T = mask.shape
+    G = -(-T // _LANES)
+    if G * _LANES > T:
+        mask = jnp.pad(mask, ((0, 0), (0, G * _LANES - T)))
+    m = mask.reshape(R, G, _LANES).astype(jnp.bfloat16)
+    lane = jnp.arange(_LANES, dtype=jnp.int32)
+    group = jnp.arange(G, dtype=jnp.int32)
+    before = (lane[:, None] < lane[None, :]).astype(jnp.bfloat16)
+    rank = jnp.einsum("rgl,lm->rgm", m, before, preferred_element_type=_F32)
+    # a set lane holds its rank in the group, from 1; an unset one 0
+    ranked = ((rank + 1.0) * m.astype(_F32)).astype(jnp.bfloat16)
+    counts = jnp.sum(m, axis=-1, dtype=_F32).astype(jnp.bfloat16)
+    upto = (group[:, None] <= group[None, :]).astype(jnp.bfloat16)
+    incl = jnp.dot(counts, upto, preferred_element_type=_F32).astype(jnp.int32)
+    excl = incl - counts.astype(jnp.int32)                   # count-before
+    # count-before and group both rise with the group: ONE max over the two
+    # packed into a number finds a slot's group and its count-before in one
+    # pass over `[R, kk, G]` (two reductions cost 0.15 ms, this 0.07)
+    bits = G.bit_length()
+    assert kk << bits < 1 << 31, (kk, G)
+    slot = jnp.arange(kk, dtype=jnp.int32)
+    packed = (excl << bits) | group
+    last = jnp.max(jnp.where(excl[:, None, :] <= slot[None, :, None],
+                             packed[:, None, :], 0), axis=-1)      # [R, kk]
+    mine, base = last & ((1 << bits) - 1), last >> bits
+    onehot = (mine[..., None] == group).astype(jnp.bfloat16)
+    ranks = jnp.einsum("rjg,rgl->rjl", onehot, ranked,
+                       preferred_element_type=_F32)                # [R, kk, 128]
+    want = (slot - base + 1).astype(_F32)[..., None]
+    found = jnp.sum(jnp.where(ranks == want, lane, 0), axis=-1)
+    return jnp.where(slot < incl[:, -1:], mine * _LANES + found, -1)
+
+
+def select_indices_fn(scores, limit, k: int):
+    """scores [B, S, T], limit [B, S] (positions `s < limit` exist) -> sel
+    [B, S, kk] int32: the `kk = min(k, T)` positions `select_mask_fn`
+    keeps, in ascending position, -1 where fewer exist. One selection rule
+    in two forms: the mask's set bits, named."""
+    T = scores.shape[-1]
+    kk = min(int(k), T)
+    # rows flat: a unit second-minor dimension pads to a tile of 8 (the
+    # chip sorted a `[64, 1, T]` array seven times slower than `[64, T]`)
+    keep = select_mask_fn(scores.reshape(-1, T), limit.reshape(-1), k)
+    return _mask_positions(keep, kk).reshape(scores.shape[:-1] + (kk,))
 
 
 def pack_selection_fn(keep, page_size: int):

@@ -6,7 +6,8 @@ import pytest
 
 import chip_smoke
 from paddle_tpu.serving import DecoderConfig
-from tools.pool_hlo import pool_sized_copies, token_row_gathers
+from tools.pool_hlo import (pool_sized_copies, sorts_over,
+                            token_row_gathers)
 
 POOL = 3072 * 16 * 12 * 64
 
@@ -167,8 +168,9 @@ def test_sparse_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
     layer carries the joined K/V rows and the per-token indexer-key pool
     without a copy of either. The decode program fetches a selected token
     ONCE, as a row of 512 words (PR 29's fetched it from a K pool and from
-    a V pool, and the chip gathers by the row: PERF.md, PR 30); the windows
-    read whole pages and gather no token."""
+    a V pool, and the chip gathers by the row: PERF.md, PR 30) and names its
+    2,048 without a sort (PR 34); the windows read whole pages and gather no
+    token."""
     import json
     import os
 
@@ -193,6 +195,8 @@ def test_sparse_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
         "decode": 0, "prefill": 0, "window": 0, "cow": 0}
     assert out["token_row_gathers"] == {
         "decode": 1, "prefill": 0, "window": 0, "cow": 0}
+    # nor does any sort a row's 36,864 scores (decode did until PR 34)
+    assert not any(out["context_sorts"].values()), out["context_sorts"]
 
 
 def test_hybrid_moe_programs_compiled_for_v5e_move_neither_pool(v5e_chip):
@@ -248,6 +252,23 @@ def test_token_row_gathers_counts_rows_not_slabs():
     assert [g["shape"][:16] for g in token_row_gathers(one, 512)] \
         == ["s32[64,2048,512]"]
     assert token_row_gathers(two, 128) == []
+
+
+def test_sorts_over_finds_the_context_sort_and_passes_a_routers():
+    """Recorded from the v5e's compiler: `lax.top_k` over a decode row's
+    36,864 scores (PR 29-33) is a stable sort of keys and positions
+    together; a sort of a row of 128 router probabilities, or along
+    another dimension, is no sort of a context."""
+    text = """\
+  %sort = (f32[64,36864]{1,0:T(8,128)S(1)}, s32[64,36864]{1,0:T(8,128)S(1)}) sort(%copy_bitcast_fusion, %iota), dimensions={1}, is_stable=true, to_apply=%compare-greater-than.1, metadata={op_name="jit(old_sort)/top_k"}
+  %sort.1 = (f32[64,128]{1,0:T(8,128)}, s32[64,128]{1,0:T(8,128)}) sort(%fusion.3, %iota.2), dimensions={1}, is_stable=true, to_apply=%compare-greater-than.2
+  ROOT %sort.2 = f32[36864,64]{1,0:T(8,128)} sort(%fusion.4), dimensions={1}, to_apply=%compare.3
+"""
+    assert sorts_over(text, 36864) == [
+        {"name": "sort", "shape": "f32[64,36864]"}]
+    assert [f["name"] for f in sorts_over(text, 64)] \
+        == ["sort", "sort.1", "sort.2"]
+    assert sorts_over(text, 36865) == []
 
 
 @pytest.mark.parametrize("q_shape,pool,dtype,bucket", [

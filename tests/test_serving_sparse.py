@@ -379,26 +379,117 @@ def test_topk_router_combines_as_a_per_token_loop():
         np.testing.assert_allclose(np.asarray(got[t]), want, atol=1e-4)
 
 
-def test_selection_mask_and_indices_name_the_same_set():
+def _sorted_indices(scores, limit, k):
+    """The form decode used until PR 34, kept as the oracle: `lax.top_k`
+    over the live scores (stable: ties to the lower position)."""
+    live = jnp.arange(scores.shape[-1]) < limit[..., None]
+    flat = jnp.where(live, jnp.where(scores == 0, 0.0, scores), -jnp.inf)
+    vals, idx = jax.lax.top_k(flat, min(int(k), scores.shape[-1]))
+    return jnp.where(vals > -jnp.inf, idx.astype(jnp.int32), -1)
+
+
+def _selection_case(case, T, k):
+    """scores [4, 1, T] and limits [4, 1] of one kind of difficulty."""
+    rng = np.random.default_rng(T + k)
+    scores = rng.standard_normal((4, 1, T)).astype(np.float32)
+    limit = np.full((4, 1), T, np.int32)
+    if case == "limits":        # below k, at a page edge, mid-page, whole
+        limit[:, 0] = [max(1, k // 2 - 3), min(T, -(-(k + 1) // 128) * 128),
+                       min(T, k + 77), T]
+    elif case == "all_ties":
+        scores[:] = 0.5
+        limit[:, 0] = [T, T - 5, max(1, k - 1), 1]
+    elif case == "ties_above_the_cut":
+        scores[:, :, ::3] = 7.0
+        scores[1, :, 1::3] = 6.0
+        limit[2:, 0] = [T - 128, max(1, T // 2 + 9)]
+    elif case == "negative_zero":
+        scores[:] = np.where(rng.random((4, 1, T)) < 0.5, -0.0, 0.0)
+        scores[1, :, ::7] = -1.0
+        scores[2, :, ::5] = 1.0
+    elif case == "clustered":   # one page holds the whole selection
+        scores[:, :, 128:256] += 50.0
+        scores[1:, :, T - 128:] += 60.0
+    return jnp.asarray(scores), jnp.asarray(limit)
+
+
+@pytest.mark.parametrize("T,k", [(40, 8), (40, 64), (640, 8), (640, 64),
+                                 (4608, 64), (4608, 2048)])
+@pytest.mark.parametrize("case", ["random", "limits", "all_ties",
+                                  "ties_above_the_cut", "negative_zero",
+                                  "clustered"])
+def test_selection_mask_and_indices_name_the_same_set(case, T, k):
+    """One selection rule in two forms: the indices a decode row gathers by
+    are the set bits of the mask a window attends under, in ascending
+    position with -1 behind them, and name the set the sorted form named
+    (PR 29-33: `lax.top_k`). T spans one ragged group of lanes (40), five
+    whole ones and the served class (several pages, k = 2,048)."""
+    scores, limit = _selection_case(case, T, k)
+    sel = np.asarray(jax.jit(sparse_moe_ops.select_indices_fn,
+                             static_argnums=2)(scores, limit, k))
+    mask = np.asarray(sparse_moe_ops.select_mask_fn(scores, limit, k))
+    was = np.asarray(_sorted_indices(scores, limit, k))
+    assert sel.shape == was.shape == (4, 1, min(k, T))
+    for b in range(4):
+        n = min(k, T, int(limit[b, 0]))
+        kept, tail = sel[b, 0, :n], sel[b, 0, n:]
+        assert (tail == -1).all() and (kept >= 0).all()
+        assert (np.diff(kept) > 0).all()               # ascending, distinct
+        assert kept.tolist() == np.flatnonzero(mask[b, 0]).tolist()
+        assert kept.tolist() == sorted(was[b, 0][was[b, 0] >= 0].tolist())
+    if case == "all_ties":
+        assert sel[0, 0].tolist() == list(range(min(k, T)))
+
+
+def test_selection_of_many_queries_a_row_keeps_its_shape():
+    """`[B, S, T]` with S > 1 (no caller today; the rows are flattened and
+    come back): every query under its own limit."""
     rng = np.random.default_rng(13)
-    scores = rng.standard_normal((2, 5, 40)).astype(np.float32)
-    scores[0, 1, :] = 0.5                          # every score ties
-    scores[0, 2, 5:30] = -0.0                      # -0.0 ties with +0.0
-    scores[0, 2, 30:] = 0.0
-    scores[1, 0, ::3] = 7.0                        # ties above the cut
-    limit = jnp.asarray([[40, 33, 40, 6, 1], [40, 20, 9, 40, 8]], jnp.int32)
-    for k in (8, 64):
-        sel = np.asarray(sparse_moe_ops.select_indices_fn(
-            jnp.asarray(scores), limit, k))
-        mask = np.asarray(sparse_moe_ops.select_mask_fn(
-            jnp.asarray(scores), limit, k))
-        for b in range(2):
-            for s in range(5):
-                kept = sel[b, s][sel[b, s] >= 0]
-                assert len(kept) == min(k, 40, int(limit[b, s]))
-                assert sorted(kept) == list(np.flatnonzero(mask[b, s]))
-    assert sorted(np.asarray(sparse_moe_ops.select_indices_fn(
-        jnp.asarray(scores), limit, 8))[0, 1]) == list(range(8))
+    scores = jnp.asarray(rng.standard_normal((2, 5, 300)).astype(np.float32))
+    limit = jnp.asarray([[300, 33, 129, 6, 1], [128, 20, 9, 256, 8]],
+                        jnp.int32)
+    sel = np.asarray(sparse_moe_ops.select_indices_fn(scores, limit, 16))
+    mask = np.asarray(sparse_moe_ops.select_mask_fn(scores, limit, 16))
+    assert sel.shape == (2, 5, 16)
+    for b in range(2):
+        for s in range(5):
+            kept = sel[b, s][sel[b, s] >= 0]
+            assert kept.tolist() == np.flatnonzero(mask[b, s]).tolist()
+            assert len(kept) == min(16, int(limit[b, s]))
+
+
+def test_decode_step_serves_the_sorted_forms_logits_and_words(monkeypatch):
+    """Paged decode steps of the block past `index_topk` positions of
+    context: with the sorted form put back in its place the engine serves
+    the same tokens, logits inside the file's tolerance (a softmax over a
+    set, summed in another order) and bit-identical `selection` words."""
+    prompts = _prompts(31, 40, 70)
+    run_step = ServingEngine._run_step
+
+    def served():
+        eng, logits = _engine(), []
+
+        def to_host(kind, target, io, feed, greedy, *args, **kw):
+            out = run_step(eng, kind, target, io, feed, False, *args, **kw)
+            if kind == "decode":
+                logits.append(np.asarray(out[2]))
+            return out
+
+        eng._run_step = to_host
+        done = _serve(eng, prompts, new=6)
+        assert eng.stats["sparse.layer_steps"] > 0 and logits
+        return done, logits
+
+    now, logits = served()
+    monkeypatch.setattr(sparse_moe_ops, "select_indices_fn", _sorted_indices)
+    was, logits_was = served()
+    for a, b in zip(now, was):
+        assert a.out_tokens == b.out_tokens
+        assert a.selection[0] == b.selection[0]
+        assert np.array_equal(a.selection[1], b.selection[1])
+    assert len(logits) == len(logits_was)
+    for a, b in zip(logits, logits_was):
+        np.testing.assert_allclose(a, b, atol=TOL)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

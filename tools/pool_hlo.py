@@ -11,6 +11,9 @@ a `transpose`, a `bitcast-convert`. On the v5e such a copy of 24 pools took
 text; `token_row_gathers(hlo_text, row_elements)` lists the gathers that
 fetch one token's row at a time (a block that gathers tokens pays by the
 row: its decode program holds ONE a layer since PR 30);
+`sorts_over(hlo_text, row_elements)` lists the sorts of rows at least that
+long (a block that selects sorted every decode row's whole context until
+PR 34: none since);
 `serving_program_hlos(engine)` compiles the engine's decode, prefill,
 window and COW programs (`serving_program_cases`) at one signature each and
 returns their texts.
@@ -37,7 +40,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-__all__ = ["pool_sized_copies", "token_row_gathers",
+__all__ = ["pool_sized_copies", "token_row_gathers", "sorts_over",
            "serving_program_cases", "serving_program_hlos"]
 
 # `  %name = f32[3072,16,768]{2,1,0:T(8,128)} opcode(operands...), attrs`
@@ -136,6 +139,23 @@ def token_row_gathers(hlo_text: str, row_elements: int) -> list[dict]:
     return [{"name": m["name"], "shape": m["shape"]}
             for line in hlo_text.splitlines()
             if (m := _GATHER.match(line)) and m["slice"] == want]
+
+
+_SORT = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*\(?"
+    r"(?P<shape>[a-z]+\d*\[(?P<dims>[\d,]*)\])\S*.*\ssort\("
+    r".*dimensions=\{(?P<dim>\d+)\}")
+
+
+def sorts_over(hlo_text: str, row_elements: int) -> list[dict]:
+    """The sorts of an optimized HLO text whose sorted dimension holds at
+    least `row_elements` values, `[{"name", "shape"}]` in text order (the
+    first operand's shape: `lax.top_k` sorts keys and positions together).
+    A router's top-k over its experts is far shorter than a context."""
+    return [{"name": m["name"], "shape": m["shape"]}
+            for line in hlo_text.splitlines()
+            if (m := _SORT.match(line))
+            and int(m["dims"].split(",")[int(m["dim"])]) >= row_elements]
 
 
 def _program_hlo(exe, target, feed, fetch_list, scope, device=None) -> str:
