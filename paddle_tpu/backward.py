@@ -145,7 +145,12 @@ def _backward_sweep(block, op_path, seed_grads: set, no_grad: set) -> set:
                             local_seen.add(n)
                         new_names.append(n)
                 outputs[slot] = new_names
-            block.append_op(spec["type"], spec["inputs"], outputs, spec.get("attrs", {}))
+            attrs = dict(spec.get("attrs", {}))
+            if op.attrs.get("op_namescope"):
+                # a grad op belongs to the scope of the op it differentiates
+                # (a custom grad maker may not copy the forward's attrs)
+                attrs.setdefault("op_namescope", op.attrs["op_namescope"])
+            block.append_op(spec["type"], spec["inputs"], outputs, attrs)
             for slot, names in outputs.items():
                 for n in names:
                     if n:

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import collections
 import logging
+import re
 import time
 import warnings
 import weakref
@@ -53,10 +54,27 @@ logger = logging.getLogger("paddle_tpu.executor")
 _SKIP_OPS = ("feed", "fetch")
 
 
+def _op_path(op) -> str:
+    """What the device's time under `op` is booked to: the name scope the
+    op was appended under, then its type (`encoder/mlm_head/matmul`)."""
+    scope = op.attrs.get("op_namescope")
+    return f"{scope}/{op.type}" if scope else op.type
+
+
 def _compute_op(opdef, ctx, op):
-    """Run one op's compute with creation-stack attribution on failure."""
+    """Run one op's compute with creation-stack attribution on failure,
+    under `jax.named_scope` of the op's path: every instruction the op
+    lowers to carries it in the compiled module's `op_name` metadata, where
+    `profiler.device_time` finds it again. Under a trace (the jitted
+    block, a traced sub-block) the scope is entered once, at lowering, and
+    a step runs none of it; a host op between two jitted segments is
+    computed eagerly, so there the scope is entered on every step (a
+    context manager's cost, as the op's own Python is). The persistent
+    compile cache leaves `op_name` out of its key: an executable loaded
+    from it carries the names of the tree that compiled it."""
     try:
-        return opdef.compute(ctx)
+        with jax.named_scope(_op_path(op)):
+            return opdef.compute(ctx)
     except OpError:
         raise
     except Exception as e:
@@ -327,6 +345,23 @@ def _step_key(seed_counter, segment=None):
     return key if segment is None else jax.random.fold_in(key, segment)
 
 
+# every name a lowered block's function goes by: `fn`, and what
+# `_named_after` gave (pipeline.jit_compile_counter counts compiles of these)
+LOWERED_FN_NAMES = {"fn"}
+
+
+def _named_after(fn, program, suffix: str = ""):
+    """`fn` called after its Program, if the Program has a name: jax names
+    a jitted function's module `jit_<name>`, which is what the `XLA
+    Modules` line of a device trace prints. An unnamed Program's entries
+    stay `jit_fn`."""
+    if program.name:
+        fn.__name__ = fn.__qualname__ = re.sub(
+            r"\W", "_", program.name) + suffix
+        LOWERED_FN_NAMES.add(fn.__name__)
+    return fn
+
+
 def _lower(block, feed_names, ro_names, rw_names, extra_w, fetch_names, axis_env=None):
     ops = [op for op in block.ops if op.type not in _SKIP_OPS]
 
@@ -369,7 +404,7 @@ def _lower(block, feed_names, ro_names, rw_names, extra_w, fetch_names, axis_env
         return fetches, new_rw, new_extra, _step_token(fetches, new_rw,
                                                        new_extra)
 
-    return fn
+    return _named_after(fn, block.program)
 
 
 class _SegmentedFn:
@@ -438,7 +473,7 @@ class _SegmentedFn:
                             env[n] = v
             return tuple(env.get(n) for n in out_names)
 
-        return fn
+        return _named_after(fn, block.program, f"_seg{index}")
 
     def __call__(self, feed_vals, ro_vals, rw_vals, seed_counter):
         env: dict[str, Any] = {}

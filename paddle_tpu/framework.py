@@ -404,7 +404,13 @@ class Block:
 
 
 class Program:
-    """A whole trainable/serializable program (reference framework.py:2826)."""
+    """A whole trainable/serializable program (reference framework.py:2826).
+
+    `name` (optional; observability/schema.PROGRAM_NAMES lists the tree's
+    own) is what the executor calls the program's compiled entries: a
+    device trace's `XLA Modules` line reads `jit_<name>`."""
+
+    name: str | None = None
 
     def __init__(self):
         self.blocks: list[Block] = [Block(self, 0)]
@@ -447,6 +453,7 @@ class Program:
         """Deep-copy the program. With for_test=True, flip training-only attrs
         (is_test) the way the reference's clone(for_test=True) does."""
         p = Program.__new__(Program)
+        p.name = self.name
         p.blocks = []
         p._current_block_idx = self._current_block_idx
         p.random_seed = self.random_seed

@@ -19,7 +19,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 from .. import layers as L
-from ..framework import default_main_program
+from ..framework import default_main_program, name_scope
 from ..param_attr import ParamAttr
 from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 from ..parallel.sharding import annotate_sharding
@@ -219,14 +219,18 @@ def bert_pretrain(cfg: TransformerConfig, seq_len: int = 128):
             annotate_sharding(block.var(n), (DATA_AXIS, SEQ_AXIS))
 
     enc = transformer_encoder(src_ids, pos_ids, cfg)  # [B,S,H]
-    logits = _fc(enc, cfg.vocab_size, "lm_head", w_spec=(None, MODEL_AXIS),
-                 b_spec=(MODEL_AXIS,), cfg=cfg)       # [B,S,V]
-    label = L.unsqueeze(lm_label, axes=[2])
-    loss = L.softmax_with_cross_entropy(logits, label)  # [B,S,1]
-    loss = L.squeeze(loss, axes=[2])
-    weighted = L.elementwise_mul(loss, lm_weight)
-    denom = L.elementwise_add(L.reduce_sum(lm_weight), _const_eps())
-    avg_loss = L.elementwise_div(L.reduce_sum(weighted), denom)
+    # the head and its loss under one scope: a device trace books the H x V
+    # product, the softmax and their grad ops to `mlm_head`
+    with name_scope("mlm_head"):
+        logits = _fc(enc, cfg.vocab_size, "lm_head",
+                     w_spec=(None, MODEL_AXIS), b_spec=(MODEL_AXIS,),
+                     cfg=cfg)                           # [B,S,V]
+        label = L.unsqueeze(lm_label, axes=[2])
+        loss = L.softmax_with_cross_entropy(logits, label)  # [B,S,1]
+        loss = L.squeeze(loss, axes=[2])
+        weighted = L.elementwise_mul(loss, lm_weight)
+        denom = L.elementwise_add(L.reduce_sum(lm_weight), _const_eps())
+        avg_loss = L.elementwise_div(L.reduce_sum(weighted), denom)
     feeds = {"src_ids": src_ids, "pos_ids": pos_ids,
              "lm_label": lm_label, "lm_weight": lm_weight}
     return avg_loss, feeds

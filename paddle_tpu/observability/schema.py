@@ -14,8 +14,16 @@ Kinds:
   gauge     — last-set value (occupancy, rates)
   histogram — streaming distribution with p50/p95/p99 (log-spaced buckets)
   event     — structured record on the event ring / JSONL stream
+
+The names the program gives the DEVICE's time live here too (the end of
+this file): a compiled Program's module name, the mode a served stack runs
+in and the pieces a stack declares. They reach a device trace through the
+compiled module's `op_name` metadata (`profiler.device_time` reads them
+back) and cost nothing at run time.
 """
 from __future__ import annotations
+
+import functools
 
 STAGE, COUNTER, GAUGE, HISTOGRAM, EVENT = (
     "stage", "counter", "gauge", "histogram", "event")
@@ -419,3 +427,63 @@ DECLARED_NAMES = frozenset(spec[0] for spec in DECLARED)
 # tests/test_observability.py greps the source tree against this set, so a
 # new bump("...") literal must be declared here to stay green
 STAGE_NAMES = frozenset(s[0] for s in DECLARED if s[1] == STAGE)
+
+
+# -- names on the device trace ----------------------------------------------
+# A device operation's path is <name scope>/<op type>[/<mode>/<piece>]
+# (executor._compute_op opens the first two, a stack lowering the rest).
+# tests/test_observability.py holds the literals in the tree to these lists,
+# and tests/test_device_names.py the benchmark's patterns to what the
+# rehearsal programs compile: dropping a name is a schema act.
+
+# Program.name of the programs the tree builds: the executor calls a
+# compiled entry's function after it, so the trace's `XLA Modules` line
+# reads jit_<name> (an unnamed Program stays jit_fn)
+PROGRAM_NAMES = frozenset({
+    "serving_decode",    # ServingEngine: one decode or verify step
+    "serving_prefill",   # a cold prompt from position 0
+    "serving_window",    # suffix windows and chunks behind a cached prefix
+    "serving_cow",       # copy-on-write of one page
+    "train_step",        # Optimizer.minimize: forward, backward, updates
+})
+
+# the `mode` a *_moe_stack op runs in: the first scope under its op type
+STACK_MODES = frozenset({"decode", "window", "prefill", "full"})
+
+# the pieces the three served stacks declare under their mode, one
+# vocabulary for all (a block declares the pieces it has)
+PIECES = frozenset({
+    "embed",      # token rows of the embedding
+    "proj",       # norms, the q/k/v/out products, rotary
+    "kv_write",   # a step's K/V (and indexer keys) into the pools
+    "indexer",    # sparse_moe: gather of the key pages and their scores
+    "select",     # sparse_moe: the cut and the mask or the indices
+    "kv_gather",  # rows or pages of K/V gathered out of a pool
+    "attend",     # the attention itself (a Pallas kernel sits inside)
+    "router",     # expert choice and combine weights
+    "experts",    # the routed experts
+    "dense_ffn",  # a shared expert, a dense layer
+    "state",      # cca_moe: the state rows read and written
+    "head",       # final norm and the vocabulary product
+})
+
+
+def piece(name: str):
+    """`with piece("indexer"):` — `jax.named_scope` of a declared piece
+    (or mode); an undeclared name raises where the program is traced."""
+    if name not in PIECES and name not in STACK_MODES:
+        raise ValueError(f"{name!r} is not a piece or mode declared in "
+                         f"observability/schema.py")
+    import jax
+
+    return jax.named_scope(name)
+
+
+def under_mode(stack_fn):
+    """A `*_moe_stack_fn(mode, ...)` traced under the scope of its mode."""
+    @functools.wraps(stack_fn)
+    def scoped(mode, *args, **kwargs):
+        with piece(mode):
+            return stack_fn(mode, *args, **kwargs)
+
+    return scoped

@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from .attention_ops import (_DROP_PAGE, _write_rows, grouped_query_attention,
                             kv_cache_append_fn, paged_decode_attention_fn,
                             paged_prefill_attention_fn)
+from ..observability.schema import piece, under_mode
 from .registry import _DYN, ExecContext, register_op
 
 _HI = jax.lax.Precision.HIGHEST
@@ -244,13 +245,17 @@ def _pre_attention(x, p, state_prev, positions, geom: Geometry):
 def _post_attention(x, o, r_prev, p, experts, layer, geom: Geometry, tag):
     """x, o [B, S, .] -> (y [B, S, H], r [B, S, R], choice [B, S])."""
     B, S, H = x.shape
-    h = x + _mm(o, p["wo"])
-    z = rms_norm_fn(h, p["ffn_norm"], geom.eps).reshape(B * S, H)
-    r, probs, choice = moe_router_fn(
-        z, r_prev.reshape(B * S, -1), dict(p, eps=geom.eps))
-    y = moe_top1_experts_fn(z, probs, choice, *experts, layer=layer, tag=tag)
-    return (h + y.reshape(B, S, H), r.reshape(B, S, -1),
-            choice.reshape(B, S))
+    with piece("proj"):
+        h = x + _mm(o, p["wo"])
+        z = rms_norm_fn(h, p["ffn_norm"], geom.eps).reshape(B * S, H)
+    with piece("router"):
+        r, probs, choice = moe_router_fn(
+            z, r_prev.reshape(B * S, -1), dict(p, eps=geom.eps))
+    with piece("experts"):
+        y = moe_top1_experts_fn(z, probs, choice, *experts, layer=layer,
+                                tag=tag)
+        return (h + y.reshape(B, S, H), r.reshape(B, S, -1),
+                choice.reshape(B, S))
 
 
 def _page_row_index(page_table, gpos, page_size, layer_off, keep):
@@ -280,6 +285,7 @@ def _read_state(s_pool, page_table, pos_prev, page_size, layer_off):
 # ---------------------------------------------------------------------------
 
 
+@under_mode
 def cca_moe_stack_fn(mode: str, tok, pos, emb, final_norm, layer_params: dict,
                      experts: tuple, geom: Geometry, pools=None,
                      page_table=None, lens=None, start=None, mask=None,
@@ -292,13 +298,15 @@ def cca_moe_stack_fn(mode: str, tok, pos, emb, final_norm, layer_params: dict,
       decode   tok/pos [B], page_table, mask [B]     -> logits [B, V]
 
     Returns a dict: logits, routes ([B, S, L], decode [B, L]) and, with
-    pools, k_pool/v_pool/s_pool as written."""
+    pools, k_pool/v_pool/s_pool as written. Traced under its mode's scope,
+    each piece (observability/schema.PIECES) under its own."""
     decode = mode == "decode"
     paged = mode != "full"
     if decode:
         # the engine feeds a decode step's tokens as a [B, 1] column
         tok, pos = jnp.reshape(tok, (-1, 1)), jnp.reshape(pos, (-1, 1))
-    x = emb[tok].astype(_F32)
+    with piece("embed"):
+        x = emb[tok].astype(_F32)
     B, S, _ = x.shape
     L = layer_params["wqk"].shape[0]
     sm_scale = geom.head_dim ** -0.5
@@ -324,51 +332,62 @@ def cca_moe_stack_fn(mode: str, tok, pos, emb, final_norm, layer_params: dict,
         if paged:
             x, r_prev, k_pool, v_pool, s_pool = carry
             off = l * num_pages
-            state_prev = _read_state(s_pool, page_table, first - 1,
-                                     page_size, off)
+            with piece("state"):
+                state_prev = _read_state(s_pool, page_table, first - 1,
+                                         page_size, off)
         else:
             x, r_prev = carry
             state_prev = jnp.zeros((B, state_width(geom)), _F32)
-        q, k, v, states = _pre_attention(x, p, state_prev, pos, geom)
+        with piece("proj"):
+            q, k, v, states = _pre_attention(x, p, state_prev, pos, geom)
         if paged:
             table = page_table + off
             kd = k.astype(k_pool.dtype)
             vd = v.astype(v_pool.dtype)
             if decode:
-                k_pool, v_pool = kv_cache_append_fn(
-                    k_pool, v_pool, kd[:, 0], vd[:, 0], table, first, live)
-                s_idx = _page_row_index(page_table, first, page_size, off,
-                                         live)
-                s_pool = s_pool.at[s_idx].set(states[:, 0], mode="drop")
-                o = paged_decode_attention_fn(
-                    q[:, 0], k_pool, v_pool, table, first + 1,
-                    sm_scale=sm_scale)[:, None]
+                with piece("kv_write"):
+                    k_pool, v_pool = kv_cache_append_fn(
+                        k_pool, v_pool, kd[:, 0], vd[:, 0], table, first,
+                        live)
+                with piece("state"):
+                    s_idx = _page_row_index(page_table, first, page_size,
+                                             off, live)
+                    s_pool = s_pool.at[s_idx].set(states[:, 0], mode="drop")
+                with piece("attend"):
+                    o = paged_decode_attention_fn(
+                        q[:, 0], k_pool, v_pool, table, first + 1,
+                        sm_scale=sm_scale)[:, None]
             else:
-                kv_idx = _page_row_index(page_table, gpos, page_size, off,
-                                          valid)
-                slot = gpos % page_size
-                k_pool = _write_rows(k_pool, kd.reshape(B, S, -1), kv_idx,
-                                     slot)
-                v_pool = _write_rows(v_pool, vd.reshape(B, S, -1), kv_idx,
-                                     slot)
-                s_idx = _page_row_index(
-                    page_table, gpos, page_size, off,
-                    valid & (last | (slot == page_size - 1)))
-                s_pool = s_pool.at[s_idx].set(states, mode="drop")
-                if mode == "window":
-                    o = paged_prefill_attention_fn(
-                        jnp.swapaxes(q, 1, 2), k_pool, v_pool, table, first,
-                        sm_scale=sm_scale)
-                else:
-                    o = grouped_query_attention(
-                        jnp.swapaxes(q, 1, 2).astype(kd.dtype),
-                        jnp.swapaxes(kd, 1, 2), jnp.swapaxes(vd, 1, 2),
-                        causal=True, sm_scale=sm_scale)
-                o = jnp.swapaxes(o, 1, 2)
+                with piece("kv_write"):
+                    kv_idx = _page_row_index(page_table, gpos, page_size,
+                                              off, valid)
+                    slot = gpos % page_size
+                    k_pool = _write_rows(k_pool, kd.reshape(B, S, -1),
+                                         kv_idx, slot)
+                    v_pool = _write_rows(v_pool, vd.reshape(B, S, -1),
+                                         kv_idx, slot)
+                with piece("state"):
+                    s_idx = _page_row_index(
+                        page_table, gpos, page_size, off,
+                        valid & (last | (slot == page_size - 1)))
+                    s_pool = s_pool.at[s_idx].set(states, mode="drop")
+                with piece("attend"):
+                    if mode == "window":
+                        o = paged_prefill_attention_fn(
+                            jnp.swapaxes(q, 1, 2), k_pool, v_pool, table,
+                            first, sm_scale=sm_scale)
+                    else:
+                        o = grouped_query_attention(
+                            jnp.swapaxes(q, 1, 2).astype(kd.dtype),
+                            jnp.swapaxes(kd, 1, 2), jnp.swapaxes(vd, 1, 2),
+                            causal=True, sm_scale=sm_scale)
+                    o = jnp.swapaxes(o, 1, 2)
         else:
-            o = jnp.swapaxes(grouped_query_attention(
-                jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                jnp.swapaxes(v, 1, 2), causal=True, sm_scale=sm_scale), 1, 2)
+            with piece("attend"):
+                o = jnp.swapaxes(grouped_query_attention(
+                    jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                    jnp.swapaxes(v, 1, 2), causal=True, sm_scale=sm_scale),
+                    1, 2)
         y, r, choice = _post_attention(
             x, o.reshape(B, S, -1).astype(_F32), r_prev, p, experts, l, geom,
             tag)
@@ -378,12 +397,13 @@ def cca_moe_stack_fn(mode: str, tok, pos, emb, final_norm, layer_params: dict,
     init = (x, r0) + (tuple(pools) if paged else ())
     xs = (jnp.arange(L, dtype=jnp.int32), layer_params)
     carry, routes = jax.lax.scan(layer, init, xs)
-    xn = rms_norm_fn(carry[0], final_norm, geom.eps)
-    if mode in ("prefill", "window"):
-        at = jnp.clip(lens - 1, 0, S - 1)[:, None, None]
-        xn = jnp.take_along_axis(xn, at, axis=1)
-    logits = jnp.einsum("bsh,vh->bsv", xn.astype(emb.dtype), emb,
-                        preferred_element_type=_F32)
+    with piece("head"):
+        xn = rms_norm_fn(carry[0], final_norm, geom.eps)
+        if mode in ("prefill", "window"):
+            at = jnp.clip(lens - 1, 0, S - 1)[:, None, None]
+            xn = jnp.take_along_axis(xn, at, axis=1)
+        logits = jnp.einsum("bsh,vh->bsv", xn.astype(emb.dtype), emb,
+                            preferred_element_type=_F32)
     routes = jnp.moveaxis(routes, 0, -1)                 # [B, S, L]
     out = {"logits": logits if mode == "full" else logits[:, 0],
            "routes": routes[:, 0] if decode else routes}

@@ -67,6 +67,7 @@ import numpy as np
 from .attention_ops import (_NEG_INF, _gather_pages, _write_rows,
                             kv_cache_append_fn, paged_decode_attention_fn)
 from .cca_moe_ops import _page_row_index, rms_norm_fn
+from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
 from .sparse_moe_ops import moe_topk_experts_fn
 
@@ -270,15 +271,22 @@ def _feed_forward(h, norm, kind: str, p, experts, index, geom: Geometry,
                   tag: str):
     """h [B, S, H] -> (y [B, S, H], ids [B, S, k] or None)."""
     B, S, H = h.shape
-    z = rms_norm_fn(h, norm, geom.eps).reshape(B * S, H)
+    with piece("proj"):
+        z = rms_norm_fn(h, norm, geom.eps).reshape(B * S, H)
     if kind == DENSE:
-        return h + swiglu_fn(z, p["w_gate"], p["w_up"],
-                             p["w_down"]).reshape(B, S, H), None
-    ids, cw = sigmoid_router_fn(z, p["router_w"], p["router_bias"],
-                                geom.experts_per_token, geom.routed_scaling)
-    y = moe_topk_experts_fn(z, cw, *experts, layer=index, tag=tag) \
-        + swiglu_fn(z, p["shared_gate"], p["shared_up"], p["shared_down"])
-    return h + y.reshape(B, S, H), ids.reshape(B, S, -1)
+        with piece("dense_ffn"):
+            return h + swiglu_fn(z, p["w_gate"], p["w_up"],
+                                 p["w_down"]).reshape(B, S, H), None
+    with piece("router"):
+        ids, cw = sigmoid_router_fn(z, p["router_w"], p["router_bias"],
+                                    geom.experts_per_token,
+                                    geom.routed_scaling)
+    with piece("experts"):
+        y = moe_topk_experts_fn(z, cw, *experts, layer=index, tag=tag)
+    with piece("dense_ffn"):
+        y = y + swiglu_fn(z, p["shared_gate"], p["shared_up"],
+                          p["shared_down"])
+        return h + y.reshape(B, S, H), ids.reshape(B, S, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -286,6 +294,7 @@ def _feed_forward(h, norm, kind: str, p, experts, index, geom: Geometry,
 # ---------------------------------------------------------------------------
 
 
+@under_mode
 def hybrid_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                         layer_params: dict, attention: dict, dense: dict,
                         moe: dict, experts: tuple, plan: tuple,
@@ -306,12 +315,15 @@ def hybrid_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
     a dict of `ATTENTION_PARAMS` stacked over the layers of that kind;
     `dense`, `moe` and `experts` alike over theirs. Returns a dict: logits;
     routes ([B, S, L_moe, k], decode [B, L_moe, k]); with `pools` (K, V of
-    the full layers, K, V of the sliding ones) the four as written."""
+    the full layers, K, V of the sliding ones) the four as written. Traced
+    under its mode's scope, each piece (observability/schema.PIECES) under
+    its own."""
     decode = mode == "decode"
     paged = mode != "full"
     if decode:
         tok, pos = jnp.reshape(tok, (-1, 1)), jnp.reshape(pos, (-1, 1))
-    x = emb[tok].astype(_F32)
+    with piece("embed"):
+        x = emb[tok].astype(_F32)
     B, S, _ = x.shape
     sm_scale = geom.head_dim ** -0.5
     tag = "decode" if decode else "prefill"
@@ -333,73 +345,89 @@ def hybrid_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
     routes = []
     for l, (a_kind, a_i, f_kind, f_i) in enumerate(plan):
         p = {k: w[a_i] for k, w in attention[a_kind].items()}
-        q, k, v, gate = _pre_attention(x, layer_params["attn_norm"][l], p,
-                                       pos, a_kind, geom)
+        with piece("proj"):
+            q, k, v, gate = _pre_attention(x, layer_params["attn_norm"][l],
+                                           p, pos, a_kind, geom)
         if not paged:
             zero = jnp.zeros((B,), jnp.int32)
             kd, vd = k.astype(emb.dtype), v.astype(emb.dtype)
-            o = causal_attention_fn(q, kd, vd, zero, sm_scale) \
-                if a_kind == FULL else \
-                band_attention_fn(q, kd, vd, zero, zero, W, sm_scale)
+            with piece("attend"):
+                o = causal_attention_fn(q, kd, vd, zero, sm_scale) \
+                    if a_kind == FULL else \
+                    band_attention_fn(q, kd, vd, zero, zero, W, sm_scale)
         elif a_kind == FULL:
             off = a_i * num_pages
             table = page_table + off
             kd, vd = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
             if decode:
-                k_pool, v_pool = kv_cache_append_fn(
-                    k_pool, v_pool, kd[:, 0], vd[:, 0], table, first,
-                    valid[:, 0])
-                o = paged_decode_attention_fn(
-                    q[:, 0], k_pool, v_pool, table, first + 1,
-                    sm_scale=sm_scale)[:, None]
+                with piece("kv_write"):
+                    k_pool, v_pool = kv_cache_append_fn(
+                        k_pool, v_pool, kd[:, 0], vd[:, 0], table, first,
+                        valid[:, 0])
+                with piece("attend"):
+                    o = paged_decode_attention_fn(
+                        q[:, 0], k_pool, v_pool, table, first + 1,
+                        sm_scale=sm_scale)[:, None]
             else:
-                idx = _page_row_index(page_table, gpos, page_size, off,
-                                      valid)
-                slot = gpos % page_size
-                k_pool = _write_rows(k_pool, kd.reshape(B, S, -1), idx, slot)
-                v_pool = _write_rows(v_pool, vd.reshape(B, S, -1), idx, slot)
-                o = causal_attention_fn(
-                    q, _gather_pages(k_pool, table, nkv),
-                    _gather_pages(v_pool, table, nkv), first, sm_scale)
+                with piece("kv_write"):
+                    idx = _page_row_index(page_table, gpos, page_size, off,
+                                          valid)
+                    slot = gpos % page_size
+                    k_pool = _write_rows(k_pool, kd.reshape(B, S, -1), idx,
+                                         slot)
+                    v_pool = _write_rows(v_pool, vd.reshape(B, S, -1), idx,
+                                         slot)
+                with piece("kv_gather"):
+                    kg = _gather_pages(k_pool, table, nkv)
+                    vg = _gather_pages(v_pool, table, nkv)
+                with piece("attend"):
+                    o = causal_attention_fn(q, kg, vg, first, sm_scale)
         else:
             off = a_i * window_pages
             table = window_table + off
             kd, vd = k.astype(wk_pool.dtype), v.astype(wv_pool.dtype)
             if decode:
                 at = local[:, 0]
-                wk_pool, wv_pool = kv_cache_append_fn(
-                    wk_pool, wv_pool, kd[:, 0], vd[:, 0], table, at,
-                    valid[:, 0])
-                o = paged_decode_attention_fn(
-                    q[:, 0], wk_pool, wv_pool, table, at + 1,
-                    sm_scale=sm_scale,
-                    first_live=jnp.maximum(at - (W - 1), 0))[:, None]
+                with piece("kv_write"):
+                    wk_pool, wv_pool = kv_cache_append_fn(
+                        wk_pool, wv_pool, kd[:, 0], vd[:, 0], table, at,
+                        valid[:, 0])
+                with piece("attend"):
+                    o = paged_decode_attention_fn(
+                        q[:, 0], wk_pool, wv_pool, table, at + 1,
+                        sm_scale=sm_scale,
+                        first_live=jnp.maximum(at - (W - 1), 0))[:, None]
             else:
-                idx = _page_row_index(window_table, local, page_size, off,
-                                      valid)
-                slot = local % page_size
-                wk_pool = _write_rows(wk_pool, kd.reshape(B, S, -1), idx,
-                                      slot)
-                wv_pool = _write_rows(wv_pool, vd.reshape(B, S, -1), idx,
-                                      slot)
-                o = band_attention_fn(
-                    q, _gather_pages(wk_pool, table, nkv),
-                    _gather_pages(wv_pool, table, nkv), first, base, W,
-                    sm_scale)
-        o = (o.astype(_F32) * gate[..., None]).reshape(B, S, -1)
-        h = x + _mm(o, p["wo"])
+                with piece("kv_write"):
+                    idx = _page_row_index(window_table, local, page_size,
+                                          off, valid)
+                    slot = local % page_size
+                    wk_pool = _write_rows(wk_pool, kd.reshape(B, S, -1),
+                                          idx, slot)
+                    wv_pool = _write_rows(wv_pool, vd.reshape(B, S, -1),
+                                          idx, slot)
+                with piece("kv_gather"):
+                    kg = _gather_pages(wk_pool, table, nkv)
+                    vg = _gather_pages(wv_pool, table, nkv)
+                with piece("attend"):
+                    o = band_attention_fn(q, kg, vg, first, base, W,
+                                          sm_scale)
+        with piece("proj"):
+            o = (o.astype(_F32) * gate[..., None]).reshape(B, S, -1)
+            h = x + _mm(o, p["wo"])
         fp = {k: w[f_i] for k, w in (dense if f_kind == DENSE
                                      else moe).items()}
         x, ids = _feed_forward(h, layer_params["ffn_norm"][l], f_kind, fp,
                                experts, f_i, geom, tag)
         if ids is not None:
             routes.append(ids)
-    xn = rms_norm_fn(x, final_norm, geom.eps)
-    if mode == "window":
-        at = jnp.clip(lens - 1, 0, S - 1)[:, None, None]
-        xn = jnp.take_along_axis(xn, at, axis=1)
-    logits = jnp.einsum("bsh,hv->bsv", xn.astype(head.dtype), head,
-                        preferred_element_type=_F32)
+    with piece("head"):
+        xn = rms_norm_fn(x, final_norm, geom.eps)
+        if mode == "window":
+            at = jnp.clip(lens - 1, 0, S - 1)[:, None, None]
+            xn = jnp.take_along_axis(xn, at, axis=1)
+        logits = jnp.einsum("bsh,hv->bsv", xn.astype(head.dtype), head,
+                            preferred_element_type=_F32)
     routes = jnp.stack(routes, axis=2)                    # [B, S, L_moe, k]
     out = {"logits": logits if mode == "full" else logits[:, 0],
            "routes": routes[:, 0] if decode else routes}

@@ -65,6 +65,7 @@ from .attention_ops import (_DROP_PAGE, _NEG_INF, _gather_pages,
                             _write_rows)
 from .cca_moe_ops import (_experts_backend, _page_row_index, rms_norm_fn,
                           rotary_partial_fn)
+from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
 
 _HI = jax.lax.Precision.HIGHEST
@@ -357,26 +358,28 @@ def sparse_decode_attention_fn(q, kv_pool, page_table, sel, sm_scale: float,
     rows, ps, words = kv_pool.shape
     P = page_table.shape[1]
     have = sel >= 0
-    at = jnp.maximum(sel, 0)
-    # the page of every selected position, as a masked sum over the table
-    # (131,072 scalar gathers a layer cost the chip 1.2 ms)
-    ordinal = jnp.arange(P, dtype=jnp.int32)
-    page = jnp.sum(jnp.where((at // ps)[..., None] == ordinal,
-                             page_table[:, None, :], 0), axis=-1)
-    flat = jnp.clip(page, 0, rows - 1) * ps + at % ps          # [B, kk]
-    tokens = kv_pool.reshape(rows * ps, words)[flat]           # [B, kk, w]
+    with piece("kv_gather"):
+        at = jnp.maximum(sel, 0)
+        # the page of every selected position, as a masked sum over the
+        # table (131,072 scalar gathers a layer cost the chip 1.2 ms)
+        ordinal = jnp.arange(P, dtype=jnp.int32)
+        page = jnp.sum(jnp.where((at // ps)[..., None] == ordinal,
+                                 page_table[:, None, :], 0), axis=-1)
+        flat = jnp.clip(page, 0, rows - 1) * ps + at % ps      # [B, kk]
+        tokens = kv_pool.reshape(rows * ps, words)[flat]       # [B, kk, w]
     nkv = words * 4 // 2 // jnp.dtype(dtype).itemsize // dh    # K's bytes
-    qg = q.reshape(B, nkv, nh // nkv, dh).astype(dtype)
-    out = []
-    for j in range(nkv):        # a KV head is a 128-lane slice of a row
-        kj, vj = head_of_rows_fn(tokens, j, dh, dtype)
-        s = jnp.einsum("bgd,bkd->bgk", qg[:, j], kj,
-                       preferred_element_type=_F32) * sm_scale
-        s = jnp.where(have[:, None, :], s, _NEG_INF)
-        probs = jax.nn.softmax(s, axis=-1)
-        out.append(jnp.einsum("bgk,bkd->bgd", probs.astype(vj.dtype), vj,
-                              preferred_element_type=_F32))
-    return jnp.stack(out, axis=1).reshape(B, nh, dh)
+    with piece("attend"):
+        qg = q.reshape(B, nkv, nh // nkv, dh).astype(dtype)
+        out = []
+        for j in range(nkv):    # a KV head is a 128-lane slice of a row
+            kj, vj = head_of_rows_fn(tokens, j, dh, dtype)
+            s = jnp.einsum("bgd,bkd->bgk", qg[:, j], kj,
+                           preferred_element_type=_F32) * sm_scale
+            s = jnp.where(have[:, None, :], s, _NEG_INF)
+            probs = jax.nn.softmax(s, axis=-1)
+            out.append(jnp.einsum("bgk,bkd->bgd", probs.astype(vj.dtype),
+                                  vj, preferred_element_type=_F32))
+        return jnp.stack(out, axis=1).reshape(B, nh, dh)
 
 
 def _masked_attention(q, k, v, mask, sm_scale):
@@ -412,11 +415,13 @@ def masked_window_attention_fn(q, kv_pool, page_table, mask,
     (shifted to the layer's rows), mask [B, S, P * page_size]: a page's
     slab of joined rows is gathered once and split into K and V."""
     dh = q.shape[-1]
-    k, v = split_rows_fn(_gather_pages(kv_pool, page_table, 1)[:, :, 0],
-                         dtype)
+    with piece("kv_gather"):
+        k, v = split_rows_fn(
+            _gather_pages(kv_pool, page_table, 1)[:, :, 0], dtype)
     heads = k.shape[:2] + (k.shape[2] // dh, dh)
-    return _masked_attention(q, k.reshape(heads), v.reshape(heads), mask,
-                             sm_scale)
+    with piece("attend"):
+        return _masked_attention(q, k.reshape(heads), v.reshape(heads),
+                                 mask, sm_scale)
 
 
 def topk_router_fn(z, router_w, k: int):
@@ -481,11 +486,14 @@ def _pre_attention(x, p, positions, geom: Geometry):
 def _post_attention(x, o, p, experts, layer, geom: Geometry, tag):
     """x [B, S, H], o [B, S, nh*dh] -> (y [B, S, H], ids [B, S, k])."""
     B, S, H = x.shape
-    h = x + _mm(o, p["wo"])
-    z = rms_norm_fn(h, p["ffn_norm"], geom.eps).reshape(B * S, H)
-    ids, cw = topk_router_fn(z, p["router_w"], geom.experts_per_token)
-    y = moe_topk_experts_fn(z, cw, *experts, layer=layer, tag=tag)
-    return h + y.reshape(B, S, H), ids.reshape(B, S, -1)
+    with piece("proj"):
+        h = x + _mm(o, p["wo"])
+        z = rms_norm_fn(h, p["ffn_norm"], geom.eps).reshape(B * S, H)
+    with piece("router"):
+        ids, cw = topk_router_fn(z, p["router_w"], geom.experts_per_token)
+    with piece("experts"):
+        y = moe_topk_experts_fn(z, cw, *experts, layer=layer, tag=tag)
+        return h + y.reshape(B, S, H), ids.reshape(B, S, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -493,6 +501,7 @@ def _post_attention(x, o, p, experts, layer, geom: Geometry, tag):
 # ---------------------------------------------------------------------------
 
 
+@under_mode
 def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                         layer_params: dict, experts: tuple, geom: Geometry,
                         pools=None, page_table=None, lens=None, start=None,
@@ -511,12 +520,14 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
     `pack_selection_fn` words [B, S, L, G, page_size]; the positions the
     marked rows of a decode step gathered, [M, L, kk], -1 where fewer
     exist; and, with `pools` (the joined K/V rows, the indexer keys),
-    kv_pool/i_pool as written."""
+    kv_pool/i_pool as written. Traced under its mode's scope, each piece
+    (observability/schema.PIECES) under its own."""
     decode = mode == "decode"
     paged = mode != "full"
     if decode:
         tok, pos = jnp.reshape(tok, (-1, 1)), jnp.reshape(pos, (-1, 1))
-    x = emb[tok].astype(_F32)
+    with piece("embed"):
+        x = emb[tok].astype(_F32)
     B, S, _ = x.shape
     L = layer_params["wq"].shape[0]
     sm_scale = geom.head_dim ** -0.5
@@ -548,14 +559,18 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
             table = page_table + off
         else:
             (x,) = carry
-        q, k, v, qi, ki, w = _pre_attention(x, p, pos, geom)
+        with piece("proj"):
+            q, k, v, qi, ki, w = _pre_attention(x, p, pos, geom)
         if paged:
-            idx = _page_row_index(page_table, gpos, page_size, off, valid)
-            slot = gpos % page_size
-            kv_pool = _write_rows(kv_pool, join_rows_fn(
-                k.reshape(B, S, -1), v.reshape(B, S, -1), kv_dtype), idx, slot)
-            i_pool = write_index_keys_fn(i_pool, ki, page_table, off, first,
-                                         count)
+            with piece("kv_write"):
+                idx = _page_row_index(page_table, gpos, page_size, off,
+                                      valid)
+                slot = gpos % page_size
+                kv_pool = _write_rows(kv_pool, join_rows_fn(
+                    k.reshape(B, S, -1), v.reshape(B, S, -1), kv_dtype),
+                    idx, slot)
+                i_pool = write_index_keys_fn(i_pool, ki, page_table, off,
+                                             first, count)
         if paged and dense_decode:
             at = jnp.arange(context, dtype=jnp.int32)[None, :]
             live = (at <= first[:, None])[:, None]              # [B, 1, T]
@@ -563,27 +578,35 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                                            sm_scale, kv_dtype)
             sel = jnp.where(live, at[:, None], -1)
         else:
-            if paged:
-                ki_ctx = i_pool[jnp.clip(table, 0, i_pool.shape[0] - 1)]
-            else:       # the sequence as one page
-                ki_ctx = jnp.swapaxes(ki.astype(emb.dtype), 1, 2)[:, None]
-            scores = indexer_scores_fn(qi, w, ki_ctx)
+            with piece("indexer"):
+                if paged:
+                    ki_ctx = i_pool[jnp.clip(table, 0, i_pool.shape[0] - 1)]
+                else:       # the sequence as one page
+                    ki_ctx = jnp.swapaxes(ki.astype(emb.dtype), 1,
+                                          2)[:, None]
+                scores = indexer_scores_fn(qi, w, ki_ctx)
             if decode:
-                sel = select_indices_fn(scores, gpos + 1, geom.index_topk)
+                with piece("select"):
+                    sel = select_indices_fn(scores, gpos + 1,
+                                            geom.index_topk)
                 o = sparse_decode_attention_fn(
                     q[:, 0], kv_pool, table, sel[:, 0], sm_scale,
                     kv_dtype)[:, None]
             else:
-                keep = select_mask_fn(scores, gpos + 1, geom.index_topk)
+                with piece("select"):
+                    keep = select_mask_fn(scores, gpos + 1, geom.index_topk)
                 if paged:
                     o = masked_window_attention_fn(q, kv_pool, table, keep,
                                                    sm_scale, kv_dtype)
                 else:
-                    o = _masked_attention(q, k.astype(emb.dtype),
-                                          v.astype(emb.dtype), keep,
-                                          sm_scale)
+                    with piece("attend"):
+                        o = _masked_attention(q, k.astype(emb.dtype),
+                                              v.astype(emb.dtype), keep,
+                                              sm_scale)
                 # handed back as attended under: the mask itself
-                sel = pack_selection_fn(keep, page_size if paged else S)
+                with piece("select"):
+                    sel = pack_selection_fn(keep,
+                                            page_size if paged else S)
         y, ids = _post_attention(x, o.reshape(B, S, -1), p, experts, l,
                                  geom, tag)
         if decode:
@@ -594,12 +617,13 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
     init = (x,) + (tuple(pools) if paged else ())
     xs = (jnp.arange(L, dtype=jnp.int32), layer_params)
     carry, (routes, selection) = jax.lax.scan(layer, init, xs)
-    xn = rms_norm_fn(carry[0], final_norm, geom.eps)
-    if mode == "window":
-        at = jnp.clip(lens - 1, 0, S - 1)[:, None, None]
-        xn = jnp.take_along_axis(xn, at, axis=1)
-    logits = jnp.einsum("bsh,hv->bsv", xn.astype(head.dtype), head,
-                        preferred_element_type=_F32)
+    with piece("head"):
+        xn = rms_norm_fn(carry[0], final_norm, geom.eps)
+        if mode == "window":
+            at = jnp.clip(lens - 1, 0, S - 1)[:, None, None]
+            xn = jnp.take_along_axis(xn, at, axis=1)
+        logits = jnp.einsum("bsh,hv->bsv", xn.astype(head.dtype), head,
+                            preferred_element_type=_F32)
     routes = jnp.moveaxis(routes, 0, -2)                  # [B, S, L, k]
     if decode:
         selection = jnp.moveaxis(selection, 0, 1)         # [M, L, kk]

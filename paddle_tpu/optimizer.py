@@ -151,6 +151,11 @@ class Optimizer:
         if guardrails.enabled():
             params_grads = guardrails.append_health_sentinel(params_grads)
         ops = self._create_optimization_pass(params_grads)
+        # a program that holds its updates is a train step: a device trace
+        # lists its compiled entries as jit_train_step (a name the caller
+        # gave the Program stands)
+        if default_main_program().name is None:
+            default_main_program().name = "train_step"
         # the StepGuard's rewind rung backs the LR off through the scope;
         # record where the LR lives (scheduler LR vars qualify too)
         try:

@@ -6,8 +6,9 @@ instead of triggering a fresh compile. This hook turns "how many compiles
 actually happened" into something a regression test can assert: it listens
 to jax.monitoring's backend-compile duration event, which jax records once
 per executable it builds or loads, and counts the ones for the executor's
-whole-block closure (named `fn`, so its events are distinguishable from the
-small utility jits jax compiles around a run).
+whole-block closures (named `fn`, or after their Program where it has a
+name: `executor.LOWERED_FN_NAMES`, so their events are distinguishable from
+the small utility jits jax compiles around a run).
 
 A persistent-cache hit is counted too: it skips XLA but is still a
 first-use stall (trace + lower + deserialize) inside the caller's window,
@@ -36,16 +37,18 @@ class _CompileCount:
 
 
 @contextlib.contextmanager
-def jit_compile_counter(fn_name: str = "fn"):
-    """Count XLA compiles of jitted functions named `fn_name` inside the
-    `with` block. Default "fn" matches the executor's whole-block closure, so
+def jit_compile_counter():
+    """Count XLA compiles of the executor's whole-block closures inside the
+    `with` block, whatever Program they are called after, so
     `counter.count` is the number of (program, signature) compile-cache
     misses the block produced."""
+    from ..executor import LOWERED_FN_NAMES as names
+
     result = _CompileCount()
-    names = (fn_name, f"jit({fn_name})")
 
     def listener(event, duration, fun_name=None, **_):
-        if event == _BACKEND_COMPILE_EVENT and fun_name in names:
+        if event == _BACKEND_COMPILE_EVENT and fun_name is not None \
+                and fun_name.removeprefix("jit(").removesuffix(")") in names:
             result.events.append(f"{fun_name} {duration:.6f}s")
             from .. import observability as obs
 

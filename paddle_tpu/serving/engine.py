@@ -134,6 +134,7 @@ Resilience layer (ISSUE 14 — see README "Serving resilience"):
 """
 from __future__ import annotations
 
+import gc
 import time
 
 import dataclasses
@@ -560,6 +561,12 @@ class ServingEngine:
         self._decode_prog = Program()
         self._window_prog = Program()
         self._cow_prog = Program()
+        # the names a device trace lists the compiled steps under
+        # (observability/schema.PROGRAM_NAMES): jit_serving_decode, ...
+        self._prefill_prog.name = "serving_prefill"
+        self._decode_prog.name = "serving_decode"
+        self._window_prog.name = "serving_window"
+        self._cow_prog.name = "serving_cow"
         startup = Program()
         decoy_startup = Program()  # non-prefill progs re-declare; inits unused
         self._prefill_prog.random_seed = startup.random_seed = self.seed
@@ -867,7 +874,20 @@ class ServingEngine:
         one pass of a workload, reset, measure the second pass. The
         registry's `serving.` series reset with it, and the `pipeline.`
         stages and `host.` series a step's spans and the collector's pauses
-        feed, so every view stays scoped to the same measurement window."""
+        feed, so every view stays scoped to the same measurement window.
+
+        The boundary is also where the set-up's heap leaves the collector's
+        work: a warmed engine holds a few hundred thousand tracked objects
+        that live as long as the process (the jaxprs and executables of 50
+        compiled entries), and CPython's next full collection walks them
+        all, one 0.3 s loop iteration somewhere in the traffic that
+        follows (PERF §7). One full collection here, while nobody waits,
+        then `gc.freeze()`: later collections scan what was allocated
+        since. Process-wide, like the collector itself; the `unfreeze`
+        first lets a second boundary reclaim what died since the last."""
+        gc.unfreeze()
+        gc.collect()
+        gc.freeze()
         for k, v in self.stats.items():
             if isinstance(v, set):
                 v.clear()

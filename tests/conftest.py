@@ -29,6 +29,8 @@ from paddle_tpu import compile_cache
 compile_cache.configure()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
+import gc
+
 import numpy as np
 import pytest
 
@@ -55,6 +57,7 @@ def fresh_programs():
     old_gen = unique_name.switch()
     _scope_stack.append(Scope())
     yield
+    gc.unfreeze()       # `ServingEngine.reset_stats` freezes the heap
     _scope_stack.pop()
     unique_name.switch(old_gen)
     pt.framework.switch_main_program(old_main)

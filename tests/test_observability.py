@@ -138,19 +138,25 @@ def test_profiler_stage_shims_keep_pr2_semantics():
     assert profiler.stage_counters() == {}
 
 
-def test_every_legacy_stage_literal_is_declared():
-    """Source-scan regression: every bump("x")/record_stage("x") literal in
-    the tree must name a declared stage — adding a stage is a schema act."""
-    pat = re.compile(r'(?:\bbump|\brecord_stage|\bstage_timer)\(\s*"([^"]+)"')
-    used = set()
-    pkg = os.path.join(REPO, "paddle_tpu")
-    for dirpath, _, files in os.walk(pkg):
+def _tree_literals(pattern):
+    """Every match of `pattern` in paddle_tpu/*.py, outside observability/
+    (the layer's own docs show `bump("...")` examples)."""
+    pat, used = re.compile(pattern), set()
+    for dirpath, _, files in os.walk(os.path.join(REPO, "paddle_tpu")):
         if os.path.basename(dirpath) == "observability":
-            continue  # the layer's own docs show `bump("...")` examples
+            continue
         for fn in files:
             if fn.endswith(".py"):
                 with open(os.path.join(dirpath, fn)) as f:
                     used |= set(pat.findall(f.read()))
+    return used
+
+
+def test_every_legacy_stage_literal_is_declared():
+    """Source-scan regression: every bump("x")/record_stage("x") literal in
+    the tree must name a declared stage — adding a stage is a schema act."""
+    used = _tree_literals(
+        r'(?:\bbump|\brecord_stage|\bstage_timer)\(\s*"([^"]+)"')
     assert used, "source scan found no stage call sites"
     undeclared = sorted(used - schema.STAGE_NAMES)
     assert not undeclared, (
@@ -206,6 +212,26 @@ def test_serving_engine_mirrors_stats_into_registry():
     parsed = parse_prometheus(text)
     assert parsed["serving_prefills"] == eng.stats["prefills"]
     assert parsed['serving_ttft_s_count'] == 2
+
+
+def test_every_piece_literal_is_declared_and_every_piece_is_used():
+    """The names a device trace books time to (ISSUE 35) drift like metric
+    names do: a `piece("x")` in the tree must be in schema.PIECES, and a
+    declared piece some stack must open (no second list in ops/)."""
+    used = _tree_literals(r'\bpiece\(\s*"([^"]+)"')
+    assert used == schema.PIECES, (
+        f"undeclared: {sorted(used - schema.PIECES)}; "
+        f"declared and unused: {sorted(schema.PIECES - used)}")
+    # a stack's mode reaches `piece` as a variable, through `under_mode`
+    assert _tree_literals(r'\n@(under_mode)\n') == {"under_mode"}
+    assert not schema.PIECES & schema.STACK_MODES
+
+
+def test_every_program_name_literal_is_declared():
+    used = _tree_literals(r'\.name = "(\w+)"')
+    assert used == schema.PROGRAM_NAMES, (
+        f"undeclared: {sorted(used - schema.PROGRAM_NAMES)}; declared and "
+        f"given to no Program: {sorted(schema.PROGRAM_NAMES - used)}")
 
 
 # -- profiler trace-lifecycle guards ------------------------------------------

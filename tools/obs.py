@@ -17,6 +17,13 @@ Usage:
     python tools/obs.py prom FILE.prom
         # strict-parse a Prometheus exposition file -> JSON on stdout;
         # exits 1 on any unparseable line (the round-trip check as a tool)
+    python tools/obs.py ops TRACE [--by module|op|piece] [--span NAME]
+        # the device's seconds of a jax profiler trace (a directory or an
+        # .xplane.pb) under the program's own names: self time by compiled
+        # module, framework op type or declared piece, unscoped
+        # instructions one by one under `piece` (profiler.device_time /
+        # device_table); --span clips to a host annotation (the
+        # benchmark's slice is bench.trace_slice)
 
 Exit status: 0 on success, 1 on malformed input, 2 on usage error.
 """
@@ -165,9 +172,27 @@ def cmd_prom(argv: list[str]) -> int:
     return 0
 
 
+def cmd_ops(argv: list[str]) -> int:
+    from paddle_tpu import profiler
+
+    def opt(flag, default):
+        return argv[argv.index(flag) + 1] if flag in argv else default
+
+    by = opt("--by", "op")
+    if by not in ("module", "op", "piece"):
+        print(f"--by must be module, op or piece, not {by!r}",
+              file=sys.stderr)
+        return 2
+    print(profiler.device_table(
+        profiler.device_time(argv[0], window_span=opt("--span", None)),
+        by=by))
+    return 0
+
+
 def main() -> int:
     cmds = {"tail": (cmd_tail, 1), "summarize": (cmd_summarize, 1),
-            "diff": (cmd_diff, 2), "prom": (cmd_prom, 1)}
+            "diff": (cmd_diff, 2), "prom": (cmd_prom, 1),
+            "ops": (cmd_ops, 1)}
     if len(sys.argv) < 2 or sys.argv[1] not in cmds:
         print(__doc__.strip(), file=sys.stderr)
         return 2
