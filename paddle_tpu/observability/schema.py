@@ -97,9 +97,14 @@ DECLARED: list[tuple] = [
     ("serving.request_s", HISTOGRAM,
      "request latency: submit -> finished", ()),
     ("serving.prefill.seconds", HISTOGRAM,
-     "prefill span durations (also a TraceAnnotation in XPlane)", ()),
+     "prefill span durations (also a TraceAnnotation in XPlane): feeds, the "
+     "enqueue, then the fetch and accept of the step dispatched BEFORE it "
+     "(ISSUE 36); not the prefill's device time", ()),
     ("serving.decode.seconds", HISTOGRAM,
-     "decode-step span durations (also a TraceAnnotation in XPlane)", ()),
+     "decode-step span durations (also a TraceAnnotation in XPlane): page "
+     "growth, feeds, the enqueue, then the fetch and accept of the step "
+     "dispatched BEFORE it (ISSUE 36); a step's device time is "
+     "jit_serving_decode's seconds a call in profiler.device_time", ()),
     # one scheduler iteration as a span tree (ISSUE 23): every span is a
     # TraceAnnotation, a <name>.seconds histogram and a JSONL record with
     # `parent` and the root's `step`
@@ -122,13 +127,31 @@ DECLARED: list[tuple] = [
     ("serving.feed_build.seconds", HISTOGRAM,
      "building the numpy feeds of one prefill or decode step", ()),
     ("serving.accept.seconds", HISTOGRAM,
-     "sampling, the accept loop over rows and prefix registration", ()),
+     "a fetched step's routes and selections booked, sampling, the accept "
+     "loop over its rows", ()),
+    ("serving.settle.seconds", HISTOGRAM,
+     "the pending step accepted with nothing enqueued behind it: before a "
+     "sampled or speculative step, before an abort, expiry, preemption or "
+     "audit touches state, or when the engine has nothing further to "
+     "dispatch (its pipeline.fetch and serving.accept sit under it)", ()),
+    # -- a step's tokens read one dispatch late (ISSUE 36) ------------------
+    ("serving.chain.steps_deferred", COUNTER,
+     "step programs (prefill, chunk, decode) accepted after the NEXT "
+     "program was enqueued: the host's work overlapped the device's", ()),
+    ("serving.chain.steps_blocking", COUNTER,
+     "step programs accepted with nothing enqueued behind them, by why: "
+     "sampled (a row's sampler reads host logits), spec (the draft reads "
+     "host history), idle (nothing further to dispatch), settle (state was "
+     "about to be read or changed)", ("why",)),
+    ("serving.chain.discarded_rows", COUNTER,
+     "rows of an accepted step whose output was dropped: the request had "
+     "stopped on eos_id while the step was in flight", ()),
     ("serving.prefill.host_seconds", HISTOGRAM,
-     "serving.prefill less the seconds inside pipeline.fetch: the host's "
-     "part of one prefill", ()),
+     "serving.prefill less the seconds inside pipeline.fetch (the wait for "
+     "the step before): the host's work under one prefill span", ()),
     ("serving.decode.host_seconds", HISTOGRAM,
-     "serving.decode less the seconds inside pipeline.fetch: the host's "
-     "part of one decode step", ()),
+     "serving.decode less the seconds inside pipeline.fetch (the wait for "
+     "the step before): the host's work under one decode span", ()),
     ("serving.slow_step", EVENT,
      "one iteration over engine.SLOW_STEP_S: step, dur_s, self seconds by "
      "span name, gc_s, rows decoded, requests admitted", ()),

@@ -631,6 +631,30 @@ def gather_token_logits_op(ctx: ExecContext):
     return {"Out": jnp.take_along_axis(x, idx, axis=1)[:, 0, :]}
 
 
+@register_op("last_token_select", grad="none")
+def last_token_select_op(ctx: ExecContext):
+    """inputs: Tok [B, 1] (the host's feed), FromHost [B, 1], Slot [B], Last
+    [N] (the token each row slot's latest step emitted, kept on the device)
+    — output [B, 1]: row b's Tok where FromHost is set, Last[Slot[b]]
+    otherwise: a decode step takes the token of a step the host has not
+    read yet."""
+    tok = ctx.input("Tok").astype(jnp.int32)
+    kept = ctx.input("Last")[ctx.input("Slot").astype(jnp.int32)]
+    out = jnp.where(ctx.input("FromHost").reshape(-1) != 0, tok.reshape(-1),
+                    kept)
+    return {"Out": out.reshape(tok.shape)}
+
+
+@register_op("last_token_write", grad="none")
+def last_token_write_op(ctx: ExecContext):
+    """inputs: Last [N], Slot [B], Next [B] — Last with Last[Slot[b]] =
+    Next[b] (padding rows name slot N - 1, which no row reads). LastOut is
+    the SAME var as Last, the pools' read-write contract."""
+    last = ctx.input("Last")
+    return {"LastOut": last.at[ctx.input("Slot").astype(jnp.int32)].set(
+        ctx.input("Next").astype(last.dtype).reshape(-1))}
+
+
 # ---------------------------------------------------------------------------
 # Ring attention (sequence parallelism over the `sp` axis)
 # ---------------------------------------------------------------------------

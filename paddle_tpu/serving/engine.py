@@ -1,7 +1,6 @@
 """Continuous-batching serving engine over the paged KV cache.
 
-The scheduling loop the "millions of users" scenario needs (ROADMAP item 2):
-requests arrive at any time, and the engine admits/evicts them BETWEEN
+Requests arrive at any time, and the engine admits/evicts them BETWEEN
 decode steps instead of running fixed generation batches:
 
     step():  (maybe) inject a chaos abort -> admit waiting requests while
@@ -12,6 +11,39 @@ decode steps instead of running fixed generation batches:
              ragged decode step over ALL running requests (a k-token
              draft-verify window when speculative decoding is on) ->
              retire finished rows.
+
+The step loop reads a step's tokens ONE DISPATCH LATE (ISSUE 36). Every
+step program (prefill, suffix window or last chunk, decode) is enqueued on
+the device and the host does not wait for it: it keeps the step's device
+handles as the one PENDING step (`_InFlight`), and right after the NEXT
+program is enqueued it accepts the pending one (`pipeline.fetch` on its
+tokens, routes and marked selections, then the accept loop). The one value
+step n+1 needs of step n, each continuing row's input token, never leaves
+the device: every program writes the token it emits to its row's slot of
+`model.LAST_TOKEN`, and a decode row whose token the host has not read
+takes it from there (`sv_from_host` 0). So the host builds, admits, stamps
+and accepts step n while the device runs step n+1. What follows from it:
+
+  * a row with a token in flight stands one position further
+    (`GenRequest.cache_len` counts `in_flight`); routes and selections are
+    booked at the positions and pages recorded at dispatch;
+  * stops on length and on `max_position` are decided BEFORE dispatch, so
+    such a row never runs an extra step; a stop on `eos_id` is found one
+    step late, and the extra step's row is dropped at its accept
+    (`serving.chain.discarded_rows`; what it wrote lies in the row's own
+    page past anything the prefix cache published);
+  * a step whose values the host needs before the next dispatch is
+    accepted at once, chosen from the input and not by a flag: a
+    non-greedy row (the host sampler reads its logits), the speculative
+    path (the draft reads host history), and the last step before the
+    engine has nothing further to dispatch. `abort`, preemption, deadline
+    expiry, recovery, the handoff, the audits and `result` settle the
+    pending step before they touch request or pool state;
+  * an error that surfaces at the deferred fetch cannot be retried alone
+    (step n+1 consumed step n's pools): it goes to the recovery pass, where
+    an exhausted retry of the enqueue ends too.
+`serving.chain.steps_deferred` / `.steps_blocking{why}` count which way
+every step program was accepted.
 
 Multi-tenant machinery (ISSUE 11), three composable stages:
   * PREFIX CACHING — prompts are indexed at page granularity
@@ -139,6 +171,7 @@ import time
 
 import dataclasses
 
+import jax.numpy as jnp
 import numpy as np
 
 from .. import flags, profiler, unique_name
@@ -173,6 +206,9 @@ _LADDER_RUNGS = {1: "spec_off", 2: "lookahead_shrink",
 # an iteration this long emits serving.slow_step with what it was made of:
 # on the chip a normal one takes 0.06-0.5 s and a stall thousands of ms
 SLOW_STEP_S = 1.0
+# the fewest row slots of `model.LAST_TOKEN` (a few hundred bytes): room for
+# a max_inflight a controller raises past the constructor's
+MIN_TOKEN_SLOTS = 64
 
 
 class AdmissionRejected(RuntimeError):
@@ -285,11 +321,32 @@ def _selection_words(pieces: list, page_size: int) -> np.ndarray:
     return np.concatenate(out)
 
 
+@dataclasses.dataclass
+class _InFlight:
+    """One step program the device has been given and the host has not read
+    (the engine holds at most one): its device handles and what its accept
+    needs of the moment it was dispatched."""
+
+    kind: str               # "decode" | "prefill" | "chunk" (of a prefill)
+    rows: list              # the requests it computed, in row order
+    tokens: object          # next_token [rows] (None: a chunk emits none)
+    logits: object          # [rows, V]; None once dropped (all rows greedy)
+    routes: object          # experts chosen, None where the block has none
+    selection: object       # what was attended, None unless a row is marked
+    # decode: each row's (position, page) the step wrote; prefill / chunk:
+    # (first position, positions computed) of its one row
+    at: list
+    marked: list            # decode: the row indices whose selection is kept
+    route_pages: object = None  # prefill / chunk: the page of each position
+
+
 class GenRequest:
     """One generate request's lifetime.
 
-    `all_tokens` is the full sequence so far (prompt + generated); the KV
-    cache always holds exactly len(all_tokens) - 1 slots while RUNNING (the
+    `all_tokens` is the full sequence the host has accepted so far (prompt
+    + generated); `in_flight` (0 or 1) counts a token a dispatched step has
+    computed and the host has not read. The KV cache holds exactly
+    `cache_len` = len(all_tokens) - 1 + in_flight slots while RUNNING (the
     newest token's KV is written by the decode step that consumes it). On
     preemption the pages are dropped and the whole prefix re-prefills — no
     separate bookkeeping for "how much cache survived".
@@ -310,6 +367,9 @@ class GenRequest:
         self.sampling = sampling or SamplingParams()
         self.state = WAITING
         self.pages: list[int] = []
+        self.in_flight = 0
+        # the row's slot of `model.LAST_TOKEN` while it has steps to run
+        self.slot: int | None = None
         # a family with sliding-window layers: the row's pages of the window
         # pool, logical pages wfirst .. wfirst + len(wpages) - 1
         self.wpages: list[int] = []
@@ -364,8 +424,9 @@ class GenRequest:
 
     @property
     def cache_len(self) -> int:
-        """Valid KV slots while RUNNING (last token not yet appended)."""
-        return len(self.all_tokens) - 1
+        """Valid KV slots while RUNNING (last token not yet appended): the
+        position the row's next step writes, a token in flight counted."""
+        return len(self.all_tokens) - 1 + self.in_flight
 
     def is_done(self) -> bool:
         return (self.n_generated >= self.max_new_tokens
@@ -572,20 +633,34 @@ class ServingEngine:
         self._prefill_prog.random_seed = startup.random_seed = self.seed
         second = {"window_pages": self.window_pool.num_pages} \
             if self.window_pool is not None else {}
+        # the last token of every row slot, on the device (model.LAST_TOKEN):
+        # a running row holds a slot while it has steps to run. Sized for
+        # the row bucket (and for what a controller may raise max_inflight
+        # to); a scope shared by several engines holds one a pool owner.
+        # The last entry is the padding rows'.
+        self._token_slots = _round_up_pow2(max(self.max_inflight,
+                                               MIN_TOKEN_SLOTS))
+        self._slots_free = list(range(self._token_slots))[::-1]
+        self._last_token = sv_model.LAST_TOKEN if shared_scope is None \
+            else f"{sv_model.LAST_TOKEN}.{getattr(self.pool, 'owner', id(self))}"
+        self._pending: _InFlight | None = None
+        self._dispatched = 0        # step programs enqueued, ever
+        kept = {"token_slots": self._token_slots,
+                "last_token": self._last_token, **second}
         with program_guard(self._prefill_prog, startup), \
                 unique_name.guard():
             self._prefill_io = sv_model.build_prefill_program(
-                self.cfg, self.pool_pages, self.page_size, **second)
+                self.cfg, self.pool_pages, self.page_size, **kept)
         with program_guard(self._decode_prog, decoy_startup), \
                 unique_name.guard():
             self._decode_io = sv_model.build_decode_program(
                 self.cfg, self.pool_pages, self.page_size, tp=self.tp,
-                **second)
+                **kept)
         with program_guard(self._window_prog, decoy_startup), \
                 unique_name.guard():
             self._window_io = sv_model.build_window_program(
                 self.cfg, self.pool_pages, self.page_size, tp=self.tp,
-                **second)
+                **kept)
         with program_guard(self._cow_prog, decoy_startup), \
                 unique_name.guard():
             self._cow_io = sv_model.build_cow_program(
@@ -595,6 +670,8 @@ class ServingEngine:
         # letting it leak into the init keys would give every engine after
         # the first different weights — silently breaking replay exactness
         self._exe.run(startup, scope=self._scope, rng_counter=1)
+        self._scope.set_var(self._last_token,
+                            jnp.zeros((self._token_slots + 1,), jnp.int32))
         # a shared scope may already carry live KV (an engine added to a
         # running disaggregated fleet): re-zeroing the pools would clobber
         # every peer's context, so only the FIRST engine materializes them.
@@ -668,6 +745,10 @@ class ServingEngine:
             "kv.global_row_pages": 0, "attn.full_context_tokens": 0,
             "attn.window_context_tokens": 0, "attn.full_layer_steps": 0,
             "attn.window_layer_steps": 0, "peak_window_pages_in_use": 0,
+            # steps read one dispatch late (ISSUE 36); steps_blocking goes
+            # to the registry by `why`
+            "chain.steps_deferred": 0, "chain.steps_blocking": 0,
+            "chain.discarded_rows": 0,
         }
         # the learned controller's per-engine epoch hook (ISSUE 20):
         # shadow by default — one perf_counter read per step until an
@@ -800,6 +881,22 @@ class ServingEngine:
         mark[:len(rows)] = rows
         return {sv_model.MARK_FEED: mark}
 
+    def _slot_feed(self, rows, bb: int, decode: bool = False) -> dict:
+        """Where a step of `bb` rows keeps the token each of `rows` emits
+        (`sv_slot`: the row's slot of `model.LAST_TOKEN`, the spare last
+        entry for padding and for a row that runs no further step) and,
+        for a decode step, which rows take their input token from there
+        (`sv_from_host` 0: the host has not read it yet)."""
+        slot = np.full((bb,), self._token_slots, np.int32)
+        for i, r in enumerate(rows):
+            if r.slot is not None:
+                slot[i] = r.slot
+        if not decode:
+            return {sv_model.SLOT_FEED: slot}
+        from_host = np.ones((bb, 1), np.int32)
+        from_host[:len(rows), 0] = [not r.in_flight for r in rows]
+        return {sv_model.SLOT_FEED: slot, sv_model.FROM_HOST_FEED: from_host}
+
     def _window_feed(self, rows, bb: int, width: int) -> dict:
         """The compact tables of `rows` in the sliding layers' pool and the
         position of each table's slot 0, for a step of `bb` rows ({} where
@@ -846,7 +943,8 @@ class ServingEngine:
                             sv_model.POS_FEED: np.zeros((bb, S), np.int32),
                             sv_model.PAGES_FEED: pages,
                             sv_model.START_FEED: np.zeros((bb,), np.int32),
-                            sv_model.LEN_FEED: np.zeros((bb,), np.int32)}
+                            sv_model.LEN_FEED: np.zeros((bb,), np.int32),
+                            **self._slot_feed((), bb)}
                     self._exe.run(self._window_run, feed=feed,
                                   fetch_list=[self._window_io["tokens"],
                                               self._window_io["logits"]],
@@ -858,6 +956,7 @@ class ServingEngine:
                             sv_model.MASK_FEED: np.zeros((bb, 1),
                                                          np.float32),
                             **self._mark_feed(),
+                            **self._slot_feed((), bb, decode=True),
                             **self._window_feed((), bb, self._wtable_decode)}
                     outs = self._exe.run(
                         self._decode_run, feed=feed,
@@ -899,12 +998,14 @@ class ServingEngine:
         for prefix in ("serving.", "pipeline.", "host."):
             obs.reset(prefix)
 
-    def _count(self, key: str, n: int = 1) -> None:
-        """Bump a stats counter AND its registry mirror (`serving.<key>`):
-        the dict stays the cheap in-process view, the registry carries the
-        same number out through snapshot/exporters."""
+    def _count(self, key: str, n: int = 1, labels: dict | None = None
+               ) -> None:
+        """Bump a stats counter AND its registry mirror (`serving.<key>`,
+        under `labels` where the series has them): the dict stays the cheap
+        in-process view, the registry carries the same number out through
+        snapshot/exporters."""
         self.stats[key] += n
-        obs.counter_inc("serving." + key, n)
+        obs.counter_inc("serving." + key, n, labels)
 
     def stats_snapshot(self) -> dict:
         """The stats dict plus derived rates, every divide guarded: a
@@ -1020,13 +1121,15 @@ class ServingEngine:
         (the zero-leak contract the chaos test asserts). A WAITING request
         leaves the admission queue AND releases any prefix-cache pages a
         failed admission attempt left pinned on it."""
+        self._settle_or_recover()
         req = self.requests.get(rid)
         if req is None or req.state in _TERMINAL:
             return
         self._terminate(req, ABORTED, "aborts")
 
     def has_work(self) -> bool:
-        return bool(self._waiting or self._running)
+        return bool(self._waiting or self._running
+                    or self._pending is not None)
 
     @property
     def decode_slots_free(self) -> int:
@@ -1045,6 +1148,7 @@ class ServingEngine:
         audit and leak accounting see the pin as a live holder throughout.
         The caller (the prefill replica) grants the lease over the
         returned page table before anything else moves."""
+        self._settle_or_recover()
         req = self.requests[rid]
         if self.cfg.scanned:
             raise NotImplementedError(
@@ -1055,6 +1159,7 @@ class ServingEngine:
                 f"request {rid} is {req.state}; only RUNNING (prefilled) "
                 f"requests can hand off")
         self._running.remove(req)
+        self._free_slot(req)
         req.state = HANDED_OFF
         self._count("handoff_extracts")
         obs.event("serving.request",
@@ -1101,7 +1206,7 @@ class ServingEngine:
             raise RuntimeError(
                 "adopt_request needs a shared pool (OwnedPoolView): a "
                 "private pool cannot receive a lease-transferred refcount")
-        if len(self._running) >= self.max_inflight:
+        if len(self._running) >= self.max_inflight or not self._slots_free:
             raise AdmissionRejected(
                 "adopt_no_decode_slot", 0.05,
                 {"running": len(self._running),
@@ -1122,6 +1227,7 @@ class ServingEngine:
         req.pages = pages
         req.deadline_t = handoff.get("deadline_t")
         req.state = RUNNING
+        req.slot = self._slots_free.pop()
         req.admit_seq = self._admit_seq
         self._admit_seq += 1
         adopt(pages)
@@ -1134,6 +1240,7 @@ class ServingEngine:
         return rid
 
     def result(self, rid: int) -> list[int]:
+        self._settle_or_recover()
         return list(self.requests[rid].out_tokens)
 
     def pop_result(self, rid: int) -> list[int]:
@@ -1141,6 +1248,7 @@ class ServingEngine:
         record. `requests` otherwise retains every completed request (full
         token list included) for the engine's lifetime — unbounded growth
         and ever-slower leak accounting under continuous serving."""
+        self._settle_or_recover()
         req = self.requests[rid]
         if req.state not in _TERMINAL:
             raise ValueError(
@@ -1164,6 +1272,7 @@ class ServingEngine:
         account for — must be zero at every quiescent point. Over a shared
         pool the base is this OWNER's pages (the OwnedPoolView ledger), not
         the global pool: peers' pages are theirs to account for."""
+        self._settle_or_recover()
         mapped: set[int] = set()
         for r in self.requests.values():
             mapped.update(r.pages)
@@ -1209,8 +1318,12 @@ class ServingEngine:
         The iteration is one span tree under `serving.step`: housekeeping,
         admission (one `serving.prefill` per admitted request), one
         `serving.decode`, and under those the feed building, the executor's
-        `pipeline.*` stages and the accept loop. Each span's self seconds
-        land in `_phase_s`, so a slow iteration says what it was made of
+        `pipeline.prepare` / `.dispatch` of the span's OWN program and then
+        the `pipeline.fetch` and `serving.accept` of the step dispatched
+        BEFORE it (the pending one; its own where the step blocks). A step
+        the iteration leaves nothing behind to overlap with is accepted
+        under `serving.settle`. Each span's self seconds land in
+        `_phase_s`, so a slow iteration says what it was made of
         (`_note_step`)."""
         self._step_i += 1
         self._step_admitted = self._step_rows = 0
@@ -1275,12 +1388,13 @@ class ServingEngine:
                     self.abort(victim.rid)
             self._expire_deadlines(time.perf_counter())
             if self.audit_every > 0 and self._step_i % self.audit_every == 0:
-                problems, poisoned = self.audit_pool()
+                problems, poisoned = self._audit_tables()
                 if problems:
                     self._recover("pool_corrupt", poisoned=poisoned,
                                   problems=problems)
                     return True
             self._update_ladder()
+        dispatched = self._dispatched
         with obs.span("serving.admit") as sp:
             admitted = self._step_admitted = self._admit()
         obs.histogram_observe("serving.admit.self_seconds", sp.self_s)
@@ -1294,6 +1408,13 @@ class ServingEngine:
             # prefilled rows sit RUNNING until extract_for_handoff moves
             # them to a decode engine
             decoded = False
+        if self._pending is not None and (
+                self._dispatched == dispatched or not self._more_to_dispatch()):
+            # nothing will be enqueued behind the pending step (every row's
+            # last step, a prefill-only engine) or nothing was, this
+            # iteration: the host has nothing to overlap it with
+            self._settle("idle")
+            decoded = True
         with obs.span("serving.housekeeping"):
             # a request that crossed its TTL inside the prefill/decode above
             # is caught here — "mid-step" expiry still releases pages this
@@ -1319,6 +1440,22 @@ class ServingEngine:
             self._note_occupancy()
         return bool(admitted or decoded)
 
+    def _leaving(self, req: GenRequest) -> bool:
+        """Whether the last token `req` is due, by length or by
+        `max_position`, is accepted or in flight: it runs no further step
+        and leaves when the pending one is accepted."""
+        return (req.n_generated + req.in_flight >= req.max_new_tokens
+                or len(req.all_tokens) + req.in_flight
+                >= self.cfg.max_position)
+
+    def _more_to_dispatch(self) -> bool:
+        """Whether the next iteration will (try to) enqueue a program: an
+        admission's prefill, or a decode of rows that go on."""
+        if self._waiting:
+            return True
+        return not self.prefill_only and any(
+            not self._leaving(r) for r in self._running)
+
     def _observe_host_seconds(self, name: str, sp, fetch0: float) -> None:
         """`<name>.host_seconds`: the span's duration less the seconds it
         spent inside `pipeline.fetch` (blocked on the device and copying the
@@ -1327,7 +1464,13 @@ class ServingEngine:
         obs.histogram_observe(name + ".host_seconds", sp.dur_s - waited)
 
     # -- internals ----------------------------------------------------------
+    def _free_slot(self, req: GenRequest) -> None:
+        if req.slot is not None:
+            self._slots_free.append(req.slot)
+            req.slot = None
+
     def _release(self, req: GenRequest) -> None:
+        self._free_slot(req)
         if req.pages:
             self.pool.release(req.pages)
             req.pages = []
@@ -1422,6 +1565,10 @@ class ServingEngine:
         'cancelled'. Returns requests expired."""
         expired = [r for r in self._running + self._waiting
                    if r.deadline_t is not None and now > r.deadline_t]
+        if expired:
+            # what a dispatched step computed for them is theirs to keep
+            self._settle()
+            expired = [r for r in expired if r.state not in _TERMINAL]
         for req in expired:
             self._terminate(req, DEADLINE_EXCEEDED, "deadline_exceeded",
                             extra={"overrun_s":
@@ -1500,27 +1647,18 @@ class ServingEngine:
             self._shed_one()
 
     # -- supervision: retried dispatch, invariant audit, recovery -----------
-    def _dispatch(self, kind: str, target, feed, fetch_list, to_host=None):
-        """Every compiled prefill/decode/window/COW step dispatches here:
+    def _dispatch(self, kind: str, target, feed, fetch_list) -> list:
+        """Every compiled prefill/decode/window/COW step is ENQUEUED here:
         the serving_step_fail fault site, then the executor, under the
-        serving RetryPolicy. Retrying a step is safe — the compiled
-        programs write fixed KV slots derived from the feed, so attempt
-        N+1 overwrites attempt N's partial effects exactly. Retry
-        exhaustion raises _StepFailure; step() turns it into the recovery
-        pass. `to_host` names, fetch by fetch, which results are copied to
-        the host (None for the others, which stay on the device unread);
-        without it every fetch is."""
+        serving RetryPolicy; returns the fetches as device handles, unread.
+        Retrying the enqueue is safe — the compiled programs write fixed KV
+        slots derived from the feed, so attempt N+1 overwrites attempt N's
+        partial effects exactly. Retry exhaustion raises _StepFailure;
+        step() turns it into the recovery pass."""
         def attempt():
             fault_point("serving_step_fail")
-            outs = self._exe.run(target, feed=feed, fetch_list=fetch_list,
-                                 scope=self._scope,
-                                 return_numpy=to_host is None)
-            if to_host is None:
-                return outs
-            with profiler.stage_timer("pipeline.fetch"):
-                # blocks until the device has produced the step
-                return [np.asarray(o) if wanted else None
-                        for o, wanted in zip(outs, to_host)]
+            return self._exe.run(target, feed=feed, fetch_list=fetch_list,
+                                 scope=self._scope, return_numpy=False)
 
         def on_retry(n, exc):
             self._count("step_retries")
@@ -1532,6 +1670,91 @@ class ServingEngine:
             return self._retry.call(attempt, on_retry=on_retry)
         except self._retry.retryable as e:
             raise _StepFailure(kind, e) from e
+
+    def _fetch(self, kind: str, handles) -> list:
+        """Copy a dispatched step's `handles` to the host (None stays None)
+        under `pipeline.fetch`: blocks until the device has produced them.
+        The step cannot be retried from here (the programs enqueued behind
+        it consumed its pools): an error goes to the recovery pass."""
+        try:
+            with profiler.stage_timer("pipeline.fetch"):
+                return [None if h is None else np.asarray(h)
+                        for h in handles]
+        except (RuntimeError,) + self._retry.retryable as e:
+            raise _StepFailure(kind + "_fetch", e) from e
+
+    def _run_step(self, kind: str, target, io: dict, feed: dict,
+                  greedy: bool, logits: str = "logits",
+                  selection: bool = False) -> dict:
+        """Enqueue one prefill / window / decode step; returns its device
+        handles by `_InFlight` field name. One compiled program serves
+        greedy and sampled rows, marked and unmarked: the logits (`[rows,
+        V]` float32) and the selection are device outputs of every step;
+        what no sampler and no marked request will read is let go of here,
+        when the step is enqueued, not held until its accept."""
+        extra = [k for k in ("routes", "selection") if k in io]
+        nxt, lg, *rest = self._dispatch(kind, target, feed,
+                                        self._step_fetches(io, logits))
+        got = dict(zip(extra, rest))
+        return {"tokens": nxt, "logits": None if greedy else lg,
+                "routes": got.get("routes"),
+                "selection": got.get("selection") if selection else None}
+
+    def _enqueued(self, step: _InFlight, blocking: str | None = None) -> None:
+        """`step` has just been enqueued: accept the step dispatched before
+        it, which the device ran meanwhile, and keep `step` as the pending
+        one; or, where `blocking` says why the host needs its values before
+        it dispatches again, accept it at once."""
+        self._dispatched += 1
+        before, self._pending = self._pending, None
+        if before is not None:
+            self._accept(before, None)
+        if step.tokens is not None:
+            for r in step.rows:
+                if r.state == RUNNING:
+                    r.in_flight = 1
+                    if self._leaving(r):
+                        # its last step: nothing reads the slot again
+                        self._free_slot(r)
+        if blocking is not None:
+            self._accept(step, blocking)
+        else:
+            self._pending = step
+
+    def _settle(self, why: str = "settle") -> None:
+        """Accept the pending step now (no-op without one): whoever is about
+        to read or change request or pool state sees every dispatched token
+        accepted."""
+        step, self._pending = self._pending, None
+        if step is not None:
+            with obs.span("serving.settle"):
+                self._accept(step, why)
+
+    def _settle_or_recover(self) -> None:
+        """`_settle` for the entry points outside the supervised step: a
+        fetch that fails runs the recovery pass here."""
+        try:
+            self._settle()
+        except _StepFailure as e:
+            self._recover(f"step_fail:{e.kind}")
+
+    def _accept(self, step: _InFlight, why: str | None) -> None:
+        """Read `step`'s outputs and do what the host does with them: book
+        routes and selections at the positions recorded at dispatch, accept
+        each row's token, release finished rows. `why` None: accepted
+        behind the next dispatch (deferred)."""
+        tokens, logits, routes, selection = self._fetch(
+            step.kind, (step.tokens, step.logits, step.routes,
+                        step.selection))
+        if why is None:
+            self._count("chain.steps_deferred")
+        else:
+            self._count("chain.steps_blocking", labels={"why": why})
+        with obs.span("serving.accept"):
+            if step.kind == "decode":
+                self._accept_decode(step, tokens, logits, routes, selection)
+            else:
+                self._accept_prefill(step, tokens, logits, routes, selection)
 
     def _corrupt_pool(self, hit: int) -> None:
         """The serving_pool_corrupt payload: vandalize ONE piece of
@@ -1561,6 +1784,14 @@ class ServingEngine:
         poisoned_rids): a request whose OWN table is malformed —
         out-of-range or duplicate ordinals — is poisoned, and recovery
         quarantines it instead of replaying it."""
+        self._settle_or_recover()
+        return self._audit_tables()
+
+    def _audit_tables(self) -> tuple[list[str], list[int]]:
+        """`audit_pool` over the tables as they stand. It reads host
+        bookkeeping alone, which a pending step leaves consistent (its rows
+        hold their pages until its accept), so the periodic audit inside a
+        step does not settle."""
         problems: list[str] = []
         poisoned: list[int] = []
         holders: dict[int, int] = {}
@@ -1610,6 +1841,11 @@ class ServingEngine:
         contract); sampled requests re-derive the same tokens through the
         per-(seed, rid, position) rng."""
         self._count("recovery.passes")
+        # what is in flight was computed over the pools being thrown away
+        self._pending = None
+        self._slots_free = list(range(self._token_slots))[::-1]
+        for req in self.requests.values():
+            req.in_flight, req.slot = 0, None
         obs.event("serving.recovery",
                   {"reason": reason, "problems": list(problems)[:8],
                    "quarantined": list(poisoned),
@@ -1660,7 +1896,7 @@ class ServingEngine:
         self.pool.reset()
         if self.window_pool is not None:
             self.window_pool.reset()
-        post, _ = self.audit_pool()
+        post, _ = self._audit_tables()
         if post:
             raise RuntimeError(
                 f"recovery left the pool inconsistent: {post[:4]}")
@@ -1674,7 +1910,11 @@ class ServingEngine:
         bump instead of an allocation."""
         admitted = 0
         for req in self.scheduler.order(self._waiting):
-            if len(self._running) >= self.max_inflight:
+            # a row whose last token is in flight takes no row of the next
+            # step: its place is free now, as it would be had the host
+            # waited for the token
+            staying = sum(not self._leaving(r) for r in self._running)
+            if staying >= self.max_inflight or not self._slots_free:
                 break
             if req.deadline_t is not None \
                     and time.perf_counter() > req.deadline_t:
@@ -1805,47 +2045,37 @@ class ServingEngine:
         return [io["next_token"], io[logits]] + [
             io[k] for k in ("routes", "selection") if k in io]
 
-    def _run_step(self, kind: str, target, io: dict, feed: dict,
-                  greedy: bool, logits: str = "logits",
-                  selection: bool = False) -> tuple:
-        """Dispatch one prefill / window / decode step; returns (next_token,
-        routes or None, logits or None, selection or None) on the host. One
-        compiled program serves greedy and sampled rows, marked and
-        unmarked: the logits (`[rows, V]` float32) and the selection are
-        device outputs of every step and cross the host link only when a
-        sampler, or a marked request, needs them."""
-        extra = [k for k in ("routes", "selection") if k in io]
-        nxt, lg, *rest = self._dispatch(
-            kind, target, feed, self._step_fetches(io, logits),
-            to_host=[True, not greedy] + [k == "routes" or selection
-                                          for k in extra])
-        got = dict(zip(extra, rest))
-        return nxt, got.get("routes"), lg, got.get("selection")
-
     def _count_routed(self, per_expert) -> None:
         for e in np.flatnonzero(per_expert):
             obs.counter_inc("serving.moe.tokens", int(per_expert[e]),
                             {"expert": str(e)})
 
-    def _note_routes(self, req: GenRequest, first: int, routes) -> None:
-        """Keep the experts chosen for `req`'s positions first.. (one row of
-        `routes` [n, layers] each) with the pages that hold their K/V, and
-        count the routed tokens per expert."""
+    def _route_pages(self, req: GenRequest, first: int, n: int):
+        """The page of each of `req`'s positions first .. first + n - 1, as
+        its table stands (None where the block keeps no routes)."""
+        if self._page_routes is None:
+            return None
+        g = first + np.arange(n)
+        return np.asarray(req.pages, np.int64)[g // self.page_size]
+
+    def _note_routes(self, pages, first: int, routes) -> None:
+        """Keep the experts chosen for positions first.. (one row of
+        `routes` [n, layers] each) with the `pages` that hold their K/V
+        (`_route_pages`, read when the step was dispatched), and count the
+        routed tokens per expert."""
         g = first + np.arange(len(routes))
-        pages = np.asarray(req.pages, np.int64)[g // self.page_size]
         self._page_routes[pages, g % self.page_size] = routes
         self._count_routed(np.bincount(routes.ravel(),
                                        minlength=self.cfg.num_experts))
 
-    def _note_decode_routes(self, rows: list, routes) -> None:
-        """One decode step's routes [rows, layers] (inside serving.accept):
-        kept per page as above, counted per expert, and how many distinct
-        experts each layer touched (what a kernel that skipped the others
-        would have to read; today's streams every held expert)."""
-        routes = np.asarray(routes)[:len(rows)]
-        ps = self.page_size
-        pages = [r.pages[r.cache_len // ps] for r in rows]
-        self._page_routes[pages, [r.cache_len % ps for r in rows]] = routes
+    def _note_decode_routes(self, at: list, routes) -> None:
+        """One decode step's routes, a row each for the (position, page)
+        pairs `at` its rows wrote (inside serving.accept): kept per page as
+        above, counted per expert, and how many distinct experts each layer
+        touched (what a kernel that skipped the others would have to read;
+        today's streams every held expert)."""
+        self._page_routes[[page for _, page in at],
+                          [pos % self.page_size for pos, _ in at]] = routes
         L, E = self.cfg.routed_layers, self.cfg.num_experts
         per_layer = np.zeros((L, E), np.int64)
         layer_of = np.arange(L).reshape((1, L) + (1,) * (routes.ndim - 2))
@@ -1907,75 +2137,80 @@ class ServingEngine:
         prefill), suffix (cached_len slots mapped shared — only the suffix
         runs, through the windowed program), full hit (every prompt page
         mapped — NO prefill compute at all; the next decode step re-derives
-        the last prompt slot under copy-on-write and emits token one)."""
+        the last prompt slot under copy-on-write and emits token one).
+
+        The program is enqueued and left pending: the first token is
+        accepted behind the next dispatch (this iteration's decode, which
+        the row joins taking its token from the device, or the next
+        admission's prefill). The prompt's pages are registered with the
+        prefix cache here, when the program that fills them is enqueued:
+        whoever maps them runs behind it on the device, and an arrival
+        admitted in this same iteration finds the hit it would have."""
         n = len(req.all_tokens)
         req.state = RUNNING
         self._mark(req)
+        req.slot = self._slots_free.pop()
         self._running.append(req)
         if req.cached_len >= n:
             self._count("prefix_full_hits")
-            with obs.span("serving.accept"):
-                self._register_prefix(req)
-            return
-        routes = None
-        if self.cfg.prefill_chunk:
-            nxt, lg = self._prefill_chunks(req, n)   # notes its own routes
-        elif req.cached_len > 0:
-            with obs.span("serving.feed_build"):
-                suf = n - req.cached_len
-                sb = self._seq_bucket(suf)
-                pb = _round_up_pow2(max(
-                    len(req.pages), self.pool.pages_for(req.cached_len + sb)))
-                tok = np.zeros((1, sb), np.int32)
-                tok[0, :suf] = req.all_tokens[req.cached_len:]
-                pos = req.cached_len + np.arange(sb, dtype=np.int32)[None, :]
-                pos = np.minimum(pos, self.cfg.max_position - 1)
-                pages = np.zeros((1, pb), np.int32)
-                pages[0, :len(req.pages)] = req.pages
-                feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
-                        sv_model.PAGES_FEED: pages,
-                        sv_model.START_FEED: np.asarray([req.cached_len],
-                                                        np.int32),
-                        sv_model.LEN_FEED: np.asarray([suf], np.int32)}
-            nxt, routes, lg, _ = self._run_step(
-                "suffix_prefill", self._window_run, self._window_io, feed,
-                req.sampling.is_greedy, "last_logits")
-            self.stats["prefill_signatures"].add(("suffix", sb, pb))
-            self._count("prefill_tokens_computed", suf)
-        else:
-            with obs.span("serving.feed_build"):
-                sb = self._seq_bucket(n)
-                pb = max(len(req.pages), self.pool.pages_for(sb))
-                tok = np.zeros((1, sb), np.int32)
-                tok[0, :n] = req.all_tokens
-                pos = np.arange(sb, dtype=np.int32)[None, :]
-                pos = np.minimum(pos, self.cfg.max_position - 1)
-                pages = np.zeros((1, pb), np.int32)
-                pages[0, :len(req.pages)] = req.pages
-                feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
-                        sv_model.PAGES_FEED: pages,
-                        sv_model.LEN_FEED: np.asarray([n], np.int32)}
-            nxt, routes, lg, _ = self._run_step(
-                "prefill", self._prefill_run, self._prefill_io, feed,
-                req.sampling.is_greedy, "last_logits")
-            self.stats["prefill_signatures"].add((sb, pb))
-            self._count("prefill_tokens_computed", n)
-        self._count("prefills")
-        with obs.span("serving.accept"):
-            if routes is not None:
-                self._note_routes(req, req.cached_len,
-                                  np.asarray(routes)[0, :n - req.cached_len])
             self._register_prefix(req)
-            self._accept_token(req, self._first_token(req, nxt, lg))
+            return
+        greedy = req.sampling.is_greedy
+        if not greedy:
+            self._settle("sampled")
+        if self.cfg.prefill_chunk:
+            step = self._prefill_chunks(req, n)
+        else:
+            first = req.cached_len
+            with obs.span("serving.feed_build"):
+                sb = self._seq_bucket(n - first)
+                tok = np.zeros((1, sb), np.int32)
+                tok[0, :n - first] = req.all_tokens[first:]
+                pos = first + np.arange(sb, dtype=np.int32)[None, :]
+                pos = np.minimum(pos, self.cfg.max_position - 1)
+                if first:
+                    pb = _round_up_pow2(max(
+                        len(req.pages), self.pool.pages_for(first + sb)))
+                else:
+                    pb = max(len(req.pages), self.pool.pages_for(sb))
+                pages = np.zeros((1, pb), np.int32)
+                pages[0, :len(req.pages)] = req.pages
+                feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
+                        sv_model.PAGES_FEED: pages,
+                        sv_model.LEN_FEED: np.asarray([n - first], np.int32),
+                        **self._slot_feed((req,), 1)}
+                if first:
+                    feed[sv_model.START_FEED] = np.asarray([first], np.int32)
+            if first:
+                handles = self._run_step(
+                    "suffix_prefill", self._window_run, self._window_io,
+                    feed, greedy, "last_logits")
+                self.stats["prefill_signatures"].add(("suffix", sb, pb))
+            else:
+                handles = self._run_step(
+                    "prefill", self._prefill_run, self._prefill_io, feed,
+                    greedy, "last_logits")
+                self.stats["prefill_signatures"].add((sb, pb))
+            self._count("prefill_tokens_computed", n - first)
+            step = _InFlight("prefill", [req], at=[(first, n - first)],
+                             marked=[],
+                             route_pages=self._route_pages(req, first,
+                                                           n - first),
+                             **handles)
+        self._count("prefills")
+        self._register_prefix(req)
+        self._enqueued(step, None if greedy else "sampled")
 
-    def _prefill_chunks(self, req: GenRequest, n: int) -> tuple:
+    def _prefill_chunks(self, req: GenRequest, n: int) -> _InFlight:
         """Positions cached_len.. of `req` as consecutive windows of
         `cfg.prefill_chunk` tokens through the window program (one
         `serving.prefill.chunk` span each): a window writes its K/V and
         indexer keys into the pool and attends the pool, so the next one
         finds them there. Every chunk runs at the page bucket of the whole
-        request: one compiled program a window length. Returns the last
-        window's (next token, last logits)."""
+        request: one compiled program a window length. Each chunk is a step
+        program like any other: enqueued, and accepted behind the next.
+        Returns the last window, enqueued and not yet handed to
+        `_enqueued`."""
         chunk = self.cfg.prefill_chunk
         pb = self._page_bucket(len(req.pages))
         pages = np.zeros((1, pb), np.int32)
@@ -2000,25 +2235,39 @@ class ServingEngine:
                             sv_model.PAGES_FEED: pages,
                             sv_model.START_FEED: np.asarray([c0], np.int32),
                             sv_model.LEN_FEED: np.asarray([m], np.int32),
+                            **self._slot_feed((req,), 1),
                             **self._window_feed((req,), 1,
                                                 self._wtable_chunk)}
-                nxt, routes, lg, sel = self._run_step(
+                handles = self._run_step(
                     "prefill_chunk", self._window_run, self._window_io,
                     feed, req.sampling.is_greedy, "last_logits",
                     selection=req.marked)
                 self.stats["prefill_signatures"].add(("suffix", sb, pb))
                 self._count("prefill_tokens_computed", m)
                 self._count("prefill.chunks")
-                with obs.span("serving.accept"):
-                    if routes is not None:
-                        self._note_routes(req, c0, np.asarray(routes)[0, :m])
-                    if req.marked:
-                        self._keep_selection(req, sel[0, :m])
-                    if self.window_pool is not None and c0 + m < n:
+                step = _InFlight("chunk", [req], at=[(c0, m)], marked=[],
+                                 route_pages=self._route_pages(req, c0, m),
+                                 **handles)
+                if c0 + m < n:
+                    # only the last window's token is the prompt's next
+                    step.tokens = step.logits = None
+                    if self.window_pool is not None:
                         # before the next chunk lets go of window pages,
                         # the cache takes its reference on them
                         self._register_prefix(req, c0 + m)
-        return nxt, lg
+                    self._enqueued(step)
+        return step
+
+    def _accept_prefill(self, step: _InFlight, tokens, logits, routes,
+                        selection) -> None:
+        """A prefill's (or one chunk's) outputs, inside serving.accept."""
+        req, (first, n) = step.rows[0], step.at[0]
+        if routes is not None:
+            self._note_routes(step.route_pages, first, routes[0, :n])
+        if selection is not None:
+            self._keep_selection(req, selection[0, :n])
+        if tokens is not None:
+            self._accept_token(req, self._first_token(req, tokens, logits))
 
     def _register_prefix(self, req: GenRequest, upto: int | None = None
                          ) -> None:
@@ -2038,6 +2287,7 @@ class ServingEngine:
                 {req.wfirst + j: p for j, p in enumerate(req.wpages)})
 
     def _accept_token(self, req: GenRequest, tok: int) -> None:
+        req.in_flight = 0
         req.all_tokens.append(tok)
         now = time.perf_counter()
         if req.t_first_token is None:
@@ -2076,6 +2326,12 @@ class ServingEngine:
         device step (the side with nothing to copy is given page 0 onto
         itself). Returns False when the pool pressure this created
         preempted `req` itself."""
+        if self._page_routes is not None:
+            # the page's routes are copied below: those of a pending step
+            # that computed positions in it must be booked first
+            self._settle()
+            if req.state != RUNNING:
+                return False
         windowed = self._window_shared(req, ordinal)
         # the caller's page is copied whoever maps it, unless the call is
         # for the window pool's page alone
@@ -2108,21 +2364,31 @@ class ServingEngine:
         return True
 
     def _take_or_preempt(self, req: GenRequest, allocate):
-        """`allocate(1)`, the youngest running request preempted while it
-        gives None; None once that was `req` itself."""
+        """`allocate(1)`, room made (`_make_room`) while it gives None; None
+        once `req` itself is no longer running."""
         new = allocate(1)
         while new is None:
-            victim = max(self._running, key=lambda r: r.admit_seq)
-            if victim is req and len(self._running) == 1:
-                raise RuntimeError(
-                    f"request {req.rid} needs a page but its pool is "
-                    f"exhausted with nothing left to preempt (the pool has "
-                    f"{self.pool.num_pages} pages)")
-            self._preempt(victim)
-            if victim is req:
+            if not self._make_room(req):
                 return None
             new = allocate(1)
         return new
+
+    def _make_room(self, req: GenRequest) -> bool:
+        """A pool ran dry under `req`: accept the pending step if there is
+        one (rows that finish return their pages, and nobody is preempted
+        with a token in flight), else preempt the youngest running request.
+        False once `req` itself is no longer running."""
+        if self._pending is not None:
+            self._settle()
+            return req.state == RUNNING
+        victim = max(self._running, key=lambda r: r.admit_seq)
+        if victim is req and len(self._running) == 1:
+            raise RuntimeError(
+                f"request {req.rid} needs a page but its pool is "
+                f"exhausted with nothing left to preempt (the pool has "
+                f"{self.pool.num_pages} pages)")
+        self._preempt(victim)
+        return victim is not req
 
     def _window_shared(self, req: GenRequest, ordinal: int) -> bool:
         """Whether logical page `ordinal` of `req` is a page of the window
@@ -2145,7 +2411,8 @@ class ServingEngine:
         ps = self.page_size
         granted: dict[int, int] = {}
         for req in list(self._running):
-            if req.state != RUNNING:
+            # a row whose last token is in flight writes nothing more
+            if req.state != RUNNING or self._leaving(req):
                 continue
             extra = lookahead
             while (req.cache_len + extra) // ps >= len(req.pages):
@@ -2156,15 +2423,7 @@ class ServingEngine:
                 if extra > 0:
                     extra -= 1
                     continue
-                victim = max(self._running, key=lambda r: r.admit_seq)
-                if victim is req and len(self._running) == 1:
-                    raise RuntimeError(
-                        f"request {req.rid} needs page "
-                        f"{len(req.pages) + 1} but the pool "
-                        f"({self.pool.num_pages} pages) is exhausted with "
-                        f"nothing left to preempt")
-                self._preempt(victim)
-                if victim is req:
+                if not self._make_room(req):
                     break
             if req.state != RUNNING:
                 continue
@@ -2187,6 +2446,9 @@ class ServingEngine:
         return granted
 
     def _preempt(self, req: GenRequest) -> None:
+        """Back to the head of the waiting queue, pages returned. Callers
+        settle the pending step first (`_make_room`): a row is never
+        preempted with a token in flight."""
         self._running.remove(req)
         self._release(req)
         req.state = WAITING
@@ -2220,16 +2482,25 @@ class ServingEngine:
         return steps
 
     def _decode_once(self, sp) -> bool:
-        """One decode step under the open `serving.decode` span `sp`."""
+        """One decode step under the open `serving.decode` span `sp`:
+        enqueued over the running rows that go on, each taking from the
+        device the token the host has not read yet; the step dispatched
+        before it is accepted behind it."""
         # ladder rung 1+ falls back to plain one-token decode: the verify
         # window is the most speculative compute in the engine, so it is
         # the first thing sustained overload switches off
         if self.draft_k > 0 and self._ladder_rung < 1:
+            self._settle("spec")        # the draft reads host history
             return self._decode_spec(sp)
+        greedy = all(r.sampling.is_greedy for r in self._running)
+        if not greedy:
+            self._settle("sampled")     # the sampler reads host logits
         self._ensure_writable(0)
-        rows = [r for r in self._running if r.state == RUNNING]
+        rows = [r for r in self._running
+                if r.state == RUNNING and not self._leaving(r)]
         if not rows:
             return False
+        ps = self.page_size
         with obs.span("serving.feed_build"):
             bb = self._row_bucket(len(rows))
             pb = self._page_bucket(max(len(r.pages) for r in rows))
@@ -2238,7 +2509,7 @@ class ServingEngine:
             pages = np.zeros((bb, pb), np.int32)
             mask = np.zeros((bb, 1), np.float32)
             for i, r in enumerate(rows):
-                tok[i, 0] = r.all_tokens[-1]
+                tok[i, 0] = r.all_tokens[-1]    # unread where one is in flight
                 pos[i] = r.cache_len
                 pages[i, :len(r.pages)] = r.pages
                 mask[i, 0] = 1.0
@@ -2246,11 +2517,15 @@ class ServingEngine:
             feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
                     sv_model.PAGES_FEED: pages, sv_model.MASK_FEED: mask,
                     **self._mark_feed(marked),
+                    **self._slot_feed(rows, bb, decode=True),
                     **self._window_feed(rows, bb, self._wtable_decode)}
+            at = [(r.cache_len, r.pages[r.cache_len // ps]) for r in rows]
         self._step_rows = len(rows)
         sp.note(rows=len(rows), bb=bb, pb=pb)
+        self._count("decode_steps")
+        self.stats["decode_signatures"].add((bb, pb))
         self._count("decode_context_pages",
-                    sum(r.cache_len // self.page_size + 1 for r in rows))
+                    sum(pos // ps + 1 for pos, _ in at))
         self._count("decode_grid_steps", self._decode_grid_steps(bb, pb))
         if self.window_pool is not None:
             full, slide = self._full_layers, self._slide_layers
@@ -2259,46 +2534,57 @@ class ServingEngine:
             self._count("kv.window_row_pages",
                         sum(len(r.wpages) for r in rows))
             self._count("attn.full_context_tokens",
-                        full * sum(r.cache_len + 1 for r in rows))
+                        full * sum(pos + 1 for pos, _ in at))
             self._count("attn.window_context_tokens",
-                        slide * sum(min(W, r.cache_len + 1) for r in rows))
+                        slide * sum(min(W, pos + 1) for pos, _ in at))
             self._count("attn.full_layer_steps", full)
             self._count("attn.window_layer_steps", slide)
-        if self.cfg.selects_within(pb * self.page_size):
+        if self.cfg.selects_within(pb * ps):
             L, k = self.cfg.num_layers, self.cfg.index_topk
             self._count("sparse.context_tokens",
-                        L * sum(r.cache_len + 1 for r in rows))
+                        L * sum(pos + 1 for pos, _ in at))
             self._count("sparse.selected_tokens",
-                        L * sum(min(k, r.cache_len + 1) for r in rows))
+                        L * sum(min(k, pos + 1) for pos, _ in at))
             self._count("sparse.layer_steps", L)
-        nxt, routes, lg, sel = self._run_step(
-            "decode", self._decode_run, self._decode_io, feed,
-            all(r.sampling.is_greedy for r in rows),
-            selection=bool(marked))
-        with obs.span("serving.accept"):
-            nxt = np.asarray(nxt).reshape(-1)
-            self._count("decode_steps")
-            self.stats["decode_signatures"].add((bb, pb))
-            if routes is not None:
-                self._note_decode_routes(rows, routes)
-            for j, i in enumerate(marked):
-                self._keep_selection(rows[i], sel[j][None])
-            for i, r in enumerate(rows):
-                if r.sampling.is_greedy:
-                    t = int(nxt[i])
-                else:
-                    rng = request_rng(self.seed, r.rid, r.n_generated)
-                    t = sample_token(lg[i], r.sampling, rng)
-                self._count("decode_tokens")
-                self._accept_token(r, t)
+        handles = self._run_step("decode", self._decode_run, self._decode_io,
+                                 feed, greedy, selection=bool(marked))
+        self._enqueued(_InFlight("decode", rows, at=at, marked=marked,
+                                 **handles),
+                       None if greedy else "sampled")
         return True
+
+    def _accept_decode(self, step: _InFlight, tokens, logits, routes,
+                       selection) -> None:
+        """A decode step's outputs, inside serving.accept. A row that
+        stopped on `eos_id` while this step was in flight ran it for
+        nothing: its output is dropped."""
+        live = [i for i, r in enumerate(step.rows) if r.state == RUNNING]
+        self._count("chain.discarded_rows", len(step.rows) - len(live))
+        tokens = tokens.reshape(-1)
+        if routes is not None and live:
+            self._note_decode_routes([step.at[i] for i in live],
+                                     routes[live])
+        for j, i in enumerate(step.marked):
+            if step.rows[i].state == RUNNING:
+                self._keep_selection(step.rows[i], selection[j][None])
+        for i in live:
+            r = step.rows[i]
+            if r.sampling.is_greedy:
+                t = int(tokens[i])
+            else:
+                rng = request_rng(self.seed, r.rid, r.n_generated)
+                t = sample_token(logits[i], r.sampling, rng)
+            self._count("decode_tokens")
+            self._accept_token(r, t)
 
     def _decode_spec(self, sp) -> bool:
         """One draft-verify window step: propose k tokens per row
         (ngram_draft over the row's own history), run all k+1 positions
         through the windowed program in ONE compiled step, and accept the
         verify's greedy tokens up to the first draft mismatch — bitwise the
-        plain greedy sequence, 1..k+1 tokens per step."""
+        plain greedy sequence, 1..k+1 tokens per step. The draft reads each
+        row's accepted history, so the step before is settled (the caller)
+        and this one is read as soon as it is enqueued."""
         k = self.draft_k
         S = k + 1
         granted = self._ensure_writable(k)
@@ -2333,19 +2619,21 @@ class ServingEngine:
                 lens[i] = n_valid
             feed = {sv_model.TOK_FEED: tok, sv_model.POS_FEED: pos,
                     sv_model.PAGES_FEED: pages, sv_model.START_FEED: start,
-                    sv_model.LEN_FEED: lens}
+                    sv_model.LEN_FEED: lens, **self._slot_feed((), bb)}
         self._step_rows = len(rows)
         sp.note(rows=len(rows), bb=bb, pb=pb)
         toks, lg = self._dispatch(
             "verify_window", self._window_run, feed,
             [self._window_io["tokens"], self._window_io["logits"]])
+        if all(r.sampling.is_greedy for r, _, _ in plans):
+            lg = None
+        toks, lg = self._fetch("verify_window", (toks, lg))
+        self._dispatched += 1
+        self._count("chain.steps_blocking", labels={"why": "spec"})
         with obs.span("serving.accept"):
-            toks = np.asarray(toks)
             self._count("decode_steps")
             self._count("spec_steps")
             self.stats["decode_signatures"].add((bb, pb))
-            lg = None if all(r.sampling.is_greedy for r, _, _ in plans) \
-                else np.asarray(lg)
             for i, (r, n_valid, drafts) in enumerate(plans):
                 if not r.sampling.is_greedy:
                     # sampling rows take exactly one (seeded) token per

@@ -109,6 +109,15 @@ COW_WSRC_FEED = "sv_cow_wsrc"   # copy-on-write in the sliding layers' pool
 COW_WDST_FEED = "sv_cow_wdst"
 # how many rows of a decode step can have their selection handed back
 MARK_ROWS = 8
+# the token each row slot's latest step emitted, kept on the device: the one
+# value a decode step needs of the step before it. Every step program writes
+# the tokens it emits to its rows' slots (`sv_slot` [B]); a decode row takes
+# its input token from its slot where `sv_from_host` [B, 1] is 0, so the
+# host dispatches step n+1 before it has read step n. int32 [slots + 1]:
+# the last entry is where padding rows write and nobody reads.
+LAST_TOKEN = "serving.last_token"
+SLOT_FEED = "sv_slot"
+FROM_HOST_FEED = "sv_from_host"
 
 
 @dataclass
@@ -910,21 +919,43 @@ def _second_pool(window_pages: int) -> dict:
     return {"window_pages": int(window_pages)} if window_pages else {}
 
 
+def _last_token_state(last_token: str, token_slots: int):
+    """Declare the `LAST_TOKEN` state (under the engine's name for it) and
+    the feed that names each row's slot in it."""
+    default_main_program().global_block.create_var(
+        name=last_token, shape=[int(token_slots) + 1], dtype="int32",
+        persistable=True, stop_gradient=True)
+    return L.data(name=SLOT_FEED, shape=[], dtype="int32")
+
+
+def _keep_last_token(io: dict, last_token: str, slot) -> dict:
+    """Append the write of the step's `next_token` to its rows' slots; the
+    family's outputs with the slot feed among its feeds."""
+    LayerHelper("last_token_write").append_op(
+        "last_token_write",
+        {"Last": [last_token], "Slot": [slot], "Next": [io["next_token"]]},
+        {"LastOut": [last_token]}, {})
+    return dict(io, extra_feeds=io.get("extra_feeds", []) + [SLOT_FEED])
+
+
 def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int,
-                          window_pages: int = 0):
+                          window_pages: int = 0, token_slots: int = 1,
+                          last_token: str = LAST_TOKEN):
     """Build (in the current default main program) the bucketed prefill.
 
     Feeds: sv_tok/sv_pos [B, S_bucket] int32, sv_pages [B, P] int32,
     sv_len [B] int32 (real prompt lengths — bucket padding past them is
     never written to the cache and, thanks to causal masking, never read by
-    a real position). Fetch: next token ids [B] (greedy)."""
+    a real position), sv_slot [B] int32 (where the next token is kept on
+    the device, `LAST_TOKEN`). Fetch: next token ids [B] (greedy)."""
     tok = L.data(name=TOK_FEED, shape=[cfg.max_position], dtype="int32")
     pos = L.data(name=POS_FEED, shape=[cfg.max_position], dtype="int32")
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     lens = L.data(name=LEN_FEED, shape=[], dtype="int32")
-    return _with_feeds(_FAMILY[cfg.block]["prefill"](
+    slot = _last_token_state(last_token, token_slots)
+    return _with_feeds(_keep_last_token(_FAMILY[cfg.block]["prefill"](
         cfg, num_pages, page_size, tok, pos, pages, lens,
-        **_second_pool(window_pages)),
+        **_second_pool(window_pages)), last_token, slot),
         [TOK_FEED, POS_FEED, PAGES_FEED, LEN_FEED])
 
 
@@ -988,7 +1019,9 @@ def _window_layer(x, i, cfg: DecoderConfig, pages, start, lens, tp: int):
 
 
 def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
-                         tp: int = 1, window_pages: int = 0):
+                         tp: int = 1, window_pages: int = 0,
+                         token_slots: int = 1,
+                         last_token: str = LAST_TOKEN):
     """Build (in the current default main program) the windowed forward the
     two ISSUE 11 stages share:
 
@@ -1004,7 +1037,8 @@ def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
 
     Feeds: sv_tok/sv_pos [B, S] int32, sv_pages [B, P] int32, sv_start [B]
     int32 (global slot of window position 0), sv_len [B] int32 (valid LOCAL
-    window positions; 0 = padded row, writes nothing). Fetches:
+    window positions; 0 = padded row, writes nothing), sv_slot [B] int32
+    (where `next_token` is kept on the device, `LAST_TOKEN`). Fetches:
     `next_token` [B] (greedy token after local position Lens-1 — the suffix
     prefill's output), `tokens` [B, S] (greedy token after every window
     position — the verify output), `logits` [B, S, V] (the sampling
@@ -1014,9 +1048,10 @@ def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     start = L.data(name=START_FEED, shape=[], dtype="int32")
     lens = L.data(name=LEN_FEED, shape=[], dtype="int32")
-    return _with_feeds(_FAMILY[cfg.block]["window"](
+    slot = _last_token_state(last_token, token_slots)
+    return _with_feeds(_keep_last_token(_FAMILY[cfg.block]["window"](
         cfg, num_pages, page_size, tp, tok, pos, pages, start, lens,
-        **_second_pool(window_pages)),
+        **_second_pool(window_pages)), last_token, slot),
         [TOK_FEED, POS_FEED, PAGES_FEED, START_FEED, LEN_FEED])
 
 
@@ -1082,22 +1117,34 @@ def _post_ln_cow(cfg, num_pages, page_size, src, dst):
 
 
 def build_decode_program(cfg: DecoderConfig, num_pages: int, page_size: int,
-                         tp: int = 1, window_pages: int = 0):
+                         tp: int = 1, window_pages: int = 0,
+                         token_slots: int = 1,
+                         last_token: str = LAST_TOKEN):
     """Build (in the current default main program) the ragged decode step.
 
     Feeds: sv_tok [B, 1] int32 (each row's latest token), sv_pos [B] int32
     (the slot that token occupies — the row's context length so far),
     sv_pages [B, P] int32, batch_mask [B, 1] float32 (0 rows are scheduler
-    padding: their KV write is dropped and their output token ignored).
-    Fetch: next token ids [B]."""
+    padding: their KV write is dropped and their output token ignored),
+    sv_slot [B] int32 and sv_from_host [B, 1] int32: a row whose
+    sv_from_host is 0 takes its token from `LAST_TOKEN[sv_slot]`, where the
+    step before left it, not from sv_tok; every row's next token is kept
+    there in turn. Fetch: next token ids [B]."""
     tok = L.data(name=TOK_FEED, shape=[], dtype="int32")
     pos = L.data(name=POS_FEED, shape=[], dtype="int32")
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     mask = L.data(name=MASK_FEED, shape=[1], dtype="float32")
-    return _with_feeds(_FAMILY[cfg.block]["decode"](
-        cfg, num_pages, page_size, tp, tok, pos, pages, mask,
-        **_second_pool(window_pages)),
-        [TOK_FEED, POS_FEED, PAGES_FEED, MASK_FEED])
+    slot = _last_token_state(last_token, token_slots)
+    from_host = L.data(name=FROM_HOST_FEED, shape=[1], dtype="int32")
+    helper = LayerHelper("last_token_select")
+    chained = helper.create_variable_for_type_inference("int32")
+    helper.append_op("last_token_select",
+                     {"Tok": [tok], "FromHost": [from_host], "Slot": [slot],
+                      "Last": [last_token]}, {"Out": [chained]}, {})
+    return _with_feeds(_keep_last_token(_FAMILY[cfg.block]["decode"](
+        cfg, num_pages, page_size, tp, chained, pos, pages, mask,
+        **_second_pool(window_pages)), last_token, slot),
+        [TOK_FEED, POS_FEED, PAGES_FEED, MASK_FEED, FROM_HOST_FEED])
 
 
 def _cca_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):

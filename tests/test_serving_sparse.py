@@ -312,14 +312,14 @@ def test_a_miscounted_mask_fails_by_select_margin(fault, monkeypatch):
 def test_only_marked_requests_bring_their_selection_to_the_host(monkeypatch):
     eng = _engine()
     fetched = []
-    dispatch = eng._dispatch
+    fetch = eng._fetch
 
-    def spy(kind, target, feed, fetch_list, to_host=None):
-        outs = dispatch(kind, target, feed, fetch_list, to_host=to_host)
+    def spy(kind, handles):
+        outs = fetch(kind, handles)
         fetched.append([None if o is None else o.shape for o in outs])
         return outs
 
-    monkeypatch.setattr(eng, "_dispatch", spy)
+    monkeypatch.setattr(eng, "_fetch", spy)
     prompts = _prompts(9, 30, 30)
     rids = [eng.submit(p, 4, keep_selection=keep)
             for p, keep in zip(prompts, (True, False))]
@@ -472,8 +472,8 @@ def test_decode_step_serves_the_sorted_forms_logits_and_words(monkeypatch):
         def to_host(kind, target, io, feed, greedy, *args, **kw):
             out = run_step(eng, kind, target, io, feed, False, *args, **kw)
             if kind == "decode":
-                logits.append(np.asarray(out[2]))
-            return out
+                logits.append(np.asarray(out["logits"]))
+            return dict(out, logits=None)
 
         eng._run_step = to_host
         done = _serve(eng, prompts, new=6)

@@ -173,14 +173,14 @@ def test_greedy_steps_fetch_no_vocabulary_wide_array(family, monkeypatch):
            else sv_model.decoder_tiny())
     eng = _engine(cfg)
     fetched = []
-    dispatch = eng._dispatch
+    fetch = eng._fetch
 
-    def spy(kind, target, feed, fetch_list, to_host=None):
-        outs = dispatch(kind, target, feed, fetch_list, to_host=to_host)
+    def spy(kind, handles):
+        outs = fetch(kind, handles)
         fetched.extend(o.shape for o in outs if o is not None)
         return outs
 
-    monkeypatch.setattr(eng, "_dispatch", spy)
+    monkeypatch.setattr(eng, "_fetch", spy)
     eng.warmup_decode(12)
     greedy, sampled = _prompts(9, 5, 5)
     _serve(eng, [greedy])

@@ -201,7 +201,7 @@ def serving_program_cases(engine, rows: int = 64, pages: int = 32,
     (suffix prefill) of the same bucket behind `pages` pages, and
     copy-on-write of page 0 onto itself. Every row is masked or of length
     0, so a run writes nothing. Feeds and fetches are the engine's own
-    (`_mark_feed`, `_window_feed`: the compact tables of a second,
+    (`_mark_feed`, `_slot_feed`, `_window_feed`: the compact tables of a second,
     sliding-window pool where the family has one, `_step_fetches`): the
     signature it serves with."""
     from paddle_tpu.serving import model as m
@@ -213,14 +213,14 @@ def serving_program_cases(engine, rows: int = 64, pages: int = 32,
             m.POS_FEED: np.zeros((rows,), i32),
             m.PAGES_FEED: np.zeros((rows, pages), i32),
             m.MASK_FEED: np.zeros((rows, 1), np.float32),
-            **e._mark_feed(),
+            **e._mark_feed(), **e._slot_feed((), rows, decode=True),
             **e._window_feed((), rows, e._wtable_decode)},
             e._step_fetches(e._decode_io)),
         "prefill": (e._prefill_run, {
             m.TOK_FEED: np.zeros((1, prompt), i32),
             m.POS_FEED: np.zeros((1, prompt), i32),
             m.PAGES_FEED: np.zeros((1, e.pool.pages_for(prompt)), i32),
-            m.LEN_FEED: np.zeros((1,), i32),
+            m.LEN_FEED: np.zeros((1,), i32), **e._slot_feed((), 1),
             **e._window_feed((), 1, e._wtable_chunk)},
             e._step_fetches(e._prefill_io, "last_logits")),
         "window": (e._window_run, {
@@ -228,7 +228,7 @@ def serving_program_cases(engine, rows: int = 64, pages: int = 32,
             m.POS_FEED: np.zeros((1, prompt), i32),
             m.PAGES_FEED: np.zeros((1, pages), i32),
             m.START_FEED: np.zeros((1,), i32),
-            m.LEN_FEED: np.zeros((1,), i32),
+            m.LEN_FEED: np.zeros((1,), i32), **e._slot_feed((), 1),
             **e._window_feed((), 1, e._wtable_chunk)},
             e._step_fetches(e._window_io, "last_logits")),
         # a family with a second pool copies a page of each
