@@ -108,6 +108,32 @@ class StackedNormal(Normal):
         )
 
 
+class BlockedNormal(Normal):
+    """Normal for a matrix `[..., R, W]` too large to draw at once and,
+    with `columns` ((width, scale) pairs that add up to W), of another
+    scale a block of columns: the startup op draws `block_rows` rows at a
+    time (all R by default) and multiplies the columns by their scales
+    (`scale` where there are no `columns`)."""
+
+    def __init__(self, scale=1.0, columns=None, block_rows=None, seed=0):
+        super().__init__(0.0, scale, seed)
+        self.columns = columns
+        self.block_rows = block_rows
+
+    def __call__(self, var, block):
+        widths, scales = zip(*(self.columns
+                               or [(var.shape[-1], self.scale)]))
+        block.append_op(
+            "blocked_gaussian_random",
+            outputs={"Out": [var.name]},
+            attrs={"shape": list(var.shape), "dtype": var.dtype.value,
+                   "col_widths": [int(w) for w in widths],
+                   "col_scales": [float(v) for v in scales],
+                   "block_rows": int(self.block_rows or var.shape[-2]),
+                   "seed": self.seed},
+        )
+
+
 class TruncatedNormal(Initializer):
     def __init__(self, loc=0.0, scale=1.0, seed=0):
         self.loc, self.scale, self.seed = loc, scale, seed

@@ -301,10 +301,12 @@ DECLARED: list[tuple] = [
      "/ router epoch tick)", ()),
     # -- per-sequence state rows and expert routing (ISSUE 25) --------------
     ("serving.state.restores", COUNTER,
-     "prefix hits that resumed from a page's state row", ()),
+     "prefix hits that resumed from a page's state row, or from a "
+     "snapshot copied into the row's slot", ()),
     ("serving.state.recomputed_tokens", COUNTER,
      "cached prompt tokens re-run because a hit was cut back to the last "
-     "whole page before the prompt's last token", ()),
+     "whole page before the prompt's last token, or to the deepest block "
+     "whose snapshot is held", ()),
     ("serving.moe.tokens", COUNTER,
      "tokens routed to an expert, summed over layers (prefill and decode)",
      ("expert",)),
@@ -367,6 +369,36 @@ DECLARED: list[tuple] = [
     ("serving.attn.window_layer_steps", COUNTER,
      "sliding-window layer x decode-step pairs: the calls of the paged "
      "decode kernel with a first live slot", ()),
+    # -- a recurrent state in slots, snapshots in the prefix cache
+    #    (ISSUE 37) ------------------------------------------------------
+    ("serving.state.snapshot.seconds", HISTOGRAM,
+     "enqueueing the copy of a row's slot of recurrent state into a "
+     "snapshot slot of the prefix cache's, at a prefill chunk's end "
+     "(under serving.prefill.chunk)", ()),
+    ("serving.state.restore.seconds", HISTOGRAM,
+     "enqueueing the copy of a snapshot into a resumed row's own slot "
+     "(under serving.prefill)", ()),
+    ("serving.state.snapshots", COUNTER,
+     "snapshots of a recurrent state taken and hung on a cached block", ()),
+    ("serving.state.snapshot_evictions", COUNTER,
+     "snapshots the prefix cache gave up: the least recently resumed for "
+     "a slot someone needed, or with their block's pages", ()),
+    ("serving.state.live_slots", GAUGE,
+     "slots of the state pools that running rows own", ()),
+    ("serving.state.snapshot_slots", GAUGE,
+     "slots of the state pools that the prefix cache holds snapshots in",
+     ()),
+    ("serving.ssm.decode_row_layers", COUNTER,
+     "row x layer pairs whose recurrent state a decode step updated, "
+     "summed over steps (x a slot's bytes, read and written: what the "
+     "update had to move)", ()),
+    ("serving.ssm.decode_layer_steps", COUNTER,
+     "layer x decode-step pairs: the calls of the one-token state update",
+     ()),
+    ("serving.ssm.scan_tokens", COUNTER,
+     "real token x layer pairs that prefill windows scanned", ()),
+    ("serving.ssm.scan_layer_steps", COUNTER,
+     "layer x window pairs: the calls of the chunked scan", ()),
     ("serving.control.rewarmups", COUNTER,
      "warmup_decode re-runs forced by an adopted bucket-geometry change "
      "(keeps XLA compiles off the serving path)", ()),
@@ -467,6 +499,7 @@ PROGRAM_NAMES = frozenset({
     "serving_prefill",   # a cold prompt from position 0
     "serving_window",    # suffix windows and chunks behind a cached prefix
     "serving_cow",       # copy-on-write of one page
+    "serving_state_copy",  # one slot of recurrent state onto another
     "train_step",        # Optimizer.minimize: forward, backward, updates
 })
 
@@ -487,6 +520,10 @@ PIECES = frozenset({
     "experts",    # the routed experts
     "dense_ffn",  # a shared expert, a dense layer
     "state",      # cca_moe: the state rows read and written
+    "conv",       # parallel_ssm: the depthwise convolution and its tail
+    "ssm_update", # parallel_ssm: a decode token's state update, in place
+    "ssm_scan",   # parallel_ssm: a window's chunked scan from its slot
+    "mlp",        # parallel_ssm: the layer's SwiGLU
     "head",       # final norm and the vocabulary product
 })
 

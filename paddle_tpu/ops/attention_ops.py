@@ -383,12 +383,30 @@ def _paged_attention_reference(q, k_pool, v_pool, page_table, kv_lens,
     return out.reshape(B, nh, dh).astype(q.dtype)
 
 
+def _padded_group_heads(nh: int, dh: int, width: int) -> int:
+    """The query heads a grouped-query call runs the Pallas kernel with:
+    `nh`, or, where `nh` fills no whole sublane tiles of 8, the heads of
+    the smallest larger group that does (20 heads over 4 KV heads, groups
+    of 5, run as 24, groups of 6: one dead query row a group, computed and
+    dropped; the K/V bytes, which are the cost, do not change)."""
+    nkv = width // dh if dh else 0
+    if nh % 8 == 0 or not nkv or nkv >= nh or nh % nkv or nkv * dh != width:
+        return nh
+    group = nh // nkv
+    while nkv * group % 8:
+        group += 1
+    return nkv * group
+
+
 def _paged_arm(q_shape, q_dtype, pool_shape, pool_dtype, bucket_pages, tp):
     """(decided backend, the per-shard pool shape the Pallas kernel runs on
     or None where the XLA gather serves the shape). Executability is
     re-checked at the SAME per-shard shapes the decision saw (under tp > 1
-    the global q/pool head counts are not what a shard runs)."""
+    the global q/pool head counts are not what a shard runs), a
+    grouped-query call's heads as `_padded_group_heads` pads them."""
     B, nh, dh = q_shape
+    nh = _padded_group_heads(nh, dh, pool_shape[2])
+    q_shape = (B, nh, dh)
     backend, _tier = paged_attention_backend(
         B, nh, bucket_pages * pool_shape[1], dh, q_dtype,
         pool_shape=pool_shape, tp=tp, pool_dtype=pool_dtype)
@@ -428,9 +446,20 @@ def paged_decode_attention_fn(q, k_pool, v_pool, page_table, kv_lens,
         from .pallas_kernels import paged_attention as ppa
 
         _note_dispatch("paged", backend, backend)
-        return ppa.paged_decode_attention(q, k_pool, v_pool, page_table,
-                                          kv_lens, sm_scale=float(sm_scale),
-                                          first_live=first_live)
+        B, nh, dh = q.shape
+        padded = _padded_group_heads(nh, dh, k_pool.shape[2])
+        if padded != nh:
+            nkv = k_pool.shape[2] // dh
+            q = jnp.pad(q.reshape(B, nkv, nh // nkv, dh),
+                        ((0, 0), (0, 0), (0, (padded - nh) // nkv), (0, 0))
+                        ).reshape(B, padded, dh)
+        out = ppa.paged_decode_attention(q, k_pool, v_pool, page_table,
+                                         kv_lens, sm_scale=float(sm_scale),
+                                         first_live=first_live)
+        if padded != nh:
+            out = out.reshape(B, nkv, padded // nkv, dh)[
+                :, :, :nh // nkv].reshape(B, nh, dh)
+        return out
     _note_dispatch("paged", backend, "xla")
     return _paged_attention_reference(q, k_pool, v_pool, page_table, kv_lens,
                                       sm_scale, first_live)

@@ -50,6 +50,7 @@ import collections
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .attention_ops import (_DROP_PAGE, _write_rows, grouped_query_attention,
                             kv_cache_append_fn, paged_decode_attention_fn,
@@ -486,3 +487,31 @@ def stacked_gaussian_random(ctx: ExecContext):
     return {"Out": jax.lax.map(
         lambda k: (jax.random.normal(k, shape[1:], _F32) * std
                    + mean).astype(dtype), keys)}
+
+
+@register_op("blocked_gaussian_random", grad="none", needs_rng=True)
+def blocked_gaussian_random(ctx: ExecContext):
+    """`stacked_gaussian_random` for a matrix `[..., R, W]`: zero-mean
+    normals drawn `block_rows` rows at a time (`lax.map`, the device's
+    `rbg` generator), the columns in blocks of `col_widths` times
+    `col_scales`."""
+    shape = tuple(ctx.attr("shape"))
+    dtype = jnp.dtype(ctx.attr("dtype", "float32"))
+    rows = int(ctx.attr("block_rows"))
+    W = shape[-1]
+    scale = jnp.concatenate([
+        jnp.full((int(n),), float(v), _F32) for n, v in zip(
+            ctx.attr("col_widths"), ctx.attr("col_scales"))])
+    if scale.shape[0] != W or shape[-2] % rows:
+        raise ValueError("blocked_gaussian_random: col_widths must add up "
+                         "to the last dimension and block_rows divide the "
+                         "one before it")
+    blocks = int(np.prod(shape[:-1])) // rows
+    data = jax.random.key_data(ctx.rng).astype(jnp.uint32).reshape(-1)[:2]
+    keys = jax.random.split(
+        jax.random.wrap_key_data(jnp.concatenate([data, data]), impl="rbg"),
+        blocks)
+    out = jax.lax.map(
+        lambda k: (jax.random.normal(k, (rows, W), _F32) * scale
+                   ).astype(dtype), keys)
+    return {"Out": out.reshape(shape)}

@@ -1,0 +1,153 @@
+"""One decode token of a Mamba-2 state-space layer, in place in the pool of
+recurrent states: a Pallas TPU kernel.
+
+What it computes, for every row b of a decode step (slot `idx[b]` of the
+pool, head h, group g(h)):
+
+    S <- a[b, h] * S + B[b, g] (x) dtx[b, h]        S: [N, P] float32
+    y[b, h] = C[b, g] . S  (over N)
+
+`a` is the token's decay, `dtx` its input times its step (`dt * x`), `B`
+and `C` the group's input and output maps. The skip term `D * x` and
+everything before and after are the caller's (`parallel_ssm_ops`).
+
+Why a kernel: the step reads and writes a row's whole state (4 MB a layer
+at 32 heads of 128 x 256 float32) and does four flops a value, so it is a
+stream of the pool through the chip and nothing else. XLA's form of it
+gathers the rows' states out of the pool, updates the copy and scatters it
+back: three passes. Here a grid step DMAs `_HEAD_BLOCK` heads of ONE slot
+(the slot is a prefetched scalar the BlockSpec index maps read), updates
+them in VMEM and writes them back where they came from (`input_output_
+aliases`): the pool moves once each way.
+
+A slot's states are kept as ONE `[heads * N, P]` slab, head after head, the
+state dimension on the sublanes (a pool of three dimensions: a gather or a
+scatter of whole slots, which a prefill window does, then has no pair of
+minor dimensions XLA could ask for the other way round; at `[rows, heads,
+N, P]` it transposed the whole pool into the layout its scan preferred and
+back, in every window). `dtx` and
+`a` (a value a lane) then broadcast over sublanes, which costs nothing, and
+`B` and `C` (a value a sublane) are made once a grid step by transposing a
+`[P, N]` broadcast; the contraction with `C` runs down the sublanes.
+
+Forward only: serving never differentiates.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# tests flip this to run the kernel through the Pallas interpreter on CPU
+INTERPRET = False
+
+_LANES = 128
+# heads of one slot a grid step holds: 8 x 128 KB in, as much out, twice
+# for the double buffer
+_HEAD_BLOCK = 8
+
+
+def update_supported(pool_shape, state: int, heads_per_group: int) -> bool:
+    """A pool `[rows, heads * N, P]` of float32 whose `[N, P]` state is
+    whole (8, 128) tiles and whose head blocks lie inside one group."""
+    if len(pool_shape) != 3 or state <= 0 or pool_shape[1] % state:
+        return False
+    H, P = pool_shape[1] // state, pool_shape[2]
+    return (P == _LANES and state % _LANES == 0 and H % _HEAD_BLOCK == 0
+            and heads_per_group % _HEAD_BLOCK == 0)
+
+
+def _kernel(idx_ref, s_ref, a_ref, dtx_ref, b_ref, c_ref, so_ref, y_ref):
+    del idx_ref                        # read by the index maps
+    N, P = b_ref.shape[3], s_ref.shape[2]
+    # B and C, a value a sublane, on every lane
+    bcol = jnp.broadcast_to(b_ref[0, 0], (P, N)).T              # [N, P]
+    ccol = jnp.broadcast_to(c_ref[0, 0], (P, N)).T
+    for h in range(s_ref.shape[1] // N):
+        rows = slice(h * N, (h + 1) * N)
+        s_new = a_ref[0, h:h + 1, :] * s_ref[0, rows] \
+            + bcol * dtx_ref[0, h:h + 1, :]
+        so_ref[0, rows] = s_new
+        y_ref[0, h:h + 1, :] = jnp.sum(s_new * ccol, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _call(pool, idx, a, dtx, bmat, cmat, interpret):
+    rows, HN, P = pool.shape
+    B, G, N = bmat.shape
+    H = HN // N
+    hb = _HEAD_BLOCK
+    per_group = H // G
+    lanes = jnp.broadcast_to(a.astype(jnp.float32)[:, :, None], (B, H, P))
+    head_rows = pl.BlockSpec((1, hb, P), lambda b, j, idx: (b, j, 0))
+    state = pl.BlockSpec((1, hb * N, P), lambda b, j, idx: (idx[b], j, 0))
+    group = pl.BlockSpec((1, 1, 1, N),
+                         lambda b, j, idx: (b, j * hb // per_group, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, H // hb),
+        in_specs=[state, head_rows, head_rows, group, group],
+        out_specs=[state, head_rows],
+    )
+    new_pool, y = pl.pallas_call(
+        _kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                   jax.ShapeDtypeStruct((B, H, P), jnp.float32)],
+        # operand 0 is the prefetched slot list
+        input_output_aliases={1: 0},
+        cost_estimate=pl.CostEstimate(
+            flops=5 * B * H * N * P, transcendentals=0,
+            bytes_accessed=2 * B * H * N * P * 4),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="ssm_decode_update",
+    )(jnp.clip(idx.astype(jnp.int32), 0, rows - 1), pool, lanes,
+      dtx.astype(jnp.float32),
+      bmat.astype(jnp.float32).reshape(B, G, 1, N),
+      cmat.astype(jnp.float32).reshape(B, G, 1, N))
+    return new_pool, y
+
+
+def _reference(pool, idx, a, dtx, bmat, cmat):
+    """The same update in plain jnp (the numeric oracle and the path off
+    the chip): the rows' states gathered, updated and scattered back. Rows
+    that name the same slot (a step's padding rows, all on the scratch
+    slot) leave one of their updates there."""
+    (B, G, N), P = bmat.shape, pool.shape[2]
+    H = pool.shape[1] // N
+    idx = jnp.clip(idx.astype(jnp.int32), 0, pool.shape[0] - 1)
+    bh = jnp.repeat(bmat.astype(jnp.float32), H // G, axis=1)   # [B, H, N]
+    ch = jnp.repeat(cmat.astype(jnp.float32), H // G, axis=1)
+    s_new = a.astype(jnp.float32)[:, :, None, None] \
+        * pool[idx].reshape(B, H, N, P) \
+        + bh[:, :, :, None] * dtx.astype(jnp.float32)[:, :, None, :]
+    y = jnp.sum(s_new * ch[:, :, :, None], axis=2)
+    return pool.at[idx].set(s_new.reshape(B, H * N, P)), y
+
+
+def _workbench_register():
+    from . import workbench
+
+    return workbench.register_kernel(
+        "ssm_decode_update",
+        reference=_reference,
+        supported=update_supported,
+        decision_op="ssm_update",
+        equivalence_test="test_ssm_decode_update_pallas_matches_reference",
+        note="one token of a Mamba-2 layer in place in the slot pool "
+             "[rows, heads * N, P] float32; slot by scalar prefetch, the "
+             "pool aliased to the output")
+
+
+@_workbench_register()
+def ssm_decode_update(pool, idx, a, dtx, bmat, cmat):
+    """pool `[rows, H * N, P]` float32, idx [B] (the row of each decode
+    row's state), a [B, H] (decay), dtx [B, H, P], bmat and cmat [B, G, N].
+    Returns (the pool with rows `idx` updated, y [B, H, P] float32).
+    Callers gate on `update_supported`."""
+    return _call(pool, idx, a, dtx, bmat, cmat, bool(INTERPRET))

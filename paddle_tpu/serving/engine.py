@@ -122,6 +122,22 @@ still held, and a prompt resumes only a prefix whose window tail is held
 (`PrefixCache.match_resumable`); under pressure in the window pool alone
 the cache gives up window pages before a row is made to wait.
 
+A recurrent state (the "parallel_ssm" block, `cfg.recurrent`): beside its
+K/V pages a row owns ONE slot of the pools of state (`state_pool`, the same
+`PagedKVPool` a third time, ids of its own, `req.sslot`), which every step
+of the row rewrites in place; admission, preemption, the leak count and the
+audit count slots beside pages. The prefix cache holds SNAPSHOTS: when a
+prefill chunk ends on a multiple of `cfg.prefill_chunk` tokens inside the
+prompt, the row's slot is copied into a free (or the least recently
+resumed) slot and hung on the cached block that ends there (`_snapshot`).
+A prompt resumes only at the deepest matched block whose snapshot is held
+and that leaves it a token to run (`PrefixCache.match_snapshot`): the pages
+matched past it are handed back and recomputed
+(`serving.state.recomputed_tokens`), and the snapshot is COPIED into the
+row's own slot (`serving.state.restore`), because unlike a K/V page a state
+is never read-only for its reader. Copy, snapshot and restore are one
+in-place device program (`model.build_state_copy_program`).
+
 Compile discipline (the PR 2 machinery doing serving duty):
   * prefill compiles once per prompt-length bucket (pow2 rounding); suffix
     prefill once per (suffix-bucket, page-bucket);
@@ -186,7 +202,7 @@ from ..resilience.retry import serving_policy
 from . import model as sv_model
 from .kv_cache import (OwnedPoolView, PagedKVPool, PrefixCache,
                        create_device_pools, create_stacked_pools,
-                       pool_var_names)
+                       create_state_pools, pool_var_names)
 from .sampling import SamplingParams, request_rng, sample_token
 
 __all__ = ["GenRequest", "ContinuousBatchingScheduler", "ServingEngine",
@@ -374,6 +390,11 @@ class GenRequest:
         # pool, logical pages wfirst .. wfirst + len(wpages) - 1
         self.wpages: list[int] = []
         self.wfirst = 0
+        # a family with a recurrent state: the row's own slot of the state
+        # pools while it runs, and the snapshot a prefix hit pinned for it
+        # until it is copied there
+        self.sslot: int | None = None
+        self.snap: int | None = None
         self.cached_len = 0      # slots mapped from the prefix cache
         self.admit_seq = -1      # admission order; preemption evicts the newest
         self.preemptions = 0
@@ -463,6 +484,7 @@ class ServingEngine:
                  max_inflight: int | None = None,
                  policy: str | None = None,
                  window_pool_pages: int | None = None,
+                 state_slots: int | None = None,
                  seed: int = 0,
                  prefix_cache: bool | None = None,
                  draft_k: int | None = None,
@@ -489,7 +511,11 @@ class ServingEngine:
         `window_pool_pages` sizes the second pool of a family with
         sliding-window layers (default: twice what `max_inflight` decoding
         rows hold there while each grows into its next page, the other half
-        for prefills in flight and the prefix cache's tails)."""
+        for prefills in flight and the prefix cache's tails).
+        `state_slots` sizes the pools of a family with a recurrent state
+        (default: a live slot a row of `max_inflight`, one scratch slot for
+        padding rows, and a quarter as many again, less one, for the prefix
+        cache's snapshots)."""
         self.cfg = cfg or sv_model.decoder_tiny()
         self.page_size = int(page_size
                              or flags.get_flag("serving_page_size"))
@@ -603,7 +629,23 @@ class ServingEngine:
             self.window_pool = PagedKVPool(
                 int(window_pool_pages or 2 * self.max_inflight
                     * (self._wtable_decode + 1)), self.page_size)
-        self.prefix_cache = PrefixCache(self.pool, self.window_pool) \
+        # the recurrent state's slots: the same allocator a third time, one
+        # "token" a page; slot ids are its page ids
+        self.state_pool = None
+        self._scratch_slot = 0
+        if self.cfg.recurrent:
+            if self.cfg.prefill_chunk % self.page_size:
+                raise ValueError(
+                    f"block {self.cfg.block!r}: prefill_chunk "
+                    f"{self.cfg.prefill_chunk} must be whole pages of "
+                    f"{self.page_size} (a snapshot hangs on the block a "
+                    f"chunk ends)")
+            self.state_pool = PagedKVPool(
+                int(state_slots or self.max_inflight + 1
+                    + max(2, self.max_inflight // 4 - 1)), 1)
+            (self._scratch_slot,) = self.state_pool.allocate(1)
+        self.prefix_cache = PrefixCache(self.pool, self.window_pool,
+                                        self.state_pool) \
             if prefix_cache else None
         self._exe = Executor()
         self._scope = shared_scope if shared_scope is not None else Scope()
@@ -633,6 +675,8 @@ class ServingEngine:
         self._prefill_prog.random_seed = startup.random_seed = self.seed
         second = {"window_pages": self.window_pool.num_pages} \
             if self.window_pool is not None else {}
+        if self.state_pool is not None:
+            second = {"state_slots": self.state_pool.num_pages}
         # the last token of every row slot, on the device (model.LAST_TOKEN):
         # a running row holds a slot while it has steps to run. Sized for
         # the row bucket (and for what a controller may raise max_inflight
@@ -665,6 +709,14 @@ class ServingEngine:
                 unique_name.guard():
             self._cow_io = sv_model.build_cow_program(
                 self.cfg, self.pool_pages, self.page_size, **second)
+        self._state_copy_run = None
+        if self.state_pool is not None:
+            prog = Program()
+            prog.name = "serving_state_copy"
+            with program_guard(prog, decoy_startup), unique_name.guard():
+                sv_model.build_state_copy_program(
+                    self.cfg, self.pool_pages, self.page_size, **second)
+            self._state_copy_run = self._exec_target(prog)
         # rng_counter pinned to what a FRESH scope's first run folds in:
         # on a shared scope the run counter has already advanced, and
         # letting it leak into the init keys would give every engine after
@@ -682,6 +734,12 @@ class ServingEngine:
                     self.cfg, self.pool_pages, self.page_size,
                     self.window_pool.num_pages):
                 create_stacked_pools(self._scope, *geometry)
+        elif self.cfg.recurrent:
+            kv, state = sv_model.ssm_pool_geometry(
+                self.cfg, self.pool_pages, self.page_size,
+                self.state_pool.num_pages)
+            create_stacked_pools(self._scope, *kv)
+            create_state_pools(self._scope, *state)
         elif self.cfg.scanned:
             create_stacked_pools(self._scope, *sv_model.stacked_pool_geometry(
                 self.cfg, self.pool_pages, self.page_size))
@@ -749,6 +807,12 @@ class ServingEngine:
             # to the registry by `why`
             "chain.steps_deferred": 0, "chain.steps_blocking": 0,
             "chain.discarded_rows": 0,
+            # a recurrent state in slots, snapshots in the prefix cache
+            # (ISSUE 37)
+            "state.snapshots": 0, "state.snapshot_evictions": 0,
+            "ssm.decode_row_layers": 0, "ssm.decode_layer_steps": 0,
+            "ssm.scan_tokens": 0, "ssm.scan_layer_steps": 0,
+            "peak_state_slots_in_use": 0,
         }
         # the learned controller's per-engine epoch hook (ISSUE 20):
         # shadow by default — one perf_counter read per step until an
@@ -897,6 +961,15 @@ class ServingEngine:
         from_host[:len(rows), 0] = [not r.in_flight for r in rows]
         return {sv_model.SLOT_FEED: slot, sv_model.FROM_HOST_FEED: from_host}
 
+    def _state_feed(self, rows, bb: int) -> dict:
+        """Each row's slot of the state pools for a step of `bb` rows, the
+        scratch slot for padding ({} where the family has no such pool)."""
+        if self.state_pool is None:
+            return {}
+        slot = np.full((bb,), self._scratch_slot, np.int32)
+        slot[:len(rows)] = [r.sslot for r in rows]
+        return {sv_model.SSLOT_FEED: slot}
+
     def _window_feed(self, rows, bb: int, width: int) -> dict:
         """The compact tables of `rows` in the sliding layers' pool and the
         position of each table's slot 0, for a step of `bb` rows ({} where
@@ -957,6 +1030,7 @@ class ServingEngine:
                                                          np.float32),
                             **self._mark_feed(),
                             **self._slot_feed((), bb, decode=True),
+                            **self._state_feed((), bb),
                             **self._window_feed((), bb, self._wtable_decode)}
                     outs = self._exe.run(
                         self._decode_run, feed=feed,
@@ -1287,6 +1361,14 @@ class ServingEngine:
                 held.update(n.wpage for n in self.prefix_cache._nodes.values()
                             if n.wpage is not None)
             leaked += self.window_pool.pages_in_use - len(held)
+        if self.state_pool is not None:
+            held = {self._scratch_slot}
+            for r in self.requests.values():
+                held.update(s for s in (r.sslot, r.snap) if s is not None)
+            if self.prefix_cache is not None:
+                held.update(n.snap for n in self.prefix_cache._nodes.values()
+                            if n.snap is not None)
+            leaked += self.state_pool.pages_in_use - len(held)
         return leaked
 
     def flush_prefix_cache(self) -> int:
@@ -1465,9 +1547,15 @@ class ServingEngine:
 
     # -- internals ----------------------------------------------------------
     def _free_slot(self, req: GenRequest) -> None:
+        """`req` runs no further step: its slot of `LAST_TOKEN` and its
+        slot of recurrent state are free for whoever is dispatched next
+        (the device runs that behind the step that last wrote them)."""
         if req.slot is not None:
             self._slots_free.append(req.slot)
             req.slot = None
+        if req.sslot is not None:
+            self.state_pool.release([req.sslot])
+            req.sslot = None
 
     def _release(self, req: GenRequest) -> None:
         self._free_slot(req)
@@ -1479,6 +1567,43 @@ class ServingEngine:
             req.wpages = []
         req.wfirst = 0
         req.cached_len = 0
+        if req.snap is not None:
+            self.state_pool.release([req.snap])
+            req.snap = None
+
+    def _allocate_slot(self) -> int | None:
+        """A free slot of the state pools; when there is none the prefix
+        cache gives up its least recently resumed snapshot."""
+        got = self.state_pool.allocate(1)
+        if got is None and self.prefix_cache is not None:
+            self._count("state.snapshot_evictions",
+                        self.prefix_cache.strip_snapshots(1))
+            got = self.state_pool.allocate(1)
+        return None if got is None else got[0]
+
+    def _copy_state(self, src: int, dst: int) -> None:
+        """Enqueue the copy of slot `src` of recurrent state onto `dst`, in
+        every layer (behind whatever was enqueued before it)."""
+        self._dispatch("state_copy", self._state_copy_run,
+                       {sv_model.SCOPY_SRC_FEED: np.asarray([src], np.int32),
+                        sv_model.SCOPY_DST_FEED: np.asarray([dst], np.int32)},
+                       [])
+
+    def _snapshot(self, req: GenRequest, upto: int) -> None:
+        """The chunk just enqueued left in `req`'s slot the state after
+        position `upto - 1`, a chunk boundary inside the prompt: copy it
+        into a slot of the prefix cache's (a free one, or its least
+        recently resumed) and hang that on the cached block that ends
+        there, unless that block holds one or no slot can be had."""
+        node = self.prefix_cache.snapshot_block(req.all_tokens[:upto],
+                                                upto // self.page_size)
+        slot = None if node is None else self._allocate_slot()
+        if slot is None:
+            return
+        with obs.span("serving.state.snapshot"):
+            self._copy_state(req.sslot, slot)
+        self.prefix_cache.hang_snapshot(node, slot)
+        self._count("state.snapshots")
 
     def _allocate_window(self, n: int) -> list[int] | None:
         """`_allocate` in the sliding layers' pool: when its free list runs
@@ -1516,7 +1641,12 @@ class ServingEngine:
             return []
         got = self.pool.allocate(n)
         if got is None and self.prefix_cache is not None:
+            held = self.prefix_cache.evicted_snapshots
             self.prefix_cache.evict(n - self.pool.free_count)
+            if self.prefix_cache.evicted_snapshots > held:
+                # a block's snapshot goes with its pages
+                self._count("state.snapshot_evictions",
+                            self.prefix_cache.evicted_snapshots - held)
             got = self.pool.allocate(n)
         return got
 
@@ -1534,6 +1664,15 @@ class ServingEngine:
                 self.stats["peak_window_pages_in_use"], wused)
             obs.gauge_set("serving.kv.global_pages_in_use", used)
             obs.gauge_set("serving.kv.window_pages_in_use", wused)
+        if self.state_pool is not None:
+            self.stats["peak_state_slots_in_use"] = max(
+                self.stats["peak_state_slots_in_use"],
+                self.state_pool.pages_in_use)
+            obs.gauge_set("serving.state.live_slots",
+                          sum(r.sslot is not None for r in self._running))
+            obs.gauge_set("serving.state.snapshot_slots",
+                          self.prefix_cache.snapshots_held
+                          if self.prefix_cache is not None else 0)
 
     # -- resilience: deadlines, shedding, the degradation ladder ------------
     def _terminate(self, req: GenRequest, state: str, counter: str,
@@ -1796,10 +1935,14 @@ class ServingEngine:
         poisoned: list[int] = []
         holders: dict[int, int] = {}
         wholders: dict[int, int] = {}
+        sholders: dict[int, int] = {self._scratch_slot: 1}
         for r in self.requests.values():
             if r.state not in _TERMINAL:
                 for p in r.wpages:
                     wholders[p] = wholders.get(p, 0) + 1
+                for slot in (r.sslot, r.snap):
+                    if slot is not None:
+                        sholders[slot] = sholders.get(slot, 0) + 1
                 if len(set(r.wpages)) != len(r.wpages):
                     problems.append(f"request {r.rid} maps a page of the "
                                     f"window pool twice")
@@ -1826,10 +1969,15 @@ class ServingEngine:
                 holders[node.page] = holders.get(node.page, 0) + 1
                 if node.wpage is not None:
                     wholders[node.wpage] = wholders.get(node.wpage, 0) + 1
+                if node.snap is not None:
+                    sholders[node.snap] = sholders.get(node.snap, 0) + 1
         problems.extend(self.pool.check_consistency(holders))
         if self.window_pool is not None:
             problems.extend("window pool: " + p for p in
                             self.window_pool.check_consistency(wholders))
+        if self.state_pool is not None:
+            problems.extend("state pool: " + p for p in
+                            self.state_pool.check_consistency(sholders))
         return problems, poisoned
 
     def _recover(self, reason: str, poisoned=(), problems=()) -> None:
@@ -1846,6 +1994,7 @@ class ServingEngine:
         self._slots_free = list(range(self._token_slots))[::-1]
         for req in self.requests.values():
             req.in_flight, req.slot = 0, None
+            req.sslot = req.snap = None
         obs.event("serving.recovery",
                   {"reason": reason, "problems": list(problems)[:8],
                    "quarantined": list(poisoned),
@@ -1896,6 +2045,9 @@ class ServingEngine:
         self.pool.reset()
         if self.window_pool is not None:
             self.window_pool.reset()
+        if self.state_pool is not None:
+            self.state_pool.reset()
+            (self._scratch_slot,) = self.state_pool.allocate(1)
         post, _ = self._audit_tables()
         if post:
             raise RuntimeError(
@@ -1930,7 +2082,20 @@ class ServingEngine:
                 matched = []
                 if self.prefix_cache is not None:
                     self._count("prefix_lookups")
-                    if self.window_pool is not None:
+                    if self.state_pool is not None:
+                        # only up to a block whose snapshot is still held,
+                        # and never the prompt's last token (its logits are
+                        # the first token); the snapshot is pinned like the
+                        # pages until it is copied into the row's slot
+                        matched, req.snap, past = \
+                            self.prefix_cache.match_snapshot(
+                                req.all_tokens[:req.prompt_len],
+                                (len(req.all_tokens) - 1) // self.page_size)
+                        if req.snap is not None:
+                            self.state_pool.share([req.snap])
+                        self._count("state.recomputed_tokens",
+                                    past * self.page_size)
+                    elif self.window_pool is not None:
                         # only a prefix whose window tail is still held
                         matched, req.wfirst, tail = \
                             self.prefix_cache.match_resumable(
@@ -1960,6 +2125,11 @@ class ServingEngine:
                     and not self._reserve_window(req):
                 self.pool.release(private)
                 private = None
+            if private is not None and self.state_pool is not None:
+                req.sslot = self._allocate_slot()
+                if req.sslot is None:
+                    self.pool.release(private)
+                    private = None
             if private is None:
                 # keep the pin on the request: abort/shed/deadline release
                 # it through _terminate, and the next attempt starts with
@@ -2158,6 +2328,13 @@ class ServingEngine:
         greedy = req.sampling.is_greedy
         if not greedy:
             self._settle("sampled")
+        if req.snap is not None:
+            # resume: the snapshot into the row's own slot, the pin returned
+            with obs.span("serving.state.restore"):
+                self._copy_state(req.snap, req.sslot)
+            self.state_pool.release([req.snap])
+            req.snap = None
+            self._count("state.restores")
         if self.cfg.prefill_chunk:
             step = self._prefill_chunks(req, n)
         else:
@@ -2236,6 +2413,7 @@ class ServingEngine:
                             sv_model.START_FEED: np.asarray([c0], np.int32),
                             sv_model.LEN_FEED: np.asarray([m], np.int32),
                             **self._slot_feed((req,), 1),
+                            **self._state_feed((req,), 1),
                             **self._window_feed((req,), 1,
                                                 self._wtable_chunk)}
                 handles = self._run_step(
@@ -2245,6 +2423,15 @@ class ServingEngine:
                 self.stats["prefill_signatures"].add(("suffix", sb, pb))
                 self._count("prefill_tokens_computed", m)
                 self._count("prefill.chunks")
+                if self.state_pool is not None:
+                    self._count("ssm.scan_tokens", m * self.cfg.num_layers)
+                    self._count("ssm.scan_layer_steps", self.cfg.num_layers)
+                    if (c0 + m) % chunk == 0 and c0 + m <= req.prompt_len \
+                            and self.prefix_cache is not None:
+                        # the state the chunk leaves is the state after a
+                        # cacheable block: the cache takes a copy of it
+                        self._register_prefix(req, c0 + m)
+                        self._snapshot(req, c0 + m)
                 step = _InFlight("chunk", [req], at=[(c0, m)], marked=[],
                                  route_pages=self._route_pages(req, c0, m),
                                  **handles)
@@ -2518,6 +2705,7 @@ class ServingEngine:
                     sv_model.PAGES_FEED: pages, sv_model.MASK_FEED: mask,
                     **self._mark_feed(marked),
                     **self._slot_feed(rows, bb, decode=True),
+                    **self._state_feed(rows, bb),
                     **self._window_feed(rows, bb, self._wtable_decode)}
             at = [(r.cache_len, r.pages[r.cache_len // ps]) for r in rows]
         self._step_rows = len(rows)
@@ -2539,6 +2727,10 @@ class ServingEngine:
                         slide * sum(min(W, pos + 1) for pos, _ in at))
             self._count("attn.full_layer_steps", full)
             self._count("attn.window_layer_steps", slide)
+        if self.state_pool is not None:
+            self._count("ssm.decode_row_layers",
+                        len(rows) * self.cfg.num_layers)
+            self._count("ssm.decode_layer_steps", self.cfg.num_layers)
         if self.cfg.selects_within(pb * ps):
             L, k = self.cfg.num_layers, self.cfg.index_topk
             self._count("sparse.context_tokens",

@@ -53,9 +53,9 @@ import jax.numpy as jnp
 __all__ = ["PagedKVPool", "PrefixCache", "OwnedPoolView", "pool_var_names",
            "pool_shape", "create_device_pools", "declare_pool_vars",
            "STACKED_POOLS", "INDEX_POOL", "JOINED_POOL", "WINDOW_POOLS",
-           "stacked_pool_shapes",
-           "declare_stacked_pools",
-           "create_stacked_pools"]
+           "STATE_POOLS", "stacked_pool_shapes", "state_pool_shapes",
+           "declare_stacked_pools", "declare_state_pools",
+           "create_stacked_pools", "create_state_pools"]
 
 
 def pool_var_names(num_layers: int) -> list[tuple[str, str]]:
@@ -126,6 +126,18 @@ JOINED_POOL = "kv_cache.kv"
 # and nothing older, so a row maps there only the pages its window touches,
 # while its full-attention layers keep every page in the pools above.
 WINDOW_POOLS = ("kv_cache.wk", "kv_cache.wv")
+# A family with a RECURRENT state ("parallel_ssm": a state-space layer's `S`
+# and its convolution's tail) keeps it in pools of SLOTS, not pages: the
+# state is rewritten in place by every token, does not grow, and is larger
+# than the K/V of the tokens that made it (4.26 MB a layer a sequence
+# beside a 128-token page's 262 KB), so one a page is not affordable. A
+# slot id names a row `l * slots + id` of both pools in every layer; the
+# ids are handed out by a third `PagedKVPool` (pages of one "token"): a
+# running row owns ONE live slot, the prefix cache owns SNAPSHOTS (a slot
+# copied from a row's at a chunk boundary, hung on the cached block that
+# ends there), and resuming from one COPIES it into the row's own slot,
+# because unlike a K/V page a state is never read-only for its reader.
+STATE_POOLS = ("kv_cache.ssm", "kv_cache.conv")
 
 
 def stacked_pool_shapes(num_layers: int, num_pages: int, page_size: int,
@@ -158,6 +170,31 @@ def stacked_pool_shapes(num_layers: int, num_pages: int, page_size: int,
         pools.append((INDEX_POOL, (rows, int(index_width), int(page_size)),
                       dtype))
     return pools
+
+
+def state_pool_shapes(num_layers: int, num_slots: int, heads: int,
+                      state: int, head_dim: int, tail_width: int):
+    """[(name, shape, dtype)] of the pools of recurrent state: `S`, `[rows,
+    heads * state, head_dim]` (a slot's heads one slab, the state dimension
+    on the sublanes: `pallas_kernels.ssm_update` says why), and the
+    convolution's tail,
+    `[rows, tail_width]` (the last `conv - 1` pre-convolution rows side by
+    side), both float32, `rows = num_layers * num_slots`."""
+    rows = int(num_layers) * int(num_slots)
+    return [(STATE_POOLS[0], (rows, int(heads) * int(state), int(head_dim)),
+             "float32"),
+            (STATE_POOLS[1], (rows, int(tail_width)), "float32")]
+
+
+def declare_state_pools(block, *geometry) -> None:
+    for name, shape, dtype in state_pool_shapes(*geometry):
+        block.create_var(name=name, shape=list(shape), dtype=dtype,
+                         persistable=True, stop_gradient=True)
+
+
+def create_state_pools(scope, *geometry) -> None:
+    for name, shape, dtype in state_pool_shapes(*geometry):
+        scope.set_var(name, jnp.zeros(shape, jnp.dtype(dtype)))
 
 
 def declare_stacked_pools(block, *geometry) -> None:
@@ -535,7 +572,7 @@ class OwnedPoolView:
 
 class _PrefixNode:
     __slots__ = ("nid", "page", "key", "parent_id", "children", "last_use",
-                 "wpage", "wlast_use")
+                 "wpage", "wlast_use", "snap", "slast_use")
 
     def __init__(self, nid, page, key, parent_id):
         self.nid = nid
@@ -546,6 +583,8 @@ class _PrefixNode:
         self.last_use = 0
         self.wpage = None           # the block's page of the window pool
         self.wlast_use = 0
+        self.snap = None            # the slot of the state after the block
+        self.slast_use = 0
 
 
 class PrefixCache:
@@ -581,13 +620,28 @@ class PrefixCache:
     least recently RESUMED FROM first (`strip_window`): a lookup stamps the
     tail it hands out, not the path to it, so the interior of a long
     shared prompt goes first and its tail last.
+
+    Recurrent state (`state_pool`, a family whose sequences carry a state
+    every token rewrites): a node MAY hold a SNAPSHOT, the slot (`snap`, the
+    cache's own, one refcount in the state pool) of the state after its
+    block's last token, hung there by the engine (`hang_snapshot`) when a
+    prefill chunk ended on that block. It is the same rule with a tail of
+    one: a prefix can be resumed only at a block whose snapshot is held
+    (`match_snapshot`), the blocks matched past it are recomputed; a node's
+    snapshot goes with its pages; and under pressure in the state pool the
+    cache gives up snapshots (never nodes), least recently resumed from
+    first (`strip_snapshots`).
     """
 
-    def __init__(self, pool: PagedKVPool, window_pool=None):
+    def __init__(self, pool: PagedKVPool, window_pool=None, state_pool=None):
         self.pool = pool
         self.window_pool = window_pool
+        self.state_pool = state_pool
         self._wheap: list[tuple[int, int]] = []  # (wlast_use, nid), lazy
+        self._sheap: list[tuple[int, int]] = []  # (slast_use, nid), lazy
         self.stripped_window_pages = 0
+        self.stripped_snapshots = 0
+        self.snapshots_held = 0
         self.page_size = pool.page_size
         self._nodes: dict[tuple, _PrefixNode] = {}
         self._by_id: dict[int, _PrefixNode] = {}
@@ -598,6 +652,7 @@ class PrefixCache:
         self.hit_pages = 0
         self.inserted_pages = 0
         self.evicted_pages = 0
+        self.evicted_snapshots = 0      # gone with their block's pages
 
     def _tick(self) -> int:
         self._clock += 1
@@ -615,32 +670,15 @@ class PrefixCache:
         """Longest chain of cached pages covering a prefix of `tokens`
         (full blocks only). Bumps LRU stamps on the path."""
         self.lookups += 1
-        pages: list[int] = []
-        pid = 0
-        for i in range(len(tokens) // self.page_size):
-            block = tuple(int(t) for t in
-                          tokens[i * self.page_size:(i + 1) * self.page_size])
-            node = self._nodes.get((pid, block))
-            if node is None:
-                break
+        path = self._path(tokens)
+        for node in path:
             self._touch(node)
-            pages.append(node.page)
-            pid = node.nid
-        self.hit_pages += len(pages)
-        return pages
+        self.hit_pages += len(path)
+        return [node.page for node in path]
 
-    def _touch_window(self, node: _PrefixNode) -> None:
-        node.wlast_use = self._tick()
-        heapq.heappush(self._wheap, (node.wlast_use, node.nid))
-
-    def match_resumable(self, tokens, tail: int) -> tuple:
-        """`match` for two pools: the longest cached prefix of `tokens`
-        (full blocks) whose last `tail` blocks (all of them, if it has
-        fewer) each hold a window page, as (pages, first block of the tail,
-        the tail's window pages); a longer match whose window tail was
-        given up falls back to the longest shorter one that can resume, or
-        to ([], 0, [])."""
-        self.lookups += 1
+    def _path(self, tokens) -> list:
+        """The chain of cached nodes covering a prefix of `tokens` (full
+        blocks), untouched."""
         path: list[_PrefixNode] = []
         pid = 0
         for i in range(len(tokens) // self.page_size):
@@ -651,13 +689,36 @@ class PrefixCache:
                 break
             path.append(node)
             pid = node.nid
-        # the longest n whose last min(tail, n) blocks all hold window pages
-        n = held = 0
+        return path
+
+    def _touch_held(self, node: _PrefixNode, stamp: str, heap: list) -> None:
+        setattr(node, stamp, self._tick())
+        heapq.heappush(heap, (getattr(node, stamp), node.nid))
+
+    def _touch_window(self, node: _PrefixNode) -> None:
+        self._touch_held(node, "wlast_use", self._wheap)
+
+    def _resumable(self, path: list, tail: int, held: str) -> tuple:
+        """(n, how many of its last blocks are the tail): the longest
+        prefix of `path` whose last min(tail, n) nodes all hold `held` (a
+        window page, a snapshot); (0, 0) if none does."""
         for end in range(len(path), 0, -1):
             want = min(tail, end)
-            if all(node.wpage is not None for node in path[end - want:end]):
-                n, held = end, want
-                break
+            if all(getattr(node, held) is not None
+                   for node in path[end - want:end]):
+                return end, want
+        return 0, 0
+
+    def match_resumable(self, tokens, tail: int) -> tuple:
+        """`match` for two pools: the longest cached prefix of `tokens`
+        (full blocks) whose last `tail` blocks (all of them, if it has
+        fewer) each hold a window page, as (pages, first block of the tail,
+        the tail's window pages); a longer match whose window tail was
+        given up falls back to the longest shorter one that can resume, or
+        to ([], 0, [])."""
+        self.lookups += 1
+        path = self._path(tokens)
+        n, held = self._resumable(path, tail, "wpage")
         for node in path[:n]:
             self._touch(node)
         for node in path[n - held:n]:
@@ -666,28 +727,82 @@ class PrefixCache:
         return ([node.page for node in path[:n]], n - held,
                 [node.wpage for node in path[n - held:n]])
 
+    def match_snapshot(self, tokens, limit: int) -> tuple:
+        """`match` for a recurrent state: the longest cached prefix of
+        `tokens` of at most `limit` blocks whose LAST block holds a
+        snapshot, as (pages, that snapshot's slot or None, blocks matched
+        past it that the caller recomputes); a longer match whose snapshot
+        was given up falls back to the deepest boundary that still holds
+        one, or to ([], None, blocks matched)."""
+        self.lookups += 1
+        path = self._path(tokens)
+        n, _ = self._resumable(path[:limit], 1, "snap")
+        for node in path[:n]:
+            self._touch(node)
+        if n:
+            self._touch_held(path[n - 1], "slast_use", self._sheap)
+        self.hit_pages += n
+        return ([node.page for node in path[:n]],
+                path[n - 1].snap if n else None, len(path) - n)
+
+    def snapshot_block(self, tokens, blocks: int):
+        """The cached block that ends `tokens[:blocks * page_size]`, if it
+        is cached and holds no snapshot yet: where `hang_snapshot` may hang
+        one. None otherwise."""
+        path = self._path(tokens[:blocks * self.page_size])
+        if len(path) != blocks or path[-1].snap is not None:
+            return None
+        return path[-1]
+
+    def hang_snapshot(self, node: _PrefixNode, slot: int) -> None:
+        """Hang `slot` (allocated by the caller: its one refcount becomes
+        the cache's) on `node`, a block `snapshot_block` gave."""
+        node.snap = int(slot)
+        self.snapshots_held += 1
+        self._touch_held(node, "slast_use", self._sheap)
+
+    def _strip(self, need: int, heap: list, held: str, stamp: str,
+               pool) -> int:
+        """Give up to `need` of the `held` ids (window pages, snapshots)
+        back to `pool`'s free list, least recently resumed from first,
+        nodes kept: only ids nobody else maps (refcount 1 == the cache's
+        own). Returns ids freed."""
+        freed = 0
+        skipped: list[tuple[int, int]] = []
+        while freed < need and heap:
+            at, nid = heapq.heappop(heap)
+            node = self._by_id.get(nid)
+            if node is None or getattr(node, held) is None \
+                    or getattr(node, stamp) != at:
+                continue
+            if pool.refcount(getattr(node, held)) != 1:
+                skipped.append((at, nid))
+                continue
+            pool.release([getattr(node, held)])
+            setattr(node, held, None)
+            freed += 1
+        for entry in skipped:
+            heapq.heappush(heap, entry)
+        return freed
+
     def strip_window(self, need: int) -> int:
         """Give up to `need` window pages back to the window pool's free
         list, least recently resumed from first, nodes kept: only pages
         nobody else maps (refcount 1 == the cache's own). Returns pages
         freed."""
-        freed = 0
-        skipped: list[tuple[int, int]] = []
-        while freed < need and self._wheap:
-            stamp, nid = heapq.heappop(self._wheap)
-            node = self._by_id.get(nid)
-            if node is None or node.wpage is None \
-                    or node.wlast_use != stamp:
-                continue
-            if self.window_pool.refcount(node.wpage) != 1:
-                skipped.append((stamp, nid))
-                continue
-            self.window_pool.release([node.wpage])
-            node.wpage = None
-            self.stripped_window_pages += 1
-            freed += 1
-        for entry in skipped:
-            heapq.heappush(self._wheap, entry)
+        freed = self._strip(need, self._wheap, "wpage", "wlast_use",
+                            self.window_pool)
+        self.stripped_window_pages += freed
+        return freed
+
+    def strip_snapshots(self, need: int) -> int:
+        """`strip_window` for snapshots: up to `need` slots back to the
+        state pool, least recently resumed from first; a snapshot a waiting
+        request has pinned stays. Returns slots freed."""
+        freed = self._strip(need, self._sheap, "snap", "slast_use",
+                            self.state_pool)
+        self.stripped_snapshots += freed
+        self.snapshots_held -= freed
         return freed
 
     def insert(self, tokens, pages: list[int], window_pages=None) -> int:
@@ -765,6 +880,10 @@ class PrefixCache:
         self.pool.release([node.page])
         if node.wpage is not None:
             self.window_pool.release([node.wpage])
+        if node.snap is not None:
+            self.state_pool.release([node.snap])
+            self.evicted_snapshots += 1
+            self.snapshots_held -= 1
         self.evicted_pages += 1
 
     def clear(self) -> int:
@@ -778,6 +897,8 @@ class PrefixCache:
         self._by_id.clear()
         self._heap.clear()
         self._wheap.clear()
+        self._sheap.clear()
+        self.snapshots_held = 0
         return n
 
     def flush(self) -> int:

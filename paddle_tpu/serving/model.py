@@ -1,4 +1,4 @@
-"""Serving program builders. FOUR block families exist, and
+"""Serving program builders. FIVE block families exist, and
 `DecoderConfig.block` selects one:
 
   * `"post_ln"` (the default; every other field at its default is the "bert
@@ -47,6 +47,21 @@
     compact page table of their own: feeds `sv_wpages`, `sv_wbase`), the
     full layers in the first. Prompts run in `prefill_chunk`-token windows
     as "sparse_moe"'s do.
+  * `"parallel_ssm"` (`ops/parallel_ssm_ops.py`; Falcon-H1's layer): ONE
+    pre-norm, then a Mamba-2 state-space mixer (`ssm_heads` heads of
+    `ssm_head_dim` over `ssm_groups` groups of `ssm_state`, a depthwise
+    convolution `ssm_conv` wide, scanned in chunks of `ssm_chunk`) and a
+    grouped-query attention with full rotary side by side on the same
+    normed input, their outputs summed into the residual, then a pre-norm
+    and a SwiGLU (`ffn_size`); an untied head; the config's muP
+    multipliers (`*_multiplier`, `mlp_multipliers` = (gate, down),
+    `ssm_multipliers` over z | x | B | C | dt). Scanned and stacked like
+    "cca_moe". Besides K/V a sequence carries a RECURRENT STATE that every
+    token rewrites in place: it lives in pools of slots, not pages
+    (`kv_cache.STATE_POOLS`; a row's slot is the feed `sv_sslot`), and
+    `build_state_copy_program` copies one slot onto another (a snapshot
+    the prefix cache keeps, a restore from one). Prompts run in
+    `prefill_chunk`-token windows as "sparse_moe"'s do.
 
 Every family is expressed several times over ONE weight namespace:
 
@@ -79,16 +94,20 @@ from .. import layers as L
 from ..framework import default_main_program
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
-from ..initializer import Constant, Normal, StackedNormal
-from ..ops import cca_moe_ops, hybrid_moe_ops, sparse_moe_ops
-from .kv_cache import (INDEX_POOL, JOINED_POOL, STACKED_POOLS, WINDOW_POOLS,
-                       declare_pool_vars, declare_stacked_pools,
+from ..initializer import (BlockedNormal, Constant, Normal, StackedNormal,
+                           Uniform)
+from ..ops import (cca_moe_ops, hybrid_moe_ops, parallel_ssm_ops,
+                   sparse_moe_ops)
+from .kv_cache import (INDEX_POOL, JOINED_POOL, STACKED_POOLS, STATE_POOLS,
+                       WINDOW_POOLS, declare_pool_vars,
+                       declare_stacked_pools, declare_state_pools,
                        pool_var_names)
 
 __all__ = ["DecoderConfig", "decoder_tiny", "cca_moe_tiny",
-           "sparse_moe_tiny", "hybrid_moe_tiny", "layer_plan",
-           "build_prefill_program",
+           "sparse_moe_tiny", "hybrid_moe_tiny", "parallel_ssm_tiny",
+           "layer_plan", "build_prefill_program",
            "build_decode_program", "build_window_program",
+           "build_state_copy_program",
            "build_full_forward_program", "apply_tp_annotations"]
 
 # feed names shared by the engine and the programs
@@ -107,6 +126,11 @@ WPAGES_FEED = "sv_wpages"
 WBASE_FEED = "sv_wbase"
 COW_WSRC_FEED = "sv_cow_wsrc"   # copy-on-write in the sliding layers' pool
 COW_WDST_FEED = "sv_cow_wdst"
+# "parallel_ssm": each row's slot in the pools of recurrent state (padding
+# rows name a scratch slot nobody reads), and the state copy's two slots
+SSLOT_FEED = "sv_sslot"
+SCOPY_SRC_FEED = "sv_scopy_src"
+SCOPY_DST_FEED = "sv_scopy_dst"
 # how many rows of a decode step can have their selection handed back
 MARK_ROWS = 8
 # the token each row slot's latest step emitted, kept on the device: the one
@@ -163,19 +187,49 @@ class DecoderConfig:
     shared_expert_size: int = 0
     dense_ffn_size: int = 0
     routed_scaling: float = 1.0
+    # "parallel_ssm" only: the state-space mixer's sizes and the config's
+    # muP multipliers (`mlp_multipliers` = (gate, down); `ssm_multipliers`
+    # over the columns z | x | B | C | dt of the mixer's input projection)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    mlp_multipliers: tuple = (1.0, 1.0)
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
     # a deployment's choice, any family: the fewest rows a decode step is
     # compiled for (a power of two). Steps of fewer live rows pay for that
     # many; every row bucket below it is a program less to compile
     min_row_bucket: int = 1
 
     def __post_init__(self):
-        if self.block not in ("post_ln", "cca_moe", "sparse_moe",
-                              "hybrid_moe"):
+        if self.block not in _FAMILY:
             raise ValueError(f"unknown DecoderConfig.block {self.block!r} "
-                             f"(post_ln | cca_moe | sparse_moe | hybrid_moe)")
+                             f"({' | '.join(_FAMILY)})")
         for name in ("layer_types", "mlp_layer_types", "heads_per_layer",
-                     "yarn"):
+                     "yarn", "mlp_multipliers", "ssm_multipliers"):
             setattr(self, name, tuple(getattr(self, name)))
+        if self.block == "parallel_ssm":
+            if min(self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+                   self.ssm_state, self.ssm_chunk, self.prefill_chunk) < 1 \
+                    or self.ssm_conv < 2 or self.ssm_heads % self.ssm_groups \
+                    or self.num_heads % self.kv_heads \
+                    or len(self.mlp_multipliers) != 2 \
+                    or len(self.ssm_multipliers) != 5:
+                raise ValueError(
+                    "block 'parallel_ssm' needs ssm_heads (a multiple of "
+                    "ssm_groups), ssm_head_dim, ssm_state, ssm_chunk, "
+                    "ssm_conv >= 2, prefill_chunk, num_kv_heads dividing "
+                    "num_heads, two mlp_multipliers and five "
+                    "ssm_multipliers")
         if self.block == "hybrid_moe":
             layer_plan(self)       # raises on lists that name no plan
             if min(self.sliding_window, self.prefill_chunk,
@@ -240,7 +294,15 @@ class DecoderConfig:
         """Whether the layers are one scanned op over stacked weights and
         stacked pools (`kv_cache.STACKED_POOLS`). Speculation, tensor
         parallelism and the fleet handoff are not written for that form."""
-        return self.block in ("cca_moe", "sparse_moe", "hybrid_moe")
+        return self.block in ("cca_moe", "sparse_moe", "hybrid_moe",
+                              "parallel_ssm")
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether a sequence carries a state that every token rewrites in
+        place (a slot of `kv_cache.STATE_POOLS`, not a row a page): the
+        prefix cache resumes it from snapshots only."""
+        return self.block == "parallel_ssm"
 
     @property
     def windowed(self) -> bool:
@@ -254,7 +316,7 @@ class DecoderConfig:
         request's `routes`)."""
         if self.block == "hybrid_moe":
             return sum(kind == "sparse" for kind in self.mlp_layer_types)
-        return self.num_layers
+        return 0 if self.block == "parallel_ssm" else self.num_layers
 
     @property
     def selects(self) -> bool:
@@ -283,8 +345,11 @@ class DecoderConfig:
         of every layer for each (row bucket, page bucket) program (ten page
         buckets below 19k tokens are seventy decode programs), and whose
         paged decode kernels pass a block of dead pages in a grid step of
-        a third of a microsecond."""
-        return self.windowed
+        a third of a microsecond. "parallel_ssm" compiles every layer once
+        (a scan) but streams 7.8 GB of weights a step whatever the table's
+        width: six page buckets would be six times the programs to warm
+        for nothing a step could gain."""
+        return self.windowed or self.recurrent
 
 
 def decoder_tiny() -> DecoderConfig:
@@ -332,6 +397,25 @@ def hybrid_moe_tiny(**over) -> DecoderConfig:
               partial_rotary_factor=0.5, rope_theta=5e5,
               yarn=(64.0, 16, 64.0, 1.0, 1.4158883083359672),
               rms_norm_eps=1e-6, max_position=128, block="hybrid_moe")
+    kw.update(over)
+    return DecoderConfig(**kw)
+
+
+def parallel_ssm_tiny(**over) -> DecoderConfig:
+    """The "parallel_ssm" block at test size: 4 query heads over 2 KV heads
+    of 8 beside 4 state-space heads of 8 in 2 groups of state 16, a
+    convolution 4 wide, scan chunks of 4, prompts in chunks of 8; every
+    multiplier another value than 1."""
+    kw = dict(vocab_size=97, hidden_size=32, num_layers=3, num_heads=4,
+              num_kv_heads=2, attn_head_dim=8, ffn_size=64, ssm_heads=4,
+              ssm_head_dim=8, ssm_groups=2, ssm_state=16, ssm_conv=4,
+              ssm_chunk=4, prefill_chunk=8, rope_theta=1e6,
+              embedding_multiplier=1.5, lm_head_multiplier=0.75,
+              ssm_in_multiplier=0.8, ssm_out_multiplier=1.25,
+              attention_in_multiplier=0.9, attention_out_multiplier=1.2,
+              key_multiplier=0.7, mlp_multipliers=(0.85, 1.1),
+              ssm_multipliers=(0.9, 1.2, 0.8, 1.1, 0.7),
+              max_position=128, block="parallel_ssm")
     kw.update(over)
     return DecoderConfig(**kw)
 
@@ -842,6 +926,212 @@ def _hybrid_cow(cfg, num_pages, page_size, src, dst, window_pages=0):
     return [COW_WSRC_FEED, COW_WDST_FEED]
 
 
+# -- the "parallel_ssm" family -----------------------------------------------
+
+
+def _ssm_geometry(cfg: DecoderConfig) -> dict:
+    return {"num_heads": cfg.num_heads, "num_kv_heads": cfg.kv_heads,
+            "head_dim": cfg.head_dim, "rope_theta": float(cfg.rope_theta),
+            "eps": float(cfg.rms_norm_eps), "ssm_heads": cfg.ssm_heads,
+            "ssm_head_dim": cfg.ssm_head_dim, "ssm_groups": cfg.ssm_groups,
+            "ssm_state": cfg.ssm_state, "ssm_conv": cfg.ssm_conv,
+            "ssm_chunk": cfg.ssm_chunk,
+            **{name: float(getattr(cfg, name)) for name in (
+                "embedding_multiplier", "lm_head_multiplier",
+                "ssm_in_multiplier", "ssm_out_multiplier",
+                "attention_in_multiplier", "attention_out_multiplier",
+                "key_multiplier")},
+            "mlp_multipliers": [float(v) for v in cfg.mlp_multipliers],
+            "ssm_multipliers": [float(v) for v in cfg.ssm_multipliers]}
+
+
+def ssm_pool_geometry(cfg: DecoderConfig, num_pages: int, page_size: int,
+                      num_slots: int) -> tuple:
+    """(`kv_cache.stacked_pool_shapes`' arguments for K and V,
+    `kv_cache.state_pool_shapes`' for the recurrent state)."""
+    geom = parallel_ssm_ops.Geometry(**_ssm_geometry(cfg))
+    return ((cfg.num_layers, num_pages, page_size,
+             cfg.kv_heads * cfg.head_dim, 0, cfg.dtype),
+            (cfg.num_layers, num_slots, cfg.ssm_heads, cfg.ssm_state,
+             cfg.ssm_head_dim,
+             (cfg.ssm_conv - 1) * parallel_ssm_ops.conv_width(geom)))
+
+
+def _ssm_param_specs(cfg: DecoderConfig) -> dict:
+    """name -> (shape, dtype, initializer), layers stacked on the leading
+    axis. The config's multipliers are small (0.011 on keys, 0.0375 and
+    0.088 on the two branches, 0.0078 on the logits): under a plain
+    fan_in^-0.5 draw attention is a uniform average and both branches a
+    rounding error, and a wrong engine reads like a right one. So every
+    matrix is drawn at `target / (its multipliers x fan_in^0.5)`, the
+    target the standard deviation of what comes out of it: the embedding 1
+    (the residual stream enters at RMS 1); queries 2 and keys 1 (attention
+    logits of standard deviation 2 over sqrt(head_dim)); values 1; the
+    mixer's z and x 1, B and C 2 (so that `C . S`, not the skip term,
+    carries the mixer's output), dt 0.5 around a bias drawn log-uniform
+    over 0.001-0.1 (`softplus`), `exp(A_log)` uniform in the exponent over
+    1-16 (a decay of 0.2-0.999 a token), the skip near 1; the two branches'
+    way back into the residual 2 (attention: its input is a weighted mean
+    of values, about a quarter of their size) and 0.5 (the mixer: its input
+    is normed); the SwiGLU's gate and up 1, its way back 1; the head 2.5:
+    each branch comes to half the residual's RMS (measured on the chip:
+    0.50, 0.47 and 0.60; PERF.md section 4). A mixer at 1 beside branches
+    at 0.3 was tried: it parts a wrong state from a right one no better and
+    float8 weights from bfloat16 ones half as well.
+    The large ones are in `cfg.dtype`; norms, the convolution and the
+    per-head scalars in float32."""
+    L, H, F, V = cfg.num_layers, cfg.hidden_size, cfg.ffn_size, \
+        cfg.vocab_size
+    nh, nkv, dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    Hs, P, GN = cfg.ssm_heads, cfg.ssm_head_dim, \
+        cfg.ssm_groups * cfg.ssm_state
+    I = Hs * P
+    C = I + 2 * GN
+    f32, big = "float32", cfg.dtype
+    near_one = Normal(1.0, 0.05)
+    root = H ** -0.5
+    m = [cfg.ssm_in_multiplier * v for v in cfg.ssm_multipliers]
+    a_in = cfg.attention_in_multiplier
+    gate_m, down_m = cfg.mlp_multipliers
+    return {
+        "dec.word_emb": ([V, H], big, BlockedNormal(
+            1.0 / cfg.embedding_multiplier, block_rows=_draw_rows(V))),
+        "dec.lm_head": ([H, V], big, BlockedNormal(
+            2.5 * root / cfg.lm_head_multiplier, block_rows=_draw_rows(H))),
+        "dec.final_norm.scale": ([H], f32, near_one),
+        "attn_norm": ([L, H], f32, near_one),
+        "w_in": ([L, H, I + C + Hs], big, BlockedNormal(columns=[
+            (I, root / m[0]), (I, root / m[1]), (GN, 2.0 * root / m[2]),
+            (GN, 2.0 * root / m[3]), (Hs, 0.5 * root / m[4])])),
+        "conv_w": ([L, C, cfg.ssm_conv], f32, Normal(0.0, 0.5)),
+        "conv_b": ([L, C], f32, Normal(0.0, 0.02)),
+        "dt_bias": ([L, Hs], f32, Uniform(-6.9, -2.25)),
+        "a_log": ([L, Hs], f32, Uniform(0.0, 2.77)),
+        "d_skip": ([L, Hs], f32, Normal(1.0, 0.1)),
+        "ssm_norm": ([L, I], f32, near_one),
+        "w_out": ([L, I, H], big, BlockedNormal(
+            0.5 * I ** -0.5 / cfg.ssm_out_multiplier)),
+        "wq": ([L, H, nh * dh], big, Normal(0.0, 2.0 * root / a_in)),
+        "wk": ([L, H, nkv * dh], big,
+               Normal(0.0, root / (a_in * cfg.key_multiplier))),
+        "wv": ([L, H, nkv * dh], big, Normal(0.0, root / a_in)),
+        "wo": ([L, nh * dh, H], big, Normal(
+            0.0, 2.0 * (nh * dh) ** -0.5 / cfg.attention_out_multiplier)),
+        "ffn_norm": ([L, H], f32, near_one),
+        "w_gate": ([L, H, F], big, BlockedNormal(root / gate_m)),
+        "w_up": ([L, H, F], big, BlockedNormal(root)),
+        "w_down": ([L, F, H], big, BlockedNormal(F ** -0.5 / down_m)),
+    }
+
+
+def _draw_rows(rows: int) -> int:
+    """Rows of a `[rows, width]` matrix drawn at a time: the largest power
+    of two up to 1,024 that divides them."""
+    n = 1024
+    while rows % n:
+        n //= 2
+    return n
+
+
+_SSM_POOLS = tuple(zip(("KPool", "VPool", "SPool", "CPool"),
+                       STACKED_POOLS[:2] + STATE_POOLS))
+
+
+def _declare_ssm_pools(cfg, num_pages, page_size, state_slots):
+    block = default_main_program().global_block
+    kv, state = ssm_pool_geometry(cfg, num_pages, page_size, state_slots)
+    declare_stacked_pools(block, *kv)
+    declare_state_pools(block, *state)
+
+
+def _ssm_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
+               page_size: int = 0, state_slots: int = 0, **feeds):
+    """Append the one `parallel_ssm_stack` op of a program; returns its
+    outputs (next_token, logits)."""
+    helper = LayerHelper("parallel_ssm_stack")
+    params = {key: helper.create_parameter(
+        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
+        for key, (shape, dtype, init) in _ssm_param_specs(cfg).items()}
+    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
+              "Head": [params["dec.lm_head"]],
+              "FinalNorm": [params["dec.final_norm.scale"]],
+              "LayerParams": [params[k]
+                              for k in parallel_ssm_ops.LAYER_PARAMS]}
+    inputs.update({slot: [var] for slot, var in feeds.items()})
+    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
+            for slot, dtype in (("NextToken", "int32"),
+                                ("Logits", "float32"))}
+    if mode != "full":
+        _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
+        inputs["StateSlot"] = [L.data(name=SSLOT_FEED, shape=[],
+                                      dtype="int32")]
+        for slot, name in _SSM_POOLS:
+            inputs[slot] = [name]
+            outs[slot + "Out"] = [name]
+    helper.append_op("parallel_ssm_stack", inputs, outs,
+                     dict(_ssm_geometry(cfg), mode=mode,
+                          num_pages=int(num_pages),
+                          num_slots=int(state_slots)))
+    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0]}
+
+
+def _ssm_window_io(out):
+    return {"next_token": out["next_token"], "last_logits": out["logits"],
+            "extra_feeds": [SSLOT_FEED]}
+
+
+def _ssm_prefill(cfg, num_pages, page_size, tok, pos, pages, lens,
+                 state_slots=0):
+    return _ssm_window_io(_ssm_stack(
+        cfg, "prefill", tok, pos, num_pages, page_size, state_slots,
+        PageTable=pages, Lens=lens))
+
+
+def _ssm_window(cfg, num_pages, page_size, tp, tok, pos, pages, start, lens,
+                state_slots=0):
+    # a prompt's chunk or the suffix behind a resumed snapshot (no verify
+    # window)
+    return _ssm_window_io(_ssm_stack(
+        cfg, "window", tok, pos, num_pages, page_size, state_slots,
+        PageTable=pages, Start=start, Lens=lens))
+
+
+def _ssm_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask,
+                state_slots=0):
+    return dict(_ssm_stack(cfg, "decode", tok, pos, num_pages, page_size,
+                           state_slots, PageTable=pages, Mask=mask),
+                extra_feeds=[SSLOT_FEED])
+
+
+def _ssm_full(cfg, tok, pos):
+    return {"logits": _ssm_stack(cfg, "full", tok, pos)["logits"]}
+
+
+def _ssm_cow(cfg, num_pages, page_size, src, dst, state_slots=0):
+    # a page's K/V slab in every layer (a state is never shared: resuming
+    # from a snapshot copies it, `build_state_copy_program`)
+    _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
+    _stacked_copy_page(STACKED_POOLS[:2], num_pages, src, dst)
+
+
+def build_state_copy_program(cfg: DecoderConfig, num_pages: int,
+                             page_size: int, state_slots: int):
+    """Build (in the current default main program) the copy of one slot of
+    recurrent state onto another, in every layer and in place: taking a
+    snapshot, restoring from one. Feeds sv_scopy_src/sv_scopy_dst [1]
+    int32; fetches nothing."""
+    src = L.data(name=SCOPY_SRC_FEED, shape=[], dtype="int32")
+    dst = L.data(name=SCOPY_DST_FEED, shape=[], dtype="int32")
+    _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
+    pools = dict(zip(("SPool", "CPool"), STATE_POOLS))
+    LayerHelper("state_slot_copy").append_op(
+        "state_slot_copy",
+        dict({k: [v] for k, v in pools.items()}, Src=[src], Dst=[dst]),
+        {k + "Out": [v] for k, v in pools.items()},
+        {"num_slots": int(state_slots)})
+    return {"feeds": [SCOPY_SRC_FEED, SCOPY_DST_FEED]}
+
+
 def _proj(x, size, name, act=None):
     return L.fc(x, size=size, num_flatten_dims=len(x.shape) - 1,
                 param_attr=ParamAttr(name=name + ".w"),
@@ -914,8 +1204,10 @@ def _prefill_layer(x, i, cfg: DecoderConfig, pages, lens, write_cache: bool):
     return _ffn_block(x, cfg, name)
 
 
-def _second_pool(window_pages: int) -> dict:
+def _second_pool(window_pages: int, state_slots: int = 0) -> dict:
     """The keyword a family with a second pool takes its size under."""
+    if state_slots:
+        return {"state_slots": int(state_slots)}
     return {"window_pages": int(window_pages)} if window_pages else {}
 
 
@@ -940,7 +1232,8 @@ def _keep_last_token(io: dict, last_token: str, slot) -> dict:
 
 def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int,
                           window_pages: int = 0, token_slots: int = 1,
-                          last_token: str = LAST_TOKEN):
+                          last_token: str = LAST_TOKEN,
+                          state_slots: int = 0):
     """Build (in the current default main program) the bucketed prefill.
 
     Feeds: sv_tok/sv_pos [B, S_bucket] int32, sv_pages [B, P] int32,
@@ -955,7 +1248,7 @@ def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     slot = _last_token_state(last_token, token_slots)
     return _with_feeds(_keep_last_token(_FAMILY[cfg.block]["prefill"](
         cfg, num_pages, page_size, tok, pos, pages, lens,
-        **_second_pool(window_pages)), last_token, slot),
+        **_second_pool(window_pages, state_slots)), last_token, slot),
         [TOK_FEED, POS_FEED, PAGES_FEED, LEN_FEED])
 
 
@@ -1021,7 +1314,8 @@ def _window_layer(x, i, cfg: DecoderConfig, pages, start, lens, tp: int):
 def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
                          tp: int = 1, window_pages: int = 0,
                          token_slots: int = 1,
-                         last_token: str = LAST_TOKEN):
+                         last_token: str = LAST_TOKEN,
+                         state_slots: int = 0):
     """Build (in the current default main program) the windowed forward the
     two ISSUE 11 stages share:
 
@@ -1051,7 +1345,7 @@ def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     slot = _last_token_state(last_token, token_slots)
     return _with_feeds(_keep_last_token(_FAMILY[cfg.block]["window"](
         cfg, num_pages, page_size, tp, tok, pos, pages, start, lens,
-        **_second_pool(window_pages)), last_token, slot),
+        **_second_pool(window_pages, state_slots)), last_token, slot),
         [TOK_FEED, POS_FEED, PAGES_FEED, START_FEED, LEN_FEED])
 
 
@@ -1084,7 +1378,7 @@ def _post_ln_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
 
 
 def build_cow_program(cfg: DecoderConfig, num_pages: int, page_size: int,
-                      window_pages: int = 0):
+                      window_pages: int = 0, state_slots: int = 0):
     """Build (in the current default main program) the copy-on-write step:
     one `kv_cache_copy_page` per layer — pool[Dst] := pool[Src] for K and V,
     in place. Feeds sv_cow_src/sv_cow_dst [1] int32; fetches nothing (the
@@ -1092,8 +1386,9 @@ def build_cow_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     per engine — COW cost is one tiny device step, not a recompile."""
     src = L.data(name=COW_SRC_FEED, shape=[], dtype="int32")
     dst = L.data(name=COW_DST_FEED, shape=[], dtype="int32")
-    more = _FAMILY[cfg.block]["cow"](cfg, num_pages, page_size, src, dst,
-                                     **_second_pool(window_pages))
+    more = _FAMILY[cfg.block]["cow"](
+        cfg, num_pages, page_size, src, dst,
+        **_second_pool(window_pages, state_slots))
     return {"feeds": [COW_SRC_FEED, COW_DST_FEED] + (more or [])}
 
 
@@ -1119,7 +1414,8 @@ def _post_ln_cow(cfg, num_pages, page_size, src, dst):
 def build_decode_program(cfg: DecoderConfig, num_pages: int, page_size: int,
                          tp: int = 1, window_pages: int = 0,
                          token_slots: int = 1,
-                         last_token: str = LAST_TOKEN):
+                         last_token: str = LAST_TOKEN,
+                         state_slots: int = 0):
     """Build (in the current default main program) the ragged decode step.
 
     Feeds: sv_tok [B, 1] int32 (each row's latest token), sv_pos [B] int32
@@ -1143,7 +1439,7 @@ def build_decode_program(cfg: DecoderConfig, num_pages: int, page_size: int,
                       "Last": [last_token]}, {"Out": [chained]}, {})
     return _with_feeds(_keep_last_token(_FAMILY[cfg.block]["decode"](
         cfg, num_pages, page_size, tp, chained, pos, pages, mask,
-        **_second_pool(window_pages)), last_token, slot),
+        **_second_pool(window_pages, state_slots)), last_token, slot),
         [TOK_FEED, POS_FEED, PAGES_FEED, MASK_FEED, FROM_HOST_FEED])
 
 
@@ -1276,4 +1572,7 @@ _FAMILY = {
     "hybrid_moe": {"prefill": _hybrid_prefill, "window": _hybrid_window,
                    "cow": _hybrid_cow, "decode": _hybrid_decode,
                    "full": _hybrid_full},
+    "parallel_ssm": {"prefill": _ssm_prefill, "window": _ssm_window,
+                     "cow": _ssm_cow, "decode": _ssm_decode,
+                     "full": _ssm_full},
 }

@@ -21,7 +21,8 @@ resolved on swept decisions.
 """
 from .db import (DB_SCHEMA, TuningDB, amp_key, attention_key, bucket_key,
                  canonical_key, collective_key, conv_key, embedding_key,
-                 epilogue_key, evidence, moe_experts_key)
+                 epilogue_key, evidence, moe_experts_key,
+                 ssm_update_key)
 from .policy import (consult_enabled, decide, device_kind, get_db,
                      invalidate_db_cache, mode, on_minimize,
                      provenance_snapshot, reset_provenance, sweep_enabled)
@@ -31,7 +32,7 @@ from .learned import maybe_explore
 __all__ = [
     "DB_SCHEMA", "TuningDB", "canonical_key", "conv_key", "attention_key",
     "bucket_key", "amp_key", "collective_key", "epilogue_key",
-    "embedding_key", "moe_experts_key", "evidence",
+    "embedding_key", "moe_experts_key", "ssm_update_key", "evidence",
     "decide", "mode", "consult_enabled",
     "sweep_enabled", "get_db", "invalidate_db_cache", "device_kind",
     "provenance_snapshot", "reset_provenance", "on_minimize",

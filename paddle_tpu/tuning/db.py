@@ -34,7 +34,8 @@ DB_SCHEMA = 1
 
 __all__ = ["DB_SCHEMA", "TuningDB", "canonical_key", "conv_key",
            "attention_key", "bucket_key", "amp_key", "collective_key",
-           "epilogue_key", "embedding_key", "moe_experts_key", "evidence"]
+           "epilogue_key", "embedding_key", "moe_experts_key", "ssm_update_key",
+           "evidence"]
 
 
 def evidence(measured: dict) -> dict:
@@ -102,6 +103,13 @@ def moe_experts_key(tokens: int, experts: int, hidden: int, ffn: int) -> str:
     the token rows of one call against the `experts` x (hidden -> ffn ->
     hidden) SwiGLU stack it holds."""
     return f"t={tokens} e={experts} h={hidden} f={ffn}"
+
+
+def ssm_update_key(rows: int, heads: int, state: int, head_dim: int) -> str:
+    """One-token state-space update decisions
+    (ops/pallas_kernels/ssm_update.py): the rows of one decode step against
+    a pool of `heads` x `[state, head_dim]` float32 states."""
+    return f"b={rows} h={heads} n={state} p={head_dim}"
 
 
 def embedding_key(table: str, vocab: int, dim: int) -> str:

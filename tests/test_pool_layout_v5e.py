@@ -234,6 +234,64 @@ def test_hybrid_moe_programs_compiled_for_v5e_move_neither_pool(v5e_chip):
         "decode": 0, "prefill": 0, "window": 0, "cow": 0}
 
 
+def test_parallel_ssm_programs_compiled_for_v5e_move_no_pool(v5e_chip):
+    """The five programs of the "parallel_ssm" block at the served widths
+    (benchmark/configs/falcon_h1_34b.json: 20 query heads over 4 KV heads of
+    128 beside 32 state-space heads of 128 x 256 in 2 groups, the SwiGLU of
+    21,504; two layers and a vocabulary of 2,048, which size nothing but the
+    weights drawn here) over the cell's K/V pool and 80 slots of state,
+    decode at 64 rows over the cell's one 24-page bucket: Mosaic takes the
+    one-token state update in place in the slot pool and the grouped-query
+    paged kernel at 24 heads (groups of 5 padded to 6); the scanned layer
+    carries K, V and the slots without a copy of any, a window's scan reads
+    and writes its slot where it lies, and the state copy moves two slots'
+    rows and not the pool."""
+    import json
+    import os
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    import numpy as np
+
+    from paddle_tpu.serving import ServingEngine
+    from tools.pool_hlo import serving_program_hlos
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "falcon_h1_34b.json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = DecoderConfig(**dict(engine["config_kwargs"], num_layers=2,
+                               vocab_size=2048))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        eng = ServingEngine(cfg, page_size=engine["page_size"],
+                            pool_pages=engine["pool_pages"], max_inflight=64)
+        texts = serving_program_hlos(eng, rows=64, pages=24,
+                                     device=v5e_chip)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    # K, V and the slots of state (the convolution's tails, 29 MB at the
+    # served size, the compiler moves into fast memory for a decode step
+    # and back: not a relayout, and not counted)
+    sizes = {int(np.prod(eng._scope.find_var(name).shape))
+             for name in ("kv_cache.k", "kv_cache.ssm")}
+    assert {name: sum(len(pool_sized_copies(text, n)) for n in sizes)
+            for name, text in texts.items()} == {
+        "decode": 0, "prefill": 0, "window": 0, "cow": 0, "state_copy": 0}
+    assert "ssm_decode_update" in texts["decode"]
+    assert "paged_decode_attention_gqa" in texts["decode"]
+    assert "f32[64,24,128]" in texts["decode"]      # 20 heads run as 24
+    # the copy slices a slot's rows out of the pool: no gather, and nothing
+    # of the pool's size but the pool
+    assert " gather(" not in texts["state_copy"]
+    slots = eng.state_pool.num_pages
+    assert slots == 80
+    assert f"f32[{2 * slots},2048,128]" not in texts["state_copy"]
+
+
 def test_token_row_gathers_counts_rows_not_slabs():
     """Recorded from the v5e's compiler: PR 29's decode layer fetched a
     selected token from two pools, PR 30's from one; a page's slab of

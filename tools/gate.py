@@ -92,6 +92,7 @@ def check_kernel_registry() -> int:
         "epilogue": tuning.epilogue_key,
         "conv2d": tuning.conv_key,
         "moe_experts": tuning.moe_experts_key,
+        "ssm_update": tuning.ssm_update_key,
     }
     test_defs = []
     for path in glob.glob(os.path.join(REPO, "tests", "*.py")):

@@ -300,7 +300,38 @@ def _moe_cases(spec):
          lambda t=t: wide_case(t)) for t in (64, 512)]
 
 
+def _ssm_update_cases(spec):
+    """The "parallel_ssm" geometry: 32 heads of a 256 x 128 float32 state in
+    2 groups, a pool of 2 layers x 20 slots, 16 rows of a decode step on
+    slots of their own and 4 padding rows on the scratch slot (whose state
+    nobody reads: left out of the comparison). The pool and y together."""
+
+    def case():
+        rows, H, N, P, G, B = 40, 32, 256, 128, 2, 20
+        ks = jax.random.split(jax.random.PRNGKey(11), 6)
+        pool = _rand(ks[0], (rows, H * N, P), "float32")
+        idx = jnp.concatenate([
+            20 + jax.random.permutation(ks[1], 19)[:16].astype(jnp.int32),
+            jnp.full((4,), 39, jnp.int32)])
+        a = jax.nn.sigmoid(_rand(ks[2], (B, H), "float32") + 2.0)
+        dtx = _rand(ks[3], (B, H, P), "float32", 0.1)
+        bm = _rand(ks[4], (B, G, N), "float32")
+        cm = _rand(ks[5], (B, G, N), "float32")
+        assert spec.supported(pool.shape, N, H // G)
+
+        def both(fn):
+            new_pool, y = fn(pool, idx, a, dtx, bm, cm)
+            return jnp.concatenate([new_pool[:39].reshape(-1),
+                                    y[:16].reshape(-1)])
+
+        return _compare(lambda: both(spec.fn), lambda: both(spec.reference),
+                        (), 0, "float32")
+
+    return [("b20 (16 live) h32 n256 p128 g2 pool 2x20 float32", case)]
+
+
 CASES = {
+    "ssm_decode_update": _ssm_update_cases,
     "attention_paged_decode": lambda spec: (_paged_cases(spec)
                                             + _paged_gqa_cases(spec)),
     "moe_top1_experts": _moe_cases,

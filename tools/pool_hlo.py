@@ -195,14 +195,16 @@ def _program_hlo(exe, target, feed, fetch_list, scope, device=None) -> str:
 
 def serving_program_cases(engine, rows: int = 64, pages: int = 32,
                           prompt: int = 128) -> dict[str, tuple]:
-    """One run of each of the four serving programs of `engine`, as
+    """One run of each of the serving programs of `engine` (four, and the
+    state copy where the family has a recurrent state), as
     `{name: (target, feed, fetch_list)}` for `Executor.run`: decode at
     `rows` x `pages`, cold prefill of a `prompt` bucket, the window program
     (suffix prefill) of the same bucket behind `pages` pages, and
     copy-on-write of page 0 onto itself. Every row is masked or of length
     0, so a run writes nothing. Feeds and fetches are the engine's own
-    (`_mark_feed`, `_slot_feed`, `_window_feed`: the compact tables of a second,
-    sliding-window pool where the family has one, `_step_fetches`): the
+    (`_mark_feed`, `_slot_feed`, `_state_feed`, `_window_feed`: the compact
+    tables of a second, sliding-window pool and the slots of a recurrent
+    state where the family has them, `_step_fetches`): the
     signature it serves with."""
     from paddle_tpu.serving import model as m
 
@@ -214,6 +216,7 @@ def serving_program_cases(engine, rows: int = 64, pages: int = 32,
             m.PAGES_FEED: np.zeros((rows, pages), i32),
             m.MASK_FEED: np.zeros((rows, 1), np.float32),
             **e._mark_feed(), **e._slot_feed((), rows, decode=True),
+            **e._state_feed((), rows),
             **e._window_feed((), rows, e._wtable_decode)},
             e._step_fetches(e._decode_io)),
         "prefill": (e._prefill_run, {
@@ -221,6 +224,7 @@ def serving_program_cases(engine, rows: int = 64, pages: int = 32,
             m.POS_FEED: np.zeros((1, prompt), i32),
             m.PAGES_FEED: np.zeros((1, e.pool.pages_for(prompt)), i32),
             m.LEN_FEED: np.zeros((1,), i32), **e._slot_feed((), 1),
+            **e._state_feed((), 1),
             **e._window_feed((), 1, e._wtable_chunk)},
             e._step_fetches(e._prefill_io, "last_logits")),
         "window": (e._window_run, {
@@ -229,11 +233,17 @@ def serving_program_cases(engine, rows: int = 64, pages: int = 32,
             m.PAGES_FEED: np.zeros((1, pages), i32),
             m.START_FEED: np.zeros((1,), i32),
             m.LEN_FEED: np.zeros((1,), i32), **e._slot_feed((), 1),
+            **e._state_feed((), 1),
             **e._window_feed((), 1, e._wtable_chunk)},
             e._step_fetches(e._window_io, "last_logits")),
         # a family with a second pool copies a page of each
         "cow": (e._cow_run, {name: np.zeros((1,), i32)
                              for name in e._cow_io["feeds"]}, []),
+        # a family with a recurrent state: one slot of it onto another
+        **({"state_copy": (e._state_copy_run, {
+            m.SCOPY_SRC_FEED: np.zeros((1,), i32),
+            m.SCOPY_DST_FEED: np.zeros((1,), i32)}, [])}
+           if e._state_copy_run is not None else {}),
     }
 
 
