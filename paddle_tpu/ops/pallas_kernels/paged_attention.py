@@ -25,7 +25,7 @@ gather IS the decode step's HBM bill. This kernel never materializes it:
     kernel reads the resident buffer — no conversion before the call.
   * the ragged part: rows in one batch have different context lengths
     (`kv_lens`, also scalar-prefetched). A block is computed in chunks of
-    `MXU_CHUNK_TOKENS` / `VPU_CHUNK_TOKENS` slots, one online-softmax update
+    `MXU_CHUNK_TOKENS` / `LANE_CHUNK_TOKENS` slots, one online-softmax update
     a chunk (one max, one rescale of m, l, acc) over all its pages: slots
     past a row's length are masked to -1e30, a chunk or block with no live
     slot is branched around, and rows the continuous-batching scheduler
@@ -33,11 +33,16 @@ gather IS the decode step's HBM bill. This kernel never materializes it:
     batch_mask convention from PR 2.
   * heads never leave the lanes: a token's row holds head h in lanes
     h*dh..(h+1)*dh. With as many KV heads as query heads, q.k is one
-    [tokens, nh*dh] VPU product a 128-lane column, and the per-head sum is
-    a butterfly of lane rotations inside each dh-lane segment that leaves
-    every lane holding its head's score: float32 products and sums, no MXU
-    pass. With fewer KV heads (grouped-query, dh = 128) a head is a whole
-    register and q.k, p.v are MXU products of float32 exactness (`_dot3`).
+    [tokens, nh*dh] float32 VPU product a 128-lane column, and the per-head
+    sum is an MXU product of that column with a 0/1 matrix that joins the
+    lanes of one head (`_head_sums`): it leaves every lane holding its
+    head's score. The product is split into the three bfloat16 pieces that
+    hold every bit of a float32 and the MXU accumulates in float32, so the
+    sum is the float32 sum of the same dh terms: nothing is rounded to
+    bfloat16 (a butterfly of lane rotations did this until PR 38 and was
+    73% of the call). With fewer KV heads (grouped-query, dh = 128) a head
+    is a whole register and q.k, p.v are MXU products of float32 exactness
+    (`_dot3`).
     The online softmax state (m, l, acc; the per-head statistics repeated
     over the head's lanes) lives in VMEM scratch across the blocks of one
     row; the output block is written once, on the row's last grid step.
@@ -92,22 +97,6 @@ def paged_supported(q_shape, pool_shape, pool_dtype=jnp.float32) -> bool:
             and nh % nkv == 0 and nh % 8 == 0 and ps % _LANES == 0)
 
 
-def _head_sums(x, head_dim):
-    """x [ps, 128]: every lane's sum over the `head_dim`-lane segment it
-    lies in (a head; segments are aligned, `head_dim` a power of two).
-    Butterfly all-reduce: at step s a lane adds its partner `lane ^ s`,
-    fetched by one rotation in each direction."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    s = 1
-    while s < head_dim:
-        # roll(x, k)[i] = x[i - k]: the partner sits s lanes below where
-        # bit s of the lane is set, s lanes above where it is clear
-        x = x + jnp.where(lane & s != 0, pltpu.roll(x, s, 1),
-                          pltpu.roll(x, _LANES - s, 1))
-        s *= 2
-    return x
-
-
 # VMEM one grid step's K + V pages may take, all together (the kernel keeps
 # two such blocks: the one it computes on and the next one's DMAs in
 # flight). Sixteen pages of a 64 KB grouped-query slab or of the 48 KB slab
@@ -118,11 +107,14 @@ BLOCK_BYTES = 2 * 1024 * 1024
 # is the kernel's code, and a decode program holds a copy for every layer:
 # unrolled chunk and column bodies made a decode program 4.5 MB larger and
 # a serving cell's warm set-up 9 s longer; my chip run, PR 26).
-# The MXU arm keeps four MXUs busy over eight 128-token pages. The VPU arm
-# pays its reductions and rescales once a chunk and column, so its chunk is
-# the whole block of 16 x 16 slots (64: 670 us a call where 256 takes 358).
+# The grouped arm keeps four MXUs busy over eight 128-token pages. The arm
+# with the heads side by side in the lanes pays its reductions over the
+# slots and its rescales once a chunk and column, so its chunk is the whole
+# block of 16 x 16 slots: 64 rows of 19-28 pages in a 32-page bucket take
+# 337 us a call at 256 slots a chunk, 401 at 128 and 571 at 64, although
+# the smaller chunks skip dead pages (my chip run, PR 38).
 MXU_CHUNK_TOKENS = 1024
-VPU_CHUNK_TOKENS = 256
+LANE_CHUNK_TOKENS = 256
 
 
 def pages_per_grid_step(bucket_pages: int, page_size: int, width: int,
@@ -177,6 +169,24 @@ def _dot3(a3, b, dims):
               for p in pieces)
     m = a3.shape[0] // 3
     return out[:m] + out[m:2 * m] + out[2 * m:]
+
+
+def _head_sums(x, head_dim):
+    """x [tokens, 128] float32: every lane's sum over the `head_dim`-lane
+    segment it lies in (a head; segments are aligned, `head_dim` a power of
+    two), on the MXU: each of x's three bfloat16 pieces times the 0/1
+    matrix that is 1 where two lanes share a head. A piece times 1.0 is
+    exact and the MXU accumulates in float32: the float32 sum of the
+    head's terms, no bit of x dropped. The matrix is an iota comparison
+    (8 registers a column; as a hoisted value it measured the same)."""
+    shift = head_dim.bit_length() - 1
+    row_head, lane_head = (
+        jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), d) >> shift
+        for d in (0, 1))
+    same_head = jnp.where(row_head == lane_head, 1.0, 0.0).astype(
+        jnp.bfloat16)
+    return sum(jnp.dot(piece, same_head, preferred_element_type=jnp.float32)
+               for piece in _bf16_pieces(x, 3))
 
 
 def _page_copy(start, pools, bufs, sem, src, half, slot):
@@ -333,10 +343,11 @@ def _chunk_update(q_ref, k_ref, v_ref, n_live, m_ref, l_ref, acc_ref, *,
                   sm_scale, head_dim):
     """`k_ref`, `v_ref` [pages, ps, nh*dh]: a chunk whose first `n_live`
     tokens are live. One query token per row: the step is bound by the page
-    DMAs and the VPU, not by FLOPs, so q.k and p.v stay on the VPU in
-    float32, one 128-lane column at a time (whole heads: dh divides 128; a
-    loop, so the code is one column's). float32 products and sums
-    throughout: no MXU pass, so nothing is rounded to bfloat16."""
+    DMAs and the VPU, not by FLOPs, so the q.k and p.v products stay on the
+    VPU in float32, one 128-lane column at a time (whole heads: dh divides
+    128; a loop, so the code is one column's); only a head's sum of q.k
+    passes the MXU, split so that it stays a float32 sum (`_head_sums`).
+    Nothing is rounded to bfloat16."""
     pages, ps, width = k_ref.shape
 
     def column(c, carry):
@@ -467,7 +478,7 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret,
     out = pl.pallas_call(
         functools.partial(
             _kernel, chunk_update=update, page_size=ps, group=group,
-            chunk_tokens=MXU_CHUNK_TOKENS if grouped else VPU_CHUNK_TOKENS,
+            chunk_tokens=MXU_CHUNK_TOKENS if grouped else LANE_CHUNK_TOKENS,
             **({"windowed": True} if windowed else {})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B,) + row_shape[1:], out_dtype),

@@ -332,15 +332,16 @@ def test_sorts_over_finds_the_context_sort_and_passes_a_routers():
 @pytest.mark.parametrize("q_shape,pool,dtype,bucket", [
     ((64, 12, 64), (3072, 16, 768), "float32", 32),
     ((64, 8, 128), (24 * 640, 128, 256), "bfloat16", 16),
-], ids=["post_ln_P32", "cca_moe_P16_stacked"])
+    ((16, 12, 64), (3072, 16, 768), "float32", 16),
+], ids=["post_ln_P32", "cca_moe_P16_stacked", "post_ln_16rows_P16"])
 def test_paged_decode_blocks_fit_their_vmem_budget_on_v5e(
         v5e_chip, q_shape, pool, dtype, bucket):
     """The blocked decode kernel alone at the serving cells' sizes (64 rows;
     a bucket of 32 pages of 16 float32 slots, and of 16 pages of 128
-    bfloat16 slots over the stacked pool): a grid step covers 16 pages, its
-    K + V block stays under `BLOCK_BYTES` (the kernel holds two: 4 MB of
-    the chip's 16 MB of scoped VMEM), and Mosaic takes it with the pools
-    left in HBM."""
+    bfloat16 slots over the stacked pool; the chat cell's 16 rows of 16
+    pages): a grid step covers 16 pages, its K + V block stays under
+    `BLOCK_BYTES` (the kernel holds two: 4 MB of the chip's 16 MB of scoped
+    VMEM), and Mosaic takes it with the pools left in HBM."""
     import jax
     import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache

@@ -125,6 +125,82 @@ def test_paged_attention_pallas_matches_reference(monkeypatch, ps, nh, dh,
     assert not out[~live].any()
 
 
+def _butterfly_head_sums(x, head_dim):
+    """The form `_head_sums` had until PR 38, kept as the oracle: at step
+    s a lane adds its partner `lane ^ s`; float32 sums."""
+    lane = np.arange(x.shape[1])
+    s = 1
+    while s < head_dim:
+        x = x + np.where(lane & s != 0, np.roll(x, s, 1), np.roll(x, -s, 1))
+        s *= 2
+    return x
+
+
+@pytest.mark.parametrize("head_dim", [8, 16, 32, 64, 128])
+def test_head_sums_on_the_mxu_are_float32_sums(head_dim):
+    """The `nkv == nh` arm's head sum alone: three bfloat16 pieces of the
+    float32 products through a 0/1 matrix give the float32 sum the
+    butterfly gave. The products of one head span 2^-20..2^20, so ONE
+    bfloat16 pass is wrong by orders of magnitude where the three pieces
+    are within float32 rounding of the exact sum."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    rng = np.random.default_rng(head_dim)
+    x = (rng.choice([-1.0, 1.0], (64, 128)) * rng.uniform(1, 2, (64, 128))
+         * 2.0 ** rng.integers(-20, 21, (64, 128))).astype(np.float32)
+    heads = x.reshape(64, 128 // head_dim, head_dim).astype(np.float64)
+    exact = np.repeat(heads.sum(-1), head_dim, axis=1)
+    scale = np.repeat(np.abs(heads).sum(-1), head_dim, axis=1)
+    # float32 rounding (three pieces read 2e-7 here, TWO read 2e-6 to 7e-6)
+    rounding = 1e-6 * scale
+    got = np.asarray(ppa._head_sums(jnp.asarray(x), head_dim))
+    assert got.dtype == np.float32
+    assert (np.abs(got - exact) <= rounding).all()
+    assert (np.abs(got - _butterfly_head_sums(x, head_dim)) <= rounding).all()
+    lane = np.arange(128)
+    same_head = (lane[:, None] // head_dim == lane[None] // head_dim)
+    one_pass = np.asarray(jnp.dot(
+        jnp.asarray(x).astype(jnp.bfloat16),
+        jnp.asarray(same_head, jnp.bfloat16),
+        preferred_element_type=jnp.float32))
+    assert (np.abs(one_pass - exact) > 100 * rounding).any()
+
+
+def _all_eqns(jaxpr):
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize("head_dim", [64, 16])
+def test_column_update_sums_heads_without_a_lane_rotation(head_dim):
+    """The arm's column body as the kernel traces it: no `roll` (the
+    butterfly is gone) and the head sum is a `dot_general` of bfloat16
+    operands accumulated in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    row = jnp.zeros((1, 128), jnp.float32)
+    slab = jnp.zeros((ppa.LANE_CHUNK_TOKENS, 128), jnp.float32)
+    eqns = list(_all_eqns(jax.make_jaxpr(
+        lambda *a: ppa._column_update(*a, sm_scale=0.125, head_dim=head_dim)
+    )(row, slab, slab, jnp.int32(40), row, row, row).jaxpr))
+    assert not [e for e in eqns if "roll" in e.primitive.name]
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 3
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+        assert e.outvars[0].aval.dtype == jnp.float32
+        assert e.outvars[0].aval.shape == slab.shape
+
+
 @pytest.mark.parametrize("nh,dh,ps,chosen", [
     (12, 64, 16, "pallas_paged"),    # nh*dh = 768: whole (8, 128) tiles
     (2, 64, 8, "pallas_paged"),
