@@ -335,6 +335,22 @@ DECLARED: list[tuple] = [
     ("serving.sparse.layer_steps", COUNTER,
      "layer x decode-step pairs in which the indexer ran (a table wider "
      "than index_topk slots)", ()),
+    # -- a latent cache row and a share of the experts (ISSUE 39) -----------
+    ("serving.latent.gathered_rows", COUNTER,
+     "cache rows decode rows read out of the latent pool, summed over "
+     "rows, layers and steps: their selection's, or every slot of a table "
+     "that fits the selection (x a row's bytes: what the gather had to "
+     "read)", ()),
+    ("serving.latent.attended_tokens", COUNTER,
+     "live cached tokens decode rows attended in the latent, summed over "
+     "rows, layers and steps (x a row's bytes, or x the absorbed form's "
+     "operations a token)", ()),
+    ("serving.moe.routed_pairs", COUNTER,
+     "(token, expert) pairs the router made, summed over layers (prefill "
+     "and decode), in an engine that holds a share of the experts", ()),
+    ("serving.moe.held_pairs", COUNTER,
+     "those of serving.moe.routed_pairs that fell on the experts this "
+     "engine holds (1 / shares of them under uniform routing)", ()),
     # -- window and full attention layers over two pools (ISSUE 33) ---------
     ("serving.kv.window_release.seconds", HISTOGRAM,
      "returning to the sliding layers' pool the pages a row's window has "
@@ -515,6 +531,9 @@ PIECES = frozenset({
     "indexer",    # sparse_moe: gather of the key pages and their scores
     "select",     # sparse_moe: the cut and the mask or the indices
     "kv_gather",  # rows or pages of K/V gathered out of a pool
+    "latent_gather",  # latent_moe: cache rows gathered out of the pool
+    "q_absorb",   # latent_moe: per-head products into and out of the latent
+    "shared",     # latent_moe: the shared expert
     "attend",     # the attention itself (a Pallas kernel sits inside)
     "router",     # expert choice and combine weights
     "experts",    # the routed experts

@@ -47,28 +47,44 @@ _TOKEN_TILE = 256
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _f_tile(ffn: int) -> int:
+# three `[H, tile]` slabs of one grid step, single-buffered: what a wide
+# tile (512, 384, a whole width) may take, and what a narrow one may where
+# the wide one does not fit (twice that is double-buffered beside the token
+# tile and the float32 output tile, under `_VMEM_LIMIT`)
+_WIDE_SLABS = 8 * 1024 * 1024
+_NARROW_SLABS = 12 * 1024 * 1024
+
+
+def _f_tile(ffn: int, hidden: int = 0, itemsize: int = 2) -> int:
     """Columns of an expert's width one grid step takes: 512, or 384 for a
     width only that divides (768 = 2 x 384: three double-buffered slabs of
-    2048 x 384 bfloat16 are 9.4 MB of VMEM), else the whole width."""
-    for tile in (512, 384):
-        if ffn % tile == 0:
-            return tile
-    return ffn
+    2048 x 384 bfloat16 are 9.4 MB of VMEM), else the whole width. Where
+    three `[hidden, tile]` slabs of that pass `_WIDE_SLABS` (a hidden size
+    of 7168: 22 MB at 512), 256 or 128 columns if they divide the width
+    and fit `_NARROW_SLABS` (7168 x 256 bfloat16: 11 MB); every shape the
+    wide tile fits keeps it."""
+    wide = next((tile for tile in (512, 384) if ffn % tile == 0), ffn)
+    if 3 * hidden * wide * itemsize <= _WIDE_SLABS:
+        return wide
+    return next((tile for tile in (256, 128) if ffn % tile == 0
+                 and 3 * hidden * tile * itemsize <= _NARROW_SLABS), wide)
 
 
 def experts_supported(z_shape, w_gate_shape, dtype) -> bool:
     """z [T, H] against W_gate `[L, E, H, F]`: whole 128-lane rows on both
     widths, a 2- or 4-byte dtype, at most 256 experts (the combine weights
     ride one lane register a 128 experts, however many of them a token's
-    row fills) and an F tile (`_f_tile`) whose three slabs fit 8 MB."""
+    row fills) and an F tile (`_f_tile`) whose three slabs fit: 8 MB a
+    wide tile, 12 MB a narrow one."""
     if len(z_shape) != 2 or len(w_gate_shape) != 4:
         return False
     _, E, H, F = w_gate_shape
+    itemsize = jnp.dtype(dtype).itemsize
+    tile = _f_tile(F, H, itemsize)
     return (z_shape[1] == H and H % _LANES == 0 and F % _LANES == 0
-            and E <= 2 * _LANES and jnp.dtype(dtype).itemsize in (2, 4)
-            and 3 * H * _f_tile(F) * jnp.dtype(dtype).itemsize
-            <= 8 * 1024 * 1024)
+            and E <= 2 * _LANES and itemsize in (2, 4)
+            and 3 * H * tile * itemsize
+            <= (_NARROW_SLABS if tile < 384 else _WIDE_SLABS))
 
 
 def _kernel(layer_ref, z_ref, cw_ref, wg_ref, wu_ref, wd_ref, o_ref):
@@ -99,7 +115,7 @@ def _call(z, cw, w_gate, w_up, w_down, layer, tag, interpret, topk=False):
     sub = 32 // dtype.itemsize                  # sublanes of one tile
     tt = _TOKEN_TILE if T > _TOKEN_TILE else -(-T // sub) * sub
     t_pad = -(-T // tt) * tt
-    tf = _f_tile(F)
+    tf = _f_tile(F, H, dtype.itemsize)
     lanes = -(-E // _LANES) * _LANES
     zp = jnp.zeros((t_pad, H), dtype).at[:T].set(z.astype(dtype))
     cwp = jnp.zeros((t_pad, lanes), jnp.float32).at[:T, :E].set(

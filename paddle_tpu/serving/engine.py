@@ -798,6 +798,9 @@ class ServingEngine:
             # chunked prefill and learned sparse attention (ISSUE 29)
             "prefill.chunks": 0, "sparse.context_tokens": 0,
             "sparse.selected_tokens": 0, "sparse.layer_steps": 0,
+            # a latent cache row and a share of the experts (ISSUE 39)
+            "latent.gathered_rows": 0, "latent.attended_tokens": 0,
+            "moe.routed_pairs": 0, "moe.held_pairs": 0,
             # window and full attention layers over two pools (ISSUE 33)
             "kv.window_pages_released": 0, "kv.window_row_pages": 0,
             "kv.global_row_pages": 0, "attn.full_context_tokens": 0,
@@ -2219,6 +2222,12 @@ class ServingEngine:
         for e in np.flatnonzero(per_expert):
             obs.counter_inc("serving.moe.tokens", int(per_expert[e]),
                             {"expert": str(e)})
+        if self.cfg.experts_held:
+            # (token, expert) pairs the router made, and those of them that
+            # fell on the experts this engine holds
+            self._count("moe.routed_pairs", int(per_expert.sum()))
+            self._count("moe.held_pairs",
+                        int(per_expert[:self.cfg.experts_held].sum()))
 
     def _route_pages(self, req: GenRequest, first: int, n: int):
         """The page of each of `req`'s positions first .. first + n - 1, as
@@ -2252,7 +2261,9 @@ class ServingEngine:
         np.add.at(per_layer, (np.broadcast_to(layer_of, routes.shape),
                               routes), 1)
         self._count_routed(per_layer.sum(axis=0))
-        self._count("moe.experts_touched", int(np.count_nonzero(per_layer)))
+        # of the experts this engine holds (all of them, but for a share)
+        self._count("moe.experts_touched", int(np.count_nonzero(
+            per_layer[:, :self.cfg.held_experts])))
         self._count("moe.layer_steps", L)
 
     def _mark(self, req: GenRequest) -> None:
@@ -2738,6 +2749,17 @@ class ServingEngine:
             self._count("sparse.selected_tokens",
                         L * sum(min(k, pos + 1) for pos, _ in at))
             self._count("sparse.layer_steps", L)
+        if self.cfg.latent:
+            # cache rows the rows' attention read out of the latent pool
+            # (their selection's, or every slot of a table that fits it)
+            # and how many of them were live positions
+            L, k = self.cfg.num_layers, self.cfg.index_topk
+            select = self.cfg.selects_within(pb * ps)
+            self._count("latent.gathered_rows", L * (
+                sum(min(k, pos + 1) for pos, _ in at) if select
+                else len(at) * pb * ps))
+            self._count("latent.attended_tokens", L * sum(
+                min(k, pos + 1) if select else pos + 1 for pos, _ in at))
         handles = self._run_step("decode", self._decode_run, self._decode_io,
                                  feed, greedy, selection=bool(marked))
         self._enqueued(_InFlight("decode", rows, at=at, marked=marked,

@@ -52,7 +52,8 @@ import jax.numpy as jnp
 
 __all__ = ["PagedKVPool", "PrefixCache", "OwnedPoolView", "pool_var_names",
            "pool_shape", "create_device_pools", "declare_pool_vars",
-           "STACKED_POOLS", "INDEX_POOL", "JOINED_POOL", "WINDOW_POOLS",
+           "STACKED_POOLS", "INDEX_POOL", "JOINED_POOL", "LATENT_POOL",
+           "WINDOW_POOLS",
            "STATE_POOLS", "stacked_pool_shapes", "state_pool_shapes",
            "declare_stacked_pools", "declare_state_pools",
            "create_stacked_pools", "create_state_pools"]
@@ -120,6 +121,11 @@ def create_device_pools(scope, num_layers: int, num_pages: int,
 STACKED_POOLS = ("kv_cache.k", "kv_cache.v", "kv_cache.state")
 INDEX_POOL = "kv_cache.index"
 JOINED_POOL = "kv_cache.kv"
+# A family whose attention keeps ONE compressed row a token ("latent_moe":
+# a latent and its one rotary key, 576 values where K and V of every head
+# would be 32,768) keeps it as 32-bit words of one pool, gathered a token
+# at a time like the joined rows, beside the indexer keys.
+LATENT_POOL = "kv_cache.latent"
 # A family with sliding-window layers ("hybrid_moe") keeps THEIR K and V in
 # a second pair of stacked pools with page ids of their own (a second
 # `PagedKVPool`): a sliding layer needs the last `window` tokens of a row
@@ -143,12 +149,15 @@ STATE_POOLS = ("kv_cache.ssm", "kv_cache.conv")
 def stacked_pool_shapes(num_layers: int, num_pages: int, page_size: int,
                         kv_width: int, state_width: int, dtype: str,
                         index_width: int = 0, joined: bool = False,
-                        names: tuple = STACKED_POOLS):
+                        names: tuple = STACKED_POOLS, latent: bool = False):
     """[(name, shape, dtype)] of the stacked pools. K and V rows are
     `kv_width = num_kv_heads * head_dim` wide (`pool_shape`'s lane-dense
     row), in a pool each or, `joined`, side by side in one row of 32-bit
     words of one pool (`sparse_moe_ops.join_rows_fn`: the same bytes, and a
-    token read with one address). With `state_width` a state pool holds one
+    token read with one address); `latent`, `kv_width` is the width of a
+    token's ONE compressed row, kept as words of `LATENT_POOL` alone (past
+    128 words the row is padded to whole 128-lane tiles). With
+    `state_width` a state pool holds one
     float32 row a page: the state after the page's latest token, final once
     the page is full. With
     `index_width` an index pool holds `index_width` values a token in the
@@ -159,7 +168,15 @@ def stacked_pool_shapes(num_layers: int, num_pages: int, page_size: int,
     # `names`: the K, V (and state) pools' names, for a second set of them
     rows = int(num_layers) * int(num_pages)
     kv = (rows, int(page_size), int(kv_width))
-    if joined:
+    if latent:
+        # rows wider than a 128-lane tile are whole tiles: the chip's
+        # client stores a `[rows, 128, 288]` array slots-minor, and every
+        # step would copy the pool into the row-major form and back
+        words = int(kv_width) * jnp.dtype(dtype).itemsize // 4
+        if words > 128:
+            words = -(-words // 128) * 128
+        pools = [(LATENT_POOL, kv[:2] + (words,), "int32")]
+    elif joined:
         words = 2 * int(kv_width) * jnp.dtype(dtype).itemsize // 4
         pools = [(JOINED_POOL, kv[:2] + (words,), "int32")]
     else:

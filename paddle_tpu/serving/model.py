@@ -1,4 +1,4 @@
-"""Serving program builders. FIVE block families exist, and
+"""Serving program builders. SIX block families exist, and
 `DecoderConfig.block` selects one:
 
   * `"post_ln"` (the default; every other field at its default is the "bert
@@ -62,6 +62,26 @@
     `build_state_copy_program` copies one slot onto another (a snapshot
     the prefix cache keeps, a restore from one). Prompts run in
     `prefill_chunk`-token windows as "sparse_moe"'s do.
+  * `"latent_moe"` (`ops/latent_moe_ops.py`; DeepSeek-V3.2's layer):
+    multi-head LATENT attention, whose cache row is a token's normalised
+    latent (`kv_lora_rank` values) and its one rotary key (`rope_head_dim`)
+    as words of ONE pool (`kv_cache.LATENT_POOL`), read through the
+    "sparse_moe" indexer (its queries taken from the query latent,
+    `q_lora_rank`; its keys in `kv_cache.INDEX_POOL`) in two forms over the
+    same weights: expanded per-head K/V where a window's whole table fits
+    the selection, absorbed projections (every head reading the same
+    gathered rows) for decode rows and windows behind a longer context;
+    rotary under YaRN with the softmax scale times `softmax_mscale`^2;
+    `dense_layers` leading SwiGLU layers (`dense_ffn_size`), then layers
+    that route each token to `experts_per_token` of `num_experts`
+    sigmoid-scored experts chosen inside `groups_per_token` of
+    `expert_groups` groups, beside one shared expert. `experts_held` says
+    how many of the experts THIS engine holds (the first ones: one chip's
+    share of a deployment that divides them); the router keeps all its
+    outputs and the layer computes its own experts' part. Weights stacked
+    by layer kind, the routed layers scanned. Prompts run in
+    `prefill_chunk`-token windows and every program hands back its
+    `selection`, as "sparse_moe"'s do.
 
 Every family is expressed several times over ONE weight namespace:
 
@@ -96,16 +116,16 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 from ..initializer import (BlockedNormal, Constant, Normal, StackedNormal,
                            Uniform)
-from ..ops import (cca_moe_ops, hybrid_moe_ops, parallel_ssm_ops,
-                   sparse_moe_ops)
-from .kv_cache import (INDEX_POOL, JOINED_POOL, STACKED_POOLS, STATE_POOLS,
-                       WINDOW_POOLS, declare_pool_vars,
+from ..ops import (cca_moe_ops, hybrid_moe_ops, latent_moe_ops,
+                   parallel_ssm_ops, sparse_moe_ops)
+from .kv_cache import (INDEX_POOL, JOINED_POOL, LATENT_POOL, STACKED_POOLS,
+                       STATE_POOLS, WINDOW_POOLS, declare_pool_vars,
                        declare_stacked_pools, declare_state_pools,
                        pool_var_names)
 
 __all__ = ["DecoderConfig", "decoder_tiny", "cca_moe_tiny",
            "sparse_moe_tiny", "hybrid_moe_tiny", "parallel_ssm_tiny",
-           "layer_plan", "build_prefill_program",
+           "latent_moe_tiny", "layer_plan", "build_prefill_program",
            "build_decode_program", "build_window_program",
            "build_state_copy_program",
            "build_full_forward_program", "apply_tp_annotations"]
@@ -205,6 +225,18 @@ class DecoderConfig:
     key_multiplier: float = 1.0
     mlp_multipliers: tuple = (1.0, 1.0)
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    # "latent_moe" only (it also reads the indexer's fields, `yarn`,
+    # `shared_expert_size`, `dense_ffn_size` and `routed_scaling` above;
+    # `attn_head_dim` is a head's width WITHOUT rotary)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    rope_head_dim: int = 0
+    v_head_dim: int = 0
+    softmax_mscale: float = 1.0    # scores x this squared
+    dense_layers: int = 0          # leading layers with a dense SwiGLU
+    expert_groups: int = 1
+    groups_per_token: int = 1
+    experts_held: int = 0          # 0: all of num_experts
     # a deployment's choice, any family: the fewest rows a decode step is
     # compiled for (a power of two). Steps of fewer live rows pay for that
     # many; every row bucket below it is a program less to compile
@@ -242,6 +274,39 @@ class DecoderConfig:
             if not 1 <= self.experts_per_token <= self.num_experts:
                 raise ValueError("block 'hybrid_moe' needs num_experts >= "
                                  "experts_per_token >= 1")
+        if self.block == "latent_moe":
+            held = self.experts_held or self.num_experts
+            if min(self.q_lora_rank, self.kv_lora_rank, self.rope_head_dim,
+                   self.v_head_dim, self.index_heads, self.index_head_dim,
+                   self.index_topk, self.prefill_chunk,
+                   self.shared_expert_size) < 1 \
+                    or self.rope_head_dim % 2 or self.kv_lora_rank % 2 \
+                    or self.index_head_dim < self.rope_head_dim \
+                    or self.yarn and len(self.yarn) != 5:
+                raise ValueError(
+                    "block 'latent_moe' needs q_lora_rank, kv_lora_rank and "
+                    "rope_head_dim (even), v_head_dim, index_heads, "
+                    "index_head_dim (at least rope_head_dim: its first "
+                    "lanes carry the rotary), index_topk, prefill_chunk, "
+                    "shared_expert_size and yarn as () or five values")
+            if not 1 <= self.dense_layers < self.num_layers \
+                    or self.dense_ffn_size < 1:
+                raise ValueError(
+                    "block 'latent_moe' needs 1 <= dense_layers < "
+                    "num_layers (dense layers lead, routed ones follow) "
+                    "and dense_ffn_size")
+            if not 1 <= self.experts_per_token <= self.num_experts \
+                    or self.num_experts % self.expert_groups \
+                    or not 1 <= self.groups_per_token <= self.expert_groups \
+                    or self.num_experts // self.expert_groups < 2 \
+                    or self.experts_per_token > self.groups_per_token \
+                    * (self.num_experts // self.expert_groups) \
+                    or not 1 <= held <= self.num_experts:
+                raise ValueError(
+                    "block 'latent_moe' needs num_experts in expert_groups "
+                    "equal groups of at least two, groups_per_token of "
+                    "them holding experts_per_token, and 1 <= experts_held "
+                    "<= num_experts")
         if self.block == "sparse_moe":
             if min(self.index_heads, self.index_head_dim, self.index_topk,
                    self.prefill_chunk) < 1 or self.index_head_dim % 4:
@@ -295,7 +360,7 @@ class DecoderConfig:
         stacked pools (`kv_cache.STACKED_POOLS`). Speculation, tensor
         parallelism and the fleet handoff are not written for that form."""
         return self.block in ("cca_moe", "sparse_moe", "hybrid_moe",
-                              "parallel_ssm")
+                              "parallel_ssm", "latent_moe")
 
     @property
     def recurrent(self) -> bool:
@@ -316,15 +381,29 @@ class DecoderConfig:
         request's `routes`)."""
         if self.block == "hybrid_moe":
             return sum(kind == "sparse" for kind in self.mlp_layer_types)
+        if self.block == "latent_moe":
+            return self.num_layers - self.dense_layers
         return 0 if self.block == "parallel_ssm" else self.num_layers
+
+    @property
+    def held_experts(self) -> int:
+        """Experts this engine holds weights for (the first ones of
+        `num_experts`): all of them unless `experts_held` says fewer."""
+        return self.experts_held or self.num_experts
 
     @property
     def selects(self) -> bool:
         """Whether attention reads a learned selection of the cache: an
         indexer scores every slot of a row's page table, a pool of its
-        own holds its keys, a token's K and V are one row of one pool, and
-        every step reports what it attended."""
-        return self.block == "sparse_moe"
+        own holds its keys, a token's K and V (or its one latent row) are
+        one row of one pool, and every step reports what it attended."""
+        return self.block in ("sparse_moe", "latent_moe")
+
+    @property
+    def latent(self) -> bool:
+        """Whether a token's cache row is ONE compressed row (a latent and
+        its rotary key in `kv_cache.LATENT_POOL`) that every head reads."""
+        return self.block == "latent_moe"
 
     def selects_within(self, slots: int) -> bool:
         """Whether a decode step over a page table of `slots` slots runs
@@ -401,6 +480,28 @@ def hybrid_moe_tiny(**over) -> DecoderConfig:
     return DecoderConfig(**kw)
 
 
+def latent_moe_tiny(**over) -> DecoderConfig:
+    """The "latent_moe" block at test size: 4 heads of 8 + 4 rotary lanes
+    over a latent of 16 (values of 8), queries from a latent of 24, an
+    indexer of 2 heads of 8 keeping 8 positions, a dense layer then two
+    routed ones: 2 of 16 experts a token inside 2 of 4 groups, beside a
+    shared expert, of which this engine holds the first 8; prompts in
+    chunks of 16."""
+    kw = dict(vocab_size=97, hidden_size=32, num_layers=3, num_heads=4,
+              attn_head_dim=8, rope_head_dim=4, v_head_dim=8,
+              q_lora_rank=24, kv_lora_rank=16, index_heads=2,
+              index_head_dim=8, index_topk=8, prefill_chunk=16,
+              dense_layers=1, dense_ffn_size=64, ffn_size=16,
+              shared_expert_size=16, num_experts=16, experts_held=8,
+              experts_per_token=2, expert_groups=4, groups_per_token=2,
+              routed_scaling=2.5, rope_theta=1e4,
+              yarn=(40.0, 16, 32.0, 1.0, 1.0),
+              softmax_mscale=latent_moe_ops.yarn_mscale(40.0, 1.0),
+              rms_norm_eps=1e-6, max_position=128, block="latent_moe")
+    kw.update(over)
+    return DecoderConfig(**kw)
+
+
 def parallel_ssm_tiny(**over) -> DecoderConfig:
     """The "parallel_ssm" block at test size: 4 query heads over 2 KV heads
     of 8 beside 4 state-space heads of 8 in 2 groups of state 16, a
@@ -444,8 +545,9 @@ def _cca_pool_geometry(cfg: DecoderConfig, num_pages: int, page_size: int):
 def stacked_pool_geometry(cfg: DecoderConfig, num_pages: int,
                           page_size: int) -> tuple:
     """`kv_cache.stacked_pool_shapes`' arguments for a scanned family."""
-    geometry = _sparse_pool_geometry if cfg.block == "sparse_moe" \
-        else _cca_pool_geometry
+    geometry = {"sparse_moe": _sparse_pool_geometry,
+                "latent_moe": _latent_pool_geometry}.get(
+                    cfg.block, _cca_pool_geometry)
     return geometry(cfg, num_pages, page_size)
 
 
@@ -924,6 +1026,173 @@ def _hybrid_cow(cfg, num_pages, page_size, src, dst, window_pages=0):
         {slot + "Out": [name] for slot, name in _HYBRID_POOLS},
         {"num_pages": int(num_pages), "window_pages": int(window_pages)})
     return [COW_WSRC_FEED, COW_WDST_FEED]
+
+
+# -- the "latent_moe" family -------------------------------------------------
+
+
+def _latent_geometry(cfg: DecoderConfig) -> dict:
+    return {"num_heads": cfg.num_heads, "nope_dim": cfg.head_dim,
+            "rope_dim": cfg.rope_head_dim, "v_dim": cfg.v_head_dim,
+            "kv_rank": cfg.kv_lora_rank,
+            "rope_theta": float(cfg.rope_theta),
+            "yarn": [float(v) for v in cfg.yarn],
+            "softmax_mscale": float(cfg.softmax_mscale),
+            "eps": float(cfg.rms_norm_eps), "index_heads": cfg.index_heads,
+            "index_dim": cfg.index_head_dim, "index_topk": cfg.index_topk,
+            "experts_per_token": cfg.experts_per_token,
+            "expert_groups": cfg.expert_groups,
+            "groups_per_token": cfg.groups_per_token,
+            "routed_scaling": float(cfg.routed_scaling),
+            "experts_held": cfg.held_experts}
+
+
+def _latent_pool_geometry(cfg: DecoderConfig, num_pages: int,
+                          page_size: int):
+    return (cfg.num_layers, num_pages, page_size,
+            cfg.kv_lora_rank + cfg.rope_head_dim, 0, cfg.dtype,
+            cfg.index_head_dim, False, STACKED_POOLS, True)
+
+
+# a token's latent and rotary key in one row of one pool, its indexer key
+_LATENT_POOLS = (("LatentPool", LATENT_POOL), ("IPool", INDEX_POOL))
+
+
+def _latent_param_specs(cfg: DecoderConfig) -> dict:
+    """name -> (shape, dtype, initializer), stacked by layer kind: `dense.*`
+    over the leading dense layers and `moe.*` over the routed ones (each its
+    attention, indexer and norms, then its own feed-forward), the held
+    experts `[L_moe, held, ...]`. Matrices are drawn at fan_in^-0.5 (the
+    fan the normed latent where one is the input); the queries' way out of
+    their latent at 2x, so that attention over random keys is peaked, as a
+    trained model's is; the attention's way back into the residual stream
+    at 0.5x and an expert's output (weighed by about scaling / k) at 2x;
+    the router at 2x, so that its sigmoids differ by more than rounding,
+    and its selection bias at 0.02, so that it decides some choices and
+    not all. The large ones are in `cfg.dtype`; norms, the router and its
+    bias in float32."""
+    H, F, E = cfg.hidden_size, cfg.ffn_size, cfg.num_experts
+    nh, dn, dr, dv = cfg.num_heads, cfg.head_dim, cfg.rope_head_dim, \
+        cfg.v_head_dim
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    J, D = cfg.index_heads, cfg.index_head_dim
+    Fd, Fs, held = cfg.dense_ffn_size, cfg.shared_expert_size, \
+        cfg.held_experts
+    Ld, Le = cfg.dense_layers, cfg.num_layers - cfg.dense_layers
+    f32, big = "float32", cfg.dtype
+    near_one = Normal(1.0, 0.05)
+
+    def fan(n, scale=1.0):
+        return StackedNormal(0.0, scale * n ** -0.5)
+
+    specs = {
+        "dec.word_emb": ([cfg.vocab_size, H], big, Normal(0.0, 0.02)),
+        "dec.lm_head": ([H, cfg.vocab_size], big, Normal(0.0, H ** -0.5)),
+        "dec.final_norm.scale": ([H], f32, near_one),
+    }
+    for kind, n in (("dense", Ld), ("moe", Le)):
+        specs.update({f"{kind}.{key}": spec for key, spec in {
+            "attn_norm": ([n, H], f32, near_one),
+            "wq_a": ([n, H, rq], big, fan(H)),
+            "q_norm": ([n, rq], f32, near_one),
+            "wq_b": ([n, rq, nh * (dn + dr)], big, fan(rq, 2.0)),
+            "wkv_a": ([n, H, rkv + dr], big, fan(H)),
+            "kv_norm": ([n, rkv], f32, near_one),
+            "wkv_b": ([n, rkv, nh * (dn + dv)], big, fan(rkv)),
+            "wo": ([n, nh * dv, H], big, fan(nh * dv, 0.5)),
+            "wqi": ([n, rq, J * D], big, fan(rq)),
+            "wki": ([n, H, D], big, fan(H)),
+            "ki_norm_w": ([n, D], f32, near_one),
+            "ki_norm_b": ([n, D], f32, Normal(0.0, 0.02)),
+            "ww": ([n, H, J], big, fan(H)),
+            "ffn_norm": ([n, H], f32, near_one)}.items()})
+    specs.update({
+        "dense.w_gate": ([Ld, H, Fd], big, fan(H)),
+        "dense.w_up": ([Ld, H, Fd], big, fan(H)),
+        "dense.w_down": ([Ld, Fd, H], big, fan(Fd)),
+        "moe.router_w": ([Le, H, E], f32, Normal(0.0, 2.0 * H ** -0.5)),
+        "moe.router_bias": ([Le, E], f32, Normal(0.0, 0.02)),
+        "moe.shared_gate": ([Le, H, Fs], big, fan(H)),
+        "moe.shared_up": ([Le, H, Fs], big, fan(H)),
+        "moe.shared_down": ([Le, Fs, H], big, fan(Fs)),
+        "w_gate": ([Le, held, H, F], big, fan(H)),
+        "w_up": ([Le, held, H, F], big, fan(H)),
+        "w_down": ([Le, held, F, H], big, fan(F, 2.0)),
+    })
+    return specs
+
+
+def _latent_stack(cfg: DecoderConfig, mode: str, tok, pos,
+                  num_pages: int = 0, page_size: int = 0, **feeds):
+    """Append the one `latent_moe_stack` op of a program; returns its
+    outputs (next_token, logits, routes, selection)."""
+    helper = LayerHelper("latent_moe_stack")
+    params = {key: helper.create_parameter(
+        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
+        for key, (shape, dtype, init) in _latent_param_specs(cfg).items()}
+    ops = latent_moe_ops
+    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
+              "Head": [params["dec.lm_head"]],
+              "FinalNorm": [params["dec.final_norm.scale"]],
+              "DenseParams": [params["dense." + k] for k in
+                              ops.ATTENTION_PARAMS + ops.DENSE_PARAMS],
+              "MoeParams": [params["moe." + k] for k in
+                            ops.ATTENTION_PARAMS + ops.MOE_PARAMS],
+              "Experts": [params[k] for k in ops.EXPERT_PARAMS]}
+    inputs.update({slot: [var] for slot, var in feeds.items()})
+    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
+            for slot, dtype in (("NextToken", "int32"),
+                                ("Logits", "float32"), ("Routes", "int32"),
+                                ("Selection", "int32"))}
+    if mode != "full":
+        declare_stacked_pools(default_main_program().global_block,
+                              *_latent_pool_geometry(cfg, num_pages,
+                                                     page_size))
+        for slot, name in _LATENT_POOLS:
+            inputs[slot] = [name]
+            outs[slot + "Out"] = [name]
+    helper.append_op("latent_moe_stack", inputs, outs,
+                     dict(_latent_geometry(cfg), mode=mode,
+                          num_pages=int(num_pages)))
+    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
+            "routes": outs["Routes"][0],
+            "selection": outs["Selection"][0]}
+
+
+def _latent_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
+    return _sparse_window_io(_latent_stack(
+        cfg, "prefill", tok, pos, num_pages, page_size, PageTable=pages,
+        Lens=lens))
+
+
+def _latent_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
+                   lens):
+    # a prompt's chunk or the suffix behind a prefix hit (no verify window)
+    return _sparse_window_io(_latent_stack(
+        cfg, "window", tok, pos, num_pages, page_size, PageTable=pages,
+        Start=start, Lens=lens))
+
+
+def _latent_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
+    mark = L.data(name=MARK_FEED, shape=[MARK_ROWS], dtype="int32",
+                  append_batch_size=False)
+    return dict(_latent_stack(cfg, "decode", tok, pos, num_pages, page_size,
+                              PageTable=pages, Mask=mask, Mark=mark),
+                extra_feeds=[MARK_FEED])
+
+
+def _latent_full(cfg, tok, pos):
+    out = _latent_stack(cfg, "full", tok, pos)
+    return {"logits": out["logits"], "routes": out["routes"],
+            "selection": out["selection"]}
+
+
+def _latent_cow(cfg, num_pages, page_size, src, dst):
+    # the page's slab of latent rows and its indexer keys, in every layer
+    declare_stacked_pools(default_main_program().global_block,
+                          *_latent_pool_geometry(cfg, num_pages, page_size))
+    _stacked_copy_page([name for _, name in _LATENT_POOLS], num_pages, src,
+                       dst)
 
 
 # -- the "parallel_ssm" family -----------------------------------------------
@@ -1575,4 +1844,7 @@ _FAMILY = {
     "parallel_ssm": {"prefill": _ssm_prefill, "window": _ssm_window,
                      "cow": _ssm_cow, "decode": _ssm_decode,
                      "full": _ssm_full},
+    "latent_moe": {"prefill": _latent_prefill, "window": _latent_window,
+                   "cow": _latent_cow, "decode": _latent_decode,
+                   "full": _latent_full},
 }

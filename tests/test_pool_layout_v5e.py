@@ -292,6 +292,98 @@ def test_parallel_ssm_programs_compiled_for_v5e_move_no_pool(v5e_chip):
     assert f"f32[{2 * slots},2048,128]" not in texts["state_copy"]
 
 
+def test_latent_moe_stack_compiled_for_v5e_moves_neither_pool(v5e_chip,
+                                                              monkeypatch):
+    """The "latent_moe" stack at the served widths
+    (benchmark/configs/deepseek_v32_exp.json: 128 heads of 128 + 64 over a
+    512-value latent, an indexer of 64 heads of 128 keeping 2,048, 16 held
+    experts of width 2,048 at hidden 7,168), a dense and a routed layer
+    deep, over pools as large as the cell's (5 x 2,304 pages), from SHAPES
+    alone (9 GB of weights are not drawn here): the decode step at 128 rows
+    and a 256-token window, both behind the cell's 288-page tables. Mosaic
+    takes the expert kernel at the narrow F tile (three slabs of 7168 x 512
+    do not fit); the latent rows are kept in whole 128-lane tiles (288
+    words in 384: as `[.., 128, 288]` the chip stores the pool slots-minor
+    and every step copied it, 1.7 GB each way), so neither pool is copied;
+    a decode row gathers a selected token ONCE, as one row, and nothing
+    sorts a row's 36,864 scores."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops import latent_moe_ops as ops
+    from paddle_tpu.ops import sparse_moe_ops
+    from paddle_tpu.serving import kv_cache
+    from paddle_tpu.serving import model as sv_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "deepseek_v32_exp.json")) as f:
+        engine = json.load(f)["engine"]
+    served = DecoderConfig(**engine["config_kwargs"])
+    cfg = DecoderConfig(**dict(engine["config_kwargs"], num_layers=2))
+    pages, ps = engine["pool_pages"], engine["page_size"]
+    one_chip = SingleDeviceSharding(v5e_chip)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {key: shape(dims, dtype) for key, (dims, dtype, _) in
+              sv_model._latent_param_specs(cfg).items()}
+    pools = tuple(shape(dims, dtype) for _, dims, dtype in
+                  kv_cache.stacked_pool_shapes(
+                      *sv_model._latent_pool_geometry(served, pages, ps)))
+    assert [p.shape for p in pools] == [(5 * pages, ps, 384),
+                                        (5 * pages, 128, ps)]
+    geom = ops.Geometry(**sv_model._latent_geometry(cfg))
+    weights = (params["dec.word_emb"], params["dec.lm_head"],
+               params["dec.final_norm.scale"],
+               {k: params["dense." + k]
+                for k in ops.ATTENTION_PARAMS + ops.DENSE_PARAMS},
+               {k: params["moe." + k]
+                for k in ops.ATTENTION_PARAMS + ops.MOE_PARAMS},
+               tuple(params[k] for k in ops.EXPERT_PARAMS))
+    # on the chip the expert layer is the kernel (here jax sees a CPU)
+    monkeypatch.setattr(sparse_moe_ops, "_experts_backend",
+                        lambda *a: "pallas")
+
+    def compiled(mode, tok_shape, rows):
+        def step(tok, pos, weights, pools, table, lens, start, mask, mark):
+            return ops.latent_moe_stack_fn(
+                mode, tok, pos, *weights, geom, pools=pools,
+                page_table=table, lens=lens, start=start, mask=mask,
+                mark=mark, num_pages=pages)
+
+        i32 = "int32"
+        return jax.jit(step, donate_argnums=(3,)).lower(
+            shape(tok_shape, i32), shape(tok_shape, i32), weights, pools,
+            shape((rows, 288), i32), shape((rows,), i32),
+            shape((rows,), i32), shape((rows, 1), "float32"),
+            shape((sv_model.MARK_ROWS,), i32)).compile().as_text()
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        texts = {"decode": compiled("decode", (128,), 128),
+                 "window": compiled("window", (1, 256), 1)}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    sizes = [5 * pages * ps * 384, 5 * pages * 128 * ps]
+    for name, text in texts.items():
+        assert not [c for n in sizes for c in pool_sized_copies(text, n)], \
+            name
+        assert "tpu_custom_call" in text and "moe_topk_experts" in text
+        assert not sorts_over(text, 288 * ps), name
+        # the dense layer's gather and the scanned routed layer's
+        assert len(token_row_gathers(text, 384)) == 2, name
+
+
 def test_token_row_gathers_counts_rows_not_slabs():
     """Recorded from the v5e's compiler: PR 29's decode layer fetched a
     selected token from two pools, PR 30's from one; a page's slab of

@@ -239,7 +239,9 @@ def _moe_cases(spec):
     out of a stack of two, at a decode batch and at a prefill window; and
     one of the "sparse_moe" block's (128 x 2048 -> 768 -> 2048, top-8), at
     a decode batch and at a 512-token chunk; and one of the "hybrid_moe"
-    block's (256 x 2048 -> 512 -> 2048, top-8), the same two."""
+    block's (256 x 2048 -> 512 -> 2048, top-8), the same two; and a share
+    of the "latent_moe" block's (16 held of 256 x 7168 -> 2048 -> 7168), at
+    a decode batch and at a 512-token chunk."""
 
     def case(tokens):
         L, E, H, F = 2, 16, 2048, 2048
@@ -292,7 +294,29 @@ def _moe_cases(spec):
                         lambda *a: spec.reference(*a, 1),
                         (z, cw, wg, wu, wd), 0, "bfloat16")
 
-    return [(f"t{t} e16 h2048 f2048 bf16 layer 1 of 2",
+    def share_case(tokens):
+        # the "latent_moe" geometry: a chip's 16 HELD experts of width 2048
+        # at hidden 7168 (the narrow F tile: 256 columns a grid step); a
+        # token's eight experts are drawn among 256, so most of a row's
+        # held columns are zero and many rows hold none
+        L, E, H, F, k, held = 2, 256, 7168, 2048, 8, 16
+        ks = jax.random.split(jax.random.PRNGKey(9), 6)
+        z = _rand(ks[0], (tokens, H), "float32")
+        wg = _rand(ks[1], (L, held, H, F), "bfloat16", H ** -0.5)
+        wu = _rand(ks[2], (L, held, H, F), "bfloat16", H ** -0.5)
+        wd = _rand(ks[3], (L, held, F, H), "bfloat16", F ** -0.5)
+        vals, ids = jax.lax.top_k(jax.random.uniform(ks[4], (tokens, E)), k)
+        cw = jnp.sum(jax.nn.one_hot(ids, E)
+                     * (2.5 * vals / vals.sum(-1, keepdims=True))[..., None],
+                     1)[:, :held]
+        assert spec.supported(z.shape, wg.shape)
+        return _compare(lambda *a: spec.fn(*a, 1),
+                        lambda *a: spec.reference(*a, 1),
+                        (z, cw, wg, wu, wd), 0, "bfloat16")
+
+    return [(f"t{t} 16 held of 256 top8 h7168 f2048 bf16 layer 1 of 2",
+             lambda t=t: share_case(t)) for t in (128, 512)] + [
+            (f"t{t} e16 h2048 f2048 bf16 layer 1 of 2",
              lambda t=t: case(t)) for t in (64, 300)] + [
         (f"t{t} e128 top8 h2048 f768 bf16 layer 1 of 2",
          lambda t=t: topk_case(t)) for t in (64, 512)] + [

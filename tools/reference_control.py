@@ -11,8 +11,8 @@ prints, before the run's own lines, `control {...}`: the second grading's
 readings and how many of the sampled requests it found wrong. For runners
 whose `check_sample` reads the reference's weights through
 `read_params(get, cfg, round_to=None)` (`serve_open_loop_sparse` with
-`keye_lm`, `serve_open_loop_routed` with `laguna_lm`, `serve_open_loop` with
-`falcon_h1_lm`).
+`keye_lm` or `deepseek_v32_lm`, `serve_open_loop_routed` with `laguna_lm`,
+`serve_open_loop` with `falcon_h1_lm`).
 """
 from __future__ import annotations
 
