@@ -53,8 +53,9 @@ from paddle_tpu.serving import model as sv_model
 from paddle_tpu.serving.kv_cache import (INDEX_POOL, JOINED_POOL,
                                          STACKED_POOLS, WINDOW_POOLS,
                                          pool_shape)
-from tools.pool_hlo import (pool_sized_copies, serving_program_hlos,
-                            sorts_over, token_row_gathers)
+from tools.pool_hlo import (kernel_calls, pool_sized_copies,
+                            serving_program_hlos, sorts_over,
+                            token_row_gathers)
 
 # How far below the dense oracle's best logit the logit of a token the
 # engine generated may sit. The engine and the oracle run different
@@ -316,6 +317,11 @@ def pool_layout_phase(cfg: DecoderConfig, page_size: int, pool_pages: int,
             for name, text in texts.items()}
         _require(out["context_sorts"]["decode"] == 0,
                  "the compiled decode program sorts a row's whole context")
+        # and a decode row's indexer scores come from the paged kernel
+        # where its gate takes the geometry (PR 40)
+        out["paged_indexer_calls"] = {
+            name: kernel_calls(text, "paged_indexer_scores")
+            for name, text in texts.items()}
     return out
 
 

@@ -335,6 +335,10 @@ DECLARED: list[tuple] = [
     ("serving.sparse.layer_steps", COUNTER,
      "layer x decode-step pairs in which the indexer ran (a table wider "
      "than index_topk slots)", ()),
+    ("serving.sparse.kernel_layer_steps", COUNTER,
+     "those of serving.sparse.layer_steps whose scores the paged Pallas "
+     "kernel computed straight from the key pool (paged_indexer_scores); 0 "
+     "on the XLA arm (over layer_steps: how often the kernel engages)", ()),
     # -- a latent cache row and a share of the experts (ISSUE 39) -----------
     ("serving.latent.gathered_rows", COUNTER,
      "cache rows decode rows read out of the latent pool, summed over "

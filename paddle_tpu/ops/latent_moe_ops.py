@@ -36,7 +36,10 @@ that runs them is `latent_moe_stack`:
 
 The indexer, the selection without a sort and its two forms (a window's
 mask, a decode row's positions) are `sparse_moe_ops`', with the indexer's
-queries taken from the query latent `c_q`.
+queries taken from the query latent `c_q`; a decode step's scores come
+from `pallas_kernels.paged_indexer`, which reads each row's key pages out
+of the pool once (`sparse_moe_ops.decode_scores_fn`), a window's from its
+pages' keys gathered once (`paged_scores_fn`).
 
 `latent_moe_stack` composes them into the decoder (embedding, leading dense
 layers, routed layers, final norm, untied head) in the shapes serving needs:
@@ -81,8 +84,9 @@ from .cca_moe_ops import _page_row_index, rms_norm_fn
 from .hybrid_moe_ops import rotary_fn, swiglu_fn, yarn_inv_freq_fn
 from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
-from .sparse_moe_ops import (_INDEX_SCORES_AT_ONCE, _mask_positions, _mm,
-                             _word_values, indexer_scores_fn, join_rows_fn,
+from .sparse_moe_ops import (_block_of, _mask_positions, _mm,
+                             _word_values, decode_scores_fn,
+                             indexer_scores_fn, join_rows_fn,
                              layer_norm_fn, moe_topk_experts_fn,
                              pack_selection_fn, select_indices_fn,
                              select_mask_fn, write_index_keys_fn)
@@ -284,35 +288,17 @@ def group_limited_router_fn(z, router_w, router_bias, k: int, groups: int,
     return ids.astype(jnp.int32), cw
 
 
-def _block_of(n: int, per_row: int) -> int:
-    """Rows of `n` scored at once: halved while a block's scores pass
-    `_INDEX_SCORES_AT_ONCE` values and the rows still divide."""
-    b = n
-    while b > 1 and b % 2 == 0 and b * per_row > _INDEX_SCORES_AT_ONCE:
-        b //= 2
-    return b
-
-
 def paged_scores_fn(qi, w, i_pool, table):
-    """The indexer's scores over a paged context: qi [B, S, J, D], w [B, S,
-    J] float32, table [B, P] (shifted to the layer's rows) -> [B, S, P *
-    page_size] float32. In blocks, so that the `[rows, heads, context]`
-    product of one block stays under `_INDEX_SCORES_AT_ONCE`: over the ROWS
-    of a decode step (each gathers its own pages' keys inside its block),
-    over the QUERIES of a window (the pages' keys gathered once)."""
+    """A window's indexer scores over its paged context: qi [B, S, J, D], w
+    [B, S, J] float32, table [B, P] (shifted to the layer's rows) -> [B, S,
+    P * page_size] float32. The pages' keys are gathered once for all the
+    window's queries, and the queries scored in blocks, so that the
+    `[queries, heads, context]` product of one block stays under
+    `_INDEX_SCORES_AT_ONCE`. (A decode step reads each row's pages where
+    they lie: `sparse_moe_ops.decode_scores_fn`.)"""
     B, S, J, _ = qi.shape
     P, ps = table.shape[1], i_pool.shape[2]
-    pages = jnp.clip(table, 0, i_pool.shape[0] - 1)
-    if S == 1:
-        b = _block_of(B, J * P * ps)
-        if b == B:
-            return indexer_scores_fn(qi, w, i_pool[pages])
-        split = lambda a: a.reshape((B // b, b) + a.shape[1:])  # noqa: E731
-        out = jax.lax.map(
-            lambda a: indexer_scores_fn(a[0], a[1], i_pool[a[2]]),
-            (split(qi), split(w), split(pages)))
-        return out.reshape(B, 1, P * ps)
-    keys = i_pool[pages]
+    keys = i_pool[jnp.clip(table, 0, i_pool.shape[0] - 1)]
     s = _block_of(S, B * J * P * ps)
     if s == S:
         return indexer_scores_fn(qi, w, keys)
@@ -494,7 +480,10 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                     sel = pack_selection_fn(live, page_size)
         else:
             with piece("indexer"):
-                if paged:
+                if decode:      # each row's pages, where they lie
+                    scores = decode_scores_fn(qi, w, i_pool, table,
+                                              (first + 1) * count)
+                elif paged:
                     scores = paged_scores_fn(qi, w, i_pool, table)
                 else:           # the sequence as one page
                     scores = indexer_scores_fn(

@@ -12,6 +12,15 @@ jax-callable function with a custom VJP so the op registry's
 derived-gradient machinery works through it, and dispatches through the
 tuning layer (keep-or-retire per shape, degradation to the reference when
 the platform cannot run the kernel).
+
+The serving kernels are forward-only and are imported where they are
+dispatched, not from here: `paged_attention` (a decode row's attention over
+its pages of the K/V pools; `attention_ops.paged_decode_attention_fn`),
+`paged_indexer` (a decode row's lightning-indexer scores over its pages of
+the key pool, where XLA gathered the pages and wrote the per-head scores;
+`sparse_moe_ops.decode_scores_fn`, its shape gate the only switch),
+`moe_experts` (the routed experts' stream) and `ssm_update` (a decode
+token's state update in place).
 """
 from . import workbench
 from .attention import short_seq_attention, short_seq_supported

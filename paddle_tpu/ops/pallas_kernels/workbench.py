@@ -145,6 +145,7 @@ def all_kernels() -> dict[str, KernelSpec]:
     """Every registered kernel (import side effect: pulls in the kernel
     modules so their registrations run)."""
     from . import (attention, epilogue, moe_experts,  # noqa: F401
-                   paged_attention, short_attention, ssm_update)
+                   paged_attention, paged_indexer, short_attention,
+                   ssm_update)
 
     return dict(_KERNELS)

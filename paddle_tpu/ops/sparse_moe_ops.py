@@ -8,7 +8,13 @@ that runs them is `sparse_moe_stack`:
   * `indexer_scores`     — the "lightning indexer" of DeepSeek Sparse
                            Attention: a few small query heads, ONE cached
                            key head, `I(t, s) = sum_j w_tj relu(qI_tj .
-                           kI_s)`, float32;
+                           kI_s)`, float32. A window scores its pages' keys
+                           gathered once for all its queries; a decode step
+                           scores each row's pages where they lie in the
+                           pool (`decode_scores`: the Pallas kernel
+                           `pallas_kernels.paged_indexer` where its shape
+                           gate takes the geometry, the gathered form
+                           elsewhere);
   * `select_mask` / `select_indices` — the `k` cached positions `s <= t`
                            of largest `I(t, s)` (every position while
                            `t < k`; ties go to the lower position): ONE
@@ -86,9 +92,12 @@ EXPERT_PARAMS = ("w_gate", "w_up", "w_down")
 _QUERY_BLOCK = 64
 # a window whose `[heads, queries, context]` indexer scores pass this many
 # values (a quarter of a GB of float32) adds the heads up one at a time
-# instead. A decode step must stay under it: one query a row, so a product
-# a head has ONE row and the chip's MXU idles (5.9 ms a layer at 64 rows
-# with the heads one at a time; my chip runs, PR 29)
+# instead. A decode step that takes the gathered form (a geometry
+# `paged_indexer_supported` refuses) stays under it by scoring its rows in
+# blocks (`decode_scores_fn`): one query a row, so a product a head has ONE
+# row and the chip's MXU idles (5.9 ms a layer at 64 rows with the heads
+# one at a time; my chip runs, PR 29). A decode step the kernel serves
+# writes no such scores at all
 _INDEX_SCORES_AT_ONCE = 1 << 26
 
 
@@ -133,6 +142,54 @@ def indexer_scores_fn(qi, w, ki_pages):
     acc, _ = jax.lax.scan(add_head, jnp.zeros((B, S, P, ps), _F32),
                           (jnp.moveaxis(qc, 2, 0), jnp.moveaxis(w, 2, 0)))
     return acc.reshape(B, S, P * ps)
+
+
+def paged_indexer_runs(q_shape, pool_shape, pool_dtype) -> bool:
+    """Whether a decode step's scores, qI `q_shape` [B, J, D] over the
+    index pool `pool_shape`, come from `pallas_kernels.paged_indexer`: its
+    shape gate decides alone, where a Pallas kernel can run at all. The
+    engine books `serving.sparse.kernel_layer_steps` by the same answer."""
+    from .pallas_kernels import paged_indexer, workbench
+
+    return (workbench.runnable(paged_indexer)
+            and paged_indexer.paged_indexer_supported(
+                tuple(q_shape), tuple(pool_shape), pool_dtype))
+
+
+def _block_of(n: int, per_row: int) -> int:
+    """Rows of `n` scored at once: halved while a block's scores pass
+    `_INDEX_SCORES_AT_ONCE` values and the rows still divide."""
+    b = n
+    while b > 1 and b % 2 == 0 and b * per_row > _INDEX_SCORES_AT_ONCE:
+        b //= 2
+    return b
+
+
+def decode_scores_fn(qi, w, i_pool, table, lens):
+    """A decode step's indexer scores over its paged context: qi [B, 1, J,
+    D], w [B, 1, J] float32, table [B, P] (shifted to the layer's rows),
+    lens [B] (a row's live tokens, 0 for one the scheduler padded in) ->
+    `I` [B, 1, P * page_size] float32. Where the kernel runs each row's
+    pages are read once where they lie: neither a gathered copy nor the
+    `[rows, heads, context]` products are written. Elsewhere (the CPU
+    rehearsal geometries) every row gathers its own pages' keys, in blocks
+    of rows whose products stay under `_INDEX_SCORES_AT_ONCE`."""
+    from .pallas_kernels import paged_indexer
+
+    B, _, J, _ = qi.shape
+    P, ps = table.shape[1], i_pool.shape[2]
+    if paged_indexer_runs(qi[:, 0].shape, i_pool.shape, i_pool.dtype):
+        return paged_indexer.paged_indexer_scores(qi[:, 0], w[:, 0], i_pool,
+                                                  table, lens)
+    pages = jnp.clip(table, 0, i_pool.shape[0] - 1)
+    b = _block_of(B, J * P * ps)
+    if b == B:
+        return indexer_scores_fn(qi, w, i_pool[pages])
+    split = lambda a: a.reshape((B // b, b) + a.shape[1:])      # noqa: E731
+    out = jax.lax.map(
+        lambda a: indexer_scores_fn(a[0], a[1], i_pool[a[2]]),
+        (split(qi), split(w), split(pages)))
+    return out.reshape(B, 1, P * ps)
 
 
 def write_index_keys_fn(i_pool, ki, page_table, layer_off, first, count):
@@ -579,12 +636,16 @@ def sparse_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
             sel = jnp.where(live, at[:, None], -1)
         else:
             with piece("indexer"):
-                if paged:
-                    ki_ctx = i_pool[jnp.clip(table, 0, i_pool.shape[0] - 1)]
-                else:       # the sequence as one page
-                    ki_ctx = jnp.swapaxes(ki.astype(emb.dtype), 1,
-                                          2)[:, None]
-                scores = indexer_scores_fn(qi, w, ki_ctx)
+                if decode:      # each row's pages, where they lie
+                    scores = decode_scores_fn(qi, w, i_pool, table,
+                                              (first + 1) * count)
+                elif paged:     # a window's, gathered once for its queries
+                    scores = indexer_scores_fn(qi, w, i_pool[jnp.clip(
+                        table, 0, i_pool.shape[0] - 1)])
+                else:           # the sequence as one page
+                    scores = indexer_scores_fn(
+                        qi, w, jnp.swapaxes(ki.astype(emb.dtype), 1,
+                                            2)[:, None])
             if decode:
                 with piece("select"):
                     sel = select_indices_fn(scores, gpos + 1,

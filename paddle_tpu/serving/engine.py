@@ -196,11 +196,11 @@ from ..data_feeder import _round_up_pow2
 from ..executor import Executor, Scope
 from ..framework import Program, program_guard
 from ..observability.slo import hist_p99_above
-from ..ops import attention_ops
+from ..ops import attention_ops, sparse_moe_ops
 from ..resilience.faults import InjectedFault, fault_point
 from ..resilience.retry import serving_policy
 from . import model as sv_model
-from .kv_cache import (OwnedPoolView, PagedKVPool, PrefixCache,
+from .kv_cache import (INDEX_POOL, OwnedPoolView, PagedKVPool, PrefixCache,
                        create_device_pools, create_stacked_pools,
                        create_state_pools, pool_var_names)
 from .sampling import SamplingParams, request_rng, sample_token
@@ -760,6 +760,7 @@ class ServingEngine:
                 + per_token,
                 np.int8 if self.cfg.num_experts <= 128 else np.int16)
         self._grid_steps_by_signature: dict[tuple[int, int], int] = {}
+        self._indexer_kernel_runs: bool | None = None
         self._prefill_run = self._exec_target(self._prefill_prog)
         self._decode_run = self._exec_target(self._decode_prog)
         self._window_run = self._exec_target(self._window_prog)
@@ -798,6 +799,7 @@ class ServingEngine:
             # chunked prefill and learned sparse attention (ISSUE 29)
             "prefill.chunks": 0, "sparse.context_tokens": 0,
             "sparse.selected_tokens": 0, "sparse.layer_steps": 0,
+            "sparse.kernel_layer_steps": 0,
             # a latent cache row and a share of the experts (ISSUE 39)
             "latent.gathered_rows": 0, "latent.attended_tokens": 0,
             "moe.routed_pairs": 0, "moe.held_pairs": 0,
@@ -2679,6 +2681,18 @@ class ServingEngine:
             self._grid_steps_by_signature[(bb, pb)] = steps
         return steps
 
+    def _indexer_kernel(self) -> bool:
+        """Whether the paged Pallas kernel scores a decode step's context
+        (the XLA gather otherwise): the ops' own answer (rows do not enter
+        it), asked once."""
+        if self._indexer_kernel_runs is None:
+            cfg = self.cfg
+            pool = self._scope.find_var(INDEX_POOL)
+            self._indexer_kernel_runs = sparse_moe_ops.paged_indexer_runs(
+                (1, cfg.index_heads, cfg.index_head_dim), pool.shape,
+                pool.dtype)
+        return self._indexer_kernel_runs
+
     def _decode_once(self, sp) -> bool:
         """One decode step under the open `serving.decode` span `sp`:
         enqueued over the running rows that go on, each taking from the
@@ -2749,6 +2763,8 @@ class ServingEngine:
             self._count("sparse.selected_tokens",
                         L * sum(min(k, pos + 1) for pos, _ in at))
             self._count("sparse.layer_steps", L)
+            self._count("sparse.kernel_layer_steps",
+                        L if self._indexer_kernel() else 0)
         if self.cfg.latent:
             # cache rows the rows' attention read out of the latent pool
             # (their selection's, or every slot of a table that fits it)

@@ -6,7 +6,7 @@ import pytest
 
 import chip_smoke
 from paddle_tpu.serving import DecoderConfig
-from tools.pool_hlo import (pool_sized_copies, sorts_over,
+from tools.pool_hlo import (kernel_calls, pool_sized_copies, sorts_over,
                             token_row_gathers)
 
 POOL = 3072 * 16 * 12 * 64
@@ -197,6 +197,10 @@ def test_sparse_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
         "decode": 1, "prefill": 0, "window": 0, "cow": 0}
     # nor does any sort a row's 36,864 scores (decode did until PR 34)
     assert not any(out["context_sorts"].values()), out["context_sorts"]
+    # a decode row's 288 key pages are read where they lie, by the paged
+    # indexer kernel (PR 40); the windows gather theirs once for all queries
+    assert out["paged_indexer_calls"] == {
+        "decode": 1, "prefill": 0, "window": 0, "cow": 0}
 
 
 def test_hybrid_moe_programs_compiled_for_v5e_move_neither_pool(v5e_chip):
@@ -317,6 +321,7 @@ def test_latent_moe_stack_compiled_for_v5e_moves_neither_pool(v5e_chip,
 
     from paddle_tpu.ops import latent_moe_ops as ops
     from paddle_tpu.ops import sparse_moe_ops
+    from paddle_tpu.ops.pallas_kernels import workbench
     from paddle_tpu.serving import kv_cache
     from paddle_tpu.serving import model as sv_model
 
@@ -348,9 +353,11 @@ def test_latent_moe_stack_compiled_for_v5e_moves_neither_pool(v5e_chip,
                {k: params["moe." + k]
                 for k in ops.ATTENTION_PARAMS + ops.MOE_PARAMS},
                tuple(params[k] for k in ops.EXPERT_PARAMS))
-    # on the chip the expert layer is the kernel (here jax sees a CPU)
+    # on the chip the expert layer is the kernel (here jax sees a CPU), and
+    # so are a decode step's indexer scores
     monkeypatch.setattr(sparse_moe_ops, "_experts_backend",
                         lambda *a: "pallas")
+    monkeypatch.setattr(workbench, "on_tpu", lambda: True)
 
     def compiled(mode, tok_shape, rows):
         def step(tok, pos, weights, pools, table, lens, start, mask, mark):
@@ -382,6 +389,11 @@ def test_latent_moe_stack_compiled_for_v5e_moves_neither_pool(v5e_chip,
         assert not sorts_over(text, 288 * ps), name
         # the dense layer's gather and the scanned routed layer's
         assert len(token_row_gathers(text, 384)) == 2, name
+    # a decode row's key pages are read where they lie, by the kernel (the
+    # dense layer's call and the scanned layer's); a window gathers its
+    # pages once for all its queries
+    assert {name: kernel_calls(text, "paged_indexer_scores")
+            for name, text in texts.items()} == {"decode": 2, "window": 0}
 
 
 def test_token_row_gathers_counts_rows_not_slabs():
@@ -462,3 +474,43 @@ def test_paged_decode_blocks_fit_their_vmem_budget_on_v5e(
         compilation_cache.reset_cache()
     assert "tpu_custom_call" in text and "paged_decode_attention" in text
     assert pool_sized_copies(text, pool[0] * ps * width) == []
+
+
+@pytest.mark.parametrize("q_shape,pool,group", [
+    ((128, 64, 128), (5 * 2304, 128, 128), 48),
+    ((64, 16, 64), (6 * 1792, 64, 128), 96),
+], ids=["deepseek_v32_exp", "keye_vl2_30b_a3b"])
+def test_paged_indexer_blocks_fit_their_vmem_budget_on_v5e(
+        v5e_chip, q_shape, pool, group):
+    """The paged indexer kernel alone at the two serving cells' sizes (128
+    rows of 64 heads of 128 over 5 x 2,304 pages; 64 rows of 16 heads of 64
+    over 6 x 1,792; 288-page tables of 128 bfloat16 tokens): a grid step
+    covers 48 pages of 32 KB or 96 of 16 KB, a block stays under
+    `BLOCK_BYTES` (the kernel holds two), the whole page table (36,864
+    entries at 128 rows, 147 KB) fits the chip's scalar memory beside the
+    lengths, and Mosaic takes it with the pool left in HBM and uncopied."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas_kernels import paged_indexer as pi
+
+    (B, J, D), (_, _, ps), P = q_shape, pool, 288
+    assert pi.paged_indexer_supported(q_shape, pool, jnp.bfloat16)
+    assert pi.pages_per_grid_step(P, D * ps * 2) == group
+    assert group * D * ps * 2 <= pi.BLOCK_BYTES and B * P <= pi.TABLE_ENTRIES
+    sh = SingleDeviceSharding(v5e_chip)
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=sh) for shape, dt in (
+        (q_shape, jnp.float32), ((B, J), jnp.float32), (pool, jnp.bfloat16),
+        ((B, P), jnp.int32), ((B,), jnp.int32))]
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(lambda *a: pi._call(*a, False)).lower(
+            *args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert kernel_calls(text, "paged_indexer_scores") == 1
+    assert pool_sized_copies(text, pool[0] * D * ps) == []

@@ -570,6 +570,44 @@ def test_moe_experts_pallas_at_the_narrow_tile(monkeypatch):
 # -- the configuration -------------------------------------------------------
 
 
+def test_the_paged_indexer_kernel_serves_what_the_gather_served(monkeypatch):
+    """The "latent_moe" decode step at a geometry `paged_indexer_supported`
+    takes (an indexer of 8 heads of 128 keeping 64 positions, bfloat16 keys
+    in 128-token pages), rows behind three pages of context: with the
+    scores computed by the paged kernel (interpreter) the engine serves the
+    tokens and hands back the selections the gathered form
+    served, `serving.sparse.kernel_layer_steps` equal to
+    `serving.sparse.layer_steps`; on the XLA arm and at the rehearsal
+    geometry it stays 0."""
+    from paddle_tpu.ops.pallas_kernels import paged_indexer
+
+    cfg = sv_model.latent_moe_tiny(
+        dtype="bfloat16", index_heads=8, index_head_dim=128, index_topk=64,
+        prefill_chunk=128, max_position=1024)
+    prompts = _prompts(43, 290, 260)
+
+    def served():
+        eng = _engine(cfg, page_size=128, pool_pages=16)
+        return eng, _serve(eng, prompts, new=5)
+
+    eng, was = served()
+    assert eng._scope.find_var("kv_cache.index").shape == (3 * 16, 128, 128)
+    assert eng.stats["sparse.layer_steps"] > 0
+    assert eng.stats["sparse.kernel_layer_steps"] == 0
+    monkeypatch.setattr(paged_indexer, "INTERPRET", True)
+    eng, now = served()
+    assert eng.stats["sparse.kernel_layer_steps"] \
+        == eng.stats["sparse.layer_steps"] > 0
+    for a, b in zip(now, was):
+        assert a.out_tokens == b.out_tokens
+        assert a.selection[0] == b.selection[0]
+        assert np.array_equal(a.selection[1], b.selection[1])
+    eng = _engine()
+    _serve(eng, _prompts(31, 40), new=4)
+    assert eng.stats["sparse.layer_steps"] > 0
+    assert eng.stats["sparse.kernel_layer_steps"] == 0
+
+
 def test_block_field_and_refusals():
     cfg = sv_model.latent_moe_tiny()
     assert cfg.block == "latent_moe" and cfg.scanned and cfg.selects \

@@ -612,6 +612,54 @@ def test_bfloat16_engine_stays_inside_the_bfloat16_tolerances():
     assert worst > 0.1
 
 
+def _kernel_geometry():
+    """The block at a geometry `paged_indexer_supported` takes: an indexer
+    of 8 heads of 128 keeping 64 positions, bfloat16 keys; the engine below
+    gives it 128-token pages."""
+    return sv_model.sparse_moe_tiny(
+        dtype="bfloat16", index_heads=8, index_head_dim=128, index_topk=64,
+        prefill_chunk=128, max_position=1024)
+
+
+def test_the_paged_indexer_kernel_serves_what_the_gather_served(monkeypatch):
+    """Decode rows behind three pages of context, the selection (64 of
+    about 300 positions) engaged: with the indexer's scores computed by the
+    paged kernel (interpreter) the engine serves the tokens and hands back
+    the selections the gathered form served, and
+    `serving.sparse.kernel_layer_steps` says that the kernel served every
+    (layer, step); on the XLA arm, and at the rehearsal geometry the gate
+    refuses, it stays 0."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.ops.pallas_kernels import paged_indexer
+
+    prompts = _prompts(41, 300, 270)
+
+    def served():
+        eng = _engine(_kernel_geometry(), page_size=128, pool_pages=16)
+        eng.reset_stats()      # the registry's serving.* series start at 0
+        return eng, _serve(eng, prompts, new=5)
+
+    eng, was = served()
+    assert eng._scope.find_var("kv_cache.index").shape == (3 * 16, 128, 128)
+    assert eng.stats["sparse.layer_steps"] > 0
+    assert eng.stats["sparse.kernel_layer_steps"] == 0
+    monkeypatch.setattr(paged_indexer, "INTERPRET", True)
+    eng, now = served()
+    steps = eng.stats["sparse.layer_steps"]
+    assert eng.stats["sparse.kernel_layer_steps"] == steps > 0
+    assert obs.snapshot()["counters"][
+        "serving.sparse.kernel_layer_steps"] == steps
+    for a, b in zip(now, was):
+        assert a.out_tokens == b.out_tokens
+        assert a.selection[0] == b.selection[0]
+        assert np.array_equal(a.selection[1], b.selection[1])
+    # 8-token pages of 2 heads of 8: the gate refuses, the gather serves
+    eng = _engine()
+    _serve(eng, _prompts(31, 40), new=4)
+    assert eng.stats["sparse.layer_steps"] > 0
+    assert eng.stats["sparse.kernel_layer_steps"] == 0
+
+
 def test_page_buckets_round_to_32_past_32():
     eng = _engine(sv_model.sparse_moe_tiny(max_position=4096),
                   pool_pages=600)

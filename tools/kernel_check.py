@@ -354,7 +354,41 @@ def _ssm_update_cases(spec):
     return [("b20 (16 live) h32 n256 p128 g2 pool 2x20 float32", case)]
 
 
+def _paged_indexer_cases(spec):
+    """The two serving geometries of the paged indexer kernel (DeepSeek-
+    V3.2-Exp: 64 heads of 128; Keye-VL2: 16 of 64; 128-token pages of
+    bfloat16 keys) behind a 96-page table of a layer's rows: rows behind
+    one document (the same pages), lengths on both sides of a page's and a
+    block's edge, a row with no context. The live positions are compared;
+    what lies past a row's length must be finite."""
+
+    def case(J, D):
+        B, ps, pages, P = 8, 128, 256, 96
+        ks = jax.random.split(jax.random.PRNGKey(12), 4)
+        qi = _rand(ks[0], (B, J, D), "float32")
+        w = _rand(ks[1], (B, J), "float32", (J * D) ** -0.5)
+        pool = _rand(ks[2], (2 * pages, D, ps), "bfloat16")
+        doc = jax.random.permutation(ks[3], pages)[:P]
+        table = jnp.stack([doc if b % 2 else doc[::-1] for b in range(B)])
+        table = (table + pages).astype(jnp.int32)       # layer 1's rows
+        lens = jnp.asarray([96 * ps, 95 * ps + 1, 48 * ps, 48 * ps + 1,
+                            8 * ps - 1, 129, 1, 0], jnp.int32)
+        assert spec.supported(qi.shape, pool.shape, pool.dtype)
+        live = (jnp.arange(P * ps)[None, :] < lens[:, None])[:, None]
+        got = spec.fn(qi, w, pool, table, lens)
+        res = _compare(lambda: jnp.where(live, got, 0.0),
+                       lambda: jnp.where(live, spec.reference(
+                           qi, w, pool, table, lens), 0.0), (), 0, "float32")
+        res["finite"] = bool(np.all(np.isfinite(np.asarray(got))))
+        res["ok"] = bool(res["ok"] and res["finite"])
+        return res
+
+    return [(f"b8 j{J} d{D} ps128 bfloat16 table 96 ragged shared pages",
+             lambda J=J, D=D: case(J, D)) for J, D in ((64, 128), (16, 64))]
+
+
 CASES = {
+    "indexer_paged_scores": _paged_indexer_cases,
     "ssm_decode_update": _ssm_update_cases,
     "attention_paged_decode": lambda spec: (_paged_cases(spec)
                                             + _paged_gqa_cases(spec)),

@@ -13,7 +13,8 @@ fetch one token's row at a time (a block that gathers tokens pays by the
 row: its decode program holds ONE a layer since PR 30);
 `sorts_over(hlo_text, row_elements)` lists the sorts of rows at least that
 long (a block that selects sorted every decode row's whole context until
-PR 34: none since);
+PR 34: none since); `kernel_calls(hlo_text, name)` counts the calls of a
+Pallas kernel;
 `serving_program_hlos(engine)` compiles the engine's decode, prefill,
 window and COW programs (`serving_program_cases`) at one signature each and
 returns their texts.
@@ -156,6 +157,15 @@ def sorts_over(hlo_text: str, row_elements: int) -> list[dict]:
             for line in hlo_text.splitlines()
             if (m := _SORT.match(line))
             and int(m["dims"].split(",")[int(m["dim"])]) >= row_elements]
+
+
+def kernel_calls(hlo_text: str, name: str) -> int:
+    """How many instructions of an optimized HLO text call the Pallas
+    kernel `name` (the `name=` of its `pallas_call`: the instruction is
+    called after it). A scanned layer's body appears once."""
+    return len(re.findall(
+        rf"^\s*(?:ROOT\s+)?%{re.escape(name)}[.\d]*\s*=\s*\S+\s+"
+        r"custom-call\(", hlo_text, flags=re.M))
 
 
 def _program_hlo(exe, target, feed, fetch_list, scope, device=None) -> str:
