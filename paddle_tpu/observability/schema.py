@@ -349,6 +349,11 @@ DECLARED: list[tuple] = [
      "live cached tokens decode rows attended in the latent, summed over "
      "rows, layers and steps (x a row's bytes, or x the absorbed form's "
      "operations a token)", ()),
+    ("serving.latent.attend_kernel_layer_steps", COUNTER,
+     "layer x decode-step pairs whose absorbed attention the Pallas kernel "
+     "computed over the gathered rows as they lie (latent_rows_attention); "
+     "0 on the XLA arm (over sparse.layer_steps where every step selects: "
+     "how often the kernel engages)", ()),
     ("serving.moe.routed_pairs", COUNTER,
      "(token, expert) pairs the router made, summed over layers (prefill "
      "and decode), in an engine that holds a share of the experts", ()),

@@ -50,6 +50,10 @@ fits the selection attends every live position, a window in the expanded
 form, a decode row in the absorbed form over its pages' slabs; behind a
 longer context both run the indexer and the absorbed form over each
 query's own gathered rows (a window in blocks of `_QUERY_BLOCK` queries).
+The absorbed form is ONE kernel wherever `latent_attend_runs` takes the
+shapes (`pallas_kernels.latent_attend`: a query's gathered rows read once
+as they lie, the words unpacked in VMEM, no scores written), and
+`absorbed_attention_fn` elsewhere.
 
 Weights are stacked by layer KIND (`dense.*` over the leading dense layers:
 attention, indexer and a SwiGLU; `moe.*` over the routed ones: attention,
@@ -246,6 +250,19 @@ def absorbed_attention_fn(q_lat, q_rope, rows, have, dtype,
                       preferred_element_type=_F32)
 
 
+def latent_attend_runs(q_shape, rows_shape, dtype, rope_dim: int) -> bool:
+    """Whether the absorbed attention of queries q_lat `q_shape` [R, nh,
+    kv_rank] over cache rows `rows_shape` [R, K, words] of `dtype` values
+    comes from `pallas_kernels.latent_attend`: its shape gate decides
+    alone, where a Pallas kernel can run at all. The engine books
+    `serving.latent.attend_kernel_layer_steps` by the same answer."""
+    from .pallas_kernels import latent_attend, workbench
+
+    return (workbench.runnable(latent_attend)
+            and latent_attend.latent_attend_supported(
+                tuple(q_shape), tuple(rows_shape), dtype, int(rope_dim)))
+
+
 def gather_rows_fn(pool, page_table, sel):
     """The cache rows of positions sel [R, K] (-1: none) of rows whose
     pages page_table [R, P] names (already shifted to the layer's rows):
@@ -341,11 +358,20 @@ def _pre_attention(x, p, positions, geom: Geometry):
 
 def _attend_rows(q_nope, q_rope, rows, have, wkv_b, dtype, geom: Geometry):
     """The absorbed form for R queries, each over the cache rows it was
-    given (rows [R, K, words], have [R, K]) -> [R, nh, v] float32."""
+    given (rows [R, K, words], have [R, K]) -> [R, nh, v] float32: one
+    Pallas kernel over the rows as they lie where `latent_attend_runs`
+    takes the shapes, `absorbed_attention_fn` elsewhere."""
     with piece("q_absorb"):
         q_lat = absorb_queries_fn(q_nope, wkv_b, geom)
     with piece("attend"):
-        u = absorbed_attention_fn(q_lat, q_rope, rows, have, dtype, geom)
+        if latent_attend_runs(q_lat.shape, rows.shape, dtype,
+                              geom.rope_dim):
+            from .pallas_kernels import latent_attend
+
+            u = latent_attend.latent_rows_attention(q_lat, q_rope, rows,
+                                                    have, dtype, geom)
+        else:
+            u = absorbed_attention_fn(q_lat, q_rope, rows, have, dtype, geom)
     with piece("q_absorb"):
         return expand_values_fn(u, wkv_b, geom)
 

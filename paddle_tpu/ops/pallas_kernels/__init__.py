@@ -19,6 +19,9 @@ its pages of the K/V pools; `attention_ops.paged_decode_attention_fn`),
 `paged_indexer` (a decode row's lightning-indexer scores over its pages of
 the key pool, where XLA gathered the pages and wrote the per-head scores;
 `sparse_moe_ops.decode_scores_fn`, its shape gate the only switch),
+`latent_attend` (the absorbed latent attention over each query's gathered
+cache rows, unpacked in VMEM; `latent_moe_ops._attend_rows`, its shape gate
+the only switch),
 `moe_experts` (the routed experts' stream) and `ssm_update` (a decode
 token's state update in place).
 """

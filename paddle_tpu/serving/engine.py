@@ -196,13 +196,14 @@ from ..data_feeder import _round_up_pow2
 from ..executor import Executor, Scope
 from ..framework import Program, program_guard
 from ..observability.slo import hist_p99_above
-from ..ops import attention_ops, sparse_moe_ops
+from ..ops import attention_ops, latent_moe_ops, sparse_moe_ops
 from ..resilience.faults import InjectedFault, fault_point
 from ..resilience.retry import serving_policy
 from . import model as sv_model
-from .kv_cache import (INDEX_POOL, OwnedPoolView, PagedKVPool, PrefixCache,
-                       create_device_pools, create_stacked_pools,
-                       create_state_pools, pool_var_names)
+from .kv_cache import (INDEX_POOL, LATENT_POOL, OwnedPoolView, PagedKVPool,
+                       PrefixCache, create_device_pools,
+                       create_stacked_pools, create_state_pools,
+                       pool_var_names)
 from .sampling import SamplingParams, request_rng, sample_token
 
 __all__ = ["GenRequest", "ContinuousBatchingScheduler", "ServingEngine",
@@ -761,6 +762,7 @@ class ServingEngine:
                 np.int8 if self.cfg.num_experts <= 128 else np.int16)
         self._grid_steps_by_signature: dict[tuple[int, int], int] = {}
         self._indexer_kernel_runs: bool | None = None
+        self._attend_kernel_runs: dict[tuple[int, int], bool] = {}
         self._prefill_run = self._exec_target(self._prefill_prog)
         self._decode_run = self._exec_target(self._decode_prog)
         self._window_run = self._exec_target(self._window_prog)
@@ -802,6 +804,7 @@ class ServingEngine:
             "sparse.kernel_layer_steps": 0,
             # a latent cache row and a share of the experts (ISSUE 39)
             "latent.gathered_rows": 0, "latent.attended_tokens": 0,
+            "latent.attend_kernel_layer_steps": 0,
             "moe.routed_pairs": 0, "moe.held_pairs": 0,
             # window and full attention layers over two pools (ISSUE 33)
             "kv.window_pages_released": 0, "kv.window_row_pages": 0,
@@ -2693,6 +2696,22 @@ class ServingEngine:
                 pool.dtype)
         return self._indexer_kernel_runs
 
+    def _attend_kernel(self, bb: int, pb: int) -> bool:
+        """Whether the Pallas kernel computes the absorbed attention of a
+        decode step of `bb` rows behind `pb` pages (`absorbed_attention_fn`
+        otherwise): the ops' own answer, asked once a signature."""
+        runs = self._attend_kernel_runs.get((bb, pb))
+        if runs is None:
+            cfg = self.cfg
+            pool = self._scope.find_var(LATENT_POOL)
+            slots = pb * self.page_size
+            runs = self._attend_kernel_runs[(bb, pb)] = \
+                latent_moe_ops.latent_attend_runs(
+                    (bb, cfg.num_heads, cfg.kv_lora_rank),
+                    (bb, min(cfg.index_topk, slots), pool.shape[-1]),
+                    cfg.dtype, cfg.rope_head_dim)
+        return runs
+
     def _decode_once(self, sp) -> bool:
         """One decode step under the open `serving.decode` span `sp`:
         enqueued over the running rows that go on, each taking from the
@@ -2776,6 +2795,8 @@ class ServingEngine:
                 else len(at) * pb * ps))
             self._count("latent.attended_tokens", L * sum(
                 min(k, pos + 1) if select else pos + 1 for pos, _ in at))
+            self._count("latent.attend_kernel_layer_steps",
+                        L if self._attend_kernel(bb, pb) else 0)
         handles = self._run_step("decode", self._decode_run, self._decode_io,
                                  feed, greedy, selection=bool(marked))
         self._enqueued(_InFlight("decode", rows, at=at, marked=marked,

@@ -394,6 +394,17 @@ def test_latent_moe_stack_compiled_for_v5e_moves_neither_pool(v5e_chip,
     # pages once for all its queries
     assert {name: kernel_calls(text, "paged_indexer_scores")
             for name, text in texts.items()} == {"decode": 2, "window": 0}
+    # the absorbed attention over the gathered rows is the kernel in both
+    # (a layer kind each; a window's inside its loop over query blocks):
+    # neither the `[queries, heads, 2,048]` scores nor an unpacked copy of
+    # the rows is written
+    assert {name: kernel_calls(text, "latent_rows_attention")
+            for name, text in texts.items()} == {"decode": 2, "window": 2}
+    for text in texts.values():
+        assert "f32[128,128,2048]" not in text \
+            and "f32[64,128,2048]" not in text \
+            and "bf16[128,2048,512]" not in text \
+            and "bf16[64,2048,512]" not in text
 
 
 def test_token_row_gathers_counts_rows_not_slabs():

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import deepseek_v32_lm as ref
+from paddle_tpu import observability as obs
 from paddle_tpu import unique_name
 from paddle_tpu.executor import Executor, Scope
 from paddle_tpu.framework import Program, program_guard
@@ -606,6 +607,66 @@ def test_the_paged_indexer_kernel_serves_what_the_gather_served(monkeypatch):
     _serve(eng, _prompts(31, 40), new=4)
     assert eng.stats["sparse.layer_steps"] > 0
     assert eng.stats["sparse.kernel_layer_steps"] == 0
+
+
+def test_the_latent_attend_kernel_serves_what_the_jnp_form_served(
+        monkeypatch):
+    """The "latent_moe" engine at a geometry `latent_attend_supported`
+    takes (8 heads over a bfloat16 latent of 256 and 8 rotary lanes in rows
+    of 256 words, an indexer keeping 128 positions), prompts of 150-210
+    tokens in windows of 64: every window past the second runs behind a
+    context longer than the selection (`_window_rows`), every decode row
+    attends a selection of 128. With the absorbed attention computed by the
+    Pallas kernel (interpreter) the engine serves the tokens the jnp form
+    served, and
+    `serving.latent.attend_kernel_layer_steps` equals
+    `serving.sparse.layer_steps`; on the jnp arm and at the rehearsal
+    geometry it stays 0."""
+    from paddle_tpu.ops.pallas_kernels import latent_attend
+
+    cfg = sv_model.latent_moe_tiny(
+        dtype="bfloat16", num_heads=8, kv_lora_rank=256, rope_head_dim=8,
+        index_topk=128, prefill_chunk=64, max_position=512)
+    prompts = _prompts(47, 210, 150)
+    calls = []
+    kernel = latent_attend.latent_rows_attention
+
+    def counted(q_lat, q_rope, rows, *rest):
+        calls.append((q_lat.shape[0], rows.shape[1]))
+        return kernel(q_lat, q_rope, rows, *rest)
+
+    monkeypatch.setattr(latent_attend, "latent_rows_attention", counted)
+
+    def served():
+        eng = _engine(cfg, pool_pages=96)
+        return eng, _serve(eng, prompts, new=5)
+
+    eng, was = served()
+    assert eng._scope.find_var("kv_cache.latent").shape == (3 * 96, PS, 256)
+    assert eng.stats["sparse.layer_steps"] > 0 and not calls
+    assert eng.stats["latent.attend_kernel_layer_steps"] == 0
+    booked = obs.snapshot()["counters"].get(
+        "serving.latent.attend_kernel_layer_steps", 0)
+    monkeypatch.setattr(latent_attend, "INTERPRET", True)
+    eng, now = served()
+    # traced for a window's 64 queries and for decode rows, all over 128
+    assert {k for _, k in calls} == {128} and 64 in {r for r, _ in calls} \
+        and len({r for r, _ in calls}) > 1
+    assert eng.stats["latent.attend_kernel_layer_steps"] \
+        == eng.stats["sparse.layer_steps"] > 0
+    assert obs.snapshot()["counters"][
+        "serving.latent.attend_kernel_layer_steps"] - booked \
+        == eng.stats["sparse.layer_steps"]
+    for a, b in zip(now, was):
+        assert a.out_tokens == b.out_tokens
+        # a later layer selects on what the attention before it gave: a
+        # sum in another order may turn a tie between two positions
+        assert a.selection[0] == b.selection[0]
+        assert np.mean(a.selection[1] == b.selection[1]) > 0.99
+    eng = _engine()
+    _serve(eng, _prompts(31, 40), new=4)
+    assert eng.stats["sparse.layer_steps"] > 0
+    assert eng.stats["latent.attend_kernel_layer_steps"] == 0
 
 
 def test_block_field_and_refusals():

@@ -387,7 +387,48 @@ def _paged_indexer_cases(spec):
              lambda J=J, D=D: case(J, D)) for J, D in ((64, 128), (16, 64))]
 
 
+def _latent_attend_cases(spec):
+    """The latent rows attention at the DeepSeek-V3.2-Exp cell's shapes:
+    128 heads over a 512-value latent and a 64-lane rotary key in rows of
+    384 words, a decode step's 128 queries and a window's block of 64, each
+    over its own 2,048 gathered rows: queries with every row, with a few
+    and with ONE (the context behind them is shorter than the selection),
+    the rows' padding words holding NaN patterns."""
+    from paddle_tpu.ops import latent_moe_ops as ops
+    from paddle_tpu.serving import DecoderConfig
+    from paddle_tpu.serving import model as sv_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "deepseek_v32_exp.json")) as f:
+        cfg = DecoderConfig(**json.load(f)["engine"]["config_kwargs"])
+    geom = ops.Geometry(**sv_model._latent_geometry(cfg))
+    assert (geom.num_heads, geom.kv_rank, geom.rope_dim) == (128, 512, 64)
+
+    def case(R):
+        K, words = 2048, 384
+        ks = jax.random.split(jax.random.PRNGKey(41), 4)
+        rows = jax.jit(lambda c, r: ops.join_latent_fn(
+            c, r, jnp.bfloat16, words).at[..., 288:].set(0x7FC1FFFF))(
+                _rand(ks[0], (R, K, 512), "float32"),
+                _rand(ks[1], (R, K, 64), "float32"))
+        q_lat = _rand(ks[2], (R, 128, 512), "float32", 0.5)
+        q_rope = _rand(ks[3], (R, 128, 64), "float32", 0.5)
+        live = jnp.where(jnp.arange(R) % 5 == 3,
+                         1 + jnp.arange(R) * 37 % K, K).at[-1].set(1)
+        have = jnp.arange(K)[None, :] < live[:, None]
+        assert spec.supported(q_lat.shape, rows.shape, jnp.bfloat16,
+                              geom.rope_dim)
+        return _compare(spec.fn, spec.reference,
+                        (q_lat, q_rope, rows, have, jnp.bfloat16, geom), 0,
+                        "bfloat16")
+
+    return [(f"r{R} nh128 latent512 rope64 k2048 words384 bfloat16 ragged",
+             lambda R=R: case(R)) for R in (128, 64)]
+
+
 CASES = {
+    "latent_rows_attention": _latent_attend_cases,
     "indexer_paged_scores": _paged_indexer_cases,
     "ssm_decode_update": _ssm_update_cases,
     "attention_paged_decode": lambda spec: (_paged_cases(spec)
