@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import flags, profiler, tuning
+from . import flags, profiler
 from . import observability as obs
 from .framework import OpError, Program, Variable, default_main_program
 from .ops.registry import ExecContext, get_op_def
@@ -637,11 +637,6 @@ class Executor:
                 while len(self._inflight) > window:
                     with profiler.stage_timer("pipeline.window_drain"):
                         self._drain_oldest()
-            # bounded online exploration (FLAGS_tuning_mode=explore): the
-            # host just drained to the runahead window, so the device has
-            # queued work and the host has an idle gap — probe at most one
-            # recorded candidate every FLAGS_tuning_explore_every steps
-            tuning.maybe_explore()
         return outs
 
     def wait(self):

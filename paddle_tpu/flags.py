@@ -72,46 +72,17 @@ _define("bn_fuse_stats", True,
         "(PERF.md r5)")
 _define("tuning_mode", "off",
         "framework-wide autotuner (paddle_tpu/tuning/): 'off' keeps every "
-        "lever on its pre-tuner logic; 'consult' resolves tunable decisions "
+        "lever on its own shape rule; 'consult' resolves tunable decisions "
         "(conv lowering, attention backend, conv+BN fusion, AMP gray ops, "
         "bucket boundaries) through the tier policy exact-DB-hit -> "
-        "learned cost model -> analytic prior -> conservative default; "
-        "'sweep' resolves analytically but records every distinct decision "
-        "key into the DB as a candidate so tools/tune.py knows what to "
-        "measure; 'explore' is consult plus bounded online measurement — "
-        "tuning/learned/explore.py probes one recorded candidate every "
-        "FLAGS_tuning_explore_every executor steps and promotes "
-        "out-of-interference-band verdicts to swept entries")
+        "analytic prior -> conservative default; 'sweep' resolves "
+        "analytically but records every distinct decision key into the DB "
+        "as a candidate so tools/tune.py knows what to measure")
 _define("tuning_db", "",
         "path of the persistent tuning decision database (schema-versioned "
         "JSON, atomic temp+rename writes; tuning/db.py). Empty = no DB: "
         "consult mode degrades to the analytic priors. A corrupt/missing "
         "file warns once and falls back to analytic — never an error")
-_define("tuning_measurements", "",
-        "path of the append-only JSONL measurement store "
-        "(tuning/learned/store.py) tools/tune.py sweeps, tools/_mc_ab.py "
-        "and explore probes append raw per-arm window timings to — the "
-        "learned cost model's training set. Empty = derived from "
-        "FLAGS_tuning_db (<db stem>.measurements.jsonl next to it); with "
-        "no DB either, nothing records")
-_define("tuning_record", "auto",
-        "measurement-store gate (tuning/learned/store.py): 'auto' "
-        "(default) records from the tools (tune.py sweeps, _mc_ab.py) "
-        "whenever a store path resolves but from the runtime "
-        "only under tuning_mode sweep/explore; 'on' always records; 'off' "
-        "never records")
-_define("tuning_model", "",
-        "path of the trained cost-model artifact (tools/costmodel.py "
-        "train; tuning/learned/model.py). Empty = derived from "
-        "FLAGS_tuning_db (<db stem>.model.json next to it). Missing file "
-        "= no learned tier; a corrupt file warns once and the policy "
-        "falls back to the analytic prior — never an error")
-_define("tuning_explore_every", 64,
-        "explore-mode pacing: probe at most one candidate key per this "
-        "many executor steps (tuning/learned/explore.py). Each probe is a "
-        "few tiny timed windows in the async window-drain gap; verdicts "
-        "inside the interference band never overwrite the analytic "
-        "decision. <= 0 disables probing even in explore mode")
 _define("pallas_epilogue", "auto",
         "fused normalize+affine+activation(+residual) epilogue kernels "
         "(ops/pallas_kernels/epilogue.py). 'auto' (default): when "
@@ -385,34 +356,6 @@ _define("disagg_lease_ttl_s", 2.0,
         "under the normal failover budget. Scaled by FLAGS_watchdog_scale "
         "(slow CI must not reap healthy handoffs); commits that lose the "
         "expiry race are rejected atomically, never half-adopted")
-# learned serving control (serving/control/ — see README "Learned serving
-# control")
-_define("serve_control_mode", "shadow",
-        "the learned serving controller: 'off' disables observation "
-        "entirely; 'shadow' (default) observes regimes, proposes knob "
-        "configs and logs/counts them but never applies one; 'apply' "
-        "stages confident proposals for adoption at the next safe "
-        "boundary (engine idle gap / router epoch tick), re-running "
-        "warmup_decode when the decode bucket geometry changes")
-_define("serve_control_store", "",
-        "measurement-store path for serving.control regime rows; empty "
-        "falls back to the tuning store (FLAGS_tuning_measurements / "
-        "derived from FLAGS_tuning_db) — kernels and regimes share one "
-        "append-only dataset unless split out")
-_define("serve_control_model", "",
-        "trained control-model artifact; empty falls back to "
-        "FLAGS_tuning_model (the serving.control group ships inside the "
-        "same tools/costmodel.py artifact). Missing = hand flags; corrupt "
-        "warns once and fails open to the hand flags")
-_define("serve_control_conf", 0.6,
-        "confidence threshold: a control proposal stands only when the "
-        "trained group's holdout rank accuracy clears this floor (the "
-        "stricter of this and the model-wide gate); below it every "
-        "regime serves the hand-flag config")
-_define("serve_control_epoch_s", 5.0,
-        "controller epoch interval in seconds: regimes are observed, "
-        "realized goodput recorded and proposals made at most once per "
-        "epoch per engine. <=0 disables the tick entirely")
 # tiered giant-embedding knobs (paddle_tpu/embedding/, the minimize()-time
 # rewrite in passes.rewrite_tiered_embeddings — see README "Tiered
 # embeddings")

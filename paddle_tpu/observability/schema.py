@@ -111,10 +111,8 @@ DECLARED: list[tuple] = [
     ("serving.step.seconds", HISTOGRAM,
      "one ServingEngine.step() iteration, the root of the tree", ()),
     ("serving.housekeeping.seconds", HISTOGRAM,
-     "config adoption, fault points, deadline expiry, pool audit, ladder, "
-     "occupancy and the controller tick (three spans a step)", ()),
-    ("serving.control.epoch.seconds", HISTOGRAM,
-     "one controller epoch: features, record_row, propose", ()),
+     "fault points, deadline expiry, pool audit, ladder and occupancy "
+     "(two spans a step)", ()),
     ("serving.admit.seconds", HISTOGRAM,
      "the admission loop: prefix match, pin, allocate with eviction, and "
      "the prefills of what it admitted", ()),
@@ -287,18 +285,6 @@ DECLARED: list[tuple] = [
     ("fleet.lease.active", GAUGE, "leases currently PREPARED", ()),
     ("fleet.lease.pinned_pages", GAUGE,
      "shared-pool pages currently pinned by leases (in transit)", ()),
-    # -- learned serving control (serving/control/, ISSUE 20) ---------------
-    ("serving.control.proposals", COUNTER,
-     "knob-config proposals resolved, by tier (learned = a gated ridge "
-     "prediction stood; hand = the flag config served)", ("tier",)),
-    ("serving.control.fallbacks", COUNTER,
-     "proposals that fell back to the hand flags, by reason (no_model/"
-     "no_group/accuracy/envelope/features/off/...)", ("reason",)),
-    ("serving.control.staged", COUNTER,
-     "apply-mode proposals staged as a pending EngineConfig", ()),
-    ("serving.control.applies", COUNTER,
-     "pending EngineConfigs adopted at a safe boundary (engine idle gap "
-     "/ router epoch tick)", ()),
     # -- per-sequence state rows and expert routing (ISSUE 25) --------------
     ("serving.state.restores", COUNTER,
      "prefix hits that resumed from a page's state row, or from a "
@@ -424,18 +410,6 @@ DECLARED: list[tuple] = [
      "real token x layer pairs that prefill windows scanned", ()),
     ("serving.ssm.scan_layer_steps", COUNTER,
      "layer x window pairs: the calls of the chunked scan", ()),
-    ("serving.control.rewarmups", COUNTER,
-     "warmup_decode re-runs forced by an adopted bucket-geometry change "
-     "(keeps XLA compiles off the serving path)", ()),
-    ("serving.control.regime", GAUGE,
-     "current traffic-regime id (stable hash bucket of the regime key)",
-     ()),
-    ("serving.control.goodput_rel_err", HISTOGRAM,
-     "realized-vs-predicted goodput relative error per controller epoch "
-     "(the controller grading its own prior)", ()),
-    ("serving.control.actuation", EVENT,
-     "actuation lifecycle record (staged/adopted, geometry change, "
-     "rewarm)", ()),
     # -- the host's own pauses (observability/registry._GcWatch) -------------
     ("host.gc.collections", COUNTER,
      "garbage collections by generation", ("generation",)),
@@ -466,20 +440,8 @@ DECLARED: list[tuple] = [
      "verdict gave way to the reference)", ("kind", "chosen", "ran")),
     # -- autotuner provenance (tuning/policy.py) ----------------------------
     ("tuning.decisions", COUNTER,
-     "decide() resolutions by (op, tier) — tier in "
-     "db/learned/analytic/default",
+     "decide() resolutions by (op, tier) — tier in db/analytic/default",
      ("op", "tier")),
-    # -- learned cost model (tuning/learned/) -------------------------------
-    ("tuning.learned.predictions", COUNTER,
-     "learned-tier decisions that stood (confidence gates passed, "
-     "validate accepted)", ("op",)),
-    ("tuning.learned.fallbacks", COUNTER,
-     "learned-tier attempts that fell back to the analytic prior, by "
-     "reason (accuracy/envelope/features/feature_drift/validate)",
-     ("op", "reason")),
-    ("tuning.learned.explore_promotions", COUNTER,
-     "explore-mode candidates promoted to swept DB entries by an "
-     "out-of-band online verdict", ("op",)),
     # -- tiered embeddings (embedding/engine.py) ----------------------------
     ("emb.hit_ids", COUNTER,
      "id occurrences served from the hot-ID cache", ("table",)),

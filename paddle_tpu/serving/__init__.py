@@ -1,9 +1,9 @@
 """LLM serving runtime: paged KV cache + continuous batching + ragged
-paged decode attention (ROADMAP item 1; "Ragged Paged Attention",
-arXiv:2604.15464 for the kernel, "Tensor Processing Primitives",
-arXiv:2104.05755 for the reusable-primitive framing).
+paged decode attention ("Ragged Paged Attention", arXiv:2604.15464 for the
+kernel, "Tensor Processing Primitives", arXiv:2104.05755 for the
+reusable-primitive framing).
 
-Four pieces, one runtime:
+Five pieces, one runtime:
   * `kv_cache`   — fixed-size pages over a preallocated HBM pool (device
                    side: persistable pool vars the compiled steps update in
                    place; host side: refcounted free-list + per-request page
@@ -57,7 +57,7 @@ Four pieces, one runtime:
 Knobs: FLAGS_serving_page_size, FLAGS_serving_pool_pages,
 FLAGS_serving_max_inflight, FLAGS_serving_sched_policy,
 FLAGS_serving_prefix_cache, FLAGS_serving_draft_k, FLAGS_serving_tp (see
-README "Serving"). Load: the serving cells of BENCHMARK.json
+README "Serving"), each read once, when an engine is built. Load: the serving cells of BENCHMARK.json
 (benchmark/runners/serve_open_loop.py drives an engine open loop from
 benchmark/traffic/open_loop.py's schedules).
 """

@@ -207,7 +207,7 @@ from .kv_cache import (INDEX_POOL, LATENT_POOL, OwnedPoolView, PagedKVPool,
 from .sampling import SamplingParams, request_rng, sample_token
 
 __all__ = ["GenRequest", "ContinuousBatchingScheduler", "ServingEngine",
-           "EngineConfig", "AdmissionRejected", "ngram_draft"]
+           "AdmissionRejected", "ngram_draft"]
 
 WAITING, RUNNING, FINISHED, ABORTED = "waiting", "running", "finished", "aborted"
 DEADLINE_EXCEEDED, SHED = "deadline_exceeded", "shed"
@@ -223,8 +223,10 @@ _LADDER_RUNGS = {1: "spec_off", 2: "lookahead_shrink",
 # an iteration this long emits serving.slow_step with what it was made of:
 # on the chip a normal one takes 0.06-0.5 s and a stall thousands of ms
 SLOW_STEP_S = 1.0
-# the fewest row slots of `model.LAST_TOKEN` (a few hundred bytes): room for
-# a max_inflight a controller raises past the constructor's
+# the fewest row slots of `model.LAST_TOKEN` (a few hundred bytes), whatever
+# max_inflight. Nothing needs the room: it stays so that the compiled step
+# programs are the ones PR 41 measured, and folds into
+# `_round_up_pow2(max_inflight)` at the next PR that may move HLO (ROADMAP D6)
 MIN_TOKEN_SLOTS = 64
 
 
@@ -249,35 +251,6 @@ class _StepFailure(RuntimeError):
         super().__init__(f"{kind} dispatch failed after retries: {cause}")
         self.kind = kind
         self.cause = cause
-
-
-@dataclasses.dataclass(frozen=True)
-class EngineConfig:
-    """One immutable snapshot of every runtime knob the engine consults
-    while scheduling (ISSUE 20). The ctor resolves flags into ONE of these;
-    every hot-path read goes through it, so a mid-request flag flip — or a
-    controller actuation — can never tear a request's config: the only way
-    a knob changes is `propose_config` staging a replacement snapshot that
-    `maybe_adopt_config` swaps in whole at a safe boundary (no in-flight
-    work). Construction-only knobs (page/pool geometry, tp, scheduler
-    policy, prefix-cache presence) stay plain attributes — no actuation
-    path exists for them."""
-
-    max_inflight: int
-    draft_k: int
-    deadline_s: float
-    priority_default: int
-    shed_occupancy: float
-    shed_queue_depth: int
-    shed_ttft_p99_ms: float
-    degrade_after: int
-    audit_every: int
-
-    def bucket_geometry(self) -> tuple:
-        """What the decode-signature lattice depends on: the batch-bucket
-        ceiling and the window program's draft width. A pending config
-        with a different geometry re-runs warmup_decode on adoption."""
-        return (_round_up_pow2(max(1, self.max_inflight)), self.draft_k)
 
 
 def ngram_draft(tokens, k: int, window: int = 128) -> list[int]:
@@ -479,6 +452,24 @@ class ServingEngine:
     scope); the parallelism is inside the compiled steps.
     """
 
+    @staticmethod
+    def default_sizes(cfg, page_size: int, max_inflight: int) -> dict:
+        """What the step programs are built with beside the KV pool, where
+        no argument of the constructor says otherwise: `token_slots` always,
+        `window_pages` of a family with sliding-window layers, `state_slots`
+        of one with a recurrent state (the constructor's docstring has the
+        reasons; tests/test_kernel_choice.py builds the benchmark's programs
+        from the same answer)."""
+        sizes = {"token_slots": _round_up_pow2(max(max_inflight,
+                                                   MIN_TOKEN_SLOTS))}
+        if cfg.windowed:
+            sizes["window_pages"] = 2 * max_inflight * (
+                sv_model.window_table_pages(cfg, page_size) + 1)
+        if cfg.recurrent:
+            sizes["state_slots"] = max_inflight + 1 \
+                + max(2, max_inflight // 4 - 1)
+        return sizes
+
     def __init__(self, cfg: "sv_model.DecoderConfig | None" = None,
                  page_size: int | None = None,
                  pool_pages: int | None = None,
@@ -528,40 +519,28 @@ class ServingEngine:
             prefix_cache = bool(flags.get_flag("serving_prefix_cache"))
         self.tp = int(tp if tp is not None else flags.get_flag("serving_tp"))
         self.seed = int(seed)
-        # runtime knobs resolve ONCE into an immutable EngineConfig
-        # snapshot (ISSUE 20): the scheduling loop reads self._ecfg, never
-        # the flags — a flag flipped mid-request changes nothing until an
-        # explicit propose/adopt cycle swaps the whole snapshot at a safe
-        # boundary. Resilience defaults keep the machinery off/cheap.
-        self._ecfg = EngineConfig(
-            max_inflight=int(max_inflight
-                             or flags.get_flag("serving_max_inflight")),
-            draft_k=int(draft_k if draft_k is not None
-                        else flags.get_flag("serving_draft_k")),
-            deadline_s=float(
-                deadline_s if deadline_s is not None
-                else flags.get_flag("serving_deadline_s")),
-            priority_default=int(
-                priority_default if priority_default is not None
-                else flags.get_flag("serving_priority_default")),
-            shed_occupancy=float(
-                shed_occupancy if shed_occupancy is not None
-                else flags.get_flag("serving_shed_occupancy")),
-            shed_queue_depth=int(
-                shed_queue_depth if shed_queue_depth is not None
-                else flags.get_flag("serving_shed_queue_depth")),
-            shed_ttft_p99_ms=float(
-                shed_ttft_p99_ms if shed_ttft_p99_ms is not None
-                else flags.get_flag("serving_shed_ttft_p99_ms")),
-            degrade_after=max(1, int(
-                degrade_after if degrade_after is not None
-                else flags.get_flag("serving_degrade_after"))),
-            audit_every=int(
-                audit_every if audit_every is not None
-                else flags.get_flag("serving_audit_every")),
-        )
-        self._pending_ecfg: EngineConfig | None = None
-        self._warm_ctx: int | None = None
+        # the runtime knobs resolve ONCE, here: the scheduling loop reads
+        # these attributes, never the flags, so a flag flipped after
+        # construction moves nothing. Resilience defaults keep the machinery
+        # off/cheap.
+        def knob(arg, flag, kind):
+            return kind(arg if arg is not None else flags.get_flag(flag))
+
+        self.max_inflight = int(max_inflight
+                                or flags.get_flag("serving_max_inflight"))
+        self.draft_k = knob(draft_k, "serving_draft_k", int)
+        self.deadline_s = knob(deadline_s, "serving_deadline_s", float)
+        self.priority_default = knob(priority_default,
+                                     "serving_priority_default", int)
+        self.shed_occupancy = knob(shed_occupancy, "serving_shed_occupancy",
+                                   float)
+        self.shed_queue_depth = knob(shed_queue_depth,
+                                     "serving_shed_queue_depth", int)
+        self.shed_ttft_p99_ms = knob(shed_ttft_p99_ms,
+                                     "serving_shed_ttft_p99_ms", float)
+        self.degrade_after = max(1, knob(degrade_after,
+                                         "serving_degrade_after", int))
+        self.audit_every = knob(audit_every, "serving_audit_every", int)
         if self.draft_k < 0:
             raise ValueError(f"draft_k must be >= 0, got {self.draft_k}")
         if self.cfg.scanned:
@@ -616,6 +595,8 @@ class ServingEngine:
                                       pool_owner or f"engine@{id(self)}")
         else:
             self.pool = PagedKVPool(self.pool_pages, self.page_size)
+        sizes = self.default_sizes(self.cfg, self.page_size,
+                                   self.max_inflight)
         # the sliding-window layers' pool: the same allocator a second time
         self.window_pool = None
         self._wtable_decode = self._wtable_chunk = 0
@@ -628,8 +609,8 @@ class ServingEngine:
             self._wtable_chunk = sv_model.window_table_pages(
                 self.cfg, self.page_size, self.cfg.prefill_chunk)
             self.window_pool = PagedKVPool(
-                int(window_pool_pages or 2 * self.max_inflight
-                    * (self._wtable_decode + 1)), self.page_size)
+                int(window_pool_pages or sizes["window_pages"]),
+                self.page_size)
         # the recurrent state's slots: the same allocator a third time, one
         # "token" a page; slot ids are its page ids
         self.state_pool = None
@@ -642,8 +623,7 @@ class ServingEngine:
                     f"{self.page_size} (a snapshot hangs on the block a "
                     f"chunk ends)")
             self.state_pool = PagedKVPool(
-                int(state_slots or self.max_inflight + 1
-                    + max(2, self.max_inflight // 4 - 1)), 1)
+                int(state_slots or sizes["state_slots"]), 1)
             (self._scratch_slot,) = self.state_pool.allocate(1)
         self.prefix_cache = PrefixCache(self.pool, self.window_pool,
                                         self.state_pool) \
@@ -680,11 +660,9 @@ class ServingEngine:
             second = {"state_slots": self.state_pool.num_pages}
         # the last token of every row slot, on the device (model.LAST_TOKEN):
         # a running row holds a slot while it has steps to run. Sized for
-        # the row bucket (and for what a controller may raise max_inflight
-        # to); a scope shared by several engines holds one a pool owner.
-        # The last entry is the padding rows'.
-        self._token_slots = _round_up_pow2(max(self.max_inflight,
-                                               MIN_TOKEN_SLOTS))
+        # the row bucket; a scope shared by several engines holds one a pool
+        # owner. The last entry is the padding rows'.
+        self._token_slots = sizes["token_slots"]
         self._slots_free = list(range(self._token_slots))[::-1]
         self._last_token = sv_model.LAST_TOKEN if shared_scope is None \
             else f"{sv_model.LAST_TOKEN}.{getattr(self.pool, 'owner', id(self))}"
@@ -793,8 +771,6 @@ class ServingEngine:
             "recovery.replayed": 0, "recovery.quarantined": 0,
             "ladder.spec_off": 0, "ladder.lookahead_shrink": 0,
             "ladder.cache_evict": 0, "ladder.shed": 0,
-            # learned serving control (ISSUE 20)
-            "control.applies": 0, "control.rewarmups": 0,
             # per-sequence state rows and expert routing (ISSUE 25)
             "state.restores": 0, "state.recomputed_tokens": 0,
             "moe.experts_touched": 0, "moe.layer_steps": 0,
@@ -822,113 +798,6 @@ class ServingEngine:
             "ssm.scan_tokens": 0, "ssm.scan_layer_steps": 0,
             "peak_state_slots_in_use": 0,
         }
-        # the learned controller's per-engine epoch hook (ISSUE 20):
-        # shadow by default — one perf_counter read per step until an
-        # epoch is due, then observe/propose/log (apply mode additionally
-        # stages a pending EngineConfig for the next safe boundary)
-        from . import control as sv_control
-
-        self._ctrl = sv_control.Controller()
-
-    # -- runtime knobs: the EngineConfig snapshot (ISSUE 20) ----------------
-    # Compatibility properties: every pre-existing `engine.<knob>` read —
-    # internal hot paths and external harnesses alike — resolves through
-    # the one immutable snapshot.
-    @property
-    def engine_config(self) -> EngineConfig:
-        return self._ecfg
-
-    @property
-    def max_inflight(self) -> int:
-        return self._ecfg.max_inflight
-
-    @property
-    def draft_k(self) -> int:
-        return self._ecfg.draft_k
-
-    @property
-    def deadline_s(self) -> float:
-        return self._ecfg.deadline_s
-
-    @property
-    def priority_default(self) -> int:
-        return self._ecfg.priority_default
-
-    @property
-    def shed_occupancy(self) -> float:
-        return self._ecfg.shed_occupancy
-
-    @property
-    def shed_queue_depth(self) -> int:
-        return self._ecfg.shed_queue_depth
-
-    @property
-    def shed_ttft_p99_ms(self) -> float:
-        return self._ecfg.shed_ttft_p99_ms
-
-    @property
-    def degrade_after(self) -> int:
-        return self._ecfg.degrade_after
-
-    @property
-    def audit_every(self) -> int:
-        return self._ecfg.audit_every
-
-    def propose_config(self, knobs: dict, source: str = "controller") -> bool:
-        """Stage a knob change (controller proposal or operator nudge) as
-        a PENDING EngineConfig. Nothing changes here: the pending snapshot
-        waits for `maybe_adopt_config` at a safe boundary. Only the
-        online-actuatable knobs are honored (mi/dk/sq/so/da — see
-        control/knobs.py); construction-only fields keep their values.
-        Returns True when a pending config was staged (i.e. the proposal
-        differs from the current snapshot)."""
-        cur = self._ecfg
-        cand = dataclasses.replace(
-            cur,
-            max_inflight=max(1, int(knobs.get("mi", cur.max_inflight))),
-            draft_k=max(0, int(knobs.get("dk", cur.draft_k))),
-            shed_queue_depth=max(0, int(knobs.get("sq",
-                                                  cur.shed_queue_depth))),
-            shed_occupancy=min(1.0, max(0.0, float(
-                knobs.get("so", cur.shed_occupancy * 100)) / 100.0)),
-            degrade_after=max(1, int(knobs.get("da", cur.degrade_after))),
-        )
-        if cand == cur:
-            self._pending_ecfg = None
-            return False
-        self._pending_ecfg = cand
-        obs.event("serving.control.actuation",
-                  {"phase": "staged", "source": source,
-                   "geometry_change": cand.bucket_geometry()
-                   != cur.bucket_geometry()})
-        return True
-
-    def maybe_adopt_config(self) -> bool:
-        """Adopt the pending EngineConfig — but ONLY at a safe boundary:
-        no waiting and no running requests (the engine idle gap; the
-        fleet replica pump and submit()/step() all call this, so the gap
-        is found wherever it opens). When the decode bucket geometry
-        changed, re-runs `warmup_decode` over the previously warmed
-        context range so the next measured pass still triggers zero fresh
-        XLA compiles — no stray compile ever lands on the serving path."""
-        pend = self._pending_ecfg
-        if pend is None or self.has_work():
-            return False
-        old = self._ecfg
-        self._ecfg = pend
-        self._pending_ecfg = None
-        self._count("control.applies")
-        rewarmed = False
-        if (pend.bucket_geometry() != old.bucket_geometry()
-                and self._warm_ctx is not None):
-            self.warmup_decode(self._warm_ctx)
-            self._count("control.rewarmups")
-            rewarmed = True
-        obs.event("serving.control.actuation",
-                  {"phase": "adopted", "rewarmed": rewarmed,
-                   "max_inflight": pend.max_inflight,
-                   "draft_k": pend.draft_k})
-        return True
 
     def _page_bucket(self, n: int) -> int:
         """The page-table width a step with `n` live pages compiles for: a
@@ -1006,9 +875,6 @@ class ServingEngine:
         buckets below it). Returns the signature count."""
         max_context = min(int(max_context or self.cfg.max_position),
                           self.cfg.max_position)
-        # remembered so a controller actuation that changes the bucket
-        # geometry can re-warm the SAME context range before serving
-        self._warm_ctx = max_context
         pbs = sorted({self._page_bucket(self.pool.pages_for(c))
                       for c in range(max(1, int(min_context)),
                                      max_context + 2)})
@@ -1145,10 +1011,6 @@ class ServingEngine:
         strictly lower priority to make room, and raises AdmissionRejected
         with a retry-after hint when that is not enough — explicit refusal
         instead of an unbounded queue."""
-        # the admit boundary is a safe boundary: nothing in flight means a
-        # staged controller config can swap in before this request's
-        # admission reads any knob
-        self.maybe_adopt_config()
         if len(prompt) + max_new_tokens > self.cfg.max_position:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_new_tokens ({max_new_tokens}) "
@@ -1167,8 +1029,7 @@ class ServingEngine:
             # grew to the pool size while the engine drained would shed
             # every future submit with no step ever running — the rung-3
             # eviction only fires on pressured STEPS, so an idle engine
-            # could never climb out (admission starvation the ISSUE 20
-            # knob sweep's engine reuse exposed).
+            # could never climb out.
             floor_pages = int(self.shed_occupancy * self.pool.num_pages)
             need = self.pool.pages_in_use - floor_pages + 1
             if need > 0:
@@ -1426,11 +1287,6 @@ class ServingEngine:
             except _StepFailure as e:
                 self._recover(f"step_fail:{e.kind}")
                 progressed = True
-            # controller epoch hook: one perf_counter read + compare per
-            # step until an epoch is due (the shadow-mode 0% overhead
-            # budget); a due epoch is its own span, serving.control.epoch
-            with obs.span("serving.housekeeping"):
-                self._ctrl.tick(self)
         self._note_step(sp.dur_s, obs.gc_pause_seconds() - gc0)
         return progressed
 
@@ -1453,7 +1309,6 @@ class ServingEngine:
 
     def _step_inner(self) -> bool:
         with obs.span("serving.housekeeping"):
-            self.maybe_adopt_config()
             try:
                 fault_point("serving_deadline")
             except InjectedFault:

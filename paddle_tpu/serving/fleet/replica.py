@@ -220,10 +220,6 @@ class EngineReplica:
         return progressed
 
     def _pump_inner(self) -> bool:
-        # the replica's idle gap is a safe actuation boundary (ISSUE 20):
-        # before admitting new arrivals, let a staged controller config
-        # swap in while nothing is in flight (no-op without one pending)
-        self.engine.maybe_adopt_config()
         progressed = self._admit_inbox()
         if self.state == DRAINING:
             self._handoff_waiting()

@@ -155,17 +155,8 @@ class FleetRouter:
                 if n_replicas is None else n_replicas)
         if n < 1:
             raise ValueError("n_replicas must be >= 1")
-        self.control_role_info: dict = {"tier": "hand",
-                                        "reason": "explicit_roles"}
         if roles is None:
-            # the prefill:decode split reads its prior from the control
-            # measurement store (ISSUE 20): the best-goodput recorded pd
-            # for THIS fleet size, confidence-gated back to the hand flag
-            # whenever the store is silent or the hand split ties it
-            from .. import control as sv_control
-
-            n_pre, self.control_role_info = \
-                sv_control.role_split_prior(n)
+            n_pre = int(flags.get_flag("disagg_prefill_replicas"))
             if n_pre:
                 if n_pre >= n:
                     raise ValueError(
@@ -374,25 +365,7 @@ class FleetRouter:
         if self.handoff is not None:
             progressed |= self._reap_orphans()
         self._check_health()
-        self._tick_control()
         return progressed
-
-    def _tick_control(self) -> None:
-        """Controller epochs for the fleet (ISSUE 20): tick every healthy
-        replica's own controller. An engine also ticks itself inside
-        step(), but an idle engine never steps — the router's poll is the
-        epoch clock of last resort. Ticks are idempotent per epoch (the
-        controller fires once per due time, whoever calls first), and
-        threaded fleets skip this entirely: the worker thread owns its
-        engine, and it ticks from inside step()."""
-        if self.pump != "inline":
-            return
-        for rep in self.replicas:
-            if rep.state != HEALTHY:
-                continue
-            ctrl = getattr(rep.engine, "_ctrl", None)
-            if ctrl is not None:
-                ctrl.tick(rep.engine)
 
     def _lease_clock(self) -> float:
         """Stall-capped clock for lease expiry, the TTL counterpart of the
@@ -479,30 +452,6 @@ class FleetRouter:
                        and q.state not in FLEET_TERMINAL)
         return max(assigned, rep.load())
 
-    def _placement_costs(self, cands) -> dict[int, float]:
-        """Apply-mode placement weighting (ISSUE 20): each candidate's
-        predicted seconds per goodput token for its CURRENT config, from
-        its controller's last epoch. Placement then minimizes
-        (load + 1) * cost — queue depth discounted by how fast the
-        replica is predicted to serve it. Every replica weighs 1.0 —
-        the plain least-loaded rule — unless the mode is apply AND a
-        prediction exists for ALL candidates: mixed scales (one replica
-        predicted at milliseconds, the rest defaulted to 1.0) would
-        stampede the predicted one."""
-        neutral = {r.rid: 1.0 for r in cands}
-        from .. import control as sv_control
-
-        if sv_control.mode() != "apply":
-            return neutral
-        out: dict[int, float] = {}
-        for r in cands:
-            ctrl = getattr(r.engine, "_ctrl", None)
-            c = ctrl.last_cost.get(id(r.engine)) if ctrl is not None else None
-            if not isinstance(c, (int, float)) or c <= 0:
-                return neutral
-            out[r.rid] = float(c)
-        return out
-
     def _place(self, freq: FleetRequest, exclude=frozenset()) -> None:
         cands = [r for r in self._healthy(exclude) if r.role != "prefill"]
         if not cands:
@@ -511,8 +460,7 @@ class FleetRouter:
                 f"(excluded {sorted(exclude)})")
         load = self._decode_load if self._disagg else \
             (lambda r: r.load())
-        costs = self._placement_costs(cands)
-        rank = lambda r: ((load(r) + 1) * costs[r.rid], r.rid)
+        rank = lambda r: (load(r), r.rid)
         if self.affinity:
             home = self._affinity_rid(freq.prompt)
             rep = next((r for r in cands if r.rid == home), None)

@@ -1,40 +1,36 @@
 """Framework-wide autotuner: a persistent per-(op, shape, dtype, device_kind)
 decision cache with measured A/B sweeps.
 
-PR 5 proved the pattern once — a hand-built tile-fill-vs-HBM cost model
-gates the implicit-GEMM conv lowering per shape. This package generalizes
-it (ROADMAP item 3, the TVM search-over-schedules framing, arXiv:1802.04799,
-with measured sweeps replacing hand models per arXiv:2008.01040): every
-per-shape perf lever resolves through ONE three-tier policy —
+Every per-shape perf lever resolves through ONE three-tier policy
+(`policy.decide`) —
 
     exact swept-DB hit  ->  analytic prior  ->  conservative default
 
-Levers wired through it today: conv2d lowering (direct vs implicit-GEMM,
-incl. 1x1-as-matmul), attention backend (XLA fusion vs the short-seq Pallas
+Levers wired through it: conv2d lowering (direct vs implicit-GEMM, incl.
+1x1-as-matmul), attention backend (XLA fusion vs the short-seq Pallas
 kernel vs the bundled flash kernel), conv+BN epilogue fusion
-(passes.fuse_conv_bn_stats), AMP gray-op list membership, and feed-bucketing
-boundaries. The DB is populated offline by `tools/tune.py`
-(median-of-windows timing, interference band, keep-or-retire verdict per
-shape) and consulted at minimize()/trace time under
-FLAGS_tuning_mode=consult; `provenance_snapshot()` says how much of a run
-resolved on swept decisions.
+(passes.fuse_conv_bn_stats), AMP gray-op list membership, feed-bucketing
+boundaries, the top-1 expert kernel and the state-space update kernel. The
+DB is populated offline by `tools/tune.py` (median-of-windows timing,
+interference band, keep-or-retire verdict per shape) and consulted at
+minimize()/trace time under FLAGS_tuning_mode=consult;
+`provenance_snapshot()` says how much of a run resolved on swept decisions.
+With the mode off (the default) each lever runs its own shape rule, and
+`tests/test_kernel_choice.py` pins the arm every benchmark configuration
+gets from it.
 """
 from .db import (DB_SCHEMA, TuningDB, amp_key, attention_key, bucket_key,
                  canonical_key, collective_key, conv_key, embedding_key,
                  epilogue_key, evidence, moe_experts_key,
                  ssm_update_key)
-from .policy import (consult_enabled, decide, device_kind, get_db,
-                     invalidate_db_cache, mode, on_minimize,
-                     provenance_snapshot, reset_provenance, sweep_enabled)
-from . import learned
-from .learned import maybe_explore
+from .policy import (decide, device_kind, get_db, invalidate_db_cache, mode,
+                     on_minimize, provenance_snapshot, reset_provenance,
+                     sweep_enabled)
 
 __all__ = [
     "DB_SCHEMA", "TuningDB", "canonical_key", "conv_key", "attention_key",
     "bucket_key", "amp_key", "collective_key", "epilogue_key",
     "embedding_key", "moe_experts_key", "ssm_update_key", "evidence",
-    "decide", "mode", "consult_enabled",
-    "sweep_enabled", "get_db", "invalidate_db_cache", "device_kind",
-    "provenance_snapshot", "reset_provenance", "on_minimize",
-    "learned", "maybe_explore",
+    "decide", "mode", "sweep_enabled", "get_db", "invalidate_db_cache",
+    "device_kind", "provenance_snapshot", "reset_provenance", "on_minimize",
 ]

@@ -41,11 +41,10 @@ __all__ = ["DB_SCHEMA", "TuningDB", "canonical_key", "conv_key",
 def evidence(measured: dict) -> dict:
     """The canonical `measured` block every writer attaches to an entry:
     {arm: {"median_s": ..., "band": ...}} distilled from full
-    tools/_timing.measure dicts. Offline sweeps (tools/tune.py) and
-    explore-mode promotions (tuning/learned/explore.py) both go through
-    here, so a candidate promoted online carries byte-identical evidence
-    to one swept offline — and a candidate entry that HAS been measured
-    (an in-band tie) keeps its times instead of just the decision."""
+    tools/_timing.measure dicts. Every writer (tools/tune.py's sweeps,
+    tools/_mc_ab.py --record) goes through here, so entries carry one
+    evidence format — and a candidate entry that HAS been measured (an
+    in-band tie) keeps its times instead of just the decision."""
     out = {}
     for arm in sorted(measured):
         m = measured[arm]

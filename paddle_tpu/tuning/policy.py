@@ -1,30 +1,24 @@
-"""Decision resolution: DB hit -> learned model -> analytic -> default.
+"""Decision resolution: swept-DB hit -> analytic prior -> default.
 
 `decide()` is the one consult point every tunable lever flows through
 (conv lowering, attention backend, conv+BN fusion, AMP list membership,
-bucket boundaries). Four tiers, strictly ordered:
+bucket boundaries, the expert and state-update kernels). Three tiers,
+strictly ordered:
 
   1. exact hit  — the swept DB has this (op, shape, dtype, device_kind) key;
-  2. learned    — the trained cost model (tuning/learned/) predicts per-arm
-                  times for this UNSEEN key and its confidence gates pass;
-  3. analytic   — the registered prior for the op kind (the PR 5 cost model
-                  for convs, the measured-dispatch rules for attention);
-  4. default    — the caller's conservative fallback (what the code did
-                  before the tuner existed).
+  2. analytic   — the registered prior for the op kind (the conv cost model,
+                  the shape rules of the attention and expert kernels);
+  3. default    — the caller's conservative fallback.
 
 Every resolution bumps a per-op provenance counter (`provenance_snapshot`):
 how much of a workload ran on swept decisions vs the prior.
 
 Modes (FLAGS_tuning_mode):
-  off     — decide() is never consulted; levers use their pre-tuner logic.
+  off     — decide() is never consulted; levers use their own shape rules.
   consult — resolve through the tiers above.
   sweep   — resolve analytically like `off`, but RECORD every distinct key
             encountered into the DB as a `candidate` entry (never clobbering
             a swept verdict) so `tools/tune.py` knows what to measure.
-  explore — consult, plus candidate recording, plus bounded ONLINE
-            measurement: tuning/learned/explore.py probes one recorded
-            candidate every FLAGS_tuning_explore_every executor steps and
-            promotes out-of-band verdicts to swept entries (TVM-style).
 """
 from __future__ import annotations
 
@@ -33,7 +27,7 @@ import threading
 from .. import flags
 from .db import TuningDB
 
-__all__ = ["decide", "mode", "consult_enabled", "sweep_enabled", "get_db",
+__all__ = ["decide", "mode", "sweep_enabled", "get_db",
            "invalidate_db_cache", "device_kind", "provenance_snapshot",
            "reset_provenance", "on_minimize"]
 
@@ -46,12 +40,7 @@ _counters: dict[str, dict[str, int]] = {}
 
 def mode() -> str:
     m = str(flags.get_flag("tuning_mode")).strip().lower()
-    return m if m in ("off", "consult", "sweep", "explore") else "off"
-
-
-def consult_enabled() -> bool:
-    # explore IS consult (same tier resolution) with online measurement on
-    return mode() in ("consult", "explore")
+    return m if m in ("off", "consult", "sweep") else "off"
 
 
 def sweep_enabled() -> bool:
@@ -118,21 +107,16 @@ def reset_provenance() -> None:
 
 
 def provenance_snapshot() -> dict:
-    """Per-op tier counts plus the aggregate rates: hit_rate is swept-DB
-    resolutions over all resolutions, tuned_rate additionally credits the
-    learned tier (a model prediction IS a measured-data decision, just an
-    interpolated one)."""
+    """Per-op tier counts plus the aggregate rate: hit_rate is swept-DB
+    resolutions over all resolutions."""
     with _lock:
         per_op = {op: dict(c) for op, c in _counters.items()}
     total = sum(sum(c.values()) for c in per_op.values())
     hits = sum(c["db"] for c in per_op.values())
-    learned = sum(c.get("learned", 0) for c in per_op.values())
     return {
         "decisions": total,
         "db_hits": hits,
-        "learned": learned,
         "hit_rate": round(hits / total, 4) if total else None,
-        "tuned_rate": round((hits + learned) / total, 4) if total else None,
         "per_op": per_op,
     }
 
@@ -140,39 +124,25 @@ def provenance_snapshot() -> dict:
 def decide(op: str, key: str, prior=None, default: dict | None = None,
            validate=None) -> tuple[dict, str]:
     """Resolve one decision. Returns (decision dict, tier) with tier in
-    {"db", "learned", "analytic", "default"}.
+    {"db", "analytic", "default"}.
 
     `prior`: zero-arg callable returning the analytic decision (evaluated
     lazily — cost models only run on a DB miss). `validate`: optional
-    predicate on a DB or learned decision; a decision the current build
-    cannot honor (e.g. a pallas backend off-TPU) falls through to the prior
-    instead of being obeyed blindly. In sweep mode the analytic resolution
-    is recorded as a candidate entry for tools/tune.py; explore mode records
-    candidates too (food for the online prober) while resolving normally."""
+    predicate on a DB decision; a decision the current build cannot honor
+    (e.g. a pallas backend off-TPU) falls through to the prior instead of
+    being obeyed blindly. In sweep mode the analytic resolution is recorded
+    as a candidate entry for tools/tune.py."""
     if sweep_enabled():
         d = _resolve_prior(op, prior, default)
         _record_candidate(key, d)
         return d
-    m = mode()
-    db = get_db()
-    entry = db.lookup(key)
+    entry = get_db().lookup(key)
     if entry is not None and entry.get("source") != "candidate":
         decision = entry["decision"]
         if validate is None or validate(decision):
             _bump(op, "db")
             return decision, "db"
-    from . import learned
-
-    ld = learned.decide_learned(op, key, validate)
-    if ld is not None:
-        _bump(op, "learned")
-        if m == "explore" and entry is None:
-            _record_candidate(key, (ld, "learned"))
-        return ld, "learned"
-    res = _resolve_prior(op, prior, default)
-    if m == "explore" and entry is None:
-        _record_candidate(key, res)
-    return res
+    return _resolve_prior(op, prior, default)
 
 
 def _resolve_prior(op, prior, default):

@@ -690,6 +690,53 @@ def test_sjf_policy_admits_shortest_first():
     assert eng.requests[long_rid].state == "finished"
 
 
+def test_knobs_are_fixed_when_the_engine_is_built():
+    """The nine runtime knobs resolve once, in the constructor, from its
+    arguments and then the flags: flipping every flag afterwards to a value
+    that would shed, expire or stall the same traffic moves neither the
+    engine's values nor what it admits and emits."""
+    hostile = {"serving_max_inflight": 1, "serving_draft_k": 3,
+               "serving_deadline_s": 1e-9, "serving_priority_default": 0,
+               "serving_shed_occupancy": 0.01, "serving_shed_queue_depth": 1,
+               "serving_shed_ttft_p99_ms": 1e-6, "serving_degrade_after": 1,
+               "serving_audit_every": 1}
+    knobs = [name[len("serving_"):] for name in hostile]
+    rng = np.random.default_rng(3)
+    prompts = [list(rng.integers(1, 97, 6 + i)) for i in range(4)]
+
+    def serve(eng):
+        rids = [eng.submit(p, max_new_tokens=4) for p in prompts]  # no reject
+        eng.step()
+        admitted = sum(eng.requests[r].state != "waiting" for r in rids)
+        eng.run_until_drained()
+        assert all(eng.requests[r].state == "finished" for r in rids)
+        return admitted, [eng.result(r) for r in rids]
+
+    def build():    # arguments for two knobs, the flags for the other seven
+        return ServingEngine(decoder_tiny(), page_size=4, pool_pages=64,
+                             max_inflight=2, degrade_after=2, seed=0)
+
+    snap = pt.flags.all_flags()
+    want_knobs = {"max_inflight": 2, "degrade_after": 2, **{
+        k: snap[f"serving_{k}"] for k in knobs
+        if k not in ("max_inflight", "degrade_after")}}
+    want = serve(build())
+    eng = build()
+    try:
+        pt.flags.set_flags(hostile)
+        assert {k: getattr(eng, k) for k in knobs} == want_knobs
+        assert eng._slo is None         # no TTFT floor when it was built
+        assert serve(eng) == want and want[0] == 2
+        assert eng.stats["shed"] == eng.stats["rejects"] == 0
+        assert eng.stats["deadline_exceeded"] == eng.stats["spec_steps"] == 0
+        # and an engine built NOW takes the flags' values
+        late = ServingEngine(decoder_tiny(), page_size=4, pool_pages=64)
+        assert {k: getattr(late, k) for k in knobs} == {
+            k: hostile[f"serving_{k}"] for k in knobs}
+    finally:
+        pt.flags.set_flags(snap)
+
+
 # -- compile discipline ------------------------------------------------------
 
 def test_decode_compiles_once_per_bucket():

@@ -34,7 +34,6 @@ ACCEPTS_UNDER = STEP_CHILDREN + ("serving.settle",)
 TREE = {
     "serving.step": (None,),
     "serving.housekeeping": ("serving.step",),
-    "serving.control.epoch": ("serving.housekeeping",),
     "serving.admit": ("serving.step",),
     "serving.prefill": ("serving.admit",),
     "serving.decode": ("serving.step",),
@@ -79,7 +78,6 @@ def _spans(records):
 
 def test_a_step_is_one_tree_with_exactly_the_declared_names(records):
     eng = _engine()
-    eng._ctrl.epoch_s = 1e-4            # an epoch falls due inside the run
     _serve(eng, shared=8)               # cold: compiles, prefix registration
     _serve(eng, seed=1, shared=8)       # suffix prefills, copy-on-write
     # the engine's own start-up run is the only work outside a step
@@ -97,6 +95,7 @@ def test_a_step_is_one_tree_with_exactly_the_declared_names(records):
         roots = [r for r in rs if r["name"] == "serving.step"]
         assert len(roots) == 1 and roots[0]["attrs"] == {"step": step}
         assert rs[-1] is roots[0]       # children close before the root
+        assert [r["name"] for r in rs].count("serving.housekeeping") == 2
         inside = sum(r["dur_s"] for r in rs
                      if r.get("parent") == "serving.step")
         assert inside <= roots[0]["dur_s"] + 1e-6
@@ -209,8 +208,7 @@ def test_the_spans_are_on_the_profilers_clock_and_nest(tmp_path, monkeypatch):
     by_name = {}
     for name, s, e in host:
         by_name.setdefault(name, []).append((s, e))
-    assert set(TREE) - {"serving.control.epoch", "pipeline.compile"} \
-        <= set(by_name)
+    assert set(TREE) - {"pipeline.compile"} <= set(by_name)
 
     def inside(child, parent):
         return all(any(ps <= s and e <= pe for ps, pe in by_name[parent])

@@ -82,6 +82,21 @@ def test_candidate_put_never_clobbers_swept(tmp_path):
     assert db.lookup("k")["decision"] == {"lowering": "igemm"}
 
 
+def test_db_evidence_schema_and_candidate_measured(tmp_path):
+    measured = {"direct": {"median_s": 1.0, "min_s": 0.9,
+                           "windows_s": [1.0, 0.9], "band": 0.11},
+                "igemm": {"median_s": 0.5, "band": 0.02},
+                "broken": "not a dict", "empty": {"median_s": None}}
+    ev = tuning.evidence(measured)
+    assert ev == {"direct": {"median_s": 1.0, "band": 0.11},
+                  "igemm": {"median_s": 0.5, "band": 0.02}}
+    p = str(tmp_path / "db.json")
+    db = tuning.TuningDB(p)
+    db.put("k", {"lowering": "direct"}, source="candidate", measured=ev)
+    db.save(p)
+    assert tuning.TuningDB(p).lookup("k")["measured"] == ev
+
+
 @pytest.mark.parametrize("payload", [
     "{corrupt json",                       # unparseable
     json.dumps({"schema": 999, "entries": {}}),   # wrong schema

@@ -251,20 +251,6 @@ def campaign(n_devices=8, iters=4, passes=2, sweep=None, record=None,
         "tokens_per_step": tokens,
     }
 
-    from paddle_tpu import tuning as _tuning
-    from paddle_tpu.tuning.learned import store as _learned_store
-
-    def _rec(arm_name, stats, n_used):
-        # raw windows -> the measurement store (the learned cost model's
-        # dataset); gated by FLAGS_tuning_record like every tool
-        if _learned_store.recording_enabled(tool=True):
-            _learned_store.record(
-                "ab.multichip",
-                f"workload=bert_mc b={batch} s={seq_len} devs={n_used}",
-                "-", _tuning.device_kind(), arm_name,
-                windows_s=stats["windows_s"], median_s=stats["median_s"],
-                min_s=stats["min_s"], band=stats["band"], source="ab")
-
     # -- single-device reference arm -----------------------------------------
     s_stats, s_params, s_losses = _run_arm(
         lambda: _build(_cfg(), seq_len), lambda m: m, feed, iters, passes)
@@ -272,7 +258,6 @@ def campaign(n_devices=8, iters=4, passes=2, sweep=None, record=None,
     out["single"] = {"tokens_per_sec": single_tok_s,
                      "band": s_stats["band"],
                      "windows_s": s_stats["windows_s"]}
-    _rec("single", s_stats, 1)
 
     scaling: dict = {}
     overlap_ab: dict = {}
@@ -288,7 +273,6 @@ def campaign(n_devices=8, iters=4, passes=2, sweep=None, record=None,
             row.update(extra)
         scaling[name] = row
         parity[name] = round(_param_drift(s_params, params), 6)
-        _rec(name, stats, n_used)
 
     # -- dp: fleet collective with the three overlap arms, interleaved -------
     mesh_dp = make_mesh({"dp": n_devices})
@@ -320,8 +304,6 @@ def campaign(n_devices=8, iters=4, passes=2, sweep=None, record=None,
         "buckets": len(on_t.last_buckets)})
     parity["dp_overlap_off"] = round(_param_drift(s_params, off_params), 6)
     parity["dp_zero1"] = round(_param_drift(s_params, z_params), 6)
-    _rec("dp_overlap_off", off_stats, n_devices)
-    _rec("dp_zero1", z_stats, n_devices)
     overlap_ab["dp_bucketed"] = _ab_row(tokens, off_stats, on_stats)
     overlap_ab["dp_zero1"] = dict(_ab_row(tokens, on_stats, z_stats),
                                   zero1_params=len(z_t.zero1_params))

@@ -1,12 +1,12 @@
 """Pre-snapshot gate: the round may not end on a red suite.
 
 Every check has a live producer in the tree: the tier-1 suite as the driver
-runs it, the single-chip compile check of `__graft_entry__.entry()`, the
-Pallas kernel registry, and the committed learned cost model. Exits non-zero
-on any failure. Nothing here judges a speed: speeds are `BENCHMARK.json` +
-`benchmark/`, recorded in `PERF_LEDGER.jsonl`.
+runs it, the single-chip compile check of `__graft_entry__.entry()` and the
+Pallas kernel registry. Exits non-zero on any failure. Nothing here judges
+a speed: speeds are `BENCHMARK.json` + `benchmark/`, recorded in
+`PERF_LEDGER.jsonl`.
 
-    python tools/gate.py             # suite + entry + kernels + costmodel
+    python tools/gate.py             # suite + entry + kernels
     python tools/gate.py --fast      # suite only
     python tools/gate.py --chaos     # `-m chaos`: the fault-injection drills
                                      # of tools/chaos.py and the SIGKILL-
@@ -14,8 +14,6 @@ on any failure. Nothing here judges a speed: speeds are `BENCHMARK.json` +
     python tools/gate.py --kernels   # kernel-registry lint only (reference,
                                      # equivalence test, tuner key and an
                                      # on-chip case per kernel)
-    python tools/gate.py --costmodel # the committed model must beat the
-                                     # analytic prior on its holdout keys
 """
 from __future__ import annotations
 
@@ -34,16 +32,6 @@ TIER1_ARGS = ["-m", "pytest", "tests/", "-q", "-m", "not slow",
               "-p", "no:randomly"]
 TIER1_ENV = {"JAX_PLATFORMS": "cpu", "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
 TIER1_LIMIT_S = 1470
-
-# learned cost model (ISSUE 15): the committed artifact must keep ranking
-# arms on its recorded holdout keys well enough to be worth a policy tier —
-# below this floor (or below the analytic prior it is supposed to beat),
-# the model is stale for the committed dataset; retrain with
-# tools/costmodel.py train. The floor sits under the committed model's
-# measured 1.0 so box-to-box eval noise does not flap the gate.
-COSTMODEL_RANK_ACC_FLOOR = 0.75
-COSTMODEL_DATA = "COSTMODEL_DATA_cpu.jsonl"
-COSTMODEL_MODEL = "COSTMODEL_cpu.json"
 
 
 def run_suite() -> int:
@@ -136,81 +124,15 @@ def run_entry() -> int:
     return r.returncode
 
 
-def check_costmodel(data_path: str | None = None,
-                    model_path: str | None = None) -> int:
-    """Learned cost-model gate (ISSUE 15): the committed model artifact must
-    keep beating the analytic prior on its recorded holdout keys.
-
-    Re-scores COSTMODEL_cpu.json against COSTMODEL_DATA_cpu.jsonl with the
-    same scorer tools/costmodel.py eval uses. Fails when any group's holdout
-    arm-ranking accuracy drops below COSTMODEL_RANK_ACC_FLOOR or below the
-    analytic prior's on the same keys (a learned tier that ranks worse than
-    the formula it shadows is a regression, not a tier). Repos without the
-    committed artifacts skip with a WARN."""
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    from paddle_tpu.tuning import learned
-
-    data_path = data_path or os.path.join(REPO, COSTMODEL_DATA)
-    model_path = model_path or os.path.join(REPO, COSTMODEL_MODEL)
-    if not os.path.exists(data_path) or not os.path.exists(model_path):
-        print(f"[gate] WARN: costmodel artifacts missing "
-              f"({COSTMODEL_DATA} / {COSTMODEL_MODEL}) — skipping",
-              flush=True)
-        return 0
-    try:
-        model = learned.load_model(model_path)
-    except ValueError as e:
-        print(f"[gate] FAIL: committed cost model {model_path} is "
-              f"unreadable ({e}) — retrain with tools/costmodel.py train",
-              flush=True)
-        return 1
-    if model is None:
-        print(f"[gate] WARN: cost model {model_path} vanished — skipping",
-              flush=True)
-        return 0
-    recs = list(learned.iter_records(data_path))
-    ev = learned.eval_model(model, recs)
-    rc = 0
-    if not ev["groups"]:
-        print(f"[gate] FAIL: committed cost model has no evaluable group "
-              f"against {os.path.basename(data_path)} — dataset/model "
-              f"drifted apart; re-run tools/costmodel.py train", flush=True)
-        return 1
-    for g, r in sorted(ev["groups"].items()):
-        acc, ana = r.get("rank_acc"), r.get("analytic_rank_acc")
-        print(f"[gate] costmodel {g}: holdout rank-acc {acc} vs analytic "
-              f"{ana} over {r.get('n')} keys", flush=True)
-        if acc is None:
-            continue
-        if acc < COSTMODEL_RANK_ACC_FLOOR:
-            print(f"[gate] FAIL: learned model ranks arms correctly on only "
-                  f"{acc:.0%} of {g} holdout keys "
-                  f"(floor {COSTMODEL_RANK_ACC_FLOOR:.0%}) — the committed "
-                  f"model is stale for the committed dataset; retrain with "
-                  f"tools/costmodel.py train", flush=True)
-            rc = 1
-        elif ana is not None and acc < ana:
-            print(f"[gate] FAIL: learned model ({acc:.0%}) ranks {g} "
-                  f"holdout arms WORSE than the analytic prior ({ana:.0%}) "
-                  f"it is supposed to beat — the tier is a regression; "
-                  f"retrain or widen the dataset", flush=True)
-            rc = 1
-    return rc
-
-
 def main() -> int:
     if "--chaos" in sys.argv:
         return run_chaos()
     if "--kernels" in sys.argv:
         return check_kernel_registry()
-    if "--costmodel" in sys.argv:
-        return check_costmodel()
     rc = run_suite()
     if "--fast" not in sys.argv:
         rc = rc or run_entry()
         rc = rc or check_kernel_registry()
-        rc = rc or check_costmodel()
     if rc == 0:
         print("[gate] OK — green suite, safe to snapshot")
     return rc

@@ -61,7 +61,6 @@ import numpy as np  # noqa: E402
 from paddle_tpu import tuning  # noqa: E402
 from paddle_tpu.ops.nn_ops import (_conv2d_igemm_f32,  # noqa: E402
                                    _igemm_predict_win)
-from paddle_tpu.tuning.learned import store as learned_store  # noqa: E402
 from tools import _timing  # noqa: E402
 
 # The ResNet-50 cost-table shapes (b128 NHWC):
@@ -155,15 +154,6 @@ def _measure_arms(arms: dict, iters: int, passes: int) -> dict:
     return out
 
 
-def _record_store(key: str, measured: dict, source: str = "sweep") -> None:
-    """Append every arm's raw windows to the measurement store
-    (tuning/learned/store.py) — the learned cost model's training set grows
-    as a side effect of sweeping. Gated by FLAGS_tuning_record ('auto'
-    records from the tools whenever a store path resolves)."""
-    if learned_store.recording_enabled(tool=True):
-        learned_store.record_measured(key, measured, source=source)
-
-
 def _verdict_vs_base(measured: dict, base: str, band: float):
     """Pick the winner against the conservative base arm: the fastest
     candidate that beats base's median by more than max(band, its own
@@ -228,7 +218,6 @@ def sweep_conv(db, shapes, dtype: str, iters: int, passes: int, band: float,
         db.put(key, {"lowering": lowering}, source="swept",
                measured=tuning.evidence(measured),
                note=f"{name}: verdict={verdict} analytic={analytic}")
-        _record_store(key, measured)
         print(json.dumps({"shape": name, "decision": lowering,
                           "verdict": verdict, "analytic": analytic}),
               flush=True)
@@ -292,7 +281,6 @@ def sweep_attention(db, shapes, dtype: str, iters: int, passes: int,
         db.put(key, {"backend": backend}, source="swept",
                measured=tuning.evidence(measured),
                note=f"{name}: verdict={verdict}")
-        _record_store(key, measured)
         print(json.dumps({"shape": name, "decision": backend,
                           "verdict": verdict}), flush=True)
 
@@ -379,7 +367,6 @@ def sweep_decode_attention(db, shapes, dtype: str, iters: int, passes: int,
         db.put(key, {"backend": backend}, source="swept",
                measured=tuning.evidence(measured),
                note=f"{name}: verdict={verdict}")
-        _record_store(key, measured)
         print(json.dumps({"shape": name, "decision": backend,
                           "verdict": verdict}), flush=True)
 
@@ -474,7 +461,6 @@ def _sweep_epilogue_jobs(db, jobs, dtype: str, iters: int, passes: int,
         db.put(key, {"backend": backend}, source="swept",
                measured=tuning.evidence(measured),
                note=f"{name}: verdict={verdict}")
-        _record_store(key, measured)
         print(json.dumps({"shape": name, "decision": backend,
                           "verdict": verdict}), flush=True)
 
@@ -611,7 +597,6 @@ def sweep_embedding(db, geometries, dtype: str, iters: int, passes: int,
                              "hit_rate": m.get("hit_rate")}
                          for a, m in {**measured, **pf_measured}.items()},
                note=f"{name}: verdict={verdict} base=slots{base_slots}")
-        _record_store(key, {**measured, **pf_measured})
         print(json.dumps({"shape": name, "decision": decision,
                           "verdict": verdict}), flush=True)
 
@@ -749,16 +734,7 @@ def main():
     ap.add_argument("--dtype", default="bfloat16" if on_tpu else "float32")
     ap.add_argument("--small", action="store_true",
                     help="shrink the default shape set (batch 8, CPU smoke)")
-    ap.add_argument("--measurements", default="",
-                    help="measurement-store JSONL path (default: derived "
-                         "from --db, see FLAGS_tuning_measurements)")
     args = ap.parse_args()
-
-    # the measurement store derives its path from the tuning flags — point
-    # them at this sweep's DB so raw windows land next to the verdicts
-    from paddle_tpu import flags as pt_flags
-    pt_flags.set_flags({"tuning_db": args.db,
-                        "tuning_measurements": args.measurements})
 
     conv_shapes = RN50_CONV_SHAPES
     attn_shapes = ATTENTION_SHAPES
