@@ -504,15 +504,18 @@ PIECES = frozenset({
     "kv_gather",  # rows or pages of K/V gathered out of a pool
     "latent_gather",  # latent_moe: cache rows gathered out of the pool
     "q_absorb",   # latent_moe: per-head products into and out of the latent
-    "shared",     # latent_moe: the shared expert
+    "shared",     # latent_moe, mixer_moe: the shared expert
+    "latent_proj",  # mixer_moe: the products into and out of the experts'
+                    # latent
     "attend",     # the attention itself (a Pallas kernel sits inside)
     "router",     # expert choice and combine weights
     "experts",    # the routed experts
     "dense_ffn",  # a shared expert, a dense layer
     "state",      # cca_moe: the state rows read and written
-    "conv",       # parallel_ssm: the depthwise convolution and its tail
-    "ssm_update", # parallel_ssm: a decode token's state update, in place
-    "ssm_scan",   # parallel_ssm: a window's chunked scan from its slot
+    "conv",       # parallel_ssm, mixer_moe: the depthwise convolution and
+                  # its tail
+    "ssm_update", # ... a decode token's state update, in place
+    "ssm_scan",   # ... a window's chunked scan from its slot
     "mlp",        # parallel_ssm: the layer's SwiGLU
     "head",       # final norm and the vocabulary product
 })

@@ -28,6 +28,15 @@ nothing else:
     down projection, so a token's row is zero for every expert it did not
     choose.
 
+A second arm, UNGATED (`moe_relu2_experts`): `out[t] = sum_e cw[t, e] *
+W_2[e] relu(W_1[e] u_t)^2`, two matrices an expert and no gate (experts
+that work in a latent `u` narrower than the hidden size: `mixer_moe_ops`).
+Same grid, same prefetched layer index, same combine weight on the hidden
+tile before the second product; a grid step DMAs ONE `[Z, tf]` slab and one
+`[tf, Z]` slab. It runs under a kernel name of its own
+(`moe_relu2_experts_<tag>`), so that a trace tells the two streams apart and
+the gated arm's programs are what they were.
+
 Forward only: serving never differentiates.
 """
 from __future__ import annotations
@@ -71,7 +80,8 @@ def _f_tile(ffn: int, hidden: int = 0, itemsize: int = 2) -> int:
 
 
 def experts_supported(z_shape, w_gate_shape, dtype) -> bool:
-    """z [T, H] against W_gate `[L, E, H, F]`: whole 128-lane rows on both
+    """z [T, H] against W_gate `[L, E, H, F]` (the ungated arm's W_1, whose
+    two slabs fit wherever three do): whole 128-lane rows on both
     widths, a 2- or 4-byte dtype, at most 256 experts (the combine weights
     ride one lane register a 128 experts, however many of them a token's
     row fills) and an F tile (`_f_tile`) whose three slabs fit: 8 MB a
@@ -195,3 +205,105 @@ def moe_topk_experts(z, cw, w_gate, w_up, w_down, layer=0, tag="decode"):
     else."""
     return _call(z, cw, w_gate, w_up, w_down, jnp.asarray(layer, jnp.int32),
                  str(tag), bool(INTERPRET), topk=True)
+
+
+# -- the ungated arm: W_2 relu(W_1 u)^2 --------------------------------------
+
+
+def _relu2_kernel(layer_ref, z_ref, cw_ref, w1_ref, w2_ref, o_ref):
+    del layer_ref                      # read by the index maps
+    e = pl.program_id(1)
+    f = pl.program_id(2)
+
+    @pl.when((e == 0) & (f == 0))
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    z = z_ref[...]                                             # [tt, Z]
+    g = jnp.maximum(jnp.dot(z, w1_ref[0, 0],
+                            preferred_element_type=jnp.float32), 0.0)
+    cw = cw_ref[...]                            # [tt, 128 or 256]
+    lane = jax.lax.broadcasted_iota(jnp.int32, cw.shape, 1)
+    col = jnp.sum(jnp.where(lane == e, cw, 0.0), axis=1, keepdims=True)
+    hidden = (g * g * col).astype(z.dtype)                     # [tt, tf]
+    o_ref[...] += jnp.dot(hidden, w2_ref[0, 0],
+                          preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("tag", "interpret"))
+def _relu2_call(z, cw, w1, w2, layer, tag, interpret):
+    T, Z = z.shape
+    _, E, _, F = w1.shape
+    dtype = w1.dtype
+    sub = 32 // dtype.itemsize                  # sublanes of one tile
+    tt = _TOKEN_TILE if T > _TOKEN_TILE else -(-T // sub) * sub
+    t_pad = -(-T // tt) * tt
+    tf = _f_tile(F, Z, dtype.itemsize)
+    lanes = -(-E // _LANES) * _LANES
+    zp = jnp.zeros((t_pad, Z), dtype).at[:T].set(z.astype(dtype))
+    cwp = jnp.zeros((t_pad, lanes), jnp.float32).at[:T, :E].set(
+        cw.astype(jnp.float32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(t_pad // tt, E, F // tf),
+        in_specs=[
+            pl.BlockSpec((tt, Z), lambda t, e, f, l: (t, 0)),
+            pl.BlockSpec((tt, lanes), lambda t, e, f, l: (t, 0)),
+            pl.BlockSpec((1, 1, Z, tf), lambda t, e, f, l: (l[0], e, 0, f)),
+            pl.BlockSpec((1, 1, tf, Z), lambda t, e, f, l: (l[0], e, f, 0)),
+        ],
+        out_specs=pl.BlockSpec((tt, Z), lambda t, e, f, l: (t, 0)),
+    )
+    out = pl.pallas_call(
+        _relu2_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t_pad, Z), jnp.float32),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * t_pad * E * Z * F,
+            bytes_accessed=((t_pad // tt) * 2 * E * Z * F * dtype.itemsize
+                            + t_pad * Z * (dtype.itemsize + 4)),
+            transcendentals=0),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_relu2_experts_" + tag,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), zp, cwp, w1, w2)
+    return out[:T]
+
+
+def _relu2_reference(z, cw, w1, w2, layer=0, tag="decode"):
+    """The ungated sum in plain jnp (the numeric oracle and the path off
+    the chip): every expert over every token, weighted."""
+    del tag
+    a = jax.lax.dynamic_index_in_dim(w1, layer, 0, keepdims=False)
+    b = jax.lax.dynamic_index_in_dim(w2, layer, 0, keepdims=False)
+    g = jnp.maximum(jnp.einsum("tz,ezf->etf", z.astype(a.dtype), a,
+                               preferred_element_type=jnp.float32), 0.0)
+    hidden = g * g * cw.astype(jnp.float32).T[:, :, None]
+    return jnp.einsum("etf,efz->tz", hidden.astype(a.dtype), b,
+                      preferred_element_type=jnp.float32)
+
+
+def _relu2_register():
+    from . import workbench
+
+    return workbench.register_kernel(
+        "moe_relu2_experts",
+        reference=_relu2_reference,
+        supported=lambda z, w: experts_supported(z, w, jnp.bfloat16),
+        decision_op="moe_experts",
+        equivalence_test="test_moe_relu2_experts_pallas_matches_reference",
+        note="top-k ungated experts W_2 relu(W_1 u)^2 over stacked [L, E, "
+             "...] weights in a latent; layer index by scalar prefetch, "
+             "every held expert streamed once")
+
+
+@_relu2_register()
+def moe_relu2_experts(z, cw, w1, w2, layer=0, tag="decode"):
+    """z [T, Z], cw [T, E] (combine weight of token t for held expert e),
+    weights `[L, E, Z, F]` and `[L, E, F, Z]`, `layer` a scalar int. Returns
+    float32 [T, Z]. Callers gate on `experts_supported(z.shape, w1.shape,
+    dtype)`."""
+    return _relu2_call(z, cw, w1, w2, jnp.asarray(layer, jnp.int32),
+                       str(tag), bool(INTERPRET))

@@ -30,6 +30,24 @@ back, in every window). `dtx` and
 `B` and `C` (a value a sublane) are made once a grid step by transposing a
 `[P, N]` broadcast; the contraction with `C` runs down the sublanes.
 
+Heads NARROWER than the lanes (64 wide over a state of 128: P < 128) lie
+`pack = 128 / P` heads of one group SIDE BY SIDE on the lanes: a slot is
+`[heads / pack * N, pack * P]`, slab j the heads `pack * j .. pack * j +
+pack - 1`, head `pack * j + i` on lanes `i * P .. (i + 1) * P - 1`. Heads
+that share a slab share a group, so `B` and `C` stay a value a sublane for
+the whole slab, `a` and `dtx` stay a value a lane (a head's `a` over its P
+lanes), and the kernel's arithmetic is the one above on `heads / pack`
+slabs of 128 lanes: the same body, whole (8, 128) tiles, no transpose and
+no lane reduction. Why not the state on the lanes (`[heads * P, N]`, the
+contraction with `C` a lane reduction): `dtx` would be a value a SUBLANE,
+which a `[B, heads, P]` operand reaches only through a transpose in VMEM a
+grid step or a lane-sparse `[B, heads * P, 1]` operand in HBM (128 x its
+bytes under the (8, 128) tiling), and `y` would leave the kernel as a
+column; that form was not built and not measured (PR 43). The wrapper
+derives `pack` from the operands' shapes (`pool.shape[2] / dtx.shape[2]`);
+what a window's scan reads and writes goes through `pack_state` /
+`unpack_state`.
+
 Forward only: serving never differentiates.
 """
 from __future__ import annotations
@@ -51,8 +69,11 @@ _HEAD_BLOCK = 8
 
 
 def update_supported(pool_shape, state: int, heads_per_group: int) -> bool:
-    """A pool `[rows, heads * N, P]` of float32 whose `[N, P]` state is
-    whole (8, 128) tiles and whose head blocks lie inside one group."""
+    """A pool `[rows, slabs * N, lanes]` of float32 whose `[N, lanes]` slab
+    (a head, or `pack` narrow heads side by side: the module docstring) is
+    whole (8, 128) tiles and whose blocks of `_HEAD_BLOCK` slabs lie inside
+    one group; `heads_per_group` counts a group's SLABS. A pool of heads
+    narrower than the lanes that was not packed is refused."""
     if len(pool_shape) != 3 or state <= 0 or pool_shape[1] % state:
         return False
     H, P = pool_shape[1] // state, pool_shape[2]
@@ -78,10 +99,12 @@ def _kernel(idx_ref, s_ref, a_ref, dtx_ref, b_ref, c_ref, so_ref, y_ref):
 def _call(pool, idx, a, dtx, bmat, cmat, interpret):
     rows, HN, P = pool.shape
     B, G, N = bmat.shape
-    H = HN // N
+    H = HN // N                 # slabs: heads, or packs of narrow heads
+    pack = a.shape[1] // H
     hb = _HEAD_BLOCK
     per_group = H // G
-    lanes = jnp.broadcast_to(a.astype(jnp.float32)[:, :, None], (B, H, P))
+    lanes = jnp.broadcast_to(a.astype(jnp.float32)[:, :, None],
+                             (B, H * pack, P // pack)).reshape(B, H, P)
     head_rows = pl.BlockSpec((1, hb, P), lambda b, j, idx: (b, j, 0))
     state = pl.BlockSpec((1, hb * N, P), lambda b, j, idx: (idx[b], j, 0))
     group = pl.BlockSpec((1, 1, 1, N),
@@ -107,10 +130,37 @@ def _call(pool, idx, a, dtx, bmat, cmat, interpret):
         interpret=interpret,
         name="ssm_decode_update",
     )(jnp.clip(idx.astype(jnp.int32), 0, rows - 1), pool, lanes,
-      dtx.astype(jnp.float32),
+      dtx.astype(jnp.float32).reshape(B, H, P),
       bmat.astype(jnp.float32).reshape(B, G, 1, N),
       cmat.astype(jnp.float32).reshape(B, G, 1, N))
-    return new_pool, y
+    return new_pool, y.reshape(dtx.shape)
+
+
+def pack_state(s, pack: int):
+    """States `[B, heads, N, P]` as slots of the pool: `[B, heads / pack *
+    N, pack * P]`, `pack` heads side by side on the lanes. Written as a
+    concatenation of the heads' lanes, not as a transpose: a transpose of
+    the rows a window writes made XLA ask for the WHOLE pool in the
+    transposed rows' layout and copy it there and back in every window
+    (3.4 GB each way at 800 slots of 4 MB; described-v5e HLO, PR 43)."""
+    B, H, N, P = s.shape
+    if pack == 1:
+        return s.reshape(B, H * N, P)
+    return jnp.concatenate([s[:, i::pack] for i in range(pack)],
+                           axis=-1).reshape(B, H // pack * N, pack * P)
+
+
+def unpack_state(slots, heads: int, state: int):
+    """`pack_state`'s inverse: slots `[B, heads / pack * N, pack * P]` ->
+    `[B, heads, N, P]`."""
+    B, rows, lanes = slots.shape
+    pack = heads * state // rows
+    if pack == 1:
+        return slots.reshape(B, heads, state, lanes)
+    P = lanes // pack
+    slabs = slots.reshape(B, heads // pack, state, lanes)
+    return jnp.stack([slabs[..., i * P:(i + 1) * P] for i in range(pack)],
+                     axis=2).reshape(B, heads, state, P)
 
 
 def _reference(pool, idx, a, dtx, bmat, cmat):
@@ -118,16 +168,16 @@ def _reference(pool, idx, a, dtx, bmat, cmat):
     the chip): the rows' states gathered, updated and scattered back. Rows
     that name the same slot (a step's padding rows, all on the scratch
     slot) leave one of their updates there."""
-    (B, G, N), P = bmat.shape, pool.shape[2]
-    H = pool.shape[1] // N
+    (B, G, N), H = bmat.shape, a.shape[1]
+    pack = H * N // pool.shape[1]
     idx = jnp.clip(idx.astype(jnp.int32), 0, pool.shape[0] - 1)
     bh = jnp.repeat(bmat.astype(jnp.float32), H // G, axis=1)   # [B, H, N]
     ch = jnp.repeat(cmat.astype(jnp.float32), H // G, axis=1)
     s_new = a.astype(jnp.float32)[:, :, None, None] \
-        * pool[idx].reshape(B, H, N, P) \
+        * unpack_state(pool[idx], H, N) \
         + bh[:, :, :, None] * dtx.astype(jnp.float32)[:, :, None, :]
     y = jnp.sum(s_new * ch[:, :, :, None], axis=2)
-    return pool.at[idx].set(s_new.reshape(B, H * N, P)), y
+    return pool.at[idx].set(pack_state(s_new, pack)), y
 
 
 def _workbench_register():
@@ -146,8 +196,9 @@ def _workbench_register():
 
 @_workbench_register()
 def ssm_decode_update(pool, idx, a, dtx, bmat, cmat):
-    """pool `[rows, H * N, P]` float32, idx [B] (the row of each decode
-    row's state), a [B, H] (decay), dtx [B, H, P], bmat and cmat [B, G, N].
-    Returns (the pool with rows `idx` updated, y [B, H, P] float32).
-    Callers gate on `update_supported`."""
+    """pool `[rows, H * N, P]` float32 (or, heads narrower than the lanes,
+    `[rows, H / pack * N, pack * P]`: the module docstring), idx [B] (the
+    row of each decode row's state), a [B, H] (decay), dtx [B, H, P], bmat
+    and cmat [B, G, N]. Returns (the pool with rows `idx` updated, y [B, H,
+    P] float32). Callers gate on `update_supported`."""
     return _call(pool, idx, a, dtx, bmat, cmat, bool(INTERPRET))

@@ -226,15 +226,17 @@ def _update_backend(rows: int, pool_shape, state: int,
 
 
 def ssm_token_update_fn(s_pool, idx, x, dt_raw, bmat, cmat, dt_bias, a_log):
-    """One token a row, in place: s_pool [rows, H * N, P], idx [B] (each
-    row's slot in this layer), x [B, H, P], dt_raw [B, H], bmat/cmat [B, G,
-    N] -> (the pool with rows `idx` updated, y [B, H, P] without the skip
-    term)."""
+    """One token a row, in place: s_pool [rows, H * N, P] (heads narrower
+    than the lanes: `pack` of them side by side, [rows, H / pack * N, pack *
+    P]; `pallas_kernels.ssm_update`), idx [B] (each row's slot in this
+    layer), x [B, H, P], dt_raw [B, H], bmat/cmat [B, G, N] -> (the pool
+    with rows `idx` updated, y [B, H, P] without the skip term)."""
     from .pallas_kernels import ssm_update
 
     la, dtx = _decay_and_input(x, dt_raw, dt_bias, a_log)
     G, N = bmat.shape[1:]
-    if _update_backend(x.shape[0], s_pool.shape, N, x.shape[1] // G) \
+    pack = s_pool.shape[2] // x.shape[2]
+    if _update_backend(x.shape[0], s_pool.shape, N, x.shape[1] // G // pack) \
             == "pallas":
         return ssm_update.ssm_decode_update(s_pool, idx, jnp.exp(la), dtx,
                                             bmat, cmat)

@@ -122,7 +122,9 @@ still held, and a prompt resumes only a prefix whose window tail is held
 (`PrefixCache.match_resumable`); under pressure in the window pool alone
 the cache gives up window pages before a row is made to wait.
 
-A recurrent state (the "parallel_ssm" block, `cfg.recurrent`): beside its
+A recurrent state (the "parallel_ssm" and "mixer_moe" blocks,
+`cfg.recurrent`; the pools hold `cfg.state_layers` layers, every layer of
+the first and the mixers of the second): beside its
 K/V pages a row owns ONE slot of the pools of state (`state_pool`, the same
 `PagedKVPool` a third time, ids of its own, `req.sslot`), which every step
 of the row rewrites in place; admission, preemption, the leak count and the
@@ -2295,8 +2297,10 @@ class ServingEngine:
                 self._count("prefill_tokens_computed", m)
                 self._count("prefill.chunks")
                 if self.state_pool is not None:
-                    self._count("ssm.scan_tokens", m * self.cfg.num_layers)
-                    self._count("ssm.scan_layer_steps", self.cfg.num_layers)
+                    self._count("ssm.scan_tokens",
+                                m * self.cfg.state_layers)
+                    self._count("ssm.scan_layer_steps",
+                                self.cfg.state_layers)
                     if (c0 + m) % chunk == 0 and c0 + m <= req.prompt_len \
                             and self.prefix_cache is not None:
                         # the state the chunk leaves is the state after a
@@ -2628,8 +2632,8 @@ class ServingEngine:
             self._count("attn.window_layer_steps", slide)
         if self.state_pool is not None:
             self._count("ssm.decode_row_layers",
-                        len(rows) * self.cfg.num_layers)
-            self._count("ssm.decode_layer_steps", self.cfg.num_layers)
+                        len(rows) * self.cfg.state_layers)
+            self._count("ssm.decode_layer_steps", self.cfg.state_layers)
         if self.cfg.selects_within(pb * ps):
             L, k = self.cfg.num_layers, self.cfg.index_topk
             self._count("sparse.context_tokens",

@@ -190,16 +190,20 @@ def stacked_pool_shapes(num_layers: int, num_pages: int, page_size: int,
 
 
 def state_pool_shapes(num_layers: int, num_slots: int, heads: int,
-                      state: int, head_dim: int, tail_width: int):
+                      state: int, head_dim: int, tail_width: int,
+                      pack: int = 1):
     """[(name, shape, dtype)] of the pools of recurrent state: `S`, `[rows,
     heads * state, head_dim]` (a slot's heads one slab, the state dimension
-    on the sublanes: `pallas_kernels.ssm_update` says why), and the
+    on the sublanes: `pallas_kernels.ssm_update` says why; `pack` heads
+    narrower than the lanes side by side in whole 128-lane rows: `[rows,
+    heads / pack * state, pack * head_dim]`), and the
     convolution's tail,
     `[rows, tail_width]` (the last `conv - 1` pre-convolution rows side by
-    side), both float32, `rows = num_layers * num_slots`."""
+    side), both float32, `rows = num_layers * num_slots` (the layers that
+    hold a state)."""
     rows = int(num_layers) * int(num_slots)
-    return [(STATE_POOLS[0], (rows, int(heads) * int(state), int(head_dim)),
-             "float32"),
+    return [(STATE_POOLS[0], (rows, int(heads) // int(pack) * int(state),
+                              int(pack) * int(head_dim)), "float32"),
             (STATE_POOLS[1], (rows, int(tail_width)), "float32")]
 
 

@@ -1,4 +1,4 @@
-"""Serving program builders. SIX block families exist, and
+"""Serving program builders. SEVEN block families exist, and
 `DecoderConfig.block` selects one:
 
   * `"post_ln"` (the default; every other field at its default is the "bert
@@ -82,6 +82,23 @@
     by layer kind, the routed layers scanned. Prompts run in
     `prefill_chunk`-token windows and every program hands back its
     `selection`, as "sparse_moe"'s do.
+  * `"mixer_moe"` (`ops/mixer_moe_ops.py`; Nemotron-H's layers with latent
+    experts): every layer is ONE sub-layer behind ONE pre-norm, its kind the
+    layer's character in `layer_pattern`: `M` a Mamba-2 mixer
+    ("parallel_ssm"'s, every multiplier 1; its heads may be narrower than
+    the 128 lanes, `ssm_head_dim` 64 over `ssm_state` 128: two of them then
+    share a slot's lane rows), `*` a grouped-query attention with no rotary,
+    `E` the top `experts_per_token` of `num_experts` sigmoid-scored experts
+    (one group, a selection bias, `routed_scaling`) that are two matrices
+    and a squared ReLU each in a latent of `latent_size` (`ffn_size` wide),
+    between a projection down from and up to the hidden size, beside a
+    shared expert (`shared_expert_size`) on the hidden itself;
+    `experts_held` as "latent_moe"'s. Weights are stacked by layer KIND and
+    the layers run one after another. The three pools answer by the COUNT
+    OF THEIR KIND: K/V pages over the `*` layers, slots of recurrent state
+    (`kv_cache.STATE_POOLS`, `build_state_copy_program`) over the `M`
+    layers, a request's routes over the `E` layers. Prompts run in
+    `prefill_chunk`-token windows as "sparse_moe"'s do.
 
 Every family is expressed several times over ONE weight namespace:
 
@@ -106,6 +123,7 @@ reads what prefill's startup initialized (or what a checkpoint restored).
 """
 from __future__ import annotations
 
+import collections
 from dataclasses import dataclass
 
 import jax.numpy as jnp
@@ -117,7 +135,7 @@ from ..param_attr import ParamAttr
 from ..initializer import (BlockedNormal, Constant, Normal, StackedNormal,
                            Uniform)
 from ..ops import (cca_moe_ops, hybrid_moe_ops, latent_moe_ops,
-                   parallel_ssm_ops, sparse_moe_ops)
+                   mixer_moe_ops, parallel_ssm_ops, sparse_moe_ops)
 from .kv_cache import (INDEX_POOL, JOINED_POOL, LATENT_POOL, STACKED_POOLS,
                        STATE_POOLS, WINDOW_POOLS, declare_pool_vars,
                        declare_stacked_pools, declare_state_pools,
@@ -125,7 +143,8 @@ from .kv_cache import (INDEX_POOL, JOINED_POOL, LATENT_POOL, STACKED_POOLS,
 
 __all__ = ["DecoderConfig", "decoder_tiny", "cca_moe_tiny",
            "sparse_moe_tiny", "hybrid_moe_tiny", "parallel_ssm_tiny",
-           "latent_moe_tiny", "layer_plan", "build_prefill_program",
+           "latent_moe_tiny", "mixer_moe_tiny", "layer_plan",
+           "build_prefill_program",
            "build_decode_program", "build_window_program",
            "build_state_copy_program",
            "build_full_forward_program", "apply_tp_annotations"]
@@ -146,8 +165,9 @@ WPAGES_FEED = "sv_wpages"
 WBASE_FEED = "sv_wbase"
 COW_WSRC_FEED = "sv_cow_wsrc"   # copy-on-write in the sliding layers' pool
 COW_WDST_FEED = "sv_cow_wdst"
-# "parallel_ssm": each row's slot in the pools of recurrent state (padding
-# rows name a scratch slot nobody reads), and the state copy's two slots
+# "parallel_ssm", "mixer_moe": each row's slot in the pools of recurrent
+# state (padding rows name a scratch slot nobody reads), and the state copy's
+# two slots
 SSLOT_FEED = "sv_sslot"
 SCOPY_SRC_FEED = "sv_scopy_src"
 SCOPY_DST_FEED = "sv_scopy_dst"
@@ -237,6 +257,13 @@ class DecoderConfig:
     expert_groups: int = 1
     groups_per_token: int = 1
     experts_held: int = 0          # 0: all of num_experts
+    # "mixer_moe" only (it also reads the `ssm_*` sizes, `num_kv_heads`,
+    # `attn_head_dim`, `num_experts`, `experts_per_token`, `experts_held`,
+    # `routed_scaling`, `shared_expert_size`; `ffn_size` is one expert's
+    # width IN the latent): a character a layer, `M` mixer | `*` attention |
+    # `E` experts, and the width of the experts' latent
+    layer_pattern: str = ""
+    latent_size: int = 0
     # a deployment's choice, any family: the fewest rows a decode step is
     # compiled for (a power of two). Steps of fewer live rows pay for that
     # many; every row bucket below it is a program less to compile
@@ -262,6 +289,31 @@ class DecoderConfig:
                     "ssm_conv >= 2, prefill_chunk, num_kv_heads dividing "
                     "num_heads, two mlp_multipliers and five "
                     "ssm_multipliers")
+        if self.block == "mixer_moe":
+            kinds = collections.Counter(self.layer_pattern)
+            if len(self.layer_pattern) != self.num_layers \
+                    or set(kinds) - set("M*E") or not kinds["M"] \
+                    or not kinds["E"]:
+                raise ValueError(
+                    "block 'mixer_moe' needs layer_pattern of num_layers "
+                    "characters of 'M' (mixer), '*' (attention) and 'E' "
+                    "(experts), with at least one 'M' and one 'E'")
+            if min(self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+                   self.ssm_state, self.ssm_chunk, self.prefill_chunk,
+                   self.latent_size, self.shared_expert_size) < 1 \
+                    or self.ssm_conv < 2 or self.ssm_heads % self.ssm_groups \
+                    or self.num_heads % self.kv_heads:
+                raise ValueError(
+                    "block 'mixer_moe' needs ssm_heads (a multiple of "
+                    "ssm_groups), ssm_head_dim, ssm_state, ssm_chunk, "
+                    "ssm_conv >= 2, prefill_chunk, latent_size, "
+                    "shared_expert_size and num_kv_heads dividing num_heads")
+            if not 1 <= self.experts_per_token <= self.num_experts \
+                    or not 1 <= self.held_experts <= self.num_experts:
+                raise ValueError(
+                    "block 'mixer_moe' needs num_experts >= "
+                    "experts_per_token >= 1 and 1 <= experts_held <= "
+                    "num_experts")
         if self.block == "hybrid_moe":
             layer_plan(self)       # raises on lists that name no plan
             if min(self.sliding_window, self.prefill_chunk,
@@ -360,14 +412,23 @@ class DecoderConfig:
         stacked pools (`kv_cache.STACKED_POOLS`). Speculation, tensor
         parallelism and the fleet handoff are not written for that form."""
         return self.block in ("cca_moe", "sparse_moe", "hybrid_moe",
-                              "parallel_ssm", "latent_moe")
+                              "parallel_ssm", "latent_moe", "mixer_moe")
 
     @property
     def recurrent(self) -> bool:
         """Whether a sequence carries a state that every token rewrites in
         place (a slot of `kv_cache.STATE_POOLS`, not a row a page): the
         prefix cache resumes it from snapshots only."""
-        return self.block == "parallel_ssm"
+        return self.block in ("parallel_ssm", "mixer_moe")
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that hold a recurrent state (the layers of
+        `kv_cache.STATE_POOLS`): every layer of "parallel_ssm", the mixers
+        of "mixer_moe"."""
+        if self.block == "mixer_moe":
+            return self.layer_pattern.count(mixer_moe_ops.MIXER)
+        return self.num_layers if self.recurrent else 0
 
     @property
     def windowed(self) -> bool:
@@ -383,6 +444,8 @@ class DecoderConfig:
             return sum(kind == "sparse" for kind in self.mlp_layer_types)
         if self.block == "latent_moe":
             return self.num_layers - self.dense_layers
+        if self.block == "mixer_moe":
+            return self.layer_pattern.count(mixer_moe_ops.EXPERTS)
         return 0 if self.block == "parallel_ssm" else self.num_layers
 
     @property
@@ -517,6 +580,25 @@ def parallel_ssm_tiny(**over) -> DecoderConfig:
               key_multiplier=0.7, mlp_multipliers=(0.85, 1.1),
               ssm_multipliers=(0.9, 1.2, 0.8, 1.1, 0.7),
               max_position=128, block="parallel_ssm")
+    kw.update(over)
+    return DecoderConfig(**kw)
+
+
+def mixer_moe_tiny(**over) -> DecoderConfig:
+    """The "mixer_moe" block at test size: the pattern MEM*EME (3 mixers, 3
+    expert layers, 1 attention); 4 state-space heads of 8 in 2 groups of
+    state 16 (heads narrower than the state: two share a slot's rows), a
+    convolution 4 wide, scan chunks of 4; 4 query heads over 2 KV heads of
+    8; 3 of 8 experts a token, 16 wide in a latent of 16, of which this
+    engine holds the first 4, beside a shared expert of 24; prompts in
+    chunks of 8."""
+    kw = dict(vocab_size=97, hidden_size=32, num_layers=7,
+              layer_pattern="MEM*EME", num_heads=4, num_kv_heads=2,
+              attn_head_dim=8, ssm_heads=4, ssm_head_dim=8, ssm_groups=2,
+              ssm_state=16, ssm_conv=4, ssm_chunk=4, prefill_chunk=8,
+              num_experts=8, experts_held=4, experts_per_token=3,
+              latent_size=16, ffn_size=16, shared_expert_size=24,
+              routed_scaling=2.5, max_position=128, block="mixer_moe")
     kw.update(over)
     return DecoderConfig(**kw)
 
@@ -1217,10 +1299,20 @@ def _ssm_geometry(cfg: DecoderConfig) -> dict:
 def ssm_pool_geometry(cfg: DecoderConfig, num_pages: int, page_size: int,
                       num_slots: int) -> tuple:
     """(`kv_cache.stacked_pool_shapes`' arguments for K and V,
-    `kv_cache.state_pool_shapes`' for the recurrent state)."""
+    `kv_cache.state_pool_shapes`' for the recurrent state). "mixer_moe"
+    answers by the count of a kind: K/V over its attention layers, the
+    state over its mixers, narrow heads packed on the lanes."""
+    width = cfg.kv_heads * cfg.head_dim
+    if cfg.block == "mixer_moe":
+        tail = (cfg.ssm_conv - 1) * (
+            cfg.ssm_heads * cfg.ssm_head_dim
+            + 2 * cfg.ssm_groups * cfg.ssm_state)
+        return ((cfg.layer_pattern.count(mixer_moe_ops.ATTENTION), num_pages,
+                 page_size, width, 0, cfg.dtype),
+                (cfg.state_layers, num_slots, cfg.ssm_heads, cfg.ssm_state,
+                 cfg.ssm_head_dim, tail, _state_pack(cfg)))
     geom = parallel_ssm_ops.Geometry(**_ssm_geometry(cfg))
-    return ((cfg.num_layers, num_pages, page_size,
-             cfg.kv_heads * cfg.head_dim, 0, cfg.dtype),
+    return ((cfg.num_layers, num_pages, page_size, width, 0, cfg.dtype),
             (cfg.num_layers, num_slots, cfg.ssm_heads, cfg.ssm_state,
              cfg.ssm_head_dim,
              (cfg.ssm_conv - 1) * parallel_ssm_ops.conv_width(geom)))
@@ -1381,6 +1473,169 @@ def _ssm_cow(cfg, num_pages, page_size, src, dst, state_slots=0):
     # from a snapshot copies it, `build_state_copy_program`)
     _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
     _stacked_copy_page(STACKED_POOLS[:2], num_pages, src, dst)
+
+
+# -- the "mixer_moe" family --------------------------------------------------
+
+
+def _state_pack(cfg: DecoderConfig) -> int:
+    return mixer_moe_ops.state_pack(cfg.ssm_head_dim,
+                                    cfg.ssm_heads // cfg.ssm_groups)
+
+
+def _mixer_geometry(cfg: DecoderConfig) -> dict:
+    return {"plan": cfg.layer_pattern, "num_heads": cfg.num_heads,
+            "num_kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
+            "eps": float(cfg.rms_norm_eps), "ssm_heads": cfg.ssm_heads,
+            "ssm_head_dim": cfg.ssm_head_dim, "ssm_groups": cfg.ssm_groups,
+            "ssm_state": cfg.ssm_state, "ssm_conv": cfg.ssm_conv,
+            "ssm_chunk": cfg.ssm_chunk,
+            "state_pack": _state_pack(cfg),
+            "experts_per_token": cfg.experts_per_token,
+            "routed_scaling": float(cfg.routed_scaling),
+            "experts_held": cfg.held_experts}
+
+
+def _mixer_param_specs(cfg: DecoderConfig) -> dict:
+    """name -> (shape, dtype, initializer), stacked by layer kind: `norm`
+    over all layers (one pre-norm a layer), `mix.*` over the mixers,
+    `attn.*` over the attention layers, `moe.*` over the expert layers, the
+    held experts `[L_experts, held, ...]`. Every matrix is drawn at `target
+    x fan_in^-0.5`, the target the standard deviation of its product: the
+    embedding 1 (the residual stream enters at RMS 1); the mixer as
+    "parallel_ssm"'s (z and x 1, B and C 2, dt 0.5 around a bias drawn
+    log-uniform over 0.001-0.1, `exp(A_log)` over 1-16, the skip near 1, the
+    convolution N(0, 0.5) with a bias of 0.02, the way back 0.5); queries 2,
+    keys and values 1 (no rotary: attention logits of standard deviation 2
+    over sqrt(head_dim)), their way back 2 (its input is a weighted mean of
+    values); the router 2 in float32 with a selection bias of 0.02, so that
+    its sigmoids neither saturate nor tie and the bias decides some choices
+    ("latent_moe"'s); the way into the latent 1, an expert's first matrix 1
+    (a squared ReLU of a unit normal has RMS 1.22), its second 1, the way
+    out of the latent 1 (about scaling x 1.22 / sqrt(k) x sqrt(held share):
+    0.65 of the residual's RMS at 22 of 512 with a quarter held); the
+    shared expert 1 and 0.5; the head 2.5. The large ones are in
+    `cfg.dtype`; norms, the router and its bias, the convolution and the
+    per-head scalars in float32."""
+    H, V, F, Z = cfg.hidden_size, cfg.vocab_size, cfg.ffn_size, \
+        cfg.latent_size
+    nh, nkv, dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    Hs, GN = cfg.ssm_heads, cfg.ssm_groups * cfg.ssm_state
+    I = Hs * cfg.ssm_head_dim
+    C = I + 2 * GN
+    E, held, Fs = cfg.num_experts, cfg.held_experts, cfg.shared_expert_size
+    kinds = collections.Counter(cfg.layer_pattern)
+    Lm, La, Le = (kinds[k] for k in (
+        mixer_moe_ops.MIXER, mixer_moe_ops.ATTENTION, mixer_moe_ops.EXPERTS))
+    f32, big = "float32", cfg.dtype
+    near_one = Normal(1.0, 0.05)
+    root = H ** -0.5
+
+    def fan(n, scale=1.0):
+        return StackedNormal(0.0, scale * n ** -0.5)
+
+    return {
+        "dec.word_emb": ([V, H], big, BlockedNormal(
+            1.0, block_rows=_draw_rows(V))),
+        "dec.lm_head": ([H, V], big, BlockedNormal(
+            2.5 * root, block_rows=_draw_rows(H))),
+        "dec.final_norm.scale": ([H], f32, near_one),
+        "norm": ([cfg.num_layers, H], f32, near_one),
+        "mix.w_in": ([Lm, H, I + C + Hs], big, BlockedNormal(columns=[
+            (I, root), (I, root), (GN, 2.0 * root), (GN, 2.0 * root),
+            (Hs, 0.5 * root)])),
+        "mix.conv_w": ([Lm, C, cfg.ssm_conv], f32, Normal(0.0, 0.5)),
+        "mix.conv_b": ([Lm, C], f32, Normal(0.0, 0.02)),
+        "mix.dt_bias": ([Lm, Hs], f32, Uniform(-6.9, -2.25)),
+        "mix.a_log": ([Lm, Hs], f32, Uniform(0.0, 2.77)),
+        "mix.d_skip": ([Lm, Hs], f32, Normal(1.0, 0.1)),
+        "mix.ssm_norm": ([Lm, I], f32, near_one),
+        "mix.w_out": ([Lm, I, H], big, BlockedNormal(0.5 * I ** -0.5)),
+        "attn.wq": ([La, H, nh * dh], big, fan(H, 2.0)),
+        "attn.wk": ([La, H, nkv * dh], big, fan(H)),
+        "attn.wv": ([La, H, nkv * dh], big, fan(H)),
+        "attn.wo": ([La, nh * dh, H], big, fan(nh * dh, 2.0)),
+        "moe.router_w": ([Le, H, E], f32, Normal(0.0, 2.0 * root)),
+        "moe.router_bias": ([Le, E], f32, Normal(0.0, 0.02)),
+        "moe.w_dn": ([Le, H, Z], big, fan(H)),
+        "moe.w_up": ([Le, Z, H], big, fan(Z)),
+        "moe.shared_in": ([Le, H, Fs], big, fan(H)),
+        "moe.shared_out": ([Le, Fs, H], big, fan(Fs, 0.5)),
+        "w1": ([Le, held, Z, F], big, fan(Z)),
+        "w2": ([Le, held, F, Z], big, fan(F)),
+    }
+
+
+_MIXER_GROUPS = (("MixerParams", "mix.", mixer_moe_ops.MIXER_PARAMS),
+                 ("AttentionParams", "attn.", mixer_moe_ops.ATTENTION_PARAMS),
+                 ("MoeParams", "moe.", mixer_moe_ops.MOE_PARAMS),
+                 ("Experts", "", mixer_moe_ops.EXPERT_PARAMS))
+
+
+def _mixer_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
+                 page_size: int = 0, state_slots: int = 0, **feeds):
+    """Append the one `mixer_moe_stack` op of a program; returns its
+    outputs (next_token, logits, routes)."""
+    helper = LayerHelper("mixer_moe_stack")
+    params = {key: helper.create_parameter(
+        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
+        for key, (shape, dtype, init) in _mixer_param_specs(cfg).items()}
+    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
+              "Head": [params["dec.lm_head"]],
+              "FinalNorm": [params["dec.final_norm.scale"]],
+              "Norms": [params["norm"]]}
+    inputs.update({slot: [params[prefix + k] for k in keys]
+                   for slot, prefix, keys in _MIXER_GROUPS})
+    inputs.update({slot: [var] for slot, var in feeds.items()})
+    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
+            for slot, dtype in (("NextToken", "int32"),
+                                ("Logits", "float32"), ("Routes", "int32"))}
+    if mode != "full":
+        _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
+        inputs["StateSlot"] = [L.data(name=SSLOT_FEED, shape=[],
+                                      dtype="int32")]
+        for slot, name in _SSM_POOLS:
+            inputs[slot] = [name]
+            outs[slot + "Out"] = [name]
+    helper.append_op("mixer_moe_stack", inputs, outs,
+                     dict(_mixer_geometry(cfg), mode=mode,
+                          num_pages=int(num_pages),
+                          num_slots=int(state_slots)))
+    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
+            "routes": outs["Routes"][0]}
+
+
+def _mixer_window_io(out):
+    return {"next_token": out["next_token"], "last_logits": out["logits"],
+            "routes": out["routes"], "extra_feeds": [SSLOT_FEED]}
+
+
+def _mixer_prefill(cfg, num_pages, page_size, tok, pos, pages, lens,
+                   state_slots=0):
+    return _mixer_window_io(_mixer_stack(
+        cfg, "prefill", tok, pos, num_pages, page_size, state_slots,
+        PageTable=pages, Lens=lens))
+
+
+def _mixer_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
+                  lens, state_slots=0):
+    # a prompt's chunk or the suffix behind a resumed snapshot (no verify
+    # window)
+    return _mixer_window_io(_mixer_stack(
+        cfg, "window", tok, pos, num_pages, page_size, state_slots,
+        PageTable=pages, Start=start, Lens=lens))
+
+
+def _mixer_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask,
+                  state_slots=0):
+    return dict(_mixer_stack(cfg, "decode", tok, pos, num_pages, page_size,
+                             state_slots, PageTable=pages, Mask=mask),
+                extra_feeds=[SSLOT_FEED])
+
+
+def _mixer_full(cfg, tok, pos):
+    out = _mixer_stack(cfg, "full", tok, pos)
+    return {"logits": out["logits"], "routes": out["routes"]}
 
 
 def build_state_copy_program(cfg: DecoderConfig, num_pages: int,
@@ -1847,4 +2102,7 @@ _FAMILY = {
     "latent_moe": {"prefill": _latent_prefill, "window": _latent_window,
                    "cow": _latent_cow, "decode": _latent_decode,
                    "full": _latent_full},
+    "mixer_moe": {"prefill": _mixer_prefill, "window": _mixer_window,
+                  "cow": _ssm_cow, "decode": _mixer_decode,
+                  "full": _mixer_full},
 }

@@ -50,7 +50,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import executor
 from paddle_tpu.ops import (attention_ops, cca_moe_ops, latent_moe_ops,
-                            parallel_ssm_ops, sparse_moe_ops)
+                            mixer_moe_ops, parallel_ssm_ops, sparse_moe_ops)
 from paddle_tpu.ops.pallas_kernels import workbench
 from paddle_tpu.serving import DecoderConfig, PagedKVPool, ServingEngine
 from paddle_tpu.serving import model as sv_model
@@ -67,9 +67,10 @@ LEVERS = {
     "window_attention": (((attention_ops, "_paged_arm"),), None,
                          r"paged_window_attention_gqa "),
     "experts": (((cca_moe_ops, "_experts_backend"),
-                 (sparse_moe_ops, "_experts_backend")),
+                 (sparse_moe_ops, "_experts_backend"),
+                 (mixer_moe_ops, "_experts_backend")),
                 lambda backend: backend == "pallas",
-                r"moe_top(1|k)_experts_"),
+                r"moe_(top1|topk|relu2)_experts_"),
     "indexer": (((sparse_moe_ops, "paged_indexer_runs"),), bool,
                 r"paged_indexer_scores "),
     "ssm_update": (((parallel_ssm_ops, "_update_backend"),),
@@ -240,6 +241,15 @@ CASES = [
         "latent_attend": "latent_rows_attention f32[128,128,512]"},
         [(128, {"experts": "moe_topk_experts_prefill f32[128,7168]",
                 "latent_attend": "latent_rows_attention f32[64,128,512]"})]),
+    # nemotron3_super_120b.reason.sat: 5 mixers x 160 slots of state, two
+    # 64-wide heads a lane row; the ungated experts in a latent of 1,024;
+    # 32 query heads over 2 KV heads (groups of 16)
+    *_serving("nemotron3_super_120b", 128, {
+        "ssm_update": "ssm_decode_update (f32[800,8192,128],..)",
+        "experts": "moe_relu2_experts_decode f32[128,1024]",
+        "full_attention": "paged_decode_attention_gqa f32[128,32,128]"},
+        [(t, {"experts": f"moe_relu2_experts_prefill f32[{t},1024]"})
+         for t in (128, 512)]),
     # bert_base.s128 (and .dp4: the same rows a chip) and .s512
     ("bert_base", "attention", "train", (128, 128), "xla"),
     ("bert_base", "attention", "train", (32, 512), "xla"),
@@ -260,6 +270,9 @@ CASES = [
     *_serving("rehearse_deepseek", 4,
               {"experts": "xla", "indexer": "xla", "latent_attend": "xla"},
               [(16, {"experts": "xla", "latent_attend": "xla"})]),
+    *_serving("rehearse_nemotron", 4,
+              {"ssm_update": "xla", "experts": "xla",
+               "full_attention": "xla"}, [(8, {"experts": "xla"})]),
 ]
 
 

@@ -296,6 +296,62 @@ def test_parallel_ssm_programs_compiled_for_v5e_move_no_pool(v5e_chip):
     assert f"f32[{2 * slots},2048,128]" not in texts["state_copy"]
 
 
+def test_mixer_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
+    """The "mixer_moe" block at the served widths (two mixers, an expert
+    layer with 8 experts held, an attention layer, a small vocabulary): the
+    state pool whose slots hold
+    two 64-wide heads a lane row keeps its layout through every program
+    (the one attention layer's K and V are not judged here: at this cut's
+    few pages the compiler stages a pool for the decode step, which it does
+    not do at the served 4,096 pages; chip_smoke-style checks on the chip
+    read the served size),
+    the windows' pack and unpack included (written as a transpose they
+    copied the whole pool there and back: PR 43), and the three Pallas
+    kernels are in the decode program."""
+    import json
+    import os
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    import numpy as np
+
+    from paddle_tpu.serving import ServingEngine
+    from tools.pool_hlo import serving_program_hlos
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron3_super_120b.json")) as f:
+        engine = json.load(f)["engine"]
+    # two mixers: with one the transposed form showed no copy either
+    cfg = DecoderConfig(**dict(engine["config_kwargs"], num_layers=4,
+                               layer_pattern="MEM*", experts_held=8,
+                               vocab_size=2048))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        eng = ServingEngine(cfg, page_size=engine["page_size"],
+                            pool_pages=1032, max_inflight=32)
+        texts = serving_program_hlos(eng, rows=32, pages=36,
+                                     device=v5e_chip)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    slots = eng.state_pool.num_pages
+    assert eng._scope.find_var("kv_cache.ssm").shape == (2 * slots,
+                                                         64 * 128, 128)
+    states = int(np.prod(eng._scope.find_var("kv_cache.ssm").shape))
+    assert {name: len(pool_sized_copies(text, states))
+            for name, text in texts.items()} == {
+        "decode": 0, "prefill": 0, "window": 0, "cow": 0, "state_copy": 0}
+    assert "ssm_decode_update" in texts["decode"]
+    assert "moe_relu2_experts_decode" in texts["decode"]
+    assert "moe_relu2_experts_prefill" in texts["window"]
+    assert "paged_decode_attention_gqa" in texts["decode"]
+    assert "f32[32,32,128]" in texts["decode"]      # groups of 16, unpadded
+    assert " gather(" not in texts["state_copy"]
+
+
 def test_latent_moe_stack_compiled_for_v5e_moves_neither_pool(v5e_chip,
                                                               monkeypatch):
     """The "latent_moe" stack at the served widths

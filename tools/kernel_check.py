@@ -351,7 +351,59 @@ def _ssm_update_cases(spec):
         return _compare(lambda: both(spec.fn), lambda: both(spec.reference),
                         (), 0, "float32")
 
-    return [("b20 (16 live) h32 n256 p128 g2 pool 2x20 float32", case)]
+    def packed_case():
+        # the "mixer_moe" geometry: 128 heads of a 128 x 64 float32 state in
+        # 8 groups, two heads of a group side by side on the lanes (a slot
+        # is [64 * 128, 128]); 2 layers x 20 slots, rows as above
+        rows, H, N, P, G, B = 40, 128, 128, 64, 8, 20
+        ks = jax.random.split(jax.random.PRNGKey(12), 6)
+        pool = _rand(ks[0], (rows, H // 2 * N, 2 * P), "float32")
+        idx = jnp.concatenate([
+            20 + jax.random.permutation(ks[1], 19)[:16].astype(jnp.int32),
+            jnp.full((4,), 39, jnp.int32)])
+        a = jax.nn.sigmoid(_rand(ks[2], (B, H), "float32") + 2.0)
+        dtx = _rand(ks[3], (B, H, P), "float32", 0.1)
+        bm = _rand(ks[4], (B, G, N), "float32")
+        cm = _rand(ks[5], (B, G, N), "float32")
+        assert spec.supported(pool.shape, N, H // G // 2)
+        assert not spec.supported((rows, H * N, P), N, H // G)
+
+        def both(fn):
+            new_pool, y = fn(pool, idx, a, dtx, bm, cm)
+            return jnp.concatenate([new_pool[:39].reshape(-1),
+                                    y[:16].reshape(-1)])
+
+        return _compare(lambda: both(spec.fn), lambda: both(spec.reference),
+                        (), 0, "float32")
+
+    return [("b20 (16 live) h32 n256 p128 g2 pool 2x20 float32", case),
+            ("b20 (16 live) h128 n128 p64 g8 packed 2 a lane row pool 2x20 "
+             "float32", packed_case)]
+
+
+def _moe_relu2_cases(spec):
+    """A chip's share of the "mixer_moe" block's experts: 128 HELD of 512,
+    two matrices 1024 -> 2688 -> 1024 and a squared ReLU, top-22 (so a
+    row's held columns are mostly zero), bfloat16, layer 1 of a stack of
+    two, at a decode batch and at a 512-token chunk."""
+
+    def case(tokens):
+        L, E, Z, F, k, held = 2, 512, 1024, 2688, 22, 128
+        ks = jax.random.split(jax.random.PRNGKey(13), 4)
+        u = _rand(ks[0], (tokens, Z), "float32")
+        w1 = _rand(ks[1], (L, held, Z, F), "bfloat16", Z ** -0.5)
+        w2 = _rand(ks[2], (L, held, F, Z), "bfloat16", F ** -0.5)
+        vals, ids = jax.lax.top_k(jax.random.uniform(ks[3], (tokens, E)), k)
+        cw = jnp.sum(jax.nn.one_hot(ids, E)
+                     * (5.0 * vals / vals.sum(-1, keepdims=True))[..., None],
+                     1)[:, :held]
+        assert spec.supported(u.shape, w1.shape)
+        return _compare(lambda *a: spec.fn(*a, 1),
+                        lambda *a: spec.reference(*a, 1),
+                        (u, cw, w1, w2), 0, "bfloat16")
+
+    return [(f"t{t} 128 held of 512 top22 z1024 f2688 bf16 layer 1 of 2",
+             lambda t=t: case(t)) for t in (128, 512)]
 
 
 def _paged_indexer_cases(spec):
@@ -434,6 +486,7 @@ CASES = {
     "attention_paged_decode": lambda spec: (_paged_cases(spec)
                                             + _paged_gqa_cases(spec)),
     "moe_top1_experts": _moe_cases,
+    "moe_relu2_experts": _moe_relu2_cases,
     # bench_bert_long: b64 s512
     "attention_short_seq": lambda spec: _attention_cases(spec, 64, 512),
     # bench_bert_short: b128 s128

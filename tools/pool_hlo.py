@@ -24,7 +24,8 @@ returns their texts.
     python tools/pool_hlo.py --config zaya1_8b --layers 2 --dump <dir>
 
 (`--config`: the engine of `benchmark/configs/<name>.json`, any block
-family, `--layers` deep where the family is one scanned layer; every pool
+family, `--layers` deep where the family is one scanned layer or a
+pattern of one character a layer; every pool
 the family keeps is listed, the sliding layers' second pool too.)
 
 (on a host without a TPU it compiles for a described v5e: what the chip's
@@ -302,6 +303,8 @@ def main(argv=None) -> int:
         kw = dict(spec["config_kwargs"])
         if a.layers and "layer_types" not in kw:
             kw["num_layers"] = a.layers
+            if "layer_pattern" in kw:   # a plan of one character a layer
+                kw["layer_pattern"] = kw["layer_pattern"][:a.layers]
         cfg = DecoderConfig(**kw)
         eng = ServingEngine(cfg, page_size=spec["page_size"],
                             pool_pages=spec["pool_pages"],
