@@ -406,6 +406,11 @@ DECLARED: list[tuple] = [
     ("serving.ssm.decode_layer_steps", COUNTER,
      "layer x decode-step pairs: the calls of the one-token state update",
      ()),
+    ("serving.ssm.conv_kernel_layer_steps", COUNTER,
+     "layer x decode-step pairs whose convolution the Pallas kernel ran, "
+     "the tail moved on in place in its slot (conv_decode_update); 0 on "
+     "the XLA arm (over ssm.decode_layer_steps: how often the kernel "
+     "engages)", ()),
     ("serving.ssm.scan_tokens", COUNTER,
      "real token x layer pairs that prefill windows scanned", ()),
     ("serving.ssm.scan_layer_steps", COUNTER,

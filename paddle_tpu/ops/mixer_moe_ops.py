@@ -7,7 +7,7 @@ character a layer (`M`, `*`, `E`), and the op that runs a stack of them.
 Every layer `l`: `x <- x + f_l(RMSNorm_l(x))`, `f_l` by its kind:
 
   * `M`  the "parallel_ssm" mixer with every multiplier 1 (its convolution,
-         chunked scan, one-token update and grouped gated norm are imported
+         chunked scan, one-token updates and grouped gated norm are imported
          from `parallel_ssm_ops`, not rewritten): `[z | xBC | dt] = x~ W_in`,
          `xBC <- silu(conv(xBC))`, the recurrence `S <- a S + B (x) dt x`,
          `y = C . S + D x`, `y <- RMSNorm_grouped(y * silu(z))`, `f = y
@@ -53,7 +53,8 @@ from .attention_ops import (_gather_pages, _write_rows, kv_cache_append_fn,
 from .cca_moe_ops import _experts_backend, _page_row_index, rms_norm_fn
 from .hybrid_moe_ops import causal_attention_fn
 from .latent_moe_ops import group_limited_router_fn
-from .parallel_ssm_ops import (causal_conv_fn, gated_group_norm_fn,
+from .parallel_ssm_ops import (causal_conv_fn, conv_token_update_fn,
+                               conv_window_update_fn, gated_group_norm_fn,
                                ssd_scan_fn, ssm_token_update_fn)
 from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
@@ -197,16 +198,18 @@ def mixer_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm, norms,
                 gate, xbc, dt_raw = (proj[..., :I], proj[..., I:I + C],
                                      proj[..., I + C:])
             with piece("conv"):
-                if paged:
-                    tail = jnp.where(fresh[:, None], 0.0,
-                                     c_pool[row]).reshape(B, K - 1, C)
+                if decode:
+                    c_pool, xbc = conv_token_update_fn(
+                        c_pool, row, xbc[:, 0], p["conv_w"], p["conv_b"])
+                    xbc = xbc[:, None]
+                elif paged:
+                    c_pool, xbc = conv_window_update_fn(
+                        c_pool, row, xbc, p["conv_w"], p["conv_b"], fresh,
+                        count)
                 else:
-                    tail = jnp.zeros((B, K - 1, C), _F32)
-                xbc, tail = causal_conv_fn(xbc, tail, p["conv_w"],
-                                           p["conv_b"],
-                                           count if paged else None)
-                if paged:
-                    c_pool = c_pool.at[row].set(tail.reshape(B, -1))
+                    xbc, _ = causal_conv_fn(
+                        xbc, jnp.zeros((B, K - 1, C), _F32), p["conv_w"],
+                        p["conv_b"])
             xs_ = xbc[..., :I].reshape(B, S, Hs, P)
             bm = xbc[..., I:I + G * N].reshape(B, S, G, N)
             cm = xbc[..., I + G * N:].reshape(B, S, G, N)

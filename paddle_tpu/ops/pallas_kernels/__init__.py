@@ -22,8 +22,12 @@ the key pool, where XLA gathered the pages and wrote the per-head scores;
 `latent_attend` (the absorbed latent attention over each query's gathered
 cache rows, unpacked in VMEM; `latent_moe_ops._attend_rows`, its shape gate
 the only switch),
-`moe_experts` (the routed experts' stream) and `ssm_update` (a decode
-token's state update in place).
+`moe_experts` (the routed experts' stream), `ssm_update` (a decode
+token's state update in place in its slot) and `conv_update` (the same
+token's causal convolution, its tail moved on in place in its slot of the
+pool of tails `[rows, tail_width / 128, 128]`, where XLA's scatter passed
+over the whole pool; `parallel_ssm_ops.conv_token_update_fn`, its shape
+gate the only switch).
 """
 from . import workbench
 from .attention import short_seq_attention, short_seq_supported

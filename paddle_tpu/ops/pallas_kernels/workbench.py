@@ -144,8 +144,8 @@ def register_kernel(name: str, *, reference, supported, decision_op,
 def all_kernels() -> dict[str, KernelSpec]:
     """Every registered kernel (import side effect: pulls in the kernel
     modules so their registrations run)."""
-    from . import (attention, epilogue, latent_attend,  # noqa: F401
-                   moe_experts, paged_attention, paged_indexer,
-                   short_attention, ssm_update)
+    from . import (attention, conv_update, epilogue,  # noqa: F401
+                   latent_attend, moe_experts, paged_attention,
+                   paged_indexer, short_attention, ssm_update)
 
     return dict(_KERNELS)

@@ -11,6 +11,10 @@ that runs them is `parallel_ssm_stack`:
                            `ssm_conv - 1` rows carried from the token before
                            (the TAIL), and the tail after the window's last
                            REAL token;
+  * `conv_token_update`  — the same convolution for ONE token a row, the
+                           tail moved on in place in the pool of tails
+                           (`pallas_kernels.conv_update` on the chip, its
+                           plain form elsewhere);
   * `ssd_scan`           — the recurrence `S_t = a_t S_{t-1} + dt_t x_t (x)
                            B_t`, `y_t = S_t C_t` over a window in CHUNKED
                            form (Mamba-2's SSD): inside a chunk of
@@ -39,10 +43,14 @@ What a sequence carries besides K/V lives in two pools of SLOTS, not pages
 (`kv_cache.STATE_POOLS`): `S` `[L * slots, heads * N, P]` float32 (a slot's
 heads one slab, the state dimension on the sublanes: see
 `pallas_kernels.ssm_update`) and the
-convolution's tail `[L * slots, (ssm_conv - 1) * channels]` float32. A row's
+convolution's tail, `(ssm_conv - 1) * channels` float32 values a slot, kept
+as whole (8, 128) tiles `[L * slots, (ssm_conv - 1) * channels / 128, 128]`
+where they are whole (`kv_cache.state_pool_shapes`; `[L * slots, (ssm_conv
+- 1) * channels]` otherwise). A row's
 slot is a feed (`sv_sslot`); a window reads it as its initial state (zeros
 where the window starts at position 0) and leaves its final state there, a
-decode step updates it in place; padding rows are given a scratch slot.
+decode step updates both in place (`ssm_token_update`, `conv_token_update`);
+padding rows are given a scratch slot.
 
 Precision: matmul operands in the weights' dtype (bfloat16 as served),
 float32 accumulation; residual stream, norms, the convolution and its tail,
@@ -119,6 +127,45 @@ def causal_conv_fn(xbc, tail, conv_w, conv_b, lens=None):
     at = lens[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
     new_tail = jnp.take_along_axis(ext, at[:, :, None], axis=1)
     return out * jax.nn.sigmoid(out), new_tail
+
+
+def conv_update_runs(pool_shape, taps: int) -> bool:
+    """Whether a decode step's convolution of `taps` taps comes from
+    `pallas_kernels.conv_update`, in place in the pool of tails
+    `pool_shape`: its shape gate decides alone, where a Pallas kernel can
+    run at all (rows do not enter it: a grid step is a row). The engine
+    books `serving.ssm.conv_kernel_layer_steps` by the same answer."""
+    from .pallas_kernels import conv_update, workbench
+
+    return (workbench.runnable(conv_update)
+            and conv_update.update_supported(tuple(pool_shape), int(taps)))
+
+
+def conv_token_update_fn(c_pool, idx, xbc, conv_w, conv_b):
+    """One token a row, in place: c_pool `[rows, (K - 1) * C / 128, 128]`
+    (or `[rows, (K - 1) * C]`: `kv_cache.state_pool_shapes`), idx [B] (each
+    row's slot in this layer), xbc [B, C], conv_w [C, K], conv_b [C] ->
+    (the pool with the tails of rows `idx` moved on one token, silu(conv)
+    [B, C])."""
+    from .pallas_kernels import conv_update
+
+    if conv_update_runs(c_pool.shape, conv_w.shape[1]):
+        return conv_update.conv_decode_update(c_pool, idx, xbc, conv_w,
+                                              conv_b)
+    return conv_update._reference(c_pool, idx, xbc, conv_w, conv_b)
+
+
+def conv_window_update_fn(c_pool, idx, xbc, conv_w, conv_b, fresh, lens):
+    """A window a row, behind the tail in the row's slot (zeros where
+    `fresh` [B]: the window starts a sequence) and leaving there the tail
+    after its last real token: c_pool and idx as `conv_token_update_fn`
+    takes them, xbc [B, S, C], lens [B] -> (the pool, silu(conv) [B, S,
+    C])."""
+    B, _, C = xbc.shape
+    tail = jnp.where(fresh[:, None, None], 0.0,
+                     c_pool[idx].reshape(B, -1, C))
+    y, tail = causal_conv_fn(xbc, tail, conv_w, conv_b, lens)
+    return c_pool.at[idx].set(tail.reshape((B,) + c_pool.shape[1:])), y
 
 
 def _decay_and_input(x, dt_raw, dt_bias, a_log, valid=None):
@@ -333,15 +380,16 @@ def parallel_ssm_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                 B, S, nkv, dh), pos, inv_freq, dh)
             v = _mm(za, p["wv"]).reshape(B, S, nkv, dh)
         with piece("conv"):
-            if paged:
-                tail = jnp.where(fresh[:, None], 0.0, c_pool[row]).reshape(
-                    B, K - 1, C)
+            if decode:
+                c_pool, xbc = conv_token_update_fn(
+                    c_pool, row, xbc[:, 0], p["conv_w"], p["conv_b"])
+                xbc = xbc[:, None]
+            elif paged:
+                c_pool, xbc = conv_window_update_fn(
+                    c_pool, row, xbc, p["conv_w"], p["conv_b"], fresh, count)
             else:
-                tail = jnp.zeros((B, K - 1, C), _F32)
-            xbc, tail = causal_conv_fn(xbc, tail, p["conv_w"], p["conv_b"],
-                                       count if paged else None)
-            if paged:
-                c_pool = c_pool.at[row].set(tail.reshape(B, -1))
+                xbc, _ = causal_conv_fn(xbc, jnp.zeros((B, K - 1, C), _F32),
+                                        p["conv_w"], p["conv_b"])
         xs_ = xbc[..., :I].reshape(B, S, Hs, P)
         bm = xbc[..., I:I + G * N].reshape(B, S, G, N)
         cm = xbc[..., I + G * N:].reshape(B, S, G, N)

@@ -198,12 +198,13 @@ from ..data_feeder import _round_up_pow2
 from ..executor import Executor, Scope
 from ..framework import Program, program_guard
 from ..observability.slo import hist_p99_above
-from ..ops import attention_ops, latent_moe_ops, sparse_moe_ops
+from ..ops import (attention_ops, latent_moe_ops, parallel_ssm_ops,
+                   sparse_moe_ops)
 from ..resilience.faults import InjectedFault, fault_point
 from ..resilience.retry import serving_policy
 from . import model as sv_model
-from .kv_cache import (INDEX_POOL, LATENT_POOL, OwnedPoolView, PagedKVPool,
-                       PrefixCache, create_device_pools,
+from .kv_cache import (INDEX_POOL, LATENT_POOL, STATE_POOLS, OwnedPoolView,
+                       PagedKVPool, PrefixCache, create_device_pools,
                        create_stacked_pools, create_state_pools,
                        pool_var_names)
 from .sampling import SamplingParams, request_rng, sample_token
@@ -743,6 +744,7 @@ class ServingEngine:
         self._grid_steps_by_signature: dict[tuple[int, int], int] = {}
         self._indexer_kernel_runs: bool | None = None
         self._attend_kernel_runs: dict[tuple[int, int], bool] = {}
+        self._conv_kernel_runs: bool | None = None
         self._prefill_run = self._exec_target(self._prefill_prog)
         self._decode_run = self._exec_target(self._decode_prog)
         self._window_run = self._exec_target(self._window_prog)
@@ -797,6 +799,7 @@ class ServingEngine:
             # (ISSUE 37)
             "state.snapshots": 0, "state.snapshot_evictions": 0,
             "ssm.decode_row_layers": 0, "ssm.decode_layer_steps": 0,
+            "ssm.conv_kernel_layer_steps": 0,
             "ssm.scan_tokens": 0, "ssm.scan_layer_steps": 0,
             "peak_state_slots_in_use": 0,
         }
@@ -2555,6 +2558,16 @@ class ServingEngine:
                 pool.dtype)
         return self._indexer_kernel_runs
 
+    def _conv_kernel(self) -> bool:
+        """Whether the Pallas kernel moves a decode step's convolution
+        tails on in place (the XLA gather and scatter otherwise): the ops'
+        own answer (rows do not enter it), asked once."""
+        if self._conv_kernel_runs is None:
+            self._conv_kernel_runs = parallel_ssm_ops.conv_update_runs(
+                self._scope.find_var(STATE_POOLS[1]).shape,
+                self.cfg.ssm_conv)
+        return self._conv_kernel_runs
+
     def _attend_kernel(self, bb: int, pb: int) -> bool:
         """Whether the Pallas kernel computes the absorbed attention of a
         decode step of `bb` rows behind `pb` pages (`absorbed_attention_fn`
@@ -2634,6 +2647,8 @@ class ServingEngine:
             self._count("ssm.decode_row_layers",
                         len(rows) * self.cfg.state_layers)
             self._count("ssm.decode_layer_steps", self.cfg.state_layers)
+            self._count("ssm.conv_kernel_layer_steps",
+                        self.cfg.state_layers if self._conv_kernel() else 0)
         if self.cfg.selects_within(pb * ps):
             L, k = self.cfg.num_layers, self.cfg.index_topk
             self._count("sparse.context_tokens",

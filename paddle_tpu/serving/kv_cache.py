@@ -143,6 +143,10 @@ WINDOW_POOLS = ("kv_cache.wk", "kv_cache.wv")
 # copied from a row's at a chunk boundary, hung on the cached block that
 # ends there), and resuming from one COPIES it into the row's own slot,
 # because unlike a K/V page a state is never read-only for its reader.
+# Both pools keep a slot as whole (8, 128) tiles where its widths are
+# (`state_pool_shapes`: `S` `[rows, heads * N, P]`, the tail `[rows,
+# tail_width / 128, 128]`), so that a decode step's kernels move ONE slot as
+# a block and nothing else.
 STATE_POOLS = ("kv_cache.ssm", "kv_cache.conv")
 
 
@@ -197,14 +201,19 @@ def state_pool_shapes(num_layers: int, num_slots: int, heads: int,
     on the sublanes: `pallas_kernels.ssm_update` says why; `pack` heads
     narrower than the lanes side by side in whole 128-lane rows: `[rows,
     heads / pack * state, pack * head_dim]`), and the
-    convolution's tail,
-    `[rows, tail_width]` (the last `conv - 1` pre-convolution rows side by
-    side), both float32, `rows = num_layers * num_slots` (the layers that
-    hold a state)."""
-    rows = int(num_layers) * int(num_slots)
+    convolution's tail, the last `conv - 1` pre-convolution rows one behind
+    the other, `tail_width` values a slot: as whole (8, 128) tiles, `[rows,
+    tail_width / 128, 128]`, where they are whole (`tail_width % 1024 ==
+    0`: a decode step then reads and writes ONE slot as a block,
+    `pallas_kernels.conv_update`), `[rows, tail_width]` otherwise. Both
+    float32, `rows = num_layers * num_slots` (the layers that hold a
+    state)."""
+    rows, tail_width = int(num_layers) * int(num_slots), int(tail_width)
+    tail = (rows, tail_width // 128, 128) if tail_width % 1024 == 0 \
+        else (rows, tail_width)
     return [(STATE_POOLS[0], (rows, int(heads) // int(pack) * int(state),
                               int(pack) * int(head_dim)), "float32"),
-            (STATE_POOLS[1], (rows, int(tail_width)), "float32")]
+            (STATE_POOLS[1], tail, "float32")]
 
 
 def declare_state_pools(block, *geometry) -> None:

@@ -3,8 +3,8 @@
 The choice of a kernel lives in code: each lever has ONE dispatch function
 that answers from shapes and dtypes (`attention_ops._paged_arm`,
 `cca_moe_ops._experts_backend`, `sparse_moe_ops.paged_indexer_runs`,
-`parallel_ssm_ops._update_backend`, `latent_moe_ops.latent_attend_runs`,
-`attention_ops.attention_backend`), and with `FLAGS_tuning_mode` off, as in
+`parallel_ssm_ops._update_backend`, `parallel_ssm_ops.conv_update_runs`,
+`latent_moe_ops.latent_attend_runs`, `attention_ops.attention_backend`), and with `FLAGS_tuning_mode` off, as in
 every cell, nothing else has a say. This file pins those answers for the
 configurations under `benchmark/configs/` (read, never written).
 
@@ -20,7 +20,8 @@ first output shape the benchmark's traced run lists it by
 `"xla"` where the shape gate refuses and XLA's form runs.
 
 Every expected kernel below is a `breakdown.device_ops` line of the ledger
-at PR 41, one case for each kernel name and row bucket listed there. The
+at PR 41 (`conv_decode_update`: of PR 44's traced runs, PERF.md section 5),
+one case for each kernel name and row bucket listed there. The
 `"xla"` cases are the other side of each gate: the `rehearse_*`
 configurations (4- and 8-token pages of 8-wide float32 heads), and the
 training cells, whose device ops in the ledger are XLA fusions
@@ -76,6 +77,8 @@ LEVERS = {
     "ssm_update": (((parallel_ssm_ops, "_update_backend"),),
                    lambda backend: backend == "pallas",
                    r"ssm_decode_update "),
+    "conv_update": (((parallel_ssm_ops, "conv_update_runs"),), bool,
+                    r"conv_decode_update "),
     "latent_attend": (((latent_moe_ops, "latent_attend_runs"),), bool,
                       r"latent_rows_attention "),
     "attention": (((attention_ops, "attention_backend"),),
@@ -233,6 +236,7 @@ CASES = [
     # run as 24 (`_padded_group_heads`)
     *_serving("falcon_h1_34b", 64, {
         "ssm_update": "ssm_decode_update (f32[480,8192,128],..)",
+        "conv_update": "conv_decode_update (f32[480,120,128],..)",
         "full_attention": "paged_decode_attention_gqa f32[64,24,128]"}),
     # deepseek_v32_exp.docs32k.sat: a window's queries attend in blocks of 64
     *_serving("deepseek_v32_exp", 128, {
@@ -242,10 +246,11 @@ CASES = [
         [(128, {"experts": "moe_topk_experts_prefill f32[128,7168]",
                 "latent_attend": "latent_rows_attention f32[64,128,512]"})]),
     # nemotron3_super_120b.reason.sat: 5 mixers x 160 slots of state, two
-    # 64-wide heads a lane row; the ungated experts in a latent of 1,024;
+    # 64-wide heads a lane row, a tail of 240 sublane rows a slot; the ungated experts in a latent of 1,024;
     # 32 query heads over 2 KV heads (groups of 16)
     *_serving("nemotron3_super_120b", 128, {
         "ssm_update": "ssm_decode_update (f32[800,8192,128],..)",
+        "conv_update": "conv_decode_update (f32[800,240,128],..)",
         "experts": "moe_relu2_experts_decode f32[128,1024]",
         "full_attention": "paged_decode_attention_gqa f32[128,32,128]"},
         [(t, {"experts": f"moe_relu2_experts_prefill f32[{t},1024]"})
@@ -266,12 +271,13 @@ CASES = [
               {"full_attention": "xla", "window_attention": "xla",
                "experts": "xla"}, [(8, {"experts": "xla"})]),
     *_serving("rehearse_falcon", 4,
-              {"ssm_update": "xla", "full_attention": "xla"}),
+              {"ssm_update": "xla", "conv_update": "xla",
+               "full_attention": "xla"}),
     *_serving("rehearse_deepseek", 4,
               {"experts": "xla", "indexer": "xla", "latent_attend": "xla"},
               [(16, {"experts": "xla", "latent_attend": "xla"})]),
     *_serving("rehearse_nemotron", 4,
-              {"ssm_update": "xla", "experts": "xla",
+              {"ssm_update": "xla", "conv_update": "xla", "experts": "xla",
                "full_attention": "xla"}, [(8, {"experts": "xla"})]),
 ]
 

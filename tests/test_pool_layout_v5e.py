@@ -7,7 +7,7 @@ import pytest
 import chip_smoke
 from paddle_tpu.serving import DecoderConfig
 from tools.pool_hlo import (kernel_calls, pool_sized_copies, sorts_over,
-                            token_row_gathers)
+                            token_row_gathers, updates_out_of_place)
 
 POOL = 3072 * 16 * 12 * 64
 
@@ -85,6 +85,94 @@ def test_pool_sized_copies_passes_in_place_updates():
         "dynamic-update-slice(", "ROOT %select.3 = f32[3072,16,768]"
         "{2,1,0:T(8,128)} select(")
     assert [c["name"] for c in pool_sized_copies(rewritten, POOL)] == [
+        "fusion.9"]
+
+
+# The parent of PR 44's decode program of `nemotron3_super_120b` (described
+# v5e): the pool of convolution tails as `[800, 30720]`, the first mixer's
+# scatter TWICE (rematerialised), both reading the parameter, so the first
+# cannot write into it. Two of the five mixers, attributes trimmed.
+TAILS = 800 * 30720
+HLO_SCATTER_TWICE = """\
+HloModule jit_serving_decode, is_scheduled=true, input_output_alias={ {7}: (35, {}, may-alias) }
+
+%fused_computation.12 (param_0.38: f32[800,30720], param_1.126: s32[1024]) -> f32[128,30720] {
+  %param_0.38 = f32[800,30720]{1,0:T(8,128)} parameter(0)
+  %param_1.126 = s32[1024]{0:T(1024)} parameter(1)
+  ROOT %gather.5 = f32[128,30720]{1,0:T(8,128)} gather(%param_0.38, %param_1.126), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,30720}
+}
+
+%fused_computation.19 (param_0.55: f32[800,30720], param_1.92: s32[128], param_2.86: f32[128,30720]) -> f32[800,30720] {
+  %param_0.55 = f32[800,30720]{1,0:T(8,128)} parameter(0)
+  %param_1.92 = s32[128]{0:T(128)} parameter(1)
+  %param_2.86 = f32[128,30720]{1,0:T(8,128)} parameter(2)
+  ROOT %scatter.23 = f32[800,30720]{1,0:T(8,128)} scatter(%param_0.55, %param_1.92, %param_2.86), update_window_dims={1}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, to_apply=%region_13.24
+}
+
+%fused_computation.18.clone (param_0.1591: f32[800,30720], param_1.1654: s32[128], param_2.1394: f32[128,30720]) -> f32[800,30720] {
+  %param_0.1591 = f32[800,30720]{1,0:T(8,128)} parameter(0)
+  %param_1.1654 = s32[128]{0:T(128)} parameter(1)
+  %param_2.1394 = f32[128,30720]{1,0:T(8,128)} parameter(2)
+  ROOT %scatter.42 = f32[800,30720]{1,0:T(8,128)} scatter(%param_0.1591, %param_1.1654, %param_2.1394), update_window_dims={1}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, to_apply=%region_2.6
+}
+
+%fused_computation.18.clone.clone (param_0.1592: f32[800,30720], param_1.1655: s32[128], param_2.1395: f32[128,30720]) -> f32[800,30720] {
+  %param_0.1592 = f32[800,30720]{1,0:T(8,128)} parameter(0)
+  %param_1.1655 = s32[128]{0:T(128)} parameter(1)
+  %param_2.1395 = f32[128,30720]{1,0:T(8,128)} parameter(2)
+  ROOT %scatter.43 = f32[800,30720]{1,0:T(8,128)} scatter(%param_0.1592, %param_1.1655, %param_2.1395), update_window_dims={1}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, to_apply=%region_2.6
+}
+
+ENTRY %main.74 (feed_vals_0_.1: f32[128,1], rw_vals_4_.1: f32[800,30720]) -> (s32[128], f32[800,30720]) {
+  %rw_vals_4_.1 = f32[800,30720]{1,0:T(8,128)} parameter(35), sharding={replicated}, metadata={op_name="rw_vals[4]"}
+  %fusion.12 = f32[128,30720]{1,0:T(8,128)} fusion(%rw_vals_4_.1, %pad_clamp_fusion.4), kind=kCustom, calls=%fused_computation.12, metadata={op_name="jit(serving_decode)/mixer_moe_stack/decode/conv/gather"}
+  %fusion.18.remat = f32[800,30720]{1,0:T(8,128)} fusion(%rw_vals_4_.1, %copy-done.108, %copy.364), kind=kCustom, calls=%fused_computation.18.clone, metadata={op_name="jit(serving_decode)/mixer_moe_stack/decode/conv/scatter"}
+  %fusion.13 = f32[128,30720]{1,0:T(8,128)} fusion(%fusion.18.remat, %pad_clamp_fusion.3), kind=kCustom, calls=%fused_computation.12, metadata={op_name="jit(serving_decode)/mixer_moe_stack/decode/conv/gather"}
+  %fusion.18.remat2 = f32[800,30720]{1,0:T(8,128)} fusion(%rw_vals_4_.1, %copy-done.109, %custom-call.52), kind=kCustom, calls=%fused_computation.18.clone.clone, metadata={op_name="jit(serving_decode)/mixer_moe_stack/decode/conv/scatter"}
+  %fusion.19 = f32[800,30720]{1,0:T(8,128)} fusion(%fusion.18.remat2, %copy-done.122, %copy.382), kind=kCustom, calls=%fused_computation.19, metadata={op_name="jit(serving_decode)/mixer_moe_stack/decode/conv/scatter"}
+  ROOT %tuple.232 = (s32[128]{0:T(128)}, f32[800,30720]{1,0:T(8,128)}) tuple(%copy-done.123, %fusion.19)
+}
+"""
+
+# PR 44's: the same pool as `[800, 240, 128]`, handed from the parameter
+# through one `conv_decode_update` a mixer, each aliasing it to its output.
+HLO_KERNEL_CHAIN = """\
+HloModule jit_serving_decode, is_scheduled=true, input_output_alias={ {7}: (35, {}, may-alias) }
+
+ENTRY %main.74 (feed_vals_0_.1: f32[128,1], rw_vals_4_.1: f32[800,240,128]) -> (s32[128], f32[800,240,128]) {
+  %rw_vals_4_.1 = f32[800,240,128]{2,1,0:T(8,128)} parameter(35), sharding={replicated}, metadata={op_name="rw_vals[4]"}
+  %conv_decode_update.10 = (f32[800,240,128]{2,1,0:T(8,128)}, f32[128,80,128]{2,1,0:T(8,128)S(1)}) custom-call(%get-tuple-element.349, %rw_vals_4_.1, %bitcast.605, %copy_bitcast_fusion.4, %bitcast.655), custom_call_target="tpu_custom_call", output_to_operand_aliasing={{0}: (1, {})}
+  %jit__call_.32 = f32[800,240,128]{2,1,0:T(8,128)} get-tuple-element(%conv_decode_update.10), index=0
+  %conv_decode_update.11 = (f32[800,240,128]{2,1,0:T(8,128)}, f32[128,80,128]{2,1,0:T(8,128)S(1)}) custom-call(%copy-done.97, %jit__call_.32, %bitcast.607, %copy_bitcast_fusion.3, %bitcast.654), custom_call_target="tpu_custom_call", output_to_operand_aliasing={{0}: (1, {})}
+  %jit__call_.38 = f32[800,240,128]{2,1,0:T(8,128)} get-tuple-element(%conv_decode_update.11), index=0
+  ROOT %tuple.232 = (s32[128]{0:T(128)}, f32[800,240,128]{2,1,0:T(8,128)}) tuple(%copy-done.123, %jit__call_.38)
+}
+"""
+
+
+def test_updates_out_of_place_names_the_scatter_whose_pool_is_read_again():
+    """An update whose pool operand has a later user cannot write into it:
+    the first of the rematerialised pair is named, the second (the
+    parameter's last user) and the scatter behind it are not."""
+    assert updates_out_of_place(HLO_SCATTER_TWICE, TAILS) == [{
+        "name": "fusion.18.remat", "op": "fusion",
+        "shape": "f32[800,30720]", "pool": "rw_vals_4_.1",
+        "read_again_by": "fusion.18.remat2"}]
+    # the instruction itself passes as an in-place update
+    assert pool_sized_copies(HLO_SCATTER_TWICE, TAILS) == []
+    assert updates_out_of_place(HLO_SCATTER_TWICE, TAILS // 2) == []
+
+
+def test_updates_out_of_place_passes_a_pool_handed_from_update_to_update():
+    """A chain of aliasing kernel calls, and PR 24's programs, whose pool
+    is read by the attention kernel BEFORE the copy-on-write updates it:
+    an earlier reader holds nothing."""
+    assert updates_out_of_place(HLO_KERNEL_CHAIN, TAILS) == []
+    assert pool_sized_copies(HLO_KERNEL_CHAIN, TAILS) == []
+    assert updates_out_of_place(HLO_IN_PLACE, POOL) == []
+    late = HLO_IN_PLACE.replace(
+        "bitcast(%fusion.9)", "bitcast(%fusion.3)")
+    assert [u["name"] for u in updates_out_of_place(late, POOL)] == [
         "fusion.9"]
 
 
@@ -286,6 +374,11 @@ def test_parallel_ssm_programs_compiled_for_v5e_move_no_pool(v5e_chip):
             for name, text in texts.items()} == {
         "decode": 0, "prefill": 0, "window": 0, "cow": 0, "state_copy": 0}
     assert "ssm_decode_update" in texts["decode"]
+    # a decode token's convolution moves its tail on in its slot (PR 44)
+    tails = eng._scope.find_var("kv_cache.conv").shape
+    assert tails == (2 * 80, 120, 128)
+    assert "conv_decode_update" in texts["decode"]
+    assert updates_out_of_place(texts["decode"], int(np.prod(tails))) == []
     assert "paged_decode_attention_gqa" in texts["decode"]
     assert "f32[64,24,128]" in texts["decode"]      # 20 heads run as 24
     # the copy slices a slot's rows out of the pool: no gather, and nothing
@@ -345,6 +438,14 @@ def test_mixer_moe_programs_compiled_for_v5e_move_no_pool(v5e_chip):
             for name, text in texts.items()} == {
         "decode": 0, "prefill": 0, "window": 0, "cow": 0, "state_copy": 0}
     assert "ssm_decode_update" in texts["decode"]
+    # each mixer's convolution hands the pool of tails on in place (PR 44:
+    # XLA's scatter into `[rows, 30720]` passed over the pool a mixer); at
+    # this cut's 80 slots the compiler stages the 9.8 MB pool in fast
+    # memory for the step, which it does not at the served 98 MB
+    tails = eng._scope.find_var("kv_cache.conv").shape
+    assert tails == (2 * slots, 240, 128)
+    assert texts["decode"].count("conv_decode_update.") >= 2
+    assert updates_out_of_place(texts["decode"], int(np.prod(tails))) == []
     assert "moe_relu2_experts_decode" in texts["decode"]
     assert "moe_relu2_experts_prefill" in texts["window"]
     assert "paged_decode_attention_gqa" in texts["decode"]

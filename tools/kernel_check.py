@@ -406,6 +406,37 @@ def _moe_relu2_cases(spec):
              lambda t=t: case(t)) for t in (128, 512)]
 
 
+def _conv_update_cases(spec):
+    """The two served tails (Falcon-H1: three rows of 5,120 channels;
+    Nemotron-H: of 10,240), a pool of 2 layers x 20 slots, 16 rows of a
+    decode step on slots of their own and 4 padding rows on the scratch
+    slot (whose tail nobody reads: left out of the comparison). The pool
+    and the convolved rows together."""
+
+    def case(C):
+        rows, K, B = 40, 4, 20
+        ks = jax.random.split(jax.random.PRNGKey(13), 5)
+        pool = _rand(ks[0], (rows, (K - 1) * C // 128, 128), "float32")
+        idx = jnp.concatenate([
+            20 + jax.random.permutation(ks[1], 19)[:16].astype(jnp.int32),
+            jnp.full((4,), 39, jnp.int32)])
+        x = _rand(ks[2], (B, C), "float32")
+        w = _rand(ks[3], (C, K), "float32", 0.5)
+        b = _rand(ks[4], (C,), "float32")
+        assert spec.supported(pool.shape, K)
+
+        def both(fn):
+            new_pool, y = fn(pool, idx, x, w, b)
+            return jnp.concatenate([new_pool[:39].reshape(-1),
+                                    y[:16].reshape(-1)])
+
+        return _compare(lambda: both(spec.fn), lambda: both(spec.reference),
+                        (), 0, "float32")
+
+    return [(f"b20 (16 live) c{C} k4 pool 2x20 float32",
+             lambda C=C: case(C)) for C in (5120, 10240)]
+
+
 def _paged_indexer_cases(spec):
     """The two serving geometries of the paged indexer kernel (DeepSeek-
     V3.2-Exp: 64 heads of 128; Keye-VL2: 16 of 64; 128-token pages of
@@ -483,6 +514,7 @@ CASES = {
     "latent_rows_attention": _latent_attend_cases,
     "indexer_paged_scores": _paged_indexer_cases,
     "ssm_decode_update": _ssm_update_cases,
+    "conv_decode_update": _conv_update_cases,
     "attention_paged_decode": lambda spec: (_paged_cases(spec)
                                             + _paged_gqa_cases(spec)),
     "moe_top1_experts": _moe_cases,

@@ -8,7 +8,10 @@ a `transpose`, a `bitcast-convert`. On the v5e such a copy of 24 pools took
 86% of the device's time in every decode and prefill step (PERF.md, PR 24).
 
 `pool_sized_copies(hlo_text, pool_elements)` finds them in an optimized HLO
-text; `token_row_gathers(hlo_text, row_elements)` lists the gathers that
+text; `updates_out_of_place(hlo_text, pool_elements)` names the in-place
+updates whose pool is read again after them (a rematerialised scatter ran
+twice a step until PR 44: a lead, which `main` prints and does not fail
+on); `token_row_gathers(hlo_text, row_elements)` lists the gathers that
 fetch one token's row at a time (a block that gathers tokens pays by the
 row: its decode program holds ONE a layer since PR 30);
 `sorts_over(hlo_text, row_elements)` lists the sorts of rows at least that
@@ -42,7 +45,8 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-__all__ = ["pool_sized_copies", "token_row_gathers", "sorts_over",
+__all__ = ["pool_sized_copies", "updates_out_of_place", "token_row_gathers",
+           "sorts_over",
            "serving_program_cases", "serving_program_hlos"]
 
 # `  %name = f32[3072,16,768]{2,1,0:T(8,128)} opcode(operands...), attrs`
@@ -125,6 +129,54 @@ def pool_sized_copies(hlo_text: str, pool_elements: int) -> list[dict]:
             "line": line.strip()[:400],
         })
     return found
+
+
+def updates_out_of_place(hlo_text: str, pool_elements: int) -> list[dict]:
+    """The updates of an optimized HLO text (a `scatter`, a `dynamic-update-
+    slice`, or a fusion that ends in one) that yield `pool_elements`
+    elements while the pool they update, their first pool-sized operand,
+    has another user LATER in the same computation: `[{"name", "op",
+    "shape", "pool", "read_again_by"}]`, in text order, which is the
+    schedule's in a module that says `is_scheduled=true`. The operand's
+    buffer is still needed, so the output cannot simply take it.
+    `pool_sized_copies` passes these, because it looks at the instruction
+    and not at its operand's other users. A lead, not a verdict (`main`
+    prints it and does not fail on it): it names the rematerialised
+    scatter of PR 43's decode program, which the chip ran a sixth time a
+    step, and also the rematerialised clones of a window's one-row
+    `dynamic-update-slice`, which the chip ran in 4-5 us each, in place
+    after all (PERF.md, PR 44)."""
+    fused = _fused_roots(hlo_text)
+    seen: list = []
+    live: list = []                 # the current computation's updates
+    pools: set = set()
+    current = None
+    for line in hlo_text.splitlines():
+        c = _COMPUTATION.match(line)
+        if c is not None:
+            current, pools, live = c["name"], set(), []
+            continue
+        m = _INSTR.match(line)
+        if m is None or current in fused:
+            continue
+        operands = [o["name"] for o in _OPERAND.finditer(
+            m["rest"].split(")", 1)[0])]
+        for update in live:
+            if not update["read_again_by"] and update["pool"] in operands:
+                update["read_again_by"] = m["name"]
+        if _elements(m["dims"]) != pool_elements:
+            continue
+        pools.add(m["name"])
+        called = _CALLS.search(line) if m["op"] == "fusion" else None
+        pool = next((o for o in operands if o in pools and o != m["name"]),
+                    None)
+        if pool is not None and (m["op"] in _UPDATES or (
+                called and fused.get(called["name"]) in _UPDATES)):
+            live.append({"name": m["name"], "op": m["op"],
+                         "shape": f"{m['dtype']}[{m['dims']}]",
+                         "pool": pool, "read_again_by": ""})
+            seen.append(live[-1])
+    return [update for update in seen if update["read_again_by"]]
 
 
 _GATHER = re.compile(
@@ -326,8 +378,9 @@ def main(argv=None) -> int:
             os.makedirs(a.dump, exist_ok=True)
             with open(os.path.join(a.dump, f"{name}.hlo.txt"), "w") as f:
                 f.write(text)
-        found = [c for n in sorted(set(pools.values()))
-                 for c in pool_sized_copies(text, n)]
+        sizes = sorted(set(pools.values()))
+        found = [c for n in sizes for c in pool_sized_copies(text, n)]
+        blocked = [u for n in sizes for u in updates_out_of_place(text, n)]
         bad += len(found)
         kinds: dict = {}
         for c in found:
@@ -337,7 +390,8 @@ def main(argv=None) -> int:
             "program": name, "pool_sized_copies": len(found),
             "compiled_for": "described v5e" if device else "this chip",
             "kinds": [{"op": k[0], "shape": k[1], "from": k[2], "to": k[3],
-                       "n": n} for k, n in kinds.items()]}), flush=True)
+                       "n": n} for k, n in kinds.items()],
+            "updates_out_of_place": blocked}), flush=True)
     return 1 if bad else 0
 
 
