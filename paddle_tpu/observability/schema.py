@@ -411,6 +411,12 @@ DECLARED: list[tuple] = [
      "the tail moved on in place in its slot (conv_decode_update); 0 on "
      "the XLA arm (over ssm.decode_layer_steps: how often the kernel "
      "engages)", ()),
+    ("serving.ssm.decode_pad_row_layers", COUNTER,
+     "padding row x layer pairs of the decode steps whose state update the "
+     "Pallas kernel ran (ssm_decode_update): rows of the step's bucket "
+     "that carry no request, whose grid steps move nothing; 0 on the XLA "
+     "arm (over itself + ssm.decode_row_layers: the share of a bucket's "
+     "traffic the kernel does not do)", ()),
     ("serving.ssm.scan_tokens", COUNTER,
      "real token x layer pairs that prefill windows scanned", ()),
     ("serving.ssm.scan_layer_steps", COUNTER,

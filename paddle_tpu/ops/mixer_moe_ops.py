@@ -179,6 +179,8 @@ def mixer_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm, norms,
         valid = (jnp.reshape(mask, (-1, 1)) > 0) if decode \
             else rel < lens[:, None]
         count = None if decode else lens
+        # a decode step's live rows come first (`engine._decode_once`)
+        n_live = jnp.sum(valid, dtype=jnp.int32) if decode else None
         slot = state_slot.astype(jnp.int32)                     # [B]
         # a window at position 0 starts a sequence: its state is zeros
         fresh = (first == 0) & (not decode)
@@ -200,7 +202,8 @@ def mixer_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm, norms,
             with piece("conv"):
                 if decode:
                     c_pool, xbc = conv_token_update_fn(
-                        c_pool, row, xbc[:, 0], p["conv_w"], p["conv_b"])
+                        c_pool, row, xbc[:, 0], p["conv_w"], p["conv_b"],
+                        n_live)
                     xbc = xbc[:, None]
                 elif paged:
                     c_pool, xbc = conv_window_update_fn(
@@ -217,7 +220,7 @@ def mixer_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm, norms,
                 with piece("ssm_update"):
                     s_pool, y = ssm_token_update_fn(
                         s_pool, row, xs_[:, 0], dt_raw[:, 0], bm[:, 0],
-                        cm[:, 0], p["dt_bias"], p["a_log"])
+                        cm[:, 0], p["dt_bias"], p["a_log"], n_live)
                     y = y[:, None]
             else:
                 with piece("ssm_scan"):

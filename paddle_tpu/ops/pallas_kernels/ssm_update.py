@@ -20,6 +20,20 @@ back: three passes. Here a grid step DMAs `_HEAD_BLOCK` heads of ONE slot
 them in VMEM and writes them back where they came from (`input_output_
 aliases`): the pool moves once each way.
 
+A step's rows are a ROW BUCKET: the first `n_live` of them carry a request,
+the rest are padding (`engine._decode_once`, `_state_feed`: on the scratch
+slot). `n_live` is a second prefetched scalar, and a padding row moves
+nothing: for its grid steps every index map but `y`'s names the blocks of
+the LAST LIVE row's last grid step (`_specs`), so the pipeline sees a block
+index that does not change and issues no DMA in and no write-back, and the
+body only writes the row's `y` as zeros. Before (PR 43 to 45) a padding row
+streamed the scratch slot's 4 MB in and out like a live one. On the chip a
+call at a bucket of 128 takes 0.14 ms + 11.8 us a LIVE row (1.65 ms at
+128, 1.32 at 100, 0.90 at 64; the parent 1.66 at each), and the Nemotron
+cell's decode program 22.5 / 20.6 / 18.1 ms a step (PERF.md, PR 46): what
+a server gains while its bucket is part filled. The scratch slot is never
+written here; nothing reads it.
+
 A slot's states are kept as ONE `[heads * N, P]` slab, head after head, the
 state dimension on the sublanes (a pool of three dimensions: a gather or a
 scatter of whole slots, which a prefill window does, then has no pair of
@@ -81,22 +95,68 @@ def update_supported(pool_shape, state: int, heads_per_group: int) -> bool:
             and heads_per_group % _HEAD_BLOCK == 0)
 
 
-def _kernel(idx_ref, s_ref, a_ref, dtx_ref, b_ref, c_ref, so_ref, y_ref):
+def live_count(n_live, rows: int):
+    """`n_live` (None: every row) as the int32 [1] both arms take, held to
+    1 .. rows: a step with no live row treats row 0 as one."""
+    n = rows if n_live is None else n_live
+    return jnp.clip(jnp.asarray(n, jnp.int32), 1, rows).reshape(1)
+
+
+def _kernel(idx_ref, n_ref, s_ref, a_ref, dtx_ref, b_ref, c_ref, so_ref,
+            y_ref):
     del idx_ref                        # read by the index maps
-    N, P = b_ref.shape[3], s_ref.shape[2]
-    # B and C, a value a sublane, on every lane
-    bcol = jnp.broadcast_to(b_ref[0, 0], (P, N)).T              # [N, P]
-    ccol = jnp.broadcast_to(c_ref[0, 0], (P, N)).T
-    for h in range(s_ref.shape[1] // N):
-        rows = slice(h * N, (h + 1) * N)
-        s_new = a_ref[0, h:h + 1, :] * s_ref[0, rows] \
-            + bcol * dtx_ref[0, h:h + 1, :]
-        so_ref[0, rows] = s_new
-        y_ref[0, h:h + 1, :] = jnp.sum(s_new * ccol, axis=0, keepdims=True)
+    live = pl.program_id(0) < n_ref[0]
+
+    @pl.when(live)
+    def _update():
+        N, P = b_ref.shape[3], s_ref.shape[2]
+        # B and C, a value a sublane, on every lane
+        bcol = jnp.broadcast_to(b_ref[0, 0], (P, N)).T          # [N, P]
+        ccol = jnp.broadcast_to(c_ref[0, 0], (P, N)).T
+        for h in range(s_ref.shape[1] // N):
+            rows = slice(h * N, (h + 1) * N)
+            s_new = a_ref[0, h:h + 1, :] * s_ref[0, rows] \
+                + bcol * dtx_ref[0, h:h + 1, :]
+            so_ref[0, rows] = s_new
+            y_ref[0, h:h + 1, :] = jnp.sum(s_new * ccol, axis=0,
+                                           keepdims=True)
+
+    @pl.when(jnp.logical_not(live))
+    def _padding():
+        # the state blocks in VMEM are the last live row's: left alone
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+def _specs(blocks: int, hb: int, N: int, P: int, per_group: int):
+    """The BlockSpecs of a grid `(rows, blocks)`: (state, head_rows, group,
+    y_rows). A grid step `(b, j)` of a live row names its own blocks. One
+    of a padding row (`b >= n[0]`) names, for the state, `a`, `dtx`, `B`
+    and `C`, the blocks of grid step `(n[0] - 1, blocks - 1)`, the last one
+    that did any work: a block index that repeats moves nothing. `y_rows`
+    (the output `y`) is every row's own."""
+
+    def at(b, j, n):
+        return jnp.minimum(b, n[0] - 1), jnp.where(b < n[0], j, blocks - 1)
+
+    def state(b, j, idx, n):
+        b, j = at(b, j, n)
+        return idx[b], j, 0
+
+    def head_rows(b, j, idx, n):
+        return (*at(b, j, n), 0)
+
+    def group(b, j, idx, n):
+        b, j = at(b, j, n)
+        return b, j * hb // per_group, 0, 0
+
+    return (pl.BlockSpec((1, hb * N, P), state),
+            pl.BlockSpec((1, hb, P), head_rows),
+            pl.BlockSpec((1, 1, 1, N), group),
+            pl.BlockSpec((1, hb, P), lambda b, j, idx, n: (b, j, 0)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _call(pool, idx, a, dtx, bmat, cmat, interpret):
+def _call(pool, idx, n_live, a, dtx, bmat, cmat, interpret):
     rows, HN, P = pool.shape
     B, G, N = bmat.shape
     H = HN // N                 # slabs: heads, or packs of narrow heads
@@ -105,23 +165,20 @@ def _call(pool, idx, a, dtx, bmat, cmat, interpret):
     per_group = H // G
     lanes = jnp.broadcast_to(a.astype(jnp.float32)[:, :, None],
                              (B, H * pack, P // pack)).reshape(B, H, P)
-    head_rows = pl.BlockSpec((1, hb, P), lambda b, j, idx: (b, j, 0))
-    state = pl.BlockSpec((1, hb * N, P), lambda b, j, idx: (idx[b], j, 0))
-    group = pl.BlockSpec((1, 1, 1, N),
-                         lambda b, j, idx: (b, j * hb // per_group, 0, 0))
+    state, head_rows, group, y_rows = _specs(H // hb, hb, N, P, per_group)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, H // hb),
         in_specs=[state, head_rows, head_rows, group, group],
-        out_specs=[state, head_rows],
+        out_specs=[state, y_rows],
     )
     new_pool, y = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                    jax.ShapeDtypeStruct((B, H, P), jnp.float32)],
-        # operand 0 is the prefetched slot list
-        input_output_aliases={1: 0},
+        # operands 0 and 1 are the prefetched slot list and live count
+        input_output_aliases={2: 0},
         cost_estimate=pl.CostEstimate(
             flops=5 * B * H * N * P, transcendentals=0,
             bytes_accessed=2 * B * H * N * P * 4),
@@ -129,7 +186,8 @@ def _call(pool, idx, a, dtx, bmat, cmat, interpret):
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="ssm_decode_update",
-    )(jnp.clip(idx.astype(jnp.int32), 0, rows - 1), pool, lanes,
+    )(jnp.clip(idx.astype(jnp.int32), 0, rows - 1), live_count(n_live, B),
+      pool, lanes,
       dtx.astype(jnp.float32).reshape(B, H, P),
       bmat.astype(jnp.float32).reshape(B, G, 1, N),
       cmat.astype(jnp.float32).reshape(B, G, 1, N))
@@ -163,13 +221,14 @@ def unpack_state(slots, heads: int, state: int):
                      axis=2).reshape(B, heads, state, P)
 
 
-def _reference(pool, idx, a, dtx, bmat, cmat):
+def _reference(pool, idx, a, dtx, bmat, cmat, n_live=None):
     """The same update in plain jnp (the numeric oracle and the path off
-    the chip): the rows' states gathered, updated and scattered back. Rows
-    that name the same slot (a step's padding rows, all on the scratch
-    slot) leave one of their updates there."""
+    the chip): the rows' states gathered, updated and scattered back. As
+    in the kernel, only the first `n_live` rows (None: all) are live: a
+    padding row's slot is left as it was and its `y` is zeros."""
     (B, G, N), H = bmat.shape, a.shape[1]
     pack = H * N // pool.shape[1]
+    live = jnp.arange(B) < live_count(n_live, B)
     idx = jnp.clip(idx.astype(jnp.int32), 0, pool.shape[0] - 1)
     bh = jnp.repeat(bmat.astype(jnp.float32), H // G, axis=1)   # [B, H, N]
     ch = jnp.repeat(cmat.astype(jnp.float32), H // G, axis=1)
@@ -177,7 +236,10 @@ def _reference(pool, idx, a, dtx, bmat, cmat):
         * unpack_state(pool[idx], H, N) \
         + bh[:, :, :, None] * dtx.astype(jnp.float32)[:, :, None, :]
     y = jnp.sum(s_new * ch[:, :, :, None], axis=2)
-    return pool.at[idx].set(pack_state(s_new, pack)), y
+    # a padding row is sent past the pool's end, where the scatter drops it
+    return pool.at[jnp.where(live, idx, pool.shape[0])].set(
+        pack_state(s_new, pack), mode="drop"), \
+        jnp.where(live[:, None, None], y, 0.0)
 
 
 def _workbench_register():
@@ -195,10 +257,12 @@ def _workbench_register():
 
 
 @_workbench_register()
-def ssm_decode_update(pool, idx, a, dtx, bmat, cmat):
+def ssm_decode_update(pool, idx, a, dtx, bmat, cmat, n_live=None):
     """pool `[rows, H * N, P]` float32 (or, heads narrower than the lanes,
     `[rows, H / pack * N, pack * P]`: the module docstring), idx [B] (the
     row of each decode row's state), a [B, H] (decay), dtx [B, H, P], bmat
-    and cmat [B, G, N]. Returns (the pool with rows `idx` updated, y [B, H,
-    P] float32). Callers gate on `update_supported`."""
-    return _call(pool, idx, a, dtx, bmat, cmat, bool(INTERPRET))
+    and cmat [B, G, N], n_live (an int32 scalar, traced or not; None: B)
+    the count of live rows, which come first. Returns (the pool with the
+    live rows' slots updated, y [B, H, P] float32, zeros in a padding
+    row). Callers gate on `update_supported`."""
+    return _call(pool, idx, n_live, a, dtx, bmat, cmat, bool(INTERPRET))

@@ -49,8 +49,10 @@ where they are whole (`kv_cache.state_pool_shapes`; `[L * slots, (ssm_conv
 - 1) * channels]` otherwise). A row's
 slot is a feed (`sv_sslot`); a window reads it as its initial state (zeros
 where the window starts at position 0) and leaves its final state there, a
-decode step updates both in place (`ssm_token_update`, `conv_token_update`);
-padding rows are given a scratch slot.
+decode step updates both in place (`ssm_token_update`, `conv_token_update`)
+for its live rows, which come first and are counted from the row mask;
+padding rows are given a scratch slot, which a window's padding writes and
+a decode step leaves alone.
 
 Precision: matmul operands in the weights' dtype (bfloat16 as served),
 float32 accumulation; residual stream, norms, the convolution and its tail,
@@ -141,18 +143,19 @@ def conv_update_runs(pool_shape, taps: int) -> bool:
             and conv_update.update_supported(tuple(pool_shape), int(taps)))
 
 
-def conv_token_update_fn(c_pool, idx, xbc, conv_w, conv_b):
+def conv_token_update_fn(c_pool, idx, xbc, conv_w, conv_b, n_live=None):
     """One token a row, in place: c_pool `[rows, (K - 1) * C / 128, 128]`
     (or `[rows, (K - 1) * C]`: `kv_cache.state_pool_shapes`), idx [B] (each
-    row's slot in this layer), xbc [B, C], conv_w [C, K], conv_b [C] ->
-    (the pool with the tails of rows `idx` moved on one token, silu(conv)
-    [B, C])."""
+    row's slot in this layer), xbc [B, C], conv_w [C, K], conv_b [C],
+    n_live (int32 scalar; None: B) the count of live rows, which come
+    first -> (the pool with the live rows' tails moved on one token,
+    silu(conv) [B, C], zeros in a padding row)."""
     from .pallas_kernels import conv_update
 
-    if conv_update_runs(c_pool.shape, conv_w.shape[1]):
-        return conv_update.conv_decode_update(c_pool, idx, xbc, conv_w,
-                                              conv_b)
-    return conv_update._reference(c_pool, idx, xbc, conv_w, conv_b)
+    update = conv_update.conv_decode_update \
+        if conv_update_runs(c_pool.shape, conv_w.shape[1]) \
+        else conv_update._reference
+    return update(c_pool, idx, xbc, conv_w, conv_b, n_live)
 
 
 def conv_window_update_fn(c_pool, idx, xbc, conv_w, conv_b, fresh, lens):
@@ -272,22 +275,35 @@ def _update_backend(rows: int, pool_shape, state: int,
     return backend if backend == "xla" or runnable() else "xla"
 
 
-def ssm_token_update_fn(s_pool, idx, x, dt_raw, bmat, cmat, dt_bias, a_log):
+def ssm_update_runs(rows: int, pool_shape, heads: int, head_dim: int,
+                    groups: int, state: int) -> bool:
+    """Whether a decode step of `rows` rows updates its states through
+    `pallas_kernels.ssm_update`, in place in the pool `pool_shape` (XLA's
+    gather, update and scatter otherwise). The engine books
+    `serving.ssm.decode_pad_row_layers` by the same answer."""
+    pack = pool_shape[2] // head_dim
+    return _update_backend(int(rows), tuple(pool_shape), int(state),
+                           heads // groups // pack) == "pallas"
+
+
+def ssm_token_update_fn(s_pool, idx, x, dt_raw, bmat, cmat, dt_bias, a_log,
+                        n_live=None):
     """One token a row, in place: s_pool [rows, H * N, P] (heads narrower
     than the lanes: `pack` of them side by side, [rows, H / pack * N, pack *
     P]; `pallas_kernels.ssm_update`), idx [B] (each row's slot in this
-    layer), x [B, H, P], dt_raw [B, H], bmat/cmat [B, G, N] -> (the pool
-    with rows `idx` updated, y [B, H, P] without the skip term)."""
+    layer), x [B, H, P], dt_raw [B, H], bmat/cmat [B, G, N], n_live (int32
+    scalar; None: B) the count of live rows, which come first -> (the pool
+    with the live rows' slots updated, y [B, H, P] without the skip term,
+    zeros in a padding row)."""
     from .pallas_kernels import ssm_update
 
     la, dtx = _decay_and_input(x, dt_raw, dt_bias, a_log)
+    B, H, P = x.shape
     G, N = bmat.shape[1:]
-    pack = s_pool.shape[2] // x.shape[2]
-    if _update_backend(x.shape[0], s_pool.shape, N, x.shape[1] // G // pack) \
-            == "pallas":
-        return ssm_update.ssm_decode_update(s_pool, idx, jnp.exp(la), dtx,
-                                            bmat, cmat)
-    return ssm_update._reference(s_pool, idx, jnp.exp(la), dtx, bmat, cmat)
+    update = ssm_update.ssm_decode_update \
+        if ssm_update_runs(B, s_pool.shape, H, P, G, N) \
+        else ssm_update._reference
+    return update(s_pool, idx, jnp.exp(la), dtx, bmat, cmat, n_live)
 
 
 def _mm(x, w):
@@ -355,6 +371,8 @@ def parallel_ssm_stack_fn(mode: str, tok, pos, emb, head, final_norm,
         valid = (jnp.reshape(mask, (-1, 1)) > 0) if decode \
             else rel < lens[:, None]
         count = None if decode else lens
+        # a decode step's live rows come first (`engine._decode_once`)
+        n_live = jnp.sum(valid, dtype=jnp.int32) if decode else None
         slot = state_slot.astype(jnp.int32)                     # [B]
         # a window at position 0 starts a sequence: its state is zeros
         fresh = (first == 0) & (not decode)
@@ -382,7 +400,8 @@ def parallel_ssm_stack_fn(mode: str, tok, pos, emb, head, final_norm,
         with piece("conv"):
             if decode:
                 c_pool, xbc = conv_token_update_fn(
-                    c_pool, row, xbc[:, 0], p["conv_w"], p["conv_b"])
+                    c_pool, row, xbc[:, 0], p["conv_w"], p["conv_b"],
+                    n_live)
                 xbc = xbc[:, None]
             elif paged:
                 c_pool, xbc = conv_window_update_fn(
@@ -397,7 +416,7 @@ def parallel_ssm_stack_fn(mode: str, tok, pos, emb, head, final_norm,
             with piece("ssm_update"):
                 s_pool, y = ssm_token_update_fn(
                     s_pool, row, xs_[:, 0], dt_raw[:, 0], bm[:, 0], cm[:, 0],
-                    p["dt_bias"], p["a_log"])
+                    p["dt_bias"], p["a_log"], n_live)
                 y = y[:, None]
         else:
             with piece("ssm_scan"):

@@ -745,6 +745,7 @@ class ServingEngine:
         self._indexer_kernel_runs: bool | None = None
         self._attend_kernel_runs: dict[tuple[int, int], bool] = {}
         self._conv_kernel_runs: bool | None = None
+        self._ssm_kernel_runs: dict[int, bool] = {}
         self._prefill_run = self._exec_target(self._prefill_prog)
         self._decode_run = self._exec_target(self._decode_prog)
         self._window_run = self._exec_target(self._window_prog)
@@ -800,6 +801,7 @@ class ServingEngine:
             "state.snapshots": 0, "state.snapshot_evictions": 0,
             "ssm.decode_row_layers": 0, "ssm.decode_layer_steps": 0,
             "ssm.conv_kernel_layer_steps": 0,
+            "ssm.decode_pad_row_layers": 0,
             "ssm.scan_tokens": 0, "ssm.scan_layer_steps": 0,
             "peak_state_slots_in_use": 0,
         }
@@ -2568,6 +2570,20 @@ class ServingEngine:
                 self.cfg.ssm_conv)
         return self._conv_kernel_runs
 
+    def _ssm_kernel(self, bb: int) -> bool:
+        """Whether the Pallas kernel updates the states of a decode step of
+        `bb` rows in place (XLA's gather, update and scatter otherwise):
+        the ops' own answer, asked once a row bucket."""
+        runs = self._ssm_kernel_runs.get(bb)
+        if runs is None:
+            cfg = self.cfg
+            runs = self._ssm_kernel_runs[bb] = \
+                parallel_ssm_ops.ssm_update_runs(
+                    bb, self._scope.find_var(STATE_POOLS[0]).shape,
+                    cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                    cfg.ssm_state)
+        return runs
+
     def _attend_kernel(self, bb: int, pb: int) -> bool:
         """Whether the Pallas kernel computes the absorbed attention of a
         decode step of `bb` rows behind `pb` pages (`absorbed_attention_fn`
@@ -2649,6 +2665,9 @@ class ServingEngine:
             self._count("ssm.decode_layer_steps", self.cfg.state_layers)
             self._count("ssm.conv_kernel_layer_steps",
                         self.cfg.state_layers if self._conv_kernel() else 0)
+            self._count("ssm.decode_pad_row_layers",
+                        (bb - len(rows)) * self.cfg.state_layers
+                        if self._ssm_kernel(bb) else 0)
         if self.cfg.selects_within(pb * ps):
             L, k = self.cfg.num_layers, self.cfg.index_topk
             self._count("sparse.context_tokens",

@@ -166,8 +166,8 @@ WBASE_FEED = "sv_wbase"
 COW_WSRC_FEED = "sv_cow_wsrc"   # copy-on-write in the sliding layers' pool
 COW_WDST_FEED = "sv_cow_wdst"
 # "parallel_ssm", "mixer_moe": each row's slot in the pools of recurrent
-# state (padding rows name a scratch slot nobody reads), and the state copy's
-# two slots
+# state (padding rows name a scratch slot nobody reads; only a window's
+# padding writes it), and the state copy's two slots
 SSLOT_FEED = "sv_sslot"
 SCOPY_SRC_FEED = "sv_scopy_src"
 SCOPY_DST_FEED = "sv_scopy_dst"

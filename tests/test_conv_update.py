@@ -110,6 +110,56 @@ def test_conv_decode_update_pallas_matches_reference(tail, taps,
     np.testing.assert_array_equal(p1[untouched], pool[untouched])
 
 
+@pytest.mark.parametrize("n_live", [0, 1, 3, 6])
+def test_conv_decode_update_moves_a_steps_live_rows_only(n_live,
+                                                         interpreted):
+    """`n_live` of a bucket's 6 rows carry a request (1, B - 3, B; 0, the
+    warm-up's step, is held to 1): the kernel, through the interpreter, and
+    the plain form leave the same WHOLE pool and the same `y`; the live
+    rows' are what a step of them alone leaves; the scratch slot and every
+    slot nobody named keep their bytes; a padding row's `y` is zeros."""
+    C, B, live_rows = 2048, 6, max(n_live, 1)
+    ks = jax.random.split(jax.random.PRNGKey(46), 4)
+    pool = jax.random.normal(ks[0], (12, (K - 1) * C // 128, 128))
+    idx = jnp.where(jnp.arange(B) < live_rows,
+                    jnp.asarray([3, 7, 1, 5, 9, 2]), 11).astype(jnp.int32)
+    x = jax.random.normal(ks[1], (B, C))
+    w, b = jax.random.normal(ks[2], (C, K)), jax.random.normal(ks[3], (C,))
+    p1, y1 = conv_update.conv_decode_update(pool, idx, x, w, b,
+                                            n_live=jnp.int32(n_live))
+    p2, y2 = conv_update._reference(pool, idx, x, w, b, n_live=n_live)
+    np.testing.assert_array_equal(p1, p2)
+    np.testing.assert_allclose(y1, y2, rtol=1e-5, atol=1e-5)
+    assert not np.any(np.asarray(y1[live_rows:]))
+    assert not np.any(np.asarray(y2[live_rows:]))
+    live = np.asarray(idx[:live_rows])
+    other = np.setdiff1d(np.arange(12), live)           # the scratch slot too
+    np.testing.assert_array_equal(p1[other], pool[other])
+    p3, y3 = conv_update.conv_decode_update(pool, idx[:live_rows],
+                                            x[:live_rows], w, b)
+    np.testing.assert_array_equal(p1, p3)
+    np.testing.assert_array_equal(y1[:live_rows], y3)
+
+
+@pytest.mark.parametrize("n_live", [1, 5, 8])
+def test_a_padding_rows_grid_step_names_the_last_live_rows_blocks(n_live):
+    """For every row `b >= n_live` the index maps of the slot (in and out)
+    and of the token's row return the last live row's blocks, so the
+    pipeline sees a block index that repeats and moves nothing (a CPU
+    cannot show the DMA going away; this pins what makes it go); `y` is
+    every row's own."""
+    idx = np.asarray([13, 2, 7, 4, 9, 0, 5, 11], np.int32)
+    n = np.asarray([n_live], np.int32)
+    slot, token, y_row = conv_update._specs(24, 8, 128)
+    for b in range(8):
+        src = min(b, n_live - 1)
+        assert tuple(int(v) for v in slot.index_map(b, idx, n)) \
+            == (int(idx[src]), 0, 0)
+        assert tuple(int(v) for v in token.index_map(b, idx, n)) \
+            == (src, 0, 0)
+        assert tuple(int(v) for v in y_row.index_map(b, idx, n)) == (b, 0, 0)
+
+
 def test_the_shape_gate_takes_whole_tiles_only():
     assert conv_update.update_supported((800, 240, 128), 4)
     assert conv_update.update_supported((480, 120, 128), 4)

@@ -26,6 +26,7 @@ from paddle_tpu.ops.pallas_kernels import ssm_update  # noqa: E402
 from paddle_tpu.serving import DecoderConfig, ServingEngine  # noqa: E402
 from paddle_tpu.serving import model as sv_model  # noqa: E402
 from paddle_tpu.serving.model import mixer_moe_tiny  # noqa: E402
+from test_serving_ssm import check_five_of_eight  # noqa: E402
 from tools import mixer_faults  # noqa: E402
 
 TOL = 1e-3          # the rehearsal configuration's tolerances
@@ -369,7 +370,7 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
 def test_ssm_decode_update_packed_heads_pallas_matches_reference(monkeypatch):
     """The decode update at heads NARROWER than the state (64 under 128),
     two of a group side by side on the lanes: pool and y against the plain
-    form; padding rows share the scratch slot, which nobody reads."""
+    form; every row live, two of them on one slot, which is left out."""
     monkeypatch.setattr(ssm_update, "INTERPRET", True)
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     H, N, P, G = 32, 128, 64, 2
@@ -398,6 +399,18 @@ def test_ssm_decode_update_packed_heads_pallas_matches_reference(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(ssm_update.pack_state(p3.reshape(12, H, N, P), 2)),
         np.asarray(p2), atol=1e-5)
+
+
+@pytest.mark.parametrize("arm", ["kernel", "xla"])
+def test_five_rows_in_a_bucket_of_eight_serve_what_eight_of_eight_do(
+        arm, monkeypatch):
+    """At packed heads (64 of 64 under a state of 128, two side by side)
+    and a tail of whole tiles, so that both decode kernels take the step:
+    `test_serving_ssm.check_five_of_eight`."""
+    cfg = mixer_moe_tiny(ssm_heads=64, ssm_head_dim=64, ssm_groups=4,
+                         ssm_state=128)
+    check_five_of_eight(lambda **kw: _engine(cfg, **kw), arm == "kernel",
+                        monkeypatch, _assert_right)
 
 
 @pytest.mark.parametrize("heads,per_group,head_dim,pack", [

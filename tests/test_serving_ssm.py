@@ -19,6 +19,7 @@ if ROOT not in sys.path:
 from benchmark.reference import falcon_h1_lm as ref  # noqa: E402
 from paddle_tpu.ops import attention_ops  # noqa: E402
 from paddle_tpu.ops import parallel_ssm_ops as ops  # noqa: E402
+from paddle_tpu.ops.pallas_kernels import conv_update  # noqa: E402
 from paddle_tpu.ops.pallas_kernels import paged_attention as ppa  # noqa: E402
 from paddle_tpu.ops.pallas_kernels import ssm_update  # noqa: E402
 from paddle_tpu.serving import DecoderConfig, ServingEngine  # noqa: E402
@@ -173,8 +174,8 @@ def test_a_padded_window_leaves_the_state_of_its_last_real_token(real):
 
 def test_ssm_decode_update_pallas_matches_reference(monkeypatch):
     """The decode update's kernel, through the interpreter, at the served
-    state (256 x 128 a head): pool and y against the plain form; padding
-    rows share the scratch slot, which nobody reads."""
+    state (256 x 128 a head): pool and y against the plain form; every
+    row live, two of them on one slot, which is left out."""
     monkeypatch.setattr(ssm_update, "INTERPRET", True)
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     H, N, P, G = 16, 256, 128, 2
@@ -194,6 +195,168 @@ def test_ssm_decode_update_pallas_matches_reference(monkeypatch):
     # the rows nobody named are untouched
     assert jnp.array_equal(p1[jnp.asarray([0, 2, 4, 5, 6, 8, 9, 10])],
                            pool[jnp.asarray([0, 2, 4, 5, 6, 8, 9, 10])])
+
+
+def _bucket(pack, n_live, B=6):
+    """A decode step's operands at a bucket of `B` rows, the first `n_live`
+    on slots of their own and the padding on the scratch slot 11; heads of
+    128 lanes (`pack` 1) or two of 64 side by side (`pack` 2)."""
+    ks = jax.random.split(jax.random.PRNGKey(46), 6)
+    H, N, P, G = 16 * pack, 128, 128 // pack, 2
+    pool = jax.random.normal(ks[0], (12, H // pack * N, pack * P))
+    idx = jnp.where(jnp.arange(B) < n_live,
+                    jnp.asarray([3, 7, 1, 5, 9, 2]), 11).astype(jnp.int32)
+    a = jax.nn.sigmoid(jax.random.normal(ks[1], (B, H)))
+    dtx = jax.random.normal(ks[2], (B, H, P))
+    bm = jax.random.normal(ks[3], (B, G, N))
+    cm = jax.random.normal(ks[4], (B, G, N))
+    assert ssm_update.update_supported(pool.shape, N, H // G // pack)
+    return pool, idx, a, dtx, bm, cm
+
+
+@pytest.mark.parametrize("pack", [1, 2])
+@pytest.mark.parametrize("n_live", [1, 3, 6])
+def test_ssm_decode_update_moves_a_steps_live_rows_only(n_live, pack,
+                                                        monkeypatch):
+    """`n_live` of a bucket's 6 rows carry a request (1, B - 3, B). On each
+    arm (the kernel through the interpreter, the plain form) the live rows'
+    slots and `y` are, bit for bit, what a step of the live rows ALONE
+    leaves on that arm; the scratch slot and every slot nobody named keep
+    their bytes; a padding row's `y` is zeros. The two arms agree on the
+    WHOLE pool and the whole `y` to rounding (the kernel contracts `a * S
+    + B * dtx` as the plain form does not)."""
+    monkeypatch.setattr(ssm_update, "INTERPRET", True)
+    pool, idx, *rest = _bucket(pack, n_live)
+    live = np.asarray(idx[:n_live])
+    other = np.setdiff1d(np.arange(12), live)           # the scratch slot too
+    arms = []
+    for update in (ssm_update.ssm_decode_update, ssm_update._reference):
+        p1, y1 = update(pool, idx, *rest, n_live=n_live)
+        alone, y_alone = update(pool, idx[:n_live],
+                                *(r[:n_live] for r in rest))
+        np.testing.assert_array_equal(p1, alone)
+        np.testing.assert_array_equal(y1[:n_live], y_alone)
+        assert not np.any(np.asarray(y1[n_live:]))
+        np.testing.assert_array_equal(p1[other], pool[other])
+        assert not np.any(np.all(np.asarray(p1[live] == pool[live]),
+                                 axis=(1, 2)))
+        arms.append((p1, y1))
+    (p1, y1), (p2, y2) = arms
+    np.testing.assert_allclose(p1, p2, atol=1e-5)
+    np.testing.assert_allclose(y1, y2, rtol=1e-6,
+                               atol=1e-6 * float(jnp.max(jnp.abs(y2))) + 1e-4)
+
+
+@pytest.mark.parametrize("pack", [1, 2])
+def test_ssm_decode_update_with_no_live_row_is_a_step_of_one(pack,
+                                                             monkeypatch):
+    """A count of 0 (the warm-up's step) is held to 1: nothing faults, and
+    row 0 is updated on both arms."""
+    monkeypatch.setattr(ssm_update, "INTERPRET", True)
+    pool, idx, *rest = _bucket(pack, 1)
+    for update in (ssm_update.ssm_decode_update, ssm_update._reference):
+        p0, y0 = update(pool, idx, *rest, n_live=jnp.int32(0))
+        p1, y1 = update(pool, idx, *rest, n_live=1)
+        np.testing.assert_array_equal(p0, p1)
+        np.testing.assert_array_equal(y0, y1)
+
+
+@pytest.mark.parametrize("n_live", [1, 5, 8])
+def test_a_padding_rows_grid_steps_name_the_last_live_steps_blocks(n_live):
+    """What takes the padding rows' traffic away cannot be seen on a CPU;
+    this pins it: for every grid step of a row `b >= n_live`, the index
+    maps of the state (in and out), `a`, `dtx`, `B` and `C` return the
+    blocks of grid step `(n_live - 1, blocks - 1)`, so the pipeline sees a
+    block index that repeats; a live row's steps name their own, and `y`
+    is every row's own."""
+    B, blocks, hb, per_group = 8, 4, 8, 16
+    idx = np.asarray([13, 2, 7, 4, 9, 0, 5, 11], np.int32)
+    n = np.asarray([n_live], np.int32)
+    specs = ssm_update._specs(blocks, hb, 128, 128, per_group)
+
+    def block(spec, b, j):
+        return tuple(int(v) for v in spec.index_map(b, j, idx, n))
+
+    *moved, y_rows = specs
+    for b in range(B):
+        for j in range(blocks):
+            src = (b, j) if b < n_live else (n_live - 1, blocks - 1)
+            assert block(y_rows, b, j) == (b, j, 0)
+            for spec in moved:
+                assert block(spec, b, j) == block(spec, *src)
+            state, head_rows, group = (block(s, b, j) for s in moved)
+            assert state == (int(idx[src[0]]), src[1], 0)
+            assert head_rows == (src[0], src[1], 0)
+            assert group == (src[0], src[1] * hb // per_group, 0, 0)
+
+
+def lockstep(engine, n, kernel, monkeypatch):
+    """`n` of eight fixed prompts through `engine(max_inflight=8,
+    prefix_cache=False)`, decoding in lockstep (one bucket of 8 every
+    step), the two decode kernels through the interpreter or not: (the
+    engine, the prompts, the requests, each request's slots of the two
+    state pools in every layer as they lie after the run). Shared with
+    `test_serving_mixer_moe.py`."""
+    monkeypatch.setattr(ssm_update, "INTERPRET", kernel)
+    monkeypatch.setattr(conv_update, "INTERPRET", kernel)
+    eng = engine(max_inflight=8, prefix_cache=False)
+    cfg = eng.cfg
+    prompts = _prompts([6] * 8, seed=46)[:n]
+    rids = [eng.submit(p, 4) for p in prompts]
+    slots = {}
+    while eng.has_work():
+        eng.step()
+        for r in rids:
+            if eng.requests[r].sslot is not None:
+                slots.setdefault(r, eng.requests[r].sslot)
+    assert eng.stats["ssm.decode_row_layers"] \
+        == n * cfg.state_layers * eng.stats["decode_steps"] > 0
+    per_layer = eng._scope.find_var("kv_cache.ssm").shape[0] \
+        // cfg.state_layers
+    rows = np.asarray([[l * per_layer + slots[r] for l in
+                        range(cfg.state_layers)] for r in rids])
+    states = [np.asarray(eng._scope.find_var(name))[rows]
+              for name in ("kv_cache.ssm", "kv_cache.conv")]
+    return eng, prompts, [eng.requests[r] for r in rids], states
+
+
+def check_five_of_eight(engine, kernel, monkeypatch, right):
+    """Five rows decode in a bucket of 8 (three padding rows a step): they
+    are `right(eng, prompts, requests)` by the family's reference, emit the
+    tokens the other arm emits and the tokens they emit as five of a FULL
+    bucket, and leave their slots of both state pools as the full bucket's
+    run does, bit for bit. The padding rows' share is booked on the kernel
+    arm only."""
+    eng, prompts, done, states = lockstep(engine, 5, kernel, monkeypatch)
+    right(eng, prompts, done)
+    layer_steps = eng.cfg.state_layers * eng.stats["decode_steps"]
+    assert eng.stats["ssm.decode_pad_row_layers"] \
+        == (3 * layer_steps if kernel else 0)
+    assert eng.stats["ssm.conv_kernel_layer_steps"] \
+        == (layer_steps if kernel else 0)
+    full, _, done8, states8 = lockstep(engine, 8, kernel, monkeypatch)
+    assert full.stats["ssm.decode_pad_row_layers"] == 0
+    toks = [list(r.out_tokens) for r in done]
+    assert toks == [list(r.out_tokens) for r in done8[:5]]
+    for mine, theirs in zip(states, states8):
+        np.testing.assert_array_equal(mine, theirs[:5])
+    if kernel:
+        assert toks == [list(r.out_tokens) for r in
+                        lockstep(engine, 5, False, monkeypatch)[2]]
+
+
+@pytest.mark.parametrize("arm", ["kernel", "xla"])
+def test_five_rows_in_a_bucket_of_eight_serve_what_eight_of_eight_do(
+        arm, monkeypatch):
+    cfg = parallel_ssm_tiny(ssm_heads=16, ssm_head_dim=128, ssm_groups=2,
+                            ssm_state=256)
+
+    def right(eng, prompts, done):
+        assert max(_gaps(eng, prompts, [list(r.out_tokens)
+                                        for r in done])) <= TOL
+
+    check_five_of_eight(lambda **kw: _engine(cfg, **kw), arm == "kernel",
+                        monkeypatch, right)
 
 
 @pytest.mark.parametrize("nh,nkv,padded", [(20, 4, 24), (8, 2, 8),

@@ -326,9 +326,9 @@ def _moe_cases(spec):
 
 def _ssm_update_cases(spec):
     """The "parallel_ssm" geometry: 32 heads of a 256 x 128 float32 state in
-    2 groups, a pool of 2 layers x 20 slots, 16 rows of a decode step on
-    slots of their own and 4 padding rows on the scratch slot (whose state
-    nobody reads: left out of the comparison). The pool and y together."""
+    2 groups, a pool of 2 layers x 20 slots, a decode step's bucket of 20
+    rows: 16 live on slots of their own and 4 padding rows on the scratch
+    slot, which stays as it was. The WHOLE pool and y together."""
 
     def case():
         rows, H, N, P, G, B = 40, 32, 256, 128, 2, 20
@@ -344,9 +344,8 @@ def _ssm_update_cases(spec):
         assert spec.supported(pool.shape, N, H // G)
 
         def both(fn):
-            new_pool, y = fn(pool, idx, a, dtx, bm, cm)
-            return jnp.concatenate([new_pool[:39].reshape(-1),
-                                    y[:16].reshape(-1)])
+            new_pool, y = fn(pool, idx, a, dtx, bm, cm, n_live=16)
+            return jnp.concatenate([new_pool.reshape(-1), y.reshape(-1)])
 
         return _compare(lambda: both(spec.fn), lambda: both(spec.reference),
                         (), 0, "float32")
@@ -369,9 +368,8 @@ def _ssm_update_cases(spec):
         assert not spec.supported((rows, H * N, P), N, H // G)
 
         def both(fn):
-            new_pool, y = fn(pool, idx, a, dtx, bm, cm)
-            return jnp.concatenate([new_pool[:39].reshape(-1),
-                                    y[:16].reshape(-1)])
+            new_pool, y = fn(pool, idx, a, dtx, bm, cm, n_live=16)
+            return jnp.concatenate([new_pool.reshape(-1), y.reshape(-1)])
 
         return _compare(lambda: both(spec.fn), lambda: both(spec.reference),
                         (), 0, "float32")
@@ -408,10 +406,10 @@ def _moe_relu2_cases(spec):
 
 def _conv_update_cases(spec):
     """The two served tails (Falcon-H1: three rows of 5,120 channels;
-    Nemotron-H: of 10,240), a pool of 2 layers x 20 slots, 16 rows of a
-    decode step on slots of their own and 4 padding rows on the scratch
-    slot (whose tail nobody reads: left out of the comparison). The pool
-    and the convolved rows together."""
+    Nemotron-H: of 10,240), a pool of 2 layers x 20 slots, a decode step's
+    bucket of 20 rows: 16 live on slots of their own and 4 padding rows on
+    the scratch slot, which stays as it was. The WHOLE pool and the
+    convolved rows together."""
 
     def case(C):
         rows, K, B = 40, 4, 20
@@ -426,9 +424,8 @@ def _conv_update_cases(spec):
         assert spec.supported(pool.shape, K)
 
         def both(fn):
-            new_pool, y = fn(pool, idx, x, w, b)
-            return jnp.concatenate([new_pool[:39].reshape(-1),
-                                    y[:16].reshape(-1)])
+            new_pool, y = fn(pool, idx, x, w, b, n_live=16)
+            return jnp.concatenate([new_pool.reshape(-1), y.reshape(-1)])
 
         return _compare(lambda: both(spec.fn), lambda: both(spec.reference),
                         (), 0, "float32")

@@ -70,10 +70,11 @@ def state_in_bfloat16():
             s = _rounded(s)
         return jnp.concatenate(ys, axis=1), s
 
-    def token_rounded(s_pool, idx, x, dt_raw, bmat, cmat, dt_bias, a_log):
+    def token_rounded(s_pool, idx, x, dt_raw, bmat, cmat, dt_bias, a_log,
+                      n_live=None):
         la, dtx = ops._decay_and_input(x, dt_raw, dt_bias, a_log)
         pool, y = ssm_update._reference(s_pool, idx, jnp.exp(la), dtx, bmat,
-                                        cmat)
+                                        cmat, n_live)
         idx = jnp.clip(idx, 0, pool.shape[0] - 1)
         return pool.at[idx].set(_rounded(pool[idx])), y
 
