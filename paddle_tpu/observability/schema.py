@@ -320,7 +320,8 @@ DECLARED: list[tuple] = [
      "to read)", ()),
     ("serving.sparse.layer_steps", COUNTER,
      "layer x decode-step pairs in which the indexer ran (a table wider "
-     "than index_topk slots)", ()),
+     "than index_topk slots); every layer x decode-step pair of a latent "
+     "family without an indexer, whose rows attend all their pages", ()),
     ("serving.sparse.kernel_layer_steps", COUNTER,
      "those of serving.sparse.layer_steps whose scores the paged Pallas "
      "kernel computed straight from the key pool (paged_indexer_scores); 0 "
@@ -337,9 +338,17 @@ DECLARED: list[tuple] = [
      "operations a token)", ()),
     ("serving.latent.attend_kernel_layer_steps", COUNTER,
      "layer x decode-step pairs whose absorbed attention the Pallas kernel "
-     "computed over the gathered rows as they lie (latent_rows_attention); "
-     "0 on the XLA arm (over sparse.layer_steps where every step selects: "
-     "how often the kernel engages)", ()),
+     "computed over the gathered rows as they lie (latent_rows_attention) "
+     "or, without an indexer, over the rows' pages where they lie "
+     "(paged_latent_attention); 0 on the XLA arm (over sparse.layer_steps "
+     "where every step selects or none does: how often the kernel "
+     "engages)", ()),
+    # -- a residual path of several streams (ISSUE 47) ----------------------
+    ("serving.hc.mix_tokens", COUNTER,
+     "(token, sub-layer) pairs whose residual streams were mapped and "
+     "mixed, windows and decode steps alike: two a layer a token (x the "
+     "streams' bytes read once and written once: what the mix has to "
+     "move)", ()),
     ("serving.moe.routed_pairs", COUNTER,
      "(token, expert) pairs the router made, summed over layers (prefill "
      "and decode), in an engine that holds a share of the experts", ()),
@@ -515,6 +524,9 @@ PIECES = frozenset({
     "kv_gather",  # rows or pages of K/V gathered out of a pool
     "latent_gather",  # latent_moe: cache rows gathered out of the pool
     "q_absorb",   # latent_moe: per-head products into and out of the latent
+    "hc_map",     # latent_moe with several residual streams: a sub-layer's
+                  # mappings (flat norm, projections, Sinkhorn)
+    "hc_mix",     # ... the pre-mix, the residual mix and the post mix
     "shared",     # latent_moe, mixer_moe: the shared expert
     "latent_proj",  # mixer_moe: the products into and out of the experts'
                     # latent

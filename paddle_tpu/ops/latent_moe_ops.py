@@ -55,6 +55,18 @@ shapes (`pallas_kernels.latent_attend`: a query's gathered rows read once
 as they lie, the words unpacked in VMEM, no scores written), and
 `absorbed_attention_fn` elsewhere.
 
+TWO THINGS THE GEOMETRY MAY CHANGE. WITHOUT AN INDEXER (`index_topk` 0: no
+indexer parameters, no key pool, no selection handed back) every step
+attends every live position at ANY context: a window in the expanded form
+over key blocks with a running softmax (`expanded_attention_blocks_fn`), a
+decode row in the absorbed form over all its pages, read in place by
+`pallas_kernels.paged_latent_attend` wherever `paged_attend_runs` takes the
+shapes (the gathered slabs and `absorbed_attention_fn` elsewhere). WITH
+`hc_mult` > 1 RESIDUAL STREAMS the layers hand on `[n, B, S, H]` float32
+and every sub-layer sits between `hyper_connection_ops`' mappings and mixes
+(`_mixed_input`; pieces `hc_map`, `hc_mix`); the embedding is copied into
+the streams and the final norm reads their sum.
+
 Weights are stacked by layer KIND (`dense.*` over the leading dense layers:
 attention, indexer and a SwiGLU; `moe.*` over the routed ones: attention,
 indexer, router and shared expert; the experts `[L_moe, held, ...]`); the
@@ -85,6 +97,7 @@ import jax.numpy as jnp
 
 from .attention_ops import _NEG_INF, _write_rows
 from .cca_moe_ops import _page_row_index, rms_norm_fn
+from . import hyper_connection_ops as hc
 from .hybrid_moe_ops import rotary_fn, swiglu_fn, yarn_inv_freq_fn
 from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
@@ -102,7 +115,10 @@ Geometry = collections.namedtuple(
     "Geometry", "num_heads nope_dim rope_dim v_dim kv_rank rope_theta "
                 "yarn softmax_mscale eps index_heads index_dim index_topk "
                 "experts_per_token expert_groups groups_per_token "
-                "routed_scaling experts_held")
+                "routed_scaling experts_held hc_mult hc_iters hc_eps "
+                "hc_clamp",
+    # one residual stream unless the configuration says more
+    defaults=(1, 0, 0.0, ()))
 
 # the stacked parameters, in the order the stack op takes them: a layer's
 # attention and indexer (a set each for the dense and the routed layers),
@@ -114,11 +130,27 @@ DENSE_PARAMS = ("w_gate", "w_up", "w_down")
 MOE_PARAMS = ("router_w", "router_bias", "shared_gate", "shared_up",
               "shared_down")
 EXPERT_PARAMS = ("w_gate", "w_up", "w_down")
+INDEXER_PARAMS = ("wqi", "wki", "ki_norm_w", "ki_norm_b", "ww")
+
+
+def attention_params(indexed: bool, streams: int) -> tuple:
+    """The per-layer parameters both layer kinds share, for a configuration
+    with or without an indexer (`index_topk`) and with `streams` residual
+    streams (`hc_mult`): `ATTENTION_PARAMS` without the indexer's where
+    there is none, then the mappings of a layer's two sub-layers, stacked
+    `[layers, 2, ...]`, where there is more than one stream."""
+    return tuple(k for k in ATTENTION_PARAMS
+                 if indexed or k not in INDEXER_PARAMS) \
+        + (hc.HC_PARAMS if streams > 1 else ())
 
 # queries attended together. Expanded: the float32 scores of one block are
 # `[heads, block, context]`. Absorbed: a block gathers `block x index_topk`
 # rows (64 x 2,048 x 1,536 B as stored = 201 MB as served)
 _QUERY_BLOCK = 64
+# keys a window without an indexer expands and scores at a time: the float32
+# scores of one block are `[heads, window, block]` (134 MB at 32 heads and a
+# 2,048-token window)
+_KEY_BLOCK = 512
 
 
 # ---------------------------------------------------------------------------
@@ -217,6 +249,63 @@ def expanded_attention_fn(q_nope, q_rope, c, r, mask, wkv_b,
     return jnp.moveaxis(out, 0, 1).reshape((B, S) + out.shape[3:])
 
 
+def key_block(T: int) -> int:
+    """Keys expanded at a time out of a context of T: T itself up to
+    `_KEY_BLOCK`, else its largest divisor under it."""
+    return next(b for b in range(min(T, _KEY_BLOCK), 0, -1) if T % b == 0)
+
+
+def expanded_attention_blocks_fn(q_nope, q_rope, c, r, gpos, wkv_b,
+                                 geom: Geometry):
+    """`expanded_attention_fn` for queries that attend EVERY cached row at
+    or before their own position (no selection), at any context: q_nope [B,
+    S, nh, nope], q_rope [B, S, nh, rope] float32; c [B, T, kv_rank], r [B,
+    T, rope] as cached, row t the token at position t; gpos [B, S] the
+    queries' positions -> [B, S, nh, v] float32. The keys run in blocks of
+    `key_block(T)`: a block's keys and values are made from its latents,
+    scored by all the queries, and folded into a running softmax (maximum,
+    sum, weighted values), so neither the expanded keys of the context nor
+    the `[queries, context]` scores ever exist whole. Row 0 is live for
+    every query, so the running maximum is a real score from the first
+    block on and a masked score's exponential is 0."""
+    B, S = q_nope.shape[:2]
+    T = c.shape[1]
+    dt, dn, nh = c.dtype, geom.nope_dim, geom.num_heads
+    kb = key_block(T)
+    scale = softmax_scale(geom)
+    w = _kv_b_heads(wkv_b, geom).astype(dt)
+    qn, qr = q_nope.astype(dt), q_rope.astype(dt)
+    split = lambda a: jnp.moveaxis(                          # noqa: E731
+        a.reshape((B, T // kb, kb) + a.shape[2:]), 1, 0)
+
+    def block(carry, xs):
+        m, l, acc = carry                    # [B, nh, S], same, [B, nh, S, v]
+        cb, rb, t0 = xs
+        kv = jnp.einsum("btc,chd->bthd", cb, w,
+                        preferred_element_type=_F32).astype(dt)
+        s = jnp.einsum("bshd,bthd->bhst", qn, kv[..., :dn],
+                       preferred_element_type=_F32) \
+            + jnp.einsum("bshd,btd->bhst", qr, rb,
+                         preferred_element_type=_F32)
+        live = (t0 + jnp.arange(kb, dtype=jnp.int32))[None, None, :] \
+            <= gpos[:, :, None]                              # [B, S, kb]
+        s = jnp.where(live[:, None], s * scale, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])       # 0 where it is masked
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhst,bthd->bhsd", p.astype(dt), kv[..., dn:],
+            preferred_element_type=_F32)
+        return (m_new, l * alpha + jnp.sum(p, axis=-1), acc), None
+
+    init = (jnp.full((B, nh, S), _NEG_INF, _F32), jnp.zeros((B, nh, S), _F32),
+            jnp.zeros((B, nh, S, geom.v_dim), _F32))
+    (_, l, acc), _ = jax.lax.scan(
+        block, init, (split(c), split(r),
+                      jnp.arange(T // kb, dtype=jnp.int32) * kb))
+    return jnp.moveaxis(acc / l[..., None], 1, 2)
+
+
 def absorb_queries_fn(q_nope, wkv_b, geom: Geometry):
     """q_nope [R, nh, nope] float32 -> q_lat [R, nh, kv_rank] float32:
     `W_uk,h^T q_nope_h`, each head's query carried into the latent."""
@@ -261,6 +350,20 @@ def latent_attend_runs(q_shape, rows_shape, dtype, rope_dim: int) -> bool:
     return (workbench.runnable(latent_attend)
             and latent_attend.latent_attend_supported(
                 tuple(q_shape), tuple(rows_shape), dtype, int(rope_dim)))
+
+
+def paged_attend_runs(q_shape, pool_shape, dtype, rope_dim: int) -> bool:
+    """Whether the absorbed attention of decode rows q_lat `q_shape` [B, nh,
+    kv_rank] over ALL their pages, read in place from the latent pool
+    `pool_shape` [rows, page_size, words] of `dtype` values, comes from
+    `pallas_kernels.paged_latent_attend`: its shape gate decides alone,
+    where a Pallas kernel can run at all. The engine books
+    `serving.latent.attend_kernel_layer_steps` by the same answer."""
+    from .pallas_kernels import paged_latent_attend, workbench
+
+    return (workbench.runnable(paged_latent_attend)
+            and paged_latent_attend.paged_latent_attend_supported(
+                tuple(q_shape), tuple(pool_shape), dtype, int(rope_dim)))
 
 
 def gather_rows_fn(pool, page_table, sel):
@@ -347,6 +450,8 @@ def _pre_attention(x, p, positions, geom: Geometry):
     c_kv = rms_norm_fn(kv[..., :geom.kv_rank], p["kv_norm"], geom.eps)
     k_rope = rotary_interleaved_fn(kv[:, :, None, geom.kv_rank:], positions,
                                    inv_freq)[:, :, 0]
+    if not geom.index_topk:         # no indexer: every cached row is read
+        return q[..., :dn], q_rope, c_kv, k_rope, None, None, None
     qi = rotary_fn(_mm(c_q, p["wqi"]).reshape(B, S, J, D), positions,
                    inv_freq, dr)
     ki = layer_norm_fn(_mm(z, p["wki"]), p["ki_norm_w"], p["ki_norm_b"],
@@ -385,16 +490,37 @@ def _attend_selected(q_nope, q_rope, pool, table, sel, wkv_b, dtype,
     return _attend_rows(q_nope, q_rope, rows, sel >= 0, wkv_b, dtype, geom)
 
 
+def _attend_pages(q_nope, q_rope, pool, table, lens, wkv_b, dtype,
+                  geom: Geometry):
+    """The absorbed form for B decode rows, each over ALL the pages of its
+    table [B, P] (shifted to the layer's rows; lens [B] live positions, 0: a
+    padding row), read where they lie in the pool -> [B, nh, v] float32:
+    `pallas_kernels.paged_latent_attend` (callers gate on
+    `paged_attend_runs`)."""
+    from .pallas_kernels import paged_latent_attend
+
+    with piece("q_absorb"):
+        q_lat = absorb_queries_fn(q_nope, wkv_b, geom)
+    with piece("attend"):
+        u = paged_latent_attend.paged_latent_attention(
+            q_lat, q_rope, pool, table, lens, dtype, geom)
+    with piece("q_absorb"):
+        return expand_values_fn(u, wkv_b, geom)
+
+
 def _feed_forward(h, kind_dense: bool, p, experts, index, geom: Geometry,
-                  tag: str):
-    """h [B, S, H] -> (y [B, S, H], ids [B, S, k] or None)."""
+                  tag: str, add: bool = True):
+    """h [B, S, H] -> (y [B, S, H], ids [B, S, k] or None): `h + F(h)`, or
+    `F(h)` alone without `add` (a residual path of several streams mixes it
+    in itself)."""
     B, S, H = h.shape
     with piece("proj"):
         z = rms_norm_fn(h, p["ffn_norm"], geom.eps).reshape(B * S, H)
     if kind_dense:
         with piece("dense_ffn"):
-            return h + swiglu_fn(z, p["w_gate"], p["w_up"],
-                                 p["w_down"]).reshape(B, S, H), None
+            y = swiglu_fn(z, p["w_gate"], p["w_up"],
+                          p["w_down"]).reshape(B, S, H)
+            return (h + y if add else y), None
     with piece("router"):
         ids, cw = group_limited_router_fn(
             z, p["router_w"], p["router_bias"], geom.experts_per_token,
@@ -403,9 +529,9 @@ def _feed_forward(h, kind_dense: bool, p, experts, index, geom: Geometry,
     with piece("experts"):
         y = moe_topk_experts_fn(z, held, *experts, layer=index, tag=tag)
     with piece("shared"):
-        y = y + swiglu_fn(z, p["shared_gate"], p["shared_up"],
-                          p["shared_down"])
-        return h + y.reshape(B, S, H), ids.reshape(B, S, -1)
+        y = (y + swiglu_fn(z, p["shared_gate"], p["shared_up"],
+                           p["shared_down"])).reshape(B, S, H)
+        return (h + y if add else y), ids.reshape(B, S, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -427,16 +553,20 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
       decode   tok/pos [B], page_table, mask [B],
                mark [M] (rows whose selection is kept) -> logits [B, V]
 
-    `dense` and `moe` hold `ATTENTION_PARAMS` and their kind's own, each
-    stacked over the layers of that kind (the dense layers lead); `experts`
-    the held experts `[L_moe, held, ...]`. Returns a dict: logits; routes
-    ([B, S, L_moe, k], decode [B, L_moe, k]: ids among ALL experts);
-    selection, what each layer's attention was given, as `sparse_moe_stack`
-    hands it back: a window's (or `full`'s) mask in `pack_selection_fn`
-    words [B, S, L, G, page_size], the positions the marked rows of a
-    decode step attended [M, L, kk]; and, with `pools` (the latent rows, the
-    indexer keys), latent_pool/i_pool as written. Traced under its mode's
-    scope, each piece (observability/schema.PIECES) under its own."""
+    `dense` and `moe` hold `attention_params(...)` and their kind's own,
+    each stacked over the layers of that kind (the dense layers lead);
+    `experts` the held experts `[L_moe, held, ...]`. Returns a dict: logits;
+    routes ([B, S, L_moe, k], decode [B, L_moe, k]: ids among ALL experts);
+    with an indexer (`geom.index_topk`) selection, what each layer's
+    attention was given, as `sparse_moe_stack` hands it back: a window's (or
+    `full`'s) mask in `pack_selection_fn` words [B, S, L, G, page_size], the
+    positions the marked rows of a decode step attended [M, L, kk]; and,
+    with `pools` (the latent rows, then the indexer keys where there is an
+    indexer), latent_pool (and i_pool) as written. With `geom.hc_mult`
+    residual streams the layers hand on `[n, B, S, H]` and every sub-layer
+    sits between `hyper_connection_ops`' mixes (`_mixed_input`). Traced
+    under its mode's scope, each piece (observability/schema.PIECES) under
+    its own."""
     decode = mode == "decode"
     paged = mode != "full"
     if decode:
@@ -446,6 +576,7 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
     B, S, _ = x.shape
     Ld, Le = dense["attn_norm"].shape[0], moe["attn_norm"].shape[0]
     nh, topk = geom.num_heads, int(geom.index_topk)
+    indexed, streams = topk > 0, int(geom.hc_mult)
     tag = "decode" if decode else "prefill"
     dtype = emb.dtype                       # the cache rows' dtype
     rel = jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -462,13 +593,19 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
             else rel < lens[:, None]
         count = valid[:, 0].astype(jnp.int32) if decode else lens
         # a step whose whole table fits the selection scores nothing and
-        # attends every live position
-        whole = context <= topk
+        # attends every live position; so does every step of a
+        # configuration without an indexer
+        whole = not indexed or context <= topk
     else:
         gpos = jnp.broadcast_to(rel, (B, S))
         context = S
+    if streams > 1:
+        with piece("embed"):
+            x = hc.spread_fn(x, streams)                        # [n, B, S, H]
 
     def layer(x, latent_pool, i_pool, l, p, kind_dense, ffn_index):
+        if streams > 1:     # the attention reads a mix of the streams
+            x, mix_attention = _mixed_input(x, p, 0, geom)
         with piece("proj"):
             q_nope, q_rope, c_kv, k_rope, qi, ki, w = _pre_attention(
                 x, p, pos, geom)
@@ -482,9 +619,18 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                     latent_pool, join_latent_fn(
                         c_kv, k_rope, dtype, latent_pool.shape[-1]), idx,
                     gpos % page_size)
-                i_pool = write_index_keys_fn(i_pool, ki, page_table, off,
-                                             first, count)
-        if paged and whole:
+                if indexed:
+                    i_pool = write_index_keys_fn(i_pool, ki, page_table,
+                                                 off, first, count)
+        sel = None
+        if paged and whole and decode and not indexed and paged_attend_runs(
+                (B, nh, geom.kv_rank), latent_pool.shape, dtype,
+                geom.rope_dim):
+            # every page of the row's table, read where it lies
+            o = _attend_pages(q_nope[:, 0], q_rope[:, 0], latent_pool, table,
+                              (first + 1) * count, p["wkv_b"], dtype,
+                              geom)[:, None]
+        elif paged and whole:
             at = jnp.arange(context, dtype=jnp.int32)
             live = at[None, None, :] <= gpos[:, :, None]        # [B, S, T]
             with piece("latent_gather"):
@@ -495,15 +641,24 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                 o = _attend_rows(q_nope[:, 0], q_rope[:, 0], slabs,
                                  live[:, 0], p["wkv_b"], dtype,
                                  geom)[:, None]
-                sel = jnp.where(live, at, -1)                   # [B, 1, T]
+                if indexed:
+                    sel = jnp.where(live, at, -1)               # [B, 1, T]
             else:
                 with piece("attend"):
                     c, r = split_latent_fn(slabs, dtype, geom.kv_rank,
                                            geom.rope_dim)
-                    o = expanded_attention_fn(q_nope, q_rope, c, r, live,
-                                              p["wkv_b"], geom)
-                with piece("select"):
-                    sel = pack_selection_fn(live, page_size)
+                    o = expanded_attention_fn(
+                        q_nope, q_rope, c, r, live, p["wkv_b"], geom) \
+                        if indexed else expanded_attention_blocks_fn(
+                            q_nope, q_rope, c, r, gpos, p["wkv_b"], geom)
+                if indexed:
+                    with piece("select"):
+                        sel = pack_selection_fn(live, page_size)
+        elif not indexed:       # the dense oracle: the sequence itself
+            with piece("attend"):
+                o = expanded_attention_blocks_fn(
+                    q_nope, q_rope, c_kv.astype(dtype), k_rope.astype(dtype),
+                    gpos, p["wkv_b"], geom)
         else:
             with piece("indexer"):
                 if decode:      # each row's pages, where they lie
@@ -534,51 +689,86 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                 # handed back as attended under: the mask itself
                 with piece("select"):
                     sel = pack_selection_fn(keep, page_size if paged else S)
-        with piece("proj"):
-            h = x + _mm(o.reshape(B, S, -1), p["wo"])
-        y, ids = _feed_forward(h, kind_dense, p, experts, ffn_index, geom,
-                               tag)
-        if decode:
+        if streams > 1:
+            with piece("proj"):
+                f = _mm(o.reshape(B, S, -1), p["wo"])
+            h, mix_ffn = _mixed_input(mix_attention(f), p, 1, geom)
+            f, ids = _feed_forward(h, kind_dense, p, experts, ffn_index,
+                                   geom, tag, add=False)
+            y = mix_ffn(f)
+        else:
+            with piece("proj"):
+                h = x + _mm(o.reshape(B, S, -1), p["wo"])
+            y, ids = _feed_forward(h, kind_dense, p, experts, ffn_index,
+                                   geom, tag)
+        if decode and indexed:
             sel = sel[:, 0][mark]                               # [M, kk]
         return y, latent_pool, i_pool, ids, sel
 
-    latent_pool, i_pool = pools if paged else (None, None)
+    # what the layers hand on: the residual (one stream or several), then
+    # the pools there are
+    pools = tuple(pools) if paged else ()
+    held = lambda carry: (carry + (None, None))[1:3]         # noqa: E731
+    carry = (x,) + pools
     selections = []
     for l in range(Ld):
-        x, latent_pool, i_pool, _, sel = layer(
-            x, latent_pool, i_pool, l, {k: v[l] for k, v in dense.items()},
+        y, latent_pool, i_pool, _, sel = layer(
+            carry[0], *held(carry), l, {k: v[l] for k, v in dense.items()},
             True, l)
+        carry = (y, latent_pool, i_pool)[:len(carry)]
         selections.append(sel)
 
     def routed(carry, xs):
         i, p = xs
-        x, latent_pool, i_pool = carry if paged else carry + (None, None)
         y, latent_pool, i_pool, ids, sel = layer(
-            x, latent_pool, i_pool, Ld + i, p, False, i)
-        return ((y, latent_pool, i_pool) if paged else (y,)), (ids, sel)
+            carry[0], *held(carry), Ld + i, p, False, i)
+        return (y, latent_pool, i_pool)[:len(carry)], (ids, sel)
 
-    init = (x, latent_pool, i_pool) if paged else (x,)
     carry, (routes, sel) = jax.lax.scan(
-        routed, init, (jnp.arange(Le, dtype=jnp.int32), moe))
-    selection = jnp.concatenate([jnp.stack(selections), sel])
+        routed, carry, (jnp.arange(Le, dtype=jnp.int32), moe))
+    if indexed:
+        selection = jnp.concatenate([jnp.stack(selections), sel])
     with piece("head"):
-        xn = rms_norm_fn(carry[0], final_norm, geom.eps)
+        xn = rms_norm_fn(hc.readout_fn(carry[0]) if streams > 1
+                         else carry[0], final_norm, geom.eps)
         if mode == "window":
             at = jnp.clip(lens - 1, 0, S - 1)[:, None, None]
             xn = jnp.take_along_axis(xn, at, axis=1)
         logits = jnp.einsum("bsh,hv->bsv", xn.astype(head.dtype), head,
                             preferred_element_type=_F32)
     routes = jnp.moveaxis(routes, 0, -2)                  # [B, S, L_moe, k]
-    if decode:
-        selection = jnp.moveaxis(selection, 0, 1)         # [M, L, kk]
-    else:
-        selection = jnp.moveaxis(selection, 0, 2)     # [B, S, L, G, ps]
+    if indexed:     # decode [M, L, kk], a window [B, S, L, G, ps]
+        selection = jnp.moveaxis(selection, 0, 1 if decode else 2)
     out = {"logits": logits if mode == "full" else logits[:, 0],
-           "routes": routes[:, 0] if decode else routes,
-           "selection": selection}
+           "routes": routes[:, 0] if decode else routes}
+    if indexed:
+        out["selection"] = selection
     if paged:
-        out.update(latent_pool=carry[1], i_pool=carry[2])
+        out.update(zip(("latent_pool", "i_pool"), carry[1:]))
     return out
+
+
+def _mixed_input(xs, p, which: int, geom: Geometry):
+    """The residual streams xs [n, B, S, H] before sub-layer `which` (0: the
+    attention, 1: the feed-forward) of a layer whose parameters are `p` ->
+    (the sub-layer's input [B, S, H], `mix(f)`: the streams after it, given
+    its output f [B, S, H]): `hyper_connection_ops`' mappings from the
+    streams themselves, the pre-mix, and the residual and post mix."""
+    n, B, S, H = xs.shape
+    flat = xs.reshape(n, B * S, H)
+    with piece("hc_map"):
+        pre, post, res = hc.mappings_fn(
+            flat, p["hc_w"][which], p["hc_a"][which], p["hc_b"][which],
+            geom.hc_iters, geom.hc_eps, tuple(geom.hc_clamp))
+    with piece("hc_mix"):
+        u = hc.pre_mix_fn(flat, pre).reshape(B, S, H)
+
+    def mix(f):
+        with piece("hc_mix"):
+            return hc.post_mix_fn(flat, res, post,
+                                  f.reshape(B * S, H)).reshape(xs.shape)
+
+    return u, mix
 
 
 def _window_rows(q_nope, q_rope, pool, table, keep, wkv_b, dtype,
@@ -619,15 +809,19 @@ def _window_rows(q_nope, q_rope, pool, table, keep, wkv_b, dtype,
 @register_op("latent_moe_stack", grad="none")
 def latent_moe_stack_op(ctx: ExecContext):
     """The whole decoder in one op; see `latent_moe_stack_fn`. inputs: Tok,
-    Pos, Emb, Head, FinalNorm, DenseParams (`ATTENTION_PARAMS` then
-    `DENSE_PARAMS`), MoeParams (`ATTENTION_PARAMS` then `MOE_PARAMS`),
+    Pos, Emb, Head, FinalNorm, DenseParams (`attention_params(...)` then
+    `DENSE_PARAMS`), MoeParams (`attention_params(...)` then `MOE_PARAMS`),
     Experts (`EXPERT_PARAMS`), and by mode PageTable, Lens, Start, Mask,
-    Mark (decode), LatentPool/IPool. attrs: mode and the geometry. Outputs:
-    NextToken (greedy), Logits, Routes, Selection, and the pools under
-    their own names."""
+    Mark (decode, with an indexer), LatentPool (and IPool with an indexer).
+    attrs: mode and the geometry. Outputs: NextToken (greedy), Logits,
+    Routes, Selection (with an indexer), and the pools under their own
+    names."""
     mode = ctx.attr("mode")
-    geom = Geometry(*(ctx.attr(f) for f in Geometry._fields))
+    geom = Geometry(**{f: v for f in Geometry._fields
+                       if (v := ctx.attr(f)) is not None})
     paged = mode != "full"
+    indexed = int(geom.index_topk) > 0
+    shared = attention_params(indexed, int(geom.hc_mult))
 
     def opt(slot):
         return ctx.input(slot).astype(jnp.int32) if ctx.has_input(slot) \
@@ -638,17 +832,20 @@ def latent_moe_stack_op(ctx: ExecContext):
         ctx.input("Tok").astype(jnp.int32),
         ctx.input("Pos").astype(jnp.int32), ctx.input("Emb"),
         ctx.input("Head"), ctx.input("FinalNorm"),
-        dict(zip(ATTENTION_PARAMS + DENSE_PARAMS, ctx.inputs("DenseParams"))),
-        dict(zip(ATTENTION_PARAMS + MOE_PARAMS, ctx.inputs("MoeParams"))),
+        dict(zip(shared + DENSE_PARAMS, ctx.inputs("DenseParams"))),
+        dict(zip(shared + MOE_PARAMS, ctx.inputs("MoeParams"))),
         tuple(ctx.inputs("Experts")), geom,
-        pools=(ctx.input("LatentPool"), ctx.input("IPool")) if paged
-        else None,
+        pools=(ctx.input("LatentPool"),) + (
+            (ctx.input("IPool"),) if indexed else ()) if paged else None,
         page_table=opt("PageTable"), lens=opt("Lens"), start=opt("Start"),
         mask=ctx.input("Mask") if ctx.has_input("Mask") else None,
         mark=opt("Mark"), num_pages=int(ctx.attr("num_pages", 0)))
-    res = {"Logits": out["logits"], "Routes": out["routes"],
-           "Selection": out["selection"],
-           "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
+    res = {"Logits": out["logits"], "Routes": out["routes"]}
+    if indexed:
+        res["Selection"] = out["selection"]
+    res["NextToken"] = jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)
     if paged:
-        res.update(LatentPoolOut=out["latent_pool"], IPoolOut=out["i_pool"])
+        res["LatentPoolOut"] = out["latent_pool"]
+        if indexed:
+            res["IPoolOut"] = out["i_pool"]
     return res

@@ -146,6 +146,7 @@ def all_kernels() -> dict[str, KernelSpec]:
     modules so their registrations run)."""
     from . import (attention, conv_update, epilogue,  # noqa: F401
                    latent_attend, moe_experts, paged_attention,
-                   paged_indexer, short_attention, ssm_update)
+                   paged_indexer, paged_latent_attend, short_attention,
+                   ssm_update)
 
     return dict(_KERNELS)

@@ -786,6 +786,8 @@ class ServingEngine:
             # a latent cache row and a share of the experts (ISSUE 39)
             "latent.gathered_rows": 0, "latent.attended_tokens": 0,
             "latent.attend_kernel_layer_steps": 0,
+            # a residual path of several streams (ISSUE 47)
+            "hc.mix_tokens": 0,
             "moe.routed_pairs": 0, "moe.held_pairs": 0,
             # window and full attention layers over two pools (ISSUE 33)
             "kv.window_pages_released": 0, "kv.window_row_pages": 0,
@@ -2301,6 +2303,7 @@ class ServingEngine:
                 self.stats["prefill_signatures"].add(("suffix", sb, pb))
                 self._count("prefill_tokens_computed", m)
                 self._count("prefill.chunks")
+                self._count_mixes(m)
                 if self.state_pool is not None:
                     self._count("ssm.scan_tokens",
                                 m * self.cfg.state_layers)
@@ -2533,7 +2536,9 @@ class ServingEngine:
         steps = self._grid_steps_by_signature.get((bb, pb))
         if steps is None:
             cfg = self.cfg
-            if cfg.selects:   # gathers through XLA at every context
+            if cfg.selects or cfg.latent:
+                # gathers through XLA at every context, or reads its pages
+                # through a kernel of its own (`_attend_kernel`)
                 steps = 0
             else:
                 pool = self._scope.find_var(
@@ -2593,12 +2598,23 @@ class ServingEngine:
             cfg = self.cfg
             pool = self._scope.find_var(LATENT_POOL)
             slots = pb * self.page_size
+            q_shape = (bb, cfg.num_heads, cfg.kv_lora_rank)
+            # without an indexer the rows' pages are read in place; behind
+            # one, the rows it selected are gathered first
             runs = self._attend_kernel_runs[(bb, pb)] = \
                 latent_moe_ops.latent_attend_runs(
-                    (bb, cfg.num_heads, cfg.kv_lora_rank),
-                    (bb, min(cfg.index_topk, slots), pool.shape[-1]),
-                    cfg.dtype, cfg.rope_head_dim)
+                    q_shape, (bb, min(cfg.index_topk, slots),
+                              pool.shape[-1]),
+                    cfg.dtype, cfg.rope_head_dim) if cfg.selects \
+                else latent_moe_ops.paged_attend_runs(
+                    q_shape, pool.shape, cfg.dtype, cfg.rope_head_dim)
         return runs
+
+    def _count_mixes(self, tokens: int) -> None:
+        """`tokens` tokens through every sub-layer's mix of a residual path
+        of several streams (two a layer)."""
+        if self.cfg.hc_mult > 1:
+            self._count("hc.mix_tokens", tokens * 2 * self.cfg.num_layers)
 
     def _decode_once(self, sp) -> bool:
         """One decode step under the open `serving.decode` span `sp`:
@@ -2679,10 +2695,15 @@ class ServingEngine:
                         L if self._indexer_kernel() else 0)
         if self.cfg.latent:
             # cache rows the rows' attention read out of the latent pool
-            # (their selection's, or every slot of a table that fits it)
-            # and how many of them were live positions
+            # (their selection's, or every slot of a table that fits it or
+            # has no indexer over it) and how many of them were live
+            # positions
             L, k = self.cfg.num_layers, self.cfg.index_topk
             select = self.cfg.selects_within(pb * ps)
+            if not self.cfg.selects:
+                # the family's layer steps, as where every step selects
+                self._count("sparse.layer_steps", L)
+            self._count_mixes(len(rows))
             self._count("latent.gathered_rows", L * (
                 sum(min(k, pos + 1) for pos, _ in at) if select
                 else len(at) * pb * ps))

@@ -1,4 +1,5 @@
-"""Serving program builders. SEVEN block families exist, and
+"""Serving program builders. SEVEN block families exist (the sixth in two
+forms: behind an indexer, or read whole under several residual streams), and
 `DecoderConfig.block` selects one:
 
   * `"post_ln"` (the default; every other field at its default is the "bert
@@ -82,6 +83,19 @@
     by layer kind, the routed layers scanned. Prompts run in
     `prefill_chunk`-token windows and every program hands back its
     `selection`, as "sparse_moe"'s do.
+    TWO THINGS THE CONFIGURATION MAY CHANGE (Xing4.0's layer has both).
+    WITHOUT AN INDEXER (`index_heads`, `index_head_dim`, `index_topk` all
+    0): every query attends every cached row at or before it, a window in
+    the expanded form over key blocks, a decode row in the absorbed form
+    over ALL its pages, read in place (`pallas_kernels.paged_latent_attend`);
+    no `kv_cache.INDEX_POOL` is allocated, shared, copied on write or
+    audited, the indexer's five parameters do not exist and no `selection`
+    is handed back (`DecoderConfig.selects` is False, `.latent` True).
+    WITH `hc_mult` > 1 RESIDUAL STREAMS: the residual is `hc_mult` float32
+    streams, mixed around every sub-layer (two a layer) by
+    manifold-constrained hyper-connections (`ops/hyper_connection_ops.py`:
+    `hc_sinkhorn_iters`, `hc_eps`, `hc_res_clamp`); the embedding is copied
+    into the streams and the final norm reads their sum.
   * `"mixer_moe"` (`ops/mixer_moe_ops.py`; Nemotron-H's layers with latent
     experts): every layer is ONE sub-layer behind ONE pre-norm, its kind the
     layer's character in `layer_pattern`: `M` a Mamba-2 mixer
@@ -143,7 +157,8 @@ from .kv_cache import (INDEX_POOL, JOINED_POOL, LATENT_POOL, STACKED_POOLS,
 
 __all__ = ["DecoderConfig", "decoder_tiny", "cca_moe_tiny",
            "sparse_moe_tiny", "hybrid_moe_tiny", "parallel_ssm_tiny",
-           "latent_moe_tiny", "mixer_moe_tiny", "layer_plan",
+           "latent_moe_tiny", "latent_streams_tiny", "mixer_moe_tiny",
+           "layer_plan",
            "build_prefill_program",
            "build_decode_program", "build_window_program",
            "build_state_copy_program",
@@ -257,6 +272,15 @@ class DecoderConfig:
     expert_groups: int = 1
     groups_per_token: int = 1
     experts_held: int = 0          # 0: all of num_experts
+    # "latent_moe": the residual path. `hc_mult` streams (1: the plain `x +
+    # f(norm(x))`), mixed around every sub-layer by manifold-constrained
+    # hyper-connections (`ops/hyper_connection_ops.py`): Sinkhorn
+    # iterations, the epsilon of the flat RMSNorm and of both
+    # normalisations, and the clip of the residual mapping's logits
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple = (-30.0, 30.0)
     # "mixer_moe" only (it also reads the `ssm_*` sizes, `num_kv_heads`,
     # `attn_head_dim`, `num_experts`, `experts_per_token`, `experts_held`,
     # `routed_scaling`, `shared_expert_size`; `ffn_size` is one expert's
@@ -274,7 +298,8 @@ class DecoderConfig:
             raise ValueError(f"unknown DecoderConfig.block {self.block!r} "
                              f"({' | '.join(_FAMILY)})")
         for name in ("layer_types", "mlp_layer_types", "heads_per_layer",
-                     "yarn", "mlp_multipliers", "ssm_multipliers"):
+                     "yarn", "mlp_multipliers", "ssm_multipliers",
+                     "hc_res_clamp"):
             setattr(self, name, tuple(getattr(self, name)))
         if self.block == "parallel_ssm":
             if min(self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
@@ -328,19 +353,31 @@ class DecoderConfig:
                                  "experts_per_token >= 1")
         if self.block == "latent_moe":
             held = self.experts_held or self.num_experts
+            indexer = (self.index_heads, self.index_head_dim,
+                       self.index_topk)
             if min(self.q_lora_rank, self.kv_lora_rank, self.rope_head_dim,
-                   self.v_head_dim, self.index_heads, self.index_head_dim,
-                   self.index_topk, self.prefill_chunk,
+                   self.v_head_dim, self.prefill_chunk,
                    self.shared_expert_size) < 1 \
                     or self.rope_head_dim % 2 or self.kv_lora_rank % 2 \
-                    or self.index_head_dim < self.rope_head_dim \
+                    or any(indexer) and (
+                        min(indexer) < 1
+                        or self.index_head_dim < self.rope_head_dim) \
                     or self.yarn and len(self.yarn) != 5:
                 raise ValueError(
                     "block 'latent_moe' needs q_lora_rank, kv_lora_rank and "
-                    "rope_head_dim (even), v_head_dim, index_heads, "
+                    "rope_head_dim (even), v_head_dim, prefill_chunk, "
+                    "shared_expert_size, yarn as () or five values, and "
+                    "an indexer given whole or not at all: index_heads, "
                     "index_head_dim (at least rope_head_dim: its first "
-                    "lanes carry the rotary), index_topk, prefill_chunk, "
-                    "shared_expert_size and yarn as () or five values")
+                    "lanes carry the rotary) and index_topk")
+            if self.hc_mult < 1 or self.hc_mult > 1 and (
+                    self.hc_sinkhorn_iters < 1 or self.hc_eps <= 0
+                    or len(self.hc_res_clamp) != 2
+                    or self.hc_res_clamp[0] >= self.hc_res_clamp[1]):
+                raise ValueError(
+                    "block 'latent_moe' with hc_mult > 1 residual streams "
+                    "needs hc_sinkhorn_iters >= 1, hc_eps > 0 and "
+                    "hc_res_clamp as (lowest, highest)")
             if not 1 <= self.dense_layers < self.num_layers \
                     or self.dense_ffn_size < 1:
                 raise ValueError(
@@ -459,8 +496,11 @@ class DecoderConfig:
         """Whether attention reads a learned selection of the cache: an
         indexer scores every slot of a row's page table, a pool of its
         own holds its keys, a token's K and V (or its one latent row) are
-        one row of one pool, and every step reports what it attended."""
-        return self.block in ("sparse_moe", "latent_moe")
+        one row of one pool, and every step reports what it attended.
+        "latent_moe" without an indexer (`index_topk` 0) attends every
+        cached row and has none of these."""
+        return self.block == "sparse_moe" \
+            or self.block == "latent_moe" and self.index_topk > 0
 
     @property
     def latent(self) -> bool:
@@ -478,7 +518,7 @@ class DecoderConfig:
         """0: page tables round up to a power of two. n: past n pages
         they round to a multiple of n, for a family whose every step scans
         its whole table (the dead part stays under an eighth)."""
-        return 32 if self.selects else 0
+        return 32 if self.selects or self.latent else 0
 
     @property
     def one_page_bucket(self) -> bool:
@@ -563,6 +603,19 @@ def latent_moe_tiny(**over) -> DecoderConfig:
               rms_norm_eps=1e-6, max_position=128, block="latent_moe")
     kw.update(over)
     return DecoderConfig(**kw)
+
+
+def latent_streams_tiny(**over) -> DecoderConfig:
+    """The "latent_moe" block WITHOUT an indexer and with four residual
+    streams at test size: `latent_moe_tiny`'s attention read whole, two
+    dense layers then two routed ones (2 of 8 experts a token in one group,
+    all held), the residual logits clipped at +-1 so that the clip cuts."""
+    kw = dict(index_heads=0, index_head_dim=0, index_topk=0, num_layers=4,
+              dense_layers=2, num_experts=8, experts_held=0,
+              expert_groups=1, groups_per_token=1, hc_mult=4,
+              hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=(-1.0, 1.0))
+    kw.update(over)
+    return latent_moe_tiny(**kw)
 
 
 def parallel_ssm_tiny(**over) -> DecoderConfig:
@@ -810,8 +863,11 @@ def _sparse_stack(cfg: DecoderConfig, mode: str, tok, pos,
 
 
 def _sparse_window_io(out):
-    return {"next_token": out["next_token"], "last_logits": out["logits"],
-            "routes": out["routes"], "selection": out["selection"]}
+    io = {"next_token": out["next_token"], "last_logits": out["logits"],
+          "routes": out["routes"]}
+    if "selection" in out:      # "latent_moe" without an indexer has none
+        io["selection"] = out["selection"]
+    return io
 
 
 def _sparse_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
@@ -1126,7 +1182,10 @@ def _latent_geometry(cfg: DecoderConfig) -> dict:
             "expert_groups": cfg.expert_groups,
             "groups_per_token": cfg.groups_per_token,
             "routed_scaling": float(cfg.routed_scaling),
-            "experts_held": cfg.held_experts}
+            "experts_held": cfg.held_experts,
+            "hc_mult": cfg.hc_mult, "hc_iters": cfg.hc_sinkhorn_iters,
+            "hc_eps": float(cfg.hc_eps),
+            "hc_clamp": [float(v) for v in cfg.hc_res_clamp]}
 
 
 def _latent_pool_geometry(cfg: DecoderConfig, num_pages: int,
@@ -1136,8 +1195,16 @@ def _latent_pool_geometry(cfg: DecoderConfig, num_pages: int,
             cfg.index_head_dim, False, STACKED_POOLS, True)
 
 
-# a token's latent and rotary key in one row of one pool, its indexer key
-_LATENT_POOLS = (("LatentPool", LATENT_POOL), ("IPool", INDEX_POOL))
+def _latent_pools(cfg: DecoderConfig) -> tuple:
+    """(op slot, pool) of the family's pools: a token's latent and rotary
+    key in one row of one pool and, behind an indexer, its indexer key."""
+    return (("LatentPool", LATENT_POOL),) + (
+        (("IPool", INDEX_POOL),) if cfg.selects else ())
+
+
+# standard deviations of a sub-layer's biases `hc_b`: pre [n], post [n],
+# res [n * n] (`_hc_param_specs` says why)
+HC_BIAS_SPREAD = (2.0, 2.0, 1.2)
 
 
 def _latent_param_specs(cfg: DecoderConfig) -> dict:
@@ -1152,7 +1219,10 @@ def _latent_param_specs(cfg: DecoderConfig) -> dict:
     the router at 2x, so that its sigmoids differ by more than rounding,
     and its selection bias at 0.02, so that it decides some choices and
     not all. The large ones are in `cfg.dtype`; norms, the router and its
-    bias in float32."""
+    bias in float32. A configuration without an indexer has none of its
+    five parameters; one with `hc_mult` > 1 residual streams has, a layer,
+    the mappings of its two sub-layers in float32 (`hc_w` [2, n H, n (n +
+    2)], `hc_a` [2, 3], `hc_b` [2, n (n + 2)]: `_hc_param_specs`)."""
     H, F, E = cfg.hidden_size, cfg.ffn_size, cfg.num_experts
     nh, dn, dr, dv = cfg.num_heads, cfg.head_dim, cfg.rope_head_dim, \
         cfg.v_head_dim
@@ -1187,7 +1257,9 @@ def _latent_param_specs(cfg: DecoderConfig) -> dict:
             "ki_norm_w": ([n, D], f32, near_one),
             "ki_norm_b": ([n, D], f32, Normal(0.0, 0.02)),
             "ww": ([n, H, J], big, fan(H)),
-            "ffn_norm": ([n, H], f32, near_one)}.items()})
+            "ffn_norm": ([n, H], f32, near_one),
+            **_hc_param_specs(cfg, n)}.items()
+            if cfg.selects or key not in latent_moe_ops.INDEXER_PARAMS})
     specs.update({
         "dense.w_gate": ([Ld, H, Fd], big, fan(H)),
         "dense.w_up": ([Ld, H, Fd], big, fan(H)),
@@ -1204,41 +1276,76 @@ def _latent_param_specs(cfg: DecoderConfig) -> dict:
     return specs
 
 
+def _hc_param_specs(cfg: DecoderConfig, layers: int) -> dict:
+    """The mappings of `layers` layers' two sub-layers in float32 (nothing
+    for one stream), seeded so that the residual path MATTERS to what is
+    served. `hc_w` N(0, (n H)^-0.5): over the normalised streams each raw
+    projection is N(0, 1) a token, and `hc_a` N(0.6, 0.05) keeps every
+    mapping a smooth function of the token. `hc_b` is drawn a block of
+    columns (`HC_BIAS_SPREAD`): the pre and post biases N(0, 2), so that a
+    sub-layer reads mostly one or two of the streams (H_pre 0.05-0.95) and
+    writes mostly into one or two (H_post 0.1-1.9): the streams then hold
+    different things, and what a later sub-layer reads depends on how H_res
+    carried them (a doubly stochastic H_res keeps the streams' SUM, which
+    is all the head reads, so under even H_pre a wrong H_res shows
+    nowhere); the residual biases N(0, 1.2): logits spread by 1.34, entries
+    of H_res from 0.02 to 0.6, columns 22% off one after ONE Sinkhorn
+    iteration, 6% after two, 2% after three (medians) and 1e-6 after the
+    configured twenty (one token in a hundred is still 7e-4 off: twenty
+    iterations are what the configuration gives, not a limit).
+    `tests/test_hyper_connections.py` measures these."""
+    n = cfg.hc_mult
+    if n < 2:
+        return {}
+    k = n * (n + 2)
+    pre, post, res = HC_BIAS_SPREAD
+    return {
+        "hc_w": ([layers, 2, n * cfg.hidden_size, k], "float32",
+                 StackedNormal(0.0, (n * cfg.hidden_size) ** -0.5)),
+        "hc_a": ([layers, 2, 3], "float32", Normal(0.6, 0.05)),
+        "hc_b": ([layers, 2, k], "float32", BlockedNormal(
+            columns=[(n, pre), (n, post), (n * n, res)]))}
+
+
 def _latent_stack(cfg: DecoderConfig, mode: str, tok, pos,
                   num_pages: int = 0, page_size: int = 0, **feeds):
     """Append the one `latent_moe_stack` op of a program; returns its
-    outputs (next_token, logits, routes, selection)."""
+    outputs (next_token, logits, routes and, behind an indexer,
+    selection)."""
     helper = LayerHelper("latent_moe_stack")
     params = {key: helper.create_parameter(
         ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
         for key, (shape, dtype, init) in _latent_param_specs(cfg).items()}
     ops = latent_moe_ops
+    shared = ops.attention_params(cfg.selects, cfg.hc_mult)
     inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
               "Head": [params["dec.lm_head"]],
               "FinalNorm": [params["dec.final_norm.scale"]],
               "DenseParams": [params["dense." + k] for k in
-                              ops.ATTENTION_PARAMS + ops.DENSE_PARAMS],
+                              shared + ops.DENSE_PARAMS],
               "MoeParams": [params["moe." + k] for k in
-                            ops.ATTENTION_PARAMS + ops.MOE_PARAMS],
+                            shared + ops.MOE_PARAMS],
               "Experts": [params[k] for k in ops.EXPERT_PARAMS]}
     inputs.update({slot: [var] for slot, var in feeds.items()})
     outs = {slot: [helper.create_variable_for_type_inference(dtype)]
             for slot, dtype in (("NextToken", "int32"),
-                                ("Logits", "float32"), ("Routes", "int32"),
-                                ("Selection", "int32"))}
+                                ("Logits", "float32"), ("Routes", "int32"))
+            + ((("Selection", "int32"),) if cfg.selects else ())}
     if mode != "full":
         declare_stacked_pools(default_main_program().global_block,
                               *_latent_pool_geometry(cfg, num_pages,
                                                      page_size))
-        for slot, name in _LATENT_POOLS:
+        for slot, name in _latent_pools(cfg):
             inputs[slot] = [name]
             outs[slot + "Out"] = [name]
     helper.append_op("latent_moe_stack", inputs, outs,
                      dict(_latent_geometry(cfg), mode=mode,
                           num_pages=int(num_pages)))
-    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
-            "routes": outs["Routes"][0],
-            "selection": outs["Selection"][0]}
+    out = {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
+           "routes": outs["Routes"][0]}
+    if cfg.selects:
+        out["selection"] = outs["Selection"][0]
+    return out
 
 
 def _latent_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
@@ -1256,6 +1363,9 @@ def _latent_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
 
 
 def _latent_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
+    if not cfg.selects:     # no selection to hand back, so no rows to mark
+        return _latent_stack(cfg, "decode", tok, pos, num_pages, page_size,
+                             PageTable=pages, Mask=mask)
     mark = L.data(name=MARK_FEED, shape=[MARK_ROWS], dtype="int32",
                   append_batch_size=False)
     return dict(_latent_stack(cfg, "decode", tok, pos, num_pages, page_size,
@@ -1265,16 +1375,16 @@ def _latent_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
 
 def _latent_full(cfg, tok, pos):
     out = _latent_stack(cfg, "full", tok, pos)
-    return {"logits": out["logits"], "routes": out["routes"],
-            "selection": out["selection"]}
+    del out["next_token"]
+    return out
 
 
 def _latent_cow(cfg, num_pages, page_size, src, dst):
-    # the page's slab of latent rows and its indexer keys, in every layer
+    # the page's slab of latent rows (and its indexer keys), in every layer
     declare_stacked_pools(default_main_program().global_block,
                           *_latent_pool_geometry(cfg, num_pages, page_size))
-    _stacked_copy_page([name for _, name in _LATENT_POOLS], num_pages, src,
-                       dst)
+    _stacked_copy_page([name for _, name in _latent_pools(cfg)], num_pages,
+                       src, dst)
 
 
 # -- the "parallel_ssm" family -----------------------------------------------

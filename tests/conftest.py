@@ -58,9 +58,24 @@ LISTS_THE_REHEARSALS = ("test_benchmark_device_names.py::"
 PINS_PR37S_TAIL = ("test_benchmark_rehearse_falcon.py::"
                    "test_the_new_entries_stand_at_the_end_in_their_order")
 
+# PR 43's own test of the lists its cell joined pins the END of each (the
+# last cell, the last configuration, eleven cells, eight configurations),
+# behind which the contract tells every later PR to append: the same case a
+# third time (PR 47 appended a cell and a configuration and joined sixteen
+# lists). What it asserts besides the tail (the lists the Nemotron cell
+# stands in, what each moves, its chips) is asserted again, for that cell
+# and for the new one, by tests/benchmark/test_benchmark_rehearse_xing.py,
+# which pins no tail and no count.
+PINS_PR43S_TAIL = ("test_benchmark_rehearse_nemotron.py::"
+                   "test_the_cell_stands_at_the_end_of_every_list_it_joined")
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(PINS_PR43S_TAIL):
+            item.add_marker(pytest.mark.xfail(
+                reason="pins the manifest's last cell and configuration to "
+                       "PR 43's; PR 47 appended behind them", strict=True))
         if item.nodeid.endswith(LISTS_THE_REHEARSALS):
             item.add_marker(pytest.mark.xfail(
                 reason="lists the reader's rehearsal configurations by "

@@ -4,7 +4,8 @@ The choice of a kernel lives in code: each lever has ONE dispatch function
 that answers from shapes and dtypes (`attention_ops._paged_arm`,
 `cca_moe_ops._experts_backend`, `sparse_moe_ops.paged_indexer_runs`,
 `parallel_ssm_ops._update_backend`, `parallel_ssm_ops.conv_update_runs`,
-`latent_moe_ops.latent_attend_runs`, `attention_ops.attention_backend`), and with `FLAGS_tuning_mode` off, as in
+`latent_moe_ops.latent_attend_runs`, `latent_moe_ops.paged_attend_runs`,
+`attention_ops.attention_backend`), and with `FLAGS_tuning_mode` off, as in
 every cell, nothing else has a say. This file pins those answers for the
 configurations under `benchmark/configs/` (read, never written).
 
@@ -81,6 +82,8 @@ LEVERS = {
                     r"conv_decode_update "),
     "latent_attend": (((latent_moe_ops, "latent_attend_runs"),), bool,
                       r"latent_rows_attention "),
+    "paged_latent_attend": (((latent_moe_ops, "paged_attend_runs"),), bool,
+                            r"paged_latent_attention "),
     "attention": (((attention_ops, "attention_backend"),),
                   lambda chosen: chosen[0] != "xla",
                   r"(short_seq|short128)_attention|flash"),
@@ -158,13 +161,10 @@ def _pallas_calls(jaxpr, found: list) -> list:
     return found
 
 
-@functools.lru_cache(maxsize=None)
-def _traced(name: str, program: str, size):
-    """(the Pallas calls of the program under the names a device trace
-    lists them by, {dispatch function: whether each answer took the
-    kernel}) of one program of a configuration, traced for the chip."""
-    block, fed, rows = _training_program(name, *size) \
-        if program == "train" else _serving_program(name, program, size)
+def _read_shapes(block, fed: dict, rows: int) -> dict:
+    """{variable: ShapeDtypeStruct} of what the program reads and no op of
+    it wrote (-1 dimensions are `rows`; `fed` names the feeds whose shapes
+    the caller chose)."""
     env, written = {}, set()
     for op in block.ops:            # what the program reads and no op wrote
         for var in (v for names in op.inputs.values() for v in names):
@@ -175,6 +175,17 @@ def _traced(name: str, program: str, size):
                                           for d in v.shape),
                     v.np_feed_dtype)
         written.update(v for names in op.outputs.values() for v in names)
+    return env
+
+
+@functools.lru_cache(maxsize=None)
+def _traced(name: str, program: str, size):
+    """(the Pallas calls of the program under the names a device trace
+    lists them by, {dispatch function: whether each answer took the
+    kernel}) of one program of a configuration, traced for the chip."""
+    block, fed, rows = _training_program(name, *size) \
+        if program == "train" else _serving_program(name, program, size)
+    env = _read_shapes(block, fed, rows)
     answers = {}
 
     def watched(fn, took, seen):
@@ -255,6 +266,14 @@ CASES = [
         "full_attention": "paged_decode_attention_gqa f32[128,32,128]"},
         [(t, {"experts": f"moe_relu2_experts_prefill f32[{t},1024]"})
          for t in (128, 512)]),
+    # xing4_29b_a4b.docs32k.sat (PR 47's traced run): no indexer, so a
+    # decode row's pages are read in place; a window attends in the
+    # expanded form (XLA) and asks neither attention kernel
+    *_serving("xing4_29b_a4b", 64, {
+        "experts": "moe_topk_experts_decode f32[64,3584]",
+        "paged_latent_attend": "paged_latent_attention f32[64,32,512]"},
+        [(t, {"experts": f"moe_topk_experts_prefill f32[{t},3584]"})
+         for t in (128, 2048)]),
     # bert_base.s128 (and .dp4: the same rows a chip) and .s512
     ("bert_base", "attention", "train", (128, 128), "xla"),
     ("bert_base", "attention", "train", (32, 512), "xla"),
@@ -276,6 +295,9 @@ CASES = [
     *_serving("rehearse_deepseek", 4,
               {"experts": "xla", "indexer": "xla", "latent_attend": "xla"},
               [(16, {"experts": "xla", "latent_attend": "xla"})]),
+    *_serving("rehearse_xing", 4,
+              {"experts": "xla", "paged_latent_attend": "xla"},
+              [(16, {"experts": "xla"})]),
     *_serving("rehearse_nemotron", 4,
               {"ssm_update": "xla", "conv_update": "xla", "experts": "xla",
                "full_attention": "xla"}, [(8, {"experts": "xla"})]),

@@ -564,6 +564,107 @@ def test_latent_moe_stack_compiled_for_v5e_moves_neither_pool(v5e_chip,
             and "bf16[64,2048,512]" not in text
 
 
+def test_latent_streams_stack_compiled_for_v5e_reads_its_pages_in_place(
+        v5e_chip, monkeypatch):
+    """The "latent_moe" stack WITHOUT an indexer and with four residual
+    streams at the served widths (benchmark/configs/xing4_29b_a4b.json: 32
+    heads of 128 + 64 over a 512-value latent, 64 experts of width 1,024 at
+    hidden 3,584), a dense and a routed layer deep, over a pool as large as
+    the cell's (7 x 1,792 pages, ONE pool), from SHAPES alone: the decode
+    step at 64 rows and windows of 256 and 2,048 tokens, all behind the
+    cell's 288-page tables. Mosaic takes the paged attention kernel (a
+    layer kind each); the pool is not copied, no cached row is gathered in a
+    decode step, and a window gathers its pages once and never holds the
+    `[heads, queries, 36,864]` scores (its keys run in blocks of 512)."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops import latent_moe_ops as ops
+    from paddle_tpu.ops import sparse_moe_ops
+    from paddle_tpu.ops.pallas_kernels import workbench
+    from paddle_tpu.serving import kv_cache
+    from paddle_tpu.serving import model as sv_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "xing4_29b_a4b.json")) as f:
+        engine = json.load(f)["engine"]
+    served = DecoderConfig(**engine["config_kwargs"])
+    cfg = DecoderConfig(**dict(engine["config_kwargs"], num_layers=2,
+                               dense_layers=1))
+    pages, ps = engine["pool_pages"], engine["page_size"]
+    one_chip = SingleDeviceSharding(v5e_chip)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {key: shape(dims, dtype) for key, (dims, dtype, _) in
+              sv_model._latent_param_specs(cfg).items()}
+    pools = tuple(shape(dims, dtype) for _, dims, dtype in
+                  kv_cache.stacked_pool_shapes(
+                      *sv_model._latent_pool_geometry(served, pages, ps)))
+    assert [p.shape for p in pools] == [(7 * pages, ps, 384)]
+    geom = ops.Geometry(**sv_model._latent_geometry(cfg))
+    shared = ops.attention_params(False, cfg.hc_mult)
+    assert "wqi" not in shared and shared[-3:] == ("hc_w", "hc_a", "hc_b")
+    weights = (params["dec.word_emb"], params["dec.lm_head"],
+               params["dec.final_norm.scale"],
+               {k: params["dense." + k] for k in shared + ops.DENSE_PARAMS},
+               {k: params["moe." + k] for k in shared + ops.MOE_PARAMS},
+               tuple(params[k] for k in ops.EXPERT_PARAMS))
+    monkeypatch.setattr(sparse_moe_ops, "_experts_backend",
+                        lambda *a: "pallas")
+    monkeypatch.setattr(workbench, "on_tpu", lambda: True)
+
+    def compiled(mode, tok_shape, rows):
+        def step(tok, pos, weights, pools, table, lens, start, mask):
+            return ops.latent_moe_stack_fn(
+                mode, tok, pos, *weights, geom, pools=pools,
+                page_table=table, lens=lens, start=start, mask=mask,
+                num_pages=pages)
+
+        i32 = "int32"
+        return jax.jit(step, donate_argnums=(3,)).lower(
+            shape(tok_shape, i32), shape(tok_shape, i32), weights, pools,
+            shape((rows, 288), i32), shape((rows,), i32),
+            shape((rows,), i32), shape((rows, 1), "float32")).compile()
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        programs = {"decode": compiled("decode", (64,), 64),
+                    "window256": compiled("window", (1, 256), 1),
+                    "window2048": compiled("window", (1, 2048), 1)}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    texts = {name: c.as_text() for name, c in programs.items()}
+    for name, text in texts.items():
+        assert not pool_sized_copies(text, 7 * pages * ps * 384), name
+        assert "tpu_custom_call" in text and "moe_topk_experts" in text
+        assert "f32[1,32,256,36864]" not in text \
+            and "f32[1,32,2048,36864]" not in text, name
+    # a decode row's pages are read where they lie, by the kernel (the dense
+    # layer's call and the scanned layer's); no token row is gathered and no
+    # page slab either
+    assert {name: kernel_calls(text, "paged_latent_attention")
+            for name, text in texts.items()} \
+        == {"decode": 2, "window256": 0, "window2048": 0}
+    assert not token_row_gathers(texts["decode"], 384)
+    assert "s32[64,288,128,384]" not in texts["decode"]
+    # what a step holds besides weights and pool: under 64 MB for the
+    # decode step, under 600 MB for a 2,048-token window
+    temp = {name: c.memory_analysis().temp_size_in_bytes
+            for name, c in programs.items()}
+    assert temp["decode"] < 64e6 and temp["window2048"] < 600e6, temp
+
+
 def test_token_row_gathers_counts_rows_not_slabs():
     """Recorded from the v5e's compiler: PR 29's decode layer fetched a
     selected token from two pools, PR 30's from one; a page's slab of

@@ -1,0 +1,67 @@
+"""The serving programs of every configuration the benchmark had before
+PR 47, traced for the chip at their benchmark shapes, hash to what they
+hashed to at PR 46 (sha256 of `str(jaxpr)`): the "latent_moe" block learnt
+to run without an indexer and with several residual streams, and every
+older family's decode and window programs, DeepSeek-V3.2's one-stream
+path through the same stack among them, are byte for byte the programs they
+were.
+
+The programs are built as `tests/test_kernel_choice.py` builds them (from
+the configuration's own `engine` block, from SHAPES alone, with
+`workbench.on_tpu` answering True so that the Pallas calls are in them).
+`HASHES` was computed by this file's `program_hash` in a checkout of commit
+757cdc2 (PR 46) and in this tree; a change that means to alter one of
+these programs recomputes its line and says so.
+"""
+import hashlib
+from unittest import mock
+
+import jax
+import pytest
+
+from paddle_tpu import executor
+from paddle_tpu.ops.pallas_kernels import workbench
+from tests.test_kernel_choice import _read_shapes, _serving_program
+
+# (configuration, program, rows or tokens) -> sha256(str(jaxpr))[:16]
+HASHES = {
+    ("bert_base_decoder", "decode", "64"): "67f5d72e4030401e",
+    ("bert_base_decoder", "prefill", "256"): "dc7d3bcbe1dcf965",
+    ("zaya1_8b", "decode", "64"): "f6405da0a9cf5a43",
+    ("zaya1_8b", "prefill", "256"): "5d2e4391d41ad57e",
+    ("keye_vl2_30b_a3b", "decode", "64"): "9a764176abb865e3",
+    ("keye_vl2_30b_a3b", "prefill", "128"): "6d1544e0c1ceb870",
+    ("laguna_xs2", "decode", "64"): "f26685738caaafda",
+    ("laguna_xs2", "prefill", "256"): "20d0e440cb6377c5",
+    ("falcon_h1_34b", "decode", "64"): "12d62a8745015e01",
+    ("falcon_h1_34b", "prefill", "512"): "26b70b79f4585ee8",
+    ("deepseek_v32_exp", "decode", "128"): "0587bbd89e2a8e47",
+    ("deepseek_v32_exp", "decode", "32"): "36487f961f762836",
+    ("deepseek_v32_exp", "prefill", "128"): "cfe584788426341a",
+    ("deepseek_v32_exp", "prefill", "512"): "affbedfc12fc5867",
+    ("nemotron3_super_120b", "decode", "128"): "6103670d68e2d4ba",
+    ("nemotron3_super_120b", "prefill", "512"): "6509dd8f853f64cd",
+}
+
+
+def program_hash(name: str, program: str, size: int) -> str:
+    block, fed, rows = _serving_program(name, program, size)
+    env = _read_shapes(block, fed, rows)
+    with mock.patch.object(workbench, "on_tpu", lambda: True):
+        jaxpr = jax.make_jaxpr(
+            lambda env: executor._run_ops_traced(block, dict(env)))(env)
+    return hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("case", sorted(HASHES), ids="-".join)
+def test_an_older_familys_program_is_the_parents(case):
+    name, program, size = case
+    assert program_hash(name, program, int(size)) == HASHES[case]
+
+
+if __name__ == "__main__":
+    import sys
+    for line in sys.argv[1:]:
+        name, program, size = line.split(":")
+        print(f'    ("{name}", "{program}", "{size}"): '
+              f'"{program_hash(name, program, int(size))}",', flush=True)

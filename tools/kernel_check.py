@@ -507,7 +507,98 @@ def _latent_attend_cases(spec):
              lambda R=R: case(R)) for R in (128, 64)]
 
 
+def _paged_latent_cases(spec):
+    """The paged latent attention at the Xing4.0 cell's shapes: 32 heads
+    over a 512-value latent and a 64-lane rotary key in rows of 384 words,
+    pages of 128 in layer 1's rows of a two-layer pool. 16 decode rows
+    behind the cell's 288-page table (rows behind one document share its
+    pages; the XLA reference gathers 0.9 GB for them) and 64 rows, a decode
+    step's, behind 32 pages: lengths on both sides of a page's, a chunk's
+    and a block's edge, a row of ONE position and a row with no context
+    (zeros), the rows' padding words holding NaN patterns. Then THE CELL'S
+    DECODE STEP, timed: 64 rows of 33,000 positions behind four documents'
+    288-page tables (`_timed`: ten calls after the first; `ms` a call and
+    `roofline_pct`, the rows' 1,152 B each against the chip's 819 GB/s, the
+    configuration's `kernel_bytes.latent_row_bytes`), its first eight rows
+    against the reference."""
+    from paddle_tpu.ops import latent_moe_ops as ops
+    from paddle_tpu.serving import DecoderConfig
+    from paddle_tpu.serving import model as sv_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "xing4_29b_a4b.json")) as f:
+        config = json.load(f)
+    cfg = DecoderConfig(**config["engine"]["config_kwargs"])
+    row_bytes = config["kernel_bytes"]["latent_row_bytes"]
+    geom = ops.Geometry(**sv_model._latent_geometry(cfg))
+    assert (geom.num_heads, geom.kv_rank, geom.rope_dim) == (32, 512, 64)
+
+    def case(B, P):
+        ps, pages, words = 128, 320, 384
+        ks = jax.random.split(jax.random.PRNGKey(47), 5)
+        pool = jax.jit(lambda c, r: ops.join_latent_fn(
+            c, r, jnp.bfloat16, words).at[..., 288:].set(0x7FC1FFFF))(
+                _rand(ks[0], (2 * pages, ps, 512), "float32"),
+                _rand(ks[1], (2 * pages, ps, 64), "float32"))
+        q_lat = _rand(ks[2], (B, 32, 512), "float32", 0.5)
+        q_rope = _rand(ks[3], (B, 32, 64), "float32", 0.5)
+        doc = jax.random.permutation(ks[4], pages)[:P]
+        table = jnp.stack([doc if b % 2 else doc[::-1] for b in range(B)])
+        table = (table + pages).astype(jnp.int32)       # layer 1's rows
+        edges = [P * ps, (P - 1) * ps + 1, P * ps // 2, P * ps // 2 + 1,
+                 8 * ps - 1, 513, 1, 0]
+        lens = jnp.asarray([edges[b % 8] if b < 8 else
+                            (b * 977) % (P * ps) + 1 for b in range(B)],
+                           jnp.int32)
+        assert spec.supported(q_lat.shape, pool.shape, jnp.bfloat16,
+                              geom.rope_dim)
+        args = (q_lat, q_rope, pool, table, lens, jnp.bfloat16, geom)
+        res = _compare(spec.fn, spec.reference, args, 0, "bfloat16")
+        got = spec.fn(*args)
+        res["zeros"] = bool(not np.asarray(got)[np.asarray(lens) == 0].any())
+        res["ok"] = bool(res["ok"] and res["zeros"])
+        return res
+
+    def timed():
+        import time
+        B, P, ps, pages, words, n = 64, 288, 128, 4 * 288, 384, 33000
+        ks = jax.random.split(jax.random.PRNGKey(48), 4)
+        pool = jax.jit(lambda c, r: ops.join_latent_fn(
+            c, r, jnp.bfloat16, words))(
+                _rand(ks[0], (2 * pages, ps, 512), "float32"),
+                _rand(ks[1], (2 * pages, ps, 64), "float32"))
+        q_lat = _rand(ks[2], (B, 32, 512), "float32", 0.5)
+        q_rope = _rand(ks[3], (B, 32, 64), "float32", 0.5)
+        doc = jnp.arange(P, dtype=jnp.int32)
+        table = jnp.stack([doc + P * (b % 4) for b in range(B)]) + pages
+        lens = jnp.full((B,), n, jnp.int32)
+        fn = jax.jit(lambda *a: spec.fn(*a, jnp.bfloat16, geom))
+        got = fn(q_lat, q_rope, pool, table, lens).block_until_ready()
+        t = time.perf_counter()
+        for _ in range(10):
+            out = fn(q_lat, q_rope, pool, table, lens)
+        out.block_until_ready()
+        seconds = (time.perf_counter() - t) / 10
+        with jax.default_matmul_precision("highest"):
+            want = spec.reference(q_lat[:8], q_rope[:8], pool, table[:8],
+                                  lens[:8], jnp.bfloat16, geom)
+        res = {"err": _rel_err(got[:8], want), "tol": TOL["bfloat16"],
+               "finite": bool(np.isfinite(np.asarray(got)).all()),
+               "ms": seconds * 1e3,
+               "roofline_pct": B * n * row_bytes / seconds / 819e9 * 100}
+        res["ok"] = bool(res["finite"] and res["err"] <= res["tol"])
+        return res
+
+    return [(f"b{B} nh32 latent512 rope64 ps128 words384 bfloat16 table {P} "
+             "ragged shared pages", lambda B=B, P=P: case(B, P))
+            for B, P in ((16, 288), (64, 32))] + [
+        ("b64 nh32 latent512 rope64 ps128 words384 bfloat16 table 288 "
+         "33000 positions a row timed", timed)]
+
+
 CASES = {
+    "paged_latent_attention": _paged_latent_cases,
     "latent_rows_attention": _latent_attend_cases,
     "indexer_paged_scores": _paged_indexer_cases,
     "ssm_decode_update": _ssm_update_cases,
