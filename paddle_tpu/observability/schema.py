@@ -343,6 +343,13 @@ DECLARED: list[tuple] = [
      "(paged_latent_attention); 0 on the XLA arm (over sparse.layer_steps "
      "where every step selects or none does: how often the kernel "
      "engages)", ()),
+    ("serving.latent.pages_read", COUNTER,
+     "pool pages the absorbed attention of decode rows WITHOUT an indexer "
+     "fetched, summed over layers and steps: under paged_latent_attention "
+     "a run of pages that rows share counted once (its row_groups over the "
+     "step's tables and lengths), on the XLA arm every live page of every "
+     "row (attended_tokens over pages_read x page_size: the sharing the "
+     "steps got, 1 where each row reads its own)", ()),
     # -- a residual path of several streams (ISSUE 47) ----------------------
     ("serving.hc.mix_tokens", COUNTER,
      "(token, sub-layer) pairs whose residual streams were mapped and "

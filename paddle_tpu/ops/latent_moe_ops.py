@@ -61,7 +61,9 @@ attends every live position at ANY context: a window in the expanded form
 over key blocks with a running softmax (`expanded_attention_blocks_fn`), a
 decode row in the absorbed form over all its pages, read in place by
 `pallas_kernels.paged_latent_attend` wherever `paged_attend_runs` takes the
-shapes (the gathered slabs and `absorbed_attention_fn` elsewhere). WITH
+shapes (the gathered slabs and `absorbed_attention_fn` elsewhere); rows
+whose tables begin with the same pages read that run once, which the stack
+works out of the step's tables before its layers (`step_plan`). WITH
 `hc_mult` > 1 RESIDUAL STREAMS the layers hand on `[n, B, S, H]` float32
 and every sub-layer sits between `hyper_connection_ops`' mappings and mixes
 (`_mixed_input`; pieces `hc_map`, `hc_mix`); the embedding is copied into
@@ -491,19 +493,21 @@ def _attend_selected(q_nope, q_rope, pool, table, sel, wkv_b, dtype,
 
 
 def _attend_pages(q_nope, q_rope, pool, table, lens, wkv_b, dtype,
-                  geom: Geometry):
+                  geom: Geometry, plan=None):
     """The absorbed form for B decode rows, each over ALL the pages of its
     table [B, P] (shifted to the layer's rows; lens [B] live positions, 0: a
-    padding row), read where they lie in the pool -> [B, nh, v] float32:
+    padding row), read where they lie in the pool, a run of pages that rows
+    share once for all of them -> [B, nh, v] float32:
     `pallas_kernels.paged_latent_attend` (callers gate on
-    `paged_attend_runs`)."""
+    `paged_attend_runs`; `plan`: its `step_plan` of the step's tables, the
+    same for every layer)."""
     from .pallas_kernels import paged_latent_attend
 
     with piece("q_absorb"):
         q_lat = absorb_queries_fn(q_nope, wkv_b, geom)
     with piece("attend"):
         u = paged_latent_attend.paged_latent_attention(
-            q_lat, q_rope, pool, table, lens, dtype, geom)
+            q_lat, q_rope, pool, table, lens, dtype, geom, plan)
     with piece("q_absorb"):
         return expand_values_fn(u, wkv_b, geom)
 
@@ -599,6 +603,17 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
     else:
         gpos = jnp.broadcast_to(rel, (B, S))
         context = S
+    # a decode row without an indexer reads every page of its table where it
+    # lies; which rows read the same pages is the tables' alone, worked out
+    # once for all layers
+    in_place = decode and not indexed and paged_attend_runs(
+        (B, nh, geom.kv_rank), pools[0].shape, dtype, geom.rope_dim)
+    if in_place:
+        from .pallas_kernels import paged_latent_attend
+
+        with piece("attend"):
+            plan = paged_latent_attend.step_plan(
+                page_table, (first + 1) * count, pools[0].shape)
     if streams > 1:
         with piece("embed"):
             x = hc.spread_fn(x, streams)                        # [n, B, S, H]
@@ -623,13 +638,10 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                     i_pool = write_index_keys_fn(i_pool, ki, page_table,
                                                  off, first, count)
         sel = None
-        if paged and whole and decode and not indexed and paged_attend_runs(
-                (B, nh, geom.kv_rank), latent_pool.shape, dtype,
-                geom.rope_dim):
-            # every page of the row's table, read where it lies
+        if in_place:
             o = _attend_pages(q_nope[:, 0], q_rope[:, 0], latent_pool, table,
-                              (first + 1) * count, p["wkv_b"], dtype,
-                              geom)[:, None]
+                              (first + 1) * count, p["wkv_b"], dtype, geom,
+                              plan)[:, None]
         elif paged and whole:
             at = jnp.arange(context, dtype=jnp.int32)
             live = at[None, None, :] <= gpos[:, :, None]        # [B, S, T]

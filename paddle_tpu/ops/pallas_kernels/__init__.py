@@ -21,10 +21,13 @@ the key pool, where XLA gathered the pages and wrote the per-head scores;
 `sparse_moe_ops.decode_scores_fn`, its shape gate the only switch),
 `latent_attend` (the absorbed latent attention over each query's gathered
 cache rows, unpacked in VMEM; `latent_moe_ops._attend_rows`, its shape gate
-the only switch), `paged_latent_attend` (the same attention for a decode
-row of a configuration WITHOUT an indexer: all the pages of its table, read
-in place from the latent pool, an online softmax across blocks of pages;
-`latent_moe_ops._attend_pages`, its shape gate the only switch),
+the only switch), `paged_latent_attend` (the same attention for the decode
+rows of a configuration WITHOUT an indexer: all the pages of each row's
+table, read in place from the latent pool, ONE grid step over a flat list
+of page blocks; rows whose tables begin with the same pages attend that run
+once, their heads stacked on the matrix unit's rows, an online softmax in
+scratch resident for all rows; `latent_moe_ops._attend_pages`, its shape
+gate the only switch and the page table the only word on what is shared),
 `moe_experts` (the routed experts' stream), `ssm_update` (a decode
 token's state update in place in its slot) and `conv_update` (the same
 token's causal convolution, its tail moved on in place in its slot of the

@@ -785,7 +785,7 @@ class ServingEngine:
             "sparse.kernel_layer_steps": 0,
             # a latent cache row and a share of the experts (ISSUE 39)
             "latent.gathered_rows": 0, "latent.attended_tokens": 0,
-            "latent.attend_kernel_layer_steps": 0,
+            "latent.attend_kernel_layer_steps": 0, "latent.pages_read": 0,
             # a residual path of several streams (ISSUE 47)
             "hc.mix_tokens": 0,
             "moe.routed_pairs": 0, "moe.held_pairs": 0,
@@ -2709,8 +2709,20 @@ class ServingEngine:
                 else len(at) * pb * ps))
             self._count("latent.attended_tokens", L * sum(
                 min(k, pos + 1) if select else pos + 1 for pos, _ in at))
+            kernel = self._attend_kernel(bb, pb)
             self._count("latent.attend_kernel_layer_steps",
-                        L if self._attend_kernel(bb, pb) else 0)
+                        L if kernel else 0)
+            if not self.cfg.selects:
+                from ..ops.pallas_kernels import paged_latent_attend
+
+                # pool pages the rows' attention fetched: the kernel reads
+                # a run of pages that rows share once for all of them (its
+                # own rule over these feeds), the XLA arm each row's
+                self._count("latent.pages_read", L * (
+                    paged_latent_attend.pages_read(
+                        pages, (pos + 1) * mask[:, 0].astype(np.int32),
+                        self._scope.find_var(LATENT_POOL).shape)
+                    if kernel else sum(pos // ps + 1 for pos, _ in at)))
         handles = self._run_step("decode", self._decode_run, self._decode_io,
                                  feed, greedy, selection=bool(marked))
         self._enqueued(_InFlight("decode", rows, at=at, marked=marked,

@@ -87,7 +87,8 @@ forms: behind an indexer, or read whole under several residual streams), and
     WITHOUT AN INDEXER (`index_heads`, `index_head_dim`, `index_topk` all
     0): every query attends every cached row at or before it, a window in
     the expanded form over key blocks, a decode row in the absorbed form
-    over ALL its pages, read in place (`pallas_kernels.paged_latent_attend`);
+    over ALL its pages, read in place and a run of pages that rows share
+    once for all of them (`pallas_kernels.paged_latent_attend`);
     no `kv_cache.INDEX_POOL` is allocated, shared, copied on write or
     audited, the indexer's five parameters do not exist and no `selection`
     is handed back (`DecoderConfig.selects` is False, `.latent` True).

@@ -370,7 +370,7 @@ def _interpreted():
 
 @pytest.mark.parametrize("pages,lens", [
     (16, [1, 0, 700, 2048, 1025]),      # two blocks of 8 pages; a padding row
-    (4, [512, 129, 3]),                 # one block, chunks of 4 pages
+    (4, [512, 129, 3]),                 # one block, one chunk
     (1, [128, 5]),                      # a table of one page
     (96, [0, 12288, 9000, 1])])         # twelve blocks; the first row padded
 def test_paged_latent_attention_pallas_matches_reference(pages, lens):
@@ -393,6 +393,132 @@ def test_paged_latent_attention_pallas_matches_reference(pages, lens):
     # here and after it there
     np.testing.assert_allclose(got, want, atol=0.02)
     assert not np.asarray(got[np.asarray(lens) == 0]).any()
+
+
+def _behind(doc, run, own):
+    """A table row of 32 entries: the first `run` pages of document `doc`
+    (pages 40 * doc ..), then the pages `own`."""
+    row = np.zeros(32, np.int32)
+    row[:run] = 40 * doc + np.arange(run)
+    row[run:run + len(own)] = own
+    return row
+
+
+def _shared_cases():
+    """name -> (table [B, 32], lens, the run each row must be found to
+    share): tables of 32 entries, so a block is 8 pages and a tile 8 rows
+    (or all of them)."""
+    ps = 128
+    # groups of uneven size behind runs of 8, 16 and 24 pages, tails of 1-3
+    # pages of their own; ten rows behind document 0 (a whole tile and a
+    # rest), a group of one (document 3) and a padding row among them
+    rows, lens, runs = [], [], []
+    own = iter(range(200, 360))
+    take = lambda n: [next(own) for _ in range(n)]           # noqa: E731
+    for doc, run, members in ((0, 8, 10), (1, 16, 3), (2, 24, 2), (3, 9, 1)):
+        for i in range(members):
+            tail = 1 + (i + doc) % 3
+            rows.append(_behind(doc, run, take(tail)))
+            lens.append((run + tail) * ps - (37 * i + 5) % ps)
+            runs.append(run if members > 1 else 0)
+    rows.insert(4, _behind(0, 8, take(2)))     # a padding row in a span
+    lens.insert(4, 0)
+    runs.insert(4, 0)
+    mix = np.random.default_rng(0).permutation(len(rows))
+    uneven = (np.stack(rows)[mix], np.asarray(lens)[mix],
+              np.asarray(runs)[mix])
+    same = np.stack([_behind(0, 21, [])] * 2)
+    return {
+        "uneven groups": uneven,
+        # one table, and the second row ends INSIDE what the first would
+        # share: the run stops at its last whole page, and at a block's end
+        "a row ends inside": (same, [20 * ps + 5, 11 * ps + 7], [8, 8]),
+        "a row ends before a block": (same, [20 * ps + 5, 7 * ps + 9],
+                                      [0, 0]),
+        # equal from entry 1 on only
+        "not from the first page": (
+            np.stack([_behind(0, 20, []),
+                      np.concatenate([[399], _behind(0, 20, [])[1:]])]),
+            [20 * ps, 19 * ps + 3], [0, 0]),
+        # 13 pages in common: one block is shared, five pages are tail
+        "not a multiple of the block": (
+            np.stack([_behind(0, 13, [300, 301, 302]),
+                      _behind(0, 13, [310, 311]),
+                      _behind(0, 13, [320])]),
+            [16 * ps, 14 * ps + 77, 13 * ps + 1], [8, 8, 8]),
+    }
+
+
+@pytest.mark.parametrize("name", list(_shared_cases()))
+def test_paged_latent_attention_behind_shared_pages_matches_reference(name):
+    """Rows whose tables begin with the same pages attend that run once,
+    stacked; what each gets is what it gets alone."""
+    table, lens, runs = _shared_cases()[name]
+    geom = _geometry()
+    rng = np.random.default_rng(len(name))
+    pool = _pool(rng, 400, 128, geom, 256)
+    B = len(lens)
+    groups = paged_latent_attend.row_groups(
+        table, np.asarray(lens), 128, 8, np)
+    assert paged_latent_attend.pages_per_grid_step(32, 128 * 256 * 4) == 8
+    assert list(groups.run[np.argsort(groups.order)]) == list(runs)
+    table, lens = jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32)
+    q_lat = jnp.asarray(rng.standard_normal((B, 8, 256)), jnp.float32)
+    q_rope = jnp.asarray(rng.standard_normal((B, 8, 64)), jnp.float32)
+    with _interpreted():
+        got = paged_latent_attend.paged_latent_attention(
+            q_lat, q_rope, pool, table, lens, jnp.bfloat16, geom)
+    want = paged_latent_attend._reference(q_lat, q_rope, pool, table, lens,
+                                          jnp.bfloat16, geom)
+    np.testing.assert_allclose(got, want, atol=0.02)
+    assert not np.asarray(got[np.asarray(lens) == 0]).any()
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_row_groups_against_a_count_by_hand(seed):
+    """The grouping on random tables (rows behind a few documents for
+    random stretches, random lengths, padding rows) against loops: every
+    member of a group holds the group's run in whole pages and in its
+    leader's ids, no run is shorter than a block or not whole blocks, a row
+    that could join a lower row's group has; the pages fetched are the
+    distinct (group, page) pairs; and jax.numpy gives what numpy gives."""
+    rng = np.random.default_rng(seed)
+    B, P, ps, G = 24, 32, 16, 4
+    docs = rng.integers(0, 1000, (3, P))
+    table = rng.integers(1000, 2000, (B, P))
+    for b in range(B):
+        n = rng.integers(0, P + 1)
+        table[b, :n] = docs[rng.integers(0, 3), :n]
+    lens = rng.integers(0, P * ps + 1, B) * (rng.random(B) > 0.15)
+    g = paged_latent_attend.row_groups(table, lens, ps, G, np)
+    for a, b in zip(g, paged_latent_attend.row_groups(
+            jnp.asarray(table), jnp.asarray(lens), ps, G)):
+        assert np.array_equal(a, b)
+    assert sorted(g.order) == list(range(B))
+    fetched = set()
+    for i, b in enumerate(g.order):
+        lead = g.order[g.first[i]]
+        members = [r for r in range(B) if np.array_equal(
+            table[r, :G], table[lead, :G]) and min(lens[r], lens[lead])
+            >= G * ps]
+        if lead != b or len(members) > 1:
+            assert b in members and lead == members[0]
+            assert g.count[i] == len(members)
+            run = min(min(lens[r] // ps, next(
+                (j for j in range(P) if table[r, j] != table[lead, j]), P))
+                for r in members) // G * G
+            assert g.run[i] == run >= G
+            assert list(g.order[g.first[i]:g.first[i] + g.count[i]]) \
+                == members
+        else:
+            assert (g.count[i], g.run[i], g.first[i]) == (1, 0, i)
+        assert g.pages[i] == -(-lens[b] // ps)
+        fetched |= {(lead, j) for j in range(g.run[i])}
+        fetched |= {(b, j) for j in range(g.run[i], g.pages[i])}
+    pool_shape = (4000, ps, 4688)       # pages of which a block takes 4
+    assert paged_latent_attend.pages_per_grid_step(P, ps * 4688 * 4) == G
+    assert paged_latent_attend.pages_read(table, lens, pool_shape) \
+        == len(fetched)
 
 
 def test_the_paged_kernel_agrees_with_the_expanded_form():
@@ -428,7 +554,7 @@ def test_the_gate_refuses_what_the_kernel_cannot_take():
     assert not supported((64, 30, 512), (7 * 2304, 128, 384), jnp.bfloat16,
                          64)
     assert paged_latent_attend.pages_per_grid_step(288, 128 * 384 * 4) == 8
-    assert paged_latent_attend.chunk_pages(8, 128) == 4
+    assert paged_latent_attend.chunk_pages(8, 128) == 8
 
 
 @pytest.mark.parametrize("T,S", [(24, 24), (64, 16), (96, 5)])
@@ -474,3 +600,48 @@ def test_the_paged_kernel_serves_what_the_gathered_form_served():
     for a, b in zip(got, want):
         assert a.out_tokens == b.out_tokens
         assert np.array_equal(a.routes, b.routes)
+
+
+def test_the_pages_read_follow_the_arm_that_ran(monkeypatch):
+    """Three rows behind one prompt of eight whole pages (the prefix cache
+    hands them the same pages), each with a question of its own. Where the
+    kernel's gate answers yes `serving.latent.pages_read` is what the
+    kernel's grouping says of every step's feeds, a layer: the shared run
+    once, so fewer pages than the rows' tables hold and a sharing above 1;
+    on the XLA arm every live page of every row, a sharing of at most 1."""
+    cfg = sv_model.latent_streams_tiny(
+        dtype="bfloat16", num_heads=8, kv_lora_rank=256, rope_head_dim=8,
+        max_position=2048, num_layers=3, dense_layers=1, prefill_chunk=256)
+    shared = _prompts(5, 8 * 128 + 9)[0]
+    prompts = _prompts(6, 3, 11, 40, shared=shared)
+    kw = dict(page_size=128, pool_pages=24, max_inflight=3)
+    said = []
+    count = paged_latent_attend.pages_read
+
+    def spied(*feeds):
+        said.append(count(*feeds))
+        return said[-1]
+
+    monkeypatch.setattr(paged_latent_attend, "pages_read", spied)
+
+    def served():
+        eng = _engine(cfg, **kw)
+        _serve(eng, [shared], new=1)        # the prompt's pages are cached
+        eng.reset_stats()
+        _serve(eng, prompts, new=4)
+        st = eng.stats
+        return st, st["latent.attended_tokens"] / (
+            st["latent.pages_read"] * 128)
+
+    st, sharing = served()
+    assert not said and st["latent.attend_kernel_layer_steps"] == 0
+    assert st["latent.pages_read"] == 3 * st["decode_context_pages"] > 0
+    assert 0.8 < sharing <= 1
+    with _interpreted():
+        st, sharing = served()
+    assert st["latent.attend_kernel_layer_steps"] \
+        == st["sparse.layer_steps"] > 0
+    assert st["latent.pages_read"] == 3 * sum(said) \
+        < 3 * st["decode_context_pages"]
+    assert sharing > 1.5
+

@@ -516,12 +516,22 @@ def _paged_latent_cases(spec):
     step's, behind 32 pages: lengths on both sides of a page's, a chunk's
     and a block's edge, a row of ONE position and a row with no context
     (zeros), the rows' padding words holding NaN patterns. Then THE CELL'S
-    DECODE STEP, timed: 64 rows of 33,000 positions behind four documents'
-    288-page tables (`_timed`: ten calls after the first; `ms` a call and
-    `roofline_pct`, the rows' 1,152 B each against the chip's 819 GB/s, the
-    configuration's `kernel_bytes.latent_row_bytes`), its first eight rows
-    against the reference."""
+    DECODE STEP, timed: 64 rows behind four documents of 256 whole pages
+    (34, 15, 9 and 6 rows a document), two pages of its own a row, 32,768 +
+    1..256 positions (`timed`: ten calls after the first; `ms` a layer's
+    call given the step's plan and `ms_alone` with the plan worked out in
+    it; `sharing`, attended positions over fetched ones; `mxu_pct`, the
+    configuration's `kernel_bytes.latent_row_flops` a position against the
+    chip's 197 TFLOP/s; `roofline_pct`, 1,152 B a position against 819
+    GB/s, which bounds a kernel that reads a page once a row and which one
+    that shares may pass), its first eight rows against the reference; and
+    the same step with every row behind a document of its OWN, and the two
+    shapes above with tables that differ from their first entry on, where
+    nothing is shared and the time is the walk's."""
+    import time
+
     from paddle_tpu.ops import latent_moe_ops as ops
+    from paddle_tpu.ops.pallas_kernels import paged_latent_attend
     from paddle_tpu.serving import DecoderConfig
     from paddle_tpu.serving import model as sv_model
 
@@ -531,20 +541,28 @@ def _paged_latent_cases(spec):
         config = json.load(f)
     cfg = DecoderConfig(**config["engine"]["config_kwargs"])
     row_bytes = config["kernel_bytes"]["latent_row_bytes"]
+    row_flops = config["kernel_bytes"]["latent_row_flops"]
     geom = ops.Geometry(**sv_model._latent_geometry(cfg))
     assert (geom.num_heads, geom.kv_rank, geom.rope_dim) == (32, 512, 64)
+    ps, words = 128, 384
 
-    def case(B, P):
-        ps, pages, words = 128, 320, 384
-        ks = jax.random.split(jax.random.PRNGKey(47), 5)
+    def feeds(B, pages, seed, nan=True):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 5)
         pool = jax.jit(lambda c, r: ops.join_latent_fn(
-            c, r, jnp.bfloat16, words).at[..., 288:].set(0x7FC1FFFF))(
+            c, r, jnp.bfloat16, words).at[..., 288:].set(
+                0x7FC1FFFF if nan else 0))(
                 _rand(ks[0], (2 * pages, ps, 512), "float32"),
                 _rand(ks[1], (2 * pages, ps, 64), "float32"))
-        q_lat = _rand(ks[2], (B, 32, 512), "float32", 0.5)
-        q_rope = _rand(ks[3], (B, 32, 64), "float32", 0.5)
-        doc = jax.random.permutation(ks[4], pages)[:P]
-        table = jnp.stack([doc if b % 2 else doc[::-1] for b in range(B)])
+        return (_rand(ks[2], (B, 32, 512), "float32", 0.5),
+                _rand(ks[3], (B, 32, 64), "float32", 0.5), pool, ks[4])
+
+    def ragged(B, P, shared=True):
+        pages = 320
+        q_lat, q_rope, pool, key = feeds(B, pages, 47)
+        doc = jax.random.permutation(key, pages)[:P]
+        every = jax.random.permutation(key, pages)
+        table = jnp.stack([(doc if b % 2 else doc[::-1]) if shared
+                           else jnp.roll(every, -5 * b)[:P] for b in range(B)])
         table = (table + pages).astype(jnp.int32)       # layer 1's rows
         edges = [P * ps, (P - 1) * ps + 1, P * ps // 2, P * ps // 2 + 1,
                  8 * ps - 1, 513, 1, 0]
@@ -553,48 +571,84 @@ def _paged_latent_cases(spec):
                            jnp.int32)
         assert spec.supported(q_lat.shape, pool.shape, jnp.bfloat16,
                               geom.rope_dim)
-        args = (q_lat, q_rope, pool, table, lens, jnp.bfloat16, geom)
+        return (q_lat, q_rope, pool, table, lens, jnp.bfloat16, geom)
+
+    def case(B, P):
+        args = ragged(B, P)
         res = _compare(spec.fn, spec.reference, args, 0, "bfloat16")
         got = spec.fn(*args)
-        res["zeros"] = bool(not np.asarray(got)[np.asarray(lens) == 0].any())
+        res["zeros"] = bool(
+            not np.asarray(got)[np.asarray(args[4]) == 0].any())
         res["ok"] = bool(res["ok"] and res["zeros"])
         return res
 
-    def timed():
-        import time
-        B, P, ps, pages, words, n = 64, 288, 128, 4 * 288, 384, 33000
-        ks = jax.random.split(jax.random.PRNGKey(48), 4)
-        pool = jax.jit(lambda c, r: ops.join_latent_fn(
-            c, r, jnp.bfloat16, words))(
-                _rand(ks[0], (2 * pages, ps, 512), "float32"),
-                _rand(ks[1], (2 * pages, ps, 64), "float32"))
-        q_lat = _rand(ks[2], (B, 32, 512), "float32", 0.5)
-        q_rope = _rand(ks[3], (B, 32, 64), "float32", 0.5)
-        doc = jnp.arange(P, dtype=jnp.int32)
-        table = jnp.stack([doc + P * (b % 4) for b in range(B)]) + pages
-        lens = jnp.full((B,), n, jnp.int32)
-        fn = jax.jit(lambda *a: spec.fn(*a, jnp.bfloat16, geom))
-        got = fn(q_lat, q_rope, pool, table, lens).block_until_ready()
-        t = time.perf_counter()
-        for _ in range(10):
-            out = fn(q_lat, q_rope, pool, table, lens)
-        out.block_until_ready()
-        seconds = (time.perf_counter() - t) / 10
+    def the_step(shared: bool):
+        """The cell's plateau step, or the same rows each behind its own
+        document."""
+        B, P, docs, own = 64, 288, 256, 2
+        pages = 4 * docs + B * own
+        q_lat, q_rope, pool, _ = feeds(B, pages, 48, nan=False)
+        doc_of = np.repeat(np.arange(4), [34, 15, 9, 6])
+        rng = np.random.default_rng(48)
+        doc_of = doc_of[rng.permutation(B)]
+        table = np.zeros((B, P), np.int32)
+        for b in range(B):
+            behind = doc_of[b] * docs + np.arange(docs) if shared \
+                else (b * 18 + np.arange(docs)) % pages
+            table[b, :docs] = behind
+            table[b, docs:docs + own] = 4 * docs + b * own + np.arange(own)
+        lens = docs * ps + 1 + (np.arange(B) * 53) % 256
+        return (q_lat, q_rope, pool, jnp.asarray(table + pages),
+                jnp.asarray(lens, jnp.int32), jnp.bfloat16, geom)
+
+    def timed(args, check_rows=8):
+        """`ms`: a layer's call given the step's plan, as the stack calls
+        it; `ms_alone`: a call that works its plan out itself, a step of
+        one layer (the difference is what a step pays once for all its
+        layers)."""
+        q_lat, q_rope, pool, table, lens = args[:5]
+
+        def seconds(fn, *a):
+            out = jax.block_until_ready(fn(*a))
+            t = time.perf_counter()
+            for _ in range(10):
+                last = fn(*a)
+            jax.block_until_ready(last)
+            return out, (time.perf_counter() - t) / 10
+
+        plan = jax.jit(lambda t, n: paged_latent_attend.step_plan(
+            t, n, pool.shape))(table, lens)
+        got, call_s = seconds(jax.jit(
+            lambda *a: spec.fn(*a[:5], jnp.bfloat16, geom, a[5])),
+            *args[:5], plan)
+        _, alone_s = seconds(jax.jit(
+            lambda *a: spec.fn(*a, jnp.bfloat16, geom)), *args[:5])
+        n = check_rows
         with jax.default_matmul_precision("highest"):
-            want = spec.reference(q_lat[:8], q_rope[:8], pool, table[:8],
-                                  lens[:8], jnp.bfloat16, geom)
-        res = {"err": _rel_err(got[:8], want), "tol": TOL["bfloat16"],
+            want = spec.reference(q_lat[:n], q_rope[:n], pool, table[:n],
+                                  lens[:n], jnp.bfloat16, geom)
+        positions = int(np.asarray(lens).sum())
+        res = {"err": _rel_err(got[:n], want), "tol": TOL["bfloat16"],
                "finite": bool(np.isfinite(np.asarray(got)).all()),
-               "ms": seconds * 1e3,
-               "roofline_pct": B * n * row_bytes / seconds / 819e9 * 100}
+               "ms": call_s * 1e3, "ms_alone": alone_s * 1e3,
+               "sharing": positions / ps / paged_latent_attend.pages_read(
+                   table, lens, pool.shape),
+               "mxu_pct": positions * row_flops / call_s / 197e12 * 100,
+               "roofline_pct": positions * row_bytes / call_s / 819e9 * 100}
         res["ok"] = bool(res["finite"] and res["err"] <= res["tol"])
         return res
 
-    return [(f"b{B} nh32 latent512 rope64 ps128 words384 bfloat16 table {P} "
-             "ragged shared pages", lambda B=B, P=P: case(B, P))
+    shape = "nh32 latent512 rope64 ps128 words384 bfloat16"
+    return [(f"b{B} {shape} table {P} ragged shared pages",
+             lambda B=B, P=P: case(B, P))
             for B, P in ((16, 288), (64, 32))] + [
-        ("b64 nh32 latent512 rope64 ps128 words384 bfloat16 table 288 "
-         "33000 positions a row timed", timed)]
+        (f"b64 {shape} table 288 four documents 34/15/9/6 rows timed",
+         lambda: timed(the_step(True))),
+        (f"b64 {shape} table 288 a document a row timed",
+         lambda: timed(the_step(False)))] + [
+        (f"b{B} {shape} table {P} ragged a document a row timed",
+         lambda B=B, P=P: timed(ragged(B, P, shared=False), check_rows=4))
+        for B, P in ((16, 288), (64, 32))]
 
 
 CASES = {
