@@ -6,8 +6,11 @@ and distribution as program transformations, Executor.run(feed, fetch) — with
 a new execution model: whole-block lowering to XLA via JAX, SPMD parallelism
 over jax.sharding meshes, and Pallas kernels for hot ops.
 """
-from . import flags  # noqa: F401  (first: other modules read flags at import)
-from . import observability  # noqa: F401  (before profiler: its shims use it)
+import time as _time
+
+_import_t0 = _time.perf_counter()
+from . import flags  # noqa: E402,F401  (first: other modules read flags at import)
+from . import observability  # noqa: E402,F401  (before profiler: its shims use it)
 from . import core  # noqa: F401
 from . import ops  # noqa: F401
 from . import profiler  # noqa: F401
@@ -79,3 +82,8 @@ class TPUPlace:
 # CUDAPlace intentionally absent: zero CUDA in this build (BASELINE.json).
 
 __version__ = "0.1.0"
+
+# what the package cost to import, this file's first line to here (jax's own
+# import too where nothing loaded it before): `setup.import.seconds`, booked
+# when the registry is first made
+observability.note_import(_time.perf_counter() - _import_t0)

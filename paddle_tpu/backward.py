@@ -10,6 +10,7 @@ file only orchestrates naming and topology, never math.
 """
 from __future__ import annotations
 
+from . import observability as obs
 from .framework import Program, Variable, grad_var_name
 from .ops.registry import default_grad_maker, get_op_def
 
@@ -50,6 +51,7 @@ def _find_op_path(block, target_names) -> list[int]:
     return path
 
 
+@obs.spanned("setup.backward")
 def append_backward(
     loss: Variable,
     parameter_list: list[str] | None = None,

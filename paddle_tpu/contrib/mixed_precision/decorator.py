@@ -14,6 +14,7 @@ decay, matching the reference-era behavior rather than Paddle 2.x SkipUpdate).
 from __future__ import annotations
 
 from ... import layers as L
+from ... import observability as obs
 from ...framework import default_main_program
 from ...initializer import Constant
 from ...layer_helper import LayerHelper
@@ -113,6 +114,7 @@ class OptimizerWithMixedPrecision:
     def apply_gradients(self, params_grads):
         return self._optimizer.apply_gradients(params_grads)
 
+    @obs.spanned("setup.minimize")
     def minimize(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None):
         params_grads = self.backward(loss, startup_program, parameter_list,

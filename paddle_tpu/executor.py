@@ -43,6 +43,7 @@ import numpy as np
 from . import flags, profiler
 from . import observability as obs
 from .framework import OpError, Program, Variable, default_main_program
+from .observability.compile_events import OTHER_FN, label_fns_by, op_scope
 from .ops.registry import ExecContext, get_op_def
 from .resilience.faults import fault_point
 from .resilience.guardrails import GUARD_HEALTH_NAME
@@ -71,9 +72,13 @@ def _compute_op(opdef, ctx, op):
     computed eagerly, so there the scope is entered on every step (a
     context manager's cost, as the op's own Python is). The persistent
     compile cache leaves `op_name` out of its key: an executable loaded
-    from it carries the names of the tree that compiled it."""
+    from it carries the names of the tree that compiled it.
+
+    While a trace is under way the op's self wall seconds go to the
+    `op_s` of the trace's `compile.entry` (`op_scope`): trace time only, so
+    neither an eager host op nor the `jax.disable_jit` replay books any."""
     try:
-        with jax.named_scope(_op_path(op)):
+        with op_scope(_op_path(op), op.type):
             return opdef.compute(ctx)
     except OpError:
         raise
@@ -237,6 +242,10 @@ class _Compiled:
         # traced and compiled a second time when its own mesh-resident
         # outputs come back as inputs.
         self.state_shardings = None
+        # (Program.name, ordinal of the signature within its Program) until
+        # the entry's first dispatch, which is the one that traces, lowers
+        # and compiles or loads it (`executor.first_dispatch`); then None
+        self.first = None
 
 
 def _on_mesh(v, sharding):
@@ -346,8 +355,11 @@ def _step_key(seed_counter, segment=None):
 
 
 # every name a lowered block's function goes by: `fn`, and what
-# `_named_after` gave (pipeline.jit_compile_counter counts compiles of these)
+# `_named_after` gave (pipeline.jit_compile_counter counts compiles of
+# these; the compiler's events carry them as `fn`, everything else as
+# `other`)
 LOWERED_FN_NAMES = {"fn"}
+label_fns_by(LOWERED_FN_NAMES.__contains__)
 
 
 def _named_after(fn, program, suffix: str = ""):
@@ -356,9 +368,11 @@ def _named_after(fn, program, suffix: str = ""):
     Modules` line of a device trace prints. An unnamed Program's entries
     stay `jit_fn`."""
     if program.name:
-        fn.__name__ = fn.__qualname__ = re.sub(
-            r"\W", "_", program.name) + suffix
-        LOWERED_FN_NAMES.add(fn.__name__)
+        name = re.sub(r"\W", "_", program.name) + suffix
+        if name == OTHER_FN:    # reserved: what is NOT a lowered block's
+            name += "_"
+        fn.__name__ = fn.__qualname__ = name
+        LOWERED_FN_NAMES.add(name)
     return fn
 
 
@@ -565,6 +579,9 @@ class Executor:
         self.place = place
         # program -> {signature: _Compiled}
         self._cache: "weakref.WeakKeyDictionary[Program, dict]" = weakref.WeakKeyDictionary()
+        # entries ever compiled of a Program (an `executor.first_dispatch`'s
+        # ordinal: the cache's own size repeats once it evicts)
+        self._entries_made = weakref.WeakKeyDictionary()
         # (step id, completion token, health vector) of dispatched-but-
         # undrained async steps (run_async window, bounded by
         # FLAGS_max_inflight_steps); the ids feed the hang watchdog's state
@@ -768,8 +785,15 @@ class Executor:
         if check_nan and not eager:
             _warn_check_nan_inf_keeps_jit()
         with profiler.stage_timer("pipeline.dispatch"):
-            fetches, new_rw, new_extra, token = comp.fn(
-                tuple(feed_vals), ro_vals, rw_vals, seed_counter)
+            args = (tuple(feed_vals), ro_vals, rw_vals, seed_counter)
+            first = comp.first      # read once: Predictor clones share entries
+            if first is None:
+                fetches, new_rw, new_extra, token = comp.fn(*args)
+            else:
+                comp.first = None
+                with obs.span("executor.first_dispatch", program=first[0],
+                              entry=first[1]):
+                    fetches, new_rw, new_extra, token = comp.fn(*args)
         if check_nan and eager and getattr(comp, "spmd_mode",
                                            "gspmd") == "shard_map":
             # under shard_map the body values stay tracers even with
@@ -894,6 +918,9 @@ class Executor:
                     program, block, feed_names, feed_vals, fetch_names,
                     scope, mesh, spmd_mode)
             comp.spmd_mode = spmd_mode
+            made = self._entries_made.get(program, 0)
+            self._entries_made[program] = made + 1
+            comp.first = (program.name or "fn", made)
             prog_cache[sig] = comp
             # bound the per-program cache (each entry pins a compiled XLA
             # executable); evict least-recently-used beyond 64 signatures

@@ -28,7 +28,8 @@ from .exporters import (  # noqa: F401
 from .registry import (  # noqa: F401
     MetricsRegistry, attach_sink, base_name, counter_inc, detach_sink,
     enabled, event, gauge_set, gc_pause_seconds, histogram_observe,
-    registry, reset, snapshot, span, stage_counters, stage_record)
+    note_import, registry, reset, snapshot, span, spanned, stage_counters,
+    stage_record)
 from .slo import SloMonitor, SloRule, default_serving_monitor  # noqa: F401
 
 
@@ -45,7 +46,8 @@ def export_prometheus(path: str | None = None) -> str | None:
 
 __all__ = [
     "MetricsRegistry", "registry", "enabled", "counter_inc", "gauge_set",
-    "histogram_observe", "event", "span", "snapshot", "stage_record",
+    "histogram_observe", "event", "span", "spanned", "snapshot",
+    "stage_record",
     "stage_counters", "reset", "attach_sink", "detach_sink", "base_name",
     "gc_pause_seconds",
     "schema", "JsonlWriter", "jsonl_line", "prometheus_text",

@@ -33,6 +33,7 @@ used to take five bespoke readers. Design points:
 from __future__ import annotations
 
 import bisect
+import functools
 import gc
 import math
 import threading
@@ -41,11 +42,13 @@ from collections import deque
 
 from .. import flags
 from . import schema as _schema
+from .compile_events import CompileWatch
 
 __all__ = ["MetricsRegistry", "registry", "enabled", "counter_inc",
-           "gauge_set", "histogram_observe", "event", "span", "snapshot",
+           "gauge_set", "histogram_observe", "event", "span", "spanned",
+           "snapshot",
            "stage_record", "stage_counters", "reset", "attach_sink",
-           "detach_sink", "gc_pause_seconds"]
+           "detach_sink", "gc_pause_seconds", "note_import"]
 
 
 def enabled() -> bool:
@@ -407,6 +410,9 @@ class MetricsRegistry:
                "level": level}
         if payload:
             rec["payload"] = payload
+        stack = _tls.stack
+        if stack:
+            rec["parent"] = stack[-1].name  # the span it happened under
         with self._lock:
             self._note(name)
             self._events.append(rec)
@@ -561,13 +567,38 @@ def gc_pause_seconds() -> float:
 # -- the process-wide default registry ----------------------------------------
 _default: MetricsRegistry | None = None
 _default_lock = threading.Lock()
+# (wall clock at the last line of paddle_tpu/__init__.py, its seconds from
+# the first) until the default registry books them
+_import: tuple[float, float] | None = None
+
+
+def note_import(seconds: float) -> None:
+    """The package's `__init__` at its last line, with what it took from
+    its first: one sample of `setup.import.seconds` and one span record
+    (`setup.import`) once the default registry exists. The import itself
+    makes none: no exporter's file or port and no listener in a process
+    that records nothing."""
+    global _import
+    _import = (time.time(), seconds)
+    if _default is not None:    # something recorded during the import
+        _book_import(_default)
+
+
+def _book_import(reg: MetricsRegistry) -> None:
+    global _import
+    (end, seconds), _import = _import, None
+    reg.histogram_observe("setup.import.seconds", seconds)
+    if reg._sinks and enabled():
+        _emit(reg._sinks, {"ts": end, "type": "span", "name": "setup.import",
+                           "dur_s": round(seconds, 9)})
 
 
 def registry() -> MetricsRegistry:
     """The default registry, created on first use with the declared schema,
     the flag-configured exporters (FLAGS_obs_jsonl_dir JSONL stream,
     FLAGS_obs_http_port /metrics endpoint) attached and the collector's
-    pauses booked into it (`_GcWatch`)."""
+    pauses (`_GcWatch`) and the compiler's events
+    (`compile_events.CompileWatch`) booked into it."""
     global _default, _gc_watch
     if _default is None:
         with _default_lock:
@@ -582,7 +613,10 @@ def registry() -> MetricsRegistry:
                 exporters.install_flag_exporters(reg)
                 _gc_watch = _GcWatch(reg)
                 gc.callbacks.append(_gc_watch)
+                CompileWatch(reg).install()
                 _default = reg
+                if _import is not None:
+                    _book_import(reg)
     return _default
 
 
@@ -604,6 +638,19 @@ def event(name, payload=None, level="info"):
 
 def span(name, labels=None, *, collect=None, **attrs):
     return registry().span(name, labels, collect=collect, **attrs)
+
+
+def spanned(name: str):
+    """Decorator: every call of the function is a `span(name)`."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with registry().span(name):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
 
 
 def snapshot(reset=False):

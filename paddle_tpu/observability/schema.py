@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import functools
 
+from .compile_events import op_scope
+
 STAGE, COUNTER, GAUGE, HISTOGRAM, EVENT = (
     "stage", "counter", "gauge", "histogram", "event")
 
@@ -443,6 +445,56 @@ DECLARED: list[tuple] = [
     ("host.gc.seconds", HISTOGRAM,
      "seconds per collection, every generation (a generation-2 collection "
      "is also a host.gc TraceAnnotation)", ()),
+    # -- set-up: the compiler's own events (observability/compile_events.py)
+    # and the program's spans before a window. Named `compile.` and `setup.`
+    # (and `executor.`) because the measurement boundaries clear `serving.`,
+    # `pipeline.`, `host.` and `train.`: these still stand at a window's end.
+    ("compile.trace.seconds", HISTOGRAM,
+     "jax's trace of a jitted function to a jaxpr, every function the "
+     "process compiles, top-level traces only", ()),
+    ("compile.lower.seconds", HISTOGRAM, "jaxpr to MLIR module", ()),
+    ("compile.backend.seconds", HISTOGRAM,
+     "XLA's compile or the persistent cache's read of one executable", ()),
+    ("compile.cache.hits", COUNTER,
+     "executables the persistent compile cache returned", ()),
+    ("compile.cache.misses", COUNTER,
+     "executables compiled and WRITTEN to the persistent cache (one under "
+     "the cache's thresholds reads neither hit nor miss)", ()),
+    ("compile.entry", EVENT,
+     "one compiled function: fn (a lowered block's function name, "
+     "executor.LOWERED_FN_NAMES, or `other`), name, trace_s, lower_s, "
+     "backend_s (on a hit the cache's read), cache (hit | miss | off), "
+     "op_s (self seconds by op path of its trace), start, end; `parent` "
+     "is the span it happened under", ()),
+    ("executor.first_dispatch.seconds", HISTOGRAM,
+     "span: the dispatch of a compile-cache entry _prepare_step has just "
+     "created (inside pipeline.dispatch; attrs program, entry): trace, "
+     "lowering, compile or cache read (the compile.entry events under "
+     "it), and as self time the executable's load and the enqueue", ()),
+    ("setup.import.seconds", HISTOGRAM,
+     "paddle_tpu/__init__.py from its first line to its last, once a "
+     "process (jax's own import too where nothing loaded it before); "
+     "booked, with a `setup.import` span record, when the registry is "
+     "first made", ()),
+    ("setup.engine_build.seconds", HISTOGRAM,
+     "span: ServingEngine.__init__", ()),
+    ("setup.engine_build.programs.seconds", HISTOGRAM,
+     "span: the step Programs built (Python, nothing traced)", ()),
+    ("setup.engine_build.startup.seconds", HISTOGRAM,
+     "span: the startup Program run (weights: its compile and enqueue; "
+     "the device's seconds land in the first wait after it)", ()),
+    ("setup.engine_build.pools.seconds", HISTOGRAM,
+     "span: the pools allocated and placed", ()),
+    ("setup.decode_lattice.seconds", HISTOGRAM,
+     "span: ServingEngine.warmup_decode", ()),
+    ("setup.decode_lattice.entry.seconds", HISTOGRAM,
+     "span: one signature of the lattice run and waited for (attrs rows, "
+     "pages: the row and page bucket)", ()),
+    ("setup.minimize.seconds", HISTOGRAM,
+     "span: Optimizer.minimize on a static graph (passes, grad ops, clip, "
+     "regularizers, update ops: Python before any trace)", ()),
+    ("setup.backward.seconds", HISTOGRAM,
+     "span: append_backward (inside setup.minimize)", ()),
     # -- training step telemetry (executor.py async window) -----------------
     ("train.steps", COUNTER, "async steps drained to completion", ()),
     ("train.step_latency_s", HISTOGRAM,
@@ -553,13 +605,13 @@ PIECES = frozenset({
 
 def piece(name: str):
     """`with piece("indexer"):` — `jax.named_scope` of a declared piece
-    (or mode); an undeclared name raises where the program is traced."""
+    (or mode); an undeclared name raises where the program is traced. Under
+    a trace its self seconds are booked like an op's, under the same path
+    (`compile_events.op_scope`)."""
     if name not in PIECES and name not in STACK_MODES:
         raise ValueError(f"{name!r} is not a piece or mode declared in "
                          f"observability/schema.py")
-    import jax
-
-    return jax.named_scope(name)
+    return op_scope(name, name)
 
 
 def under_mode(stack_fn):

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import observability as obs
 from . import unique_name
 from .backward import append_backward
 from .clip import append_gradient_clip_ops, error_clip_callback
@@ -193,8 +194,11 @@ class Optimizer:
 
         if _dy.enabled():
             return self._dygraph_minimize(loss, parameter_list)
-        params_grads = self.backward(loss, startup_program, parameter_list, no_grad_set)
-        optimize_ops = self.apply_gradients(params_grads)
+        # a training process's Python before any trace: the passes, the
+        # grad ops (`setup.backward`), clip, regularizers, update ops
+        with obs.span("setup.minimize"):
+            params_grads = self.backward(loss, startup_program, parameter_list, no_grad_set)
+            optimize_ops = self.apply_gradients(params_grads)
         return optimize_ops, params_grads
 
     # -- dygraph (imperative) path ------------------------------------------

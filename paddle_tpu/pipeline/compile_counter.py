@@ -8,7 +8,10 @@ to jax.monitoring's backend-compile duration event, which jax records once
 per executable it builds or loads, and counts the ones for the executor's
 whole-block closures (named `fn`, or after their Program where it has a
 name: `executor.LOWERED_FN_NAMES`, so their events are distinguishable from
-the small utility jits jax compiles around a run).
+the small utility jits jax compiles around a run). The process's standing
+listener (`observability/compile_events.py`, which owns the events' names)
+books every compile into the registry; this one
+counts those of a `with` block.
 
 A persistent-cache hit is counted too: it skips XLA but is still a
 first-use stall (trace + lower + deserialize) inside the caller's window,
@@ -22,9 +25,9 @@ import contextlib
 
 import jax
 
-__all__ = ["jit_compile_counter"]
+from ..observability.compile_events import BACKEND_COMPILE_EVENT
 
-_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+__all__ = ["jit_compile_counter"]
 
 
 class _CompileCount:
@@ -47,7 +50,7 @@ def jit_compile_counter():
     result = _CompileCount()
 
     def listener(event, duration, fun_name=None, **_):
-        if event == _BACKEND_COMPILE_EVENT and fun_name is not None \
+        if event == BACKEND_COMPILE_EVENT and fun_name is not None \
                 and fun_name.removeprefix("jit(").removesuffix(")") in names:
             result.events.append(f"{fun_name} {duration:.6f}s")
             from .. import observability as obs

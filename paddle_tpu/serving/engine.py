@@ -185,6 +185,7 @@ Resilience layer (ISSUE 14 — see README "Serving resilience"):
 from __future__ import annotations
 
 import gc
+import itertools
 import time
 
 import dataclasses
@@ -473,6 +474,7 @@ class ServingEngine:
                 + max(2, max_inflight // 4 - 1)
         return sizes
 
+    @obs.spanned("setup.engine_build")
     def __init__(self, cfg: "sv_model.DecoderConfig | None" = None,
                  page_size: int | None = None,
                  pool_pages: int | None = None,
@@ -673,64 +675,69 @@ class ServingEngine:
         self._dispatched = 0        # step programs enqueued, ever
         kept = {"token_slots": self._token_slots,
                 "last_token": self._last_token, **second}
-        with program_guard(self._prefill_prog, startup), \
-                unique_name.guard():
-            self._prefill_io = sv_model.build_prefill_program(
-                self.cfg, self.pool_pages, self.page_size, **kept)
-        with program_guard(self._decode_prog, decoy_startup), \
-                unique_name.guard():
-            self._decode_io = sv_model.build_decode_program(
-                self.cfg, self.pool_pages, self.page_size, tp=self.tp,
-                **kept)
-        with program_guard(self._window_prog, decoy_startup), \
-                unique_name.guard():
-            self._window_io = sv_model.build_window_program(
-                self.cfg, self.pool_pages, self.page_size, tp=self.tp,
-                **kept)
-        with program_guard(self._cow_prog, decoy_startup), \
-                unique_name.guard():
-            self._cow_io = sv_model.build_cow_program(
-                self.cfg, self.pool_pages, self.page_size, **second)
-        self._state_copy_run = None
-        if self.state_pool is not None:
-            prog = Program()
-            prog.name = "serving_state_copy"
-            with program_guard(prog, decoy_startup), unique_name.guard():
-                sv_model.build_state_copy_program(
+        with obs.span("setup.engine_build.programs"):
+            with program_guard(self._prefill_prog, startup), \
+                    unique_name.guard():
+                self._prefill_io = sv_model.build_prefill_program(
+                    self.cfg, self.pool_pages, self.page_size, **kept)
+            with program_guard(self._decode_prog, decoy_startup), \
+                    unique_name.guard():
+                self._decode_io = sv_model.build_decode_program(
+                    self.cfg, self.pool_pages, self.page_size, tp=self.tp,
+                    **kept)
+            with program_guard(self._window_prog, decoy_startup), \
+                    unique_name.guard():
+                self._window_io = sv_model.build_window_program(
+                    self.cfg, self.pool_pages, self.page_size, tp=self.tp,
+                    **kept)
+            with program_guard(self._cow_prog, decoy_startup), \
+                    unique_name.guard():
+                self._cow_io = sv_model.build_cow_program(
                     self.cfg, self.pool_pages, self.page_size, **second)
-            self._state_copy_run = self._exec_target(prog)
+            self._state_copy_run = None
+            if self.state_pool is not None:
+                prog = Program()
+                prog.name = "serving_state_copy"
+                with program_guard(prog, decoy_startup), unique_name.guard():
+                    sv_model.build_state_copy_program(
+                        self.cfg, self.pool_pages, self.page_size, **second)
+                self._state_copy_run = self._exec_target(prog)
         # rng_counter pinned to what a FRESH scope's first run folds in:
         # on a shared scope the run counter has already advanced, and
         # letting it leak into the init keys would give every engine after
         # the first different weights — silently breaking replay exactness
-        self._exe.run(startup, scope=self._scope, rng_counter=1)
-        self._scope.set_var(self._last_token,
-                            jnp.zeros((self._token_slots + 1,), jnp.int32))
-        # a shared scope may already carry live KV (an engine added to a
-        # running disaggregated fleet): re-zeroing the pools would clobber
-        # every peer's context, so only the FIRST engine materializes them.
-        # Identically-seeded startup runs make the weight re-init above a
-        # bitwise no-op on a shared scope.
-        if self.cfg.windowed:
-            for geometry in sv_model.hybrid_pool_geometry(
+        with obs.span("setup.engine_build.startup"):
+            self._exe.run(startup, scope=self._scope, rng_counter=1)
+        with obs.span("setup.engine_build.pools"):
+            self._scope.set_var(self._last_token,
+                                jnp.zeros((self._token_slots + 1,),
+                                          jnp.int32))
+            # a shared scope may already carry live KV (an engine added to
+            # a running disaggregated fleet): re-zeroing the pools would
+            # clobber every peer's context, so only the FIRST engine
+            # materializes them. Identically-seeded startup runs make the
+            # weight re-init above a bitwise no-op on a shared scope.
+            if self.cfg.windowed:
+                for geometry in sv_model.hybrid_pool_geometry(
+                        self.cfg, self.pool_pages, self.page_size,
+                        self.window_pool.num_pages):
+                    create_stacked_pools(self._scope, *geometry)
+            elif self.cfg.recurrent:
+                kv, state = sv_model.ssm_pool_geometry(
                     self.cfg, self.pool_pages, self.page_size,
-                    self.window_pool.num_pages):
-                create_stacked_pools(self._scope, *geometry)
-        elif self.cfg.recurrent:
-            kv, state = sv_model.ssm_pool_geometry(
-                self.cfg, self.pool_pages, self.page_size,
-                self.state_pool.num_pages)
-            create_stacked_pools(self._scope, *kv)
-            create_state_pools(self._scope, *state)
-        elif self.cfg.scanned:
-            create_stacked_pools(self._scope, *sv_model.stacked_pool_geometry(
-                self.cfg, self.pool_pages, self.page_size))
-        elif not self._scope.has_var(
-                pool_var_names(self.cfg.num_layers)[0][0]):
-            create_device_pools(self._scope, self.cfg.num_layers,
-                                self.pool_pages, self.page_size,
-                                self.cfg.num_heads, self.cfg.head_dim,
-                                self.cfg.dtype)
+                    self.state_pool.num_pages)
+                create_stacked_pools(self._scope, *kv)
+                create_state_pools(self._scope, *state)
+            elif self.cfg.scanned:
+                create_stacked_pools(
+                    self._scope, *sv_model.stacked_pool_geometry(
+                        self.cfg, self.pool_pages, self.page_size))
+            elif not self._scope.has_var(
+                    pool_var_names(self.cfg.num_layers)[0][0]):
+                create_device_pools(self._scope, self.cfg.num_layers,
+                                    self.pool_pages, self.page_size,
+                                    self.cfg.num_heads, self.cfg.head_dim,
+                                    self.cfg.dtype)
         # which expert each layer chose for the token in (page, slot): the
         # host twin of the pools, for blocks that route
         self._page_routes = None
@@ -869,6 +876,7 @@ class ServingEngine:
             wbase[i] = r.wfirst * self.page_size
         return {sv_model.WPAGES_FEED: wpages, sv_model.WBASE_FEED: wbase}
 
+    @obs.spanned("setup.decode_lattice")
     def warmup_decode(self, max_context: int | None = None,
                       min_context: int = 1) -> int:
         """Precompile the decode-step signature lattice for contexts up to
@@ -889,9 +897,8 @@ class ServingEngine:
                                      max_context + 2)})
         bbs = sorted({self._row_bucket(b)
                       for b in range(1, self.max_inflight + 1)})
-        n = 0
-        for bb in bbs:
-            for pb in pbs:
+        for bb, pb in itertools.product(bbs, pbs):
+            with obs.span("setup.decode_lattice.entry", rows=bb, pages=pb):
                 pages = np.zeros((bb, pb), np.int32)
                 if self.draft_k > 0:
                     S = self.draft_k + 1
@@ -919,9 +926,9 @@ class ServingEngine:
                         self._decode_run, feed=feed,
                         fetch_list=self._step_fetches(self._decode_io),
                         scope=self._scope, return_numpy=False)
-                    np.asarray(outs[0])     # wait for the step
-                n += 1
-        return n
+                    with profiler.stage_timer("pipeline.fetch"):
+                        np.asarray(outs[0])     # wait for the step
+        return len(bbs) * len(pbs)
 
     def reset_stats(self) -> None:
         """Zero the counters (and the compile-signature sets) without

@@ -403,3 +403,256 @@ def test_obs_cli_tail_summarize_diff_prom(tmp_path):
     prom.write_text("garbage line here\n")
     assert run("prom", str(prom)).returncode == 1
     assert run("nosuchcmd").returncode == 2
+
+
+# -- the compiler's own events (ISSUE 49) --------------------------------------
+
+def _entries(recs, **where):
+    """The payloads of the `compile.entry` events that match `where`."""
+    return [r["payload"] for r in recs if r["name"] == "compile.entry"
+            and all(r["payload"][k] == v for k, v in where.items())]
+
+
+@pytest.mark.parametrize("phase", ["trace", "lower", "backend"])
+def test_a_named_program_books_each_compile_phase_once(phase):
+    """A Program run twice: one `compile.entry` under its name, with one
+    sample of the phase in the histogram, and none on the second run; an
+    eager jit's entry says `other`; the `fn`s are the lowered blocks' names
+    and `other`, nothing else."""
+    import jax
+
+    from paddle_tpu import executor
+
+    name = f"probe_{phase}_step"
+    main, startup = pt.Program(), pt.Program()
+    main.name = name
+    with pt.program_guard(main, startup):
+        x = pt.layers.data(name="x", shape=[5], dtype="float32")
+        out = pt.layers.fc(x, size=3)
+    exe, scope = pt.Executor(), pt.Scope()
+    exe.run(startup, scope=scope)
+    series = f"compile.{phase}.seconds"
+
+    def count():
+        return obs.snapshot()["histograms"].get(series, {"count": 0})["count"]
+
+    recs = []
+    obs.attach_sink(recs.append)
+    try:
+        feed = {"x": np.ones((2, 5), np.float32)}
+        before = count()
+        exe.run(main, feed=feed, fetch_list=[out], scope=scope)
+        (entry,) = _entries(recs, fn=name)
+        assert entry[phase + "_s"] > 0
+        assert count() == before + 1
+        exe.run(main, feed=feed, fetch_list=[out], scope=scope)
+        assert len(_entries(recs, fn=name)) == 1 and count() == before + 1
+        jax.jit(lambda v: v * 3 + len(name))(np.ones(3, np.float32))
+        assert len(_entries(recs, fn="other")) == 1
+        assert count() == before + 2
+    finally:
+        obs.detach_sink(recs.append)
+    assert name in executor.LOWERED_FN_NAMES
+    assert {e["fn"] for e in _entries(recs)} == {name, "other"}
+
+
+def test_a_program_named_other_keeps_its_own_fn():
+    """`other` is what is NOT a lowered block's: a Program of that name is
+    compiled under a name of its own, and `jit_compile_counter` counts it."""
+    from paddle_tpu import executor
+    from paddle_tpu.pipeline import jit_compile_counter
+
+    main, startup = pt.Program(), pt.Program()
+    main.name = "other"
+    with pt.program_guard(main, startup):
+        x = pt.layers.data(name="x", shape=[6], dtype="float32")
+        out = pt.layers.fc(x, size=2)
+    exe, scope = pt.Executor(), pt.Scope()
+    exe.run(startup, scope=scope)
+    recs = []
+    obs.attach_sink(recs.append)
+    try:
+        with jit_compile_counter() as compiles:
+            exe.run(main, feed={"x": np.ones((2, 6), np.float32)},
+                    fetch_list=[out], scope=scope)
+    finally:
+        obs.detach_sink(recs.append)
+    assert compiles.count == 1
+    assert "other" not in executor.LOWERED_FN_NAMES
+    assert len(_entries(recs, fn="other_")) == 1
+    assert not _entries(recs, fn="other")
+
+
+def test_a_compile_entry_event_carries_its_phases_cache_and_parent():
+    main, startup = pt.Program(), pt.Program()
+    main.name = "probe_entry_step"
+    with pt.program_guard(main, startup):
+        x = pt.layers.data(name="x", shape=[7], dtype="float32")
+        out = pt.layers.fc(x, size=2)
+    exe, scope = pt.Executor(), pt.Scope()
+    exe.run(startup, scope=scope)
+    recs = []
+    obs.attach_sink(recs.append)
+    try:
+        exe.run(main, feed={"x": np.ones((3, 7), np.float32)},
+                fetch_list=[out], scope=scope)
+    finally:
+        obs.detach_sink(recs.append)
+    (entry,) = [r for r in recs if r["name"] == "compile.entry"
+                and r["payload"]["fn"] == main.name]
+    p = entry["payload"]
+    assert entry["parent"] == "executor.first_dispatch"
+    assert p["cache"] in ("hit", "miss", "off")
+    assert min(p["trace_s"], p["lower_s"], p["backend_s"]) > 0
+    assert p["end"] - p["start"] >= \
+        p["trace_s"] + p["lower_s"] + p["backend_s"] - 1e-3
+    assert set(p["op_s"]) == {"mul", "elementwise_add"}
+    (first,) = [r for r in recs if r["name"] == "executor.first_dispatch"]
+    assert first["parent"] == "pipeline.dispatch"
+    assert first["attrs"] == {"program": main.name, "entry": 0}
+    # the ordinal counts the entries ever made of the Program: it does not
+    # repeat when the cache has dropped some (as its LRU does past 64)
+    exe.invalidate_cache(main)
+    obs.attach_sink(recs.append)
+    try:
+        exe.run(main, feed={"x": np.ones((3, 7), np.float32)},
+                fetch_list=[out], scope=scope)
+    finally:
+        obs.detach_sink(recs.append)
+    assert [r["attrs"]["entry"] for r in recs
+            if r["name"] == "executor.first_dispatch"] == [0, 1]
+    # the cache's own verdicts reach the counters (the suite caches every
+    # executable: tests/conftest.py)
+    counters = obs.snapshot()["counters"]
+    assert counters.get("compile.cache.hits", 0) \
+        + counters.get("compile.cache.misses", 0) > 0
+
+
+def test_the_op_scope_books_self_seconds_by_path():
+    """Under a trace the scopes' SELF seconds reach the entry's `op_s` by
+    path; at the top level the same scopes book nothing."""
+    import time
+
+    import jax
+
+    from paddle_tpu.observability.compile_events import op_scope
+
+    def f(v):
+        with op_scope("outer_probe", "outer_probe"):
+            time.sleep(0.02)
+            with op_scope("inner_probe", "inner_probe"):
+                time.sleep(0.03)
+        return v + 1
+
+    recs = []
+    obs.attach_sink(recs.append)
+    try:
+        f(np.ones(2, np.float32))           # eager: no trace, no seconds
+        jax.jit(f)(np.ones(2, np.float32))
+    finally:
+        obs.detach_sink(recs.append)
+    (entry,) = [e for e in _entries(recs) if "op_s" in e]
+    op_s = entry["op_s"]
+    assert set(op_s) == {"outer_probe", "outer_probe/inner_probe"}
+    assert 0.03 <= op_s["outer_probe/inner_probe"] < 0.045
+    assert 0.02 <= op_s["outer_probe"] < 0.03   # its own sleep, not the inner's
+    assert entry["trace_s"] >= sum(op_s.values())
+
+
+def test_the_disable_jit_replay_books_no_op_seconds():
+    import jax
+
+    from paddle_tpu.observability import compile_events
+
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        x = pt.layers.data(name="x", shape=[4], dtype="float32")
+        out = pt.layers.fc(x, size=2)
+    exe, scope = pt.Executor(), pt.Scope()
+    exe.run(startup, scope=scope)
+    assert not compile_events._state.op_s
+    with jax.disable_jit():
+        exe.run(main, feed={"x": np.ones((2, 4), np.float32)},
+                fetch_list=[out], scope=scope)
+    assert not compile_events._state.op_s
+
+
+def test_measurement_boundaries_leave_setup_and_compile_standing():
+    """What the training runner clears at its window's start
+    (`obs.reset("pipeline.")`, `obs.reset("train.")`) takes no `setup.*`,
+    `compile.*` or `executor.*` series with it."""
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        x = pt.layers.data(name="x", shape=[9], dtype="float32")
+        loss = pt.layers.mean(pt.layers.fc(x, size=1))
+        pt.optimizer.SGD(0.1).minimize(loss)
+    exe, scope = pt.Executor(), pt.Scope()
+    exe.run(startup, scope=scope)
+    exe.run(main, feed={"x": np.ones((2, 9), np.float32)},
+            fetch_list=[loss], scope=scope)
+
+    def standing():
+        snap = obs.snapshot()
+        return {k: v if kind == "counters" else v["count"]
+                for kind in ("counters", "histograms")
+                for k, v in snap[kind].items()
+                if k.startswith(("setup.", "compile.", "executor."))}
+
+    before = standing()
+    assert {"setup.minimize.seconds", "setup.backward.seconds",
+            "executor.first_dispatch.seconds", "compile.trace.seconds",
+            "compile.lower.seconds", "compile.backend.seconds"} <= set(before)
+    assert "pipeline.dispatch" in obs.snapshot()["stages"]
+    obs.reset("pipeline.")
+    obs.reset("train.")
+    assert "pipeline.dispatch" not in obs.snapshot()["stages"]
+    assert standing() == before
+
+
+def test_the_import_is_one_sample_booked_when_the_registry_is_made(tmp_path):
+    """Importing the package makes no registry (no exporter's file, no
+    listener); the first use does, and books the import's seconds once,
+    with a `setup.import` span record in the stream."""
+    code = (
+        "import os, sys, json\n"
+        "import paddle_tpu\n"
+        "from paddle_tpu import observability as obs\n"
+        "reg = sys.modules['paddle_tpu.observability.registry']\n"
+        "assert reg._default is None and not os.listdir(sys.argv[1])\n"
+        "with obs.span('setup.minimize'):\n"
+        "    pass\n"
+        "h = obs.snapshot()['histograms']['setup.import.seconds']\n"
+        "print(json.dumps(h))\n")
+    r = subprocess.run(
+        [sys.executable, "-c", code, str(tmp_path)], capture_output=True,
+        text=True, timeout=300, cwd=os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))),
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "FLAGS_obs_jsonl_dir": str(tmp_path)})
+    assert r.returncode == 0, r.stderr
+    hist = json.loads(r.stdout.strip().splitlines()[-1])
+    assert hist["count"] == 1 and hist["sum"] > 0
+    recs = [json.loads(ln) for ln in
+            (tmp_path / "obs.jsonl").read_text().splitlines()]
+    assert [r["name"] for r in recs if r["type"] == "span"] \
+        == ["setup.import", "setup.minimize"]
+    assert recs[0]["dur_s"] == pytest.approx(hist["sum"], abs=1e-6)
+    assert recs[0]["ts"] <= recs[1]["ts"] - recs[1]["dur_s"]
+
+
+def test_setup_and_compile_names_are_declared():
+    new = {"compile.trace.seconds", "compile.lower.seconds",
+           "compile.backend.seconds", "compile.cache.hits",
+           "compile.cache.misses", "compile.entry", "executor.first_dispatch.seconds",
+           "setup.import.seconds", "setup.engine_build.seconds",
+           "setup.engine_build.programs.seconds",
+           "setup.engine_build.startup.seconds",
+           "setup.engine_build.pools.seconds", "setup.decode_lattice.seconds",
+           "setup.decode_lattice.entry.seconds", "setup.minimize.seconds",
+           "setup.backward.seconds"}
+    assert new <= schema.DECLARED_NAMES
+    # every span literal of these families in the tree is one of them
+    spans = _tree_literals(r'(?:\bspan|\bspanned)\(\s*"((?:setup|executor)\.[^"]+)"')
+    assert spans and {s + ".seconds" for s in spans} <= new
+    assert not [n for n in obs.snapshot()["undeclared"]
+                if n.startswith(("setup.", "compile.", "executor."))]
