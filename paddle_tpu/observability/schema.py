@@ -67,12 +67,16 @@ DECLARED: list[tuple] = [
     ("serving.decode_context_pages", COUNTER,
      "pages of context the rows of a plain decode step attended over, "
      "summed over rows and steps (x a page's K+V bytes: what decode "
-     "attention had to read in one layer)", ()),
+     "attention had to read in one layer); under the grouped-query kernel "
+     "that walks the step's list of page blocks a run of pages that rows "
+     "share counted ONCE (paged_attention.walk_counts over the step's "
+     "tables and lengths)", ()),
     ("serving.decode_grid_steps", COUNTER,
      "grid steps of one layer's paged decode kernel (padded rows x page "
-     "blocks of the bucket), summed over plain decode steps the Pallas "
-     "arm served; 0 on the XLA path (decode_context_pages over this: live "
-     "pages a grid step)", ()),
+     "blocks of the bucket; under the grouped-query kernel that walks a "
+     "list, the blocks of the list), summed over plain decode steps the "
+     "Pallas arm served; 0 on the XLA path (decode_context_pages over "
+     "this: live pages a block)", ()),
     ("serving.preemptions", COUNTER,
      "requests preempted back to the waiting queue", ()),
     ("serving.aborts", COUNTER, "requests aborted", ()),
@@ -385,9 +389,20 @@ DECLARED: list[tuple] = [
      "pages of the full layers' pool currently mapped, for a family with "
      "two pools (serving.pages_in_use reads the same)", ()),
     ("serving.attn.full_context_tokens", COUNTER,
+     "live tokens the full-attention layers' decode calls fetched, summed "
+     "over those layers and steps (x a token's K+V bytes a layer: what "
+     "the kernel had to read): every row's context, or, where the kernel "
+     "walks the step's list of page blocks, a run that rows share ONCE "
+     "and behind it every row's own", ()),
+    ("serving.attn.attended_tokens", COUNTER,
      "live tokens the rows of a decode step attended in full-attention "
-     "layers, summed over rows, those layers and steps (x a token's K+V "
-     "bytes a layer: what the kernel had to read)", ()),
+     "layers, every row's own context, summed over rows, those layers and "
+     "steps (over full_context_tokens: the sharing the steps got, 1 where "
+     "each row reads its own)", ()),
+    ("serving.attn.shared_kernel_layer_steps", COUNTER,
+     "full-attention layer x decode-step pairs in which some group of "
+     "rows had a shared run that the kernel read once (over "
+     "full_layer_steps: how often the sharing engages)", ()),
     ("serving.attn.window_context_tokens", COUNTER,
      "live tokens the rows of a decode step attended in sliding-window "
      "layers (at most the window a row), summed over rows, those layers "

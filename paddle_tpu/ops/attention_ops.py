@@ -432,14 +432,51 @@ def paged_decode_grid_steps(q_shape, q_dtype, pool_shape, pool_dtype,
                           shard_pool[2], jnp.dtype(pool_dtype).itemsize)
 
 
+def paged_decode_walks(q_shape, q_dtype, pool_shape, pool_dtype,
+                       bucket_pages, tp=1) -> bool:
+    """Whether ONE `paged_decode_attention_fn` call without a first live
+    slot at this shape runs the grouped-query kernel that walks a flat list
+    of page blocks and reads a run of pages that rows share once
+    (`paged_attention.walk_supported` at the per-shard shapes the arm was
+    decided at): what the stacks ask before they work out a step's plan,
+    and the engine before it books what the kernel read."""
+    from .pallas_kernels import paged_attention as ppa
+
+    _, shard_pool = _paged_arm(tuple(q_shape), q_dtype, tuple(pool_shape),
+                               pool_dtype, bucket_pages, tp)
+    if shard_pool is None:
+        return False
+    B, nh, dh = q_shape
+    shard_q, _ = _shard_paged_shapes(
+        (B, _padded_group_heads(nh, dh, pool_shape[2]), dh), pool_shape, tp)
+    return ppa.walk_supported(shard_q, shard_pool, pool_dtype, bucket_pages)
+
+
+def paged_decode_plan_fn(q_shape, q_dtype, k_pool, page_table, kv_lens,
+                         tp=1):
+    """The plan of a decode step's full-attention calls, from its page
+    table and lengths alone (any layer's table gives the same): worked out
+    ONCE, before the layers, and handed to every layer's
+    `paged_decode_attention_fn`; None where `paged_decode_walks` says no."""
+    if not paged_decode_walks(q_shape, q_dtype, k_pool.shape, k_pool.dtype,
+                              page_table.shape[1], tp):
+        return None
+    from .pallas_kernels import paged_attention as ppa
+
+    return ppa.walk_plan(page_table, kv_lens, k_pool.shape,
+                         jnp.dtype(k_pool.dtype).itemsize)
+
+
 def paged_decode_attention_fn(q, k_pool, v_pool, page_table, kv_lens,
-                              sm_scale=1.0, tp=1, first_live=None):
+                              sm_scale=1.0, tp=1, first_live=None,
+                              plan=None):
     """Dispatch per `paged_attention_backend`: the Pallas page-DMA kernel
     where it can run (and the tuner has not retired it for this shape), the
     XLA gather reference everywhere else — including when a swept-DB verdict
     names a kernel this platform cannot execute. `first_live` [B] int32 (a
     sliding-window layer): a row attends slots `first_live .. kv_len - 1`
-    only."""
+    only. `plan`: the step's `paged_decode_plan_fn`, where the caller runs
+    several layers over one table (the kernel works it out otherwise)."""
     backend, pallas_pool = _paged_arm(q.shape, q.dtype, k_pool.shape,
                                       k_pool.dtype, page_table.shape[1], tp)
     if pallas_pool is not None:
@@ -455,7 +492,7 @@ def paged_decode_attention_fn(q, k_pool, v_pool, page_table, kv_lens,
                         ).reshape(B, padded, dh)
         out = ppa.paged_decode_attention(q, k_pool, v_pool, page_table,
                                          kv_lens, sm_scale=float(sm_scale),
-                                         first_live=first_live)
+                                         first_live=first_live, plan=plan)
         if padded != nh:
             out = out.reshape(B, nkv, padded // nkv, dh)[
                 :, :, :nh // nkv].reshape(B, nh, dh)

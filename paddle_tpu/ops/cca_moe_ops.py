@@ -54,6 +54,7 @@ import numpy as np
 
 from .attention_ops import (_DROP_PAGE, _write_rows, grouped_query_attention,
                             kv_cache_append_fn, paged_decode_attention_fn,
+                            paged_decode_plan_fn,
                             paged_prefill_attention_fn)
 from ..observability.schema import piece, under_mode
 from .registry import _DYN, ExecContext, register_op
@@ -322,6 +323,12 @@ def cca_moe_stack_fn(mode: str, tok, pos, emb, final_norm, layer_params: dict,
         gpos = first[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
         if decode:
             live = jnp.reshape(mask, (-1,)) > 0
+            # which rows' tables begin with the same pages is the tables'
+            # alone: every layer's decode call shares one plan, a constant
+            # of the scanned body
+            walk = paged_decode_plan_fn(
+                (B, geom.num_heads, geom.head_dim), _F32, pools[0],
+                page_table, first + 1)
         else:
             valid = jnp.arange(S, dtype=jnp.int32)[None, :] < lens[:, None]
             # a page's state row is the state after its latest token: write
@@ -357,7 +364,7 @@ def cca_moe_stack_fn(mode: str, tok, pos, emb, final_norm, layer_params: dict,
                 with piece("attend"):
                     o = paged_decode_attention_fn(
                         q[:, 0], k_pool, v_pool, table, first + 1,
-                        sm_scale=sm_scale)[:, None]
+                        sm_scale=sm_scale, plan=walk)[:, None]
             else:
                 with piece("kv_write"):
                     kv_idx = _page_row_index(page_table, gpos, page_size,

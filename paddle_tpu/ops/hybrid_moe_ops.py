@@ -65,7 +65,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .attention_ops import (_NEG_INF, _gather_pages, _write_rows,
-                            kv_cache_append_fn, paged_decode_attention_fn)
+                            kv_cache_append_fn, paged_decode_attention_fn,
+                            paged_decode_plan_fn)
 from .cca_moe_ops import _page_row_index, rms_norm_fn
 from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
@@ -342,6 +343,11 @@ def hybrid_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
         valid = (jnp.reshape(mask, (-1, 1)) > 0) if decode \
             else rel < lens[:, None]
         local = gpos - base[:, None]          # slots of the compact table
+    # which rows' tables begin with the same pages is the tables' alone:
+    # the full layers' decode calls share one plan, worked out before them
+    walk = paged_decode_plan_fn(
+        (B, geom.full_heads, geom.head_dim), _F32, pools[0], page_table,
+        first + 1) if decode else None
     routes = []
     for l, (a_kind, a_i, f_kind, f_i) in enumerate(plan):
         p = {k: w[a_i] for k, w in attention[a_kind].items()}
@@ -367,7 +373,7 @@ def hybrid_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                 with piece("attend"):
                     o = paged_decode_attention_fn(
                         q[:, 0], k_pool, v_pool, table, first + 1,
-                        sm_scale=sm_scale)[:, None]
+                        sm_scale=sm_scale, plan=walk)[:, None]
             else:
                 with piece("kv_write"):
                     idx = _page_row_index(page_table, gpos, page_size, off,

@@ -49,7 +49,7 @@ import collections
 import jax.numpy as jnp
 
 from .attention_ops import (_gather_pages, _write_rows, kv_cache_append_fn,
-                            paged_decode_attention_fn)
+                            paged_decode_attention_fn, paged_decode_plan_fn)
 from .cca_moe_ops import _experts_backend, _page_row_index, rms_norm_fn
 from .hybrid_moe_ops import causal_attention_fn
 from .latent_moe_ops import group_limited_router_fn
@@ -184,6 +184,10 @@ def mixer_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm, norms,
         slot = state_slot.astype(jnp.int32)                     # [B]
         # a window at position 0 starts a sequence: its state is zeros
         fresh = (first == 0) & (not decode)
+        # which rows' tables begin with the same pages is the tables'
+        # alone: the attention layers' decode calls share one plan
+        walk = paged_decode_plan_fn((B, nh, dh), _F32, k_pool, page_table,
+                                    first + 1) if decode else None
     routes = []
     seen = {MIXER: 0, ATTENTION: 0, EXPERTS: 0}
     for l, kind in enumerate(geom.plan):
@@ -263,7 +267,7 @@ def mixer_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm, norms,
                     with piece("attend"):
                         o = paged_decode_attention_fn(
                             q[:, 0], k_pool, v_pool, table, first + 1,
-                            sm_scale=sm_scale)[:, None]
+                            sm_scale=sm_scale, plan=walk)[:, None]
                 else:
                     with piece("kv_write"):
                         idx = _page_row_index(page_table, gpos, page_size,

@@ -15,7 +15,10 @@ the platform cannot run the kernel).
 
 The serving kernels are forward-only and are imported where they are
 dispatched, not from here: `paged_attention` (a decode row's attention over
-its pages of the K/V pools; `attention_ops.paged_decode_attention_fn`),
+its pages of the K/V pools; `attention_ops.paged_decode_attention_fn`; its
+grouped-query arm walks a flat list of page blocks in one grid step and
+reads a run of pages that rows share once, by `paged_latent_attend`'s
+`row_groups`),
 `paged_indexer` (a decode row's lightning-indexer scores over its pages of
 the key pool, where XLA gathered the pages and wrote the per-head scores;
 `sparse_moe_ops.decode_scores_fn`, its shape gate the only switch),

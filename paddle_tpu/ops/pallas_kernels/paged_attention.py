@@ -47,19 +47,71 @@ gather IS the decode step's HBM bill. This kernel never materializes it:
     over the head's lanes) lives in VMEM scratch across the blocks of one
     row; the output block is written once, on the row's last grid step.
 
+THE GROUPED-QUERY ARM WITHOUT A FIRST LIVE SLOT WALKS A LIST, NOT A GRID
+(PR 50; `_walk_kernel`, the same name `paged_decode_attention_gqa`). Decode
+rows that stand behind one context hold the SAME page ids in their tables
+(the prefix cache hands them out), and the grid above fetches and scores
+such a page once a row. Here:
+
+  * the plan, from the page table, once a step (`walk_plan`; PR 48's rule,
+    `paged_latent_attend.row_groups`): rows whose first block of `G` table
+    entries are equal and whole form a group, the group's run is what ALL
+    its members share, in whole blocks; a row that shares under one block
+    is a group of one and its whole table is its tail. The flat list holds
+    every group's shared blocks, then every row's tail blocks (a live row
+    at least one, an empty one where its run is all it has), no dead
+    block. The plan is the same for every layer: the stacks work it out
+    before their layers (`attention_ops.paged_decode_plan_fn`).
+  * ONE grid step walks the list in order, the next block's K and V DMAs
+    in flight while a block is scored (the two-half scratch above). The
+    online-softmax state of the shared runs (m, l, acc) is resident for
+    ALL rows, laid out a KV head at a time, `[nkv, rows x heads_per_kv,
+    128]` by sorted row, so that a tile of `T` rows x one KV head's query
+    heads is one window of sublanes.
+  * a SHARED block is scored by tiles of `T` sorted rows (`tile_rows`: 16
+    at 4 to 8 heads a KV head, 8 at 16), a group's last rows by a half
+    tile: for KV head j the tile's `T x heads_per_kv` query rows, their
+    three bfloat16 pieces stacked (`_stack3`), against `k[:, j]` and `v[:,
+    j]`, the same `_dot3` products as `_gqa_update`'s, every row of a
+    product one that is kept and no `select` over heads
+    (`_tile_update`); `HEADS_ABREAST` KV heads in one straight run. A
+    shared chunk needs no position mask; a group's last tile masks the
+    rows that are not the group's.
+  * a TAIL block is `_gqa_update` for its one row, as in the grid, on a
+    state of its own (`[nh, 128]`) that starts from what the row gathered
+    over its group's run and is written out, normalised, behind the row's
+    last tail block. A padding row (length 0) is in no block: zeros.
+
+Only the order in which a row's chunks enter its running maximum changes;
+a table that shares nothing gives groups of one and the grid's walk
+without its dead steps. On the chip (my runs, PR 50, `tools/kernel_check.
+py`'s step of 64 rows x 48 heads behind four contexts of 128 pages, the
+grid and the walk alternating on the same arrays): 6,304 us -> 1,587 us a
+call, the same rows each behind a context of its own 6,308 -> 6,273,
+ZAYA1's step (nothing shared) 359 -> 357. The walk is bound by the matrix
+unit: a page x tile of 16 rows costs 1.6 us (of 8 rows 1.05) where the
+grid's page x row costs 0.71 us of DMA. A table under two blocks wide, a
+table past the scalar memory, or rows whose state would not stay resident
+(`walk_supported`) keep the grid; so do the `post_ln` arm and the arm with
+a first live slot.
+
 Decode q is a single token per row, so there is no backward pass: the kernel
 is forward-only (serving never differentiates), which keeps it free of the
 residual bookkeeping the short-seq training kernel needs.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .paged_latent_attend import TABLE_ENTRIES, row_groups
 
 _NEG_INF = -1e30
 
@@ -500,6 +552,396 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret,
     return out.reshape(B, nh, dh).astype(q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# The grouped-query arm without a first live slot: ONE grid step that walks a
+# flat list of page blocks, a run of pages that rows share read and scored
+# once for all of them (the module docstring's second half)
+# ---------------------------------------------------------------------------
+
+# rows of a group that one product stacks on the matrix unit's rows, at most
+# (a product's fixed cost, the K or V tile's way into the matrix unit, is
+# shared by its rows: tiles of 4 | 8 | 16 rows took 3,069 | 2,277 | 2,160 us
+# a call in the first build; a group's last rows take a half tile, so that
+# a small group does not pay for sixteen)
+TILE_ROWS = 16
+# rows of the matrix unit one tile's three bfloat16 pieces may fill: three
+# passes of 128
+TILE_MXU_ROWS = 384
+# KV heads of a tile scored in one straight run, so that one head's products
+# are in flight while another's softmax is worked out; the loop over the
+# rest keeps the body short (a decode program holds a copy a layer). The
+# step of `tools/kernel_check.py` at tiles of 8 rows: 2,185 us a call with
+# 1 head abreast, 1,975 with 2, 1,832 with 4, 1,771 with 8 (my chip runs,
+# PR 50); with whole and half tiles 1,712 | 1,597 | 1,552 at 2 | 4 | 8.
+HEADS_ABREAST = 4
+WALK_VMEM_LIMIT = 48 * 1024 * 1024
+# what a call keeps resident a row: its queries in both orders and its output
+# (operands, double-buffered) and the shared runs' running maximum, sum and
+# numerator
+WALK_RESIDENT_BYTES = 28 * 1024 * 1024
+# a block of the flat list, one scalar each: where its first page stands in
+# the flattened table, how many pages of it are fetched, its first position,
+# its rows (the first by sorted position, and how many), the positions live
+# for them, a tail's row as the caller numbers it, and whether the block is
+# the first (1) and the last (2) of its row's tail
+_FIELDS = _BASE, _PAGES, _POS, _ROW, _COUNT, _LIMIT, _ORIG, _EDGE = tuple(
+    range(8))
+
+Walk = collections.namedtuple("Walk", "order work blocks")
+
+
+def tile_rows(heads_per_kv: int) -> tuple:
+    """(T, T / 2): the rows of a group whose heads one product stacks
+    against a KV head, from the shape alone: as many as keep the three
+    bfloat16 pieces of `T x heads_per_kv` query rows within `TILE_MXU_ROWS`,
+    no more than `TILE_ROWS`, in whole sublane tiles of 8 (16 at 4 or 6
+    heads a KV head, 8 at 16, 4 at 32); and the half tile a group's last
+    rows take (0 where half a tile is no whole sublane tiles)."""
+    unit = 8 // math.gcd(heads_per_kv, 8)
+    rows = min(TILE_ROWS, TILE_MXU_ROWS // (3 * heads_per_kv))
+    tile = max(unit, rows // unit * unit)
+    return tile, 0 if tile // 2 % unit or tile < 2 else tile // 2
+
+
+def walk_supported(q_shape, pool_shape, pool_dtype, bucket_pages) -> bool:
+    """Whether the list-walking form serves a grouped-query call without a
+    first live slot: q [B, nh, dh] over `pool_shape` behind tables of
+    `bucket_pages`. The table is two blocks wide or wider (a narrower one
+    cannot hold a shared block and a row's own page behind it, and its grid
+    has one step a row: the step's plan and the walk's longer code would
+    buy nothing), it fits the scalar memory one call prefetches (`TABLE_ENTRIES`, the
+    latent kernel's), and the
+    rows' queries, outputs and running sums stay resident; everything else
+    (a short table, thousands of rows) keeps the grid over (row, block)."""
+    if not paged_supported(q_shape, pool_shape, pool_dtype):
+        return False
+    B, nh, dh = q_shape
+    _, ps, width = pool_shape
+    if nh * dh == width:
+        return False
+    group = pages_per_grid_step(bucket_pages, ps, width,
+                                jnp.dtype(pool_dtype).itemsize)
+    rows = max(B, tile_rows(nh // (width // dh))[0])
+    resident = 4 * (rows * nh * (2 * dh + 2 * _LANES + dh) + 4 * B * nh * dh)
+    return (int(bucket_pages) >= 2 * group
+            and B * int(bucket_pages) <= TABLE_ENTRIES
+            and resident <= WALK_RESIDENT_BYTES)
+
+
+def _walk_blocks(g, group, xp):
+    """Of `row_groups`' answer, by sorted position: the pages of each
+    group's shared run (at its first row, 0 elsewhere) and of each row's
+    tail, and the blocks the list gives them. A live row's tail is at least
+    one block, an empty one where its run is all it has: the row's answer
+    is written behind its last tail block."""
+    at = xp.arange(g.first.shape[0])
+    shared = xp.where(g.first == at, g.run, 0)
+    tail = g.pages - g.run
+    return (shared, tail, shared // group,
+            xp.maximum(-(-tail // group), (g.pages > 0).astype(tail.dtype)))
+
+
+def walk_plan(page_table, kv_lens, pool_shape, itemsize) -> Walk:
+    """What every layer's call of one decode step needs of its tables
+    (page_table [B, P], kv_lens [B]; a layer's offset does not enter):
+    PR 48's rule (`paged_latent_attend.row_groups`: rows whose tables begin
+    with the same `G` page ids and hold them whole form a group, the run is
+    what all members share in whole blocks) and its flat list: the rows'
+    `order` (a group's together), `work` [8 x slots] (`_BASE` .. `_EDGE`,
+    a field's `slots` entries together) of which the first `blocks` [1] are
+    real: every group's shared run block by block, then every row's tail.
+    A table that shares nothing gives groups of one: every row's blocks in
+    row order, the walk of the grid without its dead steps."""
+    _, ps, width = pool_shape
+    B, P = page_table.shape
+    G = pages_per_grid_step(P, ps, width, itemsize)
+    lens = kv_lens.astype(jnp.int32)
+    g = row_groups(page_table.astype(jnp.int32), lens, ps, G)
+    at = jnp.arange(B, dtype=jnp.int32)
+    shared, tail, shared_blocks, tail_blocks = _walk_blocks(g, G, jnp)
+    pages = jnp.concatenate([shared, tail])
+    blocks = jnp.concatenate([shared_blocks, tail_blocks])
+    ends = jnp.cumsum(blocks)
+    segments = jnp.stack([
+        pages, jnp.concatenate([0 * at, g.run]), jnp.concatenate([at, at]),
+        jnp.concatenate([g.count, 0 * at + 1]),
+        jnp.concatenate([shared * ps, lens[g.order]]),
+        jnp.concatenate([g.order, g.order]), ends - blocks, blocks], axis=1)
+    # block w of the list is block `w - start` of the segment it falls in
+    w = jnp.arange(walk_slots(B, P, G), dtype=jnp.int32)
+    s = jnp.minimum(jnp.sum(w[:, None] >= ends[None, :], axis=1), 2 * B - 1)
+    pages, page0, row, count, limit, orig, start, n = segments[s].T
+    k = w - start
+    page = page0 + k * G
+    fields = {_BASE: orig * P + page,
+              _PAGES: jnp.clip(page0 + pages - page, 0, G), _POS: page * ps,
+              _ROW: row, _COUNT: count, _LIMIT: limit, _ORIG: orig,
+              _EDGE: (k == 0) + 2 * (k == n - 1)}
+    work = jnp.concatenate([fields[f] for f in _FIELDS]).astype(jnp.int32)
+    return Walk(g.order, work, ends[-1:].astype(jnp.int32))
+
+
+def walk_slots(rows: int, bucket_pages: int, group: int) -> int:
+    """Entries of the flat list at most: every row's blocks and one more a
+    row (an empty tail)."""
+    return rows * (-(-bucket_pages // group) + 1)
+
+
+def walk_counts(page_table, kv_lens, pool_shape, itemsize) -> dict:
+    """What ONE layer's call reads with these feeds (numpy, on the host;
+    the rows the scheduler padded in left out by the caller): `pages` and
+    `tokens` fetched, a group's run once and behind it every row's own,
+    `blocks` the list walks, and whether any run is `shared`: the rule
+    `walk_plan` follows, so the engine's counters count what the kernel
+    read."""
+    _, ps, width = pool_shape
+    table = np.asarray(page_table)
+    lens = np.asarray(kv_lens).reshape(-1).astype(np.int64)
+    G = pages_per_grid_step(table.shape[1], ps, width, itemsize)
+    g = row_groups(table, lens, ps, G, np)
+    shared, tail, shared_blocks, tail_blocks = _walk_blocks(g, G, np)
+    run = shared.sum()
+    return {"pages": int(run + tail.sum()),
+            "tokens": int(run * ps + (lens[g.order] - g.run * ps).sum()),
+            "blocks": int(shared_blocks.sum() + tail_blocks.sum()),
+            "shared": bool(run)}
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale",))
+def _tile_update(q, k, v, lo, hi, m_prev, l_prev, acc_prev, *, sm_scale):
+    """A chunk of a shared run against one KV head: `q` [M, dh] (a tile's
+    rows x the head's query heads), `k`, `v` [tokens, dh], every token live
+    for every row; rows `lo .. hi` of M are the group's (a group's last
+    tile holds others', which keep what they have); returns the new (m, l,
+    acc). `_gqa_update`'s products and softmax, every row of a product one
+    that is kept."""
+    s = _dot3(_stack3(q * sm_scale), k, ((1,), (1,)))        # [M, tokens]
+    at = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    s = jax.lax.select((at >= lo) & (at < hi), s, jnp.full_like(s, _NEG_INF))
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)                          # [M, 128]
+    pexp = jnp.exp(s - m_new[:, :1])
+    pv = _dot3(_stack3(pexp), v, ((1,), (0,)))               # [M, dh]
+    return (m_new, l_prev * alpha + jnp.sum(pexp, axis=1, keepdims=True),
+            acc_prev * alpha + pv)
+
+
+def _walk_kernel(pt_ref, work_ref, n_ref, qt_ref, qr_ref, k_hbm, v_hbm, o_ref,
+                 k_buf, v_buf, sem, m_ref, l_ref, acc_ref, mt_ref, lt_ref,
+                 at_ref, *, page_size, group, tile, half_tile, heads_per_kv,
+                 sm_scale):
+    """The one grid step: every block of the flat list, in order. `qt_ref`
+    [nkv, rows x heads_per_kv, dh]: the queries a KV head at a time, by
+    sorted row, so that a tile of rows x a KV head's query heads is one
+    window of sublanes; `qr_ref`, `o_ref` [B, nh, dh] as the caller numbers
+    the rows. `m_ref`, `l_ref`, `acc_ref`: the shared runs' online softmax
+    for all rows, as `qt_ref` lies; `mt_ref`, `lt_ref`, `at_ref` [nh, .]:
+    that of the one row whose tail is being walked."""
+    nkv, _, dh = qt_ref.shape
+    rows = qt_ref.shape[1] // heads_per_kv
+    slots = work_ref.shape[0] // len(_FIELDS)
+    chunk = math.gcd(group, max(1, MXU_CHUNK_TOKENS // page_size))  # pages
+    tokens = chunk * page_size
+    blocks = n_ref[0]
+    pools, bufs = (k_hbm, v_hbm), (k_buf, v_buf)
+    field = lambda f, w: work_ref[f * slots + w]             # noqa: E731
+
+    # a padding row is in no block: zeros. A block is fetched as far as its
+    # last live page: what a chunk holds behind it is masked, and must be
+    # numbers
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+    k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+    v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+
+    # the shared runs lead the list
+    @pl.when((blocks > 0) & (field(_COUNT, 0) > 1))
+    def _some_run_is_shared():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def copies(w, half, start):
+        """Start (or wait for) the K and V DMAs of block w's live pages
+        into `half` of the two VMEM blocks."""
+        base = field(_BASE, w)
+
+        def one_page(slot, carry):
+            _page_copy(start, pools, bufs, sem, pt_ref[base + slot], half,
+                       slot)
+            return carry
+        jax.lax.fori_loop(0, field(_PAGES, w), one_page, 0)
+
+    def block(w, carry):
+        # the next block's DMAs fly while this one is scored; the first
+        # turn of the loop only starts block 0's
+        half = jax.lax.rem(w + 2, 2)
+
+        @pl.when(w + 1 < blocks)
+        def _ahead():
+            copies(w + 1, 1 - half, True)
+
+        @pl.when(w >= 0)
+        def _this():
+            copies(w, half, False)
+            score(w, half)
+        return carry
+
+    def score(w, half):
+        row, count = field(_ROW, w), field(_COUNT, w)
+        first, limit = field(_POS, w), field(_LIMIT, w)
+        live = jnp.minimum(limit - first, group * page_size)
+        chunks = jax.lax.div(live + (tokens - 1), tokens)
+
+        def a_tail():
+            orig, edge = field(_ORIG, w), field(_EDGE, w)
+
+            @pl.when((jax.lax.rem(edge, 2) == 1) & (first == 0))
+            def _a_row_of_its_own():
+                mt_ref[...] = jnp.full(mt_ref.shape, _NEG_INF, jnp.float32)
+                lt_ref[...] = jnp.zeros(lt_ref.shape, jnp.float32)
+                at_ref[...] = jnp.zeros(at_ref.shape, jnp.float32)
+
+            @pl.when((jax.lax.rem(edge, 2) == 1) & (first > 0))
+            def _behind_a_shared_run():
+                # what the row gathered over its group's run, a KV head's
+                # query heads at a time
+                its = pl.ds(row * heads_per_kv, heads_per_kv)
+
+                def a_head(j, carry):
+                    mine = pl.ds(j * heads_per_kv, heads_per_kv)
+                    for own, run in ((mt_ref, m_ref), (lt_ref, l_ref),
+                                     (at_ref, acc_ref)):
+                        own[mine, :] = run[j, its, :]
+                    return carry
+                jax.lax.fori_loop(0, nkv, a_head, 0)
+
+            def a_chunk(c, carry):
+                pages = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+                _gqa_chunk_update(
+                    qr_ref.at[pl.ds(orig, 1)], k_buf.at[half, pages],
+                    v_buf.at[half, pages], live - c * tokens, mt_ref, lt_ref,
+                    at_ref, sm_scale=sm_scale, num_kv_heads=nkv)
+                return carry
+            jax.lax.fori_loop(0, chunks, a_chunk, 0)
+
+            @pl.when(edge >= 2)
+            def _the_rows_answer():
+                o_ref[orig] = at_ref[...] / jnp.maximum(lt_ref[...], 1e-30)
+
+        def a_run():
+            abreast = math.gcd(nkv, HEADS_ABREAST)
+            # whole tiles while more rows are left than a half tile holds,
+            # then a half tile: a group's last rows pay for half the dead
+            # rows of a product
+            whole = jax.lax.div(jnp.maximum(count - half_tile, 0)
+                                + (tile - 1), tile)
+
+            def a_tile(c, size, its):
+                """`size` rows from sorted row `its` on over chunk c, a KV
+                head at a time. The last tile ends where the rows end: it
+                masks what it holds of other rows."""
+                pages = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+                r = jnp.minimum(its, rows - size)
+                at = pl.ds(r * heads_per_kv, size * heads_per_kv)
+                lo = (its - r) * heads_per_kv
+                hi = (jnp.minimum(its + size, row + count) - r) * heads_per_kv
+
+                # some heads in one straight run: one head's products are
+                # in flight while the last one's softmax is worked out
+                def some_heads(i, carry):
+                    for j in range(abreast):
+                        j += i * abreast
+                        lane = pl.ds(pl.multiple_of(j * dh, dh), dh)
+                        m_ref[j, at], l_ref[j, at], acc_ref[j, at] = \
+                            _tile_update(
+                                qt_ref[j, at],
+                                k_buf[half, pages, :, lane].reshape(tokens,
+                                                                    dh),
+                                v_buf[half, pages, :, lane].reshape(tokens,
+                                                                    dh),
+                                lo, hi, m_ref[j, at], l_ref[j, at],
+                                acc_ref[j, at], sm_scale=sm_scale)
+                    return carry
+                jax.lax.fori_loop(0, nkv // abreast, some_heads, 0)
+
+            def a_chunk(c, carry):
+                jax.lax.fori_loop(
+                    0, whole, lambda t, carry: (
+                        a_tile(c, tile, row + t * tile), carry)[1], 0)
+                if half_tile:
+                    pl.when(whole * tile < count)(
+                        lambda: a_tile(c, half_tile, row + whole * tile))
+                return carry
+            jax.lax.fori_loop(0, chunks, a_chunk, 0)
+
+        # a branch a block, so that a row's chunk is one straight run of
+        # products, as a group's tile is
+        pl.when(count == 1)(a_tail)
+        pl.when(count > 1)(a_run)
+
+    jax.lax.fori_loop(-1, blocks, block, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _walk_call(q, k_pool, v_pool, page_table, plan, sm_scale, interpret):
+    B, nh, dh = q.shape
+    num_pages, ps, width = k_pool.shape
+    P = page_table.shape[1]
+    nkv = width // dh
+    heads_per_kv = nh // nkv
+    tile, half_tile = tile_rows(heads_per_kv)
+    rows = max(B, tile)
+    group = pages_per_grid_step(P, ps, width, k_pool.dtype.itemsize)
+    # clamp so a padded/garbage table entry names a real page
+    table = jnp.clip(page_table, 0, num_pages - 1).astype(jnp.int32).reshape(
+        B * P)
+    q = q.astype(jnp.float32)
+    # a KV head at a time, a group's rows together
+    tiles = jnp.pad(
+        q[plan.order].reshape(B, nkv, heads_per_kv, dh).transpose(1, 0, 2, 3),
+        ((0, 0), (0, rows - B), (0, 0), (0, 0))
+    ).reshape(nkv, rows * heads_per_kv, dh)
+    whole = lambda shape: pl.BlockSpec(                      # noqa: E731
+        shape, lambda i, *prefetched: (0,) * len(shape))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(1,),
+        in_specs=[whole(tiles.shape), whole(q.shape), pool, pool],
+        out_specs=whole(q.shape),
+        scratch_shapes=[
+            pltpu.VMEM((2, group, ps, width), k_pool.dtype),
+            pltpu.VMEM((2, group, ps, width), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),            # [K | V, half]
+            pltpu.VMEM(tiles.shape[:2] + (_LANES,), jnp.float32),  # maximum
+            pltpu.VMEM(tiles.shape[:2] + (_LANES,), jnp.float32),  # sum
+            pltpu.VMEM(tiles.shape, jnp.float32),       # the runs' numerator
+            pltpu.VMEM((nh, _LANES), jnp.float32),      # a tail's maximum
+            pltpu.VMEM((nh, _LANES), jnp.float32),      # denominator
+            pltpu.VMEM((nh, dh), jnp.float32),          # numerator
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_walk_kernel, page_size=ps, group=group,
+                          tile=tile, half_tile=half_tile,
+                          heads_per_kv=heads_per_kv, sm_scale=sm_scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        cost_estimate=pl.CostEstimate(
+            flops=B * nh * 2 * 2 * P * ps * dh,
+            bytes_accessed=(2 * B * P * ps * width * k_pool.dtype.itemsize
+                            + 2 * B * nh * dh * 4),
+            transcendentals=B * P * ps * nh),
+        # the blocks share the rows' running sums and each starts the DMAs
+        # of the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=WALK_VMEM_LIMIT),
+        interpret=interpret,
+        name="paged_decode_attention_gqa",
+    )(table, plan.work, plan.blocks, tiles, q, k_pool, v_pool)
+
+
 def _workbench_register():
     from . import workbench
 
@@ -521,7 +963,7 @@ def _workbench_register():
 
 @_workbench_register()
 def paged_decode_attention(q, k_pool, v_pool, page_table, kv_lens,
-                           sm_scale=1.0, first_live=None):
+                           sm_scale=1.0, first_live=None, plan=None):
     """One decode step of ragged paged attention.
 
     q: [B, nh, dh] (this step's query per request row);
@@ -534,8 +976,18 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, kv_lens,
     sliding-window layer): row b attends slots `first_live[b] ..
     kv_lens[b] - 1`; the slots before are masked, the pages wholly before
     are never fetched, and the call runs under the name
-    `paged_window_attention_gqa`.
+    `paged_window_attention_gqa`. `plan`: the step's `walk_plan(page_table,
+    kv_lens, ...)` where `walk_supported` says the list-walking form serves
+    the call, which a caller of several layers works out once (any layer's
+    table gives the same).
     """
+    if first_live is None and (plan is not None or walk_supported(
+            q.shape, k_pool.shape, k_pool.dtype, page_table.shape[1])):
+        if plan is None:
+            plan = walk_plan(page_table, kv_lens, k_pool.shape,
+                             k_pool.dtype.itemsize)
+        return _walk_call(q, k_pool, v_pool, page_table, plan,
+                          float(sm_scale), bool(INTERPRET)).astype(q.dtype)
     if first_live is None:
         return _call(q, k_pool, v_pool, page_table, kv_lens,
                      float(sm_scale), bool(INTERPRET))

@@ -67,7 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from .attention_ops import (_gather_pages, _write_rows, kv_cache_append_fn,
-                            paged_decode_attention_fn)
+                            paged_decode_attention_fn, paged_decode_plan_fn)
 from .cca_moe_ops import _page_row_index, rms_norm_fn
 from .hybrid_moe_ops import causal_attention_fn, rotary_fn, yarn_inv_freq_fn
 from ..observability.schema import piece, under_mode
@@ -376,6 +376,11 @@ def parallel_ssm_stack_fn(mode: str, tok, pos, emb, head, final_norm,
         slot = state_slot.astype(jnp.int32)                     # [B]
         # a window at position 0 starts a sequence: its state is zeros
         fresh = (first == 0) & (not decode)
+        # which rows' tables begin with the same pages is the tables'
+        # alone: every layer's decode call shares one plan, a constant of
+        # the scanned body
+        walk = paged_decode_plan_fn((B, nh, dh), _F32, pools[0], page_table,
+                                    first + 1) if decode else None
 
     def layer(carry, xs):
         l, p = xs
@@ -449,7 +454,7 @@ def parallel_ssm_stack_fn(mode: str, tok, pos, emb, head, final_norm,
                 with piece("attend"):
                     o = paged_decode_attention_fn(
                         q[:, 0], k_pool, v_pool, table, first + 1,
-                        sm_scale=sm_scale)[:, None]
+                        sm_scale=sm_scale, plan=walk)[:, None]
             else:
                 with piece("kv_write"):
                     idx = _page_row_index(page_table, gpos, page_size, off,

@@ -748,7 +748,7 @@ class ServingEngine:
                 (self.pool_pages, self.page_size, self.cfg.routed_layers)
                 + per_token,
                 np.int8 if self.cfg.num_experts <= 128 else np.int16)
-        self._grid_steps_by_signature: dict[tuple[int, int], int] = {}
+        self._paged_call_by_signature: dict[tuple[int, int], tuple] = {}
         self._indexer_kernel_runs: bool | None = None
         self._attend_kernel_runs: dict[tuple[int, int], bool] = {}
         self._conv_kernel_runs: bool | None = None
@@ -799,6 +799,7 @@ class ServingEngine:
             # window and full attention layers over two pools (ISSUE 33)
             "kv.window_pages_released": 0, "kv.window_row_pages": 0,
             "kv.global_row_pages": 0, "attn.full_context_tokens": 0,
+            "attn.attended_tokens": 0, "attn.shared_kernel_layer_steps": 0,
             "attn.window_context_tokens": 0, "attn.full_layer_steps": 0,
             "attn.window_layer_steps": 0, "peak_window_pages_in_use": 0,
             # steps read one dispatch late (ISSUE 36); steps_blocking goes
@@ -2536,29 +2537,32 @@ class ServingEngine:
         # outranks new arrivals under fcfs
         self._waiting.insert(0, req)
 
-    def _decode_grid_steps(self, bb: int, pb: int) -> int:
-        """Grid steps of one layer's paged decode call at the (rows, page
-        bucket) signature, 0 where the XLA gather serves it: the kernel's
-        own arithmetic, asked once a signature."""
-        steps = self._grid_steps_by_signature.get((bb, pb))
-        if steps is None:
+    def _paged_decode_call(self, bb: int, pb: int):
+        """(grid steps, whether the kernel walks a list of blocks, the pool's
+        shape, its item size) of one layer's paged decode call at the (rows,
+        page bucket) signature; (0, False, ..) where the XLA gather serves
+        it or the family reads its pages through a kernel of its own
+        (`_attend_kernel`): the ops' own arithmetic, asked once a
+        signature."""
+        call = self._paged_call_by_signature.get((bb, pb))
+        if call is None:
             cfg = self.cfg
-            if cfg.selects or cfg.latent:
-                # gathers through XLA at every context, or reads its pages
-                # through a kernel of its own (`_attend_kernel`)
-                steps = 0
-            else:
+            call = (0, False, None, 0)
+            if not (cfg.selects or cfg.latent):
                 pool = self._scope.find_var(
                     "kv_cache.k" if cfg.scanned
                     else pool_var_names(cfg.num_layers)[0][0])
                 # the scanned blocks attend with float32 queries whatever
                 # dtype their weights and pools have
-                steps = attention_ops.paged_decode_grid_steps(
-                    (bb, cfg.num_heads, cfg.head_dim),
-                    "float32" if cfg.scanned else cfg.dtype,
-                    pool.shape, pool.dtype, pb, tp=self.tp)
-            self._grid_steps_by_signature[(bb, pb)] = steps
-        return steps
+                shape = ((bb, cfg.num_heads, cfg.head_dim),
+                         "float32" if cfg.scanned else cfg.dtype,
+                         pool.shape, pool.dtype, pb)
+                call = (attention_ops.paged_decode_grid_steps(
+                            *shape, tp=self.tp),
+                        attention_ops.paged_decode_walks(*shape, tp=self.tp),
+                        tuple(pool.shape), np.dtype(pool.dtype).itemsize)
+            self._paged_call_by_signature[(bb, pb)] = call
+        return call
 
     def _indexer_kernel(self) -> bool:
         """Whether the paged Pallas kernel scores a decode step's context
@@ -2667,17 +2671,32 @@ class ServingEngine:
         sp.note(rows=len(rows), bb=bb, pb=pb)
         self._count("decode_steps")
         self.stats["decode_signatures"].add((bb, pb))
-        self._count("decode_context_pages",
-                    sum(pos // ps + 1 for pos, _ in at))
-        self._count("decode_grid_steps", self._decode_grid_steps(bb, pb))
+        # what one layer's decode attention read: every row's own context,
+        # or, where the kernel walks the step's list of page blocks, a run
+        # of pages that rows share ONCE and behind it every row's own (its
+        # own rule over these feeds; the rows padded in left out as ever)
+        grid_steps, walks, pool_shape, itemsize = self._paged_decode_call(
+            bb, pb)
+        attended = sum(pos + 1 for pos, _ in at)
+        read = {"pages": sum(pos // ps + 1 for pos, _ in at),
+                "tokens": attended, "blocks": grid_steps, "shared": False}
+        if walks:
+            from ..ops.pallas_kernels import paged_attention
+
+            read = paged_attention.walk_counts(
+                pages[:len(rows)], pos[:len(rows)] + 1, pool_shape, itemsize)
+        self._count("decode_context_pages", read["pages"])
+        self._count("decode_grid_steps", read["blocks"])
         if self.window_pool is not None:
             full, slide = self._full_layers, self._slide_layers
             W = self.cfg.sliding_window
             self._count("kv.global_row_pages", sum(len(r.pages) for r in rows))
             self._count("kv.window_row_pages",
                         sum(len(r.wpages) for r in rows))
-            self._count("attn.full_context_tokens",
-                        full * sum(pos + 1 for pos, _ in at))
+            self._count("attn.full_context_tokens", full * read["tokens"])
+            self._count("attn.attended_tokens", full * attended)
+            self._count("attn.shared_kernel_layer_steps",
+                        full if read["shared"] else 0)
             self._count("attn.window_context_tokens",
                         slide * sum(min(W, pos + 1) for pos, _ in at))
             self._count("attn.full_layer_steps", full)

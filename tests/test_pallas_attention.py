@@ -85,3 +85,167 @@ def test_supported_gate():
                                        bias=None)
     assert psa.short_seq_supported((2, 12, 512, 64), (2, 12, 512, 64),
                                    bias=None)
+
+
+# -- the grouped-query paged decode kernel that walks a list of page blocks --
+# (PR 50): rows whose tables begin with the same pages attend that run once
+
+PS, DH = 128, 128
+
+
+def _shared_tables(kind):
+    """(table [B, P], lens [B]) with blocks of two pages; pool pages 0..3
+    are kept for the padding rows' zeros."""
+    own = iter(range(100, 10_000))
+    fresh = lambda n: [next(own) for _ in range(n)]          # noqa: E731
+    ctx = [fresh(6) for _ in range(4)]
+    rows = []                                   # (pages, length)
+
+    def behind(c, shared, mine, length):
+        pages = ctx[c][:shared] + fresh(mine)
+        rows.append((pages, length if length else len(pages) * PS - 37))
+
+    if kind == "one group of 27 and three smaller":
+        for c, n in enumerate((27, 5, 3, 2)):
+            for i in range(n):
+                behind(c, 4, 1 + i % 2, 0)
+    elif kind == "a run that ends inside a member's last whole page":
+        # the third row leaves the context after five pages, the fourth is
+        # live for four pages and a half: the run is four, one block short
+        # of what the first two share
+        behind(0, 6, 1, 0), behind(0, 6, 2, 0), behind(0, 5, 2, 0)
+        behind(0, 6, 0, 4 * PS + 64)
+    elif kind == "a row whose length ends inside the shared run":
+        # its tables names all six pages, its length three: it holds no
+        # whole block beyond the first, and keeps the group to one block
+        behind(0, 6, 1, 0), behind(0, 6, 1, 0), behind(0, 6, 0, 3 * PS - 5)
+        behind(1, 4, 1, 0), behind(1, 4, 0, 4 * PS)   # a tail of no page
+    elif kind == "a run one page short of a block":
+        behind(0, 1, 2, 0), behind(0, 1, 3, 0), behind(0, 1, 1, 0)
+    elif kind == "nested prefixes":
+        behind(0, 6, 1, 0), behind(0, 6, 1, 0), behind(0, 2, 3, 0)
+        behind(0, 4, 2, 0), behind(1, 2, 0, 2 * PS + 0)
+    elif kind == "padding rows in and between groups":
+        behind(0, 4, 1, 0), rows.append(([], 0)), behind(0, 4, 2, 0)
+        behind(1, 4, 1, 0), rows.append(([], 0)), rows.append(([], 0))
+        behind(1, 4, 1, 0), behind(0, 4, 1, 1), behind(2, 2, 1, 0)
+    else:
+        assert kind == "a table that shares nothing"
+        for i in range(9):
+            behind(i % 4, 0, 1 + i % 6, 0)
+        rows.append(([], 0)), behind(0, 0, 7, 7 * PS)
+    P = 8
+    table = np.array([(p + [0] * P)[:P] for p, _ in rows], np.int32)
+    return table, np.array([n for _, n in rows], np.int32)
+
+
+def _by_hand(table, lens, block):
+    """Pages and tokens a layer's call fetches, counted with sets: a run
+    is what ALL rows that begin with the same block of whole pages share,
+    in whole blocks."""
+    whole = lens // PS
+    groups = {}
+    for b in range(len(lens)):
+        key = tuple(table[b, :block]) if whole[b] >= block else ("own", b)
+        groups.setdefault(key, []).append(b)
+    pages = tokens = 0
+    for members in groups.values():
+        run = 0
+        if len(members) > 1:
+            first = table[members[0]]
+            common = min(next((i for i in range(table.shape[1])
+                               if table[b, i] != first[i]), table.shape[1])
+                         for b in members)
+            run = min(common, min(whole[b] for b in members)) \
+                // block * block
+        pages += run + sum(-(-lens[b] // PS) - run for b in members)
+        tokens += run * PS + sum(lens[b] - run * PS for b in members)
+    return int(pages), int(tokens)
+
+
+def _paged_case(nh, nkv, dtype, table, lens, monkeypatch, seed=0):
+    from paddle_tpu.ops.attention_ops import _paged_attention_reference
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    monkeypatch.setattr(ppa, "INTERPRET", True)
+    item = jnp.dtype(dtype).itemsize
+    # blocks of two pages
+    monkeypatch.setattr(ppa, "BLOCK_BYTES", 2 * 2 * PS * nkv * DH * item)
+    rng = np.random.default_rng(seed)
+    pool = lambda: jnp.asarray(                                 # noqa: E731
+        rng.standard_normal((int(table.max()) + 1, PS, nkv * DH)), dtype)
+    k_pool, v_pool = pool(), pool()
+    q = jnp.asarray(rng.standard_normal((len(lens), nh, DH)), jnp.float32)
+    assert ppa.walk_supported(q.shape, k_pool.shape, dtype, table.shape[1])
+    assert ppa.pages_per_grid_step(table.shape[1], PS, nkv * DH, item) == 2
+    args = (q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(lens))
+    got = np.asarray(ppa.paged_decode_attention(*args, sm_scale=DH ** -0.5))
+    want = np.asarray(_paged_attention_reference(*args, DH ** -0.5))
+    live = lens > 0
+    np.testing.assert_allclose(got[live], want[live],
+                               atol=1e-2 if item == 2 else 2e-5)
+    assert not got[~live].any()                 # a padding row: zeros
+    return ppa, args, got
+
+
+@pytest.mark.parametrize("kind,runs", [
+    ("one group of 27 and three smaller", [(4, 27), (4, 5), (4, 3), (4, 2)]),
+    ("a run that ends inside a member's last whole page", [(4, 4)]),
+    ("a row whose length ends inside the shared run", [(2, 3), (4, 2)]),
+    ("a run one page short of a block", []),
+    ("nested prefixes", [(2, 4)]),
+    ("padding rows in and between groups", [(4, 2), (4, 2)]),
+    ("a table that shares nothing", [])])
+def test_paged_gqa_walk_matches_reference(kind, runs, monkeypatch):
+    """Laguna-XS.2's head shape (48 query heads over 8 KV heads, groups of
+    6 in 8 sublanes), a bfloat16 pool, blocks of two pages: what the rows
+    share is read once, what each holds behind it is its own, and the
+    answer is the XLA reference's. `runs`: (pages, rows) of every shared
+    run the table holds."""
+    table, lens = _shared_tables(kind)
+    ppa, args, got = _paged_case(48, 8, jnp.bfloat16, table, lens,
+                                 monkeypatch)
+    read = ppa.walk_counts(table, lens, args[1].shape, 2)
+    assert (read["pages"], read["tokens"]) == _by_hand(table, lens, 2)
+    assert read["shared"] == bool(runs)
+    # a run is read once where its rows would each have read it
+    assert int((-(-lens // PS)).sum()) - read["pages"] \
+        == sum(run * (rows - 1) for run, rows in runs)
+    plan = ppa.walk_plan(args[3], args[4], args[1].shape, 2)
+    assert int(plan.blocks[0]) == read["blocks"]
+    if not runs:
+        # groups of one: the parent's grid over (row, block), to rounding
+        parent = np.asarray(ppa._call(*args, DH ** -0.5, True))
+        np.testing.assert_allclose(got[lens > 0], parent[lens > 0],
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("nh,nkv", [(48, 8), (24, 4), (32, 2)],
+                         ids=["laguna", "falcon", "nemotron"])
+def test_paged_gqa_walk_at_the_families_head_shapes(nh, nkv, dtype,
+                                                    monkeypatch):
+    """48 over 8 (Laguna-XS.2), 24 over 4 (Falcon-H1's 20 as groups of 6)
+    and 32 over 2 (Nemotron 3: groups of 16, two sublane tiles), bfloat16
+    and float32 pools, over a table that holds two groups, a group of one,
+    a padding row and a row of one token."""
+    table, lens = _shared_tables("padding rows in and between groups")
+    _paged_case(nh, nkv, dtype, table, lens, monkeypatch, seed=nh)
+
+
+def test_paged_gqa_walk_gate_and_tile():
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    pool = (2048, 128, 1024)
+    assert ppa.walk_supported((64, 48, 128), pool, jnp.bfloat16, 160)
+    # the `post_ln` arm (as many KV heads as query heads) keeps its grid
+    assert not ppa.walk_supported((64, 12, 64), (2048, 16, 768),
+                                  jnp.float32, 32)
+    # rows whose running sums no longer stay resident, and a table past the
+    # scalar memory a call prefetches, keep the grid too
+    assert not ppa.walk_supported((2048, 48, 128), pool, jnp.bfloat16, 160)
+    assert not ppa.walk_supported((64, 48, 128), pool, jnp.bfloat16,
+                                  ppa.TABLE_ENTRIES // 64 + 1)
+    assert [ppa.tile_rows(h) for h in (4, 5, 6, 12, 16, 32, 128)] == [
+        (16, 8), (16, 8), (16, 8), (10, 0), (8, 4), (4, 2), (1, 0)]

@@ -745,6 +745,48 @@ def test_paged_decode_blocks_fit_their_vmem_budget_on_v5e(
     assert pool_sized_copies(text, pool[0] * ps * width) == []
 
 
+@pytest.mark.parametrize("q_shape,pool,bucket,tiles", [
+    ((64, 48, 128), (2 * 2048, 128, 1024), 160, (16, 8)),
+    ((64, 24, 128), (6 * 768, 128, 512), 24, (16, 8)),
+    ((128, 32, 128), (4096, 128, 256), 64, (8, 4)),
+    ((2, 8, 128), (24 * 640, 128, 256), 32, (16, 8)),
+], ids=["laguna_xs2", "falcon_h1_34b", "nemotron3_super_120b",
+        "zaya1_8b_2rows"])
+def test_paged_gqa_walk_compiles_for_v5e(v5e_chip, q_shape, pool, bucket,
+                                         tiles):
+    """The grouped-query decode kernel that walks a list of page blocks
+    (PR 50) alone, at the four cells' shapes and tables: the gate says yes,
+    the tile follows from the heads a KV head, and Mosaic takes it (the
+    unaligned windows of sublanes of 6 heads a KV head among the rest) with
+    the pools left in HBM; a table one block wide keeps the grid."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    _, ps, width = pool
+    nkv = width // q_shape[2]
+    assert ppa.walk_supported(q_shape, pool, "bfloat16", bucket)
+    assert not ppa.walk_supported(q_shape, pool, "bfloat16", 4)
+    assert ppa.tile_rows(q_shape[1] // nkv) == tiles
+    sh = SingleDeviceSharding(v5e_chip)
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=sh) for shape, dt in (
+        (q_shape, jnp.float32), (pool, "bfloat16"), (pool, "bfloat16"),
+        ((q_shape[0], bucket), jnp.int32), ((q_shape[0],), jnp.int32))]
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(lambda *a: ppa.paged_decode_attention(
+            *a, sm_scale=0.088)).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in text and "paged_decode_attention_gqa" in text
+    assert pool_sized_copies(text, pool[0] * ps * width) == []
+
+
 @pytest.mark.parametrize("q_shape,pool,group", [
     ((128, 64, 128), (5 * 2304, 128, 128), 48),
     ((64, 16, 64), (6 * 1792, 64, 128), 96),

@@ -12,6 +12,14 @@ the configuration's own `engine` block, from SHAPES alone, with
 `HASHES` was computed by this file's `program_hash` in a checkout of commit
 757cdc2 (PR 46) and in this tree; a change that means to alter one of
 these programs recomputes its line and says so.
+
+PR 50 recomputed FOUR lines, the decode programs of `zaya1_8b`,
+`laguna_xs2`, `falcon_h1_34b` and `nemotron3_super_120b`: the grouped-query
+paged decode kernel without a first live slot now walks a flat list of page
+blocks and reads a run of pages that rows share once
+(`paged_attention._walk_kernel`), and each of these stacks works the step's
+plan out before its layers. Every other line, the four families' prefill
+lines among them, is what it was.
 """
 import hashlib
 from unittest import mock
@@ -27,19 +35,19 @@ from tests.test_kernel_choice import _read_shapes, _serving_program
 HASHES = {
     ("bert_base_decoder", "decode", "64"): "67f5d72e4030401e",
     ("bert_base_decoder", "prefill", "256"): "dc7d3bcbe1dcf965",
-    ("zaya1_8b", "decode", "64"): "f6405da0a9cf5a43",
+    ("zaya1_8b", "decode", "64"): "c93fd3979a914686",
     ("zaya1_8b", "prefill", "256"): "5d2e4391d41ad57e",
     ("keye_vl2_30b_a3b", "decode", "64"): "9a764176abb865e3",
     ("keye_vl2_30b_a3b", "prefill", "128"): "6d1544e0c1ceb870",
-    ("laguna_xs2", "decode", "64"): "f26685738caaafda",
+    ("laguna_xs2", "decode", "64"): "1be2f4441e04e03b",
     ("laguna_xs2", "prefill", "256"): "20d0e440cb6377c5",
-    ("falcon_h1_34b", "decode", "64"): "12d62a8745015e01",
+    ("falcon_h1_34b", "decode", "64"): "9694da0b40125e3e",
     ("falcon_h1_34b", "prefill", "512"): "26b70b79f4585ee8",
     ("deepseek_v32_exp", "decode", "128"): "0587bbd89e2a8e47",
     ("deepseek_v32_exp", "decode", "32"): "36487f961f762836",
     ("deepseek_v32_exp", "prefill", "128"): "cfe584788426341a",
     ("deepseek_v32_exp", "prefill", "512"): "affbedfc12fc5867",
-    ("nemotron3_super_120b", "decode", "128"): "6103670d68e2d4ba",
+    ("nemotron3_super_120b", "decode", "128"): "f045b546ec04edeb",
     ("nemotron3_super_120b", "prefill", "512"): "6509dd8f853f64cd",
 }
 

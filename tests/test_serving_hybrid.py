@@ -566,3 +566,63 @@ def test_window_release_is_a_span_and_the_gauges_read_both_pools():
     assert snap["gauges"]["serving.kv.global_pages_in_use"] \
         == eng.pool.pages_in_use
     assert not snap["undeclared"]
+
+
+@pytest.mark.parametrize("walks", [True, False],
+                         ids=["the kernel walks the list", "a row its own"])
+def test_the_full_layers_tokens_follow_the_kernel_that_ran(walks,
+                                                           monkeypatch):
+    """Three rows behind one prompt of six whole pages (the prefix cache
+    hands them the same pages), each with a question of its own, blocks of
+    two pages. Where the kernel's gate answers yes,
+    `attn.full_context_tokens`, `decode_context_pages` and
+    `decode_grid_steps` are what the kernel's own rule says of every step's
+    feeds (the shared run once); where it answers no, every row's context.
+    `attn.attended_tokens` is every row's own context in both, and the
+    tokens served are right in both (the program's attention is the XLA
+    arm's here: the gate steers the count and the plan, not the result)."""
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    monkeypatch.setattr(ppa, "pages_per_grid_step", lambda *a: 2)
+    monkeypatch.setattr(attention_ops, "paged_decode_walks",
+                        lambda *a, **k: walks)
+    said, own = [], []
+    count = ppa.walk_counts
+    monkeypatch.setattr(ppa, "walk_counts",
+                        lambda *feeds: said.append(count(*feeds)) or said[-1])
+    eng = _engine()
+    shared = _prompts(5, 6 * PS)[0]
+    _serve(eng, [shared], new=1)            # the prompt's pages are cached
+    eng.reset_stats()
+    run_step = eng._run_step
+
+    def spy(kind, target, io, feed, *args, **kwargs):
+        if kind == "decode":
+            live = feed[sv_model.MASK_FEED][:, 0] > 0
+            own.append(feed[sv_model.POS_FEED].reshape(-1)[live] + 1)
+        return run_step(kind, target, io, feed, *args, **kwargs)
+
+    monkeypatch.setattr(eng, "_run_step", spy)
+    prompts = _prompts(6, 3, 5, 9, shared=shared)
+    done = _serve(eng, prompts, new=5)
+    _assert_right(eng, prompts, done)
+    st, full = eng.stats, eng._full_layers
+    attended = full * sum(int(n.sum()) for n in own)
+    pages = sum(int((-(-n // PS)).sum()) for n in own)
+    assert st["attn.attended_tokens"] == attended > 0
+    assert obs.snapshot()["counters"]["serving.attn.attended_tokens"] \
+        == attended
+    if not walks:
+        assert not said and st["attn.shared_kernel_layer_steps"] == 0
+        assert st["attn.full_context_tokens"] == attended
+        assert st["decode_context_pages"] == pages
+        return
+    assert len(said) == st["decode_steps"]
+    assert st["attn.full_context_tokens"] \
+        == full * sum(r["tokens"] for r in said) < attended
+    assert st["decode_context_pages"] == sum(r["pages"] for r in said) < pages
+    assert st["decode_grid_steps"] == sum(r["blocks"] for r in said)
+    assert st["attn.shared_kernel_layer_steps"] \
+        == full * sum(r["shared"] for r in said) > 0
+    # three rows behind six of their seven to nine pages
+    assert attended / st["attn.full_context_tokens"] > 1.8

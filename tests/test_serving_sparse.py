@@ -593,7 +593,7 @@ def test_joined_pool_holds_the_bytes_of_the_two_pools(dtype):
     assert not eng._scope.has_var("kv_cache.k") \
         and not eng._scope.has_var("kv_cache.v")
     # the Pallas paged kernel reads two pools: this block never calls it
-    assert eng._decode_grid_steps(4, 1) == 0
+    assert eng._paged_decode_call(4, 1)[:2] == (0, False)
 
 
 def test_bfloat16_engine_stays_inside_the_bfloat16_tolerances():

@@ -389,6 +389,70 @@ def test_the_padded_group_runs_the_paged_kernel(monkeypatch):
     assert float(jnp.max(jnp.abs(got - want))) < 2e-3
 
 
+def test_twenty_heads_behind_one_prompt_read_it_once(monkeypatch):
+    """The same 20 heads as groups of 6 where two of three rows stand
+    behind one prompt of two whole pages (blocks of two pages): the
+    dispatch works the step's plan out, the kernel walks its list
+    (interpreter) and reads what the XLA path reads; handed the plan the
+    stack worked out before its layers, it reads the same."""
+    monkeypatch.setattr(ppa, "INTERPRET", True)
+    B, nh, nkv, dh, ps, pages, P = 3, 20, 4, 128, 128, 12, 4
+    monkeypatch.setattr(ppa, "BLOCK_BYTES", 2 * 2 * ps * nkv * dh * 4)
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(ks[0], (B, nh, dh))
+    kp = jax.random.normal(ks[1], (pages, ps, nkv * dh))
+    vp = jax.random.normal(ks[2], (pages, ps, nkv * dh))
+    table = jnp.asarray([[4, 9, 0, 3], [5, 1, 6, 0], [4, 9, 2, 7]], jnp.int32)
+    lens = jnp.asarray([400, 300, 512], jnp.int32)
+    assert attention_ops.paged_decode_walks(q.shape, q.dtype, kp.shape,
+                                            kp.dtype, P)
+    # rows 0 and 2 share pages 4, 9: one block read once, then two pages
+    # (one block) each of their own; row 1 its three pages (two blocks)
+    assert ppa.walk_counts(table, lens, kp.shape, 4) == {
+        "pages": 2 + 2 + 2 + 3, "tokens": 256 + 144 + 256 + 300,
+        "blocks": 1 + 1 + 1 + 2, "shared": True}
+    got = attention_ops.paged_decode_attention_fn(q, kp, vp, table, lens,
+                                                  sm_scale=dh ** -0.5)
+    plan = attention_ops.paged_decode_plan_fn(q.shape, q.dtype, kp, table,
+                                              lens)
+    again = attention_ops.paged_decode_attention_fn(
+        q, kp, vp, table + 0, lens, sm_scale=dh ** -0.5, plan=plan)
+    with jax.default_matmul_precision("highest"):
+        want = attention_ops._paged_attention_reference(
+            q, kp, vp, table, lens, dh ** -0.5)
+    assert got.shape == (B, nh, dh)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-3
+    assert np.array_equal(np.asarray(got), np.asarray(again))
+
+
+def test_rows_behind_one_prompt_through_the_walking_kernel(monkeypatch):
+    """The scanned stack end to end with 20 heads of 128 over 4 KV heads in
+    pages of 128 (interpreter; blocks of two pages; the one page bucket of
+    five is no whole number of blocks): three rows behind one prompt of two
+    whole pages read through the plan the stack closes its layer over. The
+    logits are the reference's and the engine booked the shared block once
+    and a page of its own a row."""
+    monkeypatch.setattr(ppa, "INTERPRET", True)
+    monkeypatch.setattr(ppa, "BLOCK_BYTES", 2 * 2 * 128 * 4 * 128 * 4)
+    cfg = parallel_ssm_tiny(attn_head_dim=128, num_heads=20, num_kv_heads=4,
+                            num_layers=2, max_position=640,
+                            prefill_chunk=128)
+    eng = _engine(cfg, page_size=128, pool_pages=24)
+    prompts = _prompts([3, 5, 9], seed=6, shared=256)
+    _serve(eng, [prompts[0][:256] + [1]], out=1)    # the prompt is cached
+    eng.reset_stats()
+    before = dict(attention_ops.dispatch_counts())
+    outs = _serve(eng, prompts, out=3)
+    assert max(_gaps(eng, prompts, outs)) < 1e-5
+    ran = {k[2] for k, n in attention_ops.dispatch_counts().items()
+           if k[0] == "paged" and n != before.get(k, 0)}
+    assert ran == {"pallas_paged"}
+    st = eng.stats
+    assert st["decode_signatures"] == {(4, 5)}
+    assert st["decode_context_pages"] == st["decode_steps"] * (2 + 3)
+    assert st["decode_grid_steps"] == st["decode_steps"] * (1 + 3)
+
+
 # -- the slot pool and its snapshots -------------------------------------------
 
 

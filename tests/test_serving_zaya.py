@@ -288,6 +288,38 @@ def test_grouped_query_paged_decode_pallas_matches_xla(dtype, lens, bucket,
     assert not np.asarray(got)[~live].any()        # a padded row: zeros
 
 
+def test_rows_behind_one_prompt_through_the_walking_kernel(monkeypatch):
+    """The scanned stack end to end with heads of 128 in pages of 128, so
+    that the grouped-query kernel serves decode (interpreter; blocks of two
+    pages): three rows behind one prompt of two whole pages, whose plan the
+    stack works out once and closes its scanned layer over. The tokens are
+    the reference's, every step ran the kernel, and the engine booked what
+    it read: the shared block once and a page of its own a row."""
+    from paddle_tpu.ops import attention_ops
+    ppa = importlib.import_module(
+        "paddle_tpu.ops.pallas_kernels.paged_attention")
+    monkeypatch.setattr(ppa, "INTERPRET", True)
+    monkeypatch.setattr(ppa, "BLOCK_BYTES", 2 * 2 * 128 * 2 * 128 * 4)
+    cfg = sv_model.cca_moe_tiny(attn_head_dim=128, num_heads=8,
+                                num_kv_heads=2, num_layers=2,
+                                max_position=640)
+    eng = _engine(cfg, page_size=128, pool_pages=24)
+    shared = _prompts(5, 256)[0]
+    _serve(eng, [shared], new=1)            # the prompt's pages are cached
+    eng.reset_stats()
+    before = dict(attention_ops.dispatch_counts())
+    prompts = _prompts(6, 3, 5, 9, shared=shared)
+    done = _serve(eng, prompts, new=3)
+    _assert_right(eng, prompts, done)
+    ran = {k[2] for k, n in attention_ops.dispatch_counts().items()
+           if k[0] == "paged" and n != before.get(k, 0)}
+    assert ran == {"pallas_paged"}
+    st = eng.stats
+    assert st["decode_signatures"] == {(4, 4)}
+    assert st["decode_context_pages"] == st["decode_steps"] * (2 + 3)
+    assert st["decode_grid_steps"] == st["decode_steps"] * (1 + 3)
+
+
 def test_bfloat16_engine_stays_inside_the_bfloat16_tolerances():
     """bfloat16 weights and pools, everything else float32, against the
     float32 reference on the SAME (bfloat16-stored) weights. At this width

@@ -227,6 +227,113 @@ def _paged_gqa_cases(spec):
          lambda nh=nh: window_case(nh)) for nh in (64, 48)]
 
 
+def _paged_gqa_step_cases(spec):
+    """The grouped-query arm at the steps the serving cells run, TIMED (ten
+    calls after the first; `us` a layer's call given the step's plan, as
+    the stacks call it, `us_alone` with the plan worked out in the call;
+    `sharing`: the live tokens the rows attend over the tokens the call
+    fetches, 1 where each row reads its own; `roofline_pct`: the K and V
+    bytes of the ATTENDED tokens against 819 GB/s, which bounds a kernel
+    that reads a page once a row and which one that shares may pass), the
+    first eight rows against the reference. Laguna-XS.2's full layer: 64
+    rows of 48 heads over 8 KV heads behind four contexts of 128 whole
+    pages (34, 15, 9 and 6 rows a context: zipf 1.2), 1-20 pages of its own
+    a row in a 160-page table; the same rows each behind a context of its
+    OWN, where nothing is shared and the time is the walk's; and ZAYA1's
+    step: 64 rows of 8 heads over 2 KV heads behind 19-28 pages of a
+    32-page table, the first four of them one of four system prompts',
+    under the block of 16 a group needs. Before them the other callers'
+    head shapes with a run to share (Falcon-H1, Nemotron 3, and ZAYA1
+    behind prompts of a whole block), sixteen rows against the reference."""
+    import time
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    dh, ps = 128, 128
+
+    def the_step(nh, nkv, P, shared_pages, own_pages, shared: bool):
+        B = 64
+        own = np.asarray(own_pages(np.arange(B)))
+        pages = 4 * shared_pages + int(own.sum())
+        ks = jax.random.split(jax.random.PRNGKey(50), 3)
+        q = _rand(ks[0], (B, nh, dh), "float32")
+        kp = _rand(ks[1], (pages, ps, nkv * dh), "bfloat16")
+        vp = _rand(ks[2], (pages, ps, nkv * dh), "bfloat16")
+        rng = np.random.default_rng(50)
+        behind = np.repeat(np.arange(4), [34, 15, 9, 6])[rng.permutation(B)]
+        table = np.zeros((B, P), np.int32)
+        at = 4 * shared_pages
+        for b in range(B):
+            table[b, :shared_pages] = (
+                behind[b] * shared_pages + np.arange(shared_pages) if shared
+                else (b * 29 + np.arange(shared_pages)) % (4 * shared_pages))
+            table[b, shared_pages:shared_pages + own[b]] = \
+                at + np.arange(own[b])
+            at += own[b]
+        lens = (shared_pages + own - 1) * ps + 1 + (np.arange(B) * 53) % ps
+        return (q, kp, vp, jnp.asarray(table), jnp.asarray(lens, jnp.int32))
+
+    def timed(args, check_rows=8):
+        q, kp, vp, table, lens = args
+        assert spec.supported(q.shape, kp.shape, "bfloat16")
+        scale = dh ** -0.5
+
+        def seconds(fn, *a):
+            out = jax.block_until_ready(fn(*a))
+            t = time.perf_counter()
+            for _ in range(10):
+                last = fn(*a)
+            jax.block_until_ready(last)
+            return out, (time.perf_counter() - t) / 10
+
+        itemsize = kp.dtype.itemsize
+        plan = jax.jit(lambda t, n: ppa.walk_plan(
+            t, n, kp.shape, itemsize))(table, lens)
+        got, call_s = seconds(jax.jit(
+            lambda *a: spec.fn(*a[:5], sm_scale=scale, plan=a[5])),
+            *args, plan)
+        _, alone_s = seconds(jax.jit(
+            lambda *a: spec.fn(*a, sm_scale=scale)), *args)
+        n = check_rows
+        with jax.default_matmul_precision("highest"):
+            want = spec.reference(q[:n], kp, vp, table[:n], lens[:n],
+                                  sm_scale=scale)
+        tokens = int(np.asarray(lens).sum())
+        read = ppa.walk_counts(table, lens, kp.shape, itemsize)
+        res = {"err": _rel_err(got[:n], want), "tol": 2e-2,
+               "finite": bool(np.isfinite(np.asarray(got)).all()),
+               "us": call_s * 1e6, "us_alone": alone_s * 1e6,
+               "sharing": tokens / read["tokens"],
+               "roofline_pct": tokens * 2 * kp.shape[2] * itemsize
+               / call_s / 819e9 * 100}
+        res["ok"] = bool(res["finite"] and res["err"] <= res["tol"])
+        return res
+
+    laguna = dict(nh=48, nkv=8, P=160, shared_pages=128,
+                  own_pages=lambda b: b * 7 % 20 + 1)
+    zaya = dict(nh=8, nkv=2, P=32, shared_pages=4,
+                own_pages=lambda b: 15 + b * 7 % 10)
+    # the other callers' shapes with a run to share: Falcon-H1's 20 heads as
+    # groups of 6 behind prompts of one block of 8 pages, Nemotron 3's
+    # groups of 16 and ZAYA1's of 4 behind one block of 16
+    falcon = dict(nh=24, nkv=4, P=24, shared_pages=8,
+                  own_pages=lambda b: 1 + b * 5 % 16)
+    nemotron = dict(nh=32, nkv=2, P=64, shared_pages=16,
+                    own_pages=lambda b: 1 + b * 7 % 20)
+    shared_zaya = dict(zaya, shared_pages=16, own_pages=lambda b: 1 + b % 12)
+    return [(f"b64 nh{c['nh']} nkv{c['nkv']} dh128 ps128 bfloat16 table "
+             f"{c['P']} four prompts of {c['shared_pages']} pages timed",
+             lambda c=c: timed(the_step(**c, shared=True), check_rows=16))
+            for c in (falcon, nemotron, shared_zaya)] + [
+        ("b64 nh48 nkv8 dh128 ps128 bfloat16 table 160 four contexts "
+         "34/15/9/6 rows timed",
+         lambda: timed(the_step(**laguna, shared=True))),
+        ("b64 nh48 nkv8 dh128 ps128 bfloat16 table 160 a context a row "
+         "timed", lambda: timed(the_step(**laguna, shared=False))),
+        ("b64 nh8 nkv2 dh128 ps128 bfloat16 table 32 four prompts of 4 "
+         "pages timed", lambda: timed(the_step(**zaya, shared=True)))]
+
+
 def _windowed_reference(q, kp, vp, table, lens, first, sm_scale):
     from paddle_tpu.ops.attention_ops import _paged_attention_reference
 
@@ -658,7 +765,8 @@ CASES = {
     "ssm_decode_update": _ssm_update_cases,
     "conv_decode_update": _conv_update_cases,
     "attention_paged_decode": lambda spec: (_paged_cases(spec)
-                                            + _paged_gqa_cases(spec)),
+                                            + _paged_gqa_cases(spec)
+                                            + _paged_gqa_step_cases(spec)),
     "moe_top1_experts": _moe_cases,
     "moe_relu2_experts": _moe_relu2_cases,
     # bench_bert_long: b64 s512
