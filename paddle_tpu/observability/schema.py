@@ -454,6 +454,26 @@ DECLARED: list[tuple] = [
      "real token x layer pairs that prefill windows scanned", ()),
     ("serving.ssm.scan_layer_steps", COUNTER,
      "layer x window pairs: the calls of the chunked scan", ()),
+    # a Kimi-Delta layer's state ("kda_moe": the same slots, snapshots and
+    # restores; its convolution's kernel is booked under
+    # serving.ssm.conv_kernel_layer_steps, over kda.decode_layer_steps)
+    ("serving.kda.decode_row_layers", COUNTER,
+     "row x Kimi-Delta layer pairs whose matrix state a decode step "
+     "updated, summed over steps (x a slot's bytes, read and written: "
+     "what the update had to move)", ()),
+    ("serving.kda.decode_layer_steps", COUNTER,
+     "Kimi-Delta layer x decode-step pairs: the calls of the one-token "
+     "delta-rule update (device name kda_decode_update where the Pallas "
+     "kernel runs)", ()),
+    ("serving.kda.decode_pad_row_layers", COUNTER,
+     "padding row x layer pairs of the decode steps whose state update the "
+     "Pallas kernel ran (kda_decode_update): rows of the bucket that carry "
+     "no request, whose grid steps move nothing; 0 on the XLA arm", ()),
+    ("serving.kda.scan_tokens", COUNTER,
+     "real token x Kimi-Delta layer pairs that prefill windows ran through "
+     "the chunked form", ()),
+    ("serving.kda.scan_layer_steps", COUNTER,
+     "Kimi-Delta layer x window pairs: the calls of the chunked form", ()),
     # -- the host's own pauses (observability/registry._GcWatch) -------------
     ("host.gc.collections", COUNTER,
      "garbage collections by generation", ("generation",)),
@@ -613,6 +633,10 @@ PIECES = frozenset({
                   # its tail
     "ssm_update", # ... a decode token's state update, in place
     "ssm_scan",   # ... a window's chunked scan from its slot
+    "kda_gate",   # kda_moe: a Kimi-Delta layer's decay, step and the
+                  # normalised heads of q and k
+    "kda_update", # ... a decode token's delta-rule update, in place
+    "kda_scan",   # ... a window's chunked (WY) form from its slot
     "mlp",        # parallel_ssm: the layer's SwiGLU
     "head",       # final norm and the vocabulary product
 })

@@ -368,6 +368,22 @@ def paged_attend_runs(q_shape, pool_shape, dtype, rope_dim: int) -> bool:
                 tuple(q_shape), tuple(pool_shape), dtype, int(rope_dim)))
 
 
+def paged_attend_rows(q_shape, pool_shape, dtype, rope_dim: int) -> int:
+    """Rows of a decode step of `q_shape` [B, nh, kv_rank] that ONE call of
+    `pallas_kernels.paged_latent_attend` takes (its queries and running
+    sums stay resident in VMEM: 64 rows of 32 heads): B where
+    `paged_attend_runs` takes the step whole, else the largest half, quarter,
+    .. of B it takes (the step's rows then go a group a call, a run of
+    pages that rows share read once a GROUP), 0 where the kernel cannot run
+    at all."""
+    B = int(q_shape[0])
+    rows = B
+    while rows and not paged_attend_runs((rows,) + tuple(q_shape[1:]),
+                                         pool_shape, dtype, rope_dim):
+        rows = rows // 2 if rows % 2 == 0 else 0
+    return rows
+
+
 def gather_rows_fn(pool, page_table, sel):
     """The cache rows of positions sel [R, K] (-1: none) of rows whose
     pages page_table [R, P] names (already shifted to the layer's rows):
@@ -448,10 +464,7 @@ def _pre_attention(x, p, positions, geom: Geometry):
     c_q = rms_norm_fn(_mm(z, p["wq_a"]), p["q_norm"], geom.eps)
     q = _mm(c_q, p["wq_b"]).reshape(B, S, nh, dn + dr)
     q_rope = rotary_interleaved_fn(q[..., dn:], positions, inv_freq)
-    kv = _mm(z, p["wkv_a"])
-    c_kv = rms_norm_fn(kv[..., :geom.kv_rank], p["kv_norm"], geom.eps)
-    k_rope = rotary_interleaved_fn(kv[:, :, None, geom.kv_rank:], positions,
-                                   inv_freq)[:, :, 0]
+    c_kv, k_rope = cache_row_parts_fn(z, p, positions, inv_freq, geom)
     if not geom.index_topk:         # no indexer: every cached row is read
         return q[..., :dn], q_rope, c_kv, k_rope, None, None, None
     qi = rotary_fn(_mm(c_q, p["wqi"]).reshape(B, S, J, D), positions,
@@ -499,17 +512,125 @@ def _attend_pages(q_nope, q_rope, pool, table, lens, wkv_b, dtype,
     padding row), read where they lie in the pool, a run of pages that rows
     share once for all of them -> [B, nh, v] float32:
     `pallas_kernels.paged_latent_attend` (callers gate on
-    `paged_attend_runs`; `plan`: its `step_plan` of the step's tables, the
-    same for every layer)."""
+    `paged_attend_rows`; `plan`: `decode_plan_fn`'s of the step's tables,
+    the same for every layer: one `step_plan` a group of rows, a call each;
+    None: one call that plans for itself)."""
     from .pallas_kernels import paged_latent_attend
 
+    plans = plan or (None,)
+    rows = q_nope.shape[0] // len(plans)
     with piece("q_absorb"):
         q_lat = absorb_queries_fn(q_nope, wkv_b, geom)
     with piece("attend"):
-        u = paged_latent_attend.paged_latent_attention(
-            q_lat, q_rope, pool, table, lens, dtype, geom, plan)
+        if len(plans) == 1:
+            u = paged_latent_attend.paged_latent_attention(
+                q_lat, q_rope, pool, table, lens, dtype, geom, plans[0])
+        else:
+            u = jnp.concatenate([
+                paged_latent_attend.paged_latent_attention(
+                    q_lat[g:g + rows], q_rope[g:g + rows], pool,
+                    table[g:g + rows], lens[g:g + rows], dtype, geom, part)
+                for g, part in zip(range(0, q_nope.shape[0], rows), plans)])
     with piece("q_absorb"):
         return expand_values_fn(u, wkv_b, geom)
+
+
+def cache_row_parts_fn(z, p, positions, inv_freq, geom: Geometry):
+    """A token's cache row before it is joined: z [B, S, H] float32 (normed)
+    -> its normalised latent c_kv [B, S, kv_rank] and its one rotary key
+    k_rope [B, S, rope] (interleaved lane pairs), float32."""
+    kv = _mm(z, p["wkv_a"])
+    c_kv = rms_norm_fn(kv[..., :geom.kv_rank], p["kv_norm"], geom.eps)
+    k_rope = rotary_interleaved_fn(kv[:, :, None, geom.kv_rank:], positions,
+                                   inv_freq)[:, :, 0]
+    return c_kv, k_rope
+
+
+def direct_queries_fn(z, p, positions, geom: Geometry):
+    """The latent attention's projections WITHOUT a query latent
+    (`q_lora_rank` null: the queries come off the hidden state through ONE
+    matrix): z [B, S, H] float32 (already normed), p holding `wq` [H, nh *
+    (nope + rope)], `wkv_a` [H, kv_rank + rope] and `kv_norm` -> q_nope [B,
+    S, nh, nope], q_rope [B, S, nh, rope], and the cache row's two parts
+    c_kv [B, S, kv_rank] (normalised) and k_rope [B, S, rope], all float32;
+    rotary on interleaved lane pairs."""
+    B, S, _ = z.shape
+    nh, dn, dr = geom.num_heads, geom.nope_dim, geom.rope_dim
+    inv_freq = yarn_inv_freq_fn(dr, geom.rope_theta, tuple(geom.yarn))
+    q = _mm(z, p["wq"]).reshape(B, S, nh, dn + dr)
+    q_rope = rotary_interleaved_fn(q[..., dn:], positions, inv_freq)
+    return (q[..., :dn], q_rope,
+            *cache_row_parts_fn(z, p, positions, inv_freq, geom))
+
+
+def decode_plan_fn(page_table, lens, q_shape, pool_shape, dtype,
+                   rope_dim: int):
+    """`paged_latent_attend.step_plan` of a decode step's tables [B, P] and
+    live lengths [B], one a group of `paged_attend_rows` rows (a tuple;
+    None where the kernel cannot run): which rows read the same pages is
+    the tables' alone, so a stack works it out once for all its latent
+    layers."""
+    rows = paged_attend_rows(q_shape, pool_shape, dtype, rope_dim)
+    if not rows:
+        return None
+    from .pallas_kernels import paged_latent_attend
+
+    with piece("attend"):
+        if rows == q_shape[0]:
+            return (paged_latent_attend.step_plan(page_table, lens,
+                                                  pool_shape),)
+        return tuple(paged_latent_attend.step_plan(
+            page_table[g:g + rows], lens[g:g + rows], pool_shape)
+            for g in range(0, q_shape[0], rows))
+
+
+def unindexed_attention_fn(q_nope, q_rope, c_kv, k_rope, wkv_b,
+                           geom: Geometry, dtype, latent_pool=None,
+                           page_table=None, offset: int = 0, gpos=None,
+                           valid=None, decode_lens=None, plan=None):
+    """One layer's latent attention WITHOUT an indexer (`latent_moe_stack`
+    without one, the latent layers of `kda_moe_stack`): every query attends
+    every cached row at or before its own position gpos [B, S]. Without a
+    pool (the dense oracle) the sequence itself is the context. With
+    `latent_pool` the step's rows are written first (page_table [B, P]
+    unshifted, `offset` the layer's first row of the pool, valid [B, S]);
+    then a window (`decode_lens`
+    None) attends its table's slabs in the expanded form over key blocks,
+    and a decode row (S = 1, `decode_lens` [B] its live length, 0: padding)
+    in the absorbed form, its pages read in place under `plan`
+    (`decode_plan_fn`) or, where that is None, gathered. -> (the pool as
+    written or None, o [B, S, nh, v] float32)."""
+    B = q_nope.shape[0]
+    if latent_pool is None:
+        with piece("attend"):
+            return None, expanded_attention_blocks_fn(
+                q_nope, q_rope, c_kv.astype(dtype), k_rope.astype(dtype),
+                gpos, wkv_b, geom)
+    page_size = latent_pool.shape[1]
+    table = page_table + offset
+    with piece("kv_write"):
+        idx = _page_row_index(page_table, gpos, page_size, offset, valid)
+        latent_pool = _write_rows(
+            latent_pool, join_latent_fn(c_kv, k_rope, dtype,
+                                        latent_pool.shape[-1]), idx,
+            gpos % page_size)
+    if plan is not None:
+        return latent_pool, _attend_pages(
+            q_nope[:, 0], q_rope[:, 0], latent_pool, table, decode_lens,
+            wkv_b, dtype, geom, plan)[:, None]
+    context = page_table.shape[1] * page_size
+    with piece("latent_gather"):
+        slabs = latent_pool[jnp.clip(
+            table, 0, latent_pool.shape[0] - 1)].reshape(B, context, -1)
+    if decode_lens is not None:
+        live = jnp.arange(context, dtype=jnp.int32)[None, :] <= gpos[:, :1]
+        return latent_pool, _attend_rows(
+            q_nope[:, 0], q_rope[:, 0], slabs, live, wkv_b, dtype,
+            geom)[:, None]
+    with piece("attend"):
+        c, r = split_latent_fn(slabs, dtype, geom.kv_rank, geom.rope_dim)
+        return latent_pool, expanded_attention_blocks_fn(
+            q_nope, q_rope, c, r, gpos, wkv_b, geom)
 
 
 def _feed_forward(h, kind_dense: bool, p, experts, index, geom: Geometry,
@@ -606,14 +727,12 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
     # a decode row without an indexer reads every page of its table where it
     # lies; which rows read the same pages is the tables' alone, worked out
     # once for all layers
-    in_place = decode and not indexed and paged_attend_runs(
-        (B, nh, geom.kv_rank), pools[0].shape, dtype, geom.rope_dim)
-    if in_place:
-        from .pallas_kernels import paged_latent_attend
-
-        with piece("attend"):
-            plan = paged_latent_attend.step_plan(
-                page_table, (first + 1) * count, pools[0].shape)
+    # (a group of rows a call where they are more than one call keeps
+    # resident: `paged_attend_rows`)
+    plan = decode_plan_fn(
+        page_table, (first + 1) * count, (B, nh, geom.kv_rank),
+        pools[0].shape, dtype, geom.rope_dim) \
+        if decode and not indexed else None
     if streams > 1:
         with piece("embed"):
             x = hc.spread_fn(x, streams)                        # [n, B, S, H]
@@ -624,83 +743,78 @@ def latent_moe_stack_fn(mode: str, tok, pos, emb, head, final_norm,
         with piece("proj"):
             q_nope, q_rope, c_kv, k_rope, qi, ki, w = _pre_attention(
                 x, p, pos, geom)
-        if paged:
-            off = l * num_pages
-            table = page_table + off
-            with piece("kv_write"):
-                idx = _page_row_index(page_table, gpos, page_size, off,
-                                      valid)
-                latent_pool = _write_rows(
-                    latent_pool, join_latent_fn(
-                        c_kv, k_rope, dtype, latent_pool.shape[-1]), idx,
-                    gpos % page_size)
-                if indexed:
+        off = l * num_pages
+        sel = None
+        if not indexed:     # every cached row is read (written first)
+            latent_pool, o = unindexed_attention_fn(
+                q_nope, q_rope, c_kv, k_rope, p["wkv_b"], geom, dtype,
+                latent_pool, page_table if paged else None, off, gpos,
+                valid if paged else None,
+                (first + 1) * count if decode else None, plan)
+        else:
+            if paged:
+                table = page_table + off
+                with piece("kv_write"):
+                    idx = _page_row_index(page_table, gpos, page_size, off,
+                                          valid)
+                    latent_pool = _write_rows(
+                        latent_pool, join_latent_fn(
+                            c_kv, k_rope, dtype, latent_pool.shape[-1]), idx,
+                        gpos % page_size)
                     i_pool = write_index_keys_fn(i_pool, ki, page_table,
                                                  off, first, count)
-        sel = None
-        if in_place:
-            o = _attend_pages(q_nope[:, 0], q_rope[:, 0], latent_pool, table,
-                              (first + 1) * count, p["wkv_b"], dtype, geom,
-                              plan)[:, None]
-        elif paged and whole:
-            at = jnp.arange(context, dtype=jnp.int32)
-            live = at[None, None, :] <= gpos[:, :, None]        # [B, S, T]
-            with piece("latent_gather"):
-                slabs = latent_pool[jnp.clip(
-                    table, 0, latent_pool.shape[0] - 1)].reshape(
-                        B, context, -1)
-            if decode:
-                o = _attend_rows(q_nope[:, 0], q_rope[:, 0], slabs,
-                                 live[:, 0], p["wkv_b"], dtype,
-                                 geom)[:, None]
-                if indexed:
+            if paged and whole:
+                at = jnp.arange(context, dtype=jnp.int32)
+                live = at[None, None, :] <= gpos[:, :, None]    # [B, S, T]
+                with piece("latent_gather"):
+                    slabs = latent_pool[jnp.clip(
+                        table, 0, latent_pool.shape[0] - 1)].reshape(
+                            B, context, -1)
+                if decode:
+                    o = _attend_rows(q_nope[:, 0], q_rope[:, 0], slabs,
+                                     live[:, 0], p["wkv_b"], dtype,
+                                     geom)[:, None]
                     sel = jnp.where(live, at, -1)               # [B, 1, T]
-            else:
-                with piece("attend"):
-                    c, r = split_latent_fn(slabs, dtype, geom.kv_rank,
-                                           geom.rope_dim)
-                    o = expanded_attention_fn(
-                        q_nope, q_rope, c, r, live, p["wkv_b"], geom) \
-                        if indexed else expanded_attention_blocks_fn(
-                            q_nope, q_rope, c, r, gpos, p["wkv_b"], geom)
-                if indexed:
-                    with piece("select"):
-                        sel = pack_selection_fn(live, page_size)
-        elif not indexed:       # the dense oracle: the sequence itself
-            with piece("attend"):
-                o = expanded_attention_blocks_fn(
-                    q_nope, q_rope, c_kv.astype(dtype), k_rope.astype(dtype),
-                    gpos, p["wkv_b"], geom)
-        else:
-            with piece("indexer"):
-                if decode:      # each row's pages, where they lie
-                    scores = decode_scores_fn(qi, w, i_pool, table,
-                                              (first + 1) * count)
-                elif paged:
-                    scores = paged_scores_fn(qi, w, i_pool, table)
-                else:           # the sequence as one page
-                    scores = indexer_scores_fn(
-                        qi, w, jnp.swapaxes(ki.astype(dtype), 1, 2)[:, None])
-            if decode:
-                with piece("select"):
-                    sel = select_indices_fn(scores, gpos + 1, topk)
-                o = _attend_selected(q_nope[:, 0], q_rope[:, 0],
-                                     latent_pool, table, sel[:, 0],
-                                     p["wkv_b"], dtype, geom)[:, None]
-            else:
-                with piece("select"):
-                    keep = select_mask_fn(scores, gpos + 1, topk)
-                if paged:
-                    o = _window_rows(q_nope, q_rope, latent_pool, table,
-                                     keep, p["wkv_b"], dtype, geom)
                 else:
                     with piece("attend"):
+                        c, r = split_latent_fn(slabs, dtype, geom.kv_rank,
+                                               geom.rope_dim)
                         o = expanded_attention_fn(
-                            q_nope, q_rope, c_kv.astype(dtype),
-                            k_rope.astype(dtype), keep, p["wkv_b"], geom)
-                # handed back as attended under: the mask itself
-                with piece("select"):
-                    sel = pack_selection_fn(keep, page_size if paged else S)
+                            q_nope, q_rope, c, r, live, p["wkv_b"], geom)
+                    with piece("select"):
+                        sel = pack_selection_fn(live, page_size)
+            else:
+                with piece("indexer"):
+                    if decode:      # each row's pages, where they lie
+                        scores = decode_scores_fn(qi, w, i_pool, table,
+                                                  (first + 1) * count)
+                    elif paged:
+                        scores = paged_scores_fn(qi, w, i_pool, table)
+                    else:           # the sequence as one page
+                        scores = indexer_scores_fn(
+                            qi, w,
+                            jnp.swapaxes(ki.astype(dtype), 1, 2)[:, None])
+                if decode:
+                    with piece("select"):
+                        sel = select_indices_fn(scores, gpos + 1, topk)
+                    o = _attend_selected(q_nope[:, 0], q_rope[:, 0],
+                                         latent_pool, table, sel[:, 0],
+                                         p["wkv_b"], dtype, geom)[:, None]
+                else:
+                    with piece("select"):
+                        keep = select_mask_fn(scores, gpos + 1, topk)
+                    if paged:
+                        o = _window_rows(q_nope, q_rope, latent_pool, table,
+                                         keep, p["wkv_b"], dtype, geom)
+                    else:
+                        with piece("attend"):
+                            o = expanded_attention_fn(
+                                q_nope, q_rope, c_kv.astype(dtype),
+                                k_rope.astype(dtype), keep, p["wkv_b"], geom)
+                    # handed back as attended under: the mask itself
+                    with piece("select"):
+                        sel = pack_selection_fn(keep,
+                                                page_size if paged else S)
         if streams > 1:
             with piece("proj"):
                 f = _mm(o.reshape(B, S, -1), p["wo"])

@@ -36,7 +36,10 @@ token's state update in place in its slot) and `conv_update` (the same
 token's causal convolution, its tail moved on in place in its slot of the
 pool of tails `[rows, tail_width / 128, 128]`, where XLA's scatter passed
 over the whole pool; `parallel_ssm_ops.conv_token_update_fn`, its shape
-gate the only switch).
+gate the only switch) and `kda_update` (a decode token of a Kimi-Delta
+linear-attention layer: decay by key channel and the delta rule's rank-one
+write, in place in the same slot pool; `kda_ops.kda_token_update_fn`, its
+shape gate the only switch).
 """
 from . import workbench
 from .attention import short_seq_attention, short_seq_supported

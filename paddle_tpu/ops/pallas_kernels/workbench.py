@@ -145,7 +145,7 @@ def all_kernels() -> dict[str, KernelSpec]:
     """Every registered kernel (import side effect: pulls in the kernel
     modules so their registrations run)."""
     from . import (attention, conv_update, epilogue,  # noqa: F401
-                   latent_attend, moe_experts, paged_attention,
+                   kda_update, latent_attend, moe_experts, paged_attention,
                    paged_indexer, paged_latent_attend, short_attention,
                    ssm_update)
 

@@ -122,9 +122,11 @@ still held, and a prompt resumes only a prefix whose window tail is held
 (`PrefixCache.match_resumable`); under pressure in the window pool alone
 the cache gives up window pages before a row is made to wait.
 
-A recurrent state (the "parallel_ssm" and "mixer_moe" blocks,
+A recurrent state (the "parallel_ssm", "mixer_moe" and "kda_moe" blocks,
 `cfg.recurrent`; the pools hold `cfg.state_layers` layers, every layer of
-the first and the mixers of the second): beside its
+the first, the mixers of the second and the Kimi-Delta layers of the third,
+whose pages hold latent rows of its latent layers, `cfg.latent_layers`):
+beside its
 K/V pages a row owns ONE slot of the pools of state (`state_pool`, the same
 `PagedKVPool` a third time, ids of its own, `req.sslot`), which every step
 of the row rewrites in place; admission, preemption, the leak count and the
@@ -199,7 +201,7 @@ from ..data_feeder import _round_up_pow2
 from ..executor import Executor, Scope
 from ..framework import Program, program_guard
 from ..observability.slo import hist_p99_above
-from ..ops import (attention_ops, latent_moe_ops, parallel_ssm_ops,
+from ..ops import (attention_ops, kda_ops, latent_moe_ops, parallel_ssm_ops,
                    sparse_moe_ops)
 from ..resilience.faults import InjectedFault, fault_point
 from ..resilience.retry import serving_policy
@@ -620,6 +622,8 @@ class ServingEngine:
         # "token" a page; slot ids are its page ids
         self.state_pool = None
         self._scratch_slot = 0
+        # which counters book the state's updates and scans
+        self._state_kind = "kda" if self.cfg.block == "kda_moe" else "ssm"
         if self.cfg.recurrent:
             if self.cfg.prefill_chunk % self.page_size:
                 raise ValueError(
@@ -813,6 +817,10 @@ class ServingEngine:
             "ssm.conv_kernel_layer_steps": 0,
             "ssm.decode_pad_row_layers": 0,
             "ssm.scan_tokens": 0, "ssm.scan_layer_steps": 0,
+            # the same of a Kimi-Delta layer's matrix state (ISSUE 51)
+            "kda.decode_row_layers": 0, "kda.decode_layer_steps": 0,
+            "kda.decode_pad_row_layers": 0,
+            "kda.scan_tokens": 0, "kda.scan_layer_steps": 0,
             "peak_state_slots_in_use": 0,
         }
 
@@ -1935,13 +1943,19 @@ class ServingEngine:
 
     def _admit(self) -> int:
         """Admit waiting requests in policy order until pages or inflight
-        slots run out. Head-of-line backpressure: the first request that
+        slots run out, or `cfg.admit_per_step` of them are in (a deployment
+        whose prompts cost more than its rows' steps caps an iteration's
+        prefills, so that the rows get their step). Head-of-line backpressure: the first request that
         does not fit stops admission (no starvation of big requests by
         later small ones under fcfs). Prefix-cache hits cut the PRIVATE
         page bill: cached full pages of the prompt map with a refcount
         bump instead of an allocation."""
         admitted = 0
+        cap = self.cfg.admit_per_step
         for req in self.scheduler.order(self._waiting):
+            if cap and admitted >= cap:
+                # the rows' step first; the queue keeps the rest
+                break
             # a row whose last token is in flight takes no row of the next
             # step: its place is free now, as it would be had the host
             # waited for the token
@@ -2313,9 +2327,9 @@ class ServingEngine:
                 self._count("prefill.chunks")
                 self._count_mixes(m)
                 if self.state_pool is not None:
-                    self._count("ssm.scan_tokens",
+                    self._count(f"{self._state_kind}.scan_tokens",
                                 m * self.cfg.state_layers)
-                    self._count("ssm.scan_layer_steps",
+                    self._count(f"{self._state_kind}.scan_layer_steps",
                                 self.cfg.state_layers)
                     if (c0 + m) % chunk == 0 and c0 + m <= req.prompt_len \
                             and self.prefix_cache is not None:
@@ -2593,17 +2607,21 @@ class ServingEngine:
         runs = self._ssm_kernel_runs.get(bb)
         if runs is None:
             cfg = self.cfg
+            pool = self._scope.find_var(STATE_POOLS[0]).shape
             runs = self._ssm_kernel_runs[bb] = \
-                parallel_ssm_ops.ssm_update_runs(
-                    bb, self._scope.find_var(STATE_POOLS[0]).shape,
-                    cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
-                    cfg.ssm_state)
+                kda_ops.kda_update_runs(pool, cfg.ssm_state) \
+                if cfg.block == "kda_moe" \
+                else parallel_ssm_ops.ssm_update_runs(
+                    bb, pool, cfg.ssm_heads, cfg.ssm_head_dim,
+                    cfg.ssm_groups, cfg.ssm_state)
         return runs
 
-    def _attend_kernel(self, bb: int, pb: int) -> bool:
+    def _attend_kernel(self, bb: int, pb: int) -> int:
         """Whether the Pallas kernel computes the absorbed attention of a
         decode step of `bb` rows behind `pb` pages (`absorbed_attention_fn`
-        otherwise): the ops' own answer, asked once a signature."""
+        otherwise): the ops' own answer, asked once a signature. Without an
+        indexer the answer is the rows ONE call takes (`bb`, or a share of
+        it where the step's rows go a group a call; 0: the XLA arm)."""
         runs = self._attend_kernel_runs.get((bb, pb))
         if runs is None:
             cfg = self.cfg
@@ -2617,7 +2635,7 @@ class ServingEngine:
                     q_shape, (bb, min(cfg.index_topk, slots),
                               pool.shape[-1]),
                     cfg.dtype, cfg.rope_head_dim) if cfg.selects \
-                else latent_moe_ops.paged_attend_runs(
+                else latent_moe_ops.paged_attend_rows(
                     q_shape, pool.shape, cfg.dtype, cfg.rope_head_dim)
         return runs
 
@@ -2702,12 +2720,15 @@ class ServingEngine:
             self._count("attn.full_layer_steps", full)
             self._count("attn.window_layer_steps", slide)
         if self.state_pool is not None:
-            self._count("ssm.decode_row_layers",
+            # the state update's rows and calls under its own kind's name:
+            # a Mamba-2 mixer's or a Kimi-Delta layer's
+            kind = self._state_kind
+            self._count(f"{kind}.decode_row_layers",
                         len(rows) * self.cfg.state_layers)
-            self._count("ssm.decode_layer_steps", self.cfg.state_layers)
+            self._count(f"{kind}.decode_layer_steps", self.cfg.state_layers)
             self._count("ssm.conv_kernel_layer_steps",
                         self.cfg.state_layers if self._conv_kernel() else 0)
-            self._count("ssm.decode_pad_row_layers",
+            self._count(f"{kind}.decode_pad_row_layers",
                         (bb - len(rows)) * self.cfg.state_layers
                         if self._ssm_kernel(bb) else 0)
         if self.cfg.selects_within(pb * ps):
@@ -2724,7 +2745,7 @@ class ServingEngine:
             # (their selection's, or every slot of a table that fits it or
             # has no indexer over it) and how many of them were live
             # positions
-            L, k = self.cfg.num_layers, self.cfg.index_topk
+            L, k = self.cfg.latent_layers, self.cfg.index_topk
             select = self.cfg.selects_within(pb * ps)
             if not self.cfg.selects:
                 # the family's layer steps, as where every step selects
@@ -2742,12 +2763,15 @@ class ServingEngine:
                 from ..ops.pallas_kernels import paged_latent_attend
 
                 # pool pages the rows' attention fetched: the kernel reads
-                # a run of pages that rows share once for all of them (its
-                # own rule over these feeds), the XLA arm each row's
+                # a run of pages that the rows of ONE call share once for
+                # all of them (its own rule over these feeds), the XLA arm
+                # each row's
+                lens = (pos + 1) * mask[:, 0].astype(np.int32)
+                shape = self._scope.find_var(LATENT_POOL).shape
                 self._count("latent.pages_read", L * (
-                    paged_latent_attend.pages_read(
-                        pages, (pos + 1) * mask[:, 0].astype(np.int32),
-                        self._scope.find_var(LATENT_POOL).shape)
+                    sum(paged_latent_attend.pages_read(
+                        pages[g:g + kernel], lens[g:g + kernel], shape)
+                        for g in range(0, bb, kernel))
                     if kernel else sum(pos // ps + 1 for pos, _ in at)))
         handles = self._run_step("decode", self._decode_run, self._decode_io,
                                  feed, greedy, selection=bool(marked))

@@ -1,4 +1,4 @@
-"""Serving program builders. SEVEN block families exist (the sixth in two
+"""Serving program builders. EIGHT block families exist (the sixth in two
 forms: behind an indexer, or read whole under several residual streams), and
 `DecoderConfig.block` selects one:
 
@@ -114,6 +114,27 @@ forms: behind an indexer, or read whole under several residual streams), and
     (`kv_cache.STATE_POOLS`, `build_state_copy_program`) over the `M`
     layers, a request's routes over the `E` layers. Prompts run in
     `prefill_chunk`-token windows as "sparse_moe"'s do.
+  * `"kda_moe"` (`ops/kda_ops.py`; a hybrid of linear and latent attention,
+    `bailing_hybrid`'s layers): TWO sub-layers a layer, each behind a
+    pre-norm, and TWO kinds of cache for one sequence. The mixer of layer
+    `l` is a multi-head latent attention where `(l + 1) % layer_group_size
+    == 0` ("latent_moe"'s WITHOUT a query latent, `q_lora_rank` 0, and
+    without an indexer, a sigmoid gate a head on its output; its cache row
+    in `kv_cache.LATENT_POOL`, stacked over these layers only) and Kimi
+    Delta Attention elsewhere: `ssm_heads` heads whose state is a matrix
+    `[ssm_state, ssm_head_dim]` (keys x values) that every token decays a
+    KEY CHANNEL at a time (log decay in `(kda_lower_bound, 0)`) and writes
+    by the delta rule, behind one causal convolution `ssm_conv` wide over q
+    | k | v; a window runs it in chunks of `ssm_chunk` tokens and
+    sub-blocks of `kda_sub_chunk`, a decode step one token in place in the
+    slot (`kv_cache.STATE_POOLS` over these layers only,
+    `build_state_copy_program`). The MLP is a dense SwiGLU
+    (`dense_ffn_size`) in the first `dense_layers` layers and "latent_moe"'s
+    group-limited experts with `experts_held` behind them. `recurrent` AND
+    `latent` are both true: a row holds a slot and pages, and a prefix hit
+    needs the pages and a snapshot at the same boundary. Weights are
+    stacked by layer KIND and the layers run one after another. Prompts run
+    in `prefill_chunk`-token windows as "sparse_moe"'s do.
 
 Every family is expressed several times over ONE weight namespace:
 
@@ -149,7 +170,7 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 from ..initializer import (BlockedNormal, Constant, Normal, StackedNormal,
                            Uniform)
-from ..ops import (cca_moe_ops, hybrid_moe_ops, latent_moe_ops,
+from ..ops import (cca_moe_ops, hybrid_moe_ops, kda_ops, latent_moe_ops,
                    mixer_moe_ops, parallel_ssm_ops, sparse_moe_ops)
 from .kv_cache import (INDEX_POOL, JOINED_POOL, LATENT_POOL, STACKED_POOLS,
                        STATE_POOLS, WINDOW_POOLS, declare_pool_vars,
@@ -159,7 +180,7 @@ from .kv_cache import (INDEX_POOL, JOINED_POOL, LATENT_POOL, STACKED_POOLS,
 __all__ = ["DecoderConfig", "decoder_tiny", "cca_moe_tiny",
            "sparse_moe_tiny", "hybrid_moe_tiny", "parallel_ssm_tiny",
            "latent_moe_tiny", "latent_streams_tiny", "mixer_moe_tiny",
-           "layer_plan",
+           "kda_moe_tiny", "layer_plan",
            "build_prefill_program",
            "build_decode_program", "build_window_program",
            "build_state_copy_program",
@@ -289,10 +310,27 @@ class DecoderConfig:
     # `E` experts, and the width of the experts' latent
     layer_pattern: str = ""
     latent_size: int = 0
+    # "kda_moe" only (it also reads `ssm_heads`, `ssm_head_dim` (a head's
+    # values), `ssm_state` (its key channels), `ssm_conv`, `ssm_chunk`, the
+    # latent attention's `kv_lora_rank`, `rope_head_dim`, `v_head_dim` and
+    # `attn_head_dim`, and "latent_moe"'s feed-forward fields): every
+    # `layer_group_size`-th layer is latent attention, the others Kimi Delta
+    # Attention; the chunked form's sub-block and the least log decay a
+    # token
+    layer_group_size: int = 0
+    kda_sub_chunk: int = 16
+    kda_lower_bound: float = -5.0
     # a deployment's choice, any family: the fewest rows a decode step is
     # compiled for (a power of two). Steps of fewer live rows pay for that
     # many; every row bucket below it is a program less to compile
     min_row_bucket: int = 1
+    # a deployment's choice, any family: the most waiting requests ONE loop
+    # iteration admits (and prefills) ahead of its decode step; 0: all that
+    # fit. Where prompts cost more of the device than the rows' steps, an
+    # iteration that admits all its waiters leaves the rows one step in
+    # hundreds of milliseconds, and every pause of the host is made up out
+    # of their steps alone; under a cap the queue carries it
+    admit_per_step: int = 0
 
     def __post_init__(self):
         if self.block not in _FAMILY:
@@ -340,6 +378,42 @@ class DecoderConfig:
                     "block 'mixer_moe' needs num_experts >= "
                     "experts_per_token >= 1 and 1 <= experts_held <= "
                     "num_experts")
+        if self.block == "kda_moe":
+            held = self.experts_held or self.num_experts
+            if min(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                   self.kda_sub_chunk, self.prefill_chunk, self.kv_lora_rank,
+                   self.rope_head_dim, self.v_head_dim,
+                   self.shared_expert_size, self.dense_ffn_size) < 1 \
+                    or self.ssm_conv < 2 or self.q_lora_rank \
+                    or self.ssm_chunk % self.kda_sub_chunk \
+                    or self.kda_sub_chunk % 2 or self.kda_lower_bound >= 0 \
+                    or self.kda_lower_bound * self.kda_sub_chunk < -160 \
+                    or self.rope_head_dim % 2 or self.kv_lora_rank % 2:
+                raise ValueError(
+                    "block 'kda_moe' needs ssm_heads, ssm_head_dim, "
+                    "ssm_state, ssm_conv >= 2, ssm_chunk in whole "
+                    "kda_sub_chunk (even; kda_lower_bound < 0 times half of "
+                    "it is an exponent float32 must hold), prefill_chunk, "
+                    "kv_lora_rank and rope_head_dim (even), v_head_dim, "
+                    "shared_expert_size, dense_ffn_size and NO q_lora_rank")
+            if not 2 <= self.layer_group_size <= self.num_layers \
+                    or not 0 <= self.dense_layers < self.num_layers:
+                raise ValueError(
+                    "block 'kda_moe' needs 2 <= layer_group_size <= "
+                    "num_layers (a latent layer among every few, at least "
+                    "one of each kind) and 0 <= dense_layers < num_layers")
+            if not 1 <= self.experts_per_token <= self.num_experts \
+                    or self.num_experts % self.expert_groups \
+                    or not 1 <= self.groups_per_token <= self.expert_groups \
+                    or self.num_experts // self.expert_groups < 2 \
+                    or self.experts_per_token > self.groups_per_token \
+                    * (self.num_experts // self.expert_groups) \
+                    or not 1 <= held <= self.num_experts:
+                raise ValueError(
+                    "block 'kda_moe' needs num_experts in expert_groups "
+                    "equal groups of at least two, groups_per_token of "
+                    "them holding experts_per_token, and 1 <= experts_held "
+                    "<= num_experts")
         if self.block == "hybrid_moe":
             layer_plan(self)       # raises on lists that name no plan
             if min(self.sliding_window, self.prefill_chunk,
@@ -417,6 +491,8 @@ class DecoderConfig:
         if self.min_row_bucket < 1 \
                 or self.min_row_bucket & (self.min_row_bucket - 1):
             raise ValueError("min_row_bucket must be a power of two")
+        if self.admit_per_step < 0:
+            raise ValueError("admit_per_step must be 0 (no cap) or more")
         if self.block == "cca_moe":
             if (self.cca_time0, self.cca_time1) != (2, 2):
                 raise ValueError(
@@ -450,23 +526,41 @@ class DecoderConfig:
         stacked pools (`kv_cache.STACKED_POOLS`). Speculation, tensor
         parallelism and the fleet handoff are not written for that form."""
         return self.block in ("cca_moe", "sparse_moe", "hybrid_moe",
-                              "parallel_ssm", "latent_moe", "mixer_moe")
+                              "parallel_ssm", "latent_moe", "mixer_moe",
+                              "kda_moe")
 
     @property
     def recurrent(self) -> bool:
         """Whether a sequence carries a state that every token rewrites in
         place (a slot of `kv_cache.STATE_POOLS`, not a row a page): the
         prefix cache resumes it from snapshots only."""
-        return self.block in ("parallel_ssm", "mixer_moe")
+        return self.block in ("parallel_ssm", "mixer_moe", "kda_moe")
 
     @property
     def state_layers(self) -> int:
         """Layers that hold a recurrent state (the layers of
         `kv_cache.STATE_POOLS`): every layer of "parallel_ssm", the mixers
-        of "mixer_moe"."""
+        of "mixer_moe", the Kimi-Delta layers of "kda_moe"."""
         if self.block == "mixer_moe":
             return self.layer_pattern.count(mixer_moe_ops.MIXER)
+        if self.block == "kda_moe":
+            return self.num_layers - self.latent_layers
         return self.num_layers if self.recurrent else 0
+
+    @property
+    def mixer_kinds(self) -> str:
+        """"kda_moe": a character a layer, `L` latent attention where `(l +
+        1) % layer_group_size == 0`, `K` Kimi Delta Attention elsewhere."""
+        return "".join(
+            kda_ops.LATENT if (l + 1) % self.layer_group_size == 0
+            else kda_ops.KDA for l in range(self.num_layers))
+
+    @property
+    def mlp_kinds(self) -> str:
+        """"kda_moe": a character a layer, `D` a dense SwiGLU in the first
+        `dense_layers` layers, `E` the experts behind them."""
+        return kda_ops.DENSE * self.dense_layers \
+            + kda_ops.EXPERTS * (self.num_layers - self.dense_layers)
 
     @property
     def windowed(self) -> bool:
@@ -480,7 +574,7 @@ class DecoderConfig:
         request's `routes`)."""
         if self.block == "hybrid_moe":
             return sum(kind == "sparse" for kind in self.mlp_layer_types)
-        if self.block == "latent_moe":
+        if self.block in ("latent_moe", "kda_moe"):
             return self.num_layers - self.dense_layers
         if self.block == "mixer_moe":
             return self.layer_pattern.count(mixer_moe_ops.EXPERTS)
@@ -507,7 +601,16 @@ class DecoderConfig:
     def latent(self) -> bool:
         """Whether a token's cache row is ONE compressed row (a latent and
         its rotary key in `kv_cache.LATENT_POOL`) that every head reads."""
-        return self.block == "latent_moe"
+        return self.block in ("latent_moe", "kda_moe")
+
+    @property
+    def latent_layers(self) -> int:
+        """Layers whose cache is such a row (the layers of
+        `kv_cache.LATENT_POOL`): every layer of "latent_moe", every
+        `layer_group_size`-th of "kda_moe"."""
+        if self.block == "kda_moe":
+            return self.num_layers // self.layer_group_size
+        return self.num_layers if self.latent else 0
 
     def selects_within(self, slots: int) -> bool:
         """Whether a decode step over a page table of `slots` slots runs
@@ -658,6 +761,24 @@ def mixer_moe_tiny(**over) -> DecoderConfig:
 
 
 # -- the "cca_moe" family ----------------------------------------------------
+
+
+def kda_moe_tiny(**over) -> DecoderConfig:
+    """The "kda_moe" block at test size: six layers, five Kimi-Delta (4
+    heads, a state of 8 key channels x 8 values, chunks of 8 in sub-blocks
+    of 4) and one latent attention (4 heads of 8 + 4 rotary over a latent of
+    16), two dense layers then 2 of 8 experts in 2 of 4 groups, 4 held."""
+    kw = dict(vocab_size=97, hidden_size=32, num_layers=6, num_heads=4,
+              attn_head_dim=8, rope_head_dim=4, v_head_dim=8,
+              kv_lora_rank=16, rope_theta=6e6, rms_norm_eps=1e-6,
+              layer_group_size=6, ssm_heads=4, ssm_head_dim=8, ssm_state=8,
+              ssm_conv=4, ssm_chunk=8, kda_sub_chunk=4, dense_layers=2,
+              dense_ffn_size=48, num_experts=8, experts_held=4,
+              experts_per_token=2, expert_groups=4, groups_per_token=2,
+              routed_scaling=2.5, ffn_size=16, shared_expert_size=16,
+              prefill_chunk=8, max_position=128, block="kda_moe")
+    kw.update(over)
+    return DecoderConfig(**kw)
 
 
 def _cca_geometry(cfg: DecoderConfig) -> dict:
@@ -1414,6 +1535,15 @@ def ssm_pool_geometry(cfg: DecoderConfig, num_pages: int, page_size: int,
     answers by the count of a kind: K/V over its attention layers, the
     state over its mixers, narrow heads packed on the lanes."""
     width = cfg.kv_heads * cfg.head_dim
+    if cfg.block == "kda_moe":
+        # ONE pool of latent rows over the latent layers; a Kimi-Delta
+        # head's state [keys, values], the tail over q | k | v
+        return ((cfg.latent_layers, num_pages, page_size,
+                 cfg.kv_lora_rank + cfg.rope_head_dim, 0, cfg.dtype, 0,
+                 False, STACKED_POOLS, True),
+                (cfg.state_layers, num_slots, cfg.ssm_heads, cfg.ssm_state,
+                 cfg.ssm_head_dim, (cfg.ssm_conv - 1) * cfg.ssm_heads
+                 * (2 * cfg.ssm_state + cfg.ssm_head_dim)))
     if cfg.block == "mixer_moe":
         tail = (cfg.ssm_conv - 1) * (
             cfg.ssm_heads * cfg.ssm_head_dim
@@ -1747,6 +1877,179 @@ def _mixer_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask,
 def _mixer_full(cfg, tok, pos):
     out = _mixer_stack(cfg, "full", tok, pos)
     return {"logits": out["logits"], "routes": out["routes"]}
+
+
+# -- the "kda_moe" family ----------------------------------------------------
+
+
+def _kda_geometry(cfg: DecoderConfig) -> dict:
+    return {"mixers": cfg.mixer_kinds, "mlps": cfg.mlp_kinds,
+            "num_heads": cfg.num_heads, "nope_dim": cfg.head_dim,
+            "rope_dim": cfg.rope_head_dim, "v_dim": cfg.v_head_dim,
+            "kv_rank": cfg.kv_lora_rank,
+            "rope_theta": float(cfg.rope_theta),
+            "eps": float(cfg.rms_norm_eps), "kda_heads": cfg.ssm_heads,
+            "kda_head_dim": cfg.ssm_head_dim, "kda_conv": cfg.ssm_conv,
+            "kda_chunk": cfg.ssm_chunk, "kda_sub_chunk": cfg.kda_sub_chunk,
+            "kda_lower_bound": float(cfg.kda_lower_bound),
+            "experts_per_token": cfg.experts_per_token,
+            "expert_groups": cfg.expert_groups,
+            "groups_per_token": cfg.groups_per_token,
+            "routed_scaling": float(cfg.routed_scaling),
+            "experts_held": cfg.held_experts}
+
+
+def _kda_param_specs(cfg: DecoderConfig) -> dict:
+    """name -> (shape, dtype, initializer), stacked by layer kind: `norm`
+    the mixers' pre-norms over all layers, `kda.*` over the Kimi-Delta
+    layers, `mla.*` over the latent layers, `dense.*` and `moe.*` over the
+    layers of either MLP (each with its pre-norm), the held experts
+    `[L_moe, held, ...]`. Every matrix is drawn at `target x fan_in^-0.5`,
+    the target the standard deviation of its product: the embedding 1 (the
+    residual stream enters at RMS 1); q, k and v 1 before a convolution
+    N(0, 0.5) of four taps (q and k are normalised a head, so only their
+    direction matters); the decay's matrix 2 around `dt_bias` uniform over
+    [-3, 1] with `exp(A_log)` uniform in the exponent over 0.5-2: `log a`
+    from -4 to -0.1 a channel, so some channels forget inside a sub-block
+    and some remember a window; `beta`'s matrix 2 (steps of 0.1-0.9); the
+    output gate 1, the norm's gain near 1, the way back 2 (a gated normed
+    head is about half a unit). The latent layer as "latent_moe"'s with the
+    queries straight off the hidden state at 2 (peaked attention), its head
+    gate's matrix 2, its way back 1; the feed-forwards, the router (2, in
+    float32, a selection bias of 0.02) and the head (2.5) as "latent_moe"'s.
+    The large ones are in `cfg.dtype`; norms, the router and its bias, the
+    convolution and the per-channel scalars in float32."""
+    H, V, F, E = cfg.hidden_size, cfg.vocab_size, cfg.ffn_size, \
+        cfg.num_experts
+    nh, dn, dr, dv = cfg.num_heads, cfg.head_dim, cfg.rope_head_dim, \
+        cfg.v_head_dim
+    rkv = cfg.kv_lora_rank
+    Hk, K, P = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim
+    Fd, Fs, held = cfg.dense_ffn_size, cfg.shared_expert_size, \
+        cfg.held_experts
+    Lk, Ll = cfg.state_layers, cfg.latent_layers
+    Ld, Le = cfg.dense_layers, cfg.num_layers - cfg.dense_layers
+    f32, big = "float32", cfg.dtype
+    near_one = Normal(1.0, 0.05)
+    root = H ** -0.5
+
+    def fan(n, scale=1.0):
+        return StackedNormal(0.0, scale * n ** -0.5)
+
+    return {
+        "dec.word_emb": ([V, H], big, BlockedNormal(
+            1.0, block_rows=_draw_rows(V))),
+        "dec.lm_head": ([H, V], big, BlockedNormal(
+            2.5 * root, block_rows=_draw_rows(H))),
+        "dec.final_norm.scale": ([H], f32, near_one),
+        "norm": ([cfg.num_layers, H], f32, near_one),
+        "kda.w_qkv": ([Lk, H, Hk * (2 * K + P)], big, fan(H)),
+        "kda.conv_w": ([Lk, Hk * (2 * K + P), cfg.ssm_conv], f32,
+                       Normal(0.0, 0.5)),
+        "kda.w_f": ([Lk, H, Hk * K], big, fan(H, 2.0)),
+        "kda.dt_bias": ([Lk, Hk * K], f32, Uniform(-3.0, 1.0)),
+        "kda.a_log": ([Lk, Hk], f32, Uniform(-0.7, 0.7)),
+        "kda.w_b": ([Lk, H, Hk], big, fan(H, 2.0)),
+        "kda.w_g": ([Lk, H, Hk * P], big, fan(H)),
+        "kda.o_norm": ([Lk, P], f32, near_one),
+        "kda.w_o": ([Lk, Hk * P, H], big, fan(Hk * P, 2.0)),
+        "mla.wq": ([Ll, H, nh * (dn + dr)], big, fan(H, 2.0)),
+        "mla.wkv_a": ([Ll, H, rkv + dr], big, fan(H)),
+        "mla.kv_norm": ([Ll, rkv], f32, near_one),
+        "mla.wkv_b": ([Ll, rkv, nh * (dn + dv)], big, fan(rkv)),
+        "mla.w_gate_h": ([Ll, H, nh], big, fan(H, 2.0)),
+        "mla.wo": ([Ll, nh * dv, H], big, fan(nh * dv)),
+        "dense.ffn_norm": ([Ld, H], f32, near_one),
+        "dense.w_gate": ([Ld, H, Fd], big, fan(H)),
+        "dense.w_up": ([Ld, H, Fd], big, fan(H)),
+        "dense.w_down": ([Ld, Fd, H], big, fan(Fd)),
+        "moe.ffn_norm": ([Le, H], f32, near_one),
+        "moe.router_w": ([Le, H, E], f32, Normal(0.0, 2.0 * root)),
+        "moe.router_bias": ([Le, E], f32, Normal(0.0, 0.02)),
+        "moe.shared_gate": ([Le, H, Fs], big, fan(H)),
+        "moe.shared_up": ([Le, H, Fs], big, fan(H)),
+        "moe.shared_down": ([Le, Fs, H], big, fan(Fs)),
+        "w_gate": ([Le, held, H, F], big, fan(H)),
+        "w_up": ([Le, held, H, F], big, fan(H)),
+        "w_down": ([Le, held, F, H], big, fan(F, 2.0)),
+    }
+
+
+_KDA_GROUPS = (("KdaParams", "kda.", kda_ops.KDA_PARAMS),
+               ("LatentParams", "mla.", kda_ops.LATENT_PARAMS),
+               ("DenseParams", "dense.", kda_ops.DENSE_PARAMS),
+               ("MoeParams", "moe.", kda_ops.MOE_PARAMS),
+               ("Experts", "", kda_ops.EXPERT_PARAMS))
+_KDA_POOLS = tuple(zip(("LatentPool", "SPool", "CPool"),
+                       (LATENT_POOL,) + STATE_POOLS))
+
+
+def _kda_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
+               page_size: int = 0, state_slots: int = 0, **feeds):
+    """Append the one `kda_moe_stack` op of a program; returns its outputs
+    (next_token, logits, routes)."""
+    helper = LayerHelper("kda_moe_stack")
+    params = {key: helper.create_parameter(
+        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
+        for key, (shape, dtype, init) in _kda_param_specs(cfg).items()}
+    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
+              "Head": [params["dec.lm_head"]],
+              "FinalNorm": [params["dec.final_norm.scale"]],
+              "Norms": [params["norm"]]}
+    inputs.update({slot: [params[prefix + k] for k in keys]
+                   for slot, prefix, keys in _KDA_GROUPS})
+    inputs.update({slot: [var] for slot, var in feeds.items()})
+    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
+            for slot, dtype in (("NextToken", "int32"),
+                                ("Logits", "float32"), ("Routes", "int32"))}
+    if mode != "full":
+        _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
+        inputs["StateSlot"] = [L.data(name=SSLOT_FEED, shape=[],
+                                      dtype="int32")]
+        for slot, name in _KDA_POOLS:
+            inputs[slot] = [name]
+            outs[slot + "Out"] = [name]
+    helper.append_op("kda_moe_stack", inputs, outs,
+                     dict(_kda_geometry(cfg), mode=mode,
+                          num_pages=int(num_pages),
+                          num_slots=int(state_slots)))
+    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
+            "routes": outs["Routes"][0]}
+
+
+def _kda_prefill(cfg, num_pages, page_size, tok, pos, pages, lens,
+                 state_slots=0):
+    return _mixer_window_io(_kda_stack(
+        cfg, "prefill", tok, pos, num_pages, page_size, state_slots,
+        PageTable=pages, Lens=lens))
+
+
+def _kda_window(cfg, num_pages, page_size, tp, tok, pos, pages, start, lens,
+                state_slots=0):
+    # a prompt's chunk or the suffix behind a resumed snapshot (no verify
+    # window)
+    return _mixer_window_io(_kda_stack(
+        cfg, "window", tok, pos, num_pages, page_size, state_slots,
+        PageTable=pages, Start=start, Lens=lens))
+
+
+def _kda_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask,
+                state_slots=0):
+    return dict(_kda_stack(cfg, "decode", tok, pos, num_pages, page_size,
+                           state_slots, PageTable=pages, Mask=mask),
+                extra_feeds=[SSLOT_FEED])
+
+
+def _kda_full(cfg, tok, pos):
+    out = _kda_stack(cfg, "full", tok, pos)
+    return {"logits": out["logits"], "routes": out["routes"]}
+
+
+def _kda_cow(cfg, num_pages, page_size, src, dst, state_slots=0):
+    # a page's slab of latent rows in every latent layer (a state is never
+    # shared: resuming from a snapshot copies it)
+    _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
+    _stacked_copy_page([LATENT_POOL], num_pages, src, dst)
 
 
 def build_state_copy_program(cfg: DecoderConfig, num_pages: int,
@@ -2216,4 +2519,6 @@ _FAMILY = {
     "mixer_moe": {"prefill": _mixer_prefill, "window": _mixer_window,
                   "cow": _ssm_cow, "decode": _mixer_decode,
                   "full": _mixer_full},
+    "kda_moe": {"prefill": _kda_prefill, "window": _kda_window,
+                "cow": _kda_cow, "decode": _kda_decode, "full": _kda_full},
 }

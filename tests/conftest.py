@@ -69,9 +69,25 @@ PINS_PR37S_TAIL = ("test_benchmark_rehearse_falcon.py::"
 PINS_PR43S_TAIL = ("test_benchmark_rehearse_nemotron.py::"
                    "test_the_cell_stands_at_the_end_of_every_list_it_joined")
 
+# PR 47's test of the cell before its own pins the END of the lists that
+# cell joined and its own did not (`lists[-1] == before`) and the last two
+# cells of `sat_tok_s`, behind which the contract tells every later PR to
+# append: the same case a fourth time (PR 51 appended a cell and a
+# configuration and joined eighteen lists, two of them such lists). What it
+# asserts besides the tails (the lists the Nemotron cell stands in, what
+# each moves, nothing between the two cells, the chips) is asserted again,
+# for both cells and the new one, by
+# tests/benchmark/test_benchmark_rehearse_ling.py, which pins no tail.
+PINS_PR47S_TAIL = ("test_benchmark_rehearse_xing.py::"
+                   "test_the_cell_before_keeps_every_list_it_joined")
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(PINS_PR47S_TAIL):
+            item.add_marker(pytest.mark.xfail(
+                reason="pins the tails of the lists PR 43's cell joined to "
+                       "PR 47's; PR 51 appended behind them", strict=True))
         if item.nodeid.endswith(PINS_PR43S_TAIL):
             item.add_marker(pytest.mark.xfail(
                 reason="pins the manifest's last cell and configuration to "

@@ -51,8 +51,9 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import executor
-from paddle_tpu.ops import (attention_ops, cca_moe_ops, latent_moe_ops,
-                            mixer_moe_ops, parallel_ssm_ops, sparse_moe_ops)
+from paddle_tpu.ops import (attention_ops, cca_moe_ops, kda_ops,
+                            latent_moe_ops, mixer_moe_ops, parallel_ssm_ops,
+                            sparse_moe_ops)
 from paddle_tpu.ops.pallas_kernels import workbench
 from paddle_tpu.serving import DecoderConfig, PagedKVPool, ServingEngine
 from paddle_tpu.serving import model as sv_model
@@ -80,6 +81,8 @@ LEVERS = {
                    r"ssm_decode_update "),
     "conv_update": (((parallel_ssm_ops, "conv_update_runs"),), bool,
                     r"conv_decode_update "),
+    "kda_update": (((kda_ops, "kda_update_runs"),), bool,
+                   r"kda_decode_update "),
     "latent_attend": (((latent_moe_ops, "latent_attend_runs"),), bool,
                       r"latent_rows_attention "),
     "paged_latent_attend": (((latent_moe_ops, "paged_attend_runs"),), bool,
@@ -274,6 +277,18 @@ CASES = [
         "paged_latent_attend": "paged_latent_attention f32[64,32,512]"},
         [(t, {"experts": f"moe_topk_experts_prefill f32[{t},3584]"})
          for t in (128, 2048)]),
+    # ling3_flash.agent8k.sat: 5 Kimi-Delta layers x 320 slots of a 32 x
+    # 128 x 128 state, a tail of 288 sublane rows a slot; the one latent
+    # layer's 256 decode rows go 64 a call (what a call keeps resident:
+    # `latent_moe_ops.paged_attend_rows`); a window's chunked form and its
+    # expanded attention are XLA's
+    *_serving("ling3_flash", 256, {
+        "kda_update": "kda_decode_update (f32[1600,4096,128],..)",
+        "conv_update": "conv_decode_update (f32[1600,288,128],..)",
+        "experts": "moe_topk_experts_decode f32[256,2560]",
+        "paged_latent_attend": "paged_latent_attention f32[64,32,512]"},
+        [(t, {"experts": f"moe_topk_experts_prefill f32[{t},2560]"})
+         for t in (128, 2048)]),
     # bert_base.s128 (and .dp4: the same rows a chip) and .s512
     ("bert_base", "attention", "train", (128, 128), "xla"),
     ("bert_base", "attention", "train", (32, 512), "xla"),
@@ -301,6 +316,9 @@ CASES = [
     *_serving("rehearse_nemotron", 4,
               {"ssm_update": "xla", "conv_update": "xla", "experts": "xla",
                "full_attention": "xla"}, [(8, {"experts": "xla"})]),
+    *_serving("rehearse_ling", 4,
+              {"kda_update": "xla", "conv_update": "xla", "experts": "xla",
+               "paged_latent_attend": "xla"}, [(8, {"experts": "xla"})]),
 ]
 
 

@@ -665,6 +665,106 @@ def test_latent_streams_stack_compiled_for_v5e_reads_its_pages_in_place(
     assert temp["decode"] < 64e6 and temp["window2048"] < 600e6, temp
 
 
+def test_kda_moe_stack_compiled_for_v5e_moves_no_pool(v5e_chip, monkeypatch):
+    """The "kda_moe" stack at the served widths (benchmark/configs/
+    ling3_flash.json: 32 Kimi-Delta heads of 128 x 128, a convolution over
+    12,288 channels, 32 latent heads of 128 + 64 over a 512-value latent, 128
+    held experts of width 768 at hidden 2,560), two layers deep (one
+    Kimi-Delta layer with a dense SwiGLU, one latent layer with experts),
+    over pools as large as the cell's (6,144 pages of latent rows in ONE
+    layer, 5 x 320 slots of state), from SHAPES alone: the decode step at
+    256 rows and a 2,048-token window, behind the cell's 112-page tables.
+    Mosaic takes the three in-place kernels of a decode step (the
+    delta-rule update, the convolution, the paged latent attention) and the
+    experts' stream; no program copies a pool, and the state pool is
+    updated in place."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops import kda_ops as ops
+    from paddle_tpu.ops import sparse_moe_ops
+    from paddle_tpu.ops.pallas_kernels import workbench
+    from paddle_tpu.serving import kv_cache
+    from paddle_tpu.serving import model as sv_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ling3_flash.json")) as f:
+        engine = json.load(f)["engine"]
+    served = DecoderConfig(**engine["config_kwargs"])
+    cfg = DecoderConfig(**dict(engine["config_kwargs"], num_layers=2,
+                               layer_group_size=2, dense_layers=1))
+    assert (served.mixer_kinds, served.mlp_kinds) == ("KKKKKL", "DDEEEE")
+    assert (cfg.mixer_kinds, cfg.mlp_kinds) == ("KL", "DE")
+    pages, ps, slots = engine["pool_pages"], engine["page_size"], 320
+    one_chip = SingleDeviceSharding(v5e_chip)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {key: shape(dims, dtype) for key, (dims, dtype, _) in
+              sv_model._kda_param_specs(cfg).items()}
+    kv, state = sv_model.ssm_pool_geometry(served, pages, ps, slots)
+    pools = tuple(shape(dims, dtype) for _, dims, dtype in
+                  kv_cache.stacked_pool_shapes(*kv)
+                  + kv_cache.state_pool_shapes(*state))
+    assert [p.shape for p in pools] == [
+        (pages, ps, 384), (5 * slots, 32 * 128, 128), (5 * slots, 288, 128)]
+    geom = ops.Geometry(**sv_model._kda_geometry(cfg))
+    weights = (params["dec.word_emb"], params["dec.lm_head"],
+               params["dec.final_norm.scale"], params["norm"]) + tuple(
+        {k: params[prefix + k] for k in keys}
+        for _, prefix, keys in sv_model._KDA_GROUPS[:4]) + (
+        tuple(params[k] for k in ops.EXPERT_PARAMS),)
+    monkeypatch.setattr(sparse_moe_ops, "_experts_backend",
+                        lambda *a: "pallas")
+    monkeypatch.setattr(workbench, "on_tpu", lambda: True)
+
+    def compiled(mode, tok_shape, rows):
+        def step(tok, pos, weights, pools, table, lens, start, mask, slot):
+            return ops.kda_moe_stack_fn(
+                mode, tok, pos, *weights, geom, pools=pools,
+                page_table=table, lens=lens, start=start, mask=mask,
+                state_slot=slot, num_pages=pages, num_slots=slots)
+
+        i32 = "int32"
+        return jax.jit(step, donate_argnums=(3,)).lower(
+            shape(tok_shape, i32), shape(tok_shape, i32), weights, pools,
+            shape((rows, 112), i32), shape((rows,), i32),
+            shape((rows,), i32), shape((rows, 1), "float32"),
+            shape((rows,), i32)).compile()
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        programs = {"decode": compiled("decode", (256,), 256),
+                    "window2048": compiled("window", (1, 2048), 1)}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    texts = {name: c.as_text() for name, c in programs.items()}
+    state_values = 5 * slots * 32 * 128 * 128
+    for name, text in texts.items():
+        assert not pool_sized_copies(text, pages * ps * 384), name
+        assert not pool_sized_copies(text, state_values), name
+        assert "moe_topk_experts" in text, name
+    for kernel in ("kda_decode_update", "conv_decode_update",
+                   "paged_latent_attention"):
+        assert kernel in texts["decode"], kernel
+        assert kernel not in texts["window2048"], kernel
+    assert not updates_out_of_place(texts["decode"], state_values)
+    # what a step holds besides weights and pools
+    temp = {name: c.memory_analysis().temp_size_in_bytes
+            for name, c in programs.items()}
+    assert temp["decode"] < 400e6 and temp["window2048"] < 1.6e9, temp
+
+
 def test_token_row_gathers_counts_rows_not_slabs():
     """Recorded from the v5e's compiler: PR 29's decode layer fetched a
     selected token from two pools, PR 30's from one; a page's slab of

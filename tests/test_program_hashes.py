@@ -20,6 +20,18 @@ blocks and reads a run of pages that rows share once
 (`paged_attention._walk_kernel`), and each of these stacks works the step's
 plan out before its layers. Every other line, the four families' prefill
 lines among them, is what it was.
+
+PR 51 added FOUR lines and recomputed none of the older ones:
+`xing4_29b_a4b`'s decode and window programs and the new `ling3_flash`'s.
+The "latent_moe" stack WITHOUT an indexer now runs its attention through
+`latent_moe_ops.unindexed_attention_fn`, the one function the "kda_moe"
+stack's latent layer runs too (a decode step of more rows than one call of
+the paged kernel keeps resident goes a group a call: `decode_plan_fn`). The
+same operations in another order, and a window's unused `[queries, context]`
+mask no longer traced: at the parent (commit de4f787) Xing's two programs
+hashed to 71a1d3e6e39727b0 and 68f77b77da474590, and before the stack was
+moved onto the shared function they still did in this tree. Every line with
+an indexer (Keye's, DeepSeek's) is what it was.
 """
 import hashlib
 from unittest import mock
@@ -49,6 +61,10 @@ HASHES = {
     ("deepseek_v32_exp", "prefill", "512"): "affbedfc12fc5867",
     ("nemotron3_super_120b", "decode", "128"): "f045b546ec04edeb",
     ("nemotron3_super_120b", "prefill", "512"): "6509dd8f853f64cd",
+    ("xing4_29b_a4b", "decode", "64"): "d78c7dfb1874891b",
+    ("xing4_29b_a4b", "prefill", "2048"): "eac4b33ecf6e9767",
+    ("ling3_flash", "decode", "256"): "c9c90a4a169294bd",
+    ("ling3_flash", "prefill", "2048"): "434349f45fe03428",
 }
 
 

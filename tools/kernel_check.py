@@ -486,6 +486,97 @@ def _ssm_update_cases(spec):
              "float32", packed_case)]
 
 
+def _kda_update_cases(spec):
+    """The "kda_moe" geometry (benchmark/configs/ling3_flash.json): 32 heads
+    of a 128 x 128 float32 state, ONE Kimi-Delta layer's 320 slots. The
+    cell's decode step, timed: 256 live rows of a 256-row bucket, and 32 of
+    32 (`us` a call, ten calls after the first; `roofline_pct`: the live
+    rows' states read once and written once, 4,194,304 B a row, against 819
+    GB/s). Then the chunked form (`kda_ops.kda_chunk_scan_fn`, no Pallas
+    kernel: XLA's block products) at a 512-token window against the token
+    recurrence: `us` a window and head-layer, `mxu_pct` its 168,448 float32
+    operations a token and head against a sixth of 197 TFLOP/s (six
+    bfloat16 passes at Precision.HIGHEST)."""
+    import time
+
+    from paddle_tpu.ops import kda_ops
+
+    H, K, V, slots = 32, 128, 128, 320
+
+    def seconds(fn, *a):
+        out = jax.block_until_ready(fn(*a))
+        t = time.perf_counter()
+        for _ in range(10):
+            last = fn(*a)
+        jax.block_until_ready(last)
+        return out, (time.perf_counter() - t) / 10
+
+    def step(B, live):
+        ks = jax.random.split(jax.random.PRNGKey(13), 7)
+        pool = _rand(ks[0], (slots, H * K, V), "float32")
+        idx = jax.random.permutation(ks[1], slots)[:B].astype(jnp.int32)
+        q = _rand(ks[2], (B, H, K), "float32", K ** -0.5)
+        k = kda_ops.l2_norm_fn(_rand(ks[3], (B, H, K), "float32"))
+        v = _rand(ks[4], (B, H, V), "float32")
+        a = jnp.exp(-5.0 * jax.nn.sigmoid(_rand(ks[5], (B, H, K),
+                                                "float32")))
+        beta = jax.nn.sigmoid(_rand(ks[6], (B, H), "float32"))
+        assert spec.supported(pool.shape, K)
+
+        def both(fn):
+            new_pool, o = fn(pool, idx, q, k, v, a, beta, live)
+            return jnp.concatenate([new_pool.reshape(-1), o.reshape(-1)])
+
+        res = _compare(lambda: both(spec.fn), lambda: both(spec.reference),
+                       (), 0, "float32")
+        # timed on a pool that is handed on from call to call (donated), as
+        # the step program hands it on
+        call = jax.jit(lambda p: spec.fn(p, idx, q, k, v, a, beta, live),
+                       donate_argnums=0)
+        state = {"pool": pool + 0.0}
+
+        def once():
+            state["pool"], o = call(state["pool"])
+            return o
+
+        _, call_s = seconds(once)
+        res.update(us=call_s * 1e6, roofline_pct=live * 2 * H * K * V * 4
+                   / call_s / 819e9 * 100)
+        return res
+
+    def window(S):
+        ks = jax.random.split(jax.random.PRNGKey(14), 6)
+        q = kda_ops.l2_norm_fn(_rand(ks[0], (1, S, H, K), "float32")) \
+            * K ** -0.5
+        k = kda_ops.l2_norm_fn(_rand(ks[1], (1, S, H, K), "float32"))
+        v = _rand(ks[2], (1, S, H, V), "float32")
+        log_a = -5.0 * jax.nn.sigmoid(_rand(ks[3], (1, S, H, K), "float32"))
+        beta = jax.nn.sigmoid(_rand(ks[4], (1, S, H), "float32"))
+        s0 = _rand(ks[5], (1, H, K, V), "float32", 0.1)
+        scan = jax.jit(lambda *a: kda_ops.kda_chunk_scan_fn(
+            *a, 64, 16, -5.0))
+        (o, s1), call_s = seconds(scan, q, k, v, log_a, beta, s0)
+        with jax.default_matmul_precision("highest"):
+            want_o, want_s = jax.jit(kda_ops.kda_token_recurrence_fn)(
+                q, k, v, log_a, beta, s0)
+        res = {"err": max(_rel_err(o, want_o), _rel_err(s1, want_s)),
+               "tol": TOL["float32"],
+               "finite": bool(np.isfinite(np.asarray(o)).all()),
+               "us": call_s * 1e6,
+               "mxu_pct": S * H * 168448 / call_s / (197e12 / 6) * 100}
+        res["ok"] = bool(res["finite"] and res["err"] <= res["tol"])
+        return res
+
+    return [("b256 (256 live) h32 k128 v128 pool 320 float32 timed",
+             lambda: step(256, 256)),
+            ("b32 (32 live) h32 k128 v128 pool 320 float32 timed",
+             lambda: step(32, 32)),
+            ("b256 (200 live) h32 k128 v128 pool 320 float32 timed",
+             lambda: step(256, 200)),
+            ("chunked form: window 512 h32 k128 v128 chunk 64 sub 16 timed",
+             lambda: window(512))]
+
+
 def _moe_relu2_cases(spec):
     """A chip's share of the "mixer_moe" block's experts: 128 HELD of 512,
     two matrices 1024 -> 2688 -> 1024 and a squared ReLU, top-22 (so a
@@ -764,6 +855,7 @@ CASES = {
     "indexer_paged_scores": _paged_indexer_cases,
     "ssm_decode_update": _ssm_update_cases,
     "conv_decode_update": _conv_update_cases,
+    "kda_decode_update": _kda_update_cases,
     "attention_paged_decode": lambda spec: (_paged_cases(spec)
                                             + _paged_gqa_cases(spec)
                                             + _paged_gqa_step_cases(spec)),

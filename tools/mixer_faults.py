@@ -154,18 +154,23 @@ def drive(engine, cfg, shared: int, unshared: list, out: int, seed: int):
     return [(p, list(r.out_tokens), r.routes) for p, r in zip(prompts, done)]
 
 
-def main(argv=None) -> int:
+def main(argv=None, faults=None, config="nemotron3_super_120b",
+         seed=2147483693, doc=None) -> int:
+    """The drive as a script; another family's tool (`tools/kda_faults.py`)
+    runs it over its own `faults`, configuration, seed and description."""
     import argparse
     import importlib
 
     from benchmark.harness import load_json
     from paddle_tpu.serving import DecoderConfig, ServingEngine
 
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--config", default="nemotron3_super_120b")
-    ap.add_argument("--faults", default=",".join(FAULTS))
+    faults = FAULTS if faults is None else faults
+    ap = argparse.ArgumentParser(
+        description=(doc or __doc__).split("\n\n")[0])
+    ap.add_argument("--config", default=config)
+    ap.add_argument("--faults", default=",".join(faults))
     ap.add_argument("--out", type=int, default=96)
-    ap.add_argument("--seed", type=int, default=2147483693)
+    ap.add_argument("--seed", type=int, default=seed)
     a = ap.parse_args(argv)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     config = load_json(root, "benchmark", "configs", a.config + ".json")
@@ -181,7 +186,7 @@ def main(argv=None) -> int:
     unshared = [2, 3, chunk * 5 // 8, chunk * 3 // 4]
     bad = 0
     for name in ["none"] + [f for f in a.faults.split(",") if f]:
-        with FAULTS[name]() if name != "none" else contextlib.nullcontext():
+        with faults[name]() if name != "none" else contextlib.nullcontext():
             engine = ServingEngine(
                 cfg, page_size=spec["page_size"],
                 pool_pages=spec["pool_pages"],
