@@ -368,6 +368,18 @@ DECLARED: list[tuple] = [
     ("serving.moe.held_pairs", COUNTER,
      "those of serving.moe.routed_pairs that fell on the experts this "
      "engine holds (1 / shares of them under uniform routing)", ()),
+    ("serving.moe.grouped_layer_steps", COUNTER,
+     "layer x window pairs whose gated expert call took the kernel's "
+     "grouped form (more rows than one token tile, on the chip): the "
+     "(row, expert) pairs sorted by expert, each held expert's slabs "
+     "streamed at most once", ()),
+    ("serving.moe.grouped_pairs", COUNTER,
+     "(row, held expert) pairs those calls multiplied, the rows of a "
+     "window's padding among them", ()),
+    ("serving.moe.grouped_tile_rows", COUNTER,
+     "rows of the tiles those calls ran: a tile of the sorted pairs is run "
+     "once by every expert with a row in it (serving.moe.grouped_pairs over "
+     "this: the share of the matrix unit's rows that held a pair)", ()),
     # -- window and full attention layers over two pools (ISSUE 33) ---------
     ("serving.kv.window_release.seconds", HISTOGRAM,
      "returning to the sliding layers' pool the pages a row's window has "

@@ -183,6 +183,16 @@ def _experts_backend(tokens, w_gate_shape, dtype):
     return backend if backend == "xla" or runnable() else "xla"
 
 
+def experts_grouped(tokens, w_gate_shape, dtype) -> bool:
+    """Whether a gated expert call of `tokens` rows takes the kernel's
+    grouped form (`moe_experts._grouped_call`): the kernel runs, and the
+    rows are more than its one token tile."""
+    from .pallas_kernels import moe_experts as pme
+
+    return tokens > pme._TOKEN_TILE \
+        and _experts_backend(tokens, w_gate_shape, dtype) == "pallas"
+
+
 def moe_top1_experts_fn(z, probs, choice, w_gate, w_up, w_down, layer=0,
                         expert_lo: int = 0, tag: str = "decode"):
     """The part of a top-1 expert layer that the holder of experts

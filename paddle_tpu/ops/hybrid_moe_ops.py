@@ -283,7 +283,8 @@ def _feed_forward(h, norm, kind: str, p, experts, index, geom: Geometry,
                                     geom.experts_per_token,
                                     geom.routed_scaling)
     with piece("experts"):
-        y = moe_topk_experts_fn(z, cw, *experts, layer=index, tag=tag)
+        y = moe_topk_experts_fn(z, cw, *experts, layer=index, tag=tag,
+                                k=geom.experts_per_token)
     with piece("dense_ffn"):
         y = y + swiglu_fn(z, p["shared_gate"], p["shared_up"],
                           p["shared_down"])

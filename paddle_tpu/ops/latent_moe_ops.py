@@ -652,7 +652,8 @@ def _feed_forward(h, kind_dense: bool, p, experts, index, geom: Geometry,
             geom.expert_groups, geom.groups_per_token, geom.routed_scaling)
         held = cw[:, :geom.experts_held]    # this chip's experts' columns
     with piece("experts"):
-        y = moe_topk_experts_fn(z, held, *experts, layer=index, tag=tag)
+        y = moe_topk_experts_fn(z, held, *experts, layer=index, tag=tag,
+                                k=geom.experts_per_token)
     with piece("shared"):
         y = (y + swiglu_fn(z, p["shared_gate"], p["shared_up"],
                            p["shared_down"])).reshape(B, S, H)

@@ -495,14 +495,16 @@ def topk_router_fn(z, router_w, k: int):
 
 
 def moe_topk_experts_fn(z, cw, w_gate, w_up, w_down, layer=0,
-                        tag: str = "decode"):
+                        tag: str = "decode", k: int | None = None):
     """`sum_e cw[t, e] * expert_e(z[t])`, float32 [T, H]; weights stacked
-    `[L, E, ...]`, `layer` picks the layer."""
+    `[L, E, ...]`, `layer` picks the layer; `k` the router's experts a
+    token (the most non-zeros a row of `cw` has: what the kernel's grouped
+    form, a window of more than 256 rows, sizes its pair list by)."""
     from .pallas_kernels import moe_experts as pme
 
     if _experts_backend(z.shape[0], w_gate.shape, w_gate.dtype) == "pallas":
         return pme.moe_topk_experts(z, cw, w_gate, w_up, w_down, layer,
-                                    tag=tag)
+                                    tag=tag, k=k)
     return pme._reference(z, cw, w_gate, w_up, w_down, layer)
 
 
@@ -549,7 +551,8 @@ def _post_attention(x, o, p, experts, layer, geom: Geometry, tag):
     with piece("router"):
         ids, cw = topk_router_fn(z, p["router_w"], geom.experts_per_token)
     with piece("experts"):
-        y = moe_topk_experts_fn(z, cw, *experts, layer=layer, tag=tag)
+        y = moe_topk_experts_fn(z, cw, *experts, layer=layer, tag=tag,
+                                k=geom.experts_per_token)
         return h + y.reshape(B, S, H), ids.reshape(B, S, -1)
 
 

@@ -201,8 +201,8 @@ from ..data_feeder import _round_up_pow2
 from ..executor import Executor, Scope
 from ..framework import Program, program_guard
 from ..observability.slo import hist_p99_above
-from ..ops import (attention_ops, kda_ops, latent_moe_ops, parallel_ssm_ops,
-                   sparse_moe_ops)
+from ..ops import (attention_ops, cca_moe_ops, kda_ops, latent_moe_ops,
+                   parallel_ssm_ops, sparse_moe_ops)
 from ..resilience.faults import InjectedFault, fault_point
 from ..resilience.retry import serving_policy
 from . import model as sv_model
@@ -745,6 +745,7 @@ class ServingEngine:
         # which expert each layer chose for the token in (page, slot): the
         # host twin of the pools, for blocks that route
         self._page_routes = None
+        self._grouped_rows: dict = {}  # a window's rows -> `_note_grouped`
         if "routes" in self._decode_io:
             per_token = (self.cfg.experts_per_token,) \
                 if self.cfg.experts_per_token > 1 else ()
@@ -800,6 +801,8 @@ class ServingEngine:
             # a residual path of several streams (ISSUE 47)
             "hc.mix_tokens": 0,
             "moe.routed_pairs": 0, "moe.held_pairs": 0,
+            "moe.grouped_layer_steps": 0, "moe.grouped_pairs": 0,
+            "moe.grouped_tile_rows": 0,
             # window and full attention layers over two pools (ISSUE 33)
             "kv.window_pages_released": 0, "kv.window_row_pages": 0,
             "kv.global_row_pages": 0, "attn.full_context_tokens": 0,
@@ -2146,16 +2149,47 @@ class ServingEngine:
         today's streams every held expert)."""
         self._page_routes[[page for _, page in at],
                           [pos % self.page_size for pos, _ in at]] = routes
+        per_layer = self._routed_per_layer(routes)
+        self._count_routed(per_layer.sum(axis=0))
+        # of the experts this engine holds (all of them, but for a share)
+        self._count("moe.experts_touched", int(np.count_nonzero(
+            per_layer[:, :self.cfg.held_experts])))
+        self._count("moe.layer_steps", len(per_layer))
+
+    def _routed_per_layer(self, routes):
+        """`routes` [rows, layers(, k)] -> how many of the rows each routed
+        layer sent to each expert, [layers, num_experts]."""
         L, E = self.cfg.routed_layers, self.cfg.num_experts
         per_layer = np.zeros((L, E), np.int64)
         layer_of = np.arange(L).reshape((1, L) + (1,) * (routes.ndim - 2))
         np.add.at(per_layer, (np.broadcast_to(layer_of, routes.shape),
                               routes), 1)
-        self._count_routed(per_layer.sum(axis=0))
-        # of the experts this engine holds (all of them, but for a share)
-        self._count("moe.experts_touched", int(np.count_nonzero(
-            per_layer[:, :self.cfg.held_experts])))
-        self._count("moe.layer_steps", L)
+        return per_layer
+
+    def _note_grouped(self, routes) -> None:
+        """A window's routes, EVERY row the program ran (`routes` [rows,
+        layers(, k)], the bucket's padding among them: the kernel multiplies
+        those rows too): where its expert calls took the kernel's grouped
+        form (`cca_moe_ops.experts_grouped`: more rows than one token tile,
+        on the chip), the calls, the (row, held expert) pairs they
+        multiplied and the rows of the tiles they ran."""
+        from ..ops.pallas_kernels import moe_experts
+
+        cfg = self.cfg
+        rows = len(routes)
+        if rows not in self._grouped_rows:
+            self._grouped_rows[rows] = cfg.block != "mixer_moe" \
+                and cca_moe_ops.experts_grouped(
+                    rows, (cfg.routed_layers, cfg.held_experts,
+                           cfg.hidden_size, cfg.ffn_size), cfg.dtype)
+        if not self._grouped_rows[rows]:
+            return
+        held = self._routed_per_layer(routes)[:, :cfg.held_experts]
+        _, visits = moe_experts.grouped_visits(held, np)
+        self._count("moe.grouped_layer_steps", len(held))
+        self._count("moe.grouped_pairs", int(held.sum()))
+        self._count("moe.grouped_tile_rows",
+                    int(visits.sum()) * moe_experts.GROUP_TILE)
 
     def _mark(self, req: GenRequest) -> None:
         """Mark `req` (just admitted) if it asked for its selection and one
@@ -2356,6 +2390,7 @@ class ServingEngine:
         req, (first, n) = step.rows[0], step.at[0]
         if routes is not None:
             self._note_routes(step.route_pages, first, routes[0, :n])
+            self._note_grouped(routes[0])
         if selection is not None:
             self._keep_selection(req, selection[0, :n])
         if tokens is not None:

@@ -32,6 +32,17 @@ mask no longer traced: at the parent (commit de4f787) Xing's two programs
 hashed to 71a1d3e6e39727b0 and 68f77b77da474590, and before the stack was
 moved onto the shared function they still did in this tree. Every line with
 an indexer (Keye's, DeepSeek's) is what it was.
+
+PR 52 recomputed THREE lines, exactly those whose windows pass more than 256
+rows through the gated expert kernel: `deepseek_v32_exp` prefill 512,
+`xing4_29b_a4b` prefill 2048 and `ling3_flash` prefill 2048 (at the parent,
+commit a61cabb: affbedfc12fc5867, eac4b33ecf6e9767, 434349f45fe03428). Such
+a call now sorts its (token, expert) pairs by expert and runs the grouped
+form (`moe_experts._grouped_call`: a `top_k`, a `sort`, a row gather and a
+`pallas_call` over the sorted rows where one `pallas_call` walked every held
+expert over every token tile). Every decode line and every other prefill
+line (windows of up to 256 rows, and the families without the gated arm)
+stands.
 """
 import hashlib
 from unittest import mock
@@ -58,13 +69,13 @@ HASHES = {
     ("deepseek_v32_exp", "decode", "128"): "0587bbd89e2a8e47",
     ("deepseek_v32_exp", "decode", "32"): "36487f961f762836",
     ("deepseek_v32_exp", "prefill", "128"): "cfe584788426341a",
-    ("deepseek_v32_exp", "prefill", "512"): "affbedfc12fc5867",
+    ("deepseek_v32_exp", "prefill", "512"): "39659a7d6d168116",
     ("nemotron3_super_120b", "decode", "128"): "f045b546ec04edeb",
     ("nemotron3_super_120b", "prefill", "512"): "6509dd8f853f64cd",
     ("xing4_29b_a4b", "decode", "64"): "d78c7dfb1874891b",
-    ("xing4_29b_a4b", "prefill", "2048"): "eac4b33ecf6e9767",
+    ("xing4_29b_a4b", "prefill", "2048"): "41d5e91d20e55186",
     ("ling3_flash", "decode", "256"): "c9c90a4a169294bd",
-    ("ling3_flash", "prefill", "2048"): "434349f45fe03428",
+    ("ling3_flash", "prefill", "2048"): "368abb70195f5e36",
 }
 
 

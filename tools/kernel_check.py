@@ -348,87 +348,134 @@ def _moe_cases(spec):
     a decode batch and at a 512-token chunk; and one of the "hybrid_moe"
     block's (256 x 2048 -> 512 -> 2048, top-8), the same two; and a share
     of the "latent_moe" block's (16 held of 256 x 7168 -> 2048 -> 7168), at
-    a decode batch and at a 512-token chunk."""
+    a decode batch and at a 512-token chunk; and the "kda_moe" block's (128
+    held of 512 x 2560 -> 768 -> 2560, top-8) at windows of 512, 1,024 and
+    2,048 tokens, at 4,096 (two blocks of what one call keeps resident) and
+    at 2,048 of which 300 are real and the others copies of one row, as a
+    window's padding is.
+
+    Every case is timed (`us`: the call as the stacks make it). A call of
+    more than 256 rows takes the grouped form, and its parts are timed
+    apart on the same arrays: `us_sort_rows` (the pairs sorted by expert
+    and z's rows gathered into that order: a few XLA operations, so never
+    under the host's 0.2 ms to enqueue them), `us_kernel`, and `us_walk`,
+    the form a call of up to 256 rows takes (every held expert over every
+    token tile: what such a call ran before PR 52), alternating with
+    `us`."""
+    import time
+
+    from paddle_tpu.ops.pallas_kernels import moe_experts as pme
+
+    def seconds(fn, *a):
+        out = jax.block_until_ready(fn(*a))
+        t = time.perf_counter()
+        for _ in range(10):
+            last = fn(*a)
+        jax.block_until_ready(last)
+        return out, (time.perf_counter() - t) / 10
+
+    def timed(z, cw, wg, wu, wd, k):
+        layer = jnp.int32(1)
+        if k == 1:
+            fn = jax.jit(lambda *a: spec.fn(*a, layer))
+        else:
+            fn = jax.jit(lambda *a: pme.moe_topk_experts(*a, layer, k=k))
+        args = (z, cw, wg, wu, wd)
+        assert spec.supported(z.shape, wg.shape)
+        got, call_s = seconds(fn, *args)
+        with jax.default_matmul_precision("highest"):
+            want = spec.reference(*args, 1)
+        res = {"err": _rel_err(got, want), "tol": TOL["bfloat16"],
+               "finite": bool(np.isfinite(np.asarray(got)).all()),
+               "us": call_s * 1e6}
+        if z.shape[0] > pme._TOKEN_TILE:
+            walk = jax.jit(lambda *a: pme._call(*a, layer, "decode", False,
+                                                topk=k > 1))
+            walked, walk_s = seconds(walk, *args)
+            _, call2_s = seconds(fn, *args)
+            _, walk2_s = seconds(walk, *args)
+            res.update({"us": min(call_s, call2_s) * 1e6,
+                        "us_walk": min(walk_s, walk2_s) * 1e6,
+                        "walk_err": _rel_err(got, walked)})
+        if pme._TOKEN_TILE < z.shape[0] <= pme._resident_tokens(
+                wg.shape, wg.dtype.itemsize, k):
+            # one block: its two parts apart
+            sort = jax.jit(lambda z, cw: pme._sort_rows(z, cw, k, wg.dtype))
+            (zs, ws, token, plan), sort_s = seconds(sort, z, cw)
+            _, kernel_s = seconds(jax.jit(
+                lambda zs, ws, token, plan, *w: pme._grouped_experts(
+                    zs, ws, token, plan, -(-z.shape[0] // 16) * 16, *w, layer,
+                    "check", False, k > 1)),
+                zs, ws, token, plan, wg, wu, wd)
+            _, visits = pme.grouped_visits(np.diff(np.asarray(plan[3])), np)
+            res.update({
+                "us_sort_rows": sort_s * 1e6, "us_kernel": kernel_s * 1e6,
+                "pairs": int(plan[3][-1]),
+                "tile_rows": int(visits.sum()) * pme.GROUP_TILE})
+        res["ok"] = bool(res["finite"] and res["err"] <= res["tol"])
+        return res
+
+    def weights(seed, E, H, F):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+        wg = _rand(ks[1], (2, E, H, F), "bfloat16", H ** -0.5)
+        wu = _rand(ks[2], (2, E, H, F), "bfloat16", H ** -0.5)
+        wd = _rand(ks[3], (2, E, F, H), "bfloat16", F ** -0.5)
+        return ks, wg, wu, wd
 
     def case(tokens):
-        L, E, H, F = 2, 16, 2048, 2048
-        ks = jax.random.split(jax.random.PRNGKey(2), 6)
+        E, H, F = 16, 2048, 2048
+        ks, wg, wu, wd = weights(2, E, H, F)
         z = _rand(ks[0], (tokens, H), "float32")
-        wg = _rand(ks[1], (L, E, H, F), "bfloat16", H ** -0.5)
-        wu = _rand(ks[2], (L, E, H, F), "bfloat16", H ** -0.5)
-        wd = _rand(ks[3], (L, E, F, H), "bfloat16", F ** -0.5)
         choice = jax.random.randint(ks[4], (tokens,), 0, E)
         cw = jax.nn.one_hot(choice, E) * jax.random.uniform(
             ks[5], (tokens, 1), minval=0.1, maxval=0.9)
-        assert spec.supported(z.shape, wg.shape)
-        return _compare(lambda *a: spec.fn(*a, 1),
-                        lambda *a: spec.reference(*a, 1),
-                        (z, cw, wg, wu, wd), 0, "bfloat16")
+        return timed(z, cw, wg, wu, wd, 1)
 
-    def topk_case(tokens):
+    def topk_case(tokens, seed, E, H, F, held=None, scale=1.0, real=None):
+        # eight combine weights a row, drawn among E experts and summing to
+        # `scale`; this chip holds the first `held` of them (all, if None),
+        # so most of a row's held columns are zero and many rows hold none.
+        # `real`: the rows behind it are copies of row 0, as a window's
+        # padding is, so that a few experts' groups span many tiles
+        k = 8
+        ks, wg, wu, wd = weights(seed, held or E, H, F)
+        z = _rand(ks[0], (tokens, H), "float32")
+        vals, ids = jax.lax.top_k(jax.random.uniform(ks[4], (tokens, E)), k)
+        if real is not None:
+            pad = jnp.arange(tokens)[:, None] >= real
+            z = jnp.where(pad, z[:1], z)
+            ids = jnp.where(pad, jnp.arange(k)[None, :] * 3, ids)
+            vals = jnp.where(pad, vals[:1], vals)
+        cw = jnp.sum(jax.nn.one_hot(ids, E)
+                     * (scale * vals / vals.sum(-1, keepdims=True))[..., None],
+                     1)[:, :held]
+        return timed(z, cw, wg, wu, wd, k)
+
+    return [
+        # the "latent_moe" geometry: a chip's 16 HELD experts of width 2048
+        # at hidden 7168 (the narrow F tile: 256 columns a grid step)
+        *[(f"t{t} 16 held of 256 top8 h7168 f2048 bf16 layer 1 of 2",
+           lambda t=t: topk_case(t, 9, 256, 7168, 2048, 16, 2.5))
+          for t in (128, 512)],
+        *[(f"t{t} e16 h2048 f2048 bf16 layer 1 of 2", lambda t=t: case(t))
+          for t in (64, 300)],
         # the "sparse_moe" geometry: 128 experts of width 768 (F tile 384),
         # eight renormalised combine weights a row
-        L, E, H, F, k = 2, 128, 2048, 768, 8
-        ks = jax.random.split(jax.random.PRNGKey(3), 6)
-        z = _rand(ks[0], (tokens, H), "float32")
-        wg = _rand(ks[1], (L, E, H, F), "bfloat16", H ** -0.5)
-        wu = _rand(ks[2], (L, E, H, F), "bfloat16", H ** -0.5)
-        wd = _rand(ks[3], (L, E, F, H), "bfloat16", F ** -0.5)
-        vals, ids = jax.lax.top_k(jax.random.uniform(ks[4], (tokens, E)), k)
-        cw = jnp.sum(jax.nn.one_hot(ids, E)
-                     * (vals / vals.sum(-1, keepdims=True))[..., None], 1)
-        assert spec.supported(z.shape, wg.shape)
-        return _compare(lambda *a: spec.fn(*a, 1),
-                        lambda *a: spec.reference(*a, 1),
-                        (z, cw, wg, wu, wd), 0, "bfloat16")
-
-    def wide_case(tokens):
+        *[(f"t{t} e128 top8 h2048 f768 bf16 layer 1 of 2",
+           lambda t=t: topk_case(t, 3, 128, 2048, 768))
+          for t in (64, 512)],
         # the "hybrid_moe" geometry: 256 experts of width 512 (one F tile an
-        # expert), the combine weights in two lane registers, eight a row
-        # summing to 2.5
-        L, E, H, F, k = 2, 256, 2048, 512, 8
-        ks = jax.random.split(jax.random.PRNGKey(7), 6)
-        z = _rand(ks[0], (tokens, H), "float32")
-        wg = _rand(ks[1], (L, E, H, F), "bfloat16", H ** -0.5)
-        wu = _rand(ks[2], (L, E, H, F), "bfloat16", H ** -0.5)
-        wd = _rand(ks[3], (L, E, F, H), "bfloat16", F ** -0.5)
-        vals, ids = jax.lax.top_k(jax.random.uniform(ks[4], (tokens, E)), k)
-        cw = jnp.sum(jax.nn.one_hot(ids, E)
-                     * (2.5 * vals / vals.sum(-1, keepdims=True))[..., None],
-                     1)
-        assert spec.supported(z.shape, wg.shape)
-        return _compare(lambda *a: spec.fn(*a, 1),
-                        lambda *a: spec.reference(*a, 1),
-                        (z, cw, wg, wu, wd), 0, "bfloat16")
-
-    def share_case(tokens):
-        # the "latent_moe" geometry: a chip's 16 HELD experts of width 2048
-        # at hidden 7168 (the narrow F tile: 256 columns a grid step); a
-        # token's eight experts are drawn among 256, so most of a row's
-        # held columns are zero and many rows hold none
-        L, E, H, F, k, held = 2, 256, 7168, 2048, 8, 16
-        ks = jax.random.split(jax.random.PRNGKey(9), 6)
-        z = _rand(ks[0], (tokens, H), "float32")
-        wg = _rand(ks[1], (L, held, H, F), "bfloat16", H ** -0.5)
-        wu = _rand(ks[2], (L, held, H, F), "bfloat16", H ** -0.5)
-        wd = _rand(ks[3], (L, held, F, H), "bfloat16", F ** -0.5)
-        vals, ids = jax.lax.top_k(jax.random.uniform(ks[4], (tokens, E)), k)
-        cw = jnp.sum(jax.nn.one_hot(ids, E)
-                     * (2.5 * vals / vals.sum(-1, keepdims=True))[..., None],
-                     1)[:, :held]
-        assert spec.supported(z.shape, wg.shape)
-        return _compare(lambda *a: spec.fn(*a, 1),
-                        lambda *a: spec.reference(*a, 1),
-                        (z, cw, wg, wu, wd), 0, "bfloat16")
-
-    return [(f"t{t} 16 held of 256 top8 h7168 f2048 bf16 layer 1 of 2",
-             lambda t=t: share_case(t)) for t in (128, 512)] + [
-            (f"t{t} e16 h2048 f2048 bf16 layer 1 of 2",
-             lambda t=t: case(t)) for t in (64, 300)] + [
-        (f"t{t} e128 top8 h2048 f768 bf16 layer 1 of 2",
-         lambda t=t: topk_case(t)) for t in (64, 512)] + [
-        (f"t{t} e256 top8 h2048 f512 bf16 layer 1 of 2",
-         lambda t=t: wide_case(t)) for t in (64, 512)]
+        # expert), the combine weights in two lane registers, summing to 2.5
+        *[(f"t{t} e256 top8 h2048 f512 bf16 layer 1 of 2",
+           lambda t=t: topk_case(t, 7, 256, 2048, 512, None, 2.5))
+          for t in (64, 512)],
+        # the "kda_moe" geometry: 128 held of 512 experts of width 768 at
+        # hidden 2560, a window's three sizes
+        *[(f"t{t} 128 held of 512 top8 h2560 f768 bf16 layer 1 of 2",
+           lambda t=t: topk_case(t, 11, 512, 2560, 768, 128, 2.5))
+          for t in (512, 1024, 2048, 4096)],
+        ("t2048 (300 real) 128 held of 512 top8 h2560 f768 bf16 layer 1 of 2",
+         lambda: topk_case(2048, 11, 512, 2560, 768, 128, 2.5, real=300))]
 
 
 def _ssm_update_cases(spec):

@@ -150,6 +150,17 @@ def cmd_diff(argv: list[str]) -> int:
         d = nc.get(k, 0) - oc.get(k, 0)
         if d:
             rows.append(f"  counter  {k:<36} {d:+g}")
+    steps, pairs, tile_rows = (
+        nc.get(k, 0) - oc.get(k, 0) for k in (
+            "serving.moe.grouped_layer_steps", "serving.moe.grouped_pairs",
+            "serving.moe.grouped_tile_rows"))
+    if steps and tile_rows:
+        # the grouped expert calls between the snapshots: the pairs a call
+        # multiplied, and the share of its tiles' rows that held one
+        rows.append(f"  derived  serving.moe.grouped: {steps:g} layer steps, "
+                    f"{pairs:g} pairs, {tile_rows:g} tile rows; "
+                    f"{pairs / steps:.1f} pairs a step, "
+                    f"{pairs / tile_rows:.1%} of tile rows")
     og, ng = old.get("gauges", {}), new.get("gauges", {})
     for k in sorted(set(og) | set(ng)):
         a, b = og.get(k), ng.get(k)
