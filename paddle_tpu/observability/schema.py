@@ -486,6 +486,26 @@ DECLARED: list[tuple] = [
      "the chunked form", ()),
     ("serving.kda.scan_layer_steps", COUNTER,
      "Kimi-Delta layer x window pairs: the calls of the chunked form", ()),
+    # layers visited several times a token ("looped_dense"), and a pool that
+    # binds the rows in flight before `max_inflight` does
+    ("serving.loop.visits", COUNTER,
+     "layer visits the step programs ran: a decode step or a prefill window "
+     "x loop_steps x layers (each a pass over one layer's weights)", ()),
+    ("serving.loop.decode_row_visits", COUNTER,
+     "decode row x layer visit pairs: each one paged attention call's row, "
+     "over a plane of K/V pages of its own", ()),
+    ("serving.loop.exit_mass", COUNTER,
+     "the exit gate's probability of leaving after each visit, summed over "
+     "the tokens decode steps emitted (over serving.decode_tokens: the mean; "
+     "the visits sum to 1): what a threshold under 1 would let leave where",
+     ("visit",)),
+    ("serving.preempted_tokens", COUNTER,
+     "tokens a resumed request prefilled again after a preemption dropped "
+     "its pages (its prompt and what it had produced, less what the prefix "
+     "cache still held)", ()),
+    ("serving.pool_bound_admissions", COUNTER,
+     "admissions that had waited for pages while a row slot was free: the "
+     "pool, not max_inflight, held them", ()),
     # -- the host's own pauses (observability/registry._GcWatch) -------------
     ("host.gc.collections", COUNTER,
      "garbage collections by generation", ("generation",)),
@@ -649,7 +669,13 @@ PIECES = frozenset({
                   # normalised heads of q and k
     "kda_update", # ... a decode token's delta-rule update, in place
     "kda_scan",   # ... a window's chunked (WY) form from its slot
-    "mlp",        # parallel_ssm: the layer's SwiGLU
+    "mlp",        # parallel_ssm, looped_dense: the layer's SwiGLU
+    "qkv",        # looped_dense: a visit's pre-norm, q | k | v product and
+                  # rotary
+    "o_proj",     # ... the attention's output product, its norm and the
+                  # residual
+    "exit_gate",  # ... the norm that closes a visit and the gate's
+                  # probability of stopping there
     "head",       # final norm and the vocabulary product
 })
 

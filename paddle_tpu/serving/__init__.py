@@ -13,18 +13,20 @@ Five pieces, one runtime:
                    windowed suffix-prefill+verify / ragged decode programs
                    over one explicit weight namespace (plus the dense
                    oracle for equivalence tests, the COW page-copy step,
-                   and the GSPMD tp annotations). EIGHT block families,
+                   and the GSPMD tp annotations). NINE block families,
                    selected by `DecoderConfig.block` (`model`'s docstring
                    has each in full; three are told here, and
                    `"hybrid_moe"`, `"parallel_ssm"`, `"latent_moe"`,
-                   `"mixer_moe"` and `"kda_moe"` there: layers of more
+                   `"mixer_moe"`, `"kda_moe"` and `"looped_dense"` there: layers of more
                    than one shape over two pools, a recurrent state in a
                    pool of slots, a latent cache row a token read through
                    the indexer with a share of the experts held, layers
                    that are a mixer, an attention or experts in a latent
                    ALONE, each pool sized by the count of its kind, and
                    linear-attention layers whose matrix state lives in a
-                   slot beside a latent-attention layer's paged rows): `"post_ln"` (the
+                   slot beside a latent-attention layer's paged rows, and
+                   layers a token passes several times, each visit with
+                   K/V pages of its own): `"post_ln"` (the
                    default: BERT-base run causally; fields vocab_size,
                    hidden_size, num_layers, num_heads, ffn_size,
                    max_position, dtype) and `"cca_moe"` (ZAYA1's layer:

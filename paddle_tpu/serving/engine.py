@@ -142,6 +142,22 @@ row's own slot (`serving.state.restore`), because unlike a K/V page a state
 is never read-only for its reader. Copy, snapshot and restore are one
 in-place device program (`model.build_state_copy_program`).
 
+Layers a token passes several times (the "looped_dense" block): the stacked
+K/V pools hold `cfg.cache_planes` = loop_steps x layers slabs a page id, so
+everything the host does by page id (allocate, share, copy-on-write, release,
+the audit, the leak count) answers for every visit of every layer at once,
+and a token's cache is that many rows: the POOL, not `max_inflight`, bounds
+the rows in flight, and the backpressure below (admission that waits for
+pages, the youngest row preempted and prefilled again) is the normal path.
+A resumed row is a prompt of its own prompt and what it had produced, a
+length no arrival has, so the family compiles one page-table width
+(`cfg.one_page_bucket`) and its windows are the arrivals' programs. Every
+step hands back, beside the logits, the exit gate's probability of leaving
+after each visit (`request.exit_mass`, one `[visits]` row a generated token;
+`serving.loop.exit_mass` by visit); nothing branches on it. The counters
+`serving.preempted_tokens` and `serving.pool_bound_admissions` say what the
+pool's bound cost (any family books them).
+
 Compile discipline (the PR 2 machinery doing serving duty):
   * prefill compiles once per prompt-length bucket (pow2 rounding); suffix
     prefill once per (suffix-bucket, page-bucket);
@@ -317,6 +333,12 @@ def _selection_words(pieces: list, page_size: int) -> np.ndarray:
     return np.concatenate(out)
 
 
+# what a family's step programs may hand back beside the token and the
+# logits, in fetch order: the experts chosen, the positions attended, the
+# probability of leaving after each visit of the layers
+_EXTRA_FETCHES = ("routes", "selection", "exit_mass")
+
+
 @dataclasses.dataclass
 class _InFlight:
     """One step program the device has been given and the host has not read
@@ -334,6 +356,7 @@ class _InFlight:
     at: list
     marked: list            # decode: the row indices whose selection is kept
     route_pages: object = None  # prefill / chunk: the page of each position
+    exit_mass: object = None    # [rows, visits], "looped_dense" only
 
 
 class GenRequest:
@@ -390,6 +413,16 @@ class GenRequest:
         # k > 1 experts a token [cache_len, layers, k]), one row per
         # position whose K/V the engine computed, set when it finishes
         self.routes = None
+        # layers visited several times a token ("looped_dense"): for each
+        # generated token the probability of leaving after each visit
+        # ([visits] float32, summing to 1), as the step that emitted it
+        # gave it
+        self.exit_mass: list = []
+        # a preemption dropped the pages: the next prefill computes again
+        # what a step had computed; and whether an admission found no pages
+        # while a row slot was free
+        self.resumed = False
+        self.waited_for_pages = False
         # learned sparse attention: `keep_selection` asks for `selection`;
         # `marked` says that this admission records it (a slot was free)
         self.keep_selection = False
@@ -825,6 +858,11 @@ class ServingEngine:
             "kda.decode_pad_row_layers": 0,
             "kda.scan_tokens": 0, "kda.scan_layer_steps": 0,
             "peak_state_slots_in_use": 0,
+            # layers visited several times a token, and a pool that binds
+            # the rows in flight (ISSUE 53)
+            "loop.visits": 0, "loop.decode_row_visits": 0,
+            "loop.exit_mass": 0.0, "preempted_tokens": 0,
+            "pool_bound_admissions": 0,
         }
 
     def _page_bucket(self, n: int) -> int:
@@ -832,10 +870,11 @@ class ServingEngine:
         power of two, or past `cfg.page_bucket_step` pages a multiple of
         it; for a family of `cfg.one_page_bucket` the width of
         `max_position` whatever `n`."""
+        step = self.cfg.page_bucket_step
         if self.cfg.one_page_bucket:
             n = max(n, self.pool.pages_for(self.cfg.max_position))
-            return n if n <= 32 else -(-n // 32) * 32
-        step = self.cfg.page_bucket_step
+            whole = step or 32
+            return n if n <= whole else -(-n // whole) * whole
         if step and n > step:
             return -(-n // step) * step
         return _round_up_pow2(n)
@@ -1722,13 +1761,14 @@ class ServingEngine:
         V]` float32) and the selection are device outputs of every step;
         what no sampler and no marked request will read is let go of here,
         when the step is enqueued, not held until its accept."""
-        extra = [k for k in ("routes", "selection") if k in io]
+        extra = [k for k in _EXTRA_FETCHES if k in io]
         nxt, lg, *rest = self._dispatch(kind, target, feed,
                                         self._step_fetches(io, logits))
         got = dict(zip(extra, rest))
         return {"tokens": nxt, "logits": None if greedy else lg,
                 "routes": got.get("routes"),
-                "selection": got.get("selection") if selection else None}
+                "selection": got.get("selection") if selection else None,
+                "exit_mass": got.get("exit_mass")}
 
     def _enqueued(self, step: _InFlight, blocking: str | None = None) -> None:
         """`step` has just been enqueued: accept the step dispatched before
@@ -1776,15 +1816,20 @@ class ServingEngine:
         tokens, logits, routes, selection = self._fetch(
             step.kind, (step.tokens, step.logits, step.routes,
                         step.selection))
+        # the one family that hands exit masses back reads them beside
+        exit_mass = None if step.exit_mass is None \
+            else self._fetch(step.kind, (step.exit_mass,))[0]
         if why is None:
             self._count("chain.steps_deferred")
         else:
             self._count("chain.steps_blocking", labels={"why": why})
         with obs.span("serving.accept"):
             if step.kind == "decode":
-                self._accept_decode(step, tokens, logits, routes, selection)
+                self._accept_decode(step, tokens, logits, routes, selection,
+                                    exit_mass)
             else:
-                self._accept_prefill(step, tokens, logits, routes, selection)
+                self._accept_prefill(step, tokens, logits, routes, selection,
+                                     exit_mass)
 
     def _corrupt_pool(self, hit: int) -> None:
         """The serving_pool_corrupt payload: vandalize ONE piece of
@@ -2033,10 +2078,15 @@ class ServingEngine:
                 # the shared pages already held
                 req.pages = matched
                 req.cached_len = len(matched) * self.page_size
+                # a row slot was free: the pool is what holds it
+                req.waited_for_pages = True
                 break
             req.pages = matched + private
             req.cached_len = len(matched) * self.page_size
             self._count("prefix_hit_tokens", req.cached_len)
+            if req.waited_for_pages:
+                req.waited_for_pages = False
+                self._count("pool_bound_admissions")
             self._waiting.remove(req)
             req.admit_seq = self._admit_seq
             self._admit_seq += 1
@@ -2110,7 +2160,7 @@ class ServingEngine:
         greedy token, the logits, the experts chosen where the block
         routes, and the positions attended where it selects them."""
         return [io["next_token"], io[logits]] + [
-            io[k] for k in ("routes", "selection") if k in io]
+            io[k] for k in _EXTRA_FETCHES if k in io]
 
     def _count_routed(self, per_expert) -> None:
         for e in np.flatnonzero(per_expert):
@@ -2257,6 +2307,10 @@ class ServingEngine:
         self._mark(req)
         req.slot = self._slots_free.pop()
         self._running.append(req)
+        if req.resumed:
+            # what a step had computed before the preemption, computed again
+            req.resumed = False
+            self._count("preempted_tokens", max(0, n - req.cached_len))
         if req.cached_len >= n:
             self._count("prefix_full_hits")
             self._register_prefix(req)
@@ -2360,6 +2414,7 @@ class ServingEngine:
                 self._count("prefill_tokens_computed", m)
                 self._count("prefill.chunks")
                 self._count_mixes(m)
+                self._count_visits()
                 if self.state_pool is not None:
                     self._count(f"{self._state_kind}.scan_tokens",
                                 m * self.cfg.state_layers)
@@ -2385,7 +2440,7 @@ class ServingEngine:
         return step
 
     def _accept_prefill(self, step: _InFlight, tokens, logits, routes,
-                        selection) -> None:
+                        selection, exit_mass=None) -> None:
         """A prefill's (or one chunk's) outputs, inside serving.accept."""
         req, (first, n) = step.rows[0], step.at[0]
         if routes is not None:
@@ -2394,6 +2449,8 @@ class ServingEngine:
         if selection is not None:
             self._keep_selection(req, selection[0, :n])
         if tokens is not None:
+            if exit_mass is not None:
+                req.exit_mass.append(exit_mass[0])
             self._accept_token(req, self._first_token(req, tokens, logits))
 
     def _register_prefix(self, req: GenRequest, upto: int | None = None
@@ -2581,6 +2638,7 @@ class ServingEngine:
         req.state = WAITING
         self._unmark(req)
         req.preemptions += 1
+        req.resumed = True
         self._count("preemptions")
         # head of the waiting queue: a preempted request lost work, so it
         # outranks new arrivals under fcfs
@@ -2674,6 +2732,16 @@ class ServingEngine:
                     q_shape, pool.shape, cfg.dtype, cfg.rope_head_dim)
         return runs
 
+    def _count_visits(self, rows: int = 0) -> None:
+        """One step program of a family whose layers a token passes several
+        times: every layer once a visit, and in a decode step of `rows`
+        rows as many row x visit pairs (one paged attention call's row
+        each)."""
+        if self.cfg.block == "looped_dense":
+            self._count("loop.visits", self.cfg.cache_planes)
+            self._count("loop.decode_row_visits",
+                        rows * self.cfg.cache_planes)
+
     def _count_mixes(self, tokens: int) -> None:
         """`tokens` tokens through every sub-layer's mix of a residual path
         of several streams (two a layer)."""
@@ -2740,6 +2808,7 @@ class ServingEngine:
                 pages[:len(rows)], pos[:len(rows)] + 1, pool_shape, itemsize)
         self._count("decode_context_pages", read["pages"])
         self._count("decode_grid_steps", read["blocks"])
+        self._count_visits(len(rows))
         if self.window_pool is not None:
             full, slide = self._full_layers, self._slide_layers
             W = self.cfg.sliding_window
@@ -2816,7 +2885,7 @@ class ServingEngine:
         return True
 
     def _accept_decode(self, step: _InFlight, tokens, logits, routes,
-                       selection) -> None:
+                       selection, exit_mass=None) -> None:
         """A decode step's outputs, inside serving.accept. A row that
         stopped on `eos_id` while this step was in flight ran it for
         nothing: its output is dropped."""
@@ -2829,6 +2898,11 @@ class ServingEngine:
         for j, i in enumerate(step.marked):
             if step.rows[i].state == RUNNING:
                 self._keep_selection(step.rows[i], selection[j][None])
+        if exit_mass is not None and live:
+            # what each visit's gate would let leave, over the rows' tokens
+            for t, mass in enumerate(exit_mass[live].sum(axis=0)):
+                self._count("loop.exit_mass", float(mass),
+                            labels={"visit": str(t + 1)})
         for i in live:
             r = step.rows[i]
             if r.sampling.is_greedy:
@@ -2837,6 +2911,8 @@ class ServingEngine:
                 rng = request_rng(self.seed, r.rid, r.n_generated)
                 t = sample_token(logits[i], r.sampling, rng)
             self._count("decode_tokens")
+            if exit_mass is not None:
+                r.exit_mass.append(exit_mass[i])
             self._accept_token(r, t)
 
     def _decode_spec(self, sp) -> bool:

@@ -1,4 +1,4 @@
-"""Serving program builders. EIGHT block families exist (the sixth in two
+"""Serving program builders. NINE block families exist (the sixth in two
 forms: behind an indexer, or read whole under several residual streams), and
 `DecoderConfig.block` selects one:
 
@@ -135,6 +135,23 @@ forms: behind an indexer, or read whole under several residual streams), and
     needs the pages and a snapshot at the same boundary. Weights are
     stacked by layer KIND and the layers run one after another. Prompts run
     in `prefill_chunk`-token windows as "sparse_moe"'s do.
+  * `"looped_dense"` (`ops/looped_dense_ops.py`; a looped language model's
+    layers): a plain pre-norm rotary multi-head SwiGLU stack whose every
+    sub-layer stands between TWO RMSNorms (before it and on its output),
+    and whose `num_layers` layers a token passes `loop_steps` TIMES, the
+    final norm closing every visit. The weights are stacked `[num_layers,
+    ...]` and stored once; visit `t` of layer `l` attends what the same
+    visit of the same layer wrote, so the stacked K/V pools hold
+    `loop_steps * num_layers` planes (`cfg.cache_planes`; plane `t *
+    num_layers + l`) and a page is that many slabs deep: copy-on-write,
+    the prefix cache's sharing and the audit answer for all of them
+    through the one page id. A gate reads the normed state after every
+    visit; the programs hand back the probability of leaving after each
+    (`exit_mass`, summing to 1) beside the logits and branch on nothing.
+    It reads num_kv_heads, attn_head_dim, rope_theta, rms_norm_eps,
+    loop_steps, prefill_chunk. One `lax.scan` over the visits around one
+    over the layers. Prompts run in `prefill_chunk`-token windows, each
+    window through every visit before the next, as "sparse_moe"'s do.
 
 Every family is expressed several times over ONE weight namespace:
 
@@ -171,7 +188,8 @@ from ..param_attr import ParamAttr
 from ..initializer import (BlockedNormal, Constant, Normal, StackedNormal,
                            Uniform)
 from ..ops import (cca_moe_ops, hybrid_moe_ops, kda_ops, latent_moe_ops,
-                   mixer_moe_ops, parallel_ssm_ops, sparse_moe_ops)
+                   looped_dense_ops, mixer_moe_ops, parallel_ssm_ops,
+                   sparse_moe_ops)
 from .kv_cache import (INDEX_POOL, JOINED_POOL, LATENT_POOL, STACKED_POOLS,
                        STATE_POOLS, WINDOW_POOLS, declare_pool_vars,
                        declare_stacked_pools, declare_state_pools,
@@ -180,7 +198,7 @@ from .kv_cache import (INDEX_POOL, JOINED_POOL, LATENT_POOL, STACKED_POOLS,
 __all__ = ["DecoderConfig", "decoder_tiny", "cca_moe_tiny",
            "sparse_moe_tiny", "hybrid_moe_tiny", "parallel_ssm_tiny",
            "latent_moe_tiny", "latent_streams_tiny", "mixer_moe_tiny",
-           "kda_moe_tiny", "layer_plan",
+           "kda_moe_tiny", "looped_dense_tiny", "layer_plan",
            "build_prefill_program",
            "build_decode_program", "build_window_program",
            "build_state_copy_program",
@@ -320,6 +338,10 @@ class DecoderConfig:
     layer_group_size: int = 0
     kda_sub_chunk: int = 16
     kda_lower_bound: float = -5.0
+    # "looped_dense" only (it also reads `num_kv_heads`, `attn_head_dim`,
+    # `rope_theta`, `rms_norm_eps`, `prefill_chunk`): how many times a token
+    # passes the `num_layers` layers, each visit with K/V pages of its own
+    loop_steps: int = 1
     # a deployment's choice, any family: the fewest rows a decode step is
     # compiled for (a power of two). Steps of fewer live rows pay for that
     # many; every row bucket below it is a program less to compile
@@ -378,6 +400,12 @@ class DecoderConfig:
                     "block 'mixer_moe' needs num_experts >= "
                     "experts_per_token >= 1 and 1 <= experts_held <= "
                     "num_experts")
+        if self.block == "looped_dense":
+            if min(self.loop_steps, self.prefill_chunk) < 1 \
+                    or self.num_heads % self.kv_heads or self.head_dim % 2:
+                raise ValueError(
+                    "block 'looped_dense' needs loop_steps, prefill_chunk, "
+                    "num_kv_heads dividing num_heads and an even head")
         if self.block == "kda_moe":
             held = self.experts_held or self.num_experts
             if min(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
@@ -527,7 +555,15 @@ class DecoderConfig:
         parallelism and the fleet handoff are not written for that form."""
         return self.block in ("cca_moe", "sparse_moe", "hybrid_moe",
                               "parallel_ssm", "latent_moe", "mixer_moe",
-                              "kda_moe")
+                              "kda_moe", "looped_dense")
+
+    @property
+    def cache_planes(self) -> int:
+        """Slabs of K/V a page id names in the stacked pools: one a layer,
+        or, where a token passes the layers `loop_steps` times
+        ("looped_dense"), one a VISIT of a layer."""
+        return self.num_layers * (self.loop_steps
+                                  if self.block == "looped_dense" else 1)
 
     @property
     def recurrent(self) -> bool:
@@ -578,7 +614,8 @@ class DecoderConfig:
             return self.num_layers - self.dense_layers
         if self.block == "mixer_moe":
             return self.layer_pattern.count(mixer_moe_ops.EXPERTS)
-        return 0 if self.block == "parallel_ssm" else self.num_layers
+        return 0 if self.block in ("parallel_ssm", "looped_dense") \
+            else self.num_layers
 
     @property
     def held_experts(self) -> int:
@@ -621,7 +658,13 @@ class DecoderConfig:
     def page_bucket_step(self) -> int:
         """0: page tables round up to a power of two. n: past n pages
         they round to a multiple of n, for a family whose every step scans
-        its whole table (the dead part stays under an eighth)."""
+        its whole table (the dead part stays under an eighth).
+        "looped_dense": 16 pages, whole grid steps of its paged decode
+        kernel at pages of 16, 32 or 64 tokens (16, 8 or 4 pages a step:
+        `paged_attention.pages_per_grid_step`), so its one page bucket is
+        the context cap's own width and not a block of dead steps wider."""
+        if self.block == "looped_dense":
+            return 16
         return 32 if self.selects or self.latent else 0
 
     @property
@@ -634,8 +677,14 @@ class DecoderConfig:
         a third of a microsecond. "parallel_ssm" compiles every layer once
         (a scan) but streams 7.8 GB of weights a step whatever the table's
         width: six page buckets would be six times the programs to warm
-        for nothing a step could gain."""
-        return self.windowed or self.recurrent
+        for nothing a step could gain. "looped_dense" is the family whose
+        POOL bounds the rows in flight: a row that lost its pages comes back
+        as a prompt of its own prompt and everything it had produced, at
+        lengths no arrival has, and under one width its windows are the
+        programs the arrivals' windows compiled (a window's length is its
+        only other shape)."""
+        return self.windowed or self.recurrent \
+            or self.block == "looped_dense"
 
 
 def decoder_tiny() -> DecoderConfig:
@@ -781,6 +830,16 @@ def kda_moe_tiny(**over) -> DecoderConfig:
     return DecoderConfig(**kw)
 
 
+def looped_dense_tiny(**over) -> DecoderConfig:
+    """Toy sizes of the "looped_dense" block for the CPU tests: three layers
+    visited three times, 4 heads of 8, prompts in chunks of 8."""
+    return DecoderConfig(**{**dict(
+        block="looped_dense", vocab_size=97, hidden_size=32, num_layers=3,
+        num_heads=4, num_kv_heads=4, attn_head_dim=8, ffn_size=64,
+        loop_steps=3, rope_theta=1e6, rms_norm_eps=1e-6, prefill_chunk=8,
+        max_position=128), **over})
+
+
 def _cca_geometry(cfg: DecoderConfig) -> dict:
     return {"num_heads": cfg.num_heads, "num_kv_heads": cfg.kv_heads,
             "head_dim": cfg.head_dim,
@@ -803,7 +862,8 @@ def stacked_pool_geometry(cfg: DecoderConfig, num_pages: int,
                           page_size: int) -> tuple:
     """`kv_cache.stacked_pool_shapes`' arguments for a scanned family."""
     geometry = {"sparse_moe": _sparse_pool_geometry,
-                "latent_moe": _latent_pool_geometry}.get(
+                "latent_moe": _latent_pool_geometry,
+                "looped_dense": _looped_pool_geometry}.get(
                     cfg.block, _cca_pool_geometry)
     return geometry(cfg, num_pages, page_size)
 
@@ -2052,6 +2112,138 @@ def _kda_cow(cfg, num_pages, page_size, src, dst, state_slots=0):
     _stacked_copy_page([LATENT_POOL], num_pages, src, dst)
 
 
+# -- the "looped_dense" family -----------------------------------------------
+
+
+def _looped_geometry(cfg: DecoderConfig) -> dict:
+    return {"num_heads": cfg.num_heads, "num_kv_heads": cfg.kv_heads,
+            "head_dim": cfg.head_dim, "rope_theta": float(cfg.rope_theta),
+            "eps": float(cfg.rms_norm_eps), "loop_steps": cfg.loop_steps}
+
+
+def _looped_pool_geometry(cfg: DecoderConfig, num_pages: int,
+                          page_size: int):
+    # a plane a VISIT of a layer: `cache_planes` where the others say layers
+    return (cfg.cache_planes, num_pages, page_size,
+            cfg.kv_heads * cfg.head_dim, 0, cfg.dtype)
+
+
+def _looped_param_specs(cfg: DecoderConfig) -> dict:
+    """name -> (shape, dtype, initializer), layers stacked on the leading
+    axis and stored ONCE whatever `loop_steps` is. Every sub-layer's output
+    is normed before it joins the residual, so a matrix's scale decides
+    only what its own product looks like: W_q at 2 and W_k at 1 over
+    fan_in^0.5 (attention logits of standard deviation 2 after the division
+    by sqrt(head_dim)), everything else at fan_in^-0.5, the head at 2.5
+    (logits of standard deviation 2.5 on a state of RMS 1). The gains of the
+    two norms BEHIND a sub-layer are drawn around 0.25, not 1: a sub-layer
+    then adds a quarter of the state's size, and 384 sub-layers a token
+    carry a bfloat16 rounding to the logits without blowing it up (on the
+    chip at the served sizes, the bfloat16 engine against the float32
+    reference: worst logit gap 0.19-0.69 under gains of 1, 0.24-0.44 under
+    0.5, 0.02-0.14 under 0.25, where float8 weights read 3.4-3.8 and either
+    planted fault 5.7-9.9; my chip run, PR 53), and the 96 sub-layers of a
+    visit still make 86% of the state that leaves it. The gate's
+    weights are drawn at fan_in^-0.5 around a bias of -1: a probability of
+    stopping of 0.1-0.5 a visit, so that every visit's share of the exit
+    mass is a number a wrong gate moves. The large ones in `cfg.dtype`;
+    norms and the gate in float32."""
+    L, H, F, V = cfg.num_layers, cfg.hidden_size, cfg.ffn_size, \
+        cfg.vocab_size
+    nh, nkv, dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    f32, big = "float32", cfg.dtype
+    near_one, behind = Normal(1.0, 0.05), Normal(0.25, 0.0125)
+    root = H ** -0.5
+    return {
+        "dec.word_emb": ([V, H], big, BlockedNormal(
+            1.0, block_rows=_draw_rows(V))),
+        "dec.lm_head": ([H, V], big, BlockedNormal(
+            2.5 * root, block_rows=_draw_rows(H))),
+        "dec.final_norm.scale": ([H], f32, near_one),
+        "dec.exit_gate.w": ([H], f32, Normal(0.0, root)),
+        "dec.exit_gate.b": ([1], f32, Constant(-1.0)),
+        "attn_norm": ([L, H], f32, near_one),
+        "attn_post_norm": ([L, H], f32, behind),
+        "wqkv": ([L, H, (nh + 2 * nkv) * dh], big, BlockedNormal(columns=[
+            (nh * dh, 2.0 * root), (nkv * dh, root), (nkv * dh, root)])),
+        "wo": ([L, nh * dh, H], big, BlockedNormal((nh * dh) ** -0.5)),
+        "ffn_norm": ([L, H], f32, near_one),
+        "ffn_post_norm": ([L, H], f32, behind),
+        "w_gate_up": ([L, H, 2 * F], big, BlockedNormal(root)),
+        "w_down": ([L, F, H], big, BlockedNormal(F ** -0.5)),
+    }
+
+
+def _looped_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
+                  page_size: int = 0, **feeds):
+    """Append the one `looped_dense_stack` op of a program; returns its
+    outputs (next_token, logits, exit_mass)."""
+    helper = LayerHelper("looped_dense_stack")
+    params = {key: helper.create_parameter(
+        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
+        for key, (shape, dtype, init) in _looped_param_specs(cfg).items()}
+    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
+              "Head": [params["dec.lm_head"]],
+              "FinalNorm": [params["dec.final_norm.scale"]],
+              "GateW": [params["dec.exit_gate.w"]],
+              "GateB": [params["dec.exit_gate.b"]],
+              "LayerParams": [params[k]
+                              for k in looped_dense_ops.LAYER_PARAMS]}
+    inputs.update({slot: [var] for slot, var in feeds.items()})
+    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
+            for slot, dtype in (("NextToken", "int32"), ("Logits", "float32"),
+                                ("ExitMass", "float32"))}
+    if mode != "full":
+        declare_stacked_pools(default_main_program().global_block,
+                              *_looped_pool_geometry(cfg, num_pages,
+                                                     page_size))
+        for slot, name in zip(("KPool", "VPool"), STACKED_POOLS):
+            inputs[slot] = [name]
+            outs[slot + "Out"] = [name]
+    helper.append_op("looped_dense_stack", inputs, outs,
+                     dict(_looped_geometry(cfg), mode=mode,
+                          num_pages=int(num_pages)))
+    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
+            "exit_mass": outs["ExitMass"][0]}
+
+
+def _looped_window_io(out):
+    return {"next_token": out["next_token"], "last_logits": out["logits"],
+            "exit_mass": out["exit_mass"]}
+
+
+def _looped_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
+    return _looped_window_io(_looped_stack(
+        cfg, "prefill", tok, pos, num_pages, page_size, PageTable=pages,
+        Lens=lens))
+
+
+def _looped_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
+                   lens):
+    # a prompt's chunk, the suffix behind a cached prefix, or a preempted
+    # row's way back (no verify window)
+    return _looped_window_io(_looped_stack(
+        cfg, "window", tok, pos, num_pages, page_size, PageTable=pages,
+        Start=start, Lens=lens))
+
+
+def _looped_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
+    return _looped_stack(cfg, "decode", tok, pos, num_pages, page_size,
+                         PageTable=pages, Mask=mask)
+
+
+def _looped_full(cfg, tok, pos):
+    out = _looped_stack(cfg, "full", tok, pos)
+    return {"logits": out["logits"], "exit_mass": out["exit_mass"]}
+
+
+def _looped_cow(cfg, num_pages, page_size, src, dst):
+    # the page's K/V slab in EVERY plane: each visit of each layer
+    declare_stacked_pools(default_main_program().global_block,
+                          *_looped_pool_geometry(cfg, num_pages, page_size))
+    _stacked_copy_page(STACKED_POOLS[:2], num_pages, src, dst)
+
+
 def build_state_copy_program(cfg: DecoderConfig, num_pages: int,
                              page_size: int, state_slots: int):
     """Build (in the current default main program) the copy of one slot of
@@ -2521,4 +2713,7 @@ _FAMILY = {
                   "full": _mixer_full},
     "kda_moe": {"prefill": _kda_prefill, "window": _kda_window,
                 "cow": _kda_cow, "decode": _kda_decode, "full": _kda_full},
+    "looped_dense": {"prefill": _looped_prefill, "window": _looped_window,
+                     "cow": _looped_cow, "decode": _looped_decode,
+                     "full": _looped_full},
 }

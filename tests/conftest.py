@@ -81,9 +81,25 @@ PINS_PR43S_TAIL = ("test_benchmark_rehearse_nemotron.py::"
 PINS_PR47S_TAIL = ("test_benchmark_rehearse_xing.py::"
                    "test_the_cell_before_keeps_every_list_it_joined")
 
+# PR 51's test of the cells before its own pins the END of the lists they
+# joined (`lists[-1] == order[-1]`) and the last three cells of `sat_tok_s`,
+# behind which the contract tells every later PR to append: the same case a
+# fifth time (PR 53 appended a cell and a configuration and joined fourteen
+# lists). What it asserts besides the tails (the lists each of the three
+# cells stands in, what each moves, nothing between them, the chips) is
+# asserted again, for the three and the new one, by
+# tests/benchmark/test_benchmark_rehearse_ouro.py, which pins no tail.
+PINS_PR51S_TAIL = ("test_benchmark_rehearse_ling.py::"
+                   "test_the_cells_before_keep_every_list_they_joined")
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(PINS_PR51S_TAIL):
+            item.add_marker(pytest.mark.xfail(
+                reason="pins the tails of the lists PR 43's and PR 47's "
+                       "cells joined to PR 51's; PR 53 appended behind them",
+                strict=True))
         if item.nodeid.endswith(PINS_PR47S_TAIL):
             item.add_marker(pytest.mark.xfail(
                 reason="pins the tails of the lists PR 43's cell joined to "

@@ -289,6 +289,15 @@ CASES = [
         "paged_latent_attend": "paged_latent_attention f32[64,32,512]"},
         [(t, {"experts": f"moe_topk_experts_prefill f32[{t},2560]"})
          for t in (128, 2048)]),
+    # ouro_2_6b.reason.sat (8, 16, 32 rows): 16 query heads over 16 KV heads
+    # of 128 in bfloat16 pages of 16 tokens. One head a KV head is the
+    # MULTI-HEAD arm (the heads side by side in the lanes, `nh * dh` the
+    # pool's width; "post_ln" runs it in float32), not the grouped one,
+    # whose gate wants fewer KV heads than query heads and pages of whole
+    # 128-token lane rows: 192 calls a step under the name
+    # `paged_decode_attention`, each over a plane of its own
+    *[("ouro_2_6b", "full_attention", "decode", rows,
+       f"paged_decode_attention f32[{rows},1,2048]") for rows in (8, 32)],
     # bert_base.s128 (and .dp4: the same rows a chip) and .s512
     ("bert_base", "attention", "train", (128, 128), "xla"),
     ("bert_base", "attention", "train", (32, 512), "xla"),
@@ -319,6 +328,7 @@ CASES = [
     *_serving("rehearse_ling", 4,
               {"kda_update": "xla", "conv_update": "xla", "experts": "xla",
                "paged_latent_attend": "xla"}, [(8, {"experts": "xla"})]),
+    ("rehearse_ouro", "full_attention", "decode", 4, "xla"),
 ]
 
 

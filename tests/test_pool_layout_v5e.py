@@ -765,6 +765,94 @@ def test_kda_moe_stack_compiled_for_v5e_moves_no_pool(v5e_chip, monkeypatch):
     assert temp["decode"] < 400e6 and temp["window2048"] < 1.6e9, temp
 
 
+def test_looped_dense_stack_compiled_for_v5e_moves_no_plane(v5e_chip,
+                                                            monkeypatch):
+    """The "looped_dense" stack at the served sizes (benchmark/configs/
+    ouro_2_6b.json: ALL 48 layers of hidden 2,048, 16 heads of 128, four
+    visits), over pools as large as the cell's (192 planes of 296 pages of
+    16 tokens: 56,832 rows of 2,048 bfloat16 values a pool, 3.72 GB each),
+    from SHAPES alone: the decode step at 32 rows and a 512-token window,
+    behind the cell's 80-page tables. The layer's body is compiled ONCE
+    (one call of the multi-head paged kernel in a decode program of 192
+    layer visits), Mosaic takes that kernel at one head a KV head in
+    bfloat16 pages of 16, and neither program copies a pool: the visits'
+    planes are written through both loops in place."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops import looped_dense_ops as ops
+    from paddle_tpu.ops.pallas_kernels import workbench
+    from paddle_tpu.serving import kv_cache
+    from paddle_tpu.serving import model as sv_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ouro_2_6b.json")) as f:
+        engine = json.load(f)["engine"]
+    cfg = DecoderConfig(**engine["config_kwargs"])
+    pages, ps = engine["pool_pages"], engine["page_size"]
+    assert (cfg.num_layers, cfg.loop_steps, cfg.cache_planes, ps) \
+        == (48, 4, 192, 16)
+    one_chip = SingleDeviceSharding(v5e_chip)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(tuple(dims), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {key: shape(dims, dtype) for key, (dims, dtype, _) in
+              sv_model._looped_param_specs(cfg).items()}
+    pools = tuple(shape(dims, dtype) for _, dims, dtype in
+                  kv_cache.stacked_pool_shapes(
+                      *sv_model.stacked_pool_geometry(cfg, pages, ps)))
+    assert [(p.shape, str(p.dtype)) for p in pools] \
+        == [((192 * pages, 16, 2048), "bfloat16")] * 2
+    geom = ops.Geometry(**sv_model._looped_geometry(cfg))
+    weights = (params["dec.word_emb"], params["dec.lm_head"],
+               params["dec.final_norm.scale"], params["dec.exit_gate.w"],
+               params["dec.exit_gate.b"],
+               {k: params[k] for k in ops.LAYER_PARAMS})
+    table = -(-cfg.max_position // ps)
+    monkeypatch.setattr(workbench, "on_tpu", lambda: True)
+
+    def compiled(mode, tok_shape, rows):
+        def step(tok, pos, weights, pools, table, lens, start, mask):
+            return ops.looped_dense_stack_fn(
+                mode, tok, pos, *weights, geom, pools=pools,
+                page_table=table, lens=lens, start=start, mask=mask,
+                num_pages=pages)
+
+        i32 = "int32"
+        return jax.jit(step, donate_argnums=(3,)).lower(
+            shape(tok_shape, i32), shape(tok_shape, i32), weights, pools,
+            shape((rows, table), i32), shape((rows,), i32),
+            shape((rows,), i32), shape((rows, 1), "float32")).compile()
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        programs = {"decode": compiled("decode", (32,), 32),
+                    "window512": compiled("window", (1, 512), 1)}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    texts = {name: c.as_text() for name, c in programs.items()}
+    values = 192 * pages * ps * 2048
+    for name, text in texts.items():
+        assert not pool_sized_copies(text, values), name
+        assert not updates_out_of_place(text, values), name
+    # 192 layer visits, ONE copy of the layer's body
+    assert kernel_calls(texts["decode"], "paged_decode_attention") == 1
+    assert kernel_calls(texts["window512"], "paged_decode_attention") == 0
+    # weights once and pools: 12.8 GB of arguments, 5.34 of them parameters
+    args = programs["decode"].memory_analysis().argument_size_in_bytes
+    assert 12.7e9 < args < 12.9e9, args
+
+
 def test_token_row_gathers_counts_rows_not_slabs():
     """Recorded from the v5e's compiler: PR 29's decode layer fetched a
     selected token from two pools, PR 30's from one; a page's slab of

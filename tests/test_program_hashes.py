@@ -43,6 +43,13 @@ form (`moe_experts._grouped_call`: a `top_k`, a `sort`, a row gather and a
 expert over every token tile). Every decode line and every other prefill
 line (windows of up to 256 rows, and the families without the gated arm)
 stands.
+
+PR 53 added TWO lines and recomputed none: the new `ouro_2_6b`'s decode step
+and 512-token window (the "looped_dense" family: a ninth op, pieces and
+counters of its own; `engine._step_fetches` asks for an `exit_mass` only of
+a program that has one). The eight other families' twenty lines are what
+they were at the parent (commit c1b9e0e), which is also what keeps their
+warm compile cache.
 """
 import hashlib
 from unittest import mock
@@ -76,6 +83,8 @@ HASHES = {
     ("xing4_29b_a4b", "prefill", "2048"): "41d5e91d20e55186",
     ("ling3_flash", "decode", "256"): "c9c90a4a169294bd",
     ("ling3_flash", "prefill", "2048"): "368abb70195f5e36",
+    ("ouro_2_6b", "decode", "32"): "d8b16d35bfc87f55",
+    ("ouro_2_6b", "prefill", "512"): "d743f065c1e242e3",
 }
 
 

@@ -896,6 +896,69 @@ def _paged_latent_cases(spec):
         for B, P in ((16, 288), (64, 32))]
 
 
+def _paged_looped_step_cases(spec):
+    """The multi-head arm at the looped model's decode step, TIMED (ten
+    calls after the first): 16 query heads over 16 KV heads of 128 (one
+    head a KV head, the heads side by side in the lanes of a 2,048-wide
+    row), bfloat16 pages of 16 tokens, 20 live rows of a bucket of 32
+    behind one of two 256-token prompts and 40-300 tokens of their own,
+    under an 80-page table, in plane 100 of a pool of 192 planes of 296
+    pages (the cell's: 3.72 GB a pool, so the rows a call reads lie where
+    a step's lie). `us` is one of a step's 192
+    calls; `roofline_pct` the K and V bytes of the attended tokens against
+    819 GB/s; the first eight rows against the reference."""
+    import time
+
+    def case():
+        B, live, nh, dh, ps, pages, planes, plane = 32, 20, 16, 128, 16, \
+            296, 192, 100
+        P = 1280 // ps
+        ks = jax.random.split(jax.random.PRNGKey(53), 3)
+        q = _rand(ks[0], (B, nh, dh), "float32")
+        # drawn in bfloat16: a float32 draw of one pool is 7.4 GB
+        kp, vp = (jax.random.normal(k, (planes * pages, ps, nh * dh),
+                                    jnp.bfloat16) for k in ks[1:])
+        rng = np.random.default_rng(53)
+        # two 256-token prompts (16 pages each) that the rows stand behind,
+        # and 40-300 tokens of a row's own: 3.9k of the pool's 4.7k tokens
+        own = rng.integers(40, 300, live)
+        lens = np.zeros((B,), np.int32)
+        lens[:live] = 256 + own
+        table = np.zeros((B, P), np.int32)
+        free = rng.permutation(pages - 32) + 32
+        at = 0
+        for b in range(live):
+            n = -(-int(own[b]) // ps)
+            table[b, :16] = plane * pages + (b % 2) * 16 + np.arange(16)
+            table[b, 16:16 + n] = plane * pages + free[at:at + n]
+            at += n
+        assert at <= pages - 32
+        args = (q, kp, vp, jnp.asarray(table), jnp.asarray(lens))
+        assert spec.supported(q.shape, kp.shape, "bfloat16")
+        scale = dh ** -0.5
+        fn = jax.jit(lambda *a: spec.fn(*a, sm_scale=scale))
+        got = jax.block_until_ready(fn(*args))
+        t = time.perf_counter()
+        for _ in range(10):
+            last = fn(*args)
+        jax.block_until_ready(last)
+        call_s = (time.perf_counter() - t) / 10
+        with jax.default_matmul_precision("highest"):
+            want = spec.reference(q[:8], kp, vp, args[3][:8], args[4][:8],
+                                  sm_scale=scale)
+        tokens = int(lens.sum())
+        res = {"err": _rel_err(got[:8], want), "tol": 2e-2,
+               "finite": bool(np.isfinite(np.asarray(got[:live])).all()),
+               "us": call_s * 1e6, "tokens": tokens,
+               "roofline_pct": tokens * 2 * nh * dh * 2 / call_s / 819e9
+               * 100}
+        res["ok"] = bool(res["finite"] and res["err"] <= res["tol"])
+        return res
+
+    return [("b32 (20 live) nh16 nkv16 dh128 ps16 bfloat16 table 80 plane "
+             "100 of 192 timed", case)]
+
+
 CASES = {
     "paged_latent_attention": _paged_latent_cases,
     "latent_rows_attention": _latent_attend_cases,
@@ -905,7 +968,8 @@ CASES = {
     "kda_decode_update": _kda_update_cases,
     "attention_paged_decode": lambda spec: (_paged_cases(spec)
                                             + _paged_gqa_cases(spec)
-                                            + _paged_gqa_step_cases(spec)),
+                                            + _paged_gqa_step_cases(spec)
+                                            + _paged_looped_step_cases(spec)),
     "moe_top1_experts": _moe_cases,
     "moe_relu2_experts": _moe_relu2_cases,
     # bench_bert_long: b64 s512
