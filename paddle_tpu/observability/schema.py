@@ -506,6 +506,11 @@ DECLARED: list[tuple] = [
     ("serving.pool_bound_admissions", COUNTER,
      "admissions that had waited for pages while a row slot was free: the "
      "pool, not max_inflight, held them", ()),
+    ("serving.growth_held_admissions", COUNTER,
+     "admissions whose request had waited at the head of the queue although "
+     "its prompt's pages were free: the pages the running rows have yet to "
+     "take to their known ends (prompt_len + max_new_tokens) were reserved "
+     "first", ()),
     # -- the host's own pauses (observability/registry._GcWatch) -------------
     ("host.gc.collections", COUNTER,
      "garbage collections by generation", ("generation",)),

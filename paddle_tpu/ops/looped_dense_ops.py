@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from .attention_ops import (_gather_pages, _write_rows, kv_cache_append_fn,
-                            paged_decode_attention_fn)
+                            paged_decode_attention_fn, paged_decode_plan_fn)
 from .cca_moe_ops import _page_row_index, rms_norm_fn
 from .hybrid_moe_ops import _mm, causal_attention_fn, yarn_inv_freq_fn
 from ..observability.schema import piece, under_mode
@@ -135,6 +135,10 @@ def looped_dense_stack_fn(mode: str, tok, pos, emb, head, final_norm, gate_w,
             else rel < lens[:, None]
     if mode == "window":
         last = jnp.clip(lens - 1, 0, S - 1)[:, None, None]
+    # a decode step's list of page blocks is its table's alone, the same
+    # for every plane: worked out once, outside the loops
+    plan = paged_decode_plan_fn((B, nh, dh), _F32, pools[0], page_table,
+                                first + 1) if decode else None
 
     def layer(carry, xs):
         plane, p = xs
@@ -166,7 +170,7 @@ def looped_dense_stack_fn(mode: str, tok, pos, emb, head, final_norm, gate_w,
                 with piece("attend"):
                     o = paged_decode_attention_fn(
                         q[:, 0], k_pool, v_pool, table, first + 1,
-                        sm_scale=sm_scale)[:, None]
+                        sm_scale=sm_scale, plan=plan)[:, None]
             else:
                 with piece("kv_write"):
                     idx = _page_row_index(page_table, gpos, page_size, off,

@@ -32,23 +32,39 @@ gather IS the decode step's HBM bill. This kernel never materializes it:
     padded in (kv_len 0) run nothing and emit zeros nobody reads — the
     batch_mask convention from PR 2.
   * heads never leave the lanes: a token's row holds head h in lanes
-    h*dh..(h+1)*dh. With as many KV heads as query heads, q.k is one
-    [tokens, nh*dh] float32 VPU product a 128-lane column, and the per-head
-    sum is an MXU product of that column with a 0/1 matrix that joins the
-    lanes of one head (`_head_sums`): it leaves every lane holding its
-    head's score. The product is split into the three bfloat16 pieces that
-    hold every bit of a float32 and the MXU accumulates in float32, so the
-    sum is the float32 sum of the same dh terms: nothing is rounded to
-    bfloat16 (a butterfly of lane rotations did this until PR 38 and was
-    73% of the call). With fewer KV heads (grouped-query, dh = 128) a head
-    is a whole register and q.k, p.v are MXU products of float32 exactness
-    (`_dot3`).
+    h*dh..(h+1)*dh. TWO ARMS, chosen by the shapes a call sees
+    (`matrix_unit_arm`):
+      - the VECTOR-UNIT arm (`_chunk_update`): as many KV heads as query
+        heads and a head NARROWER than a lane register (the BERT decoder's
+        12 heads of 64, device op `paged_decode_attention f32[rows,1,768]`;
+        also heads of 128 that fill no sublane tile of 8 or whose table's
+        chunk fills no lane row). q.k is one [tokens, nh*dh] float32 VPU
+        product a 128-lane column, and the per-head sum is an MXU product
+        of that column with a 0/1 matrix that joins the lanes of one head
+        (`_head_sums`): it leaves every lane holding its head's score. The
+        product is split into the three bfloat16 pieces that hold every
+        bit of a float32 and the MXU accumulates in float32, so the sum is
+        the float32 sum of the same dh terms: nothing is rounded to
+        bfloat16 (a butterfly of lane rotations did this until PR 38 and
+        was 73% of the call).
+      - the MATRIX-UNIT arm (`_gqa_update`): a head IS a whole register
+        (dh = 128), so a KV head's lanes are one operand tile as they lie
+        and q.k, p.v are MXU products of float32 exactness (`_dot3`: the
+        pages as they lie, the float32 queries and probabilities in the
+        three pieces that keep every bit, float32 accumulation and softmax
+        state). Every grouped-query call (fewer KV heads than query heads:
+        ZAYA1, Laguna, Falcon-H1, Nemotron; `paged_decode_attention_gqa`,
+        `paged_window_attention_gqa`) and, since PR 55, a call with ONE
+        query head a KV head of 128 (Ouro's 16 over 16 in bfloat16 pages of
+        16 tokens; it keeps the name `paged_decode_attention`, the output
+        `f32[rows,16,128]`, a head a sublane row): on the vector unit that
+        call ran at 24% of the memory bandwidth, 45% of the cell's device.
     The online softmax state (m, l, acc; the per-head statistics repeated
     over the head's lanes) lives in VMEM scratch across the blocks of one
     row; the output block is written once, on the row's last grid step.
 
-THE GROUPED-QUERY ARM WITHOUT A FIRST LIVE SLOT WALKS A LIST, NOT A GRID
-(PR 50; `_walk_kernel`, the same name `paged_decode_attention_gqa`). Decode
+THE MATRIX-UNIT ARM WITHOUT A FIRST LIVE SLOT WALKS A LIST, NOT A GRID
+(PR 50; `_walk_kernel`, under the grid's name for the call). Decode
 rows that stand behind one context hold the SAME page ids in their tables
 (the prefix cache hands them out), and the grid above fetches and scores
 such a page once a row. Here:
@@ -69,7 +85,7 @@ such a page once a row. Here:
     128]` by sorted row, so that a tile of `T` rows x one KV head's query
     heads is one window of sublanes.
   * a SHARED block is scored by tiles of `T` sorted rows (`tile_rows`: 16
-    at 4 to 8 heads a KV head, 8 at 16), a group's last rows by a half
+    at 1 to 8 heads a KV head, 8 at 16), a group's last rows by a half
     tile: for KV head j the tile's `T x heads_per_kv` query rows, their
     three bfloat16 pieces stacked (`_stack3`), against `k[:, j]` and `v[:,
     j]`, the same `_dot3` products as `_gqa_update`'s, every row of a
@@ -128,7 +144,8 @@ def paged_supported(q_shape, pool_shape, pool_dtype=jnp.float32) -> bool:
     whole tiles ((8, 128) of float32, (16, 128) of bfloat16: page_size a
     multiple of the sublane count, the row of 128) and modest enough to
     double-buffer in VMEM. With nkv == nh a head must sit inside one
-    128-lane register (dh a power of two up to 128); with fewer KV heads
+    128-lane register (dh a power of two up to 128; which arm such a call
+    takes is `matrix_unit_arm`'s to say); with fewer KV heads
     than query heads (grouped-query) a head is one register (dh 128), the
     query heads fill whole sublane tiles and a page fills whole lanes of
     the score tile. Everything else (the CPU rehearsal geometry, most unit
@@ -184,6 +201,33 @@ def grid_steps(rows: int, bucket_pages: int, page_size: int, width: int,
     """Grid steps of one call: rows x page blocks."""
     group = pages_per_grid_step(bucket_pages, page_size, width, itemsize)
     return rows * -(-bucket_pages // group)
+
+
+def _chunk_pages(group: int, page_size: int, chunk_tokens: int) -> int:
+    """Pages of one online-softmax update: a divisor of the block's `group`
+    pages that holds `chunk_tokens` slots at most."""
+    return math.gcd(group, max(1, chunk_tokens // page_size))
+
+
+def matrix_unit_arm(q_shape, pool_shape, pool_dtype, bucket_pages) -> bool:
+    """Which arm a supported call takes, from its shapes alone: True where
+    q.k and p.v are products on the matrix unit (`_gqa_update`), False
+    where the heads lie side by side in the lanes and the products stay on
+    the vector unit (`_chunk_update`). Every grouped-query call takes the
+    matrix unit (the only arm it has). With as many KV heads as query
+    heads it is taken where a head IS a whole 128-lane register (dh 128:
+    a KV head's lanes are one operand tile as they lie), the query heads
+    fill whole sublane tiles and a chunk of the call's page blocks fills
+    whole lanes of the score tile; a narrower head (12 heads of 64: two
+    heads a register) keeps the vector unit."""
+    _, nh, dh = q_shape
+    _, ps, width = pool_shape
+    if nh * dh != width:
+        return True
+    group = pages_per_grid_step(bucket_pages, ps, width,
+                                jnp.dtype(pool_dtype).itemsize)
+    return (dh == _LANES and nh % 8 == 0
+            and _chunk_pages(group, ps, MXU_CHUNK_TOKENS) * ps % _LANES == 0)
 
 
 def _bf16_pieces(x, n):
@@ -327,7 +371,7 @@ def _kernel(pt_ref, kl_ref, nxt_ref, *refs, chunk_update, chunk_tokens,
     i = pl.program_id(1)
     kv_len = kl_ref[b]
     first = i * (group * page_size)              # the block's first slot
-    chunk = math.gcd(group, max(1, chunk_tokens // page_size))  # pages
+    chunk = _chunk_pages(group, page_size, chunk_tokens)
     tokens = chunk * page_size
 
     @pl.when((b == 0) & (i == 0))
@@ -456,9 +500,10 @@ def _gqa_update(q, k, v, n_live, m_prev, l_prev, acc_prev, n_dead=None, *,
 
 def _gqa_chunk_update(q_ref, k_ref, v_ref, n_live, m_ref, l_ref, acc_ref, *,
                       sm_scale, num_kv_heads, n_dead=None):
-    """Grouped-query twin of `_chunk_update`: `nh` query heads (sublanes
-    of one [nh, dh] tile) over `nkv` KV heads of dh = 128 lanes. A head is
-    a whole register here, so the chunk is one update on the MXU."""
+    """The matrix-unit twin of `_chunk_update`: `nh` query heads (sublanes
+    of one [nh, dh] tile) over `nkv` KV heads of dh = 128 lanes (fewer than
+    `nh`, or as many). A head is a whole register here, so the chunk is
+    one update on the MXU."""
     pages, ps, width = k_ref.shape
     dead = () if n_dead is None else (n_dead,)
     m_ref[...], l_ref[...], acc_ref[...] = _gqa_update(
@@ -474,14 +519,14 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret,
     B, nh, dh = q.shape
     num_pages, ps, width = k_pool.shape
     P = page_table.shape[1]
-    grouped = nh * dh != width
+    grouped = matrix_unit_arm(q.shape, k_pool.shape, k_pool.dtype, P)
     group = pages_per_grid_step(P, ps, width, k_pool.dtype.itemsize)
     blocks = -(-P // group)
     kv_lens = kv_lens.astype(jnp.int32)
     windowed = first_live is not None
     if windowed and not grouped:
         raise NotImplementedError(
-            "a first live slot is written for the grouped-query arm only")
+            "a first live slot is written for the matrix-unit arm only")
     if windowed:
         # the pages wholly before a row's first live slot leave its table:
         # the table turns left by that many entries, and the lengths with it
@@ -544,8 +589,11 @@ def _call(q, k_pool, v_pool, page_table, kv_lens, sm_scale, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
+        # a call with a KV head a query head keeps the name a device trace
+        # knows it by, whichever arm its shapes take (literal names: the
+        # reductions and `test_every_pallas_call_names_its_kernel` grep them)
         name="paged_window_attention_gqa" if windowed
-        else "paged_decode_attention_gqa" if grouped
+        else "paged_decode_attention_gqa" if nh * dh != width
         else "paged_decode_attention",
     )(page_table, kv_lens, nxt, *((first_live,) if windowed else ()),
       q.reshape((B,) + row_shape[1:]).astype(out_dtype), k_pool, v_pool)
@@ -604,8 +652,8 @@ def tile_rows(heads_per_kv: int) -> tuple:
 
 
 def walk_supported(q_shape, pool_shape, pool_dtype, bucket_pages) -> bool:
-    """Whether the list-walking form serves a grouped-query call without a
-    first live slot: q [B, nh, dh] over `pool_shape` behind tables of
+    """Whether the list-walking form serves a call of the matrix-unit arm
+    without a first live slot: q [B, nh, dh] over `pool_shape` behind tables of
     `bucket_pages`. The table is two blocks wide or wider (a narrower one
     cannot hold a shared block and a row's own page behind it, and its grid
     has one step a row: the step's plan and the walk's longer code would
@@ -613,12 +661,12 @@ def walk_supported(q_shape, pool_shape, pool_dtype, bucket_pages) -> bool:
     latent kernel's), and the
     rows' queries, outputs and running sums stay resident; everything else
     (a short table, thousands of rows) keeps the grid over (row, block)."""
-    if not paged_supported(q_shape, pool_shape, pool_dtype):
+    if not (paged_supported(q_shape, pool_shape, pool_dtype)
+            and matrix_unit_arm(q_shape, pool_shape, pool_dtype,
+                                bucket_pages)):
         return False
     B, nh, dh = q_shape
     _, ps, width = pool_shape
-    if nh * dh == width:
-        return False
     group = pages_per_grid_step(bucket_pages, ps, width,
                                 jnp.dtype(pool_dtype).itemsize)
     rows = max(B, tile_rows(nh // (width // dh))[0])
@@ -740,7 +788,7 @@ def _walk_kernel(pt_ref, work_ref, n_ref, qt_ref, qr_ref, k_hbm, v_hbm, o_ref,
     nkv, _, dh = qt_ref.shape
     rows = qt_ref.shape[1] // heads_per_kv
     slots = work_ref.shape[0] // len(_FIELDS)
-    chunk = math.gcd(group, max(1, MXU_CHUNK_TOKENS // page_size))  # pages
+    chunk = _chunk_pages(group, page_size, MXU_CHUNK_TOKENS)
     tokens = chunk * page_size
     blocks = n_ref[0]
     pools, bufs = (k_hbm, v_hbm), (k_buf, v_buf)
@@ -938,7 +986,8 @@ def _walk_call(q, k_pool, v_pool, page_table, plan, sm_scale, interpret):
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=WALK_VMEM_LIMIT),
         interpret=interpret,
-        name="paged_decode_attention_gqa",
+        name="paged_decode_attention_gqa" if nh * dh != width
+        else "paged_decode_attention",
     )(table, plan.work, plan.blocks, tiles, q, k_pool, v_pool)
 
 

@@ -4,10 +4,12 @@ Requests arrive at any time, and the engine admits/evicts them BETWEEN
 decode steps instead of running fixed generation batches:
 
     step():  (maybe) inject a chaos abort -> admit waiting requests while
-             pages + inflight slots allow (prefix-cache hits map shared
-             pages, then prefill ONLY the uncached suffix, bucketed) ->
-             grow/allocate/copy-on-write pages for the next write window
-             (preempting the youngest request on pool exhaustion) -> one
+             inflight slots allow and the pool can carry every running
+             row, and the newcomer, to its known end (prefix-cache hits map
+             shared pages, then prefill ONLY the uncached suffix, bucketed)
+             -> grow/allocate/copy-on-write pages for the next write window
+             (the pages admission reserved; the youngest request preempted
+             only where a pool runs dry all the same) -> one
              ragged decode step over ALL running requests (a k-token
              draft-verify window when speculative decoding is on) ->
              retire finished rows.
@@ -147,16 +149,19 @@ K/V pools hold `cfg.cache_planes` = loop_steps x layers slabs a page id, so
 everything the host does by page id (allocate, share, copy-on-write, release,
 the audit, the leak count) answers for every visit of every layer at once,
 and a token's cache is that many rows: the POOL, not `max_inflight`, bounds
-the rows in flight, and the backpressure below (admission that waits for
-pages, the youngest row preempted and prefilled again) is the normal path.
-A resumed row is a prompt of its own prompt and what it had produced, a
+the rows in flight, and the backpressure below (admission that waits until
+the pool can carry every row's growth) is the normal path: a waiter stands
+in the queue WITHOUT tokens, never in and out of the pool with them. A row
+that is preempted all the same (copy-on-write, a test's own hand) comes
+back as a prompt of its own prompt and what it had produced, a
 length no arrival has, so the family compiles one page-table width
 (`cfg.one_page_bucket`) and its windows are the arrivals' programs. Every
 step hands back, beside the logits, the exit gate's probability of leaving
 after each visit (`request.exit_mass`, one `[visits]` row a generated token;
 `serving.loop.exit_mass` by visit); nothing branches on it. The counters
-`serving.preempted_tokens` and `serving.pool_bound_admissions` say what the
-pool's bound cost (any family books them).
+`serving.pool_bound_admissions`, `serving.growth_held_admissions` and
+`serving.preempted_tokens` say what the pool's bound cost (any family
+books them).
 
 Compile discipline (the PR 2 machinery doing serving duty):
   * prefill compiles once per prompt-length bucket (pow2 rounding); suffix
@@ -169,14 +174,28 @@ Compile discipline (the PR 2 machinery doing serving duty):
     at most once per bucket (via pipeline.jit_compile_counter).
 
 Failure/backpressure semantics:
-  * admission backpressure: a request whose context needs more private
-    pages than the free list holds (after evicting unshared prefix-cache
-    pages, LRU-first) WAITS — the pool can never be oversubscribed;
-  * mid-decode growth: when a running request crosses a page boundary and
-    the pool is dry, the YOUNGEST running request is preempted back to the
-    waiting queue (its refcounts released; on re-admission its
-    prompt+generated prefix re-prefills past whatever the prefix cache
-    still holds — recompute-style preemption, exact under greedy decoding);
+  * admission backpressure RESERVES GROWTH (ISSUE 55): `max_new_tokens` is
+    known at `submit`, so a row's table never outgrows
+    `pages_for(prompt_len + max_new_tokens)`. Beside rows that go on, the
+    head of the queue is admitted only if the free pages (unshared
+    prefix-cache pages counted free: they are evicted on demand, LRU-first)
+    cover what every such row has yet to take AND the head's own pages to
+    its end beyond its prefix hit; else it WAITS at the head, holding its
+    pinned hit and no token (`serving.pool_bound_admissions`; where its
+    prompt's pages were free, `serving.growth_held_admissions`). A lone
+    request is admitted whatever its end. The rule reads the pool, never a
+    knob, and does not bind where the rows' ends fit; pages that rows
+    about to finish will return are not counted, and a row that stops
+    on `eos_id` well under its cap was reserved more than it wrote
+    (ROADMAP R12 (a'), (a''));
+  * mid-decode growth therefore finds its page. The net under what the
+    reservation does not cover (a copy-on-write's fresh page, the sliding
+    layers' pool, speculation's lookahead, an adopted handoff): when a
+    pool is dry all the same, the YOUNGEST running request is preempted
+    back to the head of the waiting queue (its refcounts released; on
+    re-admission its prompt+generated prefix re-prefills past whatever the
+    prefix cache still holds — recompute-style preemption, exact under
+    greedy decoding);
   * abort (client gone, or the `serving_abort` chaos fault site): the
     request's refcounts release immediately; pages nobody else maps return
     to the free list — the zero-leak invariant the chaos test pins down.
@@ -423,6 +442,9 @@ class GenRequest:
         # while a row slot was free
         self.resumed = False
         self.waited_for_pages = False
+        # ... and whether one held it back for the running rows' growth
+        # while its prompt's pages were free
+        self.held_for_growth = False
         # learned sparse attention: `keep_selection` asks for `selection`;
         # `marked` says that this admission records it (a slot was free)
         self.keep_selection = False
@@ -862,7 +884,7 @@ class ServingEngine:
             # the rows in flight (ISSUE 53)
             "loop.visits": 0, "loop.decode_row_visits": 0,
             "loop.exit_mass": 0.0, "preempted_tokens": 0,
-            "pool_bound_admissions": 0,
+            "pool_bound_admissions": 0, "growth_held_admissions": 0,
         }
 
     def _page_bucket(self, n: int) -> int:
@@ -1997,8 +2019,20 @@ class ServingEngine:
         does not fit stops admission (no starvation of big requests by
         later small ones under fcfs). Prefix-cache hits cut the PRIVATE
         page bill: cached full pages of the prompt map with a refcount
-        bump instead of an allocation."""
+        bump instead of an allocation.
+
+        Admission RESERVES GROWTH: beside rows that go on, the head is
+        admitted only if the pool can carry every one of them, and the
+        head, to its known end (`_pages_to_end`): free pages, the prefix
+        cache's unshared pages counted free as `_allocate` treats them,
+        against what the rows still have to take and what the head takes
+        beyond its prefix hit. Else it waits at the head as it waits for
+        pages. What the rows owe is summed once a call and kept up as
+        the call admits; the pool keeps the other side (`cache_only`)."""
         admitted = 0
+        # pages the rows that go on still take to their ends: summed at
+        # the first candidate that meets such rows
+        owed = None
         cap = self.cfg.admit_per_step
         for req in self.scheduler.order(self._waiting):
             if cap and admitted >= cap:
@@ -2062,7 +2096,19 @@ class ServingEngine:
             # bare context; _ensure_writable then allocates on demand)
             lookahead = 0 if self._ladder_rung >= 2 else 1
             need = self.pool.pages_for(len(req.all_tokens) + lookahead)
-            private = self._allocate(max(0, need - len(matched)))
+            to_end = max(need, self._pages_to_end(req))
+            fits = True
+            if staying and not self.prefill_only:
+                # a lone request is admitted whatever its end: nothing
+                # runs, nothing can be owed
+                if owed is None:
+                    owed = self._growth_owed()
+                spare = self.pool.free_count + self.pool.cache_only
+                fits = owed + to_end - len(matched) <= spare
+                if not fits and need - len(matched) <= spare:
+                    req.held_for_growth = True
+            private = self._allocate(max(0, need - len(matched))) \
+                if fits else None
             if private is not None and self.window_pool is not None \
                     and not self._reserve_window(req):
                 self.pool.release(private)
@@ -2087,6 +2133,11 @@ class ServingEngine:
             if req.waited_for_pages:
                 req.waited_for_pages = False
                 self._count("pool_bound_admissions")
+            if req.held_for_growth:
+                req.held_for_growth = False
+                self._count("growth_held_admissions")
+            if owed is not None:
+                owed += to_end - len(req.pages)
             self._waiting.remove(req)
             req.admit_seq = self._admit_seq
             self._admit_seq += 1
@@ -2105,6 +2156,20 @@ class ServingEngine:
             self._observe_host_seconds("serving.prefill", sp, fetch0)
             admitted += 1
         return admitted
+
+    def _pages_to_end(self, req: GenRequest) -> int:
+        """Pages of the pool `req`'s table holds at its end: a row never
+        writes past `prompt_len + max_new_tokens` (known at `submit`), nor
+        past `max_position`. Exact where the cap is the length; under a
+        cap far above the usual stop the reservation holds pages nobody
+        writes (ROADMAP R12 (a''): an end bounded by the stops seen)."""
+        return self.pool.pages_for(min(req.prompt_len + req.max_new_tokens,
+                                       self.cfg.max_position))
+
+    def _growth_owed(self) -> int:
+        """Pages the rows that go on have yet to take to reach their ends."""
+        return sum(max(0, self._pages_to_end(r) - len(r.pages))
+                   for r in self._running if not self._leaving(r))
 
     def _reserve_window(self, req: GenRequest) -> bool:
         """Whether the sliding layers' pool has, or the prefix cache can

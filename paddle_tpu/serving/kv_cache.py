@@ -259,6 +259,11 @@ class PagedKVPool:
         self._refs: list[int] = [0] * self.num_pages
         # in-transit holder class (ISSUE 19): lease id -> pinned page table
         self._leases: dict[str, list[int]] = {}
+        # pages a prefix cache indexes (`index` / `unindex`) and how many of
+        # them nobody else holds, kept as refcounts pass through 1: what
+        # `PrefixCache.evict` could give back, read by admission in O(1)
+        self._indexed: set[int] = set()
+        self.cache_only = 0
 
     # -- sizing ---------------------------------------------------------------
     def pages_for(self, n_tokens: int) -> int:
@@ -306,6 +311,8 @@ class PagedKVPool:
             if self._refs[p] <= 0:
                 raise ValueError(f"sharing free page {p} (refcount 0)")
         for p in pages:
+            if self._refs[p] == 1 and p in self._indexed:
+                self.cache_only -= 1
             self._refs[p] += 1
 
     def release(self, pages: list[int]) -> int:
@@ -329,7 +336,25 @@ class PagedKVPool:
             if self._refs[p] == 0:
                 self._free.append(p)
                 freed += 1
+            elif self._refs[p] == 1 and p in self._indexed:
+                self.cache_only += 1
         return freed
+
+    def index(self, page: int) -> None:
+        """A prefix cache's holder on live `page`: `share`, and from here
+        on `cache_only` counts the page whenever that holder is its last."""
+        self.share([page])
+        self._indexed.add(page)
+
+    def unindex(self, pages) -> None:
+        """Forget that a cache indexes `pages` (those of them it does):
+        before the cache releases its holder, or drops it unreleased ahead
+        of a rebuild."""
+        for p in pages:
+            if p in self._indexed:
+                self._indexed.discard(p)
+                if self._refs[p] == 1:
+                    self.cache_only -= 1
 
     def free(self, pages: list[int]) -> None:
         """Single-holder spelling of `release` (the PR 7 API)."""
@@ -422,6 +447,10 @@ class PagedKVPool:
             elif r == 0 and p not in free_set:
                 problems.append(f"page {p} has refcount 0 but is missing "
                                 f"from the free list")
+        alone = sum(self._refs[p] == 1 for p in self._indexed)
+        if alone != self.cache_only:
+            problems.append(f"cache_only counts {self.cache_only} pages but "
+                            f"{alone} indexed pages have one holder")
         if holders is not None:
             for p in range(self.num_pages):
                 h = holders.get(p, 0) + lease_holds.get(p, 0)
@@ -444,6 +473,8 @@ class PagedKVPool:
         self._free = list(range(self.num_pages - 1, -1, -1))
         self._refs = [0] * self.num_pages
         self._leases = {}
+        self._indexed = set()
+        self.cache_only = 0
 
 
 class OwnedPoolView:
@@ -545,6 +576,19 @@ class OwnedPoolView:
     def free(self, pages: list[int]) -> None:
         self.release(pages)
 
+    # the prefix cache's holders: the count is the POOL's, every owner's
+    # cache in it
+    @property
+    def cache_only(self) -> int:
+        return self.pool.cache_only
+
+    def index(self, page: int) -> None:
+        self.pool.index(page)
+        self._note([page], +1)
+
+    def unindex(self, pages) -> None:
+        self.pool.unindex(pages)
+
     def adopt_transferred(self, pages: list[int]) -> None:
         """Record pins whose refcount was moved here by `lease_transfer`
         (handoff commit): ledger only — the pool refcount already counts
@@ -561,6 +605,8 @@ class OwnedPoolView:
         not the owner's, so in-transit pages survive the forfeit. Returns
         pages actually freed."""
         freed = 0
+        # a dead owner's cache unindexes nothing itself
+        self.pool.unindex(self._held)
         for p, c in list(self._held.items()):
             freed += self.pool.release([p] * c)
         self._held.clear()
@@ -838,7 +884,7 @@ class PrefixCache:
     def insert(self, tokens, pages: list[int], window_pages=None) -> int:
         """Index `tokens`' full blocks onto `pages` (pages[i] must hold
         block i's KV, already written). New nodes take a cache refcount via
-        pool.share; blocks already indexed are left on their existing page
+        pool.index; blocks already indexed are left on their existing page
         (first writer wins — both copies hold identical KV). Returns the
         number of pages newly indexed. `window_pages` {block: its page of
         the window pool}, for the blocks the writer still holds there: a
@@ -852,7 +898,7 @@ class PrefixCache:
             key = (pid, block)
             node = self._nodes.get(key)
             if node is None:
-                self.pool.share([pages[i]])
+                self.pool.index(pages[i])
                 node = _PrefixNode(self._next_id, pages[i], key, pid)
                 self._next_id += 1
                 self._nodes[key] = node
@@ -907,6 +953,7 @@ class PrefixCache:
                 # SAME evict pass can cascade up the chain (its original
                 # stamp may sit in `skipped` until the pass ends)
                 heapq.heappush(self._heap, (parent.last_use, parent.nid))
+        self.pool.unindex([node.page])
         self.pool.release([node.page])
         if node.wpage is not None:
             self.window_pool.release([node.wpage])
@@ -923,6 +970,7 @@ class PrefixCache:
         mutate state the rebuild resets anyway). Returns entries dropped.
         Use `flush` everywhere else."""
         n = len(self._nodes)
+        self.pool.unindex([node.page for node in self._nodes.values()])
         self._nodes.clear()
         self._by_id.clear()
         self._heap.clear()

@@ -290,14 +290,15 @@ CASES = [
         [(t, {"experts": f"moe_topk_experts_prefill f32[{t},2560]"})
          for t in (128, 2048)]),
     # ouro_2_6b.reason.sat (8, 16, 32 rows): 16 query heads over 16 KV heads
-    # of 128 in bfloat16 pages of 16 tokens. One head a KV head is the
-    # MULTI-HEAD arm (the heads side by side in the lanes, `nh * dh` the
-    # pool's width; "post_ln" runs it in float32), not the grouped one,
-    # whose gate wants fewer KV heads than query heads and pages of whole
-    # 128-token lane rows: 192 calls a step under the name
-    # `paged_decode_attention`, each over a plane of its own
+    # of 128 in bfloat16 pages of 16 tokens. One head a KV head whose head
+    # IS a whole 128-lane register takes the matrix-unit arm the
+    # grouped-query calls take (PR 55; `paged_attention.matrix_unit_arm`:
+    # the output is `[rows, heads, 128]`, a head a sublane row; until then
+    # `f32[rows,1,2048]`, the heads side by side in the lanes on the vector
+    # unit, where 12 heads of 64 stay) under the name it had: 192 calls a
+    # step, `paged_decode_attention`, each over a plane of its own
     *[("ouro_2_6b", "full_attention", "decode", rows,
-       f"paged_decode_attention f32[{rows},1,2048]") for rows in (8, 32)],
+       f"paged_decode_attention f32[{rows},16,128]") for rows in (8, 32)],
     # bert_base.s128 (and .dp4: the same rows a chip) and .s512
     ("bert_base", "attention", "train", (128, 128), "xla"),
     ("bert_base", "attention", "train", (32, 512), "xla"),
@@ -349,3 +350,30 @@ def test_the_arm_a_configuration_runs(case):
     if judged is not None:
         # the dispatch function's own word agrees with what was traced
         assert any(took) == (arm != "xla")
+
+
+def test_the_paged_decode_arm_is_chosen_by_shape():
+    """`dh == 128` with as many KV heads as query heads takes the matrix
+    unit and the list walk; `dh == 64` (the BERT decoder's 12 heads) keeps
+    the vector unit and the grid; a grouped-query call is the arm as it
+    was; heads that fill no sublane tile, or a table whose chunk fills no
+    lane row of the score tile, keep the vector unit too."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    ouro = ((32, 16, 128), (192 * 296, 16, 2048), jnp.bfloat16, 80)
+    bert = ((64, 12, 64), (8192, 16, 768), jnp.float32, 32)
+    zaya = ((64, 8, 128), (24 * 640, 128, 256), jnp.bfloat16, 16)
+    for q, pool, dtype, table in (ouro, bert, zaya):
+        assert ppa.paged_supported(q, pool, dtype)
+    assert ppa.matrix_unit_arm(*ouro) and ppa.walk_supported(*ouro)
+    assert not ppa.matrix_unit_arm(*bert) and not ppa.walk_supported(*bert)
+    assert ppa.matrix_unit_arm(*zaya)
+    # 12 heads of 128 fill no whole sublane tiles of 8
+    assert not ppa.matrix_unit_arm((32, 12, 128), (4096, 16, 1536),
+                                   jnp.bfloat16, 80)
+    # a table of 4 pages of 16 tokens: a chunk of 64 slots, half a lane row
+    assert not ppa.matrix_unit_arm((32, 16, 128), (4096, 16, 2048),
+                                   jnp.bfloat16, 4)
+    assert ppa.tile_rows(1) == (16, 8)

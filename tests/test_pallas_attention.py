@@ -249,3 +249,65 @@ def test_paged_gqa_walk_gate_and_tile():
                                   ppa.TABLE_ENTRIES // 64 + 1)
     assert [ppa.tile_rows(h) for h in (4, 5, 6, 12, 16, 32, 128)] == [
         (16, 8), (16, 8), (16, 8), (10, 0), (8, 4), (4, 2), (1, 0)]
+
+
+# -- a head that is a whole lane register, one head a KV head (PR 55) --------
+# (Ouro's 16 heads of 128 over 16 KV heads, bfloat16 pages of 16 tokens): the
+# matrix-unit arm, on the grid and as the list walk, against the XLA gather
+
+
+def _one_head_a_kv_head(rows, live, seed):
+    """`rows` rows of which `live` have context: each behind one of two
+    256-token prompts (16 whole pages, shared) and a ragged tail of its
+    own, one row inside its prompt, one ending on a page's last slot."""
+    nh, dh, ps, P = 16, 128, 16, 48
+    rng = np.random.default_rng(seed)
+    pages = 32 + live * 12 + 8
+    k_pool, v_pool = (jnp.asarray(rng.standard_normal((pages, ps, nh * dh)),
+                                  jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((rows, nh, dh)), jnp.float32)
+    table = np.zeros((rows, P), np.int32)
+    lens = np.zeros((rows,), np.int32)
+    free = iter(rng.permutation(np.arange(32, pages)))
+    for b in range(live):
+        own = int(rng.integers(1, 12 * ps))
+        if b == 1:
+            own = 3 * ps                       # ends on a page's last slot
+        n = -(-own // ps)
+        table[b, :16] = (b % 2) * 16 + np.arange(16)
+        table[b, 16:16 + n] = [next(free) for _ in range(n)]
+        lens[b] = 16 * ps + own
+    lens[2] = 100                              # inside its shared prompt
+    # live rows and padding rows interleave as a bucket's never do, which
+    # the kernel must not rely on
+    order = rng.permutation(rows)
+    return (q, k_pool, v_pool, jnp.asarray(table[order]),
+            jnp.asarray(lens[order]))
+
+
+@pytest.mark.parametrize("form", ["grid", "walk"])
+@pytest.mark.parametrize("rows,live", [(16, 11), (32, 20)])
+def test_paged_one_head_a_kv_head_matches_reference(rows, live, form,
+                                                    monkeypatch):
+    from paddle_tpu.ops.attention_ops import _paged_attention_reference
+    from paddle_tpu.ops.pallas_kernels import paged_attention as ppa
+
+    monkeypatch.setattr(ppa, "INTERPRET", True)
+    args = _one_head_a_kv_head(rows, live, seed=rows)
+    q, k_pool = args[:2]
+    assert ppa.paged_supported(q.shape, k_pool.shape, jnp.bfloat16)
+    assert ppa.matrix_unit_arm(q.shape, k_pool.shape, jnp.bfloat16, 48)
+    assert ppa.walk_supported(q.shape, k_pool.shape, jnp.bfloat16, 48)
+    scale = 128 ** -0.5
+    got = np.asarray(ppa._call(*args, scale, True) if form == "grid"
+                     else ppa.paged_decode_attention(*args, sm_scale=scale))
+    want = np.asarray(_paged_attention_reference(*args, scale))
+    lens = np.asarray(args[4])
+    assert (lens > 0).sum() == live
+    np.testing.assert_allclose(got[lens > 0], want[lens > 0], atol=1e-2)
+    assert not got[lens == 0].any()             # a padding row: zeros
+    if form == "walk":
+        read = ppa.walk_counts(np.asarray(args[3]), lens, k_pool.shape, 2)
+        # the two prompts' 16 pages once each, not once a row
+        assert read["shared"] and read["pages"] == int(
+            (-(-lens // 16)).sum()) - 16 * (live - 1 - 2)

@@ -773,10 +773,11 @@ def test_looped_dense_stack_compiled_for_v5e_moves_no_plane(v5e_chip,
     16 tokens: 56,832 rows of 2,048 bfloat16 values a pool, 3.72 GB each),
     from SHAPES alone: the decode step at 32 rows and a 512-token window,
     behind the cell's 80-page tables. The layer's body is compiled ONCE
-    (one call of the multi-head paged kernel in a decode program of 192
-    layer visits), Mosaic takes that kernel at one head a KV head in
-    bfloat16 pages of 16, and neither program copies a pool: the visits'
-    planes are written through both loops in place."""
+    (one call of the paged kernel in a decode program of 192 layer visits:
+    since PR 55 the matrix-unit arm as the list walk, its plan outside both
+    loops), Mosaic takes that kernel at one head a KV head in bfloat16
+    pages of 16, and neither program copies a pool: the visits' planes are
+    written through both loops in place."""
     import json
     import os
 
@@ -894,13 +895,17 @@ def test_sorts_over_finds_the_context_sort_and_passes_a_routers():
     ((64, 12, 64), (3072, 16, 768), "float32", 32),
     ((64, 8, 128), (24 * 640, 128, 256), "bfloat16", 16),
     ((16, 12, 64), (3072, 16, 768), "float32", 16),
-], ids=["post_ln_P32", "cca_moe_P16_stacked", "post_ln_16rows_P16"])
+    ((32, 16, 128), (192 * 296, 16, 2048), "bfloat16", 80),
+], ids=["post_ln_P32", "cca_moe_P16_stacked", "post_ln_16rows_P16",
+        "looped_dense_P80_stacked"])
 def test_paged_decode_blocks_fit_their_vmem_budget_on_v5e(
         v5e_chip, q_shape, pool, dtype, bucket):
     """The blocked decode kernel alone at the serving cells' sizes (64 rows;
     a bucket of 32 pages of 16 float32 slots, and of 16 pages of 128
     bfloat16 slots over the stacked pool; the chat cell's 16 rows of 16
-    pages): a grid step covers 16 pages, its K + V block stays under
+    pages; the looped cell's 32 rows of 80 pages of 16 bfloat16 slots, one
+    head of 128 a KV head: the matrix-unit arm on the grid, the form a
+    first live slot would take): a grid step covers 16 pages, its K + V block stays under
     `BLOCK_BYTES` (the kernel holds two: 4 MB of the chip's 16 MB of scoped
     VMEM), and Mosaic takes it with the pools left in HBM."""
     import jax
@@ -938,12 +943,15 @@ def test_paged_decode_blocks_fit_their_vmem_budget_on_v5e(
     ((64, 24, 128), (6 * 768, 128, 512), 24, (16, 8)),
     ((128, 32, 128), (4096, 128, 256), 64, (8, 4)),
     ((2, 8, 128), (24 * 640, 128, 256), 32, (16, 8)),
+    ((32, 16, 128), (192 * 296, 16, 2048), 80, (16, 8)),
 ], ids=["laguna_xs2", "falcon_h1_34b", "nemotron3_super_120b",
-        "zaya1_8b_2rows"])
+        "zaya1_8b_2rows", "ouro_2_6b"])
 def test_paged_gqa_walk_compiles_for_v5e(v5e_chip, q_shape, pool, bucket,
                                          tiles):
-    """The grouped-query decode kernel that walks a list of page blocks
-    (PR 50) alone, at the four cells' shapes and tables: the gate says yes,
+    """The matrix-unit decode kernel that walks a list of page blocks
+    (PR 50) alone, at the four grouped-query cells' shapes and tables and
+    at the looped cell's (PR 55: one head of 128 a KV head, pages of 16,
+    under the name it had): the gate says yes,
     the tile follows from the heads a KV head, and Mosaic takes it (the
     unaligned windows of sublanes of 6 heads a KV head among the rest) with
     the pools left in HBM; a table one block wide keeps the grid."""
@@ -971,7 +979,8 @@ def test_paged_gqa_walk_compiles_for_v5e(v5e_chip, q_shape, pool, bucket,
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
-    assert "tpu_custom_call" in text and "paged_decode_attention_gqa" in text
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    assert ("paged_decode_attention_gqa" in text) == (nkv < q_shape[1])
     assert pool_sized_copies(text, pool[0] * ps * width) == []
 
 

@@ -50,6 +50,14 @@ counters of its own; `engine._step_fetches` asks for an `exit_mass` only of
 a program that has one). The eight other families' twenty lines are what
 they were at the parent (commit c1b9e0e), which is also what keeps their
 warm compile cache.
+
+PR 55 recomputed ONE line: `ouro_2_6b` decode 32 (at the parent, commit
+52011f4: d8b16d35bfc87f55). A decode call whose head is a whole 128-lane
+register with as many KV heads as query heads now takes the matrix-unit arm
+(`paged_attention.matrix_unit_arm`) as PR 50's list walk, its plan worked
+out once before the two loops. No other configuration has such a shape:
+`ouro_2_6b`'s window (no paged decode call in it) and the twenty older
+lines stand.
 """
 import hashlib
 from unittest import mock
@@ -83,7 +91,7 @@ HASHES = {
     ("xing4_29b_a4b", "prefill", "2048"): "41d5e91d20e55186",
     ("ling3_flash", "decode", "256"): "c9c90a4a169294bd",
     ("ling3_flash", "prefill", "2048"): "368abb70195f5e36",
-    ("ouro_2_6b", "decode", "32"): "d8b16d35bfc87f55",
+    ("ouro_2_6b", "decode", "32"): "d955487264984fa1",
     ("ouro_2_6b", "prefill", "512"): "d743f065c1e242e3",
 }
 

@@ -9,10 +9,11 @@ import paddle_tpu as pt
 from paddle_tpu import tuning, unique_name
 from paddle_tpu.framework import Program, program_guard
 from paddle_tpu.ops import attention_ops as ao
-from paddle_tpu.serving import (PagedKVPool, ServingEngine, decoder_tiny,
-                                build_full_forward_program)
+from paddle_tpu.serving import (PagedKVPool, PrefixCache, ServingEngine,
+                                build_full_forward_program, decoder_tiny)
 from paddle_tpu.serving import model as sv_model
 from paddle_tpu.serving.kv_cache import pool_shape
+from serving_helpers import preempting
 
 
 def _rand(shape, seed):
@@ -573,6 +574,33 @@ def test_pool_allocator_edges():
         pool.free([99])
 
 
+def test_pool_counts_the_pages_only_a_cache_holds():
+    """`cache_only` (what admission counts free beside the free list) is
+    the indexed pages with one holder, at every step of a page's life:
+    indexed under its writer, left to the cache, hit, released, evicted;
+    the audit recounts it."""
+    pool = PagedKVPool(8, 4)
+    cache = PrefixCache(pool)
+    tokens = list(range(12))
+    pages = pool.allocate(3)
+    assert cache.insert(tokens, pages) == 3 and pool.cache_only == 0
+    pool.release(pages)                     # the writer leaves
+    assert pool.cache_only == 3 and pool.free_count == 5
+    hit = cache.match(tokens[:8])
+    pool.share(hit)                         # a reader maps two of them
+    assert pool.cache_only == 1
+    assert cache.evict(8) == 1 and pool.cache_only == 0
+    assert pool.check_consistency() == []
+    pool.release(hit)
+    assert pool.cache_only == 2
+    pool.cache_only += 1
+    assert any("cache_only" in p for p in pool.check_consistency())
+    pool.cache_only -= 1
+    assert cache.clear() == 2 and pool.cache_only == 0
+    pool.reset()
+    assert pool.free_count == 8 and pool.check_consistency() == []
+
+
 def _assert_no_leaks(eng):
     """The ISSUE 11 leak contract: every in-use page is accounted for by a
     live request or a prefix-cache entry, and flushing the cache returns
@@ -665,13 +693,16 @@ def test_preemption_recomputes_exactly():
         big.run_until_drained()
         want.append(big.result(rid))
 
-    # 9 pages of 2 slots: both requests admit (4 pages each for 7+1 slots),
-    # but growing to 15 slots each needs 16 pages total -> preemption
-    small = ServingEngine(cfg, page_size=2, pool_pages=9, max_inflight=2,
+    # 16 pages of 2 slots hold both requests to their ends (15 slots each):
+    # both are admitted, and the younger is preempted by hand, twice, with
+    # tokens of its own to prefill again
+    small = ServingEngine(cfg, page_size=2, pool_pages=16, max_inflight=2,
                           prefix_cache=False)
     rids = [small.submit(p, max_new_tokens=8) for p in prompts]
-    small.run_until_drained()
-    assert small.stats["preemptions"] >= 1, "pool pressure never triggered"
+    with preempting(small):
+        small.run_until_drained()
+    assert small.stats["preemptions"] == 2
+    assert small.requests[rids[1]].preemptions == 2
     assert [small.result(r) for r in rids] == want
     assert small.pool.free_count == small.pool.num_pages
 

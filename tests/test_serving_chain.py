@@ -12,6 +12,7 @@ from paddle_tpu import observability as obs
 from paddle_tpu.resilience.faults import fault_scope
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving import model as sv_model
+from serving_helpers import preempt_youngest
 
 # family -> (config, engine keywords)
 FAMILIES = {
@@ -33,14 +34,6 @@ def _engine(family, blocking=False, **kw):
         enqueued = eng._enqueued
         eng._enqueued = lambda step, why=None: enqueued(step, why or "forced")
     return eng
-
-
-def _preempt_youngest(eng):
-    """Make room once more than the pool asks for: the first call settles a
-    pending step, the next preempts the youngest running row."""
-    before = eng.stats["preemptions"]
-    while eng.stats["preemptions"] == before:
-        eng._make_room(eng._running[0])
 
 
 def _traffic(eng, eos_id):
@@ -72,7 +65,7 @@ def _traffic(eng, eos_id):
     eng.abort(rids[-1])
     eng.step()
     pending.append(eng._pending is not None)
-    _preempt_youngest(eng)
+    assert preempt_youngest(eng)
     # the sampled row last: every step it is in blocks
     rids += [eng.submit(prompt(6, shared), 10, keep_selection=keep),
              eng.submit(prompt(9), 7,

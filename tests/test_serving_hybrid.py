@@ -19,6 +19,7 @@ from paddle_tpu.framework import Program, program_guard
 from paddle_tpu.ops import attention_ops, hybrid_moe_ops
 from paddle_tpu.serving import DecoderConfig, ServingEngine, kv_cache
 from paddle_tpu.serving import model as sv_model
+from serving_helpers import preempting
 
 PS, W = 4, 8
 TOL = 1e-4          # float32 on both sides: rounding order only
@@ -261,9 +262,11 @@ def test_full_hit_copies_the_page_of_both_pools_on_write():
 def test_preemption_and_resume():
     prompts = _prompts(12, 18, 14, 16)
     calm = _serve(_engine(), prompts, new=12)
-    # pools this small make growth preempt the youngest row
+    # pools this small hold the later rows in the queue, and the youngest
+    # of those that run is preempted by hand
     eng = _engine(pool_pages=18, window_pool_pages=10, prefix_cache=False)
-    done = _serve(eng, prompts, new=12)
+    with preempting(eng):
+        done = _serve(eng, prompts, new=12)
     assert eng.stats["preemptions"] > 0
     assert [r.out_tokens for r in done] == [r.out_tokens for r in calm]
     _assert_right(eng, prompts, done)

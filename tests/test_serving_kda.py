@@ -28,6 +28,7 @@ from paddle_tpu.ops.sparse_moe_ops import moe_topk_experts_fn  # noqa: E402
 from paddle_tpu.serving import DecoderConfig, ServingEngine  # noqa: E402
 from paddle_tpu.serving.model import kda_moe_tiny  # noqa: E402
 from tools import kda_faults, mixer_faults  # noqa: E402
+from serving_helpers import preempting  # noqa: E402
 
 
 def _engine(cfg=None, **kw):
@@ -216,10 +217,12 @@ def test_a_prefix_hit_restored_from_a_snapshot_serves_what_a_cold_one_does():
 def test_a_preempted_and_resumed_row_equals_an_undisturbed_one():
     prompts = _prompts([9, 13, 11, 12], seed=7)
     calm = _tokens(_serve(_engine(), prompts, out=12))
-    # a pool too small for four rows' growth: the youngest is preempted,
-    # its slot and pages released, and re-admitted later
+    # a pool too small for four rows' growth holds the later ones in the
+    # queue; the youngest that runs is preempted by hand, its slot and
+    # pages released, and re-admitted later
     eng = _engine(pool_pages=17)
-    pressed = _serve(eng, prompts, out=12, audit=True)
+    with preempting(eng):
+        pressed = _serve(eng, prompts, out=12, audit=True)
     assert eng.stats["preemptions"] > 0
     assert _tokens(pressed) == calm
     _assert_right(eng, prompts, pressed)

@@ -22,6 +22,7 @@ from paddle_tpu.framework import Program, program_guard
 from paddle_tpu.ops import hybrid_moe_ops, latent_moe_ops
 from paddle_tpu.serving import DecoderConfig, ServingEngine
 from paddle_tpu.serving import model as sv_model
+from serving_helpers import preempting
 
 PS = 8
 TOL = 2e-4          # float32 on both sides: rounding order only
@@ -361,8 +362,11 @@ def test_copy_on_write_moves_the_rows_of_both_pools(pool):
 def test_preemption_and_resume():
     prompts = _prompts(7, 20, 22)
     roomy = _serve(_engine(), prompts, new=14)
-    eng = _engine(pool_pages=8, prefix_cache=False)
-    tight = _serve(eng, prompts, new=14)
+    # ten pages hold both rows to their ends (five each): both are admitted
+    # and the younger is preempted by hand
+    eng = _engine(pool_pages=10, prefix_cache=False)
+    with preempting(eng):
+        tight = _serve(eng, prompts, new=14)
     assert eng.stats["preemptions"] > 0
     assert [r.out_tokens for r in tight] == [r.out_tokens for r in roomy]
     _assert_right(eng, prompts, tight)

@@ -3,8 +3,10 @@ every visit with K/V pages of its own) behind ServingEngine, at a tiny size on
 the CPU: three layers visited three times, pages of 4. The engine's prefill,
 windows and decode through the cache against the plain reference's full
 forward (`benchmark/reference/ouro_lm.py`), the prefix cache and
-copy-on-write over every plane, a pool small enough that rows are preempted
-and come back, the loop against the same stack run once, the exit gate, and
+copy-on-write over every plane, rows preempted that come back, admission
+that reserves every row's growth to its known end under a pool too small
+for the offered rows, the loop against the same stack run once, the exit
+gate, and
 the wrong mechanisms of `tools/loop_faults.py`, which must each fail the same
 check."""
 import os
@@ -26,6 +28,7 @@ from paddle_tpu.serving import kv_cache  # noqa: E402
 from paddle_tpu.serving import model as sv_model  # noqa: E402
 from paddle_tpu.serving.model import looped_dense_tiny  # noqa: E402
 from tools import loop_faults  # noqa: E402
+from serving_helpers import preempting  # noqa: E402
 
 
 def _engine(cfg=None, **kw):
@@ -158,28 +161,105 @@ def test_copy_on_write_copies_a_shared_page_in_all_planes():
     assert np.array_equal(after[untouched], k[untouched])
 
 
-def test_a_dry_pool_preempts_rows_and_resumes_them_token_for_token():
-    """A pool that cannot hold four rows' contexts: rows are preempted
-    (their pages dropped), wait at the head of the queue and prefill their
-    prompt and what they had produced again; every request ends with the
-    tokens a large pool gives, no page leaks, and the counters say what the
-    bound cost."""
+def test_a_preempted_row_resumes_token_for_token():
+    """Rows preempted through the engine's own `_make_room` (their pages
+    dropped) wait at the head of the queue and prefill their prompt and
+    what they had produced again; every request ends with the tokens a
+    large pool gives, no page leaks, and the counters say what it cost."""
     prompts = _prompts((9, 14, 6, 11, 17, 8), seed=5, shared=8)
     roomy = _engine()
     want = [list(r.out_tokens) for r in _serve(roomy, prompts, out=24)]
     assert roomy.stats["preemptions"] == 0
     assert roomy.stats["preempted_tokens"] == 0
     tight = _engine(pool_pages=26)
-    done = _serve(tight, prompts, out=24, audit=True)
+    with preempting(tight, times=3):
+        done = _serve(tight, prompts, out=24, audit=True)
     assert [list(r.out_tokens) for r in done] == want
     st = tight.stats
-    assert st["preemptions"] >= 1
+    assert st["preemptions"] == 3
     assert st["preempted_tokens"] >= st["preemptions"]
     assert st["pool_bound_admissions"] >= 1
     assert sum(r.preemptions for r in done) == st["preemptions"]
     # a resumed row's gate readings go on where they stopped
     assert all(len(r.exit_mass) == 24 for r in done)
     _assert_right(tight, prompts, done)
+
+
+# -- admission that reserves a row's growth to its known end (ISSUE 55) ------
+
+
+def test_a_pool_too_small_for_the_rows_ends_holds_the_queue_not_the_rows():
+    """Six rows that end at 8-11 pages each (a shared head of two) offered
+    to 26 pages under four row slots: the pool cannot carry their ends, so
+    admission holds the later ones in the queue although their prompts'
+    pages are free, and NO row that holds tokens is ever preempted; what
+    they serve is what a roomy engine serves."""
+    prompts = _prompts((9, 14, 6, 11, 17, 8), seed=5, shared=8)
+    roomy = _engine()
+    want = [list(r.out_tokens) for r in _serve(roomy, prompts, out=24)]
+    assert roomy.stats["growth_held_admissions"] == 0
+    assert roomy.stats["pool_bound_admissions"] == 0
+    tight = _engine(pool_pages=26)
+    done = _serve(tight, prompts, out=24, audit=True)
+    assert [list(r.out_tokens) for r in done] == want
+    st = tight.stats
+    assert st["preemptions"] == 0 and st["preempted_tokens"] == 0
+    assert st["growth_held_admissions"] >= 1
+    # every admission the reservation held had waited for the pool
+    assert st["pool_bound_admissions"] >= st["growth_held_admissions"]
+    assert st["prefills"] == len(prompts)       # no window run twice
+    assert not any(r.preemptions for r in done)
+    # at least two rows stood together: the rule spends rows, not all
+    assert st["peak_pages_in_use"] > 13
+    _assert_right(tight, prompts, done)
+
+
+def test_a_lone_request_is_admitted_whatever_its_end():
+    """Nothing runs, so nothing can be owed: a request whose end lies past
+    what the pool holds free (and past the pool) is admitted as before, and
+    a second one waits behind its reservation."""
+    eng = _engine(pool_pages=12, max_inflight=2)
+    long = eng.submit(_prompts((9,), seed=8)[0], 60)    # ends at 16 pages
+    short = eng.submit(_prompts((5,), seed=9)[0], 3)
+    eng.step()
+    assert eng.requests[long].state == "running"
+    assert eng.requests[short].state == "waiting"
+    assert eng.requests[short].held_for_growth
+    for _ in range(4):
+        eng.step()
+    assert eng.requests[long].n_generated >= 3
+    assert eng.requests[short].state == "waiting"
+    eng.abort(long)                 # its reservation goes with it
+    eng.run_until_drained()
+    assert eng.requests[short].state == "finished"
+    assert eng.stats["growth_held_admissions"] == 1
+    assert eng.stats["preemptions"] == 0
+    assert eng.leaked_pages() == 0 and eng.audit_pool() == ([], [])
+
+
+def test_a_stop_on_eos_returns_the_reservation():
+    """A row allowed 50 tokens reserves 14 of 16 pages; it stops on
+    `eos_id` after a few, and the waiter behind it is admitted at once,
+    long before the length the reservation was made for."""
+    prompt, other = _prompts((6, 6), seed=12)
+    free = _serve(_engine(), [prompt], out=12)[0].out_tokens
+    stop = next(k for k in range(2, 12) if free[k] not in free[:k])
+    want = _serve(_engine(), [other], out=8)[0].out_tokens
+    eng = _engine(pool_pages=16, max_inflight=2)
+    first = eng.submit(prompt, 50, eos_id=free[stop])
+    second = eng.submit(other, 8)
+    steps = 0
+    while eng.requests[second].state == "waiting":
+        eng.step()
+        steps += 1
+    assert eng.requests[first].state == "finished"
+    assert eng.requests[first].out_tokens == free[:stop + 1]
+    assert steps <= stop + 4            # not the 50 the row was allowed
+    eng.run_until_drained()
+    assert eng.requests[second].out_tokens == want
+    assert eng.stats["growth_held_admissions"] == 1
+    assert eng.stats["preemptions"] == 0
+    assert eng.leaked_pages() == 0 and eng.audit_pool() == ([], [])
 
 
 # -- the loop ----------------------------------------------------------------

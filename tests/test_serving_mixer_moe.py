@@ -28,6 +28,7 @@ from paddle_tpu.serving import model as sv_model  # noqa: E402
 from paddle_tpu.serving.model import mixer_moe_tiny  # noqa: E402
 from test_serving_ssm import check_five_of_eight  # noqa: E402
 from tools import mixer_faults  # noqa: E402
+from serving_helpers import preempting  # noqa: E402
 
 TOL = 1e-3          # the rehearsal configuration's tolerances
 HI = jax.lax.Precision.HIGHEST
@@ -157,10 +158,12 @@ def test_a_prompt_in_chunks_with_a_snapshot_and_a_restore():
 def test_a_preempted_and_resumed_row_equals_an_undisturbed_one():
     prompts = _prompts([9, 13, 11, 12], seed=7)
     calm = _tokens(_serve(_engine(), prompts, out=12))
-    # a pool too small for four rows' growth: the youngest is preempted,
-    # its slot and pages released, and re-admitted later
+    # a pool too small for four rows' growth holds the later ones in the
+    # queue; the youngest that runs is preempted by hand, its slot and
+    # pages released, and re-admitted later
     eng = _engine(pool_pages=17)
-    pressed = _serve(eng, prompts, out=12, audit=True)
+    with preempting(eng):
+        pressed = _serve(eng, prompts, out=12, audit=True)
     assert eng.stats["preemptions"] > 0
     assert _tokens(pressed) == calm
     _assert_right(eng, prompts, pressed)

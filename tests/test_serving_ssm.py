@@ -27,6 +27,7 @@ from paddle_tpu.serving import model as sv_model  # noqa: E402
 from paddle_tpu.serving.kv_cache import PagedKVPool, PrefixCache  # noqa: E402
 from paddle_tpu.serving.model import parallel_ssm_tiny  # noqa: E402
 from tools import ssm_faults  # noqa: E402
+from serving_helpers import preempting  # noqa: E402
 
 TOL = 1e-3          # the rehearsal configuration's tolerance
 
@@ -510,10 +511,12 @@ def test_two_requests_on_one_snapshot_do_not_see_each_other():
 def test_a_preempted_and_resumed_row_equals_an_undisturbed_one():
     prompts = _prompts([9, 13, 11, 12], seed=7)
     calm = _serve(_engine(), prompts, out=12)
-    # a pool too small for four rows' growth: the youngest is preempted,
-    # its slot and pages released, and re-admitted later
+    # a pool too small for four rows' growth holds the later ones in the
+    # queue; the youngest that runs is preempted by hand, its slot and
+    # pages released, and re-admitted later
     eng = _engine(pool_pages=17)
-    pressed = _serve(eng, prompts, out=12, audit=True)
+    with preempting(eng):
+        pressed = _serve(eng, prompts, out=12, audit=True)
     assert eng.stats["preemptions"] > 0
     assert pressed == calm
     assert eng.leaked_pages() == 0

@@ -16,6 +16,7 @@ from paddle_tpu.ops import cca_moe_ops
 from paddle_tpu.ops.attention_ops import _paged_attention_reference
 from paddle_tpu.serving import DecoderConfig, ServingEngine
 from paddle_tpu.serving import model as sv_model
+from serving_helpers import preempting
 
 PS = 4
 TOL = 2e-4          # float32 on both sides: rounding order only
@@ -144,8 +145,11 @@ def test_copy_on_write_moves_the_state_row_and_routes():
 def test_preemption_and_resume():
     prompts = _prompts(7, 5, 6)
     roomy = _serve(_engine(), prompts, new=12)
-    eng = _engine(pool_pages=7, prefix_cache=False)
-    tight = _serve(eng, prompts, new=12)
+    # ten pages hold both rows to their ends (five each): both are admitted
+    # and the younger is preempted by hand
+    eng = _engine(pool_pages=10, prefix_cache=False)
+    with preempting(eng):
+        tight = _serve(eng, prompts, new=12)
     assert eng.stats["preemptions"] > 0
     assert [r.out_tokens for r in tight] == [r.out_tokens for r in roomy]
     _assert_right(eng, prompts, tight)

@@ -897,21 +897,31 @@ def _paged_latent_cases(spec):
 
 
 def _paged_looped_step_cases(spec):
-    """The multi-head arm at the looped model's decode step, TIMED (ten
-    calls after the first): 16 query heads over 16 KV heads of 128 (one
-    head a KV head, the heads side by side in the lanes of a 2,048-wide
-    row), bfloat16 pages of 16 tokens, 20 live rows of a bucket of 32
+    """The looped model's decode step, TIMED (ten calls after the first):
+    16 query heads over 16 KV heads of 128 (one head a KV head in a
+    2,048-wide row: since PR 55 the matrix-unit arm as the list walk, the
+    two prompts' pages read once; the vector-unit arm until then),
+    bfloat16 pages of 16 tokens, 20 live rows of a bucket of 32
     behind one of two 256-token prompts and 40-300 tokens of their own,
     under an 80-page table, in plane 100 of a pool of 192 planes of 296
     pages (the cell's: 3.72 GB a pool, so the rows a call reads lie where
-    a step's lie). `us` is one of a step's 192
-    calls; `roofline_pct` the K and V bytes of the attended tokens against
-    819 GB/s; the first eight rows against the reference."""
+    a step's lie). The calls are timed as the stack makes them: 48 of them
+    (one visit's layers, planes 100-147) in ONE program, the walk's plan
+    worked out once ahead of them, so that `us` is the device's time for
+    one of a step's 192 calls and not the host's dispatch of a program
+    (about 200 us here: until PR 55 the case timed a call a program and
+    read 249-252 us whatever the kernel took). `roofline_pct`: the K and V
+    bytes of the tokens the call READ (`tokens_read`: where it walks, a
+    run of pages that rows share once, `walk_counts`; else every row's own,
+    `tokens`) against 819 GB/s; the first eight rows of one call against
+    the reference."""
     import time
 
+    from paddle_tpu.ops.pallas_kernels import paged_attention
+
     def case():
-        B, live, nh, dh, ps, pages, planes, plane = 32, 20, 16, 128, 16, \
-            296, 192, 100
+        B, live, nh, dh, ps, pages, planes, plane, calls = 32, 20, 16, 128, \
+            16, 296, 192, 100, 48
         P = 1280 // ps
         ks = jax.random.split(jax.random.PRNGKey(53), 3)
         q = _rand(ks[0], (B, nh, dh), "float32")
@@ -936,21 +946,41 @@ def _paged_looped_step_cases(spec):
         args = (q, kp, vp, jnp.asarray(table), jnp.asarray(lens))
         assert spec.supported(q.shape, kp.shape, "bfloat16")
         scale = dh ** -0.5
-        fn = jax.jit(lambda *a: spec.fn(*a, sm_scale=scale))
-        got = jax.block_until_ready(fn(*args))
+        walks = paged_attention.walk_supported(q.shape, kp.shape, "bfloat16",
+                                               P)
+
+        def visit(q, kp, vp, table, lens):
+            plan = paged_attention.walk_plan(
+                table, lens, kp.shape, kp.dtype.itemsize) if walks else None
+
+            def layer(acc, i):
+                planed = jnp.where(lens[:, None] > 0, table + i * pages, 0)
+                return acc + spec.fn(q, kp, vp, planed, lens, sm_scale=scale,
+                                     plan=plan), None
+            return jax.lax.scan(layer, jnp.zeros(q.shape, jnp.float32),
+                                jnp.arange(calls, dtype=jnp.int32))[0]
+
+        got = jax.block_until_ready(jax.jit(
+            lambda *a: spec.fn(*a, sm_scale=scale))(*args))
+        fn = jax.jit(visit)
+        jax.block_until_ready(fn(*args))
         t = time.perf_counter()
         for _ in range(10):
             last = fn(*args)
         jax.block_until_ready(last)
-        call_s = (time.perf_counter() - t) / 10
+        call_s = (time.perf_counter() - t) / 10 / calls
         with jax.default_matmul_precision("highest"):
             want = spec.reference(q[:8], kp, vp, args[3][:8], args[4][:8],
                                   sm_scale=scale)
         tokens = int(lens.sum())
+        read = paged_attention.walk_counts(
+            table[:live], lens[:live], kp.shape,
+            kp.dtype.itemsize)["tokens"] if walks else tokens
         res = {"err": _rel_err(got[:8], want), "tol": 2e-2,
                "finite": bool(np.isfinite(np.asarray(got[:live])).all()),
-               "us": call_s * 1e6, "tokens": tokens,
-               "roofline_pct": tokens * 2 * nh * dh * 2 / call_s / 819e9
+               "us": call_s * 1e6, "tokens": tokens, "tokens_read": read,
+               "walks": bool(walks),
+               "roofline_pct": read * 2 * nh * dh * 2 / call_s / 819e9
                * 100}
         res["ok"] = bool(res["finite"] and res["err"] <= res["tol"])
         return res
