@@ -52,12 +52,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .attention_ops import (_DROP_PAGE, _write_rows, grouped_query_attention,
+from .attention_ops import (_write_rows, grouped_query_attention,
                             kv_cache_append_fn, paged_decode_attention_fn,
                             paged_decode_plan_fn,
                             paged_prefill_attention_fn)
+from .decoder_common import (_experts_backend, _mm, _page_row_index,
+                             greedy_fn, rms_norm_fn, rotary_partial_fn)
 from ..observability.schema import piece, under_mode
-from .registry import _DYN, ExecContext, register_op
+from .registry import ExecContext, register_op
 
 _HI = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
@@ -85,27 +87,6 @@ def state_width(geom: Geometry) -> int:
 # ---------------------------------------------------------------------------
 # the mechanisms
 # ---------------------------------------------------------------------------
-
-
-def rms_norm_fn(x, scale, eps: float):
-    xf = x.astype(_F32)
-    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-                        + eps)
-    return xf * inv * scale.astype(_F32)
-
-
-def rotary_partial_fn(x, positions, rotary_dim: int, theta: float):
-    """x [..., nh, dh] float32, positions [...] int (one per token): rotate
-    lanes [0, rotary_dim) of every head as (i, i + rotary_dim/2) pairs by
-    position * theta^(-2i/rotary_dim); lanes past rotary_dim pass."""
-    half = rotary_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=_F32) * 2.0 / rotary_dim)
-    ang = positions.astype(_F32)[..., None, None] * inv_freq   # [..,1,half]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2, rest = (x[..., :half], x[..., half:rotary_dim],
-                    x[..., rotary_dim:])
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
 
 def l2_norm_heads_fn(x, eps: float = 1e-6):
@@ -156,43 +137,6 @@ def moe_router_fn(z, r_prev, p):
     return r, probs, choice
 
 
-def _experts_backend(tokens, w_gate_shape, dtype):
-    from .. import tuning
-    from .pallas_kernels import moe_experts as pme
-    from .pallas_kernels import workbench
-
-    _, E, H, F = w_gate_shape
-
-    def runnable():
-        return (workbench.runnable(pme)
-                and pme.experts_supported((tokens, H), w_gate_shape, dtype))
-
-    def analytic():
-        return {"backend": "pallas" if runnable() else "xla"}
-
-    if tuning.mode() == "off" or tokens % _DYN == 0:
-        backend = analytic()["backend"]
-    else:
-        key = tuning.canonical_key(
-            "moe_experts", tuning.moe_experts_key(tokens, E, H, F),
-            str(jnp.dtype(dtype)), tuning.device_kind())
-        decision, _tier = tuning.decide(
-            "moe_experts", key, prior=analytic, default={"backend": "xla"},
-            validate=lambda dd: dd.get("backend") in ("xla", "pallas"))
-        backend = decision.get("backend", "xla")
-    return backend if backend == "xla" or runnable() else "xla"
-
-
-def experts_grouped(tokens, w_gate_shape, dtype) -> bool:
-    """Whether a gated expert call of `tokens` rows takes the kernel's
-    grouped form (`moe_experts._grouped_call`): the kernel runs, and the
-    rows are more than its one token tile."""
-    from .pallas_kernels import moe_experts as pme
-
-    return tokens > pme._TOKEN_TILE \
-        and _experts_backend(tokens, w_gate_shape, dtype) == "pallas"
-
-
 def moe_top1_experts_fn(z, probs, choice, w_gate, w_up, w_down, layer=0,
                         expert_lo: int = 0, tag: str = "decode"):
     """The part of a top-1 expert layer that the holder of experts
@@ -216,10 +160,6 @@ def moe_top1_experts_fn(z, probs, choice, w_gate, w_up, w_down, layer=0,
 # ---------------------------------------------------------------------------
 # one layer, in two halves around the attention
 # ---------------------------------------------------------------------------
-
-
-def _mm(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_F32)
 
 
 def _pre_attention(x, p, state_prev, positions, geom: Geometry):
@@ -268,19 +208,6 @@ def _post_attention(x, o, r_prev, p, experts, layer, geom: Geometry, tag):
                                 tag=tag)
         return (h + y.reshape(B, S, H), r.reshape(B, S, -1),
                 choice.reshape(B, S))
-
-
-def _page_row_index(page_table, gpos, page_size, layer_off, keep):
-    """Row of a stacked pool for global position `gpos` ([B] or [B, S],
-    `keep` alike) in this layer: its page's id plus the layer's offset, or
-    the drop sentinel where `keep` is false."""
-    P = page_table.shape[1]
-    page_of = jnp.clip(gpos // page_size, 0, P - 1)
-    if gpos.ndim == 1:
-        idx = jnp.take_along_axis(page_table, page_of[:, None], axis=1)[:, 0]
-    else:
-        idx = jnp.take_along_axis(page_table, page_of, axis=1)
-    return jnp.where(keep, idx + layer_off, _DROP_PAGE)
 
 
 def _read_state(s_pool, page_table, pos_prev, page_size, layer_off):
@@ -461,7 +388,7 @@ def cca_moe_stack_op(ctx: ExecContext):
         mask=ctx.input("Mask") if ctx.has_input("Mask") else None,
         num_pages=int(ctx.attr("num_pages", 0)))
     res = {"Logits": out["logits"], "Routes": out["routes"],
-           "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
+           "NextToken": greedy_fn(out["logits"])}
     if paged:
         res.update(KPoolOut=out["k_pool"], VPoolOut=out["v_pool"],
                    SPoolOut=out["s_pool"])

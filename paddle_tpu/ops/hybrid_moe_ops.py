@@ -58,19 +58,18 @@ selection, weights), gate logits, rotary and softmax in float32.
 from __future__ import annotations
 
 import collections
-import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from .attention_ops import (_NEG_INF, _gather_pages, _write_rows,
-                            kv_cache_append_fn, paged_decode_attention_fn,
-                            paged_decode_plan_fn)
-from .cca_moe_ops import _page_row_index, rms_norm_fn
+from .attention_ops import (_gather_pages, _write_rows, kv_cache_append_fn,
+                            paged_decode_attention_fn, paged_decode_plan_fn)
+from .decoder_common import (_attend, _by_query_block, _mm, _page_row_index,
+                             causal_attention_fn, greedy_fn,
+                             moe_topk_experts_fn, rms_norm_fn, rotary_fn,
+                             sigmoid_router_fn, swiglu_fn, yarn_inv_freq_fn)
 from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
-from .sparse_moe_ops import moe_topk_experts_fn
 
 _HI = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
@@ -90,99 +89,14 @@ EXPERT_PARAMS = ("w_gate", "w_up", "w_down")
 
 FULL, SLIDE, DENSE, MOE = "full", "slide", "dense", "moe"
 
-# queries attended together: a full layer's float32 scores of one block are
-# `[heads, block, context]` (48 x 64 x 20,480 x 4 B = 252 MB); a sliding
-# layer's band is `[heads, block, block + window]`
-_QUERY_BLOCK = 64
+# queries attended together (`decoder_common._QUERY_BLOCK` for a full
+# layer): a sliding layer's band is `[heads, block, block + window]`
 _BAND_BLOCK = 128
 
 
 # ---------------------------------------------------------------------------
 # the mechanisms
 # ---------------------------------------------------------------------------
-
-
-def yarn_inv_freq_fn(rotary_dim: int, theta: float, yarn=()) -> np.ndarray:
-    """The `rotary_dim / 2` inverse frequencies of a rotary embedding,
-    float32. `yarn` = (factor, original context, beta_fast, beta_slow,
-    attention factor) or (): lane pair i turns `theta^(-2i/d)` a position;
-    YaRN keeps that below `low`, divides it by `factor` above `high` and
-    ramps linearly between, `low`/`high` the (floored/ceiled) pair indices
-    that turn `beta_fast`/`beta_slow` times over the original context."""
-    half = rotary_dim // 2
-    inv = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / rotary_dim)
-    if not yarn:
-        return inv.astype(np.float32)
-    factor, original, beta_fast, beta_slow = (float(v) for v in yarn[:4])
-
-    def correction_dim(turns):
-        return rotary_dim * math.log(original / (turns * 2 * math.pi)) \
-            / (2 * math.log(theta))
-
-    low = max(math.floor(correction_dim(beta_fast)), 0)
-    high = min(math.ceil(correction_dim(beta_slow)), rotary_dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
-                   0.0, 1.0)
-    return (inv / factor * ramp + inv * (1.0 - ramp)).astype(np.float32)
-
-
-def rotary_fn(x, positions, inv_freq, rotary_dim: int, factor: float = 1.0):
-    """x [..., heads, dh] float32, positions [...] (one a token): lanes
-    [0, rotary_dim) of every head turn as (i, i + rotary_dim/2) pairs by
-    `position * inv_freq[i]`, cos and sin times `factor`; the lanes past
-    `rotary_dim` pass."""
-    half = rotary_dim // 2
-    ang = positions.astype(_F32)[..., None, None] * jnp.asarray(inv_freq)
-    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
-    x1, x2, rest = (x[..., :half], x[..., half:rotary_dim],
-                    x[..., rotary_dim:])
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
-
-
-def _attend(qg, k, v, mask, sm_scale):
-    """qg [B, s, nkv, g, dh], k/v [B, T, nkv, dh], mask [B, s, T] ->
-    [B, s, nkv, g, dh] float32."""
-    s = jnp.einsum("bsjgd,btjd->bjgst", qg, k,
-                   preferred_element_type=_F32) * sm_scale
-    s = jnp.where(mask[:, None, None], s, _NEG_INF)
-    probs = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bjgst,btjd->bsjgd", probs.astype(v.dtype), v,
-                      preferred_element_type=_F32)
-
-
-def _by_query_block(fn, qg, block: int):
-    """`fn(block index, qg's block [B, block, ...])` over the query blocks
-    of qg [B, S, ...], one after another (`lax.map`); one call where S is
-    no multiple of `block`."""
-    B, S = qg.shape[:2]
-    if S <= block or S % block:
-        return fn(jnp.int32(0), qg)
-    n = S // block
-    split = jnp.moveaxis(qg.reshape((B, n, block) + qg.shape[2:]), 1, 0)
-    out = jax.lax.map(lambda a: fn(*a),
-                      (jnp.arange(n, dtype=jnp.int32), split))
-    return jnp.moveaxis(out, 0, 1).reshape((B, S) + out.shape[3:])
-
-
-def causal_attention_fn(q, k, v, q_pos0, sm_scale: float):
-    """q [B, S, nh, dh] at positions `q_pos0[b] + s` over k/v [B, T, nkv,
-    dh] at positions 0..T-1: every key at or before the query, query block
-    by query block -> [B, S, nh, dh] float32."""
-    B, S, nh, dh = q.shape
-    T, nkv = k.shape[1], k.shape[2]
-    block = S if S <= _QUERY_BLOCK or S % _QUERY_BLOCK else _QUERY_BLOCK
-    kp = jnp.arange(T, dtype=jnp.int32)
-
-    def one(j, qb):
-        qp = q_pos0[:, None] + j * block + jnp.arange(block, dtype=jnp.int32)
-        return _attend(qb, k, v, kp[None, None, :] <= qp[:, :, None],
-                       sm_scale)
-
-    qg = q.reshape(B, S, nkv, nh // nkv, dh).astype(k.dtype)
-    return _by_query_block(one, qg, block).reshape(B, S, nh, dh)
 
 
 def band_attention_fn(q, k, v, q_pos0, k_pos0, window: int, sm_scale: float):
@@ -209,31 +123,6 @@ def band_attention_fn(q, k, v, q_pos0, k_pos0, window: int, sm_scale: float):
 
     qg = q.reshape(B, S, nkv, nh // nkv, dh).astype(k.dtype)
     return _by_query_block(one, qg, block).reshape(B, S, nh, dh)
-
-
-def sigmoid_router_fn(z, router_w, router_bias, k: int, scaling: float):
-    """z [T, H] float32 -> (ids [T, k] int32: the k experts of largest
-    `sigmoid(z W_r) + bias`, in order, ties to the lower index; cw [T, E]
-    float32: `scaling * s_e / sum_chosen s` at the chosen, zero
-    elsewhere)."""
-    s = jax.nn.sigmoid(jnp.dot(z, router_w, precision=_HI))
-    _, ids = jax.lax.top_k(s + router_bias, k)
-    chosen = jnp.take_along_axis(s, ids, axis=-1)
-    weights = scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
-    held = jnp.arange(s.shape[-1], dtype=jnp.int32)
-    cw = jnp.sum(jnp.where(ids[:, :, None] == held, weights[:, :, None],
-                           0.0), axis=1)
-    return ids.astype(jnp.int32), cw
-
-
-def _mm(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_F32)
-
-
-def swiglu_fn(z, w_gate, w_up, w_down):
-    """z [T, H] float32 -> `W_d(silu(W_g z) * (W_u z))` float32."""
-    g = _mm(z, w_gate)
-    return _mm(g * jax.nn.sigmoid(g) * _mm(z, w_up), w_down)
 
 
 # ---------------------------------------------------------------------------
@@ -490,7 +379,7 @@ def hybrid_moe_stack_op(ctx: ExecContext):
         num_pages=int(ctx.attr("num_pages", 0)),
         window_pages=int(ctx.attr("window_pages", 0)))
     res = {"Logits": out["logits"], "Routes": out["routes"],
-           "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
+           "NextToken": greedy_fn(out["logits"])}
     if paged:
         res.update({s + "Out": pool
                     for s, pool in zip(_POOL_SLOTS, out["pools"])})

@@ -86,12 +86,11 @@ import jax
 import jax.numpy as jnp
 
 from . import latent_moe_ops as lat
-from .cca_moe_ops import rms_norm_fn
+from .decoder_common import _mm, greedy_fn, rms_norm_fn
 from .parallel_ssm_ops import (causal_conv_fn, conv_token_update_fn,
                                conv_window_update_fn)
 from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
-from .sparse_moe_ops import _mm
 
 _HI = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
@@ -510,7 +509,7 @@ def kda_moe_stack_op(ctx: ExecContext):
         num_pages=int(ctx.attr("num_pages", 0)),
         num_slots=int(ctx.attr("num_slots", 0)))
     res = {"Logits": out["logits"], "Routes": out["routes"],
-           "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
+           "NextToken": greedy_fn(out["logits"])}
     if paged:
         res.update({s + "Out": pool
                     for s, pool in zip(_POOL_SLOTS, out["pools"])})

@@ -98,17 +98,18 @@ import jax
 import jax.numpy as jnp
 
 from .attention_ops import _NEG_INF, _write_rows
-from .cca_moe_ops import _page_row_index, rms_norm_fn
+from .decoder_common import (_mm, _page_row_index, greedy_fn,
+                             group_limited_router_fn, moe_topk_experts_fn,
+                             rms_norm_fn, rotary_fn, swiglu_fn,
+                             yarn_inv_freq_fn)
 from . import hyper_connection_ops as hc
-from .hybrid_moe_ops import rotary_fn, swiglu_fn, yarn_inv_freq_fn
 from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
-from .sparse_moe_ops import (_block_of, _mask_positions, _mm,
-                             _word_values, decode_scores_fn,
-                             indexer_scores_fn, join_rows_fn,
-                             layer_norm_fn, moe_topk_experts_fn,
-                             pack_selection_fn, select_indices_fn,
-                             select_mask_fn, write_index_keys_fn)
+from .sparse_moe_ops import (_block_of, _mask_positions, _word_values,
+                             decode_scores_fn, indexer_scores_fn,
+                             join_rows_fn, layer_norm_fn, pack_selection_fn,
+                             select_indices_fn, select_mask_fn,
+                             write_index_keys_fn)
 
 _HI = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
@@ -399,31 +400,6 @@ def gather_rows_fn(pool, page_table, sel):
                              page_table[:, None, :], 0), axis=-1)
     flat = jnp.clip(page, 0, rows - 1) * ps + at % ps
     return pool.reshape(rows * ps, words)[flat]
-
-
-def group_limited_router_fn(z, router_w, router_bias, k: int, groups: int,
-                            groups_kept: int, scaling: float):
-    """z [T, H] float32 -> (ids [T, k] int32: the k experts of largest
-    `sigmoid(z W_r) + bias` inside the `groups_kept` groups (of `groups`
-    equal, consecutive ones) whose two largest biased scores sum highest,
-    in order, ties to the lower index; cw [T, E] float32: `scaling * s_e /
-    sum_chosen s` at the chosen, zero elsewhere)."""
-    s = jax.nn.sigmoid(jnp.dot(z, router_w, precision=_HI))
-    T, E = s.shape
-    biased = s + router_bias
-    by_group = biased.reshape(T, groups, E // groups)
-    best2 = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)      # [T, groups]
-    _, kept = jax.lax.top_k(best2, groups_kept)
-    open_ = jnp.any(kept[:, :, None]
-                    == jnp.arange(groups, dtype=jnp.int32), axis=1)
-    allowed = jnp.repeat(open_, E // groups, axis=1)
-    _, ids = jax.lax.top_k(jnp.where(allowed, biased, -jnp.inf), k)
-    chosen = jnp.take_along_axis(s, ids, axis=-1)
-    weights = scaling * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
-    expert = jnp.arange(E, dtype=jnp.int32)
-    cw = jnp.sum(jnp.where(ids[:, :, None] == expert, weights[:, :, None],
-                           0.0), axis=1)
-    return ids.astype(jnp.int32), cw
 
 
 def paged_scores_fn(qi, w, i_pool, table):
@@ -970,7 +946,7 @@ def latent_moe_stack_op(ctx: ExecContext):
     res = {"Logits": out["logits"], "Routes": out["routes"]}
     if indexed:
         res["Selection"] = out["selection"]
-    res["NextToken"] = jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)
+    res["NextToken"] = greedy_fn(out["logits"])
     if paged:
         res["LatentPoolOut"] = out["latent_pool"]
         if indexed:

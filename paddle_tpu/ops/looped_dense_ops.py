@@ -49,8 +49,8 @@ import jax.numpy as jnp
 
 from .attention_ops import (_gather_pages, _write_rows, kv_cache_append_fn,
                             paged_decode_attention_fn, paged_decode_plan_fn)
-from .cca_moe_ops import _page_row_index, rms_norm_fn
-from .hybrid_moe_ops import _mm, causal_attention_fn, yarn_inv_freq_fn
+from .decoder_common import (_mm, _page_row_index, causal_attention_fn,
+                             greedy_fn, rms_norm_fn, yarn_inv_freq_fn)
 from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
 
@@ -259,7 +259,7 @@ def looped_dense_stack_op(ctx: ExecContext):
         mask=ctx.input("Mask") if ctx.has_input("Mask") else None,
         num_pages=int(ctx.attr("num_pages", 0)))
     res = {"Logits": out["logits"], "ExitMass": out["exit_mass"],
-           "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
+           "NextToken": greedy_fn(out["logits"])}
     if paged:
         res.update({s + "Out": pool
                     for s, pool in zip(_POOL_SLOTS, out["pools"])})

@@ -50,9 +50,9 @@ import jax.numpy as jnp
 
 from .attention_ops import (_gather_pages, _write_rows, kv_cache_append_fn,
                             paged_decode_attention_fn, paged_decode_plan_fn)
-from .cca_moe_ops import _experts_backend, _page_row_index, rms_norm_fn
-from .hybrid_moe_ops import causal_attention_fn
-from .latent_moe_ops import group_limited_router_fn
+from .decoder_common import (_experts_backend, _mm, _page_row_index,
+                             causal_attention_fn, greedy_fn,
+                             group_limited_router_fn, rms_norm_fn)
 from .parallel_ssm_ops import (causal_conv_fn, conv_token_update_fn,
                                conv_window_update_fn, gated_group_norm_fn,
                                ssd_scan_fn, ssm_token_update_fn)
@@ -85,10 +85,6 @@ def state_pack(head_dim: int, heads_per_group: int, lanes: int = 128) -> int:
     while pack * 2 * head_dim <= lanes and heads_per_group % (pack * 2) == 0:
         pack *= 2
     return pack
-
-
-def _mm(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_F32)
 
 
 def relu2_fn(x):
@@ -346,7 +342,7 @@ def mixer_moe_stack_op(ctx: ExecContext):
         num_pages=int(ctx.attr("num_pages", 0)),
         num_slots=int(ctx.attr("num_slots", 0)))
     res = {"Logits": out["logits"], "Routes": out["routes"],
-           "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
+           "NextToken": greedy_fn(out["logits"])}
     if paged:
         res.update({s + "Out": pool
                     for s, pool in zip(_POOL_SLOTS, out["pools"])})

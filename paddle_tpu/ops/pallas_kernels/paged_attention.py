@@ -1,7 +1,7 @@
 """Ragged paged decode attention: a Pallas TPU kernel over the KV-page pool.
 
-Why this exists (ROADMAP item 1, "Ragged Paged Attention", arXiv:2604.15464):
-the serving runtime's decode step is one query token per request attending
+Why this exists ("Ragged Paged Attention", arXiv:2604.15464): the serving
+runtime's decode step is one query token per request attending
 over that request's whole context, which lives scattered across fixed-size
 pages of the preallocated HBM pool. The XLA reference path
 (attention_ops._paged_attention_reference) gathers every row's pages into a

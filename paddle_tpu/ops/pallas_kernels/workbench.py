@@ -1,9 +1,9 @@
 """Kernel-workbench substrate: the conventions every Pallas kernel shares.
 
-ROADMAP item 5 ("Tensor Processing Primitives", arXiv:2104.05755) calls for
-a small reusable custom-kernel layer rather than a pile of one-off files.
-This module is that layer's spine — the pieces attention.py /
-paged_attention.py each re-invented privately, factored once:
+A small reusable custom-kernel layer ("Tensor Processing Primitives",
+arXiv:2104.05755) rather than a pile of one-off files: this module is that
+layer's spine — the pieces attention.py / paged_attention.py each
+re-invented privately, factored once:
 
   * block-shape helpers — `pick_block` (largest divisor under a VMEM
     budget, sublane-friendly), `fit_heads` (the attention head-block rule),

@@ -68,8 +68,9 @@ import jax.numpy as jnp
 
 from .attention_ops import (_gather_pages, _write_rows, kv_cache_append_fn,
                             paged_decode_attention_fn, paged_decode_plan_fn)
-from .cca_moe_ops import _page_row_index, rms_norm_fn
-from .hybrid_moe_ops import causal_attention_fn, rotary_fn, yarn_inv_freq_fn
+from .decoder_common import (_mm, _page_row_index, causal_attention_fn,
+                             greedy_fn, rms_norm_fn, rotary_fn,
+                             yarn_inv_freq_fn)
 from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
 
@@ -306,10 +307,6 @@ def ssm_token_update_fn(s_pool, idx, x, dt_raw, bmat, cmat, dt_bias, a_log,
     return update(s_pool, idx, jnp.exp(la), dtx, bmat, cmat, n_live)
 
 
-def _mm(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_F32)
-
-
 def gated_group_norm_fn(y, z, gain, groups: int, eps: float):
     """`y * silu(z)`, then RMSNorm within each of `groups` equal groups of
     channels, times `gain` (the gate BEFORE the norm)."""
@@ -533,7 +530,7 @@ def parallel_ssm_stack_op(ctx: ExecContext):
         num_pages=int(ctx.attr("num_pages", 0)),
         num_slots=int(ctx.attr("num_slots", 0)))
     res = {"Logits": out["logits"],
-           "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
+           "NextToken": greedy_fn(out["logits"])}
     if paged:
         res.update({s + "Out": pool
                     for s, pool in zip(_POOL_SLOTS, out["pools"])})

@@ -69,8 +69,9 @@ import jax.numpy as jnp
 
 from .attention_ops import (_DROP_PAGE, _NEG_INF, _gather_pages,
                             _write_rows)
-from .cca_moe_ops import (_experts_backend, _page_row_index, rms_norm_fn,
-                          rotary_partial_fn)
+from .decoder_common import (_mm, _page_row_index, greedy_fn,
+                             moe_topk_experts_fn, rms_norm_fn,
+                             rotary_partial_fn, topk_router_fn)
 from ..observability.schema import piece, under_mode
 from .registry import ExecContext, register_op
 
@@ -481,40 +482,9 @@ def masked_window_attention_fn(q, kv_pool, page_table, mask,
                                  mask, sm_scale)
 
 
-def topk_router_fn(z, router_w, k: int):
-    """z [T, H] float32 -> (ids [T, k] int32, the k most probable experts
-    in order; cw [T, E] float32: their probabilities renormalised to sum
-    to one, zero elsewhere)."""
-    probs = jax.nn.softmax(jnp.dot(z, router_w, precision=_HI), axis=-1)
-    vals, ids = jax.lax.top_k(probs, k)
-    weights = vals / jnp.sum(vals, axis=-1, keepdims=True)
-    held = jnp.arange(probs.shape[-1], dtype=jnp.int32)
-    cw = jnp.sum(jnp.where(ids[:, :, None] == held, weights[:, :, None],
-                           0.0), axis=1)
-    return ids.astype(jnp.int32), cw
-
-
-def moe_topk_experts_fn(z, cw, w_gate, w_up, w_down, layer=0,
-                        tag: str = "decode", k: int | None = None):
-    """`sum_e cw[t, e] * expert_e(z[t])`, float32 [T, H]; weights stacked
-    `[L, E, ...]`, `layer` picks the layer; `k` the router's experts a
-    token (the most non-zeros a row of `cw` has: what the kernel's grouped
-    form, a window of more than 256 rows, sizes its pair list by)."""
-    from .pallas_kernels import moe_experts as pme
-
-    if _experts_backend(z.shape[0], w_gate.shape, w_gate.dtype) == "pallas":
-        return pme.moe_topk_experts(z, cw, w_gate, w_up, w_down, layer,
-                                    tag=tag, k=k)
-    return pme._reference(z, cw, w_gate, w_up, w_down, layer)
-
-
 # ---------------------------------------------------------------------------
 # one layer, in two halves around the attention
 # ---------------------------------------------------------------------------
-
-
-def _mm(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_F32)
 
 
 def _pre_attention(x, p, positions, geom: Geometry):
@@ -735,7 +705,7 @@ def sparse_moe_stack_op(ctx: ExecContext):
         mark=opt("Mark"), num_pages=int(ctx.attr("num_pages", 0)))
     res = {"Logits": out["logits"], "Routes": out["routes"],
            "Selection": out["selection"],
-           "NextToken": jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)}
+           "NextToken": greedy_fn(out["logits"])}
     if paged:
         res.update(KVPoolOut=out["kv_pool"], IPoolOut=out["i_pool"])
     return res

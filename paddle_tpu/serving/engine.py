@@ -236,7 +236,7 @@ from ..data_feeder import _round_up_pow2
 from ..executor import Executor, Scope
 from ..framework import Program, program_guard
 from ..observability.slo import hist_p99_above
-from ..ops import (attention_ops, cca_moe_ops, kda_ops, latent_moe_ops,
+from ..ops import (attention_ops, decoder_common, latent_moe_ops,
                    parallel_ssm_ops, sparse_moe_ops)
 from ..resilience.faults import InjectedFault, fault_point
 from ..resilience.retry import serving_policy
@@ -678,7 +678,7 @@ class ServingEngine:
         self.state_pool = None
         self._scratch_slot = 0
         # which counters book the state's updates and scans
-        self._state_kind = "kda" if self.cfg.block == "kda_moe" else "ssm"
+        self._state_kind = self.cfg.family.state_kind
         if self.cfg.recurrent:
             if self.cfg.prefill_chunk % self.page_size:
                 raise ValueError(
@@ -2285,7 +2285,7 @@ class ServingEngine:
         """A window's routes, EVERY row the program ran (`routes` [rows,
         layers(, k)], the bucket's padding among them: the kernel multiplies
         those rows too): where its expert calls took the kernel's grouped
-        form (`cca_moe_ops.experts_grouped`: more rows than one token tile,
+        form (`decoder_common.experts_grouped`: more rows than one token tile,
         on the chip), the calls, the (row, held expert) pairs they
         multiplied and the rows of the tiles they ran."""
         from ..ops.pallas_kernels import moe_experts
@@ -2293,8 +2293,8 @@ class ServingEngine:
         cfg = self.cfg
         rows = len(routes)
         if rows not in self._grouped_rows:
-            self._grouped_rows[rows] = cfg.block != "mixer_moe" \
-                and cca_moe_ops.experts_grouped(
+            self._grouped_rows[rows] = cfg.family.grouped_experts \
+                and decoder_common.experts_grouped(
                     rows, (cfg.routed_layers, cfg.held_experts,
                            cfg.hidden_size, cfg.ffn_size), cfg.dtype)
         if not self._grouped_rows[rows]:
@@ -2767,11 +2767,7 @@ class ServingEngine:
             cfg = self.cfg
             pool = self._scope.find_var(STATE_POOLS[0]).shape
             runs = self._ssm_kernel_runs[bb] = \
-                kda_ops.kda_update_runs(pool, cfg.ssm_state) \
-                if cfg.block == "kda_moe" \
-                else parallel_ssm_ops.ssm_update_runs(
-                    bb, pool, cfg.ssm_heads, cfg.ssm_head_dim,
-                    cfg.ssm_groups, cfg.ssm_state)
+                cfg.family.state_update_runs(cfg, bb, pool)
         return runs
 
     def _attend_kernel(self, bb: int, pb: int) -> int:
@@ -2802,7 +2798,7 @@ class ServingEngine:
         times: every layer once a visit, and in a decode step of `rows`
         rows as many row x visit pairs (one paged attention call's row
         each)."""
-        if self.cfg.block == "looped_dense":
+        if self.cfg.family.loop_visits:
             self._count("loop.visits", self.cfg.cache_planes)
             self._count("loop.decode_row_visits",
                         rows * self.cfg.cache_planes)

@@ -1,11 +1,11 @@
 """Transactional KV handoff for disaggregated prefill/decode serving.
 
-ROADMAP item 2(a): the per-request page tables + refcounts make the
-prefill->decode transfer a TABLE move, not a copy — the windowed/decode
-programs already read pooled context, so a decode engine can adopt foreign
-pages the moment it learns their ids. The hard part is surviving a crash on
-either side of the move without leaking a page, double-freeing one, or
-changing one output token. This module is that protocol:
+The per-request page tables + refcounts make the prefill->decode transfer
+a TABLE move, not a copy — the windowed/decode programs already read pooled
+context, so a decode engine can adopt foreign pages the moment it learns
+their ids. The hard part is surviving a crash on either side of the move
+without leaking a page, double-freeing one, or changing one output token.
+This module is that protocol:
 
     PREPARE   the prefill replica finishes a prompt, extracts the request
               from its engine (`ServingEngine.extract_for_handoff` — the

@@ -1,6 +1,6 @@
 """Paged KV-cache manager: fixed-size pages over a preallocated HBM pool.
 
-The serving problem this solves (ROADMAP item 1 / "Ragged Paged Attention",
+The serving problem this solves ("Ragged Paged Attention",
 arXiv:2604.15464): a max-seq-len KV buffer per request wastes
 (max_len - actual_len) slots of HBM per request, which is what actually caps
 concurrent requests — not compute. Instead:

@@ -177,7 +177,9 @@ reads what prefill's startup initialized (or what a checkpoint restored).
 from __future__ import annotations
 
 import collections
+from collections.abc import Callable
 from dataclasses import dataclass
+from types import MappingProxyType
 
 import jax.numpy as jnp
 
@@ -355,184 +357,19 @@ class DecoderConfig:
     admit_per_step: int = 0
 
     def __post_init__(self):
-        if self.block not in _FAMILY:
+        if self.block not in FAMILIES:
             raise ValueError(f"unknown DecoderConfig.block {self.block!r} "
-                             f"({' | '.join(_FAMILY)})")
+                             f"({' | '.join(FAMILIES)})")
         for name in ("layer_types", "mlp_layer_types", "heads_per_layer",
                      "yarn", "mlp_multipliers", "ssm_multipliers",
                      "hc_res_clamp"):
             setattr(self, name, tuple(getattr(self, name)))
-        if self.block == "parallel_ssm":
-            if min(self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
-                   self.ssm_state, self.ssm_chunk, self.prefill_chunk) < 1 \
-                    or self.ssm_conv < 2 or self.ssm_heads % self.ssm_groups \
-                    or self.num_heads % self.kv_heads \
-                    or len(self.mlp_multipliers) != 2 \
-                    or len(self.ssm_multipliers) != 5:
-                raise ValueError(
-                    "block 'parallel_ssm' needs ssm_heads (a multiple of "
-                    "ssm_groups), ssm_head_dim, ssm_state, ssm_chunk, "
-                    "ssm_conv >= 2, prefill_chunk, num_kv_heads dividing "
-                    "num_heads, two mlp_multipliers and five "
-                    "ssm_multipliers")
-        if self.block == "mixer_moe":
-            kinds = collections.Counter(self.layer_pattern)
-            if len(self.layer_pattern) != self.num_layers \
-                    or set(kinds) - set("M*E") or not kinds["M"] \
-                    or not kinds["E"]:
-                raise ValueError(
-                    "block 'mixer_moe' needs layer_pattern of num_layers "
-                    "characters of 'M' (mixer), '*' (attention) and 'E' "
-                    "(experts), with at least one 'M' and one 'E'")
-            if min(self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
-                   self.ssm_state, self.ssm_chunk, self.prefill_chunk,
-                   self.latent_size, self.shared_expert_size) < 1 \
-                    or self.ssm_conv < 2 or self.ssm_heads % self.ssm_groups \
-                    or self.num_heads % self.kv_heads:
-                raise ValueError(
-                    "block 'mixer_moe' needs ssm_heads (a multiple of "
-                    "ssm_groups), ssm_head_dim, ssm_state, ssm_chunk, "
-                    "ssm_conv >= 2, prefill_chunk, latent_size, "
-                    "shared_expert_size and num_kv_heads dividing num_heads")
-            if not 1 <= self.experts_per_token <= self.num_experts \
-                    or not 1 <= self.held_experts <= self.num_experts:
-                raise ValueError(
-                    "block 'mixer_moe' needs num_experts >= "
-                    "experts_per_token >= 1 and 1 <= experts_held <= "
-                    "num_experts")
-        if self.block == "looped_dense":
-            if min(self.loop_steps, self.prefill_chunk) < 1 \
-                    or self.num_heads % self.kv_heads or self.head_dim % 2:
-                raise ValueError(
-                    "block 'looped_dense' needs loop_steps, prefill_chunk, "
-                    "num_kv_heads dividing num_heads and an even head")
-        if self.block == "kda_moe":
-            held = self.experts_held or self.num_experts
-            if min(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
-                   self.kda_sub_chunk, self.prefill_chunk, self.kv_lora_rank,
-                   self.rope_head_dim, self.v_head_dim,
-                   self.shared_expert_size, self.dense_ffn_size) < 1 \
-                    or self.ssm_conv < 2 or self.q_lora_rank \
-                    or self.ssm_chunk % self.kda_sub_chunk \
-                    or self.kda_sub_chunk % 2 or self.kda_lower_bound >= 0 \
-                    or self.kda_lower_bound * self.kda_sub_chunk < -160 \
-                    or self.rope_head_dim % 2 or self.kv_lora_rank % 2:
-                raise ValueError(
-                    "block 'kda_moe' needs ssm_heads, ssm_head_dim, "
-                    "ssm_state, ssm_conv >= 2, ssm_chunk in whole "
-                    "kda_sub_chunk (even; kda_lower_bound < 0 times half of "
-                    "it is an exponent float32 must hold), prefill_chunk, "
-                    "kv_lora_rank and rope_head_dim (even), v_head_dim, "
-                    "shared_expert_size, dense_ffn_size and NO q_lora_rank")
-            if not 2 <= self.layer_group_size <= self.num_layers \
-                    or not 0 <= self.dense_layers < self.num_layers:
-                raise ValueError(
-                    "block 'kda_moe' needs 2 <= layer_group_size <= "
-                    "num_layers (a latent layer among every few, at least "
-                    "one of each kind) and 0 <= dense_layers < num_layers")
-            if not 1 <= self.experts_per_token <= self.num_experts \
-                    or self.num_experts % self.expert_groups \
-                    or not 1 <= self.groups_per_token <= self.expert_groups \
-                    or self.num_experts // self.expert_groups < 2 \
-                    or self.experts_per_token > self.groups_per_token \
-                    * (self.num_experts // self.expert_groups) \
-                    or not 1 <= held <= self.num_experts:
-                raise ValueError(
-                    "block 'kda_moe' needs num_experts in expert_groups "
-                    "equal groups of at least two, groups_per_token of "
-                    "them holding experts_per_token, and 1 <= experts_held "
-                    "<= num_experts")
-        if self.block == "hybrid_moe":
-            layer_plan(self)       # raises on lists that name no plan
-            if min(self.sliding_window, self.prefill_chunk,
-                   self.shared_expert_size) < 1 or self.yarn \
-                    and len(self.yarn) != 5:
-                raise ValueError(
-                    "block 'hybrid_moe' needs sliding_window, prefill_chunk, "
-                    "shared_expert_size and yarn as () or (factor, original "
-                    "context, beta_fast, beta_slow, attention factor)")
-            if not 1 <= self.experts_per_token <= self.num_experts:
-                raise ValueError("block 'hybrid_moe' needs num_experts >= "
-                                 "experts_per_token >= 1")
-        if self.block == "latent_moe":
-            held = self.experts_held or self.num_experts
-            indexer = (self.index_heads, self.index_head_dim,
-                       self.index_topk)
-            if min(self.q_lora_rank, self.kv_lora_rank, self.rope_head_dim,
-                   self.v_head_dim, self.prefill_chunk,
-                   self.shared_expert_size) < 1 \
-                    or self.rope_head_dim % 2 or self.kv_lora_rank % 2 \
-                    or any(indexer) and (
-                        min(indexer) < 1
-                        or self.index_head_dim < self.rope_head_dim) \
-                    or self.yarn and len(self.yarn) != 5:
-                raise ValueError(
-                    "block 'latent_moe' needs q_lora_rank, kv_lora_rank and "
-                    "rope_head_dim (even), v_head_dim, prefill_chunk, "
-                    "shared_expert_size, yarn as () or five values, and "
-                    "an indexer given whole or not at all: index_heads, "
-                    "index_head_dim (at least rope_head_dim: its first "
-                    "lanes carry the rotary) and index_topk")
-            if self.hc_mult < 1 or self.hc_mult > 1 and (
-                    self.hc_sinkhorn_iters < 1 or self.hc_eps <= 0
-                    or len(self.hc_res_clamp) != 2
-                    or self.hc_res_clamp[0] >= self.hc_res_clamp[1]):
-                raise ValueError(
-                    "block 'latent_moe' with hc_mult > 1 residual streams "
-                    "needs hc_sinkhorn_iters >= 1, hc_eps > 0 and "
-                    "hc_res_clamp as (lowest, highest)")
-            if not 1 <= self.dense_layers < self.num_layers \
-                    or self.dense_ffn_size < 1:
-                raise ValueError(
-                    "block 'latent_moe' needs 1 <= dense_layers < "
-                    "num_layers (dense layers lead, routed ones follow) "
-                    "and dense_ffn_size")
-            if not 1 <= self.experts_per_token <= self.num_experts \
-                    or self.num_experts % self.expert_groups \
-                    or not 1 <= self.groups_per_token <= self.expert_groups \
-                    or self.num_experts // self.expert_groups < 2 \
-                    or self.experts_per_token > self.groups_per_token \
-                    * (self.num_experts // self.expert_groups) \
-                    or not 1 <= held <= self.num_experts:
-                raise ValueError(
-                    "block 'latent_moe' needs num_experts in expert_groups "
-                    "equal groups of at least two, groups_per_token of "
-                    "them holding experts_per_token, and 1 <= experts_held "
-                    "<= num_experts")
-        if self.block == "sparse_moe":
-            if min(self.index_heads, self.index_head_dim, self.index_topk,
-                   self.prefill_chunk) < 1 or self.index_head_dim % 4:
-                raise ValueError(
-                    "block 'sparse_moe' needs index_heads, index_head_dim "
-                    "(a multiple of 4: half of it carries rotary), "
-                    "index_topk and prefill_chunk")
-            if not 1 <= self.experts_per_token <= self.num_experts:
-                raise ValueError("block 'sparse_moe' needs num_experts >= "
-                                 "experts_per_token >= 1")
-            if self.num_heads % self.kv_heads:
-                raise ValueError("num_kv_heads must divide num_heads")
-            if self.kv_heads % 2 and jnp.dtype(self.dtype).itemsize == 2:
-                raise ValueError(
-                    "block 'sparse_moe' in a 16-bit dtype needs an even "
-                    "num_kv_heads: the halves of a token's K (and V) share "
-                    "32-bit words (sparse_moe_ops.join_rows_fn)")
         if self.min_row_bucket < 1 \
                 or self.min_row_bucket & (self.min_row_bucket - 1):
             raise ValueError("min_row_bucket must be a power of two")
         if self.admit_per_step < 0:
             raise ValueError("admit_per_step must be 0 (no cap) or more")
-        if self.block == "cca_moe":
-            if (self.cca_time0, self.cca_time1) != (2, 2):
-                raise ValueError(
-                    "block 'cca_moe' carries one token of convolution "
-                    "state: cca_time0 and cca_time1 must be 2")
-            if self.kv_heads != 2 or self.num_heads % 2:
-                raise ValueError(
-                    "block 'cca_moe' builds its values from two halves "
-                    "(this token, the last): num_kv_heads must be 2")
-            if self.num_experts < 1 or self.router_hidden_size < 1:
-                raise ValueError("block 'cca_moe' needs num_experts and "
-                                 "router_hidden_size")
+        self.family.validate(self)
 
     @property
     def head_dim(self) -> int:
@@ -543,45 +380,44 @@ class DecoderConfig:
         return self.num_kv_heads or self.num_heads
 
     @property
+    def family(self) -> "Family":
+        """The row of `FAMILIES` this configuration's `block` names: every
+        answer below that depends on the family is read from it."""
+        return FAMILIES[self.block]
+
+    @property
     def stateful(self) -> bool:
         """Whether a sequence carries state besides its K/V (one row a page
         in the state pool)."""
-        return self.block == "cca_moe"
+        return self.family.stateful
 
     @property
     def scanned(self) -> bool:
         """Whether the layers are one scanned op over stacked weights and
         stacked pools (`kv_cache.STACKED_POOLS`). Speculation, tensor
         parallelism and the fleet handoff are not written for that form."""
-        return self.block in ("cca_moe", "sparse_moe", "hybrid_moe",
-                              "parallel_ssm", "latent_moe", "mixer_moe",
-                              "kda_moe", "looped_dense")
+        return bool(self.family.op)
 
     @property
     def cache_planes(self) -> int:
         """Slabs of K/V a page id names in the stacked pools: one a layer,
         or, where a token passes the layers `loop_steps` times
         ("looped_dense"), one a VISIT of a layer."""
-        return self.num_layers * (self.loop_steps
-                                  if self.block == "looped_dense" else 1)
+        return self.family.cache_planes(self)
 
     @property
     def recurrent(self) -> bool:
         """Whether a sequence carries a state that every token rewrites in
         place (a slot of `kv_cache.STATE_POOLS`, not a row a page): the
         prefix cache resumes it from snapshots only."""
-        return self.block in ("parallel_ssm", "mixer_moe", "kda_moe")
+        return self.family.second_pool == "state_slots"
 
     @property
     def state_layers(self) -> int:
         """Layers that hold a recurrent state (the layers of
         `kv_cache.STATE_POOLS`): every layer of "parallel_ssm", the mixers
         of "mixer_moe", the Kimi-Delta layers of "kda_moe"."""
-        if self.block == "mixer_moe":
-            return self.layer_pattern.count(mixer_moe_ops.MIXER)
-        if self.block == "kda_moe":
-            return self.num_layers - self.latent_layers
-        return self.num_layers if self.recurrent else 0
+        return self.family.state_layers(self)
 
     @property
     def mixer_kinds(self) -> str:
@@ -602,20 +438,13 @@ class DecoderConfig:
     def windowed(self) -> bool:
         """Whether some layers attend a sliding window only and keep their
         K/V in a second pool under page ids of their own."""
-        return self.block == "hybrid_moe"
+        return self.family.second_pool == "window_pages"
 
     @property
     def routed_layers(self) -> int:
         """Layers that route tokens to experts (the middle axis of a
         request's `routes`)."""
-        if self.block == "hybrid_moe":
-            return sum(kind == "sparse" for kind in self.mlp_layer_types)
-        if self.block in ("latent_moe", "kda_moe"):
-            return self.num_layers - self.dense_layers
-        if self.block == "mixer_moe":
-            return self.layer_pattern.count(mixer_moe_ops.EXPERTS)
-        return 0 if self.block in ("parallel_ssm", "looped_dense") \
-            else self.num_layers
+        return self.family.routed_layers(self)
 
     @property
     def held_experts(self) -> int:
@@ -631,23 +460,20 @@ class DecoderConfig:
         one row of one pool, and every step reports what it attended.
         "latent_moe" without an indexer (`index_topk` 0) attends every
         cached row and has none of these."""
-        return self.block == "sparse_moe" \
-            or self.block == "latent_moe" and self.index_topk > 0
+        return self.family.selects(self)
 
     @property
     def latent(self) -> bool:
         """Whether a token's cache row is ONE compressed row (a latent and
         its rotary key in `kv_cache.LATENT_POOL`) that every head reads."""
-        return self.block in ("latent_moe", "kda_moe")
+        return any(pool == LATENT_POOL for _, pool in self.family.pools)
 
     @property
     def latent_layers(self) -> int:
         """Layers whose cache is such a row (the layers of
         `kv_cache.LATENT_POOL`): every layer of "latent_moe", every
         `layer_group_size`-th of "kda_moe"."""
-        if self.block == "kda_moe":
-            return self.num_layers // self.layer_group_size
-        return self.num_layers if self.latent else 0
+        return self.family.latent_layers(self)
 
     def selects_within(self, slots: int) -> bool:
         """Whether a decode step over a page table of `slots` slots runs
@@ -658,33 +484,15 @@ class DecoderConfig:
     def page_bucket_step(self) -> int:
         """0: page tables round up to a power of two. n: past n pages
         they round to a multiple of n, for a family whose every step scans
-        its whole table (the dead part stays under an eighth).
-        "looped_dense": 16 pages, whole grid steps of its paged decode
-        kernel at pages of 16, 32 or 64 tokens (16, 8 or 4 pages a step:
-        `paged_attention.pages_per_grid_step`), so its one page bucket is
-        the context cap's own width and not a block of dead steps wider."""
-        if self.block == "looped_dense":
-            return 16
-        return 32 if self.selects or self.latent else 0
+        its whole table (the dead part stays under an eighth); the rows of
+        `FAMILIES` say why each has the step it has."""
+        return self.family.page_bucket_step
 
     @property
     def one_page_bucket(self) -> bool:
         """Whether every step is compiled at ONE page-table width, that of
-        `max_position`: a family whose layers are unrolled pays a compile
-        of every layer for each (row bucket, page bucket) program (ten page
-        buckets below 19k tokens are seventy decode programs), and whose
-        paged decode kernels pass a block of dead pages in a grid step of
-        a third of a microsecond. "parallel_ssm" compiles every layer once
-        (a scan) but streams 7.8 GB of weights a step whatever the table's
-        width: six page buckets would be six times the programs to warm
-        for nothing a step could gain. "looped_dense" is the family whose
-        POOL bounds the rows in flight: a row that lost its pages comes back
-        as a prompt of its own prompt and everything it had produced, at
-        lengths no arrival has, and under one width its windows are the
-        programs the arrivals' windows compiled (a window's length is its
-        only other shape)."""
-        return self.windowed or self.recurrent \
-            or self.block == "looped_dense"
+        `max_position`; the rows of `FAMILIES` say why."""
+        return self.family.one_page_bucket
 
 
 def decoder_tiny() -> DecoderConfig:
@@ -809,9 +617,6 @@ def mixer_moe_tiny(**over) -> DecoderConfig:
     return DecoderConfig(**kw)
 
 
-# -- the "cca_moe" family ----------------------------------------------------
-
-
 def kda_moe_tiny(**over) -> DecoderConfig:
     """The "kda_moe" block at test size: six layers, five Kimi-Delta (4
     heads, a state of 8 key channels x 8 values, chunks of 8 in sub-blocks
@@ -840,6 +645,21 @@ def looped_dense_tiny(**over) -> DecoderConfig:
         max_position=128), **over})
 
 
+# -- the "cca_moe" family ----------------------------------------------------
+
+
+def _validate_cca(cfg: DecoderConfig) -> None:
+    if (cfg.cca_time0, cfg.cca_time1) != (2, 2):
+        raise ValueError("block 'cca_moe' carries one token of convolution "
+                         "state: cca_time0 and cca_time1 must be 2")
+    if cfg.kv_heads != 2 or cfg.num_heads % 2:
+        raise ValueError("block 'cca_moe' builds its values from two halves "
+                         "(this token, the last): num_kv_heads must be 2")
+    if cfg.num_experts < 1 or cfg.router_hidden_size < 1:
+        raise ValueError("block 'cca_moe' needs num_experts and "
+                         "router_hidden_size")
+
+
 def _cca_geometry(cfg: DecoderConfig) -> dict:
     return {"num_heads": cfg.num_heads, "num_kv_heads": cfg.kv_heads,
             "head_dim": cfg.head_dim,
@@ -848,24 +668,17 @@ def _cca_geometry(cfg: DecoderConfig) -> dict:
             "eps": float(cfg.rms_norm_eps)}
 
 
-def cca_state_width(cfg: DecoderConfig) -> int:
-    return cca_moe_ops.state_width(
-        cca_moe_ops.Geometry(**_cca_geometry(cfg)))
-
-
 def _cca_pool_geometry(cfg: DecoderConfig, num_pages: int, page_size: int):
+    state = cca_moe_ops.state_width(cca_moe_ops.Geometry(**_cca_geometry(cfg)))
     return (cfg.num_layers, num_pages, page_size,
-            cfg.kv_heads * cfg.head_dim, cca_state_width(cfg), cfg.dtype)
+            cfg.kv_heads * cfg.head_dim, state, cfg.dtype)
 
 
 def stacked_pool_geometry(cfg: DecoderConfig, num_pages: int,
                           page_size: int) -> tuple:
-    """`kv_cache.stacked_pool_shapes`' arguments for a scanned family."""
-    geometry = {"sparse_moe": _sparse_pool_geometry,
-                "latent_moe": _latent_pool_geometry,
-                "looped_dense": _looped_pool_geometry}.get(
-                    cfg.block, _cca_pool_geometry)
-    return geometry(cfg, num_pages, page_size)
+    """`kv_cache.stacked_pool_shapes`' arguments for a scanned family whose
+    only pools are paged (the row's `pool_geometry`)."""
+    return cfg.family.pool_geometry(cfg, num_pages, page_size)
 
 
 def _cca_param_specs(cfg: DecoderConfig) -> dict:
@@ -919,37 +732,26 @@ def cca_param_name(key: str) -> str:
     return key if key.startswith("dec.") else "dec.layers." + key
 
 
-def _cca_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
-               page_size: int = 0, **feeds):
-    """Append the one `cca_moe_stack` op of a program; returns its outputs
-    (next_token, logits, routes)."""
-    helper = LayerHelper("cca_moe_stack")
-    specs = _cca_param_specs(cfg)
-    params = {key: helper.create_parameter(
-        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
-        for key, (shape, dtype, init) in specs.items()}
-    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
-              "FinalNorm": [params["dec.final_norm.scale"]],
-              "LayerParams": [params[k] for k in cca_moe_ops.LAYER_PARAMS],
-              "Experts": [params[k] for k in cca_moe_ops.EXPERT_PARAMS]}
-    inputs.update({slot: [var] for slot, var in feeds.items()})
-    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
-            for slot, dtype in (("NextToken", "int32"),
-                                ("Logits", "float32"), ("Routes", "int32"))}
-    if mode != "full":
-        declare_stacked_pools(default_main_program().global_block,
-                              *_cca_pool_geometry(cfg, num_pages, page_size))
-        for slot, name in zip(("KPool", "VPool", "SPool"), STACKED_POOLS):
-            inputs[slot] = [name]
-            outs[slot + "Out"] = [name]
-    helper.append_op("cca_moe_stack", inputs, outs,
-                     dict(_cca_geometry(cfg), mode=mode,
-                          num_pages=int(num_pages)))
-    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
-            "routes": outs["Routes"][0]}
-
-
 # -- the "sparse_moe" family -------------------------------------------------
+
+
+def _validate_sparse(cfg: DecoderConfig) -> None:
+    if min(cfg.index_heads, cfg.index_head_dim, cfg.index_topk,
+           cfg.prefill_chunk) < 1 or cfg.index_head_dim % 4:
+        raise ValueError(
+            "block 'sparse_moe' needs index_heads, index_head_dim (a "
+            "multiple of 4: half of it carries rotary), index_topk and "
+            "prefill_chunk")
+    if not 1 <= cfg.experts_per_token <= cfg.num_experts:
+        raise ValueError("block 'sparse_moe' needs num_experts >= "
+                         "experts_per_token >= 1")
+    if cfg.num_heads % cfg.kv_heads:
+        raise ValueError("num_kv_heads must divide num_heads")
+    if cfg.kv_heads % 2 and jnp.dtype(cfg.dtype).itemsize == 2:
+        raise ValueError(
+            "block 'sparse_moe' in a 16-bit dtype needs an even num_kv_heads: "
+            "the halves of a token's K (and V) share 32-bit words "
+            "(sparse_moe_ops.join_rows_fn)")
 
 
 def _sparse_geometry(cfg: DecoderConfig) -> dict:
@@ -965,10 +767,6 @@ def _sparse_pool_geometry(cfg: DecoderConfig, num_pages: int,
     return (cfg.num_layers, num_pages, page_size,
             cfg.kv_heads * cfg.head_dim, 0, cfg.dtype, cfg.index_head_dim,
             True)
-
-
-# a token's K and V in one row of one pool: this block gathers tokens
-_SPARSE_POOLS = (("KVPool", JOINED_POOL), ("IPool", INDEX_POOL))
 
 
 def _sparse_param_specs(cfg: DecoderConfig) -> dict:
@@ -1010,102 +808,24 @@ def _sparse_param_specs(cfg: DecoderConfig) -> dict:
     }
 
 
-def _sparse_stack(cfg: DecoderConfig, mode: str, tok, pos,
-                  num_pages: int = 0, page_size: int = 0, **feeds):
-    """Append the one `sparse_moe_stack` op of a program; returns its
-    outputs (next_token, logits, routes, selection)."""
-    helper = LayerHelper("sparse_moe_stack")
-    params = {key: helper.create_parameter(
-        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
-        for key, (shape, dtype, init) in _sparse_param_specs(cfg).items()}
-    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
-              "Head": [params["dec.lm_head"]],
-              "FinalNorm": [params["dec.final_norm.scale"]],
-              "LayerParams": [params[k]
-                              for k in sparse_moe_ops.LAYER_PARAMS],
-              "Experts": [params[k] for k in sparse_moe_ops.EXPERT_PARAMS]}
-    inputs.update({slot: [var] for slot, var in feeds.items()})
-    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
-            for slot, dtype in (("NextToken", "int32"),
-                                ("Logits", "float32"), ("Routes", "int32"),
-                                ("Selection", "int32"))}
-    if mode != "full":
-        declare_stacked_pools(default_main_program().global_block,
-                              *_sparse_pool_geometry(cfg, num_pages,
-                                                     page_size))
-        for slot, name in _SPARSE_POOLS:
-            inputs[slot] = [name]
-            outs[slot + "Out"] = [name]
-    helper.append_op("sparse_moe_stack", inputs, outs,
-                     dict(_sparse_geometry(cfg), mode=mode,
-                          num_pages=int(num_pages)))
-    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
-            "routes": outs["Routes"][0],
-            "selection": outs["Selection"][0]}
-
-
-def _sparse_window_io(out):
-    io = {"next_token": out["next_token"], "last_logits": out["logits"],
-          "routes": out["routes"]}
-    if "selection" in out:      # "latent_moe" without an indexer has none
-        io["selection"] = out["selection"]
-    return io
-
-
-def _sparse_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
-    return _sparse_window_io(_sparse_stack(
-        cfg, "prefill", tok, pos, num_pages, page_size, PageTable=pages,
-        Lens=lens))
-
-
-def _sparse_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
-                   lens):
-    # a prompt's chunk or the suffix behind a prefix hit (no verify window)
-    return _sparse_window_io(_sparse_stack(
-        cfg, "window", tok, pos, num_pages, page_size, PageTable=pages,
-        Start=start, Lens=lens))
-
-
-def _sparse_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
-    # [MARK_ROWS] int32: the rows whose selection comes back, -1 unused
-    mark = L.data(name=MARK_FEED, shape=[MARK_ROWS], dtype="int32",
-                  append_batch_size=False)
-    return dict(_sparse_stack(cfg, "decode", tok, pos, num_pages, page_size,
-                              PageTable=pages, Mask=mask, Mark=mark),
-                extra_feeds=[MARK_FEED])
-
-
-def _sparse_full(cfg, tok, pos):
-    out = _sparse_stack(cfg, "full", tok, pos)
-    return {"logits": out["logits"], "routes": out["routes"],
-            "selection": out["selection"]}
-
-
-def _sparse_cow(cfg, num_pages, page_size, src, dst):
-    # the page's slab of joined K/V rows and its indexer keys, in every layer
-    declare_stacked_pools(default_main_program().global_block,
-                          *_sparse_pool_geometry(cfg, num_pages, page_size))
-    _stacked_copy_page([name for _, name in _SPARSE_POOLS], num_pages, src,
-                       dst)
-
-
-def _stacked_copy_page(pools, num_pages, src, dst):
-    """Append the copy of page Src to page Dst in every layer of up to
-    three stacked pools (`cca_state_copy_page` copies rows `l * num_pages
-    + page` of whatever it is given)."""
-    slots = dict(zip(("KPool", "VPool", "SPool"), pools))
-    LayerHelper("cca_state_copy_page").append_op(
-        "cca_state_copy_page",
-        dict({k: [v] for k, v in slots.items()}, Src=[src], Dst=[dst]),
-        {k + "Out": [v] for k, v in slots.items()},
-        {"num_pages": int(num_pages)})
-
-
 # -- the "hybrid_moe" family -------------------------------------------------
 
 _ATTENTION_KINDS = {"full_attention": hybrid_moe_ops.FULL,
                     "sliding_attention": hybrid_moe_ops.SLIDE}
 _FFN_KINDS = {"dense": hybrid_moe_ops.DENSE, "sparse": hybrid_moe_ops.MOE}
+
+
+def _validate_hybrid(cfg: DecoderConfig) -> None:
+    layer_plan(cfg)        # raises on lists that name no plan
+    if min(cfg.sliding_window, cfg.prefill_chunk,
+           cfg.shared_expert_size) < 1 or cfg.yarn and len(cfg.yarn) != 5:
+        raise ValueError(
+            "block 'hybrid_moe' needs sliding_window, prefill_chunk, "
+            "shared_expert_size and yarn as () or (factor, original "
+            "context, beta_fast, beta_slow, attention factor)")
+    if not 1 <= cfg.experts_per_token <= cfg.num_experts:
+        raise ValueError("block 'hybrid_moe' needs num_experts >= "
+                         "experts_per_token >= 1")
 
 
 def layer_plan(cfg: DecoderConfig) -> tuple:
@@ -1244,8 +964,9 @@ def _hybrid_param_specs(cfg: DecoderConfig) -> dict:
     return specs
 
 
-_HYBRID_POOLS = tuple(zip(("KPool", "VPool", "WKPool", "WVPool"),
-                          STACKED_POOLS[:2] + WINDOW_POOLS))
+def _declare_paged_pools(cfg, num_pages, page_size, second=0):
+    declare_stacked_pools(default_main_program().global_block,
+                          *stacked_pool_geometry(cfg, num_pages, page_size))
 
 
 def _declare_hybrid_pools(cfg, num_pages, page_size, window_pages):
@@ -1254,101 +975,52 @@ def _declare_hybrid_pools(cfg, num_pages, page_size, window_pages):
         declare_stacked_pools(default_main_program().global_block, *geometry)
 
 
-def _hybrid_stack(cfg: DecoderConfig, mode: str, tok, pos,
-                  num_pages: int = 0, page_size: int = 0,
-                  window_pages: int = 0, **feeds):
-    """Append the one `hybrid_moe_stack` op of a program; returns its
-    outputs (next_token, logits, routes)."""
-    helper = LayerHelper("hybrid_moe_stack")
-    params = {key: helper.create_parameter(
-        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
-        for key, (shape, dtype, init) in _hybrid_param_specs(cfg).items()}
-    ops = hybrid_moe_ops
-    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
-              "Head": [params["dec.lm_head"]],
-              "FinalNorm": [params["dec.final_norm.scale"]],
-              "LayerParams": [params[k] for k in ops.LAYER_PARAMS],
-              "FullParams": [params["full." + k]
-                             for k in ops.ATTENTION_PARAMS],
-              "SlideParams": [params["slide." + k]
-                              for k in ops.ATTENTION_PARAMS],
-              "DenseParams": [params["dense." + k]
-                              for k in ops.DENSE_PARAMS],
-              "MoeParams": [params["moe." + k] for k in ops.MOE_PARAMS],
-              "Experts": [params[k] for k in ops.EXPERT_PARAMS]}
-    inputs.update({slot: [var] for slot, var in feeds.items()})
-    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
-            for slot, dtype in (("NextToken", "int32"),
-                                ("Logits", "float32"), ("Routes", "int32"))}
-    if mode != "full":
-        _declare_hybrid_pools(cfg, num_pages, page_size, window_pages)
-        for slot, name in _HYBRID_POOLS:
-            inputs[slot] = [name]
-            outs[slot + "Out"] = [name]
-    helper.append_op(
-        "hybrid_moe_stack", inputs, outs,
-        dict(_hybrid_geometry(cfg), mode=mode, num_pages=int(num_pages),
-             window_pages=int(window_pages),
-             plan=[str(v) for layer in layer_plan(cfg) for v in layer]))
-    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
-            "routes": outs["Routes"][0]}
-
-
-def _window_feeds():
-    """The two feeds of a row's compact table in the sliding layers' pool."""
-    return {"WindowTable": L.data(name=WPAGES_FEED, shape=[1], dtype="int32"),
-            "WindowBase": L.data(name=WBASE_FEED, shape=[], dtype="int32")}
-
-
-def _hybrid_window_io(out):
-    return {"next_token": out["next_token"], "last_logits": out["logits"],
-            "routes": out["routes"],
-            "extra_feeds": [WPAGES_FEED, WBASE_FEED]}
-
-
-def _hybrid_prefill(cfg, num_pages, page_size, tok, pos, pages, lens,
-                    window_pages=0):
-    return _hybrid_window_io(_hybrid_stack(
-        cfg, "prefill", tok, pos, num_pages, page_size, window_pages,
-        PageTable=pages, Lens=lens, **_window_feeds()))
-
-
-def _hybrid_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
-                   lens, window_pages=0):
-    # a prompt's chunk or the suffix behind a prefix hit (no verify window)
-    return _hybrid_window_io(_hybrid_stack(
-        cfg, "window", tok, pos, num_pages, page_size, window_pages,
-        PageTable=pages, Start=start, Lens=lens, **_window_feeds()))
-
-
-def _hybrid_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask,
-                   window_pages=0):
-    return dict(_hybrid_stack(cfg, "decode", tok, pos, num_pages, page_size,
-                              window_pages, PageTable=pages, Mask=mask,
-                              **_window_feeds()),
-                extra_feeds=[WPAGES_FEED, WBASE_FEED])
-
-
-def _hybrid_full(cfg, tok, pos):
-    out = _hybrid_stack(cfg, "full", tok, pos)
-    return {"logits": out["logits"], "routes": out["routes"]}
-
-
-def _hybrid_cow(cfg, num_pages, page_size, src, dst, window_pages=0):
-    # a page of the full layers' pool and a page of the sliding layers'
-    _declare_hybrid_pools(cfg, num_pages, page_size, window_pages)
-    wsrc = L.data(name=COW_WSRC_FEED, shape=[], dtype="int32")
-    wdst = L.data(name=COW_WDST_FEED, shape=[], dtype="int32")
-    LayerHelper("hybrid_copy_page").append_op(
-        "hybrid_copy_page",
-        dict({slot: [name] for slot, name in _HYBRID_POOLS}, Src=[src],
-             Dst=[dst], WSrc=[wsrc], WDst=[wdst]),
-        {slot + "Out": [name] for slot, name in _HYBRID_POOLS},
-        {"num_pages": int(num_pages), "window_pages": int(window_pages)})
-    return [COW_WSRC_FEED, COW_WDST_FEED]
-
-
 # -- the "latent_moe" family -------------------------------------------------
+
+
+def _validate_expert_groups(cfg: DecoderConfig) -> None:
+    """The group-limited router's fields ("latent_moe", "kda_moe")."""
+    if not 1 <= cfg.experts_per_token <= cfg.num_experts \
+            or cfg.num_experts % cfg.expert_groups \
+            or not 1 <= cfg.groups_per_token <= cfg.expert_groups \
+            or cfg.num_experts // cfg.expert_groups < 2 \
+            or cfg.experts_per_token > cfg.groups_per_token \
+            * (cfg.num_experts // cfg.expert_groups) \
+            or not 1 <= cfg.held_experts <= cfg.num_experts:
+        raise ValueError(
+            f"block {cfg.block!r} needs num_experts in expert_groups equal "
+            "groups of at least two, groups_per_token of them holding "
+            "experts_per_token, and 1 <= experts_held <= num_experts")
+
+
+def _validate_latent(cfg: DecoderConfig) -> None:
+    indexer = (cfg.index_heads, cfg.index_head_dim, cfg.index_topk)
+    if min(cfg.q_lora_rank, cfg.kv_lora_rank, cfg.rope_head_dim,
+           cfg.v_head_dim, cfg.prefill_chunk, cfg.shared_expert_size) < 1 \
+            or cfg.rope_head_dim % 2 or cfg.kv_lora_rank % 2 \
+            or any(indexer) and (
+                min(indexer) < 1 or cfg.index_head_dim < cfg.rope_head_dim) \
+            or cfg.yarn and len(cfg.yarn) != 5:
+        raise ValueError(
+            "block 'latent_moe' needs q_lora_rank, kv_lora_rank and "
+            "rope_head_dim (even), v_head_dim, prefill_chunk, "
+            "shared_expert_size, yarn as () or five values, and an indexer "
+            "given whole or not at all: index_heads, index_head_dim (at "
+            "least rope_head_dim: its first lanes carry the rotary) and "
+            "index_topk")
+    if cfg.hc_mult < 1 or cfg.hc_mult > 1 and (
+            cfg.hc_sinkhorn_iters < 1 or cfg.hc_eps <= 0
+            or len(cfg.hc_res_clamp) != 2
+            or cfg.hc_res_clamp[0] >= cfg.hc_res_clamp[1]):
+        raise ValueError(
+            "block 'latent_moe' with hc_mult > 1 residual streams needs "
+            "hc_sinkhorn_iters >= 1, hc_eps > 0 and hc_res_clamp as "
+            "(lowest, highest)")
+    if not 1 <= cfg.dense_layers < cfg.num_layers or cfg.dense_ffn_size < 1:
+        raise ValueError(
+            "block 'latent_moe' needs 1 <= dense_layers < num_layers "
+            "(dense layers lead, routed ones follow) and dense_ffn_size")
+    _validate_expert_groups(cfg)
 
 
 def _latent_geometry(cfg: DecoderConfig) -> dict:
@@ -1377,11 +1049,12 @@ def _latent_pool_geometry(cfg: DecoderConfig, num_pages: int,
             cfg.index_head_dim, False, STACKED_POOLS, True)
 
 
-def _latent_pools(cfg: DecoderConfig) -> tuple:
-    """(op slot, pool) of the family's pools: a token's latent and rotary
-    key in one row of one pool and, behind an indexer, its indexer key."""
-    return (("LatentPool", LATENT_POOL),) + (
-        (("IPool", INDEX_POOL),) if cfg.selects else ())
+def _latent_groups(cfg: DecoderConfig) -> tuple:
+    shared = latent_moe_ops.attention_params(cfg.selects, cfg.hc_mult)
+    return _EMB_HEAD_NORM + (
+        ("DenseParams", "dense.", shared + latent_moe_ops.DENSE_PARAMS),
+        ("MoeParams", "moe.", shared + latent_moe_ops.MOE_PARAMS),
+        ("Experts", "", latent_moe_ops.EXPERT_PARAMS))
 
 
 # standard deviations of a sub-layer's biases `hc_b`: pre [n], post [n],
@@ -1489,87 +1162,20 @@ def _hc_param_specs(cfg: DecoderConfig, layers: int) -> dict:
             columns=[(n, pre), (n, post), (n * n, res)]))}
 
 
-def _latent_stack(cfg: DecoderConfig, mode: str, tok, pos,
-                  num_pages: int = 0, page_size: int = 0, **feeds):
-    """Append the one `latent_moe_stack` op of a program; returns its
-    outputs (next_token, logits, routes and, behind an indexer,
-    selection)."""
-    helper = LayerHelper("latent_moe_stack")
-    params = {key: helper.create_parameter(
-        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
-        for key, (shape, dtype, init) in _latent_param_specs(cfg).items()}
-    ops = latent_moe_ops
-    shared = ops.attention_params(cfg.selects, cfg.hc_mult)
-    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
-              "Head": [params["dec.lm_head"]],
-              "FinalNorm": [params["dec.final_norm.scale"]],
-              "DenseParams": [params["dense." + k] for k in
-                              shared + ops.DENSE_PARAMS],
-              "MoeParams": [params["moe." + k] for k in
-                            shared + ops.MOE_PARAMS],
-              "Experts": [params[k] for k in ops.EXPERT_PARAMS]}
-    inputs.update({slot: [var] for slot, var in feeds.items()})
-    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
-            for slot, dtype in (("NextToken", "int32"),
-                                ("Logits", "float32"), ("Routes", "int32"))
-            + ((("Selection", "int32"),) if cfg.selects else ())}
-    if mode != "full":
-        declare_stacked_pools(default_main_program().global_block,
-                              *_latent_pool_geometry(cfg, num_pages,
-                                                     page_size))
-        for slot, name in _latent_pools(cfg):
-            inputs[slot] = [name]
-            outs[slot + "Out"] = [name]
-    helper.append_op("latent_moe_stack", inputs, outs,
-                     dict(_latent_geometry(cfg), mode=mode,
-                          num_pages=int(num_pages)))
-    out = {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
-           "routes": outs["Routes"][0]}
-    if cfg.selects:
-        out["selection"] = outs["Selection"][0]
-    return out
-
-
-def _latent_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
-    return _sparse_window_io(_latent_stack(
-        cfg, "prefill", tok, pos, num_pages, page_size, PageTable=pages,
-        Lens=lens))
-
-
-def _latent_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
-                   lens):
-    # a prompt's chunk or the suffix behind a prefix hit (no verify window)
-    return _sparse_window_io(_latent_stack(
-        cfg, "window", tok, pos, num_pages, page_size, PageTable=pages,
-        Start=start, Lens=lens))
-
-
-def _latent_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
-    if not cfg.selects:     # no selection to hand back, so no rows to mark
-        return _latent_stack(cfg, "decode", tok, pos, num_pages, page_size,
-                             PageTable=pages, Mask=mask)
-    mark = L.data(name=MARK_FEED, shape=[MARK_ROWS], dtype="int32",
-                  append_batch_size=False)
-    return dict(_latent_stack(cfg, "decode", tok, pos, num_pages, page_size,
-                              PageTable=pages, Mask=mask, Mark=mark),
-                extra_feeds=[MARK_FEED])
-
-
-def _latent_full(cfg, tok, pos):
-    out = _latent_stack(cfg, "full", tok, pos)
-    del out["next_token"]
-    return out
-
-
-def _latent_cow(cfg, num_pages, page_size, src, dst):
-    # the page's slab of latent rows (and its indexer keys), in every layer
-    declare_stacked_pools(default_main_program().global_block,
-                          *_latent_pool_geometry(cfg, num_pages, page_size))
-    _stacked_copy_page([name for _, name in _latent_pools(cfg)], num_pages,
-                       src, dst)
-
-
 # -- the "parallel_ssm" family -----------------------------------------------
+
+
+def _validate_ssm(cfg: DecoderConfig) -> None:
+    if min(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
+           cfg.ssm_chunk, cfg.prefill_chunk) < 1 \
+            or cfg.ssm_conv < 2 or cfg.ssm_heads % cfg.ssm_groups \
+            or cfg.num_heads % cfg.kv_heads \
+            or len(cfg.mlp_multipliers) != 2 or len(cfg.ssm_multipliers) != 5:
+        raise ValueError(
+            "block 'parallel_ssm' needs ssm_heads (a multiple of "
+            "ssm_groups), ssm_head_dim, ssm_state, ssm_chunk, ssm_conv >= 2, "
+            "prefill_chunk, num_kv_heads dividing num_heads, two "
+            "mlp_multipliers and five ssm_multipliers")
 
 
 def _ssm_geometry(cfg: DecoderConfig) -> dict:
@@ -1590,30 +1196,16 @@ def _ssm_geometry(cfg: DecoderConfig) -> dict:
 
 def ssm_pool_geometry(cfg: DecoderConfig, num_pages: int, page_size: int,
                       num_slots: int) -> tuple:
-    """(`kv_cache.stacked_pool_shapes`' arguments for K and V,
-    `kv_cache.state_pool_shapes`' for the recurrent state). "mixer_moe"
-    answers by the count of a kind: K/V over its attention layers, the
-    state over its mixers, narrow heads packed on the lanes."""
-    width = cfg.kv_heads * cfg.head_dim
-    if cfg.block == "kda_moe":
-        # ONE pool of latent rows over the latent layers; a Kimi-Delta
-        # head's state [keys, values], the tail over q | k | v
-        return ((cfg.latent_layers, num_pages, page_size,
-                 cfg.kv_lora_rank + cfg.rope_head_dim, 0, cfg.dtype, 0,
-                 False, STACKED_POOLS, True),
-                (cfg.state_layers, num_slots, cfg.ssm_heads, cfg.ssm_state,
-                 cfg.ssm_head_dim, (cfg.ssm_conv - 1) * cfg.ssm_heads
-                 * (2 * cfg.ssm_state + cfg.ssm_head_dim)))
-    if cfg.block == "mixer_moe":
-        tail = (cfg.ssm_conv - 1) * (
-            cfg.ssm_heads * cfg.ssm_head_dim
-            + 2 * cfg.ssm_groups * cfg.ssm_state)
-        return ((cfg.layer_pattern.count(mixer_moe_ops.ATTENTION), num_pages,
-                 page_size, width, 0, cfg.dtype),
-                (cfg.state_layers, num_slots, cfg.ssm_heads, cfg.ssm_state,
-                 cfg.ssm_head_dim, tail, _state_pack(cfg)))
+    """(`kv_cache.stacked_pool_shapes`' arguments for the paged pools,
+    `kv_cache.state_pool_shapes`' for the recurrent state) of a recurrent
+    family (the row's `pool_geometry`)."""
+    return cfg.family.pool_geometry(cfg, num_pages, page_size, num_slots)
+
+
+def _ssm_pool_geometry(cfg, num_pages, page_size, num_slots):
     geom = parallel_ssm_ops.Geometry(**_ssm_geometry(cfg))
-    return ((cfg.num_layers, num_pages, page_size, width, 0, cfg.dtype),
+    return ((cfg.num_layers, num_pages, page_size,
+             cfg.kv_heads * cfg.head_dim, 0, cfg.dtype),
             (cfg.num_layers, num_slots, cfg.ssm_heads, cfg.ssm_state,
              cfg.ssm_head_dim,
              (cfg.ssm_conv - 1) * parallel_ssm_ops.conv_width(geom)))
@@ -1695,10 +1287,6 @@ def _draw_rows(rows: int) -> int:
     return n
 
 
-_SSM_POOLS = tuple(zip(("KPool", "VPool", "SPool", "CPool"),
-                       STACKED_POOLS[:2] + STATE_POOLS))
-
-
 def _declare_ssm_pools(cfg, num_pages, page_size, state_slots):
     block = default_main_program().global_block
     kv, state = ssm_pool_geometry(cfg, num_pages, page_size, state_slots)
@@ -1706,77 +1294,32 @@ def _declare_ssm_pools(cfg, num_pages, page_size, state_slots):
     declare_state_pools(block, *state)
 
 
-def _ssm_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
-               page_size: int = 0, state_slots: int = 0, **feeds):
-    """Append the one `parallel_ssm_stack` op of a program; returns its
-    outputs (next_token, logits)."""
-    helper = LayerHelper("parallel_ssm_stack")
-    params = {key: helper.create_parameter(
-        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
-        for key, (shape, dtype, init) in _ssm_param_specs(cfg).items()}
-    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
-              "Head": [params["dec.lm_head"]],
-              "FinalNorm": [params["dec.final_norm.scale"]],
-              "LayerParams": [params[k]
-                              for k in parallel_ssm_ops.LAYER_PARAMS]}
-    inputs.update({slot: [var] for slot, var in feeds.items()})
-    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
-            for slot, dtype in (("NextToken", "int32"),
-                                ("Logits", "float32"))}
-    if mode != "full":
-        _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
-        inputs["StateSlot"] = [L.data(name=SSLOT_FEED, shape=[],
-                                      dtype="int32")]
-        for slot, name in _SSM_POOLS:
-            inputs[slot] = [name]
-            outs[slot + "Out"] = [name]
-    helper.append_op("parallel_ssm_stack", inputs, outs,
-                     dict(_ssm_geometry(cfg), mode=mode,
-                          num_pages=int(num_pages),
-                          num_slots=int(state_slots)))
-    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0]}
-
-
-def _ssm_window_io(out):
-    return {"next_token": out["next_token"], "last_logits": out["logits"],
-            "extra_feeds": [SSLOT_FEED]}
-
-
-def _ssm_prefill(cfg, num_pages, page_size, tok, pos, pages, lens,
-                 state_slots=0):
-    return _ssm_window_io(_ssm_stack(
-        cfg, "prefill", tok, pos, num_pages, page_size, state_slots,
-        PageTable=pages, Lens=lens))
-
-
-def _ssm_window(cfg, num_pages, page_size, tp, tok, pos, pages, start, lens,
-                state_slots=0):
-    # a prompt's chunk or the suffix behind a resumed snapshot (no verify
-    # window)
-    return _ssm_window_io(_ssm_stack(
-        cfg, "window", tok, pos, num_pages, page_size, state_slots,
-        PageTable=pages, Start=start, Lens=lens))
-
-
-def _ssm_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask,
-                state_slots=0):
-    return dict(_ssm_stack(cfg, "decode", tok, pos, num_pages, page_size,
-                           state_slots, PageTable=pages, Mask=mask),
-                extra_feeds=[SSLOT_FEED])
-
-
-def _ssm_full(cfg, tok, pos):
-    return {"logits": _ssm_stack(cfg, "full", tok, pos)["logits"]}
-
-
-def _ssm_cow(cfg, num_pages, page_size, src, dst, state_slots=0):
-    # a page's K/V slab in every layer (a state is never shared: resuming
-    # from a snapshot copies it, `build_state_copy_program`)
-    _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
-    _stacked_copy_page(STACKED_POOLS[:2], num_pages, src, dst)
-
-
 # -- the "mixer_moe" family --------------------------------------------------
+
+
+def _validate_mixer(cfg: DecoderConfig) -> None:
+    kinds = collections.Counter(cfg.layer_pattern)
+    if len(cfg.layer_pattern) != cfg.num_layers \
+            or set(kinds) - set("M*E") or not kinds["M"] or not kinds["E"]:
+        raise ValueError(
+            "block 'mixer_moe' needs layer_pattern of num_layers characters "
+            "of 'M' (mixer), '*' (attention) and 'E' (experts), with at "
+            "least one 'M' and one 'E'")
+    if min(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
+           cfg.ssm_chunk, cfg.prefill_chunk, cfg.latent_size,
+           cfg.shared_expert_size) < 1 \
+            or cfg.ssm_conv < 2 or cfg.ssm_heads % cfg.ssm_groups \
+            or cfg.num_heads % cfg.kv_heads:
+        raise ValueError(
+            "block 'mixer_moe' needs ssm_heads (a multiple of ssm_groups), "
+            "ssm_head_dim, ssm_state, ssm_chunk, ssm_conv >= 2, "
+            "prefill_chunk, latent_size, shared_expert_size and "
+            "num_kv_heads dividing num_heads")
+    if not 1 <= cfg.experts_per_token <= cfg.num_experts \
+            or not 1 <= cfg.held_experts <= cfg.num_experts:
+        raise ValueError(
+            "block 'mixer_moe' needs num_experts >= experts_per_token >= 1 "
+            "and 1 <= experts_held <= num_experts")
 
 
 def _state_pack(cfg: DecoderConfig) -> int:
@@ -1795,6 +1338,18 @@ def _mixer_geometry(cfg: DecoderConfig) -> dict:
             "experts_per_token": cfg.experts_per_token,
             "routed_scaling": float(cfg.routed_scaling),
             "experts_held": cfg.held_experts}
+
+
+def _mixer_pool_geometry(cfg, num_pages, page_size, num_slots):
+    # by the count of a kind: K/V over the attention layers, the state over
+    # the mixers, narrow heads packed on the lanes
+    tail = (cfg.ssm_conv - 1) * (
+        cfg.ssm_heads * cfg.ssm_head_dim
+        + 2 * cfg.ssm_groups * cfg.ssm_state)
+    return ((cfg.layer_pattern.count(mixer_moe_ops.ATTENTION), num_pages,
+             page_size, cfg.kv_heads * cfg.head_dim, 0, cfg.dtype),
+            (cfg.state_layers, num_slots, cfg.ssm_heads, cfg.ssm_state,
+             cfg.ssm_head_dim, tail, _state_pack(cfg)))
 
 
 def _mixer_param_specs(cfg: DecoderConfig) -> dict:
@@ -1873,73 +1428,32 @@ _MIXER_GROUPS = (("MixerParams", "mix.", mixer_moe_ops.MIXER_PARAMS),
                  ("Experts", "", mixer_moe_ops.EXPERT_PARAMS))
 
 
-def _mixer_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
-                 page_size: int = 0, state_slots: int = 0, **feeds):
-    """Append the one `mixer_moe_stack` op of a program; returns its
-    outputs (next_token, logits, routes)."""
-    helper = LayerHelper("mixer_moe_stack")
-    params = {key: helper.create_parameter(
-        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
-        for key, (shape, dtype, init) in _mixer_param_specs(cfg).items()}
-    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
-              "Head": [params["dec.lm_head"]],
-              "FinalNorm": [params["dec.final_norm.scale"]],
-              "Norms": [params["norm"]]}
-    inputs.update({slot: [params[prefix + k] for k in keys]
-                   for slot, prefix, keys in _MIXER_GROUPS})
-    inputs.update({slot: [var] for slot, var in feeds.items()})
-    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
-            for slot, dtype in (("NextToken", "int32"),
-                                ("Logits", "float32"), ("Routes", "int32"))}
-    if mode != "full":
-        _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
-        inputs["StateSlot"] = [L.data(name=SSLOT_FEED, shape=[],
-                                      dtype="int32")]
-        for slot, name in _SSM_POOLS:
-            inputs[slot] = [name]
-            outs[slot + "Out"] = [name]
-    helper.append_op("mixer_moe_stack", inputs, outs,
-                     dict(_mixer_geometry(cfg), mode=mode,
-                          num_pages=int(num_pages),
-                          num_slots=int(state_slots)))
-    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
-            "routes": outs["Routes"][0]}
-
-
-def _mixer_window_io(out):
-    return {"next_token": out["next_token"], "last_logits": out["logits"],
-            "routes": out["routes"], "extra_feeds": [SSLOT_FEED]}
-
-
-def _mixer_prefill(cfg, num_pages, page_size, tok, pos, pages, lens,
-                   state_slots=0):
-    return _mixer_window_io(_mixer_stack(
-        cfg, "prefill", tok, pos, num_pages, page_size, state_slots,
-        PageTable=pages, Lens=lens))
-
-
-def _mixer_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
-                  lens, state_slots=0):
-    # a prompt's chunk or the suffix behind a resumed snapshot (no verify
-    # window)
-    return _mixer_window_io(_mixer_stack(
-        cfg, "window", tok, pos, num_pages, page_size, state_slots,
-        PageTable=pages, Start=start, Lens=lens))
-
-
-def _mixer_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask,
-                  state_slots=0):
-    return dict(_mixer_stack(cfg, "decode", tok, pos, num_pages, page_size,
-                             state_slots, PageTable=pages, Mask=mask),
-                extra_feeds=[SSLOT_FEED])
-
-
-def _mixer_full(cfg, tok, pos):
-    out = _mixer_stack(cfg, "full", tok, pos)
-    return {"logits": out["logits"], "routes": out["routes"]}
-
-
 # -- the "kda_moe" family ----------------------------------------------------
+
+
+def _validate_kda(cfg: DecoderConfig) -> None:
+    if min(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.kda_sub_chunk,
+           cfg.prefill_chunk, cfg.kv_lora_rank, cfg.rope_head_dim,
+           cfg.v_head_dim, cfg.shared_expert_size, cfg.dense_ffn_size) < 1 \
+            or cfg.ssm_conv < 2 or cfg.q_lora_rank \
+            or cfg.ssm_chunk % cfg.kda_sub_chunk \
+            or cfg.kda_sub_chunk % 2 or cfg.kda_lower_bound >= 0 \
+            or cfg.kda_lower_bound * cfg.kda_sub_chunk < -160 \
+            or cfg.rope_head_dim % 2 or cfg.kv_lora_rank % 2:
+        raise ValueError(
+            "block 'kda_moe' needs ssm_heads, ssm_head_dim, ssm_state, "
+            "ssm_conv >= 2, ssm_chunk in whole kda_sub_chunk (even; "
+            "kda_lower_bound < 0 times half of it is an exponent float32 "
+            "must hold), prefill_chunk, kv_lora_rank and rope_head_dim "
+            "(even), v_head_dim, shared_expert_size, dense_ffn_size and NO "
+            "q_lora_rank")
+    if not 2 <= cfg.layer_group_size <= cfg.num_layers \
+            or not 0 <= cfg.dense_layers < cfg.num_layers:
+        raise ValueError(
+            "block 'kda_moe' needs 2 <= layer_group_size <= num_layers (a "
+            "latent layer among every few, at least one of each kind) and "
+            "0 <= dense_layers < num_layers")
+    _validate_expert_groups(cfg)
 
 
 def _kda_geometry(cfg: DecoderConfig) -> dict:
@@ -1957,6 +1471,17 @@ def _kda_geometry(cfg: DecoderConfig) -> dict:
             "groups_per_token": cfg.groups_per_token,
             "routed_scaling": float(cfg.routed_scaling),
             "experts_held": cfg.held_experts}
+
+
+def _kda_pool_geometry(cfg, num_pages, page_size, num_slots):
+    # ONE pool of latent rows over the latent layers; a Kimi-Delta head's
+    # state [keys, values], the tail over q | k | v
+    return ((cfg.latent_layers, num_pages, page_size,
+             cfg.kv_lora_rank + cfg.rope_head_dim, 0, cfg.dtype, 0, False,
+             STACKED_POOLS, True),
+            (cfg.state_layers, num_slots, cfg.ssm_heads, cfg.ssm_state,
+             cfg.ssm_head_dim, (cfg.ssm_conv - 1) * cfg.ssm_heads
+             * (2 * cfg.ssm_state + cfg.ssm_head_dim)))
 
 
 def _kda_param_specs(cfg: DecoderConfig) -> dict:
@@ -2040,79 +1565,17 @@ _KDA_GROUPS = (("KdaParams", "kda.", kda_ops.KDA_PARAMS),
                ("DenseParams", "dense.", kda_ops.DENSE_PARAMS),
                ("MoeParams", "moe.", kda_ops.MOE_PARAMS),
                ("Experts", "", kda_ops.EXPERT_PARAMS))
-_KDA_POOLS = tuple(zip(("LatentPool", "SPool", "CPool"),
-                       (LATENT_POOL,) + STATE_POOLS))
-
-
-def _kda_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
-               page_size: int = 0, state_slots: int = 0, **feeds):
-    """Append the one `kda_moe_stack` op of a program; returns its outputs
-    (next_token, logits, routes)."""
-    helper = LayerHelper("kda_moe_stack")
-    params = {key: helper.create_parameter(
-        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
-        for key, (shape, dtype, init) in _kda_param_specs(cfg).items()}
-    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
-              "Head": [params["dec.lm_head"]],
-              "FinalNorm": [params["dec.final_norm.scale"]],
-              "Norms": [params["norm"]]}
-    inputs.update({slot: [params[prefix + k] for k in keys]
-                   for slot, prefix, keys in _KDA_GROUPS})
-    inputs.update({slot: [var] for slot, var in feeds.items()})
-    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
-            for slot, dtype in (("NextToken", "int32"),
-                                ("Logits", "float32"), ("Routes", "int32"))}
-    if mode != "full":
-        _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
-        inputs["StateSlot"] = [L.data(name=SSLOT_FEED, shape=[],
-                                      dtype="int32")]
-        for slot, name in _KDA_POOLS:
-            inputs[slot] = [name]
-            outs[slot + "Out"] = [name]
-    helper.append_op("kda_moe_stack", inputs, outs,
-                     dict(_kda_geometry(cfg), mode=mode,
-                          num_pages=int(num_pages),
-                          num_slots=int(state_slots)))
-    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
-            "routes": outs["Routes"][0]}
-
-
-def _kda_prefill(cfg, num_pages, page_size, tok, pos, pages, lens,
-                 state_slots=0):
-    return _mixer_window_io(_kda_stack(
-        cfg, "prefill", tok, pos, num_pages, page_size, state_slots,
-        PageTable=pages, Lens=lens))
-
-
-def _kda_window(cfg, num_pages, page_size, tp, tok, pos, pages, start, lens,
-                state_slots=0):
-    # a prompt's chunk or the suffix behind a resumed snapshot (no verify
-    # window)
-    return _mixer_window_io(_kda_stack(
-        cfg, "window", tok, pos, num_pages, page_size, state_slots,
-        PageTable=pages, Start=start, Lens=lens))
-
-
-def _kda_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask,
-                state_slots=0):
-    return dict(_kda_stack(cfg, "decode", tok, pos, num_pages, page_size,
-                           state_slots, PageTable=pages, Mask=mask),
-                extra_feeds=[SSLOT_FEED])
-
-
-def _kda_full(cfg, tok, pos):
-    out = _kda_stack(cfg, "full", tok, pos)
-    return {"logits": out["logits"], "routes": out["routes"]}
-
-
-def _kda_cow(cfg, num_pages, page_size, src, dst, state_slots=0):
-    # a page's slab of latent rows in every latent layer (a state is never
-    # shared: resuming from a snapshot copies it)
-    _declare_ssm_pools(cfg, num_pages, page_size, state_slots)
-    _stacked_copy_page([LATENT_POOL], num_pages, src, dst)
 
 
 # -- the "looped_dense" family -----------------------------------------------
+
+
+def _validate_looped(cfg: DecoderConfig) -> None:
+    if min(cfg.loop_steps, cfg.prefill_chunk) < 1 \
+            or cfg.num_heads % cfg.kv_heads or cfg.head_dim % 2:
+        raise ValueError(
+            "block 'looped_dense' needs loop_steps, prefill_chunk, "
+            "num_kv_heads dividing num_heads and an even head")
 
 
 def _looped_geometry(cfg: DecoderConfig) -> dict:
@@ -2174,76 +1637,6 @@ def _looped_param_specs(cfg: DecoderConfig) -> dict:
     }
 
 
-def _looped_stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
-                  page_size: int = 0, **feeds):
-    """Append the one `looped_dense_stack` op of a program; returns its
-    outputs (next_token, logits, exit_mass)."""
-    helper = LayerHelper("looped_dense_stack")
-    params = {key: helper.create_parameter(
-        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
-        for key, (shape, dtype, init) in _looped_param_specs(cfg).items()}
-    inputs = {"Tok": [tok], "Pos": [pos], "Emb": [params["dec.word_emb"]],
-              "Head": [params["dec.lm_head"]],
-              "FinalNorm": [params["dec.final_norm.scale"]],
-              "GateW": [params["dec.exit_gate.w"]],
-              "GateB": [params["dec.exit_gate.b"]],
-              "LayerParams": [params[k]
-                              for k in looped_dense_ops.LAYER_PARAMS]}
-    inputs.update({slot: [var] for slot, var in feeds.items()})
-    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
-            for slot, dtype in (("NextToken", "int32"), ("Logits", "float32"),
-                                ("ExitMass", "float32"))}
-    if mode != "full":
-        declare_stacked_pools(default_main_program().global_block,
-                              *_looped_pool_geometry(cfg, num_pages,
-                                                     page_size))
-        for slot, name in zip(("KPool", "VPool"), STACKED_POOLS):
-            inputs[slot] = [name]
-            outs[slot + "Out"] = [name]
-    helper.append_op("looped_dense_stack", inputs, outs,
-                     dict(_looped_geometry(cfg), mode=mode,
-                          num_pages=int(num_pages)))
-    return {"next_token": outs["NextToken"][0], "logits": outs["Logits"][0],
-            "exit_mass": outs["ExitMass"][0]}
-
-
-def _looped_window_io(out):
-    return {"next_token": out["next_token"], "last_logits": out["logits"],
-            "exit_mass": out["exit_mass"]}
-
-
-def _looped_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
-    return _looped_window_io(_looped_stack(
-        cfg, "prefill", tok, pos, num_pages, page_size, PageTable=pages,
-        Lens=lens))
-
-
-def _looped_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
-                   lens):
-    # a prompt's chunk, the suffix behind a cached prefix, or a preempted
-    # row's way back (no verify window)
-    return _looped_window_io(_looped_stack(
-        cfg, "window", tok, pos, num_pages, page_size, PageTable=pages,
-        Start=start, Lens=lens))
-
-
-def _looped_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
-    return _looped_stack(cfg, "decode", tok, pos, num_pages, page_size,
-                         PageTable=pages, Mask=mask)
-
-
-def _looped_full(cfg, tok, pos):
-    out = _looped_stack(cfg, "full", tok, pos)
-    return {"logits": out["logits"], "exit_mass": out["exit_mass"]}
-
-
-def _looped_cow(cfg, num_pages, page_size, src, dst):
-    # the page's K/V slab in EVERY plane: each visit of each layer
-    declare_stacked_pools(default_main_program().global_block,
-                          *_looped_pool_geometry(cfg, num_pages, page_size))
-    _stacked_copy_page(STACKED_POOLS[:2], num_pages, src, dst)
-
-
 def build_state_copy_program(cfg: DecoderConfig, num_pages: int,
                              page_size: int, state_slots: int):
     """Build (in the current default main program) the copy of one slot of
@@ -2260,6 +1653,145 @@ def build_state_copy_program(cfg: DecoderConfig, num_pages: int,
         {k + "Out": [v] for k, v in pools.items()},
         {"num_slots": int(state_slots)})
     return {"feeds": [SCOPY_SRC_FEED, SCOPY_DST_FEED]}
+
+
+# -- the composite families: ONE builder over a row of `FAMILIES` -------------
+
+# (op slot, key prefix, keys) of the variables a composite op reads first
+# (the "cca_moe" head is its embedding)
+_EMB = ("Emb", "", ("dec.word_emb",))
+_FINAL_NORM = ("FinalNorm", "", ("dec.final_norm.scale",))
+_EMB_HEAD_NORM = (_EMB, ("Head", "", ("dec.lm_head",)), _FINAL_NORM)
+# (op slot, dtype, name in a builder's result) of what a composite op writes
+_TOKEN_LOGITS = (("NextToken", "int32", "next_token"),
+                 ("Logits", "float32", "logits"))
+_ROUTES = ("Routes", "int32", "routes")
+_SELECTION = ("Selection", "int32", "selection")
+_EXIT_MASS = ("ExitMass", "float32", "exit_mass")
+# the op attribute that carries the size of a family's second pool
+_SECOND_ATTR = {"window_pages": "window_pages", "state_slots": "num_slots"}
+
+
+def _pools(cfg: DecoderConfig) -> tuple:
+    """(op slot, pool) of the family's pools; the indexer's keys only behind
+    an indexer ("latent_moe" without one allocates no `INDEX_POOL`)."""
+    return tuple((slot, pool) for slot, pool in cfg.family.pools
+                 if pool != INDEX_POOL or cfg.selects)
+
+
+def _stack(cfg: DecoderConfig, mode: str, tok, pos, num_pages: int = 0,
+           page_size: int = 0, second: int = 0, **feeds):
+    """Append the one composite op of a program of `cfg`'s family; returns
+    its outputs under their names in a builder's result, and the feeds it
+    declared itself (`extra_feeds`). Parameters are created in the order of
+    the row's `param_specs` in every program: that order is the startup
+    program's, so the order of the weights' draw and of start-up's
+    allocations (`tests/test_program_hashes.py` pins it)."""
+    fam = cfg.family
+    cached = mode != "full"
+    extra = {}
+    if mode == "decode" and cfg.selects:
+        # [MARK_ROWS] int32: the rows whose selection comes back, -1 unused
+        extra["Mark"] = L.data(name=MARK_FEED, shape=[MARK_ROWS],
+                               dtype="int32", append_batch_size=False)
+    if cached and cfg.windowed:
+        # a row's compact table in the sliding layers' pool
+        extra["WindowTable"] = L.data(name=WPAGES_FEED, shape=[1],
+                                      dtype="int32")
+        extra["WindowBase"] = L.data(name=WBASE_FEED, shape=[], dtype="int32")
+    helper = LayerHelper(fam.op)
+    params = {key: helper.create_parameter(
+        ParamAttr(name=cca_param_name(key), initializer=init), shape, dtype)
+        for key, (shape, dtype, init) in fam.param_specs(cfg).items()}
+    groups = fam.groups(cfg) if callable(fam.groups) else fam.groups
+    inputs = {"Tok": [tok], "Pos": [pos]}
+    inputs.update({slot: [params[prefix + k] for k in keys]
+                   for slot, prefix, keys in groups})
+    inputs.update({slot: [var] for slot, var in {**feeds, **extra}.items()})
+    # no selection to hand back without an indexer
+    outputs = tuple(o for o in _TOKEN_LOGITS + fam.outputs
+                    if o is not _SELECTION or cfg.selects)
+    outs = {slot: [helper.create_variable_for_type_inference(dtype)]
+            for slot, dtype, _ in outputs}
+    attrs = dict(fam.geometry(cfg), mode=mode, num_pages=int(num_pages))
+    if fam.second_pool:
+        attrs[_SECOND_ATTR[fam.second_pool]] = int(second)
+    if cached:
+        fam.declare_pools(cfg, num_pages, page_size, second)
+        if cfg.recurrent:
+            extra["StateSlot"] = L.data(name=SSLOT_FEED, shape=[],
+                                        dtype="int32")
+            inputs["StateSlot"] = [extra["StateSlot"]]
+        for slot, name in _pools(cfg):
+            inputs[slot] = [name]
+            outs[slot + "Out"] = [name]
+    helper.append_op(fam.op, inputs, outs, attrs)
+    io = {name: outs[slot][0] for slot, _, name in outputs}
+    if extra:
+        io["extra_feeds"] = [var.name for var in extra.values()]
+    return io
+
+
+def _window_io(out: dict) -> dict:
+    out["last_logits"] = out.pop("logits")
+    return out
+
+
+def _composite_prefill(cfg, num_pages, page_size, tok, pos, pages, lens,
+                       second=0):
+    return _window_io(_stack(cfg, "prefill", tok, pos, num_pages, page_size,
+                             second, PageTable=pages, Lens=lens))
+
+
+def _composite_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
+                      lens, second=0):
+    # a prompt's chunk, the suffix behind a prefix hit or a resumed
+    # snapshot, or a preempted row's way back: no verify window. A family
+    # with a state row a page ("cca_moe") restores the row of the page
+    # before Start, so Start is a page boundary
+    return _window_io(_stack(cfg, "window", tok, pos, num_pages, page_size,
+                             second, PageTable=pages, Start=start, Lens=lens))
+
+
+def _composite_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask,
+                      second=0):
+    return _stack(cfg, "decode", tok, pos, num_pages, page_size, second,
+                  PageTable=pages, Mask=mask)
+
+
+def _composite_full(cfg, tok, pos):
+    out = _stack(cfg, "full", tok, pos)
+    del out["next_token"]
+    return out
+
+
+def _composite_cow(cfg, num_pages, page_size, src, dst, second=0):
+    # the page's slab in every plane of every PAGED pool of the family: its
+    # K/V or joined or latent rows, its indexer keys, its state row (a slot
+    # of recurrent state is never shared: resuming from a snapshot copies
+    # it, `build_state_copy_program`). `cca_state_copy_page` copies rows `l
+    # * num_pages + page` of up to three pools, whatever they hold
+    cfg.family.declare_pools(cfg, num_pages, page_size, second)
+    paged = [pair for pair in _pools(cfg) if pair[1] not in STATE_POOLS]
+    op, more = "cca_state_copy_page", {}
+    pools = dict(zip(("KPool", "VPool", "SPool"), (p for _, p in paged)))
+    attrs = {"num_pages": int(num_pages)}
+    if cfg.windowed:
+        # and a page of the sliding layers' pools, under ids of its own
+        op, pools = "hybrid_copy_page", dict(paged)
+        more = {"WSrc": L.data(name=COW_WSRC_FEED, shape=[], dtype="int32"),
+                "WDst": L.data(name=COW_WDST_FEED, shape=[], dtype="int32")}
+        attrs["window_pages"] = int(second)
+    LayerHelper(op).append_op(
+        op, {k: [v] for k, v in {**pools, "Src": src, "Dst": dst,
+                                 **more}.items()},
+        {k + "Out": [v] for k, v in pools.items()}, attrs)
+    return [var.name for var in more.values()]
+
+
+_COMPOSITE = {"prefill": _composite_prefill, "window": _composite_window,
+              "decode": _composite_decode, "full": _composite_full,
+              "cow": _composite_cow}
 
 
 def _proj(x, size, name, act=None):
@@ -2335,10 +1867,10 @@ def _prefill_layer(x, i, cfg: DecoderConfig, pages, lens, write_cache: bool):
 
 
 def _second_pool(window_pages: int, state_slots: int = 0) -> dict:
-    """The keyword a family with a second pool takes its size under."""
-    if state_slots:
-        return {"state_slots": int(state_slots)}
-    return {"window_pages": int(window_pages)} if window_pages else {}
+    """The size of a family's second pool (`Family.second_pool` says which
+    of the two it has), as the keyword its builders take it under."""
+    size = int(state_slots or window_pages)
+    return {"second": size} if size else {}
 
 
 def _last_token_state(last_token: str, token_slots: int):
@@ -2350,14 +1882,16 @@ def _last_token_state(last_token: str, token_slots: int):
     return L.data(name=SLOT_FEED, shape=[], dtype="int32")
 
 
-def _keep_last_token(io: dict, last_token: str, slot) -> dict:
+def _keep_last_token(io: dict, last_token: str, slot, feeds: list) -> dict:
     """Append the write of the step's `next_token` to its rows' slots; the
-    family's outputs with the slot feed among its feeds."""
+    family body's outputs plus the program's feed names: the shared ones,
+    any the family declared itself (`extra_feeds`) and the slot feed."""
     LayerHelper("last_token_write").append_op(
         "last_token_write",
         {"Last": [last_token], "Slot": [slot], "Next": [io["next_token"]]},
         {"LastOut": [last_token]}, {})
-    return dict(io, extra_feeds=io.get("extra_feeds", []) + [SLOT_FEED])
+    io = dict(io)
+    return dict(io, feeds=feeds + io.pop("extra_feeds", []) + [SLOT_FEED])
 
 
 def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int,
@@ -2376,24 +1910,10 @@ def build_prefill_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     pages = L.data(name=PAGES_FEED, shape=[1], dtype="int32")
     lens = L.data(name=LEN_FEED, shape=[], dtype="int32")
     slot = _last_token_state(last_token, token_slots)
-    return _with_feeds(_keep_last_token(_FAMILY[cfg.block]["prefill"](
+    return _keep_last_token(cfg.family.builders["prefill"](
         cfg, num_pages, page_size, tok, pos, pages, lens,
-        **_second_pool(window_pages, state_slots)), last_token, slot),
+        **_second_pool(window_pages, state_slots)), last_token, slot,
         [TOK_FEED, POS_FEED, PAGES_FEED, LEN_FEED])
-
-
-def _with_feeds(io: dict, feeds: list) -> dict:
-    """A family body's outputs plus the program's feed names: the shared
-    ones and any the family declared itself (`extra_feeds`)."""
-    io = dict(io)
-    return dict(io, feeds=feeds + io.pop("extra_feeds", []))
-
-
-def _cca_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
-    out = _cca_stack(cfg, "prefill", tok, pos, num_pages, page_size,
-                     PageTable=pages, Lens=lens)
-    return {"next_token": out["next_token"], "last_logits": out["logits"],
-            "routes": out["routes"]}
 
 
 def _post_ln_prefill(cfg, num_pages, page_size, tok, pos, pages, lens):
@@ -2473,19 +1993,10 @@ def build_window_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     start = L.data(name=START_FEED, shape=[], dtype="int32")
     lens = L.data(name=LEN_FEED, shape=[], dtype="int32")
     slot = _last_token_state(last_token, token_slots)
-    return _with_feeds(_keep_last_token(_FAMILY[cfg.block]["window"](
+    return _keep_last_token(cfg.family.builders["window"](
         cfg, num_pages, page_size, tp, tok, pos, pages, start, lens,
-        **_second_pool(window_pages, state_slots)), last_token, slot),
+        **_second_pool(window_pages, state_slots)), last_token, slot,
         [TOK_FEED, POS_FEED, PAGES_FEED, START_FEED, LEN_FEED])
-
-
-def _cca_window(cfg, num_pages, page_size, tp, tok, pos, pages, start, lens):
-    # suffix prefill only: the window restores the state row of the page
-    # before Start, so Start is a page boundary (no verify window)
-    out = _cca_stack(cfg, "window", tok, pos, num_pages, page_size,
-                     PageTable=pages, Start=start, Lens=lens)
-    return {"next_token": out["next_token"], "last_logits": out["logits"],
-            "routes": out["routes"]}
 
 
 def _post_ln_window(cfg, num_pages, page_size, tp, tok, pos, pages, start,
@@ -2516,17 +2027,10 @@ def build_cow_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     per engine — COW cost is one tiny device step, not a recompile."""
     src = L.data(name=COW_SRC_FEED, shape=[], dtype="int32")
     dst = L.data(name=COW_DST_FEED, shape=[], dtype="int32")
-    more = _FAMILY[cfg.block]["cow"](
+    more = cfg.family.builders["cow"](
         cfg, num_pages, page_size, src, dst,
         **_second_pool(window_pages, state_slots))
     return {"feeds": [COW_SRC_FEED, COW_DST_FEED] + (more or [])}
-
-
-def _cca_cow(cfg, num_pages, page_size, src, dst):
-    # the page's K/V slab and its state row, in every layer
-    declare_stacked_pools(default_main_program().global_block,
-                          *_cca_pool_geometry(cfg, num_pages, page_size))
-    _stacked_copy_page(STACKED_POOLS, num_pages, src, dst)
 
 
 def _post_ln_cow(cfg, num_pages, page_size, src, dst):
@@ -2567,15 +2071,10 @@ def build_decode_program(cfg: DecoderConfig, num_pages: int, page_size: int,
     helper.append_op("last_token_select",
                      {"Tok": [tok], "FromHost": [from_host], "Slot": [slot],
                       "Last": [last_token]}, {"Out": [chained]}, {})
-    return _with_feeds(_keep_last_token(_FAMILY[cfg.block]["decode"](
+    return _keep_last_token(cfg.family.builders["decode"](
         cfg, num_pages, page_size, tp, chained, pos, pages, mask,
-        **_second_pool(window_pages, state_slots)), last_token, slot),
+        **_second_pool(window_pages, state_slots)), last_token, slot,
         [TOK_FEED, POS_FEED, PAGES_FEED, MASK_FEED, FROM_HOST_FEED])
-
-
-def _cca_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
-    return _cca_stack(cfg, "decode", tok, pos, num_pages, page_size,
-                      PageTable=pages, Mask=mask)
 
 
 def _post_ln_decode(cfg, num_pages, page_size, tp, tok, pos, pages, mask):
@@ -2672,13 +2171,8 @@ def build_full_forward_program(cfg: DecoderConfig):
     exactly (tests, and the debugging path for kernel mismatches)."""
     tok = L.data(name=TOK_FEED, shape=[cfg.max_position], dtype="int32")
     pos = L.data(name=POS_FEED, shape=[cfg.max_position], dtype="int32")
-    return dict(_FAMILY[cfg.block]["full"](cfg, tok, pos),
+    return dict(cfg.family.builders["full"](cfg, tok, pos),
                 feeds=[TOK_FEED, POS_FEED])
-
-
-def _cca_full(cfg, tok, pos):
-    out = _cca_stack(cfg, "full", tok, pos)
-    return {"logits": out["logits"], "routes": out["routes"]}
 
 
 def _post_ln_full(cfg, tok, pos):
@@ -2688,32 +2182,186 @@ def _post_ln_full(cfg, tok, pos):
     return {"logits": _head(x, cfg)}
 
 
-# the body of each program, by block family: the `build_*` functions above
-# declare the feeds, which the families share, and hand over here
-_FAMILY = {
-    "post_ln": {"prefill": _post_ln_prefill, "window": _post_ln_window,
-                "cow": _post_ln_cow, "decode": _post_ln_decode,
-                "full": _post_ln_full},
-    "cca_moe": {"prefill": _cca_prefill, "window": _cca_window,
-                "cow": _cca_cow, "decode": _cca_decode, "full": _cca_full},
-    "sparse_moe": {"prefill": _sparse_prefill, "window": _sparse_window,
-                   "cow": _sparse_cow, "decode": _sparse_decode,
-                   "full": _sparse_full},
-    "hybrid_moe": {"prefill": _hybrid_prefill, "window": _hybrid_window,
-                   "cow": _hybrid_cow, "decode": _hybrid_decode,
-                   "full": _hybrid_full},
-    "parallel_ssm": {"prefill": _ssm_prefill, "window": _ssm_window,
-                     "cow": _ssm_cow, "decode": _ssm_decode,
-                     "full": _ssm_full},
-    "latent_moe": {"prefill": _latent_prefill, "window": _latent_window,
-                   "cow": _latent_cow, "decode": _latent_decode,
-                   "full": _latent_full},
-    "mixer_moe": {"prefill": _mixer_prefill, "window": _mixer_window,
-                  "cow": _ssm_cow, "decode": _mixer_decode,
-                  "full": _mixer_full},
-    "kda_moe": {"prefill": _kda_prefill, "window": _kda_window,
-                "cow": _kda_cow, "decode": _kda_decode, "full": _kda_full},
-    "looped_dense": {"prefill": _looped_prefill, "window": _looped_window,
-                     "cow": _looped_cow, "decode": _looped_decode,
-                     "full": _looped_full},
+@dataclass(frozen=True)
+class Family:
+    """What a block family IS, for everything in `serving/` that has to know:
+    one row of `FAMILIES`. A composite family (every one but "post_ln") is
+    ONE op that `_stack` appends to each program, and its row describes that
+    op; the facts `DecoderConfig`'s properties (which say what each means)
+    and the engine ask for stand beside it, as functions of `cfg` where the
+    configuration's values decide. A new family is a row, its `_geometry`,
+    `_param_specs` and `validate`."""
+
+    op: str = ""    # the composite op's type; "": `builders` of its own
+    geometry: Callable = None       # cfg -> the op's attributes
+    # cfg -> {key: (shape, dtype, initializer)}, in the order of the draw
+    param_specs: Callable = None
+    validate: Callable = lambda cfg: None       # raises ValueError
+    # the op's parameter inputs in its order, (op slot, key prefix, keys) a
+    # slot (or cfg -> those rows)
+    groups: tuple | Callable = ()
+    # (op slot, dtype, name in a builder's result) of what the op writes
+    # beside the next token and the logits; a window hands all of them back
+    outputs: tuple = ()
+    # (op slot, pool) of the pools the op updates in place, and what
+    # declares them from `pool_geometry`'s answer
+    pools: tuple = ()
+    pool_geometry: Callable = None
+    declare_pools: Callable = _declare_paged_pools
+    # what a sequence holds beside its pages: "" | "window_pages" (sliding
+    # layers' K/V under page ids of their own: `windowed`) | "state_slots"
+    # (a state every token rewrites in place: `recurrent`)
+    second_pool: str = ""
+    # the five program bodies the `build_*` functions hand over to
+    builders: MappingProxyType = MappingProxyType(_COMPOSITE)
+    stateful: bool = False
+    cache_planes: Callable = lambda cfg: cfg.num_layers
+    state_layers: Callable = lambda cfg: 0
+    routed_layers: Callable = lambda cfg: cfg.num_layers
+    latent_layers: Callable = lambda cfg: 0
+    selects: Callable = lambda cfg: False
+    page_bucket_step: int = 0
+    one_page_bucket: bool = False
+    # the engine's: which counters book a state update ("ssm" | "kda");
+    # (cfg, rows, state pool's shape) -> whether the Pallas kernel updates a
+    # decode step's states; whether a window's expert calls may take the
+    # grouped form; whether a step counts visits of a loop
+    state_kind: str = "ssm"
+    state_update_runs: Callable = None
+    grouped_experts: bool = False
+    loop_visits: bool = False
+
+
+def _ssm_update_runs(cfg, rows, pool):
+    return parallel_ssm_ops.ssm_update_runs(
+        rows, pool, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+        cfg.ssm_state)
+
+
+_KV_POOLS = tuple(zip(("KPool", "VPool"), STACKED_POOLS))
+_STATE_POOLS = tuple(zip(("SPool", "CPool"), STATE_POOLS))
+
+FAMILIES = {
+    "post_ln": Family(builders=MappingProxyType({
+        "prefill": _post_ln_prefill, "window": _post_ln_window,
+        "cow": _post_ln_cow, "decode": _post_ln_decode,
+        "full": _post_ln_full})),
+    "cca_moe": Family(
+        op="cca_moe_stack", geometry=_cca_geometry,
+        param_specs=_cca_param_specs, validate=_validate_cca,
+        groups=(
+            _EMB, _FINAL_NORM, ("LayerParams", "", cca_moe_ops.LAYER_PARAMS),
+            ("Experts", "", cca_moe_ops.EXPERT_PARAMS)),
+        outputs=(_ROUTES,), pools=_KV_POOLS + (("SPool", STACKED_POOLS[2]),),
+        pool_geometry=_cca_pool_geometry, stateful=True,
+        grouped_experts=True),
+    "sparse_moe": Family(
+        op="sparse_moe_stack", geometry=_sparse_geometry,
+        param_specs=_sparse_param_specs, validate=_validate_sparse,
+        groups=_EMB_HEAD_NORM + (
+            ("LayerParams", "", sparse_moe_ops.LAYER_PARAMS),
+            ("Experts", "", sparse_moe_ops.EXPERT_PARAMS)),
+        outputs=(_ROUTES, _SELECTION),
+        # a token's K and V in one row of one pool: this block gathers tokens
+        pools=(("KVPool", JOINED_POOL), ("IPool", INDEX_POOL)),
+        pool_geometry=_sparse_pool_geometry,
+        selects=lambda cfg: True, page_bucket_step=32, grouped_experts=True),
+    "hybrid_moe": Family(
+        op="hybrid_moe_stack", geometry=lambda cfg: dict(
+            _hybrid_geometry(cfg),
+            plan=[str(v) for layer in layer_plan(cfg) for v in layer]),
+        param_specs=_hybrid_param_specs, validate=_validate_hybrid,
+        groups=_EMB_HEAD_NORM + (
+            ("LayerParams", "", hybrid_moe_ops.LAYER_PARAMS),
+            ("FullParams", "full.", hybrid_moe_ops.ATTENTION_PARAMS),
+            ("SlideParams", "slide.", hybrid_moe_ops.ATTENTION_PARAMS),
+            ("DenseParams", "dense.", hybrid_moe_ops.DENSE_PARAMS),
+            ("MoeParams", "moe.", hybrid_moe_ops.MOE_PARAMS),
+            ("Experts", "", hybrid_moe_ops.EXPERT_PARAMS)),
+        outputs=(_ROUTES,),
+        pools=_KV_POOLS + tuple(zip(("WKPool", "WVPool"), WINDOW_POOLS)),
+        pool_geometry=hybrid_pool_geometry,
+        declare_pools=_declare_hybrid_pools, second_pool="window_pages",
+        routed_layers=lambda cfg: sum(
+            kind == "sparse" for kind in cfg.mlp_layer_types),
+        # its layers are unrolled: a compile of every layer for each (row
+        # bucket, page bucket) program (ten page buckets below 19k tokens
+        # are seventy decode programs), and its paged decode kernels pass a
+        # block of dead pages in a grid step of a third of a microsecond
+        one_page_bucket=True, grouped_experts=True),
+    "parallel_ssm": Family(
+        op="parallel_ssm_stack", geometry=_ssm_geometry,
+        param_specs=_ssm_param_specs, validate=_validate_ssm,
+        groups=_EMB_HEAD_NORM + (
+            ("LayerParams", "", parallel_ssm_ops.LAYER_PARAMS),),
+        pools=_KV_POOLS + _STATE_POOLS, pool_geometry=_ssm_pool_geometry,
+        declare_pools=_declare_ssm_pools, second_pool="state_slots",
+        state_layers=lambda cfg: cfg.num_layers,
+        routed_layers=lambda cfg: 0,
+        # every layer compiles once (a scan) but a step streams 7.8 GB of
+        # weights whatever the table's width: six page buckets would be six
+        # times the programs to warm for nothing a step could gain
+        one_page_bucket=True, state_update_runs=_ssm_update_runs),
+    "latent_moe": Family(
+        op="latent_moe_stack", geometry=_latent_geometry,
+        param_specs=_latent_param_specs, validate=_validate_latent,
+        groups=_latent_groups, outputs=(_ROUTES, _SELECTION),
+        # a token's latent and rotary key in one row of one pool and, behind
+        # an indexer, its indexer key
+        pools=(("LatentPool", LATENT_POOL), ("IPool", INDEX_POOL)),
+        pool_geometry=_latent_pool_geometry,
+        routed_layers=lambda cfg: cfg.num_layers - cfg.dense_layers,
+        latent_layers=lambda cfg: cfg.num_layers,
+        selects=lambda cfg: cfg.index_topk > 0, page_bucket_step=32,
+        grouped_experts=True),
+    "mixer_moe": Family(
+        op="mixer_moe_stack", geometry=_mixer_geometry,
+        param_specs=_mixer_param_specs, validate=_validate_mixer,
+        groups=_EMB_HEAD_NORM + (("Norms", "", ("norm",)),) + _MIXER_GROUPS,
+        outputs=(_ROUTES,), pools=_KV_POOLS + _STATE_POOLS,
+        pool_geometry=_mixer_pool_geometry,
+        declare_pools=_declare_ssm_pools, second_pool="state_slots",
+        state_layers=lambda cfg: cfg.layer_pattern.count(
+            mixer_moe_ops.MIXER),
+        routed_layers=lambda cfg: cfg.layer_pattern.count(
+            mixer_moe_ops.EXPERTS),
+        one_page_bucket=True, state_update_runs=_ssm_update_runs),
+    "kda_moe": Family(
+        op="kda_moe_stack", geometry=_kda_geometry,
+        param_specs=_kda_param_specs, validate=_validate_kda,
+        groups=_EMB_HEAD_NORM + (("Norms", "", ("norm",)),) + _KDA_GROUPS,
+        outputs=(_ROUTES,),
+        pools=(("LatentPool", LATENT_POOL),) + _STATE_POOLS,
+        pool_geometry=_kda_pool_geometry, declare_pools=_declare_ssm_pools,
+        second_pool="state_slots",
+        state_layers=lambda cfg: cfg.num_layers - cfg.latent_layers,
+        routed_layers=lambda cfg: cfg.num_layers - cfg.dense_layers,
+        latent_layers=lambda cfg: cfg.num_layers // cfg.layer_group_size,
+        page_bucket_step=32, one_page_bucket=True, state_kind="kda",
+        state_update_runs=lambda cfg, rows, pool: kda_ops.kda_update_runs(
+            pool, cfg.ssm_state),
+        grouped_experts=True),
+    "looped_dense": Family(
+        op="looped_dense_stack", geometry=_looped_geometry,
+        param_specs=_looped_param_specs, validate=_validate_looped,
+        groups=_EMB_HEAD_NORM + (
+            ("GateW", "", ("dec.exit_gate.w",)),
+            ("GateB", "", ("dec.exit_gate.b",)),
+            ("LayerParams", "", looped_dense_ops.LAYER_PARAMS)),
+        outputs=(_EXIT_MASS,), pools=_KV_POOLS,
+        pool_geometry=_looped_pool_geometry,
+        # a plane a VISIT of a layer, each with K/V pages of its own
+        cache_planes=lambda cfg: cfg.num_layers * cfg.loop_steps,
+        routed_layers=lambda cfg: 0,
+        # 16 pages: whole grid steps of its paged decode kernel at pages of
+        # 16, 32 or 64 tokens (16, 8 or 4 pages a step:
+        # `paged_attention.pages_per_grid_step`), so its one page bucket is
+        # the context cap's own width and not a block of dead steps wider
+        page_bucket_step=16,
+        # the family whose POOL bounds the rows in flight: a row that lost
+        # its pages comes back as a prompt of its own prompt and everything
+        # it had produced, at lengths no arrival has, and under one width
+        # its windows are the programs the arrivals' windows compiled (a
+        # window's length is its only other shape)
+        one_page_bucket=True, loop_visits=True),
 }

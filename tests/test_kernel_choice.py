@@ -2,7 +2,7 @@
 
 The choice of a kernel lives in code: each lever has ONE dispatch function
 that answers from shapes and dtypes (`attention_ops._paged_arm`,
-`cca_moe_ops._experts_backend`, `sparse_moe_ops.paged_indexer_runs`,
+`decoder_common._experts_backend`, `sparse_moe_ops.paged_indexer_runs`,
 `parallel_ssm_ops._update_backend`, `parallel_ssm_ops.conv_update_runs`,
 `latent_moe_ops.latent_attend_runs`, `latent_moe_ops.paged_attend_runs`,
 `attention_ops.attention_backend`), and with `FLAGS_tuning_mode` off, as in
@@ -51,9 +51,9 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import executor
-from paddle_tpu.ops import (attention_ops, cca_moe_ops, kda_ops,
-                            latent_moe_ops, mixer_moe_ops, parallel_ssm_ops,
-                            sparse_moe_ops)
+from paddle_tpu.ops import (attention_ops, cca_moe_ops, decoder_common,
+                            kda_ops, latent_moe_ops, mixer_moe_ops,
+                            parallel_ssm_ops, sparse_moe_ops)
 from paddle_tpu.ops.pallas_kernels import workbench
 from paddle_tpu.serving import DecoderConfig, PagedKVPool, ServingEngine
 from paddle_tpu.serving import model as sv_model
@@ -69,8 +69,8 @@ LEVERS = {
                        r"paged_decode_attention(_gqa)? "),
     "window_attention": (((attention_ops, "_paged_arm"),), None,
                          r"paged_window_attention_gqa "),
-    "experts": (((cca_moe_ops, "_experts_backend"),
-                 (sparse_moe_ops, "_experts_backend"),
+    "experts": (((decoder_common, "_experts_backend"),
+                 (cca_moe_ops, "_experts_backend"),
                  (mixer_moe_ops, "_experts_backend")),
                 lambda backend: backend == "pallas",
                 r"moe_(top1|topk|relu2)_experts_"),

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops import cca_moe_ops
+from paddle_tpu.ops import decoder_common
 from paddle_tpu.ops.pallas_kernels import moe_experts as pme
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving import model as sv_model
@@ -199,7 +199,7 @@ def test_a_window_of_more_than_256_tokens_books_the_grouped_counters(
     chip the plain sum runs and nothing is booked."""
     if on_chip:
         monkeypatch.setattr(
-            cca_moe_ops, "experts_grouped",
+            decoder_common, "experts_grouped",
             lambda tokens, shape, dtype: tokens > pme._TOKEN_TILE)
     cfg = sv_model.latent_moe_tiny(prefill_chunk=512, max_position=1024)
     eng = ServingEngine(cfg, page_size=8, pool_pages=64, max_inflight=4,

@@ -477,7 +477,7 @@ def test_latent_moe_stack_compiled_for_v5e_moves_neither_pool(v5e_chip,
     from jax.sharding import SingleDeviceSharding
 
     from paddle_tpu.ops import latent_moe_ops as ops
-    from paddle_tpu.ops import sparse_moe_ops
+    from paddle_tpu.ops import decoder_common
     from paddle_tpu.ops.pallas_kernels import workbench
     from paddle_tpu.serving import kv_cache
     from paddle_tpu.serving import model as sv_model
@@ -512,7 +512,7 @@ def test_latent_moe_stack_compiled_for_v5e_moves_neither_pool(v5e_chip,
                tuple(params[k] for k in ops.EXPERT_PARAMS))
     # on the chip the expert layer is the kernel (here jax sees a CPU), and
     # so are a decode step's indexer scores
-    monkeypatch.setattr(sparse_moe_ops, "_experts_backend",
+    monkeypatch.setattr(decoder_common, "_experts_backend",
                         lambda *a: "pallas")
     monkeypatch.setattr(workbench, "on_tpu", lambda: True)
 
@@ -585,7 +585,7 @@ def test_latent_streams_stack_compiled_for_v5e_reads_its_pages_in_place(
     from jax.sharding import SingleDeviceSharding
 
     from paddle_tpu.ops import latent_moe_ops as ops
-    from paddle_tpu.ops import sparse_moe_ops
+    from paddle_tpu.ops import decoder_common
     from paddle_tpu.ops.pallas_kernels import workbench
     from paddle_tpu.serving import kv_cache
     from paddle_tpu.serving import model as sv_model
@@ -618,7 +618,7 @@ def test_latent_streams_stack_compiled_for_v5e_reads_its_pages_in_place(
                {k: params["dense." + k] for k in shared + ops.DENSE_PARAMS},
                {k: params["moe." + k] for k in shared + ops.MOE_PARAMS},
                tuple(params[k] for k in ops.EXPERT_PARAMS))
-    monkeypatch.setattr(sparse_moe_ops, "_experts_backend",
+    monkeypatch.setattr(decoder_common, "_experts_backend",
                         lambda *a: "pallas")
     monkeypatch.setattr(workbench, "on_tpu", lambda: True)
 
@@ -687,7 +687,7 @@ def test_kda_moe_stack_compiled_for_v5e_moves_no_pool(v5e_chip, monkeypatch):
     from jax.sharding import SingleDeviceSharding
 
     from paddle_tpu.ops import kda_ops as ops
-    from paddle_tpu.ops import sparse_moe_ops
+    from paddle_tpu.ops import decoder_common
     from paddle_tpu.ops.pallas_kernels import workbench
     from paddle_tpu.serving import kv_cache
     from paddle_tpu.serving import model as sv_model
@@ -722,7 +722,7 @@ def test_kda_moe_stack_compiled_for_v5e_moves_no_pool(v5e_chip, monkeypatch):
         {k: params[prefix + k] for k in keys}
         for _, prefix, keys in sv_model._KDA_GROUPS[:4]) + (
         tuple(params[k] for k in ops.EXPERT_PARAMS),)
-    monkeypatch.setattr(sparse_moe_ops, "_experts_backend",
+    monkeypatch.setattr(decoder_common, "_experts_backend",
                         lambda *a: "pallas")
     monkeypatch.setattr(workbench, "on_tpu", lambda: True)
 

@@ -58,6 +58,19 @@ register with as many KV heads as query heads now takes the matrix-unit arm
 out once before the two loops. No other configuration has such a shape:
 `ouro_2_6b`'s window (no paged decode call in it) and the twenty older
 lines stand.
+
+PR 56 added TWENTY-THREE lines and recomputed none, BEFORE it replaced the
+eight families' hand-written program builders with one builder over a table
+(`serving.model.FAMILIES`): for each of the ten configurations its `startup`
+line (no trace: the initializer ops of the startup program the engine runs,
+in the order the parameters are created, each with its name, shape, dtype
+and distribution; that order is the order of the weights' draw and of the
+allocations of start-up) and its `cow` line (the copy-on-write program's
+jaxpr), and for the three recurrent configurations a `state_copy` line
+(`build_state_copy_program`'s). All twenty-three were computed by this
+file's `program_hash` in a checkout of the parent (commit 95174a9), whose
+builders were the per-family ones; the twenty-two older lines are what they
+were there.
 """
 import hashlib
 from unittest import mock
@@ -65,9 +78,12 @@ from unittest import mock
 import jax
 import pytest
 
+import paddle_tpu as pt
 from paddle_tpu import executor
 from paddle_tpu.ops.pallas_kernels import workbench
-from tests.test_kernel_choice import _read_shapes, _serving_program
+from paddle_tpu.serving import DecoderConfig, ServingEngine
+from paddle_tpu.serving import model as sv_model
+from tests.test_kernel_choice import _config, _read_shapes, _serving_program
 
 # (configuration, program, rows or tokens) -> sha256(str(jaxpr))[:16]
 HASHES = {
@@ -93,16 +109,74 @@ HASHES = {
     ("ling3_flash", "prefill", "2048"): "368abb70195f5e36",
     ("ouro_2_6b", "decode", "32"): "d955487264984fa1",
     ("ouro_2_6b", "prefill", "512"): "d743f065c1e242e3",
+    # PR 56, computed at the parent (commit 95174a9): the weights' draw, the
+    # copy-on-write step and the state copy
+    ("bert_base_decoder", "startup", "0"): "647813df1f0bb392",
+    ("bert_base_decoder", "cow", "1"): "aa30212903820bbe",
+    ("zaya1_8b", "startup", "0"): "5ce1ec9e8a5211a0",
+    ("zaya1_8b", "cow", "1"): "e52d091c8b334314",
+    ("keye_vl2_30b_a3b", "startup", "0"): "56653c69176fa4ec",
+    ("keye_vl2_30b_a3b", "cow", "1"): "eea6f756af060f45",
+    ("laguna_xs2", "startup", "0"): "ceb805f866c044b2",
+    ("laguna_xs2", "cow", "1"): "5a615955c16c94bc",
+    ("falcon_h1_34b", "startup", "0"): "9239599149eb8baa",
+    ("falcon_h1_34b", "cow", "1"): "7c342ecf0eb6fbf8",
+    ("falcon_h1_34b", "state_copy", "1"): "80074feb4c7c3680",
+    ("deepseek_v32_exp", "startup", "0"): "e42e53ce4260b278",
+    ("deepseek_v32_exp", "cow", "1"): "dd17ac12483c5e35",
+    ("nemotron3_super_120b", "startup", "0"): "baaff206c8603881",
+    ("nemotron3_super_120b", "cow", "1"): "dad62859eb6491c4",
+    ("nemotron3_super_120b", "state_copy", "1"): "fca8f7d04eeaa029",
+    ("xing4_29b_a4b", "startup", "0"): "113b6c024493249e",
+    ("xing4_29b_a4b", "cow", "1"): "0ec73ef469b4f66d",
+    ("ling3_flash", "startup", "0"): "eb26ee89835463df",
+    ("ling3_flash", "cow", "1"): "a4ed9677c77d1711",
+    ("ling3_flash", "state_copy", "1"): "38327b4e1fbe7aca",
+    ("ouro_2_6b", "startup", "0"): "8d13a8289b7a584b",
+    ("ouro_2_6b", "cow", "1"): "b5d73dc8184995d7",
 }
 
 
-def program_hash(name: str, program: str, size: int) -> str:
-    block, fed, rows = _serving_program(name, program, size)
+def _traced_hash(block, fed: dict, rows: int) -> str:
     env = _read_shapes(block, fed, rows)
     with mock.patch.object(workbench, "on_tpu", lambda: True):
         jaxpr = jax.make_jaxpr(
             lambda env: executor._run_ops_traced(block, dict(env)))(env)
     return hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16]
+
+
+def _engine_sizes(name: str):
+    """(cfg, pool pages, page size, the second pool as the engine sizes it)
+    of configuration `name`."""
+    engine = _config(name)["engine"]
+    cfg = DecoderConfig(**engine["config_kwargs"])
+    sizes = ServingEngine.default_sizes(cfg, engine["page_size"],
+                                        engine["max_inflight"])
+    del sizes["token_slots"]
+    return cfg, engine["pool_pages"], engine["page_size"], sizes
+
+
+def program_hash(name: str, program: str, size: int) -> str:
+    if program == "startup":
+        # the weights' draw: every initializer op of the startup program the
+        # engine runs (the prefill program's), in the order the parameters
+        # were created, with its shape, dtype and distribution
+        cfg, pages, ps, second = _engine_sizes(name)
+        startup = pt.Program()
+        with pt.program_guard(pt.Program(), startup), pt.unique_name.guard():
+            sv_model.build_prefill_program(cfg, pages, ps, **second)
+        drawn = [(op.type, sorted(op.outputs.items()),
+                  sorted(op.attrs.items())) for op in startup.global_block.ops]
+        return hashlib.sha256(repr(drawn).encode()).hexdigest()[:16]
+    if program in ("cow", "state_copy"):
+        cfg, pages, ps, second = _engine_sizes(name)
+        build = sv_model.build_cow_program if program == "cow" \
+            else sv_model.build_state_copy_program
+        main = pt.Program()
+        with pt.program_guard(main, pt.Program()), pt.unique_name.guard():
+            build(cfg, pages, ps, **second)
+        return _traced_hash(main.global_block, {}, 1)
+    return _traced_hash(*_serving_program(name, program, size))
 
 
 @pytest.mark.parametrize("case", sorted(HASHES), ids="-".join)

@@ -16,7 +16,7 @@ from paddle_tpu import observability as obs
 from paddle_tpu import unique_name
 from paddle_tpu.executor import Executor, Scope
 from paddle_tpu.framework import Program, program_guard
-from paddle_tpu.ops import attention_ops, hybrid_moe_ops
+from paddle_tpu.ops import attention_ops, decoder_common, hybrid_moe_ops
 from paddle_tpu.serving import DecoderConfig, ServingEngine, kv_cache
 from paddle_tpu.serving import model as sv_model
 from serving_helpers import preempting
@@ -465,7 +465,7 @@ def test_yarn_inverse_frequencies_as_published():
     times slower, a linear ramp between; the op and the reference, each
     written out on its own, agree."""
     yarn = (64.0, 4096, 64.0, 1.0, 1.4158883083359672)
-    inv = hybrid_moe_ops.yarn_inv_freq_fn(64, 5e5, yarn)
+    inv = decoder_common.yarn_inv_freq_fn(64, 5e5, yarn)
     own = 5e5 ** (-np.arange(32) / 32.0)
     np.testing.assert_allclose(inv[:6], own[:6], rtol=1e-6)
     np.testing.assert_allclose(inv[16:], own[16:] / 64, rtol=1e-6)
@@ -475,7 +475,7 @@ def test_yarn_inverse_frequencies_as_published():
     np.testing.assert_allclose(
         inv, laguna_lm.inverse_frequencies(64, 5e5, yarn), rtol=1e-6)
     np.testing.assert_allclose(
-        hybrid_moe_ops.yarn_inv_freq_fn(128, 1e4),
+        decoder_common.yarn_inv_freq_fn(128, 1e4),
         laguna_lm.inverse_frequencies(128, 1e4), rtol=1e-6)
 
 
@@ -484,7 +484,7 @@ def test_sigmoid_router_weighs_without_the_bias_and_breaks_ties_low():
     w = jnp.asarray([[2., 2., 0., -1.], [0., 1., 1., 1.],
                      [1., 0., 0., 3.], [0., 0., 0., 0.]], jnp.float32)
     bias = jnp.asarray([0., 0., 0.05, 0.], jnp.float32)
-    ids, cw = hybrid_moe_ops.sigmoid_router_fn(z, w, bias, 2, 2.5)
+    ids, cw = decoder_common.sigmoid_router_fn(z, w, bias, 2, 2.5)
     s = np.asarray(jax.nn.sigmoid(w))
     # row 0: a tie of experts 0 and 1; row 1: the bias lifts expert 2 over
     # its equals; row 3: every score equal, the two lowest indices but for
@@ -546,7 +546,7 @@ def test_moe_experts_pallas_at_256_experts(monkeypatch):
     wd = jnp.asarray(rng.standard_normal((2, E, F, H)) * F ** -0.5,
                      jnp.bfloat16)
     router = jnp.asarray(rng.standard_normal((H, E)), jnp.float32)
-    ids, cw = hybrid_moe_ops.sigmoid_router_fn(
+    ids, cw = decoder_common.sigmoid_router_fn(
         z, router, jnp.zeros((E,), jnp.float32), 8, 2.5)
     assert pme.experts_supported(z.shape, wg.shape, jnp.bfloat16)
     assert int(ids.max()) >= 128        # the second register is read

@@ -22,9 +22,9 @@ if ROOT not in sys.path:
 from benchmark.reference import ling3_lm as ref  # noqa: E402
 from paddle_tpu.ops import kda_ops as ops  # noqa: E402
 from paddle_tpu.ops import latent_moe_ops  # noqa: E402
-from paddle_tpu.ops.hybrid_moe_ops import swiglu_fn  # noqa: E402
+from paddle_tpu.ops.decoder_common import (  # noqa: E402
+    group_limited_router_fn, moe_topk_experts_fn, swiglu_fn)
 from paddle_tpu.ops.pallas_kernels import conv_update, kda_update  # noqa: E402
-from paddle_tpu.ops.sparse_moe_ops import moe_topk_experts_fn  # noqa: E402
 from paddle_tpu.serving import DecoderConfig, ServingEngine  # noqa: E402
 from paddle_tpu.serving.model import kda_moe_tiny  # noqa: E402
 from tools import kda_faults, mixer_faults  # noqa: E402
@@ -302,7 +302,7 @@ def test_the_four_shares_of_the_experts_add_up_to_the_uncut_layer():
         want, _ = ref.moe_layer(full, x, whole, 0)
         p = {k: full["moe." + k][0] for k in ops.MOE_PARAMS}
         z = ref._rms(x, p["ffn_norm"], whole.rms_norm_eps)
-        ids, cw = latent_moe_ops.group_limited_router_fn(
+        ids, cw = group_limited_router_fn(
             z, p["router_w"], p["router_bias"], 2, 4, 2, 2.5)
         parts = [moe_topk_experts_fn(
             z, cw[:, lo:lo + 2], *(full[k][:, lo:lo + 2]

@@ -19,7 +19,7 @@ from paddle_tpu import observability as obs
 from paddle_tpu import unique_name
 from paddle_tpu.executor import Executor, Scope
 from paddle_tpu.framework import Program, program_guard
-from paddle_tpu.ops import hybrid_moe_ops, latent_moe_ops
+from paddle_tpu.ops import decoder_common, latent_moe_ops
 from paddle_tpu.serving import DecoderConfig, ServingEngine
 from paddle_tpu.serving import model as sv_model
 from serving_helpers import preempting
@@ -285,13 +285,13 @@ def test_a_window_behind_a_long_context_reads_each_querys_own_rows(
 
 
 def test_interleaved_and_rotate_half_rotary_pair_their_lanes():
-    inv = hybrid_moe_ops.yarn_inv_freq_fn(4, 1e4, (40.0, 16, 32.0, 1.0, 1.0))
+    inv = decoder_common.yarn_inv_freq_fn(4, 1e4, (40.0, 16, 32.0, 1.0, 1.0))
     np.testing.assert_allclose(inv, ref.yarn_inv_freq(
         4, 1e4, (40.0, 16, 32.0, 1.0)))
     x = jnp.asarray([[[1.0, 0.0, 0.0, 0.0]]])        # one token, one head
     pos = jnp.asarray([3])
     pairs = latent_moe_ops.rotary_interleaved_fn(x, pos, inv)
-    halves = hybrid_moe_ops.rotary_fn(x, pos, inv, 4)
+    halves = decoder_common.rotary_fn(x, pos, inv, 4)
     a = 3 * inv[0]
     np.testing.assert_allclose(pairs[0, 0], [np.cos(a), np.sin(a), 0, 0],
                                atol=1e-6)
@@ -388,7 +388,7 @@ def _newest_mask(scores, limit, k):
 
 def _no_group_limit(z, router_w, router_bias, k, groups, groups_kept,
                     scaling):
-    return hybrid_moe_ops.sigmoid_router_fn(z, router_w, router_bias, k,
+    return decoder_common.sigmoid_router_fn(z, router_w, router_bias, k,
                                             scaling)
 
 
@@ -404,7 +404,7 @@ def _bias_weighs(z, router_w, router_bias, k, groups, groups_kept, scaling):
                                   weights[:, :, None], 0.0), axis=1)
 
 
-_RIGHT_ROUTER = latent_moe_ops.group_limited_router_fn
+_RIGHT_ROUTER = decoder_common.group_limited_router_fn
 _FAULTS = {
     "the_newest_k": {"select_indices_fn": _newest_indices,
                      "select_mask_fn": _newest_mask},
@@ -447,7 +447,7 @@ def test_group_limited_router_keeps_the_best_groups_and_weighs_without_bias():
     logits = np.asarray([[1.0, 0.9, 2.0, -4.0, -1.0, -1.0, 0.8, 0.7],
                          [0.0] * 8, [0.0] * 8], np.float32)
     bias = np.zeros(8, np.float32)
-    ids, cw = latent_moe_ops.group_limited_router_fn(
+    ids, cw = decoder_common.group_limited_router_fn(
         z, jnp.asarray(logits), jnp.asarray(bias), 3, 4, 2, 2.5)
     assert sorted(ids[0].tolist()) == [0, 1, 6]      # not expert 2
     s = 1 / (1 + np.exp(-logits[0]))
@@ -459,7 +459,7 @@ def test_group_limited_router_keeps_the_best_groups_and_weighs_without_bias():
     assert ids[1].tolist() == [0, 1, 2]
     # a bias moves the choice and not the weight
     bias[2] = 5.0
-    ids, cw2 = latent_moe_ops.group_limited_router_fn(
+    ids, cw2 = decoder_common.group_limited_router_fn(
         z, jnp.asarray(logits), jnp.asarray(bias), 3, 4, 2, 2.5)
     assert 2 in ids[0].tolist() and float(cw2[0, 2]) < 2.5 * s[2] + 1e-6
 
@@ -484,9 +484,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
          "shared_gate": draw(H, F), "shared_up": draw(H, F),
          "shared_down": draw(F, H)}
     experts = (draw(1, E, H, F), draw(1, E, H, F), draw(1, E, F, H))
-    u = np.asarray(latent_moe_ops.rms_norm_fn(h, p["ffn_norm"],
+    u = np.asarray(decoder_common.rms_norm_fn(h, p["ffn_norm"],
                                               geom.eps)).reshape(-1, H)
-    shared = np.asarray(hybrid_moe_ops.swiglu_fn(
+    shared = np.asarray(decoder_common.swiglu_fn(
         jnp.asarray(u), p["shared_gate"], p["shared_up"], p["shared_down"]))
     total = np.zeros_like(u)
     pairs = 0

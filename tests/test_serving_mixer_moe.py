@@ -20,7 +20,7 @@ if ROOT not in sys.path:
 
 from benchmark.reference import nemotron3_lm as ref  # noqa: E402
 from paddle_tpu.ops import mixer_moe_ops as ops  # noqa: E402
-from paddle_tpu.ops.latent_moe_ops import group_limited_router_fn  # noqa: E402
+from paddle_tpu.ops.decoder_common import group_limited_router_fn  # noqa: E402
 from paddle_tpu.ops.pallas_kernels import moe_experts as pme  # noqa: E402
 from paddle_tpu.ops.pallas_kernels import ssm_update  # noqa: E402
 from paddle_tpu.serving import DecoderConfig, ServingEngine  # noqa: E402

@@ -17,7 +17,7 @@ from paddle_tpu import observability as obs
 from paddle_tpu import unique_name
 from paddle_tpu.executor import Executor, Scope
 from paddle_tpu.framework import Program, program_guard
-from paddle_tpu.ops import sparse_moe_ops
+from paddle_tpu.ops import decoder_common, sparse_moe_ops
 from paddle_tpu.serving import DecoderConfig, ServingEngine
 from paddle_tpu.serving import model as sv_model
 from serving_helpers import preempting
@@ -364,8 +364,8 @@ def test_topk_router_combines_as_a_per_token_loop():
                           jnp.float32) for _ in range(2))
     wd = jnp.asarray(rng.standard_normal((1, E, F, H)) * F ** -0.5,
                      jnp.float32)
-    ids, cw = sparse_moe_ops.topk_router_fn(z, router_w, k)
-    got = sparse_moe_ops.moe_topk_experts_fn(z, cw, wg, wu, wd)
+    ids, cw = decoder_common.topk_router_fn(z, router_w, k)
+    got = decoder_common.moe_topk_experts_fn(z, cw, wg, wu, wd)
     probs = np.asarray(jax.nn.softmax(z @ router_w, axis=-1), np.float64)
     zs = np.asarray(z, np.float64)
     for t in range(T):
@@ -524,7 +524,7 @@ def test_moe_experts_pallas_at_width_768(dtype, monkeypatch):
         got = pme.moe_topk_experts(z, jnp.asarray(cw), wg, wu, wd, layer)
         want = pme._reference(z, jnp.asarray(cw), wg, wu, wd, layer)
         np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
-    via = sparse_moe_ops.moe_topk_experts_fn(z, jnp.asarray(cw), wg, wu, wd,
+    via = decoder_common.moe_topk_experts_fn(z, jnp.asarray(cw), wg, wu, wd,
                                              layer=1)
     np.testing.assert_allclose(
         via, pme._reference(z, jnp.asarray(cw), wg, wu, wd, 1),

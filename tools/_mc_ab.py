@@ -1,7 +1,7 @@
 """Multichip scaling campaign + seeded collective-overlap A/B sweep.
 
-The measured half of ROADMAP item 2: the MULTICHIP artifact stops being a
-loss-parity dryrun and gains NUMBERS. One seeded BERT-shaped workload (same
+What the parallelism axes cost and gain on a mesh of (virtual) devices, as
+numbers beside the loss-parity check: one seeded BERT-shaped workload (same
 global batch everywhere, so tokens/s compare) is trained under every
 parallelism axis of an 8-device mesh —
 

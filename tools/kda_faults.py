@@ -47,7 +47,7 @@ from paddle_tpu.ops import kda_ops as ops  # noqa: E402
 from paddle_tpu.ops.pallas_kernels import kda_update  # noqa: E402
 from paddle_tpu.serving import model as sv_model  # noqa: E402
 from tools import mixer_faults  # noqa: E402
-from tools.ssm_faults import restore_shares_slot  # noqa: E402
+from tools.ssm_faults import geometry_of, restore_shares_slot  # noqa: E402
 
 
 @contextlib.contextmanager
@@ -131,9 +131,8 @@ def no_head_gate():
 def no_routed_scaling():
     real = sv_model._kda_geometry
 
-    with mock.patch.object(
-            sv_model, "_kda_geometry",
-            lambda cfg: dict(real(cfg), routed_scaling=1.0)):
+    with geometry_of("kda_moe",
+                     lambda cfg: dict(real(cfg), routed_scaling=1.0)):
         yield
 
 

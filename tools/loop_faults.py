@@ -45,6 +45,7 @@ import numpy as np  # noqa: E402
 
 from paddle_tpu.ops import looped_dense_ops as ops  # noqa: E402
 from paddle_tpu.serving import model as sv_model  # noqa: E402
+from tools.ssm_faults import geometry_of  # noqa: E402
 
 
 @contextlib.contextmanager
@@ -63,7 +64,7 @@ def three_visits():
         geometry = real(cfg)
         return dict(geometry, loop_steps=geometry["loop_steps"] - 1)
 
-    with mock.patch.object(sv_model, "_looped_geometry", one_fewer):
+    with geometry_of("looped_dense", one_fewer):
         yield
 
 

@@ -53,7 +53,7 @@ from paddle_tpu.ops import mixer_moe_ops as ops  # noqa: E402
 from paddle_tpu.ops import parallel_ssm_ops  # noqa: E402
 from paddle_tpu.ops.pallas_kernels import ssm_update  # noqa: E402
 from paddle_tpu.serving import model as sv_model  # noqa: E402
-from tools.ssm_faults import restore_shares_slot  # noqa: E402
+from tools.ssm_faults import geometry_of, restore_shares_slot  # noqa: E402
 
 
 @contextlib.contextmanager
@@ -94,9 +94,8 @@ def no_latent_up_projection():
 def no_routed_scaling():
     real = sv_model._mixer_geometry
 
-    with mock.patch.object(
-            sv_model, "_mixer_geometry",
-            lambda cfg: dict(real(cfg), routed_scaling=1.0)):
+    with geometry_of("mixer_moe",
+                     lambda cfg: dict(real(cfg), routed_scaling=1.0)):
         yield
 
 

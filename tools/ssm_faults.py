@@ -32,6 +32,7 @@ passes the limit. Exit 1 if the right engine fails or a wrong one passes.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
 import json
 import os
@@ -47,6 +48,14 @@ from paddle_tpu.ops import parallel_ssm_ops as ops  # noqa: E402
 from paddle_tpu.ops.pallas_kernels import ssm_update  # noqa: E402
 from paddle_tpu.serving import engine as sv_engine  # noqa: E402
 from paddle_tpu.serving import model as sv_model  # noqa: E402
+
+
+def geometry_of(block: str, geometry):
+    """Programs built inside carry `geometry(cfg)` as the attributes of the
+    `block` family's op (its row of `FAMILIES` with that one field
+    replaced)."""
+    row = dataclasses.replace(sv_model.FAMILIES[block], geometry=geometry)
+    return mock.patch.dict(sv_model.FAMILIES, {block: row})
 
 
 def _rounded(s):
@@ -152,9 +161,8 @@ def no_attention():
 def no_key_multiplier():
     real = sv_model._ssm_geometry
 
-    with mock.patch.object(
-            sv_model, "_ssm_geometry",
-            lambda cfg: dict(real(cfg), key_multiplier=1.0)):
+    with geometry_of("parallel_ssm",
+                     lambda cfg: dict(real(cfg), key_multiplier=1.0)):
         yield
 
 
