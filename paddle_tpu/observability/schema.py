@@ -511,6 +511,11 @@ DECLARED: list[tuple] = [
      "its prompt's pages were free: the pages the running rows have yet to "
      "take to their known ends (prompt_len + max_new_tokens) were reserved "
      "first", ()),
+    ("serving.timeline_admissions", COUNTER,
+     "admissions that the sum of the running rows' known ends would have "
+     "held and the timeline of those ends let in: at every decode step up "
+     "to the request's own end, the pages it and the rows hold by then fit "
+     "in what the rows that leave before have returned", ()),
     # -- the host's own pauses (observability/registry._GcWatch) -------------
     ("host.gc.collections", COUNTER,
      "garbage collections by generation", ("generation",)),

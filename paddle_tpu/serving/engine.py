@@ -150,8 +150,9 @@ everything the host does by page id (allocate, share, copy-on-write, release,
 the audit, the leak count) answers for every visit of every layer at once,
 and a token's cache is that many rows: the POOL, not `max_inflight`, bounds
 the rows in flight, and the backpressure below (admission that waits until
-the pool can carry every row's growth) is the normal path: a waiter stands
-in the queue WITHOUT tokens, never in and out of the pool with them. A row
+the pool can carry every row's growth, step by step to the newcomer's end)
+is the normal path: a waiter stands in the queue WITHOUT tokens, never in
+and out of the pool with them. A row
 that is preempted all the same (copy-on-write, a test's own hand) comes
 back as a prompt of its own prompt and what it had produced, a
 length no arrival has, so the family compiles one page-table width
@@ -159,8 +160,9 @@ length no arrival has, so the family compiles one page-table width
 step hands back, beside the logits, the exit gate's probability of leaving
 after each visit (`request.exit_mass`, one `[visits]` row a generated token;
 `serving.loop.exit_mass` by visit); nothing branches on it. The counters
-`serving.pool_bound_admissions`, `serving.growth_held_admissions` and
-`serving.preempted_tokens` say what the pool's bound cost (any family
+`serving.pool_bound_admissions`, `serving.growth_held_admissions`,
+`serving.timeline_admissions` and `serving.preempted_tokens` say what the
+pool's bound cost and what the timeline of the ends gave back (any family
 books them).
 
 Compile discipline (the PR 2 machinery doing serving duty):
@@ -176,22 +178,38 @@ Compile discipline (the PR 2 machinery doing serving duty):
 Failure/backpressure semantics:
   * admission backpressure RESERVES GROWTH (ISSUE 55): `max_new_tokens` is
     known at `submit`, so a row's table never outgrows
-    `pages_for(prompt_len + max_new_tokens)`. Beside rows that go on, the
-    head of the queue is admitted only if the free pages (unshared
+    `pages_for(prompt_len + max_new_tokens)` (`_end`). Beside rows that go
+    on, the head of the queue is admitted if the free pages (unshared
     prefix-cache pages counted free: they are evicted on demand, LRU-first)
-    cover what every such row has yet to take AND the head's own pages to
-    its end beyond its prefix hit; else it WAITS at the head, holding its
-    pinned hit and no token (`serving.pool_bound_admissions`; where its
-    prompt's pages were free, `serving.growth_held_admissions`). A lone
-    request is admitted whatever its end. The rule reads the pool, never a
-    knob, and does not bind where the rows' ends fit; pages that rows
-    about to finish will return are not counted, and a row that stops
-    on `eos_id` well under its cap was reserved more than it wrote
-    (ROADMAP R12 (a'), (a''));
+    cover what every such row has yet to take to its end AND the head's
+    own pages to its end beyond its prefix hit: the SUM of the ends, which
+    bounds every instant. Where the sum does not fit, the TIMELINE of the
+    ends decides (ISSUE 57, `_ends_fit`): counted in decode steps from
+    now, every row takes one token a step, holds
+    `pages_for(min(tokens + k + lookahead, end))` pages at step k until it
+    is gone, one step after the step of its last write has been
+    dispatched (the engine reads a step one dispatch late), and then
+    has returned the pages that it alone holds; the head is admitted iff
+    at every step up to its own end the rows' pages and its own fit in
+    what is spare now and what the rows gone by then return
+    (`serving.timeline_admissions`). Else it WAITS at the head, holding
+    its pinned hit and no token (`serving.pool_bound_admissions`; where
+    its prompt's pages were free, `serving.growth_held_admissions`). A
+    lone request is admitted whatever its end; the first request that
+    does not fit stops admission. The rule reads the pool, never a knob:
+    where the sum of the ends fits (every deployment whose pool is not
+    what bounds its rows) the timeline is not reckoned. Under
+    draft-verify steps a row takes several tokens a step and the sum
+    decides alone. A row that stops on `eos_id` well under its cap was
+    reserved more than it wrote, at every step of the timeline too
+    (ROADMAP R12 (a''));
   * mid-decode growth therefore finds its page. The net under what the
     reservation does not cover (a copy-on-write's fresh page, the sliding
-    layers' pool, speculation's lookahead, an adopted handoff): when a
-    pool is dry all the same, the YOUNGEST running request is preempted
+    layers' pool, speculation's lookahead, an adopted handoff, cached
+    pages that a waiter pinned after the rows were promised them): when a
+    pool is dry all the same, the waiting requests' pins are given back
+    first (they match again at their next attempt), then the pending step
+    is accepted, then the YOUNGEST running request is preempted
     back to the head of the waiting queue (its refcounts released; on
     re-admission its prompt+generated prefix re-prefills past whatever the
     prefix cache still holds — recompute-style preemption, exact under
@@ -885,6 +903,8 @@ class ServingEngine:
             "loop.visits": 0, "loop.decode_row_visits": 0,
             "loop.exit_mass": 0.0, "preempted_tokens": 0,
             "pool_bound_admissions": 0, "growth_held_admissions": 0,
+            # ... and admission by the timeline of the rows' ends (ISSUE 57)
+            "timeline_admissions": 0,
         }
 
     def _page_bucket(self, n: int) -> int:
@@ -2023,12 +2043,15 @@ class ServingEngine:
 
         Admission RESERVES GROWTH: beside rows that go on, the head is
         admitted only if the pool can carry every one of them, and the
-        head, to its known end (`_pages_to_end`): free pages, the prefix
-        cache's unshared pages counted free as `_allocate` treats them,
-        against what the rows still have to take and what the head takes
-        beyond its prefix hit. Else it waits at the head as it waits for
-        pages. What the rows owe is summed once a call and kept up as
-        the call admits; the pool keeps the other side (`cache_only`)."""
+        head, to its known end (`_pages_to_end`). First by the SUM of the
+        ends: free pages, the prefix cache's unshared pages counted free
+        as `_allocate` treats them, against what the rows still have to
+        take and what the head takes beyond its prefix hit. What the rows
+        owe is summed once a call and kept up as the call admits; the pool
+        keeps the other side (`cache_only`). Where the sum does not fit,
+        by the TIMELINE of the ends (`_ends_fit`): the rows do not stand
+        at their ends together, and one that leaves returns its pages to
+        those that go on. Else the head waits as it waits for pages."""
         admitted = 0
         # pages the rows that go on still take to their ends: summed at
         # the first candidate that meets such rows
@@ -2097,14 +2120,18 @@ class ServingEngine:
             lookahead = 0 if self._ladder_rung >= 2 else 1
             need = self.pool.pages_for(len(req.all_tokens) + lookahead)
             to_end = max(need, self._pages_to_end(req))
-            fits = True
+            fits = by_sum = True
             if staying and not self.prefill_only:
                 # a lone request is admitted whatever its end: nothing
                 # runs, nothing can be owed
                 if owed is None:
                     owed = self._growth_owed()
                 spare = self.pool.free_count + self.pool.cache_only
-                fits = owed + to_end - len(matched) <= spare
+                by_sum = owed + to_end - len(matched) <= spare
+                # the sum of the ends bounds every instant: where it fits
+                # the timeline could only agree, and is not reckoned
+                fits = by_sum or self._ends_fit(req, len(matched),
+                                                lookahead, spare)
                 if not fits and need - len(matched) <= spare:
                     req.held_for_growth = True
             private = self._allocate(max(0, need - len(matched))) \
@@ -2136,6 +2163,8 @@ class ServingEngine:
             if req.held_for_growth:
                 req.held_for_growth = False
                 self._count("growth_held_admissions")
+            if not by_sum:
+                self._count("timeline_admissions")
             if owed is not None:
                 owed += to_end - len(req.pages)
             self._waiting.remove(req)
@@ -2157,19 +2186,82 @@ class ServingEngine:
             admitted += 1
         return admitted
 
+    def _end(self, req: GenRequest) -> int:
+        """Tokens of `req`'s sequence at its known end: a row never writes
+        past `prompt_len + max_new_tokens` (known at `submit`), nor past
+        `max_position`. Exact where the cap is the length; under a cap far
+        above the usual stop the reservation holds pages nobody writes
+        (ROADMAP R12 (a''): an end bounded by the stops seen)."""
+        return min(req.prompt_len + req.max_new_tokens,
+                   self.cfg.max_position)
+
     def _pages_to_end(self, req: GenRequest) -> int:
-        """Pages of the pool `req`'s table holds at its end: a row never
-        writes past `prompt_len + max_new_tokens` (known at `submit`), nor
-        past `max_position`. Exact where the cap is the length; under a
-        cap far above the usual stop the reservation holds pages nobody
-        writes (ROADMAP R12 (a''): an end bounded by the stops seen)."""
-        return self.pool.pages_for(min(req.prompt_len + req.max_new_tokens,
-                                       self.cfg.max_position))
+        """Pages of the pool `req`'s table holds at its end."""
+        return self.pool.pages_for(self._end(req))
 
     def _growth_owed(self) -> int:
         """Pages the rows that go on have yet to take to reach their ends."""
         return sum(max(0, self._pages_to_end(r) - len(r.pages))
                    for r in self._running if not self._leaving(r))
+
+    def _row_ahead(self, r: GenRequest, lookahead: int) -> tuple:
+        """What the timeline of the ends (`_ends_fit`) reads of running row
+        `r`: the step from which it is gone, its tokens at step 0 (the one
+        in flight and the lookahead counted), its end, the pages it holds
+        and those of them it alone holds."""
+        at, end = len(r.all_tokens) + r.in_flight, self._end(r)
+        return (max(0, end - at) + 1, at + lookahead, end, len(r.pages),
+                self.pool.sole_count(r.pages))
+
+    def _ends_fit(self, head: GenRequest, matched: int, lookahead: int,
+                  spare: int) -> bool:
+        """Whether the pool carries the running rows and `head`, `matched`
+        pages of whose table are its pinned prefix hit, at every decode
+        step up to the head's end: the test for a head that the sum of the
+        ends refused (`_admit`), `spare` the pages free or the prefix
+        cache's alone.
+
+        Time is counted in decode steps from the next one, step 0. Every
+        row takes one token a step whatever windows run in between, so a
+        row of `at` tokens (the one in flight counted) holds
+        `pages_for(min(at + k + lookahead, end))` pages at step k. It
+        writes its last slot at step `end - at - 1`, and that step is
+        accepted, its pages released, when the step after it has been
+        enqueued: the row is counted as holding them at step `end - at`
+        too and as gone from `end - at + 1`. Gone, it has returned what it
+        took meanwhile and the pages it alone holds now
+        (`PagedKVPool.sole_count`: free or `cache_only` afterwards, which
+        `spare` counts), not its share of a prefix other requests map.
+        The head joins at step 0, one token past its prompt, and after
+        its own end holds nothing: what stands then was admitted without
+        it. Between two departures what the rows take only rises, so the
+        test is made at the last step before each, one sort and one sweep
+        over the rows. A row that stops on `eos_id` leaves earlier than
+        reckoned, which only returns pages sooner.
+
+        Under draft-verify steps a row takes up to `draft_k + 1` tokens a
+        step, the rows' ends are not ordered as their lengths are, and the
+        sum of the ends decides alone."""
+        if self.draft_k:
+            return False
+        ps = self.page_size
+        rows = sorted(self._row_ahead(r, lookahead) for r in self._running)
+        prompt, head_end = len(head.all_tokens), self._end(head)
+        # the head's last step here, had the prefix cache its whole prompt
+        # (its first token is then the first step's, not its prefill's)
+        last = max(0, head_end - prompt)
+        head_at = prompt + 1 + lookahead
+        returned = gone = 0
+        for k in sorted({min(left - 1, last) for left, *_ in rows} | {last}):
+            while gone < len(rows) and rows[gone][0] <= k:
+                returned += rows[gone][-1]
+                gone += 1
+            taken = max(0, -(-min(head_at + k, head_end) // ps) - matched)
+            for _, at, end, held, _ in rows[gone:]:
+                taken += max(0, -(-min(at + k, end) // ps) - held)
+            if taken > spare + returned:
+                return False
+        return True
 
     def _reserve_window(self, req: GenRequest) -> bool:
         """Whether the sliding layers' pool has, or the prefix cache can
@@ -2623,10 +2715,21 @@ class ServingEngine:
         return new
 
     def _make_room(self, req: GenRequest) -> bool:
-        """A pool ran dry under `req`: accept the pending step if there is
-        one (rows that finish return their pages, and nobody is preempted
-        with a token in flight), else preempt the youngest running request.
-        False once `req` itself is no longer running."""
+        """A pool ran dry under `req`: take back what the waiting requests
+        pin if they pin anything (the prefix hit of a head that admission
+        refused: where only it and the cache hold a page, the page was
+        counted spare when the rows were admitted and is theirs to evict;
+        the waiter matches again at its next attempt), else accept the
+        pending step if there is one (rows that finish return their pages,
+        and nobody is preempted with a token in flight), else preempt the
+        youngest running request. False once `req` itself is no longer
+        running."""
+        pinning = [r for r in self._waiting
+                   if r.pages or r.wpages or r.snap is not None]
+        for r in pinning:
+            self._release(r)
+        if pinning:
+            return True
         if self._pending is not None:
             self._settle()
             return req.state == RUNNING

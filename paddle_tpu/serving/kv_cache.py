@@ -284,6 +284,13 @@ class PagedKVPool:
     def refcount(self, page: int) -> int:
         return self._refs[page]
 
+    def sole_count(self, pages) -> int:
+        """How many of `pages` one of their holders would hand back by
+        releasing them: those that nobody else maps but a prefix cache,
+        free or `cache_only` afterwards."""
+        refs, indexed = self._refs, self._indexed
+        return sum(refs[p] - (p in indexed) == 1 for p in pages)
+
     # -- allocation -----------------------------------------------------------
     def can_allocate(self, n: int) -> bool:
         return n <= len(self._free)
@@ -545,6 +552,9 @@ class OwnedPoolView:
 
     def refcount(self, page: int) -> int:
         return self.pool.refcount(page)
+
+    def sole_count(self, pages) -> int:
+        return self.pool.sole_count(pages)
 
     def can_allocate(self, n: int) -> bool:
         return self.pool.can_allocate(n)

@@ -12,7 +12,7 @@ from paddle_tpu.ops import attention_ops as ao
 from paddle_tpu.serving import (PagedKVPool, PrefixCache, ServingEngine,
                                 build_full_forward_program, decoder_tiny)
 from paddle_tpu.serving import model as sv_model
-from paddle_tpu.serving.kv_cache import pool_shape
+from paddle_tpu.serving.kv_cache import OwnedPoolView, pool_shape
 from serving_helpers import preempting
 
 
@@ -599,6 +599,26 @@ def test_pool_counts_the_pages_only_a_cache_holds():
     assert cache.clear() == 2 and pool.cache_only == 0
     pool.reset()
     assert pool.free_count == 8 and pool.check_consistency() == []
+
+
+def test_pool_counts_the_pages_a_holder_alone_would_return():
+    """`sole_count` (what the timeline of the ends counts returned when a
+    row leaves) is the pages of a table that no other table maps: free, or
+    the cache's alone, once it lets go; through an owner's view too."""
+    pool = PagedKVPool(8, 4)
+    cache = PrefixCache(pool)
+    row = pool.allocate(4)
+    assert pool.sole_count(row) == 4
+    cache.insert(list(range(8)), row)       # the cache indexes two of them
+    assert pool.sole_count(row) == 4
+    other = cache.match(list(range(4)))
+    pool.share(other)                       # a second row maps the first
+    assert pool.sole_count(row) == 3 and pool.sole_count(other) == 0
+    assert OwnedPoolView(pool, "a").sole_count(row) == 3
+    pool.release(other)
+    assert pool.sole_count(row) == 4
+    pool.release(row)
+    assert pool.cache_only == 2 and pool.free_count == 6
 
 
 def _assert_no_leaks(eng):

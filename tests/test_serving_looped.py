@@ -216,38 +216,49 @@ def test_a_pool_too_small_for_the_rows_ends_holds_the_queue_not_the_rows():
 
 def test_a_lone_request_is_admitted_whatever_its_end():
     """Nothing runs, so nothing can be owed: a request whose end lies past
-    what the pool holds free (and past the pool) is admitted as before, and
-    a second one waits behind its reservation."""
-    eng = _engine(pool_pages=12, max_inflight=2)
+    what the pool holds free (and past the pool) is admitted as before.
+    Until ISSUE 57 a 3-token request behind it waited for a reservation
+    that ran to the long row's cap; by the timeline of the ends it is let
+    in at once and is gone long before the long row needs the pages. A
+    request that would stand at 10 pages beside the first's 11 waits."""
+    eng = _engine(pool_pages=12, max_inflight=3)
     long = eng.submit(_prompts((9,), seed=8)[0], 60)    # ends at 16 pages
     short = eng.submit(_prompts((5,), seed=9)[0], 3)
+    again = eng.submit(_prompts((9,), seed=10)[0], 30)  # ends at 10
     eng.step()
     assert eng.requests[long].state == "running"
-    assert eng.requests[short].state == "waiting"
-    assert eng.requests[short].held_for_growth
-    for _ in range(4):
+    assert eng.requests[short].state == "running"
+    assert not eng.requests[short].held_for_growth
+    assert eng.stats["timeline_admissions"] == 1
+    assert eng.requests[again].state == "waiting"
+    assert eng.requests[again].held_for_growth
+    for _ in range(5):
         eng.step()
+    assert eng.requests[short].state == "finished"
     assert eng.requests[long].n_generated >= 3
-    assert eng.requests[short].state == "waiting"
+    assert eng.requests[again].state == "waiting"
     eng.abort(long)                 # its reservation goes with it
     eng.run_until_drained()
-    assert eng.requests[short].state == "finished"
+    assert eng.requests[again].state == "finished"
     assert eng.stats["growth_held_admissions"] == 1
+    assert eng.stats["timeline_admissions"] == 1
     assert eng.stats["preemptions"] == 0
     assert eng.leaked_pages() == 0 and eng.audit_pool() == ([], [])
 
 
 def test_a_stop_on_eos_returns_the_reservation():
-    """A row allowed 50 tokens reserves 14 of 16 pages; it stops on
-    `eos_id` after a few, and the waiter behind it is admitted at once,
-    long before the length the reservation was made for."""
+    """A row allowed 50 tokens reserves 14 of 16 pages, and a second one
+    allowed 44 would stand beside it at 13 of its own: neither the sum of
+    the ends nor their timeline lets it in. The first stops on `eos_id`
+    after a few tokens, and the waiter behind it is admitted at once, long
+    before the length the reservation was made for."""
     prompt, other = _prompts((6, 6), seed=12)
     free = _serve(_engine(), [prompt], out=12)[0].out_tokens
     stop = next(k for k in range(2, 12) if free[k] not in free[:k])
-    want = _serve(_engine(), [other], out=8)[0].out_tokens
+    want = _serve(_engine(), [other], out=44)[0].out_tokens
     eng = _engine(pool_pages=16, max_inflight=2)
     first = eng.submit(prompt, 50, eos_id=free[stop])
-    second = eng.submit(other, 8)
+    second = eng.submit(other, 44)
     steps = 0
     while eng.requests[second].state == "waiting":
         eng.step()
@@ -258,8 +269,161 @@ def test_a_stop_on_eos_returns_the_reservation():
     eng.run_until_drained()
     assert eng.requests[second].out_tokens == want
     assert eng.stats["growth_held_admissions"] == 1
+    assert eng.stats["timeline_admissions"] == 0
     assert eng.stats["preemptions"] == 0
     assert eng.leaked_pages() == 0 and eng.audit_pool() == ([], [])
+
+
+# -- admission by the timeline of the rows' known ends (ISSUE 57) ------------
+
+
+def _by_the_sum_alone(eng):
+    """`eng` under the rule of ISSUE 55: a head that the sum of the ends
+    refuses waits, whatever their timeline."""
+    eng._ends_fit = lambda *a: False
+    return eng
+
+
+def _offer(eng, arrivals):
+    """Serve `arrivals`, (step it is submitted at, prompt, output tokens)
+    in order of step, to the end; returns the requests, and for every step
+    the rids that ran after it."""
+    arrivals, rids, ran = list(arrivals), [], []
+    while arrivals or eng.has_work():
+        while arrivals and arrivals[0][0] <= len(ran):
+            _, prompt, out = arrivals.pop(0)
+            rids.append(eng.submit(prompt, out))
+        eng.step()
+        ran.append(sorted(r.rid for r in eng._running))
+    done = [eng.requests[r] for r in rids]
+    assert all(r.state == "finished" for r in done)
+    return done, ran
+
+
+def _random_arrivals(seed, n=14):
+    """Prompts of 3-30 tokens, half of them behind one head of 8, outputs
+    of 2-40, the first four offered at once and the rest a few steps
+    apart: rows at every point of their lives beside one another."""
+    rng = np.random.default_rng(seed)
+    head = rng.integers(1, 97, 8).tolist()
+    at, out = 0, []
+    for i in range(n):
+        prompt = rng.integers(1, 97, int(rng.integers(3, 31))).tolist()
+        if rng.random() < 0.5:
+            prompt = head + prompt
+        out.append((at, prompt, int(rng.integers(2, 41))))
+        at += 0 if i < 3 else int(rng.integers(0, 6))
+    return out
+
+
+@pytest.mark.parametrize("seed", [57, 2147483659, 3, 11])
+def test_random_lengths_in_a_tight_pool_never_reach_the_net(seed):
+    """Seeded prompt and output lengths into 24 pages under six row slots
+    (the longest request alone ends at 20): every request finishes with
+    the tokens a roomy engine gives, every window runs once, and growth
+    finds its page: neither a settled chain nor a preempted row pays for
+    it, and `_make_room` is reached only to take back a waiter's pin."""
+    arrivals = _random_arrivals(seed)
+    want, _ = _offer(_engine(max_inflight=6), arrivals)
+    tight = _engine(pool_pages=24, max_inflight=6)
+    room = tight._make_room
+
+    def only_for_a_pin(req):
+        """A waiter that pins cached pages the rows were promised gives
+        them back; nothing else may ask for room."""
+        assert any(r.pages for r in tight._waiting), \
+            f"growth of request {req.rid} found the pool dry"
+        assert room(req)
+        assert not any(r.pages for r in tight._waiting)
+        return True
+    tight._make_room = only_for_a_pin
+    tight._preempt = lambda req: pytest.fail(f"request {req.rid} preempted")
+    done, _ = _offer(tight, arrivals)
+    assert [r.out_tokens for r in done] == [r.out_tokens for r in want]
+    st = tight.stats
+    assert st["preemptions"] == 0 and st["preempted_tokens"] == 0
+    assert st["prefills"] == len(arrivals)
+    assert st["timeline_admissions"] >= 1
+    assert st["peak_pages_in_use"] <= 24
+    assert tight.leaked_pages() == 0 and tight.audit_pool() == ([], [])
+
+
+@pytest.mark.parametrize("early, dry", [(0, False), (1, True)])
+def test_rows_counted_gone_a_step_early_find_the_pool_dry(
+        early, dry, monkeypatch):
+    """The timeline's two margins apart. Without the token of lookahead
+    (the ladder's second rung serves so) the pages reckoned are the pages
+    written, and growth still finds its page; with a row's pages counted
+    back in the pool `early` = 1 step sooner, at the step after its last
+    write, when the engine still holds that step unread, it finds the pool
+    dry."""
+    row_ahead = ServingEngine._row_ahead
+
+    def bare(self, r, lookahead):
+        gone, at, *rest = row_ahead(self, r, lookahead)
+        return (gone - early, at - lookahead, *rest)
+
+    monkeypatch.setattr(ServingEngine, "_row_ahead", bare)
+    found_dry = 0
+    for seed in (57, 2147483659):
+        tight = _engine(pool_pages=24, max_inflight=6)
+        room = tight._make_room
+
+        def noted(req, tight=tight, room=room):
+            nonlocal found_dry
+            found_dry += not any(r.pages for r in tight._waiting)
+            return room(req)
+        tight._make_room = noted
+        _offer(tight, _random_arrivals(seed))
+        assert tight.stats["timeline_admissions"] >= 1
+    assert bool(found_dry) == dry
+
+
+def test_the_timeline_admits_short_rows_beside_a_long_one():
+    """A row that ends at 18 pages and eight that end at 3, offered to 22
+    pages: the sum of the ends lets one short row stand beside the long
+    one at a time; the timeline lets in all that the long row's pages of
+    the moment leave room for, since each is gone before it needs them.
+    The same tokens, in fewer steps, from a fuller pool."""
+    arrivals = [(0, _prompts((9,), seed=20)[0], 60)] + [
+        (0, p, 6) for p in _prompts((5,) * 8, seed=21)]
+    eng_sum = _by_the_sum_alone(_engine(pool_pages=22, max_inflight=8))
+    want, ran_sum = _offer(eng_sum, arrivals)
+    eng = _engine(pool_pages=22, max_inflight=8)
+    done, ran = _offer(eng, arrivals)
+    assert [r.out_tokens for r in done] == [r.out_tokens for r in want]
+    assert eng_sum.stats["timeline_admissions"] == 0
+    assert eng_sum.stats["growth_held_admissions"] >= 1
+    assert eng.stats["timeline_admissions"] >= 4
+    assert max(map(len, ran)) > max(map(len, ran_sum)) == 2
+
+    def mean_occupancy(e):
+        return e.stats["occupancy_sum"] / e.stats["occupancy_n"]
+    assert mean_occupancy(eng) > mean_occupancy(eng_sum)
+    # the short rows are done in fewer steps; the long row's are its own
+
+    def steps_with_a_short_row(ran):
+        return sum(len(rids) > 1 for rids in ran)
+    assert steps_with_a_short_row(ran) < steps_with_a_short_row(ran_sum)
+    assert len(ran) == len(ran_sum)
+    for e in (eng, eng_sum):
+        assert e.stats["preemptions"] == 0
+        assert e.leaked_pages() == 0 and e.audit_pool() == ([], [])
+
+
+def test_a_roomy_pool_never_reckons_the_timeline():
+    """Where the sum of the ends fits, the head is admitted by it as
+    before: the timeline is not asked, and the rows that run after every
+    step are those of the rule of ISSUE 55."""
+    arrivals = _random_arrivals(5)
+    _, ran_sum = _offer(_by_the_sum_alone(_engine(max_inflight=6)), arrivals)
+    eng = _engine(max_inflight=6)
+    eng._ends_fit = lambda *a: pytest.fail("the timeline was reckoned")
+    _, ran = _offer(eng, arrivals)
+    assert ran == ran_sum
+    assert eng.stats["timeline_admissions"] == 0
+    assert eng.stats["growth_held_admissions"] == 0
+    assert eng.stats["pool_bound_admissions"] == 0
 
 
 # -- the loop ----------------------------------------------------------------
