@@ -20,6 +20,12 @@ white_list = {
     "conv2d_transpose",
     # MXU carrier with fp32 softmax statistics inside the kernel
     "fused_attention",
+    # a training decoder's grouped expert products and its head
+    # (ops/decoder_train_ops.py): bfloat16 operands, float32 accumulation;
+    # SiLU, the combine weights (fp16_utils._STATE_SLOTS keeps `Cw` out of
+    # the cast), the log-sum-exp and the loss are float32 inside
+    "moe_experts",
+    "lm_head_loss",
 }
 
 black_list = {
@@ -35,6 +41,12 @@ black_list = {
     "reduce_sum",
     "reduce_mean",
     "squared_l2_norm",
+    # a training decoder's norms, rotary embeddings and router: float32 in
+    # and out, so that a token's choice of experts does not hang on a
+    # bfloat16 rounding of the router's own arithmetic
+    "rms_norm",
+    "rotary_embedding",
+    "moe_router",
 }
 
 # layer_norm/softmax/batch_norm are gray, not black (a departure from the

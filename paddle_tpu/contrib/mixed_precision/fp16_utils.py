@@ -22,7 +22,10 @@ _LOW = {"bfloat16": "@BF16", "float16": "@FP16"}
 # these: a bf16 EMA update `mean*0.9 + x*0.1` rounds away increments below
 # ~0.4% of the running value, so the statistics quantize/stall over
 # training, and the "fp32" stat vars would flip dtype in checkpoints.
+# Also a white op's float32 side input that is no matmul operand: the
+# router's combine weights, which the experts op applies in float32.
 _STATE_SLOTS = {
+    "moe_experts": {"Cw"},
     "batch_norm": {"Mean", "Variance"},
     "conv2d_bn": {"Mean", "Variance"},
     "fake_quantize_dequantize_moving_average_abs_max": {"InScale"},

@@ -816,6 +816,19 @@ class Executor:
         for n, v in zip(comp.extra_w, new_extra):
             scope.set_var(n, v)
 
+        if program.device_counters:
+            # a step's count vectors (layers.device_counter) go to the
+            # registry as the device arrays they are: `snapshot()` reads
+            # them, after whatever window the caller measures
+            src = getattr(comp, "counter_src", None)
+            if src is None:     # resolve once per compiled entry
+                src = comp.counter_src = [
+                    (comp.extra_w.index(n), series)
+                    for n, series in program.device_counters.items()
+                    if n in comp.extra_w]
+            for idx, series in src:
+                obs.counter_defer(series, new_extra[idx])
+
         if emb_engine is not None and emb_ticket is not None:
             # hand the step's evicted-row output handles to the engine (no
             # sync — write-back lands when the device array materializes)

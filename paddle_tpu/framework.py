@@ -418,6 +418,10 @@ class Program:
         self.random_seed = 0
         self._version = 0  # bumped on mutation; part of the executor compile key
         self._lr_schedulers = []  # populated by learning_rate_scheduler layers
+        # persistable var -> [(metric, labels, index)]: vectors of one
+        # step's counts that ops write and the executor hands to the
+        # registry as device handles (layers.device_counter)
+        self.device_counters: dict = {}
 
     # -- block management ---------------------------------------------------
     @property
@@ -459,6 +463,7 @@ class Program:
         p.random_seed = self.random_seed
         p._version = 0
         p._lr_schedulers = list(self._lr_schedulers)
+        p.device_counters = dict(self.device_counters)
         for blk in self.blocks:
             nb = Block(p, blk.idx, blk.parent_idx)
             p.blocks.append(nb)
@@ -493,6 +498,7 @@ class Program:
         p.random_seed = d.get("random_seed", 0)
         p._version = 0
         p._lr_schedulers = []
+        p.device_counters = {}      # not serialised: a loaded program counts none
         for bd in d["blocks"]:
             b = Block(p, bd["idx"], bd["parent_idx"])
             p.blocks.append(b)

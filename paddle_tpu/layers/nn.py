@@ -110,6 +110,12 @@ __all__ = [
     "add_position_encoding",
     "fused_attention",
     "ring_attention",
+    "rms_norm",
+    "rotary_embedding",
+    "moe_router",
+    "moe_experts",
+    "lm_head_loss",
+    "device_counter",
     "nce",
     "hsigmoid",
     "warpctc",
@@ -1111,14 +1117,19 @@ def lod_reset(x, y=None, target_lod=None):
 
 
 def fused_attention(q, k, v, bias=None, causal=False, sm_scale=None,
-                    use_pallas=False, name=None):
+                    use_pallas=False, window=0, stats=None, name=None):
     """Fused scaled-dot-product attention over [B, nh, S, dh] tensors —
     one op boundary for the whole QK^T -> softmax -> PV block, dispatched by
     measurement (ops/attention_ops.py): XLA fusion at train sizes, the
     custom short-seq Pallas kernel with `use_pallas` (O(S) memory), jax's
     bundled flash kernel for long sequences. The reference builds attention
     from matmul+softmax ops (nets.py:345) — this is the TPU-native fused
-    equivalent."""
+    equivalent. K and V may carry fewer heads than Q (grouped-query
+    attention) and `window` > 0 lets a query see only the `window` last keys
+    up to its own: such calls run block by block on the chip, forward and
+    backward, skipping the key blocks outside the band. `stats`: a
+    persistable float32 [2] variable the op writes its visited and causal
+    key-block counts to (`device_counter`)."""
     helper = LayerHelper("fused_attention", name=name)
     if sm_scale is None:
         sm_scale = float(q.shape[-1]) ** -0.5
@@ -1126,12 +1137,106 @@ def fused_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]
-    helper.append_op(
-        "fused_attention", inputs, {"Out": [out]},
-        {"causal": causal, "sm_scale": float(sm_scale),
-         "use_pallas": bool(use_pallas)},
-    )
+    outputs = {"Out": [out]}
+    attrs = {"causal": causal, "sm_scale": float(sm_scale),
+             "use_pallas": bool(use_pallas)}
+    if window:
+        attrs["window"] = int(window)
+    if stats is not None:
+        outputs["Stats"] = [stats]
+    helper.append_op("fused_attention", inputs, outputs, attrs)
     return out
+
+
+def device_counter(name, size, series):
+    """A persistable float32 `[size]` variable an op writes its counts of
+    ONE step to (a `Stats` output), read as registry counters: `series` is
+    a list of `(metric, labels, index)`, element `index` of the vector
+    counting into `metric{labels}`. The executor hands every step's vector
+    to the registry as a device handle; `observability.snapshot()` sums
+    them, so nothing is read while steps are in flight."""
+    helper = LayerHelper("device_counter")
+    var = helper.create_global_variable([int(size)], "float32",
+                                        persistable=True, name=name)
+    helper.main_program.device_counters[var.name] = [
+        (str(metric), dict(labels), int(index))
+        for metric, labels, index in series]
+    return var
+
+
+def rms_norm(x, epsilon=1e-6, param_attr=None, name=None):
+    """`x / sqrt(mean(x^2) + epsilon) * scale` over the last axis, float32
+    (scale initialised to one)."""
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(param_attr, [int(x.shape[-1])],
+                                    "float32",
+                                    default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op("rms_norm", {"X": [x], "Scale": [scale]},
+                     {"Out": [out]}, {"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(x, theta, yarn=(), name=None):
+    """Rotate-half rotary embedding of x [B, S, n, dh] at positions 0..S-1
+    -> [B, n, S, dh] float32; `yarn` = (factor, original context, beta_fast,
+    beta_slow, attention factor) or ()."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op("rotary_embedding", {"X": [x]}, {"Out": [out]},
+                     {"theta": float(theta),
+                      "yarn": [float(v) for v in yarn]})
+    return out
+
+
+def moe_router(x, num_experts, top_k, param_attr=None, name=None):
+    """Softmax router over `num_experts`: the combine weights [..., E] of
+    the `top_k` most probable experts, renormalised, float32."""
+    helper = LayerHelper("moe_router", name=name)
+    w = helper.create_parameter(param_attr,
+                                [int(x.shape[-1]), int(num_experts)],
+                                "float32")
+    cw = helper.create_variable_for_type_inference("float32")
+    helper.append_op("moe_router", {"X": [x], "W": [w]}, {"Cw": [cw]},
+                     {"top_k": int(top_k)})
+    return cw
+
+
+def moe_experts(x, cw, experts_held, expert_width, top_k, first_expert=0,
+                gate_attr=None, up_attr=None, down_attr=None, stats=None,
+                name=None):
+    """The routed SwiGLU experts `first_expert .. first_expert +
+    experts_held` of those `cw` [..., E] weighs: `sum_e cw_e W_down,e
+    (silu(W_gate,e x) * W_up,e x)` over the held ones, float32, dropless
+    (ops/decoder_train_ops.py)."""
+    helper = LayerHelper("moe_experts", name=name)
+    H, E, F = int(x.shape[-1]), int(experts_held), int(expert_width)
+    wg = helper.create_parameter(gate_attr, [E, H, F], "float32")
+    wu = helper.create_parameter(up_attr, [E, H, F], "float32")
+    wd = helper.create_parameter(down_attr, [E, F, H], "float32")
+    out = helper.create_variable_for_type_inference("float32")
+    outputs = {"Out": [out]}
+    if stats is not None:
+        outputs["Stats"] = [stats]
+    helper.append_op(
+        "moe_experts",
+        {"X": [x], "Cw": [cw], "WGate": [wg], "WUp": [wu], "WDown": [wd]},
+        outputs, {"top_k": int(top_k), "first_expert": int(first_expert)})
+    return out
+
+
+def lm_head_loss(x, ids, vocab_size, param_attr=None, name=None):
+    """An untied head [H, vocab_size] over x [B, S, H] and the mean
+    next-token cross-entropy against `ids` [B, S] (position s is scored on
+    ids[s + 1]; the last has no label), without the [B, S, V] logits."""
+    helper = LayerHelper("lm_head_loss", name=name)
+    w = helper.create_parameter(param_attr,
+                                [int(x.shape[-1]), int(vocab_size)],
+                                "float32")
+    loss = helper.create_variable_for_type_inference("float32")
+    helper.append_op("lm_head_loss", {"X": [x], "W": [w], "Ids": [ids]},
+                     {"Loss": [loss]}, {})
+    return loss
 
 
 def ring_attention(q, k, v, causal=False, sm_scale=None, ring_id=0, name=None):

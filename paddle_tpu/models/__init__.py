@@ -5,6 +5,7 @@ MNIST MLP, ResNet image classification, Transformer/BERT, word2vec, DeepFM.
 Each builder appends to the current default main/startup programs (use
 `program_guard` for isolation) and returns the named output Variables.
 """
+from . import decoder_moe  # noqa: F401
 from . import deepfm  # noqa: F401
 from . import mlp  # noqa: F401
 from . import resnet  # noqa: F401

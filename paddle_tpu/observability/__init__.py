@@ -26,7 +26,8 @@ from .exporters import (  # noqa: F401
     JsonlWriter, jsonl_line, parse_prometheus, prometheus_text,
     start_http_exporter, write_prometheus)
 from .registry import (  # noqa: F401
-    MetricsRegistry, attach_sink, base_name, counter_inc, detach_sink,
+    MetricsRegistry, attach_sink, base_name, counter_defer, counter_inc,
+    detach_sink,
     enabled, event, gauge_set, gc_pause_seconds, histogram_observe,
     note_import, registry, reset, snapshot, span, spanned, stage_counters,
     stage_record)
@@ -45,7 +46,8 @@ def export_prometheus(path: str | None = None) -> str | None:
     return write_prometheus(p, snapshot())
 
 __all__ = [
-    "MetricsRegistry", "registry", "enabled", "counter_inc", "gauge_set",
+    "MetricsRegistry", "registry", "enabled", "counter_inc", "counter_defer",
+    "gauge_set",
     "histogram_observe", "event", "span", "spanned", "snapshot",
     "stage_record",
     "stage_counters", "reset", "attach_sink", "detach_sink", "base_name",

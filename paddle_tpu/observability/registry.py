@@ -40,6 +40,8 @@ import threading
 import time
 from collections import deque
 
+import numpy as np
+
 from .. import flags
 from . import schema as _schema
 from .compile_events import CompileWatch
@@ -321,6 +323,8 @@ class MetricsRegistry:
         self._lock = threading.Lock()
         self._schema: dict[str, dict] = {}
         self._counters: dict[tuple, float] = {}
+        # (series, vector): counts still on the device (`counter_defer`)
+        self._deferred: list = []
         self._gauges: dict[tuple, float] = {}
         self._hists: dict[tuple, _Histogram] = {}
         self._stages: dict[str, list] = {}  # name -> [events, seconds]
@@ -357,6 +361,24 @@ class MetricsRegistry:
         with self._lock:
             self._note(name)
             self._counters[key] = self._counters.get(key, 0) + value
+
+    def counter_defer(self, series, vector) -> None:
+        """Counts that a device program produced and nobody has read:
+        `vector` is a device array, `series` a list of `(name, labels,
+        index)`, element `index` counting into `name{labels}`. Held as it
+        is (no sync, no copy) until `snapshot()` reads it to the host, or
+        `reset()` of a prefix every one of its names starts with drops it."""
+        with self._lock:
+            self._deferred.append((series, vector))
+
+    def _settle(self) -> None:
+        """Read the deferred vectors to the host and count them."""
+        with self._lock:
+            pending, self._deferred = self._deferred, []
+        for series, vector in pending:
+            values = np.asarray(vector, np.float64).reshape(-1)
+            for name, labels, index in series:
+                self.counter_inc(name, float(values[index]), labels)
 
     def gauge_set(self, name: str, value: float,
                   labels: dict | None = None) -> None:
@@ -449,6 +471,7 @@ class MetricsRegistry:
     def snapshot(self, reset: bool = False) -> dict:
         """One atomic read of everything; reset=True zeroes the store under
         the same lock (no event can land between the read and the clear)."""
+        self._settle()
         with self._lock:
             out = {
                 "counters": {_fmt(k): v for k, v in self._counters.items()},
@@ -474,6 +497,9 @@ class MetricsRegistry:
         `prefix`) without touching the event ring or the schema — the
         measurement boundary for scoped runs (bench arms, warmup passes)."""
         with self._lock:
+            self._deferred = [] if prefix is None else [
+                d for d in self._deferred
+                if not all(name.startswith(prefix) for name, _, _ in d[0])]
             if prefix is None:
                 self._counters.clear()
                 self._gauges.clear()
@@ -622,6 +648,10 @@ def registry() -> MetricsRegistry:
 
 def counter_inc(name, value=1, labels=None):
     registry().counter_inc(name, value, labels)
+
+
+def counter_defer(series, vector):
+    registry().counter_defer(series, vector)
 
 
 def gauge_set(name, value, labels=None):

@@ -580,6 +580,28 @@ DECLARED: list[tuple] = [
      "train_from_dataset steady-state batch rate", ()),
     ("train.jit_compiles", COUNTER,
      "whole-block XLA compiles observed by jit_compile_counter", ()),
+    # -- a training decoder's own counts (models/decoder_moe.py): vectors an
+    # op writes every step, handed to the registry as device arrays
+    # (`counter_defer`) and read by `snapshot()`, never inside a window
+    ("train.moe.assignments", COUNTER,
+     "(token, expert) pairs the routers made, every layer's (tokens x "
+     "top-k x layers)", ()),
+    ("train.moe.held_assignments", COUNTER,
+     "those whose expert this chip holds: over train.moe.assignments the "
+     "held-route share", ()),
+    ("train.moe.dropped", COUNTER,
+     "held pairs the grouped products were not given: 0, the experts op "
+     "has no capacity", ()),
+    ("train.moe.expert_tokens", COUNTER,
+     "tokens each held expert computed (max over mean: the imbalance)",
+     ("layer", "expert")),
+    ("train.attn.key_blocks_visited", COUNTER,
+     "(query block, key block) pairs the attention op computed, by layer "
+     "kind (sliding | full); the dense paths off the chip visit every one",
+     ("kind",)),
+    ("train.attn.key_blocks_causal", COUNTER,
+     "those a causal pass without a window would compute: visited over "
+     "causal is what a sliding band skips", ("kind",)),
     # -- numeric guardrails (resilience/guardrails.py) ----------------------
     ("guard.events", COUNTER,
      "StepGuard verdicts by action (skip/rewind/...)", ("action",)),
@@ -687,6 +709,9 @@ PIECES = frozenset({
     "exit_gate",  # ... the norm that closes a visit and the gate's
                   # probability of stopping there
     "head",       # final norm and the vocabulary product
+    "dispatch",   # a training step's experts (ops/decoder_train_ops.py):
+                  # the (token, expert) pairs sorted and their rows gathered
+    "combine",    # ... the pairs' products weighed back into their tokens
 })
 
 

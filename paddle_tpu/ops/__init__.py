@@ -20,6 +20,7 @@ from . import collective_ops  # noqa: F401
 from . import control_flow_ops  # noqa: F401
 from . import attention_ops  # noqa: F401
 from . import cca_moe_ops  # noqa: F401
+from . import decoder_train_ops  # noqa: F401
 from . import vision_ops  # noqa: F401
 from . import crf_ops  # noqa: F401
 from . import distributed_ops  # noqa: F401

@@ -53,24 +53,139 @@ def _reference_attention(q, k, v, bias=None, causal=False, sm_scale=1.0):
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), v)
 
 
-def grouped_query_attention(q, k, v, causal=False, sm_scale=1.0):
+def band_mask(sq: int, sk: int, causal: bool, window: int):
+    """[sq, sk] bool or None: query i (the LAST `sq` of `sk` positions) sees
+    key j at or before it where `causal`, and no further back than
+    `window - 1` positions where `window` > 0."""
+    if not causal and not window:
+        return None
+    mask = jnp.tril(jnp.ones((sq, sk), jnp.bool_), sk - sq) if causal \
+        else jnp.ones((sq, sk), jnp.bool_)
+    if window:
+        qp = jnp.arange(sq, dtype=jnp.int32)[:, None] + (sk - sq)
+        kp = jnp.arange(sk, dtype=jnp.int32)[None, :]
+        mask &= qp - kp < window
+    return mask
+
+
+def grouped_query_attention(q, k, v, causal=False, sm_scale=1.0, window=0):
     """Dense attention of `nh` query heads over `nkv` key/value heads
     (`nh % nkv == 0`; query head h reads KV head `h // (nh // nkv)`), without
     repeating K/V. q [B, nh, Sq, dh], k/v [B, nkv, Sk, dh]; scores and
     softmax float32 whatever the operands (a bfloat16 score rounds a logit
-    of 11 by 0.02)."""
+    of 11 by 0.02). `window` > 0: a query sees the `window` last keys up to
+    its own (a sliding layer)."""
     B, nh, sq, dh = q.shape
     nkv, sk = k.shape[1], k.shape[2]
     qg = q.reshape(B, nkv, nh // nkv, sq, dh)
     scores = jnp.einsum("bjgqd,bjkd->bjgqk", qg, k,
                         preferred_element_type=jnp.float32) * sm_scale
-    if causal:
-        mask = jnp.tril(jnp.ones((sq, sk), jnp.bool_), sk - sq)
+    mask = band_mask(sq, sk, causal, window)
+    if mask is not None:
         scores = jnp.where(mask, scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bjgqk,bjkd->bjgqd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return out.reshape(B, nh, sq, dh).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Blockwise grouped-query attention with a backward pass (a training step at
+# thousands of positions): jax's bundled splash attention
+# ---------------------------------------------------------------------------
+
+# tests flip this to run the bundled kernels through the Pallas interpreter
+BLOCKWISE_INTERPRET = False
+# queries and keys of one grid step, forward and backward, behind a window
+# and without one. Swept on the chip at 2 x 8,192 positions, 32 heads over 4
+# of 128, forward + backward (PR 58): behind a window of 1,024 blocks of 512
+# take 14.9 ms (a query block visits 3 key blocks: 1,536 keys for the
+# 1,024-1,535 it sees), 1,024 take 16.7, 256 take 24.1; the full causal pass
+# takes 44.2 ms at 512 and 37.4 at 1,024
+BLOCKWISE_BLOCK = 512
+BLOCKWISE_BLOCK_FULL = 1024
+
+
+def blockwise_supported(q_shape, k_shape) -> bool:
+    """The bundled kernel's gate: self-attention (as many keys as queries)
+    over whole blocks of 128, heads of whole lanes."""
+    sq, sk, dh = q_shape[2], k_shape[2], q_shape[3]
+    return (sq == sk and sq % 128 == 0 and dh % 128 == 0
+            and q_shape[1] % k_shape[1] == 0)
+
+
+def _blockwise_block(s: int, window: int = 0) -> int:
+    """Positions of one block: the largest that divides `s`, `s` itself
+    where none does (the dense paths: one block)."""
+    first = BLOCKWISE_BLOCK if window else BLOCKWISE_BLOCK_FULL
+    return next((b for b in (first, 512, 256, 128) if s % b == 0), s)
+
+
+def key_blocks(s: int, block: int, causal: bool, window: int) -> tuple:
+    """(key blocks a blockwise pass over `s` positions visits, those a
+    causal pass without a window would): a block is visited where any of
+    its (query, key) pairs is seen."""
+    n = s // block
+    visited = causal_blocks = 0
+    for i in range(n):
+        last_q, first_q = i * block + block - 1, i * block
+        for j in range(n):
+            first_k, last_k = j * block, j * block + block - 1
+            seen = not causal or first_k <= last_q
+            causal_blocks += first_k <= last_q
+            if window and first_q - last_k >= window:
+                seen = False
+            visited += seen
+    return visited, causal_blocks
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(s: int, group: int, causal: bool, window: int,
+                   block: int, interpret: bool):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+
+    if window:
+        one = sm.LocalMask((s, s), (window - 1, 0 if causal else None), 0)
+    elif causal:
+        one = sm.CausalMask((s, s))
+    else:
+        one = sm.FullMask((s, s))
+    sizes = sk.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    # the mask's block tables stay numpy until a trace embeds them: a cached
+    # kernel must not hold arrays of the trace that first built it
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mqa_single_device(
+            sm.MultiHeadMask([one] * group), block_sizes=sizes,
+            interpret=interpret)
+
+
+def blockwise_attention(q, k, v, causal=True, sm_scale=1.0, window=0):
+    """q [B, nh, S, dh] over k/v [B, nkv, S, dh], block by block with an
+    online softmax (float32 statistics), forward and backward, never a
+    `[S, S]` score: the `nh // nkv` query heads of a key/value head run as
+    one multi-query call of jax's bundled splash kernel, whose block tables
+    skip the key blocks a causal or sliding mask leaves out. The kernel
+    takes no scale: it is folded into q."""
+    B, nh, s, dh = q.shape
+    nkv = k.shape[1]
+    kernel = _splash_kernel(s, nh // nkv, bool(causal), int(window),
+                            _blockwise_block(s, window),
+                            bool(BLOCKWISE_INTERPRET))
+    qs = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
+    out = jax.vmap(jax.vmap(kernel))(
+        qs.reshape(B, nkv, nh // nkv, s, dh), k, v)
+    return out.reshape(B, nh, s, dh)
+
+
+def _blockwise_runs(q_shape, k_shape) -> bool:
+    from .pallas_kernels import workbench
+
+    return ((workbench.on_tpu() or BLOCKWISE_INTERPRET)
+            and blockwise_supported(q_shape, k_shape))
 
 
 def _block_multiple_ok(s: int) -> bool:
@@ -247,22 +362,44 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=1.0,
 @register_op("fused_attention")
 def fused_attention(ctx: ExecContext):
     """inputs: Q, K, V [B, nh, S, dh], optional Bias (broadcastable to
-    [B, nh, Sq, Sk]); attrs: causal, sm_scale. Output: [B, nh, Sq, dh].
-    K and V may carry fewer heads than Q (grouped-query attention)."""
+    [B, nh, Sq, Sk]); attrs: causal, sm_scale, window (0: none; else a query
+    sees the `window` last keys up to its own). Output: Out [B, nh, Sq, dh]
+    and Stats float32 [2]: the key blocks this call visited and those a
+    causal pass would, times the batch (the dense paths visit every block).
+    K and V may carry fewer heads than Q (grouped-query attention); such a
+    call, and one with a window, runs block by block on the chip
+    (`blockwise_attention`) and dense elsewhere."""
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
     bias = ctx.input("Bias") if ctx.has_input("Bias") else None
-    if k.shape[1] != q.shape[1]:
+    causal = ctx.attr("causal", False)
+    window = int(ctx.attr("window", 0) or 0)
+    sm_scale = ctx.attr("sm_scale", 1.0)
+    blockwise = False
+    if k.shape[1] != q.shape[1] or window:
         if bias is not None:
             raise NotImplementedError(
-                "fused_attention: grouped-query heads take no Bias")
-        return {"Out": grouped_query_attention(
-            q, k, v, causal=ctx.attr("causal", False),
-            sm_scale=ctx.attr("sm_scale", 1.0))}
-    out = flash_attention(q, k, v, bias,
-                          causal=ctx.attr("causal", False),
-                          sm_scale=ctx.attr("sm_scale", 1.0),
-                          use_pallas=ctx.attr("use_pallas", False))
-    return {"Out": out.astype(q.dtype)}
+                "fused_attention: grouped-query heads and windows take no "
+                "Bias")
+        blockwise = _blockwise_runs(q.shape, k.shape)
+        if blockwise:
+            _note_dispatch("dense", "splash_bundled", "splash_bundled")
+            out = blockwise_attention(q, k, v, causal, sm_scale, window)
+        else:
+            out = grouped_query_attention(q, k, v, causal=causal,
+                                          sm_scale=sm_scale, window=window)
+    else:
+        out = flash_attention(q, k, v, bias, causal=causal,
+                              sm_scale=sm_scale,
+                              use_pallas=ctx.attr("use_pallas", False))
+    outs = {"Out": out.astype(q.dtype)}
+    if ctx.op.outputs.get("Stats"):
+        sq = q.shape[2]
+        block = _blockwise_block(sq, window)
+        visited = key_blocks(sq, block, causal and blockwise,
+                             window if blockwise else 0)[0]
+        outs["Stats"] = q.shape[0] * jnp.asarray(
+            [visited, key_blocks(sq, block, True, 0)[1]], jnp.float32)
+    return outs
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ which would be the reference's error, not the kernel's). Errors are
 max-abs differences relative to the reference's max-abs value.
 
     python tools/kernel_check.py        # exit 0 = all matched, on a TPU
+    python tools/kernel_check.py --only splash_bundled,megablox_bundled
 
 Exits 2 off the chip, 1 on any mismatch, compile failure or missing case.
 """
@@ -1030,6 +1031,143 @@ def _flash_bundled_case():
         (q, k, v), 3, "bfloat16")
 
 
+def _seconds(fn, *a, n=10):
+    import time
+
+    out = jax.block_until_ready(fn(*a))
+    t = time.perf_counter()
+    for _ in range(n):
+        last = fn(*a)
+    jax.block_until_ready(last)
+    return out, (time.perf_counter() - t) / n
+
+
+_PEAK_FLOPS = 197e12        # benchmark/peaks.json, "TPU v5 lite"
+
+
+def _train_attention_case(window: int):
+    """Not a workbench kernel but the arm of `fused_attention` a training
+    decoder runs (jax's bundled splash attention behind
+    `attention_ops.blockwise_attention`): checked against the dense form at
+    2,048 positions, forward and backward, and timed at the training cell's
+    shape (2 rows x 8,192 positions, 32 query heads over 4 of 128), forward
+    and forward + backward, against the matrix unit's time for the pairs a
+    query SEES (4 x 4096 FLOPs a pair forward, 12 x 4096 with the backward:
+    the kernels compute whole blocks and the backward computes the scores
+    again, so 100% is not reachable)."""
+    from paddle_tpu.ops import attention_ops as ao
+
+    nh, nkv, dh = 32, 4, 128
+    ks = jax.random.split(jax.random.PRNGKey(58 + window), 3)
+    small = [_rand(k, (1, n, 2048, dh), "bfloat16")
+             for k, n in zip(ks, (8, 1, 1))]
+    res = _compare(
+        lambda q, k, v: ao.blockwise_attention(q, k, v, True, dh ** -0.5,
+                                               window),
+        lambda q, k, v: ao.grouped_query_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), True, dh ** -0.5,
+            window).astype(q.dtype),
+        tuple(small), 3, "bfloat16")
+    B, S = 2, 8192
+    q, k, v = (_rand(kk, (B, n, S, dh), "bfloat16")
+               for kk, n in zip(ks, (nh, nkv, nkv)))
+    fwd = jax.jit(lambda q, k, v: ao.blockwise_attention(
+        q, k, v, True, dh ** -0.5, window))
+    both = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        ao.blockwise_attention(q, k, v, True, dh ** -0.5, window)
+        .astype(jnp.float32) * 0.5), (0, 1, 2)))
+    _, fwd_s = _seconds(fwd, q, k, v)
+    _, both_s = _seconds(both, q, k, v)
+    w = window or S
+    pairs = B * (w * (w + 1) // 2 + (S - w) * w)
+    block = ao._blockwise_block(S, window)
+    visited, causal = ao.key_blocks(S, block, True, window)
+    res.update({
+        "us_fwd": fwd_s * 1e6, "us_fwd_bwd": both_s * 1e6,
+        "pairs_seen": pairs, "pairs_visited": B * visited * block * block,
+        "key_blocks": [visited, causal],
+        "roofline_fwd_pct": 100 * 4 * nh * dh * pairs / _PEAK_FLOPS / fwd_s,
+        "roofline_fwd_bwd_pct":
+            100 * 12 * nh * dh * pairs / _PEAK_FLOPS / both_s})
+    return res
+
+
+def _train_experts_case():
+    """The training decoder's grouped expert products
+    (`decoder_train_ops`, jax's bundled megablox) at what one chunk of the
+    training cell computes: 4,096 tokens, top-8 of 64 with 16 held, 2304 ->
+    896 -> 2304 in bfloat16. The whole layer, forward and backward, against
+    the dense loop over the experts; then the three kinds of product alone
+    (forward, dX, dW), each against the matrix unit's time for the live
+    rows' 2 x 2304 x 896 FLOPs."""
+    from paddle_tpu.ops import decoder_train_ops as dt
+    from paddle_tpu.ops.decoder_common import topk_router_fn
+
+    T, H, F, E, held, k = 4096, 2304, 896, 64, 16, 8
+    ks = jax.random.split(jax.random.PRNGKey(58), 6)
+    z = _rand(ks[0], (T, H), "bfloat16")
+    _, cw = topk_router_fn(_rand(ks[1], (T, H), "float32"),
+                           _rand(ks[2], (H, E), "float32", 2 * H ** -0.5), k)
+    cw = cw[:, :held]
+    wg = _rand(ks[3], (held, H, F), "bfloat16", H ** -0.5)
+    wu = _rand(ks[4], (held, H, F), "bfloat16", H ** -0.5)
+    wd = _rand(ks[5], (held, F, H), "bfloat16", F ** -0.5)
+
+    def dense(z, wg, wu, wd, cw):
+        z, wg, wu, wd = (a.astype(jnp.float32) for a in (z, wg, wu, wd))
+        out = 0
+        for e in range(held):
+            g = z @ wg[e]
+            out = out + cw[:, e, None] * ((jax.nn.silu(g) * (z @ wu[e]))
+                                          @ wd[e])
+        return out
+
+    ours = lambda z, wg, wu, wd, cw: dt.moe_experts_train_fn(  # noqa: E731
+        z, cw, wg, wu, wd, k)[0]
+    res = _compare(ours, dense, (z, wg, wu, wd, cw), 4, "bfloat16")
+    fwd = jax.jit(ours)
+    both = jax.jit(jax.grad(lambda *a: jnp.sum(ours(*a) * 0.5), (0, 1, 2, 3)))
+    _, fwd_s = _seconds(fwd, z, wg, wu, wd, cw)
+    _, both_s = _seconds(both, z, wg, wu, wd, cw)
+    zs, _, _, sizes, _, _ = jax.jit(
+        lambda z, cw: dt._plan(z, cw, k, jnp.bfloat16))(z, cw)
+    live = int(jnp.sum(sizes[:-1]))
+    dy = _rand(ks[1], (zs.shape[0], F), "bfloat16")
+    _, gmm_s = _seconds(jax.jit(lambda a, w, s: dt.grouped_matmul(
+        a, w, s, jnp.float32)), zs, wg, sizes)
+    _, dx_s = _seconds(jax.jit(lambda a, w, s: dt.grouped_matmul(
+        a, w, s, jnp.float32, transpose_rhs=True)), dy, wg, sizes)
+    _, dw_s = _seconds(jax.jit(lambda a, b, s: dt.grouped_matmul_t(
+        a, b, s, jnp.float32)), zs, dy, sizes)
+    one = 2 * live * H * F / _PEAK_FLOPS
+    res.update({
+        "held_assignments": live, "rows": int(zs.shape[0]),
+        "tiling": [list(dt._tiling("gmm", zs.shape[0], H, F)),
+                   list(dt._tiling("gmm", zs.shape[0], F, H)),
+                   list(dt._tiling("tgmm", zs.shape[0], H, F))],
+        "us_fwd": fwd_s * 1e6, "us_fwd_bwd": both_s * 1e6,
+        "roofline_fwd_pct": 100 * 3 * one / fwd_s,
+        "roofline_fwd_bwd_pct": 100 * 9 * one / both_s,
+        "us_product_fwd": gmm_s * 1e6, "us_product_dx": dx_s * 1e6,
+        "us_product_dw": dw_s * 1e6,
+        "roofline_product_pct": [100 * one / t
+                                 for t in (gmm_s, dx_s, dw_s)]})
+    return res
+
+
+# the two bundled kernels a training decoder runs (PR 58), at the training
+# cell's shapes: the yardstick a later change to either is held to
+TRAIN_DECODER = [
+    ("splash_bundled", "b2 s8192 nh32/4 dh128 bf16 window 1024 fwd, bwd",
+     lambda: _train_attention_case(1024)),
+    ("splash_bundled", "b2 s8192 nh32/4 dh128 bf16 full causal fwd, bwd",
+     lambda: _train_attention_case(0)),
+    ("megablox_bundled", "t4096 top8/64 held16 2304x896 bf16 fwd, dX, dW",
+     _train_experts_case),
+]
+
+
 def main() -> int:
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -1050,6 +1188,11 @@ def main() -> int:
                      for label, thunk in CASES[name](kernels[name])]
     plan.append(("flash_bundled", "b2 s2048 nh12 dh64 bf16 causal fwd+bwd",
                  _flash_bundled_case))
+    plan += TRAIN_DECODER
+    if "--only" in sys.argv[1:]:
+        only = sys.argv[sys.argv.index("--only") + 1].split(",")
+        plan = [p for p in plan if p[0] in only]
+        failed = []
     results = {}
     for name, label, thunk in plan:
         # a refused compile must not hide the verdicts of the other kernels:
